@@ -34,8 +34,7 @@ import time
 # bench JSON line uses; re-exported here for the historical import path
 # (tests/test_kv_quant.py pins bench.roofline_components).
 from production_stack_tpu.perf.roofline import (  # noqa: F401,E402
-    HBM_PEAK_PRESETS_GBPS,
-    PEAK_HBM_GBS,
+    peak_hbm_gbps,
     roofline_components,
 )
 
@@ -464,13 +463,55 @@ def bench_disagg(args) -> dict:
     }
 
 
+def probe_device() -> dict:
+    """{"platform", "kind", "count"} of the devices a run will find.
+
+    ``JAX_PLATFORMS=cpu`` ASKS for the CPU correctness path (tiny model, no
+    roofline share) and is answered without starting JAX. Anything else
+    asks JAX in a SUBPROCESS that exits before any engine starts — in
+    stack mode the engine children own the chips, and the in-process modes
+    (bench_engine, the speculative A/B) open them themselves afterwards.
+    A probe that fails, or that finds only a CPU nobody asked for, is an
+    error: it must never turn a chip run into a CPU run that still prints
+    tok/s."""
+    import subprocess
+
+    from benchmarks.stack import cpu_asked_for
+
+    if cpu_asked_for():
+        return {"platform": "cpu", "kind": "cpu", "count": 0}
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, json; d = jax.devices(); print(json.dumps("
+         "{'platform': d[0].platform, 'kind': d[0].device_kind, "
+         "'count': len(d)}))"],
+        capture_output=True, text=True, timeout=300,
+    )
+    lines = probe.stdout.strip().splitlines()
+    if probe.returncode != 0 or not lines:
+        raise SystemExit(
+            f"bench.py: device probe failed (rc={probe.returncode}); "
+            f"refusing to fall back to the CPU. Set JAX_PLATFORMS=cpu to "
+            f"ask for the CPU correctness path.\n{probe.stderr[-2000:]}"
+        )
+    device = json.loads(lines[-1])
+    if device["platform"] == "cpu":
+        raise SystemExit(
+            "bench.py: JAX found no accelerator. Set JAX_PLATFORMS=cpu to "
+            "ask for the CPU correctness path."
+        )
+    return device
+
+
 # ----------------------------------------------------------- multichip mode
 def _force_virtual_devices(args, need: int) -> None:
-    """CPU backend: expose a virtual multi-device platform to this process
-    AND every engine subprocess it spawns (they inherit the environment).
-    The same serving code path on a TPU slice sees the real devices and
-    needs none of this. Idempotent; pinned to 8 devices (the CI mesh and
-    every sweep point 1/2/4/8 fit it)."""
+    """CPU backend (asked for with JAX_PLATFORMS=cpu — args.backend is
+    "cpu" in no other case, see probe_device): expose a virtual
+    multi-device platform to this process AND every engine subprocess it
+    spawns (they inherit the environment). The same serving code path on
+    a TPU host sees the real chips and needs none of this. Idempotent;
+    pinned to 8 devices (the CI mesh and every sweep point 1/2/4/8 fit
+    it)."""
     if args.backend != "cpu" or need <= 1:
         return
     import re
@@ -488,9 +529,6 @@ def _force_virtual_devices(args, need: int) -> None:
         os.environ["XLA_FLAGS"] = flags.replace(
             m.group(0), f"--xla_force_host_platform_device_count={n}"
         )
-    # The ambient environment may re-point jax at a real accelerator
-    # platform; the virtual-device flag only exists on the CPU backend.
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def bench_multichip_sweep(args) -> dict:
@@ -710,9 +748,7 @@ def bench_engine(args) -> dict:
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.engine.engine import ServingEngine
 
-    import jax
-
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = args.backend != "cpu"
     cfg = EngineConfig(
         model=args.model,
         max_model_len=args.max_model_len,
@@ -813,9 +849,7 @@ def bench_speculative_ab(args) -> dict:
     from production_stack_tpu.engine.engine import ServingEngine
     from production_stack_tpu.engine.sampling import SamplingParams
 
-    import jax
-
-    on_tpu = jax.default_backend() not in ("cpu",)
+    on_tpu = args.backend != "cpu"
     n_spec = getattr(args, "speculative_num_tokens", 0) or 3
     tree_w = getattr(args, "speculative_tree_width", 1)
     if tree_w <= 1:
@@ -1017,14 +1051,15 @@ def main():
                     help="KV-cache storage dtype for the engines AND the "
                          "roofline's KV term (int8 halves decode KV bytes "
                          "— docs/PERF.md round 7)")
-    ap.add_argument("--hbm-peak-gbps", type=float,
-                    default=PEAK_HBM_GBS,
+    ap.add_argument("--hbm-peak-gbps", type=float, default=None,
                     help="peak HBM GB/s per chip for the roofline "
-                         "denominator (v5e 819, v5p 2765, v6e 1638 — "
-                         "docs/PERF.md presets; default "
-                         "$PSTPU_PEAK_HBM_GBS or the v5e preset). "
-                         "Recorded in the JSON line as hbm_peak_gbps so "
-                         "perfwatch only compares like-for-like rooflines")
+                         "denominator (v5e 819, v5p 2765, v6e 1638; "
+                         "default: $PSTPU_PEAK_HBM_GBS, else looked up by "
+                         "the probed device kind — an unknown TPU kind is "
+                         "an error, and a CPU run records no roofline "
+                         "share). Recorded in the JSON line as "
+                         "hbm_peak_gbps so perfwatch only compares "
+                         "like-for-like rooflines")
     # Per-user seeded chat history (reference shape: 20k tokens — request
     # --history-tokens 20000 --max-model-len 32768; the default fits the
     # default 8192 context). Makes kv_hit_rate a measured quantity.
@@ -1211,17 +1246,12 @@ def main():
             with open(val[1:]) as f:
                 setattr(args, attr, f.read())
 
-    # Probe the backend in a SUBPROCESS: in stack mode the parent must not
-    # initialize the device client — the engine subprocess owns the chip.
-    import subprocess
-
-    backend = subprocess.run(
-        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
-        capture_output=True, text=True, timeout=120,
-    ).stdout.strip() or "cpu"
-    on_tpu = backend not in ("", "cpu")
-    args.model = args.model or ("llama-1b" if on_tpu else "tiny-llama")
-    args.backend = backend
+    device = probe_device()
+    args.backend = device["platform"]
+    args.device_kind = device["kind"]
+    args.model = args.model or (
+        "tiny-llama" if args.backend == "cpu" else "llama-1b"
+    )
 
     if args.soak:
         from benchmarks.soak import assert_soak_bars, run_soak
@@ -1329,7 +1359,13 @@ def _result_line(args, res) -> dict:
     engines = 2 if getattr(args, "disagg", False) \
         else max(1, getattr(args, "num_engines", 1))
     num_chips = tp * engines
-    hbm_peak = float(getattr(args, "hbm_peak_gbps", PEAK_HBM_GBS))
+    # The denominator follows the device the run found; a CPU run has
+    # none, and its roofline-derived fields are null, not a share of some
+    # accelerator's peak (the byte components below need no peak).
+    hbm_peak = peak_hbm_gbps(
+        args.backend, getattr(args, "device_kind", args.backend),
+        getattr(args, "hbm_peak_gbps", None),
+    )
     comp = roofline_components(
         args.model, dtype_bytes, args.kv_cache_dtype, max(1, args.users),
         avg_ctx, peak_gbs=hbm_peak,
@@ -1341,9 +1377,11 @@ def _result_line(args, res) -> dict:
         "metric": res["metric"],
         "value": res["value"],
         "unit": "tok/s",
-        "vs_baseline": round(res["value"] / roofline, 3),
-        "roofline_tok_s": round(roofline, 1),
-        "hbm_bw_pct": round(100 * res["value"] / roofline, 1),
+        "vs_baseline": round(res["value"] / roofline, 3)
+        if roofline else None,
+        "roofline_tok_s": round(roofline, 1) if roofline else None,
+        "hbm_bw_pct": round(100 * res["value"] / roofline, 1)
+        if roofline else None,
         "hbm_peak_gbps": hbm_peak,
         # Roofline byte components (satellite: the KV term follows the
         # KV-cache dtype; weights stay in the compute dtype).

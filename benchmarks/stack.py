@@ -17,6 +17,80 @@ from dataclasses import dataclass, field
 from typing import List, Optional
 
 
+def cpu_asked_for() -> bool:
+    """``JAX_PLATFORMS=cpu``: the CPU (tests, rehearsals) is run on because
+    it was asked for, never because a device probe came back empty."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
+
+
+def local_tpu_chips() -> List[int]:
+    """Indices of the TPU chips this host exposes, found WITHOUT starting
+    JAX (the launcher must never open a chip its children need): the
+    per-chip device nodes the TPU driver creates (/dev/vfio/<n> on v5e and
+    later, /dev/accel<n> before). Empty when the CPU was asked for
+    (JAX_PLATFORMS=cpu) or the host has no chip."""
+    import glob
+    import re
+
+    if cpu_asked_for():
+        return []
+    # The node numbers are the kernel's (IOMMU groups under vfio), the
+    # chip indices libtpu takes are 0..n-1: count, don't parse.
+    nodes = [
+        path for path in glob.glob("/dev/vfio/*") + glob.glob("/dev/accel*")
+        if re.fullmatch(r"/dev/(?:vfio/|accel)\d+", path)
+    ]
+    return list(range(len(nodes)))
+
+
+def tpu_chip_env(chips: List[int]) -> dict:
+    """The libtpu environment that makes one child process own exactly
+    ``chips`` (host chip indices) and nothing else: a chip belongs to one
+    process at a time, so every engine of a multi-engine stack must be
+    told which chips are its own — left alone, each would open all of the
+    host's chips and use the first. The process is its own one-process
+    "slice": visible chips, the bounds of that chip set, and a private
+    port for libtpu's slice builder."""
+    bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}.get(len(chips))
+    if bounds is None:
+        raise ValueError(
+            f"cannot give one process {len(chips)} TPU chips {chips} "
+            f"(supported sets: 1, 2, 4, 8)"
+        )
+    port = free_port()
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+    }
+
+
+def _mesh_size(cmd: List[str]) -> int:
+    """Devices an engine argv's mesh occupies: the product of its LAST
+    --tensor/--sequence/--data-parallel-size values (argparse keeps the
+    last, so per-engine extras override the shared args here too)."""
+    size = 1
+    for flag in ("--tensor-parallel-size", "--sequence-parallel-size",
+                 "--data-parallel-size"):
+        at = [i for i, a in enumerate(cmd[:-1]) if a == flag]
+        if at:
+            size *= int(cmd[at[-1] + 1])
+    return size
+
+
+def _child_env(engine_env: Optional[dict],
+               chips: List[int]) -> Optional[dict]:
+    """Environment of one engine child: the inherited one, the caller's
+    overrides, and — on a TPU host — the chips this child owns."""
+    if not engine_env and not chips:
+        return None
+    return {**os.environ, **(engine_env or {}),
+            **(tpu_chip_env(chips) if chips else {})}
+
+
 def free_port() -> int:
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
@@ -149,6 +223,9 @@ class StackHandle:
     engine_cmds: List[List[str]] = field(default_factory=list)
     engine_log_files: List[object] = field(default_factory=list)
     engine_env: Optional[dict] = None
+    # Host chip indices engine i owns (empty lists off-TPU): a relaunch
+    # gets the same chips back, a scale-out the lowest free ones.
+    engine_chips: List[List[int]] = field(default_factory=list)
     # Elastic fast-start (docs/ELASTIC.md): per-engine process-spawn ->
     # /health-200 seconds (initial launch, relaunches overwrite their
     # slot, scale-outs append), the served model name, and — when the
@@ -191,13 +268,23 @@ class StackHandle:
         url = f"http://127.0.0.1:{port}"
         cmd = list(self.engine_cmds[0])
         cmd[cmd.index("--port") + 1] = str(port)
+        chips: List[int] = []
+        if self.engine_chips and self.engine_chips[0]:
+            used = {c for owned in self.engine_chips for c in owned}
+            free = [c for c in local_tpu_chips() if c not in used]
+            need = len(self.engine_chips[0])
+            if len(free) < need:
+                raise RuntimeError(
+                    f"scale_out needs {need} free TPU chip(s); engines own "
+                    f"{sorted(used)} and the host has {free} left"
+                )
+            chips = free[:need]
         elog = os.path.join(self.log_dir, f"pstpu-bench-engine-{port}.log")
         elog_f = open(elog, "w")
-        env = ({**os.environ, **self.engine_env}
-               if self.engine_env else None)
         t0 = time.monotonic()
         proc = subprocess.Popen(
-            cmd, stdout=elog_f, stderr=subprocess.STDOUT, env=env,
+            cmd, stdout=elog_f, stderr=subprocess.STDOUT,
+            env=_child_env(self.engine_env, chips),
         )
         try:
             wait_health(f"{url}/health", startup_timeout_s, proc,
@@ -210,6 +297,7 @@ class StackHandle:
         self.engines.append(proc)
         self.engine_urls.append(url)
         self.engine_cmds.append(cmd)
+        self.engine_chips.append(chips)
         self.engine_log_files.append(elog_f)
         self.engine_ready_seconds.append(ready_s)
         self.log_paths.append(elog)
@@ -238,6 +326,8 @@ class StackHandle:
         proc = self.engines.pop(index)
         url = self.engine_urls.pop(index)
         self.engine_cmds.pop(index)
+        if index < len(self.engine_chips):
+            self.engine_chips.pop(index)
         elog_f = self.engine_log_files.pop(index)
         if index < len(self.engine_ready_seconds):
             self.engine_ready_seconds.pop(index)
@@ -315,13 +405,13 @@ class StackHandle:
     def _relaunch_engine(self, index: int, startup_timeout_s: float) -> None:
         """Relaunch engine ``index``'s exact argv/env on the same port and
         block until /health is 200 again."""
-        env = ({**os.environ, **self.engine_env}
-               if self.engine_env else None)
+        chips = (self.engine_chips[index]
+                 if index < len(self.engine_chips) else [])
         t0 = time.monotonic()
         new = subprocess.Popen(
             self.engine_cmds[index],
             stdout=self.engine_log_files[index], stderr=subprocess.STDOUT,
-            env=env,
+            env=_child_env(self.engine_env, chips),
         )
         self.engines[index] = new
         wait_health(f"{self.engine_urls[index]}/health", startup_timeout_s,
@@ -416,8 +506,14 @@ def launch_stack(
     mesh (threaded through per_engine_args, so a caller's own per-engine
     extras can still override it per pod). On CPU the caller must also put
     ``--xla_force_host_platform_device_count=N`` into the subprocesses'
-    XLA_FLAGS (bench.py does; the same code path IS the TPU slice path,
-    where the real devices are just present).
+    XLA_FLAGS (bench.py does).
+
+    One process for each chip: on a TPU host (local_tpu_chips) every
+    engine child is given exactly the chips its mesh needs, in order
+    (tpu_chip_env, set per child) — engine 0 the first dp*sp*tp chips,
+    engine 1 the next — and the launcher itself never starts JAX. A stack
+    that needs more chips than the host exposes is an error before
+    anything is spawned.
 
     Elastic fast-start (docs/ELASTIC.md): ``compilation_cache_dir``
     threads ``--compilation-cache-dir`` into every engine subprocess
@@ -457,37 +553,50 @@ def launch_stack(
     log_paths: List[str] = []
     log_files: List[object] = []
     rlog_f = None
+    for i in range(max(1, num_engines)):
+        engine_port = free_port()
+        extra = (
+            per_engine_args[i]
+            if per_engine_args and i < len(per_engine_args) else []
+        )
+        cmd = [
+            sys.executable, "-m",
+            "production_stack_tpu.server.api_server",
+            "--model", model, "--port", str(engine_port),
+            *(["--compilation-cache-dir", compilation_cache_dir]
+              if compilation_cache_dir is not None else []),
+            *(engine_args or []),
+            *extra,
+        ]
+        engine_urls.append(f"http://127.0.0.1:{engine_port}")
+        engine_cmds.append(cmd)
+    host_chips = local_tpu_chips()
+    sizes = [_mesh_size(cmd) if host_chips else 0 for cmd in engine_cmds]
+    if sum(sizes) > len(host_chips):
+        raise RuntimeError(
+            f"stack needs {sum(sizes)} TPU chip(s) ({sizes} per engine), "
+            f"this host exposes {len(host_chips)}: {host_chips}"
+        )
+    engine_chips = [
+        host_chips[sum(sizes[:i]):sum(sizes[:i + 1])]
+        for i in range(len(sizes))
+    ]
     try:
-        for i in range(max(1, num_engines)):
-            engine_port = free_port()
-            engine_url = f"http://127.0.0.1:{engine_port}"
+        for cmd, engine_url, chips in zip(engine_cmds, engine_urls,
+                                          engine_chips):
             elog = os.path.join(
-                log_dir, f"pstpu-bench-engine-{engine_port}.log"
+                log_dir,
+                f"pstpu-bench-engine-{engine_url.rsplit(':', 1)[1]}.log",
             )
             elog_f = open(elog, "w")
             log_paths.append(elog)
             log_files.append(elog_f)
-            extra = (
-                per_engine_args[i]
-                if per_engine_args and i < len(per_engine_args) else []
-            )
-            cmd = [
-                sys.executable, "-m",
-                "production_stack_tpu.server.api_server",
-                "--model", model, "--port", str(engine_port),
-                *(["--compilation-cache-dir", compilation_cache_dir]
-                  if compilation_cache_dir is not None else []),
-                *(engine_args or []),
-                *extra,
-            ]
             engine_spawn_times.append(time.monotonic())
             engines.append(subprocess.Popen(
                 cmd,
                 stdout=elog_f, stderr=subprocess.STDOUT,
-                env=({**os.environ, **engine_env} if engine_env else None),
+                env=_child_env(engine_env, chips),
             ))
-            engine_urls.append(engine_url)
-            engine_cmds.append(cmd)
             engine_log_files.append(elog_f)
         for engine, engine_url, spawn_t in zip(engines, engine_urls,
                                                engine_spawn_times):
@@ -559,6 +668,7 @@ def launch_stack(
         router_urls=router_urls, log_paths=log_paths, log_files=log_files,
         engine_cmds=engine_cmds, engine_log_files=engine_log_files,
         engine_env=dict(engine_env) if engine_env else None,
+        engine_chips=engine_chips,
         engine_ready_seconds=engine_ready_seconds,
         served_model=served,
         dynamic_config_path=dynamic_config_path,

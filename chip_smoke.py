@@ -1,0 +1,712 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the serving path still starts on
+the chip.
+
+Drives the stack the way a user does — ``python -m
+production_stack_tpu.server.api_server`` behind ``python -m
+production_stack_tpu.router.app``, brought up by
+``benchmarks.stack.launch_stack`` — at the full published width AND depth of
+``llama-3b`` (Llama-3.2-3B: 28 layers, hidden 3072, 24/8 heads, head_dim
+128, vocab 128256; seeded dummy weights, KV pool sized from the chip's free
+HBM), and checks what comes out by the repo's own means.
+
+    python chip_smoke.py              one chip (what the driver runs)
+    python chip_smoke.py --chips 4    replicas behind the router + tp=4,
+                                      against a one-chip reference
+    python chip_smoke.py --rehearse   CPU rehearsal of every phase at a tiny
+                                      model (JAX_PLATFORMS=cpu); never ok
+
+One JSON object per phase goes to stdout; the LAST line is
+``{"ok": ..., "device": {"platform", "kind", "count"}}`` with the device as
+the engine that served the requests reported it (``GET /version``). Exit
+code 0 only with ``"ok": true`` — which needs every phase to pass on a TPU,
+compiled (not interpreted) kernels, a compile cache, and zero warmup faults.
+
+This process never imports JAX: a chip belongs to one process at a time and
+the engine children need it (the kernel phase runs in a child of its own).
+It finds the package next to this file, not in the caller's cwd.
+"""
+
+import argparse
+import json
+import os
+import subprocess
+import sys
+import threading
+import time
+import urllib.error
+import urllib.request
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+LOG_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
+
+MODEL, FULL_DEPTH = "llama-3b", 28
+# The smoke's serving envelope (ISSUE 21 §6). Warmup compiles AND executes
+# every reachable shape family before /health turns 200, and the family
+# count grows with log2(max_num_seqs) x log2(max_num_batched_tokens), not
+# with max_model_len: 4 seqs / 256 batched tokens is 24 + 12 families on
+# the window path and 4 + 12 on the paged path (~75 / ~30 programs with the
+# logprobs/penalty variants, 2-8 s each when compiled for a described v5e in
+# the sandbox) — a cold boot of minutes, inside the 1200 s the driver
+# allows for both boots. max_model_len stays the engine default.
+ENGINE_ARGS = ["--max-num-seqs", "4", "--max-model-len", "2048",
+               "--max-num-batched-tokens", "256"]
+# Rehearsal: 8 kv heads so tp=4 shards the pool, head_dim 32 so the kernel
+# runs lane-packed; prompts of this script still span several chunks.
+REHEARSAL_MODEL, REHEARSAL_DEPTH = "tiny-llama-8kv", 2
+REHEARSAL_ENGINE_ARGS = ["--max-num-seqs", "2", "--max-model-len", "1024",
+                         "--max-num-batched-tokens", "128",
+                         "--num-decode-steps", "8"]
+MAX_TOKENS = 16
+# One boot may take this long before the phase gives up: about twice the
+# cold window-path boot measured on a v5e (399 s, 75 programs).
+BOOT_TIMEOUT_S = 800.0
+# Kernel vs XLA reference, max-abs: both sides round through bf16 (8
+# mantissa bits, 2^-8 relative) once in the PV contraction and once at the
+# output, on values of order one — a handful of bf16 ulps at 1.0.
+KERNEL_MAX_ABS_ERR = 2e-2
+# tp=4 vs tp=1 first-token logprob: the row-parallel all-reduce sums bf16
+# partials in another order, and the logit of a 128k-way softmax over
+# random weights moves by a few bf16 ulps of the logit scale.
+TP_LOGPROB_TOL = 0.15
+# tp=4: per-device bytes in use may differ by replicated leaves (norms,
+# small buffers), not by a whole copy of weights or pool.
+TP_BYTES_SPREAD = 1.25
+
+SYSTEM = (
+    "You are the smoke test of a serving stack. Every request in this "
+    "script begins with this same system prompt so that its KV blocks are "
+    "computed once and found again in the prefix cache by the next one. "
+    "Answer briefly."
+)
+QUESTIONS = [
+    "What does a router do in front of a fleet of engines?",
+    "Name one reason to keep the KV cache paged.",
+    "Why is decode bound by memory bandwidth?",
+    # Longer than the prefill chunk budget: a multi-chunk prefill whose
+    # later chunks attend the earlier ones through the history window.
+    "Summarise the following, then stop. " + " ".join(
+        f"Clause {i}: the engine batches requests continuously." for i in
+        range(12)
+    ),
+]
+
+
+def emit(obj: dict) -> dict:
+    print(json.dumps(obj), flush=True)
+    return obj
+
+
+# ------------------------------------------------------------------ HTTP
+def _http(url, body=None, headers=None, timeout=600):
+    data = None if body is None else json.dumps(body).encode()
+    req = urllib.request.Request(
+        url, data=data,
+        headers={"Content-Type": "application/json", **(headers or {})},
+    )
+    try:
+        with urllib.request.urlopen(req, timeout=timeout) as resp:
+            return resp.status, resp.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.read()
+
+
+def get_json(url):
+    status, raw = _http(url)
+    if status != 200:
+        raise RuntimeError(f"GET {url} -> {status}: {raw[:300]!r}")
+    return json.loads(raw)
+
+
+def scrape(engine_url) -> dict:
+    """Engine /metrics as {series name: value} (label sets summed)."""
+    status, raw = _http(f"{engine_url}/metrics")
+    if status != 200:
+        raise RuntimeError(f"GET {engine_url}/metrics -> {status}")
+    out = {}
+    for line in raw.decode().splitlines():
+        if line and not line.startswith("#"):
+            name, _, value = line.rpartition(" ")
+            name = name.split("{", 1)[0]
+            out[name] = out.get(name, 0.0) + float(value)
+    return out
+
+
+def expected_prompt_tokens(messages) -> int:
+    """Byte-level tokenizer + the repo's plain chat template
+    (engine/tokenizer.py:ByteTokenizer): one token per UTF-8 byte."""
+    text = "".join(f"<|{m['role']}|>\n{m['content']}\n" for m in messages)
+    return len((text + "<|assistant|>\n").encode("utf-8"))
+
+
+def chat_body(model, question, stream, logprobs=False):
+    body = {
+        "model": model, "stream": stream, "temperature": 0, "seed": 0,
+        "max_tokens": MAX_TOKENS, "ignore_eos": True,
+        "messages": [{"role": "system", "content": SYSTEM},
+                     {"role": "user", "content": question}],
+    }
+    if stream:
+        body["stream_options"] = {"include_usage": True}
+    if logprobs:
+        body.update(logprobs=True, top_logprobs=0)
+    return body
+
+
+def chat(router_url, body, headers=None) -> dict:
+    """One chat completion through the router, reduced to what the checks
+    read: status, usage, finish_reason, and — for streams — the token ids
+    the engine's per-chunk ``pstpu`` payload carries through the router's
+    relay, the chosen-token logprobs, and whether ``[DONE]`` closed it."""
+    status, raw = _http(f"{router_url}/v1/chat/completions", body, headers)
+    out = {"status": status, "toks": [], "logprobs": [], "usage": None,
+           "finish_reason": None, "done": False, "text": ""}
+    if status != 200:
+        out["error"] = raw[:300].decode(errors="replace")
+        return out
+    if not body["stream"]:
+        doc = json.loads(raw)
+        choice = doc["choices"][0]
+        out.update(usage=doc.get("usage"), done=True,
+                   finish_reason=choice.get("finish_reason"),
+                   text=choice["message"].get("content") or "")
+        return out
+    for event in raw.split(b"\n\n"):
+        for line in event.split(b"\n"):
+            if not line.startswith(b"data:"):
+                continue
+            payload = line[len(b"data:"):].strip()
+            if payload == b"[DONE]":
+                out["done"] = True
+                continue
+            doc = json.loads(payload)
+            if "error" in doc:
+                out["error"] = doc["error"]
+            if doc.get("usage"):
+                out["usage"] = doc["usage"]
+            out["toks"] += (doc.get("pstpu") or {}).get("toks", [])
+            for choice in doc.get("choices") or []:
+                out["text"] += (choice.get("delta") or {}).get("content") or ""
+                if choice.get("finish_reason"):
+                    out["finish_reason"] = choice["finish_reason"]
+                for item in (choice.get("logprobs") or {}).get("content", []):
+                    out["logprobs"].append(item["logprob"])
+    return out
+
+
+def check_completion(res: dict, body: dict) -> list:
+    """Faults of one answered request against the exact counts it must
+    carry (greedy, ignore_eos: the budget is always spent)."""
+    faults = []
+    want = expected_prompt_tokens(body["messages"])
+    if res["status"] != 200:
+        return [f"status {res['status']}: {res.get('error')}"]
+    usage = res["usage"] or {}
+    if usage != {"prompt_tokens": want, "completion_tokens": MAX_TOKENS,
+                 "total_tokens": want + MAX_TOKENS}:
+        faults.append(f"usage {usage} != prompt {want} + {MAX_TOKENS}")
+    if res["finish_reason"] != "length":
+        faults.append(f"finish_reason {res['finish_reason']!r}")
+    if not res["done"]:
+        faults.append("stream not closed by [DONE]")
+    if "error" in res:
+        faults.append(f"error event {res['error']}")
+    if body["stream"] and len(res["toks"]) != MAX_TOKENS:
+        faults.append(f"{len(res['toks'])} token ids in the stream")
+    if body.get("logprobs") and len(res["logprobs"]) != MAX_TOKENS:
+        faults.append(f"{len(res['logprobs'])} logprobs in the stream")
+    return faults
+
+
+def agreement(a: list, b: list) -> float:
+    """Share of positions at which two lists of token-id lists agree."""
+    total = sum(max(len(x), len(y)) for x, y in zip(a, b))
+    same = sum(sum(p == q for p, q in zip(x, y)) for x, y in zip(a, b))
+    return round(same / total, 4) if total else 0.0
+
+
+# ---------------------------------------------------------------- phases
+def _engine_log_tail(stack, n=1500) -> str:
+    """End of the first engine's log (the newest one when the launch itself
+    failed and left no handle)."""
+    paths = list(getattr(stack, "log_paths", []))
+    if not paths and os.path.isdir(LOG_DIR):
+        paths = sorted(
+            (os.path.join(LOG_DIR, f) for f in os.listdir(LOG_DIR)
+             if "engine" in f), key=os.path.getmtime, reverse=True,
+        )
+    if not paths:
+        return ""
+    with open(paths[0], errors="replace") as f:
+        return f.read()[-n:]
+
+
+def _boot_summary(report: dict, ready_s: float) -> dict:
+    """The per-boot numbers, from the engine's own report (GET /version)."""
+    eng = report["engine"]
+    return {
+        "engine_ready_s": round(ready_s, 1),
+        "attn_impl": eng["attn_impl"],
+        "pallas_interpret": eng["pallas_interpret"],
+        "num_layers": eng["num_layers"],
+        "warmup_families": eng["warmup_families"],
+        "deferred_families": eng["deferred_families"],
+        "warmup_failures": eng["warmup_failures"],
+        "compile_s": eng["compile_seconds"],
+        "warmup_s": eng["warmup_seconds"],
+        "weight_load_s": eng["weight_load_seconds"],
+        "cache_dir": eng["compilation_cache_dir"],
+        "cache_entries": eng["compilation_cache_entries"],
+        "cache_hit": eng["cache_hit_families"],
+        "cache_miss": eng["cache_miss_families"],
+        "kv_blocks": eng["kv_blocks"],
+        "kv_shard_shape": eng["kv_shard_shape"],
+        "bytes_in_use": eng["bytes_in_use"],
+        "device": report["device"],
+    }
+
+
+def run_phase(line: dict, body, model, engine_args, **kw) -> dict:
+    """Engines + router up the way launch_stack starts them, every engine's
+    boot summary put in ``line`` (``boot``, or ``boots`` for several), then
+    ``body(stack)`` makes the phase's requests and leaves its result and
+    ``ok`` in ``line``. A phase reports and never raises: a fault lands in
+    the line with the engine's log tail, and the stack is always stopped."""
+    from benchmarks.stack import launch_stack
+
+    line["ok"] = False
+    t0 = time.monotonic()
+    stack = None
+    try:
+        os.makedirs(LOG_DIR, exist_ok=True)
+        stack = launch_stack(
+            model, engine_args=engine_args, log_dir=LOG_DIR,
+            routing_logic="session",
+            router_args=["--session-key", "x-user-id"],
+            startup_timeout_s=BOOT_TIMEOUT_S, **kw,
+        )
+        boots = [
+            _boot_summary(get_json(f"{url}/version"), ready_s)
+            for url, ready_s in zip(stack.engine_urls,
+                                    stack.engine_ready_seconds)
+        ]
+        line.update({"boot": boots[0]} if len(boots) == 1
+                    else {"boots": boots})
+        body(stack)
+    except Exception as e:  # noqa: BLE001 — a phase reports, never raises
+        line.update(ok=False, error=f"{type(e).__name__}: {e}",
+                    engine_log_tail=_engine_log_tail(stack))
+    finally:
+        if stack is not None:
+            stack.terminate()
+        line["wall_s"] = round(time.monotonic() - t0, 1)
+    return emit(line)
+
+
+def _bytes_in_use(engine_url) -> dict:
+    return get_json(f"{engine_url}/version")["engine"]["bytes_in_use"]
+
+
+def phase_kernel(model: str, rehearse: bool) -> dict:
+    """Child of its own (it needs the chip, and must be gone before the
+    engines start): the compiled paged decode kernel against the XLA
+    reference at the model's widths, bf16 and int8 pool."""
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--kernel-child", model,
+         *(["--rehearse"] if rehearse else [])],
+        capture_output=True, text=True, timeout=900,
+    )
+    lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+    line = json.loads(lines[-1]) if lines else {}
+    line.setdefault("phase", "kernel")
+    line["wall_s"] = round(time.monotonic() - t0, 1)
+    if proc.returncode != 0 or not lines:
+        line["ok"] = False
+        line.setdefault("error", proc.stderr[-1500:])
+    return line
+
+
+def kernel_child(model: str, rehearse: bool) -> int:
+    """Runs in the child: the only code of this file that imports JAX."""
+    import jax
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "kernel", "ok": False, "device": device,
+              "error": "JAX found no TPU; nothing was run"})
+        return 1
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from production_stack_tpu.models.config import resolve_model_config
+    from production_stack_tpu.ops.attention import paged_attention_xla
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_stats,
+    )
+    from production_stack_tpu.ops.quantization import quantize_kv
+
+    interpret = dev.platform == "cpu"   # rehearsal only; fails the verdict
+    mc = resolve_model_config(model)
+    h, hkv, dh, bs = mc.num_heads, mc.num_kv_heads, mc.head_dim_, 16
+    # Two layers in the stacked pool and layer 1 addressed, ragged lengths:
+    # a full superpage run, a partial superpage, a partial page, one page.
+    max_len = min(2048, mc.max_position_embeddings)
+    lens = [max_len, max_len // 2 - 24, 17, 16]
+    b, mb, nl = len(lens), max_len // bs, 2
+    slots = (b * mb + 1) * bs
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(kq, (b, h, dh), jnp.bfloat16)
+    k_pool = jax.random.normal(kk, (nl, hkv, slots, dh), jnp.bfloat16)
+    v_pool = jax.random.normal(kv, (nl, hkv, slots, dh), jnp.bfloat16)
+    tables = jnp.asarray(
+        1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+    )
+    kv_lens = jnp.asarray(lens, jnp.int32)
+    layer = 1
+    cases = []
+    for name in ("bfloat16", "int8"):
+        scales = {}
+        kp, vp = k_pool, v_pool
+        if name == "int8":
+            kp, ks = quantize_kv(k_pool)
+            vp, vs = quantize_kv(v_pool)
+            scales = {"k_scale": ks, "v_scale": vs}
+        out, _, _ = paged_flash_decode_stats(
+            q, kp, vp, tables, kv_lens, jnp.int32(layer), block_size=bs,
+            interpret=interpret, **scales,
+        )
+        with jax.default_matmul_precision("highest"):
+            ref = paged_attention_xla(
+                q[:, None], kp[layer], vp[layer], tables, kv_lens,
+                (kv_lens - 1)[:, None], block_size=bs,
+                **{k: v[layer] for k, v in scales.items()},
+            )[:, 0]
+        err = float(jnp.max(jnp.abs(
+            out.astype(jnp.float32) - ref.astype(jnp.float32)
+        )))
+        finite = bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+        cases.append({
+            "pool": name, "max_abs_err": err, "bound": KERNEL_MAX_ABS_ERR,
+            "finite": finite, "shape": list(out.shape),
+            "ok": finite and err <= KERNEL_MAX_ABS_ERR
+            and out.shape == (b, h, dh),
+        })
+    emit({
+        "phase": "kernel", "model": model,
+        "widths": {"heads": h, "kv_heads": hkv, "head_dim": dh,
+                   "block_size": bs, "kv_lens": lens},
+        "interpret": interpret, "cases": cases, "device": device,
+        "ok": all(c["ok"] for c in cases),
+    })
+    return 0
+
+
+def phase_serve(model, engine_args, attn: str) -> dict:
+    """One boot of engine + router and a handful of requests through the
+    router."""
+    line = {"phase": f"serve[{attn}]", "model": model}
+
+    def body(stack):
+        m0 = scrape(stack.engine_url)
+        # Non-streamed, then the same prompt streamed (a whole-prompt
+        # prefix hit); two concurrent streams; then the long prompt.
+        bodies = [chat_body(model, QUESTIONS[0], stream=False),
+                  *(chat_body(model, q, stream=True)
+                    for q in (QUESTIONS[0], *QUESTIONS[1:]))]
+        results = [None] * len(bodies)
+
+        def run(i):
+            results[i] = chat(stack.router_url, bodies[i])
+
+        run(0)
+        run(1)
+        pair = [threading.Thread(target=run, args=(i,)) for i in (2, 3)]
+        for t in pair:
+            t.start()
+        for t in pair:
+            t.join(timeout=600)
+        run(4)
+
+        faults = []
+        for i, (body, res) in enumerate(zip(bodies, results)):
+            faults += [f"request {i}: {f}" for f in (
+                check_completion(res, body) if res else ["no answer"])]
+        if results[0] and results[1] and \
+                results[0]["text"] != results[1]["text"]:
+            faults.append("streamed repeat differs from non-streamed text")
+
+        # Exact counts, by the engine's own counters.
+        m1 = scrape(stack.engine_url)
+        usages = [r["usage"] for r in results if r and r["usage"]]
+        for series, key in (("vllm:prompt_tokens_total", "prompt_tokens"),
+                            ("vllm:generation_tokens_total",
+                             "completion_tokens")):
+            counted = m1.get(series, 0.0) - m0.get(series, 0.0)
+            if counted != sum(u[key] for u in usages):
+                faults.append(f"engine counted {counted} {key}, usage says "
+                              f"{sum(u[key] for u in usages)}")
+        hits = (m1.get("vllm:gpu_prefix_cache_hits_total", 0.0)
+                - m0.get("vllm:gpu_prefix_cache_hits_total", 0.0))
+        if hits <= 0:
+            faults.append("prefix-hit counter did not move")
+        if m1.get("vllm:num_requests_running") != 0:
+            faults.append(f"num_requests_running="
+                          f"{m1.get('vllm:num_requests_running')} at the end")
+        line.update(
+            statuses=[r["status"] if r else None for r in results],
+            prefix_hit_tokens=hits,
+            greedy_toks=[r["toks"] if r else [] for r in results[1:]],
+            bytes_in_use_after=_bytes_in_use(stack.engine_url),
+            faults=faults, ok=not faults,
+        )
+
+    return run_phase(line, body, model,
+                     [*engine_args, "--attn-impl", attn])
+
+
+def _logprob_requests(model, router_url):
+    """Every question once, streamed with logprobs: [(body, result)]."""
+    bodies = [chat_body(model, q, stream=True, logprobs=True)
+              for q in QUESTIONS]
+    return [(body, chat(router_url, body, {"x-user-id": f"user-{i}"}))
+            for i, body in enumerate(bodies)]
+
+
+def phases_four_chips(model, engine_args) -> list:
+    """The paths that exist only across chips — four one-chip replicas
+    behind the router, and one tp=4 engine — and the one-chip reference
+    they are compared with. Paged decode throughout: tp=4 is the
+    shard_map'd kernel path, and token identity needs one path. Prefix
+    caching off: greedy tokens are equal only where the arithmetic is, and
+    a replica that meets a prompt cold prefills it whole while the
+    reference, having served its neighbours, prefills only the tail behind
+    the cached system prompt — other chunk shapes, other bf16 rounding, and
+    with random weights a near-tie flips (seen on the chip: 8 of 9 replica
+    answers matched with caching on). The one-chip run covers the cache."""
+    args = [*engine_args, "--attn-impl", "paged",
+            "--no-enable-prefix-caching"]
+
+    # ---- reference: one engine, one chip
+    ref = {"phase": "reference[1 chip]", "model": model}
+    ref_toks, ref_lp = [], []
+
+    def reference(stack):
+        faults = []
+        for i, (body, res) in enumerate(
+                _logprob_requests(model, stack.router_url)):
+            faults += [f"request {i}: {f}" for f in
+                       check_completion(res, body)]
+            ref_toks.append(res["toks"])
+            ref_lp.append(res["logprobs"])
+        ref.update(greedy_toks=ref_toks,
+                   first_logprobs=[lp[:1] for lp in ref_lp],
+                   faults=faults, ok=not faults)
+
+    run_phase(ref, reference, model, args)
+    if not ref["ok"]:
+        return [ref]    # nothing to compare the cross-chip paths with
+
+    # ---- replicas: four one-chip engines behind one router
+    rep = {"phase": "replicas[4 x 1 chip]", "model": model}
+
+    def replicas(stack):
+        faults = []
+        served = [0] * 4            # requests each engine answered
+        matched = [0] * 4           # ... whose tokens equal the reference's
+
+        def generated():
+            return [scrape(u).get("vllm:generation_tokens_total", 0.0)
+                    for u in stack.engine_urls]
+
+        # Session routing hashes the key: walk keys, one request at a
+        # time, until every engine has answered at least one; the engine
+        # whose token counter moved is the one that served it.
+        before = generated()
+        for key in range(64):
+            if all(served):
+                break
+            i = key % len(QUESTIONS)
+            body = chat_body(model, QUESTIONS[i], stream=True, logprobs=True)
+            res = chat(stack.router_url, body,
+                       {"x-user-id": f"session-{key}"})
+            faults += [f"session-{key}: {f}" for f in
+                       check_completion(res, body)]
+            after = generated()
+            moved = [j for j in range(4) if after[j] > before[j]]
+            before = after
+            if len(moved) != 1:
+                faults.append(f"session-{key}: engines {moved} moved")
+                continue
+            served[moved[0]] += 1
+            if res["toks"] == ref_toks[i]:
+                matched[moved[0]] += 1
+            else:
+                faults.append(
+                    f"session-{key} on engine {moved[0]}: tokens "
+                    f"{res['toks']} != reference {ref_toks[i]}")
+        # Device ids are per process (every replica sees its chip as id
+        # 0): the chip index the launcher gave is what tells them apart,
+        # and libtpu lets one chip be opened by one process only.
+        owners = [(b["device"]["visible_chips"], b["device"]["ids"])
+                  for b in rep["boots"]]
+        if not all(served):
+            faults.append(f"engines served {served}: one served nothing")
+        if len({json.dumps(o) for o in owners}) != 4:
+            faults.append(f"engines do not own four distinct chips: {owners}")
+        rep.update(served=served, matched_reference=matched,
+                   chip_owners=owners, faults=faults, ok=not faults)
+
+    run_phase(rep, replicas, model, args, num_engines=4)
+
+    # ---- tp=4: one engine sharded over the four chips
+    tp = {"phase": "tp4[1 x 4 chips]", "model": model}
+
+    def sharded(stack):
+        faults, toks, first_lp = [], [], []
+        for i, (body, res) in enumerate(
+                _logprob_requests(model, stack.router_url)):
+            faults += [f"request {i}: {f}" for f in
+                       check_completion(res, body)]
+            toks.append(res["toks"])
+            first_lp.append(res["logprobs"][:1])
+        diffs = [abs(a[0] - b[0]) if a and b else None
+                 for a, b in zip(first_lp, ref_lp)]
+        if any(d is None or d > TP_LOGPROB_TOL for d in diffs):
+            faults.append(
+                f"first-token logprob differs from tp=1 by {diffs} "
+                f"(tolerance {TP_LOGPROB_TOL})")
+        kv_heads = tp["boot"]["kv_shard_shape"][1]
+        ref_heads = ref["boot"]["kv_shard_shape"][1]
+        in_use = list(_bytes_in_use(stack.engine_url).values())
+        spread = max(in_use) / max(1, min(in_use)) if in_use else None
+        if tp["boot"]["device"]["count"] != 4:
+            faults.append(f"mesh has {tp['boot']['device']['count']} devices")
+        if kv_heads * 4 != ref_heads:
+            faults.append(f"kv pool shard holds {kv_heads} kv heads, want "
+                          f"a quarter of the one-chip pool's {ref_heads}")
+        if len(in_use) != 4 or spread > TP_BYTES_SPREAD:
+            faults.append(f"per-device bytes_in_use {in_use}: spread "
+                          f"{spread} (bound {TP_BYTES_SPREAD})")
+        tp.update(
+            greedy_toks=toks, first_logprobs=first_lp,
+            first_logprob_abs_diff=diffs, logprob_tolerance=TP_LOGPROB_TOL,
+            token_agreement_vs_tp1=agreement(toks, ref_toks),
+            kv_heads_per_device=kv_heads, bytes_in_use_spread=spread,
+            faults=faults, ok=not faults,
+        )
+
+    run_phase(tp, sharded, model, args, tensor_parallel_size=4)
+    return [ref, rep, tp]
+
+
+# --------------------------------------------------------------- verdict
+def verdict(lines: list, chips: int, full_depth: int,
+            rehearsal: bool = False) -> dict:
+    """The last line. ``ok`` needs every phase to have passed AND every
+    engine that served requests to say, in its own report, that it ran on
+    a TPU at full depth with compiled kernels, a compile cache and a clean
+    warmup; the second boot of the run must have found the cache. The
+    device is the one the last serving engine reported."""
+    faults = []
+    if rehearsal:
+        faults.append("rehearsal: tiny model, never a pass")
+    boots = []
+    for line in lines:
+        if not line.get("ok"):
+            faults.append(f"{line.get('phase')}: failed")
+        if line.get("phase") == "kernel":
+            if line.get("interpret") is not False:
+                faults.append("kernel: interpreted, not compiled")
+            if (line.get("device") or {}).get("platform") != "tpu":
+                faults.append("kernel: not on a tpu")
+        boots += line.get("boots") or (
+            [line["boot"]] if "boot" in line else [])
+    if not boots:
+        faults.append("no engine report: nothing was served")
+    for boot in boots:
+        if boot["device"].get("platform") != "tpu":
+            faults.append(
+                f"engine on {boot['device'].get('platform')!r}, not a tpu")
+        if boot.get("pallas_interpret") is not False:
+            faults.append("engine kernels interpreted")
+        if not boot.get("cache_dir"):
+            faults.append("engine booted without a compile cache")
+        if boot.get("warmup_failures"):
+            faults.append(f"{boot['warmup_failures']} warmup stage(s) failed")
+        if not boot.get("warmup_families"):
+            faults.append("engine warmed no shape family")
+        if boot.get("num_layers") != full_depth:
+            faults.append(f"depth {boot.get('num_layers')} != {full_depth}")
+    if len(boots) > 1 and not any(b.get("cache_hit", 0) > 0
+                                  for b in boots[1:]):
+        faults.append("no later boot found the compile cache (0 hits)")
+    device = {k: boots[-1]["device"].get(k)
+              for k in ("platform", "kind", "count")} if boots else None
+    if device and device["count"] != chips:
+        faults.append(f"served on {device['count']} device(s), want {chips}")
+    if faults:
+        emit({"phase": "verdict", "faults": faults})
+    return {"ok": not faults, "device": device}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU rehearsal at a tiny model; never ok")
+    ap.add_argument("--kernel-child", metavar="MODEL", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+
+    sys.path.insert(0, HERE)
+    os.environ["PYTHONPATH"] = os.pathsep.join(
+        p for p in (HERE, os.environ.get("PYTHONPATH")) if p
+    )
+    if args.kernel_child:
+        return kernel_child(args.kernel_child, args.rehearse)
+
+    model, full_depth, engine_args = MODEL, FULL_DEPTH, ENGINE_ARGS
+    if args.rehearse:
+        model, full_depth, engine_args = (
+            REHEARSAL_MODEL, REHEARSAL_DEPTH, REHEARSAL_ENGINE_ARGS
+        )
+        # A rehearsal IS the CPU, asked for; four virtual devices stand in
+        # for the four chips of --chips 4.
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        if "xla_force_host_platform_device_count" not in \
+                os.environ.get("XLA_FLAGS", ""):
+            os.environ["XLA_FLAGS"] = (
+                os.environ.get("XLA_FLAGS", "")
+                + " --xla_force_host_platform_device_count=4"
+            ).strip()
+
+    lines = []
+    if args.chips == 4:
+        lines += phases_four_chips(model, engine_args)
+    else:
+        lines.append(emit(phase_kernel(model, args.rehearse)))
+        on_tpu = (lines[0].get("device") or {}).get("platform") == "tpu"
+        if on_tpu or args.rehearse:
+            # The path `auto` resolves to, then the other one: both decode
+            # attention paths run compiled, and the second boot shows the
+            # compile cache being found again.
+            for attn in ("auto", "paged"):
+                lines.append(phase_serve(model, engine_args, attn))
+            a, b = (ln.get("greedy_toks") for ln in lines[1:3])
+            if a and b:
+                emit({"phase": "paths",
+                      "auto": lines[1]["boot"]["attn_impl"],
+                      "auto_toks": a, "paged_toks": b,
+                      "token_agreement": agreement(a, b)})
+        # No accelerator and no rehearsal asked for: llama-3b is not run on
+        # a CPU — the failed kernel phase is the whole result.
+    final = verdict(lines, args.chips, full_depth, rehearsal=args.rehearse)
+    print(json.dumps(final), flush=True)
+    return 0 if final["ok"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

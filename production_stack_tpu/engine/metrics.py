@@ -35,7 +35,8 @@ class EngineMetricsCollector(Collector):
 
         def gauge(name, doc, value):
             g = GaugeMetricFamily(name, doc, labels=labels)
-            g.add_metric(lv, value)
+            if value is not None:   # None = nothing to report: no sample
+                g.add_metric(lv, value)
             return g
 
         def counter(name, doc, value):

@@ -404,7 +404,7 @@ def paged_flash_decode_stats_tp(
     """
     from jax.sharding import PartitionSpec as P
 
-    from production_stack_tpu.parallel.mesh import AXIS_TP, shard_map
+    from production_stack_tpu.parallel.mesh import AXIS_TP
 
     quantized = k_scale is not None
 
@@ -432,7 +432,7 @@ def paged_flash_decode_stats_tp(
         # free.
         in_specs += (P(None, AXIS_TP, None), P(None, AXIS_TP, None))
         args += (k_scale, v_scale)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=in_specs,

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from production_stack_tpu.parallel.mesh import AXIS_SP, shard_map
+from production_stack_tpu.parallel.mesh import AXIS_SP
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -99,7 +99,7 @@ def ring_attention(
         scale = q.shape[-1] ** -0.5
     spec_q = P(None, AXIS_SP, None, None)
     spec_pos = P(None, AXIS_SP)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_shard, axis_name=AXIS_SP, scale=float(scale)
         ),
@@ -137,7 +137,7 @@ def ring_attention_kv(
         scale = q.shape[-1] ** -0.5
     spec_seq = P(None, AXIS_SP, None, None)
     spec_pos = P(None, AXIS_SP)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_shard, axis_name=AXIS_SP, scale=float(scale)
         ),

@@ -22,27 +22,6 @@ from jax.sharding import Mesh
 AXIS_DP, AXIS_SP, AXIS_TP = "dp", "sp", "tp"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (replication check spelled
-    ``check_vma``); older versions only have
-    ``jax.experimental.shard_map.shard_map`` (spelled ``check_rep``).
-    Every in-repo shard_map call goes through this wrapper so the engine
-    serves on both."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:  # transitional versions spell it check_rep
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
-
 def make_mesh(
     dp: int = 1,
     sp: int = 1,

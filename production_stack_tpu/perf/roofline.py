@@ -12,27 +12,48 @@ dispatch window so a TPU slice reports its own roofline position as
 """
 
 import os
+from typing import Optional
 
-# Peak HBM bandwidth presets per accelerator generation, GB/s per chip
-# (public TPU spec sheets; the TPU-slice measurement campaign records
-# which preset a run used via the bench JSON line's ``hbm_peak_gbps``).
-HBM_PEAK_PRESETS_GBPS = {
-    "v5e": 819.0,
-    "v5p": 2765.0,
-    "v6e": 1638.0,
+# Peak HBM bandwidth per chip in GB/s, keyed by the ``device_kind`` JAX
+# reports (public Cloud TPU spec sheets; v5e also in the on-chip-measurement
+# guide). One table for the bench JSON line and the live engine gauges. A
+# TPU kind that is not here is an ERROR unless the operator gives the peak
+# (``--hbm-peak-gbps`` / $PSTPU_PEAK_HBM_GBS) — never a silent v5e default.
+# Only "TPU v5 lite" has been read off an attached chip (chip_smoke.py).
+HBM_PEAK_GBPS_BY_DEVICE_KIND = {
+    "TPU v5 lite": 819.0,    # v5e
+    "TPU v5": 2765.0,        # v5p
+    "TPU v6 lite": 1638.0,   # v6e
 }
 
-# Peak HBM bandwidth of the benched chip (v5e default; overridable via the
-# env var, `bench.py --hbm-peak-gbps`, or `EngineConfig.hbm_peak_gbps`
-# when the driver runs on different hardware).
-PEAK_HBM_GBS = float(
-    os.environ.get("PSTPU_PEAK_HBM_GBS", HBM_PEAK_PRESETS_GBPS["v5e"])
-)
+
+def peak_hbm_gbps(platform: str, device_kind: str,
+                  override: Optional[float] = None) -> Optional[float]:
+    """The roofline denominator for the device a run actually found.
+
+    ``override`` (flag or $PSTPU_PEAK_HBM_GBS) wins. On the CPU backend
+    there is no HBM: None, and every roofline share derived from it
+    reports nothing instead of a share of some accelerator's peak."""
+    if override is None:
+        env = os.environ.get("PSTPU_PEAK_HBM_GBS")
+        override = float(env) if env else None
+    if override is not None:
+        return float(override)
+    if platform == "cpu":
+        return None
+    try:
+        return HBM_PEAK_GBPS_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak HBM bandwidth known for device kind {device_kind!r} "
+            f"(known: {sorted(HBM_PEAK_GBPS_BY_DEVICE_KIND)}); pass "
+            f"--hbm-peak-gbps or set PSTPU_PEAK_HBM_GBS"
+        ) from None
 
 
 def roofline_components(model: str, weight_dtype_bytes: float,
                         kv_cache_dtype: str, batch: int, avg_ctx: float,
-                        peak_gbs: float = None,
+                        peak_gbs: Optional[float],
                         tokens_per_target_step: float = 1.0,
                         num_chips: int = 1) -> dict:
     """Aggregate decode roofline from the model's analytic byte counts —
@@ -59,8 +80,9 @@ def roofline_components(model: str, weight_dtype_bytes: float,
     from production_stack_tpu.engine.config import EngineConfig
     from production_stack_tpu.models.config import resolve_model_config
 
-    peak = PEAK_HBM_GBS if peak_gbs is None else peak_gbs
-    peak *= max(1, int(num_chips))
+    # No peak known (peak_hbm_gbps on the CPU backend): the byte components
+    # still hold, the ceiling itself is None.
+    peak = None if peak_gbs is None else peak_gbs * max(1, int(num_chips))
     mc = resolve_model_config(model)
     d, f, v = mc.hidden_size, mc.intermediate_size, mc.vocab_size
     dh, h, hkv, nl = mc.head_dim_, mc.num_heads, mc.num_kv_heads, mc.num_layers
@@ -79,5 +101,8 @@ def roofline_components(model: str, weight_dtype_bytes: float,
         "kv_bytes_per_step_per_row": kv_bytes_per_token * avg_ctx,
         "tokens_per_target_step": factor,
         "num_chips": max(1, int(num_chips)),
-        "roofline_tok_s": peak * 1e9 / step_bytes_per_row * factor,
+        "roofline_tok_s": (
+            None if peak is None
+            else peak * 1e9 / step_bytes_per_row * factor
+        ),
     }

@@ -532,7 +532,12 @@ class APIServer:
         return web.json_response({"status": "ok", **result})
 
     async def version(self, request: web.Request) -> web.Response:
-        return web.json_response({"version": VERSION})
+        # Beside the version: the device the engine's mesh is on and how
+        # it started (ServingEngine.report — platform, device kind, count,
+        # attention path, interpret mode, compile cache, warmup counts).
+        return web.json_response(
+            {"version": VERSION, **self.engine.report()}
+        )
 
     # ----------------------------------------------------- disagg (role split)
     def _role_gate(self, request: web.Request):
@@ -1480,9 +1485,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                    help="persistent XLA compile-cache directory "
                         "(PVC-mountable): warm boots load step executables "
                         "from it instead of recompiling — the engine "
-                        "fast-start path (docs/ELASTIC.md). Default: "
-                        "$PSTPU_COMPILATION_CACHE or ~/.cache/pstpu_xla; "
-                        "an empty string disables")
+                        "fast-start path (docs/ELASTIC.md). "
+                        "$JAX_COMPILATION_CACHE_DIR, when set, wins over "
+                        "this flag and is used as it is; default: "
+                        ".pstpu_xla_cache in the checkout; an empty "
+                        "string disables")
     p.add_argument("--no-overlap-weight-load", action="store_true",
                    help="Fallback: load weights serially before warmup "
                         "instead of overlapping the checkpoint read with "
@@ -1558,8 +1565,10 @@ def parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--hbm-peak-gbps", type=float, default=None,
                    help="per-chip peak HBM bandwidth in GB/s for the live "
                         "roofline gauges (pstpu:live_hbm_bw_pct): v5e 819, "
-                        "v5p 2765, v6e 1638 (default: EngineConfig value, "
-                        "$PSTPU_PEAK_HBM_GBS or the v5e preset)")
+                        "v5p 2765, v6e 1638 (default: $PSTPU_PEAK_HBM_GBS, "
+                        "else looked up by the device kind the engine "
+                        "finds; an unknown TPU kind is a startup error, "
+                        "the CPU backend exports no roofline share)")
     p.add_argument("--flight-recorder-capacity", type=int, default=None,
                    help="flight-recorder ring size in request records "
                         "(default: EngineConfig tuned value, 256; "

@@ -242,7 +242,9 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# HELP pstpu:live_hbm_bw_pct Achieved fraction (percent) of the "
         "decode HBM roofline for the CURRENT batch shape",
         "# TYPE pstpu:live_hbm_bw_pct gauge",
-        f"pstpu:live_hbm_bw_pct{label} {s['live_hbm_bw_pct']:.6f}",
+        # No sample where the engine knows no HBM peak (CPU backend).
+        *([f"pstpu:live_hbm_bw_pct{label} {s['live_hbm_bw_pct']:.6f}"]
+          if s["live_hbm_bw_pct"] is not None else []),
         "# HELP pstpu:live_effective_tokens_per_target_step Tokens emitted "
         "per target-model step over the rolling window (>1 only when "
         "speculation pays)",

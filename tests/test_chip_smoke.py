@@ -1,0 +1,188 @@
+"""chip_smoke.py cannot pass without a chip.
+
+The script's verdict is taken from what the serving engine reports about
+itself, so the tests feed it reports: one that is right in every respect
+passes, and each way of not being on the chip — a CPU platform, interpreted
+kernels, no compile cache, a warmup stage that failed, a cut depth, a later
+boot that never found the cache, a failed phase — fails it with a non-zero
+exit. The whole script also runs here, in the sandbox, where it must fail:
+as the driver runs it, from a bare directory, and (slow) as a full CPU
+rehearsal of every phase.
+"""
+
+import copy
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, REPO) if REPO not in sys.path else None
+
+import chip_smoke  # noqa: E402
+
+TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1, "ids": [0],
+       "visible_chips": "0"}
+
+
+def _boot(**over):
+    boot = {
+        "engine_ready_s": 200.0, "attn_impl": "window",
+        "pallas_interpret": False, "num_layers": chip_smoke.FULL_DEPTH,
+        "warmup_families": 75, "warmup_failures": 0, "compile_s": 1.0,
+        "warmup_s": 190.0, "cache_dir": "/cache", "cache_entries": 75,
+        "cache_hit": 0, "cache_miss": 75, "kv_blocks": 513,
+        "kv_shard_shape": [28, 8, 8208, 128], "bytes_in_use": {"0": 8 << 30},
+        "device": dict(TPU),
+    }
+    boot.update(over)
+    return boot
+
+
+def _good_lines():
+    return [
+        {"phase": "kernel", "ok": True, "interpret": False,
+         "device": {k: TPU[k] for k in ("platform", "kind", "count")}},
+        {"phase": "serve[auto]", "ok": True, "boot": _boot()},
+        {"phase": "serve[paged]", "ok": True,
+         "boot": _boot(attn_impl="paged", cache_hit=21, cache_miss=9)},
+    ]
+
+
+def _with(path, value):
+    """The good run with one thing wrong: ``path`` into the lines."""
+    lines = _good_lines()
+    node = lines
+    for key in path[:-1]:
+        node = node[key]
+    node[path[-1]] = value
+    return lines
+
+
+BAD_RUNS = {
+    "engine-on-cpu": _with((1, "boot", "device"),
+                           {**TPU, "platform": "cpu", "kind": "cpu"}),
+    "second-engine-on-cpu": _with((2, "boot", "device"),
+                                  {**TPU, "platform": "cpu", "kind": "cpu"}),
+    "kernel-on-cpu": _with((0, "device"),
+                           {"platform": "cpu", "kind": "cpu", "count": 1}),
+    "kernel-interpreted": _with((0, "interpret"), True),
+    "engine-interpreted": _with((2, "boot", "pallas_interpret"), True),
+    "no-compile-cache": _with((1, "boot", "cache_dir"), None),
+    "warmup-stage-failed": _with((1, "boot", "warmup_failures"), 1),
+    "nothing-warmed": _with((1, "boot", "warmup_families"), 0),
+    "depth-cut": _with((1, "boot", "num_layers"), 2),
+    "cache-never-found-again": _with((2, "boot", "cache_hit"), 0),
+    "phase-failed": _with((1, "ok"), False),
+    "wrong-device-count": _with((2, "boot", "device"), {**TPU, "count": 4}),
+    "nothing-served": _good_lines()[:1],
+}
+
+
+def test_verdict_passes_a_run_that_is_right_in_every_respect():
+    final = chip_smoke.verdict(_good_lines(), 1, chip_smoke.FULL_DEPTH)
+    assert final == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+@pytest.mark.parametrize("what", sorted(BAD_RUNS))
+def test_verdict_fails_when_not_on_the_chip(what, capsys):
+    final = chip_smoke.verdict(copy.deepcopy(BAD_RUNS[what]), 1,
+                               chip_smoke.FULL_DEPTH)
+    assert final["ok"] is False
+    faults = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert faults["phase"] == "verdict" and faults["faults"]
+
+
+def test_rehearsal_never_passes():
+    final = chip_smoke.verdict(_good_lines(), 1, chip_smoke.FULL_DEPTH,
+                               rehearsal=True)
+    assert final["ok"] is False
+
+
+def test_main_exits_nonzero_on_a_cpu_engine_report(monkeypatch, capsys):
+    """End to end through main(): phases answered by an engine that says it
+    is on a CPU -> last stdout line ``"ok": false``, exit code 1; the same
+    phases from a TPU engine -> ``"ok": true``, exit code 0."""
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    monkeypatch.setenv("PYTHONPATH", os.environ.get("PYTHONPATH", ""))
+    good = _good_lines()
+    served = iter(good[1:])
+    monkeypatch.setattr(chip_smoke, "phase_kernel", lambda *a: good[0])
+    monkeypatch.setattr(chip_smoke, "phase_serve", lambda *a: next(served))
+    assert chip_smoke.main([]) == 0
+    last = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert last == {"ok": True, "device": {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+    bad = BAD_RUNS["engine-on-cpu"]
+    served = iter(bad[1:])
+    monkeypatch.setattr(chip_smoke, "phase_kernel", lambda *a: bad[0])
+    assert chip_smoke.main([]) == 1
+    last = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert last["ok"] is False
+
+
+def _run_script(cwd, *argv, timeout=900):
+    return subprocess.run(
+        [sys.executable, os.path.join(cwd, "chip_smoke.py"), *argv],
+        cwd=cwd, capture_output=True, text=True, timeout=timeout,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+
+
+@pytest.mark.parametrize("where", ["checkout", "bare-directory"])
+def test_script_fails_without_an_accelerator(where, tmp_path):
+    """As the driver runs it first: no arguments, no chip. Non-zero exit,
+    no ``"ok": true`` anywhere, and llama-3b is not run on the CPU (the run
+    takes seconds). In a directory that holds nothing but the script it
+    must fail too."""
+    cwd = REPO
+    if where == "bare-directory":
+        shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+        cwd = str(tmp_path)
+    proc = _run_script(cwd, timeout=120)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    last = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("chips", [1, 4])
+def test_cpu_rehearsal_runs_every_phase_and_fails(chips):
+    """``--rehearse``: every phase of the script on the CPU at a tiny model
+    (JAX_PLATFORMS=cpu; four virtual devices stand in for four chips). The
+    requests themselves must be answered correctly — a phase whose own
+    checks fail here would fail on the chip too — and the last line is
+    still ``"ok": false``."""
+    proc = _run_script(REPO, "--rehearse", "--chips", str(chips))
+    lines = [json.loads(ln) for ln in proc.stdout.splitlines()
+             if ln.startswith("{")]
+    assert proc.returncode != 0, proc.stdout[-2000:]
+    assert lines[-1]["ok"] is False
+    assert lines[-1]["device"]["platform"] == "cpu"
+    phases = {ln["phase"]: ln for ln in lines if "phase" in ln}
+    if chips == 1:
+        want = ["kernel", "serve[auto]", "serve[paged]", "paths"]
+        passing = want[:3]
+        assert phases["serve[paged]"]["boot"]["cache_hit"] > 0
+        assert phases["serve[auto]"]["prefix_hit_tokens"] > 0
+    else:
+        want = ["reference[1 chip]", "replicas[4 x 1 chip]",
+                "tp4[1 x 4 chips]"]
+        # Off-TPU the replicas cannot own distinct chips and the CPU
+        # reports no bytes in use: those two phases fail on that alone.
+        passing = want[:1]
+        assert all(phases[want[1]]["served"])
+        assert phases[want[1]]["matched_reference"] == \
+            phases[want[1]]["served"]
+        assert phases[want[2]]["kv_heads_per_device"] == 2
+        assert lines[-1]["device"]["count"] == 4
+    assert all(p in phases for p in want), sorted(phases)
+    for name in passing:
+        assert phases[name]["ok"], phases[name].get("faults") or \
+            phases[name].get("error")

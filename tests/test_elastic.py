@@ -14,6 +14,7 @@ prewarm on vs off).
 
 import asyncio
 import json
+import os
 import struct
 import time
 
@@ -271,26 +272,86 @@ def test_scraper_scrapes_mid_run_backend_add_immediately(monkeypatch):
         sd._service_discovery = None
 
 
-# ------------------------------------------------ compile-cache degradation
-def test_setup_compilation_cache_failure_degrades(monkeypatch, tmp_path):
+# ------------------------------------------------ compile-cache placement
+@pytest.fixture
+def _restore_jax_cache_dir():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("placed_by", ["variable", "config", "neither"])
+def test_setup_compilation_cache_placement(placed_by, monkeypatch, tmp_path,
+                                           _restore_jax_cache_dir):
+    """JAX_COMPILATION_CACHE_DIR places the cache from outside: the
+    directory is used as it is and jax's own cache-dir config is left at
+    the variable's value (no update in code, no fingerprint sub-directory).
+    Unset, the configured directory gets the platform fingerprint and jax
+    is pointed at it; with neither there is no cache."""
     import jax
 
-    from production_stack_tpu.engine import runner as runner_mod
+    from production_stack_tpu.engine.runner import _setup_compilation_cache
 
-    monkeypatch.setattr(runner_mod, "_cache_configured_dir", None)
+    dev = jax.devices()[0]
+    outside = str(tmp_path / "placed-from-outside")
+    if placed_by == "variable":
+        # What jax does at import with the variable set.
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        jax.config.update("jax_compilation_cache_dir", outside)
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1],
+        )
+        assert _setup_compilation_cache(str(tmp_path / "cfg"), dev) == outside
+        assert jax.config.jax_compilation_cache_dir == outside
+        assert "jax_compilation_cache_dir" not in updates
+        # An empty configured dir does not switch an outside cache off.
+        assert _setup_compilation_cache("", dev) == outside
+    elif placed_by == "config":
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        got = _setup_compilation_cache(str(tmp_path / "cfg"), dev)
+        assert os.path.dirname(got) == str(tmp_path / "cfg")
+        assert os.path.basename(got).startswith(f"{dev.platform}-")
+        assert f"jax{jax.__version__}" in got
+        assert jax.config.jax_compilation_cache_dir == got
+        # Fixed for a fixed input: the directory is part of the cache key.
+        assert _setup_compilation_cache(str(tmp_path / "cfg"), dev) == got
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        assert _setup_compilation_cache("", dev) is None
+        assert jax.config.jax_compilation_cache_dir == before
 
-    def boom(*a, **kw):
-        raise RuntimeError("no such config knob")
 
-    monkeypatch.setattr(jax.config, "update", boom)
-    assert runner_mod._setup_compilation_cache(str(tmp_path)) is None
+def test_default_compilation_cache_dir_is_fixed_and_in_checkout():
+    from production_stack_tpu.engine.config import (
+        DEFAULT_COMPILATION_CACHE_DIR,
+    )
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert DEFAULT_COMPILATION_CACHE_DIR == os.path.join(
+        repo, ".pstpu_xla_cache"
+    )
+    assert EngineConfig().compilation_cache_dir == \
+        DEFAULT_COMPILATION_CACHE_DIR
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".pstpu_xla_cache/" in f.read().split()
 
 
-def test_cache_entry_count_unreadable_dir():
-    from production_stack_tpu.engine.runner import _cache_entry_count
+def test_cache_entries_unreadable_dir(tmp_path):
+    from production_stack_tpu.engine.runner import _cache_entries
 
-    assert _cache_entry_count(None) == -1
-    assert _cache_entry_count("/nonexistent/pstpu-cache-dir") == -1
+    assert _cache_entries(None) is None
+    assert _cache_entries("/nonexistent/pstpu-cache-dir") is None
+    (tmp_path / "abc-cache").write_bytes(b"x")
+    (tmp_path / "abc-atime").write_bytes(b"x")
+    assert _cache_entries(str(tmp_path)) == frozenset({"abc-cache"})
 
 
 # ------------------------------------------------------- engine-level noop

@@ -248,10 +248,11 @@ def test_roofline_components_pinned():
     # Depth-dominant regime: the KV term is ~all the traffic, so the int8
     # roofline approaches the byte ratio (1.94x at Dh=64).
     deep_bf = bench.roofline_components(
-        "tiny-llama", 2.0, "bfloat16", batch=256, avg_ctx=16384
+        "tiny-llama", 2.0, "bfloat16", batch=256, avg_ctx=16384,
+        peak_gbs=819.0,
     )
     deep_i8 = bench.roofline_components(
-        "tiny-llama", 2.0, "int8", batch=256, avg_ctx=16384
+        "tiny-llama", 2.0, "int8", batch=256, avg_ctx=16384, peak_gbs=819.0,
     )
     assert deep_i8["roofline_tok_s"] / deep_bf["roofline_tok_s"] > 1.8
 

@@ -186,7 +186,9 @@ ROUTES: Tuple[Route, ...] = (
     Route("POST", "/prewarm", ("engine", "fake"), False, False, None,
           "Prompt prewarm push (router initialize_all fan-out)."),
     Route("GET", "/version", ("engine", "fake"), False, False, None,
-          "Build/schema versions for mixed-fleet rollout checks."),
+          "Build version, plus what the engine runs on and how it started: "
+          "mesh device platform/kind/count, attention path, interpret "
+          "mode, compile-cache dir and warmup hit/miss counts."),
     Route("POST", "/disagg/prefill", ("engine",), False, True, None,
           "Internal router->engine hop 1 of the disagg flow; never "
           "client-facing."),
