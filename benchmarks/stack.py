@@ -467,7 +467,12 @@ class StackHandle:
                 proc.terminate()
         for proc in procs:
             try:
-                proc.wait(timeout=15)
+                # An engine whose profiler capture is being written when
+                # SIGTERM arrives finishes the file first (profiling.py:
+                # close); a 4 s capture of a full-size model took 13-72 s
+                # to stop (my chip run, PR 24), so 15 s would kill it
+                # mid-write.
+                proc.wait(timeout=180)
             except subprocess.TimeoutExpired:
                 proc.kill()
                 proc.wait(timeout=15)

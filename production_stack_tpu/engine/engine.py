@@ -57,6 +57,13 @@ class RequestOutput:
     # [(token_id, logprob), ...top-k]) per output token, aligned with
     # token_ids (None otherwise).
     logprobs: Optional[List] = None
+    # time.monotonic() when the request was enqueued in the scheduler and
+    # when its first token was appended (the engine's TTFT stamps): the
+    # HTTP surface measures handler entry -> enqueue and first token ->
+    # first chunk handed to the transport from them
+    # (pstpu:http_ingress_seconds, pstpu:first_chunk_emit_seconds).
+    arrival_time: Optional[float] = None
+    first_token_time: Optional[float] = None
 
 
 @dataclass
@@ -184,16 +191,6 @@ class ServingEngine:
         self._new_work = asyncio.Event()
         self._loop_task: Optional[asyncio.Task] = None
         self._running = False
-        # Optional per-dispatch timeline (production debugging): set
-        # PSTPU_DISPATCH_LOG=/path to append one line per ISSUE and one per
-        # FETCH of every device dispatch (`issue kind=... step=N ...` /
-        # `fetch kind=... step=N ... ms=...`), so prefill/decode overlap is
-        # directly visible as an issue line landing between another step's
-        # issue and fetch lines.
-        import os
-
-        _dlog = os.environ.get("PSTPU_DISPATCH_LOG")
-        self._dispatch_log = open(_dlog, "a") if _dlog else None
         # Dispatch-pipeline telemetry (the overlap win must be observable,
         # not asserted): per-kind dispatch counts, how many fetches ran with
         # another dispatch still outstanding (overlap), and the cumulative
@@ -217,9 +214,33 @@ class ServingEngine:
         # AFTER execute_async returns, so it folds compile time in; this
         # one isolates the host's own scheduling stall).
         self.host_stall_seconds_total = 0.0
+        # Loop spans (flight_recorder.LoopSpans): the loop's phases as
+        # annotations on the profiler's clock, and the per-phase seconds
+        # that tile the loop's wall time (pstpu:loop_*_seconds_total).
+        from production_stack_tpu.engine.flight_recorder import LoopSpans
+
+        self.loop_spans = LoopSpans()
+        # Decode work counted where it happens (host integers the loop
+        # holds; no device sync), all three at APPLY so that over any
+        # window row-steps less wasted row-steps is exactly the tokens
+        # decode delivered. A STEP is one iteration of the fused decode
+        # loop the device ran: the while_loop stops at the largest per-row
+        # budget, the scan runs all K. A ROW-STEP is one real row in one
+        # such step (padding rows of the shape bucket are not rows). A
+        # row-step is WASTED when its token was not delivered: the row hit
+        # EOS / max_tokens / a stop string earlier in the train, was
+        # aborted or preempted, failed the epoch check, or its fetch
+        # failed. Under a speculative mode a step is one draft/verify
+        # cycle of the K the dispatch is allowed (the host cannot see an
+        # early exit) and a row-step can deliver up to N+1 tokens, so
+        # wasted is clamped at 0 there and says little: read pstpu:spec_*.
+        self.decode_steps_total = 0
+        self.decode_row_steps_total = 0
+        self.decode_row_steps_wasted_total = 0
         # telemetry
         from production_stack_tpu.engine.metrics import (
             DispatchDurationHistograms,
+            HttpSurfaceHistograms,
             LifecycleHistograms,
             RequestLatencyHistograms,
         )
@@ -244,6 +265,10 @@ class ServingEngine:
         # Per-train issue->fetch duration histograms (prefill / decode /
         # decode_spec), observed at fetch from the handle's issue stamp.
         self.dispatch_hists = DispatchDurationHistograms()
+        # The HTTP surface's own time (server/api_server.py observes):
+        # handler entry -> Sequence enqueued, first token -> first chunk
+        # handed to the transport.
+        self.http_surface = HttpSurfaceHistograms()
         self.scheduler.on_preempt = self._on_preempt
         self.scheduler.on_restore = self._on_restore
         self.start_time = time.monotonic()
@@ -293,9 +318,6 @@ class ServingEngine:
             self.offload.close()
         if self.disagg is not None:
             self.disagg.close()
-        if self._dispatch_log is not None:
-            self._dispatch_log.close()
-            self._dispatch_log = None
 
     @property
     def offload_blocks_resident(self) -> int:
@@ -770,6 +792,9 @@ class ServingEngine:
             depth = 1
         overlap = cfg.overlap_dispatch and depth >= 2
         in_flight: deque = deque()  # (batch, step_id, DispatchHandle) FIFO
+        from production_stack_tpu.engine.flight_recorder import annotated
+
+        loop_span = self.loop_spans
 
         def abort_batch(batch):
             for seq in batch.seqs:
@@ -777,18 +802,18 @@ class ServingEngine:
                 if aborted is not None:
                     self._process_output(aborted)
 
-        def dlog(event, batch, step, extra=""):
-            if self._dispatch_log is None:
-                return
-            kt = (batch.num_steps if batch.kind == "decode"
-                  else max(batch.chunk_lens))
-            self._dispatch_log.write(
-                f"{event} kind={batch.kind} step={step} "
-                f"rows={len(batch.seqs)} kt={kt} "
-                f"inflight={len(in_flight)} t={time.monotonic():.6f}"
-                f"{extra}\n"
-            )
-            self._dispatch_log.flush()
+        def count_decode(batch, delivered):
+            """One applied decode dispatch into the step and row counters
+            (defined in __init__)."""
+            steps = batch.num_steps
+            if cfg.decode_loop != "scan" and batch.spec_mode == "off" \
+                    and batch.decode_steps:
+                steps = min(steps, max(batch.decode_steps))
+            row_steps = steps * len(batch.seqs)
+            self.decode_steps_total += steps
+            self.decode_row_steps_total += row_steps
+            self.decode_row_steps_wasted_total += max(
+                0, row_steps - delivered)
 
         async def apply_oldest():
             batch, step, handle = in_flight.popleft()
@@ -801,48 +826,64 @@ class ServingEngine:
                      if cfg.speculative_num_tokens else 0)
             spec_d0 = (self.runner.spec_draft_tokens_total
                        if cfg.speculative_num_tokens else 0)
-            try:
-                tokens, lps = await loop.run_in_executor(None, handle.fetch)
-            except Exception:  # noqa: BLE001 — engine loop must survive
-                logger.exception("Dispatch fetch failed; aborting batch")
-                abort_batch(batch)
-                self._last_fetch_done = time.monotonic()
-                return
-            dlog("fetch", batch, step, extra=(
-                f" ms={(time.monotonic() - handle.issue_time) * 1000:.1f}"
-            ))
-            self._record_fetch(
-                batch, step, tokens, handle.issue_time,
-                (self.runner.spec_accepted_tokens_total - spec0)
-                if cfg.speculative_num_tokens else 0,
-                (self.runner.spec_draft_tokens_total - spec_d0)
-                if cfg.speculative_num_tokens else 0,
-            )
-            self.last_step_time = self._last_fetch_done = time.monotonic()
-            produced, accepted = self.scheduler.apply_results(
-                batch, tokens, lps
-            )
-            self.generation_tokens_total += accepted
-            # Live roofline accounting (stats() folds the window into the
-            # pstpu:live_* gauges): all values below are host-side reads
-            # the loop already has — no device sync. target_steps counts
-            # the target model's scan steps a decode train ran, so
-            # emitted/target_steps is the Leviathan'23 amortization factor
-            # (>1 only when speculation pays).
-            train = ("prefill" if batch.kind != "decode"
-                     else "decode_spec" if batch.spec_mode != "off"
-                     else "decode")
-            duration = self._last_fetch_done - handle.issue_time
-            target_steps = (len(batch.seqs) * batch.num_steps
-                            if batch.kind == "decode" else 0)
-            self.dispatch_hists.observe(train, duration)
-            self._dispatch_window.append(
-                (self._last_fetch_done, duration, train, accepted,
-                 target_steps)
-            )
-            for seq in produced:
-                self._process_output(seq)
-            await self._publish_handoffs(produced)
+            # `sync`: whether this fetch blocks on the device at all (a
+            # prefill dispatch no row of which ended its prompt fetches
+            # nothing) — the capture's reader pairs only those.
+            sync = int(batch.kind == "decode" or any(batch.finals))
+            failed = False
+            with loop_span("pstpu.fetch", step=step, kind=batch.kind,
+                           sync=sync):
+                try:
+                    tokens, lps = await loop.run_in_executor(
+                        None, annotated, "pstpu.fetch.sync", step,
+                        handle.fetch,
+                    )
+                except Exception:  # noqa: BLE001 — engine loop must survive
+                    logger.exception("Dispatch fetch failed; aborting batch")
+                    failed = True
+            with loop_span("pstpu.apply", step=step, kind=batch.kind):
+                if failed:
+                    abort_batch(batch)
+                    self._last_fetch_done = time.monotonic()
+                    if batch.kind == "decode":
+                        count_decode(batch, 0)
+                    return
+                self._record_fetch(
+                    batch, step, tokens, handle.issue_time,
+                    (self.runner.spec_accepted_tokens_total - spec0)
+                    if cfg.speculative_num_tokens else 0,
+                    (self.runner.spec_draft_tokens_total - spec_d0)
+                    if cfg.speculative_num_tokens else 0,
+                )
+                self.last_step_time = self._last_fetch_done = \
+                    time.monotonic()
+                produced, accepted = self.scheduler.apply_results(
+                    batch, tokens, lps
+                )
+                self.generation_tokens_total += accepted
+                if batch.kind == "decode":
+                    count_decode(batch, accepted)
+                # Live roofline accounting (stats() folds the window into
+                # the pstpu:live_* gauges): all values below are host-side
+                # reads the loop already has — no device sync.
+                # target_steps counts the target model's scan steps a
+                # decode train ran, so emitted/target_steps is the
+                # Leviathan'23 amortization factor (>1 only when
+                # speculation pays).
+                train = ("prefill" if batch.kind != "decode"
+                         else "decode_spec" if batch.spec_mode != "off"
+                         else "decode")
+                duration = self._last_fetch_done - handle.issue_time
+                target_steps = (len(batch.seqs) * batch.num_steps
+                                if batch.kind == "decode" else 0)
+                self.dispatch_hists.observe(train, duration)
+                self._dispatch_window.append(
+                    (self._last_fetch_done, duration, train, accepted,
+                     target_steps)
+                )
+                for seq in produced:
+                    self._process_output(seq)
+                await self._publish_handoffs(produced)
 
         async def drain():
             while in_flight:
@@ -860,15 +901,20 @@ class ServingEngine:
                 prefer_decode=("prefill" in kinds and "decode" not in kinds)
             )
 
+        # The six loop_span phases below tile this loop's wall time: every
+        # statement of an iteration is inside exactly one of them (spans
+        # never nest; drain() between schedule and issue opens its own).
         while self._running:
-            self._apply_pending_aborts()
-            if self._pending_restores:
-                await self._apply_restores()
-            if self._pending_prewarms:
-                await self._apply_prewarms()
+            with loop_span("pstpu.housekeeping"):
+                self._apply_pending_aborts()
+                if self._pending_restores:
+                    await self._apply_restores()
+                if self._pending_prewarms:
+                    await self._apply_prewarms()
             issue_failed = False
             while len(in_flight) < depth and not issue_failed:
-                batch = next_batch()
+                with loop_span("pstpu.schedule"):
+                    batch = next_batch()
                 if batch is None:
                     break
                 # Penalty counts are built from APPLIED tokens; drain the
@@ -880,63 +926,76 @@ class ServingEngine:
                     await drain()
                 step = self._step_counter
                 self._step_counter += 1
-                # Captured BEFORE the issue call: a cold-shape compile
-                # inside execute_async belongs to this dispatch's phase
-                # interval (see _record_issue).
-                issue_wall, issue_mono = time.time(), time.monotonic()
-                try:
-                    # Issue in the executor: normally enqueue-only (~ms),
-                    # but a cold shape family compiles for seconds and a
-                    # penalty batch builds [b, vocab] counts — neither may
-                    # freeze the event loop (SSE, health). Runner state
-                    # stays effectively single-threaded: issue and fetch
-                    # are each awaited before the next runner call.
-                    handle = await loop.run_in_executor(
-                        None, self.runner.execute_async, batch, step
-                    )
-                except Exception:  # noqa: BLE001 — engine loop must survive
-                    logger.exception("Dispatch issue failed; aborting batch")
-                    abort_batch(batch)
-                    issue_failed = True
-                    break
-                if not in_flight and self._last_fetch_done is not None:
-                    self.dispatch_gap_seconds_total += (
-                        time.monotonic() - self._last_fetch_done
-                    )
-                    # issue_mono predates execute_async, so this isolates
-                    # the host's own stall from any compile inside issue.
-                    self.host_stall_seconds_total += max(
-                        0.0, issue_mono - self._last_fetch_done
-                    )
-                if batch.kind == "decode":
-                    self.decode_dispatches_total += 1
-                else:
-                    self.prefill_dispatches_total += 1
-                self.scheduler.advance_at_issue(batch)
-                dlog("issue", batch, step)
-                self._record_issue(batch, step, issue_wall, issue_mono)
-                in_flight.append((batch, step, handle))
+                with loop_span(
+                    "pstpu.issue", step=step, kind=batch.kind,
+                    rows=len(batch.seqs),
+                    k=(batch.num_steps if batch.kind == "decode"
+                       else max(batch.chunk_lens)),
+                ):
+                    # Captured BEFORE the issue call: a cold-shape compile
+                    # inside execute_async belongs to this dispatch's phase
+                    # interval (see _record_issue).
+                    issue_wall, issue_mono = time.time(), time.monotonic()
+                    try:
+                        # Issue in the executor: normally enqueue-only
+                        # (~ms), but a cold shape family compiles for
+                        # seconds and a penalty batch builds [b, vocab]
+                        # counts — neither may freeze the event loop (SSE,
+                        # health). Runner state stays effectively
+                        # single-threaded: issue and fetch are each awaited
+                        # before the next runner call.
+                        handle = await loop.run_in_executor(
+                            None, annotated, "pstpu.issue.enqueue", step,
+                            self.runner.execute_async, batch, step,
+                        )
+                    except Exception:  # noqa: BLE001 — loop must survive
+                        logger.exception(
+                            "Dispatch issue failed; aborting batch")
+                        abort_batch(batch)
+                        issue_failed = True
+                        break
+                    if not in_flight and self._last_fetch_done is not None:
+                        self.dispatch_gap_seconds_total += (
+                            time.monotonic() - self._last_fetch_done
+                        )
+                        # issue_mono predates execute_async, so this
+                        # isolates the host's own stall from any compile
+                        # inside issue.
+                        self.host_stall_seconds_total += max(
+                            0.0, issue_mono - self._last_fetch_done
+                        )
+                    if batch.kind == "decode":
+                        self.decode_dispatches_total += 1
+                    else:
+                        self.prefill_dispatches_total += 1
+                    self.scheduler.advance_at_issue(batch)
+                    self._record_issue(batch, step, issue_wall, issue_mono)
+                    in_flight.append((batch, step, handle))
             if in_flight:
                 # Applying may finish rows and free blocks, unblocking
                 # admission — the next iteration re-schedules right after.
                 await apply_oldest()
-                await asyncio.sleep(0)
+                with loop_span("pstpu.housekeeping"):
+                    await asyncio.sleep(0)
                 continue
             if issue_failed:
                 continue
-            self._new_work.clear()
-            # Idle: drop the persistent decode window so its (up to
-            # window-budget-sized) device buffers don't pin HBM.
-            self.runner._win_cache = None
-            if not self.scheduler.has_work() and not self._pending_restores:
-                try:
-                    await asyncio.wait_for(self._new_work.wait(), timeout=1.0)
-                except asyncio.TimeoutError:
-                    pass
-            else:
-                # Work exists but nothing schedulable (pool starved by
-                # in-flight requests) — yield and retry.
-                await asyncio.sleep(0.001)
+            with loop_span("pstpu.idle"):
+                self._new_work.clear()
+                # Idle: drop the persistent decode window so its (up to
+                # window-budget-sized) device buffers don't pin HBM.
+                self.runner._win_cache = None
+                if not self.scheduler.has_work() \
+                        and not self._pending_restores:
+                    try:
+                        await asyncio.wait_for(self._new_work.wait(),
+                                               timeout=1.0)
+                    except asyncio.TimeoutError:
+                        pass
+                else:
+                    # Work exists but nothing schedulable (pool starved by
+                    # in-flight requests) — yield and retry.
+                    await asyncio.sleep(0.001)
         # Drain on shutdown so no accepted tokens are lost, and let
         # in-flight handoff publishes finish so accepted transfers reach
         # the store.
@@ -1235,6 +1294,8 @@ class ServingEngine:
                 list(seq.output_logprobs)
                 if seq.sampling.logprobs is not None else None
             ),
+            arrival_time=seq.arrival_time,
+            first_token_time=seq.first_token_time,
         ))
 
     # ------------------------------------------------------------------ stats
@@ -1318,11 +1379,14 @@ class ServingEngine:
 
         r = self.runner
         cached = _cache_entries(r.compilation_cache_path)
-        bytes_in_use = {}
+        bytes_in_use, peak_bytes_in_use = {}, {}
         for d in self.mesh.devices.flat:
-            stats = d.memory_stats()
-            if stats and "bytes_in_use" in stats:
+            stats = d.memory_stats() or {}
+            if "bytes_in_use" in stats:
                 bytes_in_use[str(d.id)] = int(stats["bytes_in_use"])
+            if "peak_bytes_in_use" in stats:
+                peak_bytes_in_use[str(d.id)] = int(
+                    stats["peak_bytes_in_use"])
         return {
             "device": self.device_report(),
             "engine": {
@@ -1349,6 +1413,10 @@ class ServingEngine:
                     r.kv_k.sharding.shard_shape(r.kv_k.shape)
                 ),
                 "bytes_in_use": bytes_in_use,
+                # The allocator's high-water mark since process start
+                # (program temporaries included, which bytes_in_use after
+                # a window no longer holds).
+                "peak_bytes_in_use": peak_bytes_in_use,
                 "hbm_peak_gbps": self.hbm_peak_gbps,
             },
         }
@@ -1460,5 +1528,11 @@ class ServingEngine:
             "dispatch_gap_seconds_total": self.dispatch_gap_seconds_total,
             # Live roofline telemetry (docs/OBSERVABILITY.md fleet pane).
             "host_stall_seconds_total": self.host_stall_seconds_total,
+            # Loop spans and decode work (see __init__).
+            **self.loop_spans.counters(),
+            "decode_steps_total": self.decode_steps_total,
+            "decode_row_steps_total": self.decode_row_steps_total,
+            "decode_row_steps_wasted_total":
+                self.decode_row_steps_wasted_total,
             **self._live_perf(),
         }

@@ -20,11 +20,25 @@ The same timelines back the engine's retrospective span tree: ``phases()``
 folds a record's events into queue-wait / prefill / decode / kv-restore /
 handoff phase intervals the API server exports as OTLP child spans of the
 request's server span (production_stack_tpu/tracing.py).
+
+``LoopSpans`` (below) is the engine LOOP's side of the same seam: named
+phase spans on the profiler's clock plus the per-phase second totals
+``/metrics`` exports. A span's ``step`` is the id the ``*_issue`` /
+``*_fetch`` events above carry, so a request's timeline joins the dispatch
+span that caused it.
 """
 
 import time
 from collections import OrderedDict
 from typing import Dict, List, Optional
+
+try:
+    from jax.profiler import TraceAnnotation
+except Exception:  # noqa: BLE001 — no profiler: spans still keep totals
+    import contextlib
+
+    def TraceAnnotation(name, **attrs):  # noqa: N802 — stands in for a class
+        return contextlib.nullcontext()
 
 # Event names recorded by the engine (docs/OBSERVABILITY.md schema table).
 EVENT_NAMES = (
@@ -32,6 +46,75 @@ EVENT_NAMES = (
     "decode_issue", "decode_fetch", "restore", "preempt",
     "handoff_restore", "handoff_publish", "finish",
 )
+
+
+# The engine loop's phases. Together they tile the loop's wall time
+# (docs/OBSERVABILITY.md "Loop spans"): every second the loop lives is in
+# exactly one of them.
+# phase -> its counter on /metrics (``pstpu:<name>``) and key in stats().
+LOOP_COUNTERS = {
+    "schedule": "loop_schedule_seconds_total",
+    "issue": "loop_issue_seconds_total",
+    "fetch": "loop_fetch_wait_seconds_total",
+    "apply": "loop_apply_seconds_total",
+    "idle": "loop_idle_seconds_total",
+    "housekeeping": "loop_other_seconds_total",
+}
+
+
+class _LoopSpan:
+    __slots__ = ("_totals", "_phase", "_annotation", "_t0")
+
+    def __init__(self, totals: Dict[str, float], phase: str,
+                 annotation) -> None:
+        self._totals = totals
+        self._phase = phase
+        self._annotation = annotation
+
+    def __enter__(self) -> "_LoopSpan":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._totals[self._phase] += time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+
+
+class LoopSpans:
+    """``with loop_span("pstpu.<phase>", step=..., kind=...):`` around one
+    phase of the engine loop. Entry opens a ``jax.profiler.TraceAnnotation``
+    with those attributes, so a capture armed through ``POST
+    /debug/profile`` shows the phase on the device's time axis (no capture
+    armed: the annotation is a no-op); exit adds the elapsed
+    ``perf_counter`` time to ``seconds[<phase>]``, which ``/metrics``
+    exports under ``LOOP_COUNTERS[<phase>]``.
+
+    Spans do not nest and only the loop's own coroutine opens them: an
+    ``await`` inside one lets other coroutines run on the thread, and an
+    annotation of theirs would interleave with the open one."""
+
+    def __init__(self) -> None:
+        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_COUNTERS, 0.0)
+
+    def __call__(self, name: str, **attrs) -> _LoopSpan:
+        return _LoopSpan(self.seconds, name.rpartition(".")[2],
+                         TraceAnnotation(name, **attrs))
+
+    def counters(self) -> Dict[str, float]:
+        return {LOOP_COUNTERS[phase]: seconds
+                for phase, seconds in self.seconds.items()}
+
+
+def annotated(name: str, step: int, fn, *args):
+    """``fn(*args)`` under a ``TraceAnnotation``: the part of a loop span
+    that runs in the executor thread (``pstpu.issue.enqueue``,
+    ``pstpu.fetch.sync``) gets an event of its own there, with the span's
+    ``step``, which is what separates the blocking device call from the
+    executor hop around it. (Positional only: ``run_in_executor`` passes
+    no keywords.)"""
+    with TraceAnnotation(name, step=step):
+        return fn(*args)
 
 
 class FlightRecord:

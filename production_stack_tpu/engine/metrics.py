@@ -226,6 +226,50 @@ class EngineMetricsCollector(Collector):
                       "nothing outstanding on device (the host's own "
                       "scheduling stall, compile time excluded)",
                       getattr(eng, "host_stall_seconds_total", 0.0))
+        # Loop spans and decode work (engine.py:_run_loop; the text
+        # renderer exports the same series — PL004-aligned, "loop" group).
+        spans = getattr(eng, "loop_spans", None)
+        loop_seconds = spans.counters() if spans is not None else {}
+        yield counter("pstpu:loop_schedule_seconds_total",
+                      "Engine-loop seconds in scheduler.schedule() "
+                      "(span pstpu.schedule)",
+                      loop_seconds.get("loop_schedule_seconds_total", 0.0))
+        yield counter("pstpu:loop_issue_seconds_total",
+                      "Engine-loop seconds issuing dispatches: "
+                      "execute_async in the executor, advance_at_issue, "
+                      "issue records (span pstpu.issue)",
+                      loop_seconds.get("loop_issue_seconds_total", 0.0))
+        yield counter("pstpu:loop_fetch_wait_seconds_total",
+                      "Engine-loop seconds awaiting a dispatch's fetch: "
+                      "the host blocked on the device (span pstpu.fetch)",
+                      loop_seconds.get("loop_fetch_wait_seconds_total", 0.0))
+        yield counter("pstpu:loop_apply_seconds_total",
+                      "Engine-loop seconds applying fetched results: "
+                      "fetch records, apply_results, output processing, "
+                      "handoff publishes (span pstpu.apply)",
+                      loop_seconds.get("loop_apply_seconds_total", 0.0))
+        yield counter("pstpu:loop_idle_seconds_total",
+                      "Engine-loop seconds with nothing schedulable: "
+                      "waiting for work or retrying (span pstpu.idle)",
+                      loop_seconds.get("loop_idle_seconds_total", 0.0))
+        yield counter("pstpu:loop_other_seconds_total",
+                      "Engine-loop seconds in aborts, restores, prewarms "
+                      "and the yield after an apply "
+                      "(span pstpu.housekeeping)",
+                      loop_seconds.get("loop_other_seconds_total", 0.0))
+        yield counter("pstpu:decode_steps_total",
+                      "Decode-loop steps the device ran, over applied "
+                      "decode dispatches",
+                      getattr(eng, "decode_steps_total", 0))
+        yield counter("pstpu:decode_row_steps_total",
+                      "Real rows times the steps their decode dispatch "
+                      "ran (padding rows are not rows)",
+                      getattr(eng, "decode_row_steps_total", 0))
+        yield counter("pstpu:decode_row_steps_wasted_total",
+                      "Decode row-steps whose token was not delivered "
+                      "(row finished earlier in the train, aborted, "
+                      "preempted, or its fetch failed)",
+                      getattr(eng, "decode_row_steps_wasted_total", 0))
         # Per-train dispatch duration histogram ({train=prefill|decode|
         # decode_spec}) — the only engine family with a second live label.
         dh = getattr(eng, "dispatch_hists", None)
@@ -268,6 +312,18 @@ class EngineMetricsCollector(Collector):
                         "Duration of each shared-tier I/M restore round "
                         "trip that restored KV blocks",
                         getattr(lc, "restore_round_trip", None))
+        # The HTTP surface's own time (server/api_server.py observes).
+        hs = getattr(eng, "http_surface", None)
+        yield histogram("pstpu:http_ingress_seconds",
+                        "HTTP handler entry to the request's enqueue in "
+                        "the scheduler (body parse, chat template, "
+                        "tokenisation)",
+                        getattr(hs, "ingress", None))
+        yield histogram("pstpu:first_chunk_emit_seconds",
+                        "First token appended in the engine loop to the "
+                        "first chunk handed to the transport (the whole "
+                        "body when not streaming)",
+                        getattr(hs, "first_chunk_emit", None))
         # Exporter hygiene (docs/OBSERVABILITY.md): spans the OTLP queue
         # had to drop — tracing never blocks serving, but never silently.
         from production_stack_tpu.tracing import spans_dropped_total
@@ -464,6 +520,35 @@ class DispatchDurationHistograms:
                 "pstpu:dispatch_duration_seconds", "", tl,
             )[2:])
         return lines
+
+
+class HttpSurfaceHistograms:
+    """The engine's own HTTP surface, measured inside it (the router's
+    relay and the wire are not in these): ``ingress`` is handler entry to
+    the Sequence's enqueue (body parse, chat template, tokenisation);
+    ``first_chunk_emit`` is the first token's append in the engine loop to
+    the first SSE chunk handed to the transport — for a non-streaming
+    request to the whole body, which then contains the decode."""
+
+    def __init__(self):
+        self.ingress = Histogram(PHASE_BUCKETS)
+        self.first_chunk_emit = Histogram(PHASE_BUCKETS)
+
+    def render(self, label: str) -> list:
+        return (
+            self.ingress.render(
+                "pstpu:http_ingress_seconds",
+                "HTTP handler entry to the request's enqueue in the "
+                "scheduler (body parse, chat template, tokenisation)",
+                label,
+            )
+            + self.first_chunk_emit.render(
+                "pstpu:first_chunk_emit_seconds",
+                "First token appended in the engine loop to the first "
+                "chunk handed to the transport (the whole body when not "
+                "streaming)", label,
+            )
+        )
 
 
 class LifecycleHistograms:

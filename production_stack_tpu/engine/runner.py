@@ -1284,15 +1284,16 @@ class ModelRunner:
             else:
                 lp = None
             # Append this step's KV (+ its position) to the ring at index j.
-            ring_k = jax.lax.dynamic_update_slice(
-                ring_k, k_new, (0, 0, 0, j, 0)
-            )
-            ring_v = jax.lax.dynamic_update_slice(
-                ring_v, v_new, (0, 0, 0, j, 0)
-            )
-            ring_pos = jax.lax.dynamic_update_slice(
-                ring_pos, positions, (0, j)
-            )
+            with jax.named_scope("kv_write"):
+                ring_k = jax.lax.dynamic_update_slice(
+                    ring_k, k_new, (0, 0, 0, j, 0)
+                )
+                ring_v = jax.lax.dynamic_update_slice(
+                    ring_v, v_new, (0, 0, 0, j, 0)
+                )
+                ring_pos = jax.lax.dynamic_update_slice(
+                    ring_pos, positions, (0, j)
+                )
             # The carried token freezes at each row's step budget, so the
             # final carry is the row's LAST VALID sampled token — the
             # chain vector the next dispatch may start from.
@@ -1351,49 +1352,51 @@ class ModelRunner:
         # ONE scatter writes the whole dispatch's KV back to the paged pool
         # (quantized mode: the int8 payload + per-slot scales the scan
         # recorded; the pool never holds compute-dtype KV).
-        flat_slots = slot_steps.reshape(-1)                       # [K*b]
-        k_flat = ring_k.transpose(0, 1, 3, 2, 4).reshape(
-            nl, hkv, num_steps * b, dh
-        )
-        v_flat = ring_v.transpose(0, 1, 3, 2, 4).reshape(
-            nl, hkv, num_steps * b, dh
-        )
-        if quant:
-            ring_qk, ring_qv, ring_sk, ring_sv = qstate
-            kv_k = kv_k.at[:, :, flat_slots].set(
-                ring_qk.transpose(0, 1, 3, 2, 4).reshape(
-                    nl, hkv, num_steps * b, dh
+        with jax.named_scope("kv_write"):
+            flat_slots = slot_steps.reshape(-1)                       # [K*b]
+            k_flat = ring_k.transpose(0, 1, 3, 2, 4).reshape(
+                nl, hkv, num_steps * b, dh
+            )
+            v_flat = ring_v.transpose(0, 1, 3, 2, 4).reshape(
+                nl, hkv, num_steps * b, dh
+            )
+            if quant:
+                ring_qk, ring_qv, ring_sk, ring_sv = qstate
+                kv_k = kv_k.at[:, :, flat_slots].set(
+                    ring_qk.transpose(0, 1, 3, 2, 4).reshape(
+                        nl, hkv, num_steps * b, dh
+                    )
                 )
-            )
-            kv_v = kv_v.at[:, :, flat_slots].set(
-                ring_qv.transpose(0, 1, 3, 2, 4).reshape(
-                    nl, hkv, num_steps * b, dh
+                kv_v = kv_v.at[:, :, flat_slots].set(
+                    ring_qv.transpose(0, 1, 3, 2, 4).reshape(
+                        nl, hkv, num_steps * b, dh
+                    )
                 )
-            )
-            kv_ks = kv_ks.at[:, :, flat_slots].set(
-                ring_sk.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
-            )
-            kv_vs = kv_vs.at[:, :, flat_slots].set(
-                ring_sv.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
-            )
-        else:
-            kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-            kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
+                kv_ks = kv_ks.at[:, :, flat_slots].set(
+                    ring_sk.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
+                )
+                kv_vs = kv_vs.at[:, :, flat_slots].set(
+                    ring_sv.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
+                )
+            else:
+                kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
+                kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
         if self.attn_impl != "paged":
             # Append the dispatch's KV into the persistent window too (slot
             # s = absolute position s), so the next dispatch over the same
             # rows skips the full re-gather. Out-of-budget steps drop. The
             # quantized path appends the DEQUANTIZED values — identical to
             # what a fresh pool gather would reconstruct.
-            s_tot = mb * bs
-            iota_b = jnp.arange(b, dtype=jnp.int32)[None, :]      # [1, b]
-            widx = jnp.where(valid, iota_b * s_tot + p, b * s_tot)
-            win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
-                :, :, widx.reshape(-1)
-            ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
-            win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
-                :, :, widx.reshape(-1)
-            ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
+            with jax.named_scope("kv_write"):
+                s_tot = mb * bs
+                iota_b = jnp.arange(b, dtype=jnp.int32)[None, :]      # [1, b]
+                widx = jnp.where(valid, iota_b * s_tot + p, b * s_tot)
+                win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
+                    :, :, widx.reshape(-1)
+                ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
+                win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
+                    :, :, widx.reshape(-1)
+                ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
             return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k, win_v,
                     lp_chosen, lp_top, lp_ids, last_token,
                     *self._spec_dummy_outs(spec_k, spec_v, spec_pos))
@@ -1860,29 +1863,30 @@ class ModelRunner:
         # ONE pool scatter for the whole dispatch, slots derived from the
         # committed ring positions (invalid entries -> reserved null
         # block 0, never read).
-        valid_e = ring_pos < _POS_SENTINEL
-        blk = jnp.take_along_axis(
-            block_tables, jnp.clip(ring_pos // bs, 0, mb - 1), axis=1
-        )
-        flat_slots = jnp.where(
-            valid_e, blk * bs + ring_pos % bs, 0
-        ).reshape(-1)
-        k_flat = ring_k.reshape(nl, hkv, b * s_ring, dh)
-        v_flat = ring_v.reshape(nl, hkv, b * s_ring, dh)
-        kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-        kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
-        # Append into the persistent window too (slot s = position s), so
-        # the next dispatch over the same rows reuses it.
-        s_tot = mb * bs
-        widx = jnp.where(
-            valid_e, iota_b[:, None] * s_tot + ring_pos, b * s_tot
-        ).reshape(-1)
-        win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
-            :, :, widx
-        ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
-        win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
-            :, :, widx
-        ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
+        with jax.named_scope("kv_write"):
+            valid_e = ring_pos < _POS_SENTINEL
+            blk = jnp.take_along_axis(
+                block_tables, jnp.clip(ring_pos // bs, 0, mb - 1), axis=1
+            )
+            flat_slots = jnp.where(
+                valid_e, blk * bs + ring_pos % bs, 0
+            ).reshape(-1)
+            k_flat = ring_k.reshape(nl, hkv, b * s_ring, dh)
+            v_flat = ring_v.reshape(nl, hkv, b * s_ring, dh)
+            kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
+            kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
+            # Append into the persistent window too (slot s = position s), so
+            # the next dispatch over the same rows reuses it.
+            s_tot = mb * bs
+            widx = jnp.where(
+                valid_e, iota_b[:, None] * s_tot + ring_pos, b * s_tot
+            ).reshape(-1)
+            win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
+                :, :, widx
+            ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
+            win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
+                :, :, widx
+            ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
 
         spec_k = spec_k.at[:, :, slot_idx].set(drk, mode="drop")
         spec_v = spec_v.at[:, :, slot_idx].set(drv, mode="drop")
@@ -2301,23 +2305,24 @@ class ModelRunner:
             lp = (None, None, None)
 
         nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
-        flat_slots = slot_mapping.reshape(-1)                     # [b*t]
-        k_flat = k_new.reshape(nl, hkv, b * t, dh)
-        v_flat = v_new.reshape(nl, hkv, b * t, dh)
-        if quant:
-            # Quantize the chunk's KV on device before the single scatter
-            # — compute-dtype KV never lands in the pool.
-            from production_stack_tpu.ops.quantization import quantize_kv
+        with jax.named_scope("kv_write"):
+            flat_slots = slot_mapping.reshape(-1)                     # [b*t]
+            k_flat = k_new.reshape(nl, hkv, b * t, dh)
+            v_flat = v_new.reshape(nl, hkv, b * t, dh)
+            if quant:
+                # Quantize the chunk's KV on device before the single scatter
+                # — compute-dtype KV never lands in the pool.
+                from production_stack_tpu.ops.quantization import quantize_kv
 
-            kq, ks = quantize_kv(k_flat)
-            vq, vs = quantize_kv(v_flat)
-            kv_k = kv_k.at[:, :, flat_slots].set(kq)
-            kv_v = kv_v.at[:, :, flat_slots].set(vq)
-            kv_ks = kv_ks.at[:, :, flat_slots].set(ks)
-            kv_vs = kv_vs.at[:, :, flat_slots].set(vs)
-        else:
-            kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-            kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
+                kq, ks = quantize_kv(k_flat)
+                vq, vs = quantize_kv(v_flat)
+                kv_k = kv_k.at[:, :, flat_slots].set(kq)
+                kv_v = kv_v.at[:, :, flat_slots].set(vq)
+                kv_ks = kv_ks.at[:, :, flat_slots].set(ks)
+                kv_vs = kv_vs.at[:, :, flat_slots].set(vs)
+            else:
+                kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
+                kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
         # Speculative draft warm-up (docs/PERF.md round 8): run the DRAFT
         # model over the same chunk so its per-sequence KV ring holds the
         # prompt context before decode starts — a cold draft ring proposes

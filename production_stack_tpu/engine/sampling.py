@@ -86,6 +86,7 @@ class SamplingParams:
         )
 
 
+@jax.named_scope("sample")
 def apply_penalties(
     logits: jax.Array,       # [B, V]
     counts: jax.Array,       # [B, V] int — output-token occurrence counts
@@ -253,6 +254,7 @@ def _gumbel(seeds: jax.Array, shape) -> jax.Array:
 
 
 @jax.jit
+@jax.named_scope("sample")
 def sample_tokens(
     logits: jax.Array,       # [B, V] float32
     temperature: jax.Array,  # [B]
@@ -316,6 +318,7 @@ def sampling_scores(
     return jnp.where(temperature[:, None] <= 0.0, greedy_scores, perturbed)
 
 
+@jax.named_scope("sample")
 def compute_logprobs(
     logits: jax.Array,       # [B, V] float32
     chosen: jax.Array,       # [B] int32 sampled/continuation token ids

@@ -12,6 +12,12 @@ TPU-first design notes:
     after the forward — scanning the pools as xs/ys cost a full pool copy per
     layer (~2 ms/step on a v5e, profiled round 1).
 
+Device operations are named by ``jax.named_scope``: ``embed``, ``attn_proj``
+(QKV, rope, output projection), ``attn_core`` (every attention path), ``ffn``
+and ``logits`` here; ``sample`` in engine/sampling.py and ``kv_write`` in
+engine/runner.py. A profiler capture carries the scope in each operation's
+``tf_op`` (docs/OBSERVABILITY.md); scopes cost nothing at run time.
+
 Weight layout matches HuggingFace LlamaForCausalLM for direct safetensors
 loading (production_stack_tpu/models/weights.py).
 """
@@ -118,112 +124,116 @@ def _layer_body(
             out = out + lora_delta(x, la, lb, lora[0])
         return out
 
-    x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps)
-    q = proj(x, "wq")
-    k = proj(x, "wk")
-    v = proj(x, "wv")
-    if cfg.attention_bias:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(b, t, h, dh)
-    k = k.reshape(b, t, hkv, dh)
-    v = v.reshape(b, t, hkv, dh)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps)
+        q = proj(x, "wq")
+        k = proj(x, "wk")
+        v = proj(x, "wv")
+        if cfg.attention_bias:
+            q = q + lp["bq"]
+            k = k + lp["bk"]
+            v = v + lp["bv"]
+        q = q.reshape(b, t, h, dh)
+        k = k.reshape(b, t, hkv, dh)
+        v = v.reshape(b, t, hkv, dh)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    if ring_mesh is not None and t > 1 and win_k is None and ring_k is None:
-        # Sequence-parallel prefill: the chunk is pure causal self-attention
-        # (no history window, no intra-dispatch ring buffer), computed
-        # exactly by ring attention over the sp axis — KV shards stream
-        # around the ICI ring while each chip holds O(T/sp) tokens
-        # (ops/ring_attention.py). Padding rows/tokens carry positions
-        # beyond every real token of their row, so causal masking by
-        # absolute position excludes them as keys.
-        from production_stack_tpu.ops.ring_attention import ring_attention
+    with jax.named_scope("attn_core"):
+        if ring_mesh is not None and t > 1 and win_k is None and ring_k is None:
+            # Sequence-parallel prefill: the chunk is pure causal self-attention
+            # (no history window, no intra-dispatch ring buffer), computed
+            # exactly by ring attention over the sp axis — KV shards stream
+            # around the ICI ring while each chip holds O(T/sp) tokens
+            # (ops/ring_attention.py). Padding rows/tokens carry positions
+            # beyond every real token of their row, so causal masking by
+            # absolute position excludes them as keys.
+            from production_stack_tpu.ops.ring_attention import ring_attention
 
-        attn = ring_attention(q, k, v, positions, ring_mesh)
-    elif ring_mesh is not None and t > 1 and win_k is not None \
-            and ring_k is None:
-        # Sequence-parallel CONTINUATION chunk: the combined sequence
-        # (gathered history window ++ chunk) is the ring's KV, sharded over
-        # sp — each chip holds O((S_hist + T)/sp) keys instead of the whole
-        # window, and ring attention engages on every chunk of a long
-        # prefill, not just the first (VERDICT r4 weak #5). Window slot s
-        # holds absolute position s; slots at or beyond win_len take a
-        # sentinel position beyond every query so position-causality masks
-        # them exactly like window_attention's validity bias.
-        from production_stack_tpu.ops.ring_attention import ring_attention_kv
+            attn = ring_attention(q, k, v, positions, ring_mesh)
+        elif ring_mesh is not None and t > 1 and win_k is not None \
+                and ring_k is None:
+            # Sequence-parallel CONTINUATION chunk: the combined sequence
+            # (gathered history window ++ chunk) is the ring's KV, sharded over
+            # sp — each chip holds O((S_hist + T)/sp) keys instead of the whole
+            # window, and ring attention engages on every chunk of a long
+            # prefill, not just the first (VERDICT r4 weak #5). Window slot s
+            # holds absolute position s; slots at or beyond win_len take a
+            # sentinel position beyond every query so position-causality masks
+            # them exactly like window_attention's validity bias.
+            from production_stack_tpu.ops.ring_attention import ring_attention_kv
 
-        s_hist = win_k.shape[2]
-        kw = win_k.transpose(1, 2, 0, 3)        # [B, S, Hkv, Dh]
-        vw = win_v.transpose(1, 2, 0, 3)
-        s_idx = jnp.arange(s_hist, dtype=jnp.int32)
-        pos_w = jnp.where(
-            s_idx[None, :] < win_len[:, None], s_idx[None, :],
-            jnp.int32(2**30),
-        )                                        # [B, S]
-        attn = ring_attention_kv(
-            q, positions,
-            jnp.concatenate([kw, k], axis=1),
-            jnp.concatenate([vw, v], axis=1),
-            jnp.concatenate([pos_w, positions], axis=1),
-            ring_mesh,
-        )
-    elif paged is not None:
-        # Paged decode (T == 1): the pool segment runs in the Pallas
-        # flash-decode kernel directly against this layer of the stacked HBM
-        # pool (no gathered window copy); the intra-dispatch ring + the
-        # current token form a small dense segment; the two merge by their
-        # softmax stats. See ops/pallas/paged_attention.py.
-        from production_stack_tpu.ops.pallas.paged_attention import (
-            paged_flash_decode_stats,
-            paged_flash_decode_stats_tp,
-        )
-
-        (pool_k, pool_v, pool_ks, pool_vs, block_tables, kv_lens,
-         block_size, interpret, tp_mesh) = paged
-        q2 = q.reshape(b, h, dh)
-        if tp_mesh is not None:
-            # TP>1: the pool is kv-head-sharded; run the kernel per-shard
-            # via shard_map (exact — heads are independent) instead of
-            # letting GSPMD all-gather the pool (advisor r3 high finding).
-            out_p, m_p, l_p = paged_flash_decode_stats_tp(
-                q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
-                tp_mesh, block_size=block_size, interpret=interpret,
-                k_scale=pool_ks, v_scale=pool_vs,
+            s_hist = win_k.shape[2]
+            kw = win_k.transpose(1, 2, 0, 3)        # [B, S, Hkv, Dh]
+            vw = win_v.transpose(1, 2, 0, 3)
+            s_idx = jnp.arange(s_hist, dtype=jnp.int32)
+            pos_w = jnp.where(
+                s_idx[None, :] < win_len[:, None], s_idx[None, :],
+                jnp.int32(2**30),
+            )                                        # [B, S]
+            attn = ring_attention_kv(
+                q, positions,
+                jnp.concatenate([kw, k], axis=1),
+                jnp.concatenate([vw, v], axis=1),
+                jnp.concatenate([pos_w, positions], axis=1),
+                ring_mesh,
             )
-        else:
-            out_p, m_p, l_p = paged_flash_decode_stats(
-                q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
-                block_size=block_size, interpret=interpret,
-                k_scale=pool_ks, v_scale=pool_vs,
+        elif paged is not None:
+            # Paged decode (T == 1): the pool segment runs in the Pallas
+            # flash-decode kernel directly against this layer of the stacked HBM
+            # pool (no gathered window copy); the intra-dispatch ring + the
+            # current token form a small dense segment; the two merge by their
+            # softmax stats. See ops/pallas/paged_attention.py.
+            from production_stack_tpu.ops.pallas.paged_attention import (
+                paged_flash_decode_stats,
+                paged_flash_decode_stats_tp,
             )
-        kc = k.transpose(2, 0, 1, 3)          # [Hkv, B, 1, Dh] current token
-        vc = v.transpose(2, 0, 1, 3)
-        self_bias = jnp.zeros((b, 1), jnp.float32)
-        if ring_k is not None:
-            keys = jnp.concatenate([ring_k, kc], axis=2)
-            vals = jnp.concatenate([ring_v, vc], axis=2)
-            neg = jnp.float32(jnp.finfo(jnp.float32).min)
-            ring_bias = jnp.where(ring_pos < positions, 0.0, neg)  # [B, R]
-            bias = jnp.concatenate([ring_bias, self_bias], axis=1)
-        else:
-            keys, vals, bias = kc, vc, self_bias
-        out_d, m_d, l_d = dense_decode_stats(q2, keys, vals, bias)
-        attn = merge_attention_segments(out_p, m_p, l_p, out_d, m_d, l_d)
-        attn = attn.reshape(b, t, h, dh)
-    else:
-        attn = window_attention(
-            q, k, v, positions, chunk_lens,
-            win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-            chunk_bias=chunk_bias,
-        )
-    hidden = hidden + proj(attn.reshape(b, t, h * dh), "wo")
 
-    x = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps)
-    gated = jax.nn.silu(proj(x, "w_gate")) * proj(x, "w_up")
-    mlp = proj(gated, "w_down")
+            (pool_k, pool_v, pool_ks, pool_vs, block_tables, kv_lens,
+             block_size, interpret, tp_mesh) = paged
+            q2 = q.reshape(b, h, dh)
+            if tp_mesh is not None:
+                # TP>1: the pool is kv-head-sharded; run the kernel per-shard
+                # via shard_map (exact — heads are independent) instead of
+                # letting GSPMD all-gather the pool (advisor r3 high finding).
+                out_p, m_p, l_p = paged_flash_decode_stats_tp(
+                    q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
+                    tp_mesh, block_size=block_size, interpret=interpret,
+                    k_scale=pool_ks, v_scale=pool_vs,
+                )
+            else:
+                out_p, m_p, l_p = paged_flash_decode_stats(
+                    q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
+                    block_size=block_size, interpret=interpret,
+                    k_scale=pool_ks, v_scale=pool_vs,
+                )
+            kc = k.transpose(2, 0, 1, 3)          # [Hkv, B, 1, Dh] current token
+            vc = v.transpose(2, 0, 1, 3)
+            self_bias = jnp.zeros((b, 1), jnp.float32)
+            if ring_k is not None:
+                keys = jnp.concatenate([ring_k, kc], axis=2)
+                vals = jnp.concatenate([ring_v, vc], axis=2)
+                neg = jnp.float32(jnp.finfo(jnp.float32).min)
+                ring_bias = jnp.where(ring_pos < positions, 0.0, neg)  # [B, R]
+                bias = jnp.concatenate([ring_bias, self_bias], axis=1)
+            else:
+                keys, vals, bias = kc, vc, self_bias
+            out_d, m_d, l_d = dense_decode_stats(q2, keys, vals, bias)
+            attn = merge_attention_segments(out_p, m_p, l_p, out_d, m_d, l_d)
+            attn = attn.reshape(b, t, h, dh)
+        else:
+            attn = window_attention(
+                q, k, v, positions, chunk_lens,
+                win_k, win_v, win_len, ring_k, ring_v, ring_pos,
+                chunk_bias=chunk_bias,
+            )
+    with jax.named_scope("attn_proj"):
+        hidden = hidden + proj(attn.reshape(b, t, h * dh), "wo")
+
+    with jax.named_scope("ffn"):
+        x = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps)
+        gated = jax.nn.silu(proj(x, "w_gate")) * proj(x, "w_up")
+        mlp = proj(gated, "w_down")
     # New KV in pool layout [Hkv, B, T, Dh] for the runner's single scatter.
     return hidden + mlp, k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)
 
@@ -265,10 +275,11 @@ def forward(
     projection/MLP matmuls distribute over sp; GSPMD inserts the collectives.
     The standalone ring kernel lives in production_stack_tpu/ops/ring_attention.py.
     """
-    hidden = params["embed"][token_ids]
-    hidden = hidden.astype(
-        win_k.dtype if win_k is not None else params["embed"].dtype
-    )
+    with jax.named_scope("embed"):
+        hidden = params["embed"][token_ids]
+        hidden = hidden.astype(
+            win_k.dtype if win_k is not None else params["embed"].dtype
+        )
     if act_sharding is not None and hidden.shape[1] > 1 and \
             hidden.shape[1] % act_sharding.mesh.shape["sp"] == 0:
         hidden = jax.lax.with_sharding_constraint(hidden, act_sharding)
@@ -319,7 +330,10 @@ def forward(
 
 def compute_logits(params: Params, cfg: ModelConfig, hidden: jax.Array) -> jax.Array:
     """hidden [..., D] -> logits [..., V] in float32."""
-    head = params["embed"].T if cfg.tie_word_embeddings else params["lm_head"]
-    return jnp.dot(
-        hidden, head.astype(hidden.dtype), preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("logits"):
+        head = params["embed"].T if cfg.tie_word_embeddings \
+            else params["lm_head"]
+        return jnp.dot(
+            hidden, head.astype(hidden.dtype),
+            preferred_element_type=jnp.float32,
+        )

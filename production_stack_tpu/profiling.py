@@ -7,6 +7,16 @@ settles the attribution. One capture at a time, bounded duration, and
 404-clean when profiling is unavailable (jax.profiler missing or debug
 endpoints disabled) — production routers probing /debug must see a plain
 404, never a crash.
+
+What a capture holds: the device planes (XLA modules and operations, each
+operation's ``tf_op`` carrying its ``jax.named_scope`` path), and on the
+host planes the engine loop's ``pstpu.*`` spans (engine/flight_recorder.py:
+LoopSpans) beside the runtime's own events — NOT every Python frame: the
+Python tracer is off unless the caller asks for ``python_frames``, which
+multiplies the host planes' size and the time the stop takes. A
+``pstpu.clock`` annotation written as the capture starts carries the wall
+and monotonic clocks' readings, so flight-recorder and OTLP times (wall
+clock) can be laid on the capture's axis.
 """
 
 import asyncio
@@ -50,7 +60,8 @@ class DeviceProfiler:
         return hasattr(jp, "start_trace") and hasattr(jp, "stop_trace")
 
     async def arm(self, duration_s: float,
-                  trace_dir: Optional[str] = None) -> dict:
+                  trace_dir: Optional[str] = None,
+                  python_frames: bool = False) -> dict:
         """Start a capture; a background task stops it after
         ``duration_s``. Raises ProfilerBusy while one is in flight."""
         import jax.profiler as jp
@@ -65,10 +76,19 @@ class DeviceProfiler:
             prefix="pstpu-profile-"
         )
         os.makedirs(trace_dir, exist_ok=True)
-        jp.start_trace(trace_dir)
+        options = jp.ProfileOptions()
+        options.python_tracer_level = 1 if python_frames else 0
+        options.host_tracer_level = 2
+        jp.start_trace(trace_dir, profiler_options=options)
+        # The clock anchor: one event on the capture's axis that names the
+        # wall and monotonic clocks' readings at that instant.
+        with jp.TraceAnnotation("pstpu.clock", wall_ns=time.time_ns(),
+                                mono_ns=time.monotonic_ns()):
+            pass
         self.active = {
             "trace_dir": trace_dir,
             "duration_s": duration_s,
+            "python_frames": bool(python_frames),
             "started_at": time.time(),
         }
         self._stop_task = asyncio.get_running_loop().create_task(
@@ -82,24 +102,37 @@ class DeviceProfiler:
         try:
             await asyncio.sleep(duration_s)
         finally:
-            self._finish_capture()
+            await self._finish_capture()
 
-    def _finish_capture(self) -> None:
-        if self.active is None:
+    async def _finish_capture(self) -> None:
+        """Stop the capture in the executor: ``stop_trace`` collects the
+        planes and writes the file, which takes seconds, and the event
+        loop (SSE streams, /health, the engine loop itself) must not wait
+        for it. ``active`` stays set until the file is written, so a
+        second arm meanwhile is refused as busy."""
+        if self.active is None or self.active.get("stopping"):
             return
         import jax.profiler as jp
 
         info = self.active
-        self.active = None
+        info["stopping"] = True
+        stop_began = time.time()
         try:
-            jp.stop_trace()
+            await asyncio.get_running_loop().run_in_executor(
+                None, jp.stop_trace)
         except Exception:  # noqa: BLE001 — a failed stop must not wedge arm
             logger.exception("jax.profiler.stop_trace failed")
-            info = {**info, "error": "stop_trace failed"}
-        info = {**info, "stopped_at": time.time()}
-        self.last = info
-        logger.info("Device profiling capture complete: %s",
-                    info["trace_dir"])
+            info["error"] = "stop_trace failed"
+        finally:
+            # Also on cancellation (engine shutdown while the file is
+            # being written): never leave the profiler wedged as busy.
+            del info["stopping"]
+            info.update(stopped_at=time.time(),
+                        stop_seconds=round(time.time() - stop_began, 3))
+            self.active = None
+            self.last = info
+        logger.info("Device profiling capture complete: %s (stop took "
+                    "%.2fs)", info["trace_dir"], info["stop_seconds"])
 
     def status(self) -> dict:
         return {
@@ -109,12 +142,15 @@ class DeviceProfiler:
         }
 
     async def close(self) -> None:
-        """Stop any in-flight capture (engine shutdown)."""
+        """Stop any in-flight capture (engine shutdown). A stop that is
+        already writing its file is waited for, not cancelled: the process
+        must not exit under it."""
         task, self._stop_task = self._stop_task, None
         if task is not None and not task.done():
-            task.cancel()
+            if not (self.active or {}).get("stopping"):
+                task.cancel()       # still asleep: its finally stops now
             try:
                 await task
             except asyncio.CancelledError:
                 pass
-        self._finish_capture()
+        await self._finish_capture()

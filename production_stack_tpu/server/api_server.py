@@ -164,6 +164,9 @@ class APIServer:
     def build_app(self) -> web.Application:
         @web.middleware
         async def trace(request: web.Request, handler):
+            # Handler entry, for pstpu:http_ingress_seconds (the engine
+            # observes its distance to the request's enqueue).
+            request["pstpu_ingress_time"] = time.monotonic()
             # Continue the router's trace via the W3C traceparent header
             # (production_stack_tpu/tracing.py; enabled by the standard
             # OTEL_EXPORTER_OTLP_ENDPOINT / OTEL_SERVICE_NAME env vars —
@@ -311,7 +314,8 @@ class APIServer:
     async def debug_profile_start(self, request: web.Request) -> web.Response:
         """POST /debug/profile: arm jax.profiler.trace for a bounded
         window (perfetto trace dir; one capture at a time; 404-clean when
-        profiling is unavailable)."""
+        profiling is unavailable). ``python_frames: true`` adds every
+        Python frame to the host planes (off by default: profiling.py)."""
         if self.profiler is None or not self.profiler.available():
             return _error(404, "Device profiling unavailable",
                           etype="not_found")
@@ -328,11 +332,15 @@ class APIServer:
         trace_dir = body.get("trace_dir")
         if trace_dir is not None and not isinstance(trace_dir, str):
             return _error(400, "'trace_dir' must be a string path")
+        python_frames = body.get("python_frames", False)
+        if not isinstance(python_frames, bool):
+            return _error(400, "'python_frames' must be a boolean")
         from production_stack_tpu.profiling import ProfilerBusy
 
         try:
             info = await self.profiler.arm(float(duration),
-                                           trace_dir=trace_dir)
+                                           trace_dir=trace_dir,
+                                           python_frames=python_frames)
         except ProfilerBusy as e:
             return _error(409, str(e), etype="conflict")
         except Exception as e:  # noqa: BLE001 — capture start must not 500
@@ -1084,6 +1092,20 @@ class APIServer:
                 kw["resume_seed"] = resume_seed
             return kw
 
+        ingress_time = request.get("pstpu_ingress_time")
+
+        def observe_surface(out) -> None:
+            """The HTTP surface's own time, once per choice, when its first
+            chunk (the whole body when not streaming) has been handed to
+            the transport: handler entry -> enqueue in the scheduler, and
+            first token appended in the engine loop -> now."""
+            surface = self.engine.http_surface
+            if ingress_time is not None and out.arrival_time is not None:
+                surface.ingress.observe(out.arrival_time - ingress_time)
+            if out.first_token_time is not None:
+                surface.first_chunk_emit.observe(
+                    time.monotonic() - out.first_token_time)
+
         if stream:
             response = web.StreamResponse(
                 status=200,
@@ -1113,6 +1135,7 @@ class APIServer:
             # role delta and the resumed tokens' text/logprobs — start the
             # per-choice emission bookkeeping past them.
             first_sent = [bool(resume_tokens)] * num_choices
+            emit_observed = [False] * num_choices
             lp_sent = [n_resume] * num_choices
             lp_offset = [0] * num_choices
             # Per-chunk resume payload (single-choice streams): the output
@@ -1249,6 +1272,9 @@ class APIServer:
                             }
                             tok_sent[idx] = len(out.token_ids)
                         await response.write(_sse(payload))
+                        if not emit_observed[idx]:
+                            emit_observed[idx] = True
+                            observe_surface(out)
                 if finals and body.get("stream_options", {}).get(
                     "include_usage"
                 ):
@@ -1346,6 +1372,8 @@ class APIServer:
                 }
             choices.append(choice)
         self._emit_lifecycle_spans(request, child_rids)
+        for final in finals:
+            observe_surface(final)
         return web.json_response({
             "id": request_id,
             "object": object_name,
@@ -1420,6 +1448,13 @@ def build_engine_from_args(args: argparse.Namespace) -> ServingEngine:
         **({"flight_recorder_max_events": args.flight_recorder_max_events}
            if getattr(args, "flight_recorder_max_events", None) is not None
            else {}),
+        # Unset unless given, like the rest above: a caller that supplies
+        # its own EngineConfig defaults (kwargs.setdefault around __init__)
+        # must still decide these when the flag is absent.
+        **({"load_format": args.load_format}
+           if getattr(args, "load_format", None) is not None else {}),
+        **({"seed": args.seed}
+           if getattr(args, "seed", None) is not None else {}),
     )
     return ServingEngine(cfg)
 
@@ -1434,6 +1469,16 @@ def parse_args(argv=None) -> argparse.Namespace:
                    help="model name or HF checkpoint path to serve")
     p.add_argument("--served-model-name", default=None,
                    help="name advertised on /v1/models (default: --model)")
+    p.add_argument("--load-format", default=None,
+                   choices=["auto", "safetensors", "dummy"],
+                   help="where weights come from: auto (a checkpoint if "
+                        "--model holds one), safetensors, or dummy (random "
+                        "weights made on the device from --seed; a "
+                        "directory holding only config.json then serves) "
+                        "(default: auto)")
+    p.add_argument("--seed", type=int, default=None,
+                   help="seed the dummy or randomly initialised weights "
+                        "are made from (default: 0)")
     p.add_argument("--dtype", default="bfloat16",
                    help="compute dtype (bfloat16 | float32)")
     p.add_argument("--kv-cache-dtype", default="bfloat16",

@@ -37,7 +37,13 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "startup_total_seconds", "startup_cache_hit_families",
                 "startup_cache_miss_families",
                 "trace_spans_dropped_total",
-                "host_stall_seconds_total", "live_tok_per_s",
+                "host_stall_seconds_total",
+                "loop_schedule_seconds_total", "loop_issue_seconds_total",
+                "loop_fetch_wait_seconds_total",
+                "loop_apply_seconds_total", "loop_idle_seconds_total",
+                "loop_other_seconds_total", "decode_steps_total",
+                "decode_row_steps_total", "decode_row_steps_wasted_total",
+                "live_tok_per_s",
                 "live_hbm_bw_pct",
                 "live_effective_tokens_per_target_step"):
         s.setdefault(key, 0)
@@ -257,6 +263,60 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:host_stall_seconds_total counter",
         f"pstpu:host_stall_seconds_total{label} "
         f"{s['host_stall_seconds_total']:.6f}",
+        # Loop spans (engine.py:_run_loop, flight_recorder.LoopSpans): the
+        # six phases tile the loop's wall time, so their deltas over a
+        # window sum to the window; and decode work counted where it
+        # happens (the collector renders the same nine series).
+        "# HELP pstpu:loop_schedule_seconds_total Engine-loop seconds in "
+        "scheduler.schedule() (span pstpu.schedule)",
+        "# TYPE pstpu:loop_schedule_seconds_total counter",
+        f"pstpu:loop_schedule_seconds_total{label} "
+        f"{s['loop_schedule_seconds_total']:.6f}",
+        "# HELP pstpu:loop_issue_seconds_total Engine-loop seconds "
+        "issuing dispatches: execute_async in the executor, "
+        "advance_at_issue, issue records (span pstpu.issue)",
+        "# TYPE pstpu:loop_issue_seconds_total counter",
+        f"pstpu:loop_issue_seconds_total{label} "
+        f"{s['loop_issue_seconds_total']:.6f}",
+        "# HELP pstpu:loop_fetch_wait_seconds_total Engine-loop seconds "
+        "awaiting a dispatch's fetch: the host blocked on the device "
+        "(span pstpu.fetch)",
+        "# TYPE pstpu:loop_fetch_wait_seconds_total counter",
+        f"pstpu:loop_fetch_wait_seconds_total{label} "
+        f"{s['loop_fetch_wait_seconds_total']:.6f}",
+        "# HELP pstpu:loop_apply_seconds_total Engine-loop seconds "
+        "applying fetched results: fetch records, apply_results, output "
+        "processing, handoff publishes (span pstpu.apply)",
+        "# TYPE pstpu:loop_apply_seconds_total counter",
+        f"pstpu:loop_apply_seconds_total{label} "
+        f"{s['loop_apply_seconds_total']:.6f}",
+        "# HELP pstpu:loop_idle_seconds_total Engine-loop seconds with "
+        "nothing schedulable: waiting for work or retrying "
+        "(span pstpu.idle)",
+        "# TYPE pstpu:loop_idle_seconds_total counter",
+        f"pstpu:loop_idle_seconds_total{label} "
+        f"{s['loop_idle_seconds_total']:.6f}",
+        "# HELP pstpu:loop_other_seconds_total Engine-loop seconds in "
+        "aborts, restores, prewarms and the yield after an apply "
+        "(span pstpu.housekeeping)",
+        "# TYPE pstpu:loop_other_seconds_total counter",
+        f"pstpu:loop_other_seconds_total{label} "
+        f"{s['loop_other_seconds_total']:.6f}",
+        "# HELP pstpu:decode_steps_total Decode-loop steps the device "
+        "ran, over applied decode dispatches",
+        "# TYPE pstpu:decode_steps_total counter",
+        f"pstpu:decode_steps_total{label} {s['decode_steps_total']}",
+        "# HELP pstpu:decode_row_steps_total Real rows times the steps "
+        "their decode dispatch ran (padding rows are not rows)",
+        "# TYPE pstpu:decode_row_steps_total counter",
+        f"pstpu:decode_row_steps_total{label} "
+        f"{s['decode_row_steps_total']}",
+        "# HELP pstpu:decode_row_steps_wasted_total Decode row-steps "
+        "whose token was not delivered (row finished earlier in the "
+        "train, aborted, preempted, or its fetch failed)",
+        "# TYPE pstpu:decode_row_steps_wasted_total counter",
+        f"pstpu:decode_row_steps_wasted_total{label} "
+        f"{s['decode_row_steps_wasted_total']}",
         # Observability plane (docs/OBSERVABILITY.md): OTLP spans the
         # exporter queue had to drop — tracing never blocks serving, but
         # never silently either (the collector renders the same series;
@@ -346,4 +406,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
     dispatch_hists = getattr(engine, "dispatch_hists", None)
     if dispatch_hists is not None:
         lines += dispatch_hists.render(label)
+    # The HTTP surface's own time: ingress and first-chunk emit.
+    http_surface = getattr(engine, "http_surface", None)
+    if http_surface is not None:
+        lines += http_surface.render(label)
     return "\n".join(lines) + "\n"

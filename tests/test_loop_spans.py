@@ -1,0 +1,454 @@
+"""The engine loop's own instrumentation (docs/OBSERVABILITY.md, "Loop
+spans"): the six phase totals tile the loop's wall time, decode work is
+counted where it happens, the new series reach /metrics on both renderers,
+a capture armed through DeviceProfiler holds the ``pstpu.*`` spans with
+their ``step`` and a clock anchor and no Python frame, stopping it does
+not block the event loop, and ``--load-format`` / ``--seed`` reach
+``EngineConfig`` only when given. CPU, tiny-llama."""
+
+import asyncio
+import glob
+import json
+import os
+import time
+
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from production_stack_tpu.engine.config import EngineConfig
+from production_stack_tpu.engine.engine import ServingEngine
+from production_stack_tpu.engine.flight_recorder import (
+    LOOP_COUNTERS,
+    LoopSpans,
+)
+from production_stack_tpu.engine.sampling import SamplingParams
+from production_stack_tpu.server.api_server import (
+    APIServer,
+    build_engine_from_args,
+    parse_args,
+)
+
+NEW_SERIES = (
+    *(f"pstpu:{name}" for name in LOOP_COUNTERS.values()),
+    "pstpu:decode_steps_total", "pstpu:decode_row_steps_total",
+    "pstpu:decode_row_steps_wasted_total", "pstpu:http_ingress_seconds",
+    "pstpu:first_chunk_emit_seconds",
+)
+
+
+def _cfg(**over):
+    base = dict(model="tiny-llama", max_model_len=256, num_kv_blocks=128,
+                num_decode_steps=8, dtype="float32", max_num_seqs=4,
+                max_num_batched_tokens=64)
+    base.update(over)
+    return EngineConfig(**base)
+
+
+async def _run(engine, prompt, max_tokens, request_id=None):
+    toks = []
+    async for out in engine.generate(
+        prompt=prompt, request_id=request_id,
+        sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens,
+                                ignore_eos=True),
+    ):
+        toks = out.token_ids
+    return toks
+
+
+# ------------------------------------------------------------ loop spans
+def test_loop_span_adds_its_time_to_its_phase_only():
+    spans = LoopSpans()
+    with spans("pstpu.schedule"):
+        time.sleep(0.01)
+    with spans("pstpu.fetch", step=3, kind="decode", sync=1):
+        time.sleep(0.02)
+    assert set(spans.seconds) == set(LOOP_COUNTERS)
+    assert 0.009 < spans.seconds["schedule"] < 0.05
+    assert 0.019 < spans.seconds["fetch"] < 0.06
+    assert sum(spans.seconds.values()) == pytest.approx(
+        spans.seconds["schedule"] + spans.seconds["fetch"])
+    assert spans.counters()["loop_fetch_wait_seconds_total"] == \
+        spans.seconds["fetch"]
+    assert set(spans.counters()) == set(LOOP_COUNTERS.values())
+
+
+async def test_phase_totals_tile_the_loops_wall_time():
+    """Every second the loop lives is in exactly one phase: over a short
+    run with work and idle stretches the six totals sum to the loop's wall
+    time to within 2%."""
+    engine = ServingEngine(_cfg())
+    await engine.start()
+    began = time.perf_counter()
+    before = dict(engine.loop_spans.seconds)
+    try:
+        await asyncio.gather(
+            _run(engine, "a steady stream keeps decoding", 40),
+            _run(engine, "and a second one joins it", 24),
+        )
+        await asyncio.sleep(0.3)          # an idle stretch
+        await _run(engine, "then one more", 12)
+    finally:
+        # The loop ends inside stop(): read the totals after it, the wall
+        # time up to the same point.
+        await engine.stop()
+    wall = time.perf_counter() - began
+    spent = {k: v - before[k] for k, v in engine.loop_spans.seconds.items()}
+    assert all(v >= 0 for v in spent.values())
+    assert spent["issue"] > 0 and spent["fetch"] > 0 and spent["idle"] > 0
+    assert sum(spent.values()) == pytest.approx(wall, rel=0.02)
+
+
+# ---------------------------------------------------------- decode counts
+@pytest.mark.parametrize("decode_loop,steps,row_steps,wasted", [
+    ("scan", 8 + 8 + 8, 16 + 8 + 8, 4 + 0 + 5),
+    ("while", 8 + 8 + 3, 16 + 8 + 3, 4 + 0 + 0),
+])
+async def test_decode_counts_equal_hand_counts_on_a_scripted_run(
+        decode_loop, steps, row_steps, wasted):
+    """Two rows join one decode train of K=8 (two rows are the 8-step
+    tier). Row A may produce 5 tokens: its prefill gives 1, so its decode
+    budget is 4 and it stops mid-train by max_tokens. Row B (20 = 1 + 8 +
+    8 + 3) rides three trains. By hand:
+
+      train 1: rows A,B  8 steps -> 16 row-steps, 4 + 8 delivered, 4 wasted
+      train 2: row  B    8 steps ->  8 row-steps, 8 delivered
+      train 3: row  B    budget 3: the scan runs all 8 steps (5 wasted),
+               the while loop stops at the largest budget (3 steps, none)
+    """
+    engine = ServingEngine(_cfg(pipeline_depth=1, decode_loop=decode_loop))
+    await engine.start()
+    try:
+        a, b = await asyncio.gather(
+            _run(engine, "row a", 5, "a"), _run(engine, "row b", 20, "b"))
+    finally:
+        await engine.stop()
+    assert len(a) == 5 and len(b) == 20
+    trains = [(e["rows"], e["k"]) for e in
+              engine.recorder.get("b")["records"][0]["events"]
+              if e["event"] == "decode_issue"]
+    assert trains == [(2, 8), (1, 8), (1, 8)]
+    stats = engine.stats()
+    assert stats["decode_dispatches_total"] == 3
+    assert stats["decode_steps_total"] == steps
+    assert stats["decode_row_steps_total"] == row_steps
+    assert stats["decode_row_steps_wasted_total"] == wasted
+    # Row-steps less wasted row-steps: the tokens decode delivered, all
+    # tokens less each request's first.
+    assert row_steps - wasted == stats["generation_tokens_total"] - 2
+
+
+async def test_an_aborted_rows_undelivered_steps_count_as_wasted():
+    """A row aborted while its train is in flight discards the train's
+    tokens (epoch check): every row-step of that train is wasted, and the
+    identity row-steps - wasted = delivered still holds."""
+    engine = ServingEngine(_cfg(pipeline_depth=1))
+    await engine.start()
+    got = []
+
+    async def consume():
+        async for out in engine.generate(
+            prompt="to be aborted", request_id="victim",
+            sampling=SamplingParams(temperature=0.0, max_tokens=200,
+                                    ignore_eos=True),
+        ):
+            got.append(len(out.token_ids))
+            if len(out.token_ids) >= 9:       # first decode train applied
+                engine.abort("victim")
+
+    try:
+        await consume()
+        for _ in range(200):
+            if not engine.scheduler.has_work():
+                break
+            await asyncio.sleep(0.01)
+    finally:
+        await engine.stop()
+    stats = engine.stats()
+    delivered = stats["generation_tokens_total"] - 1
+    assert stats["decode_row_steps_total"] % 8 == 0
+    assert stats["decode_row_steps_wasted_total"] == \
+        stats["decode_row_steps_total"] - delivered
+    # The abort lands between trains or under one in flight; either way no
+    # delivered token is counted as wasted and no wasted one as delivered.
+    assert stats["decode_row_steps_wasted_total"] in (0, 8)
+    assert delivered == got[-1] - 1
+
+
+# ------------------------------------------------------------- /metrics
+async def test_new_series_are_on_both_renderers_and_pass_the_lint():
+    from prometheus_client import CollectorRegistry, generate_latest
+
+    from production_stack_tpu.engine.metrics import EngineMetricsCollector
+    from production_stack_tpu.server.metrics import render_engine_metrics
+    from tools.pstpu_lint.core import default_project_root
+    from tools.pstpu_lint.rules.metrics_drift import check_metrics
+
+    engine = ServingEngine(_cfg())
+    await engine.start()
+    try:
+        await _run(engine, "count me", 12)
+    finally:
+        await engine.stop()
+    text = render_engine_metrics(engine, "tiny-llama")
+    registry = CollectorRegistry()
+    registry.register(EngineMetricsCollector(engine))
+    collected = generate_latest(registry).decode()
+    for series in NEW_SERIES:
+        assert f"# TYPE {series} " in text, series
+        assert series.removesuffix("_total") in collected, series
+    sample = {ln.split(" ")[0].split("{")[0]: float(ln.rsplit(" ", 1)[1])
+              for ln in text.splitlines() if ln and not ln.startswith("#")
+              and "_bucket" not in ln}
+    # 12 = 1 + 8 + 3: two trains of one row, the scan runs all 8 steps.
+    assert sample["pstpu:decode_steps_total"] == 16
+    assert sample["pstpu:decode_row_steps_total"] == 16
+    assert sample["pstpu:decode_row_steps_wasted_total"] == 5
+    assert sample["pstpu:loop_fetch_wait_seconds_total"] > 0
+    # PL004: renderers, registry and docs tables agree (the whole tree).
+    assert check_metrics(default_project_root()) == []
+
+
+async def test_http_surface_histograms_observe_each_request_once():
+    server = APIServer(ServingEngine(_cfg(attn_impl="xla")))
+    client = TestClient(TestServer(server.build_app()))
+    await client.start_server()
+    try:
+        resp = await client.post("/v1/chat/completions", json={
+            "model": "tiny-llama", "stream": True, "max_tokens": 6,
+            "temperature": 0, "ignore_eos": True,
+            "messages": [{"role": "user", "content": "hello"}],
+        })
+        assert resp.status == 200
+        await resp.read()
+        resp = await client.post("/v1/completions", json={
+            "model": "tiny-llama", "prompt": "abc", "max_tokens": 3,
+            "temperature": 0, "ignore_eos": True,
+        })
+        assert resp.status == 200
+        surface = server.engine.http_surface
+        assert surface.ingress.count == 2
+        assert surface.first_chunk_emit.count == 2
+        assert 0 < surface.ingress.sum < 5
+        assert 0 <= surface.first_chunk_emit.sum < 60
+        version = await (await client.get("/version")).json()
+        assert set(version["engine"]["peak_bytes_in_use"]) <= \
+            set(version["engine"]["bytes_in_use"]) or \
+            version["engine"]["peak_bytes_in_use"] == {}
+        assert "peak_bytes_in_use" in version["engine"]
+    finally:
+        await client.close()
+
+
+# ------------------------------------------------------------ the capture
+def _capture_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[0]
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                events.append((ev.name, dict(ev.stats)))
+    return events, os.path.getsize(path)
+
+
+async def _captured_run(tmp_path, python_frames):
+    from production_stack_tpu.profiling import DeviceProfiler
+
+    profiler = DeviceProfiler()
+    if not profiler.available():
+        pytest.skip("jax.profiler unavailable in this image")
+    engine = ServingEngine(_cfg())
+    await engine.start()
+    trace_dir = str(tmp_path / ("frames" if python_frames else "spans"))
+    try:
+        await _run(engine, "warm the shapes", 10)
+        info = await profiler.arm(30.0, trace_dir=trace_dir,
+                                  python_frames=python_frames)
+        assert info["python_frames"] is python_frames
+        await _run(engine, "traced request", 12, request_id="traced")
+        await profiler.close()
+    finally:
+        await engine.stop()
+    assert profiler.active is None
+    assert profiler.last["stop_seconds"] >= 0
+    return engine, _capture_events(trace_dir)
+
+
+async def test_capture_holds_loop_spans_a_clock_anchor_and_no_frames(
+        tmp_path):
+    engine, (events, _) = await _captured_run(tmp_path, python_frames=False)
+    names = [name for name, _ in events]
+    for want in ("pstpu.schedule", "pstpu.issue", "pstpu.issue.enqueue",
+                 "pstpu.fetch", "pstpu.fetch.sync", "pstpu.apply"):
+        assert want in names, want
+    # No Python-frame event (the tracer names them "$file:line function").
+    assert not [n for n in names if n.startswith("$")]
+    clock = [stats for name, stats in events if name == "pstpu.clock"]
+    assert len(clock) == 1
+    assert abs(int(clock[0]["wall_ns"]) - time.time_ns()) < 600e9
+    assert int(clock[0]["mono_ns"]) > 0
+    issues = [s for n, s in events if n == "pstpu.issue"]
+    fetches = [s for n, s in events if n == "pstpu.fetch"]
+    assert {s["kind"] for s in issues} == {"prefill", "decode"}
+    assert all(int(s["rows"]) == 1 and int(s["k"]) >= 1 for s in issues)
+    assert all(s["kind"] in ("prefill", "decode") and
+               int(s["sync"]) in (0, 1) for s in fetches)
+    # `step` joins the capture to the request's flight record: every
+    # dispatch event of the traced request names a step that has an issue
+    # span, a fetch span and their executor-side parts in the capture.
+    record = engine.recorder.get("traced")["records"][0]["events"]
+    steps = {e["step"] for e in record if "step" in e}
+    assert steps
+    for part in ("pstpu.issue", "pstpu.issue.enqueue", "pstpu.fetch",
+                 "pstpu.fetch.sync", "pstpu.apply"):
+        assert steps <= {int(s["step"]) for n, s in events if n == part}, part
+
+
+async def test_python_frames_are_in_the_capture_only_when_asked(tmp_path):
+    _, (events, _) = await _captured_run(tmp_path, python_frames=True)
+    names = [name for name, _ in events]
+    assert [n for n in names if n.startswith("$")]
+    assert "pstpu.issue" in names
+
+
+async def test_stop_trace_does_not_block_the_event_loop(monkeypatch,
+                                                        tmp_path):
+    """While stop_trace writes its file the loop keeps turning: a ticker
+    coroutine advances during a stop that blocks its thread for 0.5 s,
+    and a second arm meanwhile is refused as busy."""
+    import jax.profiler as jp
+
+    from production_stack_tpu.profiling import DeviceProfiler, ProfilerBusy
+
+    profiler = DeviceProfiler()
+    if not profiler.available():
+        pytest.skip("jax.profiler unavailable in this image")
+    real_stop = jp.stop_trace
+
+    def slow_stop():
+        time.sleep(0.5)
+        real_stop()
+
+    monkeypatch.setattr(jp, "stop_trace", slow_stop)
+    ticks = []
+
+    async def ticker():
+        while True:
+            ticks.append(time.perf_counter())
+            await asyncio.sleep(0.01)
+
+    task = asyncio.ensure_future(ticker())
+    try:
+        await profiler.arm(0.1, trace_dir=str(tmp_path))
+        await asyncio.sleep(0.3)            # the stop is under way
+        assert profiler.active is not None and profiler.active["stopping"]
+        with pytest.raises(ProfilerBusy):
+            await profiler.arm(0.1, trace_dir=str(tmp_path))
+        for _ in range(300):
+            if profiler.active is None:
+                break
+            await asyncio.sleep(0.01)
+    finally:
+        task.cancel()
+    assert profiler.active is None
+    assert profiler.last["stop_seconds"] >= 0.5
+    gaps = [b - a for a, b in zip(ticks, ticks[1:])]
+    assert max(gaps) < 0.25, f"the event loop stalled for {max(gaps):.2f} s"
+
+
+async def test_close_waits_for_a_stop_under_way(monkeypatch, tmp_path):
+    """Engine shutdown while the capture's file is being written: close()
+    returns only when stop_trace has, so the process does not exit under
+    it (a 4 s capture of a full-size model takes 13-72 s to stop)."""
+    import jax.profiler as jp
+
+    from production_stack_tpu.profiling import DeviceProfiler
+
+    profiler = DeviceProfiler()
+    if not profiler.available():
+        pytest.skip("jax.profiler unavailable in this image")
+    real_stop, done = jp.stop_trace, []
+
+    def slow_stop():
+        time.sleep(0.5)
+        real_stop()
+        done.append(True)
+
+    monkeypatch.setattr(jp, "stop_trace", slow_stop)
+    await profiler.arm(0.05, trace_dir=str(tmp_path))
+    await asyncio.sleep(0.2)
+    assert profiler.active["stopping"] and not done
+    await profiler.close()
+    assert done and profiler.active is None
+    assert profiler.last["stop_seconds"] >= 0.5 and "error" not in profiler.last
+
+
+# --------------------------------------------------------------- the flags
+def _model_dir(tmp_path):
+    """A model directory holding only config.json (no checkpoint)."""
+    path = tmp_path / "config-only"
+    path.mkdir()
+    (path / "config.json").write_text(json.dumps({
+        "architectures": ["LlamaForCausalLM"], "model_type": "llama",
+        "hidden_size": 64, "intermediate_size": 128,
+        "num_hidden_layers": 2, "num_attention_heads": 4,
+        "num_key_value_heads": 2, "vocab_size": 512,
+        "max_position_embeddings": 256, "rms_norm_eps": 1e-5,
+        "rope_theta": 10000.0, "tie_word_embeddings": True,
+    }))
+    return str(path)
+
+
+ENGINE_ARGS = ["--max-model-len", "128", "--num-kv-blocks", "64",
+               "--max-num-seqs", "2", "--max-num-batched-tokens", "32",
+               "--no-warmup", "--dtype", "float32"]
+
+
+async def test_config_only_directory_boots_under_load_format_dummy(tmp_path):
+    model = _model_dir(tmp_path)
+    args = parse_args(["--model", model, "--load-format", "dummy",
+                       "--seed", "7", *ENGINE_ARGS])
+    engine = build_engine_from_args(args)
+    assert engine.config.load_format == "dummy" and engine.config.seed == 7
+    await engine.start()
+    try:
+        toks = await _run(engine, "hi", 4)
+    finally:
+        await engine.stop()
+    assert len(toks) == 4
+    # Without the flag the same directory is refused: no checkpoint.
+    with pytest.raises(Exception):
+        build_engine_from_args(parse_args(["--model", model, *ENGINE_ARGS]))
+
+
+def test_flags_left_out_leave_both_fields_to_a_setdefault_patch(
+        monkeypatch):
+    """The benchmark's engine shim wraps ``EngineConfig.__init__`` with
+    ``kwargs.setdefault``: with the flags absent nothing may reach
+    ``EngineConfig`` for the two fields, or the shim's defaults lose."""
+    seen = {}
+    init = EngineConfig.__init__
+
+    def patched(self, *args, **kwargs):
+        seen.update(given={k: kwargs[k] for k in ("load_format", "seed")
+                           if k in kwargs})
+        kwargs.setdefault("load_format", "dummy")
+        kwargs.setdefault("seed", 1234)
+        init(self, *args, **kwargs)
+
+    monkeypatch.setattr(EngineConfig, "__init__", patched)
+    monkeypatch.setattr(
+        "production_stack_tpu.server.api_server.ServingEngine",
+        lambda cfg: cfg)
+    args = parse_args(["--model", "tiny-llama"])
+    assert args.load_format is None and args.seed is None
+    cfg = build_engine_from_args(args)
+    assert seen["given"] == {}
+    assert cfg.load_format == "dummy" and cfg.seed == 1234
+    # Given, the flags win over the patch.
+    cfg = build_engine_from_args(parse_args(
+        ["--model", "tiny-llama", "--load-format", "auto", "--seed", "3"]))
+    assert seen["given"] == {"load_format": "auto", "seed": 3}
+    assert cfg.load_format == "auto" and cfg.seed == 3

@@ -1,8 +1,10 @@
 """Two-slot prefill/decode dispatch overlap (config.overlap_dispatch).
 
 The tentpole claim — the executor no longer serializes the two dispatch
-kinds — is asserted on the PSTPU_DISPATCH_LOG timeline: a prefill ISSUE
-line must land between a decode's ISSUE and its FETCH (and, with a chunked
+kinds — is asserted on the flight recorder's dispatch events (the
+``*_issue`` / ``*_fetch`` events every request's timeline carries, joined
+over the requests by ``step``): a prefill ISSUE
+must land between a decode's ISSUE and its FETCH (and, with a chunked
 prefill train against live decode streams, a decode issue between a
 prefill's issue and fetch — Sarathi-style stall-free batching in both
 directions). Scheduler-level invariants (dual-batch rounds, the
@@ -11,8 +13,6 @@ single-source) and the overlap telemetry are covered alongside.
 """
 
 import asyncio
-import os
-import re
 
 import pytest
 
@@ -22,21 +22,17 @@ from production_stack_tpu.engine.kv_cache import BlockPoolManager
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.scheduler import Scheduler, Sequence
 
-_EVENT = re.compile(
-    r"^(issue|fetch) kind=(prefill|decode) step=(\d+) rows=(\d+)"
-)
-
-
-def _parse_timeline(path):
-    events = []
-    with open(path) as f:
-        for line in f:
-            m = _EVENT.match(line)
-            if m:
-                events.append(
-                    (m.group(1), m.group(2), int(m.group(3)))
-                )
-    return events
+def _dispatch_timeline(recorder, request_ids):
+    """(issue|fetch, prefill|decode, step) of every dispatch the requests
+    rode, in time order; a dispatch that carried several of them counts
+    once."""
+    seen = {}
+    for rid in request_ids:
+        for ev in recorder.get(rid)["records"][0]["events"]:
+            kind, _, what = ev["event"].partition("_")
+            if kind in ("prefill", "decode") and what in ("issue", "fetch"):
+                seen.setdefault((what, kind, ev["step"]), ev["t"])
+    return [key for key, _ in sorted(seen.items(), key=lambda kv: kv[1])]
 
 
 def _overlap_windows(events, outer_kind, inner_kind):
@@ -56,20 +52,15 @@ def _overlap_windows(events, outer_kind, inner_kind):
 
 
 @pytest.mark.asyncio
-async def test_dispatch_timeline_shows_prefill_decode_overlap(tmp_path):
+async def test_dispatch_timeline_shows_prefill_decode_overlap():
     """A fresh prompt arriving mid-decode gets its prefill ISSUED while a
     fused decode scan is still in flight; decode keeps issuing through the
     newcomer's multi-chunk prefill train."""
-    log = tmp_path / "dispatch.log"
-    os.environ["PSTPU_DISPATCH_LOG"] = str(log)
-    try:
-        engine = ServingEngine(EngineConfig(
-            model="tiny-llama", max_model_len=512, num_kv_blocks=256,
-            num_decode_steps=8, dtype="float32", max_num_seqs=4,
-            max_num_batched_tokens=64,
-        ))
-    finally:
-        del os.environ["PSTPU_DISPATCH_LOG"]
+    engine = ServingEngine(EngineConfig(
+        model="tiny-llama", max_model_len=512, num_kv_blocks=256,
+        num_decode_steps=8, dtype="float32", max_num_seqs=4,
+        max_num_batched_tokens=64,
+    ))
     await engine.start()
     try:
         done = {}
@@ -81,6 +72,7 @@ async def test_dispatch_timeline_shows_prefill_decode_overlap(tmp_path):
                 sampling=SamplingParams(temperature=0.0,
                                         max_tokens=max_tokens,
                                         ignore_eos=True),
+                request_id=key,
             ):
                 toks = o.token_ids
             done[key] = toks
@@ -102,8 +94,8 @@ async def test_dispatch_timeline_shows_prefill_decode_overlap(tmp_path):
         await engine.stop()
     assert len(done["steady"]) == 96 and len(done["late"]) == 8
 
-    events = _parse_timeline(str(log))
-    assert events, "dispatch log is empty"
+    events = _dispatch_timeline(engine.recorder, ("steady", "late"))
+    assert events, "the flight recorder holds no dispatch events"
     # The two kinds genuinely interleave in flight:
     assert _overlap_windows(events, "decode", "prefill") > 0, (
         "no prefill was issued between a decode issue and its fetch:\n"

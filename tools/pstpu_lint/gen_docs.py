@@ -45,6 +45,7 @@ TABLES = {
     "elastic": "docs/ELASTIC.md",
     "lifecycle": "docs/OBSERVABILITY.md",
     "fleet-perf": "docs/OBSERVABILITY.md",
+    "loop": "docs/OBSERVABILITY.md",
 }
 
 FLAG_TABLES = {

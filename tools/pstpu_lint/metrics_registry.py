@@ -398,6 +398,59 @@ REGISTRY: Tuple[Series, ...] = (
            ("catalogue", "fleet-perf"),
            "Issue-to-fetch duration of each dispatch by train kind "
            "(prefill | decode | decode_spec)"),
+    # ------------------------------------------------ engine: loop spans
+    # The six phases tile the engine loop's wall time (their deltas over a
+    # window sum to the window); the decode counts are taken at apply.
+    Series("pstpu:loop_schedule_seconds_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds in `scheduler.schedule()` (span "
+           "`pstpu.schedule`)"),
+    Series("pstpu:loop_issue_seconds_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds issuing dispatches: `execute_async` in "
+           "the executor, `advance_at_issue`, issue records (span "
+           "`pstpu.issue`)"),
+    Series("pstpu:loop_fetch_wait_seconds_total", "counter",
+           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds awaiting a dispatch's fetch: the host "
+           "blocked on the device (span `pstpu.fetch`)"),
+    Series("pstpu:loop_apply_seconds_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds applying fetched results: fetch records, "
+           "`apply_results`, output processing, handoff publishes (span "
+           "`pstpu.apply`)"),
+    Series("pstpu:loop_idle_seconds_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds with nothing schedulable: waiting for "
+           "work or retrying (span `pstpu.idle`)"),
+    Series("pstpu:loop_other_seconds_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Engine-loop seconds in aborts, restores, prewarms and the "
+           "yield after an apply (span `pstpu.housekeeping`)"),
+    Series("pstpu:decode_steps_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Decode-loop steps the device ran, over applied decode "
+           "dispatches (the while loop stops at the largest per-row "
+           "budget; draft/verify cycles allowed under speculation)"),
+    Series("pstpu:decode_row_steps_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Real rows times the steps their decode dispatch ran (padding "
+           "rows of the shape bucket are not rows)"),
+    Series("pstpu:decode_row_steps_wasted_total", "counter",
+           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           "Decode row-steps whose token was not delivered: the row hit "
+           "EOS / max_tokens / a stop string earlier in the train, was "
+           "aborted or preempted, or its fetch failed; row-steps less "
+           "wasted is the tokens decode delivered"),
+    Series("pstpu:http_ingress_seconds", "histogram", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "HTTP handler entry to the request's enqueue in the scheduler "
+           "(body parse, chat template, tokenisation)"),
+    Series("pstpu:first_chunk_emit_seconds", "histogram", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "First token appended in the engine loop to the first chunk "
+           "handed to the transport (the whole body when not streaming, "
+           "which then contains the decode)"),
     # ------------------------------------------------ router: fleet pane
     # One operator surface over what the scraper already holds per
     # backend (GET /fleet serves the JSON view of the same aggregate).
