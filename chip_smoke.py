@@ -20,7 +20,9 @@ One JSON object per phase goes to stdout; the LAST line is
 ``{"ok": ..., "device": {"platform", "kind", "count"}}`` with the device as
 the engine that served the requests reported it (``GET /version``). Exit
 code 0 only with ``"ok": true`` — which needs every phase to pass on a TPU,
-compiled (not interpreted) kernels, a compile cache, and zero warmup faults.
+compiled (not interpreted) kernels, a compile cache, zero warmup faults, and
+dispatch programs that update the KV pools in place (``pool_programs`` in
+each serving line: no whole-pool ``copy``; their temporaries are printed).
 
 This process never imports JAX: a chip belongs to one process at a time and
 the engine children need it (the kernel phase runs in a child of its own).
@@ -292,6 +294,10 @@ def run_phase(line: dict, body, model, engine_args, **kw) -> dict:
         ]
         line.update({"boot": boots[0]} if len(boots) == 1
                     else {"boots": boots})
+        # What the engine's dispatch programs do to the KV pools, compiled
+        # by the engine itself on the device it serves on.
+        line["pool_programs"] = get_json(
+            f"{stack.engine_urls[0]}/debug/programs")["programs"]
         body(stack)
     except Exception as e:  # noqa: BLE001 — a phase reports, never raises
         line.update(ok=False, error=f"{type(e).__name__}: {e}",
@@ -609,8 +615,9 @@ def verdict(lines: list, chips: int, full_depth: int,
     """The last line. ``ok`` needs every phase to have passed AND every
     engine that served requests to say, in its own report, that it ran on
     a TPU at full depth with compiled kernels, a compile cache and a clean
-    warmup; the second boot of the run must have found the cache. The
-    device is the one the last serving engine reported."""
+    warmup, and that its dispatch programs copy no KV pool whole; the
+    second boot of the run must have found the cache. The device is the
+    one the last serving engine reported."""
     faults = []
     if rehearsal:
         faults.append("rehearsal: tiny model, never a pass")
@@ -625,6 +632,16 @@ def verdict(lines: list, chips: int, full_depth: int,
                 faults.append("kernel: not on a tpu")
         boots += line.get("boots") or (
             [line["boot"]] if "boot" in line else [])
+        if ("boot" in line or "boots" in line) and \
+                not line.get("pool_programs"):
+            faults.append(f"{line.get('phase')}: no pool-program audit")
+        for prog in line.get("pool_programs", ()):
+            name = f"{line.get('phase')} {prog['program']}{prog['family']}"
+            # temp_bytes is printed, not judged: at the smoke's small pool
+            # a history window alone outweighs it.
+            if prog["pool_copies"]:
+                faults.append(
+                    f"{name}: {prog['pool_copies']} whole-pool copies")
     if not boots:
         faults.append("no engine report: nothing was served")
     for boot in boots:

@@ -3,11 +3,15 @@
 XLA discipline (the performance-critical part of the design — every item here
 was profiled on a v5e in round 1/2):
   * The paged KV pool is gathered into a contiguous per-sequence WINDOW once
-    per dispatch (ops/attention.py:gather_window) and new KV is scattered back
+    per dispatch (ops/attention.py:gather_window) and new KV is written back
     once at the end. Per-layer gathers/scatters against the pool cost ~7 ms
     per decode step (XLA gathers run at ~15% of HBM bandwidth; pool xs/ys in
     the layer scan copy the pool every layer); the hoisted form amortizes one
     gather over num_decode_steps * num_layers uses.
+  * Every pool write is IN PLACE (ops/kv_write.py: block-wide
+    dynamic_update_slices on the donated buffers). No dispatch program may
+    read or write a whole pool: a middle-axis scatter did, twice per pool
+    per dispatch (PERF.md §6, PR 25); audit_pool_programs() counts.
   * A fused decode dispatch runs K steps in one lax.scan: tokens produced
     mid-dispatch live in a small ring buffer [L, Hkv, B, K, Dh] that the
     attention reads alongside the window, so only ONE [K, B] device->host
@@ -36,6 +40,11 @@ from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
 from production_stack_tpu.models import get_model_fns
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import gather_window
+from production_stack_tpu.ops.kv_write import (
+    pool_copies,
+    write_slabs,
+    write_token_runs,
+)
 from production_stack_tpu.parallel import kv_pool_sharding, param_shardings
 from production_stack_tpu.parallel.mesh import Mesh
 from production_stack_tpu.utils import (
@@ -753,9 +762,9 @@ class ModelRunner:
             drp = drp.reshape(-1).at[widx].set(
                 positions.reshape(-1), mode="drop"
             ).reshape(1, r_len)
-            return (spec_k.at[:, :, sl].set(drk),
-                    spec_v.at[:, :, sl].set(drv),
-                    spec_pos.at[sl].set(drp))
+            return self._write_spec_rows(
+                spec_k, spec_v, spec_pos, sl, drk, drv, drp
+            )
 
         return jax.jit(ingest, static_argnames=("t",),
                        donate_argnums=(1, 2, 3))
@@ -814,6 +823,27 @@ class ModelRunner:
         # same buffer donated twice in one call.
         return (jnp.zeros((1,), self.dtype), jnp.zeros((1,), self.dtype),
                 jnp.zeros((1,), self.dtype), jnp.zeros((1,), jnp.int32))
+
+    @staticmethod
+    def _write_spec_rows(spec_k, spec_v, spec_pos, slot_idx, drk, drv, drp):
+        """Write the batch's draft-ring rows (drk/drv [Ld, Hd, b, R, Dd],
+        drp [b, R]) back to slots ``slot_idx`` [b] of the draft pools, in
+        place (ops/kv_write.py). A slot outside the pool drops its row:
+        the host packs one for padding rows, so their stale copies never
+        clobber a live slot."""
+        n = spec_pos.shape[0]
+        b = slot_idx.shape[0]
+        spec_k, spec_v, spec_pos = write_slabs(
+            (spec_k, spec_v, spec_pos[None, None]),
+            (drk[:, :, :, None], drv[:, :, :, None],
+             drp[None, None, :, None]),
+            dst_start=jnp.clip(slot_idx, 0, n - 1),
+            src_row=jnp.arange(b, dtype=jnp.int32),
+            src_start=jnp.zeros((b,), jnp.int32),
+            width=1,
+            keep=((slot_idx >= 0) & (slot_idx < n))[:, None],
+        )
+        return spec_k, spec_v, spec_pos[0, 0]
 
     def _rebind_spec_pools(self, k, v, pos) -> None:
         if self.spec_n:
@@ -1032,7 +1062,7 @@ class ModelRunner:
     def _prefill_mb(self, live_blocks: int, has_window: bool) -> int:
         """Static block-table width for a prefill dispatch: pinned at the
         max bucket when no window is gathered (block tables only feed the
-        slot-mapping scatter — padding is free), quantized when a chunk
+        pool write's slot mapping — padding is free), quantized when a chunk
         with history gathers its [rows, mb*block_size] window."""
         cfg = self.config
         if not has_window:
@@ -1160,16 +1190,8 @@ class ModelRunner:
             tokens0,
         )
 
-        # Per-step write slots [K, b] (0 = reserved null block for rows whose
-        # budget ran out) and per-step seeds [K, b].
+        # Per-step seeds [K, b].
         k_iota = jnp.arange(num_steps, dtype=jnp.int32)
-        p = pos0[None, :] + k_iota[:, None]                     # [K, b]
-        blk_idx = jnp.clip(p // bs, 0, mb - 1)
-        blk = jnp.take_along_axis(
-            block_tables, blk_idx.T, axis=1
-        ).T                                                      # [K, b]
-        valid = k_iota[:, None] < budget[None, :]
-        slot_steps = jnp.where(valid, blk * bs + p % bs, 0)
         seed_steps = self._derive_seeds(
             seed_base[None, :], gen0[None, :], k_iota[:, None]
         )
@@ -1206,8 +1228,8 @@ class ModelRunner:
         ring_pos0 = jnp.full((b, num_steps), _POS_SENTINEL, jnp.int32)
         if quant:
             # Quantized-KV sidecar rings: the int8 payload + scales each
-            # step will scatter to the pool at the end of the dispatch.
-            # Quantizing ONCE per token (here, not at the final scatter)
+            # step will write to the pool at the end of the dispatch.
+            # Quantizing ONCE per token (here, not at the final write)
             # keeps pool contents and the dequantized attention ring /
             # persistent window derived from the same (q, scale) pair.
             from production_stack_tpu.ops.quantization import SCALE_DTYPE
@@ -1349,38 +1371,23 @@ class ModelRunner:
                 lp_chosen, lp_top, lp_ids = None, None, None
         last_token = jnp.zeros((b_max,), jnp.int32).at[:b].set(final_toks)
 
-        # ONE scatter writes the whole dispatch's KV back to the paged pool
-        # (quantized mode: the int8 payload + per-slot scales the scan
-        # recorded; the pool never holds compute-dtype KV).
+        # The dispatch's KV goes back to the paged pool in place, one
+        # block-wide slab at a time (ops/kv_write.py; quantized mode: the
+        # int8 payload + per-slot scales the scan recorded; the pool never
+        # holds compute-dtype KV). Row i's steps j < budget[i] are the
+        # consecutive positions pos0[i] + j.
+        n_valid = jnp.clip(budget, 0, num_steps)
         with jax.named_scope("kv_write"):
-            flat_slots = slot_steps.reshape(-1)                       # [K*b]
-            k_flat = ring_k.transpose(0, 1, 3, 2, 4).reshape(
-                nl, hkv, num_steps * b, dh
-            )
-            v_flat = ring_v.transpose(0, 1, 3, 2, 4).reshape(
-                nl, hkv, num_steps * b, dh
-            )
             if quant:
-                ring_qk, ring_qv, ring_sk, ring_sv = qstate
-                kv_k = kv_k.at[:, :, flat_slots].set(
-                    ring_qk.transpose(0, 1, 3, 2, 4).reshape(
-                        nl, hkv, num_steps * b, dh
-                    )
-                )
-                kv_v = kv_v.at[:, :, flat_slots].set(
-                    ring_qv.transpose(0, 1, 3, 2, 4).reshape(
-                        nl, hkv, num_steps * b, dh
-                    )
-                )
-                kv_ks = kv_ks.at[:, :, flat_slots].set(
-                    ring_sk.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
-                )
-                kv_vs = kv_vs.at[:, :, flat_slots].set(
-                    ring_sv.transpose(0, 1, 3, 2).reshape(nl, hkv, num_steps * b)
+                kv_k, kv_v, kv_ks, kv_vs = write_token_runs(
+                    (kv_k, kv_v, kv_ks, kv_vs), qstate, block_tables,
+                    pos0, n_valid, bs,
                 )
             else:
-                kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-                kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
+                kv_k, kv_v = write_token_runs(
+                    (kv_k, kv_v), (ring_k, ring_v), block_tables,
+                    pos0, n_valid, bs,
+                )
         if self.attn_impl != "paged":
             # Append the dispatch's KV into the persistent window too (slot
             # s = absolute position s), so the next dispatch over the same
@@ -1388,21 +1395,32 @@ class ModelRunner:
             # quantized path appends the DEQUANTIZED values — identical to
             # what a fresh pool gather would reconstruct.
             with jax.named_scope("kv_write"):
-                s_tot = mb * bs
-                iota_b = jnp.arange(b, dtype=jnp.int32)[None, :]      # [1, b]
-                widx = jnp.where(valid, iota_b * s_tot + p, b * s_tot)
-                win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
-                    :, :, widx.reshape(-1)
-                ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
-                win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
-                    :, :, widx.reshape(-1)
-                ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
+                win_k, win_v = self._append_window(
+                    win_k, win_v, ring_k, ring_v, pos0, n_valid
+                )
             return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k, win_v,
                     lp_chosen, lp_top, lp_ids, last_token,
                     *self._spec_dummy_outs(spec_k, spec_v, spec_pos))
         return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k_in, win_v_in,
                 lp_chosen, lp_top, lp_ids, last_token,
                 *self._spec_dummy_outs(spec_k, spec_v, spec_pos))
+
+    def _append_window(self, win_k, win_v, new_k, new_v, start, length):
+        """Append row i's tokens j < length[i] ([L, Hkv, b, T, Dh]) to the
+        persistent window [L, Hkv, b, S, Dh] at positions start[i] + j;
+        positions beyond S drop. The window is a paged pool whose row i
+        owns blocks i*mb .. i*mb + mb - 1, written in place like one."""
+        nl, hkv, b, s_tot, dh = win_k.shape
+        mb = s_tot // self.config.block_size
+        own = (jnp.arange(b, dtype=jnp.int32)[:, None] * mb
+               + jnp.arange(mb, dtype=jnp.int32)[None, :])
+        win_k, win_v = write_token_runs(
+            (win_k.reshape(nl, hkv, b * s_tot, dh),
+             win_v.reshape(nl, hkv, b * s_tot, dh)),
+            (new_k, new_v), own, start, length, self.config.block_size,
+        )
+        return (win_k.reshape(nl, hkv, b, s_tot, dh),
+                win_v.reshape(nl, hkv, b, s_tot, dh))
 
     @staticmethod
     def _spec_dummy_outs(spec_k, spec_v, spec_pos):
@@ -1512,10 +1530,10 @@ class ModelRunner:
         win_len = pos0
 
         # Draft-ring rows for this batch. GATHER clips (padding rows read
-        # some live slot harmlessly); the scatter-back uses the RAW index
-        # with mode="drop" — the host packs an out-of-range slot for
-        # padding rows, so their stale copies never clobber a live slot
-        # (duplicate-index .set order is undefined).
+        # some live slot harmlessly); the write-back (_write_spec_rows)
+        # drops a row whose RAW index is out of range — the host packs
+        # such a slot for padding rows, so their stale copies never
+        # clobber a live slot.
         slot_c = jnp.clip(slot_idx, 0, spec_pos.shape[0] - 1)
         drk0 = spec_k[:, :, slot_c]            # [Ld, Hd, b, R, Dd]
         drv0 = spec_v[:, :, slot_c]
@@ -1860,37 +1878,23 @@ class ModelRunner:
          drp, _, drafts, accepted, tree_cnt, cycles, toks_buf, emit_buf,
          lp_bufs) = final
 
-        # ONE pool scatter for the whole dispatch, slots derived from the
-        # committed ring positions (invalid entries -> reserved null
-        # block 0, never read).
+        # The dispatch's committed ring entries go to the pool and to the
+        # persistent window in place (ops/kv_write.py): ring entry r of
+        # row i holds position pos0[i] + r, and the committed entries are
+        # the first n_commit[i] (the rest still hold the sentinel).
         with jax.named_scope("kv_write"):
-            valid_e = ring_pos < _POS_SENTINEL
-            blk = jnp.take_along_axis(
-                block_tables, jnp.clip(ring_pos // bs, 0, mb - 1), axis=1
+            n_commit = jnp.sum(ring_pos < _POS_SENTINEL, axis=1,
+                               dtype=jnp.int32)
+            kv_k, kv_v = write_token_runs(
+                (kv_k, kv_v), (ring_k, ring_v), block_tables, pos0,
+                n_commit, bs,
             )
-            flat_slots = jnp.where(
-                valid_e, blk * bs + ring_pos % bs, 0
-            ).reshape(-1)
-            k_flat = ring_k.reshape(nl, hkv, b * s_ring, dh)
-            v_flat = ring_v.reshape(nl, hkv, b * s_ring, dh)
-            kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-            kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
-            # Append into the persistent window too (slot s = position s), so
-            # the next dispatch over the same rows reuses it.
-            s_tot = mb * bs
-            widx = jnp.where(
-                valid_e, iota_b[:, None] * s_tot + ring_pos, b * s_tot
-            ).reshape(-1)
-            win_k = win_k.reshape(nl, hkv, b * s_tot, dh).at[
-                :, :, widx
-            ].set(k_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
-            win_v = win_v.reshape(nl, hkv, b * s_tot, dh).at[
-                :, :, widx
-            ].set(v_flat, mode="drop").reshape(nl, hkv, b, s_tot, dh)
-
-        spec_k = spec_k.at[:, :, slot_idx].set(drk, mode="drop")
-        spec_v = spec_v.at[:, :, slot_idx].set(drv, mode="drop")
-        spec_pos = spec_pos.at[slot_idx].set(drp, mode="drop")
+            win_k, win_v = self._append_window(
+                win_k, win_v, ring_k, ring_v, pos0, n_commit
+            )
+            spec_k, spec_v, spec_pos = self._write_spec_rows(
+                spec_k, spec_v, spec_pos, slot_idx, drk, drv, drp
+            )
 
         last_token = jnp.zeros((b_max,), jnp.int32).at[:b].set(final_toks)
         lp_c_buf, lp_t_buf, lp_i_buf = lp_bufs if logprobs_k else (
@@ -2249,9 +2253,6 @@ class ModelRunner:
             chunk_start[:, None] + t_iota[None, :], cfg.max_model_len - 1
         )                                                        # [b, t]
         in_chunk = t_iota[None, :] < chunk_lens[:, None]
-        blk_idx = jnp.clip(positions // bs, 0, mb - 1)
-        blk = jnp.take_along_axis(block_tables, blk_idx, axis=1)
-        slot_mapping = jnp.where(in_chunk, blk * bs + positions % bs, 0)
 
         quant = self.kv_quantized
         if has_window:
@@ -2304,25 +2305,26 @@ class ModelRunner:
         else:
             lp = (None, None, None)
 
-        nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
+        # The chunk's KV goes to the pool in place, one block-wide slab
+        # at a time (ops/kv_write.py): row i's tokens j < chunk_lens[i]
+        # are the consecutive positions chunk_start[i] + j.
         with jax.named_scope("kv_write"):
-            flat_slots = slot_mapping.reshape(-1)                     # [b*t]
-            k_flat = k_new.reshape(nl, hkv, b * t, dh)
-            v_flat = v_new.reshape(nl, hkv, b * t, dh)
             if quant:
-                # Quantize the chunk's KV on device before the single scatter
-                # — compute-dtype KV never lands in the pool.
+                # Quantize the chunk's KV on device before the write —
+                # compute-dtype KV never lands in the pool.
                 from production_stack_tpu.ops.quantization import quantize_kv
 
-                kq, ks = quantize_kv(k_flat)
-                vq, vs = quantize_kv(v_flat)
-                kv_k = kv_k.at[:, :, flat_slots].set(kq)
-                kv_v = kv_v.at[:, :, flat_slots].set(vq)
-                kv_ks = kv_ks.at[:, :, flat_slots].set(ks)
-                kv_vs = kv_vs.at[:, :, flat_slots].set(vs)
+                kq, ks = quantize_kv(k_new)
+                vq, vs = quantize_kv(v_new)
+                kv_k, kv_v, kv_ks, kv_vs = write_token_runs(
+                    (kv_k, kv_v, kv_ks, kv_vs), (kq, vq, ks, vs),
+                    block_tables, chunk_start, chunk_lens, bs,
+                )
             else:
-                kv_k = kv_k.at[:, :, flat_slots].set(k_flat)
-                kv_v = kv_v.at[:, :, flat_slots].set(v_flat)
+                kv_k, kv_v = write_token_runs(
+                    (kv_k, kv_v), (k_new, v_new), block_tables,
+                    chunk_start, chunk_lens, bs,
+                )
         # Speculative draft warm-up (docs/PERF.md round 8): run the DRAFT
         # model over the same chunk so its per-sequence KV ring holds the
         # prompt context before decode starts — a cold draft ring proposes
@@ -2335,7 +2337,7 @@ class ModelRunner:
             dnl, dhkv, ddh = (dmc.num_layers, dmc.num_kv_heads,
                               dmc.head_dim_)
             slot_idx = scalars[12]
-            # Clipped gather / raw-index drop-mode scatter: see
+            # Clipped gather / raw-index dropping write-back: see
             # _decode_spec (padding rows must never write slot 0).
             slot_c = jnp.clip(slot_idx, 0, spec_pos.shape[0] - 1)
             drk = spec_k[:, :, slot_c]
@@ -2373,9 +2375,10 @@ class ModelRunner:
             drp = drp.reshape(-1).at[widx].set(
                 positions.reshape(-1), mode="drop"
             ).reshape(b, r_len)
-            spec_k = spec_k.at[:, :, slot_idx].set(drk, mode="drop")
-            spec_v = spec_v.at[:, :, slot_idx].set(drv, mode="drop")
-            spec_pos = spec_pos.at[slot_idx].set(drp, mode="drop")
+            with jax.named_scope("kv_write"):
+                spec_k, spec_v, spec_pos = self._write_spec_rows(
+                    spec_k, spec_v, spec_pos, slot_idx, drk, drv, drp
+                )
         # Device-resident last-token vector (final rows' sampled tokens):
         # the first decode dispatch after this prefill may chain from it
         # without a host roundtrip (see _decode_impl).
@@ -2625,25 +2628,16 @@ class ModelRunner:
         bs = self.config.block_size
 
         def scatter(kv_k, kv_v, blocks, k_new, v_new):
-            nl, hkv, ns, dh = kv_k.shape
-            kr = kv_k.reshape(nl, hkv, ns // bs, bs, dh)
-            vr = kv_v.reshape(nl, hkv, ns // bs, bs, dh)
-            kr = kr.at[:, :, blocks].set(k_new.astype(kv_k.dtype))
-            vr = vr.at[:, :, blocks].set(v_new.astype(kv_v.dtype))
-            return kr.reshape(nl, hkv, ns, dh), vr.reshape(nl, hkv, ns, dh)
-        return jax.jit(scatter, donate_argnums=(0, 1))
-
-    @functools.cached_property
-    def _scatter_scales_jit(self):
-        bs = self.config.block_size
-
-        def scatter(kv_ks, kv_vs, blocks, ks_new, vs_new):
-            nl, hkv, ns = kv_ks.shape
-            kr = kv_ks.reshape(nl, hkv, ns // bs, bs)
-            vr = kv_vs.reshape(nl, hkv, ns // bs, bs)
-            kr = kr.at[:, :, blocks].set(ks_new.astype(kv_ks.dtype))
-            vr = vr.at[:, :, blocks].set(vs_new.astype(kv_vs.dtype))
-            return kr.reshape(nl, hkv, ns), vr.reshape(nl, hkv, ns)
+            # Whole blocks ([L, Hkv, n, bs, ...] payload or scales), in
+            # place (ops/kv_write.py); padding lands in the null block.
+            n = blocks.shape[0]
+            return write_slabs(
+                (kv_k, kv_v), (k_new, v_new),
+                dst_start=blocks * bs,
+                src_row=jnp.arange(n, dtype=jnp.int32),
+                src_start=jnp.zeros((n,), jnp.int32),
+                width=bs,
+            )
         return jax.jit(scatter, donate_argnums=(0, 1))
 
     def read_blocks(self, block_ids: List[int]):
@@ -2727,7 +2721,7 @@ class ModelRunner:
                 v_scale = np.concatenate([v_scale, spad])
             ks_blk = k_scale.transpose(1, 2, 0, 3)   # [L, Hkv, nb, bs]
             vs_blk = v_scale.transpose(1, 2, 0, 3)
-            self.kv_k_scale, self.kv_v_scale = self._scatter_scales_jit(
+            self.kv_k_scale, self.kv_v_scale = self._scatter_blocks_jit(
                 self.kv_k_scale, self.kv_v_scale, jnp.asarray(blocks),
                 jnp.asarray(ks_blk), jnp.asarray(vs_blk),
             )
@@ -2813,6 +2807,109 @@ class ModelRunner:
                 t *= 2
         return sorted(fams)
 
+    def _abstract_params(self):
+        """The weights as ShapeDtypeStructs with their shardings: what a
+        dispatch program is lowered against when nothing may run."""
+        mc = self.model_config
+        abstract = jax.eval_shape(
+            lambda: self._init_fn(mc, jax.random.PRNGKey(0), self.dtype)
+        )
+        return jax.tree.map(
+            lambda leaf, sh: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sh
+            ),
+            abstract, param_shardings(mc, self.mesh, abstract),
+        )
+
+    def _lower_decode(self, aparams, db, mb, dk, cached, *,
+                      has_penalties=False, logprobs_k=0):
+        """One decode family lowered against abstract weights, argument
+        for argument what _issue_decode passes (the pools only lend their
+        shape and sharding: lowering reads no buffer, so a dispatch in
+        flight may have donated them)."""
+        mc, bs = self.model_config, self.config.block_size
+        nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
+        sds = jax.ShapeDtypeStruct
+        if cached:
+            # Cached-window variants receive windows that are COMMITTED
+            # outputs of the previous dispatch; an unsharded abstract
+            # window lowers to a different module (the committed/
+            # uncommitted cache-key split) and would compile artifacts
+            # the execute pass never loads — measured: 27/63 mismatches
+            # on CPU without this. At tp>1 the real window sharding may
+            # differ from replicated; the prepass is opportunistic there
+            # (a mismatch costs extra compiles, never correctness).
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            wk = wv = sds((nl, hkv, db, mb * bs, dh), self.dtype,
+                          sharding=NamedSharding(self.mesh, PartitionSpec()))
+        else:
+            wk = wv = sds((1, 1, 1, 1, 1), self.dtype)
+        counts = sds(
+            (db, mc.vocab_size) if has_penalties else (1, 1), jnp.int32
+        )
+        return self._decode.lower(
+            aparams, sds((NUM_SCALARS * db + db * mb,), jnp.int32),
+            self.kv_k, self.kv_v, *self._scale_pool_args(), wk, wv,
+            counts, self._zero_last, *self._spec_pool_args(),
+            b=db, mb=mb, num_steps=dk, use_cached_window=cached,
+            has_penalties=has_penalties, logprobs_k=logprobs_k,
+        )
+
+    def _lower_prefill(self, aparams, pb, t, mb, has_window, *,
+                       has_penalties=False, logprobs_k=0):
+        """One prefill family lowered like _lower_decode."""
+        mc = self.model_config
+        sds = jax.ShapeDtypeStruct
+        counts = sds(
+            (pb, mc.vocab_size) if has_penalties else (1, 1), jnp.int32
+        )
+        return self._prefill.lower(
+            aparams, sds((NUM_SCALARS * pb + pb * mb + pb * t,), jnp.int32),
+            self.kv_k, self.kv_v, *self._scale_pool_args(), counts,
+            *self._spec_pool_args(),
+            b=pb, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
+            has_penalties=has_penalties, logprobs_k=logprobs_k,
+        )
+
+    def audit_pool_programs(self) -> List[Dict]:
+        """Compile one program of each kind this engine dispatches (the
+        widest decode family, cached-window too where that exists, and
+        the widest prefill family with and without a history window) and
+        report what each does to the KV pools: ``pool_copies`` — ``copy``
+        operations whose result has a pool's shape (0: the donated pools
+        are updated in place, ops/kv_write.py) — and the program's
+        temporaries beside one payload pool's bytes. Nothing runs; with a
+        compile cache the programs are the ones warmup left there."""
+        pools = [self.kv_k] + [
+            x for x in (*self._scale_pool_args(),
+                        *self._spec_pool_args()[1:3]) if x.size > 1
+        ]
+        aparams = self._abstract_params()
+        programs = []
+        decode = self.reachable_decode_families()
+        for cached in sorted({f[3] for f in decode}):
+            fam = [f for f in decode if f[3] == cached][-1]
+            programs.append(("decode", fam,
+                             self._lower_decode(aparams, *fam)))
+        prefill = self.reachable_prefill_families()
+        for has_window in sorted({f[3] for f in prefill}):
+            fam = [f for f in prefill if f[3] == has_window][-1]
+            programs.append(("prefill", fam,
+                             self._lower_prefill(aparams, *fam)))
+        out = []
+        for kind, fam, lowered in programs:
+            compiled = lowered.compile()
+            mem = compiled.memory_analysis()
+            out.append({
+                "program": kind, "family": list(fam),
+                "pool_copies": len(pool_copies(compiled.as_text(), pools)),
+                "temp_bytes": int(mem.temp_size_in_bytes),
+                "alias_bytes": int(mem.alias_size_in_bytes),
+                "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
+            })
+        return out
+
     def _warmup_compile_prepass(self) -> int:
         """Compile-only AOT pass over every reachable shape family using
         ABSTRACT weights (jax.ShapeDtypeStruct), so XLA compilation — the
@@ -2835,37 +2932,9 @@ class ModelRunner:
         not."""
         from production_stack_tpu.utils import prefill_t_floor as _t_floor
 
-        cfg, mc = self.config, self.model_config
+        cfg = self.config
         count_dir = self.compilation_cache_path
-        abstract = jax.eval_shape(
-            lambda: self._init_fn(mc, jax.random.PRNGKey(0), self.dtype)
-        )
-        shardings = param_shardings(mc, self.mesh, abstract)
-        aparams = jax.tree.map(
-            lambda leaf, sh: jax.ShapeDtypeStruct(
-                leaf.shape, leaf.dtype, sharding=sh
-            ),
-            abstract, shardings,
-        )
-
-        def sds(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype)
-
-        # Cached-window variants receive windows that are COMMITTED
-        # outputs of the previous dispatch in the execute pass; an
-        # unsharded abstract window lowers to a different module (the
-        # committed/uncommitted cache-key split again) and would make the
-        # prepass compile 0-hit artifacts the execute pass never loads —
-        # measured: 27/63 mismatches on CPU without this. At tp>1 the real
-        # window sharding may differ from replicated; the prepass is
-        # opportunistic there (a mismatch costs extra compiles, never
-        # correctness).
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        win_sharding = NamedSharding(self.mesh, PartitionSpec())
-
-        def win_sds(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=win_sharding)
+        aparams = self._abstract_params()
 
         n = 0
         consecutive_hits = 0
@@ -2884,12 +2953,12 @@ class ModelRunner:
         # prepass's own fresh artifacts for warm-boot hits.
         self._prepass_progress = 0
 
-        def compile_counted(jitted, *args, **kwargs):
+        def compile_counted(lower, *family, **variant):
             nonlocal n, consecutive_hits
             if self.weights_ready or consecutive_hits >= warm_bail:
                 raise _PrepassDone()
             before = _cache_entries(count_dir)
-            jitted.lower(*args, **kwargs).compile()
+            lower(aparams, *family, **variant).compile()
             after = _cache_entries(count_dir)
             if before is not None and after is not None:
                 if after - before:
@@ -2901,30 +2970,13 @@ class ModelRunner:
             n += 1
             self._prepass_progress = n
 
-        nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
-        bs = cfg.block_size
         variants = ((False, 0), (False, LOGPROB_BUCKETS[0]), (True, 0))
-        kv_ks, kv_vs = self._scale_pool_args()
-        dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
         try:
             for db, mb, dk, cached in self.reachable_decode_families():
                 dvariants = variants if db == 1 else variants[:2]
                 for pen, lpk in dvariants:
-                    if cached:
-                        wk = wv = win_sds(
-                            (nl, hkv, db, mb * bs, dh), self.dtype
-                        )
-                    else:
-                        wk = wv = sds((1, 1, 1, 1, 1), self.dtype)
-                    counts = sds(
-                        (db, mc.vocab_size) if pen else (1, 1), jnp.int32
-                    )
                     compile_counted(
-                        self._decode, aparams,
-                        sds((NUM_SCALARS * db + db * mb,), jnp.int32),
-                        self.kv_k, self.kv_v, kv_ks, kv_vs, wk, wv, counts,
-                        self._zero_last, dparams, sp_k, sp_v, sp_p,
-                        b=db, mb=mb, num_steps=dk, use_cached_window=cached,
+                        self._lower_decode, db, mb, dk, cached,
                         has_penalties=pen, logprobs_k=lpk,
                     )
             t_floor = _t_floor(cfg.max_num_batched_tokens)
@@ -2937,19 +2989,8 @@ class ModelRunner:
                 else:
                     pvariants = variants[:1]
                 for pen, lpk in pvariants:
-                    counts = sds(
-                        (pb, mc.vocab_size) if pen else (1, 1), jnp.int32
-                    )
                     compile_counted(
-                        self._prefill, aparams,
-                        sds(
-                            (NUM_SCALARS * pb + pb * mb + pb * t,),
-                            jnp.int32,
-                        ),
-                        self.kv_k, self.kv_v, kv_ks, kv_vs, counts,
-                        dparams, sp_k, sp_v, sp_p,
-                        b=pb, t=t, mb=mb, has_window=has_window,
-                        b_max=self._b_max,
+                        self._lower_prefill, pb, t, mb, has_window,
                         has_penalties=pen, logprobs_k=lpk,
                     )
         except _PrepassDone:
@@ -3017,12 +3058,11 @@ class ModelRunner:
         jit.lower().compile(), which fills the persistent XLA cache but NOT
         the in-process pjit dispatch cache — the first real call would still
         pay a full retrace + cache load inside the serving path). The dummy
-        inputs are all-zero: a decode with per-row budget 0 runs ZERO
-        while_loop iterations and its trailing scatter writes only the
-        reserved null block; a prefill with chunk_lens 0 likewise touches
-        only the null block. The donated KV pool buffers are rebound from
-        the dispatch outputs, so pool contents (beyond the never-read null
-        block) survive warmup untouched.
+        inputs are all-zero: a decode with per-row budget 0 and a prefill
+        with chunk_lens 0 keep no token, so their trailing pool write
+        (ops/kv_write.py) rewrites the null block with its own content.
+        The donated KV pool buffers are rebound from the dispatch outputs,
+        so pool contents survive warmup untouched.
 
         Sampling-variant coverage contract (a mid-serving compile stalls
         the single dispatch executor, so the variants co-batched traffic
@@ -3056,11 +3096,11 @@ class ModelRunner:
         serializing — and the execute pass below then pays only a retrace
         + persistent-cache load per family.
 
-        Cost note: under the default decode_loop="while" the dummy decode
-        executions run ZERO loop iterations (budget 0). Under "scan" each
-        family executes its full K forwards (~K * one decode step, a few
-        hundred ms per family on large models) — a startup-time cost only,
-        accepted for the A/B knob.
+        Cost note: under the default decode_loop="scan" (engine/config.py)
+        each dummy decode family executes its full K forwards (~K * one
+        decode step, a few hundred ms per family on large models) — a
+        startup-time cost only. Under "while" the dummy executions run ZERO
+        loop iterations (budget 0).
         """
         import os as _os
         import time as _time

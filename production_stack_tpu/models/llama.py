@@ -6,11 +6,23 @@ TPU-first design notes:
     layer body instead of L inlined copies, which keeps XLA compile time flat
     in depth and produces identical per-layer fusions.
   * Activations are bfloat16; norms/softmax/rope math in float32.
-  * The paged KV pool is NOT threaded through the layer scan. The runner
-    gathers the pool into a contiguous per-sequence window once per dispatch
-    (ops/attention.py:gather_window) and scatters the chunk's new KV back once
-    after the forward — scanning the pools as xs/ys cost a full pool copy per
-    layer (~2 ms/step on a v5e, profiled round 1).
+  * The paged KV pool is NOT threaded through the layer scan or the step
+    scan: scanning the pools as xs/ys cost a full pool copy per layer (~2
+    ms/step on a v5e, profiled round 1). The scans only READ it, as a
+    closed-over constant — the Pallas kernel in place (``memory_space=ANY``),
+    or the window the runner gathers once per dispatch
+    (ops/attention.py:gather_window) — and the runner writes the dispatch's
+    new KV back once, after the scans, IN PLACE: the donated pool is updated
+    by ``dynamic_update_slice``s of block-wide slabs (ops/kv_write.py) and no
+    program may hold an operation that reads or writes a whole pool. The
+    measured reason (PERF.md §6, PR 25, TPU v5e): the earlier write,
+    ``pool.at[:, :, slots].set(new)``, is a scatter on a middle axis, which the
+    TPU compiler runs in a layout of its own — it copied each pool into that
+    layout and back, every dispatch: four copies of 2.4 GB, 23 ms and a
+    pool-sized temporary per decode train of qwen2.5-3b. Reading inside the
+    loops and writing after them needs no copy (XLA orders the read-only
+    loops before the in-place write); ``GET /debug/programs`` and
+    chip_smoke.py hold every later change to that.
 
 Device operations are named by ``jax.named_scope``: ``embed``, ``attn_proj``
 (QKV, rope, output projection), ``attn_core`` (every attention path), ``ffn``

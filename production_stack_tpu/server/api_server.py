@@ -272,6 +272,7 @@ class APIServer:
             app.router.add_get("/debug/timeline", self.debug_timeline)
             app.router.add_post("/debug/profile", self.debug_profile_start)
             app.router.add_get("/debug/profile", self.debug_profile_status)
+            app.router.add_get("/debug/programs", self.debug_programs)
         return app
 
     # ------------------------------------------------- observability (debug)
@@ -356,6 +357,17 @@ class APIServer:
             return _error(404, "Device profiling unavailable",
                           etype="not_found")
         return web.json_response(self.profiler.status())
+
+    async def debug_programs(self, request: web.Request) -> web.Response:
+        """GET /debug/programs: what one dispatch program of each kind
+        does to the KV pools — whole-pool ``copy`` operations (0: the pools
+        are updated in place) and temporaries beside one pool's bytes
+        (runner.audit_pool_programs). Compiles in a worker thread; with a
+        compile cache it loads the programs warmup left there."""
+        audit = await asyncio.get_running_loop().run_in_executor(
+            None, self.engine.runner.audit_pool_programs
+        )
+        return web.json_response({"programs": audit})
 
     def _emit_lifecycle_spans(self, request: web.Request,
                               request_ids) -> None:

@@ -136,3 +136,47 @@ def test_sharded_paged_decode_kernel_compiles_for_four_v5e(v5e, pool):
                   * (1 if pool == "int8" else 2))
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert per_device < 2 * pool_bytes / 4 * 1.2, (per_device, pool_bytes)
+
+
+@pytest.mark.parametrize("pool", ["bfloat16", "int8"])
+@pytest.mark.parametrize("run", ["decode-8x32", "prefill-1x512"])
+def test_pool_write_is_in_place_on_v5e(v5e, pool, run):
+    """The KV write of a dispatch (ops/kv_write.py) on donated pools at
+    llama-3b's widths: the compiled program copies no pool and holds no
+    pool-sized temporary. The form it replaced, ``pool.at[:, :,
+    slots].set(new)``, cost two whole-pool copies a pool here (PERF.md §6,
+    PR 25) — and the CPU compiler cannot show it (it has no tiled layouts
+    to change, and widens bf16 updates instead)."""
+    from production_stack_tpu.ops.kv_write import (
+        pool_copies,
+        write_token_runs,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+    mc = resolve_model_config("llama-3b")
+    hkv, dh = mc.num_kv_heads, mc.head_dim_
+    b, t = (8, 32) if run.startswith("decode") else (1, 512)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    store = jnp.int8 if pool == "int8" else jnp.bfloat16
+    pools = [sds((LAYERS, hkv, NUM_SLOTS, dh), store)] * 2
+    news = [sds((LAYERS, hkv, b, t, dh), store)] * 2
+    if pool == "int8":
+        pools += [sds((LAYERS, hkv, NUM_SLOTS), SCALE_DTYPE)] * 2
+        news += [sds((LAYERS, hkv, b, t), SCALE_DTYPE)] * 2
+
+    def write(pools, news, tables, start, length):
+        return write_token_runs(pools, news, tables, start, length,
+                                BLOCK_SIZE)
+
+    compiled = jax.jit(write, donate_argnums=0).lower(
+        pools, news, sds((b, MAX_BLOCKS), jnp.int32),
+        sds((b,), jnp.int32), sds((b,), jnp.int32),
+    ).compile()
+    assert not pool_copies(compiled.as_text(), pools)
+    mem = compiled.memory_analysis()
+    payload = LAYERS * hkv * NUM_SLOTS * dh * jnp.dtype(store).itemsize
+    assert mem.temp_size_in_bytes < payload / 4, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= 2 * payload

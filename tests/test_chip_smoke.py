@@ -4,8 +4,8 @@ The script's verdict is taken from what the serving engine reports about
 itself, so the tests feed it reports: one that is right in every respect
 passes, and each way of not being on the chip — a CPU platform, interpreted
 kernels, no compile cache, a warmup stage that failed, a cut depth, a later
-boot that never found the cache, a failed phase — fails it with a non-zero
-exit. The whole script also runs here, in the sandbox, where it must fail:
+boot that never found the cache, a failed phase, a dispatch program that
+copies a KV pool whole — fails it with a non-zero exit. The whole script also runs here, in the sandbox, where it must fail:
 as the driver runs it, from a bare directory, and (slow) as a full CPU
 rehearsal of every phase.
 """
@@ -42,13 +42,24 @@ def _boot(**over):
     return boot
 
 
+def _programs(**over):
+    """GET /debug/programs of an engine whose pools are written in place."""
+    return [{"program": kind, "family": fam, "pool_copies": 0,
+             "temp_bytes": 300 << 20, "alias_bytes": 4 << 30,
+             "pool_bytes": 2 << 30, **over}
+            for kind, fam in (("decode", [4, 128, 32, False]),
+                              ("prefill", [1, 256, 128, True]))]
+
+
 def _good_lines():
     return [
         {"phase": "kernel", "ok": True, "interpret": False,
          "device": {k: TPU[k] for k in ("platform", "kind", "count")}},
-        {"phase": "serve[auto]", "ok": True, "boot": _boot()},
+        {"phase": "serve[auto]", "ok": True, "boot": _boot(),
+         "pool_programs": _programs()},
         {"phase": "serve[paged]", "ok": True,
-         "boot": _boot(attn_impl="paged", cache_hit=21, cache_miss=9)},
+         "boot": _boot(attn_impl="paged", cache_hit=21, cache_miss=9),
+         "pool_programs": _programs()},
     ]
 
 
@@ -79,6 +90,9 @@ BAD_RUNS = {
     "phase-failed": _with((1, "ok"), False),
     "wrong-device-count": _with((2, "boot", "device"), {**TPU, "count": 4}),
     "nothing-served": _good_lines()[:1],
+    "pool-copied-whole": _with((2, "pool_programs"),
+                               _programs(pool_copies=4)),
+    "no-program-audit": _with((2, "pool_programs"), []),
 }
 
 

@@ -222,6 +222,7 @@ async def test_debug_disabled_is_404_clean(engine_cfg):
         assert (await client.get("/debug/timeline")).status == 404
         assert (await client.post("/debug/profile", json={})).status == 404
         assert (await client.get("/debug/profile")).status == 404
+        assert (await client.get("/debug/programs")).status == 404
         # Serving still works; the recorder does not exist at all.
         resp = await client.post("/v1/completions", json={
             "model": "tiny-llama", "prompt": "abc", "max_tokens": 2,

@@ -201,6 +201,9 @@ ROUTES: Tuple[Route, ...] = (
           "(409 while one is running)."),
     Route("GET", "/debug/profile", ("engine",), True, False,
           "/debug/profile", "Profiler capture status."),
+    Route("GET", "/debug/programs", ("engine",), True, False, None,
+          "Dispatch-program audit: whole-KV-pool copies and temporaries "
+          "of one compiled program of each kind."),
     Route("GET", "/fleet", ("router",), False, False, None,
           "Fleet-wide live perf rollup (docs/OBSERVABILITY.md)."),
     Route("POST", "/v1/files", ("router",), False, False, None,
