@@ -1534,5 +1534,12 @@ class ServingEngine:
             "decode_row_steps_total": self.decode_row_steps_total,
             "decode_row_steps_wasted_total":
                 self.decode_row_steps_wasted_total,
+            # How often the sampler's conditional picks engage
+            # (engine/sampling.py), counted by the runner at issue.
+            "sample_dispatches_total": self.runner.sample_dispatches_total,
+            "sample_dispatches_greedy_total":
+                self.runner.sample_dispatches_greedy_total,
+            "sample_dispatches_filtered_total":
+                self.runner.sample_dispatches_filtered_total,
             **self._live_perf(),
         }

@@ -270,6 +270,19 @@ class EngineMetricsCollector(Collector):
                       "(row finished earlier in the train, aborted, "
                       "preempted, or its fetch failed)",
                       getattr(eng, "decode_row_steps_wasted_total", 0))
+        yield counter("pstpu:sample_dispatches_total",
+                      "Prefill and decode dispatches issued (each runs "
+                      "the sampler once a step)",
+                      getattr(runner, "sample_dispatches_total", 0))
+        yield counter("pstpu:sample_dispatches_greedy_total",
+                      "Dispatches whose every row is greedy: the sampler "
+                      "runs one argmax",
+                      getattr(runner, "sample_dispatches_greedy_total", 0))
+        yield counter("pstpu:sample_dispatches_filtered_total",
+                      "Dispatches in which a sampled row has top_k or "
+                      "top_p: the sampler runs its top-128 candidate "
+                      "search",
+                      getattr(runner, "sample_dispatches_filtered_total", 0))
         # Per-train dispatch duration histogram ({train=prefill|decode|
         # decode_spec}) — the only engine family with a second live label.
         dh = getattr(eng, "dispatch_hists", None)

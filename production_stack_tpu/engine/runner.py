@@ -35,7 +35,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
-from production_stack_tpu.engine.sampling import sample_tokens, sampling_scores
+from production_stack_tpu.engine.sampling import (
+    sample_tokens,
+    sampler_paths,
+    sampling_scores,
+)
 from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
 from production_stack_tpu.models import get_model_fns
 from production_stack_tpu.models.config import ModelConfig
@@ -488,6 +492,13 @@ class ModelRunner:
             self.spec_draft_depth_sum = 0
             self.spec_live_cycles_total = 0
             self.spec_gamma0_dispatches_total = 0
+
+        # pstpu:sample_dispatches_*: how often the sampler's skipped picks
+        # stay skipped (engine/sampling.py), counted at issue from the
+        # vectors the dispatch packs.
+        self.sample_dispatches_total = 0
+        self.sample_dispatches_greedy_total = 0
+        self.sample_dispatches_filtered_total = 0
 
         self.num_kv_blocks = num_kv_blocks or config.num_kv_blocks or \
             self._derive_num_blocks()
@@ -1156,6 +1167,9 @@ class ModelRunner:
         temps = jax.lax.bitcast_convert_type(scalars[5], jnp.float32)
         top_k = scalars[6]
         top_p = jax.lax.bitcast_convert_type(scalars[7], jnp.float32)
+        # Which sampler picks some row selects: constant over the K steps,
+        # so reduced once here and not once a step inside the loop.
+        paths = sampler_paths(temps, top_k, top_p)
         adapter_idx = scalars[8]
         presence = jax.lax.bitcast_convert_type(scalars[9], jnp.float32)
         frequency = jax.lax.bitcast_convert_type(scalars[10], jnp.float32)
@@ -1294,7 +1308,7 @@ class ModelRunner:
                 eff = apply_penalties(logits, counts, presence, frequency)
             else:
                 eff = logits
-            nxt = sample_tokens(eff, temps, top_k, top_p, seeds_j)
+            nxt = sample_tokens(eff, temps, top_k, top_p, seeds_j, paths)
             if has_penalties:
                 counts = counts.at[iota_rows, nxt].add(1)
             if logprobs_k:
@@ -1508,6 +1522,7 @@ class ModelRunner:
         temps = jax.lax.bitcast_convert_type(scalars[5], jnp.float32)
         top_k = scalars[6]
         top_p = jax.lax.bitcast_convert_type(scalars[7], jnp.float32)
+        paths = sampler_paths(temps, top_k, top_p)  # once a dispatch
         adapter_idx = scalars[8]
         presence = jax.lax.bitcast_convert_type(scalars[9], jnp.float32)
         frequency = jax.lax.bitcast_convert_type(scalars[10], jnp.float32)
@@ -1612,7 +1627,7 @@ class ModelRunner:
                     seed_base, gen0 + gen_off, i.astype(jnp.uint32)
                 )
                 prop = sample_tokens(
-                    logits_d, temps, top_k, top_p, seeds_i
+                    logits_d, temps, top_k, top_p, seeds_i, paths
                 ).astype(jnp.int32)
                 props = props.at[i].set(prop)
                 if tw > 1:
@@ -1625,7 +1640,7 @@ class ModelRunner:
                     # not stacked: a [N+1, b, V] ys would be HBM waste.
                     l1 = jnp.where(
                         i == 0,
-                        sampling_scores(logits_d, temps, seeds_i),
+                        sampling_scores(logits_d, temps, seeds_i, paths[0]),
                         l1,
                     )
                     return (prop, drk, drv, drp, props, l1), None
@@ -1697,7 +1712,7 @@ class ModelRunner:
                         logits_m[:, i], cnt, presence, frequency
                     )
                     zi = sample_tokens(
-                        eff, temps, top_k, top_p, seeds_m[:, i]
+                        eff, temps, top_k, top_p, seeds_m[:, i], paths
                     ).astype(jnp.int32)
                     cnt = cnt.at[iota_b, zi].add(1)
                     zm = zm.at[:, i].set(zi)
@@ -1721,7 +1736,7 @@ class ModelRunner:
                             logits[:, a], cnt_a, presence, frequency
                         )
                         za = sample_tokens(
-                            eff_a, temps, top_k, top_p, seeds[:, a]
+                            eff_a, temps, top_k, top_p, seeds[:, a], paths
                         ).astype(jnp.int32)
                         z = z.at[:, a].set(za)
                 else:
@@ -1733,6 +1748,7 @@ class ModelRunner:
                     jnp.repeat(top_k, t_v),
                     jnp.repeat(top_p, t_v),
                     seeds.reshape(-1),
+                    paths,
                 ).reshape(b, t_v).astype(jnp.int32)
 
             # -- 3. accept/emit -----------------------------------------
@@ -2010,6 +2026,7 @@ class ModelRunner:
             f32[9, i] = sp.presence_penalty
             f32[10, i] = sp.frequency_penalty
             bt[i, :len(s.block_ids)] = s.block_ids
+        self._count_sample_dispatch(f32[5], sc[6], f32[7])
         if has_penalties:
             vocab = self.model_config.vocab_size
             counts = np.zeros((b, vocab), np.int32)
@@ -2457,6 +2474,7 @@ class ModelRunner:
             f32[10, i] = sp.frequency_penalty
             bt[i, :len(s.block_ids)] = s.block_ids
             toks[i, :ln] = s.all_token_ids[start:start + ln]
+        self._count_sample_dispatch(f32[4], sc[5], f32[6])
         if has_penalties:
             vocab = self.model_config.vocab_size
             counts = np.zeros((b, vocab), np.int32)
@@ -2523,6 +2541,15 @@ class ModelRunner:
         del self._chains[self._max_chains:]
 
     # ---------------------------------------------------------------- execute
+    def _count_sample_dispatch(self, temps: np.ndarray, top_k: np.ndarray,
+                               top_p: np.ndarray) -> None:
+        """What the device's conds will see, from the float32 vectors a
+        dispatch has just packed (padding rows included: they are greedy)."""
+        any_sampled, any_filtered = sampler_paths(temps, top_k, top_p)
+        self.sample_dispatches_total += 1
+        self.sample_dispatches_greedy_total += not any_sampled
+        self.sample_dispatches_filtered_total += bool(any_filtered)
+
     def execute_async(self, batch: ScheduledBatch,
                       step_counter: int) -> "DispatchHandle":
         """ISSUE one dispatch (async — returns before any device->host

@@ -12,10 +12,20 @@ sorts on the common paths:
   * filtered rows reduce the vocab to the top TOP_CANDIDATES logits via
     lax.top_k (O(V) per candidate, no full sort) and apply top-k/top-p masks
     among those candidates.
-Path selection is PER ROW (jnp.where over both picks) so a request's tokens
+Path selection is PER ROW (jnp.where over the picks) so a request's tokens
 never depend on co-batched requests. The filtered path truncates top-p to the
 TOP_CANDIDATES most likely tokens; mass beyond rank 128 is vanishingly small
 for real LLM logits (vLLM's TPU backend makes the same tradeoff).
+
+What a dispatch COMPUTES is narrower than what its rows may select: a pick is
+computed only when some row of the dispatch selects it (``sampler_paths``,
+two scalars reduced from the sampling vectors the program already holds, and
+a ``lax.cond`` on each). An all-greedy dispatch runs one argmax; the Gumbel
+field [B, V] exists only when some row samples, the top-128 search only when
+some sampled row filters (12% of a v5e's time at 20-32 rows x 151936, all
+for values no row's ``where`` took: PERF.md, PR 28). The ``cond`` skips a
+value nobody reads; it never replaces one row's pick by another path's — see
+``sample_tokens``.
 """
 
 from dataclasses import dataclass, field
@@ -253,6 +263,59 @@ def _gumbel(seeds: jax.Array, shape) -> jax.Array:
     )(seeds)
 
 
+def sampler_paths(temperature, top_k, top_p) -> tuple:
+    """(any_sampled, any_filtered): which of the sampler's picks SOME row of
+    this dispatch selects, from its [B] sampling vectors (top_k <= 0 and
+    top_p >= 1 are off). Scalars, replicated under any sharding. A greedy
+    row's top_k/top_p never count (greedy wins, and a shape bucket's padding
+    rows are all-zero: temperature 0 with top_p 0). The three vectors are
+    constant over a dispatch, so a program that samples in a loop computes
+    this once, outside it, and hands it to every ``sample_tokens`` call.
+    Array methods only: the runner counts ``pstpu:sample_dispatches_*`` with
+    this same function over the numpy vectors it packs."""
+    sampled = temperature > 0.0
+    return sampled.any(), (sampled & ((top_k > 0) | (top_p < 1.0))).any()
+
+
+@jax.jit
+def _filtered_pick(logits, temp, top_k, top_p, g):
+    """The candidate branch's body: top-k/top-p among the TOP_CANDIDATES
+    most likely tokens, with the shared Gumbel field ``g`` gathered at the
+    candidate indices. ``logits / temp`` is formed here, not carried in: a
+    [B, V] operand would be written out as a field of its own; here it
+    fuses into the search as it did without the cond."""
+    c = min(TOP_CANDIDATES, logits.shape[1])
+    cand_logits, cand_idx = jax.lax.top_k(logits / temp, c)    # [B, C] desc
+    probs = jax.nn.softmax(cand_logits, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    ranks = jnp.arange(c, dtype=jnp.int32)[None, :]
+    k_eff = jnp.where(top_k[:, None] <= 0, c, top_k[:, None])
+    keep = (ranks < k_eff) & ((cum - probs) < top_p[:, None])
+    keep = keep.at[:, 0].set(True)
+    masked = jnp.where(keep, cand_logits, -jnp.inf)
+    g_cand = jnp.take_along_axis(g, cand_idx, axis=-1)         # [B, C]
+    pick = jnp.argmax(masked + g_cand, axis=-1)
+    return jnp.take_along_axis(cand_idx, pick[:, None], axis=-1)[:, 0]
+
+
+@jax.jit
+def _sampled_pick(logits, temperature, top_k, top_p, seeds, any_filtered):
+    """The sampled branch's body: the Gumbel field, the exact full-vocab
+    pick, and (only when some sampled row filters) the candidate pick."""
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    g = _gumbel(seeds, logits.shape)
+    # Exact softmax sampling without a sort: argmax(logits/T + Gumbel).
+    unfiltered_pick = jnp.argmax(logits / temp + g, axis=-1)
+    row_filtered = (top_k > 0) | (top_p < 1.0)
+    return jnp.where(
+        row_filtered,
+        jax.lax.cond(any_filtered,
+                     lambda: _filtered_pick(logits, temp, top_k, top_p, g),
+                     lambda: unfiltered_pick),
+        unfiltered_pick,
+    )
+
+
 @jax.jit
 @jax.named_scope("sample")
 def sample_tokens(
@@ -261,47 +324,61 @@ def sample_tokens(
     top_k: jax.Array,        # [B] int32 (-1 = off)
     top_p: jax.Array,        # [B]
     seeds: jax.Array,        # [B] uint32 per-row PRNG seeds
+    paths: Optional[tuple] = None,  # sampler_paths(...) of the same vectors
 ) -> jax.Array:
-    """Per-ROW path selection: a row with top_k/top_p takes the truncated
-    candidate pick; an unfiltered row takes the exact full-vocab Gumbel pick.
-    One shared Gumbel field [B, V] feeds both (the candidate branch gathers
-    its noise at the candidate indices), so a row's sampled token depends only
-    on its own (logits, params, seed) — never on which rows it was batched
-    with. A batch-global lax.cond here silently top-128-truncated unfiltered
-    rows whenever ANY co-batched row had filtering on, breaking the
-    per-sequence determinism contract of runner._token_seed."""
-    b, v = logits.shape
+    """Per-ROW path selection: a greedy row takes the argmax, a row with
+    top_k/top_p the truncated candidate pick, an unfiltered row the exact
+    full-vocab Gumbel pick. One shared Gumbel field [B, V] feeds both sampled
+    picks (the candidate branch gathers its noise at the candidate indices),
+    so a row's token depends only on its own (logits, params, seed) — never
+    on which rows it was batched with (the per-sequence determinism contract
+    of runner._token_seed).
+
+    Two kinds of batch-global ``lax.cond`` look alike here; one is safe.
+      * NOT allowed: a cond that decides which pick a row RECEIVES. "If any
+        row filters, everyone takes the candidate pick" silently
+        top-128-truncated unfiltered rows whenever a co-batched row had
+        filtering on — a row's token then depended on its batchmates.
+      * Allowed, and what this function does: a cond that decides whether a
+        pick is COMPUTED, keyed on whether any row's ``where`` selects it
+        (``paths``). The skipped branch returns a stand-in of the right
+        shape that no row reads; the per-row ``where``s are unchanged, so
+        every row's token is the same function of its own inputs as in the
+        unconditional body (tests/test_sampler_paths.py holds that body as
+        the reference).
+
+    The branch bodies are jitted functions of their own (``_sampled_pick``,
+    ``_filtered_pick``), so a branch holds one call. XLA inlines them; JAX's
+    lowering does not: with the bodies written inside the conds, the TPU's
+    unrolled threefry was emitted op by op into a region nested in the decode
+    program's step loop, and every decode program took 0.65 s longer to lower
+    at each boot (setup_s +8%, PERF.md §6, PR 28).
+    """
+    any_sampled, any_filtered = (
+        sampler_paths(temperature, top_k, top_p) if paths is None else paths
+    )
     greedy = jnp.argmax(logits, axis=-1)
-
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    scaled = logits / temp
-
-    g = _gumbel(seeds, (b, v))
-    # Exact softmax sampling without a sort: argmax(logits/T + Gumbel).
-    unfiltered_pick = jnp.argmax(scaled + g, axis=-1)
-
-    c = min(TOP_CANDIDATES, v)
-    cand_logits, cand_idx = jax.lax.top_k(scaled, c)       # [B, C] desc
-    probs = jax.nn.softmax(cand_logits, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    ranks = jnp.arange(c, dtype=jnp.int32)[None, :]
-    k_eff = jnp.where(top_k[:, None] <= 0, c, top_k[:, None])
-    keep = (ranks < k_eff) & ((cum - probs) < top_p[:, None])
-    keep = keep.at[:, 0].set(True)
-    masked = jnp.where(keep, cand_logits, -jnp.inf)
-    g_cand = jnp.take_along_axis(g, cand_idx, axis=-1)     # [B, C]
-    pick = jnp.argmax(masked + g_cand, axis=-1)
-    filtered_pick = jnp.take_along_axis(cand_idx, pick[:, None], axis=-1)[:, 0]
-
-    row_filtered = (top_k > 0) | (top_p < 1.0)
-    sampled = jnp.where(row_filtered, filtered_pick, unfiltered_pick)
+    sampled = jax.lax.cond(
+        any_sampled,
+        lambda: _sampled_pick(logits, temperature, top_k, top_p, seeds,
+                              any_filtered),
+        lambda: greedy,
+    )
     return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+@jax.jit
+def _perturbed_scores(scores, temperature, seeds):
+    """``sampling_scores``' sampled branch: its own function, as above."""
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    return scores / temp + _gumbel(seeds, scores.shape)
 
 
 def sampling_scores(
     logits: jax.Array,       # [B, V] float32
     temperature: jax.Array,  # [B]
     seeds: jax.Array,        # [B] uint32 per-row PRNG seeds
+    any_sampled: Optional[jax.Array] = None,  # sampler_paths(...)[0]
 ) -> jax.Array:
     """The score field whose argmax ``sample_tokens`` returns: raw logits
     for greedy rows, ``logits/T + Gumbel(seed)`` for sampled rows. Rank-2
@@ -309,13 +386,23 @@ def sampling_scores(
     pick when its own logits diverge slightly from the caller's — the
     right candidate pool for tree-speculation alternates under the common
     random numbers seed schedule (raw-logit runner-ups are not: the
-    shared Gumbel perturbation reorders them).
+    shared Gumbel perturbation reorders them). The Gumbel field is computed
+    only when some row samples (the allowed kind of cond: see
+    ``sample_tokens``).
     """
     greedy_scores = logits.astype(jnp.float32)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    g = _gumbel(seeds, logits.shape)
-    perturbed = greedy_scores / temp + g
-    return jnp.where(temperature[:, None] <= 0.0, greedy_scores, perturbed)
+    if any_sampled is None:
+        any_sampled = (temperature > 0.0).any()
+
+    return jnp.where(
+        temperature[:, None] <= 0.0,
+        greedy_scores,
+        jax.lax.cond(
+            any_sampled,
+            lambda: _perturbed_scores(greedy_scores, temperature, seeds),
+            lambda: greedy_scores,
+        ),
+    )
 
 
 @jax.named_scope("sample")

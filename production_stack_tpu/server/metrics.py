@@ -43,6 +43,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "loop_apply_seconds_total", "loop_idle_seconds_total",
                 "loop_other_seconds_total", "decode_steps_total",
                 "decode_row_steps_total", "decode_row_steps_wasted_total",
+                "sample_dispatches_total", "sample_dispatches_greedy_total",
+                "sample_dispatches_filtered_total",
                 "live_tok_per_s",
                 "live_hbm_bw_pct",
                 "live_effective_tokens_per_target_step"):
@@ -317,6 +319,22 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:decode_row_steps_wasted_total counter",
         f"pstpu:decode_row_steps_wasted_total{label} "
         f"{s['decode_row_steps_wasted_total']}",
+        "# HELP pstpu:sample_dispatches_total Prefill and decode "
+        "dispatches issued (each runs the sampler once a step)",
+        "# TYPE pstpu:sample_dispatches_total counter",
+        f"pstpu:sample_dispatches_total{label} "
+        f"{s['sample_dispatches_total']}",
+        "# HELP pstpu:sample_dispatches_greedy_total Dispatches whose "
+        "every row is greedy: the sampler runs one argmax",
+        "# TYPE pstpu:sample_dispatches_greedy_total counter",
+        f"pstpu:sample_dispatches_greedy_total{label} "
+        f"{s['sample_dispatches_greedy_total']}",
+        "# HELP pstpu:sample_dispatches_filtered_total Dispatches in "
+        "which a sampled row has top_k or top_p: the sampler runs its "
+        "top-128 candidate search",
+        "# TYPE pstpu:sample_dispatches_filtered_total counter",
+        f"pstpu:sample_dispatches_filtered_total{label} "
+        f"{s['sample_dispatches_filtered_total']}",
         # Observability plane (docs/OBSERVABILITY.md): OTLP spans the
         # exporter queue had to drop — tracing never blocks serving, but
         # never silently either (the collector renders the same series;

@@ -13,6 +13,7 @@ chip cannot be read back without one and the next compile would only warn.
 """
 
 import os
+import re
 
 import pytest
 
@@ -180,3 +181,95 @@ def test_pool_write_is_in_place_on_v5e(v5e, pool, run):
     payload = LAYERS * hkv * NUM_SLOTS * dh * jnp.dtype(store).itemsize
     assert mem.temp_size_in_bytes < payload / 4, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= 2 * payload
+
+
+def _unguarded_instructions(hlo: str):
+    """Instruction lines of every computation the entry reaches WITHOUT
+    passing through a ``conditional``'s branch: the entry itself, ``while``
+    bodies and conditions, fusions and reducers called from those."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$", line)
+        if head and not line.startswith(" "):
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            line = re.sub(r"(branch_computations=\{[^}]*\}"
+                          r"|(true|false)_computation=%?[\w.\-]+)", "", line)
+            for group in re.findall(
+                    r"(?:calls|to_apply|body|condition|called_computations)"
+                    r"=\{?((?:%[\w.\-]+(?:,\s*)?)+)", line):
+                todo.extend(re.findall(r"%([\w.\-]+)", group))
+    return [line for comp in seen for line in comps[comp]], len(comps)
+
+
+def test_sampler_branches_sit_inside_conditionals_on_v5e(v5e):
+    """The decode program's sampler at qwen2.5-3b's width, 32 rows x 151936,
+    as the step loop calls it (predicates reduced once, outside the
+    ``while``): the TPU compiler keeps both ``conditional``s, and every
+    ``TopK`` custom call and every operation of the Gumbel field lies in a
+    branch computation — none in the entry or the ``while`` body, where an
+    all-greedy dispatch would pay for it (0.41 s of 4 s in
+    qwen2.5-3b.chat-saturated before PR 28, PERF.md §6). A refactor that
+    makes XLA flatten a cond into selects fails here, at no chip time."""
+    from production_stack_tpu.engine.sampling import (
+        sample_tokens,
+        sampler_paths,
+        sampling_scores,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+    rows, vocab, steps = 32, 151936, 8
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def train(logits, temps, top_k, top_p, seeds):
+        paths = sampler_paths(temps, top_k, top_p)
+
+        def body(state):
+            j, toks, best = state
+            step_logits = logits + j.astype(jnp.float32)
+            step_seeds = seeds + j.astype(jnp.uint32)
+            nxt = sample_tokens(step_logits, temps, top_k, top_p,
+                                step_seeds, paths)
+            scores = sampling_scores(step_logits, temps, step_seeds,
+                                     paths[0])
+            return (j + 1, toks.at[j].set(nxt.astype(jnp.int32)),
+                    jnp.maximum(best, scores.max(axis=-1)))
+
+        return jax.lax.while_loop(
+            lambda s: s[0] < steps, body,
+            (jnp.int32(0), jnp.zeros((steps, rows), jnp.int32),
+             jnp.zeros((rows,), jnp.float32)))
+
+    compiled = jax.jit(train).lower(
+        sds((rows, vocab), jnp.float32), sds((rows,), jnp.float32),
+        sds((rows,), jnp.int32), sds((rows,), jnp.float32),
+        sds((rows,), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" conditional(") >= 3, text.count(" conditional(")
+    assert 'custom_call_target="TopK"' in text
+    assert "_gumbel" in text
+    assert " while(" in text
+    unguarded, n_comps = _unguarded_instructions(text)
+    assert n_comps > 10 and unguarded
+    for line in unguarded:
+        assert 'custom_call_target="TopK"' not in line, line[:300]
+        assert "_gumbel" not in line, line[:300]
+    # The skipped picks need no buffer of the field's size kept alive
+    # outside the branches: temporaries stay a few fields' worth.
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * rows * vocab * 4

@@ -442,6 +442,20 @@ REGISTRY: Tuple[Series, ...] = (
            "EOS / max_tokens / a stop string earlier in the train, was "
            "aborted or preempted, or its fetch failed; row-steps less "
            "wasted is the tokens decode delivered"),
+    Series("pstpu:sample_dispatches_total", "counter", ("model_name",),
+           _BOTH_ENGINE, ("catalogue", "loop"),
+           "Prefill and decode dispatches issued (each runs the sampler "
+           "once a step), counted at issue"),
+    Series("pstpu:sample_dispatches_greedy_total", "counter",
+           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           "Dispatches whose every row is greedy (`temperature <= 0`): the "
+           "sampler runs one argmax, no Gumbel field and no candidate "
+           "search"),
+    Series("pstpu:sample_dispatches_filtered_total", "counter",
+           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           "Dispatches in which a sampled row has `top_k` or `top_p`: the "
+           "sampler runs its top-128 candidate search; total less greedy "
+           "less filtered ran the Gumbel pick alone"),
     Series("pstpu:http_ingress_seconds", "histogram", ("model_name",),
            _BOTH_ENGINE, ("catalogue", "loop"),
            "HTTP handler entry to the request's enqueue in the scheduler "
