@@ -403,6 +403,7 @@ class EngineConfig:
     def resolved_attn_impl(self, model_config) -> str:
         """Resolve the decode attention implementation for ``model_config``
         (see the attn_impl field comment for the semantics)."""
+        from production_stack_tpu.models import get_model
         from production_stack_tpu.ops.pallas.paged_attention import (
             supports_pallas_decode,
         )
@@ -418,7 +419,7 @@ class EngineConfig:
                 and model_config.num_heads % tp == 0)
         )
         supported = (
-            model_config.arch == "llama"
+            get_model(model_config).PAGED_DECODE_VALIDATED
             and supports_pallas_decode(model_config.head_dim_, self.block_size)
             and tp_ok
         )

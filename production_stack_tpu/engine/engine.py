@@ -32,6 +32,7 @@ from production_stack_tpu.engine.tokenizer import (
     IncrementalDetokenizer,
     get_tokenizer,
 )
+from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import resolve_model_config
 from production_stack_tpu.parallel import make_mesh
 from production_stack_tpu.protocols import random_uuid
@@ -115,7 +116,7 @@ class ServingEngine:
                 load_peft_adapter,
             )
 
-            if self.model_config.arch != "llama":
+            if not get_model(self.model_config).LORA_TARGETS:
                 raise ValueError("LoRA serving is llama-family only")
             self.lora_registry = LoRARegistry(self.model_config)
             for name, path in config.lora_modules.items():

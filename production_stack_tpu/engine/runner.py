@@ -41,9 +41,9 @@ from production_stack_tpu.engine.sampling import (
     sampling_scores,
 )
 from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
-from production_stack_tpu.models import get_model_fns
+from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import gather_window
+from production_stack_tpu.ops.attention import KVView, gather_window
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
     write_slabs,
@@ -330,8 +330,9 @@ class ModelRunner:
         self.startup_warmed_families = 0
         self.startup_warmup_failures = 0
 
-        init_fn, self._forward, self._logits_fn = get_model_fns(model_config)
-        self._init_fn = init_fn
+        model = get_model(model_config)
+        init_fn = self._init_fn = model.init_params
+        self._forward, self._logits_fn = model.forward, model.compute_logits
         self._params = None
         self._param_thread = None
         self._param_error: Optional[BaseException] = None
@@ -397,9 +398,10 @@ class ModelRunner:
         self.spec_n = int(config.speculative_num_tokens)
         if self.spec_n:
             self.spec_draft_config = config.resolved_draft_config()
-            d_init, self._draft_forward, self._draft_logits = get_model_fns(
-                self.spec_draft_config
-            )
+            draft = get_model(self.spec_draft_config)
+            d_init = draft.init_params
+            self._draft_forward = draft.forward
+            self._draft_logits = draft.compute_logits
             if config.speculative_model == config.model:
                 # Self-draft: share the target's params outright (the
                 # parity/bench configuration — identical weights make
@@ -758,7 +760,7 @@ class ModelRunner:
             _, dk, dv = self._draft_forward(
                 dparams, dmc, tokens[None, :],
                 jnp.minimum(positions, d_max - 1), length[None],
-                None, None, None, drk, drv, drp,
+                KVView(ring_k=drk, ring_v=drv, ring_pos=drp),
             )
             in_chunk = iota_t[None, :] < length
             widx = jnp.where(
@@ -819,10 +821,10 @@ class ModelRunner:
         OPT-style learned position tables are bounded by the embedding
         table size (acceptance-only saturation beyond it)."""
         dmc = self.spec_draft_config
-        if dmc.arch == "opt":
-            return min(self.config.max_model_len,
-                       dmc.max_position_embeddings)
-        return self.config.max_model_len
+        bound = get_model(dmc).position_bound(dmc)
+        if bound is None:
+            return self.config.max_model_len
+        return min(self.config.max_model_len, bound)
 
     def _spec_pool_args(self):
         """(draft_params, spec_k, spec_v, spec_pos) dispatch inputs — the
@@ -1216,14 +1218,18 @@ class ModelRunner:
             # the Pallas kernel — the live KV is never copied (int8 pools
             # dequantize IN-KERNEL as rank-1 score/weight scaling). With
             # tp>1 the pool is kv-head-sharded, so the kernel runs under
-            # shard_map over the tp axis (models/llama.py).
+            # shard_map over the tp axis (ops/attention.py:attend).
             from production_stack_tpu.parallel.mesh import AXIS_TP
 
             tp_mesh = self.mesh if self.mesh.shape[AXIS_TP] > 1 else None
-            win_k = win_v = win_len = None
-            paged = (kv_k, kv_v, kv_ks if quant else None,
-                     kv_vs if quant else None, block_tables, pos0, bs,
-                     self._pallas_interpret, tp_mesh)
+            win_k = win_v = None
+            view0 = KVView(
+                pool_k=kv_k, pool_v=kv_v,
+                k_scale=kv_ks if quant else None,
+                v_scale=kv_vs if quant else None,
+                block_tables=block_tables, kv_lens=pos0, block_size=bs,
+                interpret=self._pallas_interpret, tp_mesh=tp_mesh,
+            )
         else:
             if use_cached_window:
                 win_k, win_v = win_k_in, win_v_in
@@ -1233,8 +1239,7 @@ class ModelRunner:
                     kv_ks if quant else None, kv_vs if quant else None,
                     out_dtype=self.dtype,
                 )
-            win_len = pos0                                       # [b]
-            paged = None
+            view0 = KVView(win_k=win_k, win_v=win_v, win_len=pos0)
 
         nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
         ring_k0 = jnp.zeros((nl, hkv, b, num_steps, dh), self.dtype)
@@ -1275,8 +1280,9 @@ class ModelRunner:
             positions = jnp.minimum(pos0 + j, max_len - 1)[:, None]
             hidden, k_new, v_new = self._forward(
                 params, mc, toks[:, None], positions, ones,
-                win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-                paged=paged, lora=lora,
+                view0._replace(ring_k=ring_k, ring_v=ring_v,
+                               ring_pos=ring_pos),
+                lora=lora,
             )
             if quant:
                 # Quantize this step's fresh KV on device; the attention
@@ -1601,7 +1607,7 @@ class ModelRunner:
                 dpos_c = jnp.clip(dpos, 0, d_max_pos - 1)
                 hid, dk, dv = self._draft_forward(
                     dparams, dmc, dtok[:, None], dpos_c[:, None], ones,
-                    None, None, None, drk, drv, drp,
+                    KVView(ring_k=drk, ring_v=drv, ring_pos=drp),
                 )
                 # gamma=0 rows draft nothing this dispatch: no ring
                 # writes (the forward itself is batched and unavoidable,
@@ -1688,8 +1694,9 @@ class ModelRunner:
             v_pos_c = jnp.minimum(v_pos, max_len - 1)
             hid, k_new, v_new = self._forward(
                 params, mc, v_toks, v_pos_c, full_lens,
-                win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-                lora=lora, chunk_bias=chunk_bias,
+                KVView(win_k, win_v, win_len, ring_k, ring_v, ring_pos,
+                       chunk_bias=chunk_bias),
+                lora=lora,
             )
             logits = self._logits_fn(params, mc, hid)       # [b, T_v, V]
             vocab = logits.shape[-1]
@@ -2283,7 +2290,7 @@ class ModelRunner:
             win_k = win_v = win_len = None
 
         # Sequence-parallel prefill rides ring attention over the sp mesh
-        # axis (models/llama.py) — first chunks ring the chunk itself;
+        # axis (ops/attention.py:attend) — first chunks ring the chunk itself;
         # continuation chunks ring the combined (history window ++ chunk)
         # sequence, so EVERY chunk of a long prefill sequence-shards
         # (VERDICT r4 weak #5). Both the chunk and the combined KV length
@@ -2291,18 +2298,15 @@ class ModelRunner:
         from production_stack_tpu.parallel.mesh import AXIS_SP
 
         sp = self.mesh.shape[AXIS_SP]
-        ring_mesh = None
-        if (
+        rings = (
             t > 1 and sp > 1 and t % sp == 0
             and (not has_window or (mb * bs + t) % sp == 0)
-            and self.model_config.arch == "llama"
-        ):
-            ring_mesh = self.mesh
+        )
         hidden, k_new, v_new = self._forward(
             params, mc, token_ids, positions, chunk_lens,
-            win_k, win_v, win_len,
+            KVView(win_k, win_v, win_len,
+                   sp_mesh=self.mesh if rings else None),
             act_sharding=self._act_sharding, lora=lora,
-            ring_mesh=ring_mesh,
         )
         logit_idx = jnp.maximum(chunk_lens - 1, 0)
         last_hidden = hidden[jnp.arange(b), logit_idx]            # [b, D]
@@ -2367,7 +2371,7 @@ class ModelRunner:
             d_positions = jnp.minimum(positions, d_max_pos - 1)
             _, dk, dv = self._draft_forward(
                 dparams, dmc, token_ids, d_positions, chunk_lens,
-                None, None, None, drk, drv, drp,
+                KVView(ring_k=drk, ring_v=drv, ring_pos=drp),
             )                                  # dk: [Ld, Hd, b, t, Dd]
             # Keep only the last min(t, R) chunk tokens per row: their
             # ring indices (pos % R) are then collision-free, so the
@@ -2584,7 +2588,6 @@ class ModelRunner:
             )
             hidden, _, _ = self._forward(
                 params, self.model_config, token_ids, positions, lens,
-                None, None, None,
             )
             mask = (jnp.arange(t, dtype=jnp.int32)[None, :] < lens[:, None])
             maskf = mask.astype(jnp.float32)[:, :, None]

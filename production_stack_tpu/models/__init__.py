@@ -1,4 +1,11 @@
-"""Model registry: arch name -> (init_params, forward, compute_logits)."""
+"""Model registry: arch name -> the module that is that architecture.
+
+A module declares ``init_params``, ``forward``, ``compute_logits``, its HF
+checkpoint maps (``HF_LAYER_MAP``, ``HF_TOP_MAP``, ``required_layer_leaves``,
+``finish_params``), ``LORA_TARGETS``, ``PAGED_DECODE_VALIDATED`` and
+``position_bound`` (models/llama.py lists them with their meaning). Nothing
+outside this package asks for an architecture by name.
+"""
 
 from production_stack_tpu.models import llama, opt
 from production_stack_tpu.models.config import (
@@ -10,19 +17,16 @@ from production_stack_tpu.models.config import (
     resolve_model_config,
 )
 
-_ARCHS = {
-    "llama": (llama.init_params, llama.forward, llama.compute_logits),
-    "opt": (opt.init_params, opt.forward, opt.compute_logits),
-}
+_ARCHS = {"llama": llama, "opt": opt}
 
 
-def get_model_fns(cfg: ModelConfig):
+def get_model(cfg: ModelConfig):
     if cfg.arch not in _ARCHS:
         raise ValueError(f"Unknown arch {cfg.arch!r}; available: {list(_ARCHS)}")
     return _ARCHS[cfg.arch]
 
 
 __all__ = [
-    "ModelConfig", "resolve_model_config", "get_model_fns",
+    "ModelConfig", "resolve_model_config", "get_model",
     "NAMED_CONFIGS", "TINY_LLAMA", "OPT_125M", "LLAMA3_8B",
 ]

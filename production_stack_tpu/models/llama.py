@@ -30,23 +30,79 @@ and ``logits`` here; ``sample`` in engine/sampling.py and ``kv_write`` in
 engine/runner.py. A profiler capture carries the scope in each operation's
 ``tf_op`` (docs/OBSERVABILITY.md); scopes cost nothing at run time.
 
+The attention kernel is not chosen here: the layer hands the runner's
+``KVView`` unopened to ops/attention.py:attend.
+
 Weight layout matches HuggingFace LlamaForCausalLM for direct safetensors
-loading (production_stack_tpu/models/weights.py).
+loading: ``HF_LAYER_MAP`` / ``HF_TOP_MAP`` below, read by models/weights.py.
+The declarations under "What the rest of the tree asks of this module" are
+the whole of what an architecture is outside its file (models/__init__.py).
 """
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import (
-    dense_decode_stats,
-    merge_attention_segments,
-    window_attention,
-)
+from production_stack_tpu.ops.attention import KVView, attend, scan_layers
+from production_stack_tpu.utils import init_logger
+
+logger = init_logger(__name__)
 
 Params = Dict
+
+# --- What the rest of the tree asks of this module --------------------------
+# HF checkpoint suffix -> (our leaf name, transpose?), per layer and top level.
+HF_LAYER_MAP = {
+    "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.k_proj.weight": ("wk", True),
+    "self_attn.v_proj.weight": ("wv", True),
+    "self_attn.o_proj.weight": ("wo", True),
+    "self_attn.q_proj.bias": ("bq", False),
+    "self_attn.k_proj.bias": ("bk", False),
+    "self_attn.v_proj.bias": ("bv", False),
+    "mlp.gate_proj.weight": ("w_gate", True),
+    "mlp.up_proj.weight": ("w_up", True),
+    "mlp.down_proj.weight": ("w_down", True),
+    "input_layernorm.weight": ("attn_norm", False),
+    "post_attention_layernorm.weight": ("mlp_norm", False),
+}
+HF_TOP_MAP = {
+    "model.embed_tokens.weight": ("embed", False),
+    "model.norm.weight": ("final_norm", False),
+    "lm_head.weight": ("lm_head", True),
+}
+# Projections ``_layer_body`` adds a LoRA delta to (models/lora.py).
+LORA_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# ``attn_impl=auto`` may resolve to the Pallas paged decode for this family:
+# its token parity against the window path is held by tests/test_paged_decode.py.
+PAGED_DECODE_VALIDATED = True
+
+
+def position_bound(cfg: ModelConfig) -> Optional[int]:
+    """Largest position + 1 the forward accepts; None: RoPE takes any."""
+    return None
+
+
+def required_layer_leaves(cfg: ModelConfig) -> set:
+    """Per-layer leaves every valid checkpoint must provide."""
+    req = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+           "attn_norm", "mlp_norm"}
+    if cfg.attention_bias:
+        req |= {"bq", "bk", "bv"}
+    return req
+
+
+def finish_params(cfg: ModelConfig, params: Params) -> Params:
+    """Last step of a checkpoint load: a tied head reads ``embed``."""
+    if cfg.tie_word_embeddings:
+        params.pop("lm_head", None)
+    elif "lm_head" not in params and "embed" in params:
+        # Checkpoints sometimes omit lm_head when tied; honor the config.
+        logger.warning("lm_head missing; falling back to tied embeddings")
+    return params
 
 
 def rms_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
@@ -109,20 +165,15 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.bfloat16) -> Params:
 
 def _layer_body(
     cfg: ModelConfig,
-    hidden: jax.Array,        # [B, T, D]
-    lp: Dict,                 # one layer's params (leading L axis sliced off)
     cos: jax.Array,
     sin: jax.Array,
     positions: jax.Array,
     chunk_lens: jax.Array,
-    win_k, win_v, win_len,
-    ring_k, ring_v, ring_pos,
-    paged=None,               # (pool_k, pool_v, k_scale|None, v_scale|None,
-    layer_idx=None,           #  block_tables, kv_lens, block_size,
-                              #  interpret, tp_mesh|None) + scan layer index
+    hidden: jax.Array,        # [B, T, D]
+    lp: Dict,                 # one layer's params (leading L axis sliced off)
+    view: KVView,             # this layer's KV view, passed on to attend
+    layer=None,               # scan layer index (pool views only)
     lora=None,                # (adapter_idx [B], {target: (A, B)} ONE layer)
-    ring_mesh=None,           # Mesh with sp>1: first-chunk prefill rings
-    chunk_bias=None,          # [T, T] additive in-chunk bias (tree verify)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     b, t, d = hidden.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
@@ -152,93 +203,7 @@ def _layer_body(
         k = apply_rope(k, cos, sin)
 
     with jax.named_scope("attn_core"):
-        if ring_mesh is not None and t > 1 and win_k is None and ring_k is None:
-            # Sequence-parallel prefill: the chunk is pure causal self-attention
-            # (no history window, no intra-dispatch ring buffer), computed
-            # exactly by ring attention over the sp axis — KV shards stream
-            # around the ICI ring while each chip holds O(T/sp) tokens
-            # (ops/ring_attention.py). Padding rows/tokens carry positions
-            # beyond every real token of their row, so causal masking by
-            # absolute position excludes them as keys.
-            from production_stack_tpu.ops.ring_attention import ring_attention
-
-            attn = ring_attention(q, k, v, positions, ring_mesh)
-        elif ring_mesh is not None and t > 1 and win_k is not None \
-                and ring_k is None:
-            # Sequence-parallel CONTINUATION chunk: the combined sequence
-            # (gathered history window ++ chunk) is the ring's KV, sharded over
-            # sp — each chip holds O((S_hist + T)/sp) keys instead of the whole
-            # window, and ring attention engages on every chunk of a long
-            # prefill, not just the first (VERDICT r4 weak #5). Window slot s
-            # holds absolute position s; slots at or beyond win_len take a
-            # sentinel position beyond every query so position-causality masks
-            # them exactly like window_attention's validity bias.
-            from production_stack_tpu.ops.ring_attention import ring_attention_kv
-
-            s_hist = win_k.shape[2]
-            kw = win_k.transpose(1, 2, 0, 3)        # [B, S, Hkv, Dh]
-            vw = win_v.transpose(1, 2, 0, 3)
-            s_idx = jnp.arange(s_hist, dtype=jnp.int32)
-            pos_w = jnp.where(
-                s_idx[None, :] < win_len[:, None], s_idx[None, :],
-                jnp.int32(2**30),
-            )                                        # [B, S]
-            attn = ring_attention_kv(
-                q, positions,
-                jnp.concatenate([kw, k], axis=1),
-                jnp.concatenate([vw, v], axis=1),
-                jnp.concatenate([pos_w, positions], axis=1),
-                ring_mesh,
-            )
-        elif paged is not None:
-            # Paged decode (T == 1): the pool segment runs in the Pallas
-            # flash-decode kernel directly against this layer of the stacked HBM
-            # pool (no gathered window copy); the intra-dispatch ring + the
-            # current token form a small dense segment; the two merge by their
-            # softmax stats. See ops/pallas/paged_attention.py.
-            from production_stack_tpu.ops.pallas.paged_attention import (
-                paged_flash_decode_stats,
-                paged_flash_decode_stats_tp,
-            )
-
-            (pool_k, pool_v, pool_ks, pool_vs, block_tables, kv_lens,
-             block_size, interpret, tp_mesh) = paged
-            q2 = q.reshape(b, h, dh)
-            if tp_mesh is not None:
-                # TP>1: the pool is kv-head-sharded; run the kernel per-shard
-                # via shard_map (exact — heads are independent) instead of
-                # letting GSPMD all-gather the pool (advisor r3 high finding).
-                out_p, m_p, l_p = paged_flash_decode_stats_tp(
-                    q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
-                    tp_mesh, block_size=block_size, interpret=interpret,
-                    k_scale=pool_ks, v_scale=pool_vs,
-                )
-            else:
-                out_p, m_p, l_p = paged_flash_decode_stats(
-                    q2, pool_k, pool_v, block_tables, kv_lens, layer_idx,
-                    block_size=block_size, interpret=interpret,
-                    k_scale=pool_ks, v_scale=pool_vs,
-                )
-            kc = k.transpose(2, 0, 1, 3)          # [Hkv, B, 1, Dh] current token
-            vc = v.transpose(2, 0, 1, 3)
-            self_bias = jnp.zeros((b, 1), jnp.float32)
-            if ring_k is not None:
-                keys = jnp.concatenate([ring_k, kc], axis=2)
-                vals = jnp.concatenate([ring_v, vc], axis=2)
-                neg = jnp.float32(jnp.finfo(jnp.float32).min)
-                ring_bias = jnp.where(ring_pos < positions, 0.0, neg)  # [B, R]
-                bias = jnp.concatenate([ring_bias, self_bias], axis=1)
-            else:
-                keys, vals, bias = kc, vc, self_bias
-            out_d, m_d, l_d = dense_decode_stats(q2, keys, vals, bias)
-            attn = merge_attention_segments(out_p, m_p, l_p, out_d, m_d, l_d)
-            attn = attn.reshape(b, t, h, dh)
-        else:
-            attn = window_attention(
-                q, k, v, positions, chunk_lens,
-                win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-                chunk_bias=chunk_bias,
-            )
+        attn = attend(q, k, v, positions, chunk_lens, view, layer)
     with jax.named_scope("attn_proj"):
         hidden = hidden + proj(attn.reshape(b, t, h * dh), "wo")
 
@@ -256,86 +221,35 @@ def forward(
     token_ids: jax.Array,     # [B, T]
     positions: jax.Array,     # [B, T]
     chunk_lens: jax.Array,    # [B] valid tokens per row
-    win_k: Optional[jax.Array] = None,   # [L, Hkv, B, S, Dh] gathered window
-    win_v: Optional[jax.Array] = None,
-    win_len: Optional[jax.Array] = None,  # [B]
-    ring_k: Optional[jax.Array] = None,   # [L, Hkv, B, R, Dh]
-    ring_v: Optional[jax.Array] = None,
-    ring_pos: Optional[jax.Array] = None,  # [B, R]
+    view: KVView = KVView(),  # the KV this forward may read beside its own
     *,
     act_sharding=None,
-    paged=None,  # (pool_k [L,Hkv,S,Dh], pool_v, k_scale [L,Hkv,S]|None,
-                 #  v_scale|None, block_tables [B,Mb], kv_lens [B],
-                 #  block_size, interpret, tp_mesh|None) — paged decode
-                 #  path (tp_mesh set => shard_map over tp; scales set =>
-                 #  int8 pools, in-kernel dequantization)
     lora=None,   # (adapter_idx [B], {target: (A [L,Na,in,r], B [L,Na,r,out])})
-    ring_mesh=None,  # Mesh with sp>1: first-chunk prefill uses ring attention
-    chunk_bias=None,  # [T, T] additive in-chunk bias — speculative token-tree
-                      # verify (ops/tree_mask.py); window path only
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Returns (hidden [B,T,D], k_new [L,Hkv,B,T,Dh], v_new [L,Hkv,B,T,Dh]).
 
-    The caller owns the paged pool. Window path: it gathers the window before
-    this call and scatters (k_new, v_new) into the pool after (see
-    engine/runner.py). Paged path (``paged`` set, decode only): each layer
-    attends directly against its slice of the stacked HBM pool inside the
-    Pallas flash-decode kernel — no window copy exists.
+    The caller owns the paged pool: it describes what this forward may read
+    as ``view`` (ops/attention.py:KVView — a gathered window, the dispatch's
+    ring, or the pool itself for the Pallas decode kernel) and writes
+    (k_new, v_new) into the pool after (engine/runner.py). This module never
+    opens the view; ``attend`` chooses the kernel from it.
 
     ``act_sharding``: optional NamedSharding P(None, "sp", None) — prefill
     chunks shard the TOKEN axis over the sequence-parallel mesh axis so the
     projection/MLP matmuls distribute over sp; GSPMD inserts the collectives.
-    The standalone ring kernel lives in production_stack_tpu/ops/ring_attention.py.
     """
     with jax.named_scope("embed"):
         hidden = params["embed"][token_ids]
-        hidden = hidden.astype(
-            win_k.dtype if win_k is not None else params["embed"].dtype
-        )
+        hidden = hidden.astype(view.act_dtype(params["embed"].dtype))
     if act_sharding is not None and hidden.shape[1] > 1 and \
             hidden.shape[1] % act_sharding.mesh.shape["sp"] == 0:
         hidden = jax.lax.with_sharding_constraint(hidden, act_sharding)
     cos, sin = _rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
 
-    have_win = win_k is not None
-    have_ring = ring_k is not None
-    have_paged = paged is not None
-    have_lora = lora is not None
-
-    def scan_fn(h_carry, xs):
-        lp = xs[0]
-        i = 1
-        wk = wv = rk = rv = li = lo = None
-        if have_win:
-            wk, wv = xs[i], xs[i + 1]
-            i += 2
-        if have_ring:
-            rk, rv = xs[i], xs[i + 1]
-            i += 2
-        if have_paged:
-            li = xs[i]
-            i += 1
-        if have_lora:
-            # per-layer slices of the adapter stacks, same adapter_idx rows
-            lo = (lora[0], xs[i])
-        h_out, k_l, v_l = _layer_body(
-            cfg, h_carry, lp, cos, sin, positions, chunk_lens,
-            wk, wv, win_len, rk, rv, ring_pos,
-            paged=paged, layer_idx=li, lora=lo, ring_mesh=ring_mesh,
-            chunk_bias=chunk_bias,
-        )
-        return h_out, (k_l, v_l)
-
-    xs = (params["layers"],)
-    if have_win:
-        xs += (win_k, win_v)
-    if have_ring:
-        xs += (ring_k, ring_v)
-    if have_paged:
-        xs += (jnp.arange(cfg.num_layers, dtype=jnp.int32),)
-    if have_lora:
-        xs += (lora[1],)  # dict of (A [L,...], B [L,...]) — L axis scanned
-    hidden, (k_new, v_new) = jax.lax.scan(scan_fn, hidden, xs)
+    hidden, k_new, v_new = scan_layers(
+        functools.partial(_layer_body, cfg, cos, sin, positions, chunk_lens),
+        hidden, params["layers"], view, lora,
+    )
     hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
     return hidden, k_new, v_new
 

@@ -1,22 +1,68 @@
 """OPT-family decoder (facebook/opt-125m etc.) — functional JAX.
 
-Kept deliberately close in structure to models/llama.py (stacked layers +
-lax.scan, window attention against the runner-gathered KV window) but with
-OPT's architecture: LayerNorm with bias, learned position embeddings with
+The same shape of module as models/llama.py (stacked layers scanned by
+ops/attention.py:scan_layers, attention through ``attend`` over whatever
+``KVView`` the runner built, the same declarations for the rest of the tree)
+with OPT's architecture: LayerNorm with bias, learned position embeddings with
 OPT's +2 offset quirk, ReLU MLP, tied LM head. opt-125m is the reference's
 minimal parity config (values-01-minimal-example, BASELINE.json).
 """
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import window_attention
+from production_stack_tpu.ops.attention import KVView, attend, scan_layers
 
 Params = Dict
 _OPT_POS_OFFSET = 2  # HF OPTLearnedPositionalEmbedding offset
+
+# --- What the rest of the tree asks of this module (see models/llama.py) ----
+HF_LAYER_MAP = {
+    "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.k_proj.weight": ("wk", True),
+    "self_attn.v_proj.weight": ("wv", True),
+    "self_attn.out_proj.weight": ("wo", True),
+    "self_attn.q_proj.bias": ("bq", False),
+    "self_attn.k_proj.bias": ("bk", False),
+    "self_attn.v_proj.bias": ("bv", False),
+    "self_attn.out_proj.bias": ("bo", False),
+    "self_attn_layer_norm.weight": ("ln1_w", False),
+    "self_attn_layer_norm.bias": ("ln1_b", False),
+    "final_layer_norm.weight": ("ln2_w", False),
+    "final_layer_norm.bias": ("ln2_b", False),
+    "fc1.weight": ("fc1", True),
+    "fc1.bias": ("fc1_b", False),
+    "fc2.weight": ("fc2", True),
+    "fc2.bias": ("fc2_b", False),
+}
+HF_TOP_MAP = {
+    "model.decoder.embed_tokens.weight": ("embed", False),
+    "model.decoder.embed_positions.weight": ("pos_embed", False),
+    "model.decoder.final_layer_norm.weight": ("final_ln_w", False),
+    "model.decoder.final_layer_norm.bias": ("final_ln_b", False),
+}
+LORA_TARGETS = ()
+# The forward runs a pool view like any other (tests/test_attention.py); no
+# engine-level token parity holds ``auto`` to it yet, so ``auto`` stays off it.
+PAGED_DECODE_VALIDATED = False
+
+
+def position_bound(cfg: ModelConfig) -> Optional[int]:
+    """The learned position table's rows."""
+    return cfg.max_position_embeddings
+
+
+def required_layer_leaves(cfg: ModelConfig) -> set:
+    """The forward unconditionally reads the bias/norm leaves too."""
+    return {leaf for leaf, _ in HF_LAYER_MAP.values()}
+
+
+def finish_params(cfg: ModelConfig, params: Params) -> Params:
+    return params
 
 
 def layer_norm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float = 1e-5) -> jax.Array:
@@ -53,28 +99,27 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.bfloat16) -> Params:
     }
 
 
-def _layer_body(cfg, hidden, lp, positions, chunk_lens,
-                win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-                chunk_bias=None):
+def _layer_body(cfg, positions, chunk_lens, hidden, lp, view, layer=None,
+                lora=None):
     b, t, d = hidden.shape
     h, dh = cfg.num_heads, cfg.head_dim_
 
-    x = layer_norm(hidden, lp["ln1_w"], lp["ln1_b"])
-    q = (x @ lp["wq"] + lp["bq"]).reshape(b, t, h, dh)
-    k = (x @ lp["wk"] + lp["bk"]).reshape(b, t, h, dh)
-    v = (x @ lp["wv"] + lp["bv"]).reshape(b, t, h, dh)
+    with jax.named_scope("attn_proj"):
+        x = layer_norm(hidden, lp["ln1_w"], lp["ln1_b"])
+        q = (x @ lp["wq"] + lp["bq"]).reshape(b, t, h, dh)
+        k = (x @ lp["wk"] + lp["bk"]).reshape(b, t, h, dh)
+        v = (x @ lp["wv"] + lp["bv"]).reshape(b, t, h, dh)
 
-    attn = window_attention(
-        q, k, v, positions, chunk_lens,
-        win_k, win_v, win_len, ring_k, ring_v, ring_pos,
-        chunk_bias=chunk_bias,
-    )
-    hidden = hidden + attn.reshape(b, t, h * dh) @ lp["wo"] + lp["bo"]
+    with jax.named_scope("attn_core"):
+        attn = attend(q, k, v, positions, chunk_lens, view, layer)
+    with jax.named_scope("attn_proj"):
+        hidden = hidden + attn.reshape(b, t, h * dh) @ lp["wo"] + lp["bo"]
 
-    x = layer_norm(hidden, lp["ln2_w"], lp["ln2_b"])
-    # OPT's activation is ReLU (HF OPTConfig.activation_function default,
-    # used by facebook/opt-125m), not GELU.
-    mlp = jax.nn.relu(x @ lp["fc1"] + lp["fc1_b"]) @ lp["fc2"] + lp["fc2_b"]
+    with jax.named_scope("ffn"):
+        x = layer_norm(hidden, lp["ln2_w"], lp["ln2_b"])
+        # OPT's activation is ReLU (HF OPTConfig.activation_function default,
+        # used by facebook/opt-125m), not GELU.
+        mlp = jax.nn.relu(x @ lp["fc1"] + lp["fc1_b"]) @ lp["fc2"] + lp["fc2_b"]
     return hidden + mlp, k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)
 
 
@@ -84,67 +129,33 @@ def forward(
     token_ids: jax.Array,
     positions: jax.Array,
     chunk_lens: jax.Array,
-    win_k: Optional[jax.Array] = None,
-    win_v: Optional[jax.Array] = None,
-    win_len: Optional[jax.Array] = None,
-    ring_k: Optional[jax.Array] = None,
-    ring_v: Optional[jax.Array] = None,
-    ring_pos: Optional[jax.Array] = None,
+    view: KVView = KVView(),
     *,
     act_sharding=None,
-    paged=None,
     lora=None,
-    ring_mesh=None,
-    chunk_bias=None,  # [T, T] additive in-chunk bias (tree verify)
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Same contract as models/llama.py:forward (see its docstring).
-    The paged (Pallas flash-decode) path is llama-family-only BY POLICY
-    (engine/config.py:resolved_attn_impl requires arch == "llama"); the
-    kernel itself handles small head dims via lane packing, but this
-    forward never receives ``paged`` so it is asserted away."""
-    assert paged is None, "paged decode is llama-family only (policy)"
-    assert lora is None, "LoRA serving is llama-family only"
-    hidden = (
-        params["embed"][token_ids] + params["pos_embed"][positions + _OPT_POS_OFFSET]
-    )
-    hidden = hidden.astype(
-        win_k.dtype if win_k is not None else params["embed"].dtype
-    )
+    """Same contract as models/llama.py:forward (see its docstring)."""
+    assert lora is None, "OPT's projections apply no LoRA (LORA_TARGETS)"
+    with jax.named_scope("embed"):
+        hidden = (
+            params["embed"][token_ids]
+            + params["pos_embed"][positions + _OPT_POS_OFFSET]
+        )
+        hidden = hidden.astype(view.act_dtype(params["embed"].dtype))
     if act_sharding is not None and hidden.shape[1] > 1 and \
             hidden.shape[1] % act_sharding.mesh.shape["sp"] == 0:
         hidden = jax.lax.with_sharding_constraint(hidden, act_sharding)
-
-    have_win = win_k is not None
-    have_ring = ring_k is not None
-
-    def scan_fn(h_carry, xs):
-        lp = xs[0]
-        i = 1
-        wk = wv = rk = rv = None
-        if have_win:
-            wk, wv = xs[i], xs[i + 1]
-            i += 2
-        if have_ring:
-            rk, rv = xs[i], xs[i + 1]
-        h_out, k_l, v_l = _layer_body(
-            cfg, h_carry, lp, positions, chunk_lens,
-            wk, wv, win_len, rk, rv, ring_pos,
-            chunk_bias=chunk_bias,
-        )
-        return h_out, (k_l, v_l)
-
-    xs = (params["layers"],)
-    if have_win:
-        xs += (win_k, win_v)
-    if have_ring:
-        xs += (ring_k, ring_v)
-    hidden, (k_new, v_new) = jax.lax.scan(scan_fn, hidden, xs)
+    hidden, k_new, v_new = scan_layers(
+        functools.partial(_layer_body, cfg, positions, chunk_lens),
+        hidden, params["layers"], view,
+    )
     hidden = layer_norm(hidden, params["final_ln_w"], params["final_ln_b"])
     return hidden, k_new, v_new
 
 
 def compute_logits(params, cfg, hidden):
-    return jnp.dot(
-        hidden, params["embed"].T.astype(hidden.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("logits"):
+        return jnp.dot(
+            hidden, params["embed"].T.astype(hidden.dtype),
+            preferred_element_type=jnp.float32,
+        )

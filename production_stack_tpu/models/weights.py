@@ -19,73 +19,13 @@ from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
+from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.utils import init_logger
 
 logger = init_logger(__name__)
 
 _LAYER_RE = re.compile(r"\.(?:layers|decoder\.layers)\.(\d+)\.")
-
-# HF suffix -> (our leaf name, transpose?) for llama-family models.
-_LLAMA_MAP = {
-    "self_attn.q_proj.weight": ("wq", True),
-    "self_attn.k_proj.weight": ("wk", True),
-    "self_attn.v_proj.weight": ("wv", True),
-    "self_attn.o_proj.weight": ("wo", True),
-    "self_attn.q_proj.bias": ("bq", False),
-    "self_attn.k_proj.bias": ("bk", False),
-    "self_attn.v_proj.bias": ("bv", False),
-    "mlp.gate_proj.weight": ("w_gate", True),
-    "mlp.up_proj.weight": ("w_up", True),
-    "mlp.down_proj.weight": ("w_down", True),
-    "input_layernorm.weight": ("attn_norm", False),
-    "post_attention_layernorm.weight": ("mlp_norm", False),
-}
-_LLAMA_TOP = {
-    "model.embed_tokens.weight": ("embed", False),
-    "model.norm.weight": ("final_norm", False),
-    "lm_head.weight": ("lm_head", True),
-}
-
-_OPT_MAP = {
-    "self_attn.q_proj.weight": ("wq", True),
-    "self_attn.k_proj.weight": ("wk", True),
-    "self_attn.v_proj.weight": ("wv", True),
-    "self_attn.out_proj.weight": ("wo", True),
-    "self_attn.q_proj.bias": ("bq", False),
-    "self_attn.k_proj.bias": ("bk", False),
-    "self_attn.v_proj.bias": ("bv", False),
-    "self_attn.out_proj.bias": ("bo", False),
-    "self_attn_layer_norm.weight": ("ln1_w", False),
-    "self_attn_layer_norm.bias": ("ln1_b", False),
-    "final_layer_norm.weight": ("ln2_w", False),
-    "final_layer_norm.bias": ("ln2_b", False),
-    "fc1.weight": ("fc1", True),
-    "fc1.bias": ("fc1_b", False),
-    "fc2.weight": ("fc2", True),
-    "fc2.bias": ("fc2_b", False),
-}
-_OPT_TOP = {
-    "model.decoder.embed_tokens.weight": ("embed", False),
-    "model.decoder.embed_positions.weight": ("pos_embed", False),
-    "model.decoder.final_layer_norm.weight": ("final_ln_w", False),
-    "model.decoder.final_layer_norm.bias": ("final_ln_b", False),
-}
-
-
-def _required_layer_leaves(cfg: ModelConfig) -> set:
-    """Per-layer leaves every valid checkpoint must provide for the arch."""
-    if cfg.arch == "llama":
-        req = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-               "attn_norm", "mlp_norm"}
-        if cfg.attention_bias:
-            req |= {"bq", "bk", "bv"}
-        return req
-    # OPT: the forward unconditionally reads the bias/norm leaves too.
-    return {"wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
-            "fc1", "fc1_b", "fc2", "fc2_b",
-            "ln1_w", "ln1_b", "ln2_w", "ln2_b"}
-
 
 def _iter_checkpoint_tensors(model_dir: str) -> Iterator[Tuple[str, np.ndarray]]:
     """Yield (hf_name, numpy array) streaming over checkpoint shards."""
@@ -125,16 +65,17 @@ def load_hf_params(
     dtype,
     shardings: Optional[Dict] = None,
 ) -> Dict:
-    """Load an HF checkpoint into the stacked-layer tree used by
-    models/llama.py and models/opt.py, device_put'ing each completed stack.
+    """Load an HF checkpoint into the stacked-layer tree of the config's
+    model module (its ``HF_LAYER_MAP`` / ``HF_TOP_MAP`` name the leaves),
+    device_put'ing each completed stack.
 
     ``shardings``: optional pytree (same structure as the result) of
     NamedShardings — each leaf goes straight to its TP shard placement.
     """
     import jax
 
-    per_layer_map = _LLAMA_MAP if cfg.arch == "llama" else _OPT_MAP
-    top_map = _LLAMA_TOP if cfg.arch == "llama" else _OPT_TOP
+    model = get_model(cfg)
+    per_layer_map, top_map = model.HF_LAYER_MAP, model.HF_TOP_MAP
     nl = cfg.num_layers
 
     stacks: Dict[str, np.ndarray] = {}   # our layer leaf -> [L, ...] buffer
@@ -182,8 +123,7 @@ def load_hf_params(
         raise ValueError(
             f"Incomplete checkpoint: missing layer indices {holes}"
         )
-    required = _required_layer_leaves(cfg)
-    absent = required - set(stacks)
+    absent = model.required_layer_leaves(cfg) - set(stacks)
     if absent:
         raise ValueError(
             f"Incomplete checkpoint: no tensors at all for {sorted(absent)}"
@@ -202,12 +142,7 @@ def load_hf_params(
             arr = jax.device_put(arr, shardings[name])
         params[name] = arr
 
-    if cfg.arch == "llama" and cfg.tie_word_embeddings:
-        params.pop("lm_head", None)
-    if cfg.arch == "llama" and "lm_head" not in params \
-            and not cfg.tie_word_embeddings and "embed" in params:
-        # Checkpoints sometimes omit lm_head when tied; honor the config.
-        logger.warning("lm_head missing; falling back to tied embeddings")
+    params = model.finish_params(cfg, params)
     logger.info(
         "Loaded %d layer stacks + %d top-level tensors from %s",
         len(params["layers"]), len(top), model_dir,

@@ -1,12 +1,11 @@
 from production_stack_tpu.ops.attention import (
     gather_window,
-    paged_attention,
     paged_attention_xla,
     window_attention,
     write_kv_to_pool,
 )
 
 __all__ = [
-    "gather_window", "paged_attention", "paged_attention_xla",
-    "window_attention", "write_kv_to_pool",
+    "gather_window", "paged_attention_xla", "window_attention",
+    "write_kv_to_pool",
 ]

@@ -472,25 +472,3 @@ def paged_attention_decode_pallas(
         v_scale=None if v_scale is None else v_scale[None],
     )
     return out.reshape(b, 1, h, dh)
-
-
-def paged_attention_pallas(
-    q, k_pool, v_pool, block_tables, kv_lens, q_positions,
-    *, block_size: int, scale: Optional[float] = None,
-    interpret: bool = False, k_scale=None, v_scale=None,
-):
-    """Dispatch: decode (T==1, supported head_dim) runs the flash-decode
-    kernel; everything else falls back to the XLA gather path."""
-    if q.shape[1] == 1 and supports_pallas_decode(q.shape[-1], block_size):
-        return paged_attention_decode_pallas(
-            q, k_pool, v_pool, block_tables, kv_lens,
-            block_size=block_size, scale=scale, interpret=interpret,
-            k_scale=k_scale, v_scale=v_scale,
-        )
-    from production_stack_tpu.ops.attention import paged_attention_xla
-
-    return paged_attention_xla(
-        q, k_pool, v_pool, block_tables, kv_lens, q_positions,
-        block_size=block_size, scale=scale,
-        k_scale=k_scale, v_scale=v_scale,
-    )

@@ -90,6 +90,11 @@ class DynamicConfigWatcher:
         self.router_id = router_id or "router"
         self.current_config: Optional[DynamicRouterConfig] = None
         self._running = True
+        # One gossip pass at a time: the watch thread ticks from the moment
+        # it starts and sync_peer_state is public, and two passes at once
+        # would share one ``.tmp`` file (a doubled payload, or a replace of
+        # a file the other pass already moved).
+        self._sync_lock = threading.Lock()
         self._thread = threading.Thread(
             target=self._watch_worker, daemon=True, name="dynamic-config-watcher"
         )
@@ -126,6 +131,10 @@ class DynamicConfigWatcher:
         manager = get_resilience()
         if manager is None:
             return
+        with self._sync_lock:
+            self._sync_peer_state(manager)
+
+    def _sync_peer_state(self, manager) -> None:
         os.makedirs(self.peer_dir, exist_ok=True)
         mine = f"breakers-{self.router_id}.json"
         now = time.time()

@@ -88,8 +88,6 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# HELP vllm:generation_tokens_total Generated tokens",
         "# TYPE vllm:generation_tokens_total counter",
         f"vllm:generation_tokens_total{label} {s['generation_tokens_total']}",
-        # Same series the prometheus_client collector (engine/metrics.py)
-        # exports — the two renderers must not drift (pstpu-lint PL004).
         "# HELP pstpu:engine_uptime_seconds Engine uptime",
         "# TYPE pstpu:engine_uptime_seconds gauge",
         f"pstpu:engine_uptime_seconds{label} "
@@ -100,7 +98,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"pstpu:kv_offload_blocks{label} {s['kv_offload_blocks']}",
         # KV economy (docs/KV_ECONOMY.md): device prefix-index size (the
         # /prefix_index digest quantity) + shared-tier restore/eviction
-        # telemetry (the collector renders the same five series).
+        # telemetry.
         "# HELP pstpu:prefix_index_size Content-addressed blocks resident "
         "in the device prefix cache (the /prefix_index digest size)",
         "# TYPE pstpu:prefix_index_size gauge",
@@ -127,8 +125,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"pstpu:kv_chain_evictions_total{label} "
         f"{s['kv_chain_evictions_total']}",
         # Mid-stream resume (docs/RESILIENCE.md): prompt+resume tokens a
-        # resume request served from cache/tiers instead of recomputing
-        # (the collector renders the same series).
+        # resume request served from cache/tiers instead of recomputing.
         "# HELP pstpu:resume_restored_tokens_total Prompt+resume tokens "
         "served from the prefix cache or KV tiers on mid-stream resume "
         "requests instead of recomputed",
@@ -137,7 +134,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"{s['resume_restored_tokens_total']}",
         # Speculative decoding (docs/PERF.md round 8): whether the draft
         # path is active, draft proposals made/accepted, and the lifetime
-        # acceptance rate (the collector renders the same four series).
+        # acceptance rate.
         "# HELP pstpu:spec_enabled Speculative decoding active "
         "(--speculative-num-tokens > 0)",
         "# TYPE pstpu:spec_enabled gauge",
@@ -181,8 +178,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"pstpu:spec_acceptance_rate{label} "
         f"{s['spec_acceptance_rate']:.6f}",
         # Elastic fast-start (docs/ELASTIC.md): startup phase durations +
-        # the warmup persistent-compile-cache hit/miss split (the
-        # collector renders the same seven series).
+        # the warmup persistent-compile-cache hit/miss split.
         "# HELP pstpu:startup_weight_load_seconds Seconds loading model "
         "weights at startup (overlaps compile with overlap_weight_load)",
         "# TYPE pstpu:startup_weight_load_seconds gauge",
@@ -241,8 +237,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"{s['dispatch_gap_seconds_total']:.6f}",
         # Live roofline telemetry (docs/OBSERVABILITY.md fleet pane): the
         # engine's own roofline position from the rolling dispatch window
-        # (the collector renders the same four series + the per-train
-        # dispatch-duration histogram below — PL004 "fleet-perf" group).
+        # (with the per-train dispatch-duration histogram below: the
+        # registry's "fleet-perf" group).
         "# HELP pstpu:live_tok_per_s Generation throughput over the "
         "rolling dispatch window (tokens emitted / window wall span)",
         "# TYPE pstpu:live_tok_per_s gauge",
@@ -268,7 +264,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         # Loop spans (engine.py:_run_loop, flight_recorder.LoopSpans): the
         # six phases tile the loop's wall time, so their deltas over a
         # window sum to the window; and decode work counted where it
-        # happens (the collector renders the same nine series).
+        # happens.
         "# HELP pstpu:loop_schedule_seconds_total Engine-loop seconds in "
         "scheduler.schedule() (span pstpu.schedule)",
         "# TYPE pstpu:loop_schedule_seconds_total counter",
@@ -337,9 +333,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"{s['sample_dispatches_filtered_total']}",
         # Observability plane (docs/OBSERVABILITY.md): OTLP spans the
         # exporter queue had to drop — tracing never blocks serving, but
-        # never silently either (the collector renders the same series;
-        # the lifecycle phase histograms render below with the TTFT/e2e
-        # distributions).
+        # never silently either (the lifecycle phase histograms render
+        # below with the TTFT/e2e distributions).
         "# HELP pstpu:trace_spans_dropped_total OTLP spans dropped because "
         "the exporter queue was full",
         "# TYPE pstpu:trace_spans_dropped_total counter",
@@ -373,7 +368,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"{s['kv_handoff_failures_total']}",
         # KV-cache quantization (--kv-cache-dtype int8, docs/PERF.md round
         # 7): storage dtype as an info-style gauge + bytes the quantized
-        # pool avoided writing (collector renders the same pair).
+        # pool avoided writing.
         "# HELP pstpu:kv_cache_dtype KV-cache storage dtype of the block "
         "pool (1 = active)",
         "# TYPE pstpu:kv_cache_dtype gauge",
@@ -385,8 +380,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"pstpu:kv_quant_bytes_saved_total{label} "
         f"{s['kv_quant_bytes_saved_total']}",
         # Multi-chip serving (docs/PERF.md round 9): the mesh shape the
-        # engine's dispatches shard over (the collector renders the same
-        # series — PL004 keeps them aligned).
+        # engine's dispatches shard over.
         "# HELP pstpu:mesh_tp_size Tensor-parallel degree of the serving "
         "mesh",
         "# TYPE pstpu:mesh_tp_size gauge",

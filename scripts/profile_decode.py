@@ -11,9 +11,9 @@ import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.sampling import sample_tokens
-from production_stack_tpu.models import get_model_fns
+from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import resolve_model_config
-from production_stack_tpu.ops.attention import gather_window
+from production_stack_tpu.ops.attention import KVView, gather_window
 
 MODEL = "llama-1b"
 B = 16
@@ -34,7 +34,9 @@ def timed(fn, *args, n=10, **kw):
 
 def main():
     mc = resolve_model_config(MODEL)
-    init_fn, forward, logits_fn = get_model_fns(mc)
+    model = get_model(mc)
+    init_fn, forward, logits_fn = (
+        model.init_params, model.forward, model.compute_logits)
     params = init_fn(mc, jax.random.PRNGKey(0), jnp.bfloat16)
     params = jax.device_put(params)
     nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
@@ -69,7 +71,7 @@ def main():
 
     # 2. single forward (1 token, with window + ring)
     fwd = jax.jit(lambda p, t, po, wk, wv, rk, rv, rp: forward(
-        p, mc, t, po, ones, wk, wv, win_len, rk, rv, rp))
+        p, mc, t, po, ones, KVView(wk, wv, win_len, rk, rv, rp)))
     ms, (hidden, k_new, v_new) = timed(
         fwd, params, toks, pos, wk, wv, ring_k, ring_v, ring_pos)
     need = pbytes - 2 * mc.vocab_size * mc.hidden_size + wbytes
@@ -102,8 +104,8 @@ def main():
         def body(carry, j):
             t, rk, rv, rp = carry
             po = (pos + j)
-            h, kn, vn = forward(params, mc, t, po, ones, wk, wv, win_len,
-                                rk, rv, rp)
+            h, kn, vn = forward(params, mc, t, po, ones,
+                                KVView(wk, wv, win_len, rk, rv, rp))
             lgt = logits_fn(params, mc, h[:, 0])
             nxt = sample_tokens(lgt, temps, tk, tp, seeds)
             rk = jax.lax.dynamic_update_slice(rk, kn, (0, 0, 0, j, 0))
@@ -123,7 +125,7 @@ def main():
 
     # 6. forward WITHOUT window (weights only ceiling)
     fwd0 = jax.jit(lambda p, t, po, rk, rv, rp: forward(
-        p, mc, t, po, ones, None, None, None, rk, rv, rp))
+        p, mc, t, po, ones, KVView(ring_k=rk, ring_v=rv, ring_pos=rp)))
     ms, _ = timed(fwd0, params, toks, pos, ring_k, ring_v, ring_pos)
     print(f"forward-nowin: {ms:8.2f} ms")
 

@@ -7,9 +7,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from production_stack_tpu.models import get_model_fns
+from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import resolve_model_config
-from production_stack_tpu.ops.attention import gather_window
+from production_stack_tpu.ops.attention import KVView, gather_window
 
 MODEL = "llama-1b"
 BS = 16
@@ -30,7 +30,9 @@ def timed(fn, *args, n=5, **kw):
 
 def main():
     mc = resolve_model_config(MODEL)
-    init_fn, forward, logits_fn = get_model_fns(mc)
+    model = get_model(mc)
+    init_fn, forward, logits_fn = (
+        model.init_params, model.forward, model.compute_logits)
     params = jax.device_put(init_fn(mc, jax.random.PRNGKey(0), jnp.bfloat16))
     nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
 
@@ -52,7 +54,8 @@ def main():
             def full(params, toks, pos, lens, kv_k, kv_v, bt):
                 wk, wv = gather_window(kv_k, kv_v, bt, BS)
                 wl = jnp.full((b,), hist, jnp.int32)
-                h, kn, vn = forward(params, mc, toks, pos, lens, wk, wv, wl)
+                h, kn, vn = forward(params, mc, toks, pos, lens,
+                                    KVView(wk, wv, wl))
                 lg = logits_fn(params, mc, h[jnp.arange(b), lens - 1])
                 return lg, kn, vn
 

@@ -312,7 +312,7 @@ def test_dispatch_writes_exactly_its_rows(program, mode):
         _, k, v = runner._forward(
             runner.params, mc, toks[None],
             jnp.arange(t, dtype=jnp.int32)[None],
-            jnp.full((1,), t, jnp.int32), None, None, None,
+            jnp.full((1,), t, jnp.int32),
         )
         return k[:, :, 0], v[:, :, 0]              # [L, Hkv, T, Dh]
 

@@ -261,16 +261,11 @@ class TestMetricsDrift:
 
     @staticmethod
     def _registry():
-        from tools.pstpu_lint.metrics_registry import (
-            ENGINE_COLLECTOR,
-            ENGINE_TEXT,
-            ROUTER,
-            Series,
-        )
+        from tools.pstpu_lint.metrics_registry import ENGINE, ROUTER, Series
 
         return (
             Series("pstpu:good_total", "counter", ("model_name",),
-                   (ENGINE_TEXT, ENGINE_COLLECTOR), ("catalogue",), "doc"),
+                   (ENGINE,), ("catalogue",), "doc"),
             Series("router_good_total", "counter", (), (ROUTER,),
                    ("catalogue",), "doc", router_labels=("server",)),
         )
@@ -285,10 +280,8 @@ class TestMetricsDrift:
                 ]
         ''')
         _write(tmp_path, "production_stack_tpu/engine/metrics.py", """
-            labels = ["model_name"]
-
-            def collect(counter, eng):
-                yield counter("pstpu:good_total", "doc", eng.good)
+            class Histogram:
+                pass
         """)
         _write(tmp_path, "production_stack_tpu/router/metrics.py", """
             from prometheus_client import Counter
@@ -303,9 +296,8 @@ class TestMetricsDrift:
         assert check_metrics(str(tmp_path), registry=self._registry(),
                              docs_check=False) == []
 
-    def test_label_set_mismatch_between_renderers_fires(self, tmp_path):
-        # The text renderer grows a 'role' label the collector (and the
-        # registry) do not have — the parallel renderers drifted.
+    def test_renderer_label_the_registry_lacks_fires(self, tmp_path):
+        # The renderer grows a 'role' label the registry does not have.
         from tools.pstpu_lint.rules.metrics_drift import check_metrics
 
         self._tree(tmp_path, server_body='''
@@ -356,20 +348,19 @@ class TestMetricsDrift:
         assert "more than once" in msgs
         assert "naming convention" in msgs
 
-    def test_missing_from_one_renderer_fires(self, tmp_path):
-        # Registered for both engine surfaces but the collector dropped it.
+    def test_registered_series_the_renderer_does_not_emit_fires(
+            self, tmp_path):
         from tools.pstpu_lint.rules.metrics_drift import check_metrics
 
-        self._tree(tmp_path)
-        _write(tmp_path, "production_stack_tpu/engine/metrics.py", """
-            labels = ["model_name"]
-
-            def collect(counter, eng):
-                yield counter("pstpu:other_total", "doc", 0)
-        """)
+        self._tree(tmp_path, server_body='''
+            def render(s, label):
+                return []
+        ''')
         findings = check_metrics(str(tmp_path), registry=self._registry(),
                                  docs_check=False)
-        assert any("does not emit it" in f.message for f in findings)
+        assert [f.rule for f in findings] == ["PL004"]
+        assert "pstpu:good_total" in findings[0].message
+        assert "does not emit it" in findings[0].message
 
 
 # ---------------------------------------------------------------------- PL005

@@ -1,6 +1,6 @@
 """The engine loop's own instrumentation (docs/OBSERVABILITY.md, "Loop
 spans"): the six phase totals tile the loop's wall time, decode work is
-counted where it happens, the new series reach /metrics on both renderers,
+counted where it happens, the new series reach /metrics,
 a capture armed through DeviceProfiler holds the ``pstpu.*`` spans with
 their ``step`` and a clock anchor and no Python frame, stopping it does
 not block the event loop, and ``--load-format`` / ``--seed`` reach
@@ -175,10 +175,7 @@ async def test_an_aborted_rows_undelivered_steps_count_as_wasted():
 
 
 # ------------------------------------------------------------- /metrics
-async def test_new_series_are_on_both_renderers_and_pass_the_lint():
-    from prometheus_client import CollectorRegistry, generate_latest
-
-    from production_stack_tpu.engine.metrics import EngineMetricsCollector
+async def test_new_series_are_rendered_and_pass_the_lint():
     from production_stack_tpu.server.metrics import render_engine_metrics
     from tools.pstpu_lint.core import default_project_root
     from tools.pstpu_lint.rules.metrics_drift import check_metrics
@@ -190,12 +187,8 @@ async def test_new_series_are_on_both_renderers_and_pass_the_lint():
     finally:
         await engine.stop()
     text = render_engine_metrics(engine, "tiny-llama")
-    registry = CollectorRegistry()
-    registry.register(EngineMetricsCollector(engine))
-    collected = generate_latest(registry).decode()
     for series in NEW_SERIES:
         assert f"# TYPE {series} " in text, series
-        assert series.removesuffix("_total") in collected, series
     sample = {ln.split(" ")[0].split("{")[0]: float(ln.rsplit(" ", 1)[1])
               for ln in text.splitlines() if ln and not ln.startswith("#")
               and "_bucket" not in ln}
@@ -204,7 +197,7 @@ async def test_new_series_are_on_both_renderers_and_pass_the_lint():
     assert sample["pstpu:decode_row_steps_total"] == 16
     assert sample["pstpu:decode_row_steps_wasted_total"] == 5
     assert sample["pstpu:loop_fetch_wait_seconds_total"] > 0
-    # PL004: renderers, registry and docs tables agree (the whole tree).
+    # PL004: renderer, registry and docs tables agree (the whole tree).
     assert check_metrics(default_project_root()) == []
 
 

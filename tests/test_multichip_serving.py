@@ -206,8 +206,7 @@ def test_int8_tp_indivisible_heads_is_clean_config_error():
 
 
 # ------------------------------------------------------- mesh telemetry
-async def test_mesh_metrics_in_both_renderers():
-    from production_stack_tpu.engine.metrics import EngineMetricsCollector
+async def test_mesh_metrics_rendered():
     from production_stack_tpu.server.metrics import render_engine_metrics
 
     engine = ServingEngine(_cfg(tp=2))
@@ -225,18 +224,7 @@ async def test_mesh_metrics_in_both_renderers():
     per_dev = [int(float(ln.rsplit(" ", 1)[1])) for ln in dev_lines]
     assert sum(per_dev) == engine.runner.kv_pool_bytes
     assert per_dev[0] == per_dev[1]
-
-    fams = {
-        m.name: m for m in EngineMetricsCollector(engine).collect()
-    }
-    # prometheus_client strips the _total suffix from counter family names.
-    assert fams["pstpu:mesh_tp_size"].samples[0].value == 2
-    assert fams["pstpu:mesh_devices"].samples[0].value == 2
-    hbm = fams["pstpu:hbm_kv_bytes"]
-    assert len(hbm.samples) == 2
-    assert {s.labels["device"] for s in hbm.samples} == {"cpu:0", "cpu:1"}
-    assert sum(int(s.value) for s in hbm.samples) \
-        == engine.runner.kv_pool_bytes
+    assert all(f'device="cpu:{i}"' in dev_lines[i] for i in range(2))
 
 
 # ------------------------------------------------------- capacity model

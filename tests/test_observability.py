@@ -222,10 +222,9 @@ def test_request_stats_monitor_feeds_histograms():
     )
 
 
-def test_lifecycle_histograms_render_on_both_surfaces():
+def test_lifecycle_histograms_render():
     """The four pstpu lifecycle phase histograms render with cumulative
-    buckets on the text renderer AND the prometheus_client collector
-    (docs/OBSERVABILITY.md; PL004 keeps the surfaces aligned)."""
+    buckets on the engine's /metrics (docs/OBSERVABILITY.md)."""
     from production_stack_tpu.engine.metrics import LifecycleHistograms
     from production_stack_tpu.server.metrics import render_engine_metrics
 
@@ -260,52 +259,10 @@ def test_lifecycle_histograms_render_on_both_surfaces():
             )
         ]
         assert counts == sorted(counts) and counts[-1] == count
-    assert "pstpu:trace_spans_dropped_total" in text
-
-    # Collector surface: same four series through HistogramMetricFamily.
-    class _Cfg:
-        model_name = "m"
-        speculative_num_tokens = 0
-        role = "unified"
-        kv_cache_dtype = "bfloat16"
-
-    class _CE:
-        config = _Cfg()
-        scheduler = type("S", (), {"num_running": 0, "num_waiting": 0,
-                                   "num_preemptions_total": 0})()
-        block_manager = type(
-            "B", (), {"usage": lambda self: 0.0, "prefix_hits_total": 0,
-                      "prefix_queries_total": 0, "prefix_index_size": 0},
-        )()
-        prompt_tokens_total = 0
-        generation_tokens_total = 0
-        start_time = 0.0
-        offload_blocks_resident = 0
-        decode_dispatches_total = 0
-        prefill_dispatches_total = 0
-        fetches_total = 0
-        overlapped_fetches_total = 0
-        dispatch_gap_seconds_total = 0.0
-        resume_restored_tokens_total = 0
-        runner = None
-        disagg = None
-        offload = None
-        lifecycle = e.lifecycle
-
-        def _offload_stat(self, attr):
-            return 0
-
-    from production_stack_tpu.engine.metrics import EngineMetricsCollector
-
-    fams = {f.name: f for f in EngineMetricsCollector(_CE()).collect()}
-    # prometheus_client strips no suffix from histogram family names.
     for name, count in (("pstpu:queue_wait_seconds", 1),
                         ("pstpu:decode_train_seconds", 2)):
-        fam = fams[name]
-        samples = {s.name: s for s in fam.samples
-                   if s.name.endswith("_count")}
-        assert samples[f"{name}_count"].value == count
-    assert "pstpu:trace_spans_dropped" in fams
+        assert f'{name}_count{{model_name="m"}} {count}' in text
+    assert "# TYPE pstpu:trace_spans_dropped_total counter" in text
 
 
 def test_hpa_consumes_adapter_metric():

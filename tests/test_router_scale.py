@@ -194,9 +194,11 @@ def test_breaker_gossip_roundtrip_through_peer_files(tmp_path):
             assert mine["router_id"] == "r1"
             assert u_mine in mine["open"] and mine["open"][u_mine] > 0
 
-            # A peer file appears: its OPEN circuit is adopted locally.
+            # A peer file appears: its OPEN circuit is adopted locally
+            # (most of the open duration left, so no stall between this
+            # write and the tick can age it out).
             (tmp_path / "breakers-r2.json").write_text(json.dumps(
-                {"router_id": "r2", "open": {u_peer: 5.0}}
+                {"router_id": "r2", "open": {u_peer: 25.0}}
             ))
             # A half-written peer file must not break the tick.
             (tmp_path / "breakers-r3.json").write_text('{"router_id": "r3"')

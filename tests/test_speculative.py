@@ -379,9 +379,8 @@ def test_spec_off_engine_reports_disabled(engines):
     assert st["spec_acceptance_rate"] == 0.0
 
 
-def test_both_metrics_renderers_export_spec_series(engines):
+def test_metrics_export_spec_series(engines):
     eng, _ = engines
-    from production_stack_tpu.engine.metrics import EngineMetricsCollector
     from production_stack_tpu.server.metrics import render_engine_metrics
 
     text = render_engine_metrics(eng["self"], "m")
@@ -390,14 +389,6 @@ def test_both_metrics_renderers_export_spec_series(engines):
                  "pstpu:spec_acceptance_rate"):
         assert name in text, name
     assert 'pstpu:spec_enabled{model_name="m"} 1' in text
-    collected = {
-        m.name for m in EngineMetricsCollector(eng["self"]).collect()
-    }
-    # prometheus_client strips the _total suffix from counters.
-    for name in ("pstpu:spec_enabled", "pstpu:spec_draft_tokens",
-                 "pstpu:spec_accepted_tokens",
-                 "pstpu:spec_acceptance_rate"):
-        assert name in collected, name
 
 
 # --------------------------------------------------------------------------
@@ -653,8 +644,7 @@ def test_adaptive_engine_reports_controller_telemetry(engines_r10):
     assert "at-1" not in e.runner._spec_controller._ema
 
 
-def test_metrics_renderers_export_round10_series(engines_r10):
-    from production_stack_tpu.engine.metrics import EngineMetricsCollector
+def test_metrics_export_round10_series(engines_r10):
     from production_stack_tpu.server.metrics import render_engine_metrics
 
     eng10, _ = engines_r10
@@ -664,14 +654,6 @@ def test_metrics_renderers_export_round10_series(engines_r10):
                  "pstpu:spec_acceptance_ema",
                  "pstpu:spec_gamma0_dispatches_total"):
         assert name in text, name
-    collected = {
-        m.name for m in EngineMetricsCollector(eng10["adaptive"]).collect()
-    }
-    for name in ("pstpu:spec_acceptance_rate_window",
-                 "pstpu:spec_draft_depth", "pstpu:spec_tree_nodes",
-                 "pstpu:spec_acceptance_ema",
-                 "pstpu:spec_gamma0_dispatches"):
-        assert name in collected, name
 
 
 @pytest.mark.slow

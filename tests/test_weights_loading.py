@@ -10,12 +10,14 @@ import jax.numpy as jnp
 
 def _forward_logits(model_dir, token_ids):
     """Run our model's window forward (single chunk, no history); [T, V]."""
-    from production_stack_tpu.models import get_model_fns
+    from production_stack_tpu.models import get_model
     from production_stack_tpu.models.config import ModelConfig
     from production_stack_tpu.models.weights import load_hf_params
 
     cfg = ModelConfig.from_pretrained_dir(model_dir)
-    init_fn, forward, logits_fn = get_model_fns(cfg)
+    model = get_model(cfg)
+    init_fn, forward, logits_fn = (
+        model.init_params, model.forward, model.compute_logits)
     params = load_hf_params(cfg, model_dir, jnp.float32)
 
     t = len(token_ids)

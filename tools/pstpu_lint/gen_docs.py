@@ -74,8 +74,7 @@ HTTP_TABLES = {
 }
 
 _SURFACE_NAMES = {
-    reg.ENGINE_TEXT: "engine /metrics",
-    reg.ENGINE_COLLECTOR: "engine collector",
+    reg.ENGINE: "engine /metrics",
     reg.ROUTER: "router /metrics",
 }
 

@@ -2,25 +2,21 @@
 
 This is the single source of truth the PL004 metrics-drift rule checks the
 code against, and the input ``tools.pstpu_lint.gen_docs`` renders the docs
-metrics tables from. Three exporter surfaces:
+metrics tables from. Two exporter surfaces:
 
-  * ``engine-text``      — the engine pod's hand-rolled /metrics renderer
-                           (production_stack_tpu/server/metrics.py, plus the
-                           histogram names in engine/metrics.py it renders);
-  * ``engine-collector`` — the prometheus_client Collector alternative
-                           (production_stack_tpu/engine/metrics.py);
-  * ``router``           — the router's prometheus_client module registry
-                           (production_stack_tpu/router/metrics.py).
+  * ``engine`` — the engine pod's /metrics renderer
+                 (production_stack_tpu/server/metrics.py, plus the
+                 histogram names in engine/metrics.py it renders);
+  * ``router`` — the router's prometheus_client module registry
+                 (production_stack_tpu/router/metrics.py).
 
 Naming convention: ``pstpu:`` for series this stack introduces, ``router_``
 for router data-plane outcomes, ``vllm:`` for the scraper/dashboard
 compatibility contract (the reference Grafana dashboard and the router's
 EngineStatsScraper parse these exact names — do NOT rename them).
 
-The two engine surfaces are parallel renderers of the same stats and MUST
-agree on names and label sets wherever both render a series; PL004 enforces
-that, and enforces that this file, the renderers, and the docs tables never
-drift from each other. To add a series: emit it in the renderer(s), add a
+PL004 enforces that this file, the renderers, and the docs tables never
+drift from each other. To add a series: emit it in the renderer, add a
 ``Series`` entry here, then run ``python -m tools.pstpu_lint.gen_docs`` to
 refresh the docs tables.
 """
@@ -30,8 +26,7 @@ from typing import Dict, Tuple
 
 ALLOWED_PREFIXES = ("pstpu:", "router_", "vllm:")
 
-ENGINE_TEXT = "engine-text"
-ENGINE_COLLECTOR = "engine-collector"
+ENGINE = "engine"
 ROUTER = "router"
 
 
@@ -39,7 +34,7 @@ ROUTER = "router"
 class Series:
     name: str
     kind: str                       # gauge | counter | histogram
-    labels: Tuple[str, ...]         # label names on the engine surfaces
+    labels: Tuple[str, ...]         # label names on the engine surface
     surfaces: Tuple[str, ...]       # which exporters render it
     docs: Tuple[str, ...]           # docs table groups (gen_docs.TABLES)
     doc: str                        # one-line meaning for the docs tables
@@ -51,203 +46,201 @@ class Series:
         return self.router_labels if surface == ROUTER else self.labels
 
 
-_BOTH_ENGINE = (ENGINE_TEXT, ENGINE_COLLECTOR)
-
 REGISTRY: Tuple[Series, ...] = (
     # ------------------------------------------------ engine: vllm compat
     Series("vllm:num_requests_running", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Requests currently decoding"),
     Series("vllm:num_requests_waiting", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Requests waiting for prefill"),
     Series("vllm:gpu_cache_usage_perc", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "KV-pool usage fraction (TPU HBM)"),
     Series("vllm:gpu_prefix_cache_hits_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Prefix-cache hit tokens"),
     Series("vllm:gpu_prefix_cache_queries_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Prefix-cache queried tokens"),
     Series("vllm:num_preemptions_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Sequences preempted"),
     Series("vllm:prompt_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Prefilled tokens"),
     Series("vllm:generation_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Generated tokens"),
     Series("vllm:time_to_first_token_seconds", "histogram", ("model_name",),
-           (ENGINE_TEXT,), ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "TTFT distribution (vLLM bucket boundaries)"),
     Series("vllm:e2e_request_latency_seconds", "histogram", ("model_name",),
-           (ENGINE_TEXT,), ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "End-to-end request latency distribution"),
     # ------------------------------------------------ engine: pstpu series
     Series("pstpu:engine_uptime_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "Engine uptime"),
     Series("pstpu:kv_offload_blocks", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue",),
+           (ENGINE,), ("catalogue",),
            "KV blocks resident in the host offload pool"),
     Series("pstpu:queue_depth", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "autoscaling"),
+           (ENGINE,), ("catalogue", "autoscaling"),
            "Engine backlog (running + waiting requests) — the per-pod "
            "HPA metric"),
     Series("pstpu:decode_dispatches_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "Fused decode dispatches issued"),
     Series("pstpu:prefill_dispatches_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "Prefill chunk dispatches issued"),
     Series("pstpu:dispatch_overlap_ratio", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "Fraction of dispatch fetches with another dispatch outstanding"),
     Series("pstpu:dispatch_gap_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "Host-observed seconds with no dispatch outstanding "
            "(pipeline bubble)"),
     Series("pstpu:kv_cache_dtype", "gauge", ("model_name", "kv_cache_dtype"),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "KV-cache storage dtype of the block pool (1 = active)"),
     Series("pstpu:kv_quant_bytes_saved_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "dispatch"),
+           (ENGINE,), ("catalogue", "dispatch"),
            "KV-pool bytes the quantized cache avoided writing vs the "
            "compute dtype"),
     # ------------------------------------------- engine: KV economy
     Series("pstpu:prefix_index_size", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "kv-economy"),
+           (ENGINE,), ("catalogue", "kv-economy"),
            "Content-addressed blocks resident in the device prefix cache "
            "(the /prefix_index digest size)"),
     Series("pstpu:kv_restore_saved_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "kv-economy"),
+           (ENGINE,), ("catalogue", "kv-economy"),
            "Prompt tokens restored from the shared KV tier instead of "
            "recomputed (cost-model admitted)"),
     Series("pstpu:kv_shared_tier_hits_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "kv-economy"),
+           (ENGINE,), ("catalogue", "kv-economy"),
            "KV blocks served by the shared host/remote tiers during "
            "prefill restores"),
     Series("pstpu:kv_shared_tier_misses_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "kv-economy"),
+           (ENGINE,), ("catalogue", "kv-economy"),
            "Restore-candidate KV blocks the shared tiers did not hold"),
     Series("pstpu:kv_chain_evictions_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "kv-economy"),
+           (ENGINE,), ("catalogue", "kv-economy"),
            "Leaf-first chain evictions in the local host KV tier"),
     # --------------------------------------------- engine: multichip
     Series("pstpu:mesh_tp_size", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "multichip"),
+           (ENGINE,), ("catalogue", "multichip"),
            "Tensor-parallel degree of the serving mesh"),
     Series("pstpu:mesh_sp_size", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "multichip"),
+           (ENGINE,), ("catalogue", "multichip"),
            "Sequence-parallel degree of the serving mesh"),
     Series("pstpu:mesh_devices", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "multichip"),
+           (ENGINE,), ("catalogue", "multichip"),
            "Devices the serving mesh occupies (dp x sp x tp)"),
     Series("pstpu:hbm_kv_bytes", "gauge", ("model_name", "device"),
-           _BOTH_ENGINE, ("catalogue", "multichip"),
+           (ENGINE,), ("catalogue", "multichip"),
            "KV-pool bytes resident per mesh device (payload + scale "
            "sidecars; kv-head-sharded at tp>1)"),
     # --------------------------------------------- engine: speculative
     Series("pstpu:spec_enabled", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Speculative decoding active (--speculative-num-tokens > 0)"),
     Series("pstpu:spec_draft_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Draft-model token proposals made inside fused decode "
            "dispatches"),
     Series("pstpu:spec_accepted_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Draft proposals that survived target verification (bonus "
            "tokens not counted)"),
     Series("pstpu:spec_acceptance_rate", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Lifetime fraction of draft proposals accepted by the target"),
     Series("pstpu:spec_acceptance_rate_window", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Draft acceptance over the last <=64 dispatch fetches "
            "(windowed companion to the lifetime rate)"),
     Series("pstpu:spec_draft_depth", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Mean served draft depth per live verify cycle (adaptive "
            "gamma controller)"),
     Series("pstpu:spec_tree_nodes_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Token-tree nodes verified (tree speculation)"),
     Series("pstpu:spec_acceptance_ema", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Mean per-sequence acceptance EMA over live sequences "
            "(adaptive controller)"),
     Series("pstpu:spec_gamma0_dispatches_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "speculative"),
+           (ENGINE,), ("catalogue", "speculative"),
            "Decode dispatches the adaptive controller degraded to the "
            "plain (non-speculative) scan"),
     # --------------------------------------------- engine: elastic fast-start
     Series("pstpu:startup_weight_load_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Seconds loading model weights at startup (overlaps compile "
            "with overlap_weight_load)"),
     Series("pstpu:startup_compile_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Seconds in the AOT compile-only warmup prepass (overlapped "
            "with the weight load)"),
     Series("pstpu:startup_warmup_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Seconds executing warmup shape families before serving"),
     Series("pstpu:startup_prewarm_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Seconds serving POST /prewarm hot-chain pulls from the shared "
            "KV tier"),
     Series("pstpu:startup_total_seconds", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Engine construction to ready-to-serve, seconds"),
     Series("pstpu:startup_cache_hit_families", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Warmup variants loaded from the persistent compile cache "
            "(no recompile)"),
     Series("pstpu:startup_cache_miss_families", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "elastic"),
+           (ENGINE,), ("catalogue", "elastic"),
            "Warmup variants that compiled from scratch (cold cache or "
            "changed config)"),
     # ------------------------------------------ engine: request lifecycle
     # (docs/OBSERVABILITY.md): per-phase latency split — where a request's
     # TTFT went — plus tracing exporter hygiene.
     Series("pstpu:queue_wait_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "lifecycle"),
+           (ENGINE,), ("catalogue", "lifecycle"),
            "Arrival to first dispatch issue per request (queue wait)"),
     Series("pstpu:prefill_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "lifecycle"),
+           (ENGINE,), ("catalogue", "lifecycle"),
            "First prefill issue to final prefill chunk fetch per request"),
     Series("pstpu:decode_train_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "lifecycle"),
+           (ENGINE,), ("catalogue", "lifecycle"),
            "Issue-to-fetch duration of each fused decode dispatch (train)"),
     Series("pstpu:restore_round_trip_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "lifecycle"),
+           (ENGINE,), ("catalogue", "lifecycle"),
            "Duration of each shared-tier I/M restore round trip that "
            "restored KV blocks"),
     Series("pstpu:trace_spans_dropped_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "lifecycle"),
+           (ENGINE,), ("catalogue", "lifecycle"),
            "OTLP spans dropped because the exporter queue was full"),
     # --------------------------------------------- engine: mid-stream resume
     Series("pstpu:resume_restored_tokens_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "resume"),
+           (ENGINE,), ("catalogue", "resume"),
            "Prompt+resume tokens served from the prefix cache or KV tiers "
            "on mid-stream resume requests instead of recomputed"),
     Series("pstpu:disagg_role", "gauge", ("model_name", "role"),
-           _BOTH_ENGINE, ("catalogue", "disagg"),
+           (ENGINE,), ("catalogue", "disagg"),
            "Engine disaggregation role (1 = active)"),
     Series("pstpu:kv_handoffs_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "disagg"),
+           (ENGINE,), ("catalogue", "disagg"),
            "Completed KV handoff transfers (published or consumed)"),
     Series("pstpu:kv_handoff_bytes_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "disagg"),
+           (ENGINE,), ("catalogue", "disagg"),
            "Bytes moved through the KV handoff plane"),
     Series("pstpu:kv_handoff_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "disagg"),
+           (ENGINE,), ("catalogue", "disagg"),
            "Seconds serializing/publishing/consuming KV handoffs"),
     Series("pstpu:kv_handoff_failures_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "disagg"),
+           (ENGINE,), ("catalogue", "disagg"),
            "Failed KV handoff transfers"),
     # --------------------------------------------- router: vllm re-exports
     Series("vllm:num_requests_running", "gauge", ("model_name",),
@@ -378,23 +371,23 @@ REGISTRY: Tuple[Series, ...] = (
     # the same arithmetic bench.py's JSON line uses (shared
     # production_stack_tpu/perf/roofline.py).
     Series("pstpu:live_tok_per_s", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "fleet-perf"),
+           (ENGINE,), ("catalogue", "fleet-perf"),
            "Generation throughput over the rolling dispatch window"),
     Series("pstpu:live_hbm_bw_pct", "gauge", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "fleet-perf"),
+           (ENGINE,), ("catalogue", "fleet-perf"),
            "Achieved fraction (percent) of the decode HBM roofline for "
            "the current batch shape"),
     Series("pstpu:live_effective_tokens_per_target_step", "gauge",
-           ("model_name",), _BOTH_ENGINE, ("catalogue", "fleet-perf"),
+           ("model_name",), (ENGINE,), ("catalogue", "fleet-perf"),
            "Tokens emitted per target-model step over the rolling window "
            "(the Leviathan'23 amortization factor; >1 only when "
            "speculation pays)"),
     Series("pstpu:host_stall_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "fleet-perf"),
+           (ENGINE,), ("catalogue", "fleet-perf"),
            "Fetch-done to next issue-start gap with nothing outstanding "
            "on device (host scheduling stall, compile time excluded)"),
     Series("pstpu:dispatch_duration_seconds", "histogram",
-           ("model_name", "train"), _BOTH_ENGINE,
+           ("model_name", "train"), (ENGINE,),
            ("catalogue", "fleet-perf"),
            "Issue-to-fetch duration of each dispatch by train kind "
            "(prefill | decode | decode_spec)"),
@@ -402,66 +395,66 @@ REGISTRY: Tuple[Series, ...] = (
     # The six phases tile the engine loop's wall time (their deltas over a
     # window sum to the window); the decode counts are taken at apply.
     Series("pstpu:loop_schedule_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds in `scheduler.schedule()` (span "
            "`pstpu.schedule`)"),
     Series("pstpu:loop_issue_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds issuing dispatches: `execute_async` in "
            "the executor, `advance_at_issue`, issue records (span "
            "`pstpu.issue`)"),
     Series("pstpu:loop_fetch_wait_seconds_total", "counter",
-           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds awaiting a dispatch's fetch: the host "
            "blocked on the device (span `pstpu.fetch`)"),
     Series("pstpu:loop_apply_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds applying fetched results: fetch records, "
            "`apply_results`, output processing, handoff publishes (span "
            "`pstpu.apply`)"),
     Series("pstpu:loop_idle_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds with nothing schedulable: waiting for "
            "work or retrying (span `pstpu.idle`)"),
     Series("pstpu:loop_other_seconds_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Engine-loop seconds in aborts, restores, prewarms and the "
            "yield after an apply (span `pstpu.housekeeping`)"),
     Series("pstpu:decode_steps_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Decode-loop steps the device ran, over applied decode "
            "dispatches (the while loop stops at the largest per-row "
            "budget; draft/verify cycles allowed under speculation)"),
     Series("pstpu:decode_row_steps_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Real rows times the steps their decode dispatch ran (padding "
            "rows of the shape bucket are not rows)"),
     Series("pstpu:decode_row_steps_wasted_total", "counter",
-           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Decode row-steps whose token was not delivered: the row hit "
            "EOS / max_tokens / a stop string earlier in the train, was "
            "aborted or preempted, or its fetch failed; row-steps less "
            "wasted is the tokens decode delivered"),
     Series("pstpu:sample_dispatches_total", "counter", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "Prefill and decode dispatches issued (each runs the sampler "
            "once a step), counted at issue"),
     Series("pstpu:sample_dispatches_greedy_total", "counter",
-           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Dispatches whose every row is greedy (`temperature <= 0`): the "
            "sampler runs one argmax, no Gumbel field and no candidate "
            "search"),
     Series("pstpu:sample_dispatches_filtered_total", "counter",
-           ("model_name",), _BOTH_ENGINE, ("catalogue", "loop"),
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Dispatches in which a sampled row has `top_k` or `top_p`: the "
            "sampler runs its top-128 candidate search; total less greedy "
            "less filtered ran the Gumbel pick alone"),
     Series("pstpu:http_ingress_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "HTTP handler entry to the request's enqueue in the scheduler "
            "(body parse, chat template, tokenisation)"),
     Series("pstpu:first_chunk_emit_seconds", "histogram", ("model_name",),
-           _BOTH_ENGINE, ("catalogue", "loop"),
+           (ENGINE,), ("catalogue", "loop"),
            "First token appended in the engine loop to the first chunk "
            "handed to the transport (the whole body when not streaming, "
            "which then contains the decode)"),

@@ -1,20 +1,19 @@
 """PL004 metrics-drift: renderers, registry, and docs must agree.
 
-The stack has two parallel engine /metrics renderers (the hand-rolled text
-renderer in server/metrics.py the API server serves, and the
-prometheus_client Collector in engine/metrics.py) plus the router's own
-registry. A series added to one renderer but not the other, a label set
-that differs between them, a name outside the ``pstpu:``/``router_``/
-``vllm:`` convention, a duplicate declaration, or a series missing from the
-docs tables is exactly the silent drift the dashboards then chart wrong —
-or chart nothing.
+The engine's /metrics is rendered by server/metrics.py (the histogram names
+it renders live in engine/metrics.py) and the router's by its own
+prometheus_client registry. A series a renderer emits that the registry
+lacks, a registered series no renderer emits, a label set that differs from
+the registry's, a name outside the ``pstpu:``/``router_``/``vllm:``
+convention, a duplicate declaration, or a series missing from the docs
+tables is exactly the silent drift the dashboards then chart wrong — or
+chart nothing.
 
 Checks, all against tools/pstpu_lint/metrics_registry.py:
   1. every statically-extracted series name uses an allowed prefix;
   2. no series is declared twice on one surface;
   3. each surface's extracted name set == the registry's set for it;
-  4. extracted label sets match the registry (and the two engine surfaces
-     carry identical label sets for shared series);
+  4. extracted label sets match the registry;
   5. the generated docs tables (gen_docs markers) are up to date.
 """
 
@@ -113,58 +112,6 @@ def extract_engine_text(server_src: str,
     return out
 
 
-def extract_engine_collector(engine_src: str) -> Extracted:
-    """Series of the prometheus_client Collector: gauge()/counter() helper
-    calls plus explicit *MetricFamily constructions with constant names."""
-    out: Extracted = {}
-    dupes: List[Tuple[str, int]] = []
-    tree = ast.parse(engine_src)
-    default_labels: Optional[Tuple[str, ...]] = None
-    for node in ast.walk(tree):
-        if (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == "labels"):
-            lst = _const_str_list(node.value)
-            if lst is not None:
-                default_labels = lst
-
-    def _add(name, kind, labels, line):
-        if name in out:
-            dupes.append((name, line))
-        else:
-            out[name] = (kind, labels, line, None)
-
-    for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
-            continue
-        fn = node.func
-        if isinstance(fn, ast.Name) and fn.id in ("gauge", "counter",
-                                                  "histogram"):
-            name = _const_str(node.args[0]) if node.args else None
-            if name:
-                kind = fn.id
-                _add(name, kind, default_labels, node.lineno)
-        elif isinstance(fn, ast.Name) and fn.id in (
-            "GaugeMetricFamily", "CounterMetricFamily",
-            "HistogramMetricFamily",
-        ):
-            name = _const_str(node.args[0]) if node.args else None
-            if not name:
-                continue
-            kind = ("gauge" if fn.id.startswith("Gauge")
-                    else "counter" if fn.id.startswith("Counter")
-                    else "histogram")
-            if kind == "counter" and not name.endswith("_total"):
-                name += "_total"   # prometheus_client appends _total
-            labels = None
-            for kw in node.keywords:
-                if kw.arg == "labels":
-                    labels = _const_str_list(kw.value)
-            _add(name, kind, labels, node.lineno)
-    out["__duplicates__"] = dupes  # type: ignore[assignment]
-    return out
-
-
 def extract_router(router_src: str) -> Extracted:
     """Series of the router's prometheus_client module registry."""
     out: Extracted = {}
@@ -240,8 +187,7 @@ def _check_surface(
             findings.append(Finding(
                 "PL004", where, line,
                 f"series {name!r} label set {tuple(labels)!r} does not "
-                f"match the registry ({tuple(want)!r}); the parallel "
-                f"renderers must agree",
+                f"match the registry ({tuple(want)!r})",
             ))
     for name, entry in expected.items():
         if name not in extracted:
@@ -270,20 +216,12 @@ def check_metrics(
     router_src = _read(ROUTER_METRICS)
 
     findings += _check_surface(
-        reg.ENGINE_TEXT, extract_engine_text(server_src, engine_src),
+        reg.ENGINE, extract_engine_text(server_src, engine_src),
         SERVER_METRICS, registry,
-    )
-    findings += _check_surface(
-        reg.ENGINE_COLLECTOR, extract_engine_collector(engine_src),
-        ENGINE_METRICS, registry,
     )
     findings += _check_surface(
         reg.ROUTER, extract_router(router_src), ROUTER_METRICS, registry,
     )
-
-    # Label agreement between the two engine renderers is structural: one
-    # registry entry carries one label set for both surfaces, and each
-    # surface was checked against it above.
 
     if docs_check:
         from tools.pstpu_lint import gen_docs
