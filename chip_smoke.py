@@ -23,6 +23,10 @@ code 0 only with ``"ok": true`` — which needs every phase to pass on a TPU,
 compiled (not interpreted) kernels, a compile cache, zero warmup faults, and
 dispatch programs that update the KV pools in place (``pool_programs`` in
 each serving line: no whole-pool ``copy``; their temporaries are printed).
+The kernel phase also times the paged decode kernel alone at the benchmark's
+two decode shapes and prints, under ``timing``, µs a call, the least time the
+chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
+their ratio, the kernel's own roofline share. It is read by no metric.
 
 This process never imports JAX: a chip belongs to one process at a time and
 the engine children need it (the kernel phase runs in a child of its own).
@@ -333,6 +337,78 @@ def phase_kernel(model: str, rehearse: bool) -> dict:
     return line
 
 
+# The benchmark's two decode shapes (BENCHMARK.json's cells), Dh 128 and
+# block 16 in both: cell 3's 32-row bucket with 20 live chat contexts, and
+# cell 2's one row behind a 6144-token system prompt.
+KERNEL_TIMING_SHAPES = [
+    {"name": "chat-saturated", "rows": 32, "live": 20, "lens": (96, 2600),
+     "heads": 16, "kv_heads": 2},
+    {"name": "agent-prefix", "rows": 1, "live": 1, "lens": (6300, 6300),
+     "heads": 32, "kv_heads": 8},
+]
+KERNEL_TIMING_CALLS = 256
+
+
+def kernel_timing_case(shape, dh=128, bs=16, layers=2, seed=0):
+    """Operands of one kernel call at ``shape``: scattered pages, live rows
+    first, then the bucket's padding (``kv_len`` 0, table entries 0)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    b, live = shape["rows"], shape["live"]
+    lo, hi = shape["lens"]
+    # Chat contexts: a 64-token system prompt, a log-normal prompt (median
+    # 256) and part of an answer, as the chat traffic file draws them.
+    lens = np.clip(64 + rng.lognormal(np.log(256), 0.8, live)
+                   + rng.uniform(0, 300, live), lo, hi).astype(np.int32)
+    lens = np.concatenate([lens, np.zeros(b - live, np.int32)])
+    mb = -(-int(lens.max()) // bs)
+    bt = np.zeros((b, mb), np.int32)
+    order = 1 + rng.permutation(b * mb).reshape(b, mb)
+    for i, n in enumerate(lens):
+        bt[i, :-(-n // bs)] = order[i, :-(-n // bs)]
+    hkv = shape["kv_heads"]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = (layers, hkv, (1 + b * mb) * bs, dh)
+    return {
+        "q": jax.random.normal(kq, (b, shape["heads"], dh), jnp.bfloat16),
+        "k_pool": jax.random.normal(kk, pool, jnp.bfloat16),
+        "v_pool": jax.random.normal(kv, pool, jnp.bfloat16),
+        "tables": jnp.asarray(bt), "kv_lens": jnp.asarray(lens),
+        "block_size": bs,
+        # What the call must read: K and V of every live token, bf16.
+        "kv_bytes": int(lens.sum()) * hkv * dh * 2 * 2,
+    }
+
+
+def time_kernel(kernel, case, calls, repeats=5, **kernel_kwargs):
+    """Seconds per call of ``kernel`` alone: ``calls`` calls chained through
+    the query inside one program (so none overlaps the next and the
+    dispatch is paid once), the best of ``repeats`` on the host's clock."""
+    import jax
+
+    def chain(q, k_pool, v_pool, tables, kv_lens):
+        def one(i, q):
+            out, _, _ = kernel(
+                q, k_pool, v_pool, tables, kv_lens, i % k_pool.shape[0],
+                block_size=case["block_size"], **kernel_kwargs,
+            )
+            return out
+        return jax.lax.fori_loop(0, calls, one, q)
+
+    run = jax.jit(chain)
+    args = [case[k] for k in ("q", "k_pool", "v_pool", "tables", "kv_lens")]
+    run(*args).block_until_ready()
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        run(*args).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best / calls
+
+
 def kernel_child(model: str, rehearse: bool) -> int:
     """Runs in the child: the only code of this file that imports JAX."""
     import jax
@@ -401,11 +477,39 @@ def kernel_child(model: str, rehearse: bool) -> int:
             "ok": finite and err <= KERNEL_MAX_ABS_ERR
             and out.shape == (b, h, dh),
         })
+    # The kernel alone at the benchmark's shapes, against the least time the
+    # chip's memory allows its bytes: the kernel's own roofline share. A
+    # rehearsal walks the same code at a toy size and reports no time.
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peaks = json.load(f)["by_device_kind"]
+    hbm_gbps = peaks.get(dev.device_kind, {}).get("hbm_gbps")
+    timing = []
+    for shape in KERNEL_TIMING_SHAPES:
+        if rehearse:
+            shape = {**shape, "rows": min(shape["rows"], 3),
+                     "live": min(shape["live"], 2), "lens": (17, 600)}
+        case = kernel_timing_case(shape)
+        sec = time_kernel(
+            paged_flash_decode_stats, case,
+            2 if rehearse else KERNEL_TIMING_CALLS, interpret=interpret,
+        )
+        entry = {"shape": shape["name"], "rows": shape["rows"],
+                 "live_rows": shape["live"],
+                 "kv_tokens": int(case["kv_lens"].sum()),
+                 "kv_bytes": case["kv_bytes"],
+                 "us_per_call": None, "bytes_least_us": None,
+                 "roofline_pct": None}
+        if not rehearse and hbm_gbps:
+            least = case["kv_bytes"] / (hbm_gbps * 1e9)
+            entry.update(us_per_call=sec * 1e6, bytes_least_us=least * 1e6,
+                         roofline_pct=100.0 * least / sec)
+        timing.append(entry)
     emit({
         "phase": "kernel", "model": model,
         "widths": {"heads": h, "kv_heads": hkv, "head_dim": dh,
                    "block_size": bs, "kv_lens": lens},
-        "interpret": interpret, "cases": cases, "device": device,
+        "interpret": interpret, "cases": cases, "timing": timing,
+        "hbm_gbps": hbm_gbps, "device": device,
         "ok": all(c["ok"] for c in cases),
     })
     return 0

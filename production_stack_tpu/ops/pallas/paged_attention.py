@@ -15,7 +15,27 @@ reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
   * Pages are grouped into SUPERPAGES of 512 tokens: one compute iteration
     covers 512 keys (an MXU-friendly tile), while the underlying DMAs stay
     page-granular (pages are scattered in the pool). Two superpage buffers
-    double-buffer fetch against compute.
+    double-buffer fetch against compute ACROSS the whole call: its
+    superpages form one sequence, row after row, and while superpage n is
+    computed, superpage n + 1 is in flight into the other buffer, be it the
+    same row's next one or the NEXT ROW's first. So a short row (one
+    superpage: most chat contexts) does not start by waiting for a fetch it
+    has just issued; only the call's first live row, and a row behind an
+    empty one, do. Buffers, semaphores and the count of superpages fetched
+    so far are scratch, which outlives a program; the grid axis is
+    sequential ("arbitrary"), since the hand-over depends on program order.
+  * What a short row costs is scalar work, not bytes (a DMA start is ~23 ns
+    on a v5e, a branch about as much; PERF.md §6, PR 30): the fetch loops run
+    over the row's own pages, a buffer's page copies share one byte-counting
+    semaphore that is waited on in a few power-of-two runs, q and the
+    outputs are resident blocks (one copy a call, none a program), and a
+    row with ``kv_len == 0`` (bucket padding) issues, waits for and computes
+    nothing.
+  * Never-fetched tails are NOT zero-filled per row. What must hold is that
+    a masked key's softmax weight (0) never meets a non-finite VALUE
+    (0 * NaN = NaN in the PV contraction): V's buffers are cleared once a
+    call, and later tails hold an earlier superpage's KV, finite like the
+    pool (tests/test_pallas_kernel.py poisons the pool and the scratch).
   * Small head dims pack PACK = 128 // Dh consecutive tokens into one
     128-lane row (the pool is viewed as [L, Hkv, num_slots/PACK, 128], which
     keeps every DMA slice 128-lane aligned), and the compute splits each row
@@ -47,6 +67,8 @@ SUPER_TOKENS = 512   # keys per compute iteration (amortizes the per-iteration
                      # flash-state relayout overhead; VMEM cost is
                      # 2 bufs * 2 pools * Hkv * 512/PACK * 128 * 2B)
 NUM_BUFS = 2         # superpage double buffering
+ISSUE_UNROLL = 2     # pages a fetch-loop iteration issues (of 1, 2, 4 and 8
+                     # the fastest on a v5e at both benchmark shapes, PERF.md)
 LANES = 128          # minor-dim tiling the DMA slices must respect
 
 
@@ -60,7 +82,7 @@ def _decode_kernel(
     block_tables_ref,   # SMEM [B, Mb] int32
     kv_lens_ref,        # SMEM [B] int32
     # inputs
-    q_ref,              # VMEM [1, H, Dh]
+    q_ref,              # VMEM [B, H, Dh] (resident: fetched once a call)
     k_hbm,              # HBM  [L, Hkv, num_slots/PACK, Dh*PACK]
     v_hbm,              # HBM  [L, Hkv, num_slots/PACK, Dh*PACK]
     # quantized==True only (int8 pools): this dispatch's pre-gathered
@@ -76,15 +98,20 @@ def _decode_kernel(
 ):
     if quantized:
         (k_sc_ref, v_sc_ref, o_ref, m_ref, l_ref,
-         k_buf, v_buf, sem_k, sem_v) = rest
+         k_buf, v_buf, sem_k, sem_v, fetched_ref) = rest
     else:
         k_sc_ref = v_sc_ref = None
-        o_ref, m_ref, l_ref, k_buf, v_buf, sem_k, sem_v = rest
-    # o_ref: VMEM [1, H, Dh]; m_ref/l_ref: VMEM [1, 1, H] f32 (running max
-    # pre-normalization / softmax denominator); k_buf/v_buf: VMEM
+        o_ref, m_ref, l_ref, k_buf, v_buf, sem_k, sem_v, fetched_ref = rest
+    # o_ref: VMEM [B, H, Dh]; m_ref/l_ref: VMEM [B, 1, H] f32 (running max
+    # pre-normalization / softmax denominator), resident like q and written
+    # back once a call: a program moves no block of its own, which is most
+    # of what an empty row used to cost; k_buf/v_buf: VMEM
     # [NUM_BUFS, Hkv, SUPER_TOKENS/PACK, Dh*PACK] pool-dtype scratch;
-    # sem_k/sem_v: DMA sems (NUM_BUFS, pages_per_super).
+    # sem_k/sem_v: DMA sems (NUM_BUFS,), one a buffer; fetched_ref: SMEM
+    # [1] int32, superpages the rows before this one fetched. Scratch
+    # outlives a program, which is what hands buffers from row to row.
     b = pl.program_id(0)
+    num_rows = kv_lens_ref.shape[0]
     layer = layer_ref[0]
     bs = block_size
     spp = SUPER_TOKENS // bs            # pages per superpage
@@ -93,73 +120,115 @@ def _decode_kernel(
     pack = _pack(dh)
     bsp = bs // pack                    # packed rows per page
     stp = SUPER_TOKENS // pack          # packed rows per superpage
+    gp = min(ISSUE_UNROLL, spp)         # pages per fetch-loop iteration
     kv_len = kv_lens_ref[b]
-    n_pages = pl.cdiv(kv_len, bs)
     n_super = pl.cdiv(kv_len, SUPER_TOKENS)
+    # The call's superpages form ONE sequence across rows, and superpage n
+    # of it lives in buffer n % NUM_BUFS: a row starts in the buffer its
+    # predecessors left free, whatever their lengths were.
+    first = jnp.where(b == 0, 0, fetched_ref[0])
+    fetched_ref[0] = first + n_super
 
     # q: [H, Dh] -> [Hkv, G, Dh] fp32, pre-scaled
-    q = q_ref[0].astype(jnp.float32).reshape(hkv, g, dh) * scale
+    q = q_ref[b].astype(jnp.float32).reshape(hkv, g, dh) * scale
 
-    def start_fetch(s, slot):
-        # Issue page-granular DMAs for superpage s (pages are scattered in
-        # the pool; each is contiguous). Static unroll keeps them all in
-        # flight at once.
-        for i in range(spp):
-            page = s * spp + i
+    def pages_of(row, s):
+        # Pages of ``row`` that superpage s holds.
+        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
 
-            @pl.when(page < n_pages)
-            def _():
-                blk = block_tables_ref[b, page]
-                start = blk * bsp
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, :, pl.ds(start, bsp)],
-                    k_buf.at[slot, :, pl.ds(i * bsp, bsp)],
-                    sem_k.at[slot, i],
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, :, pl.ds(start, bsp)],
-                    v_buf.at[slot, :, pl.ds(i * bsp, bsp)],
-                    sem_v.at[slot, i],
-                ).start()
+    def start_fetch(row, s, n):
+        # Issue page-granular DMAs for superpage s of ``row``, the n-th of
+        # the call (pages are scattered in the pool; each is contiguous),
+        # all in flight at once. The loops run over the row's own pages and
+        # no further (``gp`` an iteration, then the odd ones): a short row
+        # pays for what it holds, and the kernel's code stays a few pages
+        # long where an unrolled superpage was 32 branches.
+        slot = jax.lax.rem(n, NUM_BUFS)
+        pages = pages_of(row, s)
 
-            @pl.when(page >= n_pages)
-            def _():
-                # Never-fetched tail pages must not hold NaN/Inf garbage:
-                # masked softmax weights are 0, but 0 * NaN = NaN inside the
-                # PV contraction would still poison the row.
-                k_buf[slot, :, pl.ds(i * bsp, bsp)] = jnp.zeros(
-                    (k_buf.shape[1], bsp, k_buf.shape[3]), k_buf.dtype
-                )
-                v_buf[slot, :, pl.ds(i * bsp, bsp)] = jnp.zeros(
-                    (v_buf.shape[1], bsp, v_buf.shape[3]), v_buf.dtype
-                )
+        def issue(i):
+            src = pl.ds(block_tables_ref[row, s * spp + i] * bsp, bsp)
+            dst = pl.ds(pl.multiple_of(i * bsp, bsp), bsp)
+            pltpu.make_async_copy(
+                k_hbm.at[layer, :, src], k_buf.at[slot, :, dst],
+                sem_k.at[slot],
+            ).start()
+            pltpu.make_async_copy(
+                v_hbm.at[layer, :, src], v_buf.at[slot, :, dst],
+                sem_v.at[slot],
+            ).start()
+
+        def issue_group(gi, carry):
+            for j in range(gp):
+                issue(gi * gp + j)
+            return carry
+
+        def issue_page(i, carry):
+            issue(i)
+            return carry
+
+        jax.lax.fori_loop(0, pages // gp, issue_group, 0)
+        jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
 
     def wait_fetch(s, slot):
-        for i in range(spp):
-            page = s * spp + i
-
-            @pl.when(page < n_pages)
+        # A DMA semaphore counts bytes, and a buffer's page copies all signal
+        # the one semaphore of that buffer. So the wait is for the BYTES of
+        # this superpage's pages, taken in power-of-two runs of pages (the
+        # run lengths present in the page count's binary form): at most two
+        # waits a run, whatever order the pages land in.
+        pages = pages_of(b, s)
+        run = spp
+        while run:
+            @pl.when(pages & run != 0)
             def _():
+                span = pl.ds(0, run * bsp)
                 pltpu.make_async_copy(
-                    k_hbm.at[0, :, pl.ds(0, bsp)],
-                    k_buf.at[slot, :, pl.ds(i * bsp, bsp)],
-                    sem_k.at[slot, i],
+                    k_hbm.at[0, :, span], k_buf.at[slot, :, span],
+                    sem_k.at[slot],
                 ).wait()
                 pltpu.make_async_copy(
-                    v_hbm.at[0, :, pl.ds(0, bsp)],
-                    v_buf.at[slot, :, pl.ds(i * bsp, bsp)],
-                    sem_v.at[slot, i],
+                    v_hbm.at[0, :, span], v_buf.at[slot, :, span],
+                    sem_v.at[slot],
                 ).wait()
+            run //= 2
 
-    start_fetch(0, 0)
+    # What a row does not fetch it still computes over: whole superpages,
+    # and a masked key's softmax weight (0) must not meet a non-finite value
+    # there: 0 * NaN = NaN inside the PV contraction would poison the row.
+    # (A masked SCORE is replaced, not multiplied, so K may hold anything.)
+    # Only what the call finds in V can be non-finite; what its rows leave
+    # behind is KV, finite like the pool. So V is cleared once a call,
+    # before the first DMA is in flight, and stale keys stay where they are.
+    @pl.when(b == 0)
+    def _():
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    # A live row's last iteration issues the next row's first superpage, so
+    # only the call's first row, and a row behind an empty one, starts by
+    # issuing its own (and then waits for it at once).
+    prev_len = kv_lens_ref[jnp.maximum(b - 1, 0)]
+
+    @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
+    def _():
+        start_fetch(b, 0, first)
 
     def body(s, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(s, NUM_BUFS)
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
 
-        @pl.when(s + 1 < n_super)
+        # What computes next goes in flight now, into the other buffer:
+        # this row's superpage s + 1 or, behind its last, superpage 0 of
+        # the next row (an empty next row has no pages, so nothing issues).
+        last = s + 1 == n_super
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
         def _():
-            start_fetch(s + 1, jax.lax.rem(s + 1, NUM_BUFS))
+            start_fetch(
+                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
+                jnp.where(last, 0, s + 1),
+                n + 1,
+            )
 
         wait_fetch(s, slot)
 
@@ -220,9 +289,9 @@ def _decode_kernel(
     m, l, acc = jax.lax.fori_loop(0, n_super, body, (m0, l0, acc0))
 
     out = acc / jnp.maximum(l, 1e-30)
-    o_ref[0] = out.reshape(hkv * g, dh).astype(o_ref.dtype)
-    m_ref[0, 0] = m.reshape(hkv * g)
-    l_ref[0, 0] = l.reshape(hkv * g)
+    o_ref[b] = out.reshape(hkv * g, dh).astype(o_ref.dtype)
+    m_ref[b, 0] = m.reshape(hkv * g)
+    l_ref[b, 0] = l.reshape(hkv * g)
 
 
 def supports_pallas_decode(head_dim: int, block_size: int) -> bool:
@@ -256,7 +325,14 @@ def paged_flash_decode_stats(
     Returns (out [B, H, Dh] normalized, m [B, H] f32, l [B, H] f32) so the
     caller can merge with other attention segments (see
     ops/attention.py:merge_attention_segments). Rows with kv_len == 0 return
-    (0, -inf, 0) — a no-op under the merge.
+    (0, -inf, 0) — a no-op under the merge — and cost a grid step: they
+    fetch and compute nothing.
+
+    The pool must be finite wherever a live row's pages reach; blocks no
+    live row owns (block 0 behind padded table entries among them) may hold
+    anything, and so may the VMEM the call finds: never-fetched tails of a
+    superpage are not zero-filled per row, V's buffers are cleared once a
+    call and stale finite KV stays (see the module docstring).
 
     Quantized pools (``k_scale``/``v_scale`` set, int8 payload): the page
     DMAs move int8 — half the bf16 HBM traffic — and dequantization happens
@@ -274,7 +350,6 @@ def paged_flash_decode_stats(
     if scale is None:
         scale = dh ** -0.5
     pack = _pack(dh)
-    spp = SUPER_TOKENS // block_size
     quantized = k_scale is not None
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
 
@@ -321,28 +396,27 @@ def paged_flash_decode_stats(
         block_size=block_size, num_kv_heads=hkv, q_per_kv=g,
         scale=float(scale), quantized=quantized,
     )
+
+    def resident(*shape):
+        # The whole array is one block that every program sees: copied in
+        # (or out) once a call, indexed by the program's row in the kernel.
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec(
-                (1, h, dh), lambda i, *_: (i, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
+            resident(b, h, dh),
             pl.BlockSpec(memory_space=pl.ANY),  # pool stays off-chip;
             pl.BlockSpec(memory_space=pl.ANY),  # kernel DMAs pages itself
             *sc_specs,
         ],
         out_specs=[
-            pl.BlockSpec(
-                (1, h, dh), lambda i, *_: (i, 0, 0), memory_space=pltpu.VMEM,
-            ),
-            # [B, 1, H] so each program's block (1, 1, H) spans the full
-            # trailing dims (Mosaic tiling requirement for small outputs).
-            pl.BlockSpec((1, 1, h), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, h), lambda i, *_: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            resident(b, h, dh),
+            # [B, 1, H]: a program writes row b as a whole [1, H] tile.
+            resident(b, 1, h),
+            resident(b, 1, h),
         ],
         scratch_shapes=[
             pltpu.VMEM(
@@ -353,8 +427,9 @@ def paged_flash_decode_stats(
                 (NUM_BUFS, hkv, SUPER_TOKENS // pack, dh * pack),
                 v_pool.dtype,
             ),
-            pltpu.SemaphoreType.DMA((NUM_BUFS, spp)),
-            pltpu.SemaphoreType.DMA((NUM_BUFS, spp)),
+            pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+            pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out, m, l = pl.pallas_call(
@@ -365,6 +440,10 @@ def paged_flash_decode_stats(
             jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
         ],
         grid_spec=grid_spec,
+        # Rows run in program order: each hands its buffers to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
     )(
         layer,

@@ -140,6 +140,47 @@ def test_main_exits_nonzero_on_a_cpu_engine_report(monkeypatch, capsys):
     assert last["ok"] is False
 
 
+@pytest.mark.parametrize("shape", chip_smoke.KERNEL_TIMING_SHAPES,
+                         ids=lambda s: s["name"])
+def test_kernel_timing_case_is_a_decode_bucket(shape):
+    """The kernel phase times the benchmark's decode shapes: live rows
+    first, then the bucket's padding as the runner packs it (``kv_len`` 0,
+    table entries 0), every live page a block of its own, and the bytes
+    counted are K and V of the live tokens."""
+    import numpy as np
+
+    case = chip_smoke.kernel_timing_case(shape, layers=1)
+    lens, bt = np.asarray(case["kv_lens"]), np.asarray(case["tables"])
+    live, bs = shape["live"], case["block_size"]
+    assert lens.shape == (shape["rows"],) and bt.shape[0] == shape["rows"]
+    assert np.all(lens[:live] >= shape["lens"][0])
+    assert np.all(lens[:live] <= shape["lens"][1])
+    assert np.all(lens[live:] == 0) and np.all(bt[live:] == 0)
+    pages = -(-lens // bs)
+    owned = np.concatenate([bt[i, :n] for i, n in enumerate(pages)])
+    assert owned.min() >= 1 and len(set(owned.tolist())) == owned.size
+    assert all(np.all(bt[i, n:] == 0) for i, n in enumerate(pages))
+    assert case["q"].shape == (shape["rows"], shape["heads"], 128)
+    assert case["k_pool"].shape[1] == shape["kv_heads"]
+    assert case["kv_bytes"] == int(lens.sum()) * shape["kv_heads"] * 128 * 4
+
+
+def test_time_kernel_chains_calls_through_the_query():
+    """``calls`` kernel calls inside one program, each fed the last one's
+    output: a time per call comes back, and the kernel ran (interpreted
+    here; a rehearsal's time is never reported as the chip's)."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_stats,
+    )
+
+    shape = {"name": "toy", "rows": 3, "live": 2, "lens": (17, 40),
+             "heads": 4, "kv_heads": 2}
+    case = chip_smoke.kernel_timing_case(shape)
+    sec = chip_smoke.time_kernel(paged_flash_decode_stats, case, calls=2,
+                                 repeats=1, interpret=True)
+    assert 0 < sec < 60
+
+
 def _run_script(cwd, *argv, timeout=900):
     return subprocess.run(
         [sys.executable, os.path.join(cwd, "chip_smoke.py"), *argv],
