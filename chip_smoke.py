@@ -515,6 +515,132 @@ def kernel_child(model: str, rehearse: bool) -> int:
     return 0
 
 
+# The recurrence of a Gated DeltaNet layer alone, at Olmo-Hybrid-7B's
+# published widths: one decode step of 20 rows (chat-saturated's mean) and of
+# 64, and one 2048-token prefill chunk of one row.
+GDN_WIDTHS = {"heads": 30, "dk": 96, "dv": 192}
+GDN_STEP_ROWS = (20, 64)
+GDN_CHUNK_TOKENS = 2048
+GDN_CALLS = 64
+# Layers' states a timed step program cycles through, as a decode program
+# carries its rows' 12 linear layers: ONE layer's rows (44 MB at 20 rows)
+# stay on the chip between chained calls and read faster than HBM allows.
+GDN_LAYERS = 12
+
+
+def gdn_child(rehearse: bool) -> int:
+    """``--gdn``: times ``gdn_step`` and ``gdn_chunk`` (ops/gated_delta.py)
+    alone on the chip, calls chained through the state inside one program
+    (a step program through ``GDN_LAYERS`` layers' states in turn), and
+    prints µs a call beside the least time the chip's peaks allow their
+    bytes and FLOPs (benchmarks/chip/lib/shapes_hybrid.py's count of ONE
+    layer). Run by no benchmark cell and no other phase."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.chip.lib import shapes_hybrid
+    from production_stack_tpu.ops import gated_delta as gd
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "gdn", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    widths = dict(GDN_WIDTHS)
+    rows_list, tokens, calls = GDN_STEP_ROWS, GDN_CHUNK_TOKENS, GDN_CALLS
+    layers = GDN_LAYERS
+    if rehearse:
+        widths, rows_list, tokens, calls, layers = \
+            {"heads": 4, "dk": 16, "dv": 32}, (2, 3), 80, 2, 2
+    h, dk, dv = widths["heads"], widths["dk"], widths["dv"]
+    # One linear layer of a config with these widths, for the shapes' count.
+    cfg = {"num_attention_heads": h, "hidden_size": h * 128,
+           "intermediate_size": 1, "vocab_size": 1,
+           "layer_types": ["linear_attention"],
+           "linear_num_value_heads": h, "linear_key_head_dim": dk,
+           "linear_value_head_dim": dv, "linear_conv_kernel_dim": 4}
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+
+    def inputs(key, b, t):
+        ks = jax.random.split(key, 6)
+        q, k = (jax.random.normal(ks[i], (b, t, h, dk)) for i in (0, 1))
+        v = jax.random.normal(ks[2], (b, t, h, dv))
+        beta, g = gd.gates(
+            jax.random.normal(ks[3], (b, t, h)),
+            jax.random.normal(ks[4], (b, t, h)),
+            jnp.zeros((h,)), jnp.ones((h,)), True)
+        state = gd.pack_state(0.1 * jax.random.normal(ks[5], (b, h, dk, dv)))
+        return (state, *gd.prepare(q, k, v), g, beta)
+
+    def best_of(run, args, n, repeats=5):
+        """Seconds a call: the best of ``repeats`` runs of ``n`` chained."""
+        jax.block_until_ready(run(*args))
+        best = float("inf")
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best / n
+
+    def entry(name, sec, work, **more):
+        out = {"op": name, **more, "bytes": work["bytes"],
+               "flops": work["flops"], "us_per_call": None,
+               "least_us": None, "roofline_pct": None}
+        if peak and not rehearse:
+            least = max(work["bytes"] / (peak["hbm_gbps"] * 1e9),
+                        work["flops"] / (peak["bf16_tflops"] * 1e12))
+            out.update(us_per_call=sec * 1e6, least_us=least * 1e6,
+                       roofline_pct=100.0 * least / sec)
+        return out
+
+    timing, finite = [], True
+    for b in rows_list:
+        state, q, k, v, g, beta = inputs(jax.random.PRNGKey(b), b, 1)
+        state = jnp.tile(state[None], (layers, 1, 1, 1, 1))
+        live = jnp.ones((b,), bool)
+
+        @jax.jit
+        def steps(states, q, k, v, g, beta):
+            def one(i, carry):
+                states, acc = carry
+                at = i % layers
+                o, state = gd.gdn_step(
+                    jax.lax.dynamic_index_in_dim(states, at, 0, False),
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live)
+                return jax.lax.dynamic_update_index_in_dim(
+                    states, state, at, 0), acc + o
+            return jax.lax.fori_loop(
+                0, calls * layers, one, (states, jnp.zeros((b, h, dv))))
+
+        sec = best_of(steps, (state, q, k, v, g, beta), calls * layers)
+        finite &= bool(jnp.all(jnp.isfinite(
+            steps(state, q, k, v, g, beta)[1])))
+        timing.append(entry("gdn_step", sec,
+                            shapes_hybrid.gdn_step(cfg, b), rows=b))
+    state, q, k, v, g, beta = inputs(jax.random.PRNGKey(7), 1, tokens)
+    lens = jnp.full((1,), tokens, jnp.int32)
+
+    @jax.jit
+    def chunks(state, q, k, v, g, beta):
+        def one(_, carry):
+            state, acc = carry
+            o, state = gd.gdn_chunk(state, q, k, v, g, beta, lens)
+            return state, acc + o
+        return jax.lax.fori_loop(
+            0, calls, one, (state, jnp.zeros((1, tokens, h, dv))))
+
+    sec = best_of(chunks, (state, q, k, v, g, beta), calls)
+    finite &= bool(jnp.all(jnp.isfinite(
+        chunks(state, q, k, v, g, beta)[1])))
+    timing.append(entry("gdn_chunk", sec,
+                        shapes_hybrid.gdn_chunk(cfg, tokens), tokens=tokens))
+    emit({"phase": "gdn", "widths": widths, "calls": calls,
+          "step_layers": layers, "timing": timing, "peak": peak, "device": device, "ok": finite})
+    return 0 if finite else 1
+
+
 def phase_serve(model, engine_args, attn: str) -> dict:
     """One boot of engine + router and a handful of requests through the
     router."""
@@ -780,6 +906,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU rehearsal at a tiny model; never ok")
     ap.add_argument("--kernel-child", metavar="MODEL", help=argparse.SUPPRESS)
+    ap.add_argument("--gdn", action="store_true",
+                    help="only time the Gated DeltaNet recurrence alone "
+                         "(gdn_step, gdn_chunk) and exit")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, HERE)
@@ -788,6 +917,10 @@ def main(argv=None) -> int:
     )
     if args.kernel_child:
         return kernel_child(args.kernel_child, args.rehearse)
+    if args.gdn:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return gdn_child(args.rehearse)
 
     model, full_depth, engine_args = MODEL, FULL_DEPTH, ENGINE_ARGS
     if args.rehearse:

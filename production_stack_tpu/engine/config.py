@@ -468,9 +468,12 @@ class EngineConfig:
         # with the storage dtype.
         import jax.numpy as jnp
 
+        from production_stack_tpu.models import cache_specs
+
+        kv = cache_specs(model_config).paged_kv
         worst_window_bytes = (
-            2 * model_config.num_layers * model_config.num_kv_heads
-            * model_config.head_dim_ * jnp.dtype(self.dtype).itemsize
+            2 * kv.layers * kv.kv_heads * kv.head_dim
+            * jnp.dtype(self.dtype).itemsize
             * self.max_model_len * self.max_num_seqs
         )
         return "paged" if worst_window_bytes > (4 << 30) else "window"
@@ -484,18 +487,62 @@ class EngineConfig:
         reporting, and the bench roofline's KV term."""
         import jax.numpy as jnp
 
+        from production_stack_tpu.models import cache_specs
         from production_stack_tpu.ops.quantization import SCALE_ITEMSIZE
 
+        # The layers that keep K/V are the model module's to declare.
+        kv = cache_specs(model_config).paged_kv
         if self.kv_cache_quantized:
-            per_slot = model_config.head_dim_ + SCALE_ITEMSIZE
+            per_slot = kv.head_dim + SCALE_ITEMSIZE
         else:
-            per_slot = (
-                model_config.head_dim_ * jnp.dtype(self.dtype).itemsize
-            )
-        return (
-            2 * model_config.num_layers * model_config.num_kv_heads
-            * per_slot
+            per_slot = kv.head_dim * jnp.dtype(self.dtype).itemsize
+        return 2 * kv.layers * kv.kv_heads * per_slot
+
+    def state_bytes_per_seq(self, model_config) -> int:
+        """Bytes of recurrent state one sequence holds whole, over every
+        layer that declares some (0 for a K/V-only model)."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from production_stack_tpu.models import cache_specs
+
+        return sum(
+            s.layers * int(np.prod(s.shape))
+            * jnp.dtype(s.dtype or self.dtype).itemsize
+            for s in cache_specs(model_config).state
         )
+
+    def refuse_what_state_cannot_follow(self, model_config) -> None:
+        """A model that declares recurrent state (models/config.py:
+        CacheSpecs.state) holds, per sequence, something that is not keys
+        and values of tokens: it cannot be cut at a token, copied by block
+        or rolled back yet. Every feature that would need that is refused
+        here, at start and by the declaration, never served wrong."""
+        from production_stack_tpu.models import cache_specs
+
+        if not cache_specs(model_config).state:
+            return
+        why = {
+            "speculative decoding (--speculative-num-tokens)":
+                bool(self.speculative_num_tokens),
+            "KV offload and restore (--kv-offload-cpu / --kv-remote-url)":
+                bool(self.kv_offload_cpu or self.kv_remote_url),
+            "disaggregated prefill (--role prefill|decode)":
+                self.role != "unified",
+            "--kv-cache-dtype int8": self.kv_cache_quantized,
+            "tensor or sequence parallelism (--tensor-parallel-size / "
+            "--sequence-parallel-size > 1)":
+                self.tensor_parallel_size > 1
+                or self.sequence_parallel_size > 1,
+            "LoRA adapters (--lora-modules)": bool(self.lora_modules),
+        }
+        asked = [name for name, on in why.items() if on]
+        if asked:
+            raise ValueError(
+                f"model {self.model!r} keeps recurrent state per sequence, "
+                f"which {'; '.join(asked)} cannot follow yet: the state has "
+                f"no snapshot, block copy, rollback or sharding. Start "
+                f"without it.")
 
     def kv_cache_bytes_per_block(self, model_config) -> int:
         """Pool bytes one KV block occupies (block_size tokens)."""

@@ -94,6 +94,7 @@ class ServingEngine:
         self.prewarmed_blocks_total = 0
         self.config = config
         self.model_config = resolve_model_config(config.model)
+        config.refuse_what_state_cannot_follow(self.model_config)
         self.tokenizer = get_tokenizer(config.model, self.model_config)
         self.mesh = mesh or make_mesh(
             dp=config.data_parallel_size,
@@ -131,6 +132,9 @@ class ServingEngine:
         self.block_manager = BlockPoolManager(
             self.runner.num_kv_blocks, config.block_size,
             config.enable_prefix_caching,
+            # One slot per sequence the scheduler can hold; the runner's
+            # pools have one more, the scratch slot 0.
+            num_state_slots=max(0, self.runner.num_state_slots - 1),
         )
         self.offload = None
         if config.kv_offload_cpu or config.kv_remote_url:
@@ -634,6 +638,11 @@ class ServingEngine:
         unattributed gap between phases — the phase spans must tile the
         request duration."""
         rec = self.recorder
+        # Rows of the dispatch that carry recurrent state through it (every
+        # real row of a model that declares some; absent otherwise).
+        state_rows = {"state_rows": sum(
+            1 for s in batch.seqs if s.state_slot)} \
+            if self.block_manager.num_state_slots else {}
         for idx, seq in enumerate(batch.seqs):
             if seq.first_issue_time is None:
                 seq.first_issue_time = t_mono
@@ -647,12 +656,12 @@ class ServingEngine:
             if batch.kind == "prefill":
                 rec.event(seq.request_id, "prefill_issue", {
                     "step": step, "chunk": batch.chunk_lens[idx],
-                    "start": batch.chunk_starts[idx],
+                    "start": batch.chunk_starts[idx], **state_rows,
                 }, t=t_wall)
             else:
                 data = {
                     "step": step, "rows": len(batch.seqs),
-                    "k": batch.num_steps,
+                    "k": batch.num_steps, **state_rows,
                 }
                 if getattr(batch, "spec_mode", "off") != "off":
                     # Which speculative variant the runner actually
@@ -1410,6 +1419,11 @@ class ServingEngine:
                     r.startup_weight_load_seconds, 3
                 ),
                 "kv_blocks": r.num_kv_blocks,
+                # Per-sequence recurrent state (0 / 0 for a K/V-only
+                # model): slots the block manager hands out, and the
+                # pools' bytes on the device.
+                "state_slots": self.block_manager.num_state_slots,
+                "state_bytes": r.state_pool_bytes,
                 "kv_shard_shape": list(
                     r.kv_k.sharding.shard_shape(r.kv_k.shape)
                 ),
@@ -1465,6 +1479,16 @@ class ServingEngine:
             # KV economy (docs/KV_ECONOMY.md): device prefix-index size +
             # shared-tier restore/eviction telemetry.
             "prefix_index_size": self.block_manager.prefix_index_size,
+            # Recurrent-state slots (all 0 for a K/V-only model) and the
+            # prefix hits a model with state could not be served.
+            "state_slots_total": self.block_manager.num_state_slots,
+            "state_slots_in_use": self.block_manager.state_slots_in_use,
+            "state_slot_allocs_total":
+                self.block_manager.state_slot_allocs_total,
+            "state_slot_waits_total":
+                self.block_manager.state_slot_waits_total,
+            "prefix_hit_tokens_unserved_total":
+                self.block_manager.prefix_hits_unserved_total,
             "kv_restore_saved_tokens_total": self._offload_stat(
                 "restore_saved_tokens_total"
             ),

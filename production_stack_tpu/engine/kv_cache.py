@@ -16,6 +16,15 @@ Design:
   * Copy-on-write is avoided by construction: shared (ref_count > 1 or cached)
     blocks are always FULL; writes only ever target a sequence's private tail
     block.
+  * A model that declares recurrent state (models/config.py:CacheSpecs) has a
+    second thing to hand out: STATE SLOTS, one per sequence, of the runner's
+    state pools (slot 0 is the scratch slot of padded rows and is never
+    handed out). The scheduler takes a sequence's blocks and its slot
+    together and gives both back together. For such a model the prefix index
+    still REGISTERS full blocks but a lookup SERVES no hit: a hit of p tokens
+    needs the state after exactly p tokens, and nothing keeps one yet
+    (ROADMAP R6). ``prefix_hits_unserved_total`` counts the tokens a
+    K/V-only model would have been spared.
 """
 
 import hashlib
@@ -37,9 +46,16 @@ def _block_hash(prev: bytes, tokens: Sequence[int]) -> bytes:
 
 class BlockPoolManager:
     def __init__(self, num_blocks: int, block_size: int,
-                 enable_prefix_caching: bool = True):
+                 enable_prefix_caching: bool = True,
+                 num_state_slots: int = 0):
         assert num_blocks >= 2, "need at least null block + one usable block"
         self.num_blocks = num_blocks
+        # State slots 1..num_state_slots (0 = none: a K/V-only model).
+        self.num_state_slots = num_state_slots
+        self._free_state_slots: List[int] = list(range(num_state_slots, 0, -1))
+        self.state_slot_allocs_total = 0
+        self.state_slot_waits_total = 0
+        self.prefix_hits_unserved_total = 0
         self.block_size = block_size
         self.enable_prefix_caching = enable_prefix_caching
         # Block 0 reserved as null.
@@ -140,6 +156,30 @@ class BlockPoolManager:
                 return entries, True
         return entries, False
 
+    # ------------------------------------------------------------ state slots
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.num_state_slots - len(self._free_state_slots)
+
+    def allocate_state_slot(self) -> int:
+        """A free state slot, or 0 where none is (counted as a wait: the
+        caller puts the admission off). 0 too for a K/V-only model, whose
+        sequences need none."""
+        if not self.num_state_slots:
+            return 0
+        if not self._free_state_slots:
+            self.state_slot_waits_total += 1
+            return 0
+        self.state_slot_allocs_total += 1
+        return self._free_state_slots.pop()
+
+    def free_state_slot(self, slot: int) -> None:
+        """Give a slot back (0: nothing to give). Its content stays: the
+        next sequence's first chunk starts from zeros, not from the slot
+        (engine/runner.py:_prefill_impl)."""
+        if slot:
+            self._free_state_slots.append(slot)
+
     def can_allocate(self, n: int) -> bool:
         return self.num_free_blocks >= n
 
@@ -170,8 +210,12 @@ class BlockPoolManager:
         KV computed under different LoRA adapters must never be shared, so
         each adapter seeds its own chain (Sequence.hash_seed).
         """
-        if not self.enable_prefix_caching:
+        if not self.enable_prefix_caching or self.num_state_slots:
             return [], 0
+        return self._longest_cached_prefix(token_ids, seed)
+
+    def _longest_cached_prefix(self, token_ids: Sequence[int],
+                               seed: bytes) -> Tuple[List[int], int]:
         # Leave >= 1 token to recompute.
         max_cached_tokens = len(token_ids) - 1
         usable_full_blocks = max_cached_tokens // self.block_size
@@ -212,6 +256,9 @@ class BlockPoolManager:
         # on a congested pool don't inflate the hit rate the router scrapes.
         self.prefix_queries_total += len(token_ids)
         self.prefix_hits_total += n_cached
+        if self.num_state_slots and self.enable_prefix_caching:
+            self.prefix_hits_unserved_total += self._longest_cached_prefix(
+                token_ids, seed)[1]
         return cached + fresh, n_cached
 
     def append_block(self) -> Optional[int]:

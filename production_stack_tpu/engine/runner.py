@@ -46,7 +46,9 @@ from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import KVView, gather_window
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
+    read_state_rows,
     write_slabs,
+    write_state_rows,
     write_token_runs,
 )
 from production_stack_tpu.parallel import kv_pool_sharding, param_shardings
@@ -71,7 +73,9 @@ _POS_SENTINEL = np.int32(2**30)  # ring_pos value for not-yet-written entries
 # issue dispatch N+1 before fetching N's tokens — the blocking
 # device->host sync then overlaps N+1's execution. Row 12 is the
 # sequence's slot in the speculative draft-KV ring pools (0 when
-# speculative decoding is off — the row is then never read). Row 13 is the
+# speculative decoding is off — the row is then never read), or, for a model
+# that declares recurrent state (which refuses speculation), its slot in the
+# state pools (0: the scratch slot of padded rows). Row 13 is the
 # per-row speculative draft depth gamma in [0, speculative_num_tokens]
 # (the round-10 adaptive controller's output; packed as N itself when the
 # controller is off, never read without speculation).
@@ -331,6 +335,19 @@ class ModelRunner:
         self.startup_warmup_failures = 0
 
         model = get_model(model_config)
+        # What the architecture caches per sequence, as its module declares
+        # it (models/config.py:CacheSpecs): the layers that keep paged K/V,
+        # and any state a sequence holds whole. Pool shapes, the block
+        # budget and the dispatch programs read these and never the
+        # architecture's name.
+        specs = model.cache_specs(model_config)
+        self.kv_spec = specs.paged_kv
+        self.state_specs = specs.state
+        # Slots of the state pools: one per sequence the scheduler can hold
+        # (--max-num-seqs) plus slot 0, the scratch slot every padded row of
+        # a dispatch reads and writes.
+        self.num_state_slots = config.max_num_seqs + 1 \
+            if self.state_specs else 0
         init_fn = self._init_fn = model.init_params
         self._forward, self._logits_fn = model.forward, model.compute_logits
         self._params = None
@@ -502,6 +519,9 @@ class ModelRunner:
         self.sample_dispatches_greedy_total = 0
         self.sample_dispatches_filtered_total = 0
 
+        # Before the K/V pool is sized from what is free (as the draft
+        # rings above).
+        self._alloc_state_pools()
         self.num_kv_blocks = num_kv_blocks or config.num_kv_blocks or \
             self._derive_num_blocks()
         self._alloc_kv_pools()
@@ -520,7 +540,7 @@ class ModelRunner:
             self._decode_impl,
             static_argnames=("b", "mb", "num_steps", "use_cached_window",
                              "has_penalties", "logprobs_k", "spec_on"),
-            donate_argnums=(2, 3, 4, 5, 6, 7, 11, 12, 13),
+            donate_argnums=(2, 3, 4, 5, 6, 7, 11, 12, 13, 14),
         )
         # Persistent decode window (window impl only): consecutive decode
         # dispatches over the SAME rows reuse the gathered window and append
@@ -560,7 +580,7 @@ class ModelRunner:
             self._prefill_impl,
             static_argnames=("b", "t", "mb", "has_window", "b_max",
                              "has_penalties", "logprobs_k"),
-            donate_argnums=(2, 3, 4, 5, 8, 9, 10),
+            donate_argnums=(2, 3, 4, 5, 8, 9, 10, 11),
         )
 
     # ----------------------------------------------------------------- weights
@@ -644,7 +664,8 @@ class ModelRunner:
         sidecars, kv-head-sharded like the payload."""
         mc, cfg = self.model_config, self.config
         num_slots = self.num_kv_blocks * cfg.block_size
-        kv_shape = (mc.num_layers, mc.num_kv_heads, num_slots, mc.head_dim_)
+        kv_shape = (self.kv_spec.layers, self.kv_spec.kv_heads, num_slots,
+                    self.kv_spec.head_dim)
         kv_sh = kv_pool_sharding(mc, self.mesh)
         self.kv_k = jax.device_put(
             jnp.zeros(kv_shape, self.kv_store_dtype), kv_sh
@@ -666,6 +687,40 @@ class ModelRunner:
             )
         else:
             self.kv_k_scale = self.kv_v_scale = None
+
+    def _alloc_state_pools(self) -> None:
+        """(Re)build the per-sequence state pools a model declares
+        (``[slots, layers, *shape]`` each — slots first, so a sequence's
+        state is one contiguous slab — zeroed, replicated; none for a
+        K/V-only model). Every dispatch gathers its rows' slots once, carries
+        the rows through its loops and writes them back in place once
+        (ops/kv_write.py:write_state_rows), as the K/V pools are."""
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        rep = NamedSharding(self.mesh, PartitionSpec())
+        self.state_pools = tuple(
+            jax.device_put(
+                jnp.zeros((self.num_state_slots, s.layers, *s.shape),
+                          _dtype(s.dtype) if s.dtype else self.dtype), rep)
+            for s in self.state_specs
+        )
+
+    @property
+    def state_pool_bytes(self) -> int:
+        return sum(int(p.size) * p.dtype.itemsize for p in self.state_pools)
+
+    def _read_state_rows(self, state_pools, slots, fresh=None):
+        """The rows' state [b, layers, ...] from their slots; rows that
+        are ``fresh`` (a sequence's first chunk: the slot still holds its
+        last owner's state) start from zeros."""
+        with jax.named_scope("kv_write"), jax.named_scope("state_read"):
+            rows = read_state_rows(state_pools, slots)
+            if fresh is not None:
+                rows = tuple(
+                    jnp.where(fresh.reshape((-1,) + (1,) * (r.ndim - 1)),
+                              jnp.zeros((), r.dtype), r)
+                    for r in rows)
+        return rows
 
     # -------------------------------------------------- speculative state
     def _alloc_spec_pools(self) -> None:
@@ -945,8 +1000,8 @@ class ModelRunner:
             return 0
         mc, cfg = self.model_config, self.config
         unquantized = (
-            2 * mc.num_layers * mc.num_kv_heads * mc.head_dim_
-            * jnp.dtype(self.dtype).itemsize
+            2 * self.kv_spec.layers * self.kv_spec.kv_heads
+            * self.kv_spec.head_dim * jnp.dtype(self.dtype).itemsize
         )
         saved = max(0, unquantized - cfg.kv_cache_bytes_per_token(mc))
         return self.kv_quant_tokens_written * saved
@@ -962,8 +1017,8 @@ class ModelRunner:
         mc, cfg = self.model_config, self.config
         bytes_per_block = cfg.kv_cache_bytes_per_block(mc)
         window_bytes_per_block = (
-            2 * mc.num_layers * cfg.block_size * mc.num_kv_heads
-            * mc.head_dim_ * jnp.dtype(self.dtype).itemsize
+            2 * self.kv_spec.layers * cfg.block_size * self.kv_spec.kv_heads
+            * self.kv_spec.head_dim * jnp.dtype(self.dtype).itemsize
         )
         # The budget is PER DEVICE: the least free HBM over the devices of
         # the engine's own mesh (not whatever jax.local_devices()[0] is).
@@ -989,13 +1044,19 @@ class ModelRunner:
         # yet when the pool is sized — reserve their full footprint out of
         # the probe or the pool would over-commit the HBM they land in.
         free_bytes = max(0, free_bytes - self._pending_param_bytes)
+        # The state pools are resident already (bytes_in_use holds them);
+        # a decode dispatch besides carries its rows' state through its
+        # loop as a temporary, the widest row bucket's worth.
+        free_bytes = max(0, free_bytes - _bucket(
+            cfg.max_num_seqs, 1, max(1, cfg.max_num_seqs)
+        ) * cfg.state_bytes_per_seq(mc))
         # With tp>1 the pool (and the window gathered from it) is
         # kv-head-sharded, so each device holds 1/shards of every block
         # (1 when the heads don't divide tp and the pool is replicated —
         # read off the pool's own sharding rule, not restated here).
-        shards = mc.num_kv_heads // kv_pool_sharding(
+        shards = self.kv_spec.kv_heads // kv_pool_sharding(
             mc, self.mesh
-        ).shard_shape((1, mc.num_kv_heads, 1, 1))[1]
+        ).shard_shape((1, self.kv_spec.kv_heads, 1, 1))[1]
         bytes_per_block = -(-bytes_per_block // shards)
         window_bytes_per_block = -(-window_bytes_per_block // shards)
         budget = int(free_bytes * cfg.hbm_utilization)
@@ -1072,16 +1133,32 @@ class ModelRunner:
                            max(1, cfg.max_blocks_per_seq))
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
 
-    def _prefill_mb(self, live_blocks: int, has_window: bool) -> int:
+    def _prefill_mb(self, live_blocks: int, has_window: bool,
+                    rows: int = 1) -> int:
         """Static block-table width for a prefill dispatch: pinned at the
         max bucket when no window is gathered (block tables only feed the
         pool write's slot mapping — padding is free), quantized when a chunk
-        with history gathers its [rows, mb*block_size] window."""
+        with history gathers its [rows, mb*block_size] window — unless the
+        window is pinned too (_pins_prefill_window)."""
         cfg = self.config
-        if not has_window:
-            return _bucket(cfg.max_blocks_per_seq, 1,
-                           max(1, cfg.max_blocks_per_seq))
+        full = _bucket(cfg.max_blocks_per_seq, 1,
+                       max(1, cfg.max_blocks_per_seq))
+        if not has_window or self._pins_prefill_window(rows, full):
+            return full
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
+
+    def _pins_prefill_window(self, rows: int, full_mb: int) -> bool:
+        """A model that declares recurrent state gathers its prefill
+        history window at the full width whatever the rows hold, where the
+        window budget allows that many blocks: ONE windowed family a (rows,
+        t) instead of three. Its K/V is a minority of its layers, so the
+        wider gather is cheap (0.19 GB a row at the benchmark's widths);
+        its prefill programs are twice a dense model's size, and a
+        deployment's programs have to fit the compile cache's size cap
+        together (PERF.md §6, PR 31: 78 programs of 3.4 MB against 192 MiB
+        evicted one another and every boot compiled everything)."""
+        return bool(self.state_specs) and \
+            rows * full_mb <= self.prefill_window_blocks
 
     # --------------------------------------------------------- device helpers
     def _scale_pool_args(self):
@@ -1112,7 +1189,7 @@ class ModelRunner:
     # ------------------------------------------------------------------ decode
     def _decode_impl(self, params, packed, kv_k, kv_v, kv_ks, kv_vs,
                      win_k_in, win_v_in, counts0, prev_last, dparams,
-                     spec_k, spec_v, spec_pos, *, b: int,
+                     spec_k, spec_v, spec_pos, state_pools, *, b: int,
                      mb: int, num_steps: int, use_cached_window: bool,
                      has_penalties: bool = False, logprobs_k: int = 0,
                      spec_on: bool = True):
@@ -1156,6 +1233,14 @@ class ModelRunner:
         they are 1-element donation dummies and a fresh gather builds the
         returned window. The updated window is returned so the caller can
         reuse it next dispatch.
+
+        state_pools: the per-sequence state pools of a model that declares
+        some (``()`` otherwise: the program is then the K/V-only one,
+        operand for operand). A row's state is gathered from its slot
+        (scalar row 12) once, carried through the steps — a step past the
+        row's budget delivers nothing and leaves the state as it was
+        (``chunk_lens`` 0: the model's forward holds that) — and written
+        back in place once, after the loop. Returned rebound, last.
         """
         cfg = self.config
         bs = cfg.block_size
@@ -1195,7 +1280,7 @@ class ModelRunner:
                 block_tables, b_max, b=b, mb=mb, num_steps=num_steps,
                 use_cached_window=use_cached_window,
                 has_penalties=has_penalties, logprobs_k=logprobs_k,
-            )
+            ) + (state_pools,)
 
         # Token chaining: rows continuing from the immediately-previous
         # dispatch read their start token from its device-resident
@@ -1241,7 +1326,7 @@ class ModelRunner:
                 )
             view0 = KVView(win_k=win_k, win_v=win_v, win_len=pos0)
 
-        nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
+        nl, hkv, dh = self.kv_spec
         ring_k0 = jnp.zeros((nl, hkv, b, num_steps, dh), self.dtype)
         ring_v0 = jnp.zeros((nl, hkv, b, num_steps, dh), self.dtype)
         ring_pos0 = jnp.full((b, num_steps), _POS_SENTINEL, jnp.int32)
@@ -1274,16 +1359,29 @@ class ModelRunner:
             jnp.minimum(budget, num_steps)
         ).astype(jnp.int32)
 
+        state_slots = scalars[12]
+        rows_state = self._read_state_rows(state_pools, state_slots) \
+            if self.state_specs else ()
+
         def body(carry, j):
-            toks, ring_k, ring_v, ring_pos, counts, qstate = carry
+            toks, ring_k, ring_v, ring_pos, counts, qstate, rows_state = carry
             seeds_j = seed_steps[j]
             positions = jnp.minimum(pos0 + j, max_len - 1)[:, None]
-            hidden, k_new, v_new = self._forward(
-                params, mc, toks[:, None], positions, ones,
-                view0._replace(ring_k=ring_k, ring_v=ring_v,
-                               ring_pos=ring_pos),
-                lora=lora,
-            )
+            if self.state_specs:
+                hidden, k_new, v_new, rows_state = self._forward(
+                    params, mc, toks[:, None], positions,
+                    (j < budget).astype(jnp.int32),
+                    view0._replace(ring_k=ring_k, ring_v=ring_v,
+                                   ring_pos=ring_pos),
+                    lora=lora, state=rows_state,
+                )
+            else:
+                hidden, k_new, v_new = self._forward(
+                    params, mc, toks[:, None], positions, ones,
+                    view0._replace(ring_k=ring_k, ring_v=ring_v,
+                                   ring_pos=ring_pos),
+                    lora=lora,
+                )
             if quant:
                 # Quantize this step's fresh KV on device; the attention
                 # ring carries the DEQUANTIZED values so later steps of
@@ -1342,7 +1440,8 @@ class ModelRunner:
             kept = jnp.where(
                 j < budget, nxt.astype(jnp.int32), toks
             )
-            return (kept, ring_k, ring_v, ring_pos, counts, qstate), nxt, lp
+            return (kept, ring_k, ring_v, ring_pos, counts, qstate,
+                    rows_state), nxt, lp
 
         def loop_body(state):
             j, carry, toks_all, lp_bufs = state
@@ -1356,7 +1455,8 @@ class ModelRunner:
                 )
             return j + 1, carry, toks_all, lp_bufs
 
-        carry0 = (tokens0, ring_k0, ring_v0, ring_pos0, counts0, qstate0)
+        carry0 = (tokens0, ring_k0, ring_v0, ring_pos0, counts0, qstate0,
+                  rows_state)
         if cfg.decode_loop == "scan":
             # A/B alternative: all K steps run unconditionally under
             # lax.scan (more XLA pipelining latitude, no drain-tail skip).
@@ -1364,8 +1464,8 @@ class ModelRunner:
                 carry, nxt, lp = body(carry, j)
                 return carry, (nxt, lp if logprobs_k else ())
 
-            (final_toks, ring_k, ring_v, _, _, qstate), (toks_all, lp_scan) \
-                = jax.lax.scan(
+            (final_toks, ring_k, ring_v, _, _, qstate, rows_state), \
+                (toks_all, lp_scan) = jax.lax.scan(
                     scan_body, carry0,
                     jnp.arange(num_steps, dtype=jnp.int32),
                 )
@@ -1379,8 +1479,8 @@ class ModelRunner:
                 jnp.zeros((num_steps, b, logprobs_k), jnp.float32),
                 jnp.zeros((num_steps, b, logprobs_k), jnp.int32),
             ) if logprobs_k else ()
-            _, (final_toks, ring_k, ring_v, _, _, qstate), toks_all, \
-                lp_bufs = jax.lax.while_loop(
+            _, (final_toks, ring_k, ring_v, _, _, qstate, rows_state), \
+                toks_all, lp_bufs = jax.lax.while_loop(
                     lambda st: st[0] < n_active,
                     loop_body,
                     (jnp.int32(0), carry0, toks_buf0, lp_bufs0),
@@ -1408,6 +1508,10 @@ class ModelRunner:
                     (kv_k, kv_v), (ring_k, ring_v), block_tables,
                     pos0, n_valid, bs,
                 )
+            if self.state_specs:
+                with jax.named_scope("state_write"):
+                    state_pools = write_state_rows(
+                        state_pools, rows_state, state_slots)
         if self.attn_impl != "paged":
             # Append the dispatch's KV into the persistent window too (slot
             # s = absolute position s), so the next dispatch over the same
@@ -1420,10 +1524,12 @@ class ModelRunner:
                 )
             return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k, win_v,
                     lp_chosen, lp_top, lp_ids, last_token,
-                    *self._spec_dummy_outs(spec_k, spec_v, spec_pos))
+                    *self._spec_dummy_outs(spec_k, spec_v, spec_pos),
+                    state_pools)
         return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k_in, win_v_in,
                 lp_chosen, lp_top, lp_ids, last_token,
-                *self._spec_dummy_outs(spec_k, spec_v, spec_pos))
+                *self._spec_dummy_outs(spec_k, spec_v, spec_pos),
+                state_pools)
 
     def _append_window(self, win_k, win_v, new_k, new_v, start, length):
         """Append row i's tokens j < length[i] ([L, Hkv, b, T, Dh]) to the
@@ -2033,6 +2139,8 @@ class ModelRunner:
             f32[9, i] = sp.presence_penalty
             f32[10, i] = sp.frequency_penalty
             bt[i, :len(s.block_ids)] = s.block_ids
+            if self.state_specs:
+                sc[12, i] = s.state_slot
         self._count_sample_dispatch(f32[5], sc[6], f32[7])
         if has_penalties:
             vocab = self.model_config.vocab_size
@@ -2081,10 +2189,10 @@ class ModelRunner:
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
         (toks_all, self.kv_k, self.kv_v, kv_ks2, kv_vs2, wk2, wv2, lp_c,
          lp_t, lp_i, last_token, emits, spec_stats_dev, sp_k2,
-         sp_v2, sp_p2) = self._decode(
+         sp_v2, sp_p2, self.state_pools) = self._decode(
             self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
             kv_ks, kv_vs, wk, wv, jnp.asarray(counts), prev_last,
-            dparams, sp_k, sp_v, sp_p,
+            dparams, sp_k, sp_v, sp_p, self.state_pools,
             b=b, mb=mb, num_steps=k, use_cached_window=use_cached,
             has_penalties=has_penalties, logprobs_k=logprobs_k,
             spec_on=spec_on,
@@ -2230,7 +2338,8 @@ class ModelRunner:
 
     # ----------------------------------------------------------------- prefill
     def _prefill_impl(self, params, packed, kv_k, kv_v, kv_ks, kv_vs,
-                      counts0, dparams, spec_k, spec_v, spec_pos, *,
+                      counts0, dparams, spec_k, spec_v, spec_pos,
+                      state_pools, *,
                       b: int, t: int, mb: int, has_window: bool,
                       b_max: int, has_penalties: bool = False,
                       logprobs_k: int = 0):
@@ -2253,6 +2362,12 @@ class ModelRunner:
         matter here only for preempted sequences re-prefilling with prior
         output tokens; fresh prompts have zero counts (output-only
         penalties, vLLM semantics).
+
+        state_pools: see _decode_impl. A chunk starts from its row's slot
+        — from zeros where chunk_start is 0: a slot is cleared when a
+        sequence starts in it, not when one leaves it — and leaves the
+        state after its last valid token, so a prompt longer than the
+        token budget crosses chunks through the slot.
         """
         cfg = self.config
         bs = cfg.block_size
@@ -2302,12 +2417,21 @@ class ModelRunner:
             t > 1 and sp > 1 and t % sp == 0
             and (not has_window or (mb * bs + t) % sp == 0)
         )
-        hidden, k_new, v_new = self._forward(
-            params, mc, token_ids, positions, chunk_lens,
-            KVView(win_k, win_v, win_len,
-                   sp_mesh=self.mesh if rings else None),
-            act_sharding=self._act_sharding, lora=lora,
-        )
+        if self.state_specs:
+            state_slots = scalars[12]
+            hidden, k_new, v_new, rows_state = self._forward(
+                params, mc, token_ids, positions, chunk_lens,
+                KVView(win_k, win_v, win_len),
+                lora=lora, state=self._read_state_rows(
+                    state_pools, state_slots, fresh=chunk_start == 0),
+            )
+        else:
+            hidden, k_new, v_new = self._forward(
+                params, mc, token_ids, positions, chunk_lens,
+                KVView(win_k, win_v, win_len,
+                       sp_mesh=self.mesh if rings else None),
+                act_sharding=self._act_sharding, lora=lora,
+            )
         logit_idx = jnp.maximum(chunk_lens - 1, 0)
         last_hidden = hidden[jnp.arange(b), logit_idx]            # [b, D]
         logits = self._logits_fn(params, mc, last_hidden)
@@ -2346,6 +2470,10 @@ class ModelRunner:
                     (kv_k, kv_v), (k_new, v_new), block_tables,
                     chunk_start, chunk_lens, bs,
                 )
+            if self.state_specs:
+                with jax.named_scope("state_write"):
+                    state_pools = write_state_rows(
+                        state_pools, rows_state, state_slots)
         # Speculative draft warm-up (docs/PERF.md round 8): run the DRAFT
         # model over the same chunk so its per-sequence KV ring holds the
         # prompt context before decode starts — a cold draft ring proposes
@@ -2407,7 +2535,7 @@ class ModelRunner:
             next_tokens.astype(jnp.int32)
         )
         return (next_tokens, kv_k, kv_v, kv_ks, kv_vs, lp[0], lp[1], lp[2],
-                last_token, spec_k, spec_v, spec_pos)
+                last_token, spec_k, spec_v, spec_pos, state_pools)
 
     def _issue_prefill(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config
@@ -2426,7 +2554,8 @@ class ModelRunner:
                     prefill_t_floor(cfg.max_num_batched_tokens),
                     max(16, cfg.max_num_batched_tokens))
         has_window = any(st > 0 for st in batch.chunk_starts)
-        mb = self._prefill_mb(max(len(s.block_ids) for s in seqs), has_window)
+        mb = self._prefill_mb(max(len(s.block_ids) for s in seqs),
+                              has_window, b)
 
         finals = [
             batch.chunk_starts[i] + batch.chunk_lens[i] >= seqs[i].num_tokens
@@ -2477,6 +2606,8 @@ class ModelRunner:
             f32[9, i] = sp.presence_penalty
             f32[10, i] = sp.frequency_penalty
             bt[i, :len(s.block_ids)] = s.block_ids
+            if self.state_specs:
+                sc[12, i] = s.state_slot
             toks[i, :ln] = s.all_token_ids[start:start + ln]
         self._count_sample_dispatch(f32[4], sc[5], f32[6])
         if has_penalties:
@@ -2494,9 +2625,11 @@ class ModelRunner:
         kv_ks, kv_vs = self._scale_pool_args()
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
         (next_tokens, self.kv_k, self.kv_v, kv_ks2, kv_vs2, lp_c, lp_t,
-         lp_i, last_token, sp_k2, sp_v2, sp_p2) = self._prefill(
+         lp_i, last_token, sp_k2, sp_v2, sp_p2,
+         self.state_pools) = self._prefill(
             self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
             kv_ks, kv_vs, jnp.asarray(counts), dparams, sp_k, sp_v, sp_p,
+            self.state_pools,
             b=b, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
             has_penalties=has_penalties, logprobs_k=logprobs_k,
         )
@@ -2831,7 +2964,8 @@ class ModelRunner:
                     max(16, cfg.max_num_batched_tokens // 2), 16, t_max
                 ):
                     fams.add((pb, t, full_mb, False))
-                    for mb in win_mbs:
+                    for mb in ([full_mb] if self._pins_prefill_window(
+                            pb, full_mb) else win_mbs):
                         if pb * mb <= self.prefill_window_blocks:
                             fams.add((pb, t, mb, True))
                 t *= 2
@@ -2858,7 +2992,7 @@ class ModelRunner:
         shape and sharding: lowering reads no buffer, so a dispatch in
         flight may have donated them)."""
         mc, bs = self.model_config, self.config.block_size
-        nl, hkv, dh = mc.num_layers, mc.num_kv_heads, mc.head_dim_
+        nl, hkv, dh = self.kv_spec
         sds = jax.ShapeDtypeStruct
         if cached:
             # Cached-window variants receive windows that are COMMITTED
@@ -2882,6 +3016,7 @@ class ModelRunner:
             aparams, sds((NUM_SCALARS * db + db * mb,), jnp.int32),
             self.kv_k, self.kv_v, *self._scale_pool_args(), wk, wv,
             counts, self._zero_last, *self._spec_pool_args(),
+            self.state_pools,
             b=db, mb=mb, num_steps=dk, use_cached_window=cached,
             has_penalties=has_penalties, logprobs_k=logprobs_k,
         )
@@ -2897,7 +3032,7 @@ class ModelRunner:
         return self._prefill.lower(
             aparams, sds((NUM_SCALARS * pb + pb * mb + pb * t,), jnp.int32),
             self.kv_k, self.kv_v, *self._scale_pool_args(), counts,
-            *self._spec_pool_args(),
+            *self._spec_pool_args(), self.state_pools,
             b=pb, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
             has_penalties=has_penalties, logprobs_k=logprobs_k,
         )
@@ -2914,7 +3049,7 @@ class ModelRunner:
         pools = [self.kv_k] + [
             x for x in (*self._scale_pool_args(),
                         *self._spec_pool_args()[1:3]) if x.size > 1
-        ]
+        ] + list(self.state_pools)
         aparams = self._abstract_params()
         programs = []
         decode = self.reachable_decode_families()
@@ -2937,6 +3072,7 @@ class ModelRunner:
                 "temp_bytes": int(mem.temp_size_in_bytes),
                 "alias_bytes": int(mem.alias_size_in_bytes),
                 "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
+                "state_pool_bytes": self.state_pool_bytes,
             })
         return out
 
@@ -3242,7 +3378,8 @@ class ModelRunner:
                                       jnp.int32),
                             self.kv_k, self.kv_v, kv_ks, kv_vs, wk, wv,
                             counts, self._zero_last, dparams, sp_k, sp_v,
-                            sp_p, b=db, mb=mb, num_steps=dk,
+                            sp_p, self.state_pools,
+                            b=db, mb=mb, num_steps=dk,
                             use_cached_window=cached,
                             has_penalties=pen, logprobs_k=lpk,
                             spec_on=sp_on,
@@ -3250,6 +3387,7 @@ class ModelRunner:
                         _, self.kv_k, self.kv_v = out[0], out[1], out[2]
                         self._rebind_scale_pools(out[3], out[4])
                         self._rebind_spec_pools(out[13], out[14], out[15])
+                        self.state_pools = out[16]
                         if self.attn_impl != "paged":
                             # Both variants return the (appended/gathered)
                             # windows; the inputs were donated, so rebind.
@@ -3287,7 +3425,7 @@ class ModelRunner:
                             (NUM_SCALARS * pb + pb * mb + pb * t,), jnp.int32
                         ),
                         self.kv_k, self.kv_v, kv_ks, kv_vs, counts,
-                        dparams, sp_k, sp_v, sp_p,
+                        dparams, sp_k, sp_v, sp_p, self.state_pools,
                         b=pb, t=t, mb=mb, has_window=has_window,
                         b_max=self._b_max,
                         has_penalties=pen, logprobs_k=lpk,
@@ -3295,6 +3433,7 @@ class ModelRunner:
                     self.kv_k, self.kv_v = out[1], out[2]
                     self._rebind_scale_pools(out[3], out[4])
                     self._rebind_spec_pools(out[9], out[10], out[11])
+                    self.state_pools = out[12]
                     n_warmed += 1
             if self.spec_n:
                 # Draft catch-up (ingest) families: one per T bucket, so
@@ -3380,6 +3519,7 @@ class ModelRunner:
                     "Rebuilding KV pool consumed by failed warmup"
                 )
                 self._alloc_kv_pools()
+                self._alloc_state_pools()
             if self.spec_n:
                 try:
                     spec_gone = (self.spec_k.is_deleted()

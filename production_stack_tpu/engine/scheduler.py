@@ -101,6 +101,9 @@ class Sequence:
     # (chosen_logprob, [(token_id, logprob), ...]) per accepted token.
     output_logprobs: List = field(default_factory=list)
     block_ids: List[int] = field(default_factory=list)
+    # Slot of the runner's state pools (a model that declares recurrent
+    # state): taken with the blocks, given back with them; 0 = none.
+    state_slot: int = 0
     num_computed_tokens: int = 0       # tokens whose KV is in the device pool
     num_cached_tokens: int = 0         # prefix-cache hits (telemetry)
     num_preemptions: int = 0
@@ -328,10 +331,18 @@ class Scheduler:
                 # restored straight to RUNNING, never queued here).
                 continue
             if not cand.block_ids:
+                # Blocks AND a state slot, or neither (a K/V-only model
+                # needs no slot and is never refused one).
+                if self.block_manager.num_state_slots:
+                    cand.state_slot = \
+                        self.block_manager.allocate_state_slot()
+                    if not cand.state_slot:
+                        continue  # every slot is held; put off, not failed
                 alloc = self.block_manager.allocate_prompt(
                     cand.all_token_ids, seed=cand.hash_seed
                 )
                 if alloc is None:
+                    self._free_state_slot(cand)
                     continue  # starved; a later cand may already hold blocks
                 cand.block_ids, cand.num_cached_tokens = alloc
                 cand.num_computed_tokens = cand.num_cached_tokens
@@ -394,6 +405,7 @@ class Scheduler:
         for cand in cands[n:]:
             if cand.request_id in newly_allocated:
                 self.block_manager.free_blocks(cand.block_ids)
+                self._free_state_slot(cand)
                 cand.block_ids = []
                 cand.num_computed_tokens = 0
                 cand.num_cached_tokens = 0
@@ -588,6 +600,7 @@ class Scheduler:
         if seq in self.running:
             self.running.remove(seq)
         self.block_manager.free_blocks(seq.block_ids)
+        self._free_state_slot(seq)
         seq.block_ids = []
         seq.num_computed_tokens = 0
         # In-flight unapplied tokens are DISCARDED (apply_results skips
@@ -748,7 +761,12 @@ class Scheduler:
         if seq in self.waiting:
             self.waiting.remove(seq)
         self.block_manager.free_blocks(seq.block_ids)
+        self._free_state_slot(seq)
         seq.block_ids = []
+
+    def _free_state_slot(self, seq: Sequence) -> None:
+        self.block_manager.free_state_slot(seq.state_slot)
+        seq.state_slot = 0
 
     def _register_full_blocks(self, seq: Sequence) -> None:
         if not seq.block_ids:

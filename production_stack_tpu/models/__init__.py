@@ -2,22 +2,24 @@
 
 A module declares ``init_params``, ``forward``, ``compute_logits``, its HF
 checkpoint maps (``HF_LAYER_MAP``, ``HF_TOP_MAP``, ``required_layer_leaves``,
-``finish_params``), ``LORA_TARGETS``, ``PAGED_DECODE_VALIDATED`` and
-``position_bound`` (models/llama.py lists them with their meaning). Nothing
+``finish_params``), ``LORA_TARGETS``, ``PAGED_DECODE_VALIDATED``,
+``position_bound`` and ``cache_specs`` (models/llama.py lists them with
+their meaning). Nothing
 outside this package asks for an architecture by name.
 """
 
-from production_stack_tpu.models import llama, opt
+from production_stack_tpu.models import llama, olmo_hybrid, opt
 from production_stack_tpu.models.config import (
     LLAMA3_8B,
     NAMED_CONFIGS,
     OPT_125M,
+    CacheSpecs,
     TINY_LLAMA,
     ModelConfig,
     resolve_model_config,
 )
 
-_ARCHS = {"llama": llama, "opt": opt}
+_ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid}
 
 
 def get_model(cfg: ModelConfig):
@@ -26,7 +28,12 @@ def get_model(cfg: ModelConfig):
     return _ARCHS[cfg.arch]
 
 
+def cache_specs(cfg: ModelConfig) -> CacheSpecs:
+    """What the config's architecture caches per sequence, by layer kind."""
+    return get_model(cfg).cache_specs(cfg)
+
+
 __all__ = [
-    "ModelConfig", "resolve_model_config", "get_model",
+    "ModelConfig", "resolve_model_config", "get_model", "cache_specs",
     "NAMED_CONFIGS", "TINY_LLAMA", "OPT_125M", "LLAMA3_8B",
 ]

@@ -15,12 +15,39 @@ model tier is in-repo and TPU-native.
 import json
 import os
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
+
+
+class PagedKVSpec(NamedTuple):
+    """Layers that keep paged keys and values, and a token's shape there."""
+    layers: int
+    kv_heads: int
+    head_dim: int
+
+
+class StateSpec(NamedTuple):
+    """State a sequence holds whole, whatever its length: ``shape`` per
+    sequence and layer, for ``layers`` layers. ``dtype`` None: the
+    activations'."""
+    name: str
+    layers: int
+    shape: Tuple[int, ...]
+    dtype: Optional[str]
+
+
+class CacheSpecs(NamedTuple):
+    """What a model module's ``cache_specs(cfg)`` declares it caches per
+    sequence, by layer kind: the runner sizes and owns the pools from this
+    and nothing else (engine/runner.py), and a non-empty ``state`` is what
+    the block manager hands out slots for and what the engine refuses
+    features by that cannot follow it."""
+    paged_kv: PagedKVSpec
+    state: Tuple[StateSpec, ...] = ()
 
 
 @dataclass(frozen=True)
 class ModelConfig:
-    arch: str = "llama"  # "llama" | "opt"
+    arch: str = "llama"  # "llama" | "opt" | "olmo_hybrid"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -29,12 +56,26 @@ class ModelConfig:
     num_kv_heads: int = 32
     head_dim: Optional[int] = None
     max_position_embeddings: int = 4096
-    rope_theta: float = 10000.0
+    # None: no rotary embedding (models/olmo_hybrid.py reads it so).
+    rope_theta: Optional[float] = 10000.0
     rms_norm_eps: float = 1e-5
     tie_word_embeddings: bool = False
     attention_bias: bool = False  # Qwen2-style qkv bias
     dtype: str = "bfloat16"
     name: str = "model"
+    # Layer kinds in order ("linear_attention" | "full_attention"), a whole
+    # number of equal periods; empty: every layer is full attention. The
+    # linear_* sizes are those of the linear-attention layers' recurrence.
+    layer_types: Tuple[str, ...] = ()
+    linear_num_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False
+
+    def __post_init__(self):
+        if self.layer_types:
+            layer_period(self.layer_types, self.num_layers)
 
     @property
     def head_dim_(self) -> int:
@@ -79,12 +120,65 @@ class ModelConfig:
                 tie_word_embeddings=d.get("tie_word_embeddings", True),
                 name=name,
             )
+        if model_type == "olmo_hybrid":
+            if d["linear_num_key_heads"] != d["linear_num_value_heads"]:
+                raise ValueError(
+                    "olmo_hybrid: linear_num_key_heads != "
+                    "linear_num_value_heads is not supported"
+                )
+            return ModelConfig(
+                arch="olmo_hybrid",
+                vocab_size=d["vocab_size"],
+                hidden_size=d["hidden_size"],
+                intermediate_size=d["intermediate_size"],
+                num_layers=d["num_hidden_layers"],
+                num_heads=d["num_attention_heads"],
+                num_kv_heads=d.get("num_key_value_heads",
+                                   d["num_attention_heads"]),
+                head_dim=d.get("head_dim"),
+                max_position_embeddings=d.get("max_position_embeddings", 4096),
+                rope_theta=(d.get("rope_parameters") or {}).get("rope_theta"),
+                rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                layer_types=tuple(d["layer_types"]),
+                linear_num_heads=d["linear_num_value_heads"],
+                linear_key_head_dim=d["linear_key_head_dim"],
+                linear_value_head_dim=d["linear_value_head_dim"],
+                linear_conv_kernel_dim=d.get("linear_conv_kernel_dim", 4),
+                linear_allow_neg_eigval=d.get("linear_allow_neg_eigval", False),
+                name=name,
+            )
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
     def from_pretrained_dir(path: str, name: Optional[str] = None) -> "ModelConfig":
         with open(os.path.join(path, "config.json")) as f:
             return ModelConfig.from_hf_config(json.load(f), name=name or path)
+
+
+LAYER_KINDS = ("linear_attention", "full_attention")
+
+
+def layer_period(layer_types, num_layers: int) -> Tuple[str, ...]:
+    """The repeating pattern of ``layer_types``: some linear-attention
+    layers, then one full-attention layer (what models/olmo_hybrid.py traces
+    once and scans). Refused: a length other than ``num_layers``, an unknown
+    kind, and a list that is not a whole number of such equal periods."""
+    types = tuple(layer_types)
+    if len(types) != num_layers:
+        raise ValueError(
+            f"layer_types has {len(types)} entries for {num_layers} layers")
+    unknown = sorted(set(types) - set(LAYER_KINDS))
+    if unknown:
+        raise ValueError(f"layer_types: unknown kinds {unknown}; "
+                         f"supported: {list(LAYER_KINDS)}")
+    n = types.index("full_attention") + 1 if "full_attention" in types else 0
+    if n < 2 or len(types) % n or types != types[:n] * (len(types) // n):
+        raise ValueError(
+            f"layer_types {list(types)} is not a whole number of equal "
+            f"periods of linear_attention layers closed by one "
+            f"full_attention layer")
+    return types[:n]
 
 
 # Small built-in configs for tests and single-chip benchmarks.
@@ -153,8 +247,22 @@ TINY_LLAMA_128DH = ModelConfig(
     max_position_embeddings=512, name="tiny-llama-128dh",
 )
 
+# Tiny hybrid: two periods of (3 linear-attention + 1 full-attention) layers
+# (tests/test_olmo_hybrid.py compares it with the plain reference).
+TINY_OLMO_HYBRID = ModelConfig(
+    arch="olmo_hybrid", vocab_size=512, hidden_size=128,
+    intermediate_size=256, num_layers=8, num_heads=4, num_kv_heads=4,
+    max_position_embeddings=512, rope_theta=None, rms_norm_eps=1e-6,
+    layer_types=("linear_attention",) * 3 + ("full_attention",)
+    + ("linear_attention",) * 3 + ("full_attention",),
+    linear_num_heads=4, linear_key_head_dim=16, linear_value_head_dim=32,
+    linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    name="tiny-olmo-hybrid",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-llama-8kv": TINY_LLAMA_8KV,
     "tiny-llama-128dh": TINY_LLAMA_128DH,
     "tiny-opt": TINY_OPT,

@@ -45,7 +45,11 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.config import (
+    CacheSpecs,
+    ModelConfig,
+    PagedKVSpec,
+)
 from production_stack_tpu.ops.attention import KVView, attend, scan_layers
 from production_stack_tpu.utils import init_logger
 
@@ -84,6 +88,12 @@ PAGED_DECODE_VALIDATED = True
 def position_bound(cfg: ModelConfig) -> Optional[int]:
     """Largest position + 1 the forward accepts; None: RoPE takes any."""
     return None
+
+
+def cache_specs(cfg: ModelConfig) -> CacheSpecs:
+    """What a sequence caches: paged K/V in every layer, nothing else."""
+    return CacheSpecs(
+        PagedKVSpec(cfg.num_layers, cfg.num_kv_heads, cfg.head_dim_))
 
 
 def required_layer_leaves(cfg: ModelConfig) -> set:

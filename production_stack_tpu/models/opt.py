@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from production_stack_tpu.models.config import ModelConfig
+from production_stack_tpu.models.llama import cache_specs  # noqa: F401 — K/V in every layer
 from production_stack_tpu.ops.attention import KVView, attend, scan_layers
 
 Params = Dict

@@ -77,6 +77,14 @@ def load_hf_params(
     model = get_model(cfg)
     per_layer_map, top_map = model.HF_LAYER_MAP, model.HF_TOP_MAP
     nl = cfg.num_layers
+    # A module with more than one kind of layer stacks its leaves by kind
+    # and says where each layer goes (``layer_slots``: (kind, index in the
+    # kind's stack) per layer); stacks are then keyed "kind/leaf".
+    slots = model.layer_slots(cfg) if hasattr(model, "layer_slots") else None
+    sizes: Dict[str, int] = {}
+    if slots is not None:
+        for kind, _ in slots:
+            sizes[kind] = sizes.get(kind, 0) + 1
 
     stacks: Dict[str, np.ndarray] = {}   # our layer leaf -> [L, ...] buffer
     filled: Dict[str, set] = {}          # our layer leaf -> set of layer idxs
@@ -93,14 +101,18 @@ def load_hf_params(
                 continue
             ours, transpose = mapped
             t = tensor.T if transpose else tensor
-            if ours not in stacks:
-                stacks[ours] = np.empty((nl,) + t.shape, t.dtype)
-                filled[ours] = set()
             if layer_idx >= nl:
                 raise ValueError(
                     f"Checkpoint tensor {hf_name} indexes layer {layer_idx} "
                     f"but the config has only {nl} layers"
                 )
+            depth = nl
+            if slots is not None:
+                kind, layer_idx = slots[layer_idx]
+                ours, depth = f"{kind}/{ours}", sizes[kind]
+            if ours not in stacks:
+                stacks[ours] = np.empty((depth,) + t.shape, t.dtype)
+                filled[ours] = set()
             stacks[ours][layer_idx] = t
             filled[ours].add(layer_idx)
         else:
@@ -114,16 +126,19 @@ def load_hf_params(
     # Completeness is checked per LAYER-INDEX SET, not by count: a sharded
     # checkpoint that repeats layer 0 and omits layer 7 has the right count
     # but would serve garbage for the missing layer.
-    all_layers = set(range(nl))
     holes = {
-        k: sorted(all_layers - s) for k, s in filled.items()
-        if s != all_layers
+        k: sorted(set(range(len(stacks[k]))) - s) for k, s in filled.items()
+        if s != set(range(len(stacks[k])))
     }
     if holes:
         raise ValueError(
             f"Incomplete checkpoint: missing layer indices {holes}"
         )
-    absent = model.required_layer_leaves(cfg) - set(stacks)
+    required = model.required_layer_leaves(cfg)
+    if slots is not None:
+        required = {f"{kind}/{leaf}" for kind, leaves in required.items()
+                    for leaf in leaves}
+    absent = required - set(stacks)
     if absent:
         raise ValueError(
             f"Incomplete checkpoint: no tensors at all for {sorted(absent)}"
@@ -132,9 +147,13 @@ def load_hf_params(
     params: Dict = {"layers": {}}
     for name in list(stacks):
         arr = jax.numpy.asarray(stacks[name], dtype=dtype)
-        if shardings is not None and name in shardings.get("layers", {}):
-            arr = jax.device_put(arr, shardings["layers"][name])
-        params["layers"][name] = arr
+        kind, _, leaf = name.rpartition("/")
+        into, placed = params["layers"], (shardings or {}).get("layers", {})
+        if kind:
+            into, placed = into.setdefault(kind, {}), placed.get(kind, {})
+        if leaf in placed:
+            arr = jax.device_put(arr, placed[leaf])
+        into[leaf] = arr
         stacks[name] = None  # free host memory promptly
     for name, leaf in top.items():
         arr = jax.numpy.asarray(leaf, dtype=dtype)

@@ -85,6 +85,59 @@ def write_slabs(
     return jax.lax.fori_loop(0, dst_start.shape[0], body, pools)
 
 
+def read_state_rows(
+    pools: Sequence[jax.Array],     # each [S, *W]: per-sequence state
+    slots: jax.Array,               # [b] int32 — slot of each row
+) -> Tuple[jax.Array, ...]:
+    """``pool[slots]`` of every pool, [b, *W] each, as one
+    ``dynamic_slice`` a row: whole contiguous slabs, where a gather of
+    slabs this wide is taken apart lane block by lane block over the whole
+    pool (seen in the program compiled for a v5e)."""
+    pools = tuple(pools)
+
+    def body(i, rows):
+        out = []
+        for pool, buf in zip(pools, rows):
+            zeros = (0,) * (pool.ndim - 1)
+            slab = jax.lax.dynamic_slice(
+                pool, (slots[i], *zeros), (1, *pool.shape[1:]))
+            out.append(jax.lax.dynamic_update_slice(buf, slab, (i, *zeros)))
+        return tuple(out)
+
+    return jax.lax.fori_loop(
+        0, slots.shape[0], body,
+        tuple(jnp.zeros((slots.shape[0], *p.shape[1:]), p.dtype)
+              for p in pools))
+
+
+def write_state_rows(
+    pools: Sequence[jax.Array],     # each [S, *W]: per-sequence state
+    rows: Sequence[jax.Array],      # each [b, *W], paired with pools
+    slots: jax.Array,               # [b] int32 — slot of each row
+) -> Tuple[jax.Array, ...]:
+    """For i = 0..b-1 IN ORDER, for every (pool, rows) pair:
+
+        pool[slots[i]] = rows[i]
+
+    one ``dynamic_update_slice`` a row, on the pools' MAJOR axis: a row's
+    state (the recurrent state of engine/runner.py, every layer of it) is
+    one contiguous slab and whole, so there is nothing to merge.
+    Sequential, so padded rows may all name the scratch slot."""
+    pools, rows = tuple(pools), tuple(rows)
+
+    def body(i, pools):
+        out = []
+        for pool, new in zip(pools, rows):
+            zeros = (0,) * (pool.ndim - 1)
+            slab = jax.lax.dynamic_slice(
+                new, (i, *zeros), (1, *new.shape[1:]))
+            out.append(jax.lax.dynamic_update_slice(
+                pool, slab.astype(pool.dtype), (slots[i], *zeros)))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, slots.shape[0], body, pools)
+
+
 def write_token_runs(
     pools: Sequence[jax.Array],     # each [L, Hkv, num_slots, *W]
     news: Sequence[jax.Array],      # each [L, Hkv, b, T, *W], paired with pools

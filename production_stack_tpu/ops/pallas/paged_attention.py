@@ -66,6 +66,8 @@ from jax.experimental.pallas import tpu as pltpu
 SUPER_TOKENS = 512   # keys per compute iteration (amortizes the per-iteration
                      # flash-state relayout overhead; VMEM cost is
                      # 2 bufs * 2 pools * Hkv * 512/PACK * 128 * 2B)
+BUFFER_BYTES = 8 << 20   # the superpage buffers' share of the 16 MiB of
+                         # scoped VMEM a v5e gives a kernel
 NUM_BUFS = 2         # superpage double buffering
 ISSUE_UNROLL = 2     # pages a fetch-loop iteration issues (of 1, 2, 4 and 8
                      # the fastest on a v5e at both benchmark shapes, PERF.md)
@@ -74,6 +76,18 @@ LANES = 128          # minor-dim tiling the DMA slices must respect
 
 def _pack(head_dim: int) -> int:
     return max(1, LANES // head_dim)
+
+
+def super_tokens(num_kv_heads: int, head_dim: int, itemsize: int,
+                 block_size: int) -> int:
+    """Keys per compute iteration: ``SUPER_TOKENS``, halved while the four
+    superpage buffers (every KV head each) are over ``BUFFER_BYTES`` — 30
+    bf16 KV heads of 128 take 256, up to 16 heads the full 512."""
+    tokens = SUPER_TOKENS
+    while tokens > block_size and NUM_BUFS * 2 * num_kv_heads * tokens \
+            * head_dim * itemsize > BUFFER_BYTES:
+        tokens //= 2
+    return tokens
 
 
 def _decode_kernel(
@@ -95,6 +109,7 @@ def _decode_kernel(
     q_per_kv: int,
     scale: float,
     quantized: bool,
+    super_tokens: int,
 ):
     if quantized:
         (k_sc_ref, v_sc_ref, o_ref, m_ref, l_ref,
@@ -106,7 +121,7 @@ def _decode_kernel(
     # pre-normalization / softmax denominator), resident like q and written
     # back once a call: a program moves no block of its own, which is most
     # of what an empty row used to cost; k_buf/v_buf: VMEM
-    # [NUM_BUFS, Hkv, SUPER_TOKENS/PACK, Dh*PACK] pool-dtype scratch;
+    # [NUM_BUFS, Hkv, super_tokens/PACK, Dh*PACK] pool-dtype scratch;
     # sem_k/sem_v: DMA sems (NUM_BUFS,), one a buffer; fetched_ref: SMEM
     # [1] int32, superpages the rows before this one fetched. Scratch
     # outlives a program, which is what hands buffers from row to row.
@@ -114,15 +129,15 @@ def _decode_kernel(
     num_rows = kv_lens_ref.shape[0]
     layer = layer_ref[0]
     bs = block_size
-    spp = SUPER_TOKENS // bs            # pages per superpage
+    spp = super_tokens // bs            # pages per superpage
     hkv, g = num_kv_heads, q_per_kv
     dh = q_ref.shape[-1]
     pack = _pack(dh)
     bsp = bs // pack                    # packed rows per page
-    stp = SUPER_TOKENS // pack          # packed rows per superpage
+    stp = super_tokens // pack          # packed rows per superpage
     gp = min(ISSUE_UNROLL, spp)         # pages per fetch-loop iteration
     kv_len = kv_lens_ref[b]
-    n_super = pl.cdiv(kv_len, SUPER_TOKENS)
+    n_super = pl.cdiv(kv_len, super_tokens)
     # The call's superpages form ONE sequence across rows, and superpage n
     # of it lives in buffer n % NUM_BUFS: a row starts in the buffer its
     # predecessors left free, whatever their lengths were.
@@ -255,7 +270,7 @@ def _decode_kernel(
             if quantized:
                 ksc = k_sc_ref[0, f, :, pl.ds(s * stp, stp)]  # [Hkv, S/P]
                 scores = scores * ksc[:, None, :]
-            pos = s * SUPER_TOKENS + pack * jax.lax.broadcasted_iota(
+            pos = s * super_tokens + pack * jax.lax.broadcasted_iota(
                 jnp.int32, (1, 1, stp), 2
             ) + f
             scores = jnp.where(pos < kv_len, scores, -jnp.inf)
@@ -352,6 +367,7 @@ def paged_flash_decode_stats(
     pack = _pack(dh)
     quantized = k_scale is not None
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    sup = super_tokens(hkv, dh, k_pool.dtype.itemsize, block_size)
 
     # Lane-pack the pool view: [L, Hkv, NS/PACK, Dh*PACK] (free reshape).
     kp = k_pool.reshape(l_, hkv, num_slots // pack, dh * pack)
@@ -363,11 +379,11 @@ def paged_flash_decode_stats(
         mb = block_tables.shape[1]
         nb = num_slots // block_size
         # Pad the window to whole SUPERPAGES: the kernel slices
-        # SUPER_TOKENS/PACK scale rows per compute iteration even when the
+        # sup/PACK scale rows per compute iteration even when the
         # block table covers less (tail scores there are masked by
         # pos >= kv_len, so the zero padding is never read into a result).
         total = mb * block_size
-        padded = pl.cdiv(total, SUPER_TOKENS) * SUPER_TOKENS
+        padded = pl.cdiv(total, sup) * sup
 
         def sc_window(sc_pool):
             # This layer's per-slot scales at the dispatch's pages:
@@ -394,7 +410,7 @@ def paged_flash_decode_stats(
     kernel = functools.partial(
         _decode_kernel,
         block_size=block_size, num_kv_heads=hkv, q_per_kv=g,
-        scale=float(scale), quantized=quantized,
+        scale=float(scale), quantized=quantized, super_tokens=sup,
     )
 
     def resident(*shape):
@@ -420,11 +436,11 @@ def paged_flash_decode_stats(
         ],
         scratch_shapes=[
             pltpu.VMEM(
-                (NUM_BUFS, hkv, SUPER_TOKENS // pack, dh * pack),
+                (NUM_BUFS, hkv, sup // pack, dh * pack),
                 k_pool.dtype,
             ),
             pltpu.VMEM(
-                (NUM_BUFS, hkv, SUPER_TOKENS // pack, dh * pack),
+                (NUM_BUFS, hkv, sup // pack, dh * pack),
                 v_pool.dtype,
             ),
             pltpu.SemaphoreType.DMA((NUM_BUFS,)),

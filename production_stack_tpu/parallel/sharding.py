@@ -55,7 +55,12 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh, params: Dict) -> Dict:
     out: Dict = {}
     for key, leaf in params.items():
         if key == "layers":
-            out["layers"] = {n: layer_leaf(n, l) for n, l in leaf.items()}
+            # Leaves stacked on L, or one such dict per layer kind.
+            out["layers"] = {
+                n: ({m: layer_leaf(m, x) for m, x in l.items()}
+                    if isinstance(l, dict) else layer_leaf(n, l))
+                for n, l in leaf.items()
+            }
         elif key in ("embed", "pos_embed"):
             out[key] = _shard_if_divisible(mesh, d, (None, AXIS_TP))
         elif key == "lm_head":

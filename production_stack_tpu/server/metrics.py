@@ -25,6 +25,9 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "engine_uptime_seconds", "kv_offload_blocks",
                 "kv_quant_bytes_saved_total", "queue_depth",
                 "prefix_index_size", "kv_restore_saved_tokens_total",
+                "state_slots_total", "state_slots_in_use",
+                "state_slot_allocs_total", "state_slot_waits_total",
+                "prefix_hit_tokens_unserved_total",
                 "kv_shared_tier_hits_total", "kv_shared_tier_misses_total",
                 "kv_chain_evictions_total", "resume_restored_tokens_total",
                 "spec_enabled", "spec_draft_tokens_total",
@@ -103,6 +106,33 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "in the device prefix cache (the /prefix_index digest size)",
         "# TYPE pstpu:prefix_index_size gauge",
         f"pstpu:prefix_index_size{label} {s['prefix_index_size']}",
+        # Per-sequence recurrent state (a model that declares some;
+        # docs/OBSERVABILITY.md): the block manager's slots, and the prefix
+        # hits such a model cannot be served yet.
+        "# HELP pstpu:state_slots_total Recurrent-state slots the block "
+        "manager hands out, one a sequence (0: a K/V-only model)",
+        "# TYPE pstpu:state_slots_total gauge",
+        f"pstpu:state_slots_total{label} {s['state_slots_total']}",
+        "# HELP pstpu:state_slots_in_use Recurrent-state slots held by "
+        "admitted sequences",
+        "# TYPE pstpu:state_slots_in_use gauge",
+        f"pstpu:state_slots_in_use{label} {s['state_slots_in_use']}",
+        "# HELP pstpu:state_slot_allocs_total Recurrent-state slots handed "
+        "out (a sequence takes one with its blocks)",
+        "# TYPE pstpu:state_slot_allocs_total counter",
+        f"pstpu:state_slot_allocs_total{label} "
+        f"{s['state_slot_allocs_total']}",
+        "# HELP pstpu:state_slot_waits_total Admissions put off because "
+        "every recurrent-state slot was held",
+        "# TYPE pstpu:state_slot_waits_total counter",
+        f"pstpu:state_slot_waits_total{label} "
+        f"{s['state_slot_waits_total']}",
+        "# HELP pstpu:prefix_hit_tokens_unserved_total Prompt tokens whose "
+        "K/V the prefix index held but that were prefilled again, because "
+        "nothing keeps the recurrent state after them",
+        "# TYPE pstpu:prefix_hit_tokens_unserved_total counter",
+        f"pstpu:prefix_hit_tokens_unserved_total{label} "
+        f"{s['prefix_hit_tokens_unserved_total']}",
         "# HELP pstpu:kv_restore_saved_tokens_total Prompt tokens restored "
         "from the shared KV tier instead of recomputed (cost-model "
         "admitted)",

@@ -84,8 +84,17 @@ def _kernel_args(model: str, pool: str, sharding_for):
 
 # llama-3b is the smoke's model (head_dim 128); llama-1b packs two tokens per
 # 128-lane row (head_dim 64); llama-3-8b is the reference's headline shape.
+HYBRID_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmarks", "chip", "configs", "olmo-hybrid-7b-d16")
+
+
+# ... and the hybrid configuration's full layers: 30 query and 30 KV heads of
+# 128, not a multiple of 8 (the superpage shrinks to fit VMEM: 256 keys).
 @pytest.mark.parametrize("pool", ["bfloat16", "int8"])
-@pytest.mark.parametrize("model", ["llama-3b", "llama-1b", "llama-3-8b"])
+@pytest.mark.parametrize(
+    "model", ["llama-3b", "llama-1b", "llama-3-8b", HYBRID_DIR],
+    ids=["llama-3b", "llama-1b", "llama-3-8b", "olmo-hybrid-30-heads"])
 def test_paged_decode_kernel_compiles_for_v5e(v5e, model, pool):
     one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
     args, scales, out_shape = _kernel_args(model, pool, lambda _: one_chip)
@@ -273,3 +282,97 @@ def test_sampler_branches_sit_inside_conditionals_on_v5e(v5e):
     # The skipped picks need no buffer of the field's size kept alive
     # outside the branches: temporaries stay a few fields' worth.
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * rows * vocab * 4
+
+
+
+# ------------------------------------------------ whole dispatch programs
+def _described_runner(v5e, model_dir: str, **engine):
+    """A ModelRunner that holds described devices and shapes, nothing
+    else: enough for ``_lower_decode`` / ``_lower_prefill`` to lower a whole
+    dispatch program as the engine would (no array is ever made)."""
+    import numpy as np
+
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.runner import ModelRunner, _bucket
+    from production_stack_tpu.models import get_model
+
+    mesh = Mesh(np.array(v5e.devices[:1]).reshape(1, 1, 1),
+                (AXIS_DP, AXIS_SP, AXIS_TP))
+    rep = NamedSharding(mesh, P())
+    cfg = EngineConfig(model=model_dir, attn_impl="paged", **engine)
+    mc = resolve_model_config(model_dir)
+    model = get_model(mc)
+    specs = model.cache_specs(mc)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    r = object.__new__(ModelRunner)
+    r.config, r.model_config, r.mesh = cfg, mc, mesh
+    r.attn_impl, r._pallas_interpret = "paged", False
+    r.dtype = r.kv_store_dtype = jnp.bfloat16
+    r.kv_quantized, r.spec_n, r.lora_stacks, r._act_sharding = \
+        False, 0, None, None
+    r._init_fn, r._forward, r._logits_fn = \
+        model.init_params, model.forward, model.compute_logits
+    r.kv_spec, r.state_specs = specs.paged_kv, specs.state
+    r.num_kv_blocks = cfg.num_kv_blocks
+    r.num_state_slots = cfg.max_num_seqs + 1 if specs.state else 0
+    r.kv_k = r.kv_v = sds(
+        (specs.paged_kv.layers, specs.paged_kv.kv_heads,
+         cfg.num_kv_blocks * cfg.block_size, specs.paged_kv.head_dim),
+        jnp.bfloat16)
+    r.state_pools = tuple(
+        sds((r.num_state_slots, s.layers, *s.shape),
+            jnp.dtype(s.dtype or "bfloat16")) for s in specs.state)
+    r._b_max = _bucket(cfg.max_num_seqs, 1, cfg.max_num_seqs)
+    r._zero_last = sds((r._b_max,), jnp.int32)
+    r._scale_pool_args = lambda: (sds((1,), SCALE_DTYPE),) * 2
+    r._spec_pool_args = lambda: (
+        sds((1,), jnp.bfloat16),) * 3 + (sds((1,), jnp.int32),)
+    r._decode = jax.jit(
+        r._decode_impl,
+        static_argnames=("b", "mb", "num_steps", "use_cached_window",
+                         "has_penalties", "logprobs_k", "spec_on"),
+        donate_argnums=(2, 3, 4, 5, 6, 7, 11, 12, 13, 14))
+    r._prefill = jax.jit(
+        r._prefill_impl,
+        static_argnames=("b", "t", "mb", "has_window", "b_max",
+                         "has_penalties", "logprobs_k"),
+        donate_argnums=(2, 3, 4, 5, 8, 9, 10, 11))
+    return r
+
+
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-1x2048"])
+def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
+    """The decode and prefill programs of olmo-hybrid-7b-d16's envelope
+    (deployment.json's flags, published widths) compile for a v5e, fit its
+    HBM beside their arguments, and copy no pool: K/V and the recurrent
+    state are gathered by row and written back in place."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _described_runner(
+        v5e, HYBRID_DIR, max_model_len=3072, max_num_seqs=32,
+        max_num_batched_tokens=2048, num_kv_blocks=3072)
+    assert [p.shape for p in r.state_pools] == \
+        [(33, 12, 15, 96, 384), (33, 12, 3 * 11520 // 128, 128)]
+    assert r.kv_k.shape == (4, 30, 3072 * 16, 128)
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    if program.startswith("decode"):
+        lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
+    else:
+        lowered = r._lower_prefill(aparams, 1, 2048, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    assert pool_copies(text, [r.kv_k, *r.state_pools]) == []
+    # The Mosaic kernel: the full layers' decode, and nothing of prefill.
+    assert ('custom_call_target="tpu_custom_call"' in text) == \
+        program.startswith("decode")
+    mem = compiled.memory_analysis()
+    # The rows' state is ONE loop carry (0.85 GB at 32 rows), not one a
+    # layer: the decode program's temporaries stay under 1.5 GB.
+    assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

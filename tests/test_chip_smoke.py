@@ -241,3 +241,21 @@ def test_cpu_rehearsal_runs_every_phase_and_fails(chips):
     for name in passing:
         assert phases[name]["ok"], phases[name].get("faults") or \
             phases[name].get("error")
+
+
+def test_gdn_phase_rehearses_on_the_cpu():
+    """``--gdn --rehearse``: the recurrence-alone phase walks its code at a
+    toy size on the CPU, reports the bytes and FLOPs it would be held to
+    and no time."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--gdn",
+         "--rehearse"], capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads([ln for ln in proc.stdout.splitlines()
+                       if ln.startswith("{")][-1])
+    assert line["phase"] == "gdn" and line["ok"]
+    assert [t["op"] for t in line["timing"]] == \
+        ["gdn_step", "gdn_step", "gdn_chunk"]
+    assert all(t["bytes"] > 0 and t["flops"] > 0
+               and t["us_per_call"] is None for t in line["timing"])

@@ -168,6 +168,8 @@ def test_reachable_families_cover_observed_dispatches():
         reachable_prefill_families = ModelRunner.reachable_prefill_families
         _decode_mb = ModelRunner._decode_mb
         _prefill_mb = ModelRunner._prefill_mb
+        _pins_prefill_window = ModelRunner._pins_prefill_window
+        state_specs = ()
 
     r = _FakeRunner()
     dec = set(r.reachable_decode_families())
@@ -202,9 +204,24 @@ def test_reachable_families_cover_observed_dispatches():
                 t = pow2_bucket(chunk, t_floor, cfg.max_num_batched_tokens)
                 if rows > 1 and rows * t > cfg.max_num_batched_tokens:
                     continue  # scheduler admission shrinks this away
-                mb = r._prefill_mb(live, windowed)
+                mb = r._prefill_mb(live, windowed, b)
                 assert (b, t, mb, windowed) in pre, (rows, chunk, live,
                                                      windowed)
+
+    # A model with recurrent state pins the prefill window at the full
+    # width: one windowed family a (rows, t), and still every dispatch's.
+    r.state_specs = ("some",)
+    pinned = set(r.reachable_prefill_families())
+    assert {f[2] for f in pinned} == {full_mb}
+    assert len(pinned) < len(pre)
+    for rows in (1, 3, cfg.max_prefill_seqs):
+        b = 1 if rows == 1 else pow2_bucket(
+            max(rows, cfg.max_prefill_seqs), 1, cfg.max_num_seqs)
+        for live in (1, full_mb // 2, full_mb):
+            for windowed in (False, True):
+                assert (b, t_floor, r._prefill_mb(live, windowed, b),
+                        windowed) in pinned
+    r.state_specs = ()
 
     # window impl: quantized mb ladder has at most 4 values.
     r.attn_impl = "window"

@@ -114,6 +114,27 @@ REGISTRY: Tuple[Series, ...] = (
            (ENGINE,), ("catalogue", "kv-economy"),
            "Content-addressed blocks resident in the device prefix cache "
            "(the /prefix_index digest size)"),
+    # ---------------------------------- engine: recurrent-state slots
+    Series("pstpu:state_slots_total", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Recurrent-state slots the block manager hands out, one a "
+           "sequence (0: a K/V-only model)"),
+    Series("pstpu:state_slots_in_use", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Recurrent-state slots held by admitted sequences"),
+    Series("pstpu:state_slot_allocs_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Recurrent-state slots handed out (a sequence takes one with "
+           "its blocks)"),
+    Series("pstpu:state_slot_waits_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Admissions put off because every recurrent-state slot was "
+           "held"),
+    Series("pstpu:prefix_hit_tokens_unserved_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Prompt tokens whose K/V the prefix index held but that were "
+           "prefilled again, because nothing keeps the recurrent state "
+           "after them"),
     Series("pstpu:kv_restore_saved_tokens_total", "counter", ("model_name",),
            (ENGINE,), ("catalogue", "kv-economy"),
            "Prompt tokens restored from the shared KV tier instead of "
