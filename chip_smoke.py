@@ -34,6 +34,7 @@ It finds the package next to this file, not in the caller's cwd.
 """
 
 import argparse
+import functools
 import json
 import os
 import subprocess
@@ -516,25 +517,30 @@ def kernel_child(model: str, rehearse: bool) -> int:
 
 
 # The recurrence of a Gated DeltaNet layer alone, at Olmo-Hybrid-7B's
-# published widths: one decode step of 20 rows (chat-saturated's mean) and of
-# 64, and one 2048-token prefill chunk of one row.
+# published widths: one decode step of 20 live rows (chat-saturated's mean)
+# of a 20-row carry and of a 32-row one (the cell's bucket: the 12 rows that
+# take no token should cost nothing) and of 64 of 64, and one 2048-token
+# prefill chunk of one row.
 GDN_WIDTHS = {"heads": 30, "dk": 96, "dv": 192}
-GDN_STEP_ROWS = (20, 64)
+GDN_STEP_ROWS = ((20, 20), (32, 20), (64, 64))    # (the carry's rows, live)
 GDN_CHUNK_TOKENS = 2048
 GDN_CALLS = 64
-# Layers' states a timed step program cycles through, as a decode program
-# carries its rows' 12 linear layers: ONE layer's rows (44 MB at 20 rows)
-# stay on the chip between chained calls and read faster than HBM allows.
+# Layers of the carry a timed step program cycles through, stepped in place
+# as a decode program steps its rows' 12 linear layers: ONE layer's rows (44
+# MB at 20 rows) stay on the chip between chained calls and read faster than
+# HBM allows.
 GDN_LAYERS = 12
 
 
 def gdn_child(rehearse: bool) -> int:
-    """``--gdn``: times ``gdn_step`` and ``gdn_chunk`` (ops/gated_delta.py)
-    alone on the chip, calls chained through the state inside one program
-    (a step program through ``GDN_LAYERS`` layers' states in turn), and
-    prints µs a call beside the least time the chip's peaks allow their
-    bytes and FLOPs (benchmarks/chip/lib/shapes_hybrid.py's count of ONE
-    layer). Run by no benchmark cell and no other phase."""
+    """``--gdn``: times ``gdn_step_at`` and ``gdn_chunk``
+    (ops/gated_delta.py) alone on the chip, calls chained through the state
+    inside one program (a step program through the ``GDN_LAYERS`` layers of
+    a donated carry in turn, in place), and prints µs a call beside the
+    least time the chip's peaks allow their bytes and FLOPs
+    (benchmarks/chip/lib/shapes_hybrid.py's count of ONE layer, the LIVE
+    rows' state). Fails where the step program holds the ``jnp`` form on a
+    TPU. Run by no benchmark cell and no other phase."""
     import jax
     import jax.numpy as jnp
 
@@ -552,7 +558,7 @@ def gdn_child(rehearse: bool) -> int:
     layers = GDN_LAYERS
     if rehearse:
         widths, rows_list, tokens, calls, layers = \
-            {"heads": 4, "dk": 16, "dv": 32}, (2, 3), 80, 2, 2
+            {"heads": 4, "dk": 16, "dv": 32}, ((2, 2), (3, 2)), 80, 2, 2
     h, dk, dv = widths["heads"], widths["dk"], widths["dv"]
     # One linear layer of a config with these widths, for the shapes' count.
     cfg = {"num_attention_heads": h, "hidden_size": h * 128,
@@ -595,30 +601,49 @@ def gdn_child(rehearse: bool) -> int:
                        roofline_pct=100.0 * least / sec)
         return out
 
-    timing, finite = [], True
-    for b in rows_list:
-        state, q, k, v, g, beta = inputs(jax.random.PRNGKey(b), b, 1)
-        state = jnp.tile(state[None], (layers, 1, 1, 1, 1))
-        live = jnp.ones((b,), bool)
+    timing, checks, finite, paths = [], [], True, set()
+    for b, n_live in rows_list:
+        state, *xs = inputs(jax.random.PRNGKey(b), b, 1)
+        xs = tuple(x[:, 0] for x in xs)           # q, k, v, g, beta
+        carry = jnp.tile(state[:, None], (1, layers, 1, 1, 1))
+        # The live rows spread over the carry, as a bucket's are.
+        live = (jnp.arange(b) * n_live) % b < n_live
 
-        @jax.jit
-        def steps(states, q, k, v, g, beta):
-            def one(i, carry):
-                states, acc = carry
-                at = i % layers
-                o, state = gd.gdn_step(
-                    jax.lax.dynamic_index_in_dim(states, at, 0, False),
-                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live)
-                return jax.lax.dynamic_update_index_in_dim(
-                    states, state, at, 0), acc + o
+        def steps(carry, *xs):
+            def one(i, both):
+                carry, acc = both
+                o, carry = gd.gdn_step_at(carry, i % layers, *xs, live,
+                                          interpret=rehearse)
+                return carry, acc + o
             return jax.lax.fori_loop(
-                0, calls * layers, one, (states, jnp.zeros((b, h, dv))))
+                0, calls * layers, one, (carry, jnp.zeros((b, h, dv))))
 
-        sec = best_of(steps, (state, q, k, v, g, beta), calls * layers)
-        finite &= bool(jnp.all(jnp.isfinite(
-            steps(state, q, k, v, g, beta)[1])))
-        timing.append(entry("gdn_step", sec,
-                            shapes_hybrid.gdn_step(cfg, b), rows=b))
+        # Once against the jnp form: live rows agree, the others' state is
+        # bit for bit what it was.
+        want_o, want = jax.jit(gd.gdn_step_at_jnp)(carry, 1, *xs, live)
+        got_o, got = jax.jit(functools.partial(
+            gd.gdn_step_at, interpret=rehearse))(carry, 1, *xs, live)
+        err = max(float(jnp.max(jnp.abs(got - want))),
+                  float(jnp.max(jnp.abs(got_o - want_o))))
+        same = bool(jnp.all(jnp.where(
+            live[:, None, None, None, None], True, got == carry)))
+        checks.append({"rows": b, "live": n_live, "max_abs_err": err,
+                       "others_untouched": same})
+        finite &= err < 1e-5 and same
+        steps = jax.jit(steps, donate_argnums=0).lower(carry, *xs).compile()
+        path = gd.step_path(steps.as_text())
+        paths.add(path)
+        best = float("inf")
+        for _ in range(6):        # the first run is the warm-up
+            t0 = time.perf_counter()
+            carry, acc = jax.block_until_ready(steps(carry, *xs))
+            best = min(best, time.perf_counter() - t0)
+        finite &= bool(jnp.all(jnp.isfinite(acc)))
+        timing.append(entry(
+            "gdn_step", best / (calls * layers),
+            shapes_hybrid.gdn_step(cfg, n_live), rows=b, live=n_live,
+            path=path))
+    finite &= rehearse or paths == {"pallas"}
     state, q, k, v, g, beta = inputs(jax.random.PRNGKey(7), 1, tokens)
     lens = jnp.full((1,), tokens, jnp.int32)
 
@@ -637,7 +662,8 @@ def gdn_child(rehearse: bool) -> int:
     timing.append(entry("gdn_chunk", sec,
                         shapes_hybrid.gdn_chunk(cfg, tokens), tokens=tokens))
     emit({"phase": "gdn", "widths": widths, "calls": calls,
-          "step_layers": layers, "timing": timing, "peak": peak, "device": device, "ok": finite})
+          "step_layers": layers, "checks": checks, "timing": timing,
+          "peak": peak, "device": device, "ok": finite})
     return 0 if finite else 1
 
 
@@ -872,6 +898,8 @@ def verdict(lines: list, chips: int, full_depth: int,
             if prog["pool_copies"]:
                 faults.append(
                     f"{name}: {prog['pool_copies']} whole-pool copies")
+            if prog.get("gdn_step") == "xla" and not rehearsal:
+                faults.append(f"{name}: gdn_step is not the Pallas kernel")
     if not boots:
         faults.append("no engine report: nothing was served")
     for boot in boots:

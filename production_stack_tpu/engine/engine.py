@@ -226,21 +226,24 @@ class ServingEngine:
 
         self.loop_spans = LoopSpans()
         # Decode work counted where it happens (host integers the loop
-        # holds; no device sync), all three at APPLY so that over any
+        # holds; no device sync), all at APPLY so that over any
         # window row-steps less wasted row-steps is exactly the tokens
         # decode delivered. A STEP is one iteration of the fused decode
         # loop the device ran: the while_loop stops at the largest per-row
         # budget, the scan runs all K. A ROW-STEP is one real row in one
-        # such step (padding rows of the shape bucket are not rows). A
-        # row-step is WASTED when its token was not delivered: the row hit
-        # EOS / max_tokens / a stop string earlier in the train, was
-        # aborted or preempted, failed the epoch check, or its fetch
-        # failed. Under a speculative mode a step is one draft/verify
-        # cycle of the K the dispatch is allowed (the host cannot see an
-        # early exit) and a row-step can deliver up to N+1 tokens, so
-        # wasted is clamped at 0 there and says little: read pstpu:spec_*.
+        # such step (padding rows of the shape bucket are not rows; a
+        # BUCKET row-step counts them too: what a program that works on
+        # every row of its shapes pays for). A row-step is WASTED when its
+        # token was not delivered: the row hit EOS / max_tokens / a stop
+        # string earlier in the train, was aborted or preempted, failed
+        # the epoch check, or its fetch failed. Under a speculative mode a
+        # step is one draft/verify cycle of the K the dispatch is allowed
+        # (the host cannot see an early exit) and a row-step can deliver
+        # up to N+1 tokens, so wasted is clamped at 0 there and says
+        # little: read pstpu:spec_*.
         self.decode_steps_total = 0
         self.decode_row_steps_total = 0
+        self.decode_bucket_row_steps_total = 0
         self.decode_row_steps_wasted_total = 0
         # telemetry
         from production_stack_tpu.engine.metrics import (
@@ -822,6 +825,8 @@ class ServingEngine:
             row_steps = steps * len(batch.seqs)
             self.decode_steps_total += steps
             self.decode_row_steps_total += row_steps
+            self.decode_bucket_row_steps_total += \
+                steps * self.runner.decode_bucket(len(batch.seqs))
             self.decode_row_steps_wasted_total += max(
                 0, row_steps - delivered)
 
@@ -1557,6 +1562,8 @@ class ServingEngine:
             **self.loop_spans.counters(),
             "decode_steps_total": self.decode_steps_total,
             "decode_row_steps_total": self.decode_row_steps_total,
+            "decode_bucket_row_steps_total":
+                self.decode_bucket_row_steps_total,
             "decode_row_steps_wasted_total":
                 self.decode_row_steps_wasted_total,
             # How often the sampler's conditional picks engage

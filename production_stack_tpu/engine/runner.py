@@ -44,6 +44,7 @@ from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
 from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import KVView, gather_window
+from production_stack_tpu.ops.gated_delta import step_path
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
     read_state_rows,
@@ -2034,11 +2035,15 @@ class ModelRunner:
                 lp_c_buf, lp_t_buf, lp_i_buf, last_token, emit_buf,
                 spec_stats, spec_k, spec_v, spec_pos)
 
+    def decode_bucket(self, rows: int) -> int:
+        """Rows of the decode program that ``rows`` sequences run in."""
+        return _bucket(rows, 1, max(1, self.config.max_num_seqs))
+
     def _issue_decode(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config
         seqs = batch.seqs
         k = batch.num_steps
-        b = _bucket(len(seqs), 1, max(1, cfg.max_num_seqs))
+        b = self.decode_bucket(len(seqs))
         mb = self._decode_mb(max(len(s.block_ids) for s in seqs))
 
         packed = np.zeros((NUM_SCALARS * b + b * mb,), np.int32)
@@ -3042,10 +3047,15 @@ class ModelRunner:
         widest decode family, cached-window too where that exists, and
         the widest prefill family with and without a history window) and
         report what each does to the KV pools: ``pool_copies`` — ``copy``
-        operations whose result has a pool's shape (0: the donated pools
-        are updated in place, ops/kv_write.py) — and the program's
-        temporaries beside one payload pool's bytes. Nothing runs; with a
-        compile cache the programs are the ones warmup left there."""
+        operations whose result has a pool's shape, or the shape of the
+        rows' state a decode program carries through its loops (0: the
+        donated pools are updated in place, ops/kv_write.py, and so is the
+        carried state, ops/gated_delta.py) — and the program's temporaries
+        beside one payload pool's bytes; for a decode program of a model
+        with recurrent state, ``gdn_step``: which execution of the
+        recurrence's step it holds (``"pallas"`` / ``"xla"``). Nothing
+        runs; with a compile cache the programs are the ones warmup left
+        there."""
         pools = [self.kv_k] + [
             x for x in (*self._scale_pool_args(),
                         *self._spec_pool_args()[1:3]) if x.size > 1
@@ -3066,14 +3076,20 @@ class ModelRunner:
         for kind, fam, lowered in programs:
             compiled = lowered.compile()
             mem = compiled.memory_analysis()
+            text = compiled.as_text()
+            carried = [jax.ShapeDtypeStruct((fam[0], *x.shape[1:]), x.dtype)
+                       for x in self.state_pools] if kind == "decode" else []
             out.append({
                 "program": kind, "family": list(fam),
-                "pool_copies": len(pool_copies(compiled.as_text(), pools)),
+                "pool_copies": len(pool_copies(text, pools + carried)),
                 "temp_bytes": int(mem.temp_size_in_bytes),
                 "alias_bytes": int(mem.alias_size_in_bytes),
                 "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
                 "state_pool_bytes": self.state_pool_bytes,
             })
+            path = step_path(text)
+            if path:
+                out[-1]["gdn_step"] = path
         return out
 
     def _warmup_compile_prepass(self) -> int:

@@ -263,10 +263,13 @@ def _full_layer(cfg, rope, positions, chunk_lens, hidden, lp, view, layer):
             v.transpose(2, 0, 1, 3))
 
 
-def _linear_layer(cfg, chunk_lens, hidden, lp, rec, conv):
+def _linear_layer(cfg, chunk_lens, hidden, lp, rec, conv, at, interpret):
     """One Gated DeltaNet layer over [B, T] tokens from (rec: the packed
     state [B, H/P, dk, P*dv] f32, conv [B, *its spec's shape]); returns
-    (hidden, rec, conv) after each row's ``chunk_lens`` valid tokens."""
+    (hidden, rec, conv) after each row's ``chunk_lens`` valid tokens. A
+    decode step (T == 1) takes and returns as ``rec`` the rows' WHOLE
+    carried state [B, n_linear, H/P, dk, P*dv], of which layer ``at`` is
+    stepped where it lies (ops/gated_delta.py:gdn_step_at)."""
     b, t, _ = hidden.shape
     conv_shape = conv.shape
     conv = conv.reshape(b, cfg.linear_conv_kernel_dim - 1, -1)
@@ -290,8 +293,9 @@ def _linear_layer(cfg, chunk_lens, hidden, lp, rec, conv):
             y[..., lh * dk:2 * lh * dk].reshape(b, t, lh, dk),
             y[..., 2 * lh * dk:].reshape(b, t, lh, dv))
         if t == 1:
-            o, rec = gd.gdn_step(rec, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                 beta[:, 0], live)
+            o, rec = gd.gdn_step_at(rec, at, q[:, 0], k[:, 0], v[:, 0],
+                                    g[:, 0], beta[:, 0], live,
+                                    interpret=interpret)
             o = o[:, None]
         else:
             o, rec = gd.gdn_chunk(rec, q, k, v, g, beta, chunk_lens)
@@ -351,24 +355,28 @@ def forward(
         return jax.tree.map(
             lambda x: jax.lax.dynamic_index_in_dim(x, at, 0, False), stack)
 
-    # The recurrence's own scope: a layer's state is taken out of the rows'
-    # carried state and put back under it too, because XLA fuses the
-    # recurrence's last pass (S + k u^T) into that update and names the
-    # fusion after it: the scope's time then covers the read and the write
-    # its bytes count (benchmarks/chip/lib/shapes_hybrid.py).
-    inner = "gdn_step" if token_ids.shape[1] == 1 else "gdn_chunk"
+    # The recurrence's own scope. A prefill chunk's layer state is taken out
+    # of the rows' carried state and put back under it too, because XLA
+    # fuses the chunk's last pass into that update and names the fusion
+    # after it. A decode step hands the carry itself to ``gdn_step_at``,
+    # which steps its layer ``at`` in place: no layer of it is ever sliced
+    # out. The scope's time covers the read and the write the state's
+    # bytes count either way (benchmarks/chip/lib/shapes_hybrid.py).
+    decode = token_ids.shape[1] == 1
+    inner = "gdn_step" if decode else "gdn_chunk"
 
     def linear_step(carry, at):
         hidden, rec_all, conv_all = carry
         with jax.named_scope("attn_core"), jax.named_scope(inner):
-            rec = jax.lax.dynamic_index_in_dim(rec_all, at, 1, False)
+            rec = rec_all if decode else \
+                jax.lax.dynamic_index_in_dim(rec_all, at, 1, False)
             conv = jax.lax.dynamic_index_in_dim(conv_all, at, 1, False)
         hidden, rec, conv = _linear_layer(
             cfg, chunk_lens, hidden, layer_of(layers["linear"], at),
-            rec, conv)
+            rec, conv, at, view.interpret)
         with jax.named_scope("attn_core"), jax.named_scope(inner):
-            rec_all = jax.lax.dynamic_update_index_in_dim(
-                rec_all, rec, at, 1)
+            rec_all = rec if decode else \
+                jax.lax.dynamic_update_index_in_dim(rec_all, rec, at, 1)
             conv_all = jax.lax.dynamic_update_index_in_dim(
                 conv_all, conv.astype(conv_all.dtype), at, 1)
         return (hidden, rec_all, conv_all), None

@@ -25,8 +25,10 @@ axis, ``[H/P, dk, P*dv]``, so that the minor axis is a whole number of the
 TPU's 128 lanes (dv = 192 alone would be stored as 256: a third more bytes
 to hold, read and write, every row, every layer, every step). ``gdn_step``
 computes in that layout (the per-head vectors are spread to it, which costs
-nothing beside the state's own traffic); ``gdn_chunk`` unpacks the state it
-starts from and packs the one it leaves, once a chunk.
+nothing beside the state's own traffic), on a TPU as one Pallas kernel in
+place in the decode loop's carried state (``gdn_step_at``);
+``gdn_chunk`` unpacks the state it starts from and packs the one it
+leaves, once a chunk.
 
 Everything here is float32: state, scores, accumulators. The chunkwise
 form's matrix products run at ``Precision.HIGHEST``: at the default a TPU
@@ -184,23 +186,72 @@ def delta_step(state: jax.Array,   # [B, H, dk, dv] f32
     return jnp.sum(state * q[..., None], axis=-2), state
 
 
-def gdn_step(state, q, k, v, g, beta, live):
-    """``delta_step`` for one decode step of a batch on the PACKED state
-    [B, H/P, dk, P*dv]: a row that is not ``live`` (its step delivers
-    nothing) keeps its state. Other inputs as ``delta_step`` after
-    ``prepare``; ``live`` [B] bool. Returns (o [B, H, dv], packed state)."""
+def gdn_step_at_jnp(carry, at, q, k, v, g, beta, live):
+    """``gdn_step_at`` as plain ``jnp``: the statement of the step, the
+    path of a backend without the kernel, and the tests' oracle. The
+    layer's state is taken out of the carry, stepped over EVERY row (a row
+    that is not live with its gates zeroed) and put back."""
+    state = jax.lax.dynamic_index_in_dim(carry, at, 1, False)
+    b, h, dv = v.shape
+    p = h // state.shape[1]
+    g = jnp.where(live[:, None], g, 0.0)
+    beta = jnp.where(live[:, None], beta, 0.0)
+    kx = _spread_k(k, p, dv)
+    state = state * jnp.exp(_spread_h(g, p, dv))[:, :, None, :]
+    kv_mem = jnp.sum(state * kx, axis=-2)                 # [B, Hp, P*dv]
+    u = (v.reshape(b, h // p, p * dv) - kv_mem) * _spread_h(beta, p, dv)
+    state = state + kx * u[:, :, None, :]
+    o = jnp.sum(state * _spread_k(q, p, dv), axis=-2).reshape(b, h, dv)
+    return (jnp.where(live[:, None, None], o, 0.0),
+            jax.lax.dynamic_update_index_in_dim(carry, state, at, 1))
+
+
+def gdn_step_at(carry, at, q, k, v, g, beta, live, *, interpret=False):
+    """``delta_step`` for one decode step of a batch on layer ``at`` of the
+    rows' carried PACKED state [B, n_linear, H/P, dk, P*dv]: a row that is
+    not ``live`` (its step delivers nothing) keeps its state, and its ``o``
+    is zeros. Other inputs as ``delta_step`` after ``prepare``;
+    ``live`` [B] bool. Returns (o [B, H, dv], the carry).
+
+    One algorithm, two executions, chosen HERE by what can be seen: where
+    the packed shape fits it, a program lowered for a TPU holds the Pallas
+    kernel (ops/pallas/gated_delta.py: in place in the carry, a live row's
+    state read once and written once, a row that is not live untouched),
+    and so does any program with ``interpret`` set (the runner's Pallas
+    interpret switch: a CPU's tests); every other holds the ``jnp`` form.
+    The platform is the one the program is LOWERED for
+    (``lax.platform_dependent``), not the process's default backend: a
+    program compiled for a described chip holds what the chip will run."""
+    from production_stack_tpu.ops.pallas.gated_delta import (
+        gdn_step_in_place,
+        supports_step_kernel,
+    )
+
+    args = (carry, jnp.asarray(at, jnp.int32), q, k, v, g, beta, live)
     with jax.named_scope("gdn_step"):
-        b, h, dv = v.shape
-        p = h // state.shape[1]
-        g = jnp.where(live[:, None], g, 0.0)
-        beta = jnp.where(live[:, None], beta, 0.0)
-        kx = _spread_k(k, p, dv)
-        state = state * jnp.exp(_spread_h(g, p, dv))[:, :, None, :]
-        kv_mem = jnp.sum(state * kx, axis=-2)                 # [B, Hp, P*dv]
-        u = (v.reshape(b, h // p, p * dv) - kv_mem) * _spread_h(beta, p, dv)
-        state = state + kx * u[:, :, None, :]
-        o = jnp.sum(state * _spread_k(q, p, dv), axis=-2)
-        return o.reshape(b, h, dv), state
+        if not supports_step_kernel(v.shape[1], carry.shape[2:]):
+            return gdn_step_at_jnp(*args)
+        if interpret:
+            return gdn_step_in_place(*args, interpret=True)
+        return jax.lax.platform_dependent(
+            *args, tpu=gdn_step_in_place, default=gdn_step_at_jnp)
+
+
+def step_path(hlo_text: str):
+    """Which execution of ``gdn_step_at`` a compiled program
+    (``as_text()``) holds: ``"pallas"``, ``"xla"``, or None where it holds
+    no decode step of the recurrence."""
+    if "gdn_step_in_place" in hlo_text:
+        return "pallas"
+    return "xla" if "/gdn_step/" in hlo_text else None
+
+
+def gdn_step(state, q, k, v, g, beta, live, *, interpret=False):
+    """``gdn_step_at`` on a state of one layer, [B, H/P, dk, P*dv]:
+    returns (o [B, H, dv], packed state)."""
+    o, carry = gdn_step_at(state[:, None], 0, q, k, v, g, beta, live,
+                           interpret=interpret)
+    return o, carry[:, 0]
 
 
 def _unit_lower_inverse(lower: jax.Array) -> jax.Array:

@@ -45,7 +45,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "loop_fetch_wait_seconds_total",
                 "loop_apply_seconds_total", "loop_idle_seconds_total",
                 "loop_other_seconds_total", "decode_steps_total",
-                "decode_row_steps_total", "decode_row_steps_wasted_total",
+                "decode_row_steps_total", "decode_bucket_row_steps_total",
+                "decode_row_steps_wasted_total",
                 "sample_dispatches_total", "sample_dispatches_greedy_total",
                 "sample_dispatches_filtered_total",
                 "live_tok_per_s",
@@ -339,6 +340,12 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:decode_row_steps_total counter",
         f"pstpu:decode_row_steps_total{label} "
         f"{s['decode_row_steps_total']}",
+        "# HELP pstpu:decode_bucket_row_steps_total Rows of the decode "
+        "program's shape bucket (padding included) times the steps the "
+        "dispatch ran",
+        "# TYPE pstpu:decode_bucket_row_steps_total counter",
+        f"pstpu:decode_bucket_row_steps_total{label} "
+        f"{s['decode_bucket_row_steps_total']}",
         "# HELP pstpu:decode_row_steps_wasted_total Decode row-steps "
         "whose token was not delivered (row finished earlier in the "
         "train, aborted, preempted, or its fetch failed)",

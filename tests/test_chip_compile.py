@@ -348,8 +348,11 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     """The decode and prefill programs of olmo-hybrid-7b-d16's envelope
     (deployment.json's flags, published widths) compile for a v5e, fit its
     HBM beside their arguments, and copy no pool: K/V and the recurrent
-    state are gathered by row and written back in place."""
+    state are gathered by row and written back in place. The decode
+    program steps the recurrence in place in its loops' carried state
+    (ops/pallas/gated_delta.py): no copy of the carry either."""
     from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.gated_delta import step_path
     from production_stack_tpu.ops.kv_write import pool_copies
 
     r = _described_runner(
@@ -361,18 +364,24 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     aparams = r._abstract_params()
-    if program.startswith("decode"):
+    decode = program.startswith("decode")
+    if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
         lowered = r._lower_prefill(aparams, 1, 2048, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
-    assert pool_copies(text, [r.kv_k, *r.state_pools]) == []
-    # The Mosaic kernel: the full layers' decode, and nothing of prefill.
-    assert ('custom_call_target="tpu_custom_call"' in text) == \
-        program.startswith("decode")
+    # The 32 rows' recurrent state as the decode loops carry it.
+    carry = jax.ShapeDtypeStruct((32, 12, 15, 96, 384), jnp.float32)
+    assert pool_copies(text, [r.kv_k, *r.state_pools, carry]) == []
+    # The Mosaic kernels: the full layers' paged decode and the linear
+    # layers' step, and nothing of prefill.
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (2 if decode else 0)
+    assert step_path(text) == ("pallas" if decode else None)
     mem = compiled.memory_analysis()
     # The rows' state is ONE loop carry (0.85 GB at 32 rows), not one a
-    # layer: the decode program's temporaries stay under 1.5 GB.
+    # layer, and the step kernel is aliased to it: the decode program's
+    # temporaries stay under 1.5 GB (1.246 GB, as before the kernel).
     assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

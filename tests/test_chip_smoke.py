@@ -93,6 +93,10 @@ BAD_RUNS = {
     "pool-copied-whole": _with((2, "pool_programs"),
                                _programs(pool_copies=4)),
     "no-program-audit": _with((2, "pool_programs"), []),
+    # A model with recurrent state whose decode program holds the jnp form
+    # of the step on a TPU (ops/gated_delta.py:gdn_step_at).
+    "recurrence-step-not-the-kernel": _with((2, "pool_programs"),
+                                            _programs(gdn_step="xla")),
 }
 
 
@@ -109,6 +113,11 @@ def test_verdict_fails_when_not_on_the_chip(what, capsys):
     assert final["ok"] is False
     faults = json.loads(capsys.readouterr().out.splitlines()[-1])
     assert faults["phase"] == "verdict" and faults["faults"]
+
+
+def test_verdict_passes_the_recurrences_kernel():
+    lines = _with((2, "pool_programs"), _programs(gdn_step="pallas"))
+    assert chip_smoke.verdict(lines, 1, chip_smoke.FULL_DEPTH)["ok"] is True
 
 
 def test_rehearsal_never_passes():

@@ -309,3 +309,55 @@ def test_admission_waits_for_a_state_slot():
     assert stats["state_slots_total"] == 2
     assert eng.report()["engine"]["state_bytes"] == \
         eng.runner.state_pool_bytes > 0
+
+
+# ---- what says that the decode step's kernel engages ---------------------------
+@pytest.mark.parametrize("engine_args,path", [
+    ({}, "xla"),
+    # The runner's Pallas interpret switch: a CPU engine on the paged path
+    # runs its kernels through the interpreter, this one too.
+    ({"attn_impl": "paged"}, "pallas"),
+], ids=["window-xla", "paged-pallas"])
+async def test_the_served_surface_says_which_step_runs_and_counts_bucket_rows(
+        monkeypatch, engine_args, path):
+    """``GET /debug/programs`` names the execution of ``gdn_step`` each
+    decode program holds, and bucket row-steps count the padding rows that
+    row-steps do not: three rows decode in a 4-row program."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from production_stack_tpu.server.api_server import APIServer
+
+    mc = dataclasses.replace(TINY_OLMO_HYBRID, name=f"tiny-says-{path}",
+                             num_heads=3, num_kv_heads=3, head_dim=128)
+    monkeypatch.setitem(model_configs.NAMED_CONFIGS, mc.name, mc)
+    client = TestClient(TestServer(APIServer(
+        make_engine(mc.name, **engine_args)).build_app()))
+    await client.start_server()
+    try:
+        done = await asyncio.gather(*(client.post("/v1/completions", json={
+            "model": mc.name, "prompt": prompt(12, 70 + i),
+            "max_tokens": 41, "temperature": 0, "ignore_eos": True})
+            for i in range(3)))
+        assert [r.status for r in done] == [200] * 3
+        text = await (await client.get("/metrics")).text()
+        programs = (await (await client.get("/debug/programs")).json())[
+            "programs"]
+    finally:
+        await client.close()
+    sample = {ln.split("{")[0]: float(ln.rsplit(" ", 1)[1])
+              for ln in text.splitlines() if ln.startswith("pstpu:decode_")}
+    rows, bucket, wasted = (sample[f"pstpu:decode_{k}_total"] for k in (
+        "row_steps", "bucket_row_steps", "row_steps_wasted"))
+    assert rows - wasted == 3 * 40       # each request's first is prefill's
+    # Five trains of 8 a row: the three rode some of them together, in
+    # the 4-row program.
+    assert bucket > rows > 0
+    assert {p["program"] for p in programs} == {"decode", "prefill"}
+    # (Not ``pool_copies``: the interpreter's loops copy the carry that
+    # the chip's kernel updates where it lies; tests/test_chip_compile.py
+    # holds the program compiled for the chip to that.)
+    for p in programs:
+        assert p.get("gdn_step") == \
+            (path if p["program"] == "decode" else None)

@@ -14,13 +14,22 @@ import pytest
 
 from production_stack_tpu.models.config import TINY_OLMO_HYBRID, ModelConfig
 from production_stack_tpu.ops import gated_delta as gd
+from production_stack_tpu.ops.pallas.gated_delta import supports_step_kernel
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---- (g): the chunkwise form is the recurrence ------------------------------
+# The two executions of the decode step (ops/gated_delta.py:gdn_step_at):
+# the Pallas kernel, here through the interpreter, and the ``jnp`` form, which
+# is what a program lowered for a CPU holds otherwise.
+PATHS = pytest.mark.parametrize(
+    "interpret", [False, True], ids=["xla", "pallas"])
+
+
+@PATHS
 @pytest.mark.parametrize("t", [1, 63, 64, 65, 200])
-def test_g_gdn_chunk_is_gdn_step_applied_t_times(t):
+def test_g_gdn_chunk_is_gdn_step_applied_t_times(t, interpret):
     b, h, dk, dv = 2, 4, 16, 32
     ks = jax.random.split(jax.random.PRNGKey(t), 7)
     q, k = (jax.random.normal(ks[i], (b, t, h, dk)) for i in (0, 1))
@@ -43,7 +52,7 @@ def test_g_gdn_chunk_is_gdn_step_applied_t_times(t):
     for i in range(t):
         live = i < lens
         o, packed = gd.gdn_step(packed, q[:, i], k[:, i], v[:, i], g[:, i],
-                                beta[:, i], live)
+                                beta[:, i], live, interpret=interpret)
         o_plain, stepped = gd.delta_step(
             want_state, q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i])
         want_state = jnp.where(live[:, None, None, None], stepped, want_state)
@@ -56,6 +65,122 @@ def test_g_gdn_chunk_is_gdn_step_applied_t_times(t):
     # Float32 both sides, sums in another order: 1e-5 of values of order 1.
     assert float(jnp.max(jnp.abs((out - jnp.stack(outs, 1)) * valid))) < 1e-5
     assert float(jnp.max(jnp.abs(state - want_state))) < 1e-5
+
+
+# ---- the decode step's kernel (ops/pallas/gated_delta.py), interpreted ------
+def _step_inputs(seed, b, h, dk, dv, steps=1, layers=1):
+    """(carry [b, layers, H/P, dk, P*dv], then q, k, v, g, beta
+    [b, steps, ...]) as a layer hands them to the step: prepared, gated."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
+    q, k = (jax.random.normal(ks[i], (b, steps, h, dk)) for i in (0, 1))
+    v = jax.random.normal(ks[2], (b, steps, h, dv))
+    beta, g = gd.gates(
+        jax.random.normal(ks[3], (b, steps, h)),
+        jax.random.normal(ks[4], (b, steps, h)),
+        jnp.log(jax.random.uniform(ks[5], (h,), minval=0.1, maxval=16.0)),
+        jnp.ones((h,)), True)
+    carry = 0.5 * jax.random.normal(
+        ks[6], (b, layers, *gd.packed_shape(h, dk, dv)))
+    return (carry, *gd.prepare(q, k, v), g, beta)
+
+
+def _relative(got, want):
+    return float(jnp.max(jnp.abs(got - want))
+                 / jnp.maximum(jnp.max(jnp.abs(want)), 1e-30))
+
+
+@pytest.mark.parametrize("h,dk,dv", [(30, 96, 192), (4, 64, 128)],
+                         ids=["published-P2", "P1"])
+def test_step_kernel_is_delta_step_token_by_token(h, dk, dv):
+    """Eight tokens through the kernel against the plain per-head
+    recurrence, a row dropping out on the way: at Olmo-Hybrid-7B's
+    published widths (two heads a packed row) and at one head a row."""
+    b, steps = 3, 8
+    carry, q, k, v, g, beta = _step_inputs(h, b, h, dk, dv, steps)
+    assert supports_step_kernel(h, carry.shape[2:])
+    want = gd.unpack_state(carry[:, 0], h)
+    lens = jnp.array([steps, 5, 0])
+    for i in range(steps):
+        live = i < lens
+        o, carry = gd.gdn_step_at(
+            carry, 0, q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i], live,
+            interpret=True)
+        o_plain, stepped = gd.delta_step(
+            want, q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i])
+        want = jnp.where(live[:, None, None, None], stepped, want)
+        assert _relative(o, o_plain * live[:, None, None]) < 1e-5
+        assert _relative(gd.unpack_state(carry[:, 0], h), want) < 1e-5
+
+
+@pytest.mark.parametrize("at", [0, 1, 2])
+def test_step_kernel_touches_only_live_rows_of_layer_at(at):
+    """On a carry of three layers: every other layer and every row that is
+    not live are bit for bit what they were, a row that is not live gets
+    zeros, and the live rows agree with the ``jnp`` form."""
+    h, dk, dv, b = 4, 64, 128, 5
+    carry, q, k, v, g, beta = _step_inputs(at, b, h, dk, dv, layers=3)
+    live = jnp.array([True, False, True, True, False])
+    args = (at, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live)
+    o, got = gd.gdn_step_at(carry, *args, interpret=True)
+    want_o, want = gd.gdn_step_at(carry, *args)
+    others = np.array([i != at for i in range(3)])
+    np.testing.assert_array_equal(got[:, others], carry[:, others])
+    np.testing.assert_array_equal(got[~live], carry[~live])
+    np.testing.assert_array_equal(o[~live], 0.0)
+    assert bool(jnp.all(got[live, at] != carry[live, at]))
+    assert _relative(got[live], want[live]) < 1e-5
+    assert _relative(o, want_o) < 1e-5
+
+
+def test_step_kernel_with_no_live_row_is_a_no_op():
+    carry, q, k, v, g, beta = _step_inputs(3, 4, 4, 64, 128, layers=2)
+    o, got = gd.gdn_step_at(
+        carry, 1, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+        jnp.zeros((4,), bool), interpret=True)
+    np.testing.assert_array_equal(got, carry)
+    np.testing.assert_array_equal(o, 0.0)
+
+
+@PATHS
+def test_gdn_step_on_one_layers_state_is_the_step_on_a_carry(interpret):
+    """``gdn_step``'s signature of before the kernel (a state of ONE
+    layer): the same answer through either execution, and the answer of
+    the plain recurrence."""
+    h, dk, dv, b = 4, 64, 128, 3
+    carry, q, k, v, g, beta = _step_inputs(11, b, h, dk, dv)
+    live = jnp.array([True, True, False])
+    args = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+    o, state = gd.gdn_step(carry[:, 0], *args, live, interpret=interpret)
+    assert state.shape == carry[:, 0].shape
+    want_o, want = gd.delta_step(gd.unpack_state(carry[:, 0], h), *args)
+    np.testing.assert_array_equal(state[2], carry[2, 0])
+    assert _relative(o[:2], want_o[:2]) < 1e-5
+    assert _relative(gd.unpack_state(state, h)[:2], want[:2]) < 1e-5
+
+
+@pytest.mark.parametrize("h,dk,dv,fits", [
+    (30, 96, 192, True), (4, 16, 32, True), (16, 128, 128, True),
+    (3, 64, 96, False),      # P*dv is not whole lanes
+    (4, 12, 128, False),     # dk is not whole sublanes
+    (4, 256, 128, False),    # dk is wider than the tile k and q transpose in
+], ids=lambda x: str(x))
+def test_the_step_falls_back_where_the_packed_shape_does_not_fit(
+        h, dk, dv, fits):
+    """Which execution runs is decided by the packed shape alone (and the
+    backend): a shape the kernel does not take gets the ``jnp`` form even
+    with the interpreter on, and the same answer."""
+    carry, q, k, v, g, beta = _step_inputs(5, 2, h, dk, dv)
+    assert supports_step_kernel(h, carry.shape[2:]) == fits
+    args = (0, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+            jnp.array([True, False]))
+    text = jax.jit(gd.gdn_step_at, static_argnames="interpret").lower(
+        carry, *args, interpret=True).as_text()
+    # (An interpreted kernel lowers to loops of plain operations under
+    # the kernel's jitted name.)
+    assert ("gdn_step_in_place" in text) == fits
+    o, got = gd.gdn_step_at(carry, *args, interpret=True)
+    want_o, want = gd.gdn_step_at(carry, *args)
+    assert _relative(o, want_o) < 1e-5 and _relative(got, want) < 1e-5
 
 
 # ---- the reference itself ----------------------------------------------------

@@ -450,6 +450,12 @@ REGISTRY: Tuple[Series, ...] = (
            (ENGINE,), ("catalogue", "loop"),
            "Real rows times the steps their decode dispatch ran (padding "
            "rows of the shape bucket are not rows)"),
+    Series("pstpu:decode_bucket_row_steps_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Rows of the decode program's shape bucket (padding rows "
+           "included) times the steps the dispatch ran; (row-steps less "
+           "wasted) over this is the share of a bucket's rows that take a "
+           "token in a step"),
     Series("pstpu:decode_row_steps_wasted_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Decode row-steps whose token was not delivered: the row hit "
