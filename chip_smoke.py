@@ -667,6 +667,211 @@ def gdn_child(rehearse: bool) -> int:
     return 0 if finite else 1
 
 
+# --moe: the latent decode kernel and the experts' grouped matmuls alone, at
+# kanana-2-30b-a3b's published widths (benchmarks/chip/configs/
+# kanana-2-30b-a3b-d8/config.json): rows of a decode bucket and how many of
+# them hold a sequence, and the tokens of a prefill chunk.
+MOE_CONFIG = os.path.join(HERE, "benchmarks", "chip", "configs",
+                          "kanana-2-30b-a3b-d8", "config.json")
+MOE_DECODE_ROWS = ((32, 28), (64, 40))
+MOE_PREFILL_TOKENS = 1024
+MOE_LAYERS = 3          # sparse layers of experts held (2.8 GB in bf16)
+MOE_CALLS = 40
+
+
+def moe_child(rehearse: bool) -> int:
+    """``--moe``: times the paged decode kernel over latent rows
+    (ops/pallas/paged_attention.py) and the experts' sorted grouped matmuls
+    (ops/moe.py:expert_ffn over ops/pallas/grouped_matmul.py) alone on the
+    chip, calls chained inside one program over the layers of one pool and
+    one stack of experts in turn, and prints µs a call beside the least
+    time the chip's peaks allow their bytes and FLOPs
+    (benchmarks/chip/lib/shapes_moe.py's count of ONE layer: the pool rows
+    of the live contexts; the matrices of the experts the routing TOUCHED).
+    Each is checked once against its XLA form, and the kernel's program
+    writes a token's row into the donated pool before it reads it: a
+    pool-shaped ``copy`` in that program fails the run. Run by no benchmark
+    cell and no other phase."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.chip.lib import shapes_moe
+    from production_stack_tpu.ops import moe
+    from production_stack_tpu.ops.attention import (KVView, attend,
+                                                    gather_window)
+    from production_stack_tpu.ops.kv_write import (pool_copies,
+                                                   write_token_runs)
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_latent_stats,
+    )
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "moe", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    with open(MOE_CONFIG) as f:
+        cfg = json.load(f)
+    rows_list, tokens, layers, calls = (
+        MOE_DECODE_ROWS, MOE_PREFILL_TOKENS, MOE_LAYERS, MOE_CALLS)
+    dtype = jnp.bfloat16
+    if rehearse:
+        cfg.update(hidden_size=64, num_attention_heads=4, kv_lora_rank=128,
+                   qk_rope_head_dim=8, qk_nope_head_dim=16, v_head_dim=16,
+                   n_routed_experts=16, num_experts_per_tok=3,
+                   moe_intermediate_size=32, num_hidden_layers=3)
+        rows_list, tokens, layers, calls = ((4, 3),), 24, 2, 2
+        dtype = jnp.float32
+    d = shapes_moe.dims(cfg)
+    one_layer = dict(cfg, num_hidden_layers=1, first_k_dense_replace=0)
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+
+    def entry(name, sec, work, **more):
+        out = {"op": name, **more, "bytes": work["bytes"],
+               "flops": work["flops"], "us_per_call": None,
+               "least_us": None, "roofline_pct": None}
+        if peak and not rehearse:
+            least = max(work["bytes"] / (peak["hbm_gbps"] * 1e9),
+                        work["flops"] / (peak["bf16_tflops"] * 1e12))
+            out.update(us_per_call=sec * 1e6, least_us=least * 1e6,
+                       roofline_pct=100.0 * least / sec)
+        return out
+
+    def best_of(run, args, n, repeats=5):
+        jax.block_until_ready(run(*args))
+        best = float("inf")
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            best = min(best, time.perf_counter() - t0)
+        return best / n
+
+    timing, checks, ok = [], [], True
+    bs, width, rank = 16, d["pool_row"], d["rank"]
+    scale = (d["nope"] + d["rope"]) ** -0.5
+    # ---- the latent kernel over a pool it also writes ---------------------
+    for b, live in rows_list:
+        case = kernel_timing_case(
+            {"rows": b, "live": live, "lens": (96, 2624), "heads": d["heads"],
+             "kv_heads": 1}, dh=width, bs=bs, layers=d["layers"], seed=b)
+        pool = case["k_pool"].astype(dtype).at[..., d["row"]:].set(0)
+        q = case["q"].astype(dtype).at[..., d["row"]:].set(0)
+        tables, kv_lens = case["tables"], case["kv_lens"]
+        mean_context = float(np.asarray(kv_lens).sum()) / live
+        # Once against the XLA path over the same rows gathered.
+        got, m, l = paged_flash_decode_latent_stats(
+            q, pool, tables, kv_lens, 1, block_size=bs, value_dim=rank,
+            scale=scale, interpret=rehearse)
+        win, _ = gather_window(pool, pool[..., :0], tables, bs)
+        zero = jnp.zeros((b, 1, 1, width), dtype)
+        want = attend(q[:, None], zero, None, kv_lens[:, None],
+                      jnp.zeros((b,), jnp.int32), KVView(
+                          win[1], None, kv_lens), scale=scale,
+                      value_dim=rank)[:, 0]
+        alive = (kv_lens > 0)[:, None, None]
+        err = float(jnp.max(jnp.abs(jnp.where(
+            alive, got.astype(jnp.float32) - want.astype(jnp.float32), 0))))
+        checks.append({"op": "latent_decode", "rows": b, "live": live,
+                       "max_abs_err": err})
+        ok &= err < (1e-4 if rehearse else 3e-2)
+
+        def chain(pool, q, tables, kv_lens):
+            def one(i, carry):
+                pool, q = carry
+                layer = i % pool.shape[0]
+                out, _, _ = paged_flash_decode_latent_stats(
+                    q, pool, tables, kv_lens, layer, block_size=bs,
+                    value_dim=rank, scale=scale, interpret=rehearse)
+                # The step's own row goes into the pool where it lies.
+                row = jnp.pad(out, ((0, 0), (0, 0), (0, width - rank)))
+                new = jnp.zeros((pool.shape[0], 1, b, 1, width), pool.dtype
+                                ).at[layer, 0, :, 0].set(row[:, 0])
+                (pool,) = write_token_runs(
+                    (pool,), (new,), tables, jnp.maximum(kv_lens - 1, 0),
+                    (kv_lens > 0).astype(jnp.int32), bs)
+                return pool, q + row * 0
+            return jax.lax.fori_loop(0, calls * pool.shape[0], one,
+                                     (pool, q))
+
+        program = jax.jit(chain, donate_argnums=0).lower(
+            pool, q, tables, kv_lens).compile()
+        copies = pool_copies(program.as_text(), [pool])
+        ok &= rehearse or not copies
+        best = float("inf")
+        for _ in range(6):        # the first run is the warm-up
+            t0 = time.perf_counter()
+            pool, out_q = jax.block_until_ready(
+                program(pool, q, tables, kv_lens))
+            best = min(best, time.perf_counter() - t0)
+        ok &= bool(jnp.all(jnp.isfinite(out_q.astype(jnp.float32))))
+        timing.append(entry(
+            "latent_decode+row_write", best / (calls * d["layers"]),
+            shapes_moe.mla_decode(one_layer, live, mean_context),
+            rows=b, live=live, mean_context=round(mean_context),
+            pool_copies=len(copies)))
+
+    # ---- the experts ----------------------------------------------------------
+    e, k, f, h = d["experts"], d["top_k"], d["expert_ffn"], d["hidden"]
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    w_gate_up = (jax.random.normal(ks[0], (layers * e, h, 2 * f), jnp.float32)
+                 * h ** -0.5).astype(dtype)
+    w_down = (jax.random.normal(ks[1], (layers * e, f, h), jnp.float32)
+              * f ** -0.5).astype(dtype)
+    for name, n, live in [*(("decode", b, lv) for b, lv in rows_list),
+                          ("prefill", tokens, tokens)]:
+        x = jax.random.normal(ks[2], (n, h), jnp.float32).astype(dtype)
+        idx = jnp.stack([jax.random.permutation(kk, e)[:k] for kk in
+                         jax.random.split(ks[3], n)]).astype(jnp.int32)
+        w = jnp.full((n, k), 1.0 / k, jnp.float32)
+        valid = jnp.arange(n) < live
+        touched = int(jnp.unique(idx[:live]).shape[0])
+        got, stats = jax.jit(functools.partial(
+            moe.expert_ffn, interpret=rehearse))(
+                x, idx + e, w, valid, w_gate_up, w_down)
+        # Once against ragged_dot over the same sorted rows.
+        pair = jnp.where(valid[:, None], idx + e, layers * e).reshape(-1)
+        order = jnp.argsort(pair, stable=True)
+        sizes = jnp.zeros((layers * e,), jnp.int32).at[pair].add(
+            1, mode="drop")
+        hgu = jax.lax.ragged_dot(x[order // k], w_gate_up, sizes,
+                                 preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(
+            (jax.nn.silu(hgu[:, :f]) * hgu[:, f:]).astype(dtype), w_down,
+            sizes, preferred_element_type=jnp.float32)
+        want = jnp.where(valid[:, None], jnp.sum(
+            out[jnp.argsort(order)].reshape(n, k, h) / k, axis=1), 0.0)
+        err = float(jnp.max(jnp.abs(got - want)))
+        checks.append({"op": f"expert_ffn[{name}]", "tokens": n,
+                       "live": live, "max_abs_err": err,
+                       "experts_touched": [touched, int(stats[1])]})
+        ok &= err < (1e-4 if rehearse else 3e-2) and int(stats[1]) == touched
+
+        @jax.jit
+        def chained(x, idx, w, valid, w_gate_up, w_down):
+            def one(i, acc):
+                y, _ = moe.expert_ffn(
+                    (x + acc.astype(x.dtype) * 0), idx + (i % layers) * e,
+                    w, valid, w_gate_up, w_down, interpret=rehearse)
+                return acc + y
+            return jax.lax.fori_loop(0, calls, one,
+                                     jnp.zeros((n, h), jnp.float32))
+
+        sec = best_of(chained, (x, idx, w, valid, w_gate_up, w_down), calls)
+        timing.append(entry(
+            f"expert_ffn[{name}]", sec,
+            shapes_moe.moe_gmm(one_layer, 1, live * k, touched),
+            tokens=n, live=live, experts_touched=touched))
+    emit({"phase": "moe", "widths": {k_: d[k_] for k_ in (
+        "heads", "row", "pool_row", "rank", "experts", "top_k", "expert_ffn",
+        "hidden")}, "calls": calls, "expert_layers": layers,
+        "checks": checks, "timing": timing, "peak": peak, "device": device,
+        "ok": bool(ok)})
+    return 0 if ok else 1
+
+
 def phase_serve(model, engine_args, attn: str) -> dict:
     """One boot of engine + router and a handful of requests through the
     router."""
@@ -937,6 +1142,9 @@ def main(argv=None) -> int:
     ap.add_argument("--gdn", action="store_true",
                     help="only time the Gated DeltaNet recurrence alone "
                          "(gdn_step, gdn_chunk) and exit")
+    ap.add_argument("--moe", action="store_true",
+                    help="only time the latent decode kernel and the "
+                         "experts' grouped matmuls alone and exit")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, HERE)
@@ -949,6 +1157,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return gdn_child(args.rehearse)
+    if args.moe:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return moe_child(args.rehearse)
 
     model, full_depth, engine_args = MODEL, FULL_DEPTH, ENGINE_ARGS
     if args.rehearse:

@@ -418,10 +418,22 @@ class EngineConfig:
             or (model_config.num_kv_heads % tp == 0
                 and model_config.num_heads % tp == 0)
         )
+        from production_stack_tpu.models import cache_specs
+
+        specs = cache_specs(model_config)
+        if specs.latent is not None:
+            from production_stack_tpu.ops.pallas.paged_attention import (
+                supports_latent_decode,
+            )
+
+            fits = supports_latent_decode(
+                specs.latent.width, specs.latent.rank, self.block_size)
+        else:
+            fits = supports_pallas_decode(model_config.head_dim_,
+                                          self.block_size)
         supported = (
             get_model(model_config).PAGED_DECODE_VALIDATED
-            and supports_pallas_decode(model_config.head_dim_, self.block_size)
-            and tp_ok
+            and fits and tp_ok
         )
         v = self.attn_impl
         if self.speculative_enabled and v in ("pallas", "paged"):
@@ -468,11 +480,9 @@ class EngineConfig:
         # with the storage dtype.
         import jax.numpy as jnp
 
-        from production_stack_tpu.models import cache_specs
-
-        kv = cache_specs(model_config).paged_kv
+        kv = specs.paged_kv
         worst_window_bytes = (
-            2 * kv.layers * kv.kv_heads * kv.head_dim
+            specs.kv_pools * kv.layers * kv.kv_heads * kv.head_dim
             * jnp.dtype(self.dtype).itemsize
             * self.max_model_len * self.max_num_seqs
         )
@@ -480,6 +490,7 @@ class EngineConfig:
 
     def kv_cache_bytes_per_token(self, model_config) -> int:
         """Pool bytes one token occupies across all layers: K + V payload
+        (or the one latent row, its padding to whole lanes included)
         in the pool's STORAGE dtype plus per-(slot, head) scale overhead
         when quantized (ops/quantization.py). Unquantized pools store the
         COMPUTE dtype (float32 pools cost 4 B/element, not bf16's 2). The
@@ -491,12 +502,13 @@ class EngineConfig:
         from production_stack_tpu.ops.quantization import SCALE_ITEMSIZE
 
         # The layers that keep K/V are the model module's to declare.
-        kv = cache_specs(model_config).paged_kv
+        specs = cache_specs(model_config)
+        kv = specs.paged_kv
         if self.kv_cache_quantized:
             per_slot = kv.head_dim + SCALE_ITEMSIZE
         else:
             per_slot = kv.head_dim * jnp.dtype(self.dtype).itemsize
-        return 2 * kv.layers * kv.kv_heads * per_slot
+        return specs.kv_pools * kv.layers * kv.kv_heads * per_slot
 
     def state_bytes_per_seq(self, model_config) -> int:
         """Bytes of recurrent state one sequence holds whole, over every
@@ -543,6 +555,44 @@ class EngineConfig:
                 f"which {'; '.join(asked)} cannot follow yet: the state has "
                 f"no snapshot, block copy, rollback or sharding. Start "
                 f"without it.")
+
+    def refuse_what_latent_rows_cannot_follow(self, model_config) -> None:
+        """A model whose paged rows are latent rows (models/config.py:
+        CacheSpecs.latent: one pool a layer, no kv-head axis, sparse
+        experts beside) is served by the plain path only. What would have
+        to read, cut or shard such a row and cannot yet is refused here,
+        at start, one sentence each."""
+        from production_stack_tpu.models import cache_specs
+
+        if cache_specs(model_config).latent is None:
+            return
+        why = {
+            "tensor or sequence parallelism (--tensor-parallel-size / "
+            "--sequence-parallel-size > 1): a latent row has no kv-head "
+            "axis to shard and the experts no expert axis":
+                self.tensor_parallel_size > 1
+                or self.sequence_parallel_size > 1,
+            "--kv-cache-dtype int8: a latent row is keys and values at "
+            "once and has no per-row scale beside it":
+                self.kv_cache_quantized,
+            "KV offload and restore (--kv-offload-cpu / --kv-remote-url): "
+            "the block serde moves two pools a layer":
+                bool(self.kv_offload_cpu or self.kv_remote_url),
+            "disaggregated prefill (--role prefill|decode): the handoff "
+            "ships blocks through the same serde":
+                self.role != "unified",
+            "speculative decoding (--speculative-num-tokens): the verify "
+            "step and the draft rings are keys and values of heads":
+                bool(self.speculative_num_tokens),
+            "LoRA adapters (--lora-modules): the absorbed projections and "
+            "the experts have no delta path":
+                bool(self.lora_modules),
+        }
+        asked = [name for name, on in why.items() if on]
+        if asked:
+            raise ValueError(
+                f"model {self.model!r} caches one latent row a token, which "
+                f"cannot follow: {'; '.join(asked)}. Start without it.")
 
     def kv_cache_bytes_per_block(self, model_config) -> int:
         """Pool bytes one KV block occupies (block_size tokens)."""

@@ -95,6 +95,7 @@ class ServingEngine:
         self.config = config
         self.model_config = resolve_model_config(config.model)
         config.refuse_what_state_cannot_follow(self.model_config)
+        config.refuse_what_latent_rows_cannot_follow(self.model_config)
         self.tokenizer = get_tokenizer(config.model, self.model_config)
         self.mesh = mesh or make_mesh(
             dp=config.data_parallel_size,
@@ -1317,6 +1318,26 @@ class ServingEngine:
     def _offload_stat(self, attr: str) -> int:
         return getattr(self.offload, attr, 0) if self.offload else 0
 
+    def _moe_counters(self) -> Dict[str, int]:
+        """The sparse experts' counters by the names /metrics exports:
+        pairs and the busiest expert's tokens over every call, distinct
+        experts touched and calls for decode and for prefill apart (a
+        decode call holds a step's rows, a prefill call a chunk's tokens:
+        their means are different quantities)."""
+        total = self.runner.fwd_stats_total
+        dec, pre = total["decode"], total["prefill"]
+        return {
+            "moe_assignments_total":
+                dec.get("assignments", 0) + pre.get("assignments", 0),
+            "moe_expert_load_max_total":
+                dec.get("expert_load_max", 0) + pre.get("expert_load_max", 0),
+            "moe_experts_touched_total": dec.get("experts_touched", 0),
+            "moe_layer_calls_total": dec.get("layer_calls", 0),
+            "moe_prefill_experts_touched_total":
+                pre.get("experts_touched", 0),
+            "moe_prefill_layer_calls_total": pre.get("layer_calls", 0),
+        }
+
     def _live_perf(self) -> Dict[str, float]:
         """Live roofline position from the rolling dispatch window
         (docs/OBSERVABILITY.md fleet pane): throughput over the window's
@@ -1573,5 +1594,8 @@ class ServingEngine:
                 self.runner.sample_dispatches_greedy_total,
             "sample_dispatches_filtered_total":
                 self.runner.sample_dispatches_filtered_total,
+            # What the sparse experts were given (the runner reads the
+            # forward's counters behind each fetch; zeros without experts).
+            **self._moe_counters(),
             **self._live_perf(),
         }

@@ -344,6 +344,23 @@ class ModelRunner:
         specs = model.cache_specs(model_config)
         self.kv_spec = specs.paged_kv
         self.state_specs = specs.state
+        # Pools a layer keeps a token in (keys and values; one where the
+        # rows are latent rows, which are both), and the width of a token's
+        # row in the second pool: 0 where there is none. The second pool
+        # then exists with no byte in it, so that every program keeps its
+        # operands and the writes below adapt to nothing but shapes.
+        self.kv_pools = specs.kv_pools
+        self.kv_v_dim = self.kv_spec.head_dim if specs.latent is None else 0
+        # int32 counters the model's forward returns last, summed over its
+        # layers (models/deepseek_v3.py: what the experts were given); a
+        # dispatch sums them over its steps and hands them out beside its
+        # pools, and they are read when a later fetch has made them ready.
+        self.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))
+        self.fwd_stats_total = {
+            kind: dict.fromkeys(self.fwd_stats, 0)
+            for kind in ("decode", "prefill")}
+        self._fwd_stats_pending: List[Tuple[int, str, jax.Array]] = []
+        self._fwd_stats_noted = 0
         # Slots of the state pools: one per sequence the scheduler can hold
         # (--max-num-seqs) plus slot 0, the scratch slot every padded row of
         # a dispatch reads and writes.
@@ -672,7 +689,8 @@ class ModelRunner:
             jnp.zeros(kv_shape, self.kv_store_dtype), kv_sh
         )
         self.kv_v = jax.device_put(
-            jnp.zeros(kv_shape, self.kv_store_dtype), kv_sh
+            jnp.zeros((*kv_shape[:3], self.kv_v_dim), self.kv_store_dtype),
+            kv_sh
         )
         if self.kv_quantized:
             from production_stack_tpu.ops.quantization import SCALE_DTYPE
@@ -1001,7 +1019,7 @@ class ModelRunner:
             return 0
         mc, cfg = self.model_config, self.config
         unquantized = (
-            2 * self.kv_spec.layers * self.kv_spec.kv_heads
+            self.kv_pools * self.kv_spec.layers * self.kv_spec.kv_heads
             * self.kv_spec.head_dim * jnp.dtype(self.dtype).itemsize
         )
         saved = max(0, unquantized - cfg.kv_cache_bytes_per_token(mc))
@@ -1018,7 +1036,8 @@ class ModelRunner:
         mc, cfg = self.model_config, self.config
         bytes_per_block = cfg.kv_cache_bytes_per_block(mc)
         window_bytes_per_block = (
-            2 * self.kv_spec.layers * cfg.block_size * self.kv_spec.kv_heads
+            self.kv_pools * self.kv_spec.layers * cfg.block_size
+            * self.kv_spec.kv_heads
             * self.kv_spec.head_dim * jnp.dtype(self.dtype).itemsize
         )
         # The budget is PER DEVICE: the least free HBM over the devices of
@@ -1149,16 +1168,20 @@ class ModelRunner:
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
 
     def _pins_prefill_window(self, rows: int, full_mb: int) -> bool:
-        """A model that declares recurrent state gathers its prefill
-        history window at the full width whatever the rows hold, where the
-        window budget allows that many blocks: ONE windowed family a (rows,
-        t) instead of three. Its K/V is a minority of its layers, so the
-        wider gather is cheap (0.19 GB a row at the benchmark's widths);
-        its prefill programs are twice a dense model's size, and a
-        deployment's programs have to fit the compile cache's size cap
-        together (PERF.md §6, PR 31: 78 programs of 3.4 MB against 192 MiB
-        evicted one another and every boot compiled everything)."""
-        return bool(self.state_specs) and \
+        """A model that declares recurrent state, or whose paged rows are
+        latent rows, gathers its prefill history window at the full width
+        whatever the rows hold, where the window budget allows that many
+        blocks: ONE windowed family a (rows, t) instead of three. Its
+        cached rows are cheap to gather (K/V in a minority of layers: 0.19
+        GB a row at the benchmark's widths; a latent row a ninth of a K/V
+        row of heads: 0.03 GB); its prefill programs are twice a dense
+        model's size, and a deployment's programs have to fit the compile
+        cache's size cap together (PERF.md §6, PR 31: 78 programs of 3.4 MB
+        against 192 MiB evicted one another and every boot compiled
+        everything; PR 33: 70 of 3.6 MB did the same). What it costs a
+        latent model: its window attention contracts the whole window,
+        3072 keys where the history may be 64 (PERF.md §7, PR 33)."""
+        return (bool(self.state_specs) or self.kv_pools == 1) and \
             rows * full_mb <= self.prefill_window_blocks
 
     # --------------------------------------------------------- device helpers
@@ -1178,6 +1201,36 @@ class ModelRunner:
         (quantized mode only; dummies are dropped)."""
         if self.kv_quantized:
             self.kv_k_scale, self.kv_v_scale = kv_ks, kv_vs
+
+    def _fwd_stats_zero(self):
+        """A dispatch's forward counters before its first step (``()``
+        where the model returns none: the program is then the same,
+        operand for operand)."""
+        return jnp.zeros((len(self.fwd_stats),), jnp.int32) \
+            if self.fwd_stats else ()
+
+    def _note_fwd_stats(self, kind: str, dev) -> int:
+        """Keep a dispatch's forward counters until a fetch has made them
+        ready; returns the number of dispatches noted so far, which that
+        dispatch's fetch hands to ``_drain_fwd_stats``."""
+        self._fwd_stats_noted += 1
+        self._fwd_stats_pending.append((self._fwd_stats_noted, kind, dev))
+        return self._fwd_stats_noted
+
+    def _drain_fwd_stats(self, upto: int) -> None:
+        """Add to the totals the forward counters of the dispatches noted
+        no later than number ``upto``. Called from a fetch that has just
+        read dispatch ``upto``'s tokens: dispatches complete in order, so
+        these few scalars are ready and the read waits for nothing (a
+        dispatch issued AFTER it may still run, and is left alone). A
+        prefill chunk that fetches nothing leaves its counters to the next
+        fetch."""
+        pending = self._fwd_stats_pending
+        while pending and pending[0][0] <= upto:
+            _, kind, dev = pending.pop(0)
+            total = self.fwd_stats_total[kind]
+            for name, value in zip(self.fwd_stats, np.asarray(dev)):
+                total[name] += int(value)
 
     def _derive_seeds(self, seed_base, gen0, j):
         """uint32 seed per row for generation index gen0+j; must match
@@ -1281,7 +1334,7 @@ class ModelRunner:
                 block_tables, b_max, b=b, mb=mb, num_steps=num_steps,
                 use_cached_window=use_cached_window,
                 has_penalties=has_penalties, logprobs_k=logprobs_k,
-            ) + (state_pools,)
+            ) + (state_pools, ())
 
         # Token chaining: rows continuing from the immediately-previous
         # dispatch read their start token from its device-resident
@@ -1329,7 +1382,8 @@ class ModelRunner:
 
         nl, hkv, dh = self.kv_spec
         ring_k0 = jnp.zeros((nl, hkv, b, num_steps, dh), self.dtype)
-        ring_v0 = jnp.zeros((nl, hkv, b, num_steps, dh), self.dtype)
+        ring_v0 = jnp.zeros((nl, hkv, b, num_steps, self.kv_v_dim),
+                            self.dtype)
         ring_pos0 = jnp.full((b, num_steps), _POS_SENTINEL, jnp.int32)
         if quant:
             # Quantized-KV sidecar rings: the int8 payload + scales each
@@ -1365,7 +1419,8 @@ class ModelRunner:
             if self.state_specs else ()
 
         def body(carry, j):
-            toks, ring_k, ring_v, ring_pos, counts, qstate, rows_state = carry
+            (toks, ring_k, ring_v, ring_pos, counts, qstate, rows_state,
+             fwd_stats) = carry
             seeds_j = seed_steps[j]
             positions = jnp.minimum(pos0 + j, max_len - 1)[:, None]
             if self.state_specs:
@@ -1376,6 +1431,17 @@ class ModelRunner:
                                    ring_pos=ring_pos),
                     lora=lora, state=rows_state,
                 )
+            elif self.fwd_stats:
+                # A row past its budget reaches no expert, as it leaves
+                # no state above.
+                hidden, k_new, v_new, step_stats = self._forward(
+                    params, mc, toks[:, None], positions,
+                    (j < budget).astype(jnp.int32),
+                    view0._replace(ring_k=ring_k, ring_v=ring_v,
+                                   ring_pos=ring_pos),
+                    lora=lora,
+                )
+                fwd_stats = fwd_stats + step_stats
             else:
                 hidden, k_new, v_new = self._forward(
                     params, mc, toks[:, None], positions, ones,
@@ -1442,7 +1508,7 @@ class ModelRunner:
                 j < budget, nxt.astype(jnp.int32), toks
             )
             return (kept, ring_k, ring_v, ring_pos, counts, qstate,
-                    rows_state), nxt, lp
+                    rows_state, fwd_stats), nxt, lp
 
         def loop_body(state):
             j, carry, toks_all, lp_bufs = state
@@ -1457,7 +1523,7 @@ class ModelRunner:
             return j + 1, carry, toks_all, lp_bufs
 
         carry0 = (tokens0, ring_k0, ring_v0, ring_pos0, counts0, qstate0,
-                  rows_state)
+                  rows_state, self._fwd_stats_zero())
         if cfg.decode_loop == "scan":
             # A/B alternative: all K steps run unconditionally under
             # lax.scan (more XLA pipelining latitude, no drain-tail skip).
@@ -1465,8 +1531,8 @@ class ModelRunner:
                 carry, nxt, lp = body(carry, j)
                 return carry, (nxt, lp if logprobs_k else ())
 
-            (final_toks, ring_k, ring_v, _, _, qstate, rows_state), \
-                (toks_all, lp_scan) = jax.lax.scan(
+            (final_toks, ring_k, ring_v, _, _, qstate, rows_state,
+             fwd_stats), (toks_all, lp_scan) = jax.lax.scan(
                     scan_body, carry0,
                     jnp.arange(num_steps, dtype=jnp.int32),
                 )
@@ -1480,8 +1546,8 @@ class ModelRunner:
                 jnp.zeros((num_steps, b, logprobs_k), jnp.float32),
                 jnp.zeros((num_steps, b, logprobs_k), jnp.int32),
             ) if logprobs_k else ()
-            _, (final_toks, ring_k, ring_v, _, _, qstate, rows_state), \
-                toks_all, lp_bufs = jax.lax.while_loop(
+            _, (final_toks, ring_k, ring_v, _, _, qstate, rows_state,
+                fwd_stats), toks_all, lp_bufs = jax.lax.while_loop(
                     lambda st: st[0] < n_active,
                     loop_body,
                     (jnp.int32(0), carry0, toks_buf0, lp_bufs0),
@@ -1526,11 +1592,11 @@ class ModelRunner:
             return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k, win_v,
                     lp_chosen, lp_top, lp_ids, last_token,
                     *self._spec_dummy_outs(spec_k, spec_v, spec_pos),
-                    state_pools)
+                    state_pools, fwd_stats)
         return (toks_all, kv_k, kv_v, kv_ks, kv_vs, win_k_in, win_v_in,
                 lp_chosen, lp_top, lp_ids, last_token,
                 *self._spec_dummy_outs(spec_k, spec_v, spec_pos),
-                state_pools)
+                state_pools, fwd_stats)
 
     def _append_window(self, win_k, win_v, new_k, new_v, start, length):
         """Append row i's tokens j < length[i] ([L, Hkv, b, T, Dh]) to the
@@ -1538,16 +1604,17 @@ class ModelRunner:
         positions beyond S drop. The window is a paged pool whose row i
         owns blocks i*mb .. i*mb + mb - 1, written in place like one."""
         nl, hkv, b, s_tot, dh = win_k.shape
+        dv = win_v.shape[-1]
         mb = s_tot // self.config.block_size
         own = (jnp.arange(b, dtype=jnp.int32)[:, None] * mb
                + jnp.arange(mb, dtype=jnp.int32)[None, :])
         win_k, win_v = write_token_runs(
             (win_k.reshape(nl, hkv, b * s_tot, dh),
-             win_v.reshape(nl, hkv, b * s_tot, dh)),
+             win_v.reshape(nl, hkv, b * s_tot, dv)),
             (new_k, new_v), own, start, length, self.config.block_size,
         )
         return (win_k.reshape(nl, hkv, b, s_tot, dh),
-                win_v.reshape(nl, hkv, b, s_tot, dh))
+                win_v.reshape(nl, hkv, b, s_tot, dv))
 
     @staticmethod
     def _spec_dummy_outs(spec_k, spec_v, spec_pos):
@@ -2194,7 +2261,7 @@ class ModelRunner:
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
         (toks_all, self.kv_k, self.kv_v, kv_ks2, kv_vs2, wk2, wv2, lp_c,
          lp_t, lp_i, last_token, emits, spec_stats_dev, sp_k2,
-         sp_v2, sp_p2, self.state_pools) = self._decode(
+         sp_v2, sp_p2, self.state_pools, fwd_stats) = self._decode(
             self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
             kv_ks, kv_vs, wk, wv, jnp.asarray(counts), prev_last,
             dparams, sp_k, sp_v, sp_p, self.state_pools,
@@ -2206,6 +2273,8 @@ class ModelRunner:
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
         if self.kv_quantized:
             self.kv_quant_tokens_written += sum(batch.decode_steps)
+        noted = self._note_fwd_stats("decode", fwd_stats) \
+            if self.fwd_stats else 0
         cache = None
         if self.attn_impl != "paged":
             cache = {
@@ -2307,6 +2376,7 @@ class ModelRunner:
 
         def fetch():
             out = np.asarray(toks_all)  # ONE [K, B] fetch per K*B tokens
+            self._drain_fwd_stats(noted)
             tokens = [
                 [int(out[j, i]) for j in range(steps[i])] for i in range(n)
             ]
@@ -2422,6 +2492,7 @@ class ModelRunner:
             t > 1 and sp > 1 and t % sp == 0
             and (not has_window or (mb * bs + t) % sp == 0)
         )
+        fwd_stats = ()
         if self.state_specs:
             state_slots = scalars[12]
             hidden, k_new, v_new, rows_state = self._forward(
@@ -2431,12 +2502,13 @@ class ModelRunner:
                     state_pools, state_slots, fresh=chunk_start == 0),
             )
         else:
-            hidden, k_new, v_new = self._forward(
+            hidden, k_new, v_new, *fwd_stats = self._forward(
                 params, mc, token_ids, positions, chunk_lens,
                 KVView(win_k, win_v, win_len,
                        sp_mesh=self.mesh if rings else None),
                 act_sharding=self._act_sharding, lora=lora,
             )
+        fwd_stats = fwd_stats[0] if self.fwd_stats else ()
         logit_idx = jnp.maximum(chunk_lens - 1, 0)
         last_hidden = hidden[jnp.arange(b), logit_idx]            # [b, D]
         logits = self._logits_fn(params, mc, last_hidden)
@@ -2540,7 +2612,7 @@ class ModelRunner:
             next_tokens.astype(jnp.int32)
         )
         return (next_tokens, kv_k, kv_v, kv_ks, kv_vs, lp[0], lp[1], lp[2],
-                last_token, spec_k, spec_v, spec_pos, state_pools)
+                last_token, spec_k, spec_v, spec_pos, state_pools, fwd_stats)
 
     def _issue_prefill(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config
@@ -2631,7 +2703,7 @@ class ModelRunner:
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
         (next_tokens, self.kv_k, self.kv_v, kv_ks2, kv_vs2, lp_c, lp_t,
          lp_i, last_token, sp_k2, sp_v2, sp_p2,
-         self.state_pools) = self._prefill(
+         self.state_pools, fwd_stats) = self._prefill(
             self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
             kv_ks, kv_vs, jnp.asarray(counts), dparams, sp_k, sp_v, sp_p,
             self.state_pools,
@@ -2642,6 +2714,8 @@ class ModelRunner:
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
         if self.kv_quantized:
             self.kv_quant_tokens_written += sum(batch.chunk_lens)
+        noted = self._note_fwd_stats("prefill", fwd_stats) \
+            if self.fwd_stats else 0
         # Final rows' sampled tokens are chainable by the next decode
         # dispatch without a host roundtrip. Non-final chunks produce no
         # tokens — no entry, so they never evict a live decode chain.
@@ -2662,6 +2736,7 @@ class ModelRunner:
                 # No row finished its prompt: no blocking fetch at all.
                 return [[] for _ in range(n)], None
             out = np.asarray(next_tokens)
+            self._drain_fwd_stats(noted)
             tokens = [[int(out[i])] if finals[i] else [] for i in range(n)]
             if not logprobs_k:
                 return tokens, None
@@ -3010,8 +3085,10 @@ class ModelRunner:
             # (a mismatch costs extra compiles, never correctness).
             from jax.sharding import NamedSharding, PartitionSpec
 
-            wk = wv = sds((nl, hkv, db, mb * bs, dh), self.dtype,
-                          sharding=NamedSharding(self.mesh, PartitionSpec()))
+            rep = NamedSharding(self.mesh, PartitionSpec())
+            wk = sds((nl, hkv, db, mb * bs, dh), self.dtype, sharding=rep)
+            wv = sds((nl, hkv, db, mb * bs, self.kv_v_dim), self.dtype,
+                     sharding=rep)
         else:
             wk = wv = sds((1, 1, 1, 1, 1), self.dtype)
         counts = sds(

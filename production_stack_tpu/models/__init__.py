@@ -8,7 +8,7 @@ their meaning). Nothing
 outside this package asks for an architecture by name.
 """
 
-from production_stack_tpu.models import llama, olmo_hybrid, opt
+from production_stack_tpu.models import deepseek_v3, llama, olmo_hybrid, opt
 from production_stack_tpu.models.config import (
     LLAMA3_8B,
     NAMED_CONFIGS,
@@ -19,7 +19,8 @@ from production_stack_tpu.models.config import (
     resolve_model_config,
 )
 
-_ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid}
+_ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
+          "deepseek_v3": deepseek_v3}
 
 
 def get_model(cfg: ModelConfig):

@@ -35,19 +35,43 @@ class StateSpec(NamedTuple):
     dtype: Optional[str]
 
 
+class LatentKVSpec(NamedTuple):
+    """A token's paged row is ONE latent row, not keys and values of heads:
+    the compressed KV (``rank`` values, after its norm: the keys' first part
+    and the values whole), then the rotary key every head shares
+    (``rope_dim``, after rope), then zeros up to whole 128-lane tiles. There
+    is no second pool: ``paged_kv`` then describes that one pool
+    (``kv_heads`` 1, ``head_dim`` the padded row)."""
+    rank: int
+    rope_dim: int
+
+    @property
+    def width(self) -> int:
+        return -(-(self.rank + self.rope_dim) // 128) * 128
+
+
 class CacheSpecs(NamedTuple):
     """What a model module's ``cache_specs(cfg)`` declares it caches per
     sequence, by layer kind: the runner sizes and owns the pools from this
     and nothing else (engine/runner.py), and a non-empty ``state`` is what
     the block manager hands out slots for and what the engine refuses
-    features by that cannot follow it."""
+    features by that cannot follow it. ``latent`` set: the paged rows are
+    latent rows (one pool a layer), and the engine refuses what cannot
+    follow those."""
     paged_kv: PagedKVSpec
     state: Tuple[StateSpec, ...] = ()
+    latent: Optional[LatentKVSpec] = None
+
+    @property
+    def kv_pools(self) -> int:
+        """Pools a layer keeps a token's row in: keys and values, or the
+        one latent row."""
+        return 1 if self.latent is not None else 2
 
 
 @dataclass(frozen=True)
 class ModelConfig:
-    arch: str = "llama"  # "llama" | "opt" | "olmo_hybrid"
+    arch: str = "llama"  # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -72,6 +96,23 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
     linear_allow_neg_eigval: bool = False
+    # Multi-head latent attention (models/deepseek_v3.py): a token caches
+    # kv_lora_rank + qk_rope_head_dim values; a head's query and key are
+    # qk_nope_head_dim + qk_rope_head_dim wide, its value v_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Sparse experts: layers from first_k_dense_replace on route each token
+    # to num_experts_per_tok of n_routed_experts (width
+    # moe_intermediate_size each) beside n_shared_experts always-on ones.
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
 
     def __post_init__(self):
         if self.layer_types:
@@ -146,6 +187,52 @@ class ModelConfig:
                 linear_value_head_dim=d["linear_value_head_dim"],
                 linear_conv_kernel_dim=d.get("linear_conv_kernel_dim", 4),
                 linear_allow_neg_eigval=d.get("linear_allow_neg_eigval", False),
+                name=name,
+            )
+        if model_type == "deepseek_v3":
+            # What the module does not implement is refused by its key, not
+            # served as something else.
+            unsupported = {
+                "q_lora_rank": d.get("q_lora_rank") is not None,
+                "rope_scaling": d.get("rope_scaling") is not None,
+                "n_group/topk_group != 1": (d.get("n_group", 1),
+                                            d.get("topk_group", 1)) != (1, 1),
+                "scoring_func != sigmoid":
+                    d.get("scoring_func", "sigmoid") != "sigmoid",
+                "topk_method != noaux_tc":
+                    d.get("topk_method", "noaux_tc") != "noaux_tc",
+                "rope_interleave false": not d.get("rope_interleave", True),
+                "attention_bias": bool(d.get("attention_bias", False)),
+                "moe_layer_freq != 1": d.get("moe_layer_freq", 1) != 1,
+                "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+            }
+            asked = [k for k, on in unsupported.items() if on]
+            if asked:
+                raise ValueError(
+                    f"deepseek_v3: not supported: {', '.join(asked)}")
+            return ModelConfig(
+                arch="deepseek_v3",
+                vocab_size=d["vocab_size"],
+                hidden_size=d["hidden_size"],
+                intermediate_size=d["intermediate_size"],
+                num_layers=d["num_hidden_layers"],
+                num_heads=d["num_attention_heads"],
+                num_kv_heads=d["num_attention_heads"],
+                max_position_embeddings=d.get("max_position_embeddings", 4096),
+                rope_theta=d.get("rope_theta", 10000.0),
+                rms_norm_eps=d.get("rms_norm_eps", 1e-6),
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                kv_lora_rank=d["kv_lora_rank"],
+                qk_nope_head_dim=d["qk_nope_head_dim"],
+                qk_rope_head_dim=d["qk_rope_head_dim"],
+                v_head_dim=d["v_head_dim"],
+                n_routed_experts=d["n_routed_experts"],
+                num_experts_per_tok=d["num_experts_per_tok"],
+                n_shared_experts=d.get("n_shared_experts", 0),
+                moe_intermediate_size=d["moe_intermediate_size"],
+                first_k_dense_replace=d.get("first_k_dense_replace", 0),
+                routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+                norm_topk_prob=d.get("norm_topk_prob", True),
                 name=name,
             )
         raise ValueError(f"Unsupported model_type: {model_type}")
@@ -260,8 +347,24 @@ TINY_OLMO_HYBRID = ModelConfig(
     name="tiny-olmo-hybrid",
 )
 
+# Tiny latent-attention sparse-expert decoder: 1 dense + 3 sparse layers, 16
+# experts top-3 beside one shared (tests/test_deepseek_v3.py compares it
+# with the plain reference). kv_lora_rank is whole lanes, as the paged kernel
+# over latent rows asks (values are a lane-aligned slice of a row); a row is
+# 128 + 8 values padded to 256.
+TINY_DEEPSEEK_V3 = ModelConfig(
+    arch="deepseek_v3", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=4, num_heads=4, num_kv_heads=4,
+    max_position_embeddings=512, rope_theta=10000.0, rms_norm_eps=1e-6,
+    kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    n_routed_experts=16, num_experts_per_tok=3, n_shared_experts=1,
+    moe_intermediate_size=32, first_k_dense_replace=1,
+    routed_scaling_factor=2.448, name="tiny-deepseek-v3",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-deepseek-v3": TINY_DEEPSEEK_V3,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-llama-8kv": TINY_LLAMA_8KV,
     "tiny-llama-128dh": TINY_LLAMA_128DH,

@@ -26,6 +26,10 @@ from production_stack_tpu.utils import init_logger
 logger = init_logger(__name__)
 
 _LAYER_RE = re.compile(r"\.(?:layers|decoder\.layers)\.(\d+)\.")
+# ``mlp.experts.17.up_proj.weight``: an expert's tensor. A module maps it as
+# ``mlp.experts.*.up_proj.weight``; its stack has an expert axis behind the
+# layer's, [L, E, ...].
+_EXPERT_RE = re.compile(r"^(.*\.experts\.)(\d+)(\..*)$")
 
 def _iter_checkpoint_tensors(model_dir: str) -> Iterator[Tuple[str, np.ndarray]]:
     """Yield (hf_name, numpy array) streaming over checkpoint shards."""
@@ -89,12 +93,18 @@ def load_hf_params(
     stacks: Dict[str, np.ndarray] = {}   # our layer leaf -> [L, ...] buffer
     filled: Dict[str, set] = {}          # our layer leaf -> set of layer idxs
     top: Dict[str, np.ndarray] = {}
+    seen_experts: Dict[str, Dict[int, set]] = {}  # leaf -> layer -> experts
+    # Leaves the module computes with in float32 whatever ``dtype`` is.
+    keep_f32 = set(getattr(model, "FLOAT32_LEAVES", ()))
 
     for hf_name, tensor in _iter_checkpoint_tensors(model_dir):
         m = _LAYER_RE.search(hf_name)
         if m is not None:
             layer_idx = int(m.group(1))
             suffix = hf_name[m.end():]
+            em = _EXPERT_RE.match(suffix)
+            if em is not None:
+                suffix = em.group(1) + "*" + em.group(3)
             mapped = per_layer_map.get(suffix)
             if mapped is None:
                 logger.debug("Skipping unmapped tensor %s", hf_name)
@@ -110,6 +120,20 @@ def load_hf_params(
             if slots is not None:
                 kind, layer_idx = slots[layer_idx]
                 ours, depth = f"{kind}/{ours}", sizes[kind]
+            if em is not None:
+                # Filed per (layer, expert); a layer counts as filled when
+                # its last expert has arrived (holes: the check below).
+                e, n_e = int(em.group(2)), cfg.n_routed_experts
+                if ours not in stacks:
+                    stacks[ours] = np.empty((depth, n_e) + t.shape, t.dtype)
+                    filled[ours] = set()
+                    seen_experts[ours] = {}
+                stacks[ours][layer_idx, e] = t
+                seen = seen_experts[ours].setdefault(layer_idx, set())
+                seen.add(e)
+                if len(seen) == n_e:
+                    filled[ours].add(layer_idx)
+                continue
             if ours not in stacks:
                 stacks[ours] = np.empty((depth,) + t.shape, t.dtype)
                 filled[ours] = set()
@@ -146,8 +170,10 @@ def load_hf_params(
 
     params: Dict = {"layers": {}}
     for name in list(stacks):
-        arr = jax.numpy.asarray(stacks[name], dtype=dtype)
         kind, _, leaf = name.rpartition("/")
+        arr = jax.numpy.asarray(
+            stacks[name],
+            dtype=jax.numpy.float32 if leaf in keep_f32 else dtype)
         into, placed = params["layers"], (shardings or {}).get("layers", {})
         if kind:
             into, placed = into.setdefault(kind, {}), placed.get(kind, {})

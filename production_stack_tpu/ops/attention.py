@@ -73,6 +73,7 @@ def window_attention(
     *,
     scale: Optional[float] = None,
     chunk_bias: Optional[jax.Array] = None,  # [T, T] additive f32 {0, -inf}
+    qblock: int = QBLOCK,
 ) -> jax.Array:
     """Dense attention against up to three key segments, TPU-shaped.
 
@@ -170,15 +171,15 @@ def window_attention(
         out = out / denom                                   # [Hkv, B, M, Dh]
         return out.reshape(hkv, b, g, tq, dh)
 
-    if t <= QBLOCK:
+    if t <= qblock:
         out = q_block(qf, positions)
     else:
         assert chunk_bias is None, \
             "chunk_bias (tree speculation) requires t <= QBLOCK"
-        assert t % QBLOCK == 0, "token bucket must be a multiple of QBLOCK"
-        nb = t // QBLOCK
-        qs = qf.reshape(hkv, b, g, nb, QBLOCK, dh).transpose(3, 0, 1, 2, 4, 5)
-        pos_qs = positions.reshape(b, nb, QBLOCK).transpose(1, 0, 2)
+        assert t % qblock == 0, "token bucket must be a multiple of QBLOCK"
+        nb = t // qblock
+        qs = qf.reshape(hkv, b, g, nb, qblock, dh).transpose(3, 0, 1, 2, 4, 5)
+        pos_qs = positions.reshape(b, nb, qblock).transpose(1, 0, 2)
 
         def body(_, xs):
             qb, pos_q = xs
@@ -297,9 +298,20 @@ def attend(
     chunk_lens: jax.Array,   # [B] valid tokens per row
     view: KVView,            # ONE layer's view (see scan_layers)
     layer: Optional[jax.Array] = None,  # scalar layer index; pool views only
+    *,
+    scale: Optional[float] = None,      # None: Dh ** -0.5
+    value_dim: Optional[int] = None,    # latent rows (``v`` None) only
 ) -> jax.Array:
     """Causal attention of a chunk over itself and whatever ``view`` holds,
-    by the kernel that fits: [B, T, H, Dh] in q.dtype."""
+    by the kernel that fits: [B, T, H, Dh] in q.dtype.
+
+    ``v`` None: LATENT rows (models/config.py:LatentKVSpec). ``k`` is then
+    the chunk's rows [B, T, 1, W], every view part holds such rows, ``q``
+    [B, T, H, W] is zero past the key's lanes, and the values are the rows'
+    first ``value_dim`` lanes: [B, T, H, value_dim]."""
+    if v is None:
+        return _attend_latent(q, k, positions, chunk_lens, view, layer,
+                              scale, value_dim)
     b, t, h, dh = q.shape
     if view.sp_mesh is not None and t > 1 and view.ring_k is None:
         from production_stack_tpu.ops.ring_attention import (
@@ -391,6 +403,49 @@ def attend(
     )
 
 
+def _attend_latent(q, rows, positions, chunk_lens, view, layer, scale,
+                   value_dim):
+    """``attend`` over latent rows: the same two paths with the rows as
+    keys AND values. The dense paths contract the whole row for the values
+    too and keep the first ``value_dim`` lanes of the result (a slice of the
+    OUTPUT: slicing the window would copy it, every layer); the kernel
+    slices its VMEM buffer, which is free."""
+    b, t, h, w = q.shape
+    if view.pool_k is None:
+        return window_attention(
+            q, rows, rows, positions, chunk_lens,
+            view.win_k, view.win_k, view.win_len,
+            view.ring_k, view.ring_k, view.ring_pos,
+            scale=scale, chunk_bias=view.chunk_bias,
+            # Every head shares the one row, so a query block is H times
+            # its tokens tall: as many score rows a block as 8 query heads
+            # a KV head give at QBLOCK (the score tensor is the program's
+            # largest temporary: 4 GB at 8 rows x 3072 keys otherwise).
+            qblock=max(16, QBLOCK * 8 // h),
+        )[..., :value_dim]
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_latent_stats,
+    )
+
+    q2 = q.reshape(b, h, w)
+    out_p, m_p, l_p = paged_flash_decode_latent_stats(
+        q2, view.pool_k, view.block_tables, view.kv_lens, layer,
+        block_size=view.block_size, value_dim=value_dim, scale=scale,
+        interpret=view.interpret,
+    )
+    keys = rows.transpose(2, 0, 1, 3)                  # [1, B, 1, W]
+    bias = jnp.zeros((b, 1), jnp.float32)
+    if view.ring_k is not None:
+        keys = jnp.concatenate([view.ring_k, keys], axis=2)
+        bias = jnp.concatenate([
+            jnp.where(view.ring_pos < positions, 0.0, jnp.float32(_NEG_INF)),
+            bias], axis=1)
+    out_d, m_d, l_d = dense_decode_stats(q2, keys, keys, bias, scale=scale)
+    attn = merge_attention_segments(
+        out_p, m_p, l_p, out_d[..., :value_dim], m_d, l_d)
+    return attn.reshape(b, t, h, value_dim)
+
+
 def scan_layers(
     layer_fn: Callable,   # (hidden, lp, view_l, layer, lora_l) -> (hidden, k, v)
     hidden: jax.Array,    # [B, T, D]
@@ -456,13 +511,14 @@ def gather_window(
     values (ops/quantization.py:dequantize_kv)."""
     b, mb = block_tables.shape
     l, hkv, num_slots, dh = kv_k.shape
+    dv = kv_v.shape[-1]     # the keys' width, or 0: latent rows (KVView)
     nb = num_slots // block_size
     kr = kv_k.reshape(l, hkv, nb, block_size, dh)
-    vr = kv_v.reshape(l, hkv, nb, block_size, dh)
+    vr = kv_v.reshape(l, hkv, nb, block_size, dv)
     win_k = kr[:, :, block_tables]  # [L, Hkv, B, Mb, bs, Dh]
     win_v = vr[:, :, block_tables]
     win_k = win_k.reshape(l, hkv, b, mb * block_size, dh)
-    win_v = win_v.reshape(l, hkv, b, mb * block_size, dh)
+    win_v = win_v.reshape(l, hkv, b, mb * block_size, dv)
     if k_scale is not None:
         from production_stack_tpu.ops.quantization import dequantize_kv
 

@@ -567,3 +567,213 @@ def paged_attention_decode_pallas(
         v_scale=None if v_scale is None else v_scale[None],
     )
     return out.reshape(b, 1, h, dh)
+
+
+# --------------------------------------------------------------- latent rows
+# A latent pool (models/config.py:LatentKVSpec) keeps ONE row a token and
+# layer, [L, 1, num_slots, W]: the compressed KV, the shared rotary key and
+# zeros to whole lanes. Every query head attends the same rows (absorbed
+# multi-head latent attention is multi-query attention whose keys are the
+# whole row and whose values are its first ``value_dim`` lanes), so a page is
+# fetched ONCE and serves the scores and the values. The kernel is the one
+# above with one pool, one buffer a superpage and no packing or
+# quantization: the same sequence of superpages across rows, the same
+# next-row prefetch, the same byte-counting waits.
+
+
+def _latent_decode_kernel(
+    layer_ref,          # SMEM [1] int32
+    block_tables_ref,   # SMEM [B, Mb] int32
+    kv_lens_ref,        # SMEM [B] int32
+    q_ref,              # VMEM [B, H, W] (resident; zeros past the key's lanes)
+    kv_hbm,             # HBM  [L, 1, num_slots, W]
+    o_ref,              # VMEM [B, H, Dv]
+    m_ref,              # VMEM [B, 1, H] f32
+    l_ref,              # VMEM [B, 1, H] f32
+    kv_buf,             # VMEM [NUM_BUFS, 1, super_tokens, W]
+    sem,                # DMA sems (NUM_BUFS,)
+    fetched_ref,        # SMEM [1] int32
+    *,
+    block_size: int,
+    value_dim: int,
+    scale: float,
+    super_tokens: int,
+):
+    b = pl.program_id(0)
+    num_rows = kv_lens_ref.shape[0]
+    layer = layer_ref[0]
+    bs = block_size
+    spp = super_tokens // bs
+    gp = min(ISSUE_UNROLL, spp)
+    h = q_ref.shape[1]
+    kv_len = kv_lens_ref[b]
+    n_super = pl.cdiv(kv_len, super_tokens)
+    first = jnp.where(b == 0, 0, fetched_ref[0])
+    fetched_ref[0] = first + n_super
+
+    q = q_ref[b].astype(jnp.float32)[None] * scale          # [1, H, W]
+
+    def pages_of(row, s):
+        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
+
+    def start_fetch(row, s, n):
+        slot = jax.lax.rem(n, NUM_BUFS)
+        pages = pages_of(row, s)
+
+        def issue(i):
+            src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
+            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+            pltpu.make_async_copy(
+                kv_hbm.at[layer, :, src], kv_buf.at[slot, :, dst],
+                sem.at[slot],
+            ).start()
+
+        def issue_group(gi, carry):
+            for j in range(gp):
+                issue(gi * gp + j)
+            return carry
+
+        def issue_page(i, carry):
+            issue(i)
+            return carry
+
+        jax.lax.fori_loop(0, pages // gp, issue_group, 0)
+        jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
+
+    def wait_fetch(s, slot):
+        pages = pages_of(b, s)
+        run = spp
+        while run:
+            @pl.when(pages & run != 0)
+            def _():
+                span = pl.ds(0, run * bs)
+                pltpu.make_async_copy(
+                    kv_hbm.at[0, :, span], kv_buf.at[slot, :, span],
+                    sem.at[slot],
+                ).wait()
+            run //= 2
+
+    # The buffers are keys AND values: a masked key's weight (0) must not
+    # meet a non-finite value, so they are cleared once a call; what rows
+    # leave behind is pool content, finite.
+    @pl.when(b == 0)
+    def _():
+        kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
+
+    prev_len = kv_lens_ref[jnp.maximum(b - 1, 0)]
+
+    @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
+    def _():
+        start_fetch(b, 0, first)
+
+    def body(s, carry):
+        m, l, acc = carry
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
+        last = s + 1 == n_super
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
+        def _():
+            start_fetch(
+                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
+                jnp.where(last, 0, s + 1),
+                n + 1,
+            )
+
+        wait_fetch(s, slot)
+        rows = kv_buf[slot]                                  # [1, S, W]
+        scores = jax.lax.dot_general(
+            q, rows,
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                                    # [1, H, S]
+        pos = s * super_tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, super_tokens), 2)
+        scores = jnp.where(pos < kv_len, scores, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p_ = jnp.exp(scores - m_new)
+        l_new = l * alpha + jnp.sum(p_, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p_, rows[:, :, :value_dim],
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                                    # [1, H, Dv]
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((1, h, 1), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((1, h, 1), jnp.float32)
+    acc0 = jnp.zeros((1, h, value_dim), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, n_super, body, (m0, l0, acc0))
+
+    o_ref[b] = (acc / jnp.maximum(l, 1e-30))[0].astype(o_ref.dtype)
+    m_ref[b, 0] = m.reshape(h)
+    l_ref[b, 0] = l.reshape(h)
+
+
+def supports_latent_decode(width: int, value_dim: int,
+                           block_size: int) -> bool:
+    return (width % LANES == 0 and value_dim % LANES == 0
+            and value_dim <= width and SUPER_TOKENS % block_size == 0)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "value_dim", "scale", "interpret"),
+)
+def paged_flash_decode_latent_stats(
+    q: jax.Array,             # [B, H, W] absorbed queries, zeros past the key
+    kv_pool: jax.Array,       # [L, 1, num_slots, W] latent rows
+    block_tables: jax.Array,  # [B, Mb] int32
+    kv_lens: jax.Array,       # [B] int32 — tokens resident in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    value_dim: int,
+    scale: float,
+    interpret: bool = False,
+) -> tuple:
+    """``paged_flash_decode_stats`` over a latent pool: every head of a row
+    attends the row's pages, keys the whole pool row, values its first
+    ``value_dim`` lanes. Returns (out [B, H, value_dim] normalized, m [B, H]
+    f32, l [B, H] f32); a row with ``kv_len == 0`` returns (0, -inf, 0) and
+    fetches nothing. The pool must be finite wherever a live row's pages
+    reach, its padding lanes included (the engine writes zeros there)."""
+    b, h, w = q.shape
+    sup = super_tokens(1, w, kv_pool.dtype.itemsize, block_size)
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    kernel = functools.partial(
+        _latent_decode_kernel, block_size=block_size, value_dim=value_dim,
+        scale=float(scale), super_tokens=sup,
+    )
+
+    def resident(*shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape),
+                            memory_space=pltpu.VMEM)
+
+    out, m, l = pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, value_dim), q.dtype),
+            jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
+        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[resident(b, h, w),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[resident(b, h, value_dim), resident(b, 1, h),
+                       resident(b, 1, h)],
+            scratch_shapes=[
+                pltpu.VMEM((NUM_BUFS, 1, sup, w), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+    )(layer, block_tables, kv_lens, q, kv_pool)
+    return out, m.reshape(b, h), l.reshape(b, h)

@@ -49,6 +49,10 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "decode_row_steps_wasted_total",
                 "sample_dispatches_total", "sample_dispatches_greedy_total",
                 "sample_dispatches_filtered_total",
+                "moe_assignments_total", "moe_expert_load_max_total",
+                "moe_experts_touched_total", "moe_layer_calls_total",
+                "moe_prefill_experts_touched_total",
+                "moe_prefill_layer_calls_total",
                 "live_tok_per_s",
                 "live_hbm_bw_pct",
                 "live_effective_tokens_per_target_step"):
@@ -352,6 +356,38 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:decode_row_steps_wasted_total counter",
         f"pstpu:decode_row_steps_wasted_total{label} "
         f"{s['decode_row_steps_wasted_total']}",
+        # Sparse experts (zeros for a model without any): what the routed
+        # experts were given, read behind each dispatch's fetch.
+        "# HELP pstpu:moe_assignments_total Token-expert pairs the routed "
+        "experts computed, decode and prefill",
+        "# TYPE pstpu:moe_assignments_total counter",
+        f"pstpu:moe_assignments_total{label} {s['moe_assignments_total']}",
+        "# HELP pstpu:moe_expert_load_max_total Tokens of the busiest "
+        "expert, summed over sparse-layer calls (times the experts over "
+        "the pairs: max/mean imbalance)",
+        "# TYPE pstpu:moe_expert_load_max_total counter",
+        f"pstpu:moe_expert_load_max_total{label} "
+        f"{s['moe_expert_load_max_total']}",
+        "# HELP pstpu:moe_experts_touched_total Distinct experts a DECODE "
+        "sparse-layer call gave a token, summed over the calls (the expert "
+        "matrices a step reads)",
+        "# TYPE pstpu:moe_experts_touched_total counter",
+        f"pstpu:moe_experts_touched_total{label} "
+        f"{s['moe_experts_touched_total']}",
+        "# HELP pstpu:moe_layer_calls_total Sparse-layer calls of decode "
+        "steps (steps run times sparse layers)",
+        "# TYPE pstpu:moe_layer_calls_total counter",
+        f"pstpu:moe_layer_calls_total{label} {s['moe_layer_calls_total']}",
+        "# HELP pstpu:moe_prefill_experts_touched_total Distinct experts a "
+        "PREFILL sparse-layer call gave a token, summed over the calls",
+        "# TYPE pstpu:moe_prefill_experts_touched_total counter",
+        f"pstpu:moe_prefill_experts_touched_total{label} "
+        f"{s['moe_prefill_experts_touched_total']}",
+        "# HELP pstpu:moe_prefill_layer_calls_total Sparse-layer calls of "
+        "prefill chunks",
+        "# TYPE pstpu:moe_prefill_layer_calls_total counter",
+        f"pstpu:moe_prefill_layer_calls_total{label} "
+        f"{s['moe_prefill_layer_calls_total']}",
         "# HELP pstpu:sample_dispatches_total Prefill and decode "
         "dispatches issued (each runs the sampler once a step)",
         "# TYPE pstpu:sample_dispatches_total counter",

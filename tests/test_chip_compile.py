@@ -316,12 +316,15 @@ def _described_runner(v5e, model_dir: str, **engine):
     r._init_fn, r._forward, r._logits_fn = \
         model.init_params, model.forward, model.compute_logits
     r.kv_spec, r.state_specs = specs.paged_kv, specs.state
+    r.kv_pools = specs.kv_pools
+    r.kv_v_dim = specs.paged_kv.head_dim if specs.latent is None else 0
+    r.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))
     r.num_kv_blocks = cfg.num_kv_blocks
     r.num_state_slots = cfg.max_num_seqs + 1 if specs.state else 0
-    r.kv_k = r.kv_v = sds(
-        (specs.paged_kv.layers, specs.paged_kv.kv_heads,
-         cfg.num_kv_blocks * cfg.block_size, specs.paged_kv.head_dim),
-        jnp.bfloat16)
+    pool = (specs.paged_kv.layers, specs.paged_kv.kv_heads,
+            cfg.num_kv_blocks * cfg.block_size)
+    r.kv_k = sds((*pool, specs.paged_kv.head_dim), jnp.bfloat16)
+    r.kv_v = sds((*pool, r.kv_v_dim), jnp.bfloat16)
     r.state_pools = tuple(
         sds((r.num_state_slots, s.layers, *s.shape),
             jnp.dtype(s.dtype or "bfloat16")) for s in specs.state)
@@ -384,4 +387,116 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     # layer, and the step kernel is aliased to it: the decode program's
     # temporaries stay under 1.5 GB (1.246 GB, as before the kernel).
     assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# ---- kanana-2-30b-a3b-d8: the latent kernel, the grouped matmul, the programs
+LATENT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "benchmarks", "chip", "configs", "kanana-2-30b-a3b-d8")
+
+
+@pytest.mark.parametrize("rows", [8, 64])
+def test_latent_decode_kernel_compiles_for_v5e(v5e, rows):
+    """32 query heads over ONE row a token, 640 lanes wide, values its
+    first 512: the published widths of kanana-2-30b-a3b."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_latent_stats,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = paged_flash_decode_latent_stats.lower(
+        sds((rows, 32, 640), jnp.bfloat16),
+        sds((8, 1, NUM_SLOTS, 640), jnp.bfloat16),
+        sds((rows, 192), jnp.int32), sds((rows,), jnp.int32),
+        sds((1,), jnp.int32),
+        block_size=BLOCK_SIZE, value_dim=512, scale=192 ** -0.5,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out, m, l = compiled.out_info
+    assert out.shape == (rows, 32, 512)
+    assert m.shape == l.shape == (rows, 32)
+
+
+@pytest.mark.parametrize("pairs,k,n", [
+    (32 * 6, 2048, 1536), (32 * 6, 768, 2048),         # a decode step
+    (1024 * 6, 2048, 1536), (1024 * 6, 768, 2048),     # a prefill chunk
+], ids=["decode-gate-up", "decode-down", "prefill-gate-up", "prefill-down"])
+def test_grouped_matmul_compiles_for_v5e(v5e, pairs, k, n):
+    """The experts' two products over the WHOLE stack of 7 x 128 experts
+    (a layer's groups sit at layer x 128: no slice of 1.2 GB is cut out)."""
+    from production_stack_tpu.ops.pallas.grouped_matmul import moe_gmm
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(moe_gmm).lower(
+        sds((pairs, k), jnp.bfloat16), sds((7 * 128, k, n), jnp.bfloat16),
+        sds((7 * 128,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled.out_info.shape == (pairs, n)
+    # The stack goes to the kernel as it lies: no copy of its shape.
+    assert f"bf16[{7 * 128},{k},{n}]" in text
+    assert not [ln for ln in text.splitlines()
+                if f"bf16[{7 * 128},{k},{n}]" in ln.split(" = ")[0]
+                and " copy(" in ln]
+
+
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x512-window"])
+def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
+    """The decode and the widest prefill program of kanana-2-30b-a3b-d8's
+    envelope (deployment.json's flags, published widths, all 128 experts of
+    7 sparse layers) compile for a v5e, fit its HBM beside 10.14 GB of
+    weights and the 2.68 GB latent pool, copy neither the pool nor the
+    experts' stacks, and hold the Mosaic kernels: the latent decode kernel
+    (the dense layer's call and the sparse scan's) and the two grouped
+    matmuls of the scan."""
+    import json
+
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    with open(os.path.join(LATENT_DIR, "deployment.json")) as f:
+        flags = {x["flag"]: x["value"]
+                 for x in json.load(f)["engine_flags"]}
+    r = _described_runner(
+        v5e, LATENT_DIR, max_model_len=int(flags["--max-model-len"]),
+        max_num_seqs=int(flags["--max-num-seqs"]),
+        max_num_batched_tokens=int(flags["--max-num-batched-tokens"]),
+        num_kv_blocks=int(flags["--num-kv-blocks"]))
+    assert r.kv_k.shape == (8, 1, 16384 * 16, 640)
+    assert r.kv_v.shape == (8, 1, 16384 * 16, 0)     # no second pool
+    assert r.state_pools == ()
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    sparse = aparams["layers"]["sparse"]
+    assert sparse["w_gate_up"].shape == (7, 128, 2048, 1536)
+    assert sparse["w_router"].dtype == jnp.float32
+    decode = program.startswith("decode")
+    if decode:
+        lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
+    else:
+        lowered = r._lower_prefill(aparams, 8, 512, full_mb, True)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    experts = [jax.ShapeDtypeStruct((7 * 128, *sparse[k].shape[2:]),
+                                    jnp.bfloat16)
+               for k in ("w_gate_up", "we_down")]
+    assert pool_copies(text, [r.kv_k, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (4 if decode else 2)
+    assert ("paged_flash_decode_latent_stats" in text) == decode
+    mem = compiled.memory_analysis()
+    # Weights 10.14 GB and the pool 2.68 GB are arguments; a latent row
+    # costs a decode program no temporary of its own.
+    assert 12.8e9 < mem.argument_size_in_bytes < 12.9e9
+    assert mem.temp_size_in_bytes < (0.4e9 if decode else 1.0e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -170,6 +170,7 @@ def test_reachable_families_cover_observed_dispatches():
         _prefill_mb = ModelRunner._prefill_mb
         _pins_prefill_window = ModelRunner._pins_prefill_window
         state_specs = ()
+        kv_pools = 2
 
     r = _FakeRunner()
     dec = set(r.reachable_decode_families())

@@ -130,6 +130,30 @@ REGISTRY: Tuple[Series, ...] = (
            (ENGINE,), ("catalogue", "lifecycle"),
            "Admissions put off because every recurrent-state slot was "
            "held"),
+    # ------------------------------------------ engine: sparse experts
+    Series("pstpu:moe_assignments_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Token-expert pairs the routed experts computed, decode and "
+           "prefill (0: a model without experts)"),
+    Series("pstpu:moe_expert_load_max_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Tokens of the busiest expert, summed over sparse-layer calls "
+           "(times the experts over the pairs: max/mean imbalance)"),
+    Series("pstpu:moe_experts_touched_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Distinct experts a DECODE sparse-layer call gave a token, "
+           "summed over the calls (the expert matrices a step reads)"),
+    Series("pstpu:moe_layer_calls_total", "counter", ("model_name",),
+           (ENGINE,), ("catalogue", "lifecycle"),
+           "Sparse-layer calls of decode steps (steps run times sparse "
+           "layers)"),
+    Series("pstpu:moe_prefill_experts_touched_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Distinct experts a PREFILL sparse-layer call gave a token, "
+           "summed over the calls"),
+    Series("pstpu:moe_prefill_layer_calls_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Sparse-layer calls of prefill chunks"),
     Series("pstpu:prefix_hit_tokens_unserved_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
            "Prompt tokens whose K/V the prefix index held but that were "
