@@ -27,6 +27,9 @@ The kernel phase also times the paged decode kernel alone at the benchmark's
 two decode shapes and prints, under ``timing``, µs a call, the least time the
 chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
 their ratio, the kernel's own roofline share. It is read by no metric.
+``--prefill`` (alone, like ``--gdn`` and ``--moe``) does the same for the
+prefill flash kernel at the benchmark's four prefill shapes, with
+``window_attention`` at the parent's window width beside it.
 
 This process never imports JAX: a chip belongs to one process at a time and
 the engine children need it (the kernel phase runs in a child of its own).
@@ -73,8 +76,11 @@ BOOT_TIMEOUT_S = 800.0
 # output, on values of order one — a handful of bf16 ulps at 1.0.
 KERNEL_MAX_ABS_ERR = 2e-2
 # tp=4 vs tp=1 first-token logprob: the row-parallel all-reduce sums bf16
-# partials in another order, and the logit of a 128k-way softmax over
-# random weights moves by a few bf16 ulps of the logit scale.
+# partials in another order (and since PR 35 the prompt's attention is
+# window_attention at tp=4 and the flash kernel at tp=1: float32 softmax
+# over bf16 products both, blocked differently), and the logit of a
+# 128k-way softmax over random weights moves by a few bf16 ulps of the
+# logit scale.
 TP_LOGPROB_TOL = 0.15
 # tp=4: per-device bytes in use may differ by replicated leaves (norms,
 # small buffers), not by a whole copy of weights or pool.
@@ -410,6 +416,20 @@ def time_kernel(kernel, case, calls, repeats=5, **kernel_kwargs):
     return best / calls
 
 
+def best_of(run, args, n, repeats=5):
+    """Seconds a call: the best of ``repeats`` runs of ``n`` calls chained
+    in one program, after one run that compiles (children only: JAX)."""
+    import jax
+
+    jax.block_until_ready(run(*args))
+    best = float("inf")
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best / n
+
+
 def kernel_child(model: str, rehearse: bool) -> int:
     """Runs in the child: the only code of this file that imports JAX."""
     import jax
@@ -580,16 +600,6 @@ def gdn_child(rehearse: bool) -> int:
         state = gd.pack_state(0.1 * jax.random.normal(ks[5], (b, h, dk, dv)))
         return (state, *gd.prepare(q, k, v), g, beta)
 
-    def best_of(run, args, n, repeats=5):
-        """Seconds a call: the best of ``repeats`` runs of ``n`` chained."""
-        jax.block_until_ready(run(*args))
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run(*args))
-            best = min(best, time.perf_counter() - t0)
-        return best / n
-
     def entry(name, sec, work, **more):
         out = {"op": name, **more, "bytes": work["bytes"],
                "flops": work["flops"], "us_per_call": None,
@@ -740,15 +750,6 @@ def moe_child(rehearse: bool) -> int:
                        roofline_pct=100.0 * least / sec)
         return out
 
-    def best_of(run, args, n, repeats=5):
-        jax.block_until_ready(run(*args))
-        best = float("inf")
-        for _ in range(repeats):
-            t0 = time.perf_counter()
-            jax.block_until_ready(run(*args))
-            best = min(best, time.perf_counter() - t0)
-        return best / n
-
     timing, checks, ok = [], [], True
     bs, width, rank = 16, d["pool_row"], d["rank"]
     scale = (d["nope"] + d["rope"]) ** -0.5
@@ -872,6 +873,167 @@ def moe_child(rehearse: bool) -> int:
     return 0 if ok else 1
 
 
+# The prefill chunk's attention alone at the benchmark's prefill shapes
+# (Dh 128, block 16): cell 3's 8 x 256 rectangle (rows of 64-320 tokens of
+# history, a padded row last), cell 2's one suffix in a 256-token chunk
+# behind 6300 tokens, and cell 4's two (the hybrid's full layers, 30 query
+# and 30 KV heads, so a KV head's score block is only TQ rows): one long
+# prompt's first chunk in a 1 x 2048 program, and the 8 x 256 rectangle
+# whose rows are first chunks or follow one or two (no prefix is served
+# there: a history is whole chunks). ``window`` is the width of the window
+# PR 33's tree gathered for such a dispatch with history
+# (utils/misc.py:window_mb_bucket: a quarter of the full bucket at least;
+# the hybrid's pinned at the full bucket, 4096 keys).
+PREFILL_TIMING_SHAPES = [
+    {"name": "chat-saturated", "rows": 8, "t": 256, "hist": (64, 320),
+     "chunk": (96, 256), "heads": 16, "kv_heads": 2, "window": 1024},
+    {"name": "agent-prefix", "rows": 1, "t": 256, "hist": (6300, 6300),
+     "chunk": (192, 192), "heads": 32, "kv_heads": 8, "window": 8192},
+    {"name": "hybrid-long-row", "rows": 1, "t": 2048, "hist": (0, 0),
+     "chunk": (1200, 2048), "heads": 30, "kv_heads": 30, "window": 4096},
+    {"name": "hybrid-rectangle", "rows": 8, "t": 256, "hist": (0, 2),
+     "hist_unit": 256, "chunk": (64, 256), "heads": 30, "kv_heads": 30,
+     "window": 4096},
+]
+PREFILL_TIMING_CALLS = 64
+PREFILL_MAX_ABS_ERR = 2e-2     # bf16 outputs of unit-variance values
+
+
+def prefill_child(rehearse: bool) -> int:
+    """``--prefill``: the Pallas flash prefill kernel
+    (ops/pallas/paged_attention.py:paged_flash_prefill) alone on the chip at
+    the benchmark's prefill shapes (PREFILL_TIMING_SHAPES), checked once
+    against ``window_attention`` over the gathered history, then timed (calls
+    chained through the queries inside one program over the layers of one
+    pool) against the larger of the least times its bytes and its FLOPs
+    allow (what a call must do: each valid query against its row's history
+    and the chunk's keys up to itself; K/V of the history once, q, k, v and
+    the output of the chunk once), with ``window_attention`` over a
+    pre-gathered window of the PARENT's width beside it (its gather, once a
+    dispatch for every layer, is timed apart). Run by no benchmark cell."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from production_stack_tpu.ops.attention import (gather_window,
+                                                    window_attention)
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill,
+    )
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "prefill", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    interpret = dev.platform == "cpu"
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+    dh, bs, layers = 128, 16, 4
+    calls = 2 if rehearse else PREFILL_TIMING_CALLS
+
+    timing, checks, ok = [], [], True
+    for shape in PREFILL_TIMING_SHAPES:
+        if rehearse:
+            shape = {**shape, "rows": min(shape["rows"], 2), "t": 32,
+                     "hist": (5, 40), "hist_unit": 1, "chunk": (7, 32),
+                     "heads": 4, "kv_heads": 2, "window": 64}
+        rng = np.random.default_rng(len(timing))
+        b, t, h, hkv = (shape[k] for k in ("rows", "t", "heads", "kv_heads"))
+        hist = rng.integers(shape["hist"][0], shape["hist"][1] + 1, b) \
+            * shape.get("hist_unit", 1)
+        clen = rng.integers(shape["chunk"][0], shape["chunk"][1] + 1, b)
+        if b > 2:
+            clen[-1] = 0            # a padded row of the rectangle
+            hist[-1] = 0
+        mb = shape["window"] // bs
+        live = -(-(hist + clen) // bs)
+        tables = np.zeros((b, mb), np.int32)
+        order = 1 + rng.permutation(int(live.sum()))
+        at = 0
+        for i in range(b):
+            tables[i, :live[i]] = order[at:at + live[i]]
+            at += live[i]
+        keys = jax.random.split(jax.random.PRNGKey(b), 5)
+        pool = (layers, hkv, (1 + int(live.sum())) * bs, dh)
+        q = jax.random.normal(keys[0], (b, t, h, dh), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (b, t, hkv, dh), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, t, hkv, dh), jnp.bfloat16)
+        k_pool = jax.random.normal(keys[3], pool, jnp.bfloat16)
+        v_pool = jax.random.normal(keys[4], pool, jnp.bfloat16)
+        tables = jnp.asarray(tables)
+        kv_lens = jnp.asarray(hist, jnp.int32)
+        chunk_lens = jnp.asarray(clen, jnp.int32)
+        positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+
+        # The pools and the window are ARGUMENTS of the timed programs: a
+        # closed-over array is a constant of the program, and the hybrid's
+        # window (1 GB each of K and V) as a constant took the host's
+        # 40 GiB in the compiler (my chip run, PR 35).
+        def kernel(q, layer, k_pool, v_pool):
+            return paged_flash_prefill(
+                q, k, v, positions, chunk_lens, k_pool, v_pool, tables,
+                kv_lens, layer, block_size=bs, interpret=interpret)
+
+        gather = jax.jit(lambda kp, vp: gather_window(kp, vp, tables, bs))
+        win_k, win_v = gather(k_pool, v_pool)
+
+        def window(q, layer, win_k, win_v):
+            return window_attention(
+                q, k, v, positions, chunk_lens,
+                jax.lax.dynamic_index_in_dim(win_k, layer, 0, False),
+                jax.lax.dynamic_index_in_dim(win_v, layer, 0, False),
+                kv_lens)
+
+        got = jax.jit(kernel)(q, 1, k_pool, v_pool).astype(jnp.float32)
+        want = jax.jit(window)(q, 1, win_k, win_v).astype(jnp.float32)
+        valid = (jnp.arange(t)[None] < chunk_lens[:, None])[..., None, None]
+        err = float(jnp.max(jnp.where(valid, jnp.abs(got - want), 0.0)))
+        finite = bool(jnp.all(jnp.isfinite(got)))
+        checks.append({"shape": shape["name"], "max_abs_err": err,
+                       "bound": PREFILL_MAX_ABS_ERR, "finite": finite})
+        ok = ok and finite and err <= PREFILL_MAX_ABS_ERR
+
+        def chained(fn):
+            def run(q, *held):
+                return jax.lax.fori_loop(
+                    0, calls, lambda i, x: fn(x, i % layers, *held), q)
+            return jax.jit(run)
+
+        # What a call must do: a valid query i of a row against its history
+        # and chunk keys 0..i, two products of Dh a (query, key, head).
+        pairs = int(np.sum(clen * hist + clen * (clen + 1) // 2))
+        flops = 4 * pairs * h * dh
+        nbytes = 2 * (int(hist.sum()) * hkv * dh * 2
+                      + int(clen.sum()) * (2 * h + 2 * hkv) * dh)
+        entry = {"shape": shape["name"], "rows": b, "t": t,
+                 "history": [int(x) for x in hist],
+                 "chunk_lens": [int(x) for x in clen],
+                 "flops": flops, "bytes": nbytes, "us_per_call": None,
+                 "least_us": None, "roofline_pct": None,
+                 "window_attention_us": None, "window_keys": mb * bs,
+                 "window_gather_us_per_layer": None}
+        sec = best_of(chained(kernel), (q, k_pool, v_pool), calls)
+        sec_win = best_of(chained(window), (q, win_k, win_v), calls)
+        sec_gather = best_of(gather, (k_pool, v_pool), layers)
+        if peak and not rehearse:
+            least = max(nbytes / (peak["hbm_gbps"] * 1e9),
+                        flops / (peak["bf16_tflops"] * 1e12))
+            entry.update(us_per_call=sec * 1e6, least_us=least * 1e6,
+                         roofline_pct=100.0 * least / sec,
+                         window_attention_us=sec_win * 1e6,
+                         window_gather_us_per_layer=sec_gather * 1e6)
+        timing.append(entry)
+        # As it goes, beside the phase's one line at the end: a later
+        # shape that dies keeps the earlier ones' numbers.
+        print(json.dumps(entry), file=sys.stderr, flush=True)
+    emit({"phase": "prefill", "interpret": interpret, "checks": checks,
+          "timing": timing, "peak": peak, "device": device,
+          "ok": ok and (rehearse or not interpret)})
+    return 0 if ok else 1
+
+
 def phase_serve(model, engine_args, attn: str) -> dict:
     """One boot of engine + router and a handful of requests through the
     router."""
@@ -947,7 +1109,13 @@ def phases_four_chips(model, engine_args) -> list:
     """The paths that exist only across chips — four one-chip replicas
     behind the router, and one tp=4 engine — and the one-chip reference
     they are compared with. Paged decode throughout: tp=4 is the
-    shard_map'd kernel path, and token identity needs one path. Prefix
+    shard_map'd kernel path, and token identity needs one path. Prefill
+    is one path for the replicas and the reference (one-chip engines: the
+    flash kernel over the pool) and another for tp=4 (a kv-head-sharded
+    pool keeps its gathered window and ``window_attention``: the same
+    mathematics and precision in another order), which is why tp=4 is
+    held to a first-token logprob within TP_LOGPROB_TOL and its token
+    agreement is printed, not judged. Prefix
     caching off: greedy tokens are equal only where the arithmetic is, and
     a replica that meets a prompt cold prefills it whole while the
     reference, having served its neighbours, prefills only the tail behind
@@ -1105,6 +1273,14 @@ def verdict(lines: list, chips: int, full_depth: int,
                     f"{name}: {prog['pool_copies']} whole-pool copies")
             if prog.get("gdn_step") == "xla" and not rehearsal:
                 faults.append(f"{name}: gdn_step is not the Pallas kernel")
+            # The engine's own predicate, not a flag's name: a tp=4
+            # engine, an int8 pool or latent rows gather a window and say
+            # so (``prefill_reads_pool`` false, ``"xla"``).
+            if prog.get("prefill_reads_pool") and not rehearsal \
+                    and prog.get("prefill_attn") != "pallas":
+                faults.append(
+                    f"{name}: prefill views hold the pool but the program "
+                    "does not hold the Pallas prefill kernel")
     if not boots:
         faults.append("no engine report: nothing was served")
     for boot in boots:
@@ -1121,9 +1297,20 @@ def verdict(lines: list, chips: int, full_depth: int,
             faults.append("engine warmed no shape family")
         if boot.get("num_layers") != full_depth:
             faults.append(f"depth {boot.get('num_layers')} != {full_depth}")
-    if len(boots) > 1 and not any(b.get("cache_hit", 0) > 0
-                                  for b in boots[1:]):
-        faults.append("no later boot found the compile cache (0 hits)")
+    # A boot that repeats an earlier boot's path (attention impl, device
+    # count) compiles the same programs and must find them cached. Boots
+    # of different paths share none since PR 35 (a paged engine's prefill
+    # holds the flash kernel, a window engine's ``window_attention``), so
+    # on a cold cache the one-chip run's two boots both miss everything,
+    # by design; on a warm one both hit.
+    def path(boot):
+        return boot.get("attn_impl"), boot["device"].get("count")
+
+    repeats = [b for i, b in enumerate(boots)
+               if any(path(a) == path(b) for a in boots[:i])]
+    if repeats and not any(b.get("cache_hit", 0) > 0 for b in repeats):
+        faults.append("no later boot of a path booted before found the "
+                      "compile cache (0 hits)")
     device = {k: boots[-1]["device"].get(k)
               for k in ("platform", "kind", "count")} if boots else None
     if device and device["count"] != chips:
@@ -1145,6 +1332,9 @@ def main(argv=None) -> int:
     ap.add_argument("--moe", action="store_true",
                     help="only time the latent decode kernel and the "
                          "experts' grouped matmuls alone and exit")
+    ap.add_argument("--prefill", action="store_true",
+                    help="only check and time the prefill flash kernel "
+                         "alone at the benchmark's prefill shapes and exit")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, HERE)
@@ -1161,6 +1351,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return moe_child(args.rehearse)
+    if args.prefill:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return prefill_child(args.rehearse)
 
     model, full_depth, engine_args = MODEL, FULL_DEPTH, ENGINE_ARGS
     if args.rehearse:
@@ -1184,9 +1378,10 @@ def main(argv=None) -> int:
         lines.append(emit(phase_kernel(model, args.rehearse)))
         on_tpu = (lines[0].get("device") or {}).get("platform") == "tpu"
         if on_tpu or args.rehearse:
-            # The path `auto` resolves to, then the other one: both decode
-            # attention paths run compiled, and the second boot shows the
-            # compile cache being found again.
+            # The path `auto` resolves to, then the other one: both
+            # attention paths run compiled. They share no program (see
+            # ``verdict``): a second run of the smoke on the same machine
+            # shows the compile cache being found again, a first need not.
             for attn in ("auto", "paged"):
                 lines.append(phase_serve(model, engine_args, attn))
             a, b = (ln.get("greedy_toks") for ln in lines[1:3])

@@ -2,9 +2,11 @@
 
 XLA discipline (the performance-critical part of the design — every item here
 was profiled on a v5e in round 1/2):
-  * The paged KV pool is gathered into a contiguous per-sequence WINDOW once
-    per dispatch (ops/attention.py:gather_window) and new KV is written back
-    once at the end. Per-layer gathers/scatters against the pool cost ~7 ms
+  * The paged KV pool is read IN PLACE by the Pallas kernels (paged decode;
+    a prefill chunk where ``prefill_reads_pool``) or, on the window paths,
+    gathered into a contiguous per-sequence WINDOW once per dispatch
+    (ops/attention.py:gather_window); new KV is written back once at the
+    end. Per-layer gathers/scatters against the pool cost ~7 ms
     per decode step (XLA gathers run at ~15% of HBM bandwidth; pool xs/ys in
     the layer scan copy the pool every layer); the hoisted form amortizes one
     gather over num_decode_steps * num_layers uses.
@@ -43,7 +45,12 @@ from production_stack_tpu.engine.sampling import (
 from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
 from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import ModelConfig
-from production_stack_tpu.ops.attention import KVView, gather_window
+from production_stack_tpu.ops.attention import (
+    KVView,
+    gather_window,
+    prefill_attn_path,
+    prefill_kernel_covers,
+)
 from production_stack_tpu.ops.gated_delta import step_path
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
@@ -231,6 +238,13 @@ def _setup_compilation_cache(cache_dir: str, device) -> Optional[str]:
     process with another directory resets JAX's already-opened cache so
     the new directory takes effect.
 
+    Where there is a cache, two PROCESS-WIDE JAX options are set with it
+    (both are about what a cache key is; docs/OBSERVABILITY.md, "Compile
+    cache"): no minimum compile time, and source locations of ONE frame
+    (``jax_traceback_in_locations_limit``), which also shortens the
+    locations in every program's text and in a trace's ``source`` fields
+    to the operation's own line.
+
     Returns the directory the hit/miss accounting and the warmup manifest
     read; None (uncached) only when the variable is unset AND ``cache_dir``
     is empty."""
@@ -256,6 +270,16 @@ def _setup_compilation_cache(cache_dir: str, device) -> Optional[str]:
     # compiles cached, and the hit/miss accounting below reads "no new
     # artifact" as a hit — so no min-compile-time filter.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # A Pallas kernel's serialized module is part of its program's cache
+    # key and carries each operation's source location WITH its callers'
+    # frames (ten by default). A kernel traced through few frames (the
+    # hybrid's prefill kernel: eight below ``_prefill_impl``) then keys
+    # differently from the AOT prepass, the execute pass and a deferred
+    # variant's first use in serving, and every boot compiles again
+    # (PERF.md §6, PR 35; §7, PR 29). One frame — the operation's own
+    # line — is the same from every caller, and no longer moves with this
+    # file's line numbers.
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     return path
 
 
@@ -1087,10 +1111,14 @@ class ModelRunner:
             # dispatch's bucketed rows x blocks window at pool size (window
             # budgets below).
             n = budget // (bytes_per_block + window_bytes_per_block)
+        elif self.prefill_reads_pool:
+            # Neither decode nor prefill copies the pool: all of it is pool.
+            n = budget // bytes_per_block
         else:
             # Paged decode never copies the pool, but chunked PREFILL still
-            # gathers a [rows, max_blocks] history window; reserve the
-            # worst-case bucketed prefill window out of the pool budget.
+            # gathers a [rows, max_blocks] history window here (latent rows,
+            # an int8 pool, tp or sp > 1); reserve the worst-case bucketed
+            # prefill window out of the pool budget.
             reserve_bytes = min(
                 _bucket(cfg.max_prefill_seqs, 1, max(1, cfg.max_num_seqs))
                 * _bucket(cfg.max_blocks_per_seq, 1,
@@ -1124,8 +1152,11 @@ class ModelRunner:
 
     @property
     def prefill_window_blocks(self) -> int:
-        """Per-dispatch block budget for the PREFILL history window (both
-        impls gather it for chunks past the first)."""
+        """Per-dispatch block budget for the PREFILL history window, where
+        chunks past the first gather one; no cap where the history is read
+        in place (``prefill_reads_pool``), as for paged decode."""
+        if self.prefill_reads_pool:
+            return 1 << 30
         if self.attn_impl == "window":
             return self.num_kv_blocks
         # Set by _derive_num_blocks; explicit num_kv_blocks configs skip the
@@ -1153,13 +1184,54 @@ class ModelRunner:
                            max(1, cfg.max_blocks_per_seq))
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
 
+    def _prefill_t_buckets(self) -> List[int]:
+        """Every chunk-length bucket a prefill dispatch can take: the
+        powers of two from ``prefill_t_floor`` to the token budget's."""
+        cfg = self.config
+        t_max = _bucket(cfg.max_num_batched_tokens, 16,
+                        max(16, cfg.max_num_batched_tokens))
+        ts = [prefill_t_floor(cfg.max_num_batched_tokens)]
+        while ts[-1] * 2 <= t_max:
+            ts.append(ts[-1] * 2)
+        return ts
+
+    @functools.cached_property
+    def prefill_reads_pool(self) -> bool:
+        """Whether a prefill chunk reads its rows' history IN PLACE from
+        the paged pool (``attend`` over a view that holds the pool: the
+        Pallas flash kernel in a program lowered for a TPU,
+        ops/attention.py:_attend_chunk_over_pool) instead of from a window
+        gathered once a dispatch. The view ``_prefill_impl`` builds, the
+        families warm-up and the AOT prepass enumerate, the scheduler's
+        window budget and the pool's window reserve all follow it, and it
+        is ``attend``'s own predicate (ops/attention.py:
+        prefill_kernel_covers) asked of EVERY chunk length this config can
+        dispatch: paged attention over K/V rows (latent rows have a path
+        of their own) in the compute dtype (no int8 scales) on a mesh of
+        one device (a sharded pool or a sequence-parallel chunk keeps its
+        gathered window), at a head width, block size and chunk buckets
+        the kernel tiles. Were the two ever to disagree, ``attend`` raises
+        while the program is traced, at warm-up; ``GET /debug/programs``
+        reports this beside what each prefill program holds."""
+        mc = self.model_config
+        sharded = self.mesh.size > 1
+        return self.attn_impl == "paged" and self.kv_pools == 2 and all(
+            prefill_kernel_covers(
+                t, mc.num_heads, self.kv_spec.kv_heads,
+                self.kv_spec.head_dim, self.kv_spec.head_dim,
+                self.config.block_size, (self.dtype,),
+                scales=self.kv_quantized, kv_sharded=sharded, ring=sharded)
+            for t in self._prefill_t_buckets())
+
     def _prefill_mb(self, live_blocks: int, has_window: bool,
                     rows: int = 1) -> int:
         """Static block-table width for a prefill dispatch: pinned at the
-        max bucket when no window is gathered (block tables only feed the
-        pool write's slot mapping — padding is free), quantized when a chunk
-        with history gathers its [rows, mb*block_size] window — unless the
-        window is pinned too (_pins_prefill_window)."""
+        max bucket when no window is gathered (the block tables feed the
+        pool write's slot mapping and, where the history is read in place,
+        a page loop bounded by the row's length: padding is free, as in
+        ``_decode_mb``), quantized when a chunk with history gathers its
+        [rows, mb*block_size] window — unless the window is pinned too
+        (_pins_prefill_window)."""
         cfg = self.config
         full = _bucket(cfg.max_blocks_per_seq, 1,
                        max(1, cfg.max_blocks_per_seq))
@@ -1168,7 +1240,10 @@ class ModelRunner:
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
 
     def _pins_prefill_window(self, rows: int, full_mb: int) -> bool:
-        """A model that declares recurrent state, or whose paged rows are
+        """Only where a history window is still gathered (not where
+        ``prefill_reads_pool``: the benchmark's hybrid reads its full
+        layers' pool in place since PR 35; its latent-row model does not).
+        A model that declares recurrent state, or whose paged rows are
         latent rows, gathers its prefill history window at the full width
         whatever the rows hold, where the window budget allows that many
         blocks: ONE windowed family a (rows, t) instead of three. Its
@@ -2469,15 +2544,23 @@ class ModelRunner:
         in_chunk = t_iota[None, :] < chunk_lens[:, None]
 
         quant = self.kv_quantized
-        if has_window:
+        if self.prefill_reads_pool:
+            # The rows' history is read in place, up to chunk_start, by
+            # ``attend`` (a first chunk's is empty): no window, whatever
+            # ``has_window`` says (the families carry it False).
+            view = KVView(
+                pool_k=kv_k, pool_v=kv_v, block_tables=block_tables,
+                kv_lens=chunk_start, block_size=bs,
+            )
+        elif has_window:
             win_k, win_v = gather_window(
                 kv_k, kv_v, block_tables, bs,
                 kv_ks if quant else None, kv_vs if quant else None,
                 out_dtype=self.dtype,
             )
-            win_len = chunk_start
+            view = KVView(win_k, win_v, chunk_start)
         else:
-            win_k = win_v = win_len = None
+            view = KVView()
 
         # Sequence-parallel prefill rides ring attention over the sp mesh
         # axis (ops/attention.py:attend) — first chunks ring the chunk itself;
@@ -2496,16 +2579,14 @@ class ModelRunner:
         if self.state_specs:
             state_slots = scalars[12]
             hidden, k_new, v_new, rows_state = self._forward(
-                params, mc, token_ids, positions, chunk_lens,
-                KVView(win_k, win_v, win_len),
+                params, mc, token_ids, positions, chunk_lens, view,
                 lora=lora, state=self._read_state_rows(
                     state_pools, state_slots, fresh=chunk_start == 0),
             )
         else:
             hidden, k_new, v_new, *fwd_stats = self._forward(
                 params, mc, token_ids, positions, chunk_lens,
-                KVView(win_k, win_v, win_len,
-                       sp_mesh=self.mesh if rings else None),
+                view._replace(sp_mesh=self.mesh if rings else None),
                 act_sharding=self._act_sharding, lora=lora,
             )
         fwd_stats = fwd_stats[0] if self.fwd_stats else ()
@@ -2630,7 +2711,8 @@ class ModelRunner:
         t = _bucket(max(batch.chunk_lens),
                     prefill_t_floor(cfg.max_num_batched_tokens),
                     max(16, cfg.max_num_batched_tokens))
-        has_window = any(st > 0 for st in batch.chunk_starts)
+        has_window = not self.prefill_reads_pool and \
+            any(st > 0 for st in batch.chunk_starts)
         mb = self._prefill_mb(max(len(s.block_ids) for s in seqs),
                               has_window, b)
 
@@ -3022,7 +3104,10 @@ class ModelRunner:
 
     def reachable_prefill_families(self):
         """Every (b, t, mb, has_window) prefill family reachable under this
-        config (see reachable_decode_families)."""
+        config (see reachable_decode_families). Where the history is read
+        in place (``prefill_reads_pool``) a window is no property of the
+        program: ONE family a (rows, t); where it is gathered, the family
+        without a window and the windowed ladder (or its pinned width)."""
         cfg = self.config
         full_mb = _bucket(cfg.max_blocks_per_seq, 1,
                           max(1, cfg.max_blocks_per_seq))
@@ -3034,21 +3119,25 @@ class ModelRunner:
             window_mb_bucket(m, cfg.max_blocks_per_seq)
             for m in (1, full_mb // 4, full_mb // 2, full_mb)
         })
+
+        def windowed(pb):
+            if self.prefill_reads_pool:
+                return ()
+            return [full_mb] if self._pins_prefill_window(pb, full_mb) \
+                else win_mbs
+
         fams = set()
         for pb in {1, pb_max}:
-            t = prefill_t_floor(cfg.max_num_batched_tokens)
-            while t <= t_max:
+            for t in self._prefill_t_buckets():
                 # Multi-row dispatches split the token budget fairly, so
                 # their chunk bucket never exceeds bucket(budget // 2).
                 if pb == 1 or t <= _bucket(
                     max(16, cfg.max_num_batched_tokens // 2), 16, t_max
                 ):
                     fams.add((pb, t, full_mb, False))
-                    for mb in ([full_mb] if self._pins_prefill_window(
-                            pb, full_mb) else win_mbs):
+                    for mb in windowed(pb):
                         if pb * mb <= self.prefill_window_blocks:
                             fams.add((pb, t, mb, True))
-                t *= 2
         return sorted(fams)
 
     def _abstract_params(self):
@@ -3130,7 +3219,10 @@ class ModelRunner:
         carried state, ops/gated_delta.py) — and the program's temporaries
         beside one payload pool's bytes; for a decode program of a model
         with recurrent state, ``gdn_step``: which execution of the
-        recurrence's step it holds (``"pallas"`` / ``"xla"``). Nothing
+        recurrence's step it holds (``"pallas"`` / ``"xla"``); for a
+        prefill program, ``prefill_attn``: which execution of the chunk's
+        attention (``"pallas"``: the flash kernel over the pool /
+        ``"xla"``: ``window_attention`` over gathered keys). Nothing
         runs; with a compile cache the programs are the ones warmup left
         there."""
         pools = [self.kv_k] + [
@@ -3167,6 +3259,9 @@ class ModelRunner:
             path = step_path(text)
             if path:
                 out[-1]["gdn_step"] = path
+            if kind == "prefill":
+                out[-1]["prefill_attn"] = prefill_attn_path(text)
+                out[-1]["prefill_reads_pool"] = self.prefill_reads_pool
         return out
 
     def _warmup_compile_prepass(self) -> int:

@@ -9,8 +9,9 @@ TPU-first design notes:
   * The paged KV pool is NOT threaded through the layer scan or the step
     scan: scanning the pools as xs/ys cost a full pool copy per layer (~2
     ms/step on a v5e, profiled round 1). The scans only READ it, as a
-    closed-over constant — the Pallas kernel in place (``memory_space=ANY``),
-    or the window the runner gathers once per dispatch
+    closed-over constant — the Pallas kernels in place (``memory_space=ANY``:
+    decode, and a prefill chunk's history), or the window the runner
+    gathers once per dispatch where no kernel covers the view
     (ops/attention.py:gather_window) — and the runner writes the dispatch's
     new KV back once, after the scans, IN PLACE: the donated pool is updated
     by ``dynamic_update_slice``s of block-wide slabs (ops/kv_write.py) and no

@@ -12,8 +12,14 @@ page order holds the KV for absolute token position ``j``. Both prefill chunks
 decode batches share one compiled program shape family.
 
 ``paged_attention_xla`` (pure jnp gather + einsum) is the reference every
-kernel is tested against; ``window_attention`` and the Pallas flash-decode
-kernel (ops/pallas/paged_attention.py) are what serving runs.
+kernel is tested against. What serving runs: the Pallas kernels of
+ops/pallas/paged_attention.py over the pool in place — flash decode (T ==
+1) and, in a program lowered for a TPU, flash prefill (T > 1: a chunk over
+its rows' history and itself) — and ``window_attention``, the statement of
+the chunk's attention, the kernels' oracle, and the path of everything the
+prefill kernel does not cover: a backend without the kernel, the window
+decode path, speculative verify (``chunk_bias``), the sequence-parallel
+ring, latent rows, an int8 or kv-head-sharded pool.
 
 The serving path's seam: the runner describes the KV a forward
 may read as one ``KVView``, a model module hands it unopened to ``attend``
@@ -77,11 +83,15 @@ def window_attention(
 ) -> jax.Array:
     """Dense attention against up to three key segments, TPU-shaped.
 
-    Replaces the per-layer paged gather of ``paged_attention_xla`` on the hot
-    path: the caller gathers the paged KV pool ONCE per dispatch into a
-    contiguous [Hkv, B, S, Dh] window (slot s holds the sequence's absolute
-    position s), and attention is plain masked batched matmuls that stream at
-    HBM bandwidth — no gather ops inside the step.
+    The statement of a chunk's attention, and what runs wherever the
+    Pallas prefill kernel does not (see the module docstring): the caller
+    gathers the paged KV pool ONCE per dispatch into a contiguous
+    [Hkv, B, S, Dh] window (slot s holds the sequence's absolute position
+    s), and attention is plain masked batched matmuls — no gather ops
+    inside the step. Its price, which the kernel does not pay (PERF.md §6,
+    PR 35): every row is scored against the whole window and the whole
+    chunk, and the float32 scores [Hkv, B, G*TQ, S + T] cross HBM for the
+    max, the exp, the sum and the value product.
 
     Segments:
       * window — history tokens already in the pool (valid where s < win_len);
@@ -267,9 +277,12 @@ class KVView(NamedTuple):
     ring_k: Optional[jax.Array] = None     # [L, Hkv, B, R, Dh]
     ring_v: Optional[jax.Array] = None
     ring_pos: Optional[jax.Array] = None   # [B, R]
-    # The paged pool itself, read in place by the Pallas decode kernel
-    # (T == 1). Scales set: int8 pool, dequantized in the kernel. tp_mesh
-    # set: the pool is kv-head-sharded and the kernel runs under shard_map.
+    # The paged pool itself, read in place by the Pallas kernels: decode
+    # (T == 1) and a prefill chunk (T > 1: kv_lens is then each row's
+    # history, chunk_start; only a view ``prefill_kernel_covers`` says yes
+    # to may hold the pool for a chunk, any other raises in ``attend``).
+    # Scales set: int8 pool, dequantized in the decode kernel. tp_mesh set:
+    # the pool is kv-head-sharded and the decode kernel runs under shard_map.
     pool_k: Optional[jax.Array] = None     # [L, Hkv, num_slots, Dh]
     pool_v: Optional[jax.Array] = None
     k_scale: Optional[jax.Array] = None    # [L, Hkv, num_slots]
@@ -351,6 +364,9 @@ def attend(
             jnp.concatenate([pos_w, positions], axis=1),
             view.sp_mesh,
         )
+    if view.pool_k is not None and t > 1:
+        return _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view,
+                                       layer)
     if view.pool_k is not None:
         # Paged decode (T == 1): the pool segment runs in the Pallas
         # flash-decode kernel directly against this layer of the stacked HBM
@@ -401,6 +417,103 @@ def attend(
         view.ring_k, view.ring_v, view.ring_pos,
         chunk_bias=view.chunk_bias,
     )
+
+
+def prefill_kernel_covers(
+    t: int, num_heads: int, num_kv_heads: int, head_dim: int,
+    value_dim: int, block_size: int, dtypes, *,
+    scales: bool = False, kv_sharded: bool = False, ring: bool = False,
+    chunk_bias: bool = False,
+) -> bool:
+    """THE predicate: whether the Pallas flash prefill kernel covers a
+    chunk of ``t`` tokens over a view that holds the pool. Asked in two
+    places that therefore agree: the runner, of every chunk length it can
+    dispatch, before it builds the view (``prefill_reads_pool``: only then
+    does a view hold the pool, and the window reserve, the scheduler's
+    window budget and the windowed families go), and ``attend``, of the
+    operands it is handed. Covered: K and V rows of one width in ONE dtype
+    (``dtypes``: the chunk's and the pools'), no int8 scales, no kv-head
+    sharding, no ring, no ``chunk_bias``, and a head width, block size and
+    chunk length the kernel tiles (``supports_pallas_prefill``). Not
+    covered by choice, though the decode kernel covers them: an int8 pool
+    (its scales would ride as the decode kernel's do) and a
+    kv-head-sharded pool; no benchmark cell runs either."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        supports_pallas_prefill,
+    )
+
+    kinds = {jnp.dtype(d) for d in dtypes}
+    return (
+        not (scales or kv_sharded or ring or chunk_bias)
+        and len(kinds) == 1 and value_dim == head_dim
+        and supports_pallas_prefill(t, num_heads, num_kv_heads, head_dim,
+                                    kinds.pop().itemsize, block_size)
+    )
+
+
+def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
+    """A prefill chunk (T > 1) over a view that holds the POOL: each row's
+    history is the pool's slots below ``view.kv_lens`` by its block table.
+
+    One algorithm, two executions, chosen HERE by the platform the program
+    is LOWERED for (``lax.platform_dependent``: the program's, not the
+    process's default backend; the rule of ops/gated_delta.py:gdn_step_at).
+    A program for a TPU holds the Pallas flash kernel
+    (ops/pallas/paged_attention.py:paged_flash_prefill): history read in
+    place, nothing gathered, no score tensor in HBM; so does any program
+    whose view says ``interpret`` (the kernel's own tests on a CPU). A
+    program for any other backend gathers this layer's pages of its rows
+    and is ``window_attention``: the statement of the computation and the
+    tests' oracle. There is no third execution: a pool view the kernel
+    does not cover (``prefill_kernel_covers``) is the CALLER's fault and
+    raises while the program is traced (at warm-up, on every backend),
+    so no program for a TPU gathers a window a layer unseen; whoever
+    builds views asks the same predicate first and hands ``attend`` a
+    gathered window instead."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill,
+    )
+
+    bs = view.block_size
+    b, t, h, dh = q.shape
+    if not prefill_kernel_covers(
+            t, h, k.shape[2], dh, v.shape[-1], bs,
+            (k.dtype, v.dtype, view.pool_k.dtype, view.pool_v.dtype),
+            scales=view.k_scale is not None,
+            kv_sharded=view.tp_mesh is not None,
+            ring=view.ring_k is not None,
+            chunk_bias=view.chunk_bias is not None):
+        raise ValueError(
+            f"attend: a chunk of {t} tokens ({h}/{k.shape[2]} heads x {dh}"
+            f", {k.dtype} over a {view.pool_k.dtype} pool, block {bs}) "
+            "over a pool view the prefill kernel does not cover "
+            "(ops/attention.py:prefill_kernel_covers): gather a window")
+
+    def gathered(q, k, v, positions, chunk_lens, pool_k, pool_v, tables,
+                 kv_lens, layer):
+        def one(x):
+            return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=True)
+
+        win_k, win_v = gather_window(one(pool_k), one(pool_v), tables, bs,
+                                     out_dtype=q.dtype)
+        return window_attention(q, k, v, positions, chunk_lens, win_k[0],
+                                win_v[0], kv_lens)
+
+    def kernel(*args, interpret=False):
+        return paged_flash_prefill(*args, block_size=bs, interpret=interpret)
+
+    args = (q, k, v, positions, chunk_lens, view.pool_k, view.pool_v,
+            view.block_tables, view.kv_lens, jnp.asarray(layer, jnp.int32))
+    if view.interpret:
+        return kernel(*args, interpret=True)
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=gathered)
+
+
+def prefill_attn_path(hlo_text: str):
+    """Which execution of a prefill chunk's attention a compiled program
+    (``as_text()``) holds: ``"pallas"`` (the flash kernel over the pool) or
+    ``"xla"`` (``window_attention`` over gathered keys)."""
+    return "pallas" if "paged_flash_prefill" in hlo_text else "xla"
 
 
 def _attend_latent(q, rows, positions, chunk_lens, view, layer, scale,

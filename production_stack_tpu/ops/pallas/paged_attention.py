@@ -1,4 +1,5 @@
-"""Pallas TPU flash-decode kernel over the paged KV pool.
+"""Pallas TPU flash kernels over the paged KV pool: decode (below) and
+prefill (the last section).
 
 The TPU-native replacement for the paged-attention CUDA kernels inside the
 reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
@@ -49,10 +50,11 @@ reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
     ring/self segment computed densely in XLA (ops/attention.py:
     merge_attention_segments).
 
-Decode-only (T == 1): queries sit at position >= kv_len, so causality over
-the pool is exactly "attend to slots < kv_len" and no per-token causal mask
-is needed. Prefill chunks use the XLA window path (compute-bound there,
-gather cost amortized over the chunk).
+The decode kernels take T == 1: queries sit at position >= kv_len, so
+causality over the pool is exactly "attend to slots < kv_len" and no
+per-token causal mask is needed. A prefill chunk (T > 1) has a kernel of its
+own on the same machinery, ``paged_flash_prefill`` (the last section): the
+history from the pool up to the row's length, then the chunk causally.
 """
 
 import functools
@@ -558,7 +560,7 @@ def paged_attention_decode_pallas(
 ) -> jax.Array:
     """Single-layer convenience wrapper (normalized output only)."""
     b, t, h, dh = q.shape
-    assert t == 1, "pallas kernel is decode-only; prefill uses the XLA path"
+    assert t == 1, "the decode kernel; a chunk takes paged_flash_prefill"
     out, _, _ = paged_flash_decode_stats(
         q.reshape(b, h, dh), k_pool[None], v_pool[None], block_tables,
         kv_lens, jnp.zeros((1,), jnp.int32),
@@ -777,3 +779,419 @@ def paged_flash_decode_latent_stats(
         interpret=interpret,
     )(layer, block_tables, kv_lens, q, kv_pool)
     return out, m.reshape(b, h), l.reshape(b, h)
+
+
+# ------------------------------------------------------------------ prefill
+# A prefill chunk (T > 1) attends through the same machinery: the row's
+# HISTORY is read in place from the paged pool by its block table, up to
+# ``kv_len`` and no further, then the chunk's own K/V causally; the
+# softmax's running max, sum and accumulator live in VMEM, so no gathered
+# window and no [.., M, S] score tensor ever exists in HBM. What differs
+# from decode:
+#
+#   * Grid (rows, query blocks). A program holds QUERY_BLOCK queries of one
+#     row for EVERY head: a KV head's G x TQ query rows are its matmuls' M
+#     (the wrapper lays q out as [B, Hkv, NQ, G*TQ, Dh], pre-scaled). Pages
+#     are fetched with all KV heads at once, as decode fetches them (a DMA
+#     a head would multiply the starts by Hkv: 30 for the hybrid's full
+#     layers); the heads are a loop inside, their flash state in scratch.
+#   * The call's key TILES form one sequence across programs, in the two
+#     superpage buffers, the next always in flight: a program's history
+#     superpages (cdiv(kv_len, super_tokens), none at kv_len 0), then the
+#     chunk's key blocks 0..qb, each ONE copy of [Hkv, TQ, Dh] out of the
+#     chunk's K/V [Hkv, B, T, Dh] in HBM. Key blocks above the diagonal
+#     are never fetched; block qb is masked by position, as
+#     ``window_attention`` masks it (key position <= query position, key
+#     index < chunk_len).
+#   * A program whose queries are all padding (``qb * TQ >= chunk_len``: a
+#     padded row is ``chunk_len == 0``) fetches, waits for and computes
+#     nothing, and writes zeros.
+#
+# Scores, max, exp and sums are float32; both products take operands in the
+# pool's dtype with float32 accumulation; the output is q.dtype: what
+# ``ops/attention.py:window_attention`` computes, nothing lower.
+QUERY_BLOCK = 256    # chunk queries a program, chunk keys a tile
+PREFILL_VMEM_BYTES = 64 << 20   # of a v5e's 128 MiB: buffers 8, q and o
+                                # blocks twice 8, flash state 13, scores 16
+# A masked score: finite, so that max and exp stay finite whatever a tile
+# holds, and far below any real score (exp(_MASKED - m) is exactly 0).
+_MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
+
+
+def prefill_tiles(t: int, num_heads: int, num_kv_heads: int, head_dim: int,
+                  itemsize: int, block_size: int):
+    """(superpage tokens, queries a program) for a chunk of ``t`` tokens:
+    the decode kernel's superpage, and QUERY_BLOCK queries, halved while
+    one program's rows over all heads (H x TQ x Dh: its q and o blocks and
+    flash state) or one KV head's score rows (G x TQ) are too many."""
+    sup = super_tokens(num_kv_heads, head_dim, itemsize, block_size)
+    tq = min(t, QUERY_BLOCK, sup)
+    g = num_heads // num_kv_heads
+    while tq > block_size and tq % 2 == 0 and (
+            num_heads * tq * head_dim > 8192 * LANES or g * tq > 2048):
+        tq //= 2
+    return sup, tq
+
+
+def supports_pallas_prefill(t: int, num_heads: int, num_kv_heads: int,
+                            head_dim: int, itemsize: int,
+                            block_size: int) -> bool:
+    """Whole lanes a head (no token packing: head_dim 64 stays on the
+    window path), pages that tile a superpage, and a chunk that is whole
+    query blocks of whole pages."""
+    if head_dim % LANES or SUPER_TOKENS % block_size or block_size % 8 \
+            or num_heads % num_kv_heads:
+        return False
+    _, tq = prefill_tiles(t, num_heads, num_kv_heads, head_dim, itemsize,
+                          block_size)
+    return t % tq == 0 and tq % block_size == 0
+
+
+def _prefill_kernel(
+    # scalar prefetch
+    layer_ref,          # SMEM [1] int32
+    block_tables_ref,   # SMEM [B, Mb] int32
+    kv_lens_ref,        # SMEM [B] int32: tokens of the row in the pool
+    chunk_lens_ref,     # SMEM [B] int32: valid tokens of the row's chunk
+    # inputs
+    q_ref,              # VMEM [1, Hkv, 1, G*TQ, Dh] (pre-scaled)
+    posq_ref,           # VMEM [1, 1, TQ, 1] int32: the block's positions
+    posk_ref,           # VMEM [1, NQ, 1, TQ] int32: the row's, as rows
+    kc_hbm,             # HBM  [Hkv, B, T, Dh]: the chunk's keys
+    vc_hbm,             # HBM  [Hkv, B, T, Dh]
+    k_hbm,              # HBM  [L, Hkv, num_slots, Dh]
+    v_hbm,              # HBM  [L, Hkv, num_slots, Dh]
+    # output
+    o_ref,              # VMEM [1, Hkv, 1, G*TQ, Dh]
+    # scratch (outlives a program: the buffers are handed on)
+    k_buf,              # VMEM [NUM_BUFS, Hkv, super_tokens, Dh]
+    v_buf,
+    sem_k,              # DMA sems (NUM_BUFS,)
+    sem_v,
+    fetched_ref,        # SMEM [1] int32: tiles the programs before fetched
+    m_ref,              # VMEM [Hkv, G*TQ, 1] f32: running max
+    l_ref,              # VMEM [Hkv, G*TQ, 1] f32: running sum
+    acc_ref,            # VMEM [Hkv, G*TQ, Dh] f32
+    *,
+    block_size: int,
+    super_tokens: int,
+    tq: int,
+    q_per_kv: int,
+):
+    b, qb = pl.program_id(0), pl.program_id(1)
+    num_rows, nq = pl.num_programs(0), pl.num_programs(1)
+    layer = layer_ref[0]
+    bs, sup, g = block_size, super_tokens, q_per_kv
+    spp = sup // bs                     # pages per superpage
+    gp = min(ISSUE_UNROLL, spp)
+    hkv = k_buf.shape[1]
+    kv_len = kv_lens_ref[b]
+    chunk_len = chunk_lens_ref[b]
+
+    def hist_tiles(row):
+        return pl.cdiv(kv_lens_ref[row], sup)
+
+    def tiles_of(row, blk):
+        # History superpages, then chunk key blocks 0..blk; none where the
+        # program's queries are all padding.
+        return jnp.where(blk * tq < chunk_lens_ref[row],
+                         hist_tiles(row) + blk + 1, 0)
+
+    def pages_of(row, s):
+        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
+
+    n_hist = hist_tiles(b)
+    n_tiles = tiles_of(b, qb)
+    is_first = (b == 0) & (qb == 0)
+    first = jnp.where(is_first, 0, fetched_ref[0])
+    fetched_ref[0] = first + n_tiles
+
+    def start_tile(row, blk, s, n):
+        # Tile s of program (row, blk), the n-th of the call, goes in
+        # flight into buffer n % NUM_BUFS. A program with no tiles issues
+        # nothing.
+        slot = jax.lax.rem(n, NUM_BUFS)
+        nh = hist_tiles(row)
+        has = s < tiles_of(row, blk)
+
+        @pl.when(has & (s < nh))
+        def _():
+            # A history superpage: page-granular copies, as decode's.
+            pages = pages_of(row, s)
+
+            def issue(i):
+                src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
+                dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+                pltpu.make_async_copy(
+                    k_hbm.at[layer, :, src], k_buf.at[slot, :, dst],
+                    sem_k.at[slot],
+                ).start()
+                pltpu.make_async_copy(
+                    v_hbm.at[layer, :, src], v_buf.at[slot, :, dst],
+                    sem_v.at[slot],
+                ).start()
+
+            def issue_group(gi, carry):
+                for j in range(gp):
+                    issue(gi * gp + j)
+                return carry
+
+            def issue_page(i, carry):
+                issue(i)
+                return carry
+
+            jax.lax.fori_loop(0, pages // gp, issue_group, 0)
+            jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
+
+        @pl.when(has & (s >= nh))
+        def _():
+            # A key block of the chunk: contiguous, one copy a pool.
+            src = pl.ds(pl.multiple_of((s - nh) * tq, tq), tq)
+            dst = pl.ds(0, tq)
+            pltpu.make_async_copy(
+                kc_hbm.at[:, row, src], k_buf.at[slot, :, dst],
+                sem_k.at[slot],
+            ).start()
+            pltpu.make_async_copy(
+                vc_hbm.at[:, row, src], v_buf.at[slot, :, dst],
+                sem_v.at[slot],
+            ).start()
+
+    def wait_tile(s, slot):
+        # By bytes, in power-of-two runs of pages (see the decode kernel):
+        # a chunk key block counts as its TQ / bs pages.
+        pages = jnp.where(s < n_hist, pages_of(b, s), tq // bs)
+        run = spp
+        while run:
+            @pl.when(pages & run != 0)
+            def _():
+                span = pl.ds(0, run * bs)
+                pltpu.make_async_copy(
+                    k_hbm.at[0, :, span], k_buf.at[slot, :, span],
+                    sem_k.at[slot],
+                ).wait()
+                pltpu.make_async_copy(
+                    v_hbm.at[0, :, span], v_buf.at[slot, :, span],
+                    sem_v.at[slot],
+                ).wait()
+            run //= 2
+
+    # A masked key's weight (0) must not meet a non-finite value (see the
+    # decode kernel): V is cleared once a call; what tiles leave behind is
+    # K/V, finite like the pool and the chunk.
+    @pl.when(is_first)
+    def _():
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+
+    # The program before this one issued this one's first tile from its
+    # last iteration, unless it had none (or there is none before).
+    prev_row = jnp.where(qb > 0, b, jnp.maximum(b - 1, 0))
+    prev_blk = jnp.where(qb > 0, qb - 1, nq - 1)
+
+    @pl.when((n_tiles > 0) & (is_first | (tiles_of(prev_row, prev_blk) == 0)))
+    def _():
+        start_tile(b, qb, 0, first)
+
+    wraps = qb + 1 == nq
+    next_row = jnp.minimum(jnp.where(wraps, b + 1, b), num_rows - 1)
+    next_blk = jnp.where(wraps, 0, qb + 1)
+    has_next = jnp.logical_not(wraps) | (b + 1 < num_rows)
+
+    def flash_block(keys_of, mask_of):
+        # One tile's keys against every head's query rows: the heads are a
+        # loop, their flash state in scratch. Whole heads and whole tiles
+        # at a time: what a block costs beside its scores is the state's
+        # round trip (m, l and the accumulator: 3 x M x 128 lanes of
+        # float32 read and written), so fewer, larger blocks win. On a v5e
+        # a KV head's score block in pieces of 64, 128 and 256 query rows
+        # took 2.4, 1.7 and 1.3 times as long, a history superpage in two
+        # blocks of 256 keys 1.8 times as long as in one of 512, and
+        # skipping the select where nothing is masked changed nothing
+        # (PERF.md §6, PR 35).
+        def head(hk, carry):
+            q = q_ref[0, hk, 0]                              # [M, Dh]
+            k, v = keys_of(hk)                               # [keys, Dh]
+            scores = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                # [M, keys]
+            scores = jnp.where(mask_of(), scores, _MASKED)
+            m_prev = m_ref[hk]                               # [M, 1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(scores, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(scores - m_new)
+            l_ref[hk] = alpha * l_ref[hk] + jnp.sum(
+                p, axis=-1, keepdims=True)
+            acc_ref[hk] = alpha * acc_ref[hk] + jax.lax.dot_general(
+                p.astype(v.dtype), v,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[hk] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, hkv, head, 0)
+
+    def tile(s, carry):
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
+        last = s + 1 == n_tiles
+
+        @pl.when(jnp.logical_not(last) | has_next)
+        def _():
+            start_tile(
+                jnp.where(last, next_row, b), jnp.where(last, next_blk, qb),
+                jnp.where(last, 0, s + 1), n + 1,
+            )
+
+        wait_tile(s, slot)
+
+        @pl.when(s < n_hist)
+        def _():
+            # History: every key below kv_len is before every query.
+            def mask():
+                pos = s * sup + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, sup), 1)
+                return pos < kv_len
+
+            flash_block(lambda hk: (k_buf[slot, hk], v_buf[slot, hk]), mask)
+
+        @pl.when(s >= n_hist)
+        def _():
+            # The chunk's key block c <= qb, masked as window_attention
+            # masks it: key position <= query position, key index <
+            # chunk_len (all true below the diagonal block).
+            c = s - n_hist
+
+            def mask():
+                pos_q = posq_ref[0, 0]                       # [TQ, 1]
+                pos_q = jnp.broadcast_to(
+                    pos_q[None], (g, tq, 1)).reshape(g * tq, 1)
+                idx = c * tq + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, tq), 1)
+                return (posk_ref[0, c] <= pos_q) & (idx < chunk_len)
+
+            flash_block(
+                lambda hk: (k_buf[slot, hk, pl.ds(0, tq), :],
+                            v_buf[slot, hk, pl.ds(0, tq), :]),
+                mask)
+
+        return carry
+
+    @pl.when(n_tiles > 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
+
+        def write(hk, carry):
+            out = acc_ref[hk] / jnp.maximum(l_ref[hk], 1e-30)
+            o_ref[0, hk, 0] = out.astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, hkv, write, 0)
+
+    @pl.when(n_tiles == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_size", "scale", "interpret")
+)
+def paged_flash_prefill(
+    q: jax.Array,             # [B, T, H, Dh] chunk queries (post-rope)
+    k: jax.Array,             # [B, T, Hkv, Dh] chunk keys (post-rope)
+    v: jax.Array,             # [B, T, Hkv, Dh]
+    positions: jax.Array,     # [B, T] int32 absolute position per token
+    chunk_lens: jax.Array,    # [B] int32 valid tokens per row
+    k_pool: jax.Array,        # [L, Hkv, num_slots, Dh]
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # [B, Mb] int32
+    kv_lens: jax.Array,       # [B] int32: the row's tokens in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention of a prefill chunk over its rows' history in the
+    paged pool (slots below ``kv_lens``, read in place) and over itself:
+    [B, T, H, Dh] in q.dtype, equal to ``window_attention`` over the
+    gathered history. Positions must not decrease along a row and must
+    increase over its valid tokens (key blocks above the diagonal are
+    skipped by index). Rows of a padded query block are zeros; block-table
+    entries past a row's live blocks, and what they point at, are never
+    read. See the section comment for the design and
+    ``supports_pallas_prefill`` for the shapes."""
+    b, t, h, dh = q.shape
+    l_, hkv, num_slots, _ = k_pool.shape
+    g = h // hkv
+    if scale is None:
+        scale = dh ** -0.5
+    sup, tq = prefill_tiles(t, h, hkv, dh, k_pool.dtype.itemsize,
+                            block_size)
+    nq, m = t // tq, g * tq
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    # [B, T, H, Dh] -> [B, Hkv, NQ, G*TQ, Dh]: a KV head's query rows of
+    # one block are one matmul operand; scaled as window_attention scales.
+    qf = (q.astype(jnp.float32) * scale).astype(k_pool.dtype)
+    qf = qf.reshape(b, nq, tq, hkv, g, dh).transpose(0, 3, 1, 4, 2, 5)
+    qf = qf.reshape(b, hkv, nq, m, dh)
+    kc = k.transpose(2, 0, 1, 3).astype(k_pool.dtype)     # [Hkv, B, T, Dh]
+    vc = v.transpose(2, 0, 1, 3).astype(v_pool.dtype)
+    positions = positions.astype(jnp.int32)
+
+    kernel = functools.partial(
+        _prefill_kernel, block_size=block_size, super_tokens=sup, tq=tq,
+        q_per_kv=g,
+    )
+    q_block = pl.BlockSpec((1, hkv, 1, m, dh),
+                           lambda i, j, *_: (i, 0, j, 0, 0),
+                           memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, nq, m, dh), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, nq),
+            in_specs=[
+                q_block,
+                pl.BlockSpec((1, 1, tq, 1), lambda i, j, *_: (i, j, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, nq, 1, tq), lambda i, j, *_: (i, 0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),   # the chunk's K/V and
+                pl.BlockSpec(memory_space=pl.ANY),   # the pools stay in
+                pl.BlockSpec(memory_space=pl.ANY),   # HBM: the kernel
+                pl.BlockSpec(memory_space=pl.ANY),   # copies tiles itself
+            ],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((NUM_BUFS, hkv, sup, dh), k_pool.dtype),
+                pltpu.VMEM((NUM_BUFS, hkv, sup, dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hkv, m, 1), jnp.float32),
+                pltpu.VMEM((hkv, m, 1), jnp.float32),
+                pltpu.VMEM((hkv, m, dh), jnp.float32),
+            ],
+        ),
+        # Programs run in order: each hands its buffers to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=PREFILL_VMEM_BYTES,
+        ),
+        name="paged_flash_prefill",
+        interpret=interpret,
+    )(
+        layer, block_tables, kv_lens.astype(jnp.int32),
+        chunk_lens.astype(jnp.int32),
+        qf, positions.reshape(b, nq, tq, 1), positions.reshape(b, nq, 1, tq),
+        kc, vc, k_pool, v_pool,
+    )
+    out = out.reshape(b, hkv, nq, g, tq, dh).transpose(0, 2, 4, 1, 3, 5)
+    return out.reshape(b, t, h, dh)

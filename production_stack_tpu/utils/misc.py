@@ -59,8 +59,11 @@ def round_up(x: int, multiple: int) -> int:
 
 def window_mb_bucket(live_blocks: int, max_blocks: int) -> int:
     """Block-table bucket for dispatches whose COST scales with mb (the
-    gathered-window paths): the power-of-two bucket of the live block count,
-    floored at 1/4 of the max bucket.
+    gathered-window paths: window decode, and a prefill chunk wherever its
+    history is still gathered — runner.prefill_reads_pool False; a chunk
+    that reads the pool in place pins mb at the full bucket, as paged
+    decode does): the power-of-two bucket of the live block count, floored
+    at 1/4 of the max bucket.
 
     The floor bounds the reachable family count at three (full/4, full/2,
     full) so runner.warmup() can AOT-compile every windowed family a

@@ -346,6 +346,23 @@ def _described_runner(v5e, model_dir: str, **engine):
     return r
 
 
+CONFIGS_DIR = os.path.dirname(HYBRID_DIR)
+
+
+def _deployment_runner(v5e, name):
+    import json
+
+    with open(os.path.join(CONFIGS_DIR, name, "deployment.json")) as f:
+        flags = {x["flag"]: x["value"]
+                 for x in json.load(f)["engine_flags"]}
+    return _described_runner(
+        v5e, os.path.join(CONFIGS_DIR, name),
+        max_model_len=int(flags["--max-model-len"]),
+        max_num_seqs=int(flags["--max-num-seqs"]),
+        max_num_batched_tokens=int(flags["--max-num-batched-tokens"]),
+        num_kv_blocks=int(flags["--num-kv-blocks"]))
+
+
 @pytest.mark.parametrize("program", ["decode-32x32", "prefill-1x2048"])
 def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     """The decode and prefill programs of olmo-hybrid-7b-d16's envelope
@@ -378,9 +395,9 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     carry = jax.ShapeDtypeStruct((32, 12, 15, 96, 384), jnp.float32)
     assert pool_copies(text, [r.kv_k, *r.state_pools, carry]) == []
     # The Mosaic kernels: the full layers' paged decode and the linear
-    # layers' step, and nothing of prefill.
+    # layers' step; of prefill, the full layers' flash kernel over the pool.
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        (2 if decode else 0)
+        (2 if decode else 1)
     assert step_path(text) == ("pallas" if decode else None)
     mem = compiled.memory_analysis()
     # The rows' state is ONE loop carry (0.85 GB at 32 rows), not one a
@@ -458,19 +475,10 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     experts' stacks, and hold the Mosaic kernels: the latent decode kernel
     (the dense layer's call and the sparse scan's) and the two grouped
     matmuls of the scan."""
-    import json
-
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops.kv_write import pool_copies
 
-    with open(os.path.join(LATENT_DIR, "deployment.json")) as f:
-        flags = {x["flag"]: x["value"]
-                 for x in json.load(f)["engine_flags"]}
-    r = _described_runner(
-        v5e, LATENT_DIR, max_model_len=int(flags["--max-model-len"]),
-        max_num_seqs=int(flags["--max-num-seqs"]),
-        max_num_batched_tokens=int(flags["--max-num-batched-tokens"]),
-        num_kv_blocks=int(flags["--num-kv-blocks"]))
+    r = _deployment_runner(v5e, "kanana-2-30b-a3b-d8")
     assert r.kv_k.shape == (8, 1, 16384 * 16, 640)
     assert r.kv_v.shape == (8, 1, 16384 * 16, 0)     # no second pool
     assert r.state_pools == ()
@@ -500,3 +508,141 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     assert 12.8e9 < mem.argument_size_in_bytes < 12.9e9
     assert mem.temp_size_in_bytes < (0.4e9 if decode else 1.0e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# ---- prefill attention: the flash kernel over the paged pool (PR 35)
+@pytest.mark.parametrize("rows,t,heads,kv_heads", [
+    (8, 256, 16, 2), (1, 512, 32, 8), (1, 2048, 30, 30), (1, 128, 16, 2)],
+    ids=["qwen-8x256", "mistral-1x512", "olmo-1x2048", "qwen-1x128"])
+def test_paged_prefill_kernel_compiles_for_v5e(v5e, rows, t, heads, kv_heads):
+    """The prefill flash kernel alone, at the benchmark's head layouts and
+    chunk widths: Mosaic takes it (VMEM, tiling, the page copies), and its
+    device operation does not carry the decode kernels' name (the
+    benchmark counts decode steps by the prefix ``paged_flash_decode``)."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((LAYERS, kv_heads, NUM_SLOTS, 128), jnp.bfloat16)
+    chunk = sds((rows, t, kv_heads, 128), jnp.bfloat16)
+    compiled = paged_flash_prefill.lower(
+        sds((rows, t, heads, 128), jnp.bfloat16), chunk, chunk,
+        sds((rows, t), jnp.int32), sds((rows,), jnp.int32), pool, pool,
+        sds((rows, MAX_BLOCKS), jnp.int32), sds((rows,), jnp.int32),
+        sds((1,), jnp.int32), block_size=BLOCK_SIZE).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_flash_prefill" in text
+    assert "%paged_flash_decode" not in text      # an operation's name
+    assert compiled.out_info.shape == (rows, t, heads, 128)
+
+
+# (deployment, rows, t, the parent's and this tree's temp_size_in_bytes of
+# that program: PR 33's tree gathered a window of every row at the widest
+# step of its ladder and held the float32 scores; measured at PR 35.)
+PREFILL_PROGRAMS = {
+    "qwen2.5-3b-8x256": ("qwen2.5-3b", 8, 256, 1_444_768_256, 160_311_808),
+    "mistral-7b-d16-1x512":
+        ("mistral-7b-d16", 1, 512, 844_797_440, 1_257_984),
+    "olmo-hybrid-7b-d16-1x2048":
+        ("olmo-hybrid-7b-d16", 1, 2048, 913_192_448, 661_928_960),
+}
+
+
+@pytest.mark.parametrize("program", list(PREFILL_PROGRAMS))
+def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
+    """A prefill program of the three K/V deployments, lowered for a v5e
+    as the engine lowers it: its chunk attends through the flash kernel
+    over the pool (``prefill_attn`` "pallas"), the pools are written in
+    place, nothing of a window's shape is gathered, no float32 tensor of
+    the scores' shape exists, and its temporaries are below the parent's.
+    There is ONE such program a (rows, t): a window is no property of it."""
+    import re
+
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    name, rows, t, parent_temp, temp = PREFILL_PROGRAMS[program]
+    r = _deployment_runner(v5e, name)
+    assert r.prefill_reads_pool
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    fams = [f for f in r.reachable_prefill_families()
+            if f[:2] == (rows, t)]
+    assert fams == [(rows, t, full_mb, False)]
+    compiled = r._lower_prefill(r._abstract_params(), *fams[0]).compile()
+    text = compiled.as_text()
+    assert prefill_attn_path(text) == "pallas"
+    assert "%paged_flash_prefill" in text
+    assert "%paged_flash_decode" not in text      # an operation's name
+    assert pool_copies(text, [r.kv_k, *r.state_pools]) == []
+    nl, hkv, dh = r.kv_spec
+    heads = r.model_config.num_heads
+    # A gathered window [.., Hkv, rows, keys, Dh] at any step of the
+    # parent's ladder, and a float32 tensor with the scores' leading shape
+    # [Hkv, rows, G x queries, ..] (the scores and the value product of
+    # window_attention): neither is there.
+    shapes = {tuple(int(x) for x in dims.split(","))
+              for dims in re.findall(r"[a-z]\w*\[([\d,]+)\]", text)}
+    ladder = {full_mb * 16 // d for d in (1, 2, 4)}
+    for shape in shapes:
+        assert not (len(shape) >= 4 and shape[-4:-2] == (hkv, rows)
+                    and shape[-2] in ladder and shape[-1] == dh
+                    and shape != tuple(r.kv_k.shape)), shape
+    score_rows = heads // hkv * min(t, 256)
+    lead = tuple(x for x in (hkv, rows, score_rows) if x != 1)
+    for dims in re.findall(r"f32\[([\d,]+)\]", text):
+        shape = tuple(int(x) for x in dims.split(",") if x != "1")
+        assert not (shape[:-1] == lead and shape[-1] in ladder | {t}), shape
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < parent_temp / 1.3
+    assert mem.temp_size_in_bytes <= temp * 1.05
+
+
+@pytest.mark.parametrize("name,families,in_place", [
+    ("qwen2.5-3b", 9, True), ("mistral-7b-d16", 9, True),
+    ("olmo-hybrid-7b-d16", 9, True), ("kanana-2-30b-a3b-d8", 14, False)])
+def test_prefill_family_counts_of_the_deployments(v5e, name, families,
+                                                  in_place):
+    """One prefill family a (rows, t) where the history is read in place
+    (36 -> 9 at qwen2.5-3b's envelope, 32 -> 9, 18 -> 9); latent rows keep
+    the family without a window and the pinned one (14)."""
+    r = _deployment_runner(v5e, name)
+    assert r.prefill_reads_pool is in_place
+    fams = r.reachable_prefill_families()
+    assert len(fams) == families
+    assert {f[3] for f in fams} == ({False} if in_place else {False, True})
+    assert r.prefill_window_blocks == (
+        1 << 30 if in_place else r.num_kv_blocks)
+
+
+def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
+    """kanana-2-30b-a3b-d8's widest windowed prefill program does not
+    change with the kernel: its compiled text, without source locations
+    (metadata, the location tables, the Mosaic kernels' serialized bodies,
+    which carry line numbers), hashes as PR 33's tree's does. A PR that
+    changes this program on purpose writes the new hash here."""
+    import hashlib
+    import re
+
+    from production_stack_tpu.engine.runner import _bucket
+
+    r = _deployment_runner(v5e, "kanana-2-30b-a3b-d8")
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    text = r._lower_prefill(
+        r._abstract_params(), 8, 512, full_mb, True).compile().as_text()
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>", text)
+    body = "\n".join(
+        ln for ln in text.splitlines() if not re.match(
+            r'^(\d+ ["{]|FileNames|FunctionNames|FileLocations|StackFrames)',
+            ln))
+    assert hashlib.sha1(body.encode()).hexdigest() == \
+        "4553a5ba963eafb05f2e5fbd1db379ad9eb48d70"

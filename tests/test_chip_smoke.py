@@ -86,7 +86,12 @@ BAD_RUNS = {
     "warmup-stage-failed": _with((1, "boot", "warmup_failures"), 1),
     "nothing-warmed": _with((1, "boot", "warmup_families"), 0),
     "depth-cut": _with((1, "boot", "num_layers"), 2),
-    "cache-never-found-again": _with((2, "boot", "cache_hit"), 0),
+    # The second boot of ONE path (the four-chip run's replicas after its
+    # reference) finds nothing cached.
+    "cache-never-found-again": _good_lines() + [
+        {"phase": "again[paged]", "ok": True,
+         "boot": _boot(attn_impl="paged", cache_hit=0, cache_miss=30),
+         "pool_programs": _programs()}],
     "phase-failed": _with((1, "ok"), False),
     "wrong-device-count": _with((2, "boot", "device"), {**TPU, "count": 4}),
     "nothing-served": _good_lines()[:1],
@@ -97,6 +102,12 @@ BAD_RUNS = {
     # of the step on a TPU (ops/gated_delta.py:gdn_step_at).
     "recurrence-step-not-the-kernel": _with((2, "pool_programs"),
                                             _programs(gdn_step="xla")),
+    # An engine on a TPU whose prefill views hold the pool (its own
+    # predicate, runner.prefill_reads_pool) but whose prefill program
+    # still holds window_attention.
+    "prefill-attention-not-the-kernel": _with(
+        (2, "pool_programs"),
+        _programs(prefill_attn="xla", prefill_reads_pool=True)),
 }
 
 
@@ -104,6 +115,28 @@ def test_verdict_passes_a_run_that_is_right_in_every_respect():
     final = chip_smoke.verdict(_good_lines(), 1, chip_smoke.FULL_DEPTH)
     assert final == {"ok": True, "device": {
         "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+
+
+def test_verdict_passes_two_cold_boots_of_different_paths():
+    """A window engine and a paged engine share no program (the paged
+    prefill holds the flash kernel): on a cold cache both miss everything
+    and that is no fault; a repeated path with no hit is
+    (``cache-never-found-again``)."""
+    lines = _good_lines()
+    lines[2]["boot"].update(cache_hit=0, cache_miss=15)
+    assert chip_smoke.verdict(lines, 1, chip_smoke.FULL_DEPTH)["ok"]
+
+
+@pytest.mark.parametrize("programs", [
+    _programs(prefill_attn="pallas", prefill_reads_pool=True),
+    # tp=4, an int8 pool, latent rows: a paged engine that gathers a
+    # window by design says so, and "xla" is then no fault.
+    _programs(prefill_attn="xla", prefill_reads_pool=False),
+], ids=["reads-the-pool-through-the-kernel", "gathers-a-window-by-design"])
+def test_verdict_judges_prefill_attention_by_the_engines_predicate(programs):
+    lines = _good_lines()
+    lines[2]["pool_programs"] = programs
+    assert chip_smoke.verdict(lines, 1, chip_smoke.FULL_DEPTH)["ok"]
 
 
 @pytest.mark.parametrize("what", sorted(BAD_RUNS))
@@ -117,6 +150,15 @@ def test_verdict_fails_when_not_on_the_chip(what, capsys):
 
 def test_verdict_passes_the_recurrences_kernel():
     lines = _with((2, "pool_programs"), _programs(gdn_step="pallas"))
+    assert chip_smoke.verdict(lines, 1, chip_smoke.FULL_DEPTH)["ok"] is True
+
+
+def test_verdict_passes_the_prefill_kernel_and_a_window_engines_xla():
+    """``prefill_attn`` "pallas" passes; "xla" fails a paged engine only:
+    the window path's prefill is window_attention by design."""
+    lines = _with((2, "pool_programs"), _programs(prefill_attn="pallas"))
+    lines[1]["pool_programs"] = _programs(prefill_attn="xla")
+    assert lines[1]["boot"]["attn_impl"] == "window"
     assert chip_smoke.verdict(lines, 1, chip_smoke.FULL_DEPTH)["ok"] is True
 
 
@@ -266,5 +308,26 @@ def test_gdn_phase_rehearses_on_the_cpu():
     assert line["phase"] == "gdn" and line["ok"]
     assert [t["op"] for t in line["timing"]] == \
         ["gdn_step", "gdn_step", "gdn_chunk"]
+    assert all(t["bytes"] > 0 and t["flops"] > 0
+               and t["us_per_call"] is None for t in line["timing"])
+
+
+def test_prefill_phase_rehearses_on_the_cpu():
+    """``--prefill --rehearse``: the prefill kernel alone at a toy size on
+    the CPU (interpret mode), checked against ``window_attention``; the
+    FLOPs and bytes it would be held to, and no time."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--prefill",
+         "--rehearse"], capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    line = json.loads([ln for ln in proc.stdout.splitlines()
+                       if ln.startswith("{")][-1])
+    assert line["phase"] == "prefill" and line["ok"] and line["interpret"]
+    assert [t["shape"] for t in line["timing"]] == \
+        ["chat-saturated", "agent-prefix", "hybrid-long-row",
+         "hybrid-rectangle"]
+    assert all(c["finite"] and c["max_abs_err"] <= c["bound"]
+               for c in line["checks"])
     assert all(t["bytes"] > 0 and t["flops"] > 0
                and t["us_per_call"] is None for t in line["timing"])

@@ -1,0 +1,420 @@
+"""The Pallas flash PREFILL kernel over the paged pool
+(ops/pallas/paged_attention.py:paged_flash_prefill), in interpret mode on
+the CPU, against ``window_attention`` over gathered history (the statement
+of the computation) and ``paged_attention_xla`` (the pool-only reference),
+and ``attend``'s choice between the kernel and the window path.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from production_stack_tpu.ops.attention import (
+    KVView,
+    attend,
+    gather_kv_pages,
+    paged_attention_xla,
+    prefill_kernel_covers,
+    window_attention,
+)
+from production_stack_tpu.ops.pallas.paged_attention import (
+    paged_flash_prefill,
+    prefill_tiles,
+    supports_pallas_prefill,
+)
+
+BS, DH, LAYER = 16, 128, 1
+ATOL = 2e-5          # what tests/test_paged_decode.py holds the decode kernel to
+
+
+def _case(t, h, hkv, hists, clens, *, extra_blocks=3, max_pos=None, seed=0,
+          dtype=jnp.float32):
+    """One dispatch: rows of history ``hists`` and chunk lengths ``clens``
+    at chunk width ``t``. Live blocks are scattered over the pool; table
+    entries past a row's live blocks point at a block of NaN, and so does
+    every entry of a row that holds nothing."""
+    rng = np.random.default_rng(seed)
+    b = len(hists)
+    live = [-(-(hi + cl) // BS) if cl else 0 for hi, cl in zip(hists, clens)]
+    mb = max(live) + extra_blocks
+    nblocks = 2 + sum(live)                 # block 0 null, block 1 poison
+    ids = list(rng.permutation(np.arange(2, nblocks)))
+    bt = np.ones((b, mb), np.int32)
+    for i in range(b):
+        bt[i, :live[i]] = [ids.pop() for _ in range(live[i])]
+    shape = (2, hkv, nblocks * BS, DH)
+    kp = rng.normal(size=shape).astype(np.float32)
+    vp = rng.normal(size=shape).astype(np.float32)
+    kp[:, :, BS:2 * BS] = np.nan
+    vp[:, :, BS:2 * BS] = np.nan
+    q = rng.normal(size=(b, t, h, DH)).astype(np.float32)
+    k = rng.normal(size=(b, t, hkv, DH)).astype(np.float32)
+    v = rng.normal(size=(b, t, hkv, DH)).astype(np.float32)
+    pos = np.asarray(hists)[:, None] + np.arange(t)[None, :]
+    if max_pos is not None:
+        pos = np.minimum(pos, max_pos - 1)
+    arr = lambda x: jnp.asarray(x, dtype)   # noqa: E731
+    return dict(
+        q=arr(q), k=arr(k), v=arr(v), positions=jnp.asarray(pos, jnp.int32),
+        chunk_lens=jnp.asarray(clens, jnp.int32), kp=arr(kp), vp=arr(vp),
+        bt=jnp.asarray(bt), kv_lens=jnp.asarray(hists, jnp.int32))
+
+
+def _kernel(c, **kw):
+    return paged_flash_prefill(
+        c["q"], c["k"], c["v"], c["positions"], c["chunk_lens"], c["kp"],
+        c["vp"], c["bt"], c["kv_lens"], jnp.int32(LAYER), block_size=BS,
+        interpret=True, **kw)
+
+
+def _window_reference(c):
+    """``window_attention`` over this layer's gathered pages, junk made
+    finite first (the oracle multiplies masked weights into values)."""
+    kp = jnp.nan_to_num(c["kp"][LAYER].astype(jnp.float32))
+    vp = jnp.nan_to_num(c["vp"][LAYER].astype(jnp.float32))
+    win_k = gather_kv_pages(kp, c["bt"], BS)
+    win_v = gather_kv_pages(vp, c["bt"], BS)
+    f32 = lambda x: x.astype(jnp.float32)   # noqa: E731
+    return window_attention(
+        f32(c["q"]), f32(c["k"]), f32(c["v"]), c["positions"],
+        c["chunk_lens"], win_k, win_v, c["kv_lens"])
+
+
+def _check(c, atol=ATOL):
+    out = np.asarray(_kernel(c).astype(jnp.float32))
+    ref = np.asarray(_window_reference(c))
+    t = c["q"].shape[1]
+    h, hkv = c["q"].shape[2], c["k"].shape[2]
+    _, tq = prefill_tiles(t, h, hkv, DH, c["kp"].dtype.itemsize, BS)
+    clens = np.asarray(c["chunk_lens"])
+    assert np.all(np.isfinite(out))
+    for i, cl in enumerate(clens):
+        # Valid queries agree with the oracle; so do a live block's padded
+        # ones (they see the row's valid keys, as in the window path).
+        live_to = -(-cl // tq) * tq
+        np.testing.assert_allclose(out[i, :live_to], ref[i, :live_to],
+                                   atol=atol, rtol=0)
+        # A query block that is all padding is zeros.
+        assert not out[i, live_to:].any()
+    return out
+
+
+# ---- rows of unequal history and chunk length, one dispatch; head layouts
+@pytest.mark.parametrize("h,hkv", [(16, 2), (32, 8), (30, 30)],
+                         ids=["gqa8-16x2", "gqa4-32x8", "mha-30x30"])
+def test_rows_of_unequal_history_and_chunk_match_window(h, hkv):
+    assert supports_pallas_prefill(128, h, hkv, DH, 4, BS)
+    _check(_case(128, h, hkv, hists=[0, 37, 300, 64], clens=[100, 128, 60, 7]))
+
+
+# ---- where the history ends
+@pytest.mark.parametrize("hist", [0, 5, 16, 250, 256, 511, 512, 513, 1030],
+                         ids=lambda x: f"hist{x}")
+def test_history_edges_match_window(hist):
+    """History 0 (no tile fetched), inside a block, at a block edge, at a
+    compute-block edge, at and across a superpage edge."""
+    _check(_case(128, 4, 2, hists=[hist, 3], clens=[128, 90]))
+
+
+# ---- chunk widths: one query block, and more than one
+@pytest.mark.parametrize("t,rows", [(128, 3), (256, 3), (512, 2), (2048, 1)],
+                         ids=lambda x: str(x))
+def test_chunk_widths_match_window(t, rows):
+    hists = [40, 0, 600][:rows]
+    clens = [t, t - 29, t // 2 + 3][:rows]
+    _check(_case(t, 4, 2, hists=hists, clens=clens))
+
+
+def test_matches_paged_attention_xla_with_the_chunk_written():
+    """The pool-only reference: the chunk's K/V written at its positions,
+    ``paged_attention_xla`` over history + chunk."""
+    c = _case(128, 4, 2, hists=[0, 37, 300], clens=[100, 128, 60])
+    out = np.asarray(_kernel(c))
+    kp = np.nan_to_num(np.asarray(c["kp"][LAYER]))
+    vp = np.nan_to_num(np.asarray(c["vp"][LAYER]))
+    bt = np.asarray(c["bt"])
+    for i in range(3):
+        hist, cl = int(c["kv_lens"][i]), int(c["chunk_lens"][i])
+        for j in range(cl):
+            slot = bt[i, (hist + j) // BS] * BS + (hist + j) % BS
+            kp[:, slot] = np.asarray(c["k"][i, j])
+            vp[:, slot] = np.asarray(c["v"][i, j])
+    ref = np.asarray(paged_attention_xla(
+        c["q"], jnp.asarray(kp), jnp.asarray(vp), c["bt"],
+        c["kv_lens"] + c["chunk_lens"], c["positions"], block_size=BS))
+    for i in range(3):
+        cl = int(c["chunk_lens"][i])
+        np.testing.assert_allclose(out[i, :cl], ref[i, :cl], atol=ATOL,
+                                   rtol=0)
+
+
+def test_padded_row_is_zeros_and_fetches_nothing():
+    """A row with ``chunk_len`` 0 — its table and its length pointing at
+    NaN — returns zeros, and leaves no NaN behind in the buffers the next
+    row's masked tail would meet: it issued no fetch."""
+    c = _case(128, 4, 2, hists=[64, 64, 5], clens=[0, 0, 20])
+    out = _check(c)
+    assert not out[0].any() and not out[1].any()
+
+
+def test_padded_rows_everywhere_are_zeros():
+    c = _case(128, 4, 2, hists=[0, 0], clens=[0, 0])
+    assert not np.asarray(_kernel(c)).any()
+
+
+def test_positions_clamped_at_max_model_len():
+    """The runner clamps positions at ``max_model_len - 1``: a live block's
+    padded queries then share the last position, and the valid ones are
+    untouched."""
+    c = _case(256, 4, 2, hists=[900, 1000], clens=[124, 24], max_pos=1024)
+    assert int(c["positions"].max()) == 1023
+    _check(c)
+
+
+def test_bfloat16_pool_matches_window_in_bfloat16():
+    """bf16 operands, float32 scores and accumulation: the precision of
+    ``window_attention`` on the same operands."""
+    c = _case(256, 8, 2, hists=[70, 0, 520], clens=[256, 31, 200],
+              dtype=jnp.bfloat16)
+    out = np.asarray(_kernel(c).astype(jnp.float32))
+    kp = jnp.nan_to_num(c["kp"][LAYER])
+    vp = jnp.nan_to_num(c["vp"][LAYER])
+    ref = np.asarray(window_attention(
+        c["q"], c["k"], c["v"], c["positions"], c["chunk_lens"],
+        gather_kv_pages(kp, c["bt"], BS), gather_kv_pages(vp, c["bt"], BS),
+        c["kv_lens"]).astype(jnp.float32))
+    for i, cl in enumerate(np.asarray(c["chunk_lens"])):
+        np.testing.assert_allclose(out[i, :cl], ref[i, :cl], atol=2e-2,
+                                   rtol=0)
+
+
+# ---- attend: which execution a view gets
+def _view(c, **kw):
+    return KVView(pool_k=c["kp"], pool_v=c["vp"], block_tables=c["bt"],
+                  kv_lens=c["kv_lens"], block_size=BS, **kw)
+
+
+def _attend_jaxpr(c, view, **kw):
+    def f(q, k, v):
+        return attend(q, k, v, c["positions"], c["chunk_lens"], view,
+                      jnp.int32(LAYER), **kw)
+    return str(jax.make_jaxpr(f)(c["q"], c["k"], c["v"]))
+
+
+def test_attend_takes_the_kernel_over_a_pool_view():
+    c = _case(128, 4, 2, hists=[0, 37], clens=[100, 128])
+    view = _view(c, interpret=True)
+    assert "paged_flash_prefill" in _attend_jaxpr(c, view)
+    out = attend(c["q"], c["k"], c["v"], c["positions"], c["chunk_lens"],
+                 view, jnp.int32(LAYER))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_kernel(c)),
+                               atol=0, rtol=0)
+
+
+def test_attend_on_a_cpu_program_gathers_and_is_window_attention():
+    """Without ``interpret`` the execution follows the platform the program
+    is lowered for: on the CPU, the layer's pages gathered and
+    ``window_attention``, equal to the kernel."""
+    c = _case(128, 4, 2, hists=[0, 37, 300], clens=[100, 128, 60])
+    c["kp"], c["vp"] = jnp.nan_to_num(c["kp"]), jnp.nan_to_num(c["vp"])
+    fn = jax.jit(lambda q, k, v: attend(
+        q, k, v, c["positions"], c["chunk_lens"], _view(c),
+        jnp.int32(LAYER)))
+    assert "paged_flash_prefill" not in fn.lower(
+        c["q"], c["k"], c["v"]).compile().as_text()
+    out = np.asarray(fn(c["q"], c["k"], c["v"]))
+    ref = np.asarray(_kernel(c))
+    for i, cl in enumerate(np.asarray(c["chunk_lens"])):
+        np.testing.assert_allclose(out[i, :cl], ref[i, :cl], atol=ATOL,
+                                   rtol=0)
+
+
+def _uncovered_pool_views():
+    c = _case(16, 4, 2, hists=[0, 37], clens=[16, 9])
+    c["kp"], c["vp"] = jnp.nan_to_num(c["kp"]), jnp.nan_to_num(c["vp"])
+    bias = jnp.zeros((16, 16), jnp.float32)
+    ring = jnp.zeros((2, 2, 4, DH), jnp.float32)
+    int8 = dict(pool_k=c["kp"].astype(jnp.int8),
+                pool_v=c["vp"].astype(jnp.int8),
+                k_scale=jnp.ones(c["kp"].shape[:3], jnp.bfloat16),
+                v_scale=jnp.ones(c["kp"].shape[:3], jnp.bfloat16))
+    half = dict(pool_k=c["kp"].astype(jnp.bfloat16),
+                pool_v=c["vp"].astype(jnp.bfloat16))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("tp",))
+    return c, {
+        "tree-chunk-bias": _view(c, chunk_bias=bias),
+        "ring": _view(c, ring_k=ring, ring_v=ring,
+                      ring_pos=jnp.zeros((2, 4), jnp.int32)),
+        "int8-pool": _view(c)._replace(**int8),
+        "kv-head-sharded-pool": _view(c, tp_mesh=mesh),
+        "pool-of-another-dtype": _view(c)._replace(**half),
+        "chunk-of-half-a-block": _view(c)._replace(block_size=32),
+    }
+
+
+@pytest.mark.parametrize("case", [
+    "tree-chunk-bias", "ring", "int8-pool", "kv-head-sharded-pool",
+    "pool-of-another-dtype", "chunk-of-half-a-block"])
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["lowered-for-the-backend", "interpret"])
+def test_attend_refuses_a_pool_view_the_kernel_does_not_cover(case,
+                                                              interpret):
+    """There is no third execution: a chunk over a pool view that the
+    kernel does not cover raises while the program is traced (whoever
+    builds views asks ``prefill_kernel_covers`` first and gathers a
+    window), so no program for a TPU gathers a window a layer unseen."""
+    c, views = _uncovered_pool_views()
+    with pytest.raises(ValueError, match="prefill_kernel_covers"):
+        _attend_jaxpr(c, views[case]._replace(interpret=interpret))
+
+
+@pytest.mark.parametrize("case", ["tree-chunk-bias", "ring", "plain"])
+def test_attend_keeps_the_window_path(case):
+    """What the kernel does not cover is handed a gathered window and
+    stays on ``window_attention``, even where the view says
+    ``interpret``."""
+    c = _case(16, 4, 2, hists=[0, 37], clens=[16, 9])
+    kp, vp = jnp.nan_to_num(c["kp"]), jnp.nan_to_num(c["vp"])
+    ring = jnp.zeros((2, 2, 4, DH), jnp.float32)
+    parts = {
+        "tree-chunk-bias": dict(
+            chunk_bias=jnp.zeros((16, 16), jnp.float32)),
+        "ring": dict(ring_k=ring, ring_v=ring,
+                     ring_pos=jnp.zeros((2, 4), jnp.int32)),
+        "plain": {},
+    }[case]
+    view = KVView(win_k=gather_kv_pages(kp[LAYER], c["bt"], BS),
+                  win_v=gather_kv_pages(vp[LAYER], c["bt"], BS),
+                  win_len=c["kv_lens"], interpret=True, **parts)
+    jaxpr = _attend_jaxpr(c, view)
+    assert "pallas_call" not in jaxpr and "paged_flash_prefill" not in jaxpr
+
+
+@pytest.mark.parametrize("block_size,budget,kv_quantized,devices,reads", [
+    (16, 2048, False, 1, True),      # the benchmark's dense envelopes
+    (16, 16, False, 1, True),        # one bucket: 16 tokens, whole blocks
+    (32, 16, False, 1, False),       # its only bucket is half a block
+    (32, 2048, False, 1, True),      # every bucket from 128 is whole blocks
+    (16, 2048, True, 1, False),      # an int8 pool
+    (16, 2048, False, 4, False),     # a sharded pool or chunk
+])
+def test_the_runner_asks_attends_predicate_of_every_chunk_bucket(
+        block_size, budget, kv_quantized, devices, reads):
+    """``runner.prefill_reads_pool`` is ``prefill_kernel_covers`` over
+    every chunk-length bucket the config can dispatch: one bucket the
+    kernel does not tile and every prefill view holds a window."""
+    from types import SimpleNamespace
+
+    from production_stack_tpu.engine.runner import ModelRunner
+
+    r = SimpleNamespace(
+        config=SimpleNamespace(max_num_batched_tokens=budget,
+                               block_size=block_size),
+        model_config=SimpleNamespace(num_heads=16), attn_impl="paged",
+        kv_pools=2, kv_quantized=kv_quantized, dtype=jnp.bfloat16,
+        mesh=SimpleNamespace(size=devices),
+        kv_spec=SimpleNamespace(kv_heads=2, head_dim=DH))
+    r._prefill_t_buckets = lambda: ModelRunner._prefill_t_buckets(r)
+    assert ModelRunner.prefill_reads_pool.func(r) is reads
+    assert all(prefill_kernel_covers(t, 16, 2, DH, DH, block_size,
+                                     (jnp.bfloat16,))
+               for t in r._prefill_t_buckets()) is (
+        reads or kv_quantized or devices > 1)
+
+
+def test_attend_keeps_head_dim_64_and_odd_chunks_on_the_window_path():
+    assert not supports_pallas_prefill(128, 8, 2, 64, 2, BS)
+    assert not supports_pallas_prefill(384, 8, 2, 128, 2, BS)   # 384 % 256
+    assert supports_pallas_prefill(2048, 30, 30, 128, 2, BS)
+    assert prefill_tiles(2048, 30, 30, 128, 2, BS) == (256, 256)
+    assert prefill_tiles(256, 16, 2, 128, 2, BS) == (512, 256)
+    assert prefill_tiles(512, 32, 8, 128, 2, BS) == (512, 256)
+
+
+def test_attend_latent_rows_take_the_window_path():
+    """Latent rows (``v`` None) never reach the K/V kernel."""
+    rng = np.random.default_rng(3)
+    b, t, h, w, dv = 2, 16, 4, 256, 128
+    q = jnp.asarray(rng.normal(size=(b, t, h, w)), jnp.float32)
+    rows = jnp.asarray(rng.normal(size=(b, t, 1, w)), jnp.float32)
+    win = jnp.asarray(rng.normal(size=(1, b, 32, w)), jnp.float32)
+    pos = jnp.asarray(np.arange(t)[None] + np.array([[0], [20]]), jnp.int32)
+    view = KVView(win_k=win, win_v=win, win_len=jnp.asarray([0, 20]),
+                  interpret=True)
+    jaxpr = str(jax.make_jaxpr(lambda q, r: attend(
+        q, r, None, pos, jnp.asarray([16, 9]), view, None, scale=0.1,
+        value_dim=dv))(q, rows))
+    assert "pallas_call" not in jaxpr
+
+
+# ---- the runner: a prefill dispatch through the kernel
+async def _greedy(engine, prompts, max_tokens=6):
+    import asyncio
+
+    from production_stack_tpu.engine.sampling import SamplingParams
+
+    outs = {}
+
+    async def one(i, p):
+        toks = []
+        async for o in engine.generate(
+            prompt=p, sampling=SamplingParams(
+                temperature=0.0, max_tokens=max_tokens, ignore_eos=True)):
+            toks = o.token_ids
+        outs[i] = toks
+
+    # The first prompt alone (so the second finds its prefix cached), then
+    # the rest at once: rows of unequal history in one dispatch.
+    await one(0, prompts[0])
+    await asyncio.gather(*[one(i, p) for i, p in enumerate(prompts)
+                           if i > 0])
+    return outs
+
+
+@pytest.mark.asyncio
+async def test_engine_prefill_through_the_kernel_matches_the_gathered(
+        monkeypatch):
+    """The engine's paged prefill — the view ``_prefill_impl`` builds, the
+    one family a (rows, t), positions, block tables pinned at the full
+    width — through the kernel (every view made to say ``interpret``)
+    serves the tokens the CPU's own execution (pages gathered,
+    ``window_attention``) serves: a prompt alone, a prefix hit on it, a
+    prompt that crosses chunks, short ones beside them (float32: greedy
+    near-ties of random weights are no signal in bf16)."""
+    import functools
+
+    from production_stack_tpu.engine import runner as runner_mod
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.engine import ServingEngine
+
+    base = "the quick brown fox jumps over the lazy dog " * 3
+    prompts = [base, base + "and again", "x" * 150, "hi", "hello there"]
+    results, families = {}, {}
+    for execution in ("gathered", "kernel"):
+        if execution == "kernel":
+            monkeypatch.setattr(
+                runner_mod, "KVView",
+                functools.partial(runner_mod.KVView, interpret=True))
+        cfg = EngineConfig(
+            model="tiny-llama-128dh", max_model_len=256, num_kv_blocks=128,
+            attn_impl="paged", num_decode_steps=4, dtype="float32",
+            max_num_batched_tokens=64, max_num_seqs=4, block_size=16,
+            enable_warmup=False,
+        )
+        eng = ServingEngine(cfg)
+        await eng.start()
+        try:
+            assert eng.runner.prefill_reads_pool
+            families[execution] = eng.runner.reachable_prefill_families()
+            results[execution] = await _greedy(eng, prompts)
+            # GET /debug/programs says which execution the program holds.
+            assert {p["prefill_attn"]
+                    for p in eng.runner.audit_pool_programs()
+                    if p["program"] == "prefill"} == {
+                        "pallas" if execution == "kernel" else "xla"}
+        finally:
+            await eng.stop()
+    assert {f[3] for f in families["kernel"]} == {False}
+    assert results["kernel"] == results["gathered"]
+    assert all(len(v) == 6 for v in results["kernel"].values())

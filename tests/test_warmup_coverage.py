@@ -168,9 +168,11 @@ def test_reachable_families_cover_observed_dispatches():
         reachable_prefill_families = ModelRunner.reachable_prefill_families
         _decode_mb = ModelRunner._decode_mb
         _prefill_mb = ModelRunner._prefill_mb
+        _prefill_t_buckets = ModelRunner._prefill_t_buckets
         _pins_prefill_window = ModelRunner._pins_prefill_window
         state_specs = ()
         kv_pools = 2
+        prefill_reads_pool = False    # this ladder: a gathered window
 
     r = _FakeRunner()
     dec = set(r.reachable_decode_families())
@@ -223,6 +225,15 @@ def test_reachable_families_cover_observed_dispatches():
                 assert (b, t_floor, r._prefill_mb(live, windowed, b),
                         windowed) in pinned
     r.state_specs = ()
+
+    # Where a chunk reads its history in place from the pool, a window is
+    # no property of the program: one family a (rows, t), block table at
+    # the full width, whatever the rows' history.
+    r.prefill_reads_pool = True
+    in_place = set(r.reachable_prefill_families())
+    assert in_place == {(b, t, full_mb, False) for b, t, _, _ in pre}
+    assert len(in_place) * 4 == len(pre)
+    assert r._prefill_mb(3, False, 1) == full_mb
 
     # window impl: quantized mb ladder has at most 4 values.
     r.attn_impl = "window"
