@@ -24,6 +24,7 @@ from production_stack_tpu.engine.kv_cache import BlockPoolManager
 from production_stack_tpu.engine.runner import ModelRunner
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.scheduler import (
+    PREFILL_STOPS,
     Scheduler,
     Sequence,
     SequenceStatus,
@@ -39,7 +40,7 @@ from production_stack_tpu.protocols import random_uuid
 from production_stack_tpu.tracing import (
     spans_dropped_total as _spans_dropped_total,
 )
-from production_stack_tpu.utils import init_logger
+from production_stack_tpu.utils import init_logger, prefill_rectangle
 
 logger = init_logger(__name__)
 
@@ -223,7 +224,10 @@ class ServingEngine:
         # Loop spans (flight_recorder.LoopSpans): the loop's phases as
         # annotations on the profiler's clock, and the per-phase seconds
         # that tile the loop's wall time (pstpu:loop_*_seconds_total).
-        from production_stack_tpu.engine.flight_recorder import LoopSpans
+        from production_stack_tpu.engine.flight_recorder import (
+            LoopSpans,
+            compile_clock,
+        )
 
         self.loop_spans = LoopSpans()
         # Decode work counted where it happens (host integers the loop
@@ -246,6 +250,31 @@ class ServingEngine:
         self.decode_row_steps_total = 0
         self.decode_bucket_row_steps_total = 0
         self.decode_row_steps_wasted_total = 0
+        # Steps of an applied decode dispatch that NO row used: executed
+        # steps less the most tokens one row delivered (every step of a
+        # failed dispatch; 0 where a row ran the whole train). A step
+        # costs the device the same at 2 rows as at 20, so this, not the
+        # wasted row-steps, is the device time at stake; dispatch by
+        # dispatch, empty steps x rows <= wasted row-steps.
+        self.decode_steps_empty_total = 0
+        # What a prefill dispatch carried, counted at ISSUE beside
+        # prefill_dispatches_total (the issue span carries the same
+        # numbers): the tokens really prefilled, the rectangle the program
+        # computes (utils.prefill_rectangle), the live rows, the requests
+        # the admission pass left waiting, and the limit that stopped it
+        # (scheduler.PREFILL_STOPS; the scheduler's blocked passes are
+        # added in stats()).
+        self.prefill_tokens_issued_total = 0
+        self.prefill_tokens_padded_total = 0
+        self.prefill_rows_issued_total = 0
+        self.prefill_left_waiting_total = 0
+        self.prefill_stops: Dict[str, int] = dict.fromkeys(PREFILL_STOPS, 0)
+        # Compiles and persistent-cache loads WHILE SERVING
+        # (flight_recorder.CompileClock): the process's clock, and its
+        # reading when start() ended (warm-up's own work is
+        # pstpu:startup_*'s).
+        self._compile_clock = compile_clock()
+        self._compiles_at_start = self._compile_clock.reading()
         # telemetry
         from production_stack_tpu.engine.metrics import (
             DispatchDurationHistograms,
@@ -305,6 +334,7 @@ class ServingEngine:
             # engine never reports healthy with weights still in flight.
             await loop.run_in_executor(None, self.runner.wait_for_weights)
         self.startup_total_seconds = time.monotonic() - self._startup_t0
+        self._compiles_at_start = self._compile_clock.reading()
         self._running = True
         self._loop_task = asyncio.create_task(self._run_loop())
         dev = self.device_report()
@@ -631,7 +661,7 @@ class ServingEngine:
             })
 
     def _record_issue(self, batch, step: int, t_wall: float,
-                      t_mono: float) -> None:
+                      t_mono: float, compiled: float = 0.0) -> None:
         """Dispatch-issue anchor: close each fresh row's queue-wait phase
         and append the per-request issue event. O(rows) in-memory appends
         on the engine loop — no syscalls (PL008-clean: host-side only).
@@ -640,8 +670,11 @@ class ServingEngine:
         a cold shape family compiles for seconds inside it, and that time
         belongs to the dispatch's phase (issue -> fetch), not to an
         unattributed gap between phases — the phase spans must tile the
-        request duration."""
+        request duration. ``compiled``: the seconds of that kind this
+        issue really held (flight_recorder.annotated_issue), on each row's
+        ``*_issue`` event where it is not 0."""
         rec = self.recorder
+        stalled = {"compiled": compiled} if compiled else {}
         # Rows of the dispatch that carry recurrent state through it (every
         # real row of a model that declares some; absent otherwise).
         state_rows = {"state_rows": sum(
@@ -661,11 +694,12 @@ class ServingEngine:
                 rec.event(seq.request_id, "prefill_issue", {
                     "step": step, "chunk": batch.chunk_lens[idx],
                     "start": batch.chunk_starts[idx], **state_rows,
+                    **stalled,
                 }, t=t_wall)
             else:
                 data = {
                     "step": step, "rows": len(batch.seqs),
-                    "k": batch.num_steps, **state_rows,
+                    "k": batch.num_steps, **state_rows, **stalled,
                 }
                 if getattr(batch, "spec_mode", "off") != "off":
                     # Which speculative variant the runner actually
@@ -763,6 +797,22 @@ class ServingEngine:
                 fut.set_result(res)
 
     # ------------------------------------------------------------ engine loop
+    def _count_decode(self, batch, delivered: int) -> None:
+        """One applied decode dispatch into the step and row counters
+        (defined in __init__); ``delivered`` tokens reached its rows, the
+        most one row took is on the batch (``delivered_max``)."""
+        steps = batch.num_steps
+        if self.config.decode_loop != "scan" and batch.spec_mode == "off" \
+                and batch.decode_steps:
+            steps = min(steps, max(batch.decode_steps))
+        row_steps = steps * len(batch.seqs)
+        self.decode_steps_total += steps
+        self.decode_row_steps_total += row_steps
+        self.decode_bucket_row_steps_total += \
+            steps * self.runner.decode_bucket(len(batch.seqs))
+        self.decode_row_steps_wasted_total += max(0, row_steps - delivered)
+        self.decode_steps_empty_total += max(0, steps - batch.delivered_max)
+
     async def _run_loop(self) -> None:
         """Two-slot pipelined dispatch loop (config.async_pipeline /
         config.pipeline_depth / config.overlap_dispatch).
@@ -806,7 +856,10 @@ class ServingEngine:
             depth = 1
         overlap = cfg.overlap_dispatch and depth >= 2
         in_flight: deque = deque()  # (batch, step_id, DispatchHandle) FIFO
-        from production_stack_tpu.engine.flight_recorder import annotated
+        from production_stack_tpu.engine.flight_recorder import (
+            annotated,
+            annotated_issue,
+        )
 
         loop_span = self.loop_spans
 
@@ -815,21 +868,6 @@ class ServingEngine:
                 aborted = self.scheduler.abort(seq.request_id)
                 if aborted is not None:
                     self._process_output(aborted)
-
-        def count_decode(batch, delivered):
-            """One applied decode dispatch into the step and row counters
-            (defined in __init__)."""
-            steps = batch.num_steps
-            if cfg.decode_loop != "scan" and batch.spec_mode == "off" \
-                    and batch.decode_steps:
-                steps = min(steps, max(batch.decode_steps))
-            row_steps = steps * len(batch.seqs)
-            self.decode_steps_total += steps
-            self.decode_row_steps_total += row_steps
-            self.decode_bucket_row_steps_total += \
-                steps * self.runner.decode_bucket(len(batch.seqs))
-            self.decode_row_steps_wasted_total += max(
-                0, row_steps - delivered)
 
         async def apply_oldest():
             batch, step, handle = in_flight.popleft()
@@ -862,7 +900,7 @@ class ServingEngine:
                     abort_batch(batch)
                     self._last_fetch_done = time.monotonic()
                     if batch.kind == "decode":
-                        count_decode(batch, 0)
+                        self._count_decode(batch, 0)
                     return
                 self._record_fetch(
                     batch, step, tokens, handle.issue_time,
@@ -878,7 +916,7 @@ class ServingEngine:
                 )
                 self.generation_tokens_total += accepted
                 if batch.kind == "decode":
-                    count_decode(batch, accepted)
+                    self._count_decode(batch, accepted)
                 # Live roofline accounting (stats() folds the window into
                 # the pstpu:live_* gauges): all values below are host-side
                 # reads the loop already has — no device sync.
@@ -942,11 +980,23 @@ class ServingEngine:
                     await drain()
                 step = self._step_counter
                 self._step_counter += 1
+                if batch.kind == "decode":
+                    carried = {"k": batch.num_steps}
+                else:
+                    # What the dispatch carries against the rectangle its
+                    # program computes, and what stopped admission: the
+                    # same numbers the counters below take.
+                    tokens = sum(batch.chunk_lens)
+                    prog_rows, prog_t = prefill_rectangle(
+                        len(batch.seqs), max(batch.chunk_lens), cfg)
+                    carried = {
+                        "k": max(batch.chunk_lens), "tokens": tokens,
+                        "prog_rows": prog_rows, "prog_t": prog_t,
+                        "left": batch.left_waiting, "stop": batch.stop,
+                    }
                 with loop_span(
                     "pstpu.issue", step=step, kind=batch.kind,
-                    rows=len(batch.seqs),
-                    k=(batch.num_steps if batch.kind == "decode"
-                       else max(batch.chunk_lens)),
+                    rows=len(batch.seqs), **carried,
                 ):
                     # Captured BEFORE the issue call: a cold-shape compile
                     # inside execute_async belongs to this dispatch's phase
@@ -960,8 +1010,8 @@ class ServingEngine:
                         # health). Runner state stays effectively
                         # single-threaded: issue and fetch are each awaited
                         # before the next runner call.
-                        handle = await loop.run_in_executor(
-                            None, annotated, "pstpu.issue.enqueue", step,
+                        handle, compiled = await loop.run_in_executor(
+                            None, annotated_issue, step,
                             self.runner.execute_async, batch, step,
                         )
                     except Exception:  # noqa: BLE001 — loop must survive
@@ -970,6 +1020,13 @@ class ServingEngine:
                         abort_batch(batch)
                         issue_failed = True
                         break
+                    if compiled:
+                        logger.warning(
+                            "Dispatch %d compiled or cache-loaded a "
+                            "program while serving (%.3f s): %s rows=%d "
+                            "%s; requests %s", step, compiled, batch.kind,
+                            len(batch.seqs), carried,
+                            [s.request_id for s in batch.seqs[:4]])
                     if not in_flight and self._last_fetch_done is not None:
                         self.dispatch_gap_seconds_total += (
                             time.monotonic() - self._last_fetch_done
@@ -984,8 +1041,17 @@ class ServingEngine:
                         self.decode_dispatches_total += 1
                     else:
                         self.prefill_dispatches_total += 1
+                        self.prefill_tokens_issued_total += tokens
+                        self.prefill_tokens_padded_total += \
+                            prog_rows * prog_t
+                        self.prefill_rows_issued_total += len(batch.seqs)
+                        self.prefill_left_waiting_total += \
+                            batch.left_waiting
+                        if batch.stop != "none":
+                            self.prefill_stops[batch.stop] += 1
                     self.scheduler.advance_at_issue(batch)
-                    self._record_issue(batch, step, issue_wall, issue_mono)
+                    self._record_issue(batch, step, issue_wall, issue_mono,
+                                       compiled)
                     in_flight.append((batch, step, handle))
             if in_flight:
                 # Applying may finish rows and free blocks, unblocking
@@ -1469,6 +1535,7 @@ class ServingEngine:
             "kv_handoff_seconds_total": 0.0,
             "kv_handoff_failures_total": 0,
         }
+        compiles, compile_s = self._compile_clock.reading()
         return {
             "disagg_role": self.config.role,
             **disagg,
@@ -1587,6 +1654,20 @@ class ServingEngine:
                 self.decode_bucket_row_steps_total,
             "decode_row_steps_wasted_total":
                 self.decode_row_steps_wasted_total,
+            "decode_steps_empty_total": self.decode_steps_empty_total,
+            # What prefill dispatches carried and what stopped admission
+            # (counted at issue; the scheduler's blocked passes beside the
+            # dispatches' own stops), and compiles past warm-up.
+            "prefill_tokens_issued_total": self.prefill_tokens_issued_total,
+            "prefill_tokens_padded_total": self.prefill_tokens_padded_total,
+            "prefill_rows_issued_total": self.prefill_rows_issued_total,
+            "prefill_left_waiting_total": self.prefill_left_waiting_total,
+            **{f"prefill_stop_{stop}_total":
+               n + self.scheduler.prefill_blocked[stop]
+               for stop, n in self.prefill_stops.items()},
+            "serving_compiles_total": compiles - self._compiles_at_start[0],
+            "serving_compile_seconds_total":
+                compile_s - self._compiles_at_start[1],
             # How often the sampler's conditional picks engage
             # (engine/sampling.py), counted by the runner at issue.
             "sample_dispatches_total": self.runner.sample_dispatches_total,

@@ -28,6 +28,7 @@ phase spans on the profiler's clock plus the per-phase second totals
 span that caused it.
 """
 
+import threading
 import time
 from collections import OrderedDict
 from typing import Dict, List, Optional
@@ -115,6 +116,82 @@ def annotated(name: str, step: int, fn, *args):
     no keywords.)"""
     with TraceAnnotation(name, step=step):
         return fn(*args)
+
+
+class CompileClock:
+    """Seconds this PROCESS spent tracing, lowering and compiling (or
+    loading from the persistent cache) jitted programs, and how many
+    programs that was: one ``jax.monitoring`` listener over the three
+    durations JAX records under ``/jax/core/compile/``. The last,
+    ``backend_compile_duration``, spans the persistent cache's lookup too,
+    so a deferred variant's first-use load counts like a true compile and
+    ``/jax/compilation_cache/cache_retrieval_time_sec`` is not added
+    again; a call that finds its program compiled records nothing.
+
+    The clock runs from the first ``compile_clock()`` on; an engine
+    exports what it read past its own warm-up
+    (``pstpu:serving_compile*``: engine.py takes the reading when
+    ``start()`` ends). Two engines in one process (tests) see each
+    other's compiles. The listener runs in whichever thread compiles
+    (the dispatch executor, as a rule): two adds under a lock."""
+
+    COMPILED = "/jax/core/compile/backend_compile_duration"
+    DURATIONS = frozenset((
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration", COMPILED))
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()   # any thread may compile
+        self.seconds = 0.0
+        self.count = 0
+
+    def _on_duration(self, event: str, duration: float, **_kw) -> None:
+        if event in self.DURATIONS:
+            with self._lock:
+                self.seconds += duration
+                self.count += event == self.COMPILED
+
+    def reading(self) -> tuple:
+        """``(programs, seconds)`` so far."""
+        with self._lock:
+            return self.count, self.seconds
+
+
+_COMPILE_CLOCK: Optional[CompileClock] = None
+
+
+def compile_clock() -> CompileClock:
+    """The process's one ``CompileClock``, registered at first use."""
+    global _COMPILE_CLOCK
+    if _COMPILE_CLOCK is None:
+        clock = CompileClock()
+        try:
+            from jax import monitoring
+
+            monitoring.register_event_duration_secs_listener(
+                clock._on_duration)
+        except Exception:  # noqa: BLE001 — no monitoring: it reads 0
+            pass
+        _COMPILE_CLOCK = clock
+    return _COMPILE_CLOCK
+
+
+def annotated_issue(step: int, fn, *args):
+    """``annotated("pstpu.issue.enqueue", ...)`` that also says whether the
+    call compiled: returns ``(fn(*args), seconds)``, the seconds the
+    compile clock moved by where a program was compiled or loaded during
+    the call (0.0 otherwise), and puts them on the span as ``compiled``.
+    The loop awaits each issue, so no other runner call interleaves; a
+    handler thread that jits something meanwhile would be counted in."""
+    clock = compile_clock()
+    with TraceAnnotation("pstpu.issue.enqueue", step=step) as span:
+        count, seconds = clock.reading()
+        out = fn(*args)
+        now = clock.reading()
+        compiled = round(now[1] - seconds, 6) if now[0] != count else 0.0
+        if compiled and span is not None:
+            span.set_metadata(compiled=compiled)
+    return out, compiled
 
 
 class FlightRecord:

@@ -65,6 +65,7 @@ from production_stack_tpu.utils import (
     cdiv,
     init_logger,
     pow2_bucket as _bucket,
+    prefill_rectangle,
     prefill_t_floor,
     window_mb_bucket,
 )
@@ -2699,18 +2700,7 @@ class ModelRunner:
         cfg = self.config
         seqs = batch.seqs
         n = len(seqs)
-        # Two row families only (1 and the max prefill bucket): straggler
-        # batches of 2-7 rows pad to the max bucket — the padded compute is
-        # trivial next to the compile/cache-load stall a fresh (rows, t)
-        # family costs mid-serving (multi-second on TPU).
-        if n == 1:
-            b = 1
-        else:
-            b = _bucket(max(n, cfg.max_prefill_seqs), 1,
-                        max(1, cfg.max_num_seqs))
-        t = _bucket(max(batch.chunk_lens),
-                    prefill_t_floor(cfg.max_num_batched_tokens),
-                    max(16, cfg.max_num_batched_tokens))
+        b, t = prefill_rectangle(n, max(batch.chunk_lens), cfg)
         has_window = not self.prefill_reads_pool and \
             any(st > 0 for st in batch.chunk_starts)
         mb = self._prefill_mb(max(len(s.block_ids) for s in seqs),

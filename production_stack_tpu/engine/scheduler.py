@@ -28,7 +28,7 @@ from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.utils import (
     init_logger,
     pow2_bucket as _bucket,
-    prefill_t_floor,
+    prefill_rectangle,
     window_mb_bucket,
 )
 
@@ -42,6 +42,12 @@ logger = init_logger(__name__)
 # queueing) at a few percent of per-dispatch overhead amortization.
 DECODE_STEP_TIERS = ((2, 8), (8, 32))
 INTERACTIVE_DECODE_STEPS = DECODE_STEP_TIERS[0][1]
+
+# Why a prefill admission pass took no more requests than it did
+# (ScheduledBatch.stop), in the order _try_schedule_prefill meets the
+# limits; "none": the pass emptied the queue. Each is a flag the operator
+# holds (docs/OBSERVABILITY.md, "What stopped admission").
+PREFILL_STOPS = ("rows", "seqs", "tokens", "window", "slots", "blocks")
 
 
 def decode_step_cap(num_streams: int, num_decode_steps: int) -> int:
@@ -195,6 +201,17 @@ class ScheduledBatch:
     # for the flight recorder's decode_issue events; apply_results never
     # reads it (variable-emission reconciliation is shape-driven).
     spec_mode: str = "off"
+    # prefill only, set by the scheduler: what it knew when the admission
+    # pass stopped. `stop` is "none" (the queue was emptied) or the FIRST of
+    # PREFILL_STOPS the pass met — where the shrink loop cut rows the
+    # candidate loop had gathered, the limit the loop names; `left_waiting`
+    # counts the requests still waiting that a prefill could have taken.
+    stop: str = "none"
+    left_waiting: int = 0
+    # decode only, set by apply_results: the most tokens one row of the
+    # dispatch delivered (0 until applied, and for a failed fetch): the
+    # steps beyond it served no row (pstpu:decode_steps_empty_total).
+    delivered_max: int = 0
 
     @property
     def num_tokens(self) -> int:
@@ -221,6 +238,12 @@ class Scheduler:
         self.running: List[Sequence] = []
         self.seqs: Dict[str, Sequence] = {}
         self.num_preemptions_total = 0
+        # Admission passes that scheduled NO prefill while requests waited
+        # (the running set is full, or every candidate was starved of a
+        # state slot or of blocks), by the limit that stopped them: beside
+        # the dispatches' own `stop` in pstpu:prefill_stop_*_total.
+        self.prefill_blocked: Dict[str, int] = dict.fromkeys(
+            PREFILL_STOPS, 0)
         # Decode-priority row: a row the window budget skipped last dispatch
         # decodes FIRST next dispatch (as the leading row it schedules
         # unconditionally). Held as the Sequence itself, not an index — the
@@ -313,23 +336,25 @@ class Scheduler:
         decode slot-appends preempt, which preserves FCFS progress.
         """
         cfg = self.config
-        max_rows = min(
-            cfg.max_prefill_seqs, cfg.max_num_seqs - len(self.running)
-        )
-        if not self.waiting or max_rows <= 0:
+        room = cfg.max_num_seqs - len(self.running)
+        max_rows = min(cfg.max_prefill_seqs, room)
+        if not self.waiting:
+            return None
+        if max_rows <= 0:
+            if self._num_prefillable():
+                self.prefill_blocked["seqs"] += 1
             return None
         budget = cfg.max_num_batched_tokens
         cands: List[Sequence] = []
         newly_allocated: set = set()
+        stop = None       # the first limit this pass meets (PREFILL_STOPS)
         for cand in list(self.waiting):
-            if len(cands) >= max_rows:
-                break
-            if self.config.role == "decode" and not cand.disagg_fallback:
-                # Role admission: a decode-role engine never schedules
-                # prefill batches for disagg-conforming traffic; it prefills
-                # only router-flagged fallback requests (decode-hop rows are
-                # restored straight to RUNNING, never queued here).
+            if not self._prefillable(cand):
                 continue
+            if len(cands) >= max_rows:
+                stop = stop or (
+                    "rows" if cfg.max_prefill_seqs <= room else "seqs")
+                break
             if not cand.block_ids:
                 # Blocks AND a state slot, or neither (a K/V-only model
                 # needs no slot and is never refused one).
@@ -337,12 +362,14 @@ class Scheduler:
                     cand.state_slot = \
                         self.block_manager.allocate_state_slot()
                     if not cand.state_slot:
+                        stop = stop or "slots"
                         continue  # every slot is held; put off, not failed
                 alloc = self.block_manager.allocate_prompt(
                     cand.all_token_ids, seed=cand.hash_seed
                 )
                 if alloc is None:
                     self._free_state_slot(cand)
+                    stop = stop or "blocks"
                     continue  # starved; a later cand may already hold blocks
                 cand.block_ids, cand.num_cached_tokens = alloc
                 cand.num_computed_tokens = cand.num_cached_tokens
@@ -365,6 +392,8 @@ class Scheduler:
                         )
             cands.append(cand)
         if not cands:
+            if stop:
+                self.prefill_blocked[stop] += 1
             return None
         # Shared padded chunk width: a fair share of the budget over the
         # admitted rows, NOT the queue head's remaining tail — a head with 16
@@ -377,25 +406,27 @@ class Scheduler:
         while True:
             rems = [c.num_tokens - c.num_computed_tokens for c in cands[:n]]
             chunk_cap = min(max(rems), max(16, budget // n))
-            # Bucket floor matches the runner's padded dispatch width
-            # (utils.prefill_t_floor) so the admission budget counts the
-            # compute actually spent.
-            t_bucket = prefill_t_floor(budget)
-            while t_bucket < chunk_cap:
-                t_bucket *= 2
+            # The rectangle the runner will dispatch
+            # (utils.prefill_rectangle), so the admission budget counts
+            # the compute actually spent: the chunk at its padded width,
+            # and the window at the PADDED row count (multi-row prefills
+            # pad to the max_prefill_seqs bucket, one compiled row family)
+            # or the cap is bypassed.
+            padded_rows, t_bucket = prefill_rectangle(n, chunk_cap, cfg)
             # A chunk with history gathers a [rows, max_blocks] window; keep
             # its bucketed size within the window budget too.
             has_window = any(c.num_computed_tokens > 0 for c in cands[:n])
             mb_need = max(len(c.block_ids) for c in cands[:n])
-            # The runner pads multi-row prefills to the max_prefill_seqs
-            # bucket (one compiled row family); budget the window at the
-            # PADDED row count or the cap is bypassed.
-            padded_rows = n if n == 1 else max(n, self.config.max_prefill_seqs)
             win_ok = not has_window or self._window_ok(
                 padded_rows, mb_need, self.prefill_window_budget
             )
             if n == 1 or (n * t_bucket <= budget and win_ok):
                 break
+            if n == len(cands):
+                # The first cut names the limit: the rows gathered above
+                # were not all taken, so whatever ended the candidate loop
+                # is not what bounds this dispatch.
+                stop = "tokens" if n * t_bucket > budget else "window"
             n -= 1
         seqs = cands[:n]
         # Candidates allocated THIS pass but dropped by the shrink loop must
@@ -418,9 +449,22 @@ class Scheduler:
         for seq in seqs:
             self.waiting.remove(seq)
             seq.status = SequenceStatus.RUNNING
+        left = self._num_prefillable()
         return ScheduledBatch(
-            kind="prefill", seqs=seqs, chunk_starts=starts, chunk_lens=lens
+            kind="prefill", seqs=seqs, chunk_starts=starts, chunk_lens=lens,
+            stop=stop if stop and left else "none", left_waiting=left,
         )
+
+    def _prefillable(self, seq: Sequence) -> bool:
+        """Role admission: a decode-role engine never schedules prefill
+        batches for disagg-conforming traffic; it prefills only
+        router-flagged fallback requests (decode-hop rows are restored
+        straight to RUNNING, never queued here)."""
+        return self.config.role != "decode" or seq.disagg_fallback
+
+    def _num_prefillable(self) -> int:
+        """Waiting requests a prefill pass could take."""
+        return sum(map(self._prefillable, self.waiting))
 
     def _schedule_decode(self) -> Optional[ScheduledBatch]:
         if not self.running:
@@ -708,7 +752,7 @@ class Scheduler:
                     seq.num_computed_tokens -= max(
                         0, batch.decode_steps[i] - len(toks)
                     )
-                took = False
+                took = 0
                 lps = logprob_lists[i] if logprob_lists else None
                 for j, tok in enumerate(toks):
                     if seq.status.is_finished:
@@ -716,11 +760,12 @@ class Scheduler:
                     self._append_token(
                         seq, tok, lps[j] if lps else None
                     )
-                    accepted += 1
-                    took = True
+                    took += 1
+                accepted += took
                 self._register_full_blocks(seq)
                 if took:
                     produced.append(seq)
+                    batch.delivered_max = max(batch.delivered_max, took)
         for seq in produced:
             if seq.status.is_finished and seq in self.running:
                 self.running.remove(seq)

@@ -46,7 +46,13 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "loop_apply_seconds_total", "loop_idle_seconds_total",
                 "loop_other_seconds_total", "decode_steps_total",
                 "decode_row_steps_total", "decode_bucket_row_steps_total",
-                "decode_row_steps_wasted_total",
+                "decode_row_steps_wasted_total", "decode_steps_empty_total",
+                "prefill_tokens_issued_total", "prefill_tokens_padded_total",
+                "prefill_rows_issued_total", "prefill_left_waiting_total",
+                "prefill_stop_rows_total", "prefill_stop_seqs_total",
+                "prefill_stop_tokens_total", "prefill_stop_window_total",
+                "prefill_stop_slots_total", "prefill_stop_blocks_total",
+                "serving_compiles_total", "serving_compile_seconds_total",
                 "sample_dispatches_total", "sample_dispatches_greedy_total",
                 "sample_dispatches_filtered_total",
                 "moe_assignments_total", "moe_expert_load_max_total",
@@ -356,6 +362,94 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:decode_row_steps_wasted_total counter",
         f"pstpu:decode_row_steps_wasted_total{label} "
         f"{s['decode_row_steps_wasted_total']}",
+        "# HELP pstpu:decode_steps_empty_total Steps of applied decode "
+        "dispatches that no row used: executed steps less the most "
+        "tokens one row delivered (every step of a failed dispatch)",
+        "# TYPE pstpu:decode_steps_empty_total counter",
+        f"pstpu:decode_steps_empty_total{label} "
+        f"{s['decode_steps_empty_total']}",
+        # What a prefill dispatch carried and what stopped its admission
+        # pass, counted at issue (the pstpu.issue span carries the same
+        # numbers), and compiles past warm-up.
+        "# HELP pstpu:prefill_tokens_issued_total Prompt tokens prefill "
+        "dispatches really computed (the sum of their chunks), counted "
+        "at issue",
+        "# TYPE pstpu:prefill_tokens_issued_total counter",
+        f"pstpu:prefill_tokens_issued_total{label} "
+        f"{s['prefill_tokens_issued_total']}",
+        "# HELP pstpu:prefill_tokens_padded_total Tokens of the padded "
+        "rectangle (program rows x program chunk length) prefill "
+        "dispatches ran; issued over padded is the share that was "
+        "prompt",
+        "# TYPE pstpu:prefill_tokens_padded_total counter",
+        f"pstpu:prefill_tokens_padded_total{label} "
+        f"{s['prefill_tokens_padded_total']}",
+        "# HELP pstpu:prefill_rows_issued_total Live rows of prefill "
+        "dispatches, counted at issue",
+        "# TYPE pstpu:prefill_rows_issued_total counter",
+        f"pstpu:prefill_rows_issued_total{label} "
+        f"{s['prefill_rows_issued_total']}",
+        "# HELP pstpu:prefill_left_waiting_total Requests still waiting "
+        "that a prefill could have taken, summed over prefill "
+        "dispatches at the end of their admission pass",
+        "# TYPE pstpu:prefill_left_waiting_total counter",
+        f"pstpu:prefill_left_waiting_total{label} "
+        f"{s['prefill_left_waiting_total']}",
+        "# HELP pstpu:prefill_stop_rows_total Prefill admission passes "
+        "(a dispatch, or a pass that scheduled nothing while requests "
+        "waited) first stopped by the per-dispatch row cap "
+        "(--max-prefill-seqs)",
+        "# TYPE pstpu:prefill_stop_rows_total counter",
+        f"pstpu:prefill_stop_rows_total{label} "
+        f"{s['prefill_stop_rows_total']}",
+        "# HELP pstpu:prefill_stop_seqs_total Prefill admission passes "
+        "(a dispatch, or a pass that scheduled nothing while requests "
+        "waited) first stopped by the running set's room "
+        "(--max-num-seqs less the running sequences)",
+        "# TYPE pstpu:prefill_stop_seqs_total counter",
+        f"pstpu:prefill_stop_seqs_total{label} "
+        f"{s['prefill_stop_seqs_total']}",
+        "# HELP pstpu:prefill_stop_tokens_total Prefill admission "
+        "passes (a dispatch, or a pass that scheduled nothing while "
+        "requests waited) first stopped by the token budget "
+        "(--max-num-batched-tokens: the rows at their padded width did "
+        "not fit)",
+        "# TYPE pstpu:prefill_stop_tokens_total counter",
+        f"pstpu:prefill_stop_tokens_total{label} "
+        f"{s['prefill_stop_tokens_total']}",
+        "# HELP pstpu:prefill_stop_window_total Prefill admission "
+        "passes (a dispatch, or a pass that scheduled nothing while "
+        "requests waited) first stopped by the prefill window budget (a "
+        "gathered history window at the padded rows did not fit)",
+        "# TYPE pstpu:prefill_stop_window_total counter",
+        f"pstpu:prefill_stop_window_total{label} "
+        f"{s['prefill_stop_window_total']}",
+        "# HELP pstpu:prefill_stop_slots_total Prefill admission passes "
+        "(a dispatch, or a pass that scheduled nothing while requests "
+        "waited) first stopped by a candidate found no recurrent-state "
+        "slot (one a sequence: --max-num-seqs of a model with state)",
+        "# TYPE pstpu:prefill_stop_slots_total counter",
+        f"pstpu:prefill_stop_slots_total{label} "
+        f"{s['prefill_stop_slots_total']}",
+        "# HELP pstpu:prefill_stop_blocks_total Prefill admission "
+        "passes (a dispatch, or a pass that scheduled nothing while "
+        "requests waited) first stopped by a candidate found no KV "
+        "blocks for its prompt (--num-kv-blocks)",
+        "# TYPE pstpu:prefill_stop_blocks_total counter",
+        f"pstpu:prefill_stop_blocks_total{label} "
+        f"{s['prefill_stop_blocks_total']}",
+        "# HELP pstpu:serving_compiles_total Programs compiled or "
+        "loaded from the persistent cache after warm-up (a deferred "
+        "variant's first use and a true recompile alike)",
+        "# TYPE pstpu:serving_compiles_total counter",
+        f"pstpu:serving_compiles_total{label} "
+        f"{s['serving_compiles_total']}",
+        "# HELP pstpu:serving_compile_seconds_total Seconds spent "
+        "tracing, lowering and compiling or cache-loading programs "
+        "after warm-up",
+        "# TYPE pstpu:serving_compile_seconds_total counter",
+        f"pstpu:serving_compile_seconds_total{label} "
+        f"{s['serving_compile_seconds_total']:.6f}",
         # Sparse experts (zeros for a model without any): what the routed
         # experts were given, read behind each dispatch's fetch.
         "# HELP pstpu:moe_assignments_total Token-expert pairs the routed "

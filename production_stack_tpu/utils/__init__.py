@@ -4,6 +4,7 @@ from production_stack_tpu.utils.misc import (
     SingletonABCMeta,
     cdiv,
     pow2_bucket,
+    prefill_rectangle,
     prefill_t_floor,
     round_up,
     window_mb_bucket,
@@ -21,6 +22,7 @@ __all__ = [
     "SingletonABCMeta",
     "cdiv",
     "pow2_bucket",
+    "prefill_rectangle",
     "prefill_t_floor",
     "round_up",
     "window_mb_bucket",

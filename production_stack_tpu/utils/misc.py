@@ -97,6 +97,29 @@ def prefill_t_floor(token_budget: int) -> int:
     return f
 
 
+def prefill_rectangle(n_rows: int, max_chunk: int, cfg) -> tuple:
+    """``(prog_rows, prog_t)``: the padded rectangle of the prefill program
+    that runs a dispatch of ``n_rows`` live rows whose longest chunk is
+    ``max_chunk`` tokens, under ``cfg`` (an ``EngineConfig``:
+    ``max_prefill_seqs``, ``max_num_seqs``, ``max_num_batched_tokens``).
+
+    Two row families only (1 and the max prefill bucket): straggler
+    batches of 2-7 rows pad to the max bucket — the padded compute is
+    trivial next to the compile/cache-load stall a fresh (rows, t) family
+    costs mid-serving (multi-second on TPU). The chunk length pads to a
+    power of two from ``prefill_t_floor`` up.
+
+    THE statement of a prefill dispatch's shape: the scheduler's admission
+    budget, the runner's issue and the engine loop's
+    ``pstpu:prefill_tokens_padded_total`` all call it, so what is counted
+    as padded is what the device computes."""
+    budget = cfg.max_num_batched_tokens
+    rows = 1 if n_rows == 1 else pow2_bucket(
+        max(n_rows, cfg.max_prefill_seqs), 1, max(1, cfg.max_num_seqs))
+    return rows, pow2_bucket(max_chunk, prefill_t_floor(budget),
+                             max(16, budget))
+
+
 def validate_url(url: str) -> bool:
     return bool(_URL_RE.match(url))
 

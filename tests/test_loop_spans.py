@@ -20,8 +20,17 @@ from production_stack_tpu.engine.engine import ServingEngine
 from production_stack_tpu.engine.flight_recorder import (
     LOOP_COUNTERS,
     LoopSpans,
+    annotated_issue,
+    compile_clock,
 )
+from production_stack_tpu.engine.kv_cache import BlockPoolManager
 from production_stack_tpu.engine.sampling import SamplingParams
+from production_stack_tpu.engine.scheduler import (
+    PREFILL_STOPS,
+    ScheduledBatch,
+    Scheduler,
+    Sequence,
+)
 from production_stack_tpu.server.api_server import (
     APIServer,
     build_engine_from_args,
@@ -33,6 +42,12 @@ NEW_SERIES = (
     "pstpu:decode_steps_total", "pstpu:decode_row_steps_total",
     "pstpu:decode_row_steps_wasted_total", "pstpu:http_ingress_seconds",
     "pstpu:first_chunk_emit_seconds",
+    # What a dispatch says at issue (PR 36).
+    "pstpu:decode_steps_empty_total", "pstpu:prefill_tokens_issued_total",
+    "pstpu:prefill_tokens_padded_total", "pstpu:prefill_rows_issued_total",
+    "pstpu:prefill_left_waiting_total",
+    *(f"pstpu:prefill_stop_{stop}_total" for stop in PREFILL_STOPS),
+    "pstpu:serving_compiles_total", "pstpu:serving_compile_seconds_total",
 )
 
 
@@ -99,12 +114,12 @@ async def test_phase_totals_tile_the_loops_wall_time():
 
 
 # ---------------------------------------------------------- decode counts
-@pytest.mark.parametrize("decode_loop,steps,row_steps,wasted", [
-    ("scan", 8 + 8 + 8, 16 + 8 + 8, 4 + 0 + 5),
-    ("while", 8 + 8 + 3, 16 + 8 + 3, 4 + 0 + 0),
+@pytest.mark.parametrize("decode_loop,steps,row_steps,wasted,empty", [
+    ("scan", 8 + 8 + 8, 16 + 8 + 8, 4 + 0 + 5, 0 + 0 + 5),
+    ("while", 8 + 8 + 3, 16 + 8 + 3, 4 + 0 + 0, 0 + 0 + 0),
 ])
 async def test_decode_counts_equal_hand_counts_on_a_scripted_run(
-        decode_loop, steps, row_steps, wasted):
+        decode_loop, steps, row_steps, wasted, empty):
     """Two rows join one decode train of K=8 (two rows are the 8-step
     tier). Row A may produce 5 tokens: its prefill gives 1, so its decode
     budget is 4 and it stops mid-train by max_tokens. Row B (20 = 1 + 8 +
@@ -114,6 +129,10 @@ async def test_decode_counts_equal_hand_counts_on_a_scripted_run(
       train 2: row  B    8 steps ->  8 row-steps, 8 delivered
       train 3: row  B    budget 3: the scan runs all 8 steps (5 wasted),
                the while loop stops at the largest budget (3 steps, none)
+
+    A step is EMPTY when no row of its dispatch used it: none in train 1
+    (row B ran all 8 though row A wasted 4 row-steps), the scan's 5 in
+    train 3.
     """
     engine = ServingEngine(_cfg(pipeline_depth=1, decode_loop=decode_loop))
     await engine.start()
@@ -132,6 +151,7 @@ async def test_decode_counts_equal_hand_counts_on_a_scripted_run(
     assert stats["decode_steps_total"] == steps
     assert stats["decode_row_steps_total"] == row_steps
     assert stats["decode_row_steps_wasted_total"] == wasted
+    assert stats["decode_steps_empty_total"] == empty
     # Row-steps less wasted row-steps: the tokens decode delivered, all
     # tokens less each request's first.
     assert row_steps - wasted == stats["generation_tokens_total"] - 2
@@ -172,6 +192,377 @@ async def test_an_aborted_rows_undelivered_steps_count_as_wasted():
     # delivered token is counted as wasted and no wasted one as delivered.
     assert stats["decode_row_steps_wasted_total"] in (0, 8)
     assert delivered == got[-1] - 1
+
+
+# -------------------------------------------------- empty steps, by dispatch
+def _applied(rows, num_steps, budgets, took):
+    """A decode batch as ``apply_results`` leaves it: ``took[i]`` tokens
+    delivered to row i."""
+    batch = ScheduledBatch(
+        kind="decode", seqs=[object()] * rows, num_steps=num_steps,
+        decode_steps=list(budgets))
+    batch.delivered_max = max(took, default=0)
+    return batch, sum(took)
+
+
+@pytest.mark.parametrize("decode_loop", ["scan", "while"])
+def test_empty_steps_times_rows_never_pass_wasted_row_steps(decode_loop):
+    """Dispatch by dispatch: a step no row used wastes a row-step in every
+    row. A row that ran the whole train leaves none empty; a failed
+    dispatch (nothing delivered) leaves every step empty; a speculative
+    dispatch that delivers more than a token a step reads 0, not less."""
+    engine = ServingEngine(_cfg(decode_loop=decode_loop))
+    cases = [
+        (3, 8, (8, 8, 8), (8, 4, 1)),      # one row ran the train
+        (3, 8, (8, 8, 8), (5, 4, 1)),      # every row stopped early
+        (2, 8, (3, 2), (3, 2)),            # short budgets
+        (4, 32, (32, 32, 32, 32), (0, 0, 0, 0)),   # failed / all aborted
+        (1, 8, (8,), (20,)),               # speculation: > 1 token a step
+    ]
+    for rows, k, budgets, took in cases:
+        before = engine.stats()
+        engine._count_decode(*_applied(rows, k, budgets, took))
+        after = engine.stats()
+        d = {key: after[key] - before[key] for key in (
+            "decode_steps_total", "decode_row_steps_total",
+            "decode_row_steps_wasted_total", "decode_steps_empty_total")}
+        steps = k if decode_loop == "scan" else min(k, max(budgets))
+        assert d["decode_steps_total"] == steps
+        assert d["decode_steps_empty_total"] == max(0, steps - max(took))
+        assert 0 <= d["decode_steps_empty_total"] <= steps
+        assert d["decode_steps_empty_total"] * rows <= \
+            d["decode_row_steps_wasted_total"]
+
+
+async def test_empty_steps_identity_holds_over_a_real_run():
+    """The same, on what a run's own dispatches count: every call of
+    ``_count_decode`` is wrapped and its deltas checked."""
+    engine = ServingEngine(_cfg(pipeline_depth=1))
+    seen = []
+    inner = engine._count_decode
+
+    def checked(batch, delivered):
+        before = (engine.decode_steps_empty_total,
+                  engine.decode_row_steps_wasted_total)
+        inner(batch, delivered)
+        empty = engine.decode_steps_empty_total - before[0]
+        wasted = engine.decode_row_steps_wasted_total - before[1]
+        assert 0 <= empty * len(batch.seqs) <= wasted
+        seen.append((len(batch.seqs), empty, wasted))
+
+    engine._count_decode = checked
+    await engine.start()
+    try:
+        await asyncio.gather(*(
+            _run(engine, f"row {i} of a mixed batch", n)
+            for i, n in enumerate((3, 7, 12, 21))))
+    finally:
+        await engine.stop()
+    assert len(seen) >= 3
+    assert any(rows > 1 for rows, _, _ in seen)
+    stats = engine.stats()
+    assert stats["decode_steps_empty_total"] == sum(e for _, e, _ in seen)
+    assert stats["decode_steps_empty_total"] <= stats["decode_steps_total"]
+
+
+# ------------------------------------------------ what a prefill carried
+async def test_prefill_counters_say_what_the_dispatches_carried():
+    """Over a run with a repeated prefix and prompts longer than a chunk:
+    issued tokens are the prompt tokens less the prefix hits (a prompt
+    token is prefilled once or served from the cache), never more than
+    the padded rectangles; every prefill dispatch either emptied the
+    queue or names one limit; and the ``prefill_issue`` events carry the
+    chunks the counter summed."""
+    # 256 tokens hold two rows at the chunk floor (128): no candidate is
+    # cut by the budget and allocated again, which would count its prefix
+    # hits twice.
+    engine = ServingEngine(_cfg(max_num_seqs=8, max_prefill_seqs=2,
+                                block_size=4, max_num_batched_tokens=256))
+    await engine.start()
+    try:
+        shared = "a shared system prompt that fills some blocks. "
+        await _run(engine, shared + "first", 4, "first")
+        await asyncio.gather(*(
+            _run(engine, shared + f"question {i} " + "x" * (30 * i), 6,
+                 f"q{i}")
+            for i in range(5)))
+    finally:
+        await engine.stop()
+    stats = engine.stats()
+    hits = stats["prefix_cache_hits"]
+    assert hits > 0
+    assert stats["prefill_tokens_issued_total"] == \
+        stats["prompt_tokens_total"] - hits
+    assert 0 < stats["prefill_tokens_issued_total"] <= \
+        stats["prefill_tokens_padded_total"]
+    assert stats["prefill_dispatches_total"] <= \
+        stats["prefill_rows_issued_total"] <= \
+        2 * stats["prefill_dispatches_total"]
+    # The events' chunks are what the counter summed (each row's chunk in
+    # each dispatch once).
+    chunks = sum(
+        e["chunk"]
+        for rid in ["first"] + [f"q{i}" for i in range(5)]
+        for e in engine.recorder.get(rid)["records"][0]["events"]
+        if e["event"] == "prefill_issue")
+    assert chunks == stats["prefill_tokens_issued_total"]
+    stops = {stop: stats[f"prefill_stop_{stop}_total"]
+             for stop in PREFILL_STOPS}
+    blocked = sum(engine.scheduler.prefill_blocked.values())
+    named = sum(stops.values()) - blocked
+    assert 0 < named <= stats["prefill_dispatches_total"]
+    assert named == sum(engine.prefill_stops.values())
+    # Five prompts arrive together and a dispatch takes two rows: the row
+    # cap stops admission and leaves requests waiting.
+    assert stops["rows"] > 0
+    assert stats["prefill_left_waiting_total"] >= stops["rows"]
+
+
+async def test_issue_span_carries_what_the_counters_count(tmp_path):
+    """A capture's prefill ``pstpu.issue`` spans hold ``tokens``,
+    ``prog_rows``, ``prog_t``, ``left`` and ``stop``; their sums over the
+    capture are the counters' deltas over it."""
+    from production_stack_tpu.profiling import DeviceProfiler
+    from production_stack_tpu.utils import prefill_rectangle
+
+    profiler = DeviceProfiler()
+    if not profiler.available():
+        pytest.skip("jax.profiler unavailable in this image")
+    engine = ServingEngine(_cfg(max_prefill_seqs=2))
+    await engine.start()
+    try:
+        await _run(engine, "warm the shapes", 4)
+        before = engine.stats()
+        await profiler.arm(30.0, trace_dir=str(tmp_path))
+        await asyncio.gather(*(
+            _run(engine, f"prompt {i} " + "y" * (25 * i), 5)
+            for i in range(4)))
+        await profiler.close()
+        after = engine.stats()
+    finally:
+        await engine.stop()
+    events, _ = _capture_events(str(tmp_path))
+    prefills = [s for n, s in events
+                if n == "pstpu.issue" and s["kind"] == "prefill"]
+    assert prefills
+    for span in prefills:
+        assert set(span) >= {"step", "rows", "k", "tokens", "prog_rows",
+                             "prog_t", "left", "stop"}
+        assert (int(span["prog_rows"]), int(span["prog_t"])) == \
+            prefill_rectangle(int(span["rows"]), int(span["k"]),
+                              engine.config)
+        assert 0 < int(span["tokens"]) <= \
+            int(span["prog_rows"]) * int(span["prog_t"])
+        assert span["stop"] in ("none",) + PREFILL_STOPS
+        assert (span["stop"] == "none") == (int(span["left"]) == 0)
+    decodes = [s for n, s in events
+               if n == "pstpu.issue" and s["kind"] == "decode"]
+    assert decodes and all("tokens" not in s for s in decodes)
+    for key, attr in (("prefill_tokens_issued_total", "tokens"),
+                      ("prefill_rows_issued_total", "rows"),
+                      ("prefill_left_waiting_total", "left")):
+        assert after[key] - before[key] == \
+            sum(int(s[attr]) for s in prefills), key
+    assert after["prefill_tokens_padded_total"] \
+        - before["prefill_tokens_padded_total"] == sum(
+            int(s["prog_rows"]) * int(s["prog_t"]) for s in prefills)
+
+
+# ------------------------------------------------ what stopped admission
+def _sched(blocks=256, slots=0, window=None, **over):
+    base = dict(model="tiny-llama", max_model_len=2048, block_size=16,
+                max_num_seqs=8, max_num_batched_tokens=1024,
+                max_prefill_seqs=8)
+    base.update(over)
+    cfg = EngineConfig(**base)
+    bm = BlockPoolManager(blocks, cfg.block_size,
+                          enable_prefix_caching=False,
+                          num_state_slots=slots)
+    return Scheduler(cfg, bm, prefill_window_budget=window)
+
+
+def _waiting(sched, n, tokens):
+    for i in range(len(sched.seqs), len(sched.seqs) + n):
+        sched.add_sequence(Sequence(f"s{i}", [1 + i] * tokens,
+                                    SamplingParams()))
+
+
+def test_stop_none_where_the_pass_empties_the_queue():
+    sched = _sched()
+    _waiting(sched, 3, 40)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == (3, "none", 0)
+    assert not any(sched.prefill_blocked.values())
+
+
+def test_stop_rows_at_the_row_cap():
+    """8 waiting prompts and --max-prefill-seqs 4."""
+    sched = _sched(max_prefill_seqs=4)
+    _waiting(sched, 8, 40)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == (4, "rows", 4)
+    sched.advance_at_issue(batch)
+    batch = sched._try_schedule_prefill()
+    # The second pass takes the other four and empties the queue: the cap
+    # was reached but nothing is left behind it.
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == (4, "none", 0)
+
+
+def test_stop_seqs_where_the_running_set_has_less_room_than_the_row_cap():
+    sched = _sched(max_num_seqs=4, max_prefill_seqs=4)
+    _waiting(sched, 6, 40)
+    first = sched._try_schedule_prefill()
+    assert (len(first.seqs), first.stop, first.left_waiting) == (4, "rows", 2)
+    sched.advance_at_issue(first)          # 4 running: --max-num-seqs
+    assert sched._try_schedule_prefill() is None
+    assert sched.prefill_blocked == {**dict.fromkeys(PREFILL_STOPS, 0),
+                                     "seqs": 1}
+    sched.finish("s0", sched.seqs["s0"].status.FINISHED_ABORTED)
+    sched.finish("s1", sched.seqs["s1"].status.FINISHED_ABORTED)
+    sched.finish("s2", sched.seqs["s2"].status.FINISHED_ABORTED)
+    _waiting(sched, 3, 40)
+    batch = sched._try_schedule_prefill()  # room for 3 of the 5 waiting
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == (3, "seqs", 2)
+
+
+def test_stop_tokens_where_the_budget_cuts_rows():
+    """Three 1000-token prompts under a 1024-token budget: at three rows a
+    row's share is 341 tokens, padded to 512, and 3 x 512 does not fit."""
+    sched = _sched()
+    _waiting(sched, 3, 1000)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
+        (2, "tokens", 1)
+    assert batch.chunk_lens == [512, 512]
+
+
+def test_stop_window_where_a_gathered_window_cuts_rows():
+    """Two prompts on their second chunk (history to gather) under a
+    window budget that holds one padded row family only."""
+    sched = _sched(max_num_batched_tokens=256, window=8)
+    _waiting(sched, 2, 300)
+    first = sched._try_schedule_prefill()
+    assert (len(first.seqs), first.stop) == (2, "none")
+    sched.advance_at_issue(first)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
+        (1, "window", 1)
+
+
+def test_stop_blocks_where_a_prompt_finds_no_blocks():
+    """A pool of 8 usable blocks: the first 100-token prompt takes 7."""
+    sched = _sched(blocks=9)
+    _waiting(sched, 2, 100)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
+        (1, "blocks", 1)
+    sched.advance_at_issue(batch)
+    # The pool is still held: a pass that schedules nothing says so.
+    assert sched._try_schedule_prefill() is None
+    assert sched.prefill_blocked["blocks"] == 1
+    assert sum(sched.prefill_blocked.values()) == 1
+
+
+def test_stop_slots_where_every_state_slot_is_held():
+    sched = _sched(slots=2)
+    _waiting(sched, 3, 40)
+    batch = sched._try_schedule_prefill()
+    assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
+        (2, "slots", 1)
+    assert all(s.state_slot for s in batch.seqs)
+    sched.advance_at_issue(batch)
+    assert sched._try_schedule_prefill() is None
+    assert sched.prefill_blocked["slots"] == 1
+
+
+def test_decode_role_counts_only_what_a_prefill_could_take():
+    """Disagg-conforming requests on a decode-role engine are no prefill's
+    to take: they neither stop a pass nor count as left waiting."""
+    sched = _sched(role="decode", max_prefill_seqs=1)
+    _waiting(sched, 2, 40)
+    fallback = Sequence("fb", [7] * 40, SamplingParams())
+    fallback.disagg_fallback = True
+    sched.add_sequence(fallback)
+    batch = sched._try_schedule_prefill()
+    assert [s.request_id for s in batch.seqs] == ["fb"]
+    assert (batch.stop, batch.left_waiting) == ("none", 0)
+
+
+def test_the_rectangle_is_one_function_for_scheduler_runner_and_counters():
+    """``utils.prefill_rectangle`` over the shapes the benchmark's cells
+    dispatch, and the three callers name it."""
+    import inspect
+
+    from production_stack_tpu.engine import engine, runner, scheduler
+    from production_stack_tpu.utils import prefill_rectangle
+
+    cfg = EngineConfig(model="tiny-llama", max_model_len=4096,
+                       max_num_seqs=64, max_num_batched_tokens=2048,
+                       max_prefill_seqs=8)
+    assert prefill_rectangle(1, 320, cfg) == (1, 512)
+    assert prefill_rectangle(1, 17, cfg) == (1, 128)     # the floor
+    assert prefill_rectangle(1, 2048, cfg) == (1, 2048)
+    assert prefill_rectangle(2, 256, cfg) == (8, 256)    # stragglers pad
+    assert prefill_rectangle(8, 256, cfg) == (8, 256)
+    for module, where in ((scheduler, "_try_schedule_prefill"),
+                          (runner, "_issue_prefill"), (engine, "_run_loop")):
+        assert "prefill_rectangle(" in inspect.getsource(module), where
+
+
+# ------------------------------------------------- compiles while serving
+def test_compile_clock_moves_on_a_first_call_and_not_on_the_next():
+    import jax
+    import jax.numpy as jnp
+
+    clock = compile_clock()
+    assert compile_clock() is clock           # one listener a process
+
+    @jax.jit
+    def fresh(x):
+        return x * 3 + 1
+
+    x = jnp.arange(7, dtype=jnp.float32)
+    count, seconds = clock.reading()
+    out, compiled = annotated_issue(1, fresh, x)
+    assert out.shape == (7,)
+    assert clock.count == count + 1 and clock.seconds > seconds
+    assert compiled == pytest.approx(clock.seconds - seconds, abs=1e-5)
+    count, seconds = clock.reading()
+    out, compiled = annotated_issue(2, fresh, x)
+    assert (clock.count, clock.seconds, compiled) == (count, seconds, 0.0)
+
+
+async def test_a_compile_after_warm_up_shows_on_metrics_and_the_timeline():
+    """What an engine exports is what the clock read past its own start:
+    warm-up's compiles are not in it; a program first called while serving
+    is, and the request whose dispatch held it says so."""
+    import jax
+
+    engine = ServingEngine(_cfg())
+    await engine.start()
+    try:
+        assert engine.stats()["serving_compiles_total"] == 0
+        assert engine.stats()["serving_compile_seconds_total"] == 0.0
+        # No warm-up in this config: the first request's dispatches
+        # compile their programs while serving.
+        await _run(engine, "first use compiles", 4, "cold")
+        cold = engine.stats()
+        assert cold["serving_compiles_total"] >= 2        # prefill, decode
+        assert cold["serving_compile_seconds_total"] > 0
+        await _run(engine, "second use does not", 4, "warm")
+        warm = engine.stats()
+    finally:
+        await engine.stop()
+    assert warm["serving_compiles_total"] == cold["serving_compiles_total"]
+    events = {rid: engine.recorder.get(rid)["records"][0]["events"]
+              for rid in ("cold", "warm")}
+    stalled = [e for e in events["cold"] if e.get("compiled")]
+    assert stalled and {e["event"] for e in stalled} <= {
+        "prefill_issue", "decode_issue"}
+    assert sum(e["compiled"] for e in stalled) <= \
+        cold["serving_compile_seconds_total"] + 1e-3
+    assert not [e for e in events["warm"] if "compiled" in e]
+    del jax
 
 
 # ------------------------------------------------------------- /metrics

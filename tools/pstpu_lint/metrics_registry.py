@@ -486,6 +486,95 @@ REGISTRY: Tuple[Series, ...] = (
            "EOS / max_tokens / a stop string earlier in the train, was "
            "aborted or preempted, or its fetch failed; row-steps less "
            "wasted is the tokens decode delivered"),
+    Series("pstpu:decode_steps_empty_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Steps of applied decode dispatches that NO row used: "
+           "executed steps less the most tokens one row of the dispatch "
+           "delivered (every step of a failed dispatch; 0 where a row "
+           "ran the whole train). A step costs the device the same at 2 "
+           "rows as at 20: over `pstpu:decode_steps_total` this is the "
+           "share of decode device time that served nobody; dispatch by "
+           "dispatch, empty steps x rows <= wasted row-steps"),
+    Series("pstpu:prefill_tokens_issued_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prompt tokens prefill dispatches really computed (the sum of "
+           "their chunks; prefix hits are not in it), counted at issue; "
+           "over `pstpu:prefill_dispatches_total` the tokens a dispatch "
+           "carries"),
+    Series("pstpu:prefill_tokens_padded_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Tokens of the padded rectangle prefill dispatches ran: "
+           "program rows x program chunk length "
+           "(`utils.prefill_rectangle`, the shape the device computes); "
+           "issued over padded is the share of prefill compute that was "
+           "prompt"),
+    Series("pstpu:prefill_rows_issued_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Live rows of prefill dispatches, counted at issue; over "
+           "`pstpu:prefill_dispatches_total` the rows a dispatch carries"),
+    Series("pstpu:prefill_left_waiting_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Requests still waiting that a prefill could have taken, at "
+           "the end of each dispatch's admission pass, summed; over "
+           "`pstpu:prefill_dispatches_total` the mean backlog a dispatch "
+           "leaves behind"),
+    Series("pstpu:prefill_stop_rows_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was `--max-prefill-seqs`, the rows of one prefill dispatch: "
+           "raise it (a wider rectangle, one more program family to "
+           "warm) or pack by tokens"),
+    Series("pstpu:prefill_stop_seqs_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was `--max-num-seqs` less the running sequences: the decode "
+           "batch is full; raise it if the device has room, else the "
+           "engine is at capacity"),
+    Series("pstpu:prefill_stop_tokens_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was `--max-num-batched-tokens`: the candidates at their "
+           "padded width did not fit the token budget and the shrink "
+           "loop cut rows"),
+    Series("pstpu:prefill_stop_window_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was the prefill window budget (derived from the pool where a "
+           "history window is still gathered: int8 KV, latent rows, "
+           "tp/sp > 1; unlimited where the pool is read in place): a "
+           "gathered window at the padded rows did not fit"),
+    Series("pstpu:prefill_stop_slots_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was no free recurrent-state slot (one a sequence, `--max- "
+           "num-seqs` of them, for a model that declares state): "
+           "finished sequences free them"),
+    Series("pstpu:prefill_stop_blocks_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Prefill admission passes (a dispatch, or a pass that "
+           "scheduled nothing while requests waited) whose FIRST limit "
+           "was no KV blocks for a candidate's prompt (`--num-kv- "
+           "blocks`): the pool is full; with `vllm:gpu_cache_usage_perc` "
+           "near 1 add blocks or lower `--max-model-len`"),
+    Series("pstpu:serving_compiles_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Programs compiled or loaded from the persistent compile "
+           "cache AFTER warm-up (JAX's `backend_compile_duration` "
+           "events): a deferred variant's first use and a true recompile "
+           "alike; an already-compiled call adds nothing. The "
+           "`pstpu.issue.enqueue` span and the request's `*_issue` event "
+           "of that step carry `compiled`"),
+    Series("pstpu:serving_compile_seconds_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Seconds spent tracing, lowering and compiling or cache- "
+           "loading programs after warm-up (JAX's three "
+           "`/jax/core/compile/` durations; process-wide): 0 on a warm "
+           "engine, and a dispatch stall otherwise"),
     Series("pstpu:sample_dispatches_total", "counter", ("model_name",),
            (ENGINE,), ("catalogue", "loop"),
            "Prefill and decode dispatches issued (each runs the sampler "
