@@ -43,7 +43,14 @@ class EngineConfig:
     # --- scheduler ---
     max_num_seqs: int = 64
     max_num_batched_tokens: int = 4096      # prefill dispatch token budget
-    max_prefill_seqs: int = 8               # rows per batched prefill dispatch
+    # Rows of one batched prefill dispatch, at most. None (the default, and
+    # what every deployment file leaves it at): as many as the token budget
+    # holds at the narrowest chunk bucket, max_num_batched_tokens //
+    # prefill_t_floor (16 at 2048 tokens, 8 at 1024), within max_num_seqs.
+    # A value set here still caps. Readers take the resolved value,
+    # utils.prefill_row_cap(config); the programs that follow from it are
+    # utils.prefill_rectangles(config).
+    max_prefill_seqs: Optional[int] = None
     # MAX decode steps fused into ONE device dispatch (lax.scan inside the
     # jit): K*B tokens per host round-trip instead of B. Host-side stop
     # conditions (EOS, stop strings, aborts) are applied after the fetch, so
@@ -171,7 +178,7 @@ class EngineConfig:
     speculative_model: Optional[str] = None
     # Draft KV ring length in tokens (per sequence). 0 = max_model_len
     # (full draft context — highest acceptance, but draft-KV memory is
-    # ring * (max_num_seqs + max_prefill_seqs) * draft KV bytes/token and
+    # ring * (max_num_seqs + prefill_row_cap) * draft KV bytes/token and
     # is allocated OUTSIDE the paged pool's HBM budget); the bounded
     # default keeps spec-on startup safe at long context, at the cost of
     # the draft forgetting distant context (acceptance-only effect,

@@ -66,6 +66,8 @@ from production_stack_tpu.utils import (
     init_logger,
     pow2_bucket as _bucket,
     prefill_rectangle,
+    prefill_rectangles,
+    prefill_row_cap,
     prefill_t_floor,
     window_mb_bucket,
 )
@@ -488,7 +490,7 @@ class ModelRunner:
             # Slot capacity: every RUNNING row plus a prefill batch of
             # fresh prompts can hold a slot at once; LRU eviction below is
             # the backstop, never the plan.
-            self.spec_num_slots = config.max_num_seqs + config.max_prefill_seqs
+            self.spec_num_slots = config.max_num_seqs + prefill_row_cap(config)
             self._alloc_spec_pools()
             from collections import OrderedDict
 
@@ -1121,7 +1123,7 @@ class ModelRunner:
             # an int8 pool, tp or sp > 1); reserve the worst-case bucketed
             # prefill window out of the pool budget.
             reserve_bytes = min(
-                _bucket(cfg.max_prefill_seqs, 1, max(1, cfg.max_num_seqs))
+                _bucket(prefill_row_cap(cfg), 1, max(1, cfg.max_num_seqs))
                 * _bucket(cfg.max_blocks_per_seq, 1,
                           max(1, cfg.max_blocks_per_seq))
                 * window_bytes_per_block,
@@ -1186,15 +1188,10 @@ class ModelRunner:
         return window_mb_bucket(live_blocks, cfg.max_blocks_per_seq)
 
     def _prefill_t_buckets(self) -> List[int]:
-        """Every chunk-length bucket a prefill dispatch can take: the
-        powers of two from ``prefill_t_floor`` to the token budget's."""
-        cfg = self.config
-        t_max = _bucket(cfg.max_num_batched_tokens, 16,
-                        max(16, cfg.max_num_batched_tokens))
-        ts = [prefill_t_floor(cfg.max_num_batched_tokens)]
-        while ts[-1] * 2 <= t_max:
-            ts.append(ts[-1] * 2)
-        return ts
+        """Every chunk-length bucket a prefill dispatch can take
+        (``utils.prefill_rectangles``): the powers of two from
+        ``prefill_t_floor`` up, within the token budget."""
+        return sorted({t for _, t in prefill_rectangles(self.config)})
 
     @functools.cached_property
     def prefill_reads_pool(self) -> bool:
@@ -3101,10 +3098,6 @@ class ModelRunner:
         cfg = self.config
         full_mb = _bucket(cfg.max_blocks_per_seq, 1,
                           max(1, cfg.max_blocks_per_seq))
-        t_max = _bucket(cfg.max_num_batched_tokens, 16,
-                        max(16, cfg.max_num_batched_tokens))
-        pb_max = _bucket(max(1, cfg.max_prefill_seqs), 1,
-                         max(1, cfg.max_num_seqs))
         win_mbs = sorted({
             window_mb_bucket(m, cfg.max_blocks_per_seq)
             for m in (1, full_mb // 4, full_mb // 2, full_mb)
@@ -3117,17 +3110,13 @@ class ModelRunner:
                 else win_mbs
 
         fams = set()
-        for pb in {1, pb_max}:
-            for t in self._prefill_t_buckets():
-                # Multi-row dispatches split the token budget fairly, so
-                # their chunk bucket never exceeds bucket(budget // 2).
-                if pb == 1 or t <= _bucket(
-                    max(16, cfg.max_num_batched_tokens // 2), 16, t_max
-                ):
-                    fams.add((pb, t, full_mb, False))
-                    for mb in windowed(pb):
-                        if pb * mb <= self.prefill_window_blocks:
-                            fams.add((pb, t, mb, True))
+        # Exactly the rectangles a dispatch can run: admission chooses
+        # among them and the runner issues what prefill_rectangle says.
+        for pb, t in prefill_rectangles(cfg):
+            fams.add((pb, t, full_mb, False))
+            for mb in windowed(pb):
+                if pb * mb <= self.prefill_window_blocks:
+                    fams.add((pb, t, mb, True))
         return sorted(fams)
 
     def _abstract_params(self):
@@ -3379,7 +3368,7 @@ class ModelRunner:
             "b_max": self._b_max,
             "max_model_len": cfg.max_model_len,
             "max_num_batched_tokens": cfg.max_num_batched_tokens,
-            "max_prefill_seqs": cfg.max_prefill_seqs,
+            "max_prefill_seqs": prefill_row_cap(cfg),
             "spec": cfg.speculative_num_tokens,
             "spec_ring": self.spec_ring_len,
             "spec_adaptive": cfg.speculative_adaptive,

@@ -28,7 +28,8 @@ from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.utils import (
     init_logger,
     pow2_bucket as _bucket,
-    prefill_rectangle,
+    prefill_rectangles,
+    prefill_row_cap,
     window_mb_bucket,
 )
 
@@ -203,8 +204,9 @@ class ScheduledBatch:
     spec_mode: str = "off"
     # prefill only, set by the scheduler: what it knew when the admission
     # pass stopped. `stop` is "none" (the queue was emptied) or the FIRST of
-    # PREFILL_STOPS the pass met — where the shrink loop cut rows the
-    # candidate loop had gathered, the limit the loop names; `left_waiting`
+    # PREFILL_STOPS the pass met — where the rectangle it chose took fewer
+    # rows than the candidate loop had gathered, the limit that bounded
+    # the choice (`tokens` or `window`); `left_waiting`
     # counts the requests still waiting that a prefill could have taken.
     stop: str = "none"
     left_waiting: int = 0
@@ -325,26 +327,35 @@ class Scheduler:
         return self._schedule_decode()
 
     def _try_schedule_prefill(self) -> Optional[ScheduledBatch]:
-        """Admit up to max_prefill_seqs waiting prompts into ONE batched
-        prefill dispatch (concurrent arrivals must not serialize TTFT).
+        """Admit waiting prompts into ONE batched prefill dispatch
+        (concurrent arrivals must not serialize TTFT).
 
-        Mostly-FCFS: the first admissible sequence fixes the padded chunk
-        length T (its remaining prompt, capped by the token budget); further
-        sequences join with chunk = min(remaining, T) while rows * T stays
-        within the budget. Starved prompts (no blocks available) are skipped,
-        NOT preempted-for: preempting here admits ping-pong livelock; only
-        decode slot-appends preempt, which preserves FCFS progress.
+        FCFS over what is admissible: the candidates are the first
+        ``min(prefill_row_cap, room)`` waiting requests that find blocks
+        (and a state slot, where the model keeps one); starved prompts are
+        skipped, NOT preempted-for (preempting here admits ping-pong
+        livelock; only decode slot-appends preempt, which preserves FCFS
+        progress). The dispatch is then the ``[rows, T]`` rectangle of
+        ``utils.prefill_rectangles`` (area <= the token budget, always)
+        whose rows carry the most live tokens ``sum(min(remaining, T))``
+        over the first ``min(rows, candidates)`` candidates: the device
+        computes the padded rectangle whatever it holds, so a dispatch's
+        cost is its area and its worth is what it carries. Ties go to the
+        smaller area, then to the longer chunk (fewer, longer chunks: the
+        queue's head finishes sooner). A rectangle whose rows would gather
+        a history window beyond ``prefill_window_budget`` is passed over;
+        one row always goes. Each row's chunk is ``min(remaining, T)``.
         """
         cfg = self.config
         room = cfg.max_num_seqs - len(self.running)
-        max_rows = min(cfg.max_prefill_seqs, room)
+        row_cap = prefill_row_cap(cfg)
+        max_rows = min(row_cap, room)
         if not self.waiting:
             return None
         if max_rows <= 0:
             if self._num_prefillable():
                 self.prefill_blocked["seqs"] += 1
             return None
-        budget = cfg.max_num_batched_tokens
         cands: List[Sequence] = []
         newly_allocated: set = set()
         stop = None       # the first limit this pass meets (PREFILL_STOPS)
@@ -352,8 +363,7 @@ class Scheduler:
             if not self._prefillable(cand):
                 continue
             if len(cands) >= max_rows:
-                stop = stop or (
-                    "rows" if cfg.max_prefill_seqs <= room else "seqs")
+                stop = stop or ("rows" if row_cap <= room else "seqs")
                 break
             if not cand.block_ids:
                 # Blocks AND a state slot, or neither (a K/V-only model
@@ -395,41 +405,40 @@ class Scheduler:
             if stop:
                 self.prefill_blocked[stop] += 1
             return None
-        # Shared padded chunk width: a fair share of the budget over the
-        # admitted rows, NOT the queue head's remaining tail — a head with 16
-        # leftover tokens must not cap co-scheduled fresh prompts at 16
-        # (advisor r2 finding). Rows pad to one power-of-two bucket; the
-        # PADDED width counts against the budget since that is the device
-        # compute actually spent. NOTE: a preempted sequence re-prefills
-        # prompt+output together (num_tokens includes generated tokens).
-        n = len(cands)
-        while True:
-            rems = [c.num_tokens - c.num_computed_tokens for c in cands[:n]]
-            chunk_cap = min(max(rems), max(16, budget // n))
-            # The rectangle the runner will dispatch
-            # (utils.prefill_rectangle), so the admission budget counts
-            # the compute actually spent: the chunk at its padded width,
-            # and the window at the PADDED row count (multi-row prefills
-            # pad to the max_prefill_seqs bucket, one compiled row family)
-            # or the cap is bypassed.
-            padded_rows, t_bucket = prefill_rectangle(n, chunk_cap, cfg)
-            # A chunk with history gathers a [rows, max_blocks] window; keep
-            # its bucketed size within the window budget too.
-            has_window = any(c.num_computed_tokens > 0 for c in cands[:n])
-            mb_need = max(len(c.block_ids) for c in cands[:n])
-            win_ok = not has_window or self._window_ok(
-                padded_rows, mb_need, self.prefill_window_budget
-            )
-            if n == 1 or (n * t_bucket <= budget and win_ok):
-                break
-            if n == len(cands):
-                # The first cut names the limit: the rows gathered above
-                # were not all taken, so whatever ended the candidate loop
-                # is not what bounds this dispatch.
-                stop = "tokens" if n * t_bucket > budget else "window"
-            n -= 1
+        # The rectangle. Rows pad to the ladder and chunks to their
+        # power-of-two bucket, and the PADDED area is what the device
+        # computes, so that is what the budget bounds. NOTE: a preempted
+        # sequence re-prefills prompt+output together (num_tokens includes
+        # generated tokens).
+        rems = [c.num_tokens - c.num_computed_tokens for c in cands]
+        best = free = None   # (live, -area, t, rows taken); `free` is the
+        #                      choice were there no window budget
+        for rows, t in prefill_rectangles(cfg):
+            n = min(rows, len(cands))
+            rect = (sum(min(r, t) for r in rems[:n]), -rows * t, t, n)
+            if free is None or rect > free:
+                free = rect
+            if best is not None and rect <= best:
+                continue
+            # A chunk with history gathers a [rows, max_blocks] window;
+            # keep its bucketed size (at the PADDED row count) within the
+            # window budget too. One row always goes.
+            if rows == 1 or not any(
+                c.num_computed_tokens > 0 for c in cands[:n]
+            ) or self._window_ok(
+                rows, max(len(c.block_ids) for c in cands[:n]),
+                self.prefill_window_budget,
+            ):
+                best = rect
+        _, _, chunk_cap, n = best
+        if n < len(cands):
+            # The rows gathered above were not all taken, so whatever
+            # ended the candidate loop is not what bounds this dispatch:
+            # the area bound did, or the window budget where it passed
+            # over a rectangle of more rows.
+            stop = "window" if free[3] > n else "tokens"
         seqs = cands[:n]
-        # Candidates allocated THIS pass but dropped by the shrink loop must
+        # Candidates allocated THIS pass but not taken by the rectangle must
         # not sit in waiting pinning non-evictable blocks (they could starve
         # decode's append_block under memory pressure); release them — the
         # prefix cache makes the re-allocation next pass cheap.

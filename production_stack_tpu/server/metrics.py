@@ -397,8 +397,9 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"{s['prefill_left_waiting_total']}",
         "# HELP pstpu:prefill_stop_rows_total Prefill admission passes "
         "(a dispatch, or a pass that scheduled nothing while requests "
-        "waited) first stopped by the per-dispatch row cap "
-        "(--max-prefill-seqs)",
+        "waited) first stopped by the per-dispatch row cap (what the "
+        "token budget holds at the narrowest chunk: "
+        "--max-num-batched-tokens // 128, within --max-num-seqs)",
         "# TYPE pstpu:prefill_stop_rows_total counter",
         f"pstpu:prefill_stop_rows_total{label} "
         f"{s['prefill_stop_rows_total']}",
@@ -412,8 +413,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# HELP pstpu:prefill_stop_tokens_total Prefill admission "
         "passes (a dispatch, or a pass that scheduled nothing while "
         "requests waited) first stopped by the token budget "
-        "(--max-num-batched-tokens: the rows at their padded width did "
-        "not fit)",
+        "(--max-num-batched-tokens: the rectangle of at most that area "
+        "that carries the most took fewer rows than were gathered)",
         "# TYPE pstpu:prefill_stop_tokens_total counter",
         f"pstpu:prefill_stop_tokens_total{label} "
         f"{s['prefill_stop_tokens_total']}",

@@ -5,6 +5,8 @@ from production_stack_tpu.utils.misc import (
     cdiv,
     pow2_bucket,
     prefill_rectangle,
+    prefill_rectangles,
+    prefill_row_cap,
     prefill_t_floor,
     round_up,
     window_mb_bucket,
@@ -23,6 +25,8 @@ __all__ = [
     "cdiv",
     "pow2_bucket",
     "prefill_rectangle",
+    "prefill_rectangles",
+    "prefill_row_cap",
     "prefill_t_floor",
     "round_up",
     "window_mb_bucket",

@@ -6,6 +6,7 @@ re-designed, not translated.
 """
 
 import abc
+import functools
 import re
 import resource
 from typing import Any, Dict, List
@@ -97,27 +98,79 @@ def prefill_t_floor(token_budget: int) -> int:
     return f
 
 
+def prefill_row_cap(cfg) -> int:
+    """The most rows one prefill dispatch takes under ``cfg`` (an
+    ``EngineConfig``; its resolved ``max_prefill_seqs``): as many as the
+    token budget holds at the narrowest chunk bucket, ``budget //
+    prefill_t_floor(budget)`` down to a power of two (16 at 2048 and 128,
+    8 at 1024), never more than ``max_num_seqs``, and never more than
+    ``max_prefill_seqs`` where a deployment sets that."""
+    budget = max(16, cfg.max_num_batched_tokens)
+    by_area = budget // prefill_t_floor(budget)
+    cap = min(max(1, cfg.max_num_seqs), 1 << (by_area.bit_length() - 1))
+    if cfg.max_prefill_seqs is not None:
+        cap = min(cap, max(1, cfg.max_prefill_seqs))
+    return cap
+
+
+@functools.lru_cache(maxsize=64)
+def _prefill_ladder(budget: int, max_num_seqs: int, cap: int) -> tuple:
+    budget = max(16, budget)
+    floor = prefill_t_floor(budget)
+    top = pow2_bucket(cap, 1, max(1, max_num_seqs))
+    # The thin ladder: one row, the cap's bucket and the power of two
+    # below it. A function of budget, floor and cap alone.
+    half = 1 << ((top - 1).bit_length() - 1) if top > 1 else 1
+    out = []
+    for r in sorted({1, half, top}):
+        t = floor
+        while r * t <= budget:
+            out.append((r, t))
+            t *= 2
+    return tuple(out)
+
+
+def prefill_rectangles(cfg) -> tuple:
+    """Every ``(prog_rows, prog_t)`` a prefill dispatch can run under
+    ``cfg``, ascending: the row ladder {1, half the cap's bucket, the
+    cap's bucket} (``prefill_row_cap``) times the power-of-two chunk
+    lengths from ``prefill_t_floor`` up, as far as ``rows x t`` stays
+    within ``max_num_batched_tokens``. 8 rectangles at a 2048 budget (1 x
+    {128..2048}, 8 x {128, 256}, 16 x 128), 7 at 1024.
+
+    The ladder is thin because every rectangle is a compiled program
+    (warm-up runs each, and a deployment's programs share a bounded
+    compile cache): the full ladder {1, 2, 4, ...} is 15 and 10."""
+    return _prefill_ladder(cfg.max_num_batched_tokens, cfg.max_num_seqs,
+                           prefill_row_cap(cfg))
+
+
 def prefill_rectangle(n_rows: int, max_chunk: int, cfg) -> tuple:
     """``(prog_rows, prog_t)``: the padded rectangle of the prefill program
     that runs a dispatch of ``n_rows`` live rows whose longest chunk is
-    ``max_chunk`` tokens, under ``cfg`` (an ``EngineConfig``:
-    ``max_prefill_seqs``, ``max_num_seqs``, ``max_num_batched_tokens``).
+    ``max_chunk`` tokens, under ``cfg`` (an ``EngineConfig``): the
+    smallest of ``prefill_rectangles(cfg)`` that holds them. Its area
+    never exceeds the token budget: the device computes the padded
+    rectangle, not the live tokens (an ``[8, 512]`` program with four
+    live rows ran 309.7 ms for 1369 tokens on a v5e where ``[8, 256]``
+    with eight takes 136 ms for 1750: PERF.md section 5, PR 36), so
+    admission (``Scheduler._try_schedule_prefill``) chooses among these
+    rectangles and passes no ``(n_rows, max_chunk)`` that none holds;
+    one that is passed is a ValueError, not a wider program.
 
-    Two row families only (1 and the max prefill bucket): straggler
-    batches of 2-7 rows pad to the max bucket — the padded compute is
-    trivial next to the compile/cache-load stall a fresh (rows, t) family
-    costs mid-serving (multi-second on TPU). The chunk length pads to a
-    power of two from ``prefill_t_floor`` up.
-
-    THE statement of a prefill dispatch's shape: the scheduler's admission
-    budget, the runner's issue and the engine loop's
-    ``pstpu:prefill_tokens_padded_total`` all call it, so what is counted
-    as padded is what the device computes."""
-    budget = cfg.max_num_batched_tokens
-    rows = 1 if n_rows == 1 else pow2_bucket(
-        max(n_rows, cfg.max_prefill_seqs), 1, max(1, cfg.max_num_seqs))
-    return rows, pow2_bucket(max_chunk, prefill_t_floor(budget),
-                             max(16, budget))
+    THE statement of a prefill dispatch's shape: the scheduler's
+    admission, the runner's issue and warm-up
+    (``reachable_prefill_families``) and the engine loop's
+    ``pstpu:prefill_tokens_padded_total`` all read it, so what is warmed
+    is what can run and what is counted as padded is what the device
+    computes."""
+    for rows, t in prefill_rectangles(cfg):
+        if rows >= n_rows and t >= max_chunk:
+            return rows, t
+    raise ValueError(
+        f"no prefill rectangle holds {n_rows} rows of {max_chunk} tokens "
+        f"under a budget of {cfg.max_num_batched_tokens} tokens and "
+        f"{prefill_row_cap(cfg)} rows")
 
 
 def validate_url(url: str) -> bool:

@@ -466,9 +466,9 @@ def test_grouped_matmul_compiles_for_v5e(v5e, pairs, k, n):
                 and " copy(" in ln]
 
 
-@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x512-window"])
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128-window"])
 def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
-    """The decode and the widest prefill program of kanana-2-30b-a3b-d8's
+    """The decode and the fullest prefill program of kanana-2-30b-a3b-d8's
     envelope (deployment.json's flags, published widths, all 128 experts of
     7 sparse layers) compile for a v5e, fit its HBM beside 10.14 GB of
     weights and the 2.68 GB latent pool, copy neither the pool nor the
@@ -492,7 +492,7 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
-        lowered = r._lower_prefill(aparams, 8, 512, full_mb, True)
+        lowered = r._lower_prefill(aparams, 8, 128, full_mb, True)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
     experts = [jax.ShapeDtypeStruct((7 * 128, *sparse[k].shape[2:]),
@@ -606,28 +606,35 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
 
 
 @pytest.mark.parametrize("name,families,in_place", [
-    ("qwen2.5-3b", 9, True), ("mistral-7b-d16", 9, True),
-    ("olmo-hybrid-7b-d16", 9, True), ("kanana-2-30b-a3b-d8", 14, False)])
+    ("qwen2.5-3b", 8, True), ("mistral-7b-d16", 8, True),
+    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 14, False)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place):
     """One prefill family a (rows, t) where the history is read in place
-    (36 -> 9 at qwen2.5-3b's envelope, 32 -> 9, 18 -> 9); latent rows keep
-    the family without a window and the pinned one (14)."""
+    (36 -> 9 at qwen2.5-3b's envelope, 32 -> 9, 18 -> 9, and 8 since PR
+    37's ladder: 1 x {128..2048}, 8 x {128, 256}, 16 x 128); latent rows
+    keep the family without a window and the pinned one (14: 1 x
+    {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger than
+    the token budget."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
     fams = r.reachable_prefill_families()
     assert len(fams) == families
+    assert all(rows * t <= r.config.max_num_batched_tokens
+               for rows, t, _, _ in fams)
     assert {f[3] for f in fams} == ({False} if in_place else {False, True})
     assert r.prefill_window_blocks == (
         1 << 30 if in_place else r.num_kv_blocks)
 
 
 def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
-    """kanana-2-30b-a3b-d8's widest windowed prefill program does not
-    change with the kernel: its compiled text, without source locations
-    (metadata, the location tables, the Mosaic kernels' serialized bodies,
-    which carry line numbers), hashes as PR 33's tree's does. A PR that
-    changes this program on purpose writes the new hash here."""
+    """kanana-2-30b-a3b-d8's fullest windowed prefill program ([8, 128]:
+    since PR 37 no rectangle exceeds the token budget, and [8, 512] is
+    gone) does not change with the kernel or with the ladder: its compiled
+    text, without source locations (metadata, the location tables, the
+    Mosaic kernels' serialized bodies, which carry line numbers), hashes
+    as PR 36's tree's does at that shape. A PR that changes this program
+    on purpose writes the new hash here."""
     import hashlib
     import re
 
@@ -637,7 +644,7 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     text = r._lower_prefill(
-        r._abstract_params(), 8, 512, full_mb, True).compile().as_text()
+        r._abstract_params(), 8, 128, full_mb, True).compile().as_text()
     text = re.sub(r", metadata=\{[^}]*\}", "", text)
     text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>", text)
     body = "\n".join(
@@ -645,4 +652,4 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
             r'^(\d+ ["{]|FileNames|FunctionNames|FileLocations|StackFrames)',
             ln))
     assert hashlib.sha1(body.encode()).hexdigest() == \
-        "4553a5ba963eafb05f2e5fbd1db379ad9eb48d70"
+        "4fa76b19c60943eeacfe92e4acfc3cc5e1956023"

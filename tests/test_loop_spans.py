@@ -426,27 +426,33 @@ def test_stop_seqs_where_the_running_set_has_less_room_than_the_row_cap():
 
 
 def test_stop_tokens_where_the_budget_cuts_rows():
-    """Three 1000-token prompts under a 1024-token budget: at three rows a
-    row's share is 341 tokens, padded to 512, and 3 x 512 does not fit."""
+    """Three 1000-token prompts under a 1024-token budget: no rectangle of
+    1024 tokens carries more than one whole prompt does ([1, 1024]: 1000;
+    [4, 256]: 768; [8, 128]: 384), so the area bound takes one row of the
+    three gathered."""
     sched = _sched()
     _waiting(sched, 3, 1000)
     batch = sched._try_schedule_prefill()
     assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
-        (2, "tokens", 1)
-    assert batch.chunk_lens == [512, 512]
+        (1, "tokens", 2)
+    assert batch.chunk_lens == [1000]
 
 
 def test_stop_window_where_a_gathered_window_cuts_rows():
     """Two prompts on their second chunk (history to gather) under a
-    window budget that holds one padded row family only."""
+    window budget that holds one row's window only: [2, 128] would carry
+    both tails, and the window budget passes it over."""
     sched = _sched(max_num_batched_tokens=256, window=8)
-    _waiting(sched, 2, 300)
+    _waiting(sched, 2, 200)
     first = sched._try_schedule_prefill()
-    assert (len(first.seqs), first.stop) == (2, "none")
+    # [2, 128] carries 256 tokens of the two, [1, 256] 200 of the first.
+    assert (len(first.seqs), first.chunk_lens, first.stop) == \
+        (2, [128, 128], "none")
     sched.advance_at_issue(first)
     batch = sched._try_schedule_prefill()
     assert (len(batch.seqs), batch.stop, batch.left_waiting) == \
         (1, "window", 1)
+    assert batch.chunk_lens == [72]
 
 
 def test_stop_blocks_where_a_prompt_finds_no_blocks():
@@ -497,16 +503,23 @@ def test_the_rectangle_is_one_function_for_scheduler_runner_and_counters():
     from production_stack_tpu.utils import prefill_rectangle
 
     cfg = EngineConfig(model="tiny-llama", max_model_len=4096,
-                       max_num_seqs=64, max_num_batched_tokens=2048,
-                       max_prefill_seqs=8)
+                       max_num_seqs=64, max_num_batched_tokens=2048)
     assert prefill_rectangle(1, 320, cfg) == (1, 512)
     assert prefill_rectangle(1, 17, cfg) == (1, 128)     # the floor
     assert prefill_rectangle(1, 2048, cfg) == (1, 2048)
     assert prefill_rectangle(2, 256, cfg) == (8, 256)    # stragglers pad
     assert prefill_rectangle(8, 256, cfg) == (8, 256)
-    for module, where in ((scheduler, "_try_schedule_prefill"),
-                          (runner, "_issue_prefill"), (engine, "_run_loop")):
-        assert "prefill_rectangle(" in inspect.getsource(module), where
+    assert prefill_rectangle(9, 128, cfg) == (16, 128)
+    with pytest.raises(ValueError, match="no prefill rectangle"):
+        prefill_rectangle(4, 512, cfg)     # [8, 512] is twice the budget
+    for fn, name in (
+            (scheduler.Scheduler._try_schedule_prefill,
+             "prefill_rectangles("),
+            (runner.ModelRunner._issue_prefill, "prefill_rectangle("),
+            (runner.ModelRunner.reachable_prefill_families,
+             "prefill_rectangles("),
+            (engine.ServingEngine._run_loop, "prefill_rectangle(")):
+        assert name in inspect.getsource(fn), fn.__qualname__
 
 
 # ------------------------------------------------- compiles while serving

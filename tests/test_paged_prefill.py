@@ -310,7 +310,8 @@ def test_the_runner_asks_attends_predicate_of_every_chunk_bucket(
 
     r = SimpleNamespace(
         config=SimpleNamespace(max_num_batched_tokens=budget,
-                               block_size=block_size),
+                               block_size=block_size, max_num_seqs=64,
+                               max_prefill_seqs=None),
         model_config=SimpleNamespace(num_heads=16), attn_impl="paged",
         kv_pools=2, kv_quantized=kv_quantized, dtype=jnp.bfloat16,
         mesh=SimpleNamespace(size=devices),

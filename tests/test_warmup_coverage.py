@@ -150,6 +150,8 @@ def test_reachable_families_cover_observed_dispatches():
     from production_stack_tpu.engine.runner import ModelRunner
     from production_stack_tpu.utils import (
         pow2_bucket,
+        prefill_rectangle,
+        prefill_row_cap,
         prefill_t_floor,
         window_mb_bucket,
     )
@@ -192,24 +194,22 @@ def test_reachable_families_cover_observed_dispatches():
                 mb = r._decode_mb(live)
                 assert (b, mb, k, False) in dec, (rows, live, fresh)
 
-    # Prefill: single-row any chunk; multi-row fair-share chunks.
+    # Prefill: single-row any chunk; multi-row chunks within the area
+    # bound (256 tokens at a floor of 128: two rows at most).
     t_floor = prefill_t_floor(cfg.max_num_batched_tokens)
-    for rows, chunk in [(1, 1), (1, 100), (1, 256), (2, 128), (4, 64),
-                        (8, 32)]:
+    assert prefill_row_cap(cfg) == 2
+    for rows, chunk in [(1, 1), (1, 100), (1, 256), (2, 128), (2, 64),
+                        (2, 1)]:
         for live in (1, full_mb // 3, full_mb):
             for windowed in (False, True):
-                if rows == 1:
-                    b = 1
-                else:
-                    b = pow2_bucket(
-                        max(rows, cfg.max_prefill_seqs), 1, cfg.max_num_seqs
-                    )
-                t = pow2_bucket(chunk, t_floor, cfg.max_num_batched_tokens)
-                if rows > 1 and rows * t > cfg.max_num_batched_tokens:
-                    continue  # scheduler admission shrinks this away
+                b, t = prefill_rectangle(rows, chunk, cfg)
+                assert b == pow2_bucket(rows, 1, cfg.max_num_seqs)
+                assert b * t <= cfg.max_num_batched_tokens
                 mb = r._prefill_mb(live, windowed, b)
                 assert (b, t, mb, windowed) in pre, (rows, chunk, live,
                                                      windowed)
+    with pytest.raises(ValueError):
+        prefill_rectangle(2, 256, cfg)     # admission never takes it
 
     # A model with recurrent state pins the prefill window at the full
     # width: one windowed family a (rows, t), and still every dispatch's.
@@ -217,9 +217,8 @@ def test_reachable_families_cover_observed_dispatches():
     pinned = set(r.reachable_prefill_families())
     assert {f[2] for f in pinned} == {full_mb}
     assert len(pinned) < len(pre)
-    for rows in (1, 3, cfg.max_prefill_seqs):
-        b = 1 if rows == 1 else pow2_bucket(
-            max(rows, cfg.max_prefill_seqs), 1, cfg.max_num_seqs)
+    for rows in (1, prefill_row_cap(cfg)):
+        b, _ = prefill_rectangle(rows, 1, cfg)
         for live in (1, full_mb // 2, full_mb):
             for windowed in (False, True):
                 assert (b, t_floor, r._prefill_mb(live, windowed, b),

@@ -522,9 +522,11 @@ REGISTRY: Tuple[Series, ...] = (
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Prefill admission passes (a dispatch, or a pass that "
            "scheduled nothing while requests waited) whose FIRST limit "
-           "was `--max-prefill-seqs`, the rows of one prefill dispatch: "
-           "raise it (a wider rectangle, one more program family to "
-           "warm) or pack by tokens"),
+           "was the rows of one prefill dispatch: as many as "
+           "`--max-num-batched-tokens` holds at the narrowest chunk "
+           "bucket (budget // 128: 16 at 2048), within `--max-num-seqs`; "
+           "the queue is deeper than one rectangle of the budget, so "
+           "raise the budget (at `tpot`'s cost) or pack by tokens"),
     Series("pstpu:prefill_stop_seqs_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Prefill admission passes (a dispatch, or a pass that "
@@ -536,9 +538,10 @@ REGISTRY: Tuple[Series, ...] = (
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Prefill admission passes (a dispatch, or a pass that "
            "scheduled nothing while requests waited) whose FIRST limit "
-           "was `--max-num-batched-tokens`: the candidates at their "
-           "padded width did not fit the token budget and the shrink "
-           "loop cut rows"),
+           "was `--max-num-batched-tokens`: of the rectangles within "
+           "the budget the one that carries the most live tokens took "
+           "fewer rows than admission had gathered (long chunks before "
+           "many rows)"),
     Series("pstpu:prefill_stop_window_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Prefill admission passes (a dispatch, or a pass that "
