@@ -27,7 +27,7 @@ The kernel phase also times the paged decode kernel alone at the benchmark's
 two decode shapes and prints, under ``timing``, µs a call, the least time the
 chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
 their ratio, the kernel's own roofline share. It is read by no metric.
-``--prefill`` (alone, like ``--gdn`` and ``--moe``) does the same for the
+``--prefill`` (alone, like ``--gdn``, ``--moe`` and ``--hc``) does the same for the
 prefill flash kernel at the benchmark's four prefill shapes, with
 ``window_attention`` at the parent's window width beside it.
 
@@ -674,6 +674,122 @@ def gdn_child(rehearse: bool) -> int:
     emit({"phase": "gdn", "widths": widths, "calls": calls,
           "step_layers": layers, "checks": checks, "timing": timing,
           "peak": peak, "device": device, "ok": finite})
+    return 0 if finite else 1
+
+
+# --hc: the stream mix alone (ops/hyper_connections.py) at Xing4.0-29B-A4B's
+# published widths (benchmarks/chip/configs/xing4.0-29b-a4b-d7/config.json):
+# the rows of a decode step and the tokens of a prefill chunk.
+HC_CONFIG = os.path.join(HERE, "benchmarks", "chip", "configs",
+                         "xing4.0-29b-a4b-d7", "config.json")
+HC_TOKENS = (16, 32, 1024)
+HC_SUBLAYERS = 14       # of one forward: each with its own phi, b, a
+HC_CALLS = 20
+
+
+def hc_child(rehearse: bool) -> int:
+    """``--hc``: times one sublayer's stream mix alone on the chip (the
+    pre-mix: norm, projections, sigmoid, Sinkhorn, ``H_pre x``; then the
+    post-mix ``H_res x + H_post^T branch``), chained inside one program
+    over the 14 sublayers' parameters in turn with the streams carried in
+    bf16 as the model carries them, and prints microseconds a sublayer
+    beside the least time the chip's memory allows its bytes
+    (benchmarks/chip/lib/shapes_hc.py:mix of ONE sublayer: the streams read
+    once and read and written once, ``phi`` once). Checked once against the
+    einsum form of the same equations. Run by no benchmark cell and no other
+    phase."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.chip.lib import shapes_hc
+    from production_stack_tpu.models import deepseek_v3 as ds
+    from production_stack_tpu.models.config import ModelConfig
+    from production_stack_tpu.ops import hyper_connections as hc
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "hc", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    with open(HC_CONFIG) as f:
+        cfg = json.load(f)
+    tokens_list, sublayers, calls = HC_TOKENS, HC_SUBLAYERS, HC_CALLS
+    dtype = jnp.bfloat16
+    if rehearse:
+        cfg.update(hidden_size=64)
+        tokens_list, sublayers, calls, dtype = (4, 24), 2, 2, jnp.float32
+    mc = ModelConfig.from_hf_config(cfg)
+    n, d = mc.hc_mult, mc.hidden_size
+    one_sublayer = dict(cfg, num_hidden_layers=1, first_k_dense_replace=0)
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+    lp = ds._init_mix(mc, jax.random.PRNGKey(5), -(-sublayers // 2))
+    phi = jnp.concatenate([lp["hc_attn_phi"], lp["hc_ffn_phi"]])[:sublayers]
+    b = jnp.concatenate([lp["hc_attn_b"], lp["hc_ffn_b"]])[:sublayers]
+    a = jnp.concatenate([lp["hc_attn_a"], lp["hc_ffn_a"]])[:sublayers]
+    kw = dict(iters=mc.hc_sinkhorn_iters, eps=mc.hc_eps,
+              norm_eps=mc.rms_norm_eps, clamp=mc.hc_res_clamp)
+
+    def sublayer(x, branch, at):
+        mats = hc.mix_matrices(x, phi[at], b[at], a[at], **kw)
+        h = hc.pre(x, mats[0]).astype(x.dtype)
+        # The sublayer itself is not timed: its branch is its input, scaled.
+        return hc.post(x, branch + 0.1 * h, mats[1], mats[2]).astype(x.dtype)
+
+    timing, checks, ok = [], [], True
+    for tokens in tokens_list:
+        ks = jax.random.split(jax.random.PRNGKey(tokens), 3)
+        x = (jax.random.normal(ks[0], (1, tokens, 1, d))
+             + 0.3 * jax.random.normal(ks[1], (n, tokens, 1, d))).astype(dtype)
+        branch = jax.random.normal(ks[2], (tokens, 1, d)).astype(dtype)
+        # Once against the einsum form of the same equations.
+        mats = jax.jit(lambda x: hc.mix_matrices(x, phi[0], b[0], a[0],
+                                                 **kw))(x)
+        with jax.default_matmul_precision("highest"):
+            xf = x.astype(jnp.float32)
+            want = jnp.einsum("...ij,j...d->i...d", mats[2], xf) \
+                + jnp.einsum("...i,...d->i...d", mats[1],
+                             branch.astype(jnp.float32))
+        got = jax.jit(hc.post)(x, branch, mats[1], mats[2])
+        err = float(jnp.max(jnp.abs(got - want)))
+        sums = float(jnp.max(jnp.abs(mats[2].sum(-1) - 1))), \
+            float(jnp.max(jnp.abs(mats[2].sum(-2) - 1)))
+        checks.append({"op": "hc_post", "tokens": tokens, "max_abs_err": err,
+                       "row_col_sums_off_1": sums})
+        ok &= err < 1e-4 and max(sums) < 1e-4
+
+        @jax.jit
+        def chained(x, branch):
+            return jax.lax.fori_loop(
+                0, calls * sublayers,
+                lambda i, x: sublayer(x, branch, i % sublayers), x)
+
+        program = chained.lower(x, branch).compile()
+        best = float("inf")
+        for _ in range(6):        # the first run is the warm-up
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(program(x, branch))
+            best = min(best, time.perf_counter() - t0)
+        ok &= bool(jnp.all(jnp.isfinite(out.astype(jnp.float32))))
+        work = shapes_hc.mix(one_sublayer, tokens, 1)
+        work = {k: v / 2 for k, v in work.items()}     # ONE of its two
+        sec = best / (calls * sublayers)
+        entry = {"op": "hc_pre+hc_post", "tokens": tokens,
+                 "bytes": work["bytes"], "flops": work["flops"],
+                 "us_per_sublayer": None, "least_us": None,
+                 "roofline_pct": None}
+        if peak and not rehearse:
+            least = max(work["bytes"] / (peak["hbm_gbps"] * 1e9),
+                        work["flops"] / (peak["bf16_tflops"] * 1e12))
+            entry.update(us_per_sublayer=sec * 1e6, least_us=least * 1e6,
+                         roofline_pct=100.0 * least / sec,
+                         us_per_forward=sec * 1e6 * HC_SUBLAYERS)
+        timing.append(entry)
+    finite = ok and not rehearse
+    emit({"phase": "hc", "timing": timing, "checks": checks,
+          "execution": hc.EXECUTION, "peak": peak, "device": device,
+          "ok": finite})
     return 0 if finite else 1
 
 
@@ -1332,6 +1448,9 @@ def main(argv=None) -> int:
     ap.add_argument("--moe", action="store_true",
                     help="only time the latent decode kernel and the "
                          "experts' grouped matmuls alone and exit")
+    ap.add_argument("--hc", action="store_true",
+                    help="only time the stream mix of a residual of four "
+                         "streams alone and exit")
     ap.add_argument("--prefill", action="store_true",
                     help="only check and time the prefill flash kernel "
                          "alone at the benchmark's prefill shapes and exit")
@@ -1351,6 +1470,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return moe_child(args.rehearse)
+    if args.hc:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return hc_child(args.rehearse)
     if args.prefill:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"

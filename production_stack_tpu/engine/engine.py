@@ -1494,6 +1494,7 @@ class ServingEngine:
             "engine": {
                 "model": self.config.model,
                 "num_layers": self.model_config.num_layers,
+                **r.residual_report(),
                 "mesh": dict(self.mesh.shape),
                 "attn_impl": r.attn_impl,
                 "pallas_interpret": r._pallas_interpret,

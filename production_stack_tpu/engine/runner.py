@@ -3201,7 +3201,8 @@ class ModelRunner:
         recurrence's step it holds (``"pallas"`` / ``"xla"``); for a
         prefill program, ``prefill_attn``: which execution of the chunk's
         attention (``"pallas"``: the flash kernel over the pool /
-        ``"xla"``: ``window_attention`` over gathered keys). Nothing
+        ``"xla"``: ``window_attention`` over gathered keys); for every
+        program, ``hc_mult`` and ``hc_mix`` (``residual_report``). Nothing
         runs; with a compile cache the programs are the ones warmup left
         there."""
         pools = [self.kv_k] + [
@@ -3238,10 +3239,21 @@ class ModelRunner:
             path = step_path(text)
             if path:
                 out[-1]["gdn_step"] = path
+            out[-1].update(self.residual_report())
             if kind == "prefill":
                 out[-1]["prefill_attn"] = prefill_attn_path(text)
                 out[-1]["prefill_reads_pool"] = self.prefill_reads_pool
         return out
+
+    def residual_report(self) -> Dict:
+        """How many streams the model's residual is (``hc_mult``) and,
+        where more than one, what computes their mix (``hc_mix``:
+        ops/hyper_connections.py has one execution, ``xla``)."""
+        from production_stack_tpu.ops.hyper_connections import EXECUTION
+
+        streams = self.model_config.hc_mult
+        return {"hc_mult": streams,
+                **({"hc_mix": EXECUTION} if streams > 1 else {})}
 
     def _warmup_compile_prepass(self) -> int:
         """Compile-only AOT pass over every reachable shape family using

@@ -50,6 +50,18 @@ class LatentKVSpec(NamedTuple):
         return -(-(self.rank + self.rope_dim) // 128) * 128
 
 
+class YarnScaling(NamedTuple):
+    """``rope_scaling`` of ``type: yarn`` as a DeepSeek-V3 config states it
+    (models/deepseek_v3.py:_rope_inv_freq turns it into frequencies and the
+    softmax's extra scale). ``mscale`` / ``mscale_all_dim`` 0: not given."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.0
+    mscale_all_dim: float = 0.0
+
+
 class CacheSpecs(NamedTuple):
     """What a model module's ``cache_specs(cfg)`` declares it caches per
     sequence, by layer kind: the runner sizes and owns the pools from this
@@ -113,6 +125,21 @@ class ModelConfig:
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    # Latent attention's query through a low-rank pair (0: one full matrix)
+    # and YaRN's scaled rope (None: plain rope).
+    q_lora_rank: int = 0
+    rope_scaling: Optional[YarnScaling] = None
+    # Manifold-constrained hyper-connections (ops/hyper_connections.py): the
+    # residual is hc_mult streams (1: the plain residual), a sublayer's
+    # residual mix made doubly stochastic by hc_sinkhorn_iters iterations
+    # whose sums take hc_eps, its logits clamped to hc_res_clamp first.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # Published next-token-prediction layers: read, NOT served (their
+    # tensors are not loaded; the next-token logits do not depend on them).
+    num_nextn_predict_layers: int = 0
 
     def __post_init__(self):
         if self.layer_types:
@@ -189,12 +216,13 @@ class ModelConfig:
                 linear_allow_neg_eigval=d.get("linear_allow_neg_eigval", False),
                 name=name,
             )
-        if model_type == "deepseek_v3":
+        if model_type in ("deepseek_v3", "xing4_0"):
             # What the module does not implement is refused by its key, not
             # served as something else.
+            scaling = d.get("rope_scaling")
             unsupported = {
-                "q_lora_rank": d.get("q_lora_rank") is not None,
-                "rope_scaling": d.get("rope_scaling") is not None,
+                "rope_scaling.type != yarn": scaling is not None
+                and scaling.get("type", scaling.get("rope_type")) != "yarn",
                 "n_group/topk_group != 1": (d.get("n_group", 1),
                                             d.get("topk_group", 1)) != (1, 1),
                 "scoring_func != sigmoid":
@@ -209,7 +237,16 @@ class ModelConfig:
             asked = [k for k, on in unsupported.items() if on]
             if asked:
                 raise ValueError(
-                    f"deepseek_v3: not supported: {', '.join(asked)}")
+                    f"{model_type}: not supported: {', '.join(asked)}")
+            hc = {}
+            if model_type == "xing4_0":
+                # The residual of hc_mult streams; deepseek_v3 has none.
+                hc = dict(
+                    hc_mult=d.get("hc_mult", 1),
+                    hc_sinkhorn_iters=d.get("hc_sinkhorn_iters", 20),
+                    hc_eps=d.get("hc_eps", 1e-6),
+                    hc_res_clamp=(float(d.get("mhc_h_res_clamp_min", -30)),
+                                  float(d.get("mhc_h_res_clamp_max", 30))))
             return ModelConfig(
                 arch="deepseek_v3",
                 vocab_size=d["vocab_size"],
@@ -233,7 +270,18 @@ class ModelConfig:
                 first_k_dense_replace=d.get("first_k_dense_replace", 0),
                 routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
                 norm_topk_prob=d.get("norm_topk_prob", True),
+                q_lora_rank=d.get("q_lora_rank") or 0,
+                rope_scaling=None if scaling is None else YarnScaling(
+                    factor=float(scaling["factor"]),
+                    original_max_position_embeddings=scaling[
+                        "original_max_position_embeddings"],
+                    beta_fast=float(scaling.get("beta_fast") or 32),
+                    beta_slow=float(scaling.get("beta_slow") or 1),
+                    mscale=float(scaling.get("mscale") or 0),
+                    mscale_all_dim=float(scaling.get("mscale_all_dim") or 0)),
+                num_nextn_predict_layers=d.get("num_nextn_predict_layers", 0),
                 name=name,
+                **hc,
             )
         raise ValueError(f"Unsupported model_type: {model_type}")
 
@@ -362,9 +410,28 @@ TINY_DEEPSEEK_V3 = ModelConfig(
     routed_scaling_factor=2.448, name="tiny-deepseek-v3",
 )
 
+# The same family with what Xing4.0 adds (tests/test_xing4.py compares it with
+# its plain reference): a residual of 4 streams, a low-rank query, YaRN's
+# frequencies and softmax scale (factor 64 over 4096 original positions, as
+# published), 2 dense + 2 sparse layers, 8 experts top-2 beside one shared.
+TINY_XING4 = ModelConfig(
+    arch="deepseek_v3", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=4, num_heads=4, num_kv_heads=4,
+    max_position_embeddings=8192, rope_theta=10000.0, rms_norm_eps=1e-6,
+    kv_lora_rank=128, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+    moe_intermediate_size=32, first_k_dense_replace=2,
+    routed_scaling_factor=2.0, q_lora_rank=24,
+    rope_scaling=YarnScaling(factor=64.0,
+                             original_max_position_embeddings=4096,
+                             mscale=1.0, mscale_all_dim=1.0),
+    hc_mult=4, num_nextn_predict_layers=1, name="tiny-xing4",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
     "tiny-deepseek-v3": TINY_DEEPSEEK_V3,
+    "tiny-xing4": TINY_XING4,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,
     "tiny-llama-8kv": TINY_LLAMA_8KV,
     "tiny-llama-128dh": TINY_LLAMA_128DH,

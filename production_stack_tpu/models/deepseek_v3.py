@@ -4,7 +4,7 @@ row a token, and sparse experts beside shared ones — functional JAX.
 The same shape of module as models/llama.py (the declarations under "What
 the rest of the tree asks of this module", attention through ``attend`` over
 whatever ``KVView`` the runner built, ``rms_norm`` and the rope helpers
-imported from there), with three things of its own:
+imported from there), with four things of its own, and one it leaves out:
 
   * Latent attention. A token caches ``[c | k_r]``: the compressed KV after
     its norm (``kv_lora_rank``) and the rotary key all heads share, after
@@ -19,11 +19,19 @@ imported from there), with three things of its own:
     states (tests/reference/deepseek_v3_ref.py) and this module is held to.
     ``W_kvb`` is kept as its two halves per head (``w_uk`` [H, nope, rank],
     ``w_uv`` [H, rank, v]): a checkpoint's one matrix is split when loaded.
+    With ``q_lora_rank`` the query goes through a low-rank pair and a norm
+    (``wq_a``, ``q_norm``, ``wq_b``) where it is otherwise one matrix
+    (``wq``); with ``rope_scaling`` (YaRN) the rotary frequencies are
+    blended between the published and the interpolated ones and the
+    softmax's scale takes ``mscale^2`` (``_rope_tables``, ``_softmax_scale``:
+    what HF's DeepseekV3 attention computes).
   * Two kinds of layer, not a period: ``first_k_dense_replace`` leading
     layers with a dense SiLU-gated FFN, then sparse layers. Parameters are
-    stacked BY KIND (``layers.dense``, ``layers.sparse``); the dense layers
-    are traced one by one (there is one, or three) and the sparse stack is
-    one ``lax.scan`` over the layer index, with the weights closed over and
+    stacked BY KIND (``layers.dense``, ``layers.sparse``); ONE dense layer is
+    traced where it stands, two or more are a ``lax.scan`` of their own (a
+    layer's code once: a program of this family is large, and the compile
+    cache is capped), and the sparse stack is one ``lax.scan`` over the
+    layer index, with the weights closed over and
     sliced where they are used, never a scan operand. The routed experts'
     matrices are not even sliced: the grouped matmul takes the whole stack
     ``[n_sparse * E, ...]`` and a layer's groups sit at ``layer * E`` (a
@@ -34,14 +42,33 @@ imported from there), with three things of its own:
     not count (``chunk_lens``) reach no expert. The forward returns, last,
     the int32 counters ``FORWARD_STATS`` names, summed over its sparse
     layers: the runner adds them up and the engine exports them.
+  * The residual (``hc_mult`` > 1; ops/hyper_connections.py): ``hc_mult``
+    streams, STREAM-MAJOR ``[n, B, T, D]``, which enter as copies of the
+    embedding and leave as their sum before the final norm. ``_attention``
+    and the FFNs return their BRANCH, and ``_sublayer`` wraps each (2 a
+    layer) by the mix its own ``hc_*`` leaves give: the sublayer reads
+    ``H_pre x`` and the streams become ``H_res x + H_post^T branch``, the
+    matrices computed per token in float32. The carry of the dense loop and
+    of the sparse scan is the streams. With ``hc_mult`` 1 there is one
+    stream, no mix and no such leaf: ``hidden + branch``, the programs this
+    module lowered before it knew of streams. ``forward`` returns
+    ``hidden [B, T, D]`` either way.
+  * What is published and NOT served: the next-token-prediction layers
+    (``num_nextn_predict_layers``). Their tensors lie behind the last layer
+    (``model.layers.<num_hidden_layers>.*``) and are skipped at load, as HF's
+    forward ignores them: the next-token logits do not depend on them.
 
 Device scopes: ``attn_proj`` (projections, norms, rope, the two absorbed
 products), ``attn_core``, ``ffn`` (the dense FFN; a sparse layer's norm and
 sum), and inside ``ffn`` the three of a sparse layer: ``moe_route``,
 ``moe_experts`` (sort, grouped matmuls under an inner ``moe_gmm``, unsort)
-and ``moe_shared``; ``embed``, ``logits``.
+and ``moe_shared``; ``embed``, ``logits``. The stream mix lies INSIDE the
+scope of the sublayer it wraps: ``hc_pre`` (norm, projections, sigmoid,
+Sinkhorn, ``H_pre x``) and ``hc_post`` (``H_res x + H_post^T branch``) under
+``attn_proj`` / ``ffn``, ``hc_head`` (the streams' sum) under ``logits``.
 """
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -59,6 +86,7 @@ from production_stack_tpu.models.llama import (
     compute_logits,  # noqa: F401 — the untied head is llama's
     rms_norm,
 )
+from production_stack_tpu.ops import hyper_connections as hc
 from production_stack_tpu.ops import moe
 from production_stack_tpu.ops.attention import KVView, attend
 
@@ -70,6 +98,9 @@ Params = Dict
 # behind the layer's.
 HF_LAYER_MAP = {
     "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.q_a_proj.weight": ("wq_a", True),
+    "self_attn.q_a_layernorm.weight": ("q_norm", False),
+    "self_attn.q_b_proj.weight": ("wq_b", True),
     "self_attn.kv_a_proj_with_mqa.weight": ("w_kva", True),
     "self_attn.kv_a_layernorm.weight": ("kv_norm", False),
     "self_attn.kv_b_proj.weight": ("w_kvb", True),
@@ -87,6 +118,14 @@ HF_LAYER_MAP = {
     "mlp.shared_experts.gate_proj.weight": ("ws_gate", True),
     "mlp.shared_experts.up_proj.weight": ("ws_up", True),
     "mlp.shared_experts.down_proj.weight": ("ws_down", True),
+    # The stream mix of the two sublayers (the names are ASSUMED: the
+    # config gives none; deployment.json of xing4.0-29b-a4b-d7 says so).
+    "attn_hc.phi.weight": ("hc_attn_phi", True),
+    "attn_hc.bias": ("hc_attn_b", False),
+    "attn_hc.alpha": ("hc_attn_a", False),
+    "mlp_hc.phi.weight": ("hc_ffn_phi", True),
+    "mlp_hc.bias": ("hc_ffn_b", False),
+    "mlp_hc.alpha": ("hc_ffn_a", False),
 }
 HF_TOP_MAP = {
     "model.embed_tokens.weight": ("embed", False),
@@ -94,8 +133,11 @@ HF_TOP_MAP = {
     "lm_head.weight": ("lm_head", True),
 }
 # Leaves a checkpoint load keeps in float32 whatever the engine's dtype: the
-# router computes in float32 and its bias is published in it.
-FLOAT32_LEAVES = ("w_router", "router_bias")
+# router computes in float32 and its bias is published in it; the stream mix
+# is float32 throughout.
+_HC = tuple(f"hc_{sub}_{leaf}" for sub in ("attn", "ffn")
+            for leaf in ("phi", "b", "a"))
+FLOAT32_LEAVES = ("w_router", "router_bias") + _HC
 # No LoRA on this family yet: the absorbed products and the experts have no
 # delta path (the engine refuses --lora-modules on an empty tuple).
 LORA_TARGETS = ()
@@ -106,10 +148,10 @@ PAGED_DECODE_VALIDATED = True
 # int32 counters ``forward`` returns last, summed over its sparse layers.
 FORWARD_STATS = moe.STATS
 
-_ATTN = ("wq", "w_kva", "kv_norm", "w_kvb", "wo", "attn_norm", "mlp_norm")
-_DENSE = _ATTN + ("w_gate", "w_up", "w_down")
-_SPARSE = _ATTN + ("w_router", "router_bias", "we_gate", "we_up", "we_down",
-                   "ws_gate", "ws_up", "ws_down")            # as loaded
+_ATTN = ("w_kva", "kv_norm", "w_kvb", "wo", "attn_norm", "mlp_norm")
+_DENSE = ("w_gate", "w_up", "w_down")
+_SPARSE = ("w_router", "router_bias", "we_gate", "we_up", "we_down",
+           "ws_gate", "ws_up", "ws_down")                    # as loaded
 
 
 def position_bound(cfg: ModelConfig) -> Optional[int]:
@@ -126,7 +168,9 @@ def layer_slots(cfg: ModelConfig):
 
 def required_layer_leaves(cfg: ModelConfig) -> dict:
     """Per kind, the leaves every valid checkpoint must provide."""
-    return {"dense": set(_DENSE), "sparse": set(_SPARSE)}
+    every = _ATTN + (("wq_a", "q_norm", "wq_b") if cfg.q_lora_rank
+                     else ("wq",)) + (_HC if cfg.hc_mult > 1 else ())
+    return {"dense": set(every + _DENSE), "sparse": set(every + _SPARSE)}
 
 
 def finish_params(cfg: ModelConfig, params: Params) -> Params:
@@ -189,11 +233,19 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.bfloat16) -> Params:
                        * (scale * fan_in ** -0.5)).astype(dtype),
             jax.random.split(next(keys), ns))
 
+    def query(n):
+        if not cfg.q_lora_rank:
+            return {"wq": w((n, d, h * (nope + rope)), d)}
+        return {"wq_a": w((n, d, cfg.q_lora_rank), d),
+                "q_norm": jnp.ones((n, cfg.q_lora_rank), dtype),
+                "wq_b": w((n, cfg.q_lora_rank, h * (nope + rope)),
+                          cfg.q_lora_rank)}
+
     def attn(n):
         return {
             "attn_norm": jnp.ones((n, d), dtype),
             "mlp_norm": jnp.ones((n, d), dtype),
-            "wq": w((n, d, h * (nope + rope)), d),
+            **query(n),
             "w_kva": w((n, d, rank + rope), d),
             "kv_norm": jnp.ones((n, rank), dtype),
             "w_uk": w((n, h, nope, rank), rank),
@@ -219,12 +271,51 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.bfloat16) -> Params:
         "ws_gate": w((ns, d, fs), d), "ws_up": w((ns, d, fs), d),
         "ws_down": w((ns, fs, d), fs, scale=back),
     }
+    if cfg.hc_mult > 1:
+        # Keys of their own: the other leaves are those of hc_mult 1.
+        hc_rng = jax.random.fold_in(rng, cfg.hc_mult)
+        dense.update(_init_mix(cfg, jax.random.fold_in(hc_rng, 0), nd))
+        sparse.update(_init_mix(cfg, jax.random.fold_in(hc_rng, 1), ns))
     return {
         "embed": w((v, d), 1),
         "layers": {"dense": dense, "sparse": sparse},
         "final_norm": jnp.ones((d,), dtype),
         "lm_head": w((d, v), d),
     }
+
+
+def _init_mix(cfg: ModelConfig, rng: jax.Array, layers: int) -> Params:
+    """The stream mix of ``layers`` layers' two sublayers, float32, drawn so
+    that every part of the equations MOVES the matrices (a comparison that
+    drops a part must see it):
+
+      * dynamic: ``phi`` at fan-in scale over the ``nD`` normed values, so
+        ``x~ phi`` is unit normal per column, and ``a`` about a half: a
+        token's own logits spread by half a unit around the static ones;
+      * static: ``b`` half a unit wide, and the residual mix's leaning to
+        the identity (``+1`` on its diagonal: a stream mostly keeps itself,
+        as the published initialisation has it), so ``exp`` of its logits is
+        far from doubly stochastic and one Sinkhorn iteration leaves row
+        sums a tenth off 1 where twenty leave 1e-6;
+      * ``H_post`` is around 1 (``2 sigmoid(~0)``): a branch enters the
+        streams at about the size it has in the plain residual, and the
+        streams stay the embedding's scale (``H_res`` is doubly stochastic:
+        the embedding's part of every stream is kept whole), so routing
+        sees the token as under ``hc_mult`` 1.
+    """
+    n, nd_ = cfg.hc_mult, cfg.hc_mult * cfg.hidden_size
+    cols = hc.phi_columns(n)
+    lean = jnp.concatenate([jnp.zeros((2 * n,)), jnp.eye(n).reshape(-1)])
+    out = {}
+    for at, sub in enumerate(("attn", "ffn")):
+        k_phi, k_b, k_a = jax.random.split(jax.random.fold_in(rng, at), 3)
+        out[f"hc_{sub}_phi"] = jax.random.normal(
+            k_phi, (layers, nd_, cols), jnp.float32) * nd_ ** -0.5
+        out[f"hc_{sub}_b"] = lean + 0.5 * jax.random.normal(
+            k_b, (layers, cols), jnp.float32)
+        out[f"hc_{sub}_a"] = 0.5 + 0.1 * jax.random.normal(
+            k_a, (layers, 3), jnp.float32)
+    return out
 
 
 def _rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array):
@@ -236,16 +327,70 @@ def _rope_interleaved(x: jax.Array, cos: jax.Array, sin: jax.Array):
     return apply_rope(x, cos, sin)
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rope_tables(cfg: ModelConfig, positions: jax.Array):
+    """(cos, sin) [B, T, rope/2]. Plain rope, or YaRN as HF computes it
+    (``_compute_yarn_parameters``): every frequency a blend of the published
+    one and the one interpolated by ``factor``, by where its wavelength
+    lies between ``beta_fast`` and ``beta_slow`` rotations over the original
+    context; cos and sin times ``mscale / mscale_all_dim``'s factors."""
+    dim, theta, ys = cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_scaling
+    if ys is None:
+        return _rope_cos_sin(positions, dim, theta)
+
+    def correction_dim(rotations):
+        return dim * math.log(ys.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(ys.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(ys.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0, 1)
+    pos_freqs = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inv_freq = (1.0 / (ys.factor * pos_freqs)) * ramp \
+        + (1.0 / pos_freqs) * (1 - ramp)
+    if ys.mscale and ys.mscale_all_dim:
+        amp = _yarn_mscale(ys.factor, ys.mscale) \
+            / _yarn_mscale(ys.factor, ys.mscale_all_dim)
+    else:
+        amp = _yarn_mscale(ys.factor, 1.0)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return (cos, sin) if amp == 1.0 else (cos * amp, sin * amp)
+
+
+def _softmax_scale(cfg: ModelConfig) -> float:
+    """``(nope + rope)^-0.5``, times YaRN's ``mscale^2`` of
+    ``mscale_all_dim`` where the config gives one."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    ys = cfg.rope_scaling
+    if ys is not None and ys.mscale_all_dim:
+        scale *= _yarn_mscale(ys.factor, ys.mscale_all_dim) ** 2
+    return scale
+
+
 def _attention(cfg, rope, positions, chunk_lens, hidden, lp, view, layer):
-    """Pre-norm latent attention; returns (hidden + out, the tokens' rows
-    [1, B, T, W] in pool layout)."""
+    """Pre-norm latent attention of ``hidden`` (the residual, or what the
+    stream mix hands over); returns (its branch [B, T, D], the tokens' rows
+    [B, T, 1, W])."""
     b, t, _ = hidden.shape
     h, nope, dr = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rank = cfg.kv_lora_rank
     width = LatentKVSpec(rank, dr).width
     with jax.named_scope("attn_proj"):
         x = rms_norm(hidden, lp["attn_norm"], cfg.rms_norm_eps)
-        q = (x @ lp["wq"]).reshape(b, t, h, nope + dr)
+        if cfg.q_lora_rank:
+            q = rms_norm(x @ lp["wq_a"], lp["q_norm"], cfg.rms_norm_eps) \
+                @ lp["wq_b"]
+        else:
+            q = x @ lp["wq"]
+        q = q.reshape(b, t, h, nope + dr)
         ckr = x @ lp["w_kva"]                                # [B, T, rank+dr]
         c = rms_norm(ckr[..., :rank], lp["kv_norm"], cfg.rms_norm_eps)
         k_r = _rope_interleaved(ckr[..., None, rank:], *rope)    # [B,T,1,dr]
@@ -260,22 +405,30 @@ def _attention(cfg, rope, positions, chunk_lens, hidden, lp, view, layer):
             [c[:, :, None], k_r, jnp.zeros((b, t, 1, pad), c.dtype)], axis=-1)
     with jax.named_scope("attn_core"):
         attn = attend(q_row, row, None, positions, chunk_lens, view, layer,
-                      scale=(nope + dr) ** -0.5, value_dim=rank)
+                      scale=_softmax_scale(cfg), value_dim=rank)
     with jax.named_scope("attn_proj"):
         o = jnp.einsum("bthr,hrv->bthv", attn, lp["w_uv"],
                        preferred_element_type=jnp.float32).astype(attn.dtype)
-        hidden = hidden + o.reshape(b, t, h * cfg.v_head_dim) @ lp["wo"]
-    return hidden, row.transpose(2, 0, 1, 3)
+        branch = o.reshape(b, t, h * cfg.v_head_dim) @ lp["wo"]
+    return branch, row
 
 
 def _gated_ffn(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def _dense_ffn(cfg, hidden, lp):
+    """The branch of a leading layer's FFN, pre-norm."""
+    with jax.named_scope("ffn"):
+        x = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps)
+        return _gated_ffn(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+
+
 def _sparse_ffn(cfg, hidden, lp, experts, group_base, valid, interpret):
-    """``hidden + routed + shared`` of one sparse layer; ``experts`` are the
-    WHOLE stacks (w_gate_up [n_sparse*E, D, 2F], w_down [n_sparse*E, F, D])
-    and ``group_base`` this layer's first group in them."""
+    """The branch of one sparse layer as its two parts (shared, routed);
+    ``experts`` are the WHOLE stacks (w_gate_up [n_sparse*E, D, 2F], w_down
+    [n_sparse*E, F, D]) and ``group_base`` this layer's first group in
+    them."""
     b, t, d = hidden.shape
     with jax.named_scope("ffn"):
         x = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps)
@@ -288,8 +441,39 @@ def _sparse_ffn(cfg, hidden, lp, experts, group_base, valid, interpret):
             interpret=interpret)
         with jax.named_scope("moe_shared"):
             shared = _gated_ffn(x, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
-        out = hidden + shared + routed.reshape(b, t, d).astype(hidden.dtype)
-    return out, stats, idx
+        routed = routed.reshape(b, t, d).astype(hidden.dtype)
+    return (shared, routed), stats, idx
+
+
+# A sublayer's outer device scope, by the name its mix's leaves carry.
+_SCOPE_OF = {"attn": "attn_proj", "ffn": "ffn"}
+
+
+def _sublayer(cfg, sub, x, lp, branch_of):
+    """The residual ``x`` after one sublayer (``sub``: ``attn`` / ``ffn``),
+    and what ``branch_of`` returns beside its branch. ``branch_of(h) -> (the
+    branch's parts, *rest)``. ``x`` is the plain residual [B, T, D]
+    (``hc_mult`` 1: the parts are added to it, in order) or the streams
+    [n, B, T, D], mixed by the layer's ``hc_<sub>_*`` leaves inside the
+    sublayer's own outer scope."""
+    scope = _SCOPE_OF[sub]
+    if cfg.hc_mult == 1:
+        parts, *rest = branch_of(x)
+        with jax.named_scope(scope):
+            for part in parts:
+                x = x + part
+        return (x, *rest)
+    with jax.named_scope(scope), jax.named_scope("hc_pre"):
+        h_pre, h_post, h_res = hc.mix_matrices(
+            x, lp[f"hc_{sub}_phi"], lp[f"hc_{sub}_b"], lp[f"hc_{sub}_a"],
+            iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            norm_eps=cfg.rms_norm_eps, clamp=cfg.hc_res_clamp)
+        h = hc.pre(x, h_pre).astype(x.dtype)
+    parts, *rest = branch_of(h)
+    with jax.named_scope(scope), jax.named_scope("hc_post"):
+        branch = sum(part.astype(jnp.float32) for part in parts)
+        x = hc.post(x, branch, h_post, h_res).astype(x.dtype)
+    return (x, *rest)
 
 
 def forward(
@@ -317,7 +501,10 @@ def forward(
     with jax.named_scope("embed"):
         hidden = params["embed"][token_ids]
         hidden = hidden.astype(view.act_dtype(params["embed"].dtype))
-    rope = _rope_cos_sin(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+        if cfg.hc_mult > 1:
+            # The streams enter as copies of the embedding.
+            hidden = jnp.broadcast_to(hidden, (cfg.hc_mult, *hidden.shape))
+    rope = _rope_tables(cfg, positions)
     valid = jnp.arange(t, dtype=jnp.int32)[None, :] < chunk_lens[:, None]
     dense, sparse = params["layers"]["dense"], params["layers"]["sparse"]
     experts = tuple(
@@ -338,31 +525,54 @@ def forward(
             win_k=pick(view.win_k), win_v=None, ring_k=pick(view.ring_k),
             ring_v=None), (at if view.pool_k is not None else None)
 
-    rows = []
-    for i in range(nd):
+    def attention(hidden, lp, at):
+        """(the residual after the layer's attention, the tokens' rows
+        [1, B, T, W] in pool layout)."""
+        def branch_of(h):
+            branch, row = _attention(cfg, rope, positions, chunk_lens, h, lp,
+                                     *view_of(at))
+            return (branch,), row
+        hidden, row = _sublayer(cfg, "attn", hidden, lp, branch_of)
+        return hidden, row.transpose(2, 0, 1, 3)
+
+    def dense_layer(hidden, i):
         lp = layer_of(dense, i)
-        hidden, row = _attention(cfg, rope, positions, chunk_lens, hidden,
-                                 lp, *view_of(jnp.int32(i)))
-        with jax.named_scope("ffn"):
-            x = rms_norm(hidden, lp["mlp_norm"], cfg.rms_norm_eps)
-            hidden = hidden + _gated_ffn(x, lp["w_gate"], lp["w_up"],
-                                         lp["w_down"])
-        rows.append(row)
+        hidden, row = attention(hidden, lp, i)
+        hidden, = _sublayer(cfg, "ffn", hidden, lp,
+                            lambda h: ((_dense_ffn(cfg, h, lp),),))
+        return hidden, row
+
+    if nd > 1:
+        # A scan of their own: one copy of a dense layer's code.
+        hidden, dense_rows = jax.lax.scan(
+            dense_layer, hidden, jnp.arange(nd, dtype=jnp.int32))
+        rows = [dense_rows[:, 0]]
+    else:
+        rows = []
+        for i in range(nd):
+            hidden, row = dense_layer(hidden, jnp.int32(i))
+            rows.append(row)
 
     def step(carry, i):
         hidden, stats = carry
         lp = layer_of(rest, i)
-        hidden, row = _attention(cfg, rope, positions, chunk_lens, hidden,
-                                 lp, *view_of(nd + i))
-        hidden, st, idx = _sparse_ffn(cfg, hidden, lp, experts,
-                                      i * cfg.n_routed_experts, valid,
-                                      view.interpret)
+        hidden, row = attention(hidden, lp, nd + i)
+        hidden, st, idx = _sublayer(
+            cfg, "ffn", hidden, lp,
+            lambda h: _sparse_ffn(cfg, h, lp, experts,
+                                  i * cfg.n_routed_experts, valid,
+                                  view.interpret))
         return (hidden, stats + st), (row, idx if routing else None)
 
     (hidden, stats), (sparse_rows, chosen) = jax.lax.scan(
         step, (hidden, jnp.zeros((len(FORWARD_STATS),), jnp.int32)),
         jnp.arange(ns, dtype=jnp.int32))
     k_new = jnp.concatenate([*rows, sparse_rows[:, 0]], axis=0)[:, None]
+    if cfg.hc_mult > 1:
+        # ... and leave as their sum.
+        with jax.named_scope("logits"), jax.named_scope("hc_head"):
+            hidden = jnp.sum(hidden.astype(jnp.float32), axis=0).astype(
+                hidden.dtype)
     hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
     out = (hidden, k_new, k_new[..., :0], stats)
     return out + (chosen,) if routing else out

@@ -111,6 +111,12 @@ def load_hf_params(
                 continue
             ours, transpose = mapped
             t = tensor.T if transpose else tensor
+            if nl <= layer_idx < nl + cfg.num_nextn_predict_layers:
+                # Published next-token-prediction layers lie behind the
+                # last layer and are not served (models/deepseek_v3.py).
+                logger.debug("Skipping next-token-prediction tensor %s",
+                             hf_name)
+                continue
             if layer_idx >= nl:
                 raise ValueError(
                     f"Checkpoint tensor {hf_name} indexes layer {layer_idx} "

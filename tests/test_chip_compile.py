@@ -607,7 +607,8 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
 
 @pytest.mark.parametrize("name,families,in_place", [
     ("qwen2.5-3b", 8, True), ("mistral-7b-d16", 8, True),
-    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 14, False)])
+    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 14, False),
+    ("xing4.0-29b-a4b-d7", 14, False)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place):
     """One prefill family a (rows, t) where the history is read in place
@@ -625,6 +626,58 @@ def test_prefill_family_counts_of_the_deployments(v5e, name, families,
     assert {f[3] for f in fams} == ({False} if in_place else {False, True})
     assert r.prefill_window_blocks == (
         1 << 30 if in_place else r.num_kv_blocks)
+
+
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128"])
+def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
+    """The decode and the fullest prefill program of xing4.0-29b-a4b-d7's
+    envelope (deployment.json's flags, published widths, 2 dense + 5 sparse
+    layers with all 64 experts, a residual of 4 streams) compile for a v5e,
+    fit its HBM beside 9.85 GB of weights and the 2.35 GB latent pool, copy
+    neither the pool nor the experts' stacks, hold the Mosaic kernels (the
+    latent decode kernel in the dense layers' scan and in the sparse one,
+    the two grouped matmuls) and the stream mix under its scopes, with the
+    Sinkhorn iterations as loops (a program with them unrolled was six
+    times the instructions and did not fit the compile cache's cap with
+    its 47 siblings: PERF.md section 6, PR 38)."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "xing4.0-29b-a4b-d7")
+    assert r.kv_k.shape == (7, 1, 16384 * 16, 640)
+    assert r.kv_v.shape == (7, 1, 16384 * 16, 0)
+    assert r.residual_report() == {"hc_mult": 4, "hc_mix": "xla"}
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    sparse = aparams["layers"]["sparse"]
+    assert sparse["w_gate_up"].shape == (5, 64, 3584, 2048)
+    assert sparse["hc_attn_phi"].shape == (5, 14336, 24)
+    assert sparse["hc_attn_phi"].dtype == jnp.float32
+    decode = program.startswith("decode")
+    if decode:
+        lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
+    else:
+        lowered = r._lower_prefill(aparams, 8, 128, full_mb, True)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    experts = [jax.ShapeDtypeStruct((5 * 64, *sparse[k].shape[2:]),
+                                    jnp.bfloat16)
+               for k in ("w_gate_up", "we_down")]
+    assert pool_copies(text, [r.kv_k, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (4 if decode else 2)
+    assert ("paged_flash_decode_latent_stats" in text) == decode
+    for scope in ("attn_proj/hc_pre", "ffn/hc_pre", "attn_proj/hc_post",
+                  "ffn/hc_post", "logits/hc_head"):
+        assert scope in text, scope
+    # Four sublayers' code (two scans of two), each with its Sinkhorn loop
+    # (2 iterations a trip): a third of the unrolled program's 26.6k.
+    assert len(re.findall(r"= \S+ \w[\w-]*\(", text)) < 12_000
+    mem = compiled.memory_analysis()
+    assert 12.1e9 < mem.argument_size_in_bytes < 12.3e9
+    assert mem.temp_size_in_bytes < (0.2e9 if decode else 0.7e9)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def test_latent_prefill_program_is_the_parents_on_v5e(v5e):

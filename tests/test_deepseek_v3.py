@@ -348,17 +348,26 @@ def test_a_kv_model_is_refused_nothing():
 
 
 @pytest.mark.parametrize("key,value", [
-    ("q_lora_rank", 1536), ("rope_scaling", {"type": "yarn", "factor": 40}),
-    ("n_group", 8), ("scoring_func", "softmax"), ("topk_method", "greedy"),
-    ("rope_interleave", False), ("attention_bias", True),
+    ("rope_scaling", {"type": "linear", "factor": 4}),
+    ("rope_scaling", {"rope_type": "dynamic", "factor": 2}),
+    ("n_group", 8), ("topk_group", 4), ("scoring_func", "softmax"),
+    ("topk_method", "greedy"), ("rope_interleave", False),
+    ("attention_bias", True), ("moe_layer_freq", 2), ("hidden_act", "gelu"),
 ])
 def test_what_the_module_does_not_implement_is_refused_by_its_key(key, value):
+    """Served since the module learnt them, and so no longer here: a
+    low-rank query (``q_lora_rank``) and YaRN (``rope_scaling`` of type
+    ``yarn``); tests/test_xing4.py holds both to HF's own modeling code."""
     import json
 
     with open(os.path.join(ROOT, "benchmarks", "chip", "configs",
                            "kanana-2-30b-a3b-d8", "config.json")) as f:
         cfg = json.load(f)
     assert ModelConfig.from_hf_config(cfg).arch == "deepseek_v3"
+    served = ModelConfig.from_hf_config(dict(cfg, q_lora_rank=1536, rope_scaling={
+        "type": "yarn", "factor": 40,
+        "original_max_position_embeddings": 4096}))
+    assert served.q_lora_rank == 1536 and served.rope_scaling.factor == 40
     cfg[key] = value
     with pytest.raises(ValueError, match="deepseek_v3: not supported"):
         ModelConfig.from_hf_config(cfg)

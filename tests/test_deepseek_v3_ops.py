@@ -168,8 +168,9 @@ def test_grouped_matmul_tiles():
 # ---- absorbed attention is the expanded form -------------------------------------
 def test_absorbed_attention_equals_the_expanded_form():
     """``models/deepseek_v3.py:_attention`` (queries carried into the latent
-    space, scores and values over the cached ROW) against the reference's
-    expanded keys and values of every head, one layer, one sequence."""
+    space, scores and values over the cached ROW; it returns its BRANCH, the
+    residual is its caller's) against the reference's expanded keys and
+    values of every head, one layer, one sequence."""
     mc = TINY_DEEPSEEK_V3
     params = ds.init_params(mc, jax.random.PRNGKey(3), jnp.float32)
     lp = jax.tree.map(lambda x: x[0], params["layers"]["dense"])
@@ -186,12 +187,12 @@ def test_absorbed_attention_equals_the_expanded_form():
                "kv_lora_rank": mc.kv_lora_rank, "rope_theta": mc.rope_theta,
                "rms_norm_eps": mc.rms_norm_eps}
         x = ref.rms_norm(hidden[0], lp["attn_norm"], mc.rms_norm_eps)
-        want = hidden[0] + ref.attention(cfg, lp, x)
+        want = ref.attention(cfg, lp, x)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     # What goes to the cache: [c | k_r | zeros] of the pool's width.
     spec = LatentKVSpec(mc.kv_lora_rank, mc.qk_rope_head_dim)
-    assert row.shape == (1, 1, t, spec.width)
+    assert row.shape == (1, t, 1, spec.width)
     assert bool(jnp.all(row[..., mc.kv_lora_rank + mc.qk_rope_head_dim:] == 0))
 
 
