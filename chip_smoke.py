@@ -28,8 +28,9 @@ two decode shapes and prints, under ``timing``, µs a call, the least time the
 chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
 their ratio, the kernel's own roofline share. It is read by no metric.
 ``--prefill`` (alone, like ``--gdn``, ``--moe`` and ``--hc``) does the same for the
-prefill flash kernel at the benchmark's four prefill shapes, with
-``window_attention`` at the parent's window width beside it.
+prefill flash kernels at the benchmark's prefill shapes (four over K/V rows,
+nine over latent rows), with ``window_attention`` at the parent's window
+width beside it.
 
 This process never imports JAX: a chip belongs to one process at a time and
 the engine children need it (the kernel phase runs in a child of its own).
@@ -1011,22 +1012,38 @@ PREFILL_TIMING_SHAPES = [
      "hist_unit": 256, "chunk": (64, 256), "heads": 30, "kv_heads": 30,
      "window": 4096},
 ]
+# The same over LATENT rows, at the shape both latent configurations share
+# (kanana-2-30b-a3b-d8, xing4.0-29b-a4b-d7: 32 heads over one 640-lane row
+# a token, 576 of them the key, the first 512 the values, block 16) and
+# their three fullest rectangles at a token budget of 1024, each behind the
+# cached system prompt (64), a median prompt's earlier chunks (320) and a
+# long one's (2048). ``window``: the parent's window, pinned at the full
+# block table (PR 33 to PR 38).
+LATENT_PREFILL_TIMING_SHAPES = [
+    {"name": f"latent-{rows}x{t}-hist{hist}", "rows": rows, "t": t,
+     "hist": (hist, hist), "chunk": (t * 3 // 4, t), "heads": 32,
+     "row": 640, "key": 576, "values": 512, "window": 3072}
+    for rows, t in ((8, 128), (4, 256), (1, 1024))
+    for hist in (64, 320, 2048)
+]
 PREFILL_TIMING_CALLS = 64
 PREFILL_MAX_ABS_ERR = 2e-2     # bf16 outputs of unit-variance values
 
 
 def prefill_child(rehearse: bool) -> int:
-    """``--prefill``: the Pallas flash prefill kernel
-    (ops/pallas/paged_attention.py:paged_flash_prefill) alone on the chip at
-    the benchmark's prefill shapes (PREFILL_TIMING_SHAPES), checked once
-    against ``window_attention`` over the gathered history, then timed (calls
-    chained through the queries inside one program over the layers of one
-    pool) against the larger of the least times its bytes and its FLOPs
-    allow (what a call must do: each valid query against its row's history
-    and the chunk's keys up to itself; K/V of the history once, q, k, v and
-    the output of the chunk once), with ``window_attention`` over a
-    pre-gathered window of the PARENT's width beside it (its gather, once a
-    dispatch for every layer, is timed apart). Run by no benchmark cell."""
+    """``--prefill``: the Pallas flash prefill kernels
+    (ops/pallas/paged_attention.py:paged_flash_prefill over K/V rows,
+    paged_flash_prefill_latent over latent rows) alone on the chip at the
+    benchmark's prefill shapes (PREFILL_TIMING_SHAPES,
+    LATENT_PREFILL_TIMING_SHAPES), checked once against ``window_attention``
+    over the gathered history, then timed (calls chained through the queries
+    inside one program over the layers of one pool) against the larger of
+    the least times its bytes and its FLOPs allow (what a call must do: each
+    valid query against its row's history and the chunk's keys up to itself;
+    the history's rows once, the chunk's operands and the output once), with
+    ``window_attention`` over a pre-gathered window of the PARENT's width
+    beside it (its gather, once a dispatch for every layer, is timed apart).
+    Run by no benchmark cell."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1035,6 +1052,7 @@ def prefill_child(rehearse: bool) -> int:
                                                     window_attention)
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_prefill,
+        paged_flash_prefill_latent,
     )
 
     dev = jax.devices()[0]
@@ -1050,13 +1068,15 @@ def prefill_child(rehearse: bool) -> int:
     calls = 2 if rehearse else PREFILL_TIMING_CALLS
 
     timing, checks, ok = [], [], True
-    for shape in PREFILL_TIMING_SHAPES:
+    for shape in PREFILL_TIMING_SHAPES + LATENT_PREFILL_TIMING_SHAPES:
+        latent = "row" in shape
         if rehearse:
             shape = {**shape, "rows": min(shape["rows"], 2), "t": 32,
                      "hist": (5, 40), "hist_unit": 1, "chunk": (7, 32),
-                     "heads": 4, "kv_heads": 2, "window": 64}
+                     "heads": 16 if latent else 4, "kv_heads": 2,
+                     "window": 64, "row": 256, "key": 192, "values": 128}
         rng = np.random.default_rng(len(timing))
-        b, t, h, hkv = (shape[k] for k in ("rows", "t", "heads", "kv_heads"))
+        b, t, h = (shape[k] for k in ("rows", "t", "heads"))
         hist = rng.integers(shape["hist"][0], shape["hist"][1] + 1, b) \
             * shape.get("hist_unit", 1)
         clen = rng.integers(shape["chunk"][0], shape["chunk"][1] + 1, b)
@@ -1072,38 +1092,80 @@ def prefill_child(rehearse: bool) -> int:
             tables[i, :live[i]] = order[at:at + live[i]]
             at += live[i]
         keys = jax.random.split(jax.random.PRNGKey(b), 5)
-        pool = (layers, hkv, (1 + int(live.sum())) * bs, dh)
-        q = jax.random.normal(keys[0], (b, t, h, dh), jnp.bfloat16)
-        k = jax.random.normal(keys[1], (b, t, hkv, dh), jnp.bfloat16)
-        v = jax.random.normal(keys[2], (b, t, hkv, dh), jnp.bfloat16)
-        k_pool = jax.random.normal(keys[3], pool, jnp.bfloat16)
-        v_pool = jax.random.normal(keys[4], pool, jnp.bfloat16)
+        slots = (1 + int(live.sum())) * bs
         tables = jnp.asarray(tables)
         kv_lens = jnp.asarray(hist, jnp.int32)
         chunk_lens = jnp.asarray(clen, jnp.int32)
         positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        # What a call must do: a valid query i of a row against its history
+        # and chunk keys 0..i, two products a (query, key, head).
+        pairs = int(np.sum(clen * hist + clen * (clen + 1) // 2))
+
+        def normal(key, *dims):
+            return jax.random.normal(key, dims, jnp.bfloat16)
 
         # The pools and the window are ARGUMENTS of the timed programs: a
         # closed-over array is a constant of the program, and the hybrid's
         # window (1 GB each of K and V) as a constant took the host's
         # 40 GiB in the compiler (my chip run, PR 35).
-        def kernel(q, layer, k_pool, v_pool):
-            return paged_flash_prefill(
-                q, k, v, positions, chunk_lens, k_pool, v_pool, tables,
-                kv_lens, layer, block_size=bs, interpret=interpret)
+        if latent:
+            w, dv = shape["row"], shape["values"]
+            scale = 192 ** -0.5    # 128 + 64 lanes a head before absorption
+            q = normal(keys[0], b, t, h, w)
+            rows = normal(keys[1], b, t, 1, w)
+            pools = (normal(keys[3], layers, 1, slots, w),)
 
-        gather = jax.jit(lambda kp, vp: gather_window(kp, vp, tables, bs))
-        win_k, win_v = gather(k_pool, v_pool)
+            def kernel(q, layer, pool):
+                return paged_flash_prefill_latent(
+                    q, rows, positions, chunk_lens, pool, tables, kv_lens,
+                    layer, block_size=bs, value_dim=dv, scale=scale,
+                    interpret=interpret)
 
-        def window(q, layer, win_k, win_v):
-            return window_attention(
-                q, k, v, positions, chunk_lens,
-                jax.lax.dynamic_index_in_dim(win_k, layer, 0, False),
-                jax.lax.dynamic_index_in_dim(win_v, layer, 0, False),
-                kv_lens)
+            gather = jax.jit(lambda pool: gather_window(
+                pool, pool[..., :0], tables, bs)[:1])
 
-        got = jax.jit(kernel)(q, 1, k_pool, v_pool).astype(jnp.float32)
-        want = jax.jit(window)(q, 1, win_k, win_v).astype(jnp.float32)
+            # As ops/attention.py:_attend_latent calls it over a window.
+            def window(q, layer, win):
+                win = jax.lax.dynamic_index_in_dim(win, layer, 0, False)
+                return window_attention(
+                    q, rows, rows, positions, chunk_lens, win, win, kv_lens,
+                    scale=scale, qblock=max(16, 2048 // h))[..., :dv]
+
+            # Scores over the key's lanes, values over theirs; a pool row
+            # is read whole, padding included.
+            flops = 2 * pairs * h * (shape["key"] + dv)
+            nbytes = 2 * (int(hist.sum()) * w
+                          + int(clen.sum()) * (h * w + w + h * dv))
+        else:
+            hkv = shape["kv_heads"]
+            q = normal(keys[0], b, t, h, dh)
+            k = normal(keys[1], b, t, hkv, dh)
+            v = normal(keys[2], b, t, hkv, dh)
+            pools = (normal(keys[3], layers, hkv, slots, dh),
+                     normal(keys[4], layers, hkv, slots, dh))
+
+            def kernel(q, layer, k_pool, v_pool):
+                return paged_flash_prefill(
+                    q, k, v, positions, chunk_lens, k_pool, v_pool, tables,
+                    kv_lens, layer, block_size=bs, interpret=interpret)
+
+            gather = jax.jit(
+                lambda kp, vp: gather_window(kp, vp, tables, bs))
+
+            def window(q, layer, win_k, win_v):
+                return window_attention(
+                    q, k, v, positions, chunk_lens,
+                    jax.lax.dynamic_index_in_dim(win_k, layer, 0, False),
+                    jax.lax.dynamic_index_in_dim(win_v, layer, 0, False),
+                    kv_lens)
+
+            flops = 4 * pairs * h * dh
+            nbytes = 2 * (int(hist.sum()) * hkv * dh * 2
+                          + int(clen.sum()) * (2 * h + 2 * hkv) * dh)
+        wins = gather(*pools)
+
+        got = jax.jit(kernel)(q, 1, *pools).astype(jnp.float32)
+        want = jax.jit(window)(q, 1, *wins).astype(jnp.float32)
         valid = (jnp.arange(t)[None] < chunk_lens[:, None])[..., None, None]
         err = float(jnp.max(jnp.where(valid, jnp.abs(got - want), 0.0)))
         finite = bool(jnp.all(jnp.isfinite(got)))
@@ -1117,12 +1179,6 @@ def prefill_child(rehearse: bool) -> int:
                     0, calls, lambda i, x: fn(x, i % layers, *held), q)
             return jax.jit(run)
 
-        # What a call must do: a valid query i of a row against its history
-        # and chunk keys 0..i, two products of Dh a (query, key, head).
-        pairs = int(np.sum(clen * hist + clen * (clen + 1) // 2))
-        flops = 4 * pairs * h * dh
-        nbytes = 2 * (int(hist.sum()) * hkv * dh * 2
-                      + int(clen.sum()) * (2 * h + 2 * hkv) * dh)
         entry = {"shape": shape["name"], "rows": b, "t": t,
                  "history": [int(x) for x in hist],
                  "chunk_lens": [int(x) for x in clen],
@@ -1130,9 +1186,17 @@ def prefill_child(rehearse: bool) -> int:
                  "least_us": None, "roofline_pct": None,
                  "window_attention_us": None, "window_keys": mb * bs,
                  "window_gather_us_per_layer": None}
-        sec = best_of(chained(kernel), (q, k_pool, v_pool), calls)
-        sec_win = best_of(chained(window), (q, win_k, win_v), calls)
-        sec_gather = best_of(gather, (k_pool, v_pool), layers)
+        if latent:
+            # The output is narrower than the queries: a chained call
+            # feeds one token's back into them, in place.
+            dv = shape["values"]
+            chain = lambda fn: chained(      # noqa: E731
+                lambda x, *a: x.at[:1, :1, :, :dv].set(fn(x, *a)[:1, :1]))
+        else:
+            chain = chained
+        sec = best_of(chain(kernel), (q, *pools), calls)
+        sec_win = best_of(chain(window), (q, *wins), calls)
+        sec_gather = best_of(gather, pools, layers)
         if peak and not rehearse:
             least = max(nbytes / (peak["hbm_gbps"] * 1e9),
                         flops / (peak["bf16_tflops"] * 1e12))
@@ -1390,8 +1454,8 @@ def verdict(lines: list, chips: int, full_depth: int,
             if prog.get("gdn_step") == "xla" and not rehearsal:
                 faults.append(f"{name}: gdn_step is not the Pallas kernel")
             # The engine's own predicate, not a flag's name: a tp=4
-            # engine, an int8 pool or latent rows gather a window and say
-            # so (``prefill_reads_pool`` false, ``"xla"``).
+            # engine or an int8 pool gathers a window and says so
+            # (``prefill_reads_pool`` false, ``"xla"``).
             if prog.get("prefill_reads_pool") and not rehearsal \
                     and prog.get("prefill_attn") != "pallas":
                 faults.append(

@@ -3,8 +3,10 @@
 XLA discipline (the performance-critical part of the design — every item here
 was profiled on a v5e in round 1/2):
   * The paged KV pool is read IN PLACE by the Pallas kernels (paged decode;
-    a prefill chunk where ``prefill_reads_pool``) or, on the window paths,
-    gathered into a contiguous per-sequence WINDOW once per dispatch
+    a prefill chunk where ``prefill_reads_pool``: K/V rows and latent rows
+    alike, on one device in the compute dtype) or, on the window paths
+    (the window impl; a prefill over an int8 or sharded pool), gathered
+    into a contiguous per-sequence WINDOW once per dispatch
     (ops/attention.py:gather_window); new KV is written back once at the
     end. Per-layer gathers/scatters against the pool cost ~7 ms
     per decode step (XLA gathers run at ~15% of HBM bandwidth; pool xs/ys in
@@ -378,6 +380,10 @@ class ModelRunner:
         # operands and the writes below adapt to nothing but shapes.
         self.kv_pools = specs.kv_pools
         self.kv_v_dim = self.kv_spec.head_dim if specs.latent is None else 0
+        # Lanes of a token's row that are its values: the row of the second
+        # pool, or the head of a latent row (its compressed KV).
+        self.kv_value_dim = self.kv_spec.head_dim if specs.latent is None \
+            else specs.latent.rank
         # int32 counters the model's forward returns last, summed over its
         # layers (models/deepseek_v3.py: what the experts were given); a
         # dispatch sums them over its steps and hands them out beside its
@@ -1119,9 +1125,9 @@ class ModelRunner:
             n = budget // bytes_per_block
         else:
             # Paged decode never copies the pool, but chunked PREFILL still
-            # gathers a [rows, max_blocks] history window here (latent rows,
-            # an int8 pool, tp or sp > 1); reserve the worst-case bucketed
-            # prefill window out of the pool budget.
+            # gathers a [rows, max_blocks] history window here (an int8
+            # pool, tp or sp > 1, rows no prefill kernel tiles); reserve
+            # the worst-case bucketed prefill window out of the pool budget.
             reserve_bytes = min(
                 _bucket(prefill_row_cap(cfg), 1, max(1, cfg.max_num_seqs))
                 * _bucket(cfg.max_blocks_per_seq, 1,
@@ -1204,20 +1210,22 @@ class ModelRunner:
         window budget and the pool's window reserve all follow it, and it
         is ``attend``'s own predicate (ops/attention.py:
         prefill_kernel_covers) asked of EVERY chunk length this config can
-        dispatch: paged attention over K/V rows (latent rows have a path
-        of their own) in the compute dtype (no int8 scales) on a mesh of
-        one device (a sharded pool or a sequence-parallel chunk keeps its
-        gathered window), at a head width, block size and chunk buckets
-        the kernel tiles. Were the two ever to disagree, ``attend`` raises
-        while the program is traced, at warm-up; ``GET /debug/programs``
-        reports this beside what each prefill program holds."""
+        dispatch: paged attention over K/V rows in two pools or latent rows
+        in one (each has its kernel) in the compute dtype (no int8 scales)
+        on a mesh of one device (a sharded pool or a sequence-parallel
+        chunk keeps its gathered window), at a row width, head count,
+        block size and chunk buckets the kernel tiles. Were the two ever
+        to disagree, ``attend`` raises while the program is traced, at
+        warm-up; ``GET /debug/programs`` reports this beside what each
+        prefill program holds."""
         mc = self.model_config
         sharded = self.mesh.size > 1
-        return self.attn_impl == "paged" and self.kv_pools == 2 and all(
+        return self.attn_impl == "paged" and all(
             prefill_kernel_covers(
                 t, mc.num_heads, self.kv_spec.kv_heads,
-                self.kv_spec.head_dim, self.kv_spec.head_dim,
+                self.kv_spec.head_dim, self.kv_value_dim,
                 self.config.block_size, (self.dtype,),
+                latent=self.kv_pools == 1,
                 scales=self.kv_quantized, kv_sharded=sharded, ring=sharded)
             for t in self._prefill_t_buckets())
 
@@ -1239,21 +1247,24 @@ class ModelRunner:
 
     def _pins_prefill_window(self, rows: int, full_mb: int) -> bool:
         """Only where a history window is still gathered (not where
-        ``prefill_reads_pool``: the benchmark's hybrid reads its full
-        layers' pool in place since PR 35; its latent-row model does not).
-        A model that declares recurrent state, or whose paged rows are
-        latent rows, gathers its prefill history window at the full width
-        whatever the rows hold, where the window budget allows that many
-        blocks: ONE windowed family a (rows, t) instead of three. Its
-        cached rows are cheap to gather (K/V in a minority of layers: 0.19
-        GB a row at the benchmark's widths; a latent row a ninth of a K/V
-        row of heads: 0.03 GB); its prefill programs are twice a dense
-        model's size, and a deployment's programs have to fit the compile
-        cache's size cap together (PERF.md §6, PR 31: 78 programs of 3.4 MB
-        against 192 MiB evicted one another and every boot compiled
-        everything; PR 33: 70 of 3.6 MB did the same). What it costs a
-        latent model: its window attention contracts the whole window,
-        3072 keys where the history may be 64 (PERF.md §7, PR 33)."""
+        ``prefill_reads_pool``: every benchmark configuration reads its
+        pool in place, the hybrid's full layers since PR 35, the
+        latent-row models since PR 39; what is left is what the predicate
+        refuses: an int8 pool, tp or sp over 1 (where the engine serves the
+        model with them at all), heads or widths no kernel tiles). A model that declares recurrent state, or whose paged
+        rows are latent rows, then gathers its prefill history window at
+        the full width whatever the rows hold, where the window budget
+        allows that many blocks: ONE windowed family a (rows, t) instead
+        of three. Its cached rows are cheap to gather (K/V in a minority
+        of layers: 0.19 GB a row at the benchmark's widths; a latent row a
+        ninth of a K/V row of heads: 0.03 GB); its prefill programs are
+        twice a dense model's size, and a deployment's programs have to
+        fit the compile cache's size cap together (PERF.md §6, PR 31: 78
+        programs of 3.4 MB against 192 MiB evicted one another and every
+        boot compiled everything; PR 33: 70 of 3.6 MB did the same). What
+        it costs a latent model: its window attention contracts the whole
+        window, 3072 keys where the history may be 64 (PERF.md §6, PR 39:
+        half of a prefill dispatch at the benchmark's widths)."""
         return (bool(self.state_specs) or self.kv_pools == 1) and \
             rows * full_mb <= self.prefill_window_blocks
 

@@ -17,9 +17,9 @@ ops/pallas/paged_attention.py over the pool in place — flash decode (T ==
 1) and, in a program lowered for a TPU, flash prefill (T > 1: a chunk over
 its rows' history and itself) — and ``window_attention``, the statement of
 the chunk's attention, the kernels' oracle, and the path of everything the
-prefill kernel does not cover: a backend without the kernel, the window
-decode path, speculative verify (``chunk_bias``), the sequence-parallel
-ring, latent rows, an int8 or kv-head-sharded pool.
+prefill kernels do not cover: a backend without them, the window decode
+path, speculative verify (``chunk_bias``), the sequence-parallel ring, an
+int8 or kv-head-sharded pool, K/V or latent rows alike.
 
 The serving path's seam: the runner describes the KV a forward
 may read as one ``KVView``, a model module hands it unopened to ``attend``
@@ -421,34 +421,40 @@ def attend(
 
 def prefill_kernel_covers(
     t: int, num_heads: int, num_kv_heads: int, head_dim: int,
-    value_dim: int, block_size: int, dtypes, *,
+    value_dim: int, block_size: int, dtypes, *, latent: bool = False,
     scales: bool = False, kv_sharded: bool = False, ring: bool = False,
     chunk_bias: bool = False,
 ) -> bool:
-    """THE predicate: whether the Pallas flash prefill kernel covers a
-    chunk of ``t`` tokens over a view that holds the pool. Asked in two
-    places that therefore agree: the runner, of every chunk length it can
+    """THE predicate: whether a Pallas flash prefill kernel covers a chunk
+    of ``t`` tokens over a view that holds the pool. Asked in two places
+    that therefore agree: the runner, of every chunk length it can
     dispatch, before it builds the view (``prefill_reads_pool``: only then
     does a view hold the pool, and the window reserve, the scheduler's
     window budget and the windowed families go), and ``attend``, of the
-    operands it is handed. Covered: K and V rows of one width in ONE dtype
-    (``dtypes``: the chunk's and the pools'), no int8 scales, no kv-head
-    sharding, no ring, no ``chunk_bias``, and a head width, block size and
-    chunk length the kernel tiles (``supports_pallas_prefill``). Not
-    covered by choice, though the decode kernel covers them: an int8 pool
-    (its scales would ride as the decode kernel's do) and a
-    kv-head-sharded pool; no benchmark cell runs either."""
+    operands it is handed. Covered: ONE dtype (``dtypes``: the chunk's and
+    the pools'), no int8 scales, no kv-head sharding, no ring, no
+    ``chunk_bias``, and either K and V rows of one width in two pools at a
+    head width, block size and chunk length the kernel tiles
+    (``supports_pallas_prefill``), or ``latent`` rows: one pool of one row
+    a token, ``head_dim`` its width, the values its first ``value_dim``
+    lanes (``supports_latent_prefill``). Not covered by choice, though the
+    decode kernels cover them: an int8 pool (its scales would ride as the
+    decode kernel's do) and a kv-head-sharded pool; no benchmark cell runs
+    either."""
     from production_stack_tpu.ops.pallas.paged_attention import (
+        supports_latent_prefill,
         supports_pallas_prefill,
     )
 
     kinds = {jnp.dtype(d) for d in dtypes}
-    return (
-        not (scales or kv_sharded or ring or chunk_bias)
-        and len(kinds) == 1 and value_dim == head_dim
-        and supports_pallas_prefill(t, num_heads, num_kv_heads, head_dim,
-                                    kinds.pop().itemsize, block_size)
-    )
+    if scales or kv_sharded or ring or chunk_bias or len(kinds) != 1:
+        return False
+    itemsize = kinds.pop().itemsize
+    if latent:
+        return num_kv_heads == 1 and supports_latent_prefill(
+            t, num_heads, head_dim, value_dim, itemsize, block_size)
+    return value_dim == head_dim and supports_pallas_prefill(
+        t, num_heads, num_kv_heads, head_dim, itemsize, block_size)
 
 
 def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
@@ -502,8 +508,16 @@ def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
     def kernel(*args, interpret=False):
         return paged_flash_prefill(*args, block_size=bs, interpret=interpret)
 
-    args = (q, k, v, positions, chunk_lens, view.pool_k, view.pool_v,
-            view.block_tables, view.kv_lens, jnp.asarray(layer, jnp.int32))
+    return _kernel_or_gathered(
+        view, kernel, gathered, q, k, v, positions, chunk_lens, view.pool_k,
+        view.pool_v, view.block_tables, view.kv_lens,
+        jnp.asarray(layer, jnp.int32))
+
+
+def _kernel_or_gathered(view, kernel, gathered, *args):
+    """One algorithm, two executions (``_attend_chunk_over_pool``): the
+    kernel where the view says ``interpret`` or the program is lowered for
+    a TPU, the gathered oracle on any other backend."""
     if view.interpret:
         return kernel(*args, interpret=True)
     return jax.lax.platform_dependent(*args, tpu=kernel, default=gathered)
@@ -516,26 +530,42 @@ def prefill_attn_path(hlo_text: str):
     return "pallas" if "paged_flash_prefill" in hlo_text else "xla"
 
 
+def _latent_window_attention(q, rows, positions, chunk_lens, win, win_len,
+                             scale, value_dim, ring=None, ring_pos=None,
+                             chunk_bias=None):
+    """``window_attention`` with latent rows as keys AND values: the whole
+    row is contracted for the values too and the first ``value_dim`` lanes
+    of the result kept (a slice of the OUTPUT: slicing the window would
+    copy it, every layer)."""
+    return window_attention(
+        q, rows, rows, positions, chunk_lens, win, win, win_len,
+        ring, ring, ring_pos, scale=scale, chunk_bias=chunk_bias,
+        # Every head shares the one row, so a query block is H times its
+        # tokens tall: as many score rows a block as 8 query heads a KV
+        # head give at QBLOCK (the score tensor is the program's largest
+        # temporary: 4 GB at 8 rows x 3072 keys otherwise).
+        qblock=max(16, QBLOCK * 8 // q.shape[2]),
+    )[..., :value_dim]
+
+
 def _attend_latent(q, rows, positions, chunk_lens, view, layer, scale,
                    value_dim):
-    """``attend`` over latent rows: the same two paths with the rows as
-    keys AND values. The dense paths contract the whole row for the values
-    too and keep the first ``value_dim`` lanes of the result (a slice of the
-    OUTPUT: slicing the window would copy it, every layer); the kernel
-    slices its VMEM buffer, which is free."""
+    """``attend`` over latent rows: the same paths with the rows as keys
+    AND values. A view that holds the POOL goes to a kernel that reads it
+    in place and slices the values out of its VMEM buffer, which is free:
+    the latent prefill kernel for a chunk
+    (``_attend_latent_chunk_over_pool``), the decode kernel at T == 1. A
+    gathered WINDOW (what a view the prefill kernel does not cover is
+    handed: an int8 or sharded pool, a ring, ``chunk_bias``; and every
+    window-path decode) never reaches a kernel."""
     b, t, h, w = q.shape
     if view.pool_k is None:
-        return window_attention(
-            q, rows, rows, positions, chunk_lens,
-            view.win_k, view.win_k, view.win_len,
-            view.ring_k, view.ring_k, view.ring_pos,
-            scale=scale, chunk_bias=view.chunk_bias,
-            # Every head shares the one row, so a query block is H times
-            # its tokens tall: as many score rows a block as 8 query heads
-            # a KV head give at QBLOCK (the score tensor is the program's
-            # largest temporary: 4 GB at 8 rows x 3072 keys otherwise).
-            qblock=max(16, QBLOCK * 8 // h),
-        )[..., :value_dim]
+        return _latent_window_attention(
+            q, rows, positions, chunk_lens, view.win_k, view.win_len, scale,
+            value_dim, view.ring_k, view.ring_pos, view.chunk_bias)
+    if t > 1:
+        return _attend_latent_chunk_over_pool(
+            q, rows, positions, chunk_lens, view, layer, scale, value_dim)
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_decode_latent_stats,
     )
@@ -557,6 +587,50 @@ def _attend_latent(q, rows, positions, chunk_lens, view, layer, scale,
     attn = merge_attention_segments(
         out_p, m_p, l_p, out_d[..., :value_dim], m_d, l_d)
     return attn.reshape(b, t, h, value_dim)
+
+
+def _attend_latent_chunk_over_pool(q, rows, positions, chunk_lens, view,
+                                   layer, scale, value_dim):
+    """``_attend_chunk_over_pool`` over ONE pool of latent rows: the same
+    algorithm and two executions (ops/pallas/paged_attention.py:
+    paged_flash_prefill_latent, or this layer's rows gathered and
+    ``window_attention``), the same raise at trace time on a pool view the
+    kernel does not cover."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill_latent,
+    )
+
+    bs = view.block_size
+    b, t, h, w = q.shape
+    if not prefill_kernel_covers(
+            t, h, rows.shape[2], w, value_dim, bs,
+            (rows.dtype, view.pool_k.dtype), latent=True,
+            scales=view.k_scale is not None,
+            kv_sharded=view.tp_mesh is not None,
+            ring=view.ring_k is not None,
+            chunk_bias=view.chunk_bias is not None):
+        raise ValueError(
+            f"attend: a chunk of {t} tokens ({h} heads over latent rows of "
+            f"{w} lanes, values {value_dim}, {rows.dtype} over a "
+            f"{view.pool_k.dtype} pool, block {bs}) over a pool view the "
+            "prefill kernel does not cover "
+            "(ops/attention.py:prefill_kernel_covers): gather a window")
+
+    def gathered(q, rows, positions, chunk_lens, pool, tables, kv_lens,
+                 layer):
+        page = jax.lax.dynamic_index_in_dim(pool, layer, 0, False)
+        win = gather_kv_pages(page, tables, bs).astype(q.dtype)
+        return _latent_window_attention(
+            q, rows, positions, chunk_lens, win, kv_lens, scale, value_dim)
+
+    def kernel(*args, interpret=False):
+        return paged_flash_prefill_latent(
+            *args, block_size=bs, value_dim=value_dim, scale=scale,
+            interpret=interpret)
+
+    return _kernel_or_gathered(
+        view, kernel, gathered, q, rows, positions, chunk_lens, view.pool_k,
+        view.block_tables, view.kv_lens, jnp.asarray(layer, jnp.int32))
 
 
 def scan_layers(

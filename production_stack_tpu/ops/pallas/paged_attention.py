@@ -1,5 +1,5 @@
 """Pallas TPU flash kernels over the paged KV pool: decode (below) and
-prefill (the last section).
+prefill (the last two sections), each over K/V rows and over latent rows.
 
 The TPU-native replacement for the paged-attention CUDA kernels inside the
 reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
@@ -52,8 +52,9 @@ reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
 
 The decode kernels take T == 1: queries sit at position >= kv_len, so
 causality over the pool is exactly "attend to slots < kv_len" and no
-per-token causal mask is needed. A prefill chunk (T > 1) has a kernel of its
-own on the same machinery, ``paged_flash_prefill`` (the last section): the
+per-token causal mask is needed. A prefill chunk (T > 1) has kernels of its
+own on the same machinery, ``paged_flash_prefill`` over K/V rows and
+``paged_flash_prefill_latent`` over latent rows (the last two sections): the
 history from the pool up to the row's length, then the chunk causally.
 """
 
@@ -809,7 +810,12 @@ def paged_flash_decode_latent_stats(
 #
 # Scores, max, exp and sums are float32; both products take operands in the
 # pool's dtype with float32 accumulation; the output is q.dtype: what
-# ``ops/attention.py:window_attention`` computes, nothing lower.
+# ``ops/attention.py:window_attention`` computes, nothing lower. This
+# section's kernel reads K/V rows of two pools; latent rows (one pool, every
+# head the same row) have theirs in the next section, on the same tile
+# sequence, and ``ops/attention.py:prefill_kernel_covers`` says which views
+# either covers (neither: int8 scales, a sharded pool, a ring, a
+# ``chunk_bias``; those keep the gathered window).
 QUERY_BLOCK = 256    # chunk queries a program, chunk keys a tile
 PREFILL_VMEM_BYTES = 64 << 20   # of a v5e's 128 MiB: buffers 8, q and o
                                 # blocks twice 8, flash state 13, scores 16
@@ -1195,3 +1201,338 @@ def paged_flash_prefill(
     )
     out = out.reshape(b, hkv, nq, g, tq, dh).transpose(0, 2, 4, 1, 3, 5)
     return out.reshape(b, t, h, dh)
+
+
+# ------------------------------------------------------ prefill, latent rows
+# The chunk's attention over a LATENT pool (see "latent rows" above): the
+# prefill kernel with ONE pool and one superpage buffer a tile, every head
+# attending the same rows, the values the first ``value_dim`` lanes of the
+# buffer (a slice in VMEM, free, as in ``_latent_decode_kernel``). Absorbed
+# latent attention is multi-query attention, so what differs from the K/V
+# kernel follows from there being one KV head of a wide row:
+#
+#   * A program holds TQ queries of one row for ALL heads as ONE matmul
+#     operand, M = H x TQ rows (``prefill_tiles`` at Hkv 1 and the row's
+#     width: 32 queries of 32 heads at 640 lanes). No loop over heads, and
+#     q and the output keep their own layout [B, T, H, .]: a block's
+#     [TQ, H, W] is [TQ * H, W] with no data moved where the heads fill
+#     whole sublane tiles (``supports_latent_prefill``), so no transpose of
+#     either crosses HBM.
+#   * The chunk's key tiles are ``latent_chunk_tile`` keys wide, not TQ: at
+#     TQ 32 a tile of TQ keys would pay the flash state's round trip (M x
+#     value_dim float32) for 32 keys. A query block's tiles are its row's
+#     history superpages, then the chunk's tiles up to the one that holds
+#     its last query, each ONE copy out of the chunk's rows [1, B, T, W];
+#     tiles wholly above the diagonal are never fetched.
+#   * The buffers are keys AND values: cleared once a call, whole.
+
+
+def latent_chunk_tile(t: int, super_tokens: int) -> int:
+    """Keys a chunk tile of the latent prefill kernel holds: the chunk
+    whole, up to a superpage."""
+    return min(t, super_tokens)
+
+
+def supports_latent_prefill(t: int, num_heads: int, width: int,
+                            value_dim: int, itemsize: int,
+                            block_size: int) -> bool:
+    """What ``supports_pallas_prefill`` asks at one KV head of ``width``
+    lanes, values that are whole lanes of the row, heads that fill whole
+    sublane tiles of the dtype (a block's [TQ, H, W] is then [TQ * H, W] as
+    it lies) and a chunk of whole key tiles."""
+    if value_dim % LANES or value_dim > width \
+            or num_heads % (32 // itemsize) \
+            or not supports_pallas_prefill(t, num_heads, 1, width, itemsize,
+                                           block_size):
+        return False
+    sup, _ = prefill_tiles(t, num_heads, 1, width, itemsize, block_size)
+    return t % latent_chunk_tile(t, sup) == 0
+
+
+def _latent_prefill_kernel(
+    # scalar prefetch
+    layer_ref,          # SMEM [1] int32
+    block_tables_ref,   # SMEM [B, Mb] int32
+    kv_lens_ref,        # SMEM [B] int32: tokens of the row in the pool
+    chunk_lens_ref,     # SMEM [B] int32: valid tokens of the row's chunk
+    # inputs
+    q_ref,              # VMEM [1, TQ, H, W] (pre-scaled; zeros past the key)
+    posq_ref,           # VMEM [1, 1, TQ, 1] int32: the block's positions
+    posk_ref,           # VMEM [1, NK, 1, TK] int32: the row's, as rows
+    rows_hbm,           # HBM  [1, B, T, W]: the chunk's rows
+    kv_hbm,             # HBM  [L, 1, num_slots, W]
+    # output
+    o_ref,              # VMEM [1, TQ, H, Dv]
+    # scratch (outlives a program: the buffers are handed on)
+    kv_buf,             # VMEM [NUM_BUFS, 1, super_tokens, W]
+    sem,                # DMA sems (NUM_BUFS,)
+    fetched_ref,        # SMEM [1] int32: tiles the programs before fetched
+    m_ref,              # VMEM [H*TQ, 1] f32: running max
+    l_ref,              # VMEM [H*TQ, 1] f32: running sum
+    acc_ref,            # VMEM [H*TQ, Dv] f32
+    *,
+    block_size: int,
+    super_tokens: int,
+    tk: int,
+):
+    b, qb = pl.program_id(0), pl.program_id(1)
+    num_rows, nq = pl.num_programs(0), pl.num_programs(1)
+    layer = layer_ref[0]
+    bs, sup = block_size, super_tokens
+    spp = sup // bs                     # pages per superpage
+    gp = min(ISSUE_UNROLL, spp)
+    _, tq, h, w = q_ref.shape
+    dv = o_ref.shape[-1]
+    kv_len = kv_lens_ref[b]
+    chunk_len = chunk_lens_ref[b]
+
+    def hist_tiles(row):
+        return pl.cdiv(kv_lens_ref[row], sup)
+
+    def tiles_of(row, blk):
+        # History superpages, then the chunk's key tiles up to the one that
+        # holds the block's last query; none where its queries are all
+        # padding.
+        return jnp.where(blk * tq < chunk_lens_ref[row],
+                         hist_tiles(row) + pl.cdiv((blk + 1) * tq, tk), 0)
+
+    def pages_of(row, s):
+        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
+
+    n_hist = hist_tiles(b)
+    n_tiles = tiles_of(b, qb)
+    is_first = (b == 0) & (qb == 0)
+    first = jnp.where(is_first, 0, fetched_ref[0])
+    fetched_ref[0] = first + n_tiles
+
+    def start_tile(row, blk, s, n):
+        # Tile s of program (row, blk), the n-th of the call, goes in
+        # flight into buffer n % NUM_BUFS. A program with no tiles issues
+        # nothing.
+        slot = jax.lax.rem(n, NUM_BUFS)
+        nh = hist_tiles(row)
+        has = s < tiles_of(row, blk)
+
+        @pl.when(has & (s < nh))
+        def _():
+            # A history superpage: page-granular copies, as decode's.
+            pages = pages_of(row, s)
+
+            def issue(i):
+                src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
+                dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
+                pltpu.make_async_copy(
+                    kv_hbm.at[layer, :, src], kv_buf.at[slot, :, dst],
+                    sem.at[slot],
+                ).start()
+
+            def issue_group(gi, carry):
+                for j in range(gp):
+                    issue(gi * gp + j)
+                return carry
+
+            def issue_page(i, carry):
+                issue(i)
+                return carry
+
+            jax.lax.fori_loop(0, pages // gp, issue_group, 0)
+            jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
+
+        @pl.when(has & (s >= nh))
+        def _():
+            # A key tile of the chunk: contiguous, one copy.
+            src = pl.ds(pl.multiple_of((s - nh) * tk, tk), tk)
+            pltpu.make_async_copy(
+                rows_hbm.at[:, row, src], kv_buf.at[slot, :, pl.ds(0, tk)],
+                sem.at[slot],
+            ).start()
+
+    def wait_tile(s, slot):
+        # By bytes, in power-of-two runs of pages (see the decode kernel):
+        # a chunk tile counts as its TK / bs pages.
+        pages = jnp.where(s < n_hist, pages_of(b, s), tk // bs)
+        run = spp
+        while run:
+            @pl.when(pages & run != 0)
+            def _():
+                span = pl.ds(0, run * bs)
+                pltpu.make_async_copy(
+                    kv_hbm.at[0, :, span], kv_buf.at[slot, :, span],
+                    sem.at[slot],
+                ).wait()
+            run //= 2
+
+    # A masked key's weight (0) must not meet a non-finite value: the
+    # buffers are cleared once a call; what tiles leave behind is pool and
+    # chunk content, finite.
+    @pl.when(is_first)
+    def _():
+        kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
+
+    # The program before this one issued this one's first tile from its
+    # last iteration, unless it had none (or there is none before).
+    prev_row = jnp.where(qb > 0, b, jnp.maximum(b - 1, 0))
+    prev_blk = jnp.where(qb > 0, qb - 1, nq - 1)
+
+    @pl.when((n_tiles > 0) & (is_first | (tiles_of(prev_row, prev_blk) == 0)))
+    def _():
+        start_tile(b, qb, 0, first)
+
+    wraps = qb + 1 == nq
+    next_row = jnp.minimum(jnp.where(wraps, b + 1, b), num_rows - 1)
+    next_blk = jnp.where(wraps, 0, qb + 1)
+    has_next = jnp.logical_not(wraps) | (b + 1 < num_rows)
+
+    def flash_block(rows, mask):
+        # One tile's rows [keys, W] against every head's queries at once.
+        q = q_ref[0].reshape(tq * h, w)                      # [M, W]
+        scores = jax.lax.dot_general(
+            q, rows, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                    # [M, keys]
+        scores = jnp.where(mask, scores, _MASKED)
+        m_prev = m_ref[...]                                  # [M, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :dv],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    def tile(s, carry):
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
+        last = s + 1 == n_tiles
+
+        @pl.when(jnp.logical_not(last) | has_next)
+        def _():
+            start_tile(
+                jnp.where(last, next_row, b), jnp.where(last, next_blk, qb),
+                jnp.where(last, 0, s + 1), n + 1,
+            )
+
+        wait_tile(s, slot)
+
+        @pl.when(s < n_hist)
+        def _():
+            # History: every key below kv_len is before every query.
+            pos = s * sup + jax.lax.broadcasted_iota(jnp.int32, (1, sup), 1)
+            flash_block(kv_buf[slot, 0], pos < kv_len)
+
+        @pl.when(s >= n_hist)
+        def _():
+            # The chunk's key tile c, masked as window_attention masks it:
+            # key position <= query position, key index < chunk_len. A
+            # block's rows are (query, head), query-major.
+            c = s - n_hist
+            pos_q = jnp.broadcast_to(
+                posq_ref[0, 0][:, None], (tq, h, 1)).reshape(tq * h, 1)
+            idx = c * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
+            flash_block(kv_buf[slot, 0, pl.ds(0, tk), :],
+                        (posk_ref[0, c] <= pos_q) & (idx < chunk_len))
+
+        return carry
+
+    @pl.when(n_tiles > 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        jax.lax.fori_loop(0, n_tiles, tile, 0)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = out.reshape(tq, h, dv).astype(o_ref.dtype)
+
+    @pl.when(n_tiles == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "value_dim", "scale", "interpret"),
+)
+def paged_flash_prefill_latent(
+    q: jax.Array,             # [B, T, H, W] absorbed queries, zeros past the key
+    rows: jax.Array,          # [B, T, 1, W] the chunk's latent rows
+    positions: jax.Array,     # [B, T] int32 absolute position per token
+    chunk_lens: jax.Array,    # [B] int32 valid tokens per row
+    kv_pool: jax.Array,       # [L, 1, num_slots, W] latent rows
+    block_tables: jax.Array,  # [B, Mb] int32
+    kv_lens: jax.Array,       # [B] int32: the row's tokens in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    value_dim: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """``paged_flash_prefill`` over a latent pool: causal attention of a
+    chunk over its rows' history (pool slots below ``kv_lens``, read in
+    place) and over itself, every head against the same rows, keys the whole
+    row, values its first ``value_dim`` lanes: [B, T, H, value_dim] in
+    q.dtype, equal to ``window_attention`` over the gathered rows. What
+    ``paged_flash_prefill`` asks of positions, padded blocks and block
+    tables holds here; the pool must be finite wherever a live row's pages
+    reach, padding lanes included. See the section comment and
+    ``supports_latent_prefill``."""
+    b, t, h, w = q.shape
+    sup, tq = prefill_tiles(t, h, 1, w, kv_pool.dtype.itemsize, block_size)
+    tk = latent_chunk_tile(t, sup)
+    nq, nk, m = t // tq, t // tk, h * tq
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    # Scaled as window_attention scales.
+    qf = (q.astype(jnp.float32) * scale).astype(kv_pool.dtype)
+    chunk = rows.transpose(2, 0, 1, 3).astype(kv_pool.dtype)  # [1, B, T, W]
+    positions = positions.astype(jnp.int32)
+
+    kernel = functools.partial(
+        _latent_prefill_kernel, block_size=block_size, super_tokens=sup,
+        tk=tk,
+    )
+
+    def block(lanes):
+        return pl.BlockSpec((1, tq, h, lanes), lambda i, j, *_: (i, j, 0, 0),
+                            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((b, t, h, value_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, nq),
+            in_specs=[
+                block(w),
+                pl.BlockSpec((1, 1, tq, 1), lambda i, j, *_: (i, j, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, nk, 1, tk), lambda i, j, *_: (i, 0, 0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec(memory_space=pl.ANY),   # the chunk's rows and
+                pl.BlockSpec(memory_space=pl.ANY),   # the pool stay in HBM
+            ],
+            out_specs=block(value_dim),
+            scratch_shapes=[
+                pltpu.VMEM((NUM_BUFS, 1, sup, w), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((m, 1), jnp.float32),
+                pltpu.VMEM((m, 1), jnp.float32),
+                pltpu.VMEM((m, value_dim), jnp.float32),
+            ],
+        ),
+        # Programs run in order: each hands its buffers to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=PREFILL_VMEM_BYTES,
+        ),
+        name="paged_flash_prefill_latent",
+        interpret=interpret,
+    )(
+        layer, block_tables, kv_lens.astype(jnp.int32),
+        chunk_lens.astype(jnp.int32),
+        qf, positions.reshape(b, nq, tq, 1), positions.reshape(b, nk, 1, tk),
+        chunk, kv_pool,
+    )

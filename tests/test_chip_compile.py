@@ -318,6 +318,8 @@ def _described_runner(v5e, model_dir: str, **engine):
     r.kv_spec, r.state_specs = specs.paged_kv, specs.state
     r.kv_pools = specs.kv_pools
     r.kv_v_dim = specs.paged_kv.head_dim if specs.latent is None else 0
+    r.kv_value_dim = specs.paged_kv.head_dim if specs.latent is None \
+        else specs.latent.rank
     r.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))
     r.num_kv_blocks = cfg.num_kv_blocks
     r.num_state_slots = cfg.max_num_seqs + 1 if specs.state else 0
@@ -466,15 +468,15 @@ def test_grouped_matmul_compiles_for_v5e(v5e, pairs, k, n):
                 and " copy(" in ln]
 
 
-@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128-window"])
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128"])
 def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     """The decode and the fullest prefill program of kanana-2-30b-a3b-d8's
     envelope (deployment.json's flags, published widths, all 128 experts of
     7 sparse layers) compile for a v5e, fit its HBM beside 10.14 GB of
     weights and the 2.68 GB latent pool, copy neither the pool nor the
-    experts' stacks, and hold the Mosaic kernels: the latent decode kernel
-    (the dense layer's call and the sparse scan's) and the two grouped
-    matmuls of the scan."""
+    experts' stacks, and hold the Mosaic kernels: the latent decode or
+    prefill kernel (the dense layer's call and the sparse scan's) and the
+    two grouped matmuls of the scan."""
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops.kv_write import pool_copies
 
@@ -492,21 +494,22 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
-        lowered = r._lower_prefill(aparams, 8, 128, full_mb, True)
+        lowered = r._lower_prefill(aparams, 8, 128, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
     experts = [jax.ShapeDtypeStruct((7 * 128, *sparse[k].shape[2:]),
                                     jnp.bfloat16)
                for k in ("w_gate_up", "we_down")]
     assert pool_copies(text, [r.kv_k, *experts]) == []
-    assert text.count('custom_call_target="tpu_custom_call"') == \
-        (4 if decode else 2)
-    assert ("paged_flash_decode_latent_stats" in text) == decode
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert ("%paged_flash_decode_latent_stats" in text) == decode
+    assert ("%paged_flash_prefill_latent" in text) == (not decode)
     mem = compiled.memory_analysis()
     # Weights 10.14 GB and the pool 2.68 GB are arguments; a latent row
-    # costs a decode program no temporary of its own.
+    # costs a decode program no temporary of its own, and a prefill
+    # program no window and no score tensor (0.61 GB with them, PR 38).
     assert 12.8e9 < mem.argument_size_in_bytes < 12.9e9
-    assert mem.temp_size_in_bytes < (0.4e9 if decode else 1.0e9)
+    assert mem.temp_size_in_bytes < 0.4e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
@@ -607,16 +610,16 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
 
 @pytest.mark.parametrize("name,families,in_place", [
     ("qwen2.5-3b", 8, True), ("mistral-7b-d16", 8, True),
-    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 14, False),
-    ("xing4.0-29b-a4b-d7", 14, False)])
+    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 7, True),
+    ("xing4.0-29b-a4b-d7", 7, True)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place):
     """One prefill family a (rows, t) where the history is read in place
     (36 -> 9 at qwen2.5-3b's envelope, 32 -> 9, 18 -> 9, and 8 since PR
-    37's ladder: 1 x {128..2048}, 8 x {128, 256}, 16 x 128); latent rows
-    keep the family without a window and the pinned one (14: 1 x
-    {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger than
-    the token budget."""
+    37's ladder: 1 x {128..2048}, 8 x {128, 256}, 16 x 128); so it is for
+    latent rows since PR 39 (14, with and without the pinned window, -> 7:
+    1 x {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger
+    than the token budget."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
     fams = r.reachable_prefill_families()
@@ -635,8 +638,8 @@ def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     layers with all 64 experts, a residual of 4 streams) compile for a v5e,
     fit its HBM beside 9.85 GB of weights and the 2.35 GB latent pool, copy
     neither the pool nor the experts' stacks, hold the Mosaic kernels (the
-    latent decode kernel in the dense layers' scan and in the sparse one,
-    the two grouped matmuls) and the stream mix under its scopes, with the
+    latent decode or prefill kernel in the dense layers' scan and in the
+    sparse one, the two grouped matmuls) and the stream mix under its scopes, with the
     Sinkhorn iterations as loops (a program with them unrolled was six
     times the instructions and did not fit the compile cache's cap with
     its 47 siblings: PERF.md section 6, PR 38)."""
@@ -658,16 +661,16 @@ def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
-        lowered = r._lower_prefill(aparams, 8, 128, full_mb, True)
+        lowered = r._lower_prefill(aparams, 8, 128, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
     experts = [jax.ShapeDtypeStruct((5 * 64, *sparse[k].shape[2:]),
                                     jnp.bfloat16)
                for k in ("w_gate_up", "we_down")]
     assert pool_copies(text, [r.kv_k, *experts]) == []
-    assert text.count('custom_call_target="tpu_custom_call"') == \
-        (4 if decode else 2)
-    assert ("paged_flash_decode_latent_stats" in text) == decode
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert ("%paged_flash_decode_latent_stats" in text) == decode
+    assert ("%paged_flash_prefill_latent" in text) == (not decode)
     for scope in ("attn_proj/hc_pre", "ffn/hc_pre", "attn_proj/hc_post",
                   "ffn/hc_post", "logits/hc_head"):
         assert scope in text, scope
@@ -680,16 +683,25 @@ def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+def _without_source_locations(text: str) -> str:
+    """A compiled program's text without what moves with a line number:
+    metadata, the location tables, the Mosaic kernels' serialized bodies."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>", text)
+    return "\n".join(
+        ln for ln in text.splitlines() if not re.match(
+            r'^(\d+ ["{]|FileNames|FunctionNames|FileLocations|StackFrames)',
+            ln))
+
+
 def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
-    """kanana-2-30b-a3b-d8's fullest windowed prefill program ([8, 128]:
-    since PR 37 no rectangle exceeds the token budget, and [8, 512] is
-    gone) does not change with the kernel or with the ladder: its compiled
-    text, without source locations (metadata, the location tables, the
-    Mosaic kernels' serialized bodies, which carry line numbers), hashes
-    as PR 36's tree's does at that shape. A PR that changes this program
-    on purpose writes the new hash here."""
+    """kanana-2-30b-a3b-d8's fullest prefill program ([8, 128]) is pinned
+    by the hash of its compiled text without source locations: PR 36's
+    and PR 37's trees kept PR 35's windowed program; PR 39 replaced it on
+    purpose (the history read in place by ``paged_flash_prefill_latent``:
+    no window, no family with one) and wrote this hash. A PR that changes
+    this program on purpose writes the new hash here."""
     import hashlib
-    import re
 
     from production_stack_tpu.engine.runner import _bucket
 
@@ -697,12 +709,91 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     text = r._lower_prefill(
-        r._abstract_params(), 8, 128, full_mb, True).compile().as_text()
-    text = re.sub(r", metadata=\{[^}]*\}", "", text)
-    text = re.sub(r"[A-Za-z0-9+/=]{200,}", "<payload>", text)
-    body = "\n".join(
-        ln for ln in text.splitlines() if not re.match(
-            r'^(\d+ ["{]|FileNames|FunctionNames|FileLocations|StackFrames)',
-            ln))
+        r._abstract_params(), 8, 128, full_mb, False).compile().as_text()
+    body = _without_source_locations(text)
     assert hashlib.sha1(body.encode()).hexdigest() == \
-        "4fa76b19c60943eeacfe92e4acfc3cc5e1956023"
+        "2690042c18a0360e58e59a935efed7cfd8be75a0"
+
+
+# ---- prefill attention over latent rows: the flash kernel (PR 39)
+@pytest.mark.parametrize("rows,t", [(8, 128), (4, 256), (1, 1024), (1, 128)],
+                         ids=lambda x: str(x))
+def test_latent_prefill_kernel_compiles_for_v5e(v5e, rows, t):
+    """The latent prefill kernel alone at both latent deployments' shapes
+    (32 heads over ONE 640-lane row a token, values its first 512, block
+    16) and their fullest rectangles: Mosaic takes it (a block's [32
+    queries, 32 heads, 640] as [1024, 640] with no relayout, 64 MiB of
+    VMEM, the page copies), no transpose of q or of the output surrounds
+    it, and its device operation carries the prefill kernels' name, not
+    the decode kernels' (the benchmark counts decode steps by the prefix
+    ``paged_flash_decode``)."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill_latent,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = paged_flash_prefill_latent.lower(
+        sds((rows, t, 32, 640), jnp.bfloat16),
+        sds((rows, t, 1, 640), jnp.bfloat16),
+        sds((rows, t), jnp.int32), sds((rows,), jnp.int32),
+        sds((8, 1, NUM_SLOTS, 640), jnp.bfloat16),
+        sds((rows, 192), jnp.int32), sds((rows,), jnp.int32),
+        sds((1,), jnp.int32),
+        block_size=BLOCK_SIZE, value_dim=512, scale=192 ** -0.5).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_flash_prefill_latent" in text
+    assert "%paged_flash_decode" not in text      # an operation's name
+    assert not re.search(r"= bf16\[[\d,]+\]\S* (copy|transpose)\(", text)
+    assert compiled.out_info.shape == (rows, t, 32, 512)
+
+
+# (deployment, layers, the parent's temp_size_in_bytes of the windowed
+# [8, 128] program: PR 38's tree gathered 3072 keys a row and held the
+# float32 scores; measured at PR 39.)
+LATENT_PREFILL_PROGRAMS = {
+    "kanana-2-30b-a3b-d8": (8, 607_355_392),
+    "xing4.0-29b-a4b-d7": (7, 631_235_072),
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_PREFILL_PROGRAMS))
+def test_latent_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, name):
+    """The fullest prefill program of the two latent deployments, lowered
+    for a v5e as the engine lowers it: ONE family a (rows, t), its chunk
+    attends through the latent flash kernel over the pool
+    (``prefill_attn`` "pallas"), the pool is written in place, nothing of
+    a window's shape (3072 keys a row) is gathered, no float32 tensor of
+    the scores' shape exists, and its temporaries are far below the
+    parent's."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    layers, parent_temp = LATENT_PREFILL_PROGRAMS[name]
+    r = _deployment_runner(v5e, name)
+    assert r.prefill_reads_pool and r.kv_pools == 1
+    assert r.kv_k.shape[0] == layers and r.kv_value_dim == 512
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    assert full_mb * 16 == 3072
+    fams = [f for f in r.reachable_prefill_families() if f[:2] == (8, 128)]
+    assert fams == [(8, 128, full_mb, False)]
+    compiled = r._lower_prefill(r._abstract_params(), *fams[0]).compile()
+    text = compiled.as_text()
+    assert prefill_attn_path(text) == "pallas"
+    assert "%paged_flash_prefill_latent" in text
+    assert "%paged_flash_decode" not in text      # an operation's name
+    assert pool_copies(text, [r.kv_k]) == []
+    for dims in re.findall(r"[a-z]\w*\[([\d,]+)\]", text):
+        shape = tuple(int(x) for x in dims.split(","))
+        if shape == tuple(r.kv_k.shape):
+            continue
+        # A gathered window [.., 8 rows, 3072 keys, 640] or a score
+        # tensor [.., 3072 keys]: neither is there.
+        assert 3072 not in shape, shape
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temp / 2

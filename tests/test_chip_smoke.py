@@ -313,9 +313,10 @@ def test_gdn_phase_rehearses_on_the_cpu():
 
 
 def test_prefill_phase_rehearses_on_the_cpu():
-    """``--prefill --rehearse``: the prefill kernel alone at a toy size on
-    the CPU (interpret mode), checked against ``window_attention``; the
-    FLOPs and bytes it would be held to, and no time."""
+    """``--prefill --rehearse``: the prefill kernels alone (over K/V rows
+    and over latent rows) at a toy size on the CPU (interpret mode),
+    checked against ``window_attention``; the FLOPs and bytes they would
+    be held to, and no time."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--prefill",
          "--rehearse"], capture_output=True, text=True, timeout=600,
@@ -326,7 +327,10 @@ def test_prefill_phase_rehearses_on_the_cpu():
     assert line["phase"] == "prefill" and line["ok"] and line["interpret"]
     assert [t["shape"] for t in line["timing"]] == \
         ["chat-saturated", "agent-prefix", "hybrid-long-row",
-         "hybrid-rectangle"]
+         "hybrid-rectangle"] + [
+            f"latent-{rect}-hist{hist}"
+            for rect in ("8x128", "4x256", "1x1024")
+            for hist in (64, 320, 2048)]
     assert all(c["finite"] and c["max_abs_err"] <= c["bound"]
                for c in line["checks"])
     assert all(t["bytes"] > 0 and t["flops"] > 0

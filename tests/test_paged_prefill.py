@@ -313,7 +313,8 @@ def test_the_runner_asks_attends_predicate_of_every_chunk_bucket(
                                block_size=block_size, max_num_seqs=64,
                                max_prefill_seqs=None),
         model_config=SimpleNamespace(num_heads=16), attn_impl="paged",
-        kv_pools=2, kv_quantized=kv_quantized, dtype=jnp.bfloat16,
+        kv_pools=2, kv_value_dim=DH, kv_quantized=kv_quantized,
+        dtype=jnp.bfloat16,
         mesh=SimpleNamespace(size=devices),
         kv_spec=SimpleNamespace(kv_heads=2, head_dim=DH))
     r._prefill_t_buckets = lambda: ModelRunner._prefill_t_buckets(r)

@@ -284,13 +284,18 @@ def _deployment_flags(name):
     ("mistral-7b-d16.agent-prefix", True, 8, 9),
     ("qwen2.5-3b.chat-saturated", True, 8, 9),
     ("olmo-hybrid-7b-d16.chat-saturated", True, 8, 9),
-    # Latent rows gather a window, pinned at one width: each (rows, t)
-    # with and without it.
+    # Latent rows read their pool in place since PR 39: one program a
+    # (rows, t), where PR 38's tree had each with and without a window.
+    ("kanana-2-30b-a3b-d8.chat-saturated", True, 7, 14),
+    ("xing4.0-29b-a4b-d7.chat-saturated", True, 7, 14),
+    # The same envelope where the predicate refuses the pool view (a
+    # sharded or int8 latent deployment): a gathered window, pinned at one
+    # width, each (rows, t) with and without it.
     ("kanana-2-30b-a3b-d8.chat-saturated", False, 14, 14),
 ])
 def test_warm_up_enumerates_exactly_what_a_dispatch_can_run(
         cell, reads_pool, families, before):
-    """For the five cells' engine flags: every (rows, T) that
+    """For the six cells' engine flags: every (rows, T) that
     ``prefill_rectangle`` returns for n in 1..cap and any chunk length is
     a family of ``reachable_prefill_families``, with and without a window
     where one is gathered, and the enumeration holds nothing else."""

@@ -547,8 +547,9 @@ REGISTRY: Tuple[Series, ...] = (
            "Prefill admission passes (a dispatch, or a pass that "
            "scheduled nothing while requests waited) whose FIRST limit "
            "was the prefill window budget (derived from the pool where a "
-           "history window is still gathered: int8 KV, latent rows, "
-           "tp/sp > 1; unlimited where the pool is read in place): a "
+           "history window is still gathered: int8 KV, tp/sp > 1, rows "
+           "no prefill kernel tiles; unlimited where the pool is read in "
+           "place): a "
            "gathered window at the padded rows did not fit"),
     Series("pstpu:prefill_stop_slots_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
