@@ -27,7 +27,7 @@ The kernel phase also times the paged decode kernel alone at the benchmark's
 two decode shapes and prints, under ``timing``, µs a call, the least time the
 chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
 their ratio, the kernel's own roofline share. It is read by no metric.
-``--prefill`` (alone, like ``--gdn``, ``--moe`` and ``--hc``) does the same for the
+``--prefill`` (alone, like ``--gdn``, ``--ssd``, ``--moe`` and ``--hc``) does the same for the
 prefill flash kernels at the benchmark's prefill shapes (four over K/V rows,
 nine over latent rows), with ``window_attention`` at the parent's window
 width beside it.
@@ -675,6 +675,182 @@ def gdn_child(rehearse: bool) -> int:
     emit({"phase": "gdn", "widths": widths, "calls": calls,
           "step_layers": layers, "checks": checks, "timing": timing,
           "peak": peak, "device": device, "ok": finite})
+    return 0 if finite else 1
+
+
+# --ssd: the Mamba-2 scan alone (ops/ssd.py) at granite-4.0-h-micro's
+# published widths (benchmarks/chip/configs/granite-4.0-h-micro/config.json):
+# one decode step in place in a 36-layer carry at 16 live rows of 16, at 17
+# of 32 (chat-saturated's mean in the cell's bucket: the 15 rows that take
+# no token should cost nothing) and 32 of 32;
+# one prefill chunk at [8, 256] and at one 2048-token row. Beside it the
+# paged decode kernel at this model's attention shape, both ways: its 8 KV
+# heads of 64 lanes PAIRED into 4 rows of 128 (what the model serves:
+# models/granite_hybrid.py:kv_pack) and as 8 heads of 64 lanes, two tokens
+# a 128-lane row (the kernel's own packing, which no chip run had timed).
+SSD_CONFIG = os.path.join(HERE, "benchmarks", "chip", "configs",
+                          "granite-4.0-h-micro", "config.json")
+SSD_STEP_ROWS = ((16, 16), (32, 17), (32, 32))    # (the carry's rows, live)
+SSD_CHUNKS = ((8, 256), (1, 2048))                # (rows, tokens a row)
+SSD_CALLS = 8
+SSD_KERNEL_SHAPES = [
+    {"name": "paired-4x128", "rows": 32, "live": 17, "lens": (96, 2600),
+     "heads": 32, "kv_heads": 4, "dh": 128},
+    {"name": "packed-8x64", "rows": 32, "live": 17, "lens": (96, 2600),
+     "heads": 32, "kv_heads": 8, "dh": 64},
+]
+
+
+def ssd_child(rehearse: bool) -> int:
+    """``--ssd``: times ``ssd_step_at`` and ``ssd_chunk`` (ops/ssd.py)
+    alone on the chip, calls chained through the state inside one program
+    (a step program through the layers of a donated carry in turn, in
+    place), and prints microseconds a call beside the least time the
+    chip's peaks allow their bytes and FLOPs
+    (benchmarks/chip/lib/shapes_ssm.py's count of ONE layer, the LIVE rows'
+    state), then the paged decode kernel at this model's two possible pool
+    shapes. Fails where the step program holds the ``jnp`` form on a TPU.
+    Run by no benchmark cell and no other phase."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.chip.lib import shapes_ssm
+    from production_stack_tpu.ops import ssd
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_decode_stats,
+    )
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "ssd", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    with open(SSD_CONFIG) as f:
+        cfg = json.load(f)
+    rows_list, chunk_list, calls = SSD_STEP_ROWS, SSD_CHUNKS, SSD_CALLS
+    layers = cfg["layer_types"].count("mamba")
+    if rehearse:
+        cfg = dict(cfg, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=128)
+        rows_list, chunk_list, calls, layers = \
+            ((2, 2), (3, 2)), ((2, 160),), 2, 2
+    # ONE state-space layer of these widths, for the shapes' count.
+    cfg = dict(cfg, layer_types=["mamba"])
+    h, p, n = cfg["mamba_n_heads"], cfg["mamba_d_head"], cfg["mamba_d_state"]
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+
+    def inputs(key, b, t):
+        ks = jax.random.split(key, 7)
+        x = jax.nn.silu(jax.random.normal(ks[0], (b, t, h, p)))
+        bm, cm = (jax.nn.silu(jax.random.normal(ks[i], (b, t, n)))
+                  for i in (1, 2))
+        dt0 = jax.random.uniform(ks[3], (h,), minval=1e-3, maxval=1e-1)
+        dt, da = ssd.gates(
+            jax.random.normal(ks[4], (b, t, h)),
+            jnp.log(jax.random.uniform(ks[5], (h,), minval=1.0, maxval=16.0)),
+            ssd.softplus_inverse(dt0))
+        state = 0.1 * jax.random.normal(ks[6], (b, h, p, n))
+        return state, x, bm, cm, dt, da
+
+    d_skip = jnp.ones((h,))
+
+    def entry(name, sec, work, **more):
+        out = {"op": name, **more, "bytes": work["bytes"],
+               "flops": work["flops"], "us_per_call": None,
+               "least_us": None, "roofline_pct": None}
+        if peak and not rehearse:
+            least = max(work["bytes"] / (peak["hbm_gbps"] * 1e9),
+                        work["flops"] / (peak["bf16_tflops"] * 1e12))
+            out.update(us_per_call=sec * 1e6, least_us=least * 1e6,
+                       roofline_pct=100.0 * least / sec)
+        return out
+
+    timing, checks, finite, paths = [], [], True, set()
+    for b, n_live in rows_list:
+        state, *xs = inputs(jax.random.PRNGKey(b), b, 1)
+        xs = tuple(v[:, 0] for v in xs)            # x, B, C, dt, dA
+        carry = jnp.tile(state[:, None], (1, layers, 1, 1, 1))
+        # The live rows spread over the carry, as a bucket's are.
+        live = (jnp.arange(b) * n_live) % b < n_live
+
+        def steps(carry, *xs):
+            def one(i, both):
+                carry, acc = both
+                y, carry = ssd.ssd_step_at(carry, i % layers, *xs, d_skip,
+                                           live, interpret=rehearse)
+                return carry, acc + y
+            return jax.lax.fori_loop(
+                0, calls * layers, one, (carry, jnp.zeros((b, h, p))))
+
+        # Once against the plain token: live rows agree, the others' state
+        # is bit for bit what it was.
+        want_y, want = jax.jit(ssd.ssd_step_at_jnp)(carry, 1, *xs, d_skip,
+                                                    live)
+        got_y, got = jax.jit(functools.partial(
+            ssd.ssd_step_at, interpret=rehearse))(carry, 1, *xs, d_skip,
+                                                  live)
+        err = max(float(jnp.max(jnp.abs(got - want))),
+                  float(jnp.max(jnp.abs(got_y - want_y))))
+        same = bool(jnp.all(jnp.where(
+            live[:, None, None, None, None], True, got == carry)))
+        checks.append({"rows": b, "live": n_live, "max_abs_err": err,
+                       "others_untouched": same})
+        finite &= err < 1e-5 and same
+        steps = jax.jit(steps, donate_argnums=0).lower(carry, *xs).compile()
+        best = float("inf")
+        for _ in range(6):        # the first run is the warm-up
+            t0 = time.perf_counter()
+            carry, acc = jax.block_until_ready(steps(carry, *xs))
+            best = min(best, time.perf_counter() - t0)
+        finite &= bool(jnp.all(jnp.isfinite(acc)))
+        path = ssd.step_path(steps.as_text())
+        paths.add(path)
+        timing.append(entry(
+            "ssd_step", best / (calls * layers),
+            shapes_ssm.ssd_step(cfg, n_live), rows=b, live=n_live,
+            path=path))
+    finite &= rehearse or paths == {"pallas"}
+    for b, tokens in chunk_list:
+        state, *xs = inputs(jax.random.PRNGKey(7 + b), b, tokens)
+        lens = jnp.full((b,), tokens, jnp.int32)
+
+        @jax.jit
+        def chunks(state, *xs):
+            def one(_, carry):
+                state, acc = carry
+                y, state = ssd.ssd_chunk(state, *xs, d_skip, lens)
+                return state, acc + y
+            return jax.lax.fori_loop(
+                0, calls, one, (state, jnp.zeros((b, tokens, h, p))))
+
+        sec = best_of(chunks, (state, *xs), calls)
+        finite &= bool(jnp.all(jnp.isfinite(chunks(state, *xs)[1])))
+        timing.append(entry(
+            "ssd_chunk", sec, shapes_ssm.ssd_chunk(cfg, b * tokens),
+            rows=b, tokens=tokens, chunk=ssd.CHUNK))
+    kernel = []
+    for shape in SSD_KERNEL_SHAPES:
+        if rehearse:
+            shape = {**shape, "rows": 3, "live": 2, "lens": (17, 600)}
+        case = kernel_timing_case(shape, dh=shape["dh"])
+        sec = time_kernel(
+            paged_flash_decode_stats, case,
+            2 if rehearse else KERNEL_TIMING_CALLS, interpret=rehearse)
+        item = {"shape": shape["name"], "rows": shape["rows"],
+                "live_rows": shape["live"],
+                "kv_tokens": int(case["kv_lens"].sum()),
+                "kv_bytes": case["kv_bytes"], "us_per_call": None,
+                "bytes_least_us": None, "roofline_pct": None}
+        if peak and not rehearse:
+            least = case["kv_bytes"] / (peak["hbm_gbps"] * 1e9)
+            item.update(us_per_call=sec * 1e6, bytes_least_us=least * 1e6,
+                        roofline_pct=100.0 * least / sec)
+        kernel.append(item)
+    emit({"phase": "ssd", "widths": {"heads": h, "d_head": p, "d_state": n},
+          "calls": calls, "step_layers": layers, "checks": checks,
+          "timing": timing, "paged_decode_kernel": kernel, "peak": peak,
+          "device": device, "ok": finite})
     return 0 if finite else 1
 
 
@@ -1509,6 +1685,10 @@ def main(argv=None) -> int:
     ap.add_argument("--gdn", action="store_true",
                     help="only time the Gated DeltaNet recurrence alone "
                          "(gdn_step, gdn_chunk) and exit")
+    ap.add_argument("--ssd", action="store_true",
+                    help="only time the Mamba-2 scan alone (ssd_step, "
+                         "ssd_chunk) and the paged decode kernel at its "
+                         "model's attention shape, and exit")
     ap.add_argument("--moe", action="store_true",
                     help="only time the latent decode kernel and the "
                          "experts' grouped matmuls alone and exit")
@@ -1530,6 +1710,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return gdn_child(args.rehearse)
+    if args.ssd:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return ssd_child(args.rehearse)
     if args.moe:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"

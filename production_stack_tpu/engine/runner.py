@@ -53,7 +53,7 @@ from production_stack_tpu.ops.attention import (
     prefill_attn_path,
     prefill_kernel_covers,
 )
-from production_stack_tpu.ops.gated_delta import step_path
+from production_stack_tpu.ops import gated_delta, ssd
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
     read_state_rows,
@@ -3208,8 +3208,8 @@ class ModelRunner:
         donated pools are updated in place, ops/kv_write.py, and so is the
         carried state, ops/gated_delta.py) — and the program's temporaries
         beside one payload pool's bytes; for a decode program of a model
-        with recurrent state, ``gdn_step``: which execution of the
-        recurrence's step it holds (``"pallas"`` / ``"xla"``); for a
+        with recurrent state, ``gdn_step`` / ``ssd_step``: which execution
+        of that recurrence's step it holds (``"pallas"`` / ``"xla"``); for a
         prefill program, ``prefill_attn``: which execution of the chunk's
         attention (``"pallas"``: the flash kernel over the pool /
         ``"xla"``: ``window_attention`` over gathered keys); for every
@@ -3247,9 +3247,10 @@ class ModelRunner:
                 "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
                 "state_pool_bytes": self.state_pool_bytes,
             })
-            path = step_path(text)
-            if path:
-                out[-1]["gdn_step"] = path
+            for name, op in (("gdn_step", gated_delta), ("ssd_step", ssd)):
+                path = op.step_path(text)
+                if path:
+                    out[-1][name] = path
             out[-1].update(self.residual_report())
             if kind == "prefill":
                 out[-1]["prefill_attn"] = prefill_attn_path(text)

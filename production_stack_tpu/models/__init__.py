@@ -8,7 +8,13 @@ their meaning). Nothing
 outside this package asks for an architecture by name.
 """
 
-from production_stack_tpu.models import deepseek_v3, llama, olmo_hybrid, opt
+from production_stack_tpu.models import (
+    deepseek_v3,
+    granite_hybrid,
+    llama,
+    olmo_hybrid,
+    opt,
+)
 from production_stack_tpu.models.config import (
     LLAMA3_8B,
     NAMED_CONFIGS,
@@ -20,7 +26,7 @@ from production_stack_tpu.models.config import (
 )
 
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
-          "deepseek_v3": deepseek_v3}
+          "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid}
 
 
 def get_model(cfg: ModelConfig):

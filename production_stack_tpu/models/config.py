@@ -83,7 +83,8 @@ class CacheSpecs(NamedTuple):
 
 @dataclass(frozen=True)
 class ModelConfig:
-    arch: str = "llama"  # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3"
+    # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid"
+    arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -99,9 +100,10 @@ class ModelConfig:
     attention_bias: bool = False  # Qwen2-style qkv bias
     dtype: str = "bfloat16"
     name: str = "model"
-    # Layer kinds in order ("linear_attention" | "full_attention"), a whole
-    # number of equal periods; empty: every layer is full attention. The
-    # linear_* sizes are those of the linear-attention layers' recurrence.
+    # Layer kinds in order ("linear_attention" | "full_attention"; a
+    # granite_hybrid's "mamba" | "attention"), a whole number of equal
+    # periods; empty: every layer is full attention. The linear_* sizes are
+    # those of the linear-attention layers' recurrence.
     layer_types: Tuple[str, ...] = ()
     linear_num_heads: int = 0
     linear_key_head_dim: int = 0
@@ -140,10 +142,30 @@ class ModelConfig:
     # Published next-token-prediction layers: read, NOT served (their
     # tensors are not loaded; the next-token logits do not depend on them).
     num_nextn_predict_layers: int = 0
+    # Mamba-2 state-space layers (models/granite_hybrid.py, ops/ssd.py):
+    # mamba_n_heads heads of mamba_d_head channels over a state of
+    # mamba_d_state, ONE group (every head shares B_t and C_t), a causal
+    # depthwise convolution of mamba_d_conv taps over x, B and C together.
+    # mamba_chunk_size is the published schedule of the chunkwise scan: read,
+    # the implementation's chunk is ops/ssd.py's own (the same numbers).
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_conv_bias: bool = True
+    mamba_chunk_size: int = 256
+    # Granite's four multipliers: the embedding's rows, the attention
+    # scores (in place of head_dim ** -0.5), every sublayer's output before
+    # it joins the residual, and the divisor of the logits.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.layer_types:
-            layer_period(self.layer_types, self.num_layers)
+            layer_period(self.layer_types, self.num_layers,
+                         **PERIOD_RULES.get(self.arch, {}))
 
     @property
     def head_dim_(self) -> int:
@@ -283,6 +305,51 @@ class ModelConfig:
                 name=name,
                 **hc,
             )
+        if model_type == "granitemoehybrid":
+            # The family's siblings this module does not implement are
+            # refused by their key, not served as something else.
+            unsupported = {
+                "num_local_experts > 0": d.get("num_local_experts", 0) > 0,
+                "position_embedding_type != nope":
+                    d.get("position_embedding_type", "nope") != "nope",
+                "mamba_n_groups != 1": d.get("mamba_n_groups", 1) != 1,
+                "mamba_proj_bias": bool(d.get("mamba_proj_bias", False)),
+                "attention_bias": bool(d.get("attention_bias", False)),
+                "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+                "mamba_expand * hidden_size != mamba_n_heads * mamba_d_head":
+                    d.get("mamba_expand", 2) * d["hidden_size"]
+                    != d["mamba_n_heads"] * d["mamba_d_head"],
+            }
+            asked = [k for k, on in unsupported.items() if on]
+            if asked:
+                raise ValueError(
+                    f"{model_type}: not supported: {', '.join(asked)}")
+            return ModelConfig(
+                arch="granite_hybrid",
+                vocab_size=d["vocab_size"],
+                hidden_size=d["hidden_size"],
+                intermediate_size=d["shared_intermediate_size"],
+                num_layers=d["num_hidden_layers"],
+                num_heads=d["num_attention_heads"],
+                num_kv_heads=d.get("num_key_value_heads",
+                                   d["num_attention_heads"]),
+                max_position_embeddings=d.get("max_position_embeddings", 4096),
+                rope_theta=None,
+                rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+                tie_word_embeddings=d.get("tie_word_embeddings", True),
+                layer_types=tuple(d["layer_types"]),
+                mamba_n_heads=d["mamba_n_heads"],
+                mamba_d_head=d["mamba_d_head"],
+                mamba_d_state=d["mamba_d_state"],
+                mamba_d_conv=d.get("mamba_d_conv", 4),
+                mamba_conv_bias=d.get("mamba_conv_bias", True),
+                mamba_chunk_size=d.get("mamba_chunk_size", 256),
+                embedding_multiplier=float(d.get("embedding_multiplier", 1.0)),
+                attention_multiplier=float(d["attention_multiplier"]),
+                residual_multiplier=float(d.get("residual_multiplier", 1.0)),
+                logits_scaling=float(d.get("logits_scaling", 1.0)),
+                name=name,
+            )
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
@@ -292,27 +359,43 @@ class ModelConfig:
 
 
 LAYER_KINDS = ("linear_attention", "full_attention")
+# Per arch, what ``layer_period`` is asked beside the list: the names of the
+# (state-keeping, full-attention) kinds and whether the full layer has to
+# close its period. An arch not named reads the default: olmo_hybrid's.
+PERIOD_RULES = {
+    "granite_hybrid": {"kinds": ("mamba", "attention"), "closed": False},
+}
 
 
-def layer_period(layer_types, num_layers: int) -> Tuple[str, ...]:
-    """The repeating pattern of ``layer_types``: some linear-attention
-    layers, then one full-attention layer (what models/olmo_hybrid.py traces
-    once and scans). Refused: a length other than ``num_layers``, an unknown
-    kind, and a list that is not a whole number of such equal periods."""
+def layer_period(layer_types, num_layers: int, *,
+                 kinds: Tuple[str, str] = LAYER_KINDS,
+                 closed: bool = True) -> Tuple[str, ...]:
+    """The repeating pattern of ``layer_types``: layers that keep a state
+    (``kinds[0]``) and exactly ONE full-attention layer (``kinds[1]``).
+    ``closed`` (models/olmo_hybrid.py, which traces some linear layers then
+    the full layer and scans that): the full layer ends the period;
+    otherwise (models/granite_hybrid.py, which scans the segments between
+    full layers) it may stand anywhere in it. Both modules share this one
+    helper. Refused: a length other than ``num_layers``, an unknown kind,
+    and a list that is not a whole number of such equal periods."""
     types = tuple(layer_types)
     if len(types) != num_layers:
         raise ValueError(
             f"layer_types has {len(types)} entries for {num_layers} layers")
-    unknown = sorted(set(types) - set(LAYER_KINDS))
+    unknown = sorted(set(types) - set(kinds))
     if unknown:
         raise ValueError(f"layer_types: unknown kinds {unknown}; "
-                         f"supported: {list(LAYER_KINDS)}")
-    n = types.index("full_attention") + 1 if "full_attention" in types else 0
-    if n < 2 or len(types) % n or types != types[:n] * (len(types) // n):
+                         f"supported: {list(kinds)}")
+    full = types.count(kinds[1])
+    n = len(types) // full if full else 0
+    if n < 2 or len(types) % n or types != types[:n] * (len(types) // n) \
+            or types[:n].count(kinds[1]) != 1 \
+            or (closed and types[n - 1] != kinds[1]):
         raise ValueError(
             f"layer_types {list(types)} is not a whole number of equal "
-            f"periods of linear_attention layers closed by one "
-            f"full_attention layer")
+            f"periods of {kinds[0]} layers "
+            + (f"closed by one {kinds[1]} layer" if closed
+               else f"around one {kinds[1]} layer"))
     return types[:n]
 
 
@@ -428,8 +511,27 @@ TINY_XING4 = ModelConfig(
     hc_mult=4, num_nextn_predict_layers=1, name="tiny-xing4",
 )
 
+# Tiny state-space hybrid: two periods of (2 Mamba-2 + attention + 1 Mamba-2)
+# layers, so the full layer does not close its period; attention heads of 64
+# lanes (the paged decode kernel's packed path), every multiplier off 1, a
+# tied head (tests/test_granite_hybrid.py compares it with the plain
+# reference).
+TINY_GRANITE_HYBRID = ModelConfig(
+    arch="granite_hybrid", vocab_size=512, hidden_size=64,
+    intermediate_size=128, num_layers=8, num_heads=4, num_kv_heads=2,
+    head_dim=64, max_position_embeddings=512, rope_theta=None,
+    rms_norm_eps=1e-5, tie_word_embeddings=True,
+    layer_types=("mamba", "mamba", "attention", "mamba") * 2,
+    mamba_n_heads=4, mamba_d_head=16, mamba_d_state=32, mamba_d_conv=4,
+    mamba_conv_bias=True, mamba_chunk_size=256,
+    embedding_multiplier=6.0, attention_multiplier=0.25,
+    residual_multiplier=0.4, logits_scaling=4.0,
+    name="tiny-granite-hybrid",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-granite-hybrid": TINY_GRANITE_HYBRID,
     "tiny-deepseek-v3": TINY_DEEPSEEK_V3,
     "tiny-xing4": TINY_XING4,
     "tiny-olmo-hybrid": TINY_OLMO_HYBRID,

@@ -33,6 +33,14 @@ Linear layer: see ops/gated_delta.py; ``beta`` is doubled where
 ``cfg.linear_allow_neg_eigval``. tests/reference/olmo_hybrid_ref.py is the
 plain statement of the same equations this module is held to.
 
+Shared with models/granite_hybrid.py (Mamba-2 layers where these are Gated
+DeltaNet): the causal depthwise convolution (ops/gated_delta.py:conv_step /
+conv_chunk; there with a bias), the period helper
+(models/config.py:layer_period; there the full layer may stand anywhere in
+its period, here it closes it), the ``StateSpec`` slots and the conventions
+of this forward (stacks by kind, weights closed over and sliced where used,
+a decode step's layer stepped in place in the rows' carried state).
+
 Device scopes: the six in-projections and both out-projections under
 ``attn_proj``, attention and the recurrence under ``attn_core`` (the
 recurrence with an inner ``gdn_step`` / ``gdn_chunk``), ``ffn``, ``embed``,

@@ -326,6 +326,11 @@ def attend(
         return _attend_latent(q, k, positions, chunk_lens, view, layer,
                               scale, value_dim)
     b, t, h, dh = q.shape
+    if scale is not None:
+        # Every K/V path below scales the scores by Dh ** -0.5; a model's
+        # own scale rides on the queries (one more rounding of q in its
+        # own dtype where the ratio is no power of two).
+        q = (q.astype(jnp.float32) * (scale * dh ** 0.5)).astype(q.dtype)
     if view.sp_mesh is not None and t > 1 and view.ring_k is None:
         from production_stack_tpu.ops.ring_attention import (
             ring_attention,

@@ -10,7 +10,8 @@ Per head, with state ``S`` in R^{dk x dv} (float32), for token t:
 ``q`` arrives l2-normalised and scaled by dk^-0.5, ``k`` l2-normalised
 (``prepare``). Before that, q, k and v pass a causal depthwise convolution
 of width W over the token axis and a SiLU; the *conv state* of a sequence is
-the last W - 1 inputs of each channel.
+the last W - 1 inputs of each channel. ``conv_step`` / ``conv_chunk`` are
+also the convolution of a Mamba-2 layer (ops/ssd.py), which adds a bias.
 
 ``gdn_chunk`` is the chunkwise form of the same recurrence (chunks of up to
 64 tokens, the WY representation: HF's ``torch_chunk_gated_delta_rule`` is a
@@ -139,6 +140,7 @@ def conv_step(x: jax.Array,           # [B, C] this token's channels
               conv_state: jax.Array,  # [B, W-1, C] the W-1 inputs before it
               w: jax.Array,           # [W, C]; w[W-1] weighs the newest
               live: jax.Array,        # [B] bool: rows that take the token
+              bias=None,              # [C], or None (ops/ssd.py's layers)
               ) -> Tuple[jax.Array, jax.Array]:
     """SiLU(conv) of one token a row and the conv state after it; a row that
     is not ``live`` keeps its state."""
@@ -146,6 +148,8 @@ def conv_step(x: jax.Array,           # [B, C] this token's channels
                              axis=1)                           # [B, W, C]
     y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None],
                 axis=1)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     new_state = jnp.where(live[:, None, None], window[:, 1:], conv_state)
     return jax.nn.silu(y).astype(x.dtype), new_state
 
@@ -154,6 +158,7 @@ def conv_chunk(x: jax.Array,           # [B, T, C]
                conv_state: jax.Array,  # [B, W-1, C]
                w: jax.Array,           # [W, C]
                lens: jax.Array,        # [B] valid tokens of each row
+               bias=None,              # [C], or None
                ) -> Tuple[jax.Array, jax.Array]:
     """SiLU(causal depthwise conv) of a chunk that continues ``conv_state``,
     and the conv state after each row's last valid token (a row of length 0
@@ -164,6 +169,8 @@ def conv_chunk(x: jax.Array,           # [B, T, C]
     wf = w.astype(jnp.float32)
     y = sum(ext[:, i:i + t].astype(jnp.float32) * wf[i][None, None]
             for i in range(width))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     # ext[len : len + W-1] are the inputs of tokens len-W+1 .. len-1.
     idx = lens[:, None] + jnp.arange(width - 1, dtype=jnp.int32)[None, :]
     new_state = jnp.take_along_axis(ext, idx[:, :, None], axis=1)

@@ -40,10 +40,25 @@ import pytest
 # ``tests/chip_bench/test_bench_hc.py``. Conditional and strict: not applied
 # while the six are last, and a ``benchmark`` PR that loosens the assertion
 # makes the test pass, which fails the run until this mark is deleted.
+#
+# PR 38's ``test_bench_hc.py`` pins its own entries to the ENDS of their
+# lists in three tests, which the next appending PR (PR 40) cannot keep
+# either: the same mark, on the same condition (entries follow PR 38's
+# last). What else the three assert is held by
+# ``tests/chip_bench/test_bench_ssm.py`` with PR 38's block pinned to the
+# indices it has. (``..._since_the_parent`` skips where git has no history,
+# which a strict xfail lets through.) From PR 40 on the benchmark's tests
+# pin their entries by INDEX and nothing to an end, so this list need not
+# grow again.
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PR36_TEST = ("test_bench_issue.py::"
               "test_the_six_are_the_last_of_per_layer_and_list_every_cell")
 _PR36_LAST = "decode_empty_step_pct"
+_PR38_TESTS = tuple("test_bench_hc.py::" + name for name in (
+    "test_the_new_entries_are_the_last_of_their_lists",
+    "test_the_cell_is_named_last_where_its_readers_find_something",
+    "test_the_manifest_only_grew_at_the_ends_since_the_parent"))
+_PR38_LAST = "hc_share_pct"
 
 
 def pytest_collection_modifyitems(items):
@@ -52,14 +67,18 @@ def pytest_collection_modifyitems(items):
         if inspect.iscoroutinefunction(getattr(item, "function", None)):
             item.obj = _sync_wrapper(item.function)
     with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        if json.load(f)["per_layer"][-1]["name"] == _PR36_LAST:
-            return
+        last = json.load(f)["per_layer"][-1]["name"]
+    overtaken = [(tests, pr) for tests, is_last, pr in (
+        ((_PR36_TEST,), _PR36_LAST, 36), (_PR38_TESTS, _PR38_LAST, 38))
+        if last != is_last]
     for item in items:
-        if item.nodeid.endswith(_PR36_TEST):
-            item.add_marker(pytest.mark.xfail(
-                strict=True, raises=AssertionError,
-                reason="asserts PR 36's metrics are the last of per_layer; "
-                       "a PR may only append (see the note above)"))
+        for tests, pr in overtaken:
+            if item.nodeid.endswith(tests):
+                item.add_marker(pytest.mark.xfail(
+                    strict=True, raises=AssertionError,
+                    reason=f"asserts PR {pr}'s entries are the last of "
+                           "their lists; a PR may only append (see the "
+                           "note above)"))
 
 
 def _sync_wrapper(fn):

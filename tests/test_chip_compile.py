@@ -409,6 +409,79 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+# ---- granite-4.0-h-micro: state-space layers beside 64-lane attention heads
+# Instructions of a compiled dispatch program (2796 and 2673 at the time of
+# writing: ONE state-space layer's code and ONE attention layer's, whatever
+# the depth; a second traced copy of either shows here).
+STATE_SPACE_INSTRUCTIONS = 3600
+
+
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x256"])
+def test_state_space_dispatch_programs_compile_in_place_for_v5e(v5e, program):
+    """The decode program at the 32-row bucket and the [8, 256] prefill
+    program of granite-4.0-h-micro's envelope (deployment.json's flags,
+    published widths, all 40 layers) compile for a v5e, fit its HBM beside
+    their arguments, and copy no pool: K/V, the scan's state and the conv
+    state are gathered by row and written back in place. The decode program
+    steps the scan in place in its loops' carried state
+    (ops/pallas/ssd.py): no copy of the carry either, and 2.6 GB of
+    temporaries, of which the 32 rows' carried state is 2.45. The attention layers' 64-lane KV heads lie
+    two to a row of 128 lanes (models/granite_hybrid.py:kv_pack), so both
+    paged kernels take them as they are: with a pool whose minor axis was
+    64 the compiler kept it slots-minor, copied both pools whole into
+    every dispatch and reshaped them whole a layer a step for the decode
+    kernel's two-tokens-a-row view (8.7 GB of temporaries: it did not
+    fit), and with dt's 64 columns beside z | xBC it copied the in_proj
+    stack (1.25 GB)."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops import ssd
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "granite-4.0-h-micro")
+    assert [p.shape for p in r.state_pools] == \
+        [(33, 36, 64, 64, 128), (33, 36, 3 * 4352 // 128, 128)]
+    assert [str(p.dtype) for p in r.state_pools] == ["float32", "bfloat16"]
+    assert r.kv_k.shape == (4, 4, 6144 * 16, 128)
+    assert r.prefill_reads_pool
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    decode = program.startswith("decode")
+    if decode:
+        lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
+    else:
+        assert (8, 256, full_mb, False) in r.reachable_prefill_families()
+        lowered = r._lower_prefill(aparams, 8, 256, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    # The rows' state as the decode loops carry it (2.42 GB + 30 MB), and
+    # the weights' largest stacks.
+    carried = [jax.ShapeDtypeStruct((32, 36, 64, 64, 128), jnp.float32),
+               jax.ShapeDtypeStruct((32, 36, 102, 128), jnp.bfloat16)]
+    stacks = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in (
+        (36, 2048, 8448), (36, 4096, 2048), (36, 2048, 16384),
+        (36, 8192, 2048))]
+    assert pool_copies(
+        text, [r.kv_k, *r.state_pools, *carried, *stacks]) == []
+    # The Mosaic kernels: the attention layers' paged decode and the
+    # state-space layers' step; of prefill, the flash kernel over the pool.
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (2 if decode else 1)
+    assert ssd.step_path(text) == ("pallas" if decode else None)
+    assert ("/ssd_chunk/" in text) == (not decode)
+    if not decode:
+        assert prefill_attn_path(text) == "pallas"
+    for scope in ("embed", "attn_proj", "attn_core", "ffn", "logits",
+                  "kv_write", "sample"):
+        assert f"/{scope}/" in text, scope
+    instructions = sum(1 for ln in text.splitlines() if " = " in ln)
+    assert instructions < STATE_SPACE_INSTRUCTIONS, instructions
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < (3.0 if decode else 2.2) * 1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 # ---- kanana-2-30b-a3b-d8: the latent kernel, the grouped matmul, the programs
 LATENT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -611,7 +684,7 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
 @pytest.mark.parametrize("name,families,in_place", [
     ("qwen2.5-3b", 8, True), ("mistral-7b-d16", 8, True),
     ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 7, True),
-    ("xing4.0-29b-a4b-d7", 7, True)])
+    ("xing4.0-29b-a4b-d7", 7, True), ("granite-4.0-h-micro", 8, True)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place):
     """One prefill family a (rows, t) where the history is read in place
