@@ -29,7 +29,9 @@ state a row leaves is that of its last W - 1 *valid* tokens.
 The state lies as ``[H, P, N]``: ``N`` = 128 at the published widths, whole
 lanes, and ``P`` = 64 whole sublane tiles, so nothing is packed. ``ssd_step``
 runs in that layout, on a TPU as one Pallas kernel in place in the decode
-loop's carried state (``ssd_step_at``).
+loop's carried state (``ssd_step_at``): the update elementwise on the vector
+unit, as ``ssd_token`` writes it, and ``S C`` (a sum along the lanes) as one
+matrix-unit product a block of heads at ``Precision.HIGHEST``.
 
 Everything here is float32, and every matrix product runs at
 ``Precision.HIGHEST``: at the default a TPU takes bf16 operands, which rounds
@@ -82,8 +84,7 @@ def ssd_token(state: jax.Array,   # [B, H, P, N] f32
               d_skip: jax.Array,  # [H] f32
               ) -> Tuple[jax.Array, jax.Array]:
     """One token of the recurrence: (y [B, H, P], state after it). Sums of
-    float32 products on the vector unit: no matrix product rounds the
-    state."""
+    float32 products: nothing rounds the state."""
     state = state * jnp.exp(da)[..., None, None] \
         + (dt[..., None] * x)[..., None] * b[:, None, None, :]
     y = jnp.sum(state * c[:, None, None, :], axis=-1)
@@ -118,7 +119,11 @@ def ssd_step_at(carry, at, x, b, c, dt, da, d_skip, live, *,
     Pallas kernel (ops/pallas/ssd.py: in place in the carry, a live row's
     state read once and written once, a row that is not live untouched),
     and so does any program with ``interpret`` set (the runner's Pallas
-    interpret switch: a CPU's tests); every other holds the ``jnp`` form."""
+    interpret switch: a CPU's tests); every other holds the ``jnp`` form.
+    The two compute the state by the same float32 expression (on the chip
+    the same bits); ``y`` differs by the order of a float32 sum over the
+    state axis (the kernel's is the matrix unit's at
+    ``Precision.HIGHEST``)."""
     from production_stack_tpu.ops.pallas.ssd import (
         ssd_step_in_place,
         supports_step_kernel,

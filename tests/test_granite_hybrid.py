@@ -18,6 +18,7 @@ the same numbers by 0.25 to several units, so 5e-3 leaves both sides room.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -342,8 +343,13 @@ PATHS = pytest.mark.parametrize(
     (3, 32, 16, 128, (1, 0, 1)),                 # two blocks of heads a row
     (8, 16, 8, 128, (0, 1, 1, 1, 0, 0, 1, 1)),
     (4, 4, 16, 128, (0, 0, 0, 0)),               # nothing to step
-    (32, 16, 8, 128, (1, 0) * 16),               # four programs of 8 rows
-])
+    (32, 16, 8, 128, (1, 0) * 16),
+    # The published head sizes: four blocks of 16 heads a row.
+    (4, 64, 64, 128, (0, 0, 1, 0)),              # one live row
+    (16, 64, 64, 128, (0,) + (1,) * 14 + (0,)),  # first and last rows dead
+    (16, 64, 64, 128, (1,) * 16),                # a 16-row bucket, all live
+    (32, 64, 64, 128, tuple(i * 17 % 32 < 17 for i in range(32))),
+], ids=lambda v: str(sum(v)) + "live" if isinstance(v, tuple) else str(v))
 def test_the_two_executions_of_the_step_agree(interpret, rows, h, p, n, live):
     """ops/ssd.py:ssd_step_at through the Pallas kernel (interpreted) and
     through the ``jnp`` form against ``ssd_token``: the live rows' slabs of
@@ -370,13 +376,95 @@ def test_the_two_executions_of_the_step_agree(interpret, rows, h, p, n, live):
                                    atol=1e-5)
 
 
+def test_the_step_kernel_hands_its_buffers_from_program_to_program(
+        monkeypatch):
+    """Rows whose small operands outgrow one program's VMEM are several
+    programs (64 rows at the published widths are two); the block
+    sequence, two blocks in flight, runs on through them. Forced here at a
+    small shape: four programs of 8 rows, a row of two blocks."""
+    from production_stack_tpu.ops.pallas import ssd as kernel
+
+    rows, h, p, n = 32, 32, 8, 128
+    # A row's operands here: 8 KB of dt x, 4 KB of B | C, 4 KB of y.
+    monkeypatch.setattr(kernel, "OPERAND_BYTES", 2 * 8 * (16 << 10))
+    x, bm, cm, dt, da, d_skip, state0 = _scan_inputs(rows, 1, h, p, n, 5)
+    carry = jnp.stack([state0, 2.0 * state0], axis=1)
+    live = jnp.asarray([0, 0, 1] + [1, 0, 1, 1] * 7 + [0], bool)
+    args = (carry, 0, x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0], da[:, 0],
+            d_skip, live)
+    step = functools.partial(kernel.ssd_step_in_place, interpret=True)
+    assert "grid=(4,)" in str(jax.make_jaxpr(step)(*args))
+    y, got = step(*args)
+    want_y, want = ssd.ssd_step_at_jnp(*args)
+    np.testing.assert_array_equal(got[:, 1], carry[:, 1])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(y, want_y, rtol=1e-4, atol=1e-5)
+
+
+REC_TOL = 5e-5   # check_reference.py's, of granite-4.0-h-micro: the chip's
+
+
+@pytest.mark.parametrize("lens,live", [
+    ((300, 131), (1, 1)),             # two chunks and a part; both decode
+    ((128, 1, 40), (1, 0, 1)),        # the middle row takes no token
+], ids=["2rows", "3rows-1dead"])
+def test_a_chain_of_kernel_steps_holds_the_recurrence_tolerance(lens, live):
+    """Tier-1's copy of the ``recurrence`` stage of
+    benchmarks/chip/configs/granite-4.0-h-micro/check_reference.py at the
+    published head sizes: from the state ``ssd_chunk`` leaves, 64 steps
+    through the Pallas kernel (interpreted) against the float32
+    ``ssd_token`` chain: outputs and final states within REC_TOL of their
+    norms (the kernel's update IS the token's; only y's sum over the state
+    axis runs in another order, on the matrix unit on the chip). The same
+    chain with the contraction as a default-precision product takes it on a
+    TPU (operands rounded to bf16, float32 sums) falls outside: a
+    float32 state is not to be read through bf16."""
+    h, p, n, steps = 64, 64, 128, 64
+    rows, t = len(lens), max(lens)
+    x, bm, cm, dt, da, d_skip, _ = _scan_inputs(rows, t + steps, h, p, n, 41)
+    lv = jnp.asarray(live, bool)
+    _, state0 = jax.jit(ssd.ssd_chunk)(
+        jnp.zeros((rows, h, p, n)), x[:, :t], bm[:, :t], cm[:, :t],
+        dt[:, :t], da[:, :t], d_skip, jnp.asarray(lens, jnp.int32))
+    xs = tuple(jnp.moveaxis(v[:, t:], 1, 0) for v in (x, bm, cm, dt, da))
+
+    def chain(step):
+        def one(state, v):
+            y, state = step(state, *v)
+            return state, y
+        return jax.jit(lambda s: jax.lax.scan(one, s, xs))(state0)
+
+    def token(state, x, b, c, dt, da, low=False):
+        y, new = ssd.ssd_token(state, x, b, c, dt, da, d_skip)
+        if low:
+            def bf(v):
+                return v.astype(jnp.bfloat16).astype(jnp.float32)
+            y = jnp.sum(bf(new) * bf(c)[:, None, None, :], axis=-1) \
+                + d_skip[None, :, None] * x
+        keep = lv[:, None, None]
+        return jnp.where(keep, y, 0.0), jnp.where(keep[..., None], new, state)
+
+    want_s, want_y = chain(token)
+    got_s, got_y = chain(lambda s, *v: ssd.ssd_step(
+        s, *v, d_skip, lv, interpret=True))
+    low_s, low_y = chain(lambda s, *v: token(s, *v, low=True))
+    assert _relative(got_y, want_y) < REC_TOL
+    assert _relative(got_s, want_s) < REC_TOL
+    dead = ~np.asarray(lv)
+    np.testing.assert_array_equal(got_s[dead], state0[dead])
+    assert not np.any(np.asarray(got_y)[:, dead])
+    assert _relative(low_y, want_y) > 4 * REC_TOL
+    np.testing.assert_array_equal(low_s, want_s)
+
+
 @pytest.mark.parametrize("shape,fits", [
     ((64, 64, 128), True), ((4, 16, 128), True), ((4, 16, 32), False),
-    ((24, 16, 128), False), ((16, 12, 128), False)])
+    ((24, 16, 128), False), ((16, 12, 128), False), ((16, 24, 128), False)])
 def test_the_step_kernel_takes_whole_lanes_sublanes_and_blocks(shape, fits):
     """The published state fits; the tiny preset's 32-wide state, heads
-    that are not whole blocks of 16 and channels that are not whole
-    sublanes keep the ``jnp`` form, whatever the platform."""
+    that are not whole blocks of 16, channels that are not whole sublanes
+    and channels that are no whole fraction of a row of lanes keep the
+    ``jnp`` form, whatever the platform."""
     from production_stack_tpu.ops.pallas.ssd import supports_step_kernel
 
     assert supports_step_kernel(shape) is fits
