@@ -540,17 +540,43 @@ def kernel_child(model: str, rehearse: bool) -> int:
 # The recurrence of a Gated DeltaNet layer alone, at Olmo-Hybrid-7B's
 # published widths: one decode step of 20 live rows (chat-saturated's mean)
 # of a 20-row carry and of a 32-row one (the cell's bucket: the 12 rows that
-# take no token should cost nothing) and of 64 of 64, and one 2048-token
-# prefill chunk of one row.
+# take no token should cost nothing) and of 64 of 64, and one prefill chunk:
+# of one 2048-token row, and of the rectangles chat-saturated dispatches,
+# [16, 128] and [8, 256], with lengths drawn to the cell's 72% fill.
 GDN_WIDTHS = {"heads": 30, "dk": 96, "dv": 192}
 GDN_STEP_ROWS = ((20, 20), (32, 20), (64, 64))    # (the carry's rows, live)
-GDN_CHUNK_TOKENS = 2048
+GDN_CHUNK_SHAPES = ((1, 2048, 1.0), (16, 128, 0.72), (8, 256, 0.72))
 GDN_CALLS = 64
 # Layers of the carry a timed step program cycles through, stepped in place
 # as a decode program steps its rows' 12 linear layers: ONE layer's rows (44
 # MB at 20 rows) stay on the chip between chained calls and read faster than
 # HBM allows.
 GDN_LAYERS = 12
+
+
+def chained_chunks(fn, lens, calls: int):
+    """The program ``--gdn`` times a form of ``gdn_chunk`` in: ``calls``
+    calls chained through the state. q, k, v and the gates are the same in
+    every call, so each call takes them through a barrier with the carried
+    state: without it XLA lifts whatever does not read the state out of the
+    loop (of the ``jnp`` form everything but the scan over chunks: the
+    ``k_beta k^T | q k^T`` product, the 63-trip inverse, ``u`` and ``w``)
+    and computes it once for all the calls, which is how PR 32 and PR 42
+    first read that form at 1.8 ms a layer where a prefill program, whose
+    layers each bring their own q, k and v, pays it 4.8."""
+    import jax
+    import jax.numpy as jnp
+
+    def chunks(state, q, k, v, g, beta):
+        def one(_, carry):
+            state, acc = carry
+            o, state = fn(*jax.lax.optimization_barrier(
+                (state, q, k, v, g, beta)), lens)
+            return state, acc + o
+        return jax.lax.fori_loop(
+            0, calls, one, (state, jnp.zeros(v.shape, jnp.float32)))
+
+    return chunks
 
 
 def gdn_child(rehearse: bool) -> int:
@@ -564,6 +590,7 @@ def gdn_child(rehearse: bool) -> int:
     TPU. Run by no benchmark cell and no other phase."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from benchmarks.chip.lib import shapes_hybrid
     from production_stack_tpu.ops import gated_delta as gd
@@ -575,11 +602,12 @@ def gdn_child(rehearse: bool) -> int:
               "error": "no TPU: nothing was timed"})
         return 1
     widths = dict(GDN_WIDTHS)
-    rows_list, tokens, calls = GDN_STEP_ROWS, GDN_CHUNK_TOKENS, GDN_CALLS
+    rows_list, chunk_shapes, calls = GDN_STEP_ROWS, GDN_CHUNK_SHAPES, GDN_CALLS
     layers = GDN_LAYERS
     if rehearse:
-        widths, rows_list, tokens, calls, layers = \
-            {"heads": 4, "dk": 16, "dv": 32}, ((2, 2), (3, 2)), 80, 2, 2
+        widths, rows_list, chunk_shapes, calls, layers = \
+            {"heads": 4, "dk": 16, "dv": 32}, ((2, 2), (3, 2)), \
+            ((1, 128, 1.0), (3, 64, 0.72)), 2, 2
     h, dk, dv = widths["heads"], widths["dk"], widths["dv"]
     # One linear layer of a config with these widths, for the shapes' count.
     cfg = {"num_attention_heads": h, "hidden_size": h * 128,
@@ -655,23 +683,38 @@ def gdn_child(rehearse: bool) -> int:
             shapes_hybrid.gdn_step(cfg, n_live), rows=b, live=n_live,
             path=path))
     finite &= rehearse or paths == {"pallas"}
-    state, q, k, v, g, beta = inputs(jax.random.PRNGKey(7), 1, tokens)
-    lens = jnp.full((1,), tokens, jnp.int32)
-
-    @jax.jit
-    def chunks(state, q, k, v, g, beta):
-        def one(_, carry):
-            state, acc = carry
-            o, state = gd.gdn_chunk(state, q, k, v, g, beta, lens)
-            return state, acc + o
-        return jax.lax.fori_loop(
-            0, calls, one, (state, jnp.zeros((1, tokens, h, dv))))
-
-    sec = best_of(chunks, (state, q, k, v, g, beta), calls)
-    finite &= bool(jnp.all(jnp.isfinite(
-        chunks(state, q, k, v, g, beta)[1])))
-    timing.append(entry("gdn_chunk", sec,
-                        shapes_hybrid.gdn_chunk(cfg, tokens), tokens=tokens))
+    # The chunkwise form, both executions (the kernel is what ``gdn_chunk``
+    # holds on a TPU; here under the interpreter in a rehearsal), at each
+    # shape: µs a call and a VALID token, and once the kernel against the
+    # ``jnp`` form on the same inputs.
+    for b, t, fill in chunk_shapes:
+        state, q, k, v, g, beta = inputs(jax.random.PRNGKey(7 + b), b, t)
+        lens = jnp.asarray(np.random.default_rng(b).integers(
+            round((2 * fill - 1) * t), t, b, endpoint=True), jnp.int32)
+        valid = int(lens.sum())
+        forms = {"kernel": functools.partial(gd.gdn_chunk,
+                                             interpret=rehearse),
+                 "jnp": gd.gdn_chunk_jnp}
+        got = {}
+        for form, fn in forms.items():
+            chunks = jax.jit(chained_chunks(fn, lens, calls)).lower(
+                state, q, k, v, g, beta).compile()
+            path = gd.chunk_path(chunks.as_text())
+            finite &= rehearse or (path == "pallas") == (form == "kernel")
+            sec = best_of(chunks, (state, q, k, v, g, beta), calls)
+            got[form] = jax.jit(fn)(state, q, k, v, g, beta, lens)
+            finite &= bool(jnp.all(jnp.isfinite(got[form][1])))
+            timing.append(entry(
+                "gdn_chunk", sec, shapes_hybrid.gdn_chunk(cfg, valid),
+                rows=b, tokens=t, valid_tokens=valid, form=form, path=path,
+                us_per_valid_token=None if rehearse else sec * 1e6 / valid))
+        live = (jnp.arange(t)[None] < lens[:, None])[..., None, None]
+        err = max(
+            float(jnp.max(jnp.abs((got["kernel"][0] - got["jnp"][0]) * live))),
+            float(jnp.max(jnp.abs(got["kernel"][1] - got["jnp"][1]))))
+        checks.append({"rows": b, "tokens": t, "valid_tokens": valid,
+                       "chunk_max_abs_err": err})
+        finite &= err < 1e-4
     emit({"phase": "gdn", "widths": widths, "calls": calls,
           "step_layers": layers, "checks": checks, "timing": timing,
           "peak": peak, "device": device, "ok": finite})
@@ -1627,8 +1670,9 @@ def verdict(lines: list, chips: int, full_depth: int,
             if prog["pool_copies"]:
                 faults.append(
                     f"{name}: {prog['pool_copies']} whole-pool copies")
-            if prog.get("gdn_step") == "xla" and not rehearsal:
-                faults.append(f"{name}: gdn_step is not the Pallas kernel")
+            for op in ("gdn_step", "gdn_chunk"):
+                if prog.get(op) == "xla" and not rehearsal:
+                    faults.append(f"{name}: {op} is not the Pallas kernel")
             # The engine's own predicate, not a flag's name: a tp=4
             # engine or an int8 pool gathers a window and says so
             # (``prefill_reads_pool`` false, ``"xla"``).

@@ -3209,7 +3209,9 @@ class ModelRunner:
         carried state, ops/gated_delta.py) — and the program's temporaries
         beside one payload pool's bytes; for a decode program of a model
         with recurrent state, ``gdn_step`` / ``ssd_step``: which execution
-        of that recurrence's step it holds (``"pallas"`` / ``"xla"``); for a
+        of that recurrence's step it holds (``"pallas"`` / ``"xla"``), and
+        for a prefill program of a Gated DeltaNet model ``gdn_chunk``
+        likewise (the chunkwise form); for a
         prefill program, ``prefill_attn``: which execution of the chunk's
         attention (``"pallas"``: the flash kernel over the pool /
         ``"xla"``: ``window_attention`` over gathered keys); for every
@@ -3247,8 +3249,9 @@ class ModelRunner:
                 "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
                 "state_pool_bytes": self.state_pool_bytes,
             })
-            for name, op in (("gdn_step", gated_delta), ("ssd_step", ssd)):
-                path = op.step_path(text)
+            for name, path in (("gdn_step", gated_delta.step_path(text)),
+                               ("gdn_chunk", gated_delta.chunk_path(text)),
+                               ("ssd_step", ssd.step_path(text))):
                 if path:
                     out[-1][name] = path
             out[-1].update(self.residual_report())

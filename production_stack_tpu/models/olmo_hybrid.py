@@ -306,7 +306,8 @@ def _linear_layer(cfg, chunk_lens, hidden, lp, rec, conv, at, interpret):
                                     interpret=interpret)
             o = o[:, None]
         else:
-            o, rec = gd.gdn_chunk(rec, q, k, v, g, beta, chunk_lens)
+            o, rec = gd.gdn_chunk(rec, q, k, v, g, beta, chunk_lens,
+                                  interpret=interpret)
         # Per head: RMSNorm over dv, gated by silu(z); float32 until the
         # out-projection's operand. z stays flat (dv alone is not whole
         # lanes: a reshape of it reaches back to its matrix's layout).
@@ -364,12 +365,18 @@ def forward(
             lambda x: jax.lax.dynamic_index_in_dim(x, at, 0, False), stack)
 
     # The recurrence's own scope. A prefill chunk's layer state is taken out
-    # of the rows' carried state and put back under it too, because XLA
-    # fuses the chunk's last pass into that update and names the fusion
-    # after it. A decode step hands the carry itself to ``gdn_step_at``,
-    # which steps its layer ``at`` in place: no layer of it is ever sliced
-    # out. The scope's time covers the read and the write the state's
-    # bytes count either way (benchmarks/chip/lib/shapes_hybrid.py).
+    # of the rows' carried state and put back under it too (``gdn_chunk``
+    # takes one layer's state and returns it in the same buffer; XLA names
+    # the update's fusion after the scope). What ``gdn_chunk`` leaves in
+    # ``o`` past a row's ``chunk_lens`` is never read: everything after it
+    # is token-wise or masks by ``chunk_lens`` (the conv state, the gates,
+    # attention's keys), and the runner takes a row's logits at
+    # ``chunk_lens - 1`` and writes K/V below ``chunk_lens`` only
+    # (engine/runner.py:_prefill_impl). A decode step hands the carry
+    # itself to ``gdn_step_at``, which steps its layer ``at`` in place: no
+    # layer of it is ever sliced out. The scope's time covers the read and
+    # the write the state's bytes count either way
+    # (benchmarks/chip/lib/shapes_hybrid.py).
     decode = token_ids.shape[1] == 1
     inner = "gdn_step" if decode else "gdn_chunk"
 

@@ -17,7 +17,10 @@ also the convolution of a Mamba-2 layer (ops/ssd.py), which adds a bias.
 64 tokens, the WY representation: HF's ``torch_chunk_gated_delta_rule`` is a
 plain statement of it): inside a chunk the tokens' updates are solved
 together as one unit-lower-triangular system (by forward substitution),
-between chunks the state is carried by a ``lax.scan``. Padded positions are inert: ``beta = 0, g = 0``
+between chunks the state is carried: by a ``lax.scan`` in the ``jnp`` form
+(``gdn_chunk_jnp``), in VMEM across a row's chunks in the Pallas kernel a
+TPU's prefill programs hold (ops/pallas/gated_delta.py:gdn_chunk_in_place;
+``gdn_chunk`` chooses). Padded positions are inert: ``beta = 0, g = 0``
 leave ``S`` untouched, and the conv state a row leaves is that of its last
 W - 1 *valid* tokens.
 
@@ -27,9 +30,9 @@ TPU's 128 lanes (dv = 192 alone would be stored as 256: a third more bytes
 to hold, read and write, every row, every layer, every step). ``gdn_step``
 computes in that layout (the per-head vectors are spread to it, which costs
 nothing beside the state's own traffic), on a TPU as one Pallas kernel in
-place in the decode loop's carried state (``gdn_step_at``);
-``gdn_chunk`` unpacks the state it starts from and packs the one it
-leaves, once a chunk.
+place in the decode loop's carried state (``gdn_step_at``); the chunk
+kernel works on a head's lanes of the packed state, and ``gdn_chunk_jnp``
+unpacks the state it starts from and packs the one it leaves, once a call.
 
 Everything here is float32: state, scores, accumulators. The chunkwise
 form's matrix products run at ``Precision.HIGHEST``: at the default a TPU
@@ -43,6 +46,7 @@ under an inner ``jax.named_scope`` (``gdn_step`` / ``gdn_chunk``) that a
 trace reader can split out of the caller's ``attn_core``.
 """
 
+import functools
 from typing import Tuple
 
 import jax
@@ -288,69 +292,110 @@ def gdn_chunk(state: jax.Array,   # [B, H/P, dk, P*dv] f32 packed, before the ch
               g: jax.Array,       # [B, T, H] f32
               beta: jax.Array,    # [B, T, H] f32
               lens: jax.Array,    # [B] valid tokens of each row
-              ) -> Tuple[jax.Array, jax.Array]:
+              *, interpret=False) -> Tuple[jax.Array, jax.Array]:
     """T tokens a row from ``state``: (o [B, T, H, dv] f32, the packed state
     after each row's last valid token). Equals ``delta_step`` applied to the
-    valid tokens in turn."""
+    valid tokens in turn; ``o`` past a row's length is not to be read (the
+    kernel leaves zeros there, the ``jnp`` form what the padding computes).
+
+    One algorithm, two executions, chosen as ``gdn_step_at`` chooses: where
+    the shapes fit it (``supports_chunk_kernel``: T whole chunks of 64 among
+    them), a program lowered for a TPU holds the Pallas kernel
+    (ops/pallas/gated_delta.py:gdn_chunk_in_place: a row's state stays in
+    VMEM across its chunks, chunks past a row's length are skipped), and so
+    does any program with ``interpret`` set; every other holds
+    ``gdn_chunk_jnp``."""
+    from production_stack_tpu.ops.pallas.gated_delta import (
+        gdn_chunk_in_place,
+        supports_chunk_kernel,
+    )
+
+    args = (state, q, k, v, g, beta, lens)
     with jax.named_scope("gdn_chunk"):
-        b, t, h, dk = q.shape
-        state = unpack_state(state, h)
-        dv = v.shape[-1]
-        c = min(CHUNK, t)
-        pad = -t % c
-        valid = (jnp.arange(t, dtype=jnp.int32)[None, :] < lens[:, None])
-        g = jnp.where(valid[..., None], g, 0.0)
-        beta = jnp.where(valid[..., None], beta, 0.0)
-        if pad:
-            q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                       for x in (q, k, v))
-            g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-                       for x in (g, beta))
-        n = (t + pad) // c
+        if not supports_chunk_kernel(q.shape[1], q.shape[2], state.shape[1:]):
+            return gdn_chunk_jnp(*args)
+        # The kernel's products at this form's precision, as it stands when
+        # the program is traced (a static argument: its jit cache keys on it).
+        kernel = functools.partial(gdn_chunk_in_place, precision=_HI)
+        if interpret:
+            return kernel(*args, interpret=True)
+        return jax.lax.platform_dependent(
+            *args, tpu=kernel, default=gdn_chunk_jnp)
 
-        def chunks(x):   # [B, T, H, ...] -> [N, B, H, C, ...]
-            x = x.reshape(b, n, c, *x.shape[2:])
-            return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
 
-        qc, kc, vc = chunks(q), chunks(k), chunks(v)
-        gc = jnp.cumsum(chunks(g), axis=-1)            # [N, B, H, C]
-        bc = chunks(beta)
-        k_beta = kc * bc[..., None]
-        v_beta = vc * bc[..., None]
-        tril = jnp.tril(jnp.ones((c, c), bool))
-        # exp(gc_i - gc_j) for i >= j; masked BEFORE the exp so the upper
-        # half (a positive exponent) cannot overflow.
-        decay = jnp.exp(jnp.where(
-            tril, gc[..., :, None] - gc[..., None, :], -jnp.inf))
-        # k_beta k^T and q k^T as ONE product (its rows split again below).
-        kq = jnp.einsum("nbhid,nbhjd->nbhij",
-                        jnp.concatenate([k_beta, qc], axis=-2), kc,
-                        precision=_HI) * jnp.concatenate(
-                            [decay, decay], axis=-2)
-        lower = jnp.where(jnp.tril(tril, -1), kq[..., :c, :], 0.0)
-        tmat = _unit_lower_inverse(lower)
-        u = jnp.einsum("nbhij,nbhjd->nbhid", tmat, v_beta, precision=_HI)
-        w = jnp.einsum("nbhij,nbhjd->nbhid", tmat,
-                       k_beta * jnp.exp(gc)[..., None], precision=_HI)
-        qk = jnp.where(tril, kq[..., c:, :], 0.0)
-        # w and the decayed q meet the state in one product a chunk.
-        wq = jnp.concatenate([w, qc * jnp.exp(gc)[..., None]], axis=-2)
-        g_last = gc[..., -1]                           # [N, B, H]
-        k_out = kc * jnp.exp(g_last[..., None] - gc)[..., None]
+def chunk_path(hlo_text: str):
+    """Which execution of ``gdn_chunk`` a compiled program (``as_text()``)
+    holds: ``"pallas"``, ``"xla"``, or None where it holds no chunk of the
+    recurrence."""
+    if "gdn_chunk_in_place" in hlo_text:
+        return "pallas"
+    return "xla" if "/gdn_chunk/" in hlo_text else None
 
-        def body(s, xs):
-            u_i, wq_i, qk_i, k_i, gl = xs
-            from_s = jnp.einsum("bhik,bhkv->bhiv", wq_i, s, precision=_HI)
-            v_new = u_i - from_s[..., :c, :]
-            o = from_s[..., c:, :] + jnp.einsum(
-                "bhij,bhjv->bhiv", qk_i, v_new, precision=_HI)
-            s = s * jnp.exp(gl)[..., None, None] + jnp.einsum(
-                "bhik,bhiv->bhkv", k_i, v_new, precision=_HI)
-            return s, o
 
-        state, out = jax.lax.scan(
-            body, state, (u, wq, qk, k_out, g_last))
-        # [N, B, H, C, dv] -> [B, T, H, dv]
-        out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)
-        out = out.reshape(b, n * c, h, dv)[:, :t]
-        return out, pack_state(state)
+def gdn_chunk_jnp(state, q, k, v, g, beta, lens):
+    """``gdn_chunk`` as plain ``jnp``: the statement of the chunkwise form,
+    the path of a backend without the kernel and of a T that is not whole
+    chunks, and the tests' oracle. The chunks' products are made for the
+    whole call at once, the state is carried between chunks by a
+    ``lax.scan``, and every padded position is computed."""
+    b, t, h, dk = q.shape
+    state = unpack_state(state, h)
+    dv = v.shape[-1]
+    c = min(CHUNK, t)
+    pad = -t % c
+    valid = (jnp.arange(t, dtype=jnp.int32)[None, :] < lens[:, None])
+    g = jnp.where(valid[..., None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+                   for x in (g, beta))
+    n = (t + pad) // c
+
+    def chunks(x):   # [B, T, H, ...] -> [N, B, H, C, ...]
+        x = x.reshape(b, n, c, *x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 2), 1, 0)
+
+    qc, kc, vc = chunks(q), chunks(k), chunks(v)
+    gc = jnp.cumsum(chunks(g), axis=-1)            # [N, B, H, C]
+    bc = chunks(beta)
+    k_beta = kc * bc[..., None]
+    v_beta = vc * bc[..., None]
+    tril = jnp.tril(jnp.ones((c, c), bool))
+    # exp(gc_i - gc_j) for i >= j; masked BEFORE the exp so the upper
+    # half (a positive exponent) cannot overflow.
+    decay = jnp.exp(jnp.where(
+        tril, gc[..., :, None] - gc[..., None, :], -jnp.inf))
+    # k_beta k^T and q k^T as ONE product (its rows split again below).
+    kq = jnp.einsum("nbhid,nbhjd->nbhij",
+                    jnp.concatenate([k_beta, qc], axis=-2), kc,
+                    precision=_HI) * jnp.concatenate(
+                        [decay, decay], axis=-2)
+    lower = jnp.where(jnp.tril(tril, -1), kq[..., :c, :], 0.0)
+    tmat = _unit_lower_inverse(lower)
+    u = jnp.einsum("nbhij,nbhjd->nbhid", tmat, v_beta, precision=_HI)
+    w = jnp.einsum("nbhij,nbhjd->nbhid", tmat,
+                   k_beta * jnp.exp(gc)[..., None], precision=_HI)
+    qk = jnp.where(tril, kq[..., c:, :], 0.0)
+    # w and the decayed q meet the state in one product a chunk.
+    wq = jnp.concatenate([w, qc * jnp.exp(gc)[..., None]], axis=-2)
+    g_last = gc[..., -1]                           # [N, B, H]
+    k_out = kc * jnp.exp(g_last[..., None] - gc)[..., None]
+
+    def body(s, xs):
+        u_i, wq_i, qk_i, k_i, gl = xs
+        from_s = jnp.einsum("bhik,bhkv->bhiv", wq_i, s, precision=_HI)
+        v_new = u_i - from_s[..., :c, :]
+        o = from_s[..., c:, :] + jnp.einsum(
+            "bhij,bhjv->bhiv", qk_i, v_new, precision=_HI)
+        s = s * jnp.exp(gl)[..., None, None] + jnp.einsum(
+            "bhik,bhiv->bhkv", k_i, v_new, precision=_HI)
+        return s, o
+
+    state, out = jax.lax.scan(
+        body, state, (u, wq, qk, k_out, g_last))
+    # [N, B, H, C, dv] -> [B, T, H, dv]
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)
+    out = out.reshape(b, n * c, h, dv)[:, :t]
+    return out, pack_state(state)

@@ -374,7 +374,7 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     program steps the recurrence in place in its loops' carried state
     (ops/pallas/gated_delta.py): no copy of the carry either."""
     from production_stack_tpu.engine.runner import _bucket
-    from production_stack_tpu.ops.gated_delta import step_path
+    from production_stack_tpu.ops.gated_delta import chunk_path, step_path
     from production_stack_tpu.ops.kv_write import pool_copies
 
     r = _described_runner(
@@ -397,16 +397,106 @@ def test_hybrid_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     carry = jax.ShapeDtypeStruct((32, 12, 15, 96, 384), jnp.float32)
     assert pool_copies(text, [r.kv_k, *r.state_pools, carry]) == []
     # The Mosaic kernels: the full layers' paged decode and the linear
-    # layers' step; of prefill, the full layers' flash kernel over the pool.
-    assert text.count('custom_call_target="tpu_custom_call"') == \
-        (2 if decode else 1)
+    # layers' step; of prefill, the full layers' flash kernel over the pool
+    # and the linear layers' chunkwise form.
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert step_path(text) == ("pallas" if decode else None)
+    assert chunk_path(text) == (None if decode else "pallas")
     mem = compiled.memory_analysis()
     # The rows' state is ONE loop carry (0.85 GB at 32 rows), not one a
     # layer, and the step kernel is aliased to it: the decode program's
     # temporaries stay under 1.5 GB (1.246 GB, as before the kernel).
     assert mem.temp_size_in_bytes < 1.5 * (1 << 30)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# The shapes a prefill dispatch of olmo-hybrid-7b-d16's deployment has: its
+# eight families (1 x {128..2048}, 8 x {128, 256}, 16 x 128; T is always whole
+# chunks of 64).
+HYBRID_PREFILL_FAMILIES = [(1, 128), (1, 256), (1, 512), (1, 1024),
+                           (1, 2048), (8, 128), (8, 256), (16, 128)]
+
+
+@pytest.mark.parametrize("rows,t", [(16, 128), (8, 256), (8, 128), (1, 2048)])
+def test_gdn_chunk_kernel_compiles_for_v5e(v5e, rows, t):
+    """The chunkwise kernel alone (ops/pallas/gated_delta.py) at
+    Olmo-Hybrid-7B's published head shapes, 30 x 96 x 192: it compiles for
+    a v5e (VMEM: a row's 2.2 MB state in and out beside a chunk's blocks),
+    and the state it returns is the buffer it was given."""
+    from production_stack_tpu.ops.pallas.gated_delta import (
+        gdn_chunk_in_place,
+        supports_chunk_kernel,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    h, dk, dv = 30, 96, 192
+    assert supports_chunk_kernel(t, h, (15, 96, 384))
+    text = gdn_chunk_in_place.lower(
+        sds(rows, 15, 96, 384), sds(rows, t, h, dk), sds(rows, t, h, dk),
+        sds(rows, t, h, dv), sds(rows, t, h), sds(rows, t, h),
+        sds(rows, dtype=jnp.int32)).compile().as_text()
+    call = [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(call) == 1 and "gdn_chunk_in_place" in call[0]
+    assert "output_to_operand_aliasing={{1}: (5, {})}" in call[0]
+
+
+def test_the_smoke_times_the_whole_jnp_chunk_form_on_v5e(v5e):
+    """``chip_smoke.py --gdn`` chains calls of a form of ``gdn_chunk``
+    through the state with the same q, k, v and gates every call. Compiled
+    for a v5e, the program of the ``jnp`` form holds its three loops (the
+    calls, the 63-trip substitution, the scan over chunks) with only the
+    first in the entry computation: nothing the form computes is lifted out
+    of the timed loop and done once for all the calls."""
+    import chip_smoke
+    from production_stack_tpu.ops.gated_delta import gdn_chunk_jnp
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    t, h, dk, dv = 128, 30, 96, 192
+    text = jax.jit(chip_smoke.chained_chunks(
+        gdn_chunk_jnp, jnp.array([t], jnp.int32), 4)).lower(
+            sds(1, 15, 96, 384), sds(1, t, h, dk), sds(1, t, h, dk),
+            sds(1, t, h, dv), sds(1, t, h), sds(1, t, h)).compile().as_text()
+    entry = next(c for c in text.split("\n\n") if c.startswith("ENTRY"))
+    assert text.count(" while(") == 3 and entry.count(" while(") == 1
+
+
+@pytest.mark.parametrize("rows,t", HYBRID_PREFILL_FAMILIES)
+def test_hybrid_prefill_programs_hold_the_chunk_kernel_on_v5e(v5e, rows, t):
+    """Every prefill family of olmo-hybrid-7b-d16's deployment holds the
+    chunkwise kernel: under the recurrence's scope no loop is left (the
+    63-trip substitution, the scan over chunks) and nothing is copied or
+    transposed (q, k and v reach the kernel from the fusions that make
+    them, the state as the slice of the rows' carried state), and the state
+    pools are updated in place."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.gated_delta import chunk_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "olmo-hybrid-7b-d16")
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    assert [f[:2] for f in r.reachable_prefill_families()] == \
+        HYBRID_PREFILL_FAMILIES
+    text = r._lower_prefill(
+        r._abstract_params(), rows, t, full_mb, False).compile().as_text()
+    assert chunk_path(text) == "pallas"
+    assert pool_copies(text, [r.kv_k, *r.state_pools]) == []
+    # Operations under the recurrence's scope (a result's type, a tuple's
+    # too, ends at the last "} " or ") " before the operation's name).
+    ops = {m.group(1) for m in (
+        re.search(r" = (?:\(.*?\)|\S+) ([a-z][\w\-]*)\(", ln)
+        for ln in text.splitlines() if "/gdn_chunk/" in ln) if m}
+    assert "custom-call" in ops
+    assert not ops & {"while", "copy", "copy-start", "transpose"}, ops
 
 
 # ---- granite-4.0-h-micro: state-space layers beside 64-lane attention heads
@@ -490,34 +580,40 @@ def test_state_space_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
-# The decode program of every OTHER configuration at its deployment's widest
-# bucket, compiled for a described v5e: (rows, instructions). None holds a
-# state-space layer, so a change to ops/ssd.py or ops/pallas/ssd.py leaves
-# each as it was (PR 41: the parent's numbers, to the instruction).
-OTHER_DECODE_PROGRAMS = {
-    "qwen2.5-3b": (64, 2047),
-    "mistral-7b-d16": (16, 2007),
-    "olmo-hybrid-7b-d16": (32, 2749),
-    "kanana-2-30b-a3b-d8": (64, 4003),
-    "xing4.0-29b-a4b-d7": (64, 8260),
+# The decode program of every configuration at its deployment's widest
+# bucket, compiled for a described v5e: (rows, instructions, the state-space
+# step it holds). A change to one recurrence's operations leaves every
+# program that does not run them as it was, to the instruction: PR 41
+# (ops/ssd.py, ops/pallas/ssd.py) the five without a state-space layer, PR 42
+# (the chunkwise form of ops/gated_delta.py, which no decode program runs)
+# all six.
+DECODE_PROGRAMS = {
+    "qwen2.5-3b": (64, 2047, None),
+    "mistral-7b-d16": (16, 2007, None),
+    "olmo-hybrid-7b-d16": (32, 2749, None),
+    "kanana-2-30b-a3b-d8": (64, 4003, None),
+    "xing4.0-29b-a4b-d7": (64, 8260, None),
+    "granite-4.0-h-micro": (32, 2730, "pallas"),
 }
 
 
-@pytest.mark.parametrize("name", list(OTHER_DECODE_PROGRAMS))
+@pytest.mark.parametrize("name", list(DECODE_PROGRAMS))
 def test_decode_programs_without_the_scan_are_unchanged_on_v5e(v5e, name):
     """A configuration with no state-space layer holds no step of the scan,
-    and its decode program counts the instructions it did."""
+    no decode program holds a chunk of the gated delta rule, and each counts
+    the instructions it did."""
     from production_stack_tpu.engine.runner import _bucket
-    from production_stack_tpu.ops import ssd
+    from production_stack_tpu.ops import gated_delta, ssd
 
-    rows, instructions = OTHER_DECODE_PROGRAMS[name]
+    rows, instructions, scan_step = DECODE_PROGRAMS[name]
     r = _deployment_runner(v5e, name)
     assert r._b_max == rows
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     text = r._lower_decode(
         r._abstract_params(), rows, full_mb, 32, False).compile().as_text()
-    assert ssd.step_path(text) is None
+    assert ssd.step_path(text) == scan_step
+    assert gated_delta.chunk_path(text) is None
     assert sum(1 for ln in text.splitlines() if " = " in ln) == instructions
 
 

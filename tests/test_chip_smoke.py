@@ -306,10 +306,15 @@ def test_gdn_phase_rehearses_on_the_cpu():
     line = json.loads([ln for ln in proc.stdout.splitlines()
                        if ln.startswith("{")][-1])
     assert line["phase"] == "gdn" and line["ok"]
-    assert [t["op"] for t in line["timing"]] == \
-        ["gdn_step", "gdn_step", "gdn_chunk"]
+    # Two step shapes; two chunk shapes, each as the kernel (interpreted
+    # here) and as the ``jnp`` form, checked against each other.
+    assert [(t["op"], t.get("form")) for t in line["timing"]] == \
+        [("gdn_step", None)] * 2 + [("gdn_chunk", "kernel"),
+                                    ("gdn_chunk", "jnp")] * 2
+    assert [t["path"] for t in line["timing"][2:]] == ["pallas", None] * 2
     assert all(t["bytes"] > 0 and t["flops"] > 0
                and t["us_per_call"] is None for t in line["timing"])
+    assert all(c["chunk_max_abs_err"] < 1e-4 for c in line["checks"][2:])
 
 
 def test_prefill_phase_rehearses_on_the_cpu():

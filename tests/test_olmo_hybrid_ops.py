@@ -5,6 +5,7 @@ reference, and what ``layer_types`` may be."""
 
 import dataclasses
 import filecmp
+import functools
 import os
 
 import jax
@@ -14,26 +15,65 @@ import pytest
 
 from production_stack_tpu.models.config import TINY_OLMO_HYBRID, ModelConfig
 from production_stack_tpu.ops import gated_delta as gd
-from production_stack_tpu.ops.pallas.gated_delta import supports_step_kernel
+from production_stack_tpu.ops.pallas.gated_delta import (
+    supports_chunk_kernel,
+    supports_step_kernel,
+)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---- (g): the chunkwise form is the recurrence ------------------------------
-# The two executions of the decode step (ops/gated_delta.py:gdn_step_at):
-# the Pallas kernel, here through the interpreter, and the ``jnp`` form, which
-# is what a program lowered for a CPU holds otherwise.
+# The two executions of the recurrence (ops/gated_delta.py:gdn_step_at and
+# gdn_chunk): the Pallas kernels, here through the interpreter, and the
+# ``jnp`` forms, which are what a program lowered for a CPU holds otherwise.
 PATHS = pytest.mark.parametrize(
     "interpret", [False, True], ids=["xla", "pallas"])
 
 
+@functools.partial(jax.jit, static_argnames="interpret")
+def _token_by_token(state0, q, k, v, g, beta, lens, interpret):
+    """The step on the packed state (what decode runs) over the valid
+    tokens in turn, and beside it the plain per-head recurrence: (packed
+    state, plain state, o [B, T, H, dv], the largest difference of a valid
+    token's o between the two)."""
+    def one(carry, xs):
+        packed, want = carry
+        i, q_i, k_i, v_i, g_i, b_i = xs
+        live = i < lens
+        o, packed = gd.gdn_step(packed, q_i, k_i, v_i, g_i, b_i, live,
+                                interpret=interpret)
+        o_plain, stepped = gd.delta_step(want, q_i, k_i, v_i, g_i, b_i)
+        want = jnp.where(live[:, None, None, None], stepped, want)
+        return (packed, want), (o, jnp.max(jnp.abs(
+            (o - o_plain) * live[:, None, None])))
+
+    t = q.shape[1]
+    (packed, want), (outs, errs) = jax.lax.scan(
+        one, (gd.pack_state(state0), state0),
+        (jnp.arange(t), *(jnp.moveaxis(x, 1, 0)
+                          for x in (q, k, v, g, beta))))
+    return packed, want, jnp.moveaxis(outs, 0, 1), jnp.max(errs)
+
+
+# (T, the rows' lengths). The first five are no whole chunks of 64 or one
+# alone: the ``jnp`` form whatever the execution asked for, but for T = 64.
+# The last three are the shapes a prefill dispatch has (T whole chunks: the
+# kernel where it is asked for), every kind of row in one batch: none of its
+# tokens valid, one, a chunk less one, a whole chunk, a chunk and one, all.
+CHUNK_CASES = [(t, (t, max(t - 5, 0))) for t in (1, 63, 64, 65, 200)] + [
+    (t, (0, 1, 63, 64, 65, t)) for t in (128, 256, 2048)]
+
+
 @PATHS
-@pytest.mark.parametrize("t", [1, 63, 64, 65, 200])
-def test_g_gdn_chunk_is_gdn_step_applied_t_times(t, interpret):
-    b, h, dk, dv = 2, 4, 16, 32
+@pytest.mark.parametrize("t,lens", CHUNK_CASES,
+                         ids=[f"T{t}x{len(n)}" for t, n in CHUNK_CASES])
+def test_g_gdn_chunk_is_gdn_step_applied_t_times(t, lens, interpret):
+    b, h, dk, dv = len(lens), 4, 16, 32
     ks = jax.random.split(jax.random.PRNGKey(t), 7)
     q, k = (jax.random.normal(ks[i], (b, t, h, dk)) for i in (0, 1))
     v = jax.random.normal(ks[2], (b, t, h, dv))
+    # Gates that allow negative eigenvalues: beta in (0, 2), |beta k.k| <= 2.
     beta, g = gd.gates(
         jax.random.normal(ks[3], (b, t, h)),
         jax.random.normal(ks[4], (b, t, h)),
@@ -41,30 +81,43 @@ def test_g_gdn_chunk_is_gdn_step_applied_t_times(t, interpret):
         jnp.ones((h,)), True)
     q, k, v = gd.prepare(q, k, v)
     state0 = 0.5 * jax.random.normal(ks[6], (b, h, dk, dv))
-    lens = jnp.array([t, max(t - 5, 0)])
-    out, state = gd.gdn_chunk(gd.pack_state(state0), q, k, v, g, beta, lens)
-    assert state.shape == (b, *gd.packed_shape(h, dk, dv)) == (b, 1, dk, 128)
-    state = gd.unpack_state(state, h)
-    # The step on the packed state (what decode runs), and beside it the
-    # plain per-head recurrence, which it has to equal to the last bit of
-    # a float32 sum.
-    want_state, packed, outs = state0, gd.pack_state(state0), []
-    for i in range(t):
-        live = i < lens
-        o, packed = gd.gdn_step(packed, q[:, i], k[:, i], v[:, i], g[:, i],
-                                beta[:, i], live, interpret=interpret)
-        o_plain, stepped = gd.delta_step(
-            want_state, q[:, i], k[:, i], v[:, i], g[:, i], beta[:, i])
-        want_state = jnp.where(live[:, None, None, None], stepped, want_state)
-        assert float(jnp.max(jnp.abs(
-            (o - o_plain) * live[:, None, None]))) < 1e-6
-        outs.append(o)
+    lens = jnp.array(lens)
+    args = (gd.pack_state(state0), q, k, v, g, beta, lens)
+    kernel = interpret and supports_chunk_kernel(t, h, args[0].shape[1:])
+    assert kernel == (interpret and t % 64 == 0)
+    text = jax.jit(gd.gdn_chunk, static_argnames="interpret").lower(
+        *args, interpret=interpret).as_text()
+    assert ("gdn_chunk_in_place" in text) == kernel
+    out, packed = gd.gdn_chunk(*args, interpret=interpret)
+    assert packed.shape == (b, *gd.packed_shape(h, dk, dv)) == (b, 1, dk, 128)
+    state = gd.unpack_state(packed, h)
+    stepped, want_state, outs, step_err = _token_by_token(
+        state0, q, k, v, g, beta, lens, interpret)
+    # The step on the packed state equals the plain recurrence to the last
+    # bit of a float32 sum.
+    assert float(step_err) < 1e-6
     assert float(jnp.max(jnp.abs(
-        gd.unpack_state(packed, h) - want_state))) < 1e-6
+        gd.unpack_state(stepped, h) - want_state))) < 1e-6
     valid = (jnp.arange(t)[None, :] < lens[:, None])[..., None, None]
-    # Float32 both sides, sums in another order: 1e-5 of values of order 1.
-    assert float(jnp.max(jnp.abs((out - jnp.stack(outs, 1)) * valid))) < 1e-5
-    assert float(jnp.max(jnp.abs(state - want_state))) < 1e-5
+    # Float32 both sides, sums in another order: 1e-5 of values of order 1,
+    # absolute, at every T (the largest read here: 5.0e-6 of o at T = 2048
+    # in the ``jnp`` form, 2.3e-6 in the kernel; under 1e-6 below T = 2048).
+    tol = 1e-5
+    assert float(jnp.max(jnp.abs((out - outs) * valid))) < tol
+    assert float(jnp.max(jnp.abs(state - want_state))) < tol
+    if kernel:
+        # Against the ``jnp`` form on the same inputs; a row with no valid
+        # token keeps its state bit for bit, and what the kernel skips (a
+        # chunk past its row's length) is zeros.
+        want_out, want_packed = gd.gdn_chunk_jnp(*args)
+        assert float(jnp.max(jnp.abs((out - want_out) * valid))) < tol
+        assert float(jnp.max(jnp.abs(packed - want_packed))) < tol
+        dead = np.asarray(lens) == 0
+        np.testing.assert_array_equal(
+            np.asarray(packed)[dead], np.asarray(args[0])[dead])
+        skipped = jnp.arange(t)[None, :] >= -(-lens[:, None] // 64) * 64
+        np.testing.assert_array_equal(
+            np.asarray(out)[np.asarray(skipped)], 0.0)
 
 
 # ---- the decode step's kernel (ops/pallas/gated_delta.py), interpreted ------
@@ -181,6 +234,75 @@ def test_the_step_falls_back_where_the_packed_shape_does_not_fit(
     o, got = gd.gdn_step_at(carry, *args, interpret=True)
     want_o, want = gd.gdn_step_at(carry, *args)
     assert _relative(o, want_o) < 1e-5 and _relative(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("h,dk,dv", [(30, 96, 192), (4, 64, 128)],
+                         ids=["published-P2", "P1"])
+def test_chunk_kernel_is_the_jnp_form_at_other_packings(h, dk, dv):
+    """The chunk kernel through the interpreter against the ``jnp`` form at
+    Olmo-Hybrid-7B's published widths (two heads a packed row, head slices
+    that start off a lane tile) and at one head a row: a full row, a row
+    that ends inside its second chunk, a row with no token."""
+    b, t = 3, 128
+    carry, q, k, v, g, beta = _step_inputs(h + 1, b, h, dk, dv, t)
+    state, lens = carry[:, 0], jnp.array([t, 70, 0])
+    assert supports_chunk_kernel(t, h, state.shape[1:])
+    o, got = gd.gdn_chunk(state, q, k, v, g, beta, lens, interpret=True)
+    want_o, want = gd.gdn_chunk_jnp(state, q, k, v, g, beta, lens)
+    valid = (jnp.arange(t)[None, :] < lens[:, None])[..., None, None]
+    assert _relative(o * valid, want_o * valid) < 1e-5
+    assert _relative(got, want) < 1e-5
+    np.testing.assert_array_equal(got[2], state[2])
+    np.testing.assert_array_equal(o[2], 0.0)
+
+
+@pytest.mark.parametrize("t,h,dk,dv,fits", [
+    (128, 30, 96, 192, True), (2048, 4, 16, 32, True),
+    (32, 30, 96, 192, False),    # T is not whole chunks (check_reference's row)
+    (128, 3, 64, 128, False),    # heads do not pair
+    (128, 4, 12, 128, False),    # dk is not whole sublanes
+    (128, 6, 64, 96, False),     # P*dv is not whole lanes
+    (128, 128, 64, 128, False),  # the gates of 128 heads pass one lane tile
+], ids=lambda x: str(x))
+def test_the_chunk_falls_back_where_the_shapes_do_not_fit(t, h, dk, dv, fits):
+    """Which execution of ``gdn_chunk`` a program holds is decided by the
+    shapes alone (and the backend): what the kernel does not take gets the
+    ``jnp`` form even with the interpreter on."""
+    sds = jax.ShapeDtypeStruct
+    packed = gd.packed_shape(h, dk, dv)
+    assert supports_chunk_kernel(t, h, packed) == fits
+    args = (sds((2, *packed), jnp.float32),
+            *(sds((2, t, h, d), jnp.float32) for d in (dk, dk, dv)),
+            sds((2, t, h), jnp.float32), sds((2, t, h), jnp.float32),
+            sds((2,), jnp.int32))
+    text = jax.jit(gd.gdn_chunk, static_argnames="interpret").lower(
+        *args, interpret=True).as_text()
+    assert ("gdn_chunk_in_place" in text) == fits
+    # Without the interpreter a program lowered for a CPU holds the jnp form.
+    assert "gdn_chunk_in_place" not in jax.jit(gd.gdn_chunk).lower(
+        *args).as_text()
+
+
+def test_the_chunk_kernel_takes_the_precision_it_is_traced_under(monkeypatch):
+    """The kernel's products run at the ``jnp`` form's precision as it
+    stands when a program is traced (``check_reference.py``'s control lowers
+    it to the default to show that the default fails): a static argument of
+    the kernel's jit, so a program traced after the change holds it with no
+    cache cleared."""
+    sds = jax.ShapeDtypeStruct
+    args = (sds((2, 1, 16, 128), jnp.float32),
+            *(sds((2, 64, 4, d), jnp.float32) for d in (16, 16, 32)),
+            sds((2, 64, 4), jnp.float32), sds((2, 64, 4), jnp.float32),
+            sds((2,), jnp.int32))
+    assert gd.CHUNK == 64
+
+    def lowered():
+        return jax.jit(lambda *a: gd.gdn_chunk(*a, interpret=True)).lower(
+            *args).as_text()
+
+    assert "HIGHEST" in lowered()
+    monkeypatch.setattr(gd, "_HI", jax.lax.Precision.DEFAULT)
+    assert "HIGHEST" not in lowered()
 
 
 # ---- the reference itself ----------------------------------------------------
