@@ -9,20 +9,12 @@ was three passes over every row of the bucket (decay and ``S^T k``; the
 rank-one update, fused into the carry's ``dynamic_update_slice``;
 ``S^T q``), live or not: four times the bytes (PERF.md §6, PR 32).
 
-  * The carry stays in HBM and is ALIASED to the kernel's output: nothing
-    of its size is allocated, copied, sliced out or put back. The kernel
-    DMAs blocks of ``HB`` packed heads ``[HB, dk, P*dv]`` of a live row's
-    slab (contiguous: a slab is ``[H/P, dk, P*dv]``) into one of
-    ``NUM_BUFS`` VMEM buffers, updates the block there and DMAs it back to
-    where it came from.
-  * The call's live blocks form ONE sequence, row after row: while block n
-    is computed, block n + 1 (the same row's next one or the next LIVE
-    row's first) is in flight into the next buffer and block n - 1 on its
-    way out of the one before. Buffers, semaphores and the compacted list
-    of live rows are scratch, which outlives a program; the grid axis (row
-    chunks, one chunk where the small operands fit VMEM) is sequential.
-  * A row that is not live moves no byte of state: it is not in the list.
-    Its ``o`` is zeros.
+  * The data movement is ops/pallas/live_blocks.py's: the carry aliased
+    and left in HBM, blocks of ``HB`` packed heads ``[HB, dk, P*dv]`` of a
+    live row's slab (contiguous: a slab is ``[H/P, dk, P*dv]``) through
+    ``NUM_BUFS`` VMEM buffers as one sequence over the call's live rows, one
+    in flight towards the block that is computed.
+  * A row that is not live moves no byte of state. Its ``o`` is zeros.
   * Arithmetic: float32 on the vector unit, a packed head
     ``[dk, P*dv]`` (36 vregs at 96 x 384) at a time: ``S *= exp(g)``;
     ``kv = S^T k``; ``u = (v - kv) beta``; ``S += k u^T``; ``o = S^T q``.
@@ -52,15 +44,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from production_stack_tpu.ops.pallas.live_blocks import (
+    OPERAND_BYTES,
+    live_blocks,
+    step_call,
+)
+
 # On a v5e, 20 rows of [15, 96, 384] a layer-step (PERF.md §6, PR 32): 2 / 3 /
 # 4 buffers 188 / 149 / 150 us; blocks of 1 / 3 / 5 / 15 packed heads 222 /
 # 162 / 149 / 145 us (about 0.3 us a block of fixed cost).
 NUM_BUFS = 3             # one block coming in, one computed, one going out
 BLOCK_BYTES = 1 << 20    # largest state block (a buffer): 5 packed heads of
                          # 96 x 384, so a 15-head slab is three blocks
-OPERAND_BYTES = 6 << 20  # VMEM the per-row operands of one program may take,
-                         # both copies Pallas keeps of a block: 32 rows of
-                         # 30 heads are one program, 64 rows two
+FETCH_AHEAD = 1          # blocks in flight towards the one computed
 LANES, SUBLANES = 128, 8
 
 
@@ -88,12 +84,6 @@ def _heads_per_block(hp: int, dk: int, pdv: int) -> int:
                if hp % n == 0 and n * dk * pdv * 4 <= BLOCK_BYTES)
 
 
-def _rows_per_program(b: int, row_bytes: int) -> int:
-    return max(n for n in range(1, b + 1)
-               if b % n == 0 and (n == 1 or 2 * n * row_bytes
-                                  <= OPERAND_BYTES))
-
-
 def _step_kernel(
     # scalar prefetch
     at_ref,        # SMEM [1] int32: which layer of the carry
@@ -106,60 +96,16 @@ def _step_kernel(
     # outputs
     o_ref,         # VMEM [RB, H/P, P*dv] f32
     s_out,         # HBM: the carry again (aliased to s_in)
-    # scratch (outlives a program)
+    # scratch: live_blocks', of which the kernel touches the buffers
     buf,           # VMEM [NUM_BUFS, HB, dk, P*dv] f32
-    sem_in,        # DMA (NUM_BUFS,)
-    sem_out,       # DMA (NUM_BUFS,)
-    rows_ref,      # SMEM [B] int32: the live rows, in order
-    count_ref,     # SMEM [1] int32: how many
-    *,
+    *scratch,
     pack: int,
 ):
-    pid = pl.program_id(0)
-    num_rows = live_ref.shape[0]
-    rb = o_ref.shape[0]
     _, hb, dk, pdv = buf.shape
     dv = pdv // pack
-    nb = s_in.shape[2] // hb             # blocks a row
     tile = _tile_rows(pack)
-    at = at_ref[0]
-
-    @pl.when(pid == 0)
-    def _():
-        def add(b, n):
-            @pl.when(live_ref[b] != 0)
-            def _():
-                rows_ref[n] = b
-            return n + (live_ref[b] != 0).astype(jnp.int32)
-
-        count_ref[0] = jax.lax.fori_loop(0, num_rows, add, jnp.int32(0))
-
-    def live_below(row):
-        return jax.lax.fori_loop(
-            0, row, lambda b, n: n + (live_ref[b] != 0).astype(jnp.int32),
-            jnp.int32(0))
-
-    total = count_ref[0] * nb            # live blocks of the call
-    lo = live_below(pid * rb)            # live rows before this program's
-    hi = live_below(pid * rb + rb)       # and up to its last
-
-    def block(n):
-        # (row, first packed head) of the call's n-th live block.
-        li = n // nb
-        return (rows_ref[jnp.minimum(li, num_rows - 1)], (n - li * nb) * hb)
-
-    def fetch(n):
-        row, h0 = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        return pltpu.make_async_copy(
-            s_in.at[row, at, pl.ds(h0, hb)], buf.at[slot], sem_in.at[slot])
-
-    def store(n):
-        row, h0 = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        return pltpu.make_async_copy(
-            buf.at[slot], s_out.at[row, at, pl.ds(h0, hb)], sem_out.at[slot])
-
+    run = live_blocks(at_ref, live_ref, s_in, s_out, buf, *scratch,
+                      rows=o_ref.shape[0], fetch_ahead=FETCH_AHEAD)
     lane_head = jax.lax.broadcasted_iota(jnp.int32, (1, pdv), 1) // dv
 
     def spread(parts, rows):
@@ -172,26 +118,8 @@ def _step_kernel(
 
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    def step(n, carry):
-        row, h0 = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        r = row - pid * rb
-
-        @pl.when(n == 0)
-        def _():
-            fetch(n).start()
-
-        # The next block goes in flight now, into the buffer that the
-        # block NUM_BUFS before it left: whose write-back has to have
-        # landed first.
-        @pl.when(n + 1 < total)
-        def _():
-            @pl.when(n + 1 >= NUM_BUFS)
-            def _():
-                store(n + 1 - NUM_BUFS).wait()
-            fetch(n + 1).start()
-
-        fetch(n).wait()
+    def compute(n, row, j, slot, r):
+        h0 = j * hb
 
         def head(h, carry):
             hp = h0 + h
@@ -216,18 +144,8 @@ def _step_kernel(
             return carry
 
         jax.lax.fori_loop(0, hb, head, 0)
-        store(n).start()
-        return carry
 
-    jax.lax.fori_loop(lo * nb, hi * nb, step, 0)
-
-    # The call's last write-backs: those no later block waited for.
-    @pl.when(pid == pl.num_programs(0) - 1)
-    def _():
-        for back in range(NUM_BUFS, 0, -1):
-            @pl.when(total >= back)
-            def _():
-                store(total - back).wait()
+    run(compute)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -267,45 +185,14 @@ def gdn_step_in_place(
     kq = jnp.pad(kq.reshape(b, used, LANES),
                  ((0, 0), (0, n_rows - used), (0, 0)))
     hb = _heads_per_block(hp, dk, pdv)
-    rb = _rows_per_program(b, (n_rows * LANES + 2 * hp * pdv) * 4)
-
-    def rows(*shape):
-        return pl.BlockSpec((rb, *shape), lambda i, *_: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    o, carry = pl.pallas_call(
+    o, carry = step_call(
         functools.partial(_step_kernel, pack=p),
-        out_shape=[jax.ShapeDtypeStruct((b, hp, pdv), jnp.float32),
-                   jax.ShapeDtypeStruct(carry.shape, carry.dtype)],
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b // rb,),
-            in_specs=[
-                rows(n_rows, LANES),
-                rows(hp, pdv),
-                pl.BlockSpec(memory_space=pl.ANY),   # the carry stays in HBM
-            ],
-            out_specs=[rows(hp, pdv), pl.BlockSpec(memory_space=pl.ANY)],
-            scratch_shapes=[
-                pltpu.VMEM((NUM_BUFS, hb, dk, pdv), jnp.float32),
-                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
-                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
-                pltpu.SMEM((b,), jnp.int32),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        # at, live, kq, v, carry -> (o, carry): in place.
-        input_output_aliases={4: 1},
-        # Programs run in order: each hands its buffers to the next.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="gdn_step_in_place",
-    )(
-        jnp.asarray(at, jnp.int32).reshape(1), live.astype(jnp.int32),
-        kq,
-        v.reshape(b, hp, pdv).astype(jnp.float32), carry,
-    )
+        (jnp.asarray(at, jnp.int32).reshape(1), live.astype(jnp.int32)),
+        (kq, v.reshape(b, hp, pdv).astype(jnp.float32)), carry,
+        out_row=(hp, pdv), heads_per_block=hb, num_bufs=NUM_BUFS,
+        row_bytes=(n_rows * LANES + 2 * hp * pdv) * 4,
+        operand_bytes=OPERAND_BYTES, name="gdn_step_in_place",
+        interpret=interpret)
     return o.reshape(b, h, dv), carry
 
 

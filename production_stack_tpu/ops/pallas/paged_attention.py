@@ -93,6 +93,150 @@ def super_tokens(num_kv_heads: int, head_dim: int, itemsize: int,
     return tokens
 
 
+class _PageFetch:
+    """How pages of a row reach VMEM, stated once for the four kernels
+    below (trace time: built inside a kernel from its refs).
+
+    A page is one copy a STREAM: ``streams`` lists ``(pool ref [L, heads,
+    rows, lanes], buffer ref [NUM_BUFS, heads, a superpage's rows, lanes],
+    DMA semaphores (NUM_BUFS,))``, K and V for the K/V kernels, the one
+    latent pool for the others, and ``rows_per_page`` is what a page takes
+    of a pool's row axis (``block_size``, over PACK where tokens are packed
+    into whole lanes). Which buffer a superpage goes to, and who issues a
+    program's first, is the hand-off's (``_superpage_sequence`` for the
+    decode kernels, ``_tile_sequence`` for the prefill kernels)."""
+
+    def __init__(self, streams, rows_per_page, *, block_size, super_tokens,
+                 layer, block_tables_ref, kv_lens_ref):
+        self.streams, self.rpp = streams, rows_per_page
+        self.bs, self.sup = block_size, super_tokens
+        self.spp = super_tokens // block_size   # pages per superpage
+        self.layer = layer
+        self.block_tables_ref, self.kv_lens_ref = block_tables_ref, kv_lens_ref
+
+    def pages_of(self, row, s):
+        # Pages of ``row`` that superpage s holds.
+        return jnp.clip(pl.cdiv(self.kv_lens_ref[row], self.bs)
+                        - s * self.spp, 0, self.spp)
+
+    def start(self, row, s, slot):
+        # Issue page-granular DMAs for superpage s of ``row`` into buffer
+        # ``slot`` (pages are scattered in the pool; each is contiguous),
+        # all in flight at once. The loops run over the row's own pages and
+        # no further (``gp`` an iteration, then the odd ones): a short row
+        # pays for what it holds, and the kernel's code stays a few pages
+        # long where an unrolled superpage was 32 branches.
+        rpp, spp = self.rpp, self.spp
+        gp = min(ISSUE_UNROLL, spp)     # pages per fetch-loop iteration
+        pages = self.pages_of(row, s)
+
+        def issue(i):
+            src = pl.ds(self.block_tables_ref[row, s * spp + i] * rpp, rpp)
+            dst = pl.ds(pl.multiple_of(i * rpp, rpp), rpp)
+            for pool, buf, sem in self.streams:
+                pltpu.make_async_copy(
+                    pool.at[self.layer, :, src], buf.at[slot, :, dst],
+                    sem.at[slot],
+                ).start()
+
+        def issue_group(gi, carry):
+            for j in range(gp):
+                issue(gi * gp + j)
+            return carry
+
+        def issue_page(i, carry):
+            issue(i)
+            return carry
+
+        jax.lax.fori_loop(0, pages // gp, issue_group, 0)
+        jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
+
+    def start_run(self, sources, row, at, rows, slot):
+        # ``rows`` contiguous rows from ``at`` of ``row`` in ``sources``
+        # ([heads, B, T, lanes], one a stream: a prefill chunk's own keys)
+        # to the head of buffer ``slot``: one copy a stream.
+        src = pl.ds(pl.multiple_of(at, rows), rows)
+        for source, (_, buf, sem) in zip(sources, self.streams):
+            pltpu.make_async_copy(
+                source.at[:, row, src], buf.at[slot, :, pl.ds(0, rows)],
+                sem.at[slot],
+            ).start()
+
+    def wait(self, pages, slot):
+        # A DMA semaphore counts bytes, and a buffer's page copies all signal
+        # the one semaphore of that buffer. So the wait is for the BYTES of
+        # ``pages`` pages, taken in power-of-two runs of pages (the run
+        # lengths present in the page count's binary form): at most two
+        # waits a run, whatever order the pages land in.
+        run = self.spp
+        while run:
+            @pl.when(pages & run != 0)
+            def _():
+                span = pl.ds(0, run * self.rpp)
+                for pool, buf, sem in self.streams:
+                    pltpu.make_async_copy(
+                        pool.at[0, :, span], buf.at[slot, :, span],
+                        sem.at[slot],
+                    ).wait()
+            run //= 2
+
+
+def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first):
+    """The decode kernels' hand-off of the superpage buffers from row to
+    row: the call's superpages form ONE sequence across rows, superpage n of
+    it in buffer n % NUM_BUFS, and row ``b`` (``n_super`` superpages of
+    ``kv_len`` keys) starts at ``first``, in the buffer its predecessors
+    left free, whatever their lengths were. Clears ``cleared`` once a call,
+    issues the row's first superpage where no row before it did, and
+    returns ``advance(s)``: puts what computes after superpage s in flight,
+    waits for s and returns its buffer."""
+    kv_lens_ref = fetch.kv_lens_ref
+    num_rows = kv_lens_ref.shape[0]
+
+    # What a row does not fetch it still computes over: whole superpages,
+    # and a masked key's softmax weight (0) must not meet a non-finite value
+    # there: 0 * NaN = NaN inside the PV contraction would poison the row.
+    # (A masked SCORE is replaced, not multiplied, so K may hold anything.)
+    # Only what the call finds in its value buffers can be non-finite; what
+    # its rows leave behind is KV, finite like the pool. So they are cleared
+    # once a call, before the first DMA is in flight, and stale keys stay
+    # where they are.
+    @pl.when(b == 0)
+    def _():
+        cleared[...] = jnp.zeros(cleared.shape, cleared.dtype)
+
+    # A live row's last iteration issues the next row's first superpage, so
+    # only the call's first row, and a row behind an empty one, starts by
+    # issuing its own (and then waits for it at once).
+    prev_len = kv_lens_ref[jnp.maximum(b - 1, 0)]
+
+    @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
+    def _():
+        fetch.start(b, 0, jax.lax.rem(first, NUM_BUFS))
+
+    def advance(s):
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
+
+        # What computes next goes in flight now, into the other buffer:
+        # this row's superpage s + 1 or, behind its last, superpage 0 of
+        # the next row (an empty next row has no pages, so nothing issues).
+        last = s + 1 == n_super
+
+        @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
+        def _():
+            fetch.start(
+                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
+                jnp.where(last, 0, s + 1),
+                jax.lax.rem(n + 1, NUM_BUFS),
+            )
+
+        fetch.wait(fetch.pages_of(b, s), slot)
+        return slot
+
+    return advance
+
+
 def _decode_kernel(
     # scalar prefetch
     layer_ref,          # SMEM [1] int32 — which layer of the stacked pool
@@ -129,127 +273,31 @@ def _decode_kernel(
     # [1] int32, superpages the rows before this one fetched. Scratch
     # outlives a program, which is what hands buffers from row to row.
     b = pl.program_id(0)
-    num_rows = kv_lens_ref.shape[0]
     layer = layer_ref[0]
     bs = block_size
-    spp = super_tokens // bs            # pages per superpage
     hkv, g = num_kv_heads, q_per_kv
     dh = q_ref.shape[-1]
     pack = _pack(dh)
-    bsp = bs // pack                    # packed rows per page
     stp = super_tokens // pack          # packed rows per superpage
-    gp = min(ISSUE_UNROLL, spp)         # pages per fetch-loop iteration
     kv_len = kv_lens_ref[b]
     n_super = pl.cdiv(kv_len, super_tokens)
-    # The call's superpages form ONE sequence across rows, and superpage n
-    # of it lives in buffer n % NUM_BUFS: a row starts in the buffer its
-    # predecessors left free, whatever their lengths were.
+    # The call's superpages before this row's (``_superpage_sequence``).
     first = jnp.where(b == 0, 0, fetched_ref[0])
     fetched_ref[0] = first + n_super
 
     # q: [H, Dh] -> [Hkv, G, Dh] fp32, pre-scaled
     q = q_ref[b].astype(jnp.float32).reshape(hkv, g, dh) * scale
 
-    def pages_of(row, s):
-        # Pages of ``row`` that superpage s holds.
-        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
+    fetch = _PageFetch(
+        [(k_hbm, k_buf, sem_k), (v_hbm, v_buf, sem_v)], bs // pack,
+        block_size=bs, super_tokens=super_tokens, layer=layer,
+        block_tables_ref=block_tables_ref, kv_lens_ref=kv_lens_ref)
 
-    def start_fetch(row, s, n):
-        # Issue page-granular DMAs for superpage s of ``row``, the n-th of
-        # the call (pages are scattered in the pool; each is contiguous),
-        # all in flight at once. The loops run over the row's own pages and
-        # no further (``gp`` an iteration, then the odd ones): a short row
-        # pays for what it holds, and the kernel's code stays a few pages
-        # long where an unrolled superpage was 32 branches.
-        slot = jax.lax.rem(n, NUM_BUFS)
-        pages = pages_of(row, s)
-
-        def issue(i):
-            src = pl.ds(block_tables_ref[row, s * spp + i] * bsp, bsp)
-            dst = pl.ds(pl.multiple_of(i * bsp, bsp), bsp)
-            pltpu.make_async_copy(
-                k_hbm.at[layer, :, src], k_buf.at[slot, :, dst],
-                sem_k.at[slot],
-            ).start()
-            pltpu.make_async_copy(
-                v_hbm.at[layer, :, src], v_buf.at[slot, :, dst],
-                sem_v.at[slot],
-            ).start()
-
-        def issue_group(gi, carry):
-            for j in range(gp):
-                issue(gi * gp + j)
-            return carry
-
-        def issue_page(i, carry):
-            issue(i)
-            return carry
-
-        jax.lax.fori_loop(0, pages // gp, issue_group, 0)
-        jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
-
-    def wait_fetch(s, slot):
-        # A DMA semaphore counts bytes, and a buffer's page copies all signal
-        # the one semaphore of that buffer. So the wait is for the BYTES of
-        # this superpage's pages, taken in power-of-two runs of pages (the
-        # run lengths present in the page count's binary form): at most two
-        # waits a run, whatever order the pages land in.
-        pages = pages_of(b, s)
-        run = spp
-        while run:
-            @pl.when(pages & run != 0)
-            def _():
-                span = pl.ds(0, run * bsp)
-                pltpu.make_async_copy(
-                    k_hbm.at[0, :, span], k_buf.at[slot, :, span],
-                    sem_k.at[slot],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[0, :, span], v_buf.at[slot, :, span],
-                    sem_v.at[slot],
-                ).wait()
-            run //= 2
-
-    # What a row does not fetch it still computes over: whole superpages,
-    # and a masked key's softmax weight (0) must not meet a non-finite value
-    # there: 0 * NaN = NaN inside the PV contraction would poison the row.
-    # (A masked SCORE is replaced, not multiplied, so K may hold anything.)
-    # Only what the call finds in V can be non-finite; what its rows leave
-    # behind is KV, finite like the pool. So V is cleared once a call,
-    # before the first DMA is in flight, and stale keys stay where they are.
-    @pl.when(b == 0)
-    def _():
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
-
-    # A live row's last iteration issues the next row's first superpage, so
-    # only the call's first row, and a row behind an empty one, starts by
-    # issuing its own (and then waits for it at once).
-    prev_len = kv_lens_ref[jnp.maximum(b - 1, 0)]
-
-    @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
-    def _():
-        start_fetch(b, 0, first)
+    advance = _superpage_sequence(fetch, v_buf, b, kv_len, n_super, first)
 
     def body(s, carry):
         m, l, acc = carry
-        n = first + s
-        slot = jax.lax.rem(n, NUM_BUFS)
-
-        # What computes next goes in flight now, into the other buffer:
-        # this row's superpage s + 1 or, behind its last, superpage 0 of
-        # the next row (an empty next row has no pages, so nothing issues).
-        last = s + 1 == n_super
-
-        @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
-        def _():
-            start_fetch(
-                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
-                jnp.where(last, 0, s + 1),
-                n + 1,
-            )
-
-        wait_fetch(s, slot)
-
+        slot = advance(s)
         k_sup = k_buf[slot]   # [Hkv, S/PACK, Dh*PACK] — head-major: batch
         v_sup = v_buf[slot]   # dim leads, so NO per-superpage relayout.
 
@@ -603,11 +651,8 @@ def _latent_decode_kernel(
     super_tokens: int,
 ):
     b = pl.program_id(0)
-    num_rows = kv_lens_ref.shape[0]
     layer = layer_ref[0]
     bs = block_size
-    spp = super_tokens // bs
-    gp = min(ISSUE_UNROLL, spp)
     h = q_ref.shape[1]
     kv_len = kv_lens_ref[b]
     n_super = pl.cdiv(kv_len, super_tokens)
@@ -616,74 +661,17 @@ def _latent_decode_kernel(
 
     q = q_ref[b].astype(jnp.float32)[None] * scale          # [1, H, W]
 
-    def pages_of(row, s):
-        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
+    fetch = _PageFetch(
+        [(kv_hbm, kv_buf, sem)], bs, block_size=bs,
+        super_tokens=super_tokens, layer=layer,
+        block_tables_ref=block_tables_ref, kv_lens_ref=kv_lens_ref)
 
-    def start_fetch(row, s, n):
-        slot = jax.lax.rem(n, NUM_BUFS)
-        pages = pages_of(row, s)
-
-        def issue(i):
-            src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
-            dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-            pltpu.make_async_copy(
-                kv_hbm.at[layer, :, src], kv_buf.at[slot, :, dst],
-                sem.at[slot],
-            ).start()
-
-        def issue_group(gi, carry):
-            for j in range(gp):
-                issue(gi * gp + j)
-            return carry
-
-        def issue_page(i, carry):
-            issue(i)
-            return carry
-
-        jax.lax.fori_loop(0, pages // gp, issue_group, 0)
-        jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
-
-    def wait_fetch(s, slot):
-        pages = pages_of(b, s)
-        run = spp
-        while run:
-            @pl.when(pages & run != 0)
-            def _():
-                span = pl.ds(0, run * bs)
-                pltpu.make_async_copy(
-                    kv_hbm.at[0, :, span], kv_buf.at[slot, :, span],
-                    sem.at[slot],
-                ).wait()
-            run //= 2
-
-    # The buffers are keys AND values: a masked key's weight (0) must not
-    # meet a non-finite value, so they are cleared once a call; what rows
-    # leave behind is pool content, finite.
-    @pl.when(b == 0)
-    def _():
-        kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
-
-    prev_len = kv_lens_ref[jnp.maximum(b - 1, 0)]
-
-    @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
-    def _():
-        start_fetch(b, 0, first)
+    # The buffers are keys AND values: cleared whole.
+    advance = _superpage_sequence(fetch, kv_buf, b, kv_len, n_super, first)
 
     def body(s, carry):
         m, l, acc = carry
-        n = first + s
-        slot = jax.lax.rem(n, NUM_BUFS)
-        last = s + 1 == n_super
-
-        @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
-        def _():
-            start_fetch(
-                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
-                jnp.where(last, 0, s + 1),
-                n + 1,
-            )
-
-        wait_fetch(s, slot)
+        slot = advance(s)
         rows = kv_buf[slot]                                  # [1, S, W]
         scores = jax.lax.dot_general(
             q, rows,
@@ -853,6 +841,93 @@ def supports_pallas_prefill(t: int, num_heads: int, num_kv_heads: int,
     return t % tq == 0 and tq % block_size == 0
 
 
+def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
+                   program, programs, tq, tile_rows, tiles):
+    """The prefill kernels' hand-off of the superpage buffers from program
+    to program. Program (row, query block) reads its row's history
+    superpages (``fetch``'s pages, ``fetch.sup`` keys each; none at kv_len
+    0) and then key tiles of the chunk, ``tile_rows`` keys each, ONE copy a
+    stream out of ``chunk`` (the chunk's rows in HBM, [heads, B, T, lanes] a
+    stream): ``tiles(history tiles, block)`` in all, none where its ``tq``
+    queries are all padding. The call's tiles form one sequence across programs, tile n in
+    buffer n % NUM_BUFS, the next always in flight. Returns (history tiles,
+    tiles, ``advance``) of this program: ``advance(s)`` puts what computes
+    after tile s in flight, waits for tile s and returns its buffer."""
+    (b, qb), (num_rows, nq) = program, programs
+    kv_lens_ref, sup = fetch.kv_lens_ref, fetch.sup
+
+    def hist_tiles(row):
+        return pl.cdiv(kv_lens_ref[row], sup)
+
+    def tiles_of(row, blk):
+        return jnp.where(blk * tq < chunk_lens_ref[row],
+                         tiles(hist_tiles(row), blk), 0)
+
+    n_hist = hist_tiles(b)
+    n_tiles = tiles_of(b, qb)
+    is_first = (b == 0) & (qb == 0)
+    first = jnp.where(is_first, 0, fetched_ref[0])
+    fetched_ref[0] = first + n_tiles
+
+    def start_tile(row, blk, s, n):
+        # Tile s of program (row, blk), the n-th of the call, goes in
+        # flight into buffer n % NUM_BUFS. A program with no tiles issues
+        # nothing.
+        slot = jax.lax.rem(n, NUM_BUFS)
+        nh = hist_tiles(row)
+        has = s < tiles_of(row, blk)
+
+        @pl.when(has & (s < nh))
+        def _():
+            # A history superpage: page-granular copies, as decode's.
+            fetch.start(row, s, slot)
+
+        @pl.when(has & (s >= nh))
+        def _():
+            # A key tile of the chunk: contiguous.
+            fetch.start_run(chunk, row, (s - nh) * tile_rows, tile_rows, slot)
+
+    # A masked key's weight (0) must not meet a non-finite value (see the
+    # decode kernel): what holds values is cleared once a call; what tiles
+    # leave behind is pool and chunk content, finite.
+    @pl.when(is_first)
+    def _():
+        cleared[...] = jnp.zeros(cleared.shape, cleared.dtype)
+
+    # The program before this one issued this one's first tile from its
+    # last iteration, unless it had none (or there is none before).
+    prev_row = jnp.where(qb > 0, b, jnp.maximum(b - 1, 0))
+    prev_blk = jnp.where(qb > 0, qb - 1, nq - 1)
+
+    @pl.when((n_tiles > 0) & (is_first | (tiles_of(prev_row, prev_blk) == 0)))
+    def _():
+        start_tile(b, qb, 0, first)
+
+    wraps = qb + 1 == nq
+    next_row = jnp.minimum(jnp.where(wraps, b + 1, b), num_rows - 1)
+    next_blk = jnp.where(wraps, 0, qb + 1)
+    has_next = jnp.logical_not(wraps) | (b + 1 < num_rows)
+
+    def advance(s):
+        n = first + s
+        slot = jax.lax.rem(n, NUM_BUFS)
+        last = s + 1 == n_tiles
+
+        @pl.when(jnp.logical_not(last) | has_next)
+        def _():
+            start_tile(
+                jnp.where(last, next_row, b), jnp.where(last, next_blk, qb),
+                jnp.where(last, 0, s + 1), n + 1,
+            )
+
+        # By bytes, in runs of pages: a chunk tile counts as its pages.
+        fetch.wait(jnp.where(s < n_hist, fetch.pages_of(b, s),
+                             tile_rows // fetch.bs), slot)
+        return slot
+
+    return n_hist, n_tiles, advance
+
+
 def _prefill_kernel(
     # scalar prefetch
     layer_ref,          # SMEM [1] int32
@@ -888,120 +963,18 @@ def _prefill_kernel(
     num_rows, nq = pl.num_programs(0), pl.num_programs(1)
     layer = layer_ref[0]
     bs, sup, g = block_size, super_tokens, q_per_kv
-    spp = sup // bs                     # pages per superpage
-    gp = min(ISSUE_UNROLL, spp)
     hkv = k_buf.shape[1]
     kv_len = kv_lens_ref[b]
     chunk_len = chunk_lens_ref[b]
-
-    def hist_tiles(row):
-        return pl.cdiv(kv_lens_ref[row], sup)
-
-    def tiles_of(row, blk):
-        # History superpages, then chunk key blocks 0..blk; none where the
-        # program's queries are all padding.
-        return jnp.where(blk * tq < chunk_lens_ref[row],
-                         hist_tiles(row) + blk + 1, 0)
-
-    def pages_of(row, s):
-        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
-
-    n_hist = hist_tiles(b)
-    n_tiles = tiles_of(b, qb)
-    is_first = (b == 0) & (qb == 0)
-    first = jnp.where(is_first, 0, fetched_ref[0])
-    fetched_ref[0] = first + n_tiles
-
-    def start_tile(row, blk, s, n):
-        # Tile s of program (row, blk), the n-th of the call, goes in
-        # flight into buffer n % NUM_BUFS. A program with no tiles issues
-        # nothing.
-        slot = jax.lax.rem(n, NUM_BUFS)
-        nh = hist_tiles(row)
-        has = s < tiles_of(row, blk)
-
-        @pl.when(has & (s < nh))
-        def _():
-            # A history superpage: page-granular copies, as decode's.
-            pages = pages_of(row, s)
-
-            def issue(i):
-                src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
-                dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-                pltpu.make_async_copy(
-                    k_hbm.at[layer, :, src], k_buf.at[slot, :, dst],
-                    sem_k.at[slot],
-                ).start()
-                pltpu.make_async_copy(
-                    v_hbm.at[layer, :, src], v_buf.at[slot, :, dst],
-                    sem_v.at[slot],
-                ).start()
-
-            def issue_group(gi, carry):
-                for j in range(gp):
-                    issue(gi * gp + j)
-                return carry
-
-            def issue_page(i, carry):
-                issue(i)
-                return carry
-
-            jax.lax.fori_loop(0, pages // gp, issue_group, 0)
-            jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
-
-        @pl.when(has & (s >= nh))
-        def _():
-            # A key block of the chunk: contiguous, one copy a pool.
-            src = pl.ds(pl.multiple_of((s - nh) * tq, tq), tq)
-            dst = pl.ds(0, tq)
-            pltpu.make_async_copy(
-                kc_hbm.at[:, row, src], k_buf.at[slot, :, dst],
-                sem_k.at[slot],
-            ).start()
-            pltpu.make_async_copy(
-                vc_hbm.at[:, row, src], v_buf.at[slot, :, dst],
-                sem_v.at[slot],
-            ).start()
-
-    def wait_tile(s, slot):
-        # By bytes, in power-of-two runs of pages (see the decode kernel):
-        # a chunk key block counts as its TQ / bs pages.
-        pages = jnp.where(s < n_hist, pages_of(b, s), tq // bs)
-        run = spp
-        while run:
-            @pl.when(pages & run != 0)
-            def _():
-                span = pl.ds(0, run * bs)
-                pltpu.make_async_copy(
-                    k_hbm.at[0, :, span], k_buf.at[slot, :, span],
-                    sem_k.at[slot],
-                ).wait()
-                pltpu.make_async_copy(
-                    v_hbm.at[0, :, span], v_buf.at[slot, :, span],
-                    sem_v.at[slot],
-                ).wait()
-            run //= 2
-
-    # A masked key's weight (0) must not meet a non-finite value (see the
-    # decode kernel): V is cleared once a call; what tiles leave behind is
-    # K/V, finite like the pool and the chunk.
-    @pl.when(is_first)
-    def _():
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
-
-    # The program before this one issued this one's first tile from its
-    # last iteration, unless it had none (or there is none before).
-    prev_row = jnp.where(qb > 0, b, jnp.maximum(b - 1, 0))
-    prev_blk = jnp.where(qb > 0, qb - 1, nq - 1)
-
-    @pl.when((n_tiles > 0) & (is_first | (tiles_of(prev_row, prev_blk) == 0)))
-    def _():
-        start_tile(b, qb, 0, first)
-
-    wraps = qb + 1 == nq
-    next_row = jnp.minimum(jnp.where(wraps, b + 1, b), num_rows - 1)
-    next_blk = jnp.where(wraps, 0, qb + 1)
-    has_next = jnp.logical_not(wraps) | (b + 1 < num_rows)
+    fetch = _PageFetch(
+        [(k_hbm, k_buf, sem_k), (v_hbm, v_buf, sem_v)], bs, block_size=bs,
+        super_tokens=sup, layer=layer, block_tables_ref=block_tables_ref,
+        kv_lens_ref=kv_lens_ref)
+    # Chunk key blocks 0..qb, TQ keys each; V is what holds values.
+    n_hist, n_tiles, advance = _tile_sequence(
+        fetch, (kc_hbm, vc_hbm), chunk_lens_ref, fetched_ref, v_buf,
+        program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tq,
+        tiles=lambda hist, blk: hist + blk + 1)
 
     def flash_block(keys_of, mask_of):
         # One tile's keys against every head's query rows: the heads are a
@@ -1040,18 +1013,7 @@ def _prefill_kernel(
         jax.lax.fori_loop(0, hkv, head, 0)
 
     def tile(s, carry):
-        n = first + s
-        slot = jax.lax.rem(n, NUM_BUFS)
-        last = s + 1 == n_tiles
-
-        @pl.when(jnp.logical_not(last) | has_next)
-        def _():
-            start_tile(
-                jnp.where(last, next_row, b), jnp.where(last, next_blk, qb),
-                jnp.where(last, 0, s + 1), n + 1,
-            )
-
-        wait_tile(s, slot)
+        slot = advance(s)
 
         @pl.when(s < n_hist)
         def _():
@@ -1279,109 +1241,20 @@ def _latent_prefill_kernel(
     num_rows, nq = pl.num_programs(0), pl.num_programs(1)
     layer = layer_ref[0]
     bs, sup = block_size, super_tokens
-    spp = sup // bs                     # pages per superpage
-    gp = min(ISSUE_UNROLL, spp)
     _, tq, h, w = q_ref.shape
     dv = o_ref.shape[-1]
     kv_len = kv_lens_ref[b]
     chunk_len = chunk_lens_ref[b]
-
-    def hist_tiles(row):
-        return pl.cdiv(kv_lens_ref[row], sup)
-
-    def tiles_of(row, blk):
-        # History superpages, then the chunk's key tiles up to the one that
-        # holds the block's last query; none where its queries are all
-        # padding.
-        return jnp.where(blk * tq < chunk_lens_ref[row],
-                         hist_tiles(row) + pl.cdiv((blk + 1) * tq, tk), 0)
-
-    def pages_of(row, s):
-        return jnp.clip(pl.cdiv(kv_lens_ref[row], bs) - s * spp, 0, spp)
-
-    n_hist = hist_tiles(b)
-    n_tiles = tiles_of(b, qb)
-    is_first = (b == 0) & (qb == 0)
-    first = jnp.where(is_first, 0, fetched_ref[0])
-    fetched_ref[0] = first + n_tiles
-
-    def start_tile(row, blk, s, n):
-        # Tile s of program (row, blk), the n-th of the call, goes in
-        # flight into buffer n % NUM_BUFS. A program with no tiles issues
-        # nothing.
-        slot = jax.lax.rem(n, NUM_BUFS)
-        nh = hist_tiles(row)
-        has = s < tiles_of(row, blk)
-
-        @pl.when(has & (s < nh))
-        def _():
-            # A history superpage: page-granular copies, as decode's.
-            pages = pages_of(row, s)
-
-            def issue(i):
-                src = pl.ds(block_tables_ref[row, s * spp + i] * bs, bs)
-                dst = pl.ds(pl.multiple_of(i * bs, bs), bs)
-                pltpu.make_async_copy(
-                    kv_hbm.at[layer, :, src], kv_buf.at[slot, :, dst],
-                    sem.at[slot],
-                ).start()
-
-            def issue_group(gi, carry):
-                for j in range(gp):
-                    issue(gi * gp + j)
-                return carry
-
-            def issue_page(i, carry):
-                issue(i)
-                return carry
-
-            jax.lax.fori_loop(0, pages // gp, issue_group, 0)
-            jax.lax.fori_loop(pages // gp * gp, pages, issue_page, 0)
-
-        @pl.when(has & (s >= nh))
-        def _():
-            # A key tile of the chunk: contiguous, one copy.
-            src = pl.ds(pl.multiple_of((s - nh) * tk, tk), tk)
-            pltpu.make_async_copy(
-                rows_hbm.at[:, row, src], kv_buf.at[slot, :, pl.ds(0, tk)],
-                sem.at[slot],
-            ).start()
-
-    def wait_tile(s, slot):
-        # By bytes, in power-of-two runs of pages (see the decode kernel):
-        # a chunk tile counts as its TK / bs pages.
-        pages = jnp.where(s < n_hist, pages_of(b, s), tk // bs)
-        run = spp
-        while run:
-            @pl.when(pages & run != 0)
-            def _():
-                span = pl.ds(0, run * bs)
-                pltpu.make_async_copy(
-                    kv_hbm.at[0, :, span], kv_buf.at[slot, :, span],
-                    sem.at[slot],
-                ).wait()
-            run //= 2
-
-    # A masked key's weight (0) must not meet a non-finite value: the
-    # buffers are cleared once a call; what tiles leave behind is pool and
-    # chunk content, finite.
-    @pl.when(is_first)
-    def _():
-        kv_buf[...] = jnp.zeros(kv_buf.shape, kv_buf.dtype)
-
-    # The program before this one issued this one's first tile from its
-    # last iteration, unless it had none (or there is none before).
-    prev_row = jnp.where(qb > 0, b, jnp.maximum(b - 1, 0))
-    prev_blk = jnp.where(qb > 0, qb - 1, nq - 1)
-
-    @pl.when((n_tiles > 0) & (is_first | (tiles_of(prev_row, prev_blk) == 0)))
-    def _():
-        start_tile(b, qb, 0, first)
-
-    wraps = qb + 1 == nq
-    next_row = jnp.minimum(jnp.where(wraps, b + 1, b), num_rows - 1)
-    next_blk = jnp.where(wraps, 0, qb + 1)
-    has_next = jnp.logical_not(wraps) | (b + 1 < num_rows)
+    fetch = _PageFetch(
+        [(kv_hbm, kv_buf, sem)], bs, block_size=bs, super_tokens=sup,
+        layer=layer, block_tables_ref=block_tables_ref,
+        kv_lens_ref=kv_lens_ref)
+    # The chunk's key tiles up to the one that holds the block's last
+    # query, TK keys each; the buffers are keys AND values, cleared whole.
+    n_hist, n_tiles, advance = _tile_sequence(
+        fetch, (rows_hbm,), chunk_lens_ref, fetched_ref, kv_buf,
+        program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tk,
+        tiles=lambda hist, blk: hist + pl.cdiv((blk + 1) * tq, tk))
 
     def flash_block(rows, mask):
         # One tile's rows [keys, W] against every head's queries at once.
@@ -1404,18 +1277,7 @@ def _latent_prefill_kernel(
         m_ref[...] = m_new
 
     def tile(s, carry):
-        n = first + s
-        slot = jax.lax.rem(n, NUM_BUFS)
-        last = s + 1 == n_tiles
-
-        @pl.when(jnp.logical_not(last) | has_next)
-        def _():
-            start_tile(
-                jnp.where(last, next_row, b), jnp.where(last, next_blk, qb),
-                jnp.where(last, 0, s + 1), n + 1,
-            )
-
-        wait_tile(s, slot)
+        slot = advance(s)
 
         @pl.when(s < n_hist)
         def _():

@@ -10,14 +10,10 @@ carry and the contraction with C reading it again, over EVERY row of the
 bucket, live or not: 304 us a layer at 17 live rows of 32 where their bytes
 take 87 (PERF.md section 6, PR 40).
 
-The machinery is ops/pallas/gated_delta.py's, whose sibling this is: the
-carry stays in HBM and is ALIASED to the kernel's output; blocks of ``HB``
-heads ``[HB, P, N]`` of a live row's slab (contiguous) go through
-``NUM_BUFS`` VMEM buffers as ONE sequence over the call's live rows,
-``FETCH_AHEAD`` blocks in flight towards the one that is computed and the
-ones behind it on their way out; a row that is not live moves no byte and
-gets zeros; the grid axis (row chunks) is sequential and hands its buffers
-on.
+The data movement is ops/pallas/live_blocks.py's (the carry aliased and
+left in HBM, the live rows' blocks of ``HB`` heads ``[HB, P, N]`` through
+``NUM_BUFS`` VMEM buffers as one sequence, ``FETCH_AHEAD`` in flight); what
+is here is the operands' layout and a block's arithmetic.
 
 Arithmetic, a block ``[HB, P, N]`` (a head is 8 vregs at 64 x 128):
 
@@ -100,13 +96,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from production_stack_tpu.ops.pallas.live_blocks import (
+    OPERAND_BYTES,
+    live_blocks,
+    step_call,
+)
 
 NUM_BUFS = 4             # two blocks coming in, one computed, one going out
 FETCH_AHEAD = 2          # blocks in flight towards the one computed
 HEADS_PER_BLOCK = 16     # unrolled in the kernel; 512 KB at 64 x 128
-OPERAND_BYTES = 6 << 20  # VMEM the per-row operands of one program may take,
-                         # both copies Pallas keeps of a block
 LANES, SUBLANES = 128, 8
 TILE = SUBLANES * LANES
 
@@ -119,12 +118,6 @@ def supports_step_kernel(shape) -> bool:
     h, p, n = shape
     return n % LANES == 0 and p % SUBLANES == 0 and LANES % p == 0 \
         and h % min(h, HEADS_PER_BLOCK) == 0
-
-
-def _rows_per_program(b: int, row_bytes: int) -> int:
-    return max(n for n in range(1, b + 1)
-               if b % n == 0 and (n == 1 or 2 * n * row_bytes
-                                  <= OPERAND_BYTES))
 
 
 def _step_kernel(
@@ -140,82 +133,16 @@ def _step_kernel(
     # outputs
     o_ref,         # VMEM [RB, H/HB, HB P] f32: y, (head, p) along the lanes
     s_out,         # HBM: the carry again (aliased to s_in)
-    # scratch (outlives a program)
+    # scratch: live_blocks', of which the kernel touches the buffers
     buf,           # VMEM [NUM_BUFS, HB, P, N] f32
-    sem_in,        # DMA (NUM_BUFS,)
-    sem_out,       # DMA (NUM_BUFS,)
-    rows_ref,      # SMEM [B] int32: the live rows, in order
-    count_ref,     # SMEM [1] int32: how many
+    *scratch,
 ):
-    pid = pl.program_id(0)
-    num_rows = live_ref.shape[0]
-    rb, nb, _ = o_ref.shape
     _, hb, p, n_state = buf.shape
-    at = at_ref[0]
-
-    @pl.when(pid == 0)
-    def _():
-        def add(b, n):
-            @pl.when(live_ref[b] != 0)
-            def _():
-                rows_ref[n] = b
-            return n + (live_ref[b] != 0).astype(jnp.int32)
-
-        count_ref[0] = jax.lax.fori_loop(0, num_rows, add, jnp.int32(0))
-
-    def live_below(row):
-        return jax.lax.fori_loop(
-            0, row, lambda b, n: n + (live_ref[b] != 0).astype(jnp.int32),
-            jnp.int32(0))
-
-    total = count_ref[0] * nb            # live blocks of the call
-    lo = live_below(pid * rb)            # live rows before this program's
-    hi = live_below(pid * rb + rb)       # and up to its last
-
-    def block(n):
-        # (row, block of heads) of the call's n-th live block.
-        li = n // nb
-        return rows_ref[jnp.minimum(li, num_rows - 1)], n - li * nb
-
-    def fetch(n):
-        row, j = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        return pltpu.make_async_copy(
-            s_in.at[row, at, pl.ds(j * hb, hb)], buf.at[slot],
-            sem_in.at[slot])
-
-    def store(n):
-        row, j = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        return pltpu.make_async_copy(
-            buf.at[slot], s_out.at[row, at, pl.ds(j * hb, hb)],
-            sem_out.at[slot])
-
+    run = live_blocks(at_ref, live_ref, s_in, s_out, buf, *scratch,
+                      rows=o_ref.shape[0], fetch_ahead=FETCH_AHEAD)
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    def step(n, carry):
-        row, j = block(n)
-        slot = jax.lax.rem(n, NUM_BUFS)
-        r = row - pid * rb
-
-        @pl.when(n == 0)
-        def _():
-            for first in range(FETCH_AHEAD):
-                @pl.when(first < total)
-                def _():
-                    fetch(first).start()
-
-        # One more block goes in flight now, into the buffer that the
-        # block NUM_BUFS before it left: whose write-back has to have
-        # landed first.
-        @pl.when(n + FETCH_AHEAD < total)
-        def _():
-            @pl.when(n + FETCH_AHEAD >= NUM_BUFS)
-            def _():
-                store(n + FETCH_AHEAD - NUM_BUFS).wait()
-            fetch(n + FETCH_AHEAD).start()
-
-        fetch(n).wait()
+    def compute(n, row, j, slot, r):
         # The block's dt x, (head, p) along the lanes of its rows, as
         # columns: head i's p numbers run down column i P // 128 from row
         # i P % 128.
@@ -232,18 +159,8 @@ def _step_kernel(
             (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         o_ref[r, pl.ds(j, 1), :] = y[1:2]
-        store(n).start()
-        return carry
 
-    jax.lax.fori_loop(lo * nb, hi * nb, step, 0)
-
-    # The call's last write-backs: those no later block waited for.
-    @pl.when(pid == pl.num_programs(0) - 1)
-    def _():
-        for back in range(NUM_BUFS, 0, -1):
-            @pl.when(total >= back)
-            def _():
-                store(total - back).wait()
+    run(compute)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -275,47 +192,14 @@ def ssd_step_in_place(
     bc = jnp.pad(jnp.stack([b, c], axis=1),
                  ((0, 0), (0, SUBLANES - 2), (0, 0)))
     # A row's operands in VMEM: dt x, B | C and y (nb rows of a whole tile).
-    rb = _rows_per_program(
-        bsz, (dtx[0].size + SUBLANES * n
-              + -(-nb // SUBLANES) * SUBLANES * per) * 4)
-
-    def rows(*shape):
-        return pl.BlockSpec((rb, *shape),
-                            lambda i, *_: (i,) + (0,) * len(shape),
-                            memory_space=pltpu.VMEM)
-
-    o, carry = pl.pallas_call(
+    row_bytes = (dtx[0].size + SUBLANES * n
+                 + -(-nb // SUBLANES) * SUBLANES * per) * 4
+    o, carry = step_call(
         _step_kernel,
-        out_shape=[jax.ShapeDtypeStruct((bsz, nb, per), jnp.float32),
-                   jax.ShapeDtypeStruct(carry.shape, carry.dtype)],
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(bsz // rb,),
-            in_specs=[
-                rows(*dtx.shape[1:]),
-                rows(SUBLANES, n),
-                pl.BlockSpec(memory_space=pl.ANY),   # the carry stays in HBM
-            ],
-            out_specs=[rows(nb, per),
-                       pl.BlockSpec(memory_space=pl.ANY)],
-            scratch_shapes=[
-                pltpu.VMEM((NUM_BUFS, hb, p, n), jnp.float32),
-                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
-                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
-                pltpu.SMEM((bsz,), jnp.int32),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        # at, live, decay, dtx, bc, carry -> (o, carry): in place.
-        input_output_aliases={5: 1},
-        # Programs run in order: each hands its buffers to the next.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="ssd_step_in_place",
-    )(
-        jnp.asarray(at, jnp.int32).reshape(1), live.astype(jnp.int32),
-        jnp.exp(da), dtx, bc, carry,
-    )
+        (jnp.asarray(at, jnp.int32).reshape(1), live.astype(jnp.int32),
+         jnp.exp(da)),
+        (dtx, bc), carry, out_row=(nb, per), heads_per_block=hb,
+        num_bufs=NUM_BUFS, row_bytes=row_bytes, operand_bytes=OPERAND_BYTES,
+        name="ssd_step_in_place", interpret=interpret)
     y = o.reshape(bsz, h, p) + d_skip.astype(jnp.float32)[None, :, None] * x
     return jnp.where(live[:, None, None], y, 0.0), carry

@@ -12,6 +12,7 @@ cache is off around the compiles, because what is written for a described
 chip cannot be read back without one and the next compile would only warn.
 """
 
+import functools
 import os
 import re
 
@@ -921,6 +922,92 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
     body = _without_source_locations(text)
     assert hashlib.sha1(body.encode()).hexdigest() == \
         "2690042c18a0360e58e59a935efed7cfd8be75a0"
+
+
+def _kernel_entry_points():
+    """name -> (entry point, ShapeDtypeStructs at one cell's shape, the sha1
+    of its jaxpr's text): the seven Pallas kernels of the serving path."""
+    from production_stack_tpu.ops.pallas import gated_delta, ssd
+    from production_stack_tpu.ops.pallas import paged_attention as pa
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    mb = 3072 // BLOCK_SIZE                 # --max-model-len 3072
+    slots = (64 * mb + 1) * BLOCK_SIZE
+
+    def tables(rows):
+        return (sds(rows, mb, dtype=i32), sds(rows, dtype=i32),
+                sds(1, dtype=i32))
+
+    kv = sds(36, 2, slots, 128, dtype=bf16)         # qwen2.5-3b: 16 / 2 x 128
+    latent = sds(8, 1, slots, 640, dtype=bf16)      # kanana: 512 + 64 -> 640
+    latent_kw = dict(block_size=BLOCK_SIZE, value_dim=512, scale=192 ** -0.5)
+    return {
+        "paged_flash_decode_stats-qwen-32": (
+            functools.partial(pa.paged_flash_decode_stats,
+                              block_size=BLOCK_SIZE),
+            (sds(32, 16, 128, dtype=bf16), kv, kv, *tables(32)),
+            "dea4f5850f07a2ea3ae541363a15e50eb5e23fc1"),
+        "paged_flash_decode_latent_stats-kanana-64": (
+            functools.partial(pa.paged_flash_decode_latent_stats,
+                              **latent_kw),
+            (sds(64, 32, 640, dtype=bf16), latent, *tables(64)),
+            "6307294aa962f5dd45b9b96ee08e563d6f3f45a8"),
+        "paged_flash_prefill-qwen-8x256": (
+            functools.partial(pa.paged_flash_prefill, block_size=BLOCK_SIZE),
+            (sds(8, 256, 16, 128, dtype=bf16), sds(8, 256, 2, 128, dtype=bf16),
+             sds(8, 256, 2, 128, dtype=bf16), sds(8, 256, dtype=i32),
+             sds(8, dtype=i32), kv, kv, *tables(8)),
+            "a8e076241545956b243979e09031bc2abcd4d9af"),
+        "paged_flash_prefill_latent-kanana-8x128": (
+            functools.partial(pa.paged_flash_prefill_latent, **latent_kw),
+            (sds(8, 128, 32, 640, dtype=bf16), sds(8, 128, 1, 640, dtype=bf16),
+             sds(8, 128, dtype=i32), sds(8, dtype=i32), latent, *tables(8)),
+            "e990b7f0ab5d2c3ff8c4af2d2e746f4fb768e25c"),
+        "gdn_step_in_place-olmo-32": (      # 12 layers of 30 x 96 x 192
+            gated_delta.gdn_step_in_place,
+            (sds(32, 12, 15, 96, 384), sds(dtype=i32), sds(32, 30, 96),
+             sds(32, 30, 96), sds(32, 30, 192), sds(32, 30), sds(32, 30),
+             sds(32, dtype=jnp.bool_)),
+            "83e332bc883e10ac630d06f1d9d7de6491d5866e"),
+        "gdn_chunk_in_place-olmo-8x256": (
+            gated_delta.gdn_chunk_in_place,
+            (sds(8, 15, 96, 384), sds(8, 256, 30, 96), sds(8, 256, 30, 96),
+             sds(8, 256, 30, 192), sds(8, 256, 30), sds(8, 256, 30),
+             sds(8, dtype=i32)),
+            "02c1bc0ffbae0858902a9830ce28cb4b2a7b1d79"),
+        "ssd_step_in_place-granite-32": (   # 36 layers of 64 x 64 x 128
+            ssd.ssd_step_in_place,
+            (sds(32, 36, 64, 64, 128), sds(dtype=i32), sds(32, 64, 64),
+             sds(32, 128), sds(32, 128), sds(32, 64), sds(32, 64), sds(64),
+             sds(32, dtype=jnp.bool_)),
+            "de3586aacc14ccaf97b2d31521467be0726a83ef"),
+    }
+
+
+KERNEL_ENTRY_POINTS = _kernel_entry_points()
+
+
+@pytest.mark.parametrize("name", list(KERNEL_ENTRY_POINTS))
+def test_kernel_entry_point_is_the_program_it_was(name):
+    """Each Pallas kernel of the serving path, at one cell's shape, is
+    pinned by the hash of its jaxpr's text (the kernel's body, its
+    ``dma_start`` / ``dma_wait`` equations and the grid mapping; no file
+    name and no line number, so it moves only when the program does, and it
+    needs no described chip). PR 43 moved the kernels' data movement into
+    shared code (``_PageFetch`` and the two sequences of
+    ops/pallas/paged_attention.py, ops/pallas/live_blocks.py) and wrote
+    these: six are the hashes of PR 42's tree, ``gdn_step_in_place``'s is
+    new (its first fetch took the guarded form of ``ssd_step_in_place``'s).
+    A PR that changes a kernel on purpose writes the new hash here."""
+    import hashlib
+
+    fn, args, want = KERNEL_ENTRY_POINTS[name]
+    text = str(jax.make_jaxpr(fn)(*args))
+    assert "pallas_call" in text
+    assert hashlib.sha1(text.encode()).hexdigest() == want
 
 
 # ---- prefill attention over latent rows: the flash kernel (PR 39)
