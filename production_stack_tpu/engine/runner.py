@@ -1507,32 +1507,24 @@ class ModelRunner:
              fwd_stats) = carry
             seeds_j = seed_steps[j]
             positions = jnp.minimum(pos0 + j, max_len - 1)[:, None]
+            # One call whatever the module declares: a state it carries
+            # (``cache_specs(cfg).state``: handed in as ``state=`` and
+            # returned fourth) and counters (``FORWARD_STATS``: returned
+            # last) are independent. With either, a row past its budget
+            # is handed length 0: it leaves no state and reaches no expert.
+            hidden, k_new, v_new, *extra = self._forward(
+                params, mc, toks[:, None], positions,
+                (j < budget).astype(jnp.int32)
+                if self.state_specs or self.fwd_stats else ones,
+                view0._replace(ring_k=ring_k, ring_v=ring_v,
+                               ring_pos=ring_pos),
+                lora=lora,
+                **({"state": rows_state} if self.state_specs else {}),
+            )
             if self.state_specs:
-                hidden, k_new, v_new, rows_state = self._forward(
-                    params, mc, toks[:, None], positions,
-                    (j < budget).astype(jnp.int32),
-                    view0._replace(ring_k=ring_k, ring_v=ring_v,
-                                   ring_pos=ring_pos),
-                    lora=lora, state=rows_state,
-                )
-            elif self.fwd_stats:
-                # A row past its budget reaches no expert, as it leaves
-                # no state above.
-                hidden, k_new, v_new, step_stats = self._forward(
-                    params, mc, toks[:, None], positions,
-                    (j < budget).astype(jnp.int32),
-                    view0._replace(ring_k=ring_k, ring_v=ring_v,
-                                   ring_pos=ring_pos),
-                    lora=lora,
-                )
-                fwd_stats = fwd_stats + step_stats
-            else:
-                hidden, k_new, v_new = self._forward(
-                    params, mc, toks[:, None], positions, ones,
-                    view0._replace(ring_k=ring_k, ring_v=ring_v,
-                                   ring_pos=ring_pos),
-                    lora=lora,
-                )
+                rows_state = extra.pop(0)
+            if self.fwd_stats:
+                fwd_stats = fwd_stats + extra.pop(0)
             if quant:
                 # Quantize this step's fresh KV on device; the attention
                 # ring carries the DEQUANTIZED values so later steps of
@@ -2584,21 +2576,23 @@ class ModelRunner:
             t > 1 and sp > 1 and t % sp == 0
             and (not has_window or (mb * bs + t) % sp == 0)
         )
-        fwd_stats = ()
+        # The decode loop's one call shape: state in and out where the
+        # module declares any (such a module is refused sequence
+        # parallelism, so ``rings`` is false), counters last where it
+        # declares those.
+        state_in = {}
         if self.state_specs:
             state_slots = scalars[12]
-            hidden, k_new, v_new, rows_state = self._forward(
-                params, mc, token_ids, positions, chunk_lens, view,
-                lora=lora, state=self._read_state_rows(
-                    state_pools, state_slots, fresh=chunk_start == 0),
-            )
-        else:
-            hidden, k_new, v_new, *fwd_stats = self._forward(
-                params, mc, token_ids, positions, chunk_lens,
-                view._replace(sp_mesh=self.mesh if rings else None),
-                act_sharding=self._act_sharding, lora=lora,
-            )
-        fwd_stats = fwd_stats[0] if self.fwd_stats else ()
+            state_in["state"] = self._read_state_rows(
+                state_pools, state_slots, fresh=chunk_start == 0)
+        hidden, k_new, v_new, *extra = self._forward(
+            params, mc, token_ids, positions, chunk_lens,
+            view._replace(sp_mesh=self.mesh if rings else None),
+            act_sharding=self._act_sharding, lora=lora, **state_in,
+        )
+        if self.state_specs:
+            rows_state = extra.pop(0)
+        fwd_stats = extra.pop(0) if self.fwd_stats else ()
         logit_idx = jnp.maximum(chunk_lens - 1, 0)
         last_hidden = hidden[jnp.arange(b), logit_idx]            # [b, D]
         logits = self._logits_fn(params, mc, last_hidden)
@@ -3211,7 +3205,8 @@ class ModelRunner:
         with recurrent state, ``gdn_step`` / ``ssd_step``: which execution
         of that recurrence's step it holds (``"pallas"`` / ``"xla"``), and
         for a prefill program of a Gated DeltaNet model ``gdn_chunk``
-        likewise (the chunkwise form); for a
+        likewise (the chunkwise form), and ``short_conv`` on every line of
+        a model of gated short convolutions; for a
         prefill program, ``prefill_attn``: which execution of the chunk's
         attention (``"pallas"``: the flash kernel over the pool /
         ``"xla"``: ``window_attention`` over gathered keys); for every
@@ -3251,7 +3246,9 @@ class ModelRunner:
             })
             for name, path in (("gdn_step", gated_delta.step_path(text)),
                                ("gdn_chunk", gated_delta.chunk_path(text)),
-                               ("ssd_step", ssd.step_path(text))):
+                               ("ssd_step", ssd.step_path(text)),
+                               ("short_conv",
+                                gated_delta.short_conv_path(text))):
                 if path:
                     out[-1][name] = path
             out[-1].update(self.residual_report())

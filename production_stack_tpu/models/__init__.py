@@ -11,6 +11,7 @@ outside this package asks for an architecture by name.
 from production_stack_tpu.models import (
     deepseek_v3,
     granite_hybrid,
+    lfm2_moe,
     llama,
     olmo_hybrid,
     opt,
@@ -26,7 +27,8 @@ from production_stack_tpu.models.config import (
 )
 
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
-          "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid}
+          "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid,
+          "lfm2_moe": lfm2_moe}
 
 
 def get_model(cfg: ModelConfig):

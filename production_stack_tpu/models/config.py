@@ -83,7 +83,8 @@ class CacheSpecs(NamedTuple):
 
 @dataclass(frozen=True)
 class ModelConfig:
-    # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid"
+    # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid" |
+    # "lfm2_moe"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -102,8 +103,9 @@ class ModelConfig:
     name: str = "model"
     # Layer kinds in order ("linear_attention" | "full_attention"; a
     # granite_hybrid's "mamba" | "attention"), a whole number of equal
-    # periods; empty: every layer is full attention. The linear_* sizes are
-    # those of the linear-attention layers' recurrence.
+    # periods (an lfm2_moe's "conv" | "full_attention" in ANY order:
+    # ``FREE_LAYER_LISTS``); empty: every layer is full attention. The
+    # linear_* sizes are those of the linear-attention layers' recurrence.
     layer_types: Tuple[str, ...] = ()
     linear_num_heads: int = 0
     linear_key_head_dim: int = 0
@@ -161,9 +163,19 @@ class ModelConfig:
     attention_multiplier: Optional[float] = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # Gated short convolutions (models/lfm2_moe.py): conv_l_cache taps over
+    # hidden_size channels, so a sequence's state a layer is conv_l_cache -
+    # 1 tokens of them. use_expert_bias false: a checkpoint carries no bias
+    # of the router's choice (served as a zero bias).
+    conv_l_cache: int = 3
+    use_expert_bias: bool = True
 
     def __post_init__(self):
-        if self.layer_types:
+        if self.arch in FREE_LAYER_LISTS:
+            free_layer_list(self.layer_types, self.num_layers,
+                            self.first_k_dense_replace,
+                            FREE_LAYER_LISTS[self.arch])
+        elif self.layer_types:
             layer_period(self.layer_types, self.num_layers,
                          **PERIOD_RULES.get(self.arch, {}))
 
@@ -350,6 +362,47 @@ class ModelConfig:
                 logits_scaling=float(d.get("logits_scaling", 1.0)),
                 name=name,
             )
+        if model_type == "lfm2_moe":
+            # What the module does not implement is refused by its key, not
+            # served as something else.
+            unsupported = {
+                "conv_bias": bool(d.get("conv_bias", False)),
+                "rope_scaling": d.get("rope_scaling") is not None,
+                "conv_L_cache < 2": d.get("conv_L_cache", 3) < 2,
+                "num_experts < 1": d.get("num_experts", 0) < 1,
+                "num_shared_experts": bool(d.get("num_shared_experts", 0)),
+                "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+            }
+            asked = [k for k, on in unsupported.items() if on]
+            if asked:
+                raise ValueError(
+                    f"{model_type}: not supported: {', '.join(asked)}")
+            return ModelConfig(
+                arch="lfm2_moe",
+                vocab_size=d["vocab_size"],
+                hidden_size=d["hidden_size"],
+                intermediate_size=d["intermediate_size"],
+                num_layers=d["num_hidden_layers"],
+                num_heads=d["num_attention_heads"],
+                num_kv_heads=d.get("num_key_value_heads",
+                                   d["num_attention_heads"]),
+                head_dim=d.get("head_dim"),
+                max_position_embeddings=d.get("max_position_embeddings", 4096),
+                rope_theta=float(d.get("rope_theta", 1000000.0)),
+                rms_norm_eps=d.get("norm_eps", 1e-5),
+                tie_word_embeddings=d.get("tie_word_embeddings", True),
+                layer_types=tuple(d["layer_types"]),
+                n_routed_experts=d["num_experts"],
+                num_experts_per_tok=d["num_experts_per_tok"],
+                moe_intermediate_size=d["moe_intermediate_size"],
+                first_k_dense_replace=d.get("num_dense_layers", 0),
+                routed_scaling_factor=float(
+                    d.get("routed_scaling_factor", 1.0)),
+                norm_topk_prob=d.get("norm_topk_prob", True),
+                conv_l_cache=d.get("conv_L_cache", 3),
+                use_expert_bias=d.get("use_expert_bias", True),
+                name=name,
+            )
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
@@ -367,6 +420,44 @@ PERIOD_RULES = {
 }
 
 
+# Per arch whose module takes its two kinds (state-keeping, full-attention)
+# in ANY order (models/lfm2_moe.py scans layers one at a time with the
+# operator chosen by a table): the published list there is not equal periods.
+FREE_LAYER_LISTS = {"lfm2_moe": ("conv", "full_attention")}
+
+
+def _known_kinds(layer_types, num_layers: int,
+                 kinds: Tuple[str, str]) -> Tuple[str, ...]:
+    """``layer_types`` as a tuple; refused: a length other than
+    ``num_layers`` and an unknown kind."""
+    types = tuple(layer_types)
+    if len(types) != num_layers:
+        raise ValueError(
+            f"layer_types has {len(types)} entries for {num_layers} layers")
+    unknown = sorted(set(types) - set(kinds))
+    if unknown:
+        raise ValueError(f"layer_types: unknown kinds {unknown}; "
+                         f"supported: {list(kinds)}")
+    return types
+
+
+def free_layer_list(layer_types, num_layers: int, dense_layers: int,
+                    kinds: Tuple[str, str]) -> None:
+    """Refused: a length other than ``num_layers``, an unknown kind, a list
+    without a layer of each kind (a pool or a state spec of no layer), and
+    a full-attention layer among the ``dense_layers`` leading ones (their
+    scan holds the state-keeping operator alone)."""
+    types = _known_kinds(layer_types, num_layers, kinds)
+    if set(types) != set(kinds):
+        raise ValueError(
+            f"layer_types {list(types)} needs a layer of each of {kinds}")
+    if not 0 <= dense_layers < num_layers \
+            or kinds[1] in types[:dense_layers]:
+        raise ValueError(
+            f"layer_types: the {dense_layers} leading dense layers must be "
+            f"{kinds[0]} layers with a sparse layer behind them")
+
+
 def layer_period(layer_types, num_layers: int, *,
                  kinds: Tuple[str, str] = LAYER_KINDS,
                  closed: bool = True) -> Tuple[str, ...]:
@@ -378,14 +469,7 @@ def layer_period(layer_types, num_layers: int, *,
     full layers) it may stand anywhere in it. Both modules share this one
     helper. Refused: a length other than ``num_layers``, an unknown kind,
     and a list that is not a whole number of such equal periods."""
-    types = tuple(layer_types)
-    if len(types) != num_layers:
-        raise ValueError(
-            f"layer_types has {len(types)} entries for {num_layers} layers")
-    unknown = sorted(set(types) - set(kinds))
-    if unknown:
-        raise ValueError(f"layer_types: unknown kinds {unknown}; "
-                         f"supported: {list(kinds)}")
+    types = _known_kinds(layer_types, num_layers, kinds)
     full = types.count(kinds[1])
     n = len(types) // full if full else 0
     if n < 2 or len(types) % n or types != types[:n] * (len(types) // n) \
@@ -529,8 +613,28 @@ TINY_GRANITE_HYBRID = ModelConfig(
     name="tiny-granite-hybrid",
 )
 
+# Tiny gated-short-convolution sparse-expert hybrid at the PUBLISHED depth
+# and irregular pattern (five periods of conv conv full conv, then conv full
+# conv conv: attention at 2, 6, 10, 14, 18, 21), 2 leading dense layers, 8
+# experts top-2 with a bias on the choice, 64-lane heads with a per-head norm
+# of q and k, a tied head (tests/test_lfm2_moe.py compares it with the plain
+# reference).
+LFM2_LAYER_TYPES = ("conv", "conv", "full_attention", "conv") * 5 \
+    + ("conv", "full_attention", "conv", "conv")
+TINY_LFM2_MOE = ModelConfig(
+    arch="lfm2_moe", vocab_size=512, hidden_size=128,
+    intermediate_size=256, num_layers=24, num_heads=4, num_kv_heads=2,
+    head_dim=64, max_position_embeddings=512, rope_theta=1000000.0,
+    rms_norm_eps=1e-5, tie_word_embeddings=True,
+    layer_types=LFM2_LAYER_TYPES,
+    n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=64,
+    first_k_dense_replace=2, routed_scaling_factor=1.0,
+    conv_l_cache=3, name="tiny-lfm2-moe",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-lfm2-moe": TINY_LFM2_MOE,
     "tiny-granite-hybrid": TINY_GRANITE_HYBRID,
     "tiny-deepseek-v3": TINY_DEEPSEEK_V3,
     "tiny-xing4": TINY_XING4,

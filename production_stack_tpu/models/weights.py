@@ -83,12 +83,16 @@ def load_hf_params(
     nl = cfg.num_layers
     # A module with more than one kind of layer stacks its leaves by kind
     # and says where each layer goes (``layer_slots``: (kind, index in the
-    # kind's stack) per layer); stacks are then keyed "kind/leaf".
+    # kind's stack) per layer); stacks are then keyed "kind/leaf". A layer
+    # whose sublayers are of independent kinds (models/lfm2_moe.py) gives
+    # {leaf: (kind, index)} instead.
     slots = model.layer_slots(cfg) if hasattr(model, "layer_slots") else None
     sizes: Dict[str, int] = {}
     if slots is not None:
-        for kind, _ in slots:
-            sizes[kind] = sizes.get(kind, 0) + 1
+        for slot in slots:
+            filed = slot.values() if isinstance(slot, dict) else (slot,)
+            for kind in {kind for kind, _ in filed}:
+                sizes[kind] = sizes.get(kind, 0) + 1
 
     stacks: Dict[str, np.ndarray] = {}   # our layer leaf -> [L, ...] buffer
     filled: Dict[str, set] = {}          # our layer leaf -> set of layer idxs
@@ -124,7 +128,9 @@ def load_hf_params(
                 )
             depth = nl
             if slots is not None:
-                kind, layer_idx = slots[layer_idx]
+                slot = slots[layer_idx]
+                kind, layer_idx = slot[ours] if isinstance(slot, dict) \
+                    else slot
                 ours, depth = f"{kind}/{ours}", sizes[kind]
             if em is not None:
                 # Filed per (layer, expert); a layer counts as filled when

@@ -145,9 +145,10 @@ def conv_step(x: jax.Array,           # [B, C] this token's channels
               w: jax.Array,           # [W, C]; w[W-1] weighs the newest
               live: jax.Array,        # [B] bool: rows that take the token
               bias=None,              # [C], or None (ops/ssd.py's layers)
+              silu: bool = True,      # False: the sum itself (lfm2_moe.py)
               ) -> Tuple[jax.Array, jax.Array]:
-    """SiLU(conv) of one token a row and the conv state after it; a row that
-    is not ``live`` keeps its state."""
+    """SiLU(conv) of one token a row (``silu`` false: the conv itself) and
+    the conv state after it; a row that is not ``live`` keeps its state."""
     window = jnp.concatenate([conv_state, x[:, None].astype(conv_state.dtype)],
                              axis=1)                           # [B, W, C]
     y = jnp.sum(window.astype(jnp.float32) * w.astype(jnp.float32)[None],
@@ -155,7 +156,7 @@ def conv_step(x: jax.Array,           # [B, C] this token's channels
     if bias is not None:
         y = y + bias.astype(jnp.float32)
     new_state = jnp.where(live[:, None, None], window[:, 1:], conv_state)
-    return jax.nn.silu(y).astype(x.dtype), new_state
+    return (jax.nn.silu(y) if silu else y).astype(x.dtype), new_state
 
 
 def conv_chunk(x: jax.Array,           # [B, T, C]
@@ -163,10 +164,11 @@ def conv_chunk(x: jax.Array,           # [B, T, C]
                w: jax.Array,           # [W, C]
                lens: jax.Array,        # [B] valid tokens of each row
                bias=None,              # [C], or None
+               silu: bool = True,      # False: the sum itself
                ) -> Tuple[jax.Array, jax.Array]:
-    """SiLU(causal depthwise conv) of a chunk that continues ``conv_state``,
-    and the conv state after each row's last valid token (a row of length 0
-    keeps its state)."""
+    """SiLU(causal depthwise conv) of a chunk that continues ``conv_state``
+    (``silu`` false: the conv itself), and the conv state after each row's
+    last valid token (a row of length 0 keeps its state)."""
     width = w.shape[0]
     t = x.shape[1]
     ext = jnp.concatenate([conv_state, x.astype(conv_state.dtype)], axis=1)
@@ -178,7 +180,7 @@ def conv_chunk(x: jax.Array,           # [B, T, C]
     # ext[len : len + W-1] are the inputs of tokens len-W+1 .. len-1.
     idx = lens[:, None] + jnp.arange(width - 1, dtype=jnp.int32)[None, :]
     new_state = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
-    return jax.nn.silu(y).astype(x.dtype), new_state
+    return (jax.nn.silu(y) if silu else y).astype(x.dtype), new_state
 
 
 # ------------------------------------------------------------- recurrence
@@ -255,6 +257,13 @@ def step_path(hlo_text: str):
     if "gdn_step_in_place" in hlo_text:
         return "pallas"
     return "xla" if "/gdn_step/" in hlo_text else None
+
+
+def short_conv_path(hlo_text: str):
+    """``"xla"`` where a compiled program (``as_text()``) holds a gated
+    short convolution (models/lfm2_moe.py's inner scope; ``conv_step`` /
+    ``conv_chunk`` have the one execution), else None."""
+    return "xla" if "/short_conv/" in hlo_text else None
 
 
 def gdn_step(state, q, k, v, g, beta, live, *, interpret=False):

@@ -6,7 +6,7 @@ flips on a bf16 rounding, and a flipped choice is a different function of
 the token, not a small error):
 
     s = sigmoid(x W_r);  chosen = top_k(s + bias);
-    w = s[chosen] / sum(s[chosen]) * scaling
+    w = s[chosen] / (sum(s[chosen]) + eps) * scaling
 
 ``bias`` (``e_score_correction_bias``) moves the CHOICE only; the weights
 are the scores themselves.
@@ -39,9 +39,11 @@ STATS = ("assignments", "experts_touched", "expert_load_max", "layer_calls")
 
 
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float, norm_topk_prob: bool = True,
+          scaling: float, norm_topk_prob: bool = True, eps: float = 1e-20,
           ) -> Tuple[jax.Array, jax.Array]:
-    """x [N, D] -> (chosen experts [N, k] int32, weights [N, k] float32)."""
+    """x [N, D] -> (chosen experts [N, k] int32, weights [N, k] float32).
+    ``eps``: what the weights' sum takes (DeepSeek-V3's 1e-20; LFM2's
+    published 1e-6)."""
     with jax.named_scope("moe_route"):
         s = jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), w_router.astype(jnp.float32),
@@ -49,7 +51,7 @@ def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
         _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
         w = jnp.take_along_axis(s, idx, axis=1)
         if norm_topk_prob:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + eps)
         return idx.astype(jnp.int32), w * scaling
 
 

@@ -49,7 +49,15 @@ import pytest
 # indices it has. (``..._since_the_parent`` skips where git has no history,
 # which a strict xfail lets through.) From PR 40 on the benchmark's tests
 # pin their entries by INDEX and nothing to an end, so this list need not
-# grow again.
+# grow again for THAT reason.
+#
+# PR 40's ``test_bench_ssm.py`` holds one more assumption: that every
+# ``workloads`` list which changed since PR 40's parent changed by granite's
+# cell. PR 44's cell reports ``moe_experts_touched`` (its experts are the
+# program's counters'), a list granite's cell is not in, so that one test
+# fails at the name it finds there. Same mark, on that condition; what else
+# it asserts (nothing that was there changed, lists only grew at their ends)
+# is held against PR 44's parent by ``tests/chip_bench/test_bench_lfm.py``.
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PR36_TEST = ("test_bench_issue.py::"
               "test_the_six_are_the_last_of_per_layer_and_list_every_cell")
@@ -59,6 +67,10 @@ _PR38_TESTS = tuple("test_bench_hc.py::" + name for name in (
     "test_the_cell_is_named_last_where_its_readers_find_something",
     "test_the_manifest_only_grew_at_the_ends_since_the_parent"))
 _PR38_LAST = "hc_share_pct"
+_PR40_TEST = ("test_bench_ssm.py::"
+              "test_the_manifest_only_grew_since_the_parent")
+_PR40_NOT_IN = "moe_experts_touched"
+_PR40_CELL = "granite-4.0-h-micro.chat-saturated"
 
 
 def pytest_collection_modifyitems(items):
@@ -67,18 +79,24 @@ def pytest_collection_modifyitems(items):
         if inspect.iscoroutinefunction(getattr(item, "function", None)):
             item.obj = _sync_wrapper(item.function)
     with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        last = json.load(f)["per_layer"][-1]["name"]
+        per_layer = json.load(f)["per_layer"]
+    last = per_layer[-1]["name"]
     overtaken = [(tests, pr) for tests, is_last, pr in (
         ((_PR36_TEST,), _PR36_LAST, 36), (_PR38_TESTS, _PR38_LAST, 38))
         if last != is_last]
+    others = next(m["workloads"] for m in per_layer
+                  if m["name"] == _PR40_NOT_IN)
+    if _PR40_CELL not in others and len(others) > 2:
+        overtaken.append(((_PR40_TEST,), 40))
     for item in items:
         for tests, pr in overtaken:
             if item.nodeid.endswith(tests):
                 item.add_marker(pytest.mark.xfail(
                     strict=True, raises=AssertionError,
                     reason=f"asserts PR {pr}'s entries are the last of "
-                           "their lists; a PR may only append (see the "
-                           "note above)"))
+                           "their lists (PR 40: the only cell lists grew "
+                           "by); a PR may only append (see the note "
+                           "above)"))
 
 
 def _sync_wrapper(fn):

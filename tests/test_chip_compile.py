@@ -1092,3 +1092,85 @@ def test_latent_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, name):
         # tensor [.., 3072 keys]: neither is there.
         assert 3072 not in shape, shape
     assert compiled.memory_analysis().temp_size_in_bytes < parent_temp / 2
+
+
+# ---- lfm2-8b-a1b-d16: gated short convolutions around routed experts (PR 44)
+# Instructions of a compiled dispatch program (3908 and 4291 at the time of
+# writing, kanana's 4003 and 4257 beside them: ONE attention operator, ONE
+# sparse FFN and one convolution a scan, whatever the depth and wherever the
+# attention layers stand; a second traced copy of the experts shows here).
+LFM_INSTRUCTIONS = 4900
+
+
+@pytest.mark.parametrize("program", ["decode-64x32", "decode-16x32",
+                                     "prefill-8x128", "prefill-1x1024"])
+def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
+        v5e, program):
+    """The decode program at the widest and at the window's 16-row bucket
+    and the fullest and the longest prefill program of lfm2-8b-a1b-d16's
+    envelope (deployment.json's flags, published widths, all 32 experts of
+    14 sparse layers, 12 conv layers' state in 65 slots) compile for a v5e,
+    fit its HBM beside 10.80 GB of weights and the 1.61 GB K/V pool, and
+    copy neither a pool, nor the conv state a decode loop carries, nor an
+    expert stack. They hold the Mosaic kernels: the attention layers' paged
+    kernel (decode or prefill flash, over 64-lane KV heads two to a row) and
+    the two grouped matmuls of the sparse scan; the convolution is plain
+    XLA under its own scope."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops import gated_delta, ssd
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "lfm2-8b-a1b-d16")
+    assert [p.shape for p in r.state_pools] == [(65, 12, 32, 128)]
+    assert [str(p.dtype) for p in r.state_pools] == ["bfloat16"]
+    assert r.kv_k.shape == r.kv_v.shape == (4, 4, 12288 * 16, 128)
+    assert r.prefill_reads_pool and r.fwd_stats
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    sparse = aparams["layers"]["sparse"]
+    assert sparse["w_gate_up"].shape == (14, 32, 2048, 3584)
+    assert sparse["w_router"].dtype == jnp.float32
+    decode = program.startswith("decode")
+    rows, t = (int(x) for x in program.split("-")[1].split("x"))
+    if decode:
+        lowered = r._lower_decode(aparams, rows, full_mb, t, False)
+    else:
+        assert (rows, t, full_mb, False) in r.reachable_prefill_families()
+        lowered = r._lower_prefill(aparams, rows, t, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    carried = [jax.ShapeDtypeStruct((rows, 12, 32, 128), jnp.bfloat16)]
+    experts = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for k in (
+        "w_gate_up", "we_down") for shape in (
+            sparse[k].shape, (14 * 32, *sparse[k].shape[2:]))]
+    assert pool_copies(
+        text, [r.kv_k, *r.state_pools, *carried, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert gated_delta.short_conv_path(text) == "xla"
+    assert ssd.step_path(text) is None
+    assert gated_delta.step_path(text) is None
+    if not decode:
+        assert prefill_attn_path(text) == "pallas"
+    for scope in ("embed", "attn_proj", "attn_core", "short_conv", "ffn",
+                  "moe_route", "moe_experts", "moe_gmm", "logits",
+                  "kv_write", "state_write", "sample"):
+        assert f"/{scope}/" in text, scope
+    instructions = sum(1 for ln in text.splitlines() if " = " in ln)
+    assert instructions < LFM_INSTRUCTIONS, instructions
+    mem = compiled.memory_analysis()
+    # Weights 10.80 GB, K/V 1.61 GB and 6.4 MB of slots are arguments; a
+    # program's temporaries (0.11 GB at the widest) fit beside them.
+    assert 12.4e9 < mem.argument_size_in_bytes < 12.45e9
+    assert mem.temp_size_in_bytes < 0.4e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_prefill_family_count_of_the_short_conv_deployment(v5e):
+    """7 prefill families (1 x {128..1024}, 4 x {128, 256}, 8 x 128), as
+    kanana's under the same token budget, none with a window."""
+    r = _deployment_runner(v5e, "lfm2-8b-a1b-d16")
+    fams = r.reachable_prefill_families()
+    assert len(fams) == 7 and {f[3] for f in fams} == {False}
+    assert all(rows * t <= 1024 for rows, t, _, _ in fams)
