@@ -113,9 +113,10 @@ class EngineConfig:
     # issued while a fused decode scan is still in flight (and decode keeps
     # its cadence during a long prompt's chunk train) instead of the two
     # kinds strictly alternating through a single slot. Rows finishing
-    # their prompt in an in-flight prefill join decode only after that
-    # prefill's tokens are applied (single-source token chaining). False is
-    # the fallback to the round-5 one-batch-per-round loop.
+    # their prompt in an in-flight prefill join the decode issued right
+    # behind it, chaining their start token from the prefill's device
+    # vector (one source a decode: the loop's depth is 2). False is the
+    # fallback to the round-5 one-batch-per-round loop.
     overlap_dispatch: bool = True
     # --- prefill/decode disaggregation (docs/DISAGG.md) ---
     # "unified" serves prompts end-to-end. "prefill" computes prompt KV +

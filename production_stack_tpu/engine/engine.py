@@ -257,6 +257,13 @@ class ServingEngine:
         # wasted row-steps, is the device time at stake; dispatch by
         # dispatch, empty steps x rows <= wasted row-steps.
         self.decode_steps_empty_total = 0
+        # The hand-off from prefill to decode, counted at ISSUE: rows a
+        # decode dispatch took for the first time since their last prompt
+        # chunk, and those of them whose first token was still in the
+        # in-flight prefill's device vector (they ride the train issued
+        # right behind their prefill; the rest waited out its apply).
+        self.decode_rows_first_total = 0
+        self.decode_rows_joined_total = 0
         # What a prefill dispatch carried, counted at ISSUE beside
         # prefill_dispatches_total (the issue span carries the same
         # numbers): the tokens really prefilled, the rectangle the program
@@ -699,7 +706,8 @@ class ServingEngine:
             else:
                 data = {
                     "step": step, "rows": len(batch.seqs),
-                    "k": batch.num_steps, **state_rows, **stalled,
+                    "k": batch.num_steps, "joined": batch.joined_rows,
+                    **state_rows, **stalled,
                 }
                 if getattr(batch, "spec_mode", "off") != "off":
                     # Which speculative variant the runner actually
@@ -834,16 +842,29 @@ class ServingEngine:
         (apply_results), strictly in issue order; rows that finish or get
         preempted while a dispatch is in flight simply discard its tokens
         for them (epoch check), and a chained dispatch's start tokens ride
-        ONE device-resident last-token vector (fresh prefill rows join
-        decode only after their prefill's apply, so a decode never needs
-        chains from two in-flight dispatches)."""
+        ONE device-resident last-token vector. That single source rests on
+        this loop's depth and on nothing in the scheduler: see the clamp
+        below. A row whose last prompt chunk is in flight therefore joins
+        the decode train issued right behind it (it chains its start token
+        from the prefill's vector) and does not sit out a whole train
+        between its first and second token."""
         loop = asyncio.get_running_loop()
         cfg = self.config
-        # Clamped to 2: at depth >= 3 a third decode could need start-token
-        # chains from TWO unapplied decode dispatches at once (a row the
-        # window budget skipped in the middle one), breaking the
-        # single-source invariant — and a device queue of 2 already hides
-        # the host round-trip.
+        # Clamped to 2, and the single-source rule of token chaining (a
+        # decode program takes ONE prev_last vector) rests on it: a decode
+        # is issued only while fewer than `depth` dispatches are in flight,
+        # so at most ONE other dispatch is unapplied then; fetches apply in
+        # issue order, so every older dispatch's tokens are on the host
+        # (or its rows aborted). Whatever that one dispatch is — a decode,
+        # or a prefill whose final rows the scheduler now hands straight to
+        # the next decode — it is the only vector a row's start token can
+        # still live in. The penalty drain() only empties the pipeline
+        # further; restores and prewarms add rows whose tokens are on the
+        # host. runner._issue_decode checks it (its two RuntimeErrors). At
+        # depth >= 3 a third decode could need chains from TWO unapplied
+        # dispatches at once (a row the window budget skipped in the middle
+        # one, or a prefill behind a decode) — and a device queue of 2
+        # already hides the host round-trip.
         depth = max(1, min(2, cfg.pipeline_depth)) if cfg.async_pipeline \
             else 1
         if cfg.speculative_num_tokens:
@@ -981,7 +1002,10 @@ class ServingEngine:
                 step = self._step_counter
                 self._step_counter += 1
                 if batch.kind == "decode":
-                    carried = {"k": batch.num_steps}
+                    batch.joined_rows = sum(
+                        s.pending_prefill_apply for s in batch.seqs)
+                    carried = {"k": batch.num_steps,
+                               "joined": batch.joined_rows}
                 else:
                     # What the dispatch carries against the rectangle its
                     # program computes, and what stopped admission: the
@@ -1039,6 +1063,9 @@ class ServingEngine:
                         )
                     if batch.kind == "decode":
                         self.decode_dispatches_total += 1
+                        self.decode_rows_first_total += sum(
+                            s.awaits_first_decode for s in batch.seqs)
+                        self.decode_rows_joined_total += batch.joined_rows
                     else:
                         self.prefill_dispatches_total += 1
                         self.prefill_tokens_issued_total += tokens
@@ -1656,6 +1683,8 @@ class ServingEngine:
             "decode_row_steps_wasted_total":
                 self.decode_row_steps_wasted_total,
             "decode_steps_empty_total": self.decode_steps_empty_total,
+            "decode_rows_first_total": self.decode_rows_first_total,
+            "decode_rows_joined_total": self.decode_rows_joined_total,
             # What prefill dispatches carried and what stopped admission
             # (counted at issue; the scheduler's blocked passes beside the
             # dispatches' own stops), and compiles past warm-up.

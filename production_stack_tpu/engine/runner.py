@@ -605,10 +605,12 @@ class ModelRunner:
         # host has not fetched yet (pipelined engine loop). A LIST (newest
         # first) because the two-slot overlap loop can interleave kinds —
         # e.g. decode D1, prefill P1, decode D2: D2's rows chain from D1's
-        # vector even though P1's entry is newer. Any one decode still
-        # chains from a SINGLE source vector (the scheduler keeps
-        # fresh-prefill rows out of decode until their tokens are applied);
-        # _issue_decode enforces that invariant.
+        # vector even though P1's entry is newer — and P1's final rows
+        # chain from P1's vector when D2 is issued before P1's apply. Any
+        # one decode still chains from a SINGLE source vector: the engine
+        # loop holds at most two dispatches in flight, so only one is
+        # unapplied when a decode is issued (engine._run_loop);
+        # _issue_decode checks that invariant.
         self._b_max = _bucket(config.max_num_seqs, 1,
                               max(1, config.max_num_seqs))
         self._chains: List[Dict] = []
@@ -2246,10 +2248,14 @@ class ModelRunner:
             # an in-flight dispatch's device buffer (unapplied — the
             # pipelined engine issues before fetching) reads it ON DEVICE
             # from that dispatch's last-token vector; rows with
-            # fully-applied host tokens take the packed tokens0. All
-            # chained rows must resolve to the SAME source dispatch — the
-            # scheduler guarantees it (fresh prefill rows wait for apply;
-            # at most one token-producing dispatch is unapplied at issue).
+            # fully-applied host tokens take the packed tokens0. The
+            # source may be a decode (the row rode it) or a prefill (the
+            # row's last prompt chunk: it joins this train straight behind
+            # it). All chained rows must resolve to the SAME source
+            # dispatch — the engine loop's depth guarantees it (at most
+            # two dispatches in flight, so exactly one is unapplied when
+            # this one is issued: engine._run_loop); the two errors below
+            # are the check.
             if pos < len(s.all_token_ids):
                 sc[0, i] = s.all_token_ids[pos]
             else:

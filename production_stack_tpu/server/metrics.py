@@ -47,6 +47,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "loop_other_seconds_total", "decode_steps_total",
                 "decode_row_steps_total", "decode_bucket_row_steps_total",
                 "decode_row_steps_wasted_total", "decode_steps_empty_total",
+                "decode_rows_first_total", "decode_rows_joined_total",
                 "prefill_tokens_issued_total", "prefill_tokens_padded_total",
                 "prefill_rows_issued_total", "prefill_left_waiting_total",
                 "prefill_stop_rows_total", "prefill_stop_seqs_total",
@@ -368,6 +369,18 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:decode_steps_empty_total counter",
         f"pstpu:decode_steps_empty_total{label} "
         f"{s['decode_steps_empty_total']}",
+        "# HELP pstpu:decode_rows_first_total Rows a decode dispatch took "
+        "for the first time since their last prompt chunk, counted at "
+        "issue",
+        "# TYPE pstpu:decode_rows_first_total counter",
+        f"pstpu:decode_rows_first_total{label} "
+        f"{s['decode_rows_first_total']}",
+        "# HELP pstpu:decode_rows_joined_total Of those, rows whose first "
+        "token was still in the in-flight prefill's device vector: they "
+        "ride the decode train issued right behind their prefill",
+        "# TYPE pstpu:decode_rows_joined_total counter",
+        f"pstpu:decode_rows_joined_total{label} "
+        f"{s['decode_rows_joined_total']}",
         # What a prefill dispatch carried and what stopped its admission
         # pass, counted at issue (the pstpu.issue span carries the same
         # numbers), and compiles past warm-up.

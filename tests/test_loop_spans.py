@@ -48,6 +48,8 @@ NEW_SERIES = (
     "pstpu:prefill_left_waiting_total",
     *(f"pstpu:prefill_stop_{stop}_total" for stop in PREFILL_STOPS),
     "pstpu:serving_compiles_total", "pstpu:serving_compile_seconds_total",
+    # The hand-off from prefill to decode (PR 45).
+    "pstpu:decode_rows_first_total", "pstpu:decode_rows_joined_total",
 )
 
 

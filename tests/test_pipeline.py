@@ -221,3 +221,170 @@ async def test_prefill_arrives_mid_decode_parity():
     assert outs["pipeline"] == outs["strict"]
     assert len(outs["overlap"]["long"]) == 48
     assert len(outs["overlap"]["late"]) == 12
+
+
+def _issues(recorder, request_id, kind):
+    """The ``<kind>_issue`` events of one request, in time order."""
+    events = recorder.get(request_id)["records"][0]["events"]
+    return [ev for ev in events if ev["event"] == f"{kind}_issue"]
+
+
+# A second model keeps recurrent state in slots beside its K/V blocks: the
+# joined train runs behind the prefill that wrote the row's slot.
+@pytest.mark.parametrize("model", ("tiny-llama", "tiny-granite-hybrid"))
+@pytest.mark.asyncio
+async def test_late_row_rides_the_train_behind_its_prefill(model):
+    """A prompt of several chunks arrives while a stream decodes. The
+    tokens are the strict loop's, and the dispatch timeline shows the
+    row's first decode dispatch is the one issued right after the prefill
+    that held its last chunk: it chained its start token from that
+    prefill's device vector and sat out no train."""
+    outs, engines = {}, {}
+    for name, over in (LOOP_MODES[0], LOOP_MODES[2]):
+        engine = engines[name] = ServingEngine(_cfg(
+            True, model=model, max_num_batched_tokens=64, **over))
+        await engine.start()
+        try:
+            results = {}
+
+            async def collect(key, prompt, sp):
+                toks = []
+                async for o in engine.generate(prompt=prompt, sampling=sp,
+                                               request_id=key):
+                    toks = o.token_ids
+                results[key] = toks
+
+            steady = asyncio.create_task(collect(
+                "steady", "steady decode stream goes on",
+                SamplingParams(temperature=0.0, max_tokens=64,
+                               ignore_eos=True),
+            ))
+            for _ in range(800):
+                if engine.scheduler.num_running > 0:
+                    break
+                await asyncio.sleep(0.005)
+            late = asyncio.create_task(collect(
+                "late", " ".join(f"ctx{i}" for i in range(30)),
+                SamplingParams(temperature=0.9, seed=7, max_tokens=12,
+                               ignore_eos=True),
+            ))
+            await asyncio.gather(steady, late)
+            outs[name] = results
+        finally:
+            await engine.stop()
+    assert outs["overlap"] == outs["strict"]
+    assert len(outs["overlap"]["steady"]) == 64
+    assert len(outs["overlap"]["late"]) == 12
+
+    rec = engines["overlap"].recorder
+    chunks = _issues(rec, "late", "prefill")
+    assert len(chunks) > 1, "the late prompt was one chunk"
+    last_chunk = chunks[-1]["step"]
+    first_decode = _issues(rec, "late", "decode")[0]
+    decode_steps = sorted({ev["step"] for rid in ("steady", "late")
+                           for ev in _issues(rec, rid, "decode")})
+    assert first_decode["step"] == min(
+        st for st in decode_steps if st > last_chunk)
+    assert first_decode["joined"] == 1 and first_decode["rows"] == 2
+    # Both hand-offs joined: the steady row's on an idle engine too (the
+    # loop fills its second slot before it fetches the prefill).
+    stats = engines["overlap"].stats()
+    assert stats["decode_rows_first_total"] == 2
+    assert stats["decode_rows_joined_total"] == 2
+    # The strict loop applies every prefill before it schedules again.
+    strict = engines["strict"].stats()
+    assert strict["decode_rows_first_total"] == 2
+    assert strict["decode_rows_joined_total"] == 0
+
+
+@pytest.mark.asyncio
+async def test_chaining_keeps_one_source_under_a_random_schedule():
+    """Arrivals at random moments, aborts mid-stream and preemptions under
+    a tight pool: no decode dispatch ever finds a row whose start token is
+    neither on the host nor in a recent dispatch's vector, or rows that
+    chain from two dispatches (``_issue_decode`` raises either as a
+    RuntimeError and the loop would abort the batch). The requests that
+    run to their end give the strict loop's tokens."""
+    import random
+
+    rng = random.Random(45)
+    plan = []
+    for i in range(14):
+        plan.append(dict(
+            key=f"r{i}", prompt=" ".join(
+                f"w{rng.randrange(50)}" for _ in range(rng.randrange(2, 14))),
+            max_tokens=rng.randrange(2, 34), delay=rng.random() * 0.25,
+            seed=None if i % 3 else 100 + i,
+            abort_after=rng.randrange(1, 6) if i % 5 == 4 else None,
+        ))
+    plan[3]["max_tokens"] = 1
+
+    async def run_plan(engine, plan, paced):
+        done = {}
+
+        async def one(req):
+            if paced:
+                await asyncio.sleep(req["delay"])
+            sp = SamplingParams(
+                temperature=0.0 if req["seed"] is None else 0.8,
+                seed=req["seed"], max_tokens=req["max_tokens"],
+                ignore_eos=True)
+            agen = engine.generate(prompt=req["prompt"], sampling=sp,
+                                   request_id=req["key"])
+            toks = []
+            async for o in agen:
+                toks = o.token_ids
+                if req["abort_after"] and paced and \
+                        o.num_output_tokens >= req["abort_after"]:
+                    await agen.aclose()
+                    return
+            done[req["key"]] = toks
+
+        await asyncio.gather(*(one(req) for req in plan))
+        return done
+
+    engine = ServingEngine(_cfg(
+        True, num_kv_blocks=12, max_model_len=256, max_num_seqs=4,
+        max_num_batched_tokens=64))
+    raised = []
+    issue = engine.runner.execute_async
+
+    def checked_issue(batch, step):
+        try:
+            return issue(batch, step)
+        except Exception as e:  # noqa: BLE001 — recorded, then as before
+            raised.append(repr(e))
+            raise
+
+    engine.runner.execute_async = checked_issue
+    await engine.start()
+    try:
+        served = await run_plan(engine, plan, paced=True)
+        for _ in range(200):
+            if not engine.scheduler.has_work():
+                break
+            await asyncio.sleep(0.02)
+        stats = engine.stats()
+        assert not engine.scheduler.has_work()
+        assert engine.block_manager.num_used_blocks == 0
+    finally:
+        await engine.stop()
+    assert raised == []
+    assert stats["num_preemptions"] > 0, "the pool no longer forces one"
+    assert stats["decode_rows_joined_total"] > 0
+    assert stats["decode_rows_joined_total"] <= \
+        stats["decode_rows_first_total"]
+    finishing = [req for req in plan if not req["abort_after"]]
+    assert sorted(served) == sorted(req["key"] for req in finishing)
+
+    strict = ServingEngine(_cfg(
+        False, max_model_len=256, max_num_seqs=4, max_num_batched_tokens=64,
+        overlap_dispatch=False))
+    await strict.start()
+    try:
+        calm = await run_plan(strict, finishing, paced=False)
+    finally:
+        await strict.stop()
+    assert served == calm
+    assert [len(served[req["key"]]) for req in finishing] == \
+        [req["max_tokens"] for req in finishing]

@@ -495,6 +495,22 @@ REGISTRY: Tuple[Series, ...] = (
            "rows as at 20: over `pstpu:decode_steps_total` this is the "
            "share of decode device time that served nobody; dispatch by "
            "dispatch, empty steps x rows <= wasted row-steps"),
+    Series("pstpu:decode_rows_first_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Rows a decode dispatch took for the first time since their "
+           "last prompt chunk, counted at issue (a preempted row "
+           "prefilled again counts again; a row that ends at its first "
+           "token never counts)"),
+    Series("pstpu:decode_rows_joined_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Of `pstpu:decode_rows_first_total`, rows whose first token was "
+           "still in the in-flight prefill's device vector at that issue: "
+           "the decode chained its start token from it, so the row rides "
+           "the train issued right behind its prefill; the rest waited "
+           "out the prefill's apply (the window budget or the block pool "
+           "left the row out, a penalty batch drained the pipeline, or "
+           "the loop runs at depth 1). The ratio is the share of "
+           "hand-offs that cost no train"),
     Series("pstpu:prefill_tokens_issued_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Prompt tokens prefill dispatches really computed (the sum of "
