@@ -1245,6 +1245,23 @@ LATENT_PREFILL_TIMING_SHAPES = [
     for rows, t in ((8, 128), (4, 256), (1, 1024))
     for hist in (64, 320, 2048)
 ]
+# A PACKED row (PR 46: ``runner.prefill_packs``) beside the rectangle the
+# same sequences would have run as: cell 3's dispatch, 16 segments that
+# fill a 2048-token row behind histories of 0-300 tokens, against the
+# ``[16, 128]`` rectangle of their first 128 tokens each; and cell 2's, one
+# 256-token suffix behind 6000 tokens, against its ``[1, 256]`` row (a
+# segment that fills whole query blocks must cost what its row costs).
+# ``sub_blocks``: the packed kernel also at these sub-block widths (tokens;
+# the first is ``packed_sub_block``'s own; a segment that has a whole
+# block takes it in the kernel's wider pieces whatever this says).
+PACKED_PREFILL_TIMING_SHAPES = [
+    {"name": "packed-chat-saturated", "t": 2048, "segments": 16, "slots": 16,
+     "hist": (0, 300), "heads": 16, "kv_heads": 2, "rect": (16, 128),
+     "sub_blocks": (32, 64, 256)},
+    {"name": "packed-agent-prefix", "t": 256, "segments": 1, "slots": 16,
+     "hist": (6000, 6000), "heads": 32, "kv_heads": 8, "rect": (1, 256),
+     "sub_blocks": (64, 128)},
+]
 PREFILL_TIMING_CALLS = 64
 PREFILL_MAX_ABS_ERR = 2e-2     # bf16 outputs of unit-variance values
 
@@ -1427,10 +1444,135 @@ def prefill_child(rehearse: bool) -> int:
         # As it goes, beside the phase's one line at the end: a later
         # shape that dies keeps the earlier ones' numbers.
         print(json.dumps(entry), file=sys.stderr, flush=True)
+    packed = []
+    for shape in PACKED_PREFILL_TIMING_SHAPES:
+        if rehearse:
+            shape = {**shape, "t": 64, "segments": min(shape["segments"], 3),
+                     "slots": 4, "hist": (0, 40), "heads": 4, "kv_heads": 2,
+                     "rect": (min(shape["rect"][0], 4), 32),
+                     "sub_blocks": (16, 32)}
+        entry, err = _time_packed_prefill(shape, calls, layers, interpret)
+        checks.append({"shape": shape["name"], "max_abs_err": err,
+                       "bound": PREFILL_MAX_ABS_ERR,
+                       "finite": err != float("inf")})
+        ok = ok and err <= PREFILL_MAX_ABS_ERR
+        packed.append(entry)
+        print(json.dumps(entry), file=sys.stderr, flush=True)
     emit({"phase": "prefill", "interpret": interpret, "checks": checks,
-          "timing": timing, "peak": peak, "device": device,
-          "ok": ok and (rehearse or not interpret)})
+          "timing": timing, "packed": packed, "peak": peak,
+          "device": device, "ok": ok and (rehearse or not interpret)})
     return 0 if ok else 1
+
+
+def _time_packed_prefill(shape, calls, layers, interpret):
+    """One of PACKED_PREFILL_TIMING_SHAPES: the packed kernel over a row of
+    ``segments`` segments that fill ``t`` tokens, checked against the
+    rectangle kernel over the row taken apart (a row a segment), then both
+    timed: the packed row, and the ``rect`` rectangle of the segments'
+    first tokens, every operand an argument behind an
+    ``optimization_barrier`` (``chained_chunks``'s lesson: what does not
+    change between calls is otherwise lifted out of the loop). Returns (the
+    entry, the largest difference over the segments' tokens)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from production_stack_tpu.ops.attention import unpack_segments
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill,
+        paged_flash_prefill_packed,
+    )
+
+    dh, bs = 128, 16
+    rng = np.random.default_rng(46)
+    t, n, slots_n, h, hkv = (shape[k] for k in (
+        "t", "segments", "slots", "heads", "kv_heads"))
+    # Lengths that fill the row: a random cut of t into n segments.
+    cuts = np.sort(rng.choice(np.arange(1, t), n - 1, replace=False)) \
+        if n > 1 else np.zeros((0,), np.int64)
+    lens = np.zeros((slots_n,), np.int64)
+    lens[:n] = np.diff(np.concatenate([[0], cuts, [t]]))
+    hist = np.zeros((slots_n,), np.int64)
+    hist[:n] = rng.integers(shape["hist"][0], shape["hist"][1] + 1, n)
+    live = -(-(hist + lens) // bs) * (lens > 0)
+    mb = int(max(live)) + 4
+    tables = np.zeros((slots_n, mb), np.int32)
+    order = 1 + rng.permutation(int(live.sum()))
+    at = 0
+    for i in range(slots_n):
+        tables[i, :live[i]] = order[at:at + live[i]]
+        at += live[i]
+    pool_slots = (1 + int(live.sum())) * bs
+    keys = jax.random.split(jax.random.PRNGKey(46), 5)
+
+    def normal(key, *dims):
+        return jax.random.normal(key, dims, jnp.bfloat16)
+
+    q, k, v = (normal(keys[0], 1, t, h, dh), normal(keys[1], 1, t, hkv, dh),
+               normal(keys[2], 1, t, hkv, dh))
+    pools = (normal(keys[3], layers, hkv, pool_slots, dh),
+             normal(keys[4], layers, hkv, pool_slots, dh))
+    tables = jnp.asarray(tables)
+    seg_lens = jnp.asarray(lens, jnp.int32)
+    kv_lens = jnp.asarray(hist, jnp.int32)
+
+    def packed_call(sub_block):
+        def call(q, layer, k, v, seg_lens, tables, kv_lens, *pools):
+            return paged_flash_prefill_packed(
+                q, k, v, seg_lens, *pools, tables, kv_lens, layer,
+                block_size=bs, interpret=interpret, sub_block=sub_block)
+        return call
+
+    def rectangle(q, layer, k, v, chunk_lens, tables, kv_lens, *pools):
+        positions = kv_lens[:, None] + jnp.arange(
+            q.shape[1], dtype=jnp.int32)[None]
+        return paged_flash_prefill(
+            q, k, v, positions, chunk_lens, *pools, tables, kv_lens, layer,
+            block_size=bs, interpret=interpret)
+
+    # The row taken apart, a row a segment of t tokens: the check.
+    rows, put_back = unpack_segments(seg_lens, t)
+    held = (k, v, seg_lens, tables, kv_lens, *pools)
+    got = jax.jit(packed_call(None))(q, 1, *held).astype(jnp.float32)
+    apart = jax.jit(rectangle)(
+        q[0][rows], 1, k[0][rows], v[0][rows], *held[2:])
+    want = put_back(apart.astype(jnp.float32))[None]
+    err = float(jnp.max(jnp.abs(got - want))) \
+        if bool(jnp.all(jnp.isfinite(got))) else float("inf")
+
+    def chained(fn):
+        def run(x, *held):
+            def one(i, x):
+                x, args = jax.lax.optimization_barrier((x, held))
+                return fn(x, i % layers, *args)
+            return jax.lax.fori_loop(0, calls, one, x)
+        return jax.jit(run)
+
+    # The rectangle the same sequences would have run as: their first
+    # ``rect`` tokens each, a row a sequence.
+    rb, rt = shape["rect"]
+    rect_lens = jnp.minimum(seg_lens[:rb], rt)
+    rect_held = (k[0][rows[:rb, :rt]], v[0][rows[:rb, :rt]], rect_lens,
+                 tables[:rb], kv_lens[:rb], *pools)
+    sec_rect = best_of(chained(rectangle),
+                       (q[0][rows[:rb, :rt]], *rect_held), calls)
+    entry = {
+        "shape": shape["name"], "t": t, "segments": n,
+        "seg_lens": [int(x) for x in lens[:n]],
+        "history": [int(x) for x in hist[:n]],
+        "rectangle": [rb, rt], "rectangle_tokens": int(rect_lens.sum()),
+        "rectangle_us": None, "packed_us": {}}
+    for sb in shape["sub_blocks"]:
+        sec = best_of(chained(packed_call(sb)), (q, *held), calls)
+        if not interpret:
+            entry["packed_us"][str(sb)] = sec * 1e6
+    if not interpret:
+        entry["rectangle_us"] = sec_rect * 1e6
+        first = entry["packed_us"][str(shape["sub_blocks"][0])]
+        entry["packed_us_per_token"] = first / t
+        entry["rectangle_us_per_token"] = \
+            sec_rect * 1e6 / max(1, entry["rectangle_tokens"])
+    return entry, err
 
 
 def phase_serve(model, engine_args, attn: str) -> dict:

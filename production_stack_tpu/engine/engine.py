@@ -178,6 +178,7 @@ class ServingEngine:
             config, self.block_manager, offload=self.offload,
             decode_window_budget=self.runner.decode_window_blocks,
             prefill_window_budget=self.runner.prefill_window_blocks,
+            prefill_packed=self.runner.prefill_packs,
         )
 
         self._streams: Dict[str, _StreamState] = {}
@@ -266,14 +267,18 @@ class ServingEngine:
         self.decode_rows_joined_total = 0
         # What a prefill dispatch carried, counted at ISSUE beside
         # prefill_dispatches_total (the issue span carries the same
-        # numbers): the tokens really prefilled, the rectangle the program
-        # computes (utils.prefill_rectangle), the live rows, the requests
-        # the admission pass left waiting, and the limit that stopped it
+        # numbers): the tokens really prefilled, the shape the program
+        # computes (utils.prefill_rectangle: rows x T, ONE row where the
+        # sequences' chunks are packed end to end), the sequences it
+        # carried, those of them that lay as segments of a packed row
+        # (0 while every dispatch is a rectangle), the requests the
+        # admission pass left waiting, and the limit that stopped it
         # (scheduler.PREFILL_STOPS; the scheduler's blocked passes are
         # added in stats()).
         self.prefill_tokens_issued_total = 0
         self.prefill_tokens_padded_total = 0
         self.prefill_rows_issued_total = 0
+        self.prefill_segments_total = 0
         self.prefill_left_waiting_total = 0
         self.prefill_stops: Dict[str, int] = dict.fromkeys(PREFILL_STOPS, 0)
         # Compiles and persistent-cache loads WHILE SERVING
@@ -687,6 +692,9 @@ class ServingEngine:
         state_rows = {"state_rows": sum(
             1 for s in batch.seqs if s.state_slot)} \
             if self.block_manager.num_state_slots else {}
+        # A packed prefill: how many sequences' chunks share its one row.
+        segments = {"segments": len(batch.seqs)} \
+            if batch.kind == "prefill" and batch.packed else {}
         for idx, seq in enumerate(batch.seqs):
             if seq.first_issue_time is None:
                 seq.first_issue_time = t_mono
@@ -700,8 +708,8 @@ class ServingEngine:
             if batch.kind == "prefill":
                 rec.event(seq.request_id, "prefill_issue", {
                     "step": step, "chunk": batch.chunk_lens[idx],
-                    "start": batch.chunk_starts[idx], **state_rows,
-                    **stalled,
+                    "start": batch.chunk_starts[idx], **segments,
+                    **state_rows, **stalled,
                 }, t=t_wall)
             else:
                 data = {
@@ -1007,15 +1015,18 @@ class ServingEngine:
                     carried = {"k": batch.num_steps,
                                "joined": batch.joined_rows}
                 else:
-                    # What the dispatch carries against the rectangle its
+                    # What the dispatch carries against the shape its
                     # program computes, and what stopped admission: the
                     # same numbers the counters below take.
                     tokens = sum(batch.chunk_lens)
                     prog_rows, prog_t = prefill_rectangle(
-                        len(batch.seqs), max(batch.chunk_lens), cfg)
+                        len(batch.seqs), max(batch.chunk_lens), cfg,
+                        tokens if batch.packed else None)
+                    segments = len(batch.seqs) if batch.packed else 0
                     carried = {
                         "k": max(batch.chunk_lens), "tokens": tokens,
                         "prog_rows": prog_rows, "prog_t": prog_t,
+                        "segments": segments,
                         "left": batch.left_waiting, "stop": batch.stop,
                     }
                 with loop_span(
@@ -1072,6 +1083,7 @@ class ServingEngine:
                         self.prefill_tokens_padded_total += \
                             prog_rows * prog_t
                         self.prefill_rows_issued_total += len(batch.seqs)
+                        self.prefill_segments_total += segments
                         self.prefill_left_waiting_total += \
                             batch.left_waiting
                         if batch.stop != "none":
@@ -1691,6 +1703,7 @@ class ServingEngine:
             "prefill_tokens_issued_total": self.prefill_tokens_issued_total,
             "prefill_tokens_padded_total": self.prefill_tokens_padded_total,
             "prefill_rows_issued_total": self.prefill_rows_issued_total,
+            "prefill_segments_total": self.prefill_segments_total,
             "prefill_left_waiting_total": self.prefill_left_waiting_total,
             **{f"prefill_stop_{stop}_total":
                n + self.scheduler.prefill_blocked[stop]

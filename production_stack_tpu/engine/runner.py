@@ -52,6 +52,7 @@ from production_stack_tpu.ops.attention import (
     gather_window,
     prefill_attn_path,
     prefill_kernel_covers,
+    segment_of_token,
 )
 from production_stack_tpu.ops import gated_delta, ssd
 from production_stack_tpu.ops.kv_write import (
@@ -632,7 +633,7 @@ class ModelRunner:
         self._prefill = jax.jit(
             self._prefill_impl,
             static_argnames=("b", "t", "mb", "has_window", "b_max",
-                             "has_penalties", "logprobs_k"),
+                             "has_penalties", "logprobs_k", "segs"),
             donate_argnums=(2, 3, 4, 5, 8, 9, 10, 11),
         )
 
@@ -1230,6 +1231,42 @@ class ModelRunner:
                 latent=self.kv_pools == 1,
                 scales=self.kv_quantized, kv_sharded=sharded, ring=sharded)
             for t in self._prefill_t_buckets())
+
+    @functools.cached_property
+    def prefill_packs(self) -> bool:
+        """Which of its two forms a prefill dispatch takes, decided HERE
+        for the scheduler (which packs), the engine loop (which counts)
+        and this runner (which warms and issues). True: ONE row of tokens,
+        ``[1, T]``, in which the sequences' chunks lie end to end as
+        segments, so that only the row's end is padding. False: a
+        ``[rows, T]`` rectangle, a row a sequence, every row padded to T.
+
+        A row can be packed where nothing but attention ties a token to
+        its sequence and the kernel can tell the segments apart: the chunk
+        reads paged K/V rows in place and the flash prefill kernel covers
+        every packed row this config can dispatch (``prefill_reads_pool``,
+        and ``prefill_kernel_covers(..., packed=True)``: not latent rows,
+        whose kernel has no packed form yet), the model keeps no per-row
+        state (a scan runs a row of ONE sequence from ONE slot), and no
+        per-row operand rides the forward (LoRA's adapter of a row, the
+        speculative draft's ring of a row)."""
+        mc = self.model_config
+        return self.prefill_reads_pool and not self.state_specs \
+            and not self.lora_stacks and not self.spec_n and all(
+                prefill_kernel_covers(
+                    t, mc.num_heads, self.kv_spec.kv_heads,
+                    self.kv_spec.head_dim, self.kv_value_dim,
+                    self.config.block_size, (self.dtype,),
+                    latent=self.kv_pools == 1, packed=True)
+                for _, t in prefill_rectangles(self.config, True))
+
+    @property
+    def _prefill_segs(self) -> int:
+        """Sequences a packed prefill program has scalars for (0: this
+        runner's dispatches are rectangles)."""
+        cfg = self.config
+        return _bucket(prefill_row_cap(cfg), 1, max(1, cfg.max_num_seqs)) \
+            if self.prefill_packs else 0
 
     def _prefill_mb(self, live_blocks: int, has_window: bool,
                     rows: int = 1) -> int:
@@ -2499,8 +2536,15 @@ class ModelRunner:
                       state_pools, *,
                       b: int, t: int, mb: int, has_window: bool,
                       b_max: int, has_penalties: bool = False,
-                      logprobs_k: int = 0):
+                      logprobs_k: int = 0, segs: int = 0):
         """One (multi-sequence) prefill chunk dispatch.
+
+        Two forms (``prefill_packs``). ``segs`` 0: a ``[b, t]`` rectangle,
+        sequence i the row i. ``segs`` > 0 (and b == 1): ONE packed row of
+        t tokens holding up to ``segs`` sequences' chunks end to end from
+        token 0 (``_forward_packed_row``). Either way ``packed`` carries a
+        set of scalars and a block-table row a SEQUENCE, and what is
+        sampled, chained and fetched is indexed by sequence.
 
         kv_ks/kv_vs: per-(slot, head) dequant scale pools when the KV cache
         is quantized (donated + returned rebound, like _decode_impl); the
@@ -2508,11 +2552,11 @@ class ModelRunner:
         — no extra host round-trip — and the history window gather
         dequantizes inline.
 
-        packed: int32[b*(NUM_SCALARS+mb) + b*t]: per-row scalars
-        (chunk_start, chunk_len, seed_base, gen0, temps, top_k, top_p, pad,
-        adapter, presence, frequency), the [b, mb] block tables, then the
-        [b, t] chunk token ids. Positions and the KV write slots are
-        derived on device.
+        packed: int32[n*(NUM_SCALARS+mb) + b*t], n = segs or b: per-
+        sequence scalars (chunk_start, chunk_len, seed_base, gen0, temps,
+        top_k, top_p, pad, adapter, presence, frequency), the [n, mb]
+        block tables, then the [b, t] chunk token ids. Positions and the
+        KV write slots are derived on device.
 
         counts0/has_penalties/logprobs_k: see _decode_impl — they shape the
         FINAL sampled token (non-final chunks never fetch it). Penalties
@@ -2529,7 +2573,8 @@ class ModelRunner:
         cfg = self.config
         bs = cfg.block_size
         mc = self.model_config
-        scalars = packed[: NUM_SCALARS * b].reshape(NUM_SCALARS, b)
+        n = segs or b       # sequences: segments of the row, or rows
+        scalars = packed[: NUM_SCALARS * n].reshape(NUM_SCALARS, n)
         chunk_start = scalars[0]
         chunk_lens = scalars[1]
         seed_base = jax.lax.bitcast_convert_type(scalars[2], jnp.uint32)
@@ -2541,8 +2586,20 @@ class ModelRunner:
         presence = jax.lax.bitcast_convert_type(scalars[9], jnp.float32)
         frequency = jax.lax.bitcast_convert_type(scalars[10], jnp.float32)
         lora = (adapter_idx, self.lora_stacks) if self.lora_stacks else None
-        block_tables = packed[NUM_SCALARS * b: NUM_SCALARS * b + b * mb].reshape(b, mb)
-        token_ids = packed[NUM_SCALARS * b + b * mb:].reshape(b, t)
+        block_tables = packed[NUM_SCALARS * n: NUM_SCALARS * n + n * mb].reshape(n, mb)
+        token_ids = packed[NUM_SCALARS * n + n * mb:].reshape(b, t)
+        if segs:
+            last_hidden, kv_k, kv_v, fwd_stats = self._forward_packed_row(
+                params, token_ids, block_tables, chunk_start, chunk_lens,
+                kv_k, kv_v)
+            next_tokens, lp = self._sample_first_tokens(
+                params, last_hidden, counts0, temps, top_k, top_p,
+                seed_base, gen0, presence, frequency, has_penalties,
+                logprobs_k)
+            last_token = jnp.zeros((b_max,), jnp.int32).at[:n].set(
+                next_tokens.astype(jnp.int32))
+            return (next_tokens, kv_k, kv_v, kv_ks, kv_vs, *lp, last_token,
+                    spec_k, spec_v, spec_pos, state_pools, fwd_stats)
 
         t_iota = jnp.arange(t, dtype=jnp.int32)
         positions = jnp.minimum(
@@ -2601,21 +2658,9 @@ class ModelRunner:
         fwd_stats = extra.pop(0) if self.fwd_stats else ()
         logit_idx = jnp.maximum(chunk_lens - 1, 0)
         last_hidden = hidden[jnp.arange(b), logit_idx]            # [b, D]
-        logits = self._logits_fn(params, mc, last_hidden)
-        seeds = self._derive_seeds(seed_base, gen0, jnp.uint32(0))
-        if has_penalties:
-            from production_stack_tpu.engine.sampling import apply_penalties
-
-            eff = apply_penalties(logits, counts0, presence, frequency)
-        else:
-            eff = logits
-        next_tokens = sample_tokens(eff, temps, top_k, top_p, seeds)
-        if logprobs_k:
-            from production_stack_tpu.engine.sampling import compute_logprobs
-
-            lp = compute_logprobs(logits, next_tokens, logprobs_k)
-        else:
-            lp = (None, None, None)
+        next_tokens, lp = self._sample_first_tokens(
+            params, last_hidden, counts0, temps, top_k, top_p, seed_base,
+            gen0, presence, frequency, has_penalties, logprobs_k)
 
         # The chunk's KV goes to the pool in place, one block-wide slab
         # at a time (ops/kv_write.py): row i's tokens j < chunk_lens[i]
@@ -2704,11 +2749,77 @@ class ModelRunner:
         return (next_tokens, kv_k, kv_v, kv_ks, kv_vs, lp[0], lp[1], lp[2],
                 last_token, spec_k, spec_v, spec_pos, state_pools, fwd_stats)
 
+    def _sample_first_tokens(self, params, last_hidden, counts0, temps,
+                             top_k, top_p, seed_base, gen0, presence,
+                             frequency, has_penalties: bool,
+                             logprobs_k: int):
+        """The token each sequence of a prefill dispatch would emit after
+        its chunk, from the hidden state of the chunk's last token ([n,
+        D]), and its log-probabilities ((None,) * 3 where none are asked
+        for): what both forms of ``_prefill_impl`` end in."""
+        logits = self._logits_fn(params, self.model_config, last_hidden)
+        seeds = self._derive_seeds(seed_base, gen0, jnp.uint32(0))
+        if has_penalties:
+            from production_stack_tpu.engine.sampling import apply_penalties
+
+            eff = apply_penalties(logits, counts0, presence, frequency)
+        else:
+            eff = logits
+        next_tokens = sample_tokens(eff, temps, top_k, top_p, seeds)
+        if logprobs_k:
+            from production_stack_tpu.engine.sampling import compute_logprobs
+
+            lp = compute_logprobs(logits, next_tokens, logprobs_k)
+        else:
+            lp = (None, None, None)
+        return next_tokens, lp
+
+    def _forward_packed_row(self, params, token_ids, block_tables,
+                            chunk_start, chunk_lens, kv_k, kv_v):
+        """The forward of a PACKED prefill row (``prefill_packs``):
+        ``token_ids`` [1, t] holds the sequences' chunks end to end from
+        token 0, segment i ``chunk_lens[i]`` tokens at positions
+        ``chunk_start[i]`` on, its history the pool's slots below
+        ``chunk_start[i]`` by ``block_tables[i]``. Everything but
+        attention is a function of a token, so the model runs the row as
+        it runs any row; ``attend`` is told where each segment begins
+        (``KVView.seg_lens``). Returns (the hidden state of each segment's
+        last token [n, D], the pools with the row's K/V written to each
+        segment's slots, the forward's counters)."""
+        cfg = self.config
+        seg_end = jnp.cumsum(chunk_lens)
+        seg, within = segment_of_token(chunk_lens, token_ids.shape[1])
+        positions = jnp.minimum(
+            chunk_start[seg] + within, cfg.max_model_len - 1)[None]  # [1, t]
+        view = KVView(
+            pool_k=kv_k, pool_v=kv_v, block_tables=block_tables,
+            kv_lens=chunk_start, seg_lens=chunk_lens,
+            block_size=cfg.block_size,
+        )
+        hidden, k_new, v_new, *extra = self._forward(
+            params, self.model_config, token_ids, positions, seg_end[-1:],
+            view, act_sharding=self._act_sharding, lora=None,
+        )
+        fwd_stats = extra.pop(0) if self.fwd_stats else ()
+        last_hidden = hidden[0, jnp.maximum(seg_end - 1, 0)]      # [n, D]
+        with jax.named_scope("kv_write"):
+            kv_k, kv_v = write_token_runs(
+                (kv_k, kv_v), (k_new, v_new), block_tables, chunk_start,
+                chunk_lens, cfg.block_size,
+                source_start=seg_end - chunk_lens,
+            )
+        return last_hidden, kv_k, kv_v, fwd_stats
+
     def _issue_prefill(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config
         seqs = batch.seqs
         n = len(seqs)
-        b, t = prefill_rectangle(n, max(batch.chunk_lens), cfg)
+        b, t = prefill_rectangle(
+            n, max(batch.chunk_lens), cfg,
+            sum(batch.chunk_lens) if batch.packed else None)
+        # A packed row's scalars and block tables are a SEGMENT each.
+        segs = self._prefill_segs if batch.packed else 0
+        rows = segs or b
         has_window = not self.prefill_reads_pool and \
             any(st > 0 for st in batch.chunk_starts)
         mb = self._prefill_mb(max(len(s.block_ids) for s in seqs),
@@ -2732,10 +2843,12 @@ class ModelRunner:
                 default=0,
             )
 
-        packed = np.zeros((NUM_SCALARS * b + b * mb + b * t,), np.int32)
-        sc = packed[: NUM_SCALARS * b].reshape(NUM_SCALARS, b)
-        bt = packed[NUM_SCALARS * b: NUM_SCALARS * b + b * mb].reshape(b, mb)
-        toks = packed[NUM_SCALARS * b + b * mb:].reshape(b, t)
+        packed = np.zeros((NUM_SCALARS * rows + rows * mb + b * t,),
+                          np.int32)
+        sc = packed[: NUM_SCALARS * rows].reshape(NUM_SCALARS, rows)
+        bt = packed[NUM_SCALARS * rows:
+                    NUM_SCALARS * rows + rows * mb].reshape(rows, mb)
+        toks = packed[NUM_SCALARS * rows + rows * mb:].reshape(b, t)
         f32 = sc.view(np.float32)
         u32 = sc.view(np.uint32)
         if self.spec_n:
@@ -2765,11 +2878,13 @@ class ModelRunner:
             bt[i, :len(s.block_ids)] = s.block_ids
             if self.state_specs:
                 sc[12, i] = s.state_slot
-            toks[i, :ln] = s.all_token_ids[start:start + ln]
+            # A packed row's chunks lie end to end; a rectangle's a row each.
+            row, at = (0, sum(batch.chunk_lens[:i])) if segs else (i, 0)
+            toks[row, at:at + ln] = s.all_token_ids[start:start + ln]
         self._count_sample_dispatch(f32[4], sc[5], f32[6])
         if has_penalties:
             vocab = self.model_config.vocab_size
-            counts = np.zeros((b, vocab), np.int32)
+            counts = np.zeros((rows, vocab), np.int32)
             for i, s in enumerate(seqs):
                 if s.output_token_ids:
                     np.add.at(
@@ -2788,7 +2903,7 @@ class ModelRunner:
             kv_ks, kv_vs, jnp.asarray(counts), dparams, sp_k, sp_v, sp_p,
             self.state_pools,
             b=b, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
-            has_penalties=has_penalties, logprobs_k=logprobs_k,
+            has_penalties=has_penalties, logprobs_k=logprobs_k, segs=segs,
         )
         self._rebind_scale_pools(kv_ks2, kv_vs2)
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
@@ -3104,8 +3219,10 @@ class ModelRunner:
         """Every (b, t, mb, has_window) prefill family reachable under this
         config (see reachable_decode_families). Where the history is read
         in place (``prefill_reads_pool``) a window is no property of the
-        program: ONE family a (rows, t); where it is gathered, the family
-        without a window and the windowed ladder (or its pinned width)."""
+        program: ONE family a (rows, t), and where the dispatches are
+        packed rows (``prefill_packs``) rows is 1; where it is gathered,
+        the family without a window and the windowed ladder (or its pinned
+        width)."""
         cfg = self.config
         full_mb = _bucket(cfg.max_blocks_per_seq, 1,
                           max(1, cfg.max_blocks_per_seq))
@@ -3123,7 +3240,7 @@ class ModelRunner:
         fams = set()
         # Exactly the rectangles a dispatch can run: admission chooses
         # among them and the runner issues what prefill_rectangle says.
-        for pb, t in prefill_rectangles(cfg):
+        for pb, t in prefill_rectangles(cfg, self.prefill_packs):
             fams.add((pb, t, full_mb, False))
             for mb in windowed(pb):
                 if pb * mb <= self.prefill_window_blocks:
@@ -3187,16 +3304,27 @@ class ModelRunner:
         """One prefill family lowered like _lower_decode."""
         mc = self.model_config
         sds = jax.ShapeDtypeStruct
+        length, seqs, shape = self._prefill_program_shape(
+            pb, t, mb, has_window)
         counts = sds(
-            (pb, mc.vocab_size) if has_penalties else (1, 1), jnp.int32
+            (seqs, mc.vocab_size) if has_penalties else (1, 1), jnp.int32
         )
         return self._prefill.lower(
-            aparams, sds((NUM_SCALARS * pb + pb * mb + pb * t,), jnp.int32),
+            aparams, sds((length,), jnp.int32),
             self.kv_k, self.kv_v, *self._scale_pool_args(), counts,
             *self._spec_pool_args(), self.state_pools,
-            b=pb, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
-            has_penalties=has_penalties, logprobs_k=logprobs_k,
+            **shape, has_penalties=has_penalties, logprobs_k=logprobs_k,
         )
+
+    def _prefill_program_shape(self, pb, t, mb, has_window):
+        """Of one prefill family: (the length of its ``packed`` operand,
+        the sequences it has scalars for, its static shape arguments): as
+        ``_issue_prefill`` builds them."""
+        segs = self._prefill_segs
+        seqs = segs or pb
+        return NUM_SCALARS * seqs + seqs * mb + pb * t, seqs, dict(
+            b=pb, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
+            segs=segs)
 
     def audit_pool_programs(self) -> List[Dict]:
         """Compile one program of each kind this engine dispatches (the
@@ -3609,23 +3737,21 @@ class ModelRunner:
                 if warm_verified:
                     self.startup_deferred_families += len(pvariants) - 1
                     pvariants = variants[:1]
+                length, seqs, shape = self._prefill_program_shape(
+                    pb, t, mb, has_window)
                 for pen, lpk in pvariants:
                     counts = jnp.zeros(
-                        (pb, mc.vocab_size) if pen else (1, 1), jnp.int32
+                        (seqs, mc.vocab_size) if pen else (1, 1), jnp.int32
                     )
                     kv_ks, kv_vs = self._scale_pool_args()
                     dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
                     out = counted(
                         self._prefill,
                         self.params,
-                        jnp.zeros(
-                            (NUM_SCALARS * pb + pb * mb + pb * t,), jnp.int32
-                        ),
+                        jnp.zeros((length,), jnp.int32),
                         self.kv_k, self.kv_v, kv_ks, kv_vs, counts,
                         dparams, sp_k, sp_v, sp_p, self.state_pools,
-                        b=pb, t=t, mb=mb, has_window=has_window,
-                        b_max=self._b_max,
-                        has_penalties=pen, logprobs_k=lpk,
+                        **shape, has_penalties=pen, logprobs_k=lpk,
                     )
                     self.kv_k, self.kv_v = out[1], out[2]
                     self._rebind_scale_pools(out[3], out[4])

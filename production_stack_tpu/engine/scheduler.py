@@ -217,6 +217,9 @@ class ScheduledBatch:
     # counts the requests still waiting that a prefill could have taken.
     stop: str = "none"
     left_waiting: int = 0
+    # prefill only, set by the scheduler: the chunks lie end to end in ONE
+    # row (utils.prefill_rectangle's ``packed_tokens`` form is the shape).
+    packed: bool = False
     # decode only, set by apply_results: the most tokens one row of the
     # dispatch delivered (0 until applied, and for a failed fetch): the
     # steps beyond it served no row (pstpu:decode_steps_empty_total).
@@ -233,11 +236,34 @@ class ScheduledBatch:
         return sum(self.decode_steps) or len(self.seqs)
 
 
+def _packed_chunk_lens(remaining: List[int], budget: int) -> List[int]:
+    """The chunks of a PACKED prefill dispatch: each of the ``n``
+    candidates gets ``min(remaining, budget // n)``, what a rectangle of
+    ``n`` rows would give it; then the slack the short ones leave goes to
+    those that still have prompt left, in queue order, until the budget
+    is full. Nobody's share shrinks and nobody is passed over: what
+    leaves is the padding."""
+    share = budget // len(remaining)
+    lens = [min(r, share) for r in remaining]
+    slack = budget - sum(lens)
+    for i, r in enumerate(remaining):
+        more = min(r - lens[i], slack)
+        lens[i] += more
+        slack -= more
+    return lens
+
+
 class Scheduler:
     def __init__(self, config: EngineConfig, block_manager: BlockPoolManager,
                  offload=None, decode_window_budget: Optional[int] = None,
-                 prefill_window_budget: Optional[int] = None):
+                 prefill_window_budget: Optional[int] = None,
+                 prefill_packed: bool = False):
         self.config = config
+        # Which form a prefill dispatch takes: the runner's to say
+        # (ModelRunner.prefill_packs), the engine hands it over. False: a
+        # [rows, T] rectangle, a row a sequence; True: ONE row in which the
+        # sequences' chunks lie end to end.
+        self.prefill_packed = prefill_packed
         self.block_manager = block_manager
         self.offload = offload  # KVOffloadManager (host/remote KV tiers)
         # A dispatch with history gathers bucket(rows) x bucket(max_blocks)
@@ -346,7 +372,18 @@ class Scheduler:
         (and a state slot, where the model keeps one); starved prompts are
         skipped, NOT preempted-for (preempting here admits ping-pong
         livelock; only decode slot-appends preempt, which preserves FCFS
-        progress). The dispatch is then the ``[rows, T]`` rectangle of
+        progress). What the dispatch then is has two forms
+        (``prefill_packed``: the runner's choice, by what its model keeps
+        and its kernels cover):
+
+        PACKED, one row of tokens: every candidate is taken and its chunk
+        lies behind its predecessor's in ONE row (``_packed_chunk_lens``:
+        an equal share of the budget each, then the slack the short ones
+        leave to whoever has prompt left, in queue order); the program is
+        the smallest one-row ``[1, T]`` of ``utils.prefill_rectangles``
+        that holds the sum, so only the row's END is padding.
+
+        A RECTANGLE, a row a sequence: the ``[rows, T]`` of
         ``utils.prefill_rectangles`` (area <= the token budget, always)
         whose rows carry the most live tokens ``sum(min(remaining, T))``
         over the first ``min(rows, candidates)`` candidates: the device
@@ -416,12 +453,34 @@ class Scheduler:
             if stop:
                 self.prefill_blocked[stop] += 1
             return None
-        # The rectangle. Rows pad to the ladder and chunks to their
-        # power-of-two bucket, and the PADDED area is what the device
-        # computes, so that is what the budget bounds. NOTE: a preempted
-        # sequence re-prefills prompt+output together (num_tokens includes
-        # generated tokens).
+        # NOTE: a preempted sequence re-prefills prompt+output together
+        # (num_tokens includes generated tokens).
         rems = [c.num_tokens - c.num_computed_tokens for c in cands]
+        if self.prefill_packed:
+            lens = _packed_chunk_lens(
+                rems, prefill_rectangles(cfg, True)[-1][1])
+        else:
+            lens, stop = self._rectangle_chunk_lens(
+                cands, rems, newly_allocated, stop)
+        seqs = cands[:len(lens)]
+        starts = [s.num_computed_tokens for s in seqs]
+        for seq in seqs:
+            self.waiting.remove(seq)
+            seq.status = SequenceStatus.RUNNING
+        left = self._num_prefillable()
+        return ScheduledBatch(
+            kind="prefill", seqs=seqs, chunk_starts=starts, chunk_lens=lens,
+            stop=stop if stop and left else "none", left_waiting=left,
+            packed=self.prefill_packed,
+        )
+
+    def _rectangle_chunk_lens(self, cands, rems, newly_allocated, stop):
+        """The rectangle over the candidates (``_try_schedule_prefill``):
+        (the chunk of each row taken, the pass's ``stop``). Rows pad to the
+        ladder and chunks to their power-of-two bucket, and the PADDED
+        area is what the device computes, so that is what the budget
+        bounds."""
+        cfg = self.config
         best = free = None   # (live, -area, t, rows taken); `free` is the
         #                      choice were there no window budget
         for rows, t in prefill_rectangles(cfg):
@@ -448,7 +507,6 @@ class Scheduler:
             # the area bound did, or the window budget where it passed
             # over a rectangle of more rows.
             stop = "window" if free[3] > n else "tokens"
-        seqs = cands[:n]
         # Candidates allocated THIS pass but not taken by the rectangle must
         # not sit in waiting pinning non-evictable blocks (they could starve
         # decode's append_block under memory pressure); release them — the
@@ -462,18 +520,7 @@ class Scheduler:
                 cand.num_cached_tokens = 0
                 cand._prev_hash = cand.hash_seed
                 cand._num_hashed_blocks = 0
-        starts = [s.num_computed_tokens for s in seqs]
-        lens = [
-            min(s.num_tokens - s.num_computed_tokens, chunk_cap) for s in seqs
-        ]
-        for seq in seqs:
-            self.waiting.remove(seq)
-            seq.status = SequenceStatus.RUNNING
-        left = self._num_prefillable()
-        return ScheduledBatch(
-            kind="prefill", seqs=seqs, chunk_starts=starts, chunk_lens=lens,
-            stop=stop if stop and left else "none", left_waiting=left,
-        )
+        return [min(r, chunk_cap) for r in rems[:n]], stop
 
     def _prefillable(self, seq: Sequence) -> bool:
         """Role admission: a decode-role engine never schedules prefill

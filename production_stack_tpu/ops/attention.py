@@ -289,6 +289,12 @@ class KVView(NamedTuple):
     v_scale: Optional[jax.Array] = None
     block_tables: Optional[jax.Array] = None  # [B, Mb]
     kv_lens: Optional[jax.Array] = None    # [B] tokens of each row in the pool
+    # Set: the chunk is ONE packed row ([1, T]) whose tokens are S
+    # sequences' chunks end to end from token 0, ``seg_lens`` [S] tokens
+    # each, live ones first; ``block_tables`` and ``kv_lens`` are then a
+    # SEGMENT each ([S, Mb], [S]). Pool views of K/V rows only
+    # (``prefill_kernel_covers(..., packed=True)``).
+    seg_lens: Optional[jax.Array] = None
     block_size: int = 0
     interpret: bool = False
     tp_mesh: Optional[jax.sharding.Mesh] = None
@@ -428,7 +434,7 @@ def prefill_kernel_covers(
     t: int, num_heads: int, num_kv_heads: int, head_dim: int,
     value_dim: int, block_size: int, dtypes, *, latent: bool = False,
     scales: bool = False, kv_sharded: bool = False, ring: bool = False,
-    chunk_bias: bool = False,
+    chunk_bias: bool = False, packed: bool = False,
 ) -> bool:
     """THE predicate: whether a Pallas flash prefill kernel covers a chunk
     of ``t`` tokens over a view that holds the pool. Asked in two places
@@ -442,12 +448,15 @@ def prefill_kernel_covers(
     head width, block size and chunk length the kernel tiles
     (``supports_pallas_prefill``), or ``latent`` rows: one pool of one row
     a token, ``head_dim`` its width, the values its first ``value_dim``
-    lanes (``supports_latent_prefill``). Not covered by choice, though the
-    decode kernels cover them: an int8 pool (its scales would ride as the
-    decode kernel's do) and a kv-head-sharded pool; no benchmark cell runs
-    either."""
+    lanes (``supports_latent_prefill``). ``packed``: the chunk is one row
+    of several sequences' segments (``KVView.seg_lens``), which only the
+    K/V kernel has a form for (``supports_packed_prefill``). Not covered
+    by choice, though the decode kernels cover them: an int8 pool (its
+    scales would ride as the decode kernel's do) and a kv-head-sharded
+    pool; no benchmark cell runs either."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         supports_latent_prefill,
+        supports_packed_prefill,
         supports_pallas_prefill,
     )
 
@@ -456,9 +465,10 @@ def prefill_kernel_covers(
         return False
     itemsize = kinds.pop().itemsize
     if latent:
-        return num_kv_heads == 1 and supports_latent_prefill(
+        return not packed and num_kv_heads == 1 and supports_latent_prefill(
             t, num_heads, head_dim, value_dim, itemsize, block_size)
-    return value_dim == head_dim and supports_pallas_prefill(
+    supports = supports_packed_prefill if packed else supports_pallas_prefill
+    return value_dim == head_dim and supports(
         t, num_heads, num_kv_heads, head_dim, itemsize, block_size)
 
 
@@ -483,17 +493,20 @@ def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
     gathered window instead."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_prefill,
+        paged_flash_prefill_packed,
     )
 
     bs = view.block_size
     b, t, h, dh = q.shape
+    packed = view.seg_lens is not None
     if not prefill_kernel_covers(
             t, h, k.shape[2], dh, v.shape[-1], bs,
             (k.dtype, v.dtype, view.pool_k.dtype, view.pool_v.dtype),
             scales=view.k_scale is not None,
             kv_sharded=view.tp_mesh is not None,
             ring=view.ring_k is not None,
-            chunk_bias=view.chunk_bias is not None):
+            chunk_bias=view.chunk_bias is not None,
+            packed=packed) or (packed and b != 1):
         raise ValueError(
             f"attend: a chunk of {t} tokens ({h}/{k.shape[2]} heads x {dh}"
             f", {k.dtype} over a {view.pool_k.dtype} pool, block {bs}) "
@@ -513,10 +526,60 @@ def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
     def kernel(*args, interpret=False):
         return paged_flash_prefill(*args, block_size=bs, interpret=interpret)
 
+    if packed:
+        # The two executions of a PACKED row: the kernel's packed form, and
+        # the same oracle over the row taken apart, a row a segment.
+        def unpacked(q, k, v, seg_lens, pool_k, pool_v, tables, kv_lens,
+                     layer):
+            rows, put_back = unpack_segments(seg_lens, t)
+            positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)
+            return put_back(gathered(
+                q[0][rows], k[0][rows], v[0][rows], positions, seg_lens,
+                pool_k, pool_v, tables, kv_lens, layer))[None]
+
+        def packed_kernel(*args, interpret=False):
+            return paged_flash_prefill_packed(
+                *args, block_size=bs, interpret=interpret)
+
+        return _kernel_or_gathered(
+            view, packed_kernel, unpacked, q, k, v, view.seg_lens,
+            view.pool_k, view.pool_v, view.block_tables, view.kv_lens,
+            jnp.asarray(layer, jnp.int32))
     return _kernel_or_gathered(
         view, kernel, gathered, q, k, v, positions, chunk_lens, view.pool_k,
         view.pool_v, view.block_tables, view.kv_lens,
         jnp.asarray(layer, jnp.int32))
+
+
+def segment_of_token(seg_lens: jax.Array, t: int):
+    """Of a packed row of ``t`` tokens (segments of ``seg_lens`` tokens end
+    to end from token 0), for every token: (its segment, its index within
+    the segment), [t] int32 each; a token past the last segment counts on
+    from the last slot's start."""
+    ends = jnp.cumsum(seg_lens)
+    iota = jnp.arange(t, dtype=jnp.int32)
+    seg = jnp.minimum(jnp.sum(iota[:, None] >= ends[None, :], axis=1),
+                      seg_lens.shape[0] - 1)
+    return seg, iota - (ends - seg_lens)[seg]
+
+
+def unpack_segments(seg_lens: jax.Array, t: int):
+    """A packed row of ``t`` tokens taken apart: ``rows`` [S, t] int32, the
+    row's token behind token j of segment i (clipped past the segment's
+    end: whatever lies there is masked by the segment's length), and
+    ``put_back``, which lays [S, t, ...] values of the segments' tokens
+    end to end again as [t, ...], zeros past the last."""
+    ends = jnp.cumsum(seg_lens)
+    iota = jnp.arange(t, dtype=jnp.int32)
+    rows = jnp.minimum((ends - seg_lens)[:, None] + iota[None, :], t - 1)
+    seg, within = segment_of_token(seg_lens, t)
+
+    def put_back(x):
+        out = x[seg, within]
+        live = (iota < ends[-1]).reshape((t,) + (1,) * (out.ndim - 1))
+        return jnp.where(live, out, 0)
+
+    return rows, put_back
 
 
 def _kernel_or_gathered(view, kernel, gathered, *args):

@@ -145,12 +145,21 @@ def write_token_runs(
     start: jax.Array,               # [b] int32 — position of each row's token 0
     length: jax.Array,              # [b] int32 — tokens of the row that count
     block_size: int,
+    source_start: Optional[jax.Array] = None,   # [b] int32 — see below
 ) -> Tuple[jax.Array, ...]:
     """Write row i's tokens j < length[i] to the slots of positions
     start[i] + j (slot = block_tables[i, pos // bs] * bs + pos % bs).
     Tokens at or beyond ``length`` and positions beyond the block table
     write nothing: the reserved null block 0 takes no garbage either.
+
+    ``source_start`` given: ``news`` holds ONE row (``[L, Hkv, 1, T, *W]``:
+    a packed prefill's row of segments) and run i's tokens are that row's
+    from ``source_start[i]`` on; the runs fit the row together
+    (``sum(length) <= T``).
     """
+    if source_start is not None:
+        return _write_packed_runs(pools, news, block_tables, start, length,
+                                  block_size, source_start)
     bs = block_size
     b, t = news[0].shape[2:4]
     mb = block_tables.shape[1]
@@ -181,4 +190,46 @@ def write_token_runs(
         src_start=(bs + c[None, :] * bs - off[:, None]).reshape(-1),
         width=bs,
         keep=keep.reshape(-1, bs),
+    )
+
+
+def _write_packed_runs(pools, news, block_tables, start, length, block_size,
+                       source_start):
+    """``write_token_runs`` out of one packed row: the slabs are as many
+    as T tokens in ``b`` runs can touch (a run of n tokens touches at most
+    n // bs + 2 blocks), not ``b`` times a whole row's; slab n of the
+    dispatch is block c of the run whose blocks it falls among."""
+    bs = block_size
+    t = news[0].shape[3]
+    b, mb = block_tables.shape
+    off = start % bs
+    nblk = jnp.where(length > 0, (off + length - 1) // bs + 1, 0)     # [b]
+    first = jnp.cumsum(nblk) - nblk
+    n = jnp.arange(t // bs + 2 * b, dtype=jnp.int32)
+    run = jnp.clip(jnp.sum(first[None, :] <= n[:, None], axis=1) - 1,
+                   0, b - 1).astype(jnp.int32)
+    c = n - first[run]       # past the last run's blocks: out of the run
+    lb = start[run] // bs + c
+    phys = block_tables[run, jnp.clip(lb, 0, mb - 1)]
+    # Token index (within the run) of entry o of block c, as above.
+    j = (c[:, None] * bs + jnp.arange(bs, dtype=jnp.int32)[None, :]
+         - off[run][:, None])                                     # [n, bs]
+    keep = (j >= 0) & (j < length[run][:, None]) & (lb < mb)[:, None]
+    # bs entries in front and a block behind the row's last whole one, so
+    # every slab that keeps a token lies inside the padded row; one that
+    # keeps none is cut at the row's start.
+    pad = (bs, bs + (-t) % bs)
+    padded = [
+        jnp.pad(x, ((0, 0), (0, 0), (0, 0), pad) + ((0, 0),) * (x.ndim - 4))
+        for x in news
+    ]
+    return write_slabs(
+        pools, padded,
+        dst_start=phys * bs,
+        src_row=jnp.zeros_like(run),
+        src_start=jnp.where(
+            keep.any(axis=1),
+            bs + c * bs - off[run] + source_start[run], 0),
+        width=bs,
+        keep=keep,
     )

@@ -842,26 +842,34 @@ def supports_pallas_prefill(t: int, num_heads: int, num_kv_heads: int,
 
 
 def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
-                   program, programs, tq, tile_rows, tiles):
+                   program, programs, tq, tile_rows, tiles, chunk_at=None):
     """The prefill kernels' hand-off of the superpage buffers from program
     to program. Program (row, query block) reads its row's history
     superpages (``fetch``'s pages, ``fetch.sup`` keys each; none at kv_len
     0) and then key tiles of the chunk, ``tile_rows`` keys each, ONE copy a
     stream out of ``chunk`` (the chunk's rows in HBM, [heads, B, T, lanes] a
-    stream): ``tiles(history tiles, block)`` in all, none where its ``tq``
-    queries are all padding. The call's tiles form one sequence across programs, tile n in
+    stream): ``tiles(row, history tiles, block)`` in all, none where its
+    ``tq`` queries are all padding. Chunk tile c of a row is
+    ``chunk_at(row, c)`` = (row of ``chunk``, first key): the row's own
+    keys from c * tile_rows unless the caller says otherwise (a "row" of
+    the packed kernel is a SEGMENT's share of a query block, and its keys
+    lie in the one packed row from the tile that holds the segment's first
+    token). The call's tiles form one sequence across programs, tile n in
     buffer n % NUM_BUFS, the next always in flight. Returns (history tiles,
     tiles, ``advance``) of this program: ``advance(s)`` puts what computes
     after tile s in flight, waits for tile s and returns its buffer."""
     (b, qb), (num_rows, nq) = program, programs
     kv_lens_ref, sup = fetch.kv_lens_ref, fetch.sup
+    if chunk_at is None:
+        def chunk_at(row, c):
+            return row, c * tile_rows
 
     def hist_tiles(row):
         return pl.cdiv(kv_lens_ref[row], sup)
 
     def tiles_of(row, blk):
         return jnp.where(blk * tq < chunk_lens_ref[row],
-                         tiles(hist_tiles(row), blk), 0)
+                         tiles(row, hist_tiles(row), blk), 0)
 
     n_hist = hist_tiles(b)
     n_tiles = tiles_of(b, qb)
@@ -885,7 +893,7 @@ def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
         @pl.when(has & (s >= nh))
         def _():
             # A key tile of the chunk: contiguous.
-            fetch.start_run(chunk, row, (s - nh) * tile_rows, tile_rows, slot)
+            fetch.start_run(chunk, *chunk_at(row, s - nh), tile_rows, slot)
 
     # A masked key's weight (0) must not meet a non-finite value (see the
     # decode kernel): what holds values is cleared once a call; what tiles
@@ -974,7 +982,7 @@ def _prefill_kernel(
     n_hist, n_tiles, advance = _tile_sequence(
         fetch, (kc_hbm, vc_hbm), chunk_lens_ref, fetched_ref, v_buf,
         program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tq,
-        tiles=lambda hist, blk: hist + blk + 1)
+        tiles=lambda row, hist, blk: hist + blk + 1)
 
     def flash_block(keys_of, mask_of):
         # One tile's keys against every head's query rows: the heads are a
@@ -1254,7 +1262,7 @@ def _latent_prefill_kernel(
     n_hist, n_tiles, advance = _tile_sequence(
         fetch, (rows_hbm,), chunk_lens_ref, fetched_ref, kv_buf,
         program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tk,
-        tiles=lambda hist, blk: hist + pl.cdiv((blk + 1) * tq, tk))
+        tiles=lambda row, hist, blk: hist + pl.cdiv((blk + 1) * tq, tk))
 
     def flash_block(rows, mask):
         # One tile's rows [keys, W] against every head's queries at once.
@@ -1398,3 +1406,402 @@ def paged_flash_prefill_latent(
         qf, positions.reshape(b, nq, tq, 1), positions.reshape(b, nk, 1, tk),
         chunk, kv_pool,
     )
+
+
+# ------------------------------------------------------ prefill, a packed row
+# A PACKED prefill dispatch is ONE row of T tokens in which up to S
+# sequences' chunks ("segments") lie end to end from token 0, each with its
+# own history in the pool; only the row's end is padding. The K/V kernel
+# above, with one thing changed: a program is no longer (row, query block)
+# but (segment, query block), a PAIR, for every query block a segment has
+# tokens in.
+#
+#   * The query blocks stay TQ tokens at static offsets of the row, and a
+#     KV head's G x TQ query rows one resident block; the pairs of a block
+#     follow one another in the grid (at most NQ + S - 1 pairs: a segment
+#     boundary inside the row adds one), the block's flash state stays in
+#     scratch across them, and the last pair of a block writes it out. A
+#     block no segment reaches has one pair that reads nothing: zeros.
+#   * A pair reads its SEGMENT's history superpages (the segment's block
+#     table and kv_len) and then the row's key tiles from the tile that
+#     holds the segment's first token up to the diagonal, through the same
+#     tile sequence (``_tile_sequence``: a pair is a "row" with one block).
+#   * A pair touches only the query rows of its segment, in SUB-BLOCKS of
+#     ``packed_sub_block`` tokens (x G rows a head): what a tile costs is
+#     the flash state's round trip over the rows it visits (the section
+#     above), so a segment of 100 tokens must not pay for the 256 of its
+#     block. A sub-block two segments share is visited by both pairs;
+#     queries are masked by segment (row index within [start, end)), keys
+#     by segment and causally by row index (inside a segment that is the
+#     order of positions). ``_MASKED`` is finite: a query that meets only
+#     masked keys first is corrected by ``alpha`` when its own arrive, and
+#     every query has at least its own key; one no segment owns stays
+#     finite garbage, which nothing reads.
+#   * A segment that fills whole query blocks visits what its row visits in
+#     the kernel above, tile for tile.
+PACKED_SUB_ROWS = 256    # query rows (G x tokens) a sub-block keeps at least:
+                         # 16 segments in a 2048-token row at 8 query heads
+                         # a KV head took 630 / 500 / 438 / 359 us a layer
+                         # in sub-blocks of 256 / 128 / 64 / 32 tokens on a
+                         # v5e (PERF.md section 6, PR 46)
+
+
+PACKED_HISTORY_ROWS = 512    # ... and a history tile's of a WHOLE block: one
+                             # suffix behind 6000 tokens at 4 query heads a
+                             # KV head took 426 / 380 / 417 us in pieces of
+                             # 1024 / 512 / 256 rows (the rectangle: 410)
+
+
+def packed_sub_block(tq: int, q_per_kv: int, itemsize: int) -> int:
+    """Tokens a sub-block of a packed query block holds: ``tq`` halved
+    while G x tokens stays ``PACKED_SUB_ROWS`` rows (the matmuls' M) and
+    whole sublane tiles of the dtype."""
+    sb = tq
+    while sb % 2 == 0 and (sb // 2) % (32 // itemsize) == 0 \
+            and q_per_kv * (sb // 2) >= PACKED_SUB_ROWS:
+        sb //= 2
+    return sb
+
+
+def supports_packed_prefill(t: int, num_heads: int, num_kv_heads: int,
+                            head_dim: int, itemsize: int,
+                            block_size: int) -> bool:
+    """What ``supports_pallas_prefill`` asks, and query blocks of whole
+    sublane tiles (a sub-block is sliced out of the block's token axis)."""
+    if not supports_pallas_prefill(t, num_heads, num_kv_heads, head_dim,
+                                   itemsize, block_size):
+        return False
+    _, tq = prefill_tiles(t, num_heads, num_kv_heads, head_dim, itemsize,
+                          block_size)
+    return tq % (32 // itemsize) == 0
+
+
+def _packed_prefill_kernel(
+    # scalar prefetch, one entry a PAIR (segment, query block)
+    layer_ref,          # SMEM [1] int32
+    block_tables_ref,   # SMEM [P, Mb] int32: the pair's segment's
+    kv_lens_ref,        # SMEM [P] int32: its tokens in the pool
+    live_ref,           # SMEM [P] int32: its tokens in the block; 0: no pair
+    blk_ref,            # SMEM [P] int32: the pair's query block
+    start_ref,          # SMEM [P] int32: the segment's first token in the row
+    end_ref,            # SMEM [P] int32: one past its last
+    # inputs
+    q_ref,              # VMEM [1, Hkv, 1, G, TQ, Dh] (pre-scaled)
+    kc_hbm,             # HBM  [Hkv, 1, T, Dh]: the row's keys
+    vc_hbm,             # HBM  [Hkv, 1, T, Dh]
+    k_hbm,              # HBM  [L, Hkv, num_slots, Dh]
+    v_hbm,              # HBM  [L, Hkv, num_slots, Dh]
+    # output
+    o_ref,              # VMEM [1, Hkv, 1, G, TQ, Dh]
+    # scratch (outlives a program: buffers and flash state are handed on)
+    k_buf,              # VMEM [NUM_BUFS, Hkv, super_tokens, Dh]
+    v_buf,
+    sem_k,              # DMA sems (NUM_BUFS,)
+    sem_v,
+    fetched_ref,        # SMEM [1] int32: tiles the programs before fetched
+    m_ref,              # VMEM [Hkv, G, TQ, 1] f32: running max
+    l_ref,              # VMEM [Hkv, G, TQ, 1] f32: running sum
+    acc_ref,            # VMEM [Hkv, G, TQ, Dh] f32
+    *,
+    block_size: int,
+    super_tokens: int,
+    tq: int,
+    sub_block: int,
+):
+    p, pairs = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    bs, sup, sb = block_size, super_tokens, sub_block
+    hkv, g, dh = k_buf.shape[1], q_ref.shape[3], q_ref.shape[5]
+    kv_len = kv_lens_ref[p]
+    blk, start, end = blk_ref[p], start_ref[p], end_ref[p]
+    fetch = _PageFetch(
+        [(k_hbm, k_buf, sem_k), (v_hbm, v_buf, sem_v)], bs, block_size=bs,
+        super_tokens=sup, layer=layer, block_tables_ref=block_tables_ref,
+        kv_lens_ref=kv_lens_ref)
+
+    def first_tile(pair):
+        return start_ref[pair] // tq
+
+    # A pair is a row of one block: its segment's history, then the row's
+    # key tiles from the segment's first up to the diagonal.
+    n_hist, n_tiles, advance = _tile_sequence(
+        fetch, (kc_hbm, vc_hbm), live_ref, fetched_ref, v_buf,
+        program=(p, 0), programs=(pairs, 1), tq=tq, tile_rows=tq,
+        tiles=lambda pair, hist, _:
+            hist + blk_ref[pair] - first_tile(pair) + 1,
+        chunk_at=lambda pair, c: (0, (first_tile(pair) + c) * tq))
+
+    # The block's flash state: begun by its first pair, written out by its
+    # last (the pairs past the row's last all name the last block and read
+    # nothing, so the call's last program writes that block).
+    @pl.when((p == 0) | (blk_ref[jnp.maximum(p - 1, 0)] != blk))
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    # The sub-blocks that hold the segment's queries of this block.
+    base = blk * tq
+    j_lo = (jnp.maximum(start, base) - base) // sb
+    j_hi = pl.cdiv(jnp.minimum(end, base + tq) - base, sb)
+
+    whole = (start <= base) & (end >= base + tq)
+
+    def flash_tile(slot, widths, whole_sb, key_mask, keys_seen):
+        # The keys of the tile in buffer ``slot`` against the segment's
+        # query rows, a sub-block at a time and a head at a time:
+        # ``_prefill_kernel``'s flash block over G x SB rows of the
+        # state, and over no more of the tile's
+        # keys than the sub-block can see (``keys_seen(j, size)``, rounded
+        # up to one of the static ``widths``): a short history or the
+        # first sub-blocks of the diagonal see a fraction of their tile.
+        # A segment that has the whole block wastes nothing at a boundary
+        # and takes it in sub-blocks of ``whole_sb`` tokens, wide enough
+        # to cost what the block costs the kernel above.
+        def sub(size, j, carry):
+            rows = pl.ds(pl.multiple_of(j * size, size), size)
+            idx_q = base + j * size + jax.lax.broadcasted_iota(
+                jnp.int32, (g, size, 1), 1).reshape(g * size, 1)
+            own = (idx_q >= start) & (idx_q < end)
+            seen = keys_seen(j, size)
+
+            def flash(width):
+                mask = own & key_mask(idx_q, width)
+
+                def head(hk, carry):
+                    q = q_ref[0, hk, 0, :, rows, :].reshape(g * size, dh)
+                    k = k_buf[slot, hk, pl.ds(0, width), :]  # [keys, Dh]
+                    v = v_buf[slot, hk, pl.ds(0, width), :]
+                    scores = jax.lax.dot_general(
+                        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )                                        # [M, keys]
+                    scores = jnp.where(mask, scores, _MASKED)
+                    m_prev = m_ref[hk, :, rows, :].reshape(g * size, 1)
+                    l_prev = l_ref[hk, :, rows, :].reshape(g * size, 1)
+                    acc_prev = acc_ref[hk, :, rows, :].reshape(g * size, dh)
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(scores, axis=-1, keepdims=True))
+                    alpha = jnp.exp(m_prev - m_new)
+                    pr = jnp.exp(scores - m_new)
+                    l_new = alpha * l_prev + jnp.sum(
+                        pr, axis=-1, keepdims=True)
+                    acc_new = alpha * acc_prev + jax.lax.dot_general(
+                        pr.astype(v.dtype), v,
+                        dimension_numbers=(((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+                    l_ref[hk, :, rows, :] = l_new.reshape(g, size, 1)
+                    acc_ref[hk, :, rows, :] = acc_new.reshape(g, size, dh)
+                    m_ref[hk, :, rows, :] = m_new.reshape(g, size, 1)
+                    return carry
+
+                jax.lax.fori_loop(0, hkv, head, 0)
+
+            below = 0
+            for width in widths:
+                pl.when((seen > below) & (seen <= width))(
+                    functools.partial(flash, width))
+                below = width
+            return carry
+
+        if whole_sb == sb:
+            jax.lax.fori_loop(j_lo, j_hi, functools.partial(sub, sb), 0)
+            return
+
+        @pl.when(whole)
+        def _():
+            jax.lax.fori_loop(
+                0, tq // whole_sb, functools.partial(sub, whole_sb), 0)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            jax.lax.fori_loop(j_lo, j_hi, functools.partial(sub, sb), 0)
+
+    # The key counts a block is compiled for: a history superpage's live
+    # keys up to 128, 256 or all of it; a row tile's in quarters (or
+    # sub-blocks, where those are wider). A whole block takes a history
+    # tile PACKED_HISTORY_ROWS query rows at a time and a row tile at once.
+    hist_widths = [w for w in (LANES, 2 * LANES) if w < sup] + [sup]
+    step = max(sb, tq // 4)
+    row_widths = list(range(step, tq + 1, step))
+    hist_sb = tq
+    while hist_sb // 2 >= sb and g * hist_sb > PACKED_HISTORY_ROWS:
+        hist_sb //= 2
+
+    def tile(s, carry):
+        slot = advance(s)
+
+        @pl.when(s < n_hist)
+        def _():
+            # The segment's history: every key below kv_len is before
+            # every query of the segment.
+            def mask(idx_q, width):
+                pos = s * sup + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, width), 1)
+                return pos < kv_len
+
+            flash_tile(slot, hist_widths, hist_sb, mask,
+                       lambda j, size: jnp.minimum(kv_len - s * sup, sup))
+
+        @pl.when(s >= n_hist)
+        def _():
+            # Key tile c of the row: the segment's own keys, causally; on
+            # the diagonal a sub-block sees the keys up to its own end.
+            c = first_tile(p) + s - n_hist
+
+            def mask(idx_q, width):
+                idx_k = c * tq + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, width), 1)
+                return (idx_k >= start) & (idx_k <= idx_q)
+
+            flash_tile(slot, row_widths, tq, mask,
+                       lambda j, size: jnp.where(c < blk, tq, (j + 1) * size))
+
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, tile, 0)
+
+    @pl.when((p + 1 == pairs) | (blk_ref[jnp.minimum(p + 1, pairs - 1)] != blk))
+    def _():
+        def write(hk, carry):
+            out = acc_ref[hk] / jnp.maximum(l_ref[hk], 1e-30)
+            o_ref[0, hk, 0] = out.astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, hkv, write, 0)
+
+
+def packed_pairs(seg_lens: jax.Array, nq: int, tq: int):
+    """The (segment, query block) pairs of a packed row, block by block,
+    as arrays of NQ + S - 1 entries: (segment, block, the segment's first
+    token in the row, one past its last, its tokens in the block). A block
+    no segment reaches has one pair of 0 tokens; the entries past the last
+    block's name that block, 0 tokens. ``seg_lens`` [S]: the segments lie
+    end to end from token 0, the live ones first."""
+    s = seg_lens.shape[0]
+    ends = jnp.cumsum(seg_lens)
+    starts = ends - seg_lens
+    live = seg_lens > 0
+    lo_tok = jnp.arange(nq, dtype=jnp.int32) * tq                  # [NQ]
+    # Of block j: its first segment (those wholly before it come first),
+    # and one past its last.
+    lo = jnp.sum(live[None, :] & (ends[None, :] <= lo_tok[:, None]), axis=1)
+    hi = jnp.sum(live[None, :] & (starts[None, :] < lo_tok[:, None] + tq),
+                 axis=1)
+    n = jnp.maximum(hi - lo, 0)
+    count = jnp.maximum(n, 1)
+    first = jnp.cumsum(count) - count                              # [NQ]
+    p = jnp.arange(nq + s - 1, dtype=jnp.int32)
+    blk = jnp.clip(jnp.sum(first[None, :] <= p[:, None], axis=1) - 1,
+                   0, nq - 1).astype(jnp.int32)
+    i = p - first[blk]
+    has = i < n[blk]
+    seg = jnp.where(has, jnp.minimum(lo[blk] + i, s - 1), 0).astype(jnp.int32)
+    start = jnp.where(has, starts[seg], 0).astype(jnp.int32)
+    end = jnp.where(has, ends[seg], 0).astype(jnp.int32)
+    tokens = jnp.minimum(end, (blk + 1) * tq) - jnp.maximum(start, blk * tq)
+    return seg, blk, start, end, jnp.where(has, tokens, 0).astype(jnp.int32)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "scale", "interpret", "sub_block"),
+)
+def paged_flash_prefill_packed(
+    q: jax.Array,             # [1, T, H, Dh] the row's queries (post-rope)
+    k: jax.Array,             # [1, T, Hkv, Dh] the row's keys (post-rope)
+    v: jax.Array,             # [1, T, Hkv, Dh]
+    seg_lens: jax.Array,      # [S] int32 tokens of each segment of the row
+    k_pool: jax.Array,        # [L, Hkv, num_slots, Dh]
+    v_pool: jax.Array,
+    block_tables: jax.Array,  # [S, Mb] int32, a segment each
+    kv_lens: jax.Array,       # [S] int32: the segment's tokens in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    sub_block: Optional[int] = None,
+) -> jax.Array:
+    """``paged_flash_prefill`` of a PACKED row: segment i is the row's
+    tokens [sum(seg_lens[:i]), sum(seg_lens[:i + 1])), a chunk of a
+    sequence whose history is the pool's slots below ``kv_lens[i]`` by
+    ``block_tables[i]``; each attends its history and itself causally and
+    nothing of its neighbours: [1, T, H, Dh] in q.dtype, a segment's
+    tokens equal to ``paged_flash_prefill`` of the segment as a row of its
+    own (one segment that fills the row: bit for bit). Live segments come
+    first; tokens past the last are padding, and what the kernel writes
+    there is finite and means nothing. See the section comment;
+    ``supports_packed_prefill`` for the shapes; ``sub_block`` (tokens)
+    overrides ``packed_sub_block`` for tests and sweeps."""
+    _, t, h, dh = q.shape
+    hkv = k_pool.shape[1]
+    g = h // hkv
+    if scale is None:
+        scale = dh ** -0.5
+    itemsize = k_pool.dtype.itemsize
+    sup, tq = prefill_tiles(t, h, hkv, dh, itemsize, block_size)
+    sb = sub_block or packed_sub_block(tq, g, itemsize)
+    nq = t // tq
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    seg, blk, start, end, tokens = packed_pairs(
+        seg_lens.astype(jnp.int32), nq, tq)
+    pairs = seg.shape[0]
+    # [1, T, H, Dh] -> [1, Hkv, NQ, G, TQ, Dh]: as ``paged_flash_prefill``
+    # lays a block out, its G x TQ rows not yet merged (a sub-block is a
+    # slice of the token axis).
+    qf = (q.astype(jnp.float32) * scale).astype(k_pool.dtype)
+    qf = qf.reshape(1, nq, tq, hkv, g, dh).transpose(0, 3, 1, 4, 2, 5)
+    kc = k.transpose(2, 0, 1, 3).astype(k_pool.dtype)     # [Hkv, 1, T, Dh]
+    vc = v.transpose(2, 0, 1, 3).astype(v_pool.dtype)
+
+    kernel = functools.partial(
+        _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
+        tq=tq, sub_block=sb,
+    )
+    # A pair's blocks of q and the output are its query block's: resident
+    # while the block's pairs follow one another.
+    q_block = pl.BlockSpec(
+        (1, hkv, 1, g, tq, dh),
+        lambda i, layer, bt, lens, live, blk, *_: (0, 0, blk[i], 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((1, hkv, nq, g, tq, dh), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(pairs,),
+            in_specs=[
+                q_block,
+                pl.BlockSpec(memory_space=pl.ANY),   # the row's K/V and
+                pl.BlockSpec(memory_space=pl.ANY),   # the pools stay in
+                pl.BlockSpec(memory_space=pl.ANY),   # HBM: the kernel
+                pl.BlockSpec(memory_space=pl.ANY),   # copies tiles itself
+            ],
+            out_specs=q_block,
+            scratch_shapes=[
+                pltpu.VMEM((NUM_BUFS, hkv, sup, dh), k_pool.dtype),
+                pltpu.VMEM((NUM_BUFS, hkv, sup, dh), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((hkv, g, tq, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, tq, 1), jnp.float32),
+                pltpu.VMEM((hkv, g, tq, dh), jnp.float32),
+            ],
+        ),
+        # Programs run in order: each hands its buffers to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PREFILL_VMEM_BYTES,
+        ),
+        name="paged_flash_prefill_packed",
+        interpret=interpret,
+    )(
+        layer, block_tables[seg], kv_lens.astype(jnp.int32)[seg] * (tokens > 0),
+        tokens, blk, start, end,
+        qf, kc, vc, k_pool, v_pool,
+    )
+    out = out.transpose(0, 2, 4, 1, 3, 5)         # [1, NQ, TQ, Hkv, G, Dh]
+    return out.reshape(1, t, h, dh)

@@ -49,7 +49,8 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "decode_row_steps_wasted_total", "decode_steps_empty_total",
                 "decode_rows_first_total", "decode_rows_joined_total",
                 "prefill_tokens_issued_total", "prefill_tokens_padded_total",
-                "prefill_rows_issued_total", "prefill_left_waiting_total",
+                "prefill_rows_issued_total", "prefill_segments_total",
+                "prefill_left_waiting_total",
                 "prefill_stop_rows_total", "prefill_stop_seqs_total",
                 "prefill_stop_tokens_total", "prefill_stop_window_total",
                 "prefill_stop_slots_total", "prefill_stop_blocks_total",
@@ -391,9 +392,9 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         f"pstpu:prefill_tokens_issued_total{label} "
         f"{s['prefill_tokens_issued_total']}",
         "# HELP pstpu:prefill_tokens_padded_total Tokens of the padded "
-        "rectangle (program rows x program chunk length) prefill "
-        "dispatches ran; issued over padded is the share that was "
-        "prompt",
+        "shape (program rows x program chunk length; one row where the "
+        "chunks are packed end to end) prefill dispatches ran; issued "
+        "over padded is the share that was prompt",
         "# TYPE pstpu:prefill_tokens_padded_total counter",
         f"pstpu:prefill_tokens_padded_total{label} "
         f"{s['prefill_tokens_padded_total']}",
@@ -402,6 +403,12 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:prefill_rows_issued_total counter",
         f"pstpu:prefill_rows_issued_total{label} "
         f"{s['prefill_rows_issued_total']}",
+        "# HELP pstpu:prefill_segments_total Sequences whose chunks lay "
+        "end to end in the one row of a packed prefill dispatch, counted "
+        "at issue (0 while every dispatch is a rectangle)",
+        "# TYPE pstpu:prefill_segments_total counter",
+        f"pstpu:prefill_segments_total{label} "
+        f"{s['prefill_segments_total']}",
         "# HELP pstpu:prefill_left_waiting_total Requests still waiting "
         "that a prefill could have taken, summed over prefill "
         "dispatches at the end of their admission pass",

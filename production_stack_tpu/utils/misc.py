@@ -9,7 +9,7 @@ import abc
 import functools
 import re
 import resource
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 from production_stack_tpu.utils.logging import init_logger
 
@@ -130,46 +130,61 @@ def _prefill_ladder(budget: int, max_num_seqs: int, cap: int) -> tuple:
     return tuple(out)
 
 
-def prefill_rectangles(cfg) -> tuple:
+def prefill_rectangles(cfg, packed: bool = False) -> tuple:
     """Every ``(prog_rows, prog_t)`` a prefill dispatch can run under
-    ``cfg``, ascending: the row ladder {1, half the cap's bucket, the
-    cap's bucket} (``prefill_row_cap``) times the power-of-two chunk
-    lengths from ``prefill_t_floor`` up, as far as ``rows x t`` stays
-    within ``max_num_batched_tokens``. 8 rectangles at a 2048 budget (1 x
-    {128..2048}, 8 x {128, 256}, 16 x 128), 7 at 1024.
+    ``cfg``, ascending. A RECTANGLE dispatch (``packed`` false: a row a
+    sequence, every row padded to ``prog_t``): the row ladder {1, half the
+    cap's bucket, the cap's bucket} (``prefill_row_cap``) times the
+    power-of-two chunk lengths from ``prefill_t_floor`` up, as far as
+    ``rows x t`` stays within ``max_num_batched_tokens``. 8 rectangles at
+    a 2048 budget (1 x {128..2048}, 8 x {128, 256}, 16 x 128), 7 at 1024.
+    A PACKED dispatch (the sequences' chunks end to end in ONE row, only
+    the row's end padding): the one-row column of that ladder alone, 5 at
+    2048.
 
     The ladder is thin because every rectangle is a compiled program
     (warm-up runs each, and a deployment's programs share a bounded
     compile cache): the full ladder {1, 2, 4, ...} is 15 and 10."""
-    return _prefill_ladder(cfg.max_num_batched_tokens, cfg.max_num_seqs,
-                           prefill_row_cap(cfg))
+    ladder = _prefill_ladder(cfg.max_num_batched_tokens, cfg.max_num_seqs,
+                             prefill_row_cap(cfg))
+    return tuple(r for r in ladder if r[0] == 1) if packed else ladder
 
 
-def prefill_rectangle(n_rows: int, max_chunk: int, cfg) -> tuple:
-    """``(prog_rows, prog_t)``: the padded rectangle of the prefill program
-    that runs a dispatch of ``n_rows`` live rows whose longest chunk is
-    ``max_chunk`` tokens, under ``cfg`` (an ``EngineConfig``): the
-    smallest of ``prefill_rectangles(cfg)`` that holds them. Its area
-    never exceeds the token budget: the device computes the padded
-    rectangle, not the live tokens (an ``[8, 512]`` program with four
-    live rows ran 309.7 ms for 1369 tokens on a v5e where ``[8, 256]``
-    with eight takes 136 ms for 1750: PERF.md section 5, PR 36), so
-    admission (``Scheduler._try_schedule_prefill``) chooses among these
-    rectangles and passes no ``(n_rows, max_chunk)`` that none holds;
-    one that is passed is a ValueError, not a wider program.
+def prefill_rectangle(n_rows: int, max_chunk: int, cfg,
+                      packed_tokens: Optional[int] = None) -> tuple:
+    """``(prog_rows, prog_t)``: the shape of the prefill program that runs
+    a dispatch of ``n_rows`` sequences whose longest chunk is ``max_chunk``
+    tokens, under ``cfg`` (an ``EngineConfig``). A RECTANGLE holds them a
+    row each: the smallest of ``prefill_rectangles(cfg)`` with that many
+    rows of that length. Where the dispatch is PACKED
+    (``ScheduledBatch.packed``; which form dispatches take is the
+    runner's to say, ``ModelRunner.prefill_packs``), ``packed_tokens`` is
+    the sum of its chunks and the shape is the smallest one-row ``(1, T)``
+    of ``prefill_rectangles(cfg, True)`` that holds the sum. The area
+    never exceeds the token budget: the device computes the padded shape,
+    not the live tokens (an ``[8, 512]`` program with four live rows ran
+    309.7 ms for 1369 tokens on a v5e where ``[8, 256]`` with eight takes
+    136 ms for 1750: PERF.md section 5, PR 36), so admission
+    (``Scheduler._try_schedule_prefill``) chooses among these shapes and
+    passes nothing that none holds; what is passed all the same is a
+    ValueError, not a wider program.
 
     THE statement of a prefill dispatch's shape: the scheduler's
     admission, the runner's issue and warm-up
     (``reachable_prefill_families``) and the engine loop's
-    ``pstpu:prefill_tokens_padded_total`` all read it, so what is warmed
-    is what can run and what is counted as padded is what the device
-    computes."""
-    for rows, t in prefill_rectangles(cfg):
-        if rows >= n_rows and t >= max_chunk:
-            return rows, t
+    ``pstpu:prefill_tokens_padded_total`` (``prog_rows x prog_t``: ``T``
+    for a packed row) all read it, so what is warmed is what can run and
+    what is counted as padded is what the device computes."""
+    packed = packed_tokens is not None
+    rows, width = (1, packed_tokens) if packed else (n_rows, max_chunk)
+    for prog_rows, t in prefill_rectangles(cfg, packed):
+        if prog_rows >= rows and t >= width:
+            return prog_rows, t
     raise ValueError(
-        f"no prefill rectangle holds {n_rows} rows of {max_chunk} tokens "
-        f"under a budget of {cfg.max_num_batched_tokens} tokens and "
+        f"no prefill {'row' if packed else 'rectangle'} holds {n_rows} "
+        f"chunks of up to {max_chunk} tokens"
+        f"{f', {packed_tokens} in all,' if packed else ''} under a budget "
+        f"of {cfg.max_num_batched_tokens} tokens and "
         f"{prefill_row_cap(cfg)} rows")
 
 

@@ -344,7 +344,7 @@ def _described_runner(v5e, model_dir: str, **engine):
     r._prefill = jax.jit(
         r._prefill_impl,
         static_argnames=("b", "t", "mb", "has_window", "b_max",
-                         "has_penalties", "logprobs_k"),
+                         "has_penalties", "logprobs_k", "segs"),
         donate_argnums=(2, 3, 4, 5, 8, 9, 10, 11))
     return r
 
@@ -758,9 +758,12 @@ def test_paged_prefill_kernel_compiles_for_v5e(v5e, rows, t, heads, kv_heads):
 # that program: PR 33's tree gathered a window of every row at the widest
 # step of its ladder and held the float32 scores; measured at PR 35.)
 PREFILL_PROGRAMS = {
-    "qwen2.5-3b-8x256": ("qwen2.5-3b", 8, 256, 1_444_768_256, 160_311_808),
+    # The dense deployments' dispatches are packed rows since PR 46: the
+    # 2048 tokens that were 8 x 256 are one row (162.7 MB at PR 46).
+    "qwen2.5-3b-1x2048":
+        ("qwen2.5-3b", 1, 2048, 1_444_768_256, 162_667_008),
     "mistral-7b-d16-1x512":
-        ("mistral-7b-d16", 1, 512, 844_797_440, 1_257_984),
+        ("mistral-7b-d16", 1, 512, 844_797_440, 3_024_896),
     "olmo-hybrid-7b-d16-1x2048":
         ("olmo-hybrid-7b-d16", 1, 2048, 913_192_448, 661_928_960),
 }
@@ -817,27 +820,142 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
     assert mem.temp_size_in_bytes <= temp * 1.05
 
 
-@pytest.mark.parametrize("name,families,in_place", [
-    ("qwen2.5-3b", 8, True), ("mistral-7b-d16", 8, True),
-    ("olmo-hybrid-7b-d16", 8, True), ("kanana-2-30b-a3b-d8", 7, True),
-    ("xing4.0-29b-a4b-d7", 7, True), ("granite-4.0-h-micro", 8, True)])
+@pytest.mark.parametrize("name,families,in_place,packs", [
+    ("qwen2.5-3b", 5, True, True), ("mistral-7b-d16", 5, True, True),
+    ("olmo-hybrid-7b-d16", 8, True, False),
+    ("kanana-2-30b-a3b-d8", 7, True, False),
+    ("xing4.0-29b-a4b-d7", 7, True, False),
+    ("granite-4.0-h-micro", 8, True, False),
+    ("lfm2-8b-a1b-d16", 7, True, False)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
-                                                  in_place):
+                                                  in_place, packs):
     """One prefill family a (rows, t) where the history is read in place
     (36 -> 9 at qwen2.5-3b's envelope, 32 -> 9, 18 -> 9, and 8 since PR
     37's ladder: 1 x {128..2048}, 8 x {128, 256}, 16 x 128); so it is for
     latent rows since PR 39 (14, with and without the pinned window, -> 7:
     1 x {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger
-    than the token budget."""
+    than the token budget. The dense deployments' dispatches are packed
+    rows since PR 46 (``prefill_packs``): the one-row column alone, 8 -> 5;
+    whoever keeps a state a row or latent rows keeps the rectangles."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
+    assert r.prefill_packs is packs
     fams = r.reachable_prefill_families()
     assert len(fams) == families
+    assert ({f[0] for f in fams} == {1}) is packs
     assert all(rows * t <= r.config.max_num_batched_tokens
                for rows, t, _, _ in fams)
     assert {f[3] for f in fams} == ({False} if in_place else {False, True})
     assert r.prefill_window_blocks == (
         1 << 30 if in_place else r.num_kv_blocks)
+
+
+# What the prefill programs of the deployments that keep their rectangles
+# lowered to for a described v5e at PR 45 (the parent of PR 46, which gave
+# the dense deployments a second form of dispatch beside them): sha256 of
+# the module's text without locations and without the Mosaic kernels'
+# serialized bodies, which carry the checkout's path and line numbers. The
+# bodies are held by the two kernels' jaxprs below.
+_PARENT_PREFILL_TEXT = {
+    ("olmo-hybrid-7b-d16", 1, 128): "8d7ed2eace3103d5",
+    ("olmo-hybrid-7b-d16", 16, 128): "75209d90425a943e",
+    ("granite-4.0-h-micro", 1, 128): "7f5d94d2e2f99f3d",
+    ("granite-4.0-h-micro", 16, 128): "808fe16c0d7e1615",
+    ("lfm2-8b-a1b-d16", 1, 128): "09fdbfc9a3401f0c",
+    ("lfm2-8b-a1b-d16", 8, 128): "940c596990f0cce9",
+    ("kanana-2-30b-a3b-d8", 1, 128): "5c5f75cc068c10e1",
+    ("kanana-2-30b-a3b-d8", 8, 128): "22be0e6501ecf8fc",
+    ("xing4.0-29b-a4b-d7", 1, 128): "6e63f654eef0fb27",
+    ("xing4.0-29b-a4b-d7", 8, 128): "fc8c9c7bdbc1342b",
+}
+_PARENT_KERNEL_JAXPR = {"kv": "46493c11e2bc8df7", "latent": "578dfb29646ddfdd"}
+
+
+def _digest(text: str) -> str:
+    import hashlib
+
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("name,rows,t", sorted(_PARENT_PREFILL_TEXT))
+def test_rectangle_prefill_programs_lower_to_the_parents_text(v5e, name,
+                                                              rows, t):
+    """The state-keeping and the latent deployments run PR 45's prefill
+    programs: the narrowest and the widest family of each lowers for a v5e
+    to the text it lowered to there."""
+    r = _deployment_runner(v5e, name)
+    assert not r.prefill_packs
+    fams = r.reachable_prefill_families()
+    fam = next(f for f in (fams[0], fams[-1]) if f[:2] == (rows, t))
+    text = r._lower_prefill(r._abstract_params(), *fam) \
+        .compiler_ir().operation.get_asm(enable_debug_info=False)
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    assert "BODY" in text
+    assert _digest(text) == _PARENT_PREFILL_TEXT[name, rows, t]
+
+
+@pytest.mark.parametrize("kernel", sorted(_PARENT_KERNEL_JAXPR))
+def test_rectangle_prefill_kernels_trace_to_the_parents_jaxpr(kernel):
+    """... and the two rectangle kernels' own jaxprs (what a Mosaic body is
+    made from) are PR 45's, at the hybrid's full layers' and the latent
+    configurations' shapes: ``_tile_sequence`` serves a third caller since
+    PR 46 and emits for these two what it emitted."""
+    from production_stack_tpu.ops.pallas import paged_attention as pa
+
+    sds = jax.ShapeDtypeStruct
+    b, t, mb, slots = 8, 256, 192, 3072 * 16
+    tail = (sds((b, mb), jnp.int32), sds((b,), jnp.int32),
+            sds((), jnp.int32))
+    if kernel == "kv":
+        h = hkv = 30
+        jaxpr = jax.make_jaxpr(
+            lambda *a: pa.paged_flash_prefill(*a, block_size=16))(
+            sds((b, t, h, 128), jnp.bfloat16),
+            sds((b, t, hkv, 128), jnp.bfloat16),
+            sds((b, t, hkv, 128), jnp.bfloat16), sds((b, t), jnp.int32),
+            sds((b,), jnp.int32), sds((4, hkv, slots, 128), jnp.bfloat16),
+            sds((4, hkv, slots, 128), jnp.bfloat16), *tail)
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda *a: pa.paged_flash_prefill_latent(
+                *a, block_size=16, value_dim=512, scale=0.1))(
+            sds((b, t, 32, 640), jnp.bfloat16),
+            sds((b, t, 1, 640), jnp.bfloat16), sds((b, t), jnp.int32),
+            sds((b,), jnp.int32), sds((8, 1, slots, 640), jnp.bfloat16),
+            *tail)
+    assert _digest(str(jaxpr)) == _PARENT_KERNEL_JAXPR[kernel]
+
+
+@pytest.mark.parametrize("name,t", [("qwen2.5-3b", 2048),
+                                    ("mistral-7b-d16", 512),
+                                    ("qwen2.5-3b", 128)])
+def test_packed_prefill_programs_compile_in_place_for_v5e(v5e, name, t):
+    """A dense deployment's packed prefill program (one row of ``t`` tokens,
+    up to 16 segments) compiles for a v5e, holds the packed flash kernel
+    and no other execution of the chunk's attention, copies no pool (the
+    segments' K/V go to their slots slab by slab out of the one row) and
+    keeps the temporaries of the rectangle it replaces."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, name)
+    assert r.prefill_packs and r._prefill_segs == 16
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    assert (1, t, full_mb, False) in r.reachable_prefill_families()
+    compiled = r._lower_prefill(
+        r._abstract_params(), 1, t, full_mb, False).compile()
+    text = compiled.as_text()
+    assert pool_copies(text, [r.kv_k]) == []
+    assert "paged_flash_prefill_packed" in text
+    assert prefill_attn_path(text) == "pallas"
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    for scope in ("embed", "attn_proj", "attn_core", "ffn", "logits",
+                  "kv_write", "sample"):
+        assert f"/{scope}/" in text, scope
+    # 2048 tokens of a 3B model's activations: 163 MB at PR 46.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
 @pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128"])

@@ -340,3 +340,11 @@ def test_prefill_phase_rehearses_on_the_cpu():
                for c in line["checks"])
     assert all(t["bytes"] > 0 and t["flops"] > 0
                and t["us_per_call"] is None for t in line["timing"])
+    # A packed row beside the rectangle of the same sequences (PR 46):
+    # checked against the rectangle kernel over the row taken apart.
+    assert [(p["shape"], p["segments"], sum(p["seg_lens"]) == p["t"],
+             p["rectangle_us"]) for p in line["packed"]] == [
+        ("packed-chat-saturated", 3, True, None),
+        ("packed-agent-prefix", 1, True, None)]
+    assert [c["shape"] for c in line["checks"]][-2:] == \
+        ["packed-chat-saturated", "packed-agent-prefix"]

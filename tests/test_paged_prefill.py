@@ -19,8 +19,12 @@ from production_stack_tpu.ops.attention import (
     window_attention,
 )
 from production_stack_tpu.ops.pallas.paged_attention import (
+    packed_pairs,
+    packed_sub_block,
     paged_flash_prefill,
+    paged_flash_prefill_packed,
     prefill_tiles,
+    supports_packed_prefill,
     supports_pallas_prefill,
 )
 
@@ -420,3 +424,207 @@ async def test_engine_prefill_through_the_kernel_matches_the_gathered(
     assert {f[3] for f in families["kernel"]} == {False}
     assert results["kernel"] == results["gathered"]
     assert all(len(v) == 6 for v in results["kernel"].values())
+
+
+# ---------------------------------------------------------------- a packed row
+# PR 46: the sequences' chunks end to end in ONE row, the kernel told where
+# each begins (paged_flash_prefill_packed). The oracle is unchanged: a
+# segment's tokens are what ``window_attention`` gives the segment as a row
+# of its own.
+def _pack(c, clens, t):
+    """The rows of ``_case`` ``c`` laid end to end in one row of ``t``
+    tokens; what lies past the last is junk, but finite."""
+    def pack(x):
+        row = np.concatenate(
+            [np.asarray(x)[i, :cl] for i, cl in enumerate(clens)], 0)
+        junk = np.full((t - row.shape[0], *row.shape[1:]), 7.0, row.dtype)
+        return jnp.asarray(np.concatenate([row, junk], 0)[None])
+    return pack(c["q"]), pack(c["k"]), pack(c["v"])
+
+
+def _packed_kernel(c, clens, t, **kw):
+    q, k, v = _pack(c, clens, t)
+    return np.asarray(paged_flash_prefill_packed(
+        q, k, v, jnp.asarray(clens, jnp.int32), c["kp"], c["vp"], c["bt"],
+        c["kv_lens"], jnp.int32(LAYER), block_size=BS, interpret=True, **kw))
+
+
+@pytest.mark.parametrize("t,hists,clens,sub_block", [
+    # A boundary inside a query block (and inside a sub-block).
+    (256, [0, 37], [100, 120], 64),
+    # A segment over several query blocks, between two short ones.
+    (768, [300, 20, 0], [40, 600, 100], 64),
+    # Three segments in one block.
+    (256, [5, 0, 16], [60, 70, 80], 32),
+    # Histories of none, one and several superpages beside each other.
+    (512, [0, 500, 1100], [100, 200, 150], 128),
+    # A row whose tail is padding: the end of a block, and a whole block.
+    (512, [64, 64], [90, 30], 64),
+    # Sub-blocks as wide as the query block (what few heads a KV head get).
+    (512, [100, 0, 513], [300, 10, 150], None),
+    # Sixteen segments, the last slots empty.
+    (256, [0, 3, 64, 17] + [0] * 12, [30, 50, 60, 40] + [0] * 12, 32),
+], ids=["boundary-in-a-block", "segment-over-blocks", "three-in-a-block",
+        "history-superpages", "padded-tail", "whole-block-sub-blocks",
+        "sixteen-slots"])
+def test_packed_segments_match_window_a_segment(t, hists, clens, sub_block):
+    h, hkv = 4, 2
+    assert supports_packed_prefill(t, h, hkv, DH, 4, BS)
+    c = _case(t, h, hkv, hists=hists, clens=clens)
+    out = _packed_kernel(c, clens, t, sub_block=sub_block)
+    ref = np.asarray(_window_reference(c))
+    assert np.all(np.isfinite(out))
+    at = 0
+    for i, cl in enumerate(clens):
+        np.testing.assert_allclose(out[0, at:at + cl], ref[i, :cl],
+                                   atol=ATOL, rtol=0)
+        at += cl
+    # A query block no segment reaches is zeros.
+    _, tq = prefill_tiles(t, h, hkv, DH, 4, BS)
+    assert not out[0, -(-at // tq) * tq:].any()
+
+
+@pytest.mark.parametrize("hist,sub_block", [(0, 64), (700, 64), (40, None)])
+def test_one_segment_that_fills_the_row_is_the_row_bit_for_bit(hist,
+                                                               sub_block):
+    """``[1, T]`` through the packed kernel equals ``[1, T]`` through the
+    rectangle kernel exactly: the same tiles in the same order over the
+    same rows, whatever the sub-blocks."""
+    c = _case(512, 4, 2, hists=[hist], clens=[512])
+    pad = jnp.ones_like(c["bt"])
+    packed = paged_flash_prefill_packed(
+        c["q"], c["k"], c["v"], jnp.asarray([512, 0, 0], jnp.int32),
+        c["kp"], c["vp"], jnp.concatenate([c["bt"], pad, pad]),
+        jnp.asarray([hist, 0, 0], jnp.int32), jnp.int32(LAYER),
+        block_size=BS, interpret=True, sub_block=sub_block)
+    assert np.array_equal(np.asarray(packed), np.asarray(_kernel(c)))
+
+
+@pytest.mark.parametrize("lens,nq,tq,want", [
+    # (segment, block) pairs, block by block; a block nobody reaches has a
+    # pair of no tokens, and so have the entries past the last block.
+    ([100, 120, 0], 1, 256, [(0, 0, 100), (1, 0, 120), (0, 0, 0)]),
+    ([300, 10, 150], 2, 256,
+     [(0, 0, 256), (0, 1, 44), (1, 1, 10), (2, 1, 150)]),
+    ([40, 600, 100], 3, 256,
+     [(0, 0, 40), (1, 0, 216), (1, 1, 256), (1, 2, 128), (2, 2, 100)]),
+    ([90, 30], 2, 256, [(0, 0, 90), (1, 0, 30), (0, 1, 0)]),
+    ([256, 256], 2, 256, [(0, 0, 256), (1, 1, 256), (0, 1, 0)]),
+    ([0, 0], 2, 256, [(0, 0, 0), (0, 1, 0), (0, 1, 0)]),
+])
+def test_the_pairs_of_a_packed_row(lens, nq, tq, want):
+    seg, blk, start, end, tokens = (np.asarray(x) for x in packed_pairs(
+        jnp.asarray(lens, jnp.int32), nq, tq))
+    assert len(seg) == nq + len(lens) - 1
+    assert [(int(s) if n else 0, int(b), int(n))
+            for s, b, n in zip(seg, blk, tokens)] == want
+    assert int(tokens.sum()) == sum(lens)
+    offs = np.concatenate([[0], np.cumsum(lens)])
+    for s, a, e, n in zip(seg, start, end, tokens):
+        if n:
+            assert (a, e) == (offs[s], offs[s + 1])
+    assert np.all(np.diff(blk) >= 0)
+
+
+@pytest.mark.parametrize("tq,g,itemsize,want", [
+    (256, 8, 2, 32),      # qwen2.5-3b: 256 query rows a sub-block
+    (256, 4, 2, 64),      # mistral-7b
+    (128, 8, 2, 32),
+    (256, 1, 2, 256),     # one query head a KV head: the block whole
+    (256, 64, 2, 16),     # never under a sublane tile of the dtype
+    (32, 2, 4, 32),
+])
+def test_sub_blocks_keep_256_query_rows(tq, g, itemsize, want):
+    assert packed_sub_block(tq, g, itemsize) == want
+
+
+def test_attend_over_a_packed_view_agrees_in_both_executions():
+    """``attend`` over a view that says ``seg_lens``: the kernel (the view
+    says ``interpret``) and the other backends' execution, which takes the
+    row apart into a row a segment and runs ``window_attention``."""
+    t, h, hkv = 256, 4, 2
+    hists, clens = [0, 40, 300, 0], [70, 90, 50, 0]
+    c = _case(t, h, hkv, hists=hists, clens=clens)
+    q, k, v = _pack(c, clens, t)
+    kp, vp = (jnp.nan_to_num(c[x]) for x in ("kp", "vp"))
+    view = KVView(pool_k=kp, pool_v=vp, block_tables=c["bt"],
+                  kv_lens=c["kv_lens"], block_size=BS,
+                  seg_lens=jnp.asarray(clens, jnp.int32))
+    positions = jnp.zeros((1, t), jnp.int32)     # read by neither
+    row_len = jnp.asarray([sum(clens)], jnp.int32)
+    outs = [np.asarray(attend(q, k, v, positions, row_len,
+                              view._replace(interpret=interpret),
+                              jnp.int32(LAYER)))
+            for interpret in (False, True)]
+    live = sum(clens)
+    np.testing.assert_allclose(outs[0][0, :live], outs[1][0, :live],
+                               atol=ATOL, rtol=0)
+    assert not outs[0][0, live:].any()
+    # Latent rows have no packed form, and a packed view is one row.
+    assert not prefill_kernel_covers(
+        256, 32, 1, 640, 512, BS, (jnp.bfloat16,), latent=True, packed=True)
+    with pytest.raises(ValueError, match="does not cover"):
+        attend(jnp.concatenate([q, q]), jnp.concatenate([k, k]),
+               jnp.concatenate([v, v]), positions, row_len, view,
+               jnp.int32(LAYER))
+
+
+@pytest.mark.asyncio
+async def test_engine_sixteen_prompts_packed_serve_what_each_serves_alone():
+    """Sixteen prompts of mixed lengths at once through an engine whose
+    prefill dispatches are packed rows (``prefill_packs``: paged K/V rows
+    read in place, no state): the same greedy tokens and log-probabilities
+    as each prompt alone, one that crosses the token budget among them;
+    the dispatches carried several segments each and padded only their
+    rows' ends."""
+    import asyncio
+
+    from production_stack_tpu.engine.config import EngineConfig
+    from production_stack_tpu.engine.engine import ServingEngine
+    from production_stack_tpu.engine.sampling import SamplingParams
+
+    rng = np.random.default_rng(46)
+    lens = [5, 130, 17, 260, 64, 33, 700, 90, 8, 200, 45, 128, 3, 77, 150,
+            21]
+    prompts = [[int(x) for x in rng.integers(3, 200, n)] for n in lens]
+    cfg = EngineConfig(
+        model="tiny-llama-128dh", max_model_len=1024, num_kv_blocks=1024,
+        attn_impl="paged", num_decode_steps=4, dtype="float32",
+        max_num_batched_tokens=512, max_num_seqs=16, max_prefill_seqs=16,
+        block_size=16, enable_warmup=False, enable_prefix_caching=False,
+    )
+    eng = ServingEngine(cfg)
+    await eng.start()
+
+    async def one(i):
+        out = None
+        async for o in eng.generate(
+            prompt_token_ids=prompts[i], sampling=SamplingParams(
+                temperature=0.0, max_tokens=5, ignore_eos=True,
+                logprobs=2)):
+            out = o
+        return out.token_ids, [lp[0] for lp in out.logprobs]
+
+    try:
+        assert eng.runner.prefill_packs and eng.scheduler.prefill_packed
+        assert {f[0] for f in eng.runner.reachable_prefill_families()} == \
+            {1}
+        alone = [await one(i) for i in range(len(prompts))]
+        before = eng.stats()
+        together = await asyncio.gather(*map(one, range(len(prompts))))
+        after = eng.stats()
+    finally:
+        await eng.stop()
+    for (toks_a, lps_a), (toks_t, lps_t) in zip(alone, together):
+        assert toks_a == toks_t and len(toks_t) == 5
+        np.testing.assert_allclose(lps_a, lps_t, atol=1e-4, rtol=0)
+
+    def delta(name):
+        return after[name] - before[name]
+
+    dispatches = delta("prefill_dispatches_total")
+    assert delta("prefill_tokens_issued_total") == sum(lens)
+    assert delta("prefill_segments_total") == \
+        delta("prefill_rows_issued_total") > dispatches
+    # Only the rows' ends are padding: under a power of two of the whole.
+    assert delta("prefill_tokens_padded_total") < 2 * sum(lens)

@@ -1,8 +1,11 @@
-"""A prefill dispatch's rectangle (PR 37): its area never exceeds the token
-budget, the row cap follows from the budget, admission takes the rectangle
-of the ladder that carries the most live tokens over the FCFS prefix, and
-warm-up enumerates exactly what ``utils.prefill_rectangle`` can return.
-Pure scheduling: no model runs here."""
+"""A prefill dispatch's shape (PR 37, PR 46): its area never exceeds the
+token budget and the row cap follows from the budget; where dispatches are
+rectangles, admission takes the rectangle of the ladder that carries the
+most live tokens over the FCFS prefix; where they are packed rows, every
+candidate's chunk lies behind its predecessor's in one row (a share each,
+then the slack in queue order); warm-up enumerates exactly what
+``utils.prefill_rectangle`` can return. Pure scheduling: no model runs
+here."""
 
 import json
 import os
@@ -34,12 +37,13 @@ def _cfg(**over):
     return EngineConfig(**base)
 
 
-def _sched(blocks=1 << 15, slots=0, window=None, **over):
+def _sched(blocks=1 << 15, slots=0, window=None, packed=False, **over):
     cfg = _cfg(**over)
     bm = BlockPoolManager(blocks, cfg.block_size,
                           enable_prefix_caching=False,
                           num_state_slots=slots)
-    return Scheduler(cfg, bm, prefill_window_budget=window)
+    return Scheduler(cfg, bm, prefill_window_budget=window,
+                     prefill_packed=packed)
 
 
 def _waiting(sched, lengths):
@@ -188,6 +192,150 @@ def test_the_choice_at_a_2048_budget(lengths, rows, chunks):
     assert (len(batch.seqs), batch.chunk_lens) == (rows, chunks)
 
 
+# ------------------------------------------------- a packed row (PR 46)
+@pytest.mark.parametrize("lengths,chunks,t", [
+    # The cases above where dispatches are packed rows. Sixteen median
+    # prompts: a share of 128 each fills the row (no slack to hand on).
+    ([320] * 16, [128] * 16, 2048),
+    # Sixteen short prompts: all of each; the row's end is the padding.
+    ([100] * 16, [100] * 16, 2048),
+    # Two prompts arriving together: both whole, in a 1024-token row
+    # (the rectangle took 256 of each in [8, 256]).
+    ([320, 320], [320, 320], 1024),
+    # One long prompt has the whole budget.
+    ([3000], [2048], 2048),
+    # A long head and short followers: the followers whole, and what they
+    # leave of their shares goes to the head (the rectangle took the head
+    # alone, or 256 of it).
+    ([2000] + [60] * 7, [1628] + [60] * 7, 2048),
+    ([600] + [200] * 7, [600] + [200] * 7, 2048),
+    # The slack goes in queue order: the first with prompt left is filled
+    # before the second sees any.
+    ([100, 1500, 1500, 100], [100, 1336, 512, 100], 2048),
+    # As much in less: the smallest row that holds the sum.
+    ([90], [90], 128),
+    ([90, 60], [90, 60], 256),
+])
+def test_packed_chunks_take_a_share_then_the_slack(lengths, chunks, t):
+    sched = _sched(packed=True)
+    _waiting(sched, lengths)
+    queue = list(sched.waiting)
+    batch = sched._try_schedule_prefill()
+    assert batch.packed and batch.seqs == queue[:len(lengths)]
+    assert batch.chunk_lens == chunks
+    assert prefill_rectangle(len(chunks), max(chunks), sched.config,
+                             packed_tokens=sum(chunks)) == (1, t)
+
+
+@pytest.mark.parametrize("seed", (0, 1, 2))
+@pytest.mark.parametrize("budget", BUDGETS)
+def test_a_packed_dispatch_is_one_row_over_the_fcfs_prefix(budget, seed):
+    """Random queues at room 1..64 where dispatches are packed: every
+    candidate the pass gathered is taken, in queue order; nobody gets less
+    than ``min(remaining, budget // n)`` nor more than it has; the sum
+    fits the widest row and the program is the smallest one-row shape
+    that holds it; whoever got more than a share stands behind nobody with
+    prompt left; where the row is not full nobody has prompt left; `stop`
+    names the candidate loop's limit; the counters' padded area is T."""
+    rng = random.Random(2000 * budget + seed)
+    cfg = _cfg(max_num_batched_tokens=budget)
+    cap = prefill_row_cap(cfg)
+    rows_of_one = prefill_rectangles(cfg, True)
+    assert rows_of_one == tuple(r for r in prefill_rectangles(cfg)
+                                if r[0] == 1)
+    widest = rows_of_one[-1][1]
+    for _ in range(40):
+        sched = _sched(packed=True, max_num_batched_tokens=budget)
+        room = rng.randint(1, 64)
+        _occupy(sched, 64 - room)
+        lengths = [min(3000, max(1, int(rng.lognormvariate(5.7, 0.9))))
+                   for _ in range(rng.randint(1, 24))]
+        _waiting(sched, lengths)
+        queue = list(sched.waiting)
+        batch = sched._try_schedule_prefill()
+        n = len(batch.seqs)
+        assert n == min(cap, room, len(queue)) and batch.seqs == queue[:n]
+        share = widest // n
+        lens, rems = batch.chunk_lens, lengths[:n]
+        assert all(min(r, share) <= x <= r for x, r in zip(lens, rems))
+        assert sum(lens) <= widest
+        rows, t = prefill_rectangle(n, max(lens), cfg,
+                                    packed_tokens=sum(lens))
+        assert rows == 1 and t == min(
+            t2 for _, t2 in rows_of_one if t2 >= sum(lens))
+        if sum(lens) < widest:
+            assert lens == rems
+        for i, x in enumerate(lens):
+            if x > share:
+                assert lens[:i] == rems[:i]
+        assert batch.chunk_starts == [0] * n
+        assert batch.left_waiting == len(queue) - n
+        assert batch.stop == _expected_stop(
+            n, n, batch.left_waiting, cap, room)
+        assert all(s.block_ids for s in batch.seqs)
+        assert not any(s.block_ids for s in queue[n:])
+
+
+def test_a_packed_row_continues_what_an_earlier_row_began():
+    """A long head beside seven short prompts: the first row takes 1628 of
+    the head behind... before the seven whole; the second is the head's
+    remaining 372 tokens alone, from where the first left it."""
+    sched = _sched(packed=True)
+    _waiting(sched, [2000] + [60] * 7)
+    first = sched._try_schedule_prefill()
+    assert first.chunk_lens == [1628] + [60] * 7
+    sched.advance_at_issue(first)
+    batch = sched._try_schedule_prefill()
+    assert (batch.chunk_starts, batch.chunk_lens, batch.stop) == \
+        ([1628], [372], "none")
+    assert prefill_rectangle(1, 372, sched.config, packed_tokens=372) == \
+        (1, 512)
+
+
+def _predicate_runner(**over):
+    """A ModelRunner that holds only what ``prefill_packs`` reads."""
+    from types import SimpleNamespace
+
+    from production_stack_tpu.engine.runner import ModelRunner
+
+    r = object.__new__(ModelRunner)
+    r.config = _cfg()
+    r.model_config = SimpleNamespace(num_heads=16)
+    r.kv_spec = SimpleNamespace(kv_heads=2, head_dim=128)
+    r.kv_value_dim, r.kv_pools, r.dtype = 128, 2, "bfloat16"
+    r.state_specs, r.lora_stacks, r.spec_n = (), None, 0
+    r.attn_impl, r.num_kv_blocks = "paged", 1 << 20
+    r.__dict__["prefill_reads_pool"] = True
+    for k, v in over.items():
+        if k in ("kv_heads", "head_dim"):
+            setattr(r.kv_spec, k, v)
+        elif k == "prefill_reads_pool":
+            r.__dict__[k] = v
+        else:
+            setattr(r, k, v)
+    return r
+
+
+@pytest.mark.parametrize("case,over,packs", [
+    ("dense K/V rows read in place", {}, True),
+    ("a gathered window", {"prefill_reads_pool": False}, False),
+    ("recurrent state", {"state_specs": ("some",)}, False),
+    ("latent rows", {"kv_pools": 1, "kv_heads": 1, "head_dim": 640,
+                     "kv_value_dim": 512}, False),
+    ("an adapter a row", {"lora_stacks": {"wq": None}}, False),
+    ("a draft ring a row", {"spec_n": 3}, False),
+])
+def test_which_form_a_dispatch_takes_is_decided_in_one_place(case, over,
+                                                             packs):
+    """``ModelRunner.prefill_packs``, from what the runner holds: the
+    state-keeping and the latent configurations keep their rectangles."""
+    r = _predicate_runner(**over)
+    assert r.prefill_packs is packs
+    assert r._prefill_segs == (16 if packs else 0)
+    assert {rows for rows, _, _, _ in r.reachable_prefill_families()} == \
+        ({1} if packs else {1, 8, 16})
+
+
 # ------------------------------------------- rows not taken give back what
 # ------------------------------------------- they took this pass
 @pytest.mark.parametrize("slots", (0, 4))
@@ -277,28 +425,33 @@ def _deployment_flags(name):
         num_kv_blocks=int(flags["--num-kv-blocks"]))
 
 
-@pytest.mark.parametrize("cell,reads_pool,families,before", [
+@pytest.mark.parametrize("cell,reads_pool,packs,families,before", [
     # (rows, t) programs a deployment warms, by what PERF.md section 7
-    # states: this PR's, and the two row families' before it.
-    ("qwen2.5-3b.chat-steady", True, 8, 9),
-    ("mistral-7b-d16.agent-prefix", True, 8, 9),
-    ("qwen2.5-3b.chat-saturated", True, 8, 9),
-    ("olmo-hybrid-7b-d16.chat-saturated", True, 8, 9),
+    # states: this PR's, and PR 37's before it. The dense cells' dispatches
+    # are packed rows since PR 46: the one-row column alone.
+    ("qwen2.5-3b.chat-steady", True, True, 5, 8),
+    ("mistral-7b-d16.agent-prefix", True, True, 5, 8),
+    ("qwen2.5-3b.chat-saturated", True, True, 5, 8),
+    # ... and what they warmed as rectangles (a dense model behind an
+    # adapter or a draft still does).
+    ("qwen2.5-3b.chat-saturated", True, False, 8, 9),
+    ("olmo-hybrid-7b-d16.chat-saturated", True, False, 8, 9),
     # Latent rows read their pool in place since PR 39: one program a
     # (rows, t), where PR 38's tree had each with and without a window.
-    ("kanana-2-30b-a3b-d8.chat-saturated", True, 7, 14),
-    ("xing4.0-29b-a4b-d7.chat-saturated", True, 7, 14),
+    ("kanana-2-30b-a3b-d8.chat-saturated", True, False, 7, 14),
+    ("xing4.0-29b-a4b-d7.chat-saturated", True, False, 7, 14),
     # The same envelope where the predicate refuses the pool view (a
     # sharded or int8 latent deployment): a gathered window, pinned at one
     # width, each (rows, t) with and without it.
-    ("kanana-2-30b-a3b-d8.chat-saturated", False, 14, 14),
+    ("kanana-2-30b-a3b-d8.chat-saturated", False, False, 14, 14),
 ])
 def test_warm_up_enumerates_exactly_what_a_dispatch_can_run(
-        cell, reads_pool, families, before):
-    """For the six cells' engine flags: every (rows, T) that
-    ``prefill_rectangle`` returns for n in 1..cap and any chunk length is
-    a family of ``reachable_prefill_families``, with and without a window
-    where one is gathered, and the enumeration holds nothing else."""
+        cell, reads_pool, packs, families, before):
+    """For the cells' engine flags: every (rows, T) that
+    ``prefill_rectangle`` returns for n in 1..cap and any chunk length
+    (where dispatches are packed: for any sum of chunks) is a family of
+    ``reachable_prefill_families``, with and without a window where one
+    is gathered, and the enumeration holds nothing else."""
     from production_stack_tpu.engine.runner import ModelRunner
 
     cfg = _cfg(model="tiny-llama", **_deployment_flags(cell.rsplit(".", 1)[0]))
@@ -315,6 +468,7 @@ def test_warm_up_enumerates_exactly_what_a_dispatch_can_run(
         state_specs = ()
         kv_pools = 2 if reads_pool else 1     # latent rows: one pool
         prefill_reads_pool = reads_pool
+        prefill_packs = packs
 
     r = _FakeRunner()
     fams = r.reachable_prefill_families()
@@ -323,6 +477,18 @@ def test_warm_up_enumerates_exactly_what_a_dispatch_can_run(
     assert cap == min(cfg.max_num_seqs, budget // prefill_t_floor(budget))
     full_mb = pow2_bucket(cfg.max_blocks_per_seq, 1, cfg.max_blocks_per_seq)
     seen = set()
+    if packs:
+        # Whatever the chunks, their sum decides, and it never passes the
+        # widest row (``_packed_chunk_lens``).
+        for tokens in range(1, budget + 1):
+            rows, t = prefill_rectangle(cap, 1, cfg, packed_tokens=tokens)
+            fam = (rows, t, r._prefill_mb(full_mb // 2, False, rows), False)
+            assert rows == 1 and t >= tokens and fam in fams
+            seen.add(fam)
+        with pytest.raises(ValueError):
+            prefill_rectangle(1, 1, cfg, packed_tokens=budget + 1)
+        assert seen == set(fams)
+        return
     for n in range(1, cap + 1):
         for chunk in range(1, budget + 1):
             try:

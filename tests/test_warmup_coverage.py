@@ -175,6 +175,7 @@ def test_reachable_families_cover_observed_dispatches():
         state_specs = ()
         kv_pools = 2
         prefill_reads_pool = False    # this ladder: a gathered window
+        prefill_packs = False         # ... and so a row a sequence
 
     r = _FakeRunner()
     dec = set(r.reachable_decode_families())
@@ -233,6 +234,16 @@ def test_reachable_families_cover_observed_dispatches():
     assert in_place == {(b, t, full_mb, False) for b, t, _, _ in pre}
     assert len(in_place) * 4 == len(pre)
     assert r._prefill_mb(3, False, 1) == full_mb
+    # ... and where such dispatches are packed rows, the one-row column of
+    # that ladder, whatever the sum of the chunks.
+    r.prefill_packs = True
+    packed = set(r.reachable_prefill_families())
+    assert packed == {f for f in in_place if f[0] == 1}
+    for tokens in (1, 100, 129, 256):
+        b, t = prefill_rectangle(2, tokens // 2 + 1, cfg,
+                                 packed_tokens=tokens)
+        assert (b, t, full_mb, False) in packed
+    r.prefill_packs = False
 
     # window impl: quantized mb ladder has at most 4 values.
     r.attn_impl = "window"

@@ -519,15 +519,22 @@ REGISTRY: Tuple[Series, ...] = (
            "carries"),
     Series("pstpu:prefill_tokens_padded_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
-           "Tokens of the padded rectangle prefill dispatches ran: "
+           "Tokens of the padded shape prefill dispatches ran: "
            "program rows x program chunk length "
-           "(`utils.prefill_rectangle`, the shape the device computes); "
+           "(`utils.prefill_rectangle`, the shape the device computes; "
+           "one row of T tokens where the chunks are packed end to end); "
            "issued over padded is the share of prefill compute that was "
            "prompt"),
     Series("pstpu:prefill_rows_issued_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Live rows of prefill dispatches, counted at issue; over "
            "`pstpu:prefill_dispatches_total` the rows a dispatch carries"),
+    Series("pstpu:prefill_segments_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Sequences whose chunks lay end to end as segments of the one "
+           "row of a PACKED prefill dispatch, counted at issue; 0 while "
+           "every dispatch is a rectangle (a model with per-row state or "
+           "latent rows, a gathered window)"),
     Series("pstpu:prefill_left_waiting_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Requests still waiting that a prefill could have taken, at "
