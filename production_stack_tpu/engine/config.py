@@ -602,6 +602,38 @@ class EngineConfig:
                 f"model {self.model!r} caches one latent row a token, which "
                 f"cannot follow: {'; '.join(asked)}. Start without it.")
 
+    def refuse_what_a_span_cannot_follow(self, model_config) -> None:
+        """A model with a layer whose attention is bounded (its module's
+        ``bounded_layers``: a span of keys a query sees, ops/attention.py)
+        is served by the executions that honour the bound: K/V rows of one
+        chip through ``window_attention`` and the dense paged kernels.
+        What would attend through another is refused here, at start."""
+        from production_stack_tpu.models import get_model
+
+        bounded = getattr(get_model(model_config), "bounded_layers", None)
+        if bounded is None or not bounded(model_config):
+            return
+        why = {
+            "speculative decoding (--speculative-num-tokens): the verify "
+            "step's token tree has no bounded mask":
+                bool(self.speculative_num_tokens),
+            "LoRA adapters (--lora-modules): the gate and the experts have "
+            "no delta path": bool(self.lora_modules),
+            "--kv-cache-dtype int8: the paged kernels skip no superpage of "
+            "an int8 pool's scales": self.kv_cache_quantized,
+            "tensor parallelism (--tensor-parallel-size > 1): the sharded "
+            "decode kernel takes no bound":
+                self.tensor_parallel_size > 1,
+            "sequence parallelism (--sequence-parallel-size > 1): the ring "
+            "masks by causality alone": self.sequence_parallel_size > 1,
+        }
+        asked = [name for name, on in why.items() if on]
+        if asked:
+            raise ValueError(
+                f"model {self.model!r} bounds the keys a query sees in "
+                f"layers {bounded(model_config)}, which cannot follow: "
+                f"{'; '.join(asked)}. Start without it.")
+
     def kv_cache_bytes_per_block(self, model_config) -> int:
         """Pool bytes one KV block occupies (block_size tokens)."""
         return self.block_size * self.kv_cache_bytes_per_token(model_config)

@@ -17,7 +17,9 @@ import asyncio
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import AsyncIterator, Dict, List, Optional, Set
+from typing import AsyncIterator, Dict, List, Optional, Set, Tuple
+
+import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.kv_cache import BlockPoolManager
@@ -35,6 +37,7 @@ from production_stack_tpu.engine.tokenizer import (
 )
 from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import resolve_model_config
+from production_stack_tpu.ops.attention import NO_SPAN, keys_in_span
 from production_stack_tpu.parallel import make_mesh
 from production_stack_tpu.protocols import random_uuid
 from production_stack_tpu.tracing import (
@@ -97,6 +100,7 @@ class ServingEngine:
         self.model_config = resolve_model_config(config.model)
         config.refuse_what_state_cannot_follow(self.model_config)
         config.refuse_what_latent_rows_cannot_follow(self.model_config)
+        config.refuse_what_a_span_cannot_follow(self.model_config)
         self.tokenizer = get_tokenizer(config.model, self.model_config)
         self.mesh = mesh or make_mesh(
             dp=config.data_parallel_size,
@@ -281,6 +285,13 @@ class ServingEngine:
         self.prefill_segments_total = 0
         self.prefill_left_waiting_total = 0
         self.prefill_stops: Dict[str, int] = dict.fromkeys(PREFILL_STOPS, 0)
+        # Keys the attention layers' queries see, exact on the host
+        # (``_attn_keys``): summed over layers, for every prefill token at
+        # issue and every delivered decode row-step at apply; ``held`` is
+        # the same with no layer bounded (what the one block table keeps).
+        # Both stay 0 for a model without a bounded layer.
+        self.attn_keys_in_span_total = 0
+        self.attn_keys_held_total = 0
         # Compiles and persistent-cache loads WHILE SERVING
         # (flight_recorder.CompileClock): the process's clock, and its
         # reading when start() ended (warm-up's own work is
@@ -813,6 +824,20 @@ class ServingEngine:
                 fut.set_result(res)
 
     # ------------------------------------------------------------ engine loop
+    def _attn_keys(self, starts, lengths) -> Tuple[int, int]:
+        """(keys inside their layer's span, keys held) that runs of
+        ``lengths`` tokens at positions ``starts`` on see, summed over the
+        model's layers: the closed form of ops/attention.py:keys_in_span.
+        (0, 0) for a model without a bounded layer."""
+        spans = self.runner.layer_spans
+        if spans is None:
+            return 0, 0
+        kinds, counts = np.unique(spans, return_counts=True)
+        seen = sum(int(n) * int(keys_in_span(starts, lengths, kind).sum())
+                   for kind, n in zip(kinds, counts))
+        return seen, len(spans) * int(
+            keys_in_span(starts, lengths, NO_SPAN).sum())
+
     def _count_decode(self, batch, delivered: int) -> None:
         """One applied decode dispatch into the step and row counters
         (defined in __init__); ``delivered`` tokens reached its rows, the
@@ -940,10 +965,24 @@ class ServingEngine:
                 )
                 self.last_step_time = self._last_fetch_done = \
                     time.monotonic()
+                bounded = batch.kind == "decode" \
+                    and self.runner.layer_spans is not None
+                if bounded:
+                    # The query of output token j sits at position
+                    # prompt + j - 1.
+                    before = [s.num_prompt_tokens + len(s.output_token_ids)
+                              - 1 for s in batch.seqs]
                 produced, accepted = self.scheduler.apply_results(
                     batch, tokens, lps
                 )
                 self.generation_tokens_total += accepted
+                if bounded:
+                    after = [s.num_prompt_tokens + len(s.output_token_ids)
+                             - 1 for s in batch.seqs]
+                    keys_seen, keys_held = self._attn_keys(
+                        before, np.subtract(after, before))
+                    self.attn_keys_in_span_total += keys_seen
+                    self.attn_keys_held_total += keys_held
                 if batch.kind == "decode":
                     self._count_decode(batch, accepted)
                 # Live roofline accounting (stats() folds the window into
@@ -1023,11 +1062,15 @@ class ServingEngine:
                         len(batch.seqs), max(batch.chunk_lens), cfg,
                         tokens if batch.packed else None)
                     segments = len(batch.seqs) if batch.packed else 0
+                    keys_seen, keys_held = self._attn_keys(
+                        batch.chunk_starts, batch.chunk_lens)
                     carried = {
                         "k": max(batch.chunk_lens), "tokens": tokens,
                         "prog_rows": prog_rows, "prog_t": prog_t,
                         "segments": segments,
                         "left": batch.left_waiting, "stop": batch.stop,
+                        **({"keys_in_span": keys_seen,
+                            "keys_held": keys_held} if keys_held else {}),
                     }
                 with loop_span(
                     "pstpu.issue", step=step, kind=batch.kind,
@@ -1084,6 +1127,8 @@ class ServingEngine:
                             prog_rows * prog_t
                         self.prefill_rows_issued_total += len(batch.seqs)
                         self.prefill_segments_total += segments
+                        self.attn_keys_in_span_total += keys_seen
+                        self.attn_keys_held_total += keys_held
                         self.prefill_left_waiting_total += \
                             batch.left_waiting
                         if batch.stop != "none":
@@ -1534,6 +1579,7 @@ class ServingEngine:
                 "model": self.config.model,
                 "num_layers": self.model_config.num_layers,
                 **r.residual_report(),
+                **r.span_report(),
                 "mesh": dict(self.mesh.shape),
                 "attn_impl": r.attn_impl,
                 "pallas_interpret": r._pallas_interpret,
@@ -1704,6 +1750,8 @@ class ServingEngine:
             "prefill_tokens_padded_total": self.prefill_tokens_padded_total,
             "prefill_rows_issued_total": self.prefill_rows_issued_total,
             "prefill_segments_total": self.prefill_segments_total,
+            "attn_keys_in_span_total": self.attn_keys_in_span_total,
+            "attn_keys_held_total": self.attn_keys_held_total,
             "prefill_left_waiting_total": self.prefill_left_waiting_total,
             **{f"prefill_stop_{stop}_total":
                n + self.scheduler.prefill_blocked[stop]

@@ -48,6 +48,7 @@ from production_stack_tpu.engine.scheduler import ScheduledBatch, Sequence
 from production_stack_tpu.models import get_model
 from production_stack_tpu.models.config import ModelConfig
 from production_stack_tpu.ops.attention import (
+    NO_SPAN,
     KVView,
     gather_window,
     prefill_attn_path,
@@ -3386,6 +3387,7 @@ class ModelRunner:
                 if path:
                     out[-1][name] = path
             out[-1].update(self.residual_report())
+            out[-1].update(self.span_report())
             if kind == "prefill":
                 out[-1]["prefill_attn"] = prefill_attn_path(text)
                 out[-1]["prefill_reads_pool"] = self.prefill_reads_pool
@@ -3400,6 +3402,25 @@ class ModelRunner:
         streams = self.model_config.hc_mult
         return {"hc_mult": streams,
                 **({"hc_mix": EXECUTION} if streams > 1 else {})}
+
+    @functools.cached_property
+    def layer_spans(self):
+        """Per layer, the keys a query sees up to itself (ops/attention.py:
+        a span; NO_SPAN where the layer has none) of a model that bounds
+        some layer's attention; None for every other."""
+        model = get_model(self.model_config)
+        bounded = getattr(model, "bounded_layers", None)
+        return model.spans(self.model_config) \
+            if bounded is not None and bounded(self.model_config) else None
+
+    def span_report(self) -> Dict:
+        """Of a model that bounds some layer's attention: which layers
+        (``span_layers``) and by how many keys (``span``); else nothing."""
+        if self.layer_spans is None:
+            return {}
+        bounded = [i for i, s in enumerate(self.layer_spans) if s != NO_SPAN]
+        return {"span_layers": bounded,
+                "span": int(self.layer_spans[bounded[0]])}
 
     def _warmup_compile_prepass(self) -> int:
         """Compile-only AOT pass over every reachable shape family using

@@ -9,6 +9,7 @@ outside this package asks for an architecture by name.
 """
 
 from production_stack_tpu.models import (
+    afmoe,
     deepseek_v3,
     granite_hybrid,
     lfm2_moe,
@@ -28,7 +29,7 @@ from production_stack_tpu.models.config import (
 
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
           "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid,
-          "lfm2_moe": lfm2_moe}
+          "lfm2_moe": lfm2_moe, "afmoe": afmoe}
 
 
 def get_model(cfg: ModelConfig):

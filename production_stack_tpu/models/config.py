@@ -84,7 +84,7 @@ class CacheSpecs(NamedTuple):
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid" |
-    # "lfm2_moe"
+    # "lfm2_moe" | "afmoe"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -169,9 +169,15 @@ class ModelConfig:
     # of the router's choice (served as a zero bias).
     conv_l_cache: int = 3
     use_expert_bias: bool = True
+    # A "sliding_attention" layer's span (models/afmoe.py): a query sees
+    # itself and the sliding_window - 1 keys before it. 0: no such layer.
+    sliding_window: int = 0
 
     def __post_init__(self):
-        if self.arch in FREE_LAYER_LISTS:
+        if self.arch in ANY_ORDER_LISTS:
+            _known_kinds(self.layer_types, self.num_layers,
+                         ANY_ORDER_LISTS[self.arch])
+        elif self.arch in FREE_LAYER_LISTS:
             free_layer_list(self.layer_types, self.num_layers,
                             self.first_k_dense_replace,
                             FREE_LAYER_LISTS[self.arch])
@@ -403,6 +409,57 @@ class ModelConfig:
                 use_expert_bias=d.get("use_expert_bias", True),
                 name=name,
             )
+        if model_type == "afmoe":
+            # What the module does not implement is refused by its key, not
+            # served as something else.
+            unsupported = {
+                "rope_scaling": d.get("rope_scaling") is not None,
+                "score_func != sigmoid":
+                    d.get("score_func", "sigmoid") != "sigmoid",
+                "n_group/num_expert_groups/topk_group > 1": max(
+                    d.get("n_group", 1), d.get("num_expert_groups", 1),
+                    d.get("topk_group", 1)) > 1,
+                "num_experts < 1": d.get("num_experts", 0) < 1,
+                "sliding_window < 1 beside a sliding_attention layer":
+                    "sliding_attention" in d["layer_types"]
+                    and int(d.get("sliding_window") or 0) < 1,
+                "num_dense_layers >= num_hidden_layers":
+                    d.get("num_dense_layers", 0) >= d["num_hidden_layers"],
+                "attention_bias": bool(d.get("attention_bias", False)),
+                "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+            }
+            asked = [k for k, on in unsupported.items() if on]
+            if asked:
+                raise ValueError(
+                    f"{model_type}: not supported: {', '.join(asked)}")
+            return ModelConfig(
+                arch="afmoe",
+                vocab_size=d["vocab_size"],
+                hidden_size=d["hidden_size"],
+                intermediate_size=d["intermediate_size"],
+                num_layers=d["num_hidden_layers"],
+                num_heads=d["num_attention_heads"],
+                num_kv_heads=d.get("num_key_value_heads",
+                                   d["num_attention_heads"]),
+                head_dim=d.get("head_dim"),
+                max_position_embeddings=d.get("max_position_embeddings", 4096),
+                rope_theta=float(d.get("rope_theta", 10000.0)),
+                rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+                tie_word_embeddings=d.get("tie_word_embeddings", False),
+                layer_types=tuple(d["layer_types"]),
+                sliding_window=int(d.get("sliding_window") or 0),
+                n_routed_experts=d["num_experts"],
+                num_experts_per_tok=d["num_experts_per_tok"],
+                n_shared_experts=d.get("num_shared_experts", 0),
+                moe_intermediate_size=d["moe_intermediate_size"],
+                first_k_dense_replace=d.get("num_dense_layers", 0),
+                routed_scaling_factor=float(d.get("route_scale", 1.0)),
+                norm_topk_prob=d.get("route_norm", True),
+                # mup_enabled: the table's rows times sqrt(hidden_size).
+                embedding_multiplier=float(d["hidden_size"]) ** 0.5
+                if d.get("mup_enabled", False) else 1.0,
+                name=name,
+            )
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
@@ -424,6 +481,10 @@ PERIOD_RULES = {
 # in ANY order (models/lfm2_moe.py scans layers one at a time with the
 # operator chosen by a table): the published list there is not equal periods.
 FREE_LAYER_LISTS = {"lfm2_moe": ("conv", "full_attention")}
+# Per arch whose layers differ by DATA alone (models/afmoe.py: a layer's span
+# and whether it rotates are two scalars its scans index), so its list is
+# taken in any order and need not hold both kinds.
+ANY_ORDER_LISTS = {"afmoe": ("sliding_attention", "full_attention")}
 
 
 def _known_kinds(layer_types, num_layers: int,
@@ -632,8 +693,26 @@ TINY_LFM2_MOE = ModelConfig(
     conv_l_cache=3, name="tiny-lfm2-moe",
 )
 
+# Tiny bounded-span sparse-expert decoder: the published period (sliding x 3,
+# full) twice, 2 leading dense layers, 8 experts top-2 beside a shared one,
+# a span of 24 keys (not a multiple of the block), 128-lane heads so that
+# the paged kernels take it (tests/test_afmoe.py compares it with the plain
+# reference).
+AFMOE_LAYER_TYPES = ("sliding_attention",) * 3 + ("full_attention",)
+TINY_AFMOE = ModelConfig(
+    arch="afmoe", vocab_size=512, hidden_size=128, intermediate_size=256,
+    num_layers=8, num_heads=4, num_kv_heads=2, head_dim=128,
+    max_position_embeddings=512, rope_theta=10000.0, rms_norm_eps=1e-5,
+    layer_types=AFMOE_LAYER_TYPES * 2, sliding_window=24,
+    n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+    moe_intermediate_size=64, first_k_dense_replace=2,
+    routed_scaling_factor=2.826, embedding_multiplier=128 ** 0.5,
+    name="tiny-afmoe",
+)
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-afmoe": TINY_AFMOE,
     "tiny-lfm2-moe": TINY_LFM2_MOE,
     "tiny-granite-hybrid": TINY_GRANITE_HYBRID,
     "tiny-deepseek-v3": TINY_DEEPSEEK_V3,

@@ -26,6 +26,14 @@ may read as one ``KVView``, a model module hands it unopened to ``attend``
 from inside its layer, and ``attend`` picks the kernel. ``scan_layers`` runs
 a model's layer function over the stacked layers and slices the view per
 layer. No model file names a kernel.
+
+Two things are told apart by name. The engine's WINDOW (``KVView.win_k``,
+``window_attention``, ``gather_window``) is a gathered buffer of a row's
+history: an execution, which changes no result. A layer's SPAN (``attend``'s
+``span``, a model's sliding-window attention) is part of the model's
+equations: a query at position i sees the keys at ``i - span < j <= i`` and
+no others, on every execution, and the paged kernels neither fetch nor score
+the superpages that lie wholly behind it.
 """
 
 import functools
@@ -33,6 +41,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -80,6 +89,7 @@ def window_attention(
     scale: Optional[float] = None,
     chunk_bias: Optional[jax.Array] = None,  # [T, T] additive f32 {0, -inf}
     qblock: int = QBLOCK,
+    span: Optional[jax.Array] = None,        # [] int32: the layer's span
 ) -> jax.Array:
     """Dense attention against up to three key segments, TPU-shaped.
 
@@ -109,6 +119,10 @@ def window_attention(
     Only the single-Q-block path supports it (speculative verify chunks are
     N+W <= 24 tokens, far under QBLOCK).
 
+    ``span`` (see the module docstring): in every segment a key at position
+    j is valid for a query at position i only where ``i - j < span`` as
+    well. None (static): the masks below, and the program, as they were.
+
     Returns [B, T, H, Dh] in q.dtype.
     """
     b, t, h, dh = q.shape
@@ -137,16 +151,26 @@ def window_attention(
         s_idx = jnp.arange(s, dtype=jnp.int32)
         win_bias = jnp.where(s_idx[None, :] < win_len[:, None], 0.0, neg)  # [B, S]
 
+    def in_span(pos_q, pos_k):
+        # [B, TQ] x [B, S] -> [B, TQ, S]: the key lies inside the span.
+        return pos_q[:, :, None] - pos_k[:, None, :] < span
+
+    def per_query(bias, tq):
+        # [B, TQ, S] -> [1, B, G*TQ, S]: a score block's rows.
+        return jnp.broadcast_to(
+            bias[:, None, :, :], (b, g, tq, bias.shape[-1])
+        ).reshape(1, b, g * tq, bias.shape[-1])
+
     def q_block(qb, pos_q):
         # qb: [Hkv, B, G, TQ, Dh]; pos_q: [B, TQ] query positions
         tq = qb.shape[3]
         m = g * tq
         qb = qb.reshape(hkv, b, m, dh)
-        cb = jnp.where(
-            chunk_valid[:, None, :]
-            & (positions[:, None, :] <= pos_q[:, :, None]),
-            0.0, neg,
-        )                                                   # [B, TQ, T]
+        seen = chunk_valid[:, None, :] \
+            & (positions[:, None, :] <= pos_q[:, :, None])
+        if span is not None:
+            seen = seen & in_span(pos_q, positions)
+        cb = jnp.where(seen, 0.0, neg)                      # [B, TQ, T]
         if chunk_bias is not None:
             # Clamped add: both masks bottom out at _NEG_INF, and
             # (-inf) + (-inf) would overflow the finite sentinel.
@@ -154,11 +178,18 @@ def window_attention(
         segs = []
         if win_k is not None:
             sw = _seg_scores(qb, win_k)
-            segs.append(sw + win_bias[None, :, None, :])
+            if span is None:
+                segs.append(sw + win_bias[None, :, None, :])
+            else:
+                # Slot s of the window holds position s.
+                segs.append(sw + per_query(jnp.where(
+                    in_span(pos_q, jnp.broadcast_to(s_idx, (b, s))),
+                    win_bias[:, None, :], neg), tq))
         if ring_k is not None:
-            rb = jnp.where(
-                ring_pos[:, None, :] < pos_q[:, :, None], 0.0, neg
-            )                                               # [B, TQ, R]
+            seen = ring_pos[:, None, :] < pos_q[:, :, None]
+            if span is not None:
+                seen = seen & in_span(pos_q, ring_pos)
+            rb = jnp.where(seen, 0.0, neg)                  # [B, TQ, R]
             sr = _seg_scores(qb, ring_k)
             rb4 = jnp.broadcast_to(
                 rb[:, None, :, :], (b, g, tq, rb.shape[-1])
@@ -320,14 +351,29 @@ def attend(
     *,
     scale: Optional[float] = None,      # None: Dh ** -0.5
     value_dim: Optional[int] = None,    # latent rows (``v`` None) only
+    span: Optional[jax.Array] = None,   # [] int32: this layer's span
 ) -> jax.Array:
     """Causal attention of a chunk over itself and whatever ``view`` holds,
     by the kernel that fits: [B, T, H, Dh] in q.dtype.
+
+    ``span`` (see the module docstring): a traced scalar of the LAYER, so
+    that one scan holds bounded and unbounded layers (``NO_SPAN`` for the
+    latter); every query sees the ``span`` newest keys up to itself. None
+    is static: the programs of a model without one. K/V rows on one chip
+    only: latent rows, a sharded pool, an int8 pool, the sequence-parallel
+    ring and the speculative tree raise here and are refused at start
+    (engine/config.py:refuse_what_a_span_cannot_follow).
 
     ``v`` None: LATENT rows (models/config.py:LatentKVSpec). ``k`` is then
     the chunk's rows [B, T, 1, W], every view part holds such rows, ``q``
     [B, T, H, W] is zero past the key's lanes, and the values are the rows'
     first ``value_dim`` lanes: [B, T, H, value_dim]."""
+    if span is not None and (
+            v is None or view.tp_mesh is not None or view.sp_mesh is not None
+            or view.k_scale is not None or view.chunk_bias is not None):
+        raise ValueError(
+            "attend: a span over latent rows, a sharded or int8 pool, the "
+            "sequence-parallel ring or a speculative tree has no execution")
     if v is None:
         return _attend_latent(q, k, positions, chunk_lens, view, layer,
                               scale, value_dim)
@@ -377,7 +423,7 @@ def attend(
         )
     if view.pool_k is not None and t > 1:
         return _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view,
-                                       layer)
+                                       layer, span)
     if view.pool_k is not None:
         # Paged decode (T == 1): the pool segment runs in the Pallas
         # flash-decode kernel directly against this layer of the stacked HBM
@@ -406,6 +452,8 @@ def attend(
                 view.kv_lens, layer,
                 block_size=view.block_size, interpret=view.interpret,
                 k_scale=view.k_scale, v_scale=view.v_scale,
+                # The first slot of the pool the row's query still sees.
+                kv_lo=None if span is None else positions[:, 0] - span + 1,
             )
         kc = k.transpose(2, 0, 1, 3)          # [Hkv, B, 1, Dh] current token
         vc = v.transpose(2, 0, 1, 3)
@@ -413,9 +461,10 @@ def attend(
         if view.ring_k is not None:
             keys = jnp.concatenate([view.ring_k, kc], axis=2)
             vals = jnp.concatenate([view.ring_v, vc], axis=2)
-            ring_bias = jnp.where(
-                view.ring_pos < positions, 0.0, jnp.float32(_NEG_INF)
-            )                                                      # [B, R]
+            seen = view.ring_pos < positions
+            if span is not None:
+                seen = seen & (positions - view.ring_pos < span)
+            ring_bias = jnp.where(seen, 0.0, jnp.float32(_NEG_INF))  # [B, R]
             bias = jnp.concatenate([ring_bias, self_bias], axis=1)
         else:
             keys, vals, bias = kc, vc, self_bias
@@ -426,8 +475,27 @@ def attend(
         q, k, v, positions, chunk_lens,
         view.win_k, view.win_v, view.win_len,
         view.ring_k, view.ring_v, view.ring_pos,
-        chunk_bias=view.chunk_bias,
+        chunk_bias=view.chunk_bias, span=span,
     )
+
+
+# What a layer with no bound hands ``attend`` where another layer of the same
+# scan has one: no position reaches it.
+NO_SPAN = 1 << 30
+
+
+def keys_in_span(start, length, span):
+    """Keys that ``length`` tokens at positions ``start`` on see under
+    ``span`` (themselves included), summed: the closed form the engine's
+    counters use (exact host integers or arrays). The token at position p
+    sees min(p + 1, span)."""
+    start, length, span = (np.asarray(x, np.int64)
+                           for x in (start, length, span))
+    end = start + length
+    # Positions below span - 1 see p + 1 keys, the others span.
+    ramp_end = np.clip(span - 1, start, end)
+    ramp = (ramp_end * (ramp_end + 1) - start * (start + 1)) // 2
+    return ramp + (end - ramp_end) * span
 
 
 def prefill_kernel_covers(
@@ -472,9 +540,11 @@ def prefill_kernel_covers(
         t, num_heads, num_kv_heads, head_dim, itemsize, block_size)
 
 
-def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
+def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer,
+                            span=None):
     """A prefill chunk (T > 1) over a view that holds the POOL: each row's
     history is the pool's slots below ``view.kv_lens`` by its block table.
+    ``span`` rides both executions as their last operand where there is one.
 
     One algorithm, two executions, chosen HERE by the platform the program
     is LOWERED for (``lax.platform_dependent``: the program's, not the
@@ -513,42 +583,53 @@ def _attend_chunk_over_pool(q, k, v, positions, chunk_lens, view, layer):
             "over a pool view the prefill kernel does not cover "
             "(ops/attention.py:prefill_kernel_covers): gather a window")
 
+    bound = () if span is None else (jnp.asarray(span, jnp.int32),)
+
+    def spanned(of):
+        # The layer's span, where it rides as the last operand.
+        return of[0] if of else None
+
     def gathered(q, k, v, positions, chunk_lens, pool_k, pool_v, tables,
-                 kv_lens, layer):
+                 kv_lens, layer, *of):
         def one(x):
             return jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=True)
 
         win_k, win_v = gather_window(one(pool_k), one(pool_v), tables, bs,
                                      out_dtype=q.dtype)
         return window_attention(q, k, v, positions, chunk_lens, win_k[0],
-                                win_v[0], kv_lens)
+                                win_v[0], kv_lens, span=spanned(of))
 
-    def kernel(*args, interpret=False):
-        return paged_flash_prefill(*args, block_size=bs, interpret=interpret)
+    def kernel(q, k, v, positions, chunk_lens, pool_k, pool_v, tables,
+               kv_lens, layer, *of, interpret=False):
+        return paged_flash_prefill(
+            q, k, v, positions, chunk_lens, pool_k, pool_v, tables, kv_lens,
+            layer, block_size=bs, interpret=interpret, span=spanned(of))
 
     if packed:
         # The two executions of a PACKED row: the kernel's packed form, and
         # the same oracle over the row taken apart, a row a segment.
         def unpacked(q, k, v, seg_lens, pool_k, pool_v, tables, kv_lens,
-                     layer):
+                     layer, *of):
             rows, put_back = unpack_segments(seg_lens, t)
             positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)
             return put_back(gathered(
                 q[0][rows], k[0][rows], v[0][rows], positions, seg_lens,
-                pool_k, pool_v, tables, kv_lens, layer))[None]
+                pool_k, pool_v, tables, kv_lens, layer, *of))[None]
 
-        def packed_kernel(*args, interpret=False):
+        def packed_kernel(q, k, v, seg_lens, pool_k, pool_v, tables,
+                          kv_lens, layer, *of, interpret=False):
             return paged_flash_prefill_packed(
-                *args, block_size=bs, interpret=interpret)
+                q, k, v, seg_lens, pool_k, pool_v, tables, kv_lens, layer,
+                block_size=bs, interpret=interpret, span=spanned(of))
 
         return _kernel_or_gathered(
             view, packed_kernel, unpacked, q, k, v, view.seg_lens,
             view.pool_k, view.pool_v, view.block_tables, view.kv_lens,
-            jnp.asarray(layer, jnp.int32))
+            jnp.asarray(layer, jnp.int32), *bound)
     return _kernel_or_gathered(
         view, kernel, gathered, q, k, v, positions, chunk_lens, view.pool_k,
         view.pool_v, view.block_tables, view.kv_lens,
-        jnp.asarray(layer, jnp.int32))
+        jnp.asarray(layer, jnp.int32), *bound)
 
 
 def segment_of_token(seg_lens: jax.Array, t: int):
@@ -816,6 +897,7 @@ def paged_attention_xla(
     scale: Optional[float] = None,
     k_scale: Optional[jax.Array] = None,  # [Hkv, num_slots] (int8 pools)
     v_scale: Optional[jax.Array] = None,
+    span: Optional[jax.Array] = None,     # [] int32: the layer's span
 ) -> jax.Array:
     """Reference paged attention: gather pages, masked softmax attention.
 
@@ -853,6 +935,8 @@ def paged_attention_xla(
     key_pos = jnp.arange(s, dtype=jnp.int32)[None, :]               # [1, S]
     valid = key_pos < kv_lens[:, None]                               # [B, S]
     causal = key_pos[:, None, :] <= q_positions[:, :, None]          # [B, T, S]
+    if span is not None:
+        causal &= q_positions[:, :, None] - key_pos[:, None, :] < span
     mask = (valid[:, None, :] & causal)[:, None, None, :, :]         # [B,1,1,T,S]
     scores = jnp.where(mask, scores, _NEG_INF)
 

@@ -181,7 +181,8 @@ class _PageFetch:
             run //= 2
 
 
-def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first):
+def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first,
+                        first_of=None):
     """The decode kernels' hand-off of the superpage buffers from row to
     row: the call's superpages form ONE sequence across rows, superpage n of
     it in buffer n % NUM_BUFS, and row ``b`` (``n_super`` superpages of
@@ -189,9 +190,17 @@ def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first):
     left free, whatever their lengths were. Clears ``cleared`` once a call,
     issues the row's first superpage where no row before it did, and
     returns ``advance(s)``: puts what computes after superpage s in flight,
-    waits for s and returns its buffer."""
+    waits for s and returns its buffer. ``first_of(row)``: the superpage of
+    its pages a row begins at (a layer's span: the ones wholly behind it
+    are neither fetched nor waited for); absent, 0."""
     kv_lens_ref = fetch.kv_lens_ref
     num_rows = kv_lens_ref.shape[0]
+    if first_of is None:
+        def at(row, s):
+            return s
+    else:
+        def at(row, s):
+            return first_of(row) + s
 
     # What a row does not fetch it still computes over: whole superpages,
     # and a masked key's softmax weight (0) must not meet a non-finite value
@@ -212,7 +221,7 @@ def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first):
 
     @pl.when((kv_len > 0) & ((b == 0) | (prev_len == 0)))
     def _():
-        fetch.start(b, 0, jax.lax.rem(first, NUM_BUFS))
+        fetch.start(b, at(b, 0), jax.lax.rem(first, NUM_BUFS))
 
     def advance(s):
         n = first + s
@@ -225,16 +234,24 @@ def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first):
 
         @pl.when(jnp.logical_not(last) | (b + 1 < num_rows))
         def _():
-            fetch.start(
-                jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b),
-                jnp.where(last, 0, s + 1),
-                jax.lax.rem(n + 1, NUM_BUFS),
-            )
+            row = jnp.where(last, jnp.minimum(b + 1, num_rows - 1), b)
+            fetch.start(row, at(row, jnp.where(last, 0, s + 1)),
+                        jax.lax.rem(n + 1, NUM_BUFS))
 
-        fetch.wait(fetch.pages_of(b, s), slot)
+        fetch.wait(fetch.pages_of(b, at(b, s)), slot)
         return slot
 
     return advance
+
+
+def _one_more_prefetched(kernel, prefetched: int, keyword: str):
+    """``kernel`` with ONE more scalar-prefetch ref behind the ``prefetched``
+    it has, handed on as ``keyword``."""
+    def one_more(*refs):
+        kernel(*refs[:prefetched], *refs[prefetched + 1:],
+               **{keyword: refs[prefetched]})
+
+    return one_more
 
 
 def _decode_kernel(
@@ -257,6 +274,7 @@ def _decode_kernel(
     scale: float,
     quantized: bool,
     super_tokens: int,
+    lo_ref=None,        # SMEM [B] int32: a row's first visible key (a span)
 ):
     if quantized:
         (k_sc_ref, v_sc_ref, o_ref, m_ref, l_ref,
@@ -281,6 +299,14 @@ def _decode_kernel(
     stp = super_tokens // pack          # packed rows per superpage
     kv_len = kv_lens_ref[b]
     n_super = pl.cdiv(kv_len, super_tokens)
+    first_of = None
+    if lo_ref is not None:
+        # A row under a span begins at the superpage that holds its first
+        # visible key (``lo < kv_len`` in every live row: the wrapper's).
+        def first_of(row):
+            return lo_ref[row] // super_tokens
+
+        n_super = n_super - first_of(b)
     # The call's superpages before this row's (``_superpage_sequence``).
     first = jnp.where(b == 0, 0, fetched_ref[0])
     fetched_ref[0] = first + n_super
@@ -293,11 +319,14 @@ def _decode_kernel(
         block_size=bs, super_tokens=super_tokens, layer=layer,
         block_tables_ref=block_tables_ref, kv_lens_ref=kv_lens_ref)
 
-    advance = _superpage_sequence(fetch, v_buf, b, kv_len, n_super, first)
+    advance = _superpage_sequence(fetch, v_buf, b, kv_len, n_super, first,
+                                  first_of)
 
     def body(s, carry):
         m, l, acc = carry
         slot = advance(s)
+        if lo_ref is not None:
+            s = s + first_of(b)          # the superpage among the row's own
         k_sup = k_buf[slot]   # [Hkv, S/PACK, Dh*PACK] — head-major: batch
         v_sup = v_buf[slot]   # dim leads, so NO per-superpage relayout.
 
@@ -324,7 +353,12 @@ def _decode_kernel(
             pos = s * super_tokens + pack * jax.lax.broadcasted_iota(
                 jnp.int32, (1, 1, stp), 2
             ) + f
-            scores = jnp.where(pos < kv_len, scores, -jnp.inf)
+            live = pos < kv_len
+            if lo_ref is not None:
+                # The first superpage's keys before the span's bound (it
+                # holds at least one visible key, so the max stays finite).
+                live = live & (pos >= lo_ref[b])
+            scores = jnp.where(live, scores, -jnp.inf)
             s_parts.append(scores)
             m_parts.append(jnp.max(scores, axis=-1, keepdims=True))
 
@@ -385,8 +419,16 @@ def paged_flash_decode_stats(
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [L, Hkv, num_slots] — int8 pools
     v_scale: Optional[jax.Array] = None,
+    kv_lo: Optional[jax.Array] = None,    # [B] int32: first visible key
 ) -> tuple:
     """Pool-segment flash decode for one layer of the stacked pool.
+
+    ``kv_lo`` (a layer's span, ops/attention.py): row b attends the pool's
+    slots ``kv_lo[b] <= j < kv_lens[b]`` and no others. It starts at the
+    superpage that holds slot ``kv_lo[b]``: the superpages wholly behind it
+    are neither fetched nor scored, and the slots of that first superpage
+    that lie before ``kv_lo[b]`` are masked. A row with ``kv_lo >= kv_lens``
+    is an empty row. Absent (static), the program is the unbounded one.
 
     Returns (out [B, H, Dh] normalized, m [B, H] f32, l [B, H] f32) so the
     caller can merge with other attention segments (see
@@ -419,6 +461,13 @@ def paged_flash_decode_stats(
     quantized = k_scale is not None
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     sup = super_tokens(hkv, dh, k_pool.dtype.itemsize, block_size)
+    bound = ()
+    if kv_lo is not None:
+        assert not quantized, "a span over an int8 pool is refused at start"
+        kv_lo = jnp.maximum(kv_lo.astype(jnp.int32), 0)
+        # No visible key in the pool: an empty row (fetches nothing).
+        kv_lens = jnp.where(kv_lo < kv_lens, kv_lens, 0)
+        bound = (kv_lo,)
 
     # Lane-pack the pool view: [L, Hkv, NS/PACK, Dh*PACK] (free reshape).
     kp = k_pool.reshape(l_, hkv, num_slots // pack, dh * pack)
@@ -463,6 +512,8 @@ def paged_flash_decode_stats(
         block_size=block_size, num_kv_heads=hkv, q_per_kv=g,
         scale=float(scale), quantized=quantized, super_tokens=sup,
     )
+    if kv_lo is not None:
+        kernel = _one_more_prefetched(kernel, 3, "lo_ref")
 
     def resident(*shape):
         # The whole array is one block that every program sees: copied in
@@ -471,7 +522,7 @@ def paged_flash_decode_stats(
                             memory_space=pltpu.VMEM)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(bound),
         grid=(b,),
         in_specs=[
             resident(b, h, dh),
@@ -514,7 +565,7 @@ def paged_flash_decode_stats(
         interpret=interpret,
     )(
         layer,
-        block_tables, kv_lens, q, kp, vp, *sc_inputs,
+        block_tables, kv_lens, *bound, q, kp, vp, *sc_inputs,
     )
     return out, m.reshape(b, h), l.reshape(b, h)
 
@@ -842,36 +893,51 @@ def supports_pallas_prefill(t: int, num_heads: int, num_kv_heads: int,
 
 
 def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
-                   program, programs, tq, tile_rows, tiles, chunk_at=None):
+                   program, programs, tq, tile_rows, tiles, chunk_at=None,
+                   hist_from=None):
     """The prefill kernels' hand-off of the superpage buffers from program
     to program. Program (row, query block) reads its row's history
     superpages (``fetch``'s pages, ``fetch.sup`` keys each; none at kv_len
     0) and then key tiles of the chunk, ``tile_rows`` keys each, ONE copy a
     stream out of ``chunk`` (the chunk's rows in HBM, [heads, B, T, lanes] a
     stream): ``tiles(row, history tiles, block)`` in all, none where its
-    ``tq`` queries are all padding. Chunk tile c of a row is
-    ``chunk_at(row, c)`` = (row of ``chunk``, first key): the row's own
-    keys from c * tile_rows unless the caller says otherwise (a "row" of
-    the packed kernel is a SEGMENT's share of a query block, and its keys
-    lie in the one packed row from the tile that holds the segment's first
-    token). The call's tiles form one sequence across programs, tile n in
-    buffer n % NUM_BUFS, the next always in flight. Returns (history tiles,
-    tiles, ``advance``) of this program: ``advance(s)`` puts what computes
-    after tile s in flight, waits for tile s and returns its buffer."""
+    ``tq`` queries are all padding. Chunk tile c of a program is
+    ``chunk_at(row, blk, c)`` = (row of ``chunk``, first key): the row's
+    own keys from c * tile_rows unless the caller says otherwise (a "row"
+    of the packed kernel is a SEGMENT's share of a query block, and its
+    keys lie in the one packed row from the tile that holds the segment's
+    first token; under a span a block's first tile is the one that holds
+    its first query's bound). ``hist_from(row, blk)``, under a span: the
+    first history superpage the program reads (the row's count of them
+    where it reads none); absent, 0. The call's tiles form one sequence
+    across programs, tile n in buffer n % NUM_BUFS, the next always in
+    flight. Returns (history tiles, tiles, ``advance``) of this program:
+    ``advance(s)`` puts what computes after tile s in flight, waits for
+    tile s and returns its buffer."""
     (b, qb), (num_rows, nq) = program, programs
     kv_lens_ref, sup = fetch.kv_lens_ref, fetch.sup
     if chunk_at is None:
-        def chunk_at(row, c):
+        def chunk_at(row, blk, c):
             return row, c * tile_rows
 
-    def hist_tiles(row):
-        return pl.cdiv(kv_lens_ref[row], sup)
+    if hist_from is None:
+        def hist_tiles(row, blk):
+            return pl.cdiv(kv_lens_ref[row], sup)
+
+        def hist_at(row, blk, s):
+            return s
+    else:
+        def hist_tiles(row, blk):
+            return pl.cdiv(kv_lens_ref[row], sup) - hist_from(row, blk)
+
+        def hist_at(row, blk, s):
+            return hist_from(row, blk) + s
 
     def tiles_of(row, blk):
         return jnp.where(blk * tq < chunk_lens_ref[row],
-                         tiles(row, hist_tiles(row), blk), 0)
+                         tiles(row, hist_tiles(row, blk), blk), 0)
 
-    n_hist = hist_tiles(b)
+    n_hist = hist_tiles(b, qb)
     n_tiles = tiles_of(b, qb)
     is_first = (b == 0) & (qb == 0)
     first = jnp.where(is_first, 0, fetched_ref[0])
@@ -882,18 +948,19 @@ def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
         # flight into buffer n % NUM_BUFS. A program with no tiles issues
         # nothing.
         slot = jax.lax.rem(n, NUM_BUFS)
-        nh = hist_tiles(row)
+        nh = hist_tiles(row, blk)
         has = s < tiles_of(row, blk)
 
         @pl.when(has & (s < nh))
         def _():
             # A history superpage: page-granular copies, as decode's.
-            fetch.start(row, s, slot)
+            fetch.start(row, hist_at(row, blk, s), slot)
 
         @pl.when(has & (s >= nh))
         def _():
             # A key tile of the chunk: contiguous.
-            fetch.start_run(chunk, *chunk_at(row, s - nh), tile_rows, slot)
+            fetch.start_run(chunk, *chunk_at(row, blk, s - nh), tile_rows,
+                            slot)
 
     # A masked key's weight (0) must not meet a non-finite value (see the
     # decode kernel): what holds values is cleared once a call; what tiles
@@ -929,11 +996,22 @@ def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
             )
 
         # By bytes, in runs of pages: a chunk tile counts as its pages.
-        fetch.wait(jnp.where(s < n_hist, fetch.pages_of(b, s),
+        fetch.wait(jnp.where(s < n_hist,
+                             fetch.pages_of(b, hist_at(b, qb, s)),
                              tile_rows // fetch.bs), slot)
         return slot
 
     return n_hist, n_tiles, advance
+
+
+def _span_behind(kernel, prefetched: int, span):
+    """(``kernel`` taking the layer's span as one more scalar-prefetch ref
+    behind the ``prefetched`` it has, the operand to pass there): the
+    kernel as it is and nothing where ``span`` is None."""
+    if span is None:
+        return kernel, ()
+    return _one_more_prefetched(kernel, prefetched, "span_ref"), (
+        jnp.maximum(jnp.asarray(span, jnp.int32), 1).reshape(1),)
 
 
 def _prefill_kernel(
@@ -966,6 +1044,7 @@ def _prefill_kernel(
     super_tokens: int,
     tq: int,
     q_per_kv: int,
+    span_ref=None,      # SMEM [1] int32: the layer's span (absent: none)
 ):
     b, qb = pl.program_id(0), pl.program_id(1)
     num_rows, nq = pl.num_programs(0), pl.num_programs(1)
@@ -979,10 +1058,34 @@ def _prefill_kernel(
         super_tokens=sup, layer=layer, block_tables_ref=block_tables_ref,
         kv_lens_ref=kv_lens_ref)
     # Chunk key blocks 0..qb, TQ keys each; V is what holds values.
+    bounds = {"tiles": lambda row, hist, blk: hist + blk + 1}
+    if span_ref is not None:
+        # Under a span (token i of a row sits at position kv_len + i) a
+        # block reads from the history superpage and from the chunk tile
+        # that hold the first key its FIRST query sees; later queries'
+        # bounds lie further on and are masked.
+        span = span_ref[0]
+
+        def hist_from(row, blk):
+            n = kv_lens_ref[row]
+            lo = jnp.maximum(n + blk * tq - span + 1, 0)
+            return jnp.where(lo < n, lo // sup, pl.cdiv(n, sup))
+
+        def chunk_from(blk):
+            return jnp.maximum(blk * tq - span + 1, 0) // tq
+
+        bounds = {
+            "tiles": lambda row, hist, blk: hist + blk - chunk_from(blk) + 1,
+            "chunk_at": lambda row, blk, c: (row, (chunk_from(blk) + c) * tq),
+            "hist_from": hist_from}
     n_hist, n_tiles, advance = _tile_sequence(
         fetch, (kc_hbm, vc_hbm), chunk_lens_ref, fetched_ref, v_buf,
         program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tq,
-        tiles=lambda row, hist, blk: hist + blk + 1)
+        **bounds)
+
+    def block_positions():
+        pos_q = posq_ref[0, 0]                               # [TQ, 1]
+        return jnp.broadcast_to(pos_q[None], (g, tq, 1)).reshape(g * tq, 1)
 
     def flash_block(keys_of, mask_of):
         # One tile's keys against every head's query rows: the heads are a
@@ -1027,9 +1130,13 @@ def _prefill_kernel(
         def _():
             # History: every key below kv_len is before every query.
             def mask():
-                pos = s * sup + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, sup), 1)
-                return pos < kv_len
+                if span_ref is None:
+                    pos = s * sup + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, sup), 1)
+                    return pos < kv_len
+                pos = (hist_from(b, qb) + s) * sup \
+                    + jax.lax.broadcasted_iota(jnp.int32, (1, sup), 1)
+                return (pos < kv_len) & (block_positions() - pos < span)
 
             flash_block(lambda hk: (k_buf[slot, hk], v_buf[slot, hk]), mask)
 
@@ -1039,14 +1146,17 @@ def _prefill_kernel(
             # masks it: key position <= query position, key index <
             # chunk_len (all true below the diagonal block).
             c = s - n_hist
+            if span_ref is not None:
+                c = c + chunk_from(qb)
 
             def mask():
-                pos_q = posq_ref[0, 0]                       # [TQ, 1]
-                pos_q = jnp.broadcast_to(
-                    pos_q[None], (g, tq, 1)).reshape(g * tq, 1)
+                pos_q = block_positions()
                 idx = c * tq + jax.lax.broadcasted_iota(
                     jnp.int32, (1, tq), 1)
-                return (posk_ref[0, c] <= pos_q) & (idx < chunk_len)
+                seen = (posk_ref[0, c] <= pos_q) & (idx < chunk_len)
+                if span_ref is not None:
+                    seen = seen & (pos_q - posk_ref[0, c] < span)
+                return seen
 
             flash_block(
                 lambda hk: (k_buf[slot, hk, pl.ds(0, tq), :],
@@ -1092,6 +1202,7 @@ def paged_flash_prefill(
     block_size: int,
     scale: Optional[float] = None,
     interpret: bool = False,
+    span: Optional[jax.Array] = None,   # [] int32: the layer's span
 ) -> jax.Array:
     """Causal attention of a prefill chunk over its rows' history in the
     paged pool (slots below ``kv_lens``, read in place) and over itself:
@@ -1101,7 +1212,14 @@ def paged_flash_prefill(
     skipped by index). Rows of a padded query block are zeros; block-table
     entries past a row's live blocks, and what they point at, are never
     read. See the section comment for the design and
-    ``supports_pallas_prefill`` for the shapes."""
+    ``supports_pallas_prefill`` for the shapes.
+
+    ``span`` (ops/attention.py): a query at position i sees the keys at
+    ``i - span < j <= i`` only, and token i of a row must sit at position
+    ``kv_lens[row] + i`` (the engine's chunks do). A query block reads the
+    history from the superpage, and the chunk from the tile, that hold its
+    first query's bound: what lies wholly behind is neither fetched nor
+    scored. Absent (static), the program is the unbounded one."""
     b, t, h, dh = q.shape
     l_, hkv, num_slots, _ = k_pool.shape
     g = h // hkv
@@ -1124,6 +1242,7 @@ def paged_flash_prefill(
         _prefill_kernel, block_size=block_size, super_tokens=sup, tq=tq,
         q_per_kv=g,
     )
+    kernel, bound = _span_behind(kernel, 4, span)
     q_block = pl.BlockSpec((1, hkv, 1, m, dh),
                            lambda i, j, *_: (i, 0, j, 0, 0),
                            memory_space=pltpu.VMEM)
@@ -1131,7 +1250,7 @@ def paged_flash_prefill(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, hkv, nq, m, dh), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=4 + len(bound),
             grid=(b, nq),
             in_specs=[
                 q_block,
@@ -1165,7 +1284,7 @@ def paged_flash_prefill(
         interpret=interpret,
     )(
         layer, block_tables, kv_lens.astype(jnp.int32),
-        chunk_lens.astype(jnp.int32),
+        chunk_lens.astype(jnp.int32), *bound,
         qf, positions.reshape(b, nq, tq, 1), positions.reshape(b, nq, 1, tq),
         kc, vc, k_pool, v_pool,
     )
@@ -1507,6 +1626,7 @@ def _packed_prefill_kernel(
     super_tokens: int,
     tq: int,
     sub_block: int,
+    span_ref=None,      # SMEM [1] int32: the layer's span (absent: none)
 ):
     p, pairs = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
@@ -1522,6 +1642,26 @@ def _packed_prefill_kernel(
     def first_tile(pair):
         return start_ref[pair] // tq
 
+    hist_from = None
+    if span_ref is not None:
+        # Under a span (a segment's token i sits at position kv_len + i) a
+        # pair reads from the history superpage and from the row tile that
+        # hold the first key its FIRST query sees.
+        span = span_ref[0]
+
+        def first_query(pair):
+            return jnp.maximum(start_ref[pair], blk_ref[pair] * tq)
+
+        def hist_from(pair, _):
+            n = kv_lens_ref[pair]
+            lo = jnp.maximum(
+                n + first_query(pair) - start_ref[pair] - span + 1, 0)
+            return jnp.where(lo < n, lo // sup, pl.cdiv(n, sup))
+
+        def first_tile(pair):
+            return jnp.maximum(start_ref[pair],
+                               first_query(pair) - span + 1) // tq
+
     # A pair is a row of one block: its segment's history, then the row's
     # key tiles from the segment's first up to the diagonal.
     n_hist, n_tiles, advance = _tile_sequence(
@@ -1529,7 +1669,8 @@ def _packed_prefill_kernel(
         program=(p, 0), programs=(pairs, 1), tq=tq, tile_rows=tq,
         tiles=lambda pair, hist, _:
             hist + blk_ref[pair] - first_tile(pair) + 1,
-        chunk_at=lambda pair, c: (0, (first_tile(pair) + c) * tq))
+        chunk_at=lambda pair, _, c: (0, (first_tile(pair) + c) * tq),
+        hist_from=hist_from)
 
     # The block's flash state: begun by its first pair, written out by its
     # last (the pairs past the row's last all name the last block and read
@@ -1636,13 +1777,17 @@ def _packed_prefill_kernel(
         def _():
             # The segment's history: every key below kv_len is before
             # every query of the segment.
+            at = s if span_ref is None else hist_from(p, 0) + s
+
             def mask(idx_q, width):
-                pos = s * sup + jax.lax.broadcasted_iota(
+                pos = at * sup + jax.lax.broadcasted_iota(
                     jnp.int32, (1, width), 1)
-                return pos < kv_len
+                if span_ref is None:
+                    return pos < kv_len
+                return (pos < kv_len) & (kv_len + idx_q - start - pos < span)
 
             flash_tile(slot, hist_widths, hist_sb, mask,
-                       lambda j, size: jnp.minimum(kv_len - s * sup, sup))
+                       lambda j, size: jnp.minimum(kv_len - at * sup, sup))
 
         @pl.when(s >= n_hist)
         def _():
@@ -1653,7 +1798,10 @@ def _packed_prefill_kernel(
             def mask(idx_q, width):
                 idx_k = c * tq + jax.lax.broadcasted_iota(
                     jnp.int32, (1, width), 1)
-                return (idx_k >= start) & (idx_k <= idx_q)
+                seen = (idx_k >= start) & (idx_k <= idx_q)
+                if span_ref is not None:
+                    seen = seen & (idx_q - idx_k < span)
+                return seen
 
             flash_tile(slot, row_widths, tq, mask,
                        lambda j, size: jnp.where(c < blk, tq, (j + 1) * size))
@@ -1723,6 +1871,7 @@ def paged_flash_prefill_packed(
     scale: Optional[float] = None,
     interpret: bool = False,
     sub_block: Optional[int] = None,
+    span: Optional[jax.Array] = None,   # [] int32: the layer's span
 ) -> jax.Array:
     """``paged_flash_prefill`` of a PACKED row: segment i is the row's
     tokens [sum(seg_lens[:i]), sum(seg_lens[:i + 1])), a chunk of a
@@ -1734,7 +1883,10 @@ def paged_flash_prefill_packed(
     first; tokens past the last are padding, and what the kernel writes
     there is finite and means nothing. See the section comment;
     ``supports_packed_prefill`` for the shapes; ``sub_block`` (tokens)
-    overrides ``packed_sub_block`` for tests and sweeps."""
+    overrides ``packed_sub_block`` for tests and sweeps. ``span``: as
+    ``paged_flash_prefill``'s, a segment's token i at position
+    ``kv_lens[segment] + i``; a (segment, query block) pair reads from the
+    history superpage and the row tile that hold its first query's bound."""
     _, t, h, dh = q.shape
     hkv = k_pool.shape[1]
     g = h // hkv
@@ -1760,6 +1912,7 @@ def paged_flash_prefill_packed(
         _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
         tq=tq, sub_block=sb,
     )
+    kernel, bound = _span_behind(kernel, 7, span)
     # A pair's blocks of q and the output are its query block's: resident
     # while the block's pairs follow one another.
     q_block = pl.BlockSpec(
@@ -1770,7 +1923,7 @@ def paged_flash_prefill_packed(
         kernel,
         out_shape=jax.ShapeDtypeStruct((1, hkv, nq, g, tq, dh), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=7 + len(bound),
             grid=(pairs,),
             in_specs=[
                 q_block,
@@ -1800,7 +1953,7 @@ def paged_flash_prefill_packed(
         interpret=interpret,
     )(
         layer, block_tables[seg], kv_lens.astype(jnp.int32)[seg] * (tokens > 0),
-        tokens, blk, start, end,
+        tokens, blk, start, end, *bound,
         qf, kc, vc, k_pool, v_pool,
     )
     out = out.transpose(0, 2, 4, 1, 3, 5)         # [1, NQ, TQ, Hkv, G, Dh]

@@ -50,6 +50,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "decode_rows_first_total", "decode_rows_joined_total",
                 "prefill_tokens_issued_total", "prefill_tokens_padded_total",
                 "prefill_rows_issued_total", "prefill_segments_total",
+                "attn_keys_in_span_total", "attn_keys_held_total",
                 "prefill_left_waiting_total",
                 "prefill_stop_rows_total", "prefill_stop_seqs_total",
                 "prefill_stop_tokens_total", "prefill_stop_window_total",
@@ -409,6 +410,17 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:prefill_segments_total counter",
         f"pstpu:prefill_segments_total{label} "
         f"{s['prefill_segments_total']}",
+        "# HELP pstpu:attn_keys_in_span_total Keys the attention layers' "
+        "queries see inside their layer's span, summed over layers, for "
+        "every prefill token at issue and every delivered decode row-step "
+        "(0 for a model without a bounded layer)",
+        "# TYPE pstpu:attn_keys_in_span_total counter",
+        f"pstpu:attn_keys_in_span_total{label} "
+        f"{s['attn_keys_in_span_total']}",
+        "# HELP pstpu:attn_keys_held_total The same with no layer bounded: "
+        "the keys the one block table holds for those queries",
+        "# TYPE pstpu:attn_keys_held_total counter",
+        f"pstpu:attn_keys_held_total{label} {s['attn_keys_held_total']}",
         "# HELP pstpu:prefill_left_waiting_total Requests still waiting "
         "that a prefill could have taken, summed over prefill "
         "dispatches at the end of their admission pass",

@@ -826,7 +826,8 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
     ("kanana-2-30b-a3b-d8", 7, True, False),
     ("xing4.0-29b-a4b-d7", 7, True, False),
     ("granite-4.0-h-micro", 8, True, False),
-    ("lfm2-8b-a1b-d16", 7, True, False)])
+    ("lfm2-8b-a1b-d16", 7, True, False),
+    ("trinity-mini-d8", 5, True, True)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place, packs):
     """One prefill family a (rows, t) where the history is read in place
@@ -836,7 +837,9 @@ def test_prefill_family_counts_of_the_deployments(v5e, name, families,
     1 x {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger
     than the token budget. The dense deployments' dispatches are packed
     rows since PR 46 (``prefill_packs``): the one-row column alone, 8 -> 5;
-    whoever keeps a state a row or latent rows keeps the rectangles."""
+    whoever keeps a state a row or latent rows keeps the rectangles, and
+    trinity-mini-d8 (K/V rows only, sparse experts) is the first sparse
+    model through the packed row."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
     assert r.prefill_packs is packs
@@ -1292,3 +1295,84 @@ def test_prefill_family_count_of_the_short_conv_deployment(v5e):
     fams = r.reachable_prefill_families()
     assert len(fams) == 7 and {f[3] for f in fams} == {False}
     assert all(rows * t <= 1024 for rows, t, _, _ in fams)
+
+
+# ---- trinity-mini-d8: a span inside the paged kernels (PR 47)
+# Instructions of a compiled dispatch program (two scans: the dense layers'
+# and the sparse layers', ONE attention operator each whatever the list of
+# layer kinds; kanana's 4003 and 4257 stand beside).
+AFMOE_INSTRUCTIONS = 6000
+
+
+@pytest.mark.parametrize("program", ["decode-16x32", "decode-8x32",
+                                     "prefill-1x2048", "prefill-1x128"])
+def test_bounded_span_dispatch_programs_compile_in_place_for_v5e(v5e,
+                                                                 program):
+    """The decode program at the widest and at the window's 8-row bucket and
+    the longest and the shortest packed prefill program of trinity-mini-d8's
+    envelope (deployment.json's flags, published widths, all 128 experts of
+    6 sparse layers, a span of 2048 keys in 6 of 8 layers) compile for a
+    v5e, fit its HBM beside 11.97 GB of weights and the 2.15 GB K/V pool,
+    and copy neither a pool nor an expert stack. They hold the Mosaic
+    kernels: the BOUNDED paged kernel in the dense layers' scan and in the
+    sparse one (decode, or the packed flash prefill) and the two grouped
+    matmuls."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "trinity-mini-d8")
+    assert r.kv_k.shape == r.kv_v.shape == (8, 4, 8192 * 16, 128)
+    assert r.prefill_reads_pool and r.prefill_packs and r.fwd_stats
+    assert r.span_report() == {"span_layers": [0, 1, 2, 4, 5, 6],
+                               "span": 2048}
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    sparse = aparams["layers"]["sparse"]
+    assert sparse["w_gate_up"].shape == (6, 128, 2048, 2048)
+    assert sparse["w_router"].dtype == jnp.float32
+    decode = program.startswith("decode")
+    rows, t = (int(x) for x in program.split("-")[1].split("x"))
+    if decode:
+        lowered = r._lower_decode(aparams, rows, full_mb, t, False)
+    else:
+        assert (rows, t, full_mb, False) in r.reachable_prefill_families()
+        lowered = r._lower_prefill(aparams, rows, t, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    experts = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for k in (
+        "w_gate_up", "we_down") for shape in (
+            sparse[k].shape, (6 * 128, *sparse[k].shape[2:]))]
+    assert pool_copies(text, [r.kv_k, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert ("%paged_flash_decode" in text) == decode
+    assert ("paged_flash_prefill_packed" in text) == (not decode)
+    if not decode:
+        assert prefill_attn_path(text) == "pallas"
+    for scope in ("embed", "attn_proj", "attn_core", "attn_span", "ffn",
+                  "moe_route", "moe_experts", "moe_gmm", "moe_shared",
+                  "logits", "kv_write", "sample"):
+        assert f"/{scope}/" in text, scope
+    instructions = sum(1 for ln in text.splitlines() if " = " in ln)
+    assert instructions < AFMOE_INSTRUCTIONS, instructions
+    mem = compiled.memory_analysis()
+    # Weights 11.97 GB and K/V 2.15 GB are arguments; a program's
+    # temporaries fit beside them.
+    assert 14.0e9 < mem.argument_size_in_bytes < 14.3e9
+    assert mem.temp_size_in_bytes < 0.9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_programs_of_the_bounded_span_deployment_fit_the_compile_cache(v5e):
+    """5 prefill programs (1 x {128..2048}) and the decode families of
+    trinity-mini-d8's envelope, counted before chip time; the chip machine
+    caps a configuration's compile cache at 192 MiB (PERF.md section 6,
+    PR 31 and PR 33), and a program of this family serializes to a few MB
+    (its executables measured on the chip: PERF.md section 6, PR 47)."""
+    r = _deployment_runner(v5e, "trinity-mini-d8")
+    prefill = r.reachable_prefill_families()
+    assert [f[:2] for f in prefill] == [(1, t) for t in
+                                        (128, 256, 512, 1024, 2048)]
+    assert {f[3] for f in prefill} == {False}
+    assert len(r.reachable_decode_families()) + len(prefill) <= 24

@@ -535,6 +535,19 @@ REGISTRY: Tuple[Series, ...] = (
            "row of a PACKED prefill dispatch, counted at issue; 0 while "
            "every dispatch is a rectangle (a model with per-row state or "
            "latent rows, a gathered window)"),
+    Series("pstpu:attn_keys_in_span_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Keys the attention layers' queries see inside their layer's "
+           "span (a model's sliding-window attention), summed over the "
+           "layers, for every prefill token at issue and every delivered "
+           "decode row-step at apply: exact host integers "
+           "(`ops/attention.py:keys_in_span`); 0 for a model without a "
+           "bounded layer"),
+    Series("pstpu:attn_keys_held_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "The same with no layer bounded: the keys the one block table "
+           "holds for those queries; in-span over held is the share of "
+           "the held keys the bounded kernels read"),
     Series("pstpu:prefill_left_waiting_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Requests still waiting that a prefill could have taken, at "
