@@ -1261,6 +1261,21 @@ PACKED_PREFILL_TIMING_SHAPES = [
     {"name": "packed-agent-prefix", "t": 256, "segments": 1, "slots": 16,
      "hist": (6000, 6000), "heads": 32, "kv_heads": 8, "rect": (1, 256),
      "sub_blocks": (64, 128)},
+    # PR 48: the same over LATENT rows (LATENT_PREFILL_TIMING_SHAPES'
+    # widths), cells 5 and 6's dispatch at a 1024-token budget: 8 segments
+    # of 128 tokens behind the cached system prompt beside the ``[8, 128]``
+    # rectangle of the same tokens, and 8 segments cut at random behind
+    # 64-320 tokens beside the rectangle of their first 128 each.
+    # ``key_tiles``: the kernel also at these widths of a row tile (keys;
+    # the first is ``packed_latent_tile``'s own).
+    {"name": "packed-latent-8x128", "t": 1024, "segments": 8, "slots": 8,
+     "equal": True, "hist": (64, 64), "heads": 32, "row": 640, "key": 576,
+     "values": 512, "rect": (8, 128), "sub_blocks": (16, 32),
+     "key_tiles": (256, 128, 512)},
+    {"name": "packed-latent-chat-saturated", "t": 1024, "segments": 8,
+     "slots": 8, "hist": (64, 320), "heads": 32, "row": 640, "key": 576,
+     "values": 512, "rect": (8, 128), "sub_blocks": (16,),
+     "key_tiles": (256, 128)},
 ]
 PREFILL_TIMING_CALLS = 64
 PREFILL_MAX_ABS_ERR = 2e-2     # bf16 outputs of unit-variance values
@@ -1451,6 +1466,9 @@ def prefill_child(rehearse: bool) -> int:
                      "slots": 4, "hist": (0, 40), "heads": 4, "kv_heads": 2,
                      "rect": (min(shape["rect"][0], 4), 32),
                      "sub_blocks": (16, 32)}
+            if "row" in shape:
+                shape.update(heads=16, row=256, key=192, values=128,
+                             key_tiles=(32, 64))
         entry, err = _time_packed_prefill(shape, calls, layers, interpret)
         checks.append({"shape": shape["name"], "max_abs_err": err,
                        "bound": PREFILL_MAX_ABS_ERR,
@@ -1480,16 +1498,22 @@ def _time_packed_prefill(shape, calls, layers, interpret):
     from production_stack_tpu.ops.attention import unpack_segments
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_prefill,
+        paged_flash_prefill_latent,
         paged_flash_prefill_packed,
+        paged_flash_prefill_packed_latent,
     )
 
     dh, bs = 128, 16
     rng = np.random.default_rng(46)
-    t, n, slots_n, h, hkv = (shape[k] for k in (
-        "t", "segments", "slots", "heads", "kv_heads"))
-    # Lengths that fill the row: a random cut of t into n segments.
+    latent = "row" in shape
+    t, n, slots_n, h = (shape[k] for k in (
+        "t", "segments", "slots", "heads"))
+    # Lengths that fill the row: a random cut of t into n segments (or an
+    # equal one).
     cuts = np.sort(rng.choice(np.arange(1, t), n - 1, replace=False)) \
         if n > 1 else np.zeros((0,), np.int64)
+    if shape.get("equal"):
+        cuts = np.arange(1, n) * (t // n)
     lens = np.zeros((slots_n,), np.int64)
     lens[:n] = np.diff(np.concatenate([[0], cuts, [t]]))
     hist = np.zeros((slots_n,), np.int64)
@@ -1508,39 +1532,75 @@ def _time_packed_prefill(shape, calls, layers, interpret):
     def normal(key, *dims):
         return jax.random.normal(key, dims, jnp.bfloat16)
 
-    q, k, v = (normal(keys[0], 1, t, h, dh), normal(keys[1], 1, t, hkv, dh),
-               normal(keys[2], 1, t, hkv, dh))
-    pools = (normal(keys[3], layers, hkv, pool_slots, dh),
-             normal(keys[4], layers, hkv, pool_slots, dh))
     tables = jnp.asarray(tables)
     seg_lens = jnp.asarray(lens, jnp.int32)
     kv_lens = jnp.asarray(hist, jnp.int32)
+    # ``own``: the row's operands beside q (K and V, or the latent rows).
+    if latent:
+        w, dv = shape["row"], shape["values"]
+        scale = 192 ** -0.5
+        q, own = normal(keys[0], 1, t, h, w), (normal(keys[1], 1, t, 1, w),)
+        pools = (normal(keys[3], layers, 1, pool_slots, w),)
 
-    def packed_call(sub_block):
-        def call(q, layer, k, v, seg_lens, tables, kv_lens, *pools):
-            return paged_flash_prefill_packed(
-                q, k, v, seg_lens, *pools, tables, kv_lens, layer,
-                block_size=bs, interpret=interpret, sub_block=sub_block)
-        return call
+        def packed_call(sub_block, key_tile=None):
+            def call(q, layer, rows, seg_lens, tables, kv_lens, pool):
+                return paged_flash_prefill_packed_latent(
+                    q, rows, seg_lens, pool, tables, kv_lens, layer,
+                    block_size=bs, value_dim=dv, scale=scale,
+                    interpret=interpret, sub_block=sub_block,
+                    key_tile=key_tile)
+            return call
 
-    def rectangle(q, layer, k, v, chunk_lens, tables, kv_lens, *pools):
-        positions = kv_lens[:, None] + jnp.arange(
-            q.shape[1], dtype=jnp.int32)[None]
-        return paged_flash_prefill(
-            q, k, v, positions, chunk_lens, *pools, tables, kv_lens, layer,
-            block_size=bs, interpret=interpret)
+        def rectangle(q, layer, rows, chunk_lens, tables, kv_lens, pool):
+            positions = kv_lens[:, None] + jnp.arange(
+                q.shape[1], dtype=jnp.int32)[None]
+            return paged_flash_prefill_latent(
+                q, rows, positions, chunk_lens, pool, tables, kv_lens,
+                layer, block_size=bs, value_dim=dv, scale=scale,
+                interpret=interpret)
+
+        # The output is narrower than the queries: a chained call feeds
+        # one token's back into them, in place (``prefill_child``'s way).
+        def fed_back(fn):
+            return lambda x, *a: x.at[:1, :1, :, :dv].set(fn(x, *a)[:1, :1])
+    else:
+        hkv = shape["kv_heads"]
+        q, own = normal(keys[0], 1, t, h, dh), (
+            normal(keys[1], 1, t, hkv, dh), normal(keys[2], 1, t, hkv, dh))
+        pools = (normal(keys[3], layers, hkv, pool_slots, dh),
+                 normal(keys[4], layers, hkv, pool_slots, dh))
+
+        def packed_call(sub_block, key_tile=None):
+            def call(q, layer, k, v, seg_lens, tables, kv_lens, *pools):
+                return paged_flash_prefill_packed(
+                    q, k, v, seg_lens, *pools, tables, kv_lens, layer,
+                    block_size=bs, interpret=interpret, sub_block=sub_block)
+            return call
+
+        def rectangle(q, layer, k, v, chunk_lens, tables, kv_lens, *pools):
+            positions = kv_lens[:, None] + jnp.arange(
+                q.shape[1], dtype=jnp.int32)[None]
+            return paged_flash_prefill(
+                q, k, v, positions, chunk_lens, *pools, tables, kv_lens,
+                layer, block_size=bs, interpret=interpret)
+
+        def fed_back(fn):
+            return fn
 
     # The row taken apart, a row a segment of t tokens: the check.
     rows, put_back = unpack_segments(seg_lens, t)
-    held = (k, v, seg_lens, tables, kv_lens, *pools)
+    scalars = (seg_lens, tables, kv_lens, *pools)
+    held = (*own, *scalars)
     got = jax.jit(packed_call(None))(q, 1, *held).astype(jnp.float32)
     apart = jax.jit(rectangle)(
-        q[0][rows], 1, k[0][rows], v[0][rows], *held[2:])
+        q[0][rows], 1, *(x[0][rows] for x in own), *scalars)
     want = put_back(apart.astype(jnp.float32))[None]
     err = float(jnp.max(jnp.abs(got - want))) \
         if bool(jnp.all(jnp.isfinite(got))) else float("inf")
 
     def chained(fn):
+        fn = fed_back(fn)
+
         def run(x, *held):
             def one(i, x):
                 x, args = jax.lax.optimization_barrier((x, held))
@@ -1552,7 +1612,7 @@ def _time_packed_prefill(shape, calls, layers, interpret):
     # ``rect`` tokens each, a row a sequence.
     rb, rt = shape["rect"]
     rect_lens = jnp.minimum(seg_lens[:rb], rt)
-    rect_held = (k[0][rows[:rb, :rt]], v[0][rows[:rb, :rt]], rect_lens,
+    rect_held = (*(x[0][rows[:rb, :rt]] for x in own), rect_lens,
                  tables[:rb], kv_lens[:rb], *pools)
     sec_rect = best_of(chained(rectangle),
                        (q[0][rows[:rb, :rt]], *rect_held), calls)
@@ -1566,6 +1626,11 @@ def _time_packed_prefill(shape, calls, layers, interpret):
         sec = best_of(chained(packed_call(sb)), (q, *held), calls)
         if not interpret:
             entry["packed_us"][str(sb)] = sec * 1e6
+    for tk in shape.get("key_tiles", ())[1:]:
+        sec = best_of(chained(packed_call(None, tk)), (q, *held), calls)
+        if not interpret:
+            entry.setdefault("packed_us_by_key_tile", {})[str(tk)] = \
+                sec * 1e6
     if not interpret:
         entry["rectangle_us"] = sec_rect * 1e6
         first = entry["packed_us"][str(shape["sub_blocks"][0])]

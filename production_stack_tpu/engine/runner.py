@@ -1244,13 +1244,13 @@ class ModelRunner:
 
         A row can be packed where nothing but attention ties a token to
         its sequence and the kernel can tell the segments apart: the chunk
-        reads paged K/V rows in place and the flash prefill kernel covers
-        every packed row this config can dispatch (``prefill_reads_pool``,
-        and ``prefill_kernel_covers(..., packed=True)``: not latent rows,
-        whose kernel has no packed form yet), the model keeps no per-row
-        state (a scan runs a row of ONE sequence from ONE slot), and no
-        per-row operand rides the forward (LoRA's adapter of a row, the
-        speculative draft's ring of a row)."""
+        reads its paged rows in place (K/V rows in two pools or latent
+        rows in one) and the flash prefill kernel covers every packed row
+        this config can dispatch (``prefill_reads_pool``, and
+        ``prefill_kernel_covers(..., packed=True)``), the model keeps no
+        per-row state (a scan runs a row of ONE sequence from ONE slot),
+        and no per-row operand rides the forward (LoRA's adapter of a row,
+        the speculative draft's ring of a row)."""
         mc = self.model_config
         return self.prefill_reads_pool and not self.state_specs \
             and not self.lora_stacks and not self.spec_n and all(

@@ -323,8 +323,8 @@ class KVView(NamedTuple):
     # Set: the chunk is ONE packed row ([1, T]) whose tokens are S
     # sequences' chunks end to end from token 0, ``seg_lens`` [S] tokens
     # each, live ones first; ``block_tables`` and ``kv_lens`` are then a
-    # SEGMENT each ([S, Mb], [S]). Pool views of K/V rows only
-    # (``prefill_kernel_covers(..., packed=True)``).
+    # SEGMENT each ([S, Mb], [S]). Pool views only, of K/V rows or of
+    # latent rows (``prefill_kernel_covers(..., packed=True)``).
     seg_lens: Optional[jax.Array] = None
     block_size: int = 0
     interpret: bool = False
@@ -517,13 +517,15 @@ def prefill_kernel_covers(
     (``supports_pallas_prefill``), or ``latent`` rows: one pool of one row
     a token, ``head_dim`` its width, the values its first ``value_dim``
     lanes (``supports_latent_prefill``). ``packed``: the chunk is one row
-    of several sequences' segments (``KVView.seg_lens``), which only the
-    K/V kernel has a form for (``supports_packed_prefill``). Not covered
+    of several sequences' segments (``KVView.seg_lens``), for which either
+    kernel has a form (``supports_packed_prefill``,
+    ``supports_packed_latent_prefill``: the same body). Not covered
     by choice, though the decode kernels cover them: an int8 pool (its
     scales would ride as the decode kernel's do) and a kv-head-sharded
     pool; no benchmark cell runs either."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         supports_latent_prefill,
+        supports_packed_latent_prefill,
         supports_packed_prefill,
         supports_pallas_prefill,
     )
@@ -533,7 +535,9 @@ def prefill_kernel_covers(
         return False
     itemsize = kinds.pop().itemsize
     if latent:
-        return not packed and num_kv_heads == 1 and supports_latent_prefill(
+        supports = supports_packed_latent_prefill if packed \
+            else supports_latent_prefill
+        return num_kv_heads == 1 and supports(
             t, num_heads, head_dim, value_dim, itemsize, block_size)
     supports = supports_packed_prefill if packed else supports_pallas_prefill
     return value_dim == head_dim and supports(
@@ -743,21 +747,25 @@ def _attend_latent_chunk_over_pool(q, rows, positions, chunk_lens, view,
     """``_attend_chunk_over_pool`` over ONE pool of latent rows: the same
     algorithm and two executions (ops/pallas/paged_attention.py:
     paged_flash_prefill_latent, or this layer's rows gathered and
-    ``window_attention``), the same raise at trace time on a pool view the
-    kernel does not cover."""
+    ``window_attention``), the same pair again for a PACKED row
+    (``paged_flash_prefill_packed_latent``, or the row taken apart), the
+    same raise at trace time on a pool view the kernel does not cover."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_prefill_latent,
+        paged_flash_prefill_packed_latent,
     )
 
     bs = view.block_size
     b, t, h, w = q.shape
+    packed = view.seg_lens is not None
     if not prefill_kernel_covers(
             t, h, rows.shape[2], w, value_dim, bs,
             (rows.dtype, view.pool_k.dtype), latent=True,
             scales=view.k_scale is not None,
             kv_sharded=view.tp_mesh is not None,
             ring=view.ring_k is not None,
-            chunk_bias=view.chunk_bias is not None):
+            chunk_bias=view.chunk_bias is not None,
+            packed=packed) or (packed and b != 1):
         raise ValueError(
             f"attend: a chunk of {t} tokens ({h} heads over latent rows of "
             f"{w} lanes, values {value_dim}, {rows.dtype} over a "
@@ -777,6 +785,25 @@ def _attend_latent_chunk_over_pool(q, rows, positions, chunk_lens, view,
             *args, block_size=bs, value_dim=value_dim, scale=scale,
             interpret=interpret)
 
+    if packed:
+        # As for K/V rows: the kernel's packed form, and the oracle over
+        # the row taken apart, a row a segment.
+        def unpacked(q, rows, seg_lens, pool, tables, kv_lens, layer):
+            take, put_back = unpack_segments(seg_lens, t)
+            positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)
+            return put_back(gathered(
+                q[0][take], rows[0][take], positions, seg_lens, pool,
+                tables, kv_lens, layer))[None]
+
+        def packed_kernel(*args, interpret=False):
+            return paged_flash_prefill_packed_latent(
+                *args, block_size=bs, value_dim=value_dim, scale=scale,
+                interpret=interpret)
+
+        return _kernel_or_gathered(
+            view, packed_kernel, unpacked, q, rows, view.seg_lens,
+            view.pool_k, view.block_tables, view.kv_lens,
+            jnp.asarray(layer, jnp.int32))
     return _kernel_or_gathered(
         view, kernel, gathered, q, rows, positions, chunk_lens, view.pool_k,
         view.block_tables, view.kv_lens, jnp.asarray(layer, jnp.int32))

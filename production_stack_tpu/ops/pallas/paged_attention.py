@@ -1,5 +1,6 @@
 """Pallas TPU flash kernels over the paged KV pool: decode (below) and
-prefill (the last two sections), each over K/V rows and over latent rows.
+prefill (the sections behind it: a rectangle of rows, and ONE packed row of
+several sequences' segments), each over K/V rows and over latent rows.
 
 The TPU-native replacement for the paged-attention CUDA kernels inside the
 reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
@@ -54,8 +55,11 @@ The decode kernels take T == 1: queries sit at position >= kv_len, so
 causality over the pool is exactly "attend to slots < kv_len" and no
 per-token causal mask is needed. A prefill chunk (T > 1) has kernels of its
 own on the same machinery, ``paged_flash_prefill`` over K/V rows and
-``paged_flash_prefill_latent`` over latent rows (the last two sections): the
-history from the pool up to the row's length, then the chunk causally.
+``paged_flash_prefill_latent`` over latent rows: the history from the pool up
+to the row's length, then the chunk causally; and their packed forms,
+``paged_flash_prefill_packed`` and ``paged_flash_prefill_packed_latent`` (one
+kernel body: the last two sections), for a row in which several sequences'
+chunks lie end to end.
 """
 
 import functools
@@ -1558,6 +1562,9 @@ def paged_flash_prefill_latent(
 #     finite garbage, which nothing reads.
 #   * A segment that fills whole query blocks visits what its row visits in
 #     the kernel above, tile for tile.
+#   * The body serves latent rows too (the next section): what it asks of a
+#     pool is how a query block lies in VMEM and what a page's streams are,
+#     and the two classes below say that for K/V rows and for latent rows.
 PACKED_SUB_ROWS = 256    # query rows (G x tokens) a sub-block keeps at least:
                          # 16 segments in a 2048-token row at 8 query heads
                          # a KV head took 630 / 500 / 438 / 359 us a layer
@@ -1595,6 +1602,103 @@ def supports_packed_prefill(t: int, num_heads: int, num_kv_heads: int,
     return tq % (32 // itemsize) == 0
 
 
+class _HeadMajorBlock:
+    """A packed query block over K/V rows: q, the output and the flash
+    state are a KV head's [G, TQ, .] each, the heads a loop, K and V a
+    stream each. A sub-block is a slice of the token axis, its rows (head,
+    token)."""
+
+    def __init__(self, q_ref, o_ref, bufs, state):
+        self.q_ref, self.o_ref, self.state = q_ref, o_ref, state
+        self.k_buf, self.v_buf = bufs
+        self.hkv, self.g, self.dh = (
+            self.k_buf.shape[1], q_ref.shape[3], q_ref.shape[5])
+        self.score_rows = self.g        # of one matmul, a token
+
+    def rows(self, j, size):
+        return pl.ds(pl.multiple_of(j * size, size), size)
+
+    def tokens(self, size):
+        # The token of each of a sub-block's rows, from its first.
+        return jax.lax.broadcasted_iota(
+            jnp.int32, (self.g, size, 1), 1).reshape(self.g * size, 1)
+
+    def queries(self, hk, rows, size):
+        return self.q_ref[0, hk, 0, :, rows, :].reshape(
+            self.g * size, self.dh)
+
+    def keys_values(self, slot, hk, width):
+        return (self.k_buf[slot, hk, pl.ds(0, width), :],
+                self.v_buf[slot, hk, pl.ds(0, width), :])
+
+    def get(self, ref, hk, rows, size):
+        return ref[hk, :, rows, :].reshape(self.g * size, ref.shape[-1])
+
+    def put(self, ref, hk, rows, size, x):
+        ref[hk, :, rows, :] = x.reshape(self.g, size, ref.shape[-1])
+
+    def each_head(self, fn):
+        jax.lax.fori_loop(0, self.hkv, fn, 0)
+
+    def write(self):
+        _, l_ref, acc_ref = self.state
+
+        def write(hk, carry):
+            out = acc_ref[hk] / jnp.maximum(l_ref[hk], 1e-30)
+            self.o_ref[0, hk, 0] = out.astype(self.o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, self.hkv, write, 0)
+
+
+class _TokenMajorBlock:
+    """A packed query block over LATENT rows: every head attends the one
+    row, so a sub-block's tokens x heads are ONE matmul operand, q and the
+    output in their own layout [TQ, H, .] (no transpose crosses HBM, as in
+    ``_latent_prefill_kernel``), the flash state flat [TQ * H, .], rows
+    (token, head); the one stream is keys AND values, the values the first
+    lanes of the buffer."""
+
+    def __init__(self, q_ref, o_ref, bufs, state):
+        self.q_ref, self.o_ref, self.state = q_ref, o_ref, state
+        self.kv_buf, = bufs
+        self.h, self.dv = q_ref.shape[2], o_ref.shape[-1]
+        self.score_rows = self.h        # of one matmul, a token
+
+    def rows(self, j, size):
+        # (the sub-block's tokens, its rows of the flat state)
+        m = size * self.h
+        return (pl.ds(pl.multiple_of(j * size, size), size),
+                pl.ds(pl.multiple_of(j * m, m), m))
+
+    def tokens(self, size):
+        return jax.lax.broadcasted_iota(
+            jnp.int32, (size, self.h, 1), 0).reshape(size * self.h, 1)
+
+    def queries(self, hk, rows, size):
+        return self.q_ref[0, rows[0], :, :].reshape(
+            size * self.h, self.q_ref.shape[-1])
+
+    def keys_values(self, slot, hk, width):
+        keys = self.kv_buf[slot, 0, pl.ds(0, width), :]
+        return keys, keys[:, :self.dv]
+
+    def get(self, ref, hk, rows, size):
+        return ref[rows[1], :]
+
+    def put(self, ref, hk, rows, size, x):
+        ref[rows[1], :] = x
+
+    def each_head(self, fn):
+        fn(0, 0)
+
+    def write(self):
+        _, l_ref, acc_ref = self.state
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        self.o_ref[0] = out.reshape(self.o_ref.shape[1:]).astype(
+            self.o_ref.dtype)
+
+
 def _packed_prefill_kernel(
     # scalar prefetch, one entry a PAIR (segment, query block)
     layer_ref,          # SMEM [1] int32
@@ -1605,42 +1709,53 @@ def _packed_prefill_kernel(
     start_ref,          # SMEM [P] int32: the segment's first token in the row
     end_ref,            # SMEM [P] int32: one past its last
     # inputs
-    q_ref,              # VMEM [1, Hkv, 1, G, TQ, Dh] (pre-scaled)
-    kc_hbm,             # HBM  [Hkv, 1, T, Dh]: the row's keys
-    vc_hbm,             # HBM  [Hkv, 1, T, Dh]
-    k_hbm,              # HBM  [L, Hkv, num_slots, Dh]
-    v_hbm,              # HBM  [L, Hkv, num_slots, Dh]
-    # output
-    o_ref,              # VMEM [1, Hkv, 1, G, TQ, Dh]
-    # scratch (outlives a program: buffers and flash state are handed on)
-    k_buf,              # VMEM [NUM_BUFS, Hkv, super_tokens, Dh]
-    v_buf,
-    sem_k,              # DMA sems (NUM_BUFS,)
-    sem_v,
-    fetched_ref,        # SMEM [1] int32: tiles the programs before fetched
-    m_ref,              # VMEM [Hkv, G, TQ, 1] f32: running max
-    l_ref,              # VMEM [Hkv, G, TQ, 1] f32: running sum
-    acc_ref,            # VMEM [Hkv, G, TQ, Dh] f32
-    *,
+    q_ref,              # VMEM (pre-scaled) [1, Hkv, 1, G, TQ, Dh] over K/V
+                        # rows, [1, TQ, H, W] over latent rows
+    *refs,              # N streams (K and V, or the one of latent rows):
+                        # HBM  [heads, 1, T, lanes] a stream: the row's keys
+                        # HBM  [L, heads, num_slots, lanes] a stream: pools
+                        # output: VMEM, as q_ref ([1, TQ, H, Dv] latent)
+                        # scratch (outlives a program: buffers and flash
+                        # state are handed on), a stream each:
+                        # VMEM [NUM_BUFS, heads, super_tokens, lanes],
+                        # DMA sems (NUM_BUFS,); then
+                        # SMEM [1] int32: tiles the programs before fetched
+                        # VMEM f32 running max, running sum [.., 1] and
+                        # accumulator: [Hkv, G, TQ, .] or [TQ * H, .]
     block_size: int,
     super_tokens: int,
     tq: int,
     sub_block: int,
+    tile_rows: int,     # keys a tile of the ROW holds: TQ, or whole TQs
     span_ref=None,      # SMEM [1] int32: the layer's span (absent: none)
 ):
+    """ONE body for both pools: what differs between K/V rows and latent
+    rows is how a query block lies in VMEM (``_HeadMajorBlock``,
+    ``_TokenMajorBlock``) and how many streams a page is."""
     p, pairs = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
-    bs, sup, sb = block_size, super_tokens, sub_block
-    hkv, g, dh = k_buf.shape[1], q_ref.shape[3], q_ref.shape[5]
+    bs, sup, sb, tk = block_size, super_tokens, sub_block, tile_rows
+    ns = (len(refs) - 5) // 4                     # streams
+    chunk, pools, o_ref = refs[:ns], refs[ns:2 * ns], refs[2 * ns]
+    bufs, sems = refs[2 * ns + 1:3 * ns + 1], refs[3 * ns + 1:4 * ns + 1]
+    fetched_ref, m_ref, l_ref, acc_ref = refs[4 * ns + 1:]
+    block = (_TokenMajorBlock if ns == 1 else _HeadMajorBlock)(
+        q_ref, o_ref, bufs, (m_ref, l_ref, acc_ref))
     kv_len = kv_lens_ref[p]
     blk, start, end = blk_ref[p], start_ref[p], end_ref[p]
     fetch = _PageFetch(
-        [(k_hbm, k_buf, sem_k), (v_hbm, v_buf, sem_v)], bs, block_size=bs,
+        list(zip(pools, bufs, sems)), bs, block_size=bs,
         super_tokens=sup, layer=layer, block_tables_ref=block_tables_ref,
         kv_lens_ref=kv_lens_ref)
 
     def first_tile(pair):
-        return start_ref[pair] // tq
+        return start_ref[pair] // tk
+
+    def last_tile(pair):
+        # The tile that holds the pair's block (whole: TQ divides it).
+        # Where a tile IS a block (K/V rows) this and ``keys_seen`` below
+        # keep their shorter spelling: those programs are pinned by text.
+        return blk_ref[pair] if tk == tq else blk_ref[pair] * tq // tk
 
     hist_from = None
     if span_ref is not None:
@@ -1660,16 +1775,17 @@ def _packed_prefill_kernel(
 
         def first_tile(pair):
             return jnp.maximum(start_ref[pair],
-                               first_query(pair) - span + 1) // tq
+                               first_query(pair) - span + 1) // tk
 
     # A pair is a row of one block: its segment's history, then the row's
-    # key tiles from the segment's first up to the diagonal.
+    # key tiles from the segment's first up to the diagonal. What holds
+    # values is the last stream's buffers.
     n_hist, n_tiles, advance = _tile_sequence(
-        fetch, (kc_hbm, vc_hbm), live_ref, fetched_ref, v_buf,
-        program=(p, 0), programs=(pairs, 1), tq=tq, tile_rows=tq,
+        fetch, chunk, live_ref, fetched_ref, bufs[-1],
+        program=(p, 0), programs=(pairs, 1), tq=tq, tile_rows=tk,
         tiles=lambda pair, hist, _:
-            hist + blk_ref[pair] - first_tile(pair) + 1,
-        chunk_at=lambda pair, _, c: (0, (first_tile(pair) + c) * tq),
+            hist + last_tile(pair) - first_tile(pair) + 1,
+        chunk_at=lambda pair, _, c: (0, (first_tile(pair) + c) * tk),
         hist_from=hist_from)
 
     # The block's flash state: begun by its first pair, written out by its
@@ -1700,9 +1816,8 @@ def _packed_prefill_kernel(
         # and takes it in sub-blocks of ``whole_sb`` tokens, wide enough
         # to cost what the block costs the kernel above.
         def sub(size, j, carry):
-            rows = pl.ds(pl.multiple_of(j * size, size), size)
-            idx_q = base + j * size + jax.lax.broadcasted_iota(
-                jnp.int32, (g, size, 1), 1).reshape(g * size, 1)
+            rows = block.rows(j, size)
+            idx_q = base + j * size + block.tokens(size)
             own = (idx_q >= start) & (idx_q < end)
             seen = keys_seen(j, size)
 
@@ -1710,17 +1825,16 @@ def _packed_prefill_kernel(
                 mask = own & key_mask(idx_q, width)
 
                 def head(hk, carry):
-                    q = q_ref[0, hk, 0, :, rows, :].reshape(g * size, dh)
-                    k = k_buf[slot, hk, pl.ds(0, width), :]  # [keys, Dh]
-                    v = v_buf[slot, hk, pl.ds(0, width), :]
+                    q = block.queries(hk, rows, size)        # [M, lanes]
+                    k, v = block.keys_values(slot, hk, width)
                     scores = jax.lax.dot_general(
                         q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     )                                        # [M, keys]
                     scores = jnp.where(mask, scores, _MASKED)
-                    m_prev = m_ref[hk, :, rows, :].reshape(g * size, 1)
-                    l_prev = l_ref[hk, :, rows, :].reshape(g * size, 1)
-                    acc_prev = acc_ref[hk, :, rows, :].reshape(g * size, dh)
+                    m_prev = block.get(m_ref, hk, rows, size)
+                    l_prev = block.get(l_ref, hk, rows, size)
+                    acc_prev = block.get(acc_ref, hk, rows, size)
                     m_new = jnp.maximum(
                         m_prev, jnp.max(scores, axis=-1, keepdims=True))
                     alpha = jnp.exp(m_prev - m_new)
@@ -1732,12 +1846,12 @@ def _packed_prefill_kernel(
                         dimension_numbers=(((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32,
                     )
-                    l_ref[hk, :, rows, :] = l_new.reshape(g, size, 1)
-                    acc_ref[hk, :, rows, :] = acc_new.reshape(g, size, dh)
-                    m_ref[hk, :, rows, :] = m_new.reshape(g, size, 1)
+                    block.put(l_ref, hk, rows, size, l_new)
+                    block.put(acc_ref, hk, rows, size, acc_new)
+                    block.put(m_ref, hk, rows, size, m_new)
                     return carry
 
-                jax.lax.fori_loop(0, hkv, head, 0)
+                block.each_head(head)
 
             below = 0
             for width in widths:
@@ -1764,10 +1878,11 @@ def _packed_prefill_kernel(
     # sub-blocks, where those are wider). A whole block takes a history
     # tile PACKED_HISTORY_ROWS query rows at a time and a row tile at once.
     hist_widths = [w for w in (LANES, 2 * LANES) if w < sup] + [sup]
-    step = max(sb, tq // 4)
-    row_widths = list(range(step, tq + 1, step))
+    step = max(sb, tk // 4)
+    row_widths = list(range(step, tk + 1, step))
     hist_sb = tq
-    while hist_sb // 2 >= sb and g * hist_sb > PACKED_HISTORY_ROWS:
+    while hist_sb // 2 >= sb \
+            and block.score_rows * hist_sb > PACKED_HISTORY_ROWS:
         hist_sb //= 2
 
     def tile(s, carry):
@@ -1796,15 +1911,19 @@ def _packed_prefill_kernel(
             c = first_tile(p) + s - n_hist
 
             def mask(idx_q, width):
-                idx_k = c * tq + jax.lax.broadcasted_iota(
+                idx_k = c * tk + jax.lax.broadcasted_iota(
                     jnp.int32, (1, width), 1)
                 seen = (idx_k >= start) & (idx_k <= idx_q)
                 if span_ref is not None:
                     seen = seen & (idx_q - idx_k < span)
                 return seen
 
-            flash_tile(slot, row_widths, tq, mask,
-                       lambda j, size: jnp.where(c < blk, tq, (j + 1) * size))
+            def keys_seen(j, size):
+                if tk == tq:
+                    return jnp.where(c < blk, tq, (j + 1) * size)
+                return jnp.minimum(base + (j + 1) * size - c * tk, tk)
+
+            flash_tile(slot, row_widths, tq, mask, keys_seen)
 
         return carry
 
@@ -1812,12 +1931,7 @@ def _packed_prefill_kernel(
 
     @pl.when((p + 1 == pairs) | (blk_ref[jnp.minimum(p + 1, pairs - 1)] != blk))
     def _():
-        def write(hk, carry):
-            out = acc_ref[hk] / jnp.maximum(l_ref[hk], 1e-30)
-            o_ref[0, hk, 0] = out.astype(o_ref.dtype)
-            return carry
-
-        jax.lax.fori_loop(0, hkv, write, 0)
+        block.write()
 
 
 def packed_pairs(seg_lens: jax.Array, nq: int, tq: int):
@@ -1910,7 +2024,7 @@ def paged_flash_prefill_packed(
 
     kernel = functools.partial(
         _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
-        tq=tq, sub_block=sb,
+        tq=tq, sub_block=sb, tile_rows=tq,
     )
     kernel, bound = _span_behind(kernel, 7, span)
     # A pair's blocks of q and the output are its query block's: resident
@@ -1958,3 +2072,134 @@ def paged_flash_prefill_packed(
     )
     out = out.transpose(0, 2, 4, 1, 3, 5)         # [1, NQ, TQ, Hkv, G, Dh]
     return out.reshape(1, t, h, dh)
+
+
+# ------------------------------------------ prefill, a packed row, latent rows
+# The packed row over a LATENT pool: the kernel above with one stream (a
+# tile is keys AND values, cleared whole) and the query block in the latent
+# rectangle kernel's layout (``_TokenMajorBlock``). What follows from one row
+# for every head, as there: TQ is 32 tokens at 32 heads of 640 lanes, too
+# few keys for a tile of the row (the flash state's round trip, M x
+# value_dim float32, for 32 keys), so a row tile is ``packed_latent_tile``
+# keys, whole query blocks of them: a pair reads from the tile that holds
+# its segment's first token to the one that holds its block, and a
+# sub-block the keys up to its own end.
+PACKED_LATENT_TILE = 256     # keys a row tile holds, a whole TQ where that
+                             # is wider: 8 segments cut at random in a
+                             # 1024-token row behind 124-271 tokens took
+                             # 533 / 467 us a layer at 128 / 256 keys on a
+                             # v5e, 8 x 128 behind 64 took 387 / 388 / 423
+                             # at 128 / 256 / 512 (PERF.md section 6, PR 48)
+
+
+def packed_latent_tile(t: int, tq: int) -> int:
+    """Keys a row tile of the packed latent kernel holds."""
+    return max(tq, min(t, PACKED_LATENT_TILE))
+
+
+def supports_packed_latent_prefill(t: int, num_heads: int, width: int,
+                                   value_dim: int, itemsize: int,
+                                   block_size: int) -> bool:
+    """What ``supports_latent_prefill`` asks, query blocks of whole
+    sublane tiles (``supports_packed_prefill``'s reason) and a row of whole
+    key tiles of whole query blocks."""
+    if not supports_latent_prefill(t, num_heads, width, value_dim, itemsize,
+                                   block_size):
+        return False
+    _, tq = prefill_tiles(t, num_heads, 1, width, itemsize, block_size)
+    tk = packed_latent_tile(t, tq)
+    return tq % (32 // itemsize) == 0 and tk % tq == 0 and t % tk == 0
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "value_dim", "scale", "interpret",
+                     "sub_block", "key_tile"),
+)
+def paged_flash_prefill_packed_latent(
+    q: jax.Array,             # [1, T, H, W] absorbed queries, zeros past the key
+    rows: jax.Array,          # [1, T, 1, W] the row's latent rows
+    seg_lens: jax.Array,      # [S] int32 tokens of each segment of the row
+    kv_pool: jax.Array,       # [L, 1, num_slots, W] latent rows
+    block_tables: jax.Array,  # [S, Mb] int32, a segment each
+    kv_lens: jax.Array,       # [S] int32: the segment's tokens in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    value_dim: int,
+    scale: float,
+    interpret: bool = False,
+    sub_block: Optional[int] = None,
+    key_tile: Optional[int] = None,
+) -> jax.Array:
+    """``paged_flash_prefill_packed`` over a latent pool, which is
+    ``paged_flash_prefill_latent`` of a PACKED row: segment i is the row's
+    tokens [sum(seg_lens[:i]), sum(seg_lens[:i + 1])), its history the
+    pool's slots below ``kv_lens[i]`` by ``block_tables[i]``; every head
+    attends the segment's history and the segment causally, keys the whole
+    row, values its first ``value_dim`` lanes: [1, T, H, value_dim] in
+    q.dtype, a segment's tokens equal to ``paged_flash_prefill_latent`` of
+    the segment as a row of its own. Live segments come first; tokens past
+    the last are padding (finite, meaning nothing; a query block no segment
+    reaches is zeros). See the section comments and
+    ``supports_packed_latent_prefill``; ``sub_block`` (tokens) and
+    ``key_tile`` (keys) override ``packed_sub_block`` and
+    ``packed_latent_tile`` for tests and sweeps."""
+    _, t, h, w = q.shape
+    itemsize = kv_pool.dtype.itemsize
+    sup, tq = prefill_tiles(t, h, 1, w, itemsize, block_size)
+    sb = sub_block or packed_sub_block(tq, h, itemsize)
+    tk = key_tile or packed_latent_tile(t, tq)
+    nq, m = t // tq, h * tq
+    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
+    seg, blk, start, end, tokens = packed_pairs(
+        seg_lens.astype(jnp.int32), nq, tq)
+    # Scaled as window_attention scales.
+    qf = (q.astype(jnp.float32) * scale).astype(kv_pool.dtype)
+    chunk = rows.transpose(2, 0, 1, 3).astype(kv_pool.dtype)  # [1, 1, T, W]
+
+    kernel = functools.partial(
+        _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
+        tq=tq, sub_block=sb, tile_rows=tk,
+    )
+
+    def block(lanes):
+        # A pair's blocks of q and the output are its query block's.
+        return pl.BlockSpec(
+            (1, tq, h, lanes),
+            lambda i, layer, bt, lens, live, blk, *_: (0, blk[i], 0, 0),
+            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((1, t, h, value_dim), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(seg.shape[0],),
+            in_specs=[
+                block(w),
+                pl.BlockSpec(memory_space=pl.ANY),   # the row's rows and
+                pl.BlockSpec(memory_space=pl.ANY),   # the pool stay in HBM
+            ],
+            out_specs=block(value_dim),
+            scratch_shapes=[
+                pltpu.VMEM((NUM_BUFS, 1, sup, w), kv_pool.dtype),
+                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((m, 1), jnp.float32),
+                pltpu.VMEM((m, 1), jnp.float32),
+                pltpu.VMEM((m, value_dim), jnp.float32),
+            ],
+        ),
+        # Programs run in order: each hands its buffers to the next.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=PREFILL_VMEM_BYTES,
+        ),
+        name="paged_flash_prefill_packed_latent",
+        interpret=interpret,
+    )(
+        layer, block_tables[seg], kv_lens.astype(jnp.int32)[seg] * (tokens > 0),
+        tokens, blk, start, end,
+        qf, chunk, kv_pool,
+    )

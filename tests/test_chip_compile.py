@@ -677,7 +677,7 @@ def test_grouped_matmul_compiles_for_v5e(v5e, pairs, k, n):
                 and " copy(" in ln]
 
 
-@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128"])
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-1x1024"])
 def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     """The decode and the fullest prefill program of kanana-2-30b-a3b-d8's
     envelope (deployment.json's flags, published widths, all 128 experts of
@@ -703,7 +703,7 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
-        lowered = r._lower_prefill(aparams, 8, 128, full_mb, False)
+        lowered = r._lower_prefill(aparams, 1, 1024, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
     experts = [jax.ShapeDtypeStruct((7 * 128, *sparse[k].shape[2:]),
@@ -712,7 +712,7 @@ def test_latent_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     assert pool_copies(text, [r.kv_k, *experts]) == []
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert ("%paged_flash_decode_latent_stats" in text) == decode
-    assert ("%paged_flash_prefill_latent" in text) == (not decode)
+    assert ("%paged_flash_prefill_packed_latent" in text) == (not decode)
     mem = compiled.memory_analysis()
     # Weights 10.14 GB and the pool 2.68 GB are arguments; a latent row
     # costs a decode program no temporary of its own, and a prefill
@@ -823,8 +823,8 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
 @pytest.mark.parametrize("name,families,in_place,packs", [
     ("qwen2.5-3b", 5, True, True), ("mistral-7b-d16", 5, True, True),
     ("olmo-hybrid-7b-d16", 8, True, False),
-    ("kanana-2-30b-a3b-d8", 7, True, False),
-    ("xing4.0-29b-a4b-d7", 7, True, False),
+    ("kanana-2-30b-a3b-d8", 4, True, True),
+    ("xing4.0-29b-a4b-d7", 4, True, True),
     ("granite-4.0-h-micro", 8, True, False),
     ("lfm2-8b-a1b-d16", 7, True, False),
     ("trinity-mini-d8", 5, True, True)])
@@ -837,9 +837,14 @@ def test_prefill_family_counts_of_the_deployments(v5e, name, families,
     1 x {128..1024}, 4 x {128, 256}, 8 x 128), and no program is larger
     than the token budget. The dense deployments' dispatches are packed
     rows since PR 46 (``prefill_packs``): the one-row column alone, 8 -> 5;
-    whoever keeps a state a row or latent rows keeps the rectangles, and
-    trinity-mini-d8 (K/V rows only, sparse experts) is the first sparse
-    model through the packed row."""
+    whoever keeps a state a row keeps the rectangles, and trinity-mini-d8
+    (K/V rows only, sparse experts) is the first sparse model through the
+    packed row. The two latent deployments follow in PR 48 (the packed
+    kernel's body over one page stream): 7 -> 4, 1 x {128..1024}; their
+    4 prefill programs, compiled here for a described v5e into an empty
+    cache one variant each, are 17.2 and 21.5 MB where the 7 were 28.1
+    and 35.8 (PERF.md section 6, PR 48), a boot holds three prefill
+    families fewer, and a configuration's cache is capped at 192 MiB."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
     assert r.prefill_packs is packs
@@ -883,10 +888,18 @@ def _digest(text: str) -> str:
 @pytest.mark.parametrize("name,rows,t", sorted(_PARENT_PREFILL_TEXT))
 def test_rectangle_prefill_programs_lower_to_the_parents_text(v5e, name,
                                                               rows, t):
-    """The state-keeping and the latent deployments run PR 45's prefill
-    programs: the narrowest and the widest family of each lowers for a v5e
-    to the text it lowered to there."""
+    """The state-keeping deployments run PR 45's prefill programs, and so
+    does whatever still dispatches rectangles over latent rows: the
+    narrowest and the widest family of each lowers for a v5e to the text
+    it lowered to there."""
     r = _deployment_runner(v5e, name)
+    if r.kv_pools == 1:
+        # The latent deployments' own dispatches are packed rows since PR
+        # 48; what is held to the parent's text is the rectangle program a
+        # runner with an adapter or a draft's ring a row still builds
+        # (``prefill_packs`` false: the cached property, said for it).
+        assert r.prefill_packs
+        r.__dict__["prefill_packs"] = False
     assert not r.prefill_packs
     fams = r.reachable_prefill_families()
     fam = next(f for f in (fams[0], fams[-1]) if f[:2] == (rows, t))
@@ -931,19 +944,23 @@ def test_rectangle_prefill_kernels_trace_to_the_parents_jaxpr(kernel):
 
 @pytest.mark.parametrize("name,t", [("qwen2.5-3b", 2048),
                                     ("mistral-7b-d16", 512),
-                                    ("qwen2.5-3b", 128)])
+                                    ("qwen2.5-3b", 128),
+                                    ("kanana-2-30b-a3b-d8", 1024),
+                                    ("xing4.0-29b-a4b-d7", 1024)])
 def test_packed_prefill_programs_compile_in_place_for_v5e(v5e, name, t):
-    """A dense deployment's packed prefill program (one row of ``t`` tokens,
-    up to 16 segments) compiles for a v5e, holds the packed flash kernel
-    and no other execution of the chunk's attention, copies no pool (the
-    segments' K/V go to their slots slab by slab out of the one row) and
-    keeps the temporaries of the rectangle it replaces."""
+    """A deployment's packed prefill program (one row of ``t`` tokens, up
+    to 16 segments at a 2048-token budget, 8 at 1024) compiles for a v5e,
+    holds the packed flash kernel and no other execution of the chunk's
+    attention, copies no pool (the segments' K/V, or latent rows since PR
+    48, go to their slots slab by slab out of the one row) and keeps the
+    temporaries of the rectangle it replaces."""
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops.attention import prefill_attn_path
     from production_stack_tpu.ops.kv_write import pool_copies
 
     r = _deployment_runner(v5e, name)
-    assert r.prefill_packs and r._prefill_segs == 16
+    latent = r.kv_pools == 1
+    assert r.prefill_packs and r._prefill_segs == (8 if latent else 16)
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     assert (1, t, full_mb, False) in r.reachable_prefill_families()
@@ -952,8 +969,13 @@ def test_packed_prefill_programs_compile_in_place_for_v5e(v5e, name, t):
     text = compiled.as_text()
     assert pool_copies(text, [r.kv_k]) == []
     assert "paged_flash_prefill_packed" in text
+    assert ("%paged_flash_prefill_packed_latent" in text) is latent
+    assert "%paged_flash_prefill_latent" not in text
     assert prefill_attn_path(text) == "pallas"
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # The one kernel; where experts are routed, the dense layers' call and
+    # the sparse scan's of it, and the scan's two grouped matmuls.
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (4 if latent else 1)
     for scope in ("embed", "attn_proj", "attn_core", "ffn", "logits",
                   "kv_write", "sample"):
         assert f"/{scope}/" in text, scope
@@ -961,7 +983,7 @@ def test_packed_prefill_programs_compile_in_place_for_v5e(v5e, name, t):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
-@pytest.mark.parametrize("program", ["decode-32x32", "prefill-8x128"])
+@pytest.mark.parametrize("program", ["decode-32x32", "prefill-1x1024"])
 def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     """The decode and the fullest prefill program of xing4.0-29b-a4b-d7's
     envelope (deployment.json's flags, published widths, 2 dense + 5 sparse
@@ -991,7 +1013,7 @@ def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     if decode:
         lowered = r._lower_decode(aparams, 32, full_mb, 32, False)
     else:
-        lowered = r._lower_prefill(aparams, 8, 128, full_mb, False)
+        lowered = r._lower_prefill(aparams, 1, 1024, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
     experts = [jax.ShapeDtypeStruct((5 * 64, *sparse[k].shape[2:]),
@@ -1000,7 +1022,7 @@ def test_four_stream_dispatch_programs_compile_for_v5e(v5e, program):
     assert pool_copies(text, [r.kv_k, *experts]) == []
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert ("%paged_flash_decode_latent_stats" in text) == decode
-    assert ("%paged_flash_prefill_latent" in text) == (not decode)
+    assert ("%paged_flash_prefill_packed_latent" in text) == (not decode)
     for scope in ("attn_proj/hc_pre", "ffn/hc_pre", "attn_proj/hc_post",
                   "ffn/hc_post", "logits/hc_head"):
         assert scope in text, scope
@@ -1036,6 +1058,10 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
     from production_stack_tpu.engine.runner import _bucket
 
     r = _deployment_runner(v5e, "kanana-2-30b-a3b-d8")
+    # Since PR 48 the deployment's own dispatches are packed rows; the
+    # pinned program is the rectangle a runner with an adapter or a
+    # draft's ring a row still builds (``prefill_packs`` false).
+    r.__dict__["prefill_packs"] = False
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     text = r._lower_prefill(
@@ -1047,7 +1073,7 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
 
 def _kernel_entry_points():
     """name -> (entry point, ShapeDtypeStructs at one cell's shape, the sha1
-    of its jaxpr's text): the seven Pallas kernels of the serving path."""
+    of its jaxpr's text): the nine Pallas kernels of the serving path."""
     from production_stack_tpu.ops.pallas import gated_delta, ssd
     from production_stack_tpu.ops.pallas import paged_attention as pa
 
@@ -1087,6 +1113,25 @@ def _kernel_entry_points():
             (sds(8, 128, 32, 640, dtype=bf16), sds(8, 128, 1, 640, dtype=bf16),
              sds(8, 128, dtype=i32), sds(8, dtype=i32), latent, *tables(8)),
             "e990b7f0ab5d2c3ff8c4af2d2e746f4fb768e25c"),
+        # The packed row's kernel (PR 46), ONE body for both pools since PR
+        # 48: over K/V rows it is the program PR 47's tree traced (this
+        # hash is that tree's: it moves only if the dense cells' prefill
+        # programs do), over latent rows it is new.
+        "paged_flash_prefill_packed-qwen-1x2048": (
+            functools.partial(pa.paged_flash_prefill_packed,
+                              block_size=BLOCK_SIZE),
+            (sds(1, 2048, 16, 128, dtype=bf16),
+             sds(1, 2048, 2, 128, dtype=bf16),
+             sds(1, 2048, 2, 128, dtype=bf16), sds(16, dtype=i32), kv, kv,
+             *tables(16)),
+            "dced19ac85561731751ec092dcbe267047bbe01b"),
+        "paged_flash_prefill_packed_latent-kanana-1x1024": (
+            functools.partial(pa.paged_flash_prefill_packed_latent,
+                              **latent_kw),
+            (sds(1, 1024, 32, 640, dtype=bf16),
+             sds(1, 1024, 1, 640, dtype=bf16), sds(8, dtype=i32), latent,
+             *tables(8)),
+            "84a56b7ee25fe7b1b36640220b609e2058c0e0fb"),
         "gdn_step_in_place-olmo-32": (      # 12 layers of 30 x 96 x 192
             gated_delta.gdn_step_in_place,
             (sds(32, 12, 15, 96, 384), sds(dtype=i32), sds(32, 30, 96),
@@ -1168,6 +1213,37 @@ def test_latent_prefill_kernel_compiles_for_v5e(v5e, rows, t):
     assert compiled.out_info.shape == (rows, t, 32, 512)
 
 
+@pytest.mark.parametrize("t", [1024, 128])
+def test_packed_latent_prefill_kernel_compiles_for_v5e(v5e, t):
+    """The packed latent kernel alone at both latent deployments' shapes
+    and the widest and the narrowest row of their envelope (8 segments):
+    Mosaic takes the packed kernel's body over ONE page stream and the
+    token-major query block (a sub-block's [16 queries, 32 heads, 640] as
+    [512, 640] with no relayout), no transpose or copy of q or of the
+    output surrounds it, and the device operation's name says which
+    kernel it is."""
+    from production_stack_tpu.ops.pallas.paged_attention import (
+        paged_flash_prefill_packed_latent,
+    )
+
+    one_chip = jax.sharding.SingleDeviceSharding(v5e.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = paged_flash_prefill_packed_latent.lower(
+        sds((1, t, 32, 640), jnp.bfloat16), sds((1, t, 1, 640), jnp.bfloat16),
+        sds((8,), jnp.int32), sds((8, 1, NUM_SLOTS, 640), jnp.bfloat16),
+        sds((8, 192), jnp.int32), sds((8,), jnp.int32), sds((1,), jnp.int32),
+        block_size=BLOCK_SIZE, value_dim=512, scale=192 ** -0.5).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%paged_flash_prefill_packed_latent" in text
+    assert "%paged_flash_decode" not in text      # an operation's name
+    assert not re.search(r"= bf16\[[\d,]+\]\S* (copy|transpose)\(", text)
+    assert compiled.out_info.shape == (1, t, 32, 512)
+
+
 # (deployment, layers, the parent's temp_size_in_bytes of the windowed
 # [8, 128] program: PR 38's tree gathered 3072 keys a row and held the
 # float32 scores; measured at PR 39.)
@@ -1179,7 +1255,8 @@ LATENT_PREFILL_PROGRAMS = {
 
 @pytest.mark.parametrize("name", list(LATENT_PREFILL_PROGRAMS))
 def test_latent_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, name):
-    """The fullest prefill program of the two latent deployments, lowered
+    """The fullest prefill program of the two latent deployments (since PR
+    48 the packed row of 1024 tokens), lowered
     for a v5e as the engine lowers it: ONE family a (rows, t), its chunk
     attends through the latent flash kernel over the pool
     (``prefill_attn`` "pallas"), the pool is written in place, nothing of
@@ -1197,12 +1274,12 @@ def test_latent_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, name):
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     assert full_mb * 16 == 3072
-    fams = [f for f in r.reachable_prefill_families() if f[:2] == (8, 128)]
-    assert fams == [(8, 128, full_mb, False)]
+    fams = [f for f in r.reachable_prefill_families() if f[1] == 1024]
+    assert fams == [(1, 1024, full_mb, False)]
     compiled = r._lower_prefill(r._abstract_params(), *fams[0]).compile()
     text = compiled.as_text()
     assert prefill_attn_path(text) == "pallas"
-    assert "%paged_flash_prefill_latent" in text
+    assert "%paged_flash_prefill_packed_latent" in text
     assert "%paged_flash_decode" not in text      # an operation's name
     assert pool_copies(text, [r.kv_k]) == []
     for dims in re.findall(r"[a-z]\w*\[([\d,]+)\]", text):

@@ -345,6 +345,10 @@ def test_prefill_phase_rehearses_on_the_cpu():
     assert [(p["shape"], p["segments"], sum(p["seg_lens"]) == p["t"],
              p["rectangle_us"]) for p in line["packed"]] == [
         ("packed-chat-saturated", 3, True, None),
-        ("packed-agent-prefix", 1, True, None)]
-    assert [c["shape"] for c in line["checks"]][-2:] == \
-        ["packed-chat-saturated", "packed-agent-prefix"]
+        ("packed-agent-prefix", 1, True, None),
+        # ... and the same over latent rows (PR 48).
+        ("packed-latent-8x128", 3, True, None),
+        ("packed-latent-chat-saturated", 3, True, None)]
+    assert [c["shape"] for c in line["checks"]][-4:] == \
+        ["packed-chat-saturated", "packed-agent-prefix",
+         "packed-latent-8x128", "packed-latent-chat-saturated"]

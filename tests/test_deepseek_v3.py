@@ -228,6 +228,68 @@ def test_f_preempt_and_recompute(engine):
     assert worst(engine, seq) < TOL and worst(engine, other) < TOL
 
 
+def packed_row_against_rectangle(monkeypatch, tiny, module):
+    """Five prompts through an engine whose prefill dispatches are packed
+    rows over the latent pool (``prefill_packs``: the tiny model with heads
+    enough to fill a sublane tile in float32, on the paged path) and through
+    one made to dispatch rectangles: a prompt alone, then at once a prefix
+    hit on it, one that crosses the budget and two short ones. The packed
+    engine's log-probabilities are the reference's, and both engines serve
+    the same tokens. ``module``: the test module's ``make_engine``, ``add``,
+    ``drive``, ``worst`` (tests/test_xing4.py calls this with its own)."""
+    import dataclasses
+
+    from production_stack_tpu.models import config as models_config
+
+    name = tiny.name + "-8-heads"
+    monkeypatch.setitem(
+        models_config.NAMED_CONFIGS, name, dataclasses.replace(
+            tiny, num_heads=8, num_kv_heads=8, name=name))
+    shared = prompt(64, 80)
+    served = {}
+    for form in ("packed", "rectangle"):
+        eng = module.make_engine(model=name, attn_impl="paged",
+                                 max_num_batched_tokens=512)
+        assert eng.runner.kv_pools == 1
+        assert eng.runner.prefill_packs and eng.scheduler.prefill_packed
+        assert {f[0] for f in eng.runner.reachable_prefill_families()} == {1}
+        if form == "rectangle":
+            eng.runner.__dict__["prefill_packs"] = False
+            eng.scheduler.prefill_packed = False
+        first = module.add(eng, "g0", shared + prompt(10, 81), 3)
+        module.drive(eng)
+        seqs = [first] + [
+            module.add(eng, f"g{i + 1}", tokens, 5) for i, tokens in
+            enumerate((shared + prompt(12, 82), prompt(470, 2), prompt(5, 3),
+                       prompt(40, 4)))]
+        prefills = [b for b in module.drive(eng) if b.kind == "prefill"]
+        assert seqs[1].num_cached_tokens == 64
+        assert all(b.packed for b in prefills) is (form == "packed")
+        if form == "packed":
+            assert max(len(b.seqs) for b in prefills) == 4
+            # The prefix hit (history 64) lay in one row with first
+            # chunks, and the long prompt crossed the budget.
+            assert any(64 in b.chunk_starts and 0 in b.chunk_starts
+                       for b in prefills)
+            assert sum(seqs[2] in b.seqs for b in prefills) > 1
+            for seq in seqs:
+                assert module.worst(eng, seq) < TOL
+        served[form] = [
+            (seq.output_token_ids, [lp for lp, _ in seq.output_logprobs])
+            for seq in seqs]
+    for (toks_p, lps_p), (toks_r, lps_r) in zip(served["packed"],
+                                                served["rectangle"]):
+        assert toks_p == toks_r and len(toks_p) in (3, 5)
+        np.testing.assert_allclose(lps_p, lps_r, atol=TOL, rtol=0)
+
+
+def test_g_a_packed_prefill_row_serves_what_the_rectangle_serves(monkeypatch):
+    import sys
+
+    packed_row_against_rectangle(
+        monkeypatch, TINY_DEEPSEEK_V3, sys.modules[__name__])
+
+
 # ---- the tolerance is tight enough -------------------------------------------
 @pytest.fixture(scope="module")
 def served(engine):

@@ -431,15 +431,16 @@ async def test_engine_prefill_through_the_kernel_matches_the_gathered(
 # each begins (paged_flash_prefill_packed). The oracle is unchanged: a
 # segment's tokens are what ``window_attention`` gives the segment as a row
 # of its own.
-def _pack(c, clens, t):
-    """The rows of ``_case`` ``c`` laid end to end in one row of ``t``
-    tokens; what lies past the last is junk, but finite."""
+def _pack(c, clens, t, names=("q", "k", "v")):
+    """The rows of ``_case`` ``c`` (its operands ``names``) laid end to end
+    in one row of ``t`` tokens; what lies past the last is junk, but
+    finite."""
     def pack(x):
         row = np.concatenate(
             [np.asarray(x)[i, :cl] for i, cl in enumerate(clens)], 0)
         junk = np.full((t - row.shape[0], *row.shape[1:]), 7.0, row.dtype)
         return jnp.asarray(np.concatenate([row, junk], 0)[None])
-    return pack(c["q"]), pack(c["k"]), pack(c["v"])
+    return tuple(pack(c[name]) for name in names)
 
 
 def _packed_kernel(c, clens, t, **kw):
@@ -560,8 +561,9 @@ def test_attend_over_a_packed_view_agrees_in_both_executions():
     np.testing.assert_allclose(outs[0][0, :live], outs[1][0, :live],
                                atol=ATOL, rtol=0)
     assert not outs[0][0, live:].any()
-    # Latent rows have no packed form, and a packed view is one row.
-    assert not prefill_kernel_covers(
+    # Latent rows have their packed form since PR 48 (its twin:
+    # tests/test_paged_prefill_latent.py), and a packed view is one row.
+    assert prefill_kernel_covers(
         256, 32, 1, 640, 512, BS, (jnp.bfloat16,), latent=True, packed=True)
     with pytest.raises(ValueError, match="does not cover"):
         attend(jnp.concatenate([q, q]), jnp.concatenate([k, k]),

@@ -23,11 +23,16 @@ from production_stack_tpu.ops.attention import (
 )
 from production_stack_tpu.ops.pallas.paged_attention import (
     latent_chunk_tile,
+    packed_latent_tile,
+    packed_sub_block,
     paged_flash_prefill_latent,
+    paged_flash_prefill_packed_latent,
     prefill_tiles,
     supports_latent_prefill,
+    supports_packed_latent_prefill,
 )
 from tests.test_paged_prefill import _greedy
+from tests.test_paged_prefill import _pack as _pack_row
 
 BS, LAYER, SCALE = 16, 1, 0.0721
 ATOL = 2e-5          # what tests/test_paged_prefill.py holds the K/V kernel to
@@ -209,6 +214,12 @@ def test_prefill_kernel_covers_latent_rows():
     assert not covers(h=4, dtypes=(jnp.float32,))
     assert not covers(t=16, bs=32)          # a chunk of half a block
     assert not covers(t=768)                # not whole key tiles of 512
+    # A PACKED row (PR 48): the same, and query blocks of whole sublane
+    # tiles; the rows a deployment's envelope dispatches are covered.
+    assert all(covers(t=t, packed=True) for t in (128, 256, 512, 1024))
+    assert covers(h=8, w=256, dv=128, dtypes=(jnp.float32,), packed=True)
+    assert not covers(h=8, packed=True) and not covers(t=768, packed=True)
+    assert not covers(scales=True, packed=True)
     # K/V rows are not read as latent ones, nor latent rows as K/V.
     assert not prefill_kernel_covers(128, 32, 1, 640, 512, BS,
                                      (jnp.bfloat16,))
@@ -304,6 +315,149 @@ def test_attend_refuses_heads_that_do_not_fill_a_sublane_tile():
         _attend_jaxpr(c, _view(c, interpret=True))
 
 
+# ---------------------------------------------------------------- a packed row
+# PR 48: the sequences' chunks end to end in ONE row over the latent pool
+# (paged_flash_prefill_packed_latent: tests/test_paged_prefill.py's packed
+# kernel body with one page stream). The oracle is unchanged: a segment's
+# tokens are what ``window_attention`` gives the segment as a row of its own.
+# (heads, row width, value lanes): 16 heads take a query block of 128 tokens,
+# 32 heads one of 64, each under a row tile of 256 keys.
+HEADS16, HEADS32 = (16, 256, 128), (32, 256, 128)
+
+
+def _pack(c, clens, t):
+    return _pack_row(c, clens, t, names=("q", "rows"))
+
+
+def _packed_kernel(c, clens, t, seg_lens=None, **kw):
+    q, rows = _pack(c, clens, t)
+    return np.asarray(paged_flash_prefill_packed_latent(
+        q, rows, jnp.asarray(seg_lens or clens, jnp.int32), c["pool"],
+        c["bt"], c["kv_lens"], jnp.int32(LAYER), block_size=BS,
+        value_dim=c["dv"], scale=SCALE, interpret=True, **kw))
+
+
+@pytest.mark.parametrize("t,shape,hists,clens,kw", [
+    # A boundary inside a query block, behind no history and behind some.
+    (256, SMALL, [0, 37], [100, 120], {}),
+    # The served widths (values 512 of 640 lanes, 32 heads: 32-token query
+    # blocks under 256-key row tiles): the cached system prompt, none,
+    # and several pages; segments that start inside a block and a tile.
+    (256, SERVED, [64, 0, 300], [100, 30, 120], {}),
+    # Histories one below, at and one above a page boundary.
+    (256, HEADS16, [15, 16, 17, 64], [60, 70, 80, 30], {}),
+    # None, one and several superpages of history beside each other.
+    (512, HEADS32, [0, 500, 1100], [100, 200, 150], {}),
+    # A segment over several row tiles, between two short ones.
+    (512, HEADS32, [300, 20, 0], [40, 400, 60], {}),
+    # Slots of length 0 behind the live ones; the row's tail is padding:
+    # the end of a block, and whole blocks.
+    (512, HEADS32, [64, 64, 0, 0], [90, 30, 0, 0], {}),
+    # A tile that is a block (the K/V kernel's spelling of the diagonal),
+    # and a narrower row tile and wider sub-block than the rule's own.
+    (256, HEADS16, [5, 0, 16], [60, 70, 80], {"key_tile": 128}),
+    (512, HEADS32, [5, 0, 16], [160, 170, 180],
+     {"sub_block": 32, "key_tile": 128}),
+    # One segment that fills the row.
+    (256, HEADS16, [40], [256], {}),
+    (256, HEADS32, [700], [256], {}),
+], ids=["boundary-in-a-block", "served-widths", "page-boundaries",
+        "history-superpages", "segment-over-tiles", "empty-slots-padded-tail",
+        "tile-is-a-block", "narrow-tile-wide-sub-block",
+        "one-segment-16-heads",
+        "one-segment-32-heads"])
+@pytest.mark.parametrize("starts", ["as-packed", "one-off"])
+def test_packed_latent_segments_match_window_a_segment(t, shape, hists,
+                                                       clens, kw, starts):
+    """Each segment of a packed row equals ``_latent_window_attention``'s
+    statement of it (``window_attention`` over its gathered rows, a row of
+    its own); one segment that fills the row equals the rectangle latent
+    kernel; and the kernel told a WRONG start (the first boundary one token
+    late) is told apart: the neighbour's first token then attends the wrong
+    sequence."""
+    h, w, dv = shape
+    assert supports_packed_latent_prefill(t, h, w, dv, 4, BS)
+    c = _case(t, shape, hists=hists, clens=clens)
+    ref = np.asarray(_window_reference(c))
+    live = [cl for cl in clens if cl]
+    if starts == "one-off":
+        if len(live) < 2:
+            pytest.skip("one segment has no boundary to move")
+        wrong = [clens[0] + 1, clens[1] - 1, *clens[2:]]
+        out = _packed_kernel(c, clens, t, seg_lens=wrong, **kw)
+        at = clens[0]
+        assert np.abs(out[0, at] - ref[1, 0]).max() > 1e-2
+        return
+    out = _packed_kernel(c, clens, t, **kw)
+    assert out.shape == (1, t, h, dv) and np.all(np.isfinite(out))
+    at = 0
+    for i, cl in enumerate(clens):
+        np.testing.assert_allclose(out[0, at:at + cl], ref[i, :cl],
+                                   atol=ATOL, rtol=0)
+        assert cl == 0 or np.abs(out[0, at:at + cl]).max() > 1e-3
+        at += cl
+    # A query block no segment reaches is zeros.
+    _, tq = prefill_tiles(t, h, 1, w, 4, BS)
+    assert not out[0, -(-at // tq) * tq:].any()
+    if live == [t]:
+        np.testing.assert_allclose(
+            out, np.asarray(_kernel(c)), atol=ATOL, rtol=0)
+
+
+def test_the_packed_tiles_follow_from_heads_and_width():
+    """At the served widths a query block is 32 tokens, a sub-block 16 (512
+    score rows: every head shares the row) and a row tile 256 keys, the
+    row whole where it is shorter; few narrow heads take one block and
+    sub-block of 128 under the same tile."""
+    assert prefill_tiles(1024, 32, 1, 640, 2, BS) == (512, 32)
+    assert packed_sub_block(32, 32, 2) == 16
+    assert packed_latent_tile(1024, 32) == packed_latent_tile(256, 32) == 256
+    assert packed_latent_tile(128, 32) == 128
+    assert prefill_tiles(256, 16, 1, 256, 4, BS) == (512, 128)
+    assert packed_latent_tile(128, 128) == 128      # a tile that is a block
+    assert packed_latent_tile(256, 128) == 256
+    assert prefill_tiles(512, 32, 1, 256, 4, BS) == (512, 64)
+
+
+def test_attend_over_a_packed_latent_view_agrees_in_both_executions():
+    """``attend`` over a view of latent rows that says ``seg_lens``: the
+    kernel (the view says ``interpret``) and the other backends' execution,
+    which takes the row apart into a row a segment and runs
+    ``_latent_window_attention``; a packed view of two rows, or of heads
+    that do not fill a sublane tile, still raises while it is traced."""
+    t = 256
+    hists, clens = [0, 40, 300, 0], [70, 90, 50, 0]
+    c = _case(t, HEADS16, hists=hists, clens=clens)
+    c["pool"] = jnp.nan_to_num(c["pool"])
+    q, rows = _pack(c, clens, t)
+    view = _view(c, seg_lens=jnp.asarray(clens, jnp.int32))
+    positions = jnp.zeros((1, t), jnp.int32)     # read by neither
+    row_len = jnp.asarray([sum(clens)], jnp.int32)
+
+    def run(q, rows, view):
+        return attend(q, rows, None, positions, row_len, view,
+                      jnp.int32(LAYER), scale=SCALE, value_dim=c["dv"])
+
+    jaxpr = str(jax.make_jaxpr(lambda q, rows: run(
+        q, rows, view._replace(interpret=True)))(q, rows))
+    assert "paged_flash_prefill_packed_latent" in jaxpr
+    outs = [np.asarray(run(q, rows, view._replace(interpret=interpret)))
+            for interpret in (False, True)]
+    live = sum(clens)
+    np.testing.assert_allclose(outs[0][0, :live], outs[1][0, :live],
+                               atol=ATOL, rtol=0)
+    assert np.abs(outs[1][0, :live]).max() > 1e-3
+    assert not outs[0][0, live:].any()
+    np.testing.assert_allclose(outs[1], _packed_kernel(c, clens, t),
+                               atol=0, rtol=0)
+    with pytest.raises(ValueError, match="does not cover"):
+        run(jnp.concatenate([q, q]), jnp.concatenate([rows, rows]), view)
+    few = _case(t, (4, 256, 128), hists=hists, clens=clens)
+    with pytest.raises(ValueError, match="does not cover"):
+        run(*_pack(few, clens, t), _view(
+            few, seg_lens=jnp.asarray(clens, jnp.int32), interpret=True))
+
+
 # ---- the runner: a latent model's prefill dispatch through the kernel
 # (``_greedy``: tests/test_paged_prefill.py's, a prompt alone, then the rest)
 @pytest.mark.parametrize("model", ["tiny-deepseek-v3", "tiny-xing4"])
@@ -351,6 +505,9 @@ async def test_latent_engine_prefill_through_the_kernel_matches_the_gathered(
             runner = eng.runner
             assert runner.kv_pools == 1 and runner.prefill_reads_pool
             assert runner.prefill_window_blocks == 1 << 30
+            # Since PR 48 its dispatches are packed rows: both executions
+            # below are the packed pair's (the row taken apart, the kernel).
+            assert runner.prefill_packs and eng.scheduler.prefill_packed
             families[execution] = runner.reachable_prefill_families()
             seen = windows[execution] = []
             prefill = runner._prefill

@@ -292,6 +292,12 @@ def test_a_packed_row_continues_what_an_earlier_row_began():
         (1, 512)
 
 
+# One pool of latent rows at the served widths (32 heads over 640 lanes,
+# values the first 512).
+LATENT_ROWS = {"kv_pools": 1, "kv_heads": 1, "head_dim": 640,
+               "kv_value_dim": 512, "num_heads": 32}
+
+
 def _predicate_runner(**over):
     """A ModelRunner that holds only what ``prefill_packs`` reads."""
     from types import SimpleNamespace
@@ -311,6 +317,8 @@ def _predicate_runner(**over):
             setattr(r.kv_spec, k, v)
         elif k == "prefill_reads_pool":
             r.__dict__[k] = v
+        elif k == "num_heads":
+            r.model_config.num_heads = v
         else:
             setattr(r, k, v)
     return r
@@ -320,15 +328,22 @@ def _predicate_runner(**over):
     ("dense K/V rows read in place", {}, True),
     ("a gathered window", {"prefill_reads_pool": False}, False),
     ("recurrent state", {"state_specs": ("some",)}, False),
-    ("latent rows", {"kv_pools": 1, "kv_heads": 1, "head_dim": 640,
-                     "kv_value_dim": 512}, False),
+    ("latent rows read in place", LATENT_ROWS, True),
+    ("latent rows of heads that fill no sublane tile",
+     {**LATENT_ROWS, "num_heads": 8}, False),
     ("an adapter a row", {"lora_stacks": {"wq": None}}, False),
     ("a draft ring a row", {"spec_n": 3}, False),
+    ("an adapter a row over latent rows",
+     {**LATENT_ROWS, "lora_stacks": {"wq": None}}, False),
+    ("a draft ring a row over latent rows",
+     {**LATENT_ROWS, "spec_n": 3}, False),
 ])
 def test_which_form_a_dispatch_takes_is_decided_in_one_place(case, over,
                                                              packs):
     """``ModelRunner.prefill_packs``, from what the runner holds: the
-    state-keeping and the latent configurations keep their rectangles."""
+    state-keeping configurations keep their rectangles, and so does
+    whatever rides a row (an adapter, a draft's ring) over either pool;
+    latent rows pack since PR 48, where the packed kernel covers them."""
     r = _predicate_runner(**over)
     assert r.prefill_packs is packs
     assert r._prefill_segs == (16 if packs else 0)
@@ -437,7 +452,11 @@ def _deployment_flags(name):
     ("qwen2.5-3b.chat-saturated", True, False, 8, 9),
     ("olmo-hybrid-7b-d16.chat-saturated", True, False, 8, 9),
     # Latent rows read their pool in place since PR 39: one program a
-    # (rows, t), where PR 38's tree had each with and without a window.
+    # (rows, t), where PR 38's tree had each with and without a window;
+    # since PR 48 their dispatches are packed rows too: 1 x {128..1024} ...
+    ("kanana-2-30b-a3b-d8.chat-saturated", True, True, 4, 7),
+    ("xing4.0-29b-a4b-d7.chat-saturated", True, True, 4, 7),
+    # ... and the rectangles an adapter or a draft over them would keep.
     ("kanana-2-30b-a3b-d8.chat-saturated", True, False, 7, 14),
     ("xing4.0-29b-a4b-d7.chat-saturated", True, False, 7, 14),
     # The same envelope where the predicate refuses the pool view (a

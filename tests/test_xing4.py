@@ -217,6 +217,15 @@ def test_f_preempt_and_recompute(engine):
     assert worst(engine, seq) < TOL and worst(engine, other) < TOL
 
 
+def test_g_a_packed_prefill_row_serves_what_the_rectangle_serves(monkeypatch):
+    """tests/test_deepseek_v3.py's, over four residual streams: the mix is
+    a function of a token, so a packed row runs it as any row."""
+    from tests.test_deepseek_v3 import packed_row_against_rectangle
+
+    packed_row_against_rectangle(
+        monkeypatch, TINY_XING4, sys.modules[__name__])
+
+
 # ---- the tolerance is tight enough -------------------------------------------
 @pytest.fixture(scope="module")
 def served(engine):
