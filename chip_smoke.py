@@ -27,6 +27,9 @@ The kernel phase also times the paged decode kernel alone at the benchmark's
 two decode shapes and prints, under ``timing``, µs a call, the least time the
 chip's memory allows the call's KV bytes (``benchmarks/chip/peaks.json``) and
 their ratio, the kernel's own roofline share. It is read by no metric.
+``--memory-stats`` (alone) times ``device.memory_stats()``, which the engine
+calls twice a dispatch. Each serving line's ``memory`` is the engine's memory
+ledger after the phase (``GET /debug/memory``: residents, rises, the last).
 ``--prefill`` (alone, like ``--gdn``, ``--ssd``, ``--moe`` and ``--hc``) does the same for the
 prefill flash kernels at the benchmark's prefill shapes (four over K/V rows,
 nine over latent rows), with ``window_attention`` at the parent's window
@@ -311,6 +314,7 @@ def run_phase(line: dict, body, model, engine_args, **kw) -> dict:
         line["pool_programs"] = get_json(
             f"{stack.engine_urls[0]}/debug/programs")["programs"]
         body(stack)
+        line["memory"] = _memory_summary(stack.engine_urls[0])
     except Exception as e:  # noqa: BLE001 — a phase reports, never raises
         line.update(ok=False, error=f"{type(e).__name__}: {e}",
                     engine_log_tail=_engine_log_tail(stack))
@@ -319,6 +323,24 @@ def run_phase(line: dict, body, model, engine_args, **kw) -> dict:
             stack.terminate()
         line["wall_s"] = round(time.monotonic() - t0, 1)
     return emit(line)
+
+
+def _memory_summary(engine_url) -> dict:
+    """What holds the first engine's device after the phase's requests,
+    from ``GET /debug/memory``: the ledger's residents, how many programs
+    it measured, how often the allocator's peak rose in each phase and
+    the last rise. Printed, and part of no verdict."""
+    try:
+        ledger = get_json(f"{engine_url}/debug/memory")
+    except Exception as e:  # noqa: BLE001 — printing only
+        return {"error": f"{type(e).__name__}: {e}"}
+    return {
+        "device": ledger["device"], "residents": ledger["residents"],
+        "programs_measured": len(ledger["programs"]),
+        "rises": ledger["rises"], "rise_bytes": ledger["rise_bytes"],
+        "last_event": ledger["events"][-1] if ledger["events"] else None,
+        "now": ledger["now"].get(ledger["device"]),
+    }
 
 
 def _bytes_in_use(engine_url) -> dict:
@@ -1927,6 +1949,114 @@ def verdict(lines: list, chips: int, full_depth: int,
     return {"ok": not faults, "device": device}
 
 
+def memory_stats_child(rehearse: bool) -> int:
+    """What one ``memory_stats()`` call costs on the engine's device: the
+    engine makes two a dispatch in its executor thread (the memory
+    ledger's reads). 10,000 calls on an idle device, and 10,000 while a
+    program of about four seconds runs on it (a chain of matrix products
+    behind one enqueue, as a decode train is). Then a program with a
+    known temporary, polled while it runs: whether the allocator's bytes
+    in use and peak show a program's temporaries, and from when. Two JSON
+    lines."""
+    import jax
+    import jax.numpy as jnp
+
+    device = jax.devices()[0]
+    calls = 200 if rehearse else 10_000
+
+    def timed():
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            stats = device.memory_stats()
+        return (time.perf_counter() - t0) / calls * 1e6, stats
+
+    @jax.jit
+    def busy(x):
+        return jax.lax.fori_loop(
+            0, 8 if rehearse else 6000,
+            lambda _, a: (a @ a) * jnp.bfloat16(1e-3), x)
+
+    x = jnp.ones((64, 64) if rehearse else (4096, 4096), jnp.bfloat16)
+    busy(x).block_until_ready()
+    idle_us, stats = timed()
+    t0 = time.perf_counter()
+    out = busy(x)
+    busy_us, _ = timed()
+    still_running = not out.is_ready()
+    out.block_until_ready()
+    busy_s = time.perf_counter() - t0
+
+    # When does the allocator take a program's temporaries, and do its
+    # counts show them? A program with a known temporary (two float32
+    # squares carried through a loop), polled while it runs.
+    n = 64 if rehearse else 16384
+
+    @jax.jit
+    def hungry(x):
+        wide = x.astype(jnp.float32)
+
+        def body(_, pair):
+            a, b = pair
+            return (a @ b) * 1e-4, a + b
+
+        a, b = jax.lax.fori_loop(0, 3, body, (wide @ wide.T, wide))
+        return jnp.sum(a) + jnp.sum(b)
+
+    y = jnp.ones((n, n), jnp.bfloat16)
+    compiled = hungry.lower(y).compile()
+    analysis = compiled.memory_analysis()
+    hungry(y).block_until_ready()
+    before = device.memory_stats() or {}
+    t0 = time.perf_counter()
+    out = hungry(y)
+    after_enqueue = device.memory_stats() or {}
+    most, polls = 0, 0
+    while not out.is_ready():
+        most = max(most, (device.memory_stats() or {}).get(
+            "bytes_in_use", 0))
+        polls += 1
+    ran_s = time.perf_counter() - t0
+    settled = device.memory_stats() or {}
+    # Two of them enqueued back to back: do both hold their temporaries
+    # at once (the peak rises by another program's worth), or one after
+    # the other as they run?
+    first, second = hungry(y), hungry(y)
+    most_two = 0
+    while not second.is_ready():
+        most_two = max(most_two, (device.memory_stats() or {}).get(
+            "bytes_in_use", 0))
+    first.block_until_ready()
+    two = device.memory_stats() or {}
+    emit({
+        "phase": "memory_stats.temporaries",
+        "two_in_flight_in_use_most": most_two,
+        "two_in_flight_peak_after": two.get("peak_bytes_in_use"),
+        "temp_bytes": int(getattr(analysis, "temp_size_in_bytes", 0)),
+        "output_bytes": int(getattr(analysis, "output_size_in_bytes", 0)),
+        "argument_bytes": int(getattr(analysis, "argument_size_in_bytes",
+                                      0)),
+        "program_s": round(ran_s, 3), "polls": polls,
+        "in_use_before": before.get("bytes_in_use"),
+        "in_use_after_enqueue": after_enqueue.get("bytes_in_use"),
+        "in_use_most_while_running": most,
+        "in_use_after": settled.get("bytes_in_use"),
+        "peak_before": before.get("peak_bytes_in_use"),
+        "peak_after": settled.get("peak_bytes_in_use"),
+        "stats_after": settled,
+    })
+    emit({
+        "phase": "memory_stats", "calls": calls,
+        "us_per_call_idle": round(idle_us, 2),
+        "us_per_call_busy": round(busy_us, 2),
+        "program_still_running_after_the_busy_loop": still_running,
+        "program_s": round(busy_s, 3),
+        "keys": sorted(stats or {}),
+        "device": {"platform": device.platform, "kind": device.device_kind},
+        "ok": bool(stats) and still_running and not rehearse,
+    })
+    return 0
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
@@ -1949,6 +2079,9 @@ def main(argv=None) -> int:
     ap.add_argument("--prefill", action="store_true",
                     help="only check and time the prefill flash kernel "
                          "alone at the benchmark's prefill shapes and exit")
+    ap.add_argument("--memory-stats", action="store_true",
+                    help="only time device.memory_stats(), idle and under "
+                         "a running program, and exit")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, HERE)
@@ -1977,6 +2110,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return prefill_child(args.rehearse)
+    if args.memory_stats:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return memory_stats_child(args.rehearse)
 
     model, full_depth, engine_args = MODEL, FULL_DEPTH, ENGINE_ARGS
     if args.rehearse:

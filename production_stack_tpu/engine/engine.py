@@ -14,6 +14,7 @@ This tier replaces the external vLLM engine images of the reference stack
 """
 
 import asyncio
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -356,6 +357,9 @@ class ServingEngine:
             # Overlapped weight loading without warmup: join here so the
             # engine never reports healthy with weights still in flight.
             await loop.run_in_executor(None, self.runner.wait_for_weights)
+        # What is resident now that warm-up is over, and the line between
+        # the memory ledger's two phases (a rise past here is serving's).
+        await loop.run_in_executor(None, self.runner.build_memory_ledger)
         self.startup_total_seconds = time.monotonic() - self._startup_t0
         self._compiles_at_start = self._compile_clock.reading()
         self._running = True
@@ -684,7 +688,8 @@ class ServingEngine:
             })
 
     def _record_issue(self, batch, step: int, t_wall: float,
-                      t_mono: float, compiled: float = 0.0) -> None:
+                      t_mono: float, compiled: float = 0.0,
+                      hbm_rise: Optional[dict] = None) -> None:
         """Dispatch-issue anchor: close each fresh row's queue-wait phase
         and append the per-request issue event. O(rows) in-memory appends
         on the engine loop — no syscalls (PL008-clean: host-side only).
@@ -695,9 +700,13 @@ class ServingEngine:
         unattributed gap between phases — the phase spans must tile the
         request duration. ``compiled``: the seconds of that kind this
         issue really held (flight_recorder.annotated_issue), on each row's
-        ``*_issue`` event where it is not 0."""
+        ``*_issue`` event where it is not 0; ``hbm_rise``: where the read
+        after this enqueue found the allocator's peak higher, by how much
+        and what of it is unexplained (MemoryLedger.rise_at)."""
         rec = self.recorder
         stalled = {"compiled": compiled} if compiled else {}
+        if hbm_rise:
+            stalled["hbm_rise"] = hbm_rise
         # Rows of the dispatch that carry recurrent state through it (every
         # real row of a model that declares some; absent otherwise).
         state_rows = {"state_rows": sum(
@@ -743,6 +752,10 @@ class ServingEngine:
         per-request fetch events (tokens emitted, spec acceptance)."""
         now = time.monotonic()
         rec = self.recorder
+        # The read after this sync found the allocator's peak higher.
+        hbm_rise = self.runner.memory.rise_at(step, "fetch") \
+            if rec is not None else None
+        risen = {"hbm_rise": hbm_rise} if hbm_rise else {}
         if batch.kind == "decode":
             self.lifecycle.decode_train.observe(now - issue_time)
         for idx, seq in enumerate(batch.seqs):
@@ -755,14 +768,14 @@ class ServingEngine:
                 if rec is not None:
                     rec.event(seq.request_id, "prefill_fetch", {
                         "step": step, "final": final,
-                        "cached_tokens": seq.num_cached_tokens,
+                        "cached_tokens": seq.num_cached_tokens, **risen,
                     })
             elif rec is not None:
                 data = {
                     "step": step,
                     "tokens": len(token_lists[idx])
                     if idx < len(token_lists) else 0,
-                    "ms": round((now - issue_time) * 1000, 2),
+                    "ms": round((now - issue_time) * 1000, 2), **risen,
                 }
                 if spec_accepted_delta:
                     # Explicitly BATCH-level: the device commits
@@ -910,11 +923,15 @@ class ServingEngine:
             depth = 1
         overlap = cfg.overlap_dispatch and depth >= 2
         in_flight: deque = deque()  # (batch, step_id, DispatchHandle) FIFO
-        from production_stack_tpu.engine.flight_recorder import (
-            annotated,
-            annotated_issue,
-        )
+        from production_stack_tpu.engine import flight_recorder
 
+        # The two executor-side parts of a dispatch read the allocator
+        # once each for the runner's memory ledger.
+        memory = self.runner.memory
+        annotated = functools.partial(flight_recorder.annotated,
+                                      memory=memory)
+        annotated_issue = functools.partial(flight_recorder.annotated_issue,
+                                            memory=memory)
         loop_span = self.loop_spans
 
         def abort_batch(batch):
@@ -1135,7 +1152,8 @@ class ServingEngine:
                             self.prefill_stops[batch.stop] += 1
                     self.scheduler.advance_at_issue(batch)
                     self._record_issue(batch, step, issue_wall, issue_mono,
-                                       compiled)
+                                       compiled,
+                                       memory.rise_at(step, "issue"))
                     in_flight.append((batch, step, handle))
             if in_flight:
                 # Applying may finish rows and free blocks, unblocking
@@ -1541,6 +1559,24 @@ class ServingEngine:
             pass
         return out
 
+    def _memory_stats(self) -> Dict:
+        """The memory ledger's side of ``stats()``: residents by device
+        and holder (from the arrays themselves until ``start()`` has built
+        the ledger), the fullest device's reading now, and the peak's
+        rises by phase."""
+        memory = self.runner.memory
+        now = memory.reading()
+        return {
+            "hbm_resident_bytes": memory.residents_by_device
+            or self.runner.resident_bytes(),
+            "hbm_bytes_in_use": int(now.get("bytes_in_use", 0)),
+            "hbm_peak_bytes": int(now.get("peak_bytes_in_use", 0)),
+            "hbm_limit_bytes": int(now.get("bytes_limit", 0)),
+            "hbm_reserved_bytes": int(now.get("bytes_reserved", 0)),
+            "hbm_peak_rises": dict(memory.rises),
+            "hbm_peak_rise_bytes": dict(memory.rise_bytes),
+        }
+
     def device_report(self) -> Dict:
         """The devices of this engine's MESH, as JAX reports them."""
         import os
@@ -1566,8 +1602,7 @@ class ServingEngine:
         r = self.runner
         cached = _cache_entries(r.compilation_cache_path)
         bytes_in_use, peak_bytes_in_use = {}, {}
-        for d in self.mesh.devices.flat:
-            stats = d.memory_stats() or {}
+        for d, stats in zip(self.mesh.devices.flat, r.device_memory()):
             if "bytes_in_use" in stats:
                 bytes_in_use[str(d.id)] = int(stats["bytes_in_use"])
             if "peak_bytes_in_use" in stats:
@@ -1638,13 +1673,13 @@ class ServingEngine:
                 self.runner.kv_quant_bytes_saved_total,
             # Multi-chip serving (docs/PERF.md round 9): the mesh this
             # engine's dispatches shard over (the LIVE mesh — an explicit
-            # mesh= override wins over the config axes), plus the KV
-            # pool's actual per-device HBM footprint (payload + scale
-            # sidecars).
+            # mesh= override wins over the config axes), and what holds
+            # each device's memory (the KV pool's footprint is the "kv"
+            # holder).
             "mesh_tp_size": self.mesh.shape.get("tp", 1),
             "mesh_sp_size": self.mesh.shape.get("sp", 1),
             "mesh_devices": self.mesh.size,
-            "hbm_kv_bytes_per_device": self.runner.per_device_hbm_kv_bytes(),
+            **self._memory_stats(),
             "num_requests_running": self.scheduler.num_running,
             "num_requests_waiting": self.scheduler.num_waiting,
             # Autoscaling signal (docs/SOAK.md): total backlog on this

@@ -107,15 +107,24 @@ class LoopSpans:
                 for phase, seconds in self.seconds.items()}
 
 
-def annotated(name: str, step: int, fn, *args):
+def annotated(name: str, step: int, fn, *args, memory=None):
     """``fn(*args)`` under a ``TraceAnnotation``: the part of a loop span
-    that runs in the executor thread (``pstpu.issue.enqueue``,
-    ``pstpu.fetch.sync``) gets an event of its own there, with the span's
-    ``step``, which is what separates the blocking device call from the
-    executor hop around it. (Positional only: ``run_in_executor`` passes
-    no keywords.)"""
-    with TraceAnnotation(name, step=step):
-        return fn(*args)
+    that runs in the executor thread (``pstpu.fetch.sync``) gets an event
+    of its own there, with the span's ``step``, which is what separates
+    the blocking device call from the executor hop around it. With the
+    runner's ``memory`` ledger the event also carries what the allocator
+    says once the call is back (``MemoryLedger.fetched``: ``hbm``,
+    ``hbm_peak``, ``hbm_limit``; nothing where the device reports none).
+    (``run_in_executor`` passes no keywords: bind ``memory`` with
+    ``functools.partial``.)"""
+    with TraceAnnotation(name, step=step) as span:
+        try:
+            return fn(*args)
+        finally:
+            # A fetch that raised is in flight no longer either.
+            said = memory.fetched(step) if memory is not None else None
+            if said and span is not None:
+                span.set_metadata(**said)
 
 
 class CompileClock:
@@ -156,6 +165,12 @@ class CompileClock:
         with self._lock:
             return self.count, self.seconds
 
+    def since(self, reading: tuple) -> float:
+        """Seconds the clock moved since ``reading`` where a program was
+        compiled or loaded meanwhile (0.0 otherwise: tracing alone)."""
+        count, seconds = self.reading()
+        return round(seconds - reading[1], 6) if count != reading[0] else 0.0
+
 
 _COMPILE_CLOCK: Optional[CompileClock] = None
 
@@ -176,21 +191,28 @@ def compile_clock() -> CompileClock:
     return _COMPILE_CLOCK
 
 
-def annotated_issue(step: int, fn, *args):
+def annotated_issue(step: int, fn, *args, memory=None):
     """``annotated("pstpu.issue.enqueue", ...)`` that also says whether the
     call compiled: returns ``(fn(*args), seconds)``, the seconds the
     compile clock moved by where a program was compiled or loaded during
     the call (0.0 otherwise), and puts them on the span as ``compiled``.
     The loop awaits each issue, so no other runner call interleaves; a
-    handler thread that jits something meanwhile would be counted in."""
+    handler thread that jits something meanwhile would be counted in.
+    With the runner's ``memory`` ledger the span also carries what the
+    allocator says right after the enqueue (``MemoryLedger.issued``:
+    ``hbm``, ``hbm_peak``, ``hbm_limit``, ``hbm_explained``); ``fn`` then
+    returns a ``DispatchHandle``, which names its program and rows."""
     clock = compile_clock()
     with TraceAnnotation("pstpu.issue.enqueue", step=step) as span:
-        count, seconds = clock.reading()
+        before = clock.reading()
         out = fn(*args)
-        now = clock.reading()
-        compiled = round(now[1] - seconds, 6) if now[0] != count else 0.0
-        if compiled and span is not None:
-            span.set_metadata(compiled=compiled)
+        compiled = clock.since(before)
+        said = memory.issued(step, out.program, out.rows, compiled) \
+            if memory is not None else {}
+        if compiled:
+            said = {**said, "compiled": compiled}
+        if said and span is not None:
+            span.set_metadata(**said)
     return out, compiled
 
 

@@ -39,6 +39,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
+from production_stack_tpu.engine.memory_ledger import (
+    MemoryLedger,
+    analysis_of,
+)
 from production_stack_tpu.engine.sampling import (
     sample_tokens,
     sampler_paths,
@@ -210,12 +214,18 @@ class DispatchHandle:
 
     fetch() performs the blocking device->host sync (idempotent; caches
     the result). The pipelined engine loop issues the NEXT dispatch before
-    fetching, so the sync overlaps device execution."""
+    fetching, so the sync overlaps device execution. ``program`` says
+    which program the dispatch enqueued (``ModelRunner.program``) and
+    ``rows`` for how many sequences."""
 
-    __slots__ = ("_fetch", "_result", "_done", "issue_time")
+    __slots__ = ("_fetch", "_result", "_done", "issue_time", "program",
+                 "rows")
 
-    def __init__(self, fetch_fn):
+    def __init__(self, fetch_fn, program: Optional[Dict] = None,
+                 rows: int = 0):
         self._fetch = fetch_fn
+        self.program = program
+        self.rows = rows
         self._result = None
         self._done = False
         self.issue_time = time.monotonic()
@@ -309,6 +319,28 @@ def _cache_entries(cache_dir: Optional[str]) -> Optional[frozenset]:
         return None
 
 
+def _device_label(device) -> str:
+    """``platform:id``: how metrics and the memory ledger name a device."""
+    return f"{device.platform}:{device.id}"
+
+
+def _bytes_by_device(arrays) -> Dict[str, int]:
+    """``{"platform:id": bytes}`` these arrays lay on each device, each
+    array once, from shapes and shardings alone."""
+    out: Dict[str, int] = {}
+    seen = set()
+    for array in arrays:
+        if id(array) in seen:
+            continue
+        seen.add(id(array))
+        per_device = int(np.prod(array.sharding.shard_shape(array.shape),
+                                 dtype=np.int64)) * array.dtype.itemsize
+        for d in array.sharding.device_set:
+            label = _device_label(d)
+            out[label] = out.get(label, 0) + per_device
+    return out
+
+
 class ModelRunner:
     def __init__(
         self,
@@ -326,6 +358,8 @@ class ModelRunner:
         self.lora_stacks = lora_registry.stacks() if lora_registry else None
         self.model_config = model_config
         self.mesh = mesh
+        # What holds the devices' memory (engine/memory_ledger.py).
+        self.memory = MemoryLedger(self.device_memory)
         # "paged": decode attends directly against the HBM pool inside the
         # Pallas flash-decode kernel (no gathered window copy, pool not
         # halved). "window": decode gathers the live KV into a contiguous
@@ -1011,32 +1045,104 @@ class ModelRunner:
             return 0.0
         return self._spec_controller.mean_ema()
 
-    def per_device_hbm_kv_bytes(self) -> Dict[str, int]:
-        """Actual device bytes the KV pool (payload + scale sidecars)
-        occupies on EACH mesh device, keyed "platform:id" — the
-        pstpu:hbm_kv_bytes{device} gauge. With tp>1 the pools are kv-head-
-        sharded, so each device holds ~1/tp of kv_pool_bytes; a replicated
-        fallback (indivisible heads) is immediately visible as every
-        device holding the full pool. Probed from the live arrays'
-        addressable shards; a dispatch may have donated the pool buffers
-        mid-probe, in which case the last good snapshot is returned."""
-        out: Dict[str, int] = {}
-        try:
-            pools = [self.kv_k, self.kv_v]
-            if self.kv_quantized:
-                pools += [self.kv_k_scale, self.kv_v_scale]
-            for pool in pools:
-                for sh in pool.addressable_shards:
-                    dev = f"{sh.device.platform}:{sh.device.id}"
-                    out[dev] = out.get(dev, 0) + int(sh.data.nbytes)
-        except (RuntimeError, ValueError):  # donated mid-step; keep last
-            # The donation race surfaces as RuntimeError on TPU and
-            # ValueError INVALID_ARGUMENT on the CPU backend — the same
-            # pair read_blocks_retry retries on. Anything else is a real
-            # bug that must surface, not a stale-but-plausible gauge.
-            return getattr(self, "_last_device_kv_bytes", {})
-        self._last_device_kv_bytes = out
+    # ---------------------------------------------------------------- memory
+    def device_memory(self) -> List[Dict]:
+        """Per device of the engine's mesh, in ``mesh.devices.flat`` order,
+        its ``memory_stats()`` (``{}`` where the backend reports none: the
+        CPU). The one place the engine reads the allocator: ``GET
+        /version``, the pool's sizing and the memory ledger, whose
+        ``fullest`` picks the device every HBM number is about."""
+        return [d.memory_stats() or {} for d in self.mesh.devices.flat]
+
+    def device_labels(self) -> List[str]:
+        """``platform:id`` of the mesh's devices, in ``device_memory``'s
+        order."""
+        return [_device_label(d) for d in self.mesh.devices.flat]
+
+    @staticmethod
+    def program(kind: str, family, has_penalties: bool = False,
+                logprobs_k: int = 0, spec_on: bool = True) -> Dict:
+        """How the memory ledger names one dispatch program: the kind,
+        the family (decode ``(b, mb, K, cached window)``, prefill ``(b, t,
+        mb, has window)``) and the sampling variant, the static arguments
+        that make it a program of its own."""
+        family = [int(x) for x in family]
+        key = f"{kind}{family}".replace(" ", "") \
+            + ("+pen" if has_penalties else "") \
+            + (f"+lp{logprobs_k}" if logprobs_k else "") \
+            + ("" if spec_on else "+plain")
+        return {"key": key, "kind": kind, "family": family,
+                "has_penalties": bool(has_penalties),
+                "logprobs_k": int(logprobs_k), "spec_on": bool(spec_on)}
+
+    def resident_arrays(self) -> Dict[str, List]:
+        """The arrays this runner keeps on the devices, by holder
+        (memory_ledger.HOLDERS less ``other``). A draft that is the
+        target itself holds no weights of its own."""
+        weights = jax.tree.leaves(self._params)
+        spec = []
+        if self.spec_n:
+            mine = {id(x) for x in weights}
+            spec = [self.spec_k, self.spec_v, self.spec_pos] + [
+                x for x in jax.tree.leaves(self.spec_params)
+                if id(x) not in mine]
+        return {
+            "weights": weights,
+            "kv": [self.kv_k, self.kv_v] + (
+                [self.kv_k_scale, self.kv_v_scale] if self.kv_quantized
+                else []),
+            "state": list(self.state_pools),
+            "spec": spec,
+            "lora": jax.tree.leaves(self.lora_stacks or {}),
+        }
+
+    def resident_bytes(self) -> Dict[str, Dict[str, int]]:
+        """``{"platform:id": {holder: bytes}}`` for every device of the
+        mesh: what ``resident_arrays`` lays on it, from each array's shape
+        and sharding (no buffer is touched, so a pool a dispatch has just
+        donated reads like any other). With tp>1 the pools are kv-head-
+        sharded and a device holds ~1/tp of them; a replicated fallback
+        shows as every device holding the whole pool."""
+        out = {label: {} for label in self.device_labels()}
+        for holder, arrays in self.resident_arrays().items():
+            for label, nbytes in _bytes_by_device(arrays).items():
+                out.setdefault(label, {})[holder] = nbytes
+            for named in out.values():
+                named.setdefault(holder, 0)
         return out
+
+    def build_memory_ledger(self) -> None:
+        """Enter what is resident into ``self.memory`` (the engine calls
+        this once, when ``start()`` ends and nothing is in flight): the
+        named holders from the arrays the runner holds, the 16 largest
+        groups of live arrays outside them, and ``other`` from the
+        allocator's own count. Lowers and compiles nothing."""
+        named = self.resident_arrays()
+        mine = {id(x) for arrays in named.values() for x in arrays}
+        labels = self.device_labels()
+        groups: Dict[Tuple, Dict] = {}
+        for array in jax.live_arrays():
+            if id(array) in mine:
+                continue
+            for label, nbytes in _bytes_by_device([array]).items():
+                if label not in labels:
+                    continue
+                group = groups.setdefault(
+                    (label, tuple(array.shape), str(array.dtype)),
+                    {"device": label, "shape": list(array.shape),
+                     "dtype": str(array.dtype), "bytes": 0, "count": 0})
+                group["bytes"] += nbytes
+                group["count"] += 1
+        largest = sorted(groups.values(),
+                         key=lambda g: -g["bytes"])[:16 * len(labels)]
+        self.memory.build(self.resident_bytes(), largest)
+        logger.info(
+            "Memory ledger (%s): residents %s; %d programs measured; "
+            "peak rose %d times in warm-up",
+            self.memory.device,
+            {h: f"{b / 1e9:.3f} GB"
+             for h, b in self.memory.residents.items()},
+            len(self.memory.programs), self.memory.rises["warmup"])
 
     @property
     def kv_pool_bytes(self) -> int:
@@ -1083,9 +1189,8 @@ class ModelRunner:
         # explicitly or take this nominal 2 GiB); an accelerator that
         # cannot say what is free is an error, never a guessed pool.
         free_bytes = None
-        for dev in self.mesh.devices.flat:
-            stats = dev.memory_stats()
-            if stats and "bytes_limit" in stats:
+        for dev, stats in zip(self.mesh.devices.flat, self.device_memory()):
+            if "bytes_limit" in stats:
                 free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
                 free_bytes = free if free_bytes is None \
                     else min(free_bytes, free)
@@ -2389,6 +2494,8 @@ class ModelRunner:
             has_penalties=has_penalties, logprobs_k=logprobs_k,
             spec_on=spec_on,
         )
+        program = self.program("decode", (b, mb, k, use_cached),
+                               has_penalties, logprobs_k, spec_on)
         self._rebind_scale_pools(kv_ks2, kv_vs2)
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
         if self.kv_quantized:
@@ -2492,7 +2599,7 @@ class ModelRunner:
                     lps.append(entries)
                 return tokens, lps
 
-            return DispatchHandle(fetch)
+            return DispatchHandle(fetch, program, n)
 
         def fetch():
             out = np.asarray(toks_all)  # ONE [K, B] fetch per K*B tokens
@@ -2507,7 +2614,7 @@ class ModelRunner:
                 np.asarray(lp_i),
             )
 
-        return DispatchHandle(fetch)
+        return DispatchHandle(fetch, program, n)
 
     @staticmethod
     def _gather_logprobs(seqs, steps, lp_c, lp_t, lp_i):
@@ -2943,7 +3050,8 @@ class ModelRunner:
             )
             return tokens, lp
 
-        return DispatchHandle(fetch)
+        return DispatchHandle(fetch, self.program(
+            "prefill", (b, t, mb, has_window), has_penalties, logprobs_k), n)
 
     # ------------------------------------------------------------ token chain
     def _push_chain(self, entry: Dict) -> None:
@@ -3367,15 +3475,21 @@ class ModelRunner:
         out = []
         for kind, fam, lowered in programs:
             compiled = lowered.compile()
-            mem = compiled.memory_analysis()
+            analysis = analysis_of(compiled)
+            program = self.program(kind, fam)
+            self.memory.analysed(program, analysis)
             text = compiled.as_text()
             carried = [jax.ShapeDtypeStruct((fam[0], *x.shape[1:]), x.dtype)
                        for x in self.state_pools] if kind == "decode" else []
             out.append({
                 "program": kind, "family": list(fam),
                 "pool_copies": len(pool_copies(text, pools + carried)),
-                "temp_bytes": int(mem.temp_size_in_bytes),
-                "alias_bytes": int(mem.alias_size_in_bytes),
+                # temp_bytes, alias_bytes, ... (memory_analysis), and
+                # beside them what the allocator gave the family's
+                # dispatch where the memory ledger has measured it.
+                **analysis,
+                **{k: v for k, v in self.memory.programs[program["key"]]
+                   .items() if k in ("held_bytes", "in_company")},
                 "pool_bytes": int(self.kv_k.size * self.kv_k.dtype.itemsize),
                 "state_pool_bytes": self.state_pool_bytes,
             })
@@ -3392,6 +3506,31 @@ class ModelRunner:
                 out[-1]["prefill_attn"] = prefill_attn_path(text)
                 out[-1]["prefill_reads_pool"] = self.prefill_reads_pool
         return out
+
+    def analyse_programs(self, keys) -> None:
+        """Attach ``memory_analysis()`` to these programs of the memory
+        ledger that lack it (``GET /debug/memory?analyze=1``: the families
+        its events name). Lowers and compiles each in the caller's thread;
+        with a compile cache the program is the one warm-up left there. A
+        program of the plain decode body under speculation
+        (``spec_on`` false) has no lowering of its own here and is left."""
+        keys = set(keys)
+        wanted = [(k, p) for k, p in self.memory.snapshot()["programs"].items()
+                  if k in keys and "temp_bytes" not in p and p["spec_on"]]
+        if not wanted:
+            return
+        aparams = self._abstract_params()
+        for key, program in wanted:
+            *shape, flag = program["family"]
+            compiled = self._lowering(program["kind"])(
+                aparams, *shape, bool(flag),
+                has_penalties=program["has_penalties"],
+                logprobs_k=program["logprobs_k"]).compile()
+            self.memory.analysed({"key": key}, analysis_of(compiled))
+
+    def _lowering(self, kind: str):
+        """``_lower_decode`` or ``_lower_prefill``, by a program's kind."""
+        return self._lower_decode if kind == "decode" else self._lower_prefill
 
     def residual_report(self) -> Dict:
         """How many streams the model's residual is (``hc_mult``) and,
@@ -3465,12 +3604,17 @@ class ModelRunner:
         # prepass's own fresh artifacts for warm-boot hits.
         self._prepass_progress = 0
 
-        def compile_counted(lower, *family, **variant):
+        def compile_counted(kind, *family, **variant):
             nonlocal n, consecutive_hits
             if self.weights_ready or consecutive_hits >= warm_bail:
                 raise _PrepassDone()
             before = _cache_entries(count_dir)
-            lower(aparams, *family, **variant).compile()
+            compiled = self._lowering(kind)(
+                aparams, *family, **variant).compile()
+            # A compiled program is at hand: the memory ledger takes its
+            # analysis (the execute pass holds none).
+            self.memory.analysed(self.program(kind, family, **variant),
+                                 analysis_of(compiled))
             after = _cache_entries(count_dir)
             if before is not None and after is not None:
                 if after - before:
@@ -3488,7 +3632,7 @@ class ModelRunner:
                 dvariants = variants if db == 1 else variants[:2]
                 for pen, lpk in dvariants:
                     compile_counted(
-                        self._lower_decode, db, mb, dk, cached,
+                        "decode", db, mb, dk, cached,
                         has_penalties=pen, logprobs_k=lpk,
                     )
             t_floor = _t_floor(cfg.max_num_batched_tokens)
@@ -3502,7 +3646,7 @@ class ModelRunner:
                     pvariants = variants[:1]
                 for pen, lpk in pvariants:
                     compile_counted(
-                        self._lower_prefill, pb, t, mb, has_window,
+                        "prefill", pb, t, mb, has_window,
                         has_penalties=pen, logprobs_k=lpk,
                     )
         except _PrepassDone:
@@ -3680,6 +3824,27 @@ class ModelRunner:
                     self.startup_cache_hit_families += 1
             return out
 
+        # Where the devices report their memory the ledger reads the
+        # allocator right before and right after each family's enqueue:
+        # the difference is what the count shows of the program (its code
+        # and outputs). No sync: a family still running changes neither
+        # read (its temporaries are in no count; PERF.md section 6, PR 49),
+        # and a sync a family cost a warm boot 2-3 s.
+        from production_stack_tpu.engine.flight_recorder import compile_clock
+
+        reads = bool(self.memory.reading())
+        clock = compile_clock()
+
+        def measured(program, rows, fn, *args, **kwargs):
+            if not reads:
+                return counted(fn, *args, **kwargs)
+            self.memory.quiet()
+            before = clock.reading()
+            out = counted(fn, *args, **kwargs)
+            self.memory.issued(n_warmed, program, rows, clock.since(before))
+            self.memory.fetched(n_warmed)
+            return out
+
         variants = ((False, 0), (False, LOGPROB_BUCKETS[0]), (True, 0))
         n_warmed = 0
         # Serving's cached-window dispatches receive window buffers that are
@@ -3717,7 +3882,9 @@ class ModelRunner:
                         )
                         kv_ks, kv_vs = self._scale_pool_args()
                         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
-                        out = counted(
+                        out = measured(
+                            self.program("decode", (db, mb, dk, cached),
+                                         pen, lpk, sp_on), db,
                             self._decode,
                             self.params,
                             jnp.zeros((NUM_SCALARS * db + db * mb,),
@@ -3766,7 +3933,9 @@ class ModelRunner:
                     )
                     kv_ks, kv_vs = self._scale_pool_args()
                     dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
-                    out = counted(
+                    out = measured(
+                        self.program("prefill", (pb, t, mb, has_window),
+                                     pen, lpk), seqs,
                         self._prefill,
                         self.params,
                         jnp.zeros((length,), jnp.int32),

@@ -273,6 +273,7 @@ class APIServer:
             app.router.add_post("/debug/profile", self.debug_profile_start)
             app.router.add_get("/debug/profile", self.debug_profile_status)
             app.router.add_get("/debug/programs", self.debug_programs)
+            app.router.add_get("/debug/memory", self.debug_memory)
         return app
 
     # ------------------------------------------------- observability (debug)
@@ -368,6 +369,25 @@ class APIServer:
             None, self.engine.runner.audit_pool_programs
         )
         return web.json_response({"programs": audit})
+
+    async def debug_memory(self, request: web.Request) -> web.Response:
+        """GET /debug/memory: what holds the device's memory: the memory
+        ledger (residents by holder, what each dispatch program held while
+        it ran) and the events that raised the allocator's peak
+        (engine/memory_ledger.py), beside every device's reading now.
+        ``?analyze=1`` first compiles, in a worker thread and from the
+        compile cache where there is one, the programs the events name
+        and attaches their ``memory_analysis()``."""
+        runner = self.engine.runner
+        if request.query.get("analyze") in ("1", "true"):
+            named = {key for event in runner.memory.snapshot()["events"]
+                     for key in (event["family"], *event["in_flight"])}
+            await asyncio.get_running_loop().run_in_executor(
+                None, runner.analyse_programs, named)
+        return web.json_response({
+            **runner.memory.snapshot(),
+            "now": dict(zip(runner.device_labels(), runner.device_memory())),
+        })
 
     def _emit_lifecycle_spans(self, request: web.Request,
                               request_ids) -> None:

@@ -71,7 +71,12 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
     s.setdefault("mesh_tp_size", 1)
     s.setdefault("mesh_sp_size", 1)
     s.setdefault("mesh_devices", 1)
-    s.setdefault("hbm_kv_bytes_per_device", {})
+    s.setdefault("hbm_resident_bytes", {})
+    for key in ("hbm_bytes_in_use", "hbm_peak_bytes", "hbm_limit_bytes",
+                "hbm_reserved_bytes"):
+        s.setdefault(key, 0)
+    for key in ("hbm_peak_rises", "hbm_peak_rise_bytes"):
+        s.setdefault(key, {"warmup": 0, "serving": 0})
     label = f'{{model_name="{model_name}"}}'
     lines = [
         "# HELP vllm:num_requests_running Running requests",
@@ -593,13 +598,51 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "(dp x sp x tp)",
         "# TYPE pstpu:mesh_devices gauge",
         f"pstpu:mesh_devices{label} {s['mesh_devices']}",
-        "# HELP pstpu:hbm_kv_bytes KV-pool bytes resident per mesh device "
-        "(payload + scale sidecars; kv-head-sharded at tp>1)",
-        "# TYPE pstpu:hbm_kv_bytes gauge",
+        # What holds the HBM (docs/OBSERVABILITY.md): the memory ledger's
+        # residents per mesh device, the fullest device's reading at this
+        # scrape, and how often and how far the allocator's peak rose.
+        "# HELP pstpu:hbm_resident_bytes Bytes resident per mesh device by "
+        "holder (weights, kv = the pool's payload + scale sidecars, state, "
+        "spec, lora, other = in use beyond the named holders)",
+        "# TYPE pstpu:hbm_resident_bytes gauge",
         *[
-            f'pstpu:hbm_kv_bytes{{model_name="{model_name}",'
-            f'device="{dev}"}} {b}'
-            for dev, b in sorted(s["hbm_kv_bytes_per_device"].items())
+            f'pstpu:hbm_resident_bytes{{model_name="{model_name}",'
+            f'holder="{holder}",device="{dev}"}} {b}'
+            for dev, holders in sorted(s["hbm_resident_bytes"].items())
+            for holder, b in holders.items()
+        ],
+        "# HELP pstpu:hbm_bytes_in_use Allocator bytes in use on the "
+        "fullest mesh device at this scrape (0: the backend reports none)",
+        "# TYPE pstpu:hbm_bytes_in_use gauge",
+        f"pstpu:hbm_bytes_in_use{label} {s['hbm_bytes_in_use']}",
+        "# HELP pstpu:hbm_peak_bytes Allocator high-water mark since "
+        "process start on that device",
+        "# TYPE pstpu:hbm_peak_bytes gauge",
+        f"pstpu:hbm_peak_bytes{label} {s['hbm_peak_bytes']}",
+        "# HELP pstpu:hbm_limit_bytes Bytes the allocator may hand out on "
+        "that device",
+        "# TYPE pstpu:hbm_limit_bytes gauge",
+        f"pstpu:hbm_limit_bytes{label} {s['hbm_limit_bytes']}",
+        "# HELP pstpu:hbm_reserved_bytes Bytes the runtime holds outside "
+        "bytes in use for programs' temporaries (one scratch region, the "
+        "largest program's)",
+        "# TYPE pstpu:hbm_reserved_bytes gauge",
+        f"pstpu:hbm_reserved_bytes{label} {s['hbm_reserved_bytes']}",
+        "# HELP pstpu:hbm_peak_rises_total Reads after a dispatch's "
+        "enqueue or sync that found the allocator's peak higher, by phase",
+        "# TYPE pstpu:hbm_peak_rises_total counter",
+        *[
+            f'pstpu:hbm_peak_rises_total{{model_name="{model_name}",'
+            f'phase="{phase}"}} {n}'
+            for phase, n in s["hbm_peak_rises"].items()
+        ],
+        "# HELP pstpu:hbm_peak_rise_bytes_total Bytes the allocator's peak "
+        "rose by over those reads, by phase",
+        "# TYPE pstpu:hbm_peak_rise_bytes_total counter",
+        *[
+            f'pstpu:hbm_peak_rise_bytes_total{{model_name="{model_name}",'
+            f'phase="{phase}"}} {n}'
+            for phase, n in s["hbm_peak_rise_bytes"].items()
         ],
     ]
     # TTFT / e2e latency distributions (the reference dashboard's two

@@ -58,6 +58,16 @@ import pytest
 # fails at the name it finds there. Same mark, on that condition; what else
 # it asserts (nothing that was there changed, lists only grew at their ends)
 # is held against PR 44's parent by ``tests/chip_bench/test_bench_lfm.py``.
+#
+# Four tests (PR 38's cell in ``test_bench_ssm.py``, and ``test_what_the_
+# cell_reports`` of PR 40, PR 44 and PR 47) hold the SET of metrics whose
+# ``workloads`` list names their cell to be exactly what their PR left.
+# PR 49 appends three metrics of the device's memory that list every cell
+# (``hbm_high_water_gb`` the first), so each of those sets grew by three.
+# Same mark, while an entry of that name lists cells; what else the four
+# assert (the list-less metrics and the end-to-end ones a cell reports,
+# and the set itself among the entries that were there) is held by
+# ``tests/chip_bench/test_bench_memory.py``.
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PR36_TEST = ("test_bench_issue.py::"
               "test_the_six_are_the_last_of_per_layer_and_list_every_cell")
@@ -71,6 +81,11 @@ _PR40_TEST = ("test_bench_ssm.py::"
               "test_the_manifest_only_grew_since_the_parent")
 _PR40_NOT_IN = "moe_experts_touched"
 _PR40_CELL = "granite-4.0-h-micro.chat-saturated"
+_PR49_TESTS = ("test_bench_ssm.py::test_pr38_cell_reports_what_it_did",
+               "test_bench_ssm.py::test_what_the_cell_reports",
+               "test_bench_lfm.py::test_what_the_cell_reports",
+               "test_bench_afmoe.py::test_what_the_cell_reports")
+_PR49_FIRST = "hbm_high_water_gb"
 
 
 def pytest_collection_modifyitems(items):
@@ -88,6 +103,9 @@ def pytest_collection_modifyitems(items):
                   if m["name"] == _PR40_NOT_IN)
     if _PR40_CELL not in others and len(others) > 2:
         overtaken.append(((_PR40_TEST,), 40))
+    if any(m["name"] == _PR49_FIRST and m.get("workloads")
+           for m in per_layer):
+        overtaken.append((_PR49_TESTS, 47))
     for item in items:
         for tests, pr in overtaken:
             if item.nodeid.endswith(tests):
@@ -95,7 +113,8 @@ def pytest_collection_modifyitems(items):
                     strict=True, raises=AssertionError,
                     reason=f"asserts PR {pr}'s entries are the last of "
                            "their lists (PR 40: the only cell lists grew "
-                           "by); a PR may only append (see the note "
+                           "by; PR 47: the only metrics that name a "
+                           "cell); a PR may only append (see the note "
                            "above)"))
 
 

@@ -50,7 +50,13 @@ NEW_SERIES = (
     "pstpu:serving_compiles_total", "pstpu:serving_compile_seconds_total",
     # The hand-off from prefill to decode (PR 45).
     "pstpu:decode_rows_first_total", "pstpu:decode_rows_joined_total",
+    # What holds the HBM (PR 49).
+    "pstpu:hbm_resident_bytes", "pstpu:hbm_bytes_in_use",
+    "pstpu:hbm_peak_bytes", "pstpu:hbm_limit_bytes",
+    "pstpu:hbm_reserved_bytes",
+    "pstpu:hbm_peak_rises_total", "pstpu:hbm_peak_rise_bytes_total",
 )
+HBM_ATTRS = {"hbm", "hbm_peak", "hbm_limit", "hbm_reserved"}
 
 
 def _cfg(**over):
@@ -320,16 +326,35 @@ async def test_prefill_counters_say_what_the_dispatches_carried():
     assert stats["prefill_left_waiting_total"] >= stops["rows"]
 
 
-async def test_issue_span_carries_what_the_counters_count(tmp_path):
+def _stub_device_memory(monkeypatch, limit=16 * 10 ** 9):
+    """An allocator for the CPU, which reports none: every read finds 4 KB
+    more in use than the last, so the peak rises at every read."""
+    from production_stack_tpu.engine.runner import ModelRunner
+
+    in_use = [10 ** 9]
+
+    def device_memory(self):
+        in_use[0] += 4096
+        return [{"bytes_in_use": in_use[0], "peak_bytes_in_use": in_use[0],
+                 "bytes_limit": limit} for _ in self.mesh.devices.flat]
+
+    monkeypatch.setattr(ModelRunner, "device_memory", device_memory)
+
+
+async def test_issue_span_carries_what_the_counters_count(tmp_path,
+                                                          monkeypatch):
     """A capture's prefill ``pstpu.issue`` spans hold ``tokens``,
     ``prog_rows``, ``prog_t``, ``left`` and ``stop``; their sums over the
-    capture are the counters' deltas over it."""
+    capture are the counters' deltas over it. Where the devices report
+    their memory (a stub here), the two executor-side spans say what the
+    allocator read."""
     from production_stack_tpu.profiling import DeviceProfiler
     from production_stack_tpu.utils import prefill_rectangle
 
     profiler = DeviceProfiler()
     if not profiler.available():
         pytest.skip("jax.profiler unavailable in this image")
+    _stub_device_memory(monkeypatch)
     engine = ServingEngine(_cfg(max_prefill_seqs=2))
     await engine.start()
     try:
@@ -368,6 +393,27 @@ async def test_issue_span_carries_what_the_counters_count(tmp_path):
     assert after["prefill_tokens_padded_total"] \
         - before["prefill_tokens_padded_total"] == sum(
             int(s["prog_rows"]) * int(s["prog_t"]) for s in prefills)
+    # The allocator's reading on the executor-side spans: after every
+    # enqueue (with what residents and programs in flight explain) and
+    # after every sync, rising as the stub does, step by step.
+    enqueues = [s for n, s in events if n == "pstpu.issue.enqueue"]
+    syncs = [s for n, s in events if n == "pstpu.fetch.sync"]
+    assert enqueues and syncs
+    assert all(set(s) >= HBM_ATTRS | {"hbm_explained", "step"}
+               for s in enqueues)
+    assert all(set(s) >= HBM_ATTRS | {"step"} and "hbm_explained" not in s
+               for s in syncs)
+    for span in enqueues + syncs:
+        assert int(span["hbm"]) == int(span["hbm_peak"]) > 10 ** 9
+        assert int(span["hbm_limit"]) == 16 * 10 ** 9
+    assert all(0 < int(s["hbm_explained"]) <= int(s["hbm"])
+               for s in enqueues)
+    by_step = sorted(enqueues, key=lambda s: int(s["step"]))
+    assert [int(s["hbm"]) for s in by_step] == \
+        sorted(int(s["hbm"]) for s in by_step)
+    # The loop's own spans (event-loop side) carry none of it.
+    assert not [k for n, s in events if n in ("pstpu.issue", "pstpu.fetch")
+                for k in s if k.startswith("hbm")]
 
 
 # ------------------------------------------------ what stopped admission
@@ -603,6 +649,14 @@ async def test_new_series_are_rendered_and_pass_the_lint():
     assert sample["pstpu:decode_row_steps_total"] == 16
     assert sample["pstpu:decode_row_steps_wasted_total"] == 5
     assert sample["pstpu:loop_fetch_wait_seconds_total"] > 0
+    # The ledger's residents a holder (the kv holder is the pool), the
+    # reading and the rises: 0 on a backend that reports no memory.
+    assert ('pstpu:hbm_resident_bytes{model_name="tiny-llama",holder="kv",'
+            f'device="cpu:0"}} {engine.runner.kv_pool_bytes}') in text
+    assert 'holder="weights",device="cpu:0"} ' in text
+    assert 'pstpu:hbm_peak_rises_total{model_name="tiny-llama",' \
+        'phase="serving"} 0' in text
+    assert sample["pstpu:hbm_bytes_in_use"] == 0
     # PL004: renderer, registry and docs tables agree (the whole tree).
     assert check_metrics(default_project_root()) == []
 
@@ -636,6 +690,56 @@ async def test_http_surface_histograms_observe_each_request_once():
         assert "peak_bytes_in_use" in version["engine"]
     finally:
         await client.close()
+
+
+async def test_debug_memory_answers_inside_the_debug_gate_only(monkeypatch):
+    """``GET /debug/memory``: the ledger, the events and every device's
+    reading now; a plain 404 under ``--no-debug-endpoints`` (PL012)."""
+    _stub_device_memory(monkeypatch)
+    for debug in (True, False):
+        server = APIServer(ServingEngine(_cfg(debug_endpoints=debug)))
+        client = TestClient(TestServer(server.build_app()))
+        await client.start_server()
+        try:
+            resp = await client.post("/v1/completions", json={
+                "model": "tiny-llama", "prompt": "abc", "max_tokens": 3,
+                "temperature": 0, "ignore_eos": True,
+            })
+            assert resp.status == 200
+            resp = await client.get("/debug/memory")
+            if not debug:
+                assert resp.status == 404
+                continue
+            body = await resp.json()
+        finally:
+            await client.close()
+        assert resp.status == 200
+        assert body["phase"] == "serving" and body["device"] == "cpu:0"
+        assert list(body["residents"]) == [
+            "weights", "kv", "state", "spec", "lora", "other"]
+        assert sum(body["residents"].values()) == \
+            body["built"]["bytes_in_use"]
+        # What the programs first run under traffic loaded is resident
+        # since (no warm-up here: every program was).
+        assert body["resident_bytes"] == body["built"]["bytes_in_use"] + sum(
+            p["held_bytes"] for p in body["programs"].values())
+        assert body["residents_by_device"] == {"cpu:0": body["residents"]}
+        assert set(body["now"]) == {"cpu:0"}
+        assert body["now"]["cpu:0"]["bytes_in_use"] > body["resident_bytes"]
+        # The first read (here the build's: no warm-up) is the first
+        # event and nobody's rise; every other is one dispatch's.
+        assert body["events"][0]["at"] == "boot"
+        assert body["rises"]["serving"] == len(body["events"]) - 1 > 0
+        for event in body["events"][1:]:
+            assert set(event) >= {
+                "step", "phase", "at", "kind", "family", "in_flight",
+                "compiled", "rose_by", "bytes_in_use", "peak_bytes_in_use",
+                "bytes_limit", "explained", "unexplained"}
+            assert event["family"] in body["programs"]
+        # No warm-up here: every program was first seen under traffic.
+        assert body["programs"] and all(
+            "in_company" in p and p["measured"] == "serving"
+            for p in body["programs"].values())
 
 
 # ------------------------------------------------------------ the capture
@@ -684,6 +788,9 @@ async def test_capture_holds_loop_spans_a_clock_anchor_and_no_frames(
         assert want in names, want
     # No Python-frame event (the tracer names them "$file:line function").
     assert not [n for n in names if n.startswith("$")]
+    # The CPU reports no memory: no span says anything of the allocator.
+    assert not [k for n, s in events if n.startswith("pstpu.")
+                for k in s if k.startswith("hbm")]
     clock = [stats for name, stats in events if name == "pstpu.clock"]
     assert len(clock) == 1
     assert abs(int(clock[0]["wall_ns"]) - time.time_ns()) < 600e9

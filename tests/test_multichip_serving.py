@@ -218,7 +218,8 @@ async def test_mesh_metrics_rendered():
     # the (kv-head-sharded) pool.
     dev_lines = [
         ln for ln in text.splitlines()
-        if ln.startswith("pstpu:hbm_kv_bytes{")
+        if ln.startswith("pstpu:hbm_resident_bytes{")
+        and 'holder="kv"' in ln
     ]
     assert len(dev_lines) == 2, dev_lines
     per_dev = [int(float(ln.rsplit(" ", 1)[1])) for ln in dev_lines]
@@ -359,5 +360,5 @@ def test_tp2_served_parity_real_engines():
     for a, b in zip(tp2_outs, tp1_outs):
         assert a["choices"][0]["text"] == b["choices"][0]["text"]
     assert "pstpu:mesh_tp_size" in tp2_metrics
-    assert 'pstpu:hbm_kv_bytes{model_name="tiny-llama-8kv",device="cpu:0"}' \
-        in tp2_metrics
+    assert ('pstpu:hbm_resident_bytes{model_name="tiny-llama-8kv",'
+            'holder="kv",device="cpu:0"}') in tp2_metrics

@@ -204,6 +204,12 @@ ROUTES: Tuple[Route, ...] = (
     Route("GET", "/debug/programs", ("engine",), True, False, None,
           "Dispatch-program audit: whole-KV-pool copies and temporaries "
           "of one compiled program of each kind."),
+    Route("GET", "/debug/memory", ("engine",), True, False, None,
+          "What holds the device's memory: the ledger of residents by "
+          "holder and of what each dispatch program held while it ran, "
+          "the events that raised the allocator's peak, and every "
+          "device's reading now (`?analyze=1` attaches the named "
+          "programs' `memory_analysis()`)."),
     Route("GET", "/fleet", ("router",), False, False, None,
           "Fleet-wide live perf rollup (docs/OBSERVABILITY.md)."),
     Route("POST", "/v1/files", ("router",), False, False, None,

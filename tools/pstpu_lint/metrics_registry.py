@@ -183,10 +183,48 @@ REGISTRY: Tuple[Series, ...] = (
     Series("pstpu:mesh_devices", "gauge", ("model_name",),
            (ENGINE,), ("catalogue", "multichip"),
            "Devices the serving mesh occupies (dp x sp x tp)"),
-    Series("pstpu:hbm_kv_bytes", "gauge", ("model_name", "device"),
-           (ENGINE,), ("catalogue", "multichip"),
-           "KV-pool bytes resident per mesh device (payload + scale "
-           "sidecars; kv-head-sharded at tp>1)"),
+    Series("pstpu:hbm_resident_bytes", "gauge",
+           ("model_name", "holder", "device"),
+           (ENGINE,), ("catalogue", "multichip", "loop"),
+           "Bytes resident per mesh device by holder, from the memory "
+           "ledger built when `start()` ends: `weights`, `kv` (the pool's "
+           "payload + scale sidecars; kv-head-sharded at tp>1), `state`, "
+           "`spec`, `lora`, and `other` = the allocator's bytes in use "
+           "beyond the named holders (`GET /debug/memory` lists its "
+           "largest arrays)"),
+    Series("pstpu:hbm_bytes_in_use", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "loop"),
+           "Allocator bytes in use on the fullest mesh device at this "
+           "scrape (`memory_stats()`; 0 where the backend reports none)"),
+    Series("pstpu:hbm_peak_bytes", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "loop"),
+           "The allocator's high-water mark of bytes in use since process "
+           "start on that device (loading's transients included; a "
+           "program's temporaries are in `pstpu:hbm_reserved_bytes`)"),
+    Series("pstpu:hbm_limit_bytes", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "loop"),
+           "Bytes the allocator may hand out on that device; the limit "
+           "less the peak is what is left before an allocation fails"),
+    Series("pstpu:hbm_reserved_bytes", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "loop"),
+           "Bytes the runtime holds on that device OUTSIDE bytes in use "
+           "and its peak for the programs' temporaries "
+           "(`memory_stats()['bytes_reserved']`): on a TPU one scratch "
+           "region the size of the largest program's temporaries so far, "
+           "shared by the programs as they run one at a time. In use + "
+           "reserved against the limit is what an allocation has left"),
+    Series("pstpu:hbm_peak_rises_total", "counter",
+           ("model_name", "phase"), (ENGINE,), ("catalogue", "loop"),
+           "Reads after a dispatch's enqueue or sync that found the "
+           "allocator's peak higher than the last read, by `phase` "
+           "(`warmup` / `serving`); each is one event of `GET "
+           "/debug/memory` and one log line. `serving` staying 0 is what "
+           "warm-up is for: alert on its increase"),
+    Series("pstpu:hbm_peak_rise_bytes_total", "counter",
+           ("model_name", "phase"), (ENGINE,), ("catalogue", "loop"),
+           "Bytes the allocator's peak rose by over those reads, by "
+           "`phase`: its delta over a window is how far the peak rose "
+           "under that window's traffic"),
     # --------------------------------------------- engine: speculative
     Series("pstpu:spec_enabled", "gauge", ("model_name",),
            (ENGINE,), ("catalogue", "speculative"),
