@@ -425,6 +425,10 @@ class ModelRunner:
         # dispatch sums them over its steps and hands them out beside its
         # pools, and they are read when a later fetch has made them ready.
         self.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))
+        # The states (by name) the module can carry across a segment
+        # boundary inside a packed prefill row (``prefill_packs``).
+        self.states_crossing_segments = frozenset(
+            getattr(model, "STATES_CROSSING_SEGMENTS", ()))
         self.fwd_stats_total = {
             kind: dict.fromkeys(self.fwd_stats, 0)
             for kind in ("decode", "prefill")}
@@ -1347,17 +1351,21 @@ class ModelRunner:
         segments, so that only the row's end is padding. False: a
         ``[rows, T]`` rectangle, a row a sequence, every row padded to T.
 
-        A row can be packed where nothing but attention ties a token to
-        its sequence and the kernel can tell the segments apart: the chunk
-        reads its paged rows in place (K/V rows in two pools or latent
-        rows in one) and the flash prefill kernel covers every packed row
-        this config can dispatch (``prefill_reads_pool``, and
-        ``prefill_kernel_covers(..., packed=True)``), the model keeps no
-        per-row state (a scan runs a row of ONE sequence from ONE slot),
-        and no per-row operand rides the forward (LoRA's adapter of a row,
-        the speculative draft's ring of a row)."""
+        A row can be packed where whatever ties a token to its sequence
+        can tell the segments apart: the chunk reads its paged rows in
+        place (K/V rows in two pools or latent rows in one) and the flash
+        prefill kernel covers every packed row this config can dispatch
+        (``prefill_reads_pool``, and ``prefill_kernel_covers(...,
+        packed=True)``); every state the model keeps a sequence is one its
+        module declares to cross a segment boundary inside the row (its
+        ``STATES_CROSSING_SEGMENTS``: a finite window of inputs does, a
+        scan that runs a row of ONE sequence from ONE slot does not); and
+        no per-row operand rides the forward (LoRA's adapter of a row, the
+        speculative draft's ring of a row)."""
         mc = self.model_config
-        return self.prefill_reads_pool and not self.state_specs \
+        return self.prefill_reads_pool \
+            and all(s.name in self.states_crossing_segments
+                    for s in self.state_specs) \
             and not self.lora_stacks and not self.spec_n and all(
                 prefill_kernel_covers(
                     t, mc.num_heads, self.kv_spec.kv_heads,
@@ -2697,9 +2705,11 @@ class ModelRunner:
         block_tables = packed[NUM_SCALARS * n: NUM_SCALARS * n + n * mb].reshape(n, mb)
         token_ids = packed[NUM_SCALARS * n + n * mb:].reshape(b, t)
         if segs:
-            last_hidden, kv_k, kv_v, fwd_stats = self._forward_packed_row(
+            (last_hidden, kv_k, kv_v, state_pools,
+             fwd_stats) = self._forward_packed_row(
                 params, token_ids, block_tables, chunk_start, chunk_lens,
-                kv_k, kv_v)
+                kv_k, kv_v, state_pools,
+                scalars[12] if self.state_specs else None)
             next_tokens, lp = self._sample_first_tokens(
                 params, last_hidden, counts0, temps, top_k, top_p,
                 seed_base, gen0, presence, frequency, has_penalties,
@@ -2883,17 +2893,23 @@ class ModelRunner:
         return next_tokens, lp
 
     def _forward_packed_row(self, params, token_ids, block_tables,
-                            chunk_start, chunk_lens, kv_k, kv_v):
+                            chunk_start, chunk_lens, kv_k, kv_v,
+                            state_pools, state_slots):
         """The forward of a PACKED prefill row (``prefill_packs``):
         ``token_ids`` [1, t] holds the sequences' chunks end to end from
         token 0, segment i ``chunk_lens[i]`` tokens at positions
         ``chunk_start[i]`` on, its history the pool's slots below
         ``chunk_start[i]`` by ``block_tables[i]``. Everything but
-        attention is a function of a token, so the model runs the row as
-        it runs any row; ``attend`` is told where each segment begins
-        (``KVView.seg_lens``). Returns (the hidden state of each segment's
-        last token [n, D], the pools with the row's K/V written to each
-        segment's slots, the forward's counters)."""
+        attention and a state the module keeps is a function of a token,
+        so the model runs the row as it runs any row; ``attend`` is told
+        where each segment begins (``KVView.seg_lens``), and so is whatever
+        keeps a state: the module takes and hands back a row of state a
+        SEGMENT (its ``STATES_CROSSING_SEGMENTS``), read here from the
+        segments' slots (``state_slots``; from zeros where a segment is its
+        sequence's first chunk) and written back to them, as a rectangle's
+        rows are. Returns (the hidden state of each segment's last token
+        [n, D], the pools with the row's K/V written to each segment's
+        slots, the state pools, the forward's counters)."""
         cfg = self.config
         seg_end = jnp.cumsum(chunk_lens)
         seg, within = segment_of_token(chunk_lens, token_ids.shape[1])
@@ -2904,10 +2920,16 @@ class ModelRunner:
             kv_lens=chunk_start, seg_lens=chunk_lens,
             block_size=cfg.block_size,
         )
+        state_in = {}
+        if self.state_specs:
+            state_in["state"] = self._read_state_rows(
+                state_pools, state_slots, fresh=chunk_start == 0)
         hidden, k_new, v_new, *extra = self._forward(
             params, self.model_config, token_ids, positions, seg_end[-1:],
-            view, act_sharding=self._act_sharding, lora=None,
+            view, act_sharding=self._act_sharding, lora=None, **state_in,
         )
+        if self.state_specs:
+            segs_state = extra.pop(0)
         fwd_stats = extra.pop(0) if self.fwd_stats else ()
         last_hidden = hidden[0, jnp.maximum(seg_end - 1, 0)]      # [n, D]
         with jax.named_scope("kv_write"):
@@ -2916,7 +2938,11 @@ class ModelRunner:
                 chunk_lens, cfg.block_size,
                 source_start=seg_end - chunk_lens,
             )
-        return last_hidden, kv_k, kv_v, fwd_stats
+            if self.state_specs:
+                with jax.named_scope("state_write"):
+                    state_pools = write_state_rows(
+                        state_pools, segs_state, state_slots)
+        return last_hidden, kv_k, kv_v, state_pools, fwd_stats
 
     def _issue_prefill(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config

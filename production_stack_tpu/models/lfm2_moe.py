@@ -18,7 +18,11 @@ stats). Of its own:
     taps, zeros before the sequence, NO activation and no bias:
     ops/gated_delta.py:conv_step / conv_chunk with ``silu=False``); ``y = C
     * c``; ``W_out``. A sequence's whole state a layer is the last L - 1
-    tokens of ``z``.
+    tokens of ``z``: a finite window and no recurrence, so it crosses a
+    segment boundary inside a PACKED prefill row (``KVView.seg_lens``:
+    several sequences' chunks end to end in one row, a row of state a
+    segment; ops/gated_delta.py:conv_packed_row), which this module
+    declares (``STATES_CROSSING_SEGMENTS``).
   * A layer is TWO independent kinds: its operator (``cfg.layer_types``:
     ``conv`` / ``full_attention``, in ANY order: the published list is not
     equal periods) and its FFN (dense below ``first_k_dense_replace``,
@@ -119,6 +123,12 @@ PAGED_DECODE_VALIDATED = True
 FLOAT32_LEAVES = ("w_router", "router_bias")
 # int32 counters ``forward`` returns last, summed over its sparse layers.
 FORWARD_STATS = moe.STATS
+# The states of ``cache_specs`` (by ``StateSpec.name``) that cross a segment
+# boundary inside a packed prefill row: where the view says ``seg_lens``,
+# ``forward`` takes ``state`` with a row a SEGMENT and runs the one row of
+# tokens from it (``_conv_op``). A module whose every state is named here may
+# be dispatched packed rows (engine/runner.py:prefill_packs).
+STATES_CROSSING_SEGMENTS = ("conv",)
 # What the weights' sum takes (the published modeling code's; the latent
 # family's is 1e-20).
 ROUTE_EPS = 1e-6
@@ -352,18 +362,23 @@ def _attention_op(cfg, rope, positions, chunk_lens, hidden, lp, view, layer):
     return branch, k.transpose(2, 0, 1, 3), v.transpose(2, 0, 1, 3)
 
 
-def _conv_op(cfg, chunk_lens, hidden, lp, conv):
+def _conv_op(cfg, chunk_lens, hidden, lp, conv, seg_lens=None):
     """The gated short convolution's branch [B, T, D] from ``conv`` (a
     row's conv state [B, *its spec's shape]) and the state after each row's
-    ``chunk_lens`` valid tokens."""
+    ``chunk_lens`` valid tokens. ``seg_lens`` [S] given: ``hidden`` is ONE
+    packed row [1, T, D] of S segments and ``conv`` a SEGMENT's state a row
+    [S, *its spec's shape], before and after."""
     b, t, d = hidden.shape
     with jax.named_scope("attn_proj"):
         x = rms_norm(hidden, lp["op_norm"], cfg.rms_norm_eps)
         bcx = x @ lp["in_proj"]                               # [B, T, 3D]
     with jax.named_scope("attn_core"), jax.named_scope("short_conv"):
-        state = conv.reshape(b, cfg.conv_l_cache - 1, d)
+        state = conv.reshape(conv.shape[0], cfg.conv_l_cache - 1, d)
         z = bcx[..., :d] * bcx[..., 2 * d:]
-        if t == 1:
+        if seg_lens is not None:
+            c, state = gd.conv_packed_row(z, state, lp["conv_w"], seg_lens,
+                                          silu=False)
+        elif t == 1:
             c, state = gd.conv_step(z[:, 0], state, lp["conv_w"],
                                     chunk_lens > 0, silu=False)
             c = c[:, None]
@@ -433,7 +448,13 @@ def forward(
     the first token, one array per spec of ``cache_specs``, rows first as
     the runner's pools are; ``None`` starts every row from zeros (a whole
     sequence in one call). The returned state is that after each row's last
-    valid token. The view's layer axis counts the attention layers only."""
+    valid token. The view's layer axis counts the attention layers only.
+
+    A PACKED row (``view.seg_lens`` [S]: B is 1, the sequences' chunks end
+    to end from token 0, ``chunk_lens`` the row's live tokens): ``state``
+    in and out has a row a SEGMENT, [S, n_conv, ...]; the convolution and
+    ``attend`` tell the segments apart, everything else is a function of
+    a token."""
     b, t = token_ids.shape
     nd = cfg.first_k_dense_replace
     ns = cfg.num_layers - nd
@@ -441,8 +462,9 @@ def forward(
         hidden = params["embed"][token_ids]
         hidden = hidden.astype(view.act_dtype(params["embed"].dtype))
     if state is None:
+        seqs = b if view.seg_lens is None else view.seg_lens.shape[0]
         state = tuple(
-            jnp.zeros((b, s.layers, *s.shape), s.dtype or hidden.dtype)
+            jnp.zeros((seqs, s.layers, *s.shape), s.dtype or hidden.dtype)
             for s in cache_specs(cfg).state)
     conv_all, = state
     rope = _rope_cos_sin(positions, cfg.head_dim_, cfg.rope_theta)
@@ -472,7 +494,8 @@ def forward(
 
     def conv_operator(hidden, conv, at):
         branch, conv = _conv_op(cfg, chunk_lens, hidden,
-                                layer_of(layers["conv"], at), conv)
+                                layer_of(layers["conv"], at), conv,
+                                view.seg_lens)
         return hidden + branch, conv
 
     def dense_layer(carry, i):

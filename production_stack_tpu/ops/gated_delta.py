@@ -11,7 +11,11 @@ Per head, with state ``S`` in R^{dk x dv} (float32), for token t:
 (``prepare``). Before that, q, k and v pass a causal depthwise convolution
 of width W over the token axis and a SiLU; the *conv state* of a sequence is
 the last W - 1 inputs of each channel. ``conv_step`` / ``conv_chunk`` are
-also the convolution of a Mamba-2 layer (ops/ssd.py), which adds a bias.
+also the convolution of a Mamba-2 layer (ops/ssd.py), which adds a bias;
+``conv_packed_row`` is ``conv_chunk`` for ONE row in which several
+sequences' chunks lie end to end, a conv state a segment (a packed prefill
+row: models/lfm2_moe.py; the scans behind the other two models' convolutions
+have no such form yet).
 
 ``gdn_chunk`` is the chunkwise form of the same recurrence (chunks of up to
 64 tokens, the WY representation: HF's ``torch_chunk_gated_delta_rule`` is a
@@ -51,6 +55,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+from production_stack_tpu.ops.attention import segment_of_token
 
 CHUNK = 64
 L2_EPS = 1e-6
@@ -181,6 +187,62 @@ def conv_chunk(x: jax.Array,           # [B, T, C]
     idx = lens[:, None] + jnp.arange(width - 1, dtype=jnp.int32)[None, :]
     new_state = jnp.take_along_axis(ext, idx[:, :, None], axis=1)
     return (jax.nn.silu(y) if silu else y).astype(x.dtype), new_state
+
+
+def conv_packed_row(x: jax.Array,           # [1, T, C]
+                    conv_state: jax.Array,  # [S, W-1, C]
+                    w: jax.Array,           # [W, C]
+                    seg_lens: jax.Array,    # [S] tokens of each segment
+                    bias=None,              # [C], or None
+                    silu: bool = True,      # False: the sum itself
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """``conv_chunk`` of ONE row that holds S sequences' chunks end to end
+    from token 0 (a packed prefill row, ops/attention.py:KVView.seg_lens),
+    segment s continuing ``conv_state[s]``: the token at offset j of segment
+    s reads lag k from the row's token k before it where j >= k and from
+    ``conv_state[s]`` where j < k, so nothing crosses from a segment into
+    the next. Returns (the row [1, T, C], the conv state after each
+    segment's last token [S, W-1, C]: the last W - 1 inputs of
+    ``conv_state[s]`` ++ its tokens; a segment of length 0 keeps its state).
+    Equals ``conv_chunk`` over the same sequences a row each, value for
+    value: the same inputs meet the same taps in the same order.
+
+    What a segment's first W - 1 tokens read of its state is picked by a
+    one-hot product (S * (W-1) rows against T tokens: exact for finite
+    values, a value times one and zeros) and the state a segment leaves by
+    a gather of S * (W-1) rows; everything else is the row shifted."""
+    width = w.shape[0]
+    t = x.shape[1]
+    s = seg_lens.shape[0]
+    seg, within = segment_of_token(seg_lens, t)
+    xs = x[0].astype(conv_state.dtype)                           # [T, C]
+    flat = conv_state.reshape(s * (width - 1), -1)
+    entry = jnp.arange(s * (width - 1), dtype=jnp.int32)[None, :]
+    lagged = [xs]                                  # lagged[k][t] = input t-k
+    for k in range(1, width):
+        shifted = jnp.pad(xs, ((k, 0), (0, 0)))[:t]
+        # Lag k of offset j < k is entry W-1-k+j of the segment's state.
+        pick = (within < k)[:, None] & (
+            entry == (seg * (width - 1) + width - 1 - k + within)[:, None])
+        from_state = jnp.dot(pick.astype(flat.dtype), flat, precision=_HI,
+                             preferred_element_type=jnp.float32)
+        lagged.append(jnp.where((within >= k)[:, None], shifted,
+                                from_state.astype(xs.dtype)))
+    wf = w.astype(jnp.float32)
+    y = sum(lagged[width - 1 - i].astype(jnp.float32) * wf[i][None]
+            for i in range(width))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    # Entry i of the state after a segment of n tokens is input n + i of
+    # (its state ++ its tokens): the row's token start + n + i - (W-1), or
+    # where that lies before the segment, its state's entry n + i.
+    at = seg_lens[:, None] + jnp.arange(width - 1, dtype=jnp.int32)[None, :]
+    start = jnp.cumsum(seg_lens) - seg_lens
+    row_at = jnp.clip(start[:, None] + at - (width - 1), 0, t - 1)
+    kept = jnp.take_along_axis(
+        conv_state, jnp.clip(at, 0, width - 2)[:, :, None], axis=1)
+    new_state = jnp.where((at >= width - 1)[:, :, None], xs[row_at], kept)
+    return (jax.nn.silu(y) if silu else y).astype(x.dtype)[None], new_state
 
 
 # ------------------------------------------------------------- recurrence

@@ -131,3 +131,26 @@ def _reset_singletons():
     SingletonMeta._instances.clear()
     yield
     SingletonMeta._instances.clear()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """When a test module ends, drop what it compiled. A compiled CPU
+    program holds memory mappings for as long as a jit cache (or an engine
+    a module-scoped fixture built) holds it, a worker process runs many
+    modules, and the kernel bounds the mappings of ONE process
+    (``vm.max_map_count``, 65530): tests/test_lfm2_moe.py alone ends at
+    26,000, and a worker that reached the bound died inside XLA (a
+    segmentation fault reading the compile cache, an abort writing it), in
+    whatever test happened to run then. After the module's own fixtures are
+    gone, collect and clear JAX's in-memory caches: the same module then
+    ends at 800. What a later module needs again comes from the persistent
+    cache or is compiled again."""
+    yield
+    import gc
+
+    import jax
+
+    gc.collect()
+    jax.clear_caches()
+    gc.collect()

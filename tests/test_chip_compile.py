@@ -322,6 +322,8 @@ def _described_runner(v5e, model_dir: str, **engine):
     r.kv_value_dim = specs.paged_kv.head_dim if specs.latent is None \
         else specs.latent.rank
     r.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))
+    r.states_crossing_segments = frozenset(
+        getattr(model, "STATES_CROSSING_SEGMENTS", ()))
     r.num_kv_blocks = cfg.num_kv_blocks
     r.num_state_slots = cfg.max_num_seqs + 1 if specs.state else 0
     pool = (specs.paged_kv.layers, specs.paged_kv.kv_heads,
@@ -826,7 +828,7 @@ def test_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, program):
     ("kanana-2-30b-a3b-d8", 4, True, True),
     ("xing4.0-29b-a4b-d7", 4, True, True),
     ("granite-4.0-h-micro", 8, True, False),
-    ("lfm2-8b-a1b-d16", 7, True, False),
+    ("lfm2-8b-a1b-d16", 4, True, True),
     ("trinity-mini-d8", 5, True, True)])
 def test_prefill_family_counts_of_the_deployments(v5e, name, families,
                                                   in_place, packs):
@@ -844,7 +846,11 @@ def test_prefill_family_counts_of_the_deployments(v5e, name, families,
     4 prefill programs, compiled here for a described v5e into an empty
     cache one variant each, are 17.2 and 21.5 MB where the 7 were 28.1
     and 35.8 (PERF.md section 6, PR 48), a boot holds three prefill
-    families fewer, and a configuration's cache is capped at 192 MiB."""
+    families fewer, and a configuration's cache is capped at 192 MiB.
+    lfm2-8b-a1b-d16 follows in PR 50, 7 -> 4: its one state is the short
+    convolution's last two inputs, which cross a segment boundary inside
+    the row (``STATES_CROSSING_SEGMENTS``); the two deployments whose
+    states are scans' keep their rectangles."""
     r = _deployment_runner(v5e, name)
     assert r.prefill_reads_pool is in_place
     assert r.prefill_packs is packs
@@ -885,6 +891,16 @@ def _digest(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
+def _prefill_text_digest(r, fam) -> str:
+    """``_digest`` of what prefill family ``fam`` of runner ``r`` lowers to
+    for its described device, as the tables above and below hold it."""
+    text = r._lower_prefill(r._abstract_params(), *fam) \
+        .compiler_ir().operation.get_asm(enable_debug_info=False)
+    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
+    assert "BODY" in text
+    return _digest(text)
+
+
 @pytest.mark.parametrize("name,rows,t", sorted(_PARENT_PREFILL_TEXT))
 def test_rectangle_prefill_programs_lower_to_the_parents_text(v5e, name,
                                                               rows, t):
@@ -893,21 +909,18 @@ def test_rectangle_prefill_programs_lower_to_the_parents_text(v5e, name,
     narrowest and the widest family of each lowers for a v5e to the text
     it lowered to there."""
     r = _deployment_runner(v5e, name)
-    if r.kv_pools == 1:
+    if name not in ("olmo-hybrid-7b-d16", "granite-4.0-h-micro"):
         # The latent deployments' own dispatches are packed rows since PR
-        # 48; what is held to the parent's text is the rectangle program a
-        # runner with an adapter or a draft's ring a row still builds
+        # 48 and the short-convolution deployment's since PR 50; what is
+        # held to the parent's text is the rectangle program a runner with
+        # an adapter or a draft's ring a row still builds
         # (``prefill_packs`` false: the cached property, said for it).
         assert r.prefill_packs
         r.__dict__["prefill_packs"] = False
     assert not r.prefill_packs
     fams = r.reachable_prefill_families()
     fam = next(f for f in (fams[0], fams[-1]) if f[:2] == (rows, t))
-    text = r._lower_prefill(r._abstract_params(), *fam) \
-        .compiler_ir().operation.get_asm(enable_debug_info=False)
-    text = re.sub(r'\\22body\\22: \\22[^\\]*\\22', "BODY", text)
-    assert "BODY" in text
-    assert _digest(text) == _PARENT_PREFILL_TEXT[name, rows, t]
+    assert _prefill_text_digest(r, fam) == _PARENT_PREFILL_TEXT[name, rows, t]
 
 
 @pytest.mark.parametrize("kernel", sorted(_PARENT_KERNEL_JAXPR))
@@ -942,42 +955,78 @@ def test_rectangle_prefill_kernels_trace_to_the_parents_jaxpr(kernel):
     assert _digest(str(jaxpr)) == _PARENT_KERNEL_JAXPR[kernel]
 
 
+# What a packed prefill program of a deployment WITHOUT state lowered to for
+# a described v5e at PR 49 (the parent of PR 50, which gave the packed row's
+# forward a state to read and write where the module keeps one): digests as
+# ``_PARENT_PREFILL_TEXT``'s. The added reads and writes hang on
+# ``state_specs``, a Python value, so these programs hold none of them.
+_PARENT_PACKED_TEXT = {
+    ("qwen2.5-3b", 2048): "6400a8983d1865ae",
+    ("kanana-2-30b-a3b-d8", 1024): "7f5e64a67ccf7a51",
+    ("trinity-mini-d8", 2048): "c518ae71e225d241",
+}
+
+
+@pytest.mark.parametrize("name,t", sorted(_PARENT_PACKED_TEXT))
+def test_stateless_packed_prefill_programs_lower_to_the_parents_text(v5e,
+                                                                     name, t):
+    """Dense K/V rows, latent rows and a bounded span: the fullest packed
+    program of each lowers for a v5e to the text it lowered to before a
+    packed row could carry a state."""
+    from production_stack_tpu.engine.runner import _bucket
+
+    r = _deployment_runner(v5e, name)
+    assert r.prefill_packs and not r.state_specs
+    fam = (1, t, _bucket(r.config.max_blocks_per_seq, 1,
+                         r.config.max_blocks_per_seq), False)
+    assert fam in r.reachable_prefill_families()
+    assert _prefill_text_digest(r, fam) == _PARENT_PACKED_TEXT[name, t]
+
+
 @pytest.mark.parametrize("name,t", [("qwen2.5-3b", 2048),
                                     ("mistral-7b-d16", 512),
                                     ("qwen2.5-3b", 128),
                                     ("kanana-2-30b-a3b-d8", 1024),
-                                    ("xing4.0-29b-a4b-d7", 1024)])
+                                    ("xing4.0-29b-a4b-d7", 1024),
+                                    ("lfm2-8b-a1b-d16", 1024),
+                                    ("lfm2-8b-a1b-d16", 128)])
 def test_packed_prefill_programs_compile_in_place_for_v5e(v5e, name, t):
     """A deployment's packed prefill program (one row of ``t`` tokens, up
     to 16 segments at a 2048-token budget, 8 at 1024) compiles for a v5e,
     holds the packed flash kernel and no other execution of the chunk's
     attention, copies no pool (the segments' K/V, or latent rows since PR
-    48, go to their slots slab by slab out of the one row) and keeps the
-    temporaries of the rectangle it replaces."""
+    48, go to their slots slab by slab out of the one row; since PR 50 the
+    segments' conv state of lfm2-8b-a1b-d16 from and to its slot pool
+    likewise) and keeps the temporaries of the rectangle it replaces."""
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops.attention import prefill_attn_path
     from production_stack_tpu.ops.kv_write import pool_copies
 
     r = _deployment_runner(v5e, name)
     latent = r.kv_pools == 1
-    assert r.prefill_packs and r._prefill_segs == (8 if latent else 16)
+    experts = bool(r.fwd_stats)
+    assert r.prefill_packs and r._prefill_segs == \
+        r.config.max_num_batched_tokens // 128
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     assert (1, t, full_mb, False) in r.reachable_prefill_families()
     compiled = r._lower_prefill(
         r._abstract_params(), 1, t, full_mb, False).compile()
     text = compiled.as_text()
-    assert pool_copies(text, [r.kv_k]) == []
+    assert pool_copies(text, [r.kv_k, *r.state_pools]) == []
     assert "paged_flash_prefill_packed" in text
     assert ("%paged_flash_prefill_packed_latent" in text) is latent
     assert "%paged_flash_prefill_latent" not in text
     assert prefill_attn_path(text) == "pallas"
-    # The one kernel; where experts are routed, the dense layers' call and
-    # the sparse scan's of it, and the scan's two grouped matmuls.
+    # The one kernel; where experts are routed, the sparse scan's call of
+    # it and the scan's two grouped matmuls, and the dense layers' call
+    # where those hold attention (lfm2's two are convolutions).
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        (4 if latent else 1)
+        (4 if latent else 3 if experts else 1)
     for scope in ("embed", "attn_proj", "attn_core", "ffn", "logits",
-                  "kv_write", "sample"):
+                  "kv_write", "sample") + (
+                      ("short_conv", "state_read", "state_write")
+                      if r.state_specs else ()):
         assert f"/{scope}/" in text, scope
     # 2048 tokens of a 3B model's activations: 163 MB at PR 46.
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
@@ -1297,23 +1346,30 @@ def test_latent_prefill_programs_hold_the_flash_kernel_on_v5e(v5e, name):
 # writing, kanana's 4003 and 4257 beside them: ONE attention operator, ONE
 # sparse FFN and one convolution a scan, whatever the depth and wherever the
 # attention layers stand; a second traced copy of the experts shows here).
+# The packed [1, 1024] prefill program (PR 50) counts 5626 where the rectangle
+# it replaces counted 4291: the segments' bookkeeping (which slot a token's
+# K/V goes to out of the one row, the packed kernel's tiles), as kanana's
+# packed program of the same row counts 5310 where its rectangle counted 4257.
 LFM_INSTRUCTIONS = 4900
+LFM_PACKED_INSTRUCTIONS = 6200
 
 
 @pytest.mark.parametrize("program", ["decode-64x32", "decode-16x32",
-                                     "prefill-8x128", "prefill-1x1024"])
+                                     "prefill-1x128", "prefill-1x1024"])
 def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
         v5e, program):
     """The decode program at the widest and at the window's 16-row bucket
-    and the fullest and the longest prefill program of lfm2-8b-a1b-d16's
+    and the shortest and the longest prefill program of lfm2-8b-a1b-d16's
     envelope (deployment.json's flags, published widths, all 32 experts of
     14 sparse layers, 12 conv layers' state in 65 slots) compile for a v5e,
     fit its HBM beside 10.80 GB of weights and the 1.61 GB K/V pool, and
-    copy neither a pool, nor the conv state a decode loop carries, nor an
-    expert stack. They hold the Mosaic kernels: the attention layers' paged
-    kernel (decode or prefill flash, over 64-lane KV heads two to a row) and
-    the two grouped matmuls of the sparse scan; the convolution is plain
-    XLA under its own scope."""
+    copy neither a pool, nor the conv state a decode loop carries or a
+    prefill row's segments read and write, nor an expert stack. They hold
+    the Mosaic kernels: the attention layers' paged kernel (decode, or the
+    PACKED prefill flash since PR 50: a prefill program is one row of up to
+    8 segments, the [1, 1024] row what the [8, 128] rectangle was; over
+    64-lane KV heads two to a row) and the two grouped matmuls of the
+    sparse scan; the convolution is plain XLA under its own scope."""
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops import gated_delta, ssd
     from production_stack_tpu.ops.attention import prefill_attn_path
@@ -1324,6 +1380,7 @@ def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
     assert [str(p.dtype) for p in r.state_pools] == ["bfloat16"]
     assert r.kv_k.shape == r.kv_v.shape == (4, 4, 12288 * 16, 128)
     assert r.prefill_reads_pool and r.fwd_stats
+    assert r.prefill_packs and r._prefill_segs == 8
     full_mb = _bucket(r.config.max_blocks_per_seq, 1,
                       r.config.max_blocks_per_seq)
     aparams = r._abstract_params()
@@ -1339,7 +1396,12 @@ def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
         lowered = r._lower_prefill(aparams, rows, t, full_mb, False)
     compiled = lowered.compile()      # raises where HBM or VMEM overflow
     text = compiled.as_text()
-    carried = [jax.ShapeDtypeStruct((rows, 12, 32, 128), jnp.bfloat16)]
+    # The state a decode loop carries of its rows, every step. (A packed
+    # prefill row's, a segment each, [8, 12, 32, 128], is read once and
+    # written once a dispatch; between the dense layers' scan and the
+    # sparse layers' the compiler lays its 0.8 MB out anew in VMEM, once.)
+    carried = [jax.ShapeDtypeStruct((rows, 12, 32, 128), jnp.bfloat16)] \
+        if decode else []
     experts = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for k in (
         "w_gate_up", "we_down") for shape in (
             sparse[k].shape, (14 * 32, *sparse[k].shape[2:]))]
@@ -1351,12 +1413,14 @@ def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
     assert gated_delta.step_path(text) is None
     if not decode:
         assert prefill_attn_path(text) == "pallas"
+        assert "%paged_flash_prefill_packed" in text
     for scope in ("embed", "attn_proj", "attn_core", "short_conv", "ffn",
                   "moe_route", "moe_experts", "moe_gmm", "logits",
                   "kv_write", "state_write", "sample"):
         assert f"/{scope}/" in text, scope
     instructions = sum(1 for ln in text.splitlines() if " = " in ln)
-    assert instructions < LFM_INSTRUCTIONS, instructions
+    assert instructions < (
+        LFM_INSTRUCTIONS if decode else LFM_PACKED_INSTRUCTIONS), instructions
     mem = compiled.memory_analysis()
     # Weights 10.80 GB, K/V 1.61 GB and 6.4 MB of slots are arguments; a
     # program's temporaries (0.11 GB at the widest) fit beside them.
@@ -1366,12 +1430,13 @@ def test_short_conv_expert_dispatch_programs_compile_in_place_for_v5e(
 
 
 def test_prefill_family_count_of_the_short_conv_deployment(v5e):
-    """7 prefill families (1 x {128..1024}, 4 x {128, 256}, 8 x 128), as
-    kanana's under the same token budget, none with a window."""
+    """4 prefill families of one row (1 x {128..1024}; 7 with 4 x {128,
+    256} and 8 x 128 before PR 50), as kanana's under the same token
+    budget since PR 48, none with a window."""
     r = _deployment_runner(v5e, "lfm2-8b-a1b-d16")
     fams = r.reachable_prefill_families()
-    assert len(fams) == 7 and {f[3] for f in fams} == {False}
-    assert all(rows * t <= 1024 for rows, t, _, _ in fams)
+    assert [f[:2] for f in fams] == [(1, 128), (1, 256), (1, 512), (1, 1024)]
+    assert {f[3] for f in fams} == {False}
 
 
 # ---- trinity-mini-d8: a span inside the paged kernels (PR 47)
@@ -1424,7 +1489,12 @@ def test_bounded_span_dispatch_programs_compile_in_place_for_v5e(v5e,
     assert pool_copies(text, [r.kv_k, *experts]) == []
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert ("%paged_flash_decode" in text) == decode
-    assert ("paged_flash_prefill_packed" in text) == (not decode)
+    # The computation's name, not the bare word: a decode program's table of
+    # source frames may name the packed kernel's wrapper where a small jitted
+    # helper it calls (same shapes: 8 rows, 8 segments) was first traced
+    # under lfm2's packed programs above and its jaxpr, frames and all, is
+    # cached.
+    assert ("%paged_flash_prefill_packed" in text) == (not decode)
     if not decode:
         assert prefill_attn_path(text) == "pallas"
     for scope in ("embed", "attn_proj", "attn_core", "attn_span", "ffn",

@@ -187,16 +187,28 @@ def test_c_a_decode_train_in_which_one_row_ends_early(engine):
         * (mc.num_layers - mc.first_k_dense_replace)
 
 
-def test_d_rows_of_unequal_length_in_one_prefill_rectangle():
-    """Five rows in one rectangle; two are shorter than the convolution's
-    three taps, so the conv state they leave holds zeros from before the
-    sequence; a row's padding reaches no expert."""
-    engine = make_engine(max_num_batched_tokens=1024)
+@pytest.mark.parametrize("attn_impl,form", [
+    ("window", "rectangle"), ("paged", "rectangle"), ("paged", "packed")],
+    ids=["window-rectangle", "paged-rectangle", "paged-packed"])
+def test_d_rows_of_unequal_length_in_one_prefill_rectangle(attn_impl, form):
+    """Five sequences in one dispatch; two are shorter than the
+    convolution's three taps, so the conv state they leave holds zeros from
+    before the sequence; padding reaches no expert. As a rectangle, a row
+    each (the window path every CPU engine takes, and the pool read in
+    place with ``prefill_packs`` forced false: what a runner with an
+    adapter a row builds), and as the segments of ONE packed row, where the
+    one-token sequence's neighbours lie right before and behind it."""
+    engine = make_engine(max_num_batched_tokens=1024, attn_impl=attn_impl)
+    assert engine.runner.prefill_packs is (attn_impl == "paged")
+    if form == "rectangle":
+        engine.runner.__dict__["prefill_packs"] = False
+        engine.scheduler.prefill_packed = False
     lens = (5, 12, 1, 2, 11)
     seqs = [add(engine, f"d{i}", prompt(n, 20 + i), 3)
             for i, n in enumerate(lens)]
     batches = drive(engine)
     assert batches[0].kind == "prefill" and len(batches[0].seqs) == 5
+    assert batches[0].packed is (form == "packed")
     for seq in seqs:
         assert worst(engine, seq) < TOL
     mc = engine.model_config
@@ -268,6 +280,138 @@ def test_h_decode_through_the_state_slots_and_the_pool(monkeypatch, mc,
     drive(eng)
     for seq in seqs:
         assert worst(eng, seq) < TOL
+
+
+# ---- packed rows: a segment a sequence, a slot's state a segment ------------
+PACKED_BUDGET = 512     # four segments a row; an equal share is 170 tokens
+
+
+@pytest.fixture(scope="module")
+def packed():
+    """An engine whose prefill dispatches are packed rows: the pool read in
+    place by the packed flash kernel (interpreted), the conv state of each
+    SEGMENT from and to its sequence's slot."""
+    eng = make_engine(attn_impl="paged", max_model_len=1024,
+                      num_kv_blocks=256,
+                      max_num_batched_tokens=PACKED_BUDGET)
+    assert eng.runner.state_specs and eng.runner.prefill_packs
+    assert eng.scheduler.prefill_packed and eng.runner._prefill_segs == 4
+    assert {f[0] for f in eng.runner.reachable_prefill_families()} == {1}
+    return eng
+
+
+def prefills(batches):
+    return [b for b in batches if b.kind == "prefill"]
+
+
+def test_i_a_prompt_crossing_three_packed_rows_beside_two_neighbours(packed):
+    """Three prompts longer than their share of three successive rows: each
+    crosses twice through its slot, and its first tokens of the second and
+    third row read the slot's two tokens while the row's token before them
+    is a neighbour's last."""
+    seqs = [add(packed, f"i{i}", prompt(n, 100 + i), 3)
+            for i, n in enumerate((500, 400, 380))]
+    rows = prefills(drive(packed))
+    assert all(b.packed and b.seqs == seqs for b in rows)
+    assert [b.chunk_lens for b in rows] == \
+        [[172, 170, 170], [172, 170, 170], [156, 60, 40]]
+    assert [b.chunk_starts for b in rows][1:] == \
+        [[172, 170, 170], [344, 340, 340]]
+    for seq in seqs:
+        assert worst(packed, seq) < TOL
+
+
+def test_j_a_second_request_on_a_freed_slot_starts_from_zeros_in_a_packed_row(
+        packed):
+    """The slot a finished sequence leaves holds its last two tokens; the
+    next owner's first segment, in a row with a neighbour, starts from
+    zeros all the same (``fresh``: the segment's chunk starts at 0)."""
+    first = add(packed, "j1", prompt(33, 40), 9)
+    step(packed)
+    slot = first.state_slot
+    drive(packed)
+    assert slot and packed.block_manager.state_slots_in_use == 0
+    assert np.any(np.asarray(packed.runner.state_pools[0][slot]) != 0)
+    second = add(packed, "j2", prompt(21, 41), 9)
+    beside = add(packed, "j3", prompt(2, 42), 9)
+    batch = step(packed)
+    assert batch.packed and batch.seqs == [second, beside]
+    assert second.state_slot == slot
+    drive(packed)
+    assert worst(packed, second) < TOL and worst(packed, beside) < TOL
+
+
+@pytest.fixture(scope="module")
+def served_packed(packed):
+    """300 prompt tokens between two neighbours' 300: the prompt's second
+    segment starts at its token 172, behind a neighbour-free row's start
+    and before two neighbours' segments, and 40 tokens are decoded."""
+    beside = [add(packed, "w0", prompt(300, 71), 2)]
+    seq = add(packed, "w", prompt(300, 70), 40)
+    beside.append(add(packed, "w2", prompt(300, 72), 2))
+    rows = prefills(drive(packed))
+    assert [b.chunk_lens for b in rows] == [[172, 170, 170], [128, 130, 130]]
+    assert all(b.packed for b in rows)
+    assert worst(packed, seq) < TOL
+    return seq
+
+
+@pytest.mark.parametrize("wrong", ref.WRONG)
+def test_the_tolerance_tells_a_wrong_model_through_packed_rows(
+        packed, served_packed, wrong):
+    """What packed rows served is as far from every wrong model as what
+    rectangles served."""
+    assert worst(packed, served_packed, wrong=(wrong,)) > 10 * TOL
+
+
+@pytest.mark.asyncio
+async def test_k_the_engine_loop_counts_the_segments_of_its_packed_rows():
+    """Six prompts at once through the engine's own loop: the same greedy
+    tokens as each alone, ``pstpu:prefill_segments_total`` counts a segment
+    a sequence a dispatch (as ``pstpu:prefill_rows_issued_total`` does,
+    and more of them than dispatches), and every token reached the experts
+    once: a row's padded end reached none."""
+    import asyncio
+
+    lens = [5, 130, 17, 300, 64, 2]
+    prompts = [prompt(n, 200 + i) for i, n in enumerate(lens)]
+    eng = make_engine(attn_impl="paged", max_model_len=1024,
+                      num_kv_blocks=256, num_decode_steps=4,
+                      max_num_batched_tokens=PACKED_BUDGET,
+                      enable_warmup=False)
+    await eng.start()
+
+    async def one(i):
+        out = None
+        async for o in eng.generate(
+                prompt_token_ids=prompts[i], sampling=SamplingParams(
+                    temperature=0.0, max_tokens=5, ignore_eos=True)):
+            out = o
+        return out.token_ids
+
+    try:
+        assert eng.runner.prefill_packs and eng.scheduler.prefill_packed
+        alone = [await one(i) for i in range(len(prompts))]
+        before = eng.stats()
+        together = await asyncio.gather(*map(one, range(len(prompts))))
+        after = eng.stats()
+    finally:
+        await eng.stop()
+    assert alone == together and all(len(t) == 5 for t in together)
+
+    def delta(name):
+        return after[name] - before[name]
+
+    mc = eng.model_config
+    sparse = mc.num_layers - mc.first_k_dense_replace
+    dispatches = delta("prefill_dispatches_total")
+    assert delta("prefill_tokens_issued_total") == sum(lens)
+    assert delta("prefill_segments_total") == \
+        delta("prefill_rows_issued_total") > dispatches
+    assert delta("moe_prefill_layer_calls_total") == sparse * dispatches
+    # Five answered tokens a request, four of them decoded.
+    assert delta("moe_assignments_total") == \
+        (sum(lens) + 4 * len(lens)) * mc.num_experts_per_tok * sparse
 
 
 # ---- the tolerance is tight enough -----------------------------------------

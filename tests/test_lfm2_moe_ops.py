@@ -1,7 +1,8 @@
 """What models/lfm2_moe.py asks of the shared operations, alone: the causal
 depthwise convolution of ops/gated_delta.py with and without the SiLU behind
 its sum, at this family's 3 taps and the older hybrids' 4, against a direct
-sum; the per-head norm of queries and keys with rope behind it; and
+sum, a rectangle (a sequence a row) and a packed row (the sequences end to
+end, a state a segment) alike; the per-head norm of queries and keys with rope behind it; and
 ``from_hf_config``'s refusals, by key."""
 
 import json
@@ -41,10 +42,11 @@ def _direct(x, state, w, bias, silu):
     return y / (1 + np.exp(-y)) if silu else y
 
 
-def _inputs(b, t, c, width, seed):
+def _inputs(b, t, c, width, seed, states=None):
+    """(x [b, t, c], conv state [states or b, W-1, c], w, bias)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     return (jax.random.normal(ks[0], (b, t, c)),
-            jax.random.normal(ks[1], (b, width - 1, c)),
+            jax.random.normal(ks[1], (states or b, width - 1, c)),
             jax.random.normal(ks[2], (width, c)),
             jax.random.normal(ks[3], (c,)))
 
@@ -127,6 +129,119 @@ def test_the_default_is_the_older_hybrids_silu():
     plain = gd.conv_chunk(x, state, w, lens, silu=False)[0]
     np.testing.assert_allclose(gd.conv_chunk(x, state, w, lens)[0],
                                jax.nn.silu(plain), rtol=1e-6)
+
+
+# ---- a packed row: segments end to end, a state a segment ------------------
+# Segment lengths of one row (a slot each; zeros are slots that hold nothing)
+# and the row's tokens. Among them: a segment of one token, of none (between
+# others, first, and last: a dispatch's unused slots), one shorter than the
+# W - 1 tokens the state holds, a first segment that is empty so that the
+# second begins at the row's token 0, and one that fills the row.
+LAYOUTS = pytest.mark.parametrize("seg_lens,t", [
+    ((5, 1, 0, 2, 7, 0), 24), ((0, 3, 1, 1, 9), 16), ((16,), 16),
+    ((1, 1, 1, 1), 8), ((2, 0, 0, 0), 8), ((3, 2, 3), 8)],
+    ids=lambda v: "+".join(map(str, v)) if isinstance(v, tuple) else f"t{v}")
+
+
+@pytest.mark.parametrize("width", [2, 3, 4])
+@SILU
+@BIAS
+@LAYOUTS
+def test_conv_packed_row_is_the_direct_sum(width, silu, with_bias, seg_lens,
+                                           t):
+    """Every segment of the row is the direct sum over (its state ++ its
+    own tokens) and nothing else: its neighbours' tokens lie right before
+    its first token in the row and are not read. The state after it is the
+    last W - 1 inputs of that; an empty segment keeps its state."""
+    x, state, w, bias = _inputs(1, t, 8, width, 30 + width, len(seg_lens))
+    bias = bias if with_bias else None
+    y, new = gd.conv_packed_row(x, state, w, jnp.array(seg_lens, jnp.int32),
+                                bias, silu=silu)
+    assert y.shape == x.shape and new.shape == state.shape
+    at = 0
+    for s, n in enumerate(seg_lens):
+        want = _direct(x[:, at:at + n], state[s:s + 1], w, bias, silu)
+        np.testing.assert_allclose(y[:, at:at + n], want, rtol=1e-5,
+                                   atol=1e-5)
+        ext = np.concatenate([state[s], x[0, at:at + n]])
+        np.testing.assert_array_equal(new[s], ext[n:n + width - 1])
+        at += n
+
+
+@pytest.mark.parametrize("width", [2, 3, 4])
+@SILU
+@BIAS
+@LAYOUTS
+def test_a_row_of_segments_is_the_rectangle_of_the_same_sequences(
+        width, silu, with_bias, seg_lens, t):
+    """``conv_packed_row`` over segments against ``conv_chunk`` over the
+    same sequences a row each from the same states: the same outputs, value
+    for value (the same inputs meet the same taps in the same order), and
+    the same states after."""
+    x, state, w, bias = _inputs(1, t, 8, width, 40 + width, len(seg_lens))
+    bias = bias if with_bias else None
+    lens = jnp.array(seg_lens, jnp.int32)
+    y, new = gd.conv_packed_row(x, state, w, lens, bias, silu=silu)
+    rows = np.zeros((len(seg_lens), max(seg_lens), 8), np.float32)
+    at = 0
+    for s, n in enumerate(seg_lens):
+        rows[s, :n] = x[0, at:at + n]
+        at += n
+    y_rect, new_rect = gd.conv_chunk(jnp.asarray(rows), state, w, lens, bias,
+                                     silu=silu)
+    at = 0
+    for s, n in enumerate(seg_lens):
+        np.testing.assert_array_equal(y[0, at:at + n], y_rect[s, :n])
+        at += n
+    np.testing.assert_array_equal(new, new_rect)
+
+
+@pytest.mark.parametrize("width", [2, 3, 4])
+@SILU
+@pytest.mark.parametrize("cuts", [(13, 2, 25), (1, 1, 38), (38, 1, 1),
+                                  (20, 20, 0)],
+                         ids=lambda c: "+".join(map(str, c)))
+def test_a_prompt_in_three_packed_dispatches_through_its_slot_is_the_whole(
+        width, silu, cuts):
+    """A 40-token prompt prefilled as a segment of three successive packed
+    rows, between two neighbours whose tokens change every dispatch, its
+    state carried from row to row in its slot (of three): the outputs and
+    the final state of ``conv_chunk`` over the whole prompt from zeros."""
+    x, _, w, _ = _inputs(1, 40, 8, width, 50 + width)
+    whole, end = gd.conv_chunk(x, jnp.zeros((1, width - 1, 8)), w,
+                               jnp.array([40]), silu=silu)
+    slots = jax.random.normal(jax.random.PRNGKey(7), (3, width - 1, 8))
+    slots = slots.at[1].set(0.0)        # the prompt's slot: a fresh sequence
+    at, outs = 0, []
+    for d, n in enumerate(cuts):
+        before, after = 3 + d, 5 - d    # the neighbours' chunks
+        others = jax.random.normal(jax.random.PRNGKey(60 + d),
+                                   (1, before + after, 8))
+        row = jnp.concatenate([others[:, :before], x[:, at:at + n],
+                               others[:, before:]], axis=1)
+        row = jnp.pad(row, ((0, 0), (0, 64 - row.shape[1]), (0, 0)))
+        y, slots = gd.conv_packed_row(
+            row, slots, w, jnp.array([before, n, after], jnp.int32),
+            silu=silu)
+        outs.append(y[:, before:before + n])
+        at += n
+    np.testing.assert_array_equal(jnp.concatenate(outs, 1), whole)
+    np.testing.assert_array_equal(slots[1:2], end)
+
+
+def test_a_packed_row_keeps_the_activations_dtype_and_a_float32_sum():
+    """bf16 inputs and state: the sum is made in float32 and rounded once,
+    as ``conv_chunk``'s is (the same values, bit for bit)."""
+    x, state, w, _ = _inputs(1, 8, 8, 3, 9, states=2)
+    x, state, w = (v.astype(jnp.bfloat16) for v in (x, state, w))
+    lens = jnp.array([5, 3], jnp.int32)
+    y, new = gd.conv_packed_row(x, state, w, lens, silu=False)
+    assert y.dtype == new.dtype == jnp.bfloat16
+    rows = jnp.stack([x[0, :5], jnp.pad(x[0, 5:], ((0, 2), (0, 0)))])
+    y_rect, new_rect = gd.conv_chunk(rows, state, w, lens, silu=False)
+    np.testing.assert_array_equal(y[0, :5], y_rect[0])
+    np.testing.assert_array_equal(y[0, 5:], y_rect[1, :3])
+    np.testing.assert_array_equal(new, new_rect)
 
 
 # ---- the per-head norm ----------------------------------------------------------

@@ -17,6 +17,7 @@ from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.kv_cache import BlockPoolManager
 from production_stack_tpu.engine.sampling import SamplingParams
 from production_stack_tpu.engine.scheduler import Scheduler, Sequence
+from production_stack_tpu.models.config import StateSpec
 from production_stack_tpu.utils import (
     pow2_bucket,
     prefill_rectangle,
@@ -298,6 +299,9 @@ LATENT_ROWS = {"kv_pools": 1, "kv_heads": 1, "head_dim": 640,
                "kv_value_dim": 512, "num_heads": 32}
 
 
+_CONV, _SCAN = (StateSpec(name, 2, (128,), None) for name in ("conv", "scan"))
+
+
 def _predicate_runner(**over):
     """A ModelRunner that holds only what ``prefill_packs`` reads."""
     from types import SimpleNamespace
@@ -310,6 +314,7 @@ def _predicate_runner(**over):
     r.kv_spec = SimpleNamespace(kv_heads=2, head_dim=128)
     r.kv_value_dim, r.kv_pools, r.dtype = 128, 2, "bfloat16"
     r.state_specs, r.lora_stacks, r.spec_n = (), None, 0
+    r.states_crossing_segments = frozenset()
     r.attn_impl, r.num_kv_blocks = "paged", 1 << 20
     r.__dict__["prefill_reads_pool"] = True
     for k, v in over.items():
@@ -327,7 +332,15 @@ def _predicate_runner(**over):
 @pytest.mark.parametrize("case,over,packs", [
     ("dense K/V rows read in place", {}, True),
     ("a gathered window", {"prefill_reads_pool": False}, False),
-    ("recurrent state", {"state_specs": ("some",)}, False),
+    ("recurrent state", {"state_specs": (_SCAN,)}, False),
+    ("a state its module says crosses segments",
+     {"state_specs": (_CONV,), "states_crossing_segments": {"conv"}}, True),
+    ("two states, one of which crosses segments",
+     {"state_specs": (_CONV, _SCAN), "states_crossing_segments": {"conv"}},
+     False),
+    ("an adapter a row beside a state that crosses segments",
+     {"state_specs": (_CONV,), "states_crossing_segments": {"conv"},
+      "lora_stacks": {"wq": None}}, False),
     ("latent rows read in place", LATENT_ROWS, True),
     ("latent rows of heads that fill no sublane tile",
      {**LATENT_ROWS, "num_heads": 8}, False),
@@ -341,9 +354,11 @@ def _predicate_runner(**over):
 def test_which_form_a_dispatch_takes_is_decided_in_one_place(case, over,
                                                              packs):
     """``ModelRunner.prefill_packs``, from what the runner holds: the
-    state-keeping configurations keep their rectangles, and so does
-    whatever rides a row (an adapter, a draft's ring) over either pool;
-    latent rows pack since PR 48, where the packed kernel covers them."""
+    configurations that keep a scan's state keep their rectangles, and so
+    does whatever rides a row (an adapter, a draft's ring) over either
+    pool; latent rows pack since PR 48, where the packed kernel covers
+    them, and since PR 50 a module whose EVERY state is one it declares to
+    cross a segment boundary (``STATES_CROSSING_SEGMENTS``)."""
     r = _predicate_runner(**over)
     assert r.prefill_packs is packs
     assert r._prefill_segs == (16 if packs else 0)

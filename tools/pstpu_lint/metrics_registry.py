@@ -571,8 +571,9 @@ REGISTRY: Tuple[Series, ...] = (
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Sequences whose chunks lay end to end as segments of the one "
            "row of a PACKED prefill dispatch, counted at issue; 0 while "
-           "every dispatch is a rectangle (a model with per-row state, an "
-           "adapter or a draft's ring a row, a gathered window)"),
+           "every dispatch is a rectangle (a model with a per-row state "
+           "its module does not declare to cross segments, an adapter or a "
+           "draft's ring a row, a gathered window)"),
     Series("pstpu:attn_keys_in_span_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Keys the attention layers' queries see inside their layer's "
