@@ -5,7 +5,7 @@ program's share of peak compute. The operations and bytes come from
 ``lib/shapes.py``, the peaks from ``peaks.json``."""
 
 from statistics import fmean
-from typing import List, Optional
+from typing import Collection, List, Optional
 
 from benchmarks.chip.lib import shapes, xplane
 
@@ -17,9 +17,20 @@ ATTENTION_OPS = ("paged_flash_decode",)
 
 
 def reduce(trace_info: dict, model_config: dict, peak: Optional[dict],
-           results: List, window_counters: dict) -> dict:
+           results: List, window_counters: dict,
+           wanted: Optional[Collection[str]] = None) -> dict:
     """One engine's capture (the first; the others are averaged for busy
-    time only) with the counters' deltas over the traced seconds."""
+    time only) with the counters' deltas over the traced seconds.
+
+    ``wanted``: the fields some metric of the cell reads (``None``: all).
+    ``decode_roofline`` and ``prefill_mfu`` hold ``model_config`` to a
+    dense decoder's count (``lib/shapes.py``) and are worked out only where
+    asked for: a cell whose model is not one lists neither, and its
+    ``config.json`` need not have a dense decoder's keys. Busy, idle, the
+    breakdown, ``attn_share`` and ``decode_step_s`` need no count."""
+    def asked(field: str) -> bool:
+        return wanted is None or field in wanted
+
     reductions = []
     notes = []
     for trace_dir in trace_info["dirs"]:
@@ -64,7 +75,7 @@ def reduce(trace_info: dict, model_config: dict, peak: Optional[dict],
         "vllm:time_to_first_token_seconds_count", 0) / n_engines
     if steps and decode_s:
         out["decode_step_s"] = decode_s / steps
-    if peak and steps and decode_s and ok:
+    if asked("decode_roofline") and peak and steps and decode_s and ok:
         # Every request's first token comes from its prefill.
         decoded = counters.get(
             "vllm:generation_tokens_total", 0) / n_engines - requests
@@ -76,7 +87,7 @@ def reduce(trace_info: dict, model_config: dict, peak: Optional[dict],
         out["decode_roofline"] = steps * least["seconds"] / decode_s
         notes.append(f"decode: {steps:.0f} steps, {rows:.2f} rows a step, "
                      f"context {context:.0f}, {least['bound']}-bound")
-    if peak and prefill_s and ok:
+    if asked("prefill_mfu") and peak and prefill_s and ok:
         # Prompt tokens the traced seconds saw, less the share the prefix
         # cache served over the whole window (hits are counted when a
         # request is admitted and prompt tokens when it ends, so their

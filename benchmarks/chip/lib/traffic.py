@@ -5,8 +5,17 @@ alone (never of the clock or of the program's answers). Lengths are
 STRATIFIED: the file's distributions are cut into as many equal-probability
 strata as the window holds requests, so every seed offers the same number
 of requests, the same multiset of (prompt, output) lengths and so the same
-token totals; the seed permutes the order (within ``balanced_order``), draws the
-arrival gaps and fills the text. A different seed is another interleaving of the same work.
+token totals. In a closed loop the seed permutes the order (within
+``balanced_order``) and fills the text: another interleaving of the same work.
+
+An open loop goes further (PR 51): the order of the lengths and the gaps
+between arrivals are drawn from the FILE's ``schedule_seed``, and the run's
+seed turns that schedule (starts it at another of its requests, each
+keeping the gap before it), picks the first tenant and fills the text.
+Every seed then offers the same requests after the same gaps, in another
+order: which request meets which other's prefill no longer differs from
+seed to seed, and the median of some eighty requests stops swinging by a
+tenth with it (PERF.md sections 2 and 6).
 
 Prompts are ASCII of an exact byte length: the engine's byte tokenizer
 gives one token per byte, and the repo's plain chat template
@@ -14,6 +23,7 @@ gives one token per byte, and the repo's plain chat template
 generator knows every request's prompt-token count before it is sent.
 """
 
+import itertools
 import math
 import random
 from dataclasses import dataclass
@@ -106,20 +116,31 @@ def balanced_order(pairs: List[tuple], rng: random.Random,
     return out
 
 
-def arrival_offsets(arrival: dict, n: int, seconds: float,
-                    rng: random.Random) -> List[float]:
-    """``n`` due times in [0, seconds): a gamma-renewal process of the
-    file's coefficient of variation, scaled so that every seed puts the
-    same count into the window (the mean rate is exactly n / seconds)."""
+def arrival_gaps(arrival: dict, n: int, seconds: float,
+                 rng: random.Random) -> List[float]:
+    """The gap before each of ``n`` arrivals: a gamma-renewal process of
+    the file's coefficient of variation, scaled so that every seed puts the
+    same count into the window (the mean rate is exactly n / seconds; the
+    gap after the last arrival is drawn too and is the window's tail)."""
     if arrival.get("kind", "gamma") != "gamma":
         raise ValueError(f"unknown arrival process {arrival.get('kind')!r}")
     shape = 1.0 / (arrival["cv"] ** 2)
     gaps = [rng.gammavariate(shape, 1.0 / shape) for _ in range(n + 1)]
-    total, at, out = sum(gaps), 0.0, []
-    for gap in gaps[:n]:
-        at += gap
-        out.append(seconds * at / total)
-    return out
+    total = sum(gaps)
+    return [seconds * gap / total for gap in gaps[:n]]
+
+
+def open_schedule(spec: dict, n: int, seconds: float,
+                  rng: random.Random) -> tuple:
+    """An open loop's window: the length pairs in their order and the gap
+    before each, drawn from the file's ``schedule_seed`` and only TURNED
+    by ``rng``, the run's: started at another request, each keeping its
+    gap, the end joined to the start."""
+    plan = random.Random(spec["schedule_seed"] * 1_000_003 + 1)
+    pairs = balanced_order(length_pairs(spec, n), plan)
+    gaps = arrival_gaps(spec["arrival"], n, seconds, plan)
+    turn = rng.randrange(n)
+    return pairs[turn:] + pairs[:turn], gaps[turn:] + gaps[:turn]
 
 
 def ascii_text(rng: random.Random, nbytes: int) -> str:
@@ -163,9 +184,11 @@ def generate(spec: dict, seed: int, seconds: float,
              variation: int = 0) -> dict:
     """The requests of one window, and what set-up sends first.
 
-    The tenants' system prompts come from ``seed`` alone; order, arrival
-    gaps and the other text from ``seed`` and ``variation``, so that a
+    The tenants' system prompts come from ``seed`` alone; order (a closed
+    loop's), the turn and the other text from ``seed`` and ``variation``, so that a
     sweep offers step after step new requests to the same cached tenants.
+    An open loop's order and gaps come from the file's ``schedule_seed``,
+    and ``seed`` and ``variation`` only turn that schedule.
 
     Open loop: ``round(rate_rps * seconds)`` requests with due times.
     Closed loop: ``users * rounds_max`` requests without due times, in
@@ -177,8 +200,8 @@ def generate(spec: dict, seed: int, seconds: float,
     tenants = spec["system"]["tenants"]
     if spec["loop"] == "open":
         n = max(1, int(round(spec["rate_rps"] * seconds)))
-        pairs = balanced_order(length_pairs(spec, n), rng)
-        dues = arrival_offsets(spec["arrival"], n, seconds, rng)
+        pairs, gaps = open_schedule(spec, n, seconds, rng)
+        dues = list(itertools.accumulate(gaps))
     elif spec["loop"] == "closed":
         one_round = length_pairs(spec, spec["users"])
         pairs = []

@@ -67,17 +67,23 @@ def reduce_metrics(run: CellRun, win: dict, trace: bool) -> tuple:
         model_config=run.model_config, bytes_in_use=run.bytes_in_use,
         trace=None,
     )
+    group = "per_layer" if trace else "end_to_end"
+    readers = [(metric, *manifest.reader(metric["name"]))
+               for metric in manifest.metrics_of(run.cell["name"], group)]
     if trace and win["trace_info"].get("dirs"):
         from benchmarks.chip.lib import roofline
+        from benchmarks.chip.readers import trace_field
 
+        # The fields this cell's metrics read of the reduction, which works
+        # out a share by the dense count only where one of them asks.
         ctx["trace"] = roofline.reduce(
             win["trace_info"], run.model_config,
             None if run.rehearse else manifest.peaks(run.device["kind"]),
-            win["results"], win["counters"])
+            win["results"], win["counters"],
+            wanted={args["field"] for _, read, args in readers
+                    if read is trace_field.read})
     out = {}
-    group = "per_layer" if trace else "end_to_end"
-    for metric in manifest.metrics_of(run.cell["name"], group):
-        read, args = manifest.reader(metric["name"])
+    for metric, read, args in readers:
         value = read(ctx, **args)
         if value is not None:
             out[metric["name"]] = {"value": value, "unit": metric["unit"]}

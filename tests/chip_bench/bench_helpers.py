@@ -1,5 +1,7 @@
-"""Shared by the rehearsal tests: run the benchmark's command in a child,
-the way the driver does, and split off its last line."""
+"""Shared by the benchmark's tests: run the benchmark's command in a child,
+the way the driver does, and split off its last line; and ``BENCHMARK.json``
+beside the ONE recorded copy of it that the tests of earlier PRs' entries
+compare with (``data/manifest.recorded.json``, see ``data/README.txt``)."""
 
 import json
 import os
@@ -8,6 +10,46 @@ import sys
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RECORD = os.path.join(os.path.dirname(__file__), "data",
+                      "manifest.recorded.json")
+
+
+def live():
+    """``BENCHMARK.json`` as the checkout has it."""
+    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+
+
+def recorded():
+    """``BENCHMARK.json`` as the last PR that changed an entry left it."""
+    return json.load(open(RECORD))
+
+
+def grown_from(doc, was):
+    """The ways in which ``doc`` is NOT ``was`` grown at its ends (none: an
+    empty list). What ``was`` holds is in ``doc`` as it was and where it
+    was; a list of entries may have more entries behind the ones it had;
+    an entry that names its cells may name more behind the ones it named,
+    and may differ in nothing else."""
+    faults = []
+    if set(doc) != set(was):
+        faults.append(f"keys {sorted(set(doc) ^ set(was))}")
+    for key in ("command", "paths", "run_seconds", "end_to_end"):
+        if doc.get(key) != was[key]:
+            faults.append(f"{key} changed")
+    for key in ("configs", "workloads"):
+        if doc[key][:len(was[key])] != was[key]:
+            faults.append(f"{key}: what was there changed or moved")
+    if len(doc["per_layer"]) < len(was["per_layer"]):
+        faults.append("per_layer lost an entry")
+    for now, then in zip(doc["per_layer"], was["per_layer"]):
+        if now == then:
+            continue
+        had = then.get("workloads")
+        if had is None or now != dict(then, workloads=now.get("workloads")):
+            faults.append(f"{then['name']}: changed or moved")
+        elif now["workloads"][:len(had)] != had:
+            faults.append(f"{then['name']}: a cell it named went or moved")
+    return faults
 
 
 def run_cell(root, cell, *extra, seed=2**31 + 11, seconds=5, trace=0,

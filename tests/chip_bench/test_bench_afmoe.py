@@ -5,24 +5,25 @@ entries have and to no end of a list, so that the next appending PR needs no
 mark (``tests/chip_bench/test_bench_lfm.py`` did the same for PR 44). The
 reader of five of the six on hand-built contexts: nothing without a capture,
 for a model of another family, or on a capture recorded before the scopes
-and the spans' fields existed; its arithmetic on a made-up capture."""
+and the spans' fields existed; its arithmetic on a made-up capture. What
+the cell REPORTS is held as the ONE recorded manifest has it
+(``data/manifest.recorded.json``, PR 51), from which the live one may only
+have grown."""
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO  # noqa: E402
+from bench_helpers import REPO, grown_from, live, recorded  # noqa: E402
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 from benchmarks.chip.lib import shapes, shapes_afmoe  # noqa: E402
 from benchmarks.chip.lib.manifest import Manifest, validate  # noqa: E402
 from benchmarks.chip.readers import afmoe_trace, counter_ratio  # noqa: E402
-from test_bench_ssm import EVERY_CELL  # noqa: E402
+from test_bench_ssm import EVERY_CELL, HBM_METRICS  # noqa: E402
 
 CELL = "trinity-mini-d8.longdoc-saturated"
 CONFIG = "trinity-mini-d8"
@@ -47,13 +48,17 @@ NOT_OURS = ("hyb_decode_step_ms", "hyb_decode_roofline_pct",
             "ssd_share_pct", "lfm_decode_step_ms", "lfm_decode_roofline_pct",
             "lfm_gmm_roofline_pct", "lfm_moe_share_pct",
             "sconv_step_roofline_pct", "sconv_share_pct")
-PARENT = "bcd1f184f67220f53862a4fd0ee468cefaa40cbc"
 PEAK = {"bf16_tflops": 197.0, "hbm_gbps": 819.0}
 
 
 @pytest.fixture(scope="module")
 def doc():
-    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    return live()
+
+
+@pytest.fixture(scope="module")
+def was():
+    return recorded()
 
 
 @pytest.fixture(scope="module")
@@ -104,44 +109,37 @@ def test_another_architectures_arithmetic_is_not_this_cells(by_name, name):
     assert CELL not in by_name[name]["workloads"]
 
 
-def test_what_the_cell_reports(doc):
-    listed = {m["name"] for m in doc["per_layer"]
+def test_what_the_cell_reports_in_the_record(was):
+    listed = {m["name"] for m in was["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == set(EVERY_CELL + AFM_METRICS + tuple(NAMED_AT))
+    # Every one of the 8 layers calls the paged decode kernel once a step:
+    # kernel calls / layers IS a step, so the per-step time names the cell.
+    assert listed == set(EVERY_CELL + AFM_METRICS + tuple(NAMED_AT)
+                         + HBM_METRICS + ("decode_step_ms",))
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "per_layer")}
-    # Those without a list are reported in every cell, this one too (with
-    # a dense llama's arithmetic: PERF.md section 7).
-    assert {"decode_step_ms", "decode_roofline_pct", "attn_share_pct",
-            "prefill_mfu_pct", "hbm_peak_gb", "device_idle_pct"} <= reported
+    # Those without a list are reported in every cell, this one too.
+    assert listed | {"attn_share_pct", "hbm_peak_gb",
+                     "device_idle_pct"} <= reported
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
 
-def test_the_manifest_only_grew_since_the_parent(doc):
-    """Against the committed parent where git has one (a checkout the
-    driver made has no history: skipped there): nothing that was there
-    changed but thirteen ``workloads`` lists, each by this cell's name."""
-    try:
-        was = json.loads(subprocess.run(
-            ["git", "show", f"{PARENT}:BENCHMARK.json"], cwd=REPO,
-            capture_output=True, check=True, text=True).stdout)
-    except (subprocess.CalledProcessError, OSError):
-        pytest.skip("no git history here")
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert doc[key] == was[key]
-    for key, at in (("configs", CONFIG_AT), ("workloads", CELL_AT)):
-        assert doc[key][:len(was[key])] == was[key]
-        assert len(was[key]) == at
-    assert len(was["per_layer"]) == AFM_AT
-    grew = []
-    for now, then in zip(doc["per_layer"], was["per_layer"]):
-        if now != then:
-            at = len(then["workloads"])
-            assert now == dict(then, workloads=now["workloads"])
-            assert now["workloads"][:at] == then["workloads"]
-            assert now["workloads"][at] == CELL
-            grew.append(now["name"])
+def test_the_manifest_only_grew_from_the_record(doc, was):
+    """PR 47's configuration, cell and six metrics stand in the record at
+    the ends of what its parent had, and the live manifest holds the
+    record as its head."""
+    assert grown_from(doc, was) == []
+    assert (was["configs"][CONFIG_AT]["name"],
+            was["workloads"][CELL_AT]["name"]) == (CONFIG, CELL)
+    assert CELL_AT == CONFIG_AT + 1
+    assert [m["name"] for m in was["per_layer"][
+        AFM_AT:AFM_AT + len(AFM_METRICS)]] == list(AFM_METRICS)
+    # Among what the parent had, the lists that named the cell when its PR
+    # ended name it where it stood then.
+    grew = [then["name"] for then in was["per_layer"][:AFM_AT]
+            if CELL in then.get("workloads", ())
+            and then["name"] in EVERY_CELL + tuple(NAMED_AT)]
     assert sorted(grew) == sorted(EVERY_CELL + tuple(NAMED_AT))
 
 

@@ -1,35 +1,35 @@
 """What PR 38 appended to ``BENCHMARK.json`` (a configuration, a cell, three
 per-layer metrics of the stream mix, and the cell's name at the end of the
-lists that name every cell), and what
-``test_bench_issue.py::test_the_six_are_the_last_of_per_layer_and_list_every_cell``
-asserts of PR 36's six except that they are LAST, which no appending PR can
-keep (``tests/conftest.py`` marks that one test): here the block is pinned
-to the indices it has. The reader of the three metrics on hand-built
-contexts: nothing without a capture, for a model with one stream, or on
-the capture recorded before the scopes existed."""
+lists that name every cell). PR 38 held its entries to be the LAST of their
+lists, which the next appending PR could not keep: they are held where the
+ONE recorded manifest has them (``data/manifest.recorded.json``, PR 51),
+from which the live one may only have grown (``bench_helpers.grown_from``);
+``test_bench_ssm.py`` holds them at the same indices on the live manifest.
+PR 36's six likewise, on the live manifest here. The reader of the three
+metrics on hand-built contexts: nothing without a capture, for a model
+with one stream, or on the capture recorded before the scopes existed."""
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO  # noqa: E402
+from bench_helpers import REPO, grown_from, live, recorded  # noqa: E402
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 from benchmarks.chip.lib.manifest import Manifest, validate  # noqa: E402
 from benchmarks.chip.readers import hc_trace  # noqa: E402
-from test_bench_issue import ISSUE_METRICS, SPAN_METRIC  # noqa: E402
+from test_bench_issue import (ISSUE_METRICS, PR36_AT,  # noqa: E402
+                              SPAN_METRIC)
 
 CELL = "xing4.0-29b-a4b-d7.chat-saturated"
 CONFIG = "xing4.0-29b-a4b-d7"
 HC_METRICS = ("hc_decode_roofline_pct", "hc_mix_roofline_pct",
               "hc_share_pct")
-# Where PR 36's six start in ``per_layer`` (PR 35's list had 40).
-PR36_AT = 40
+# Where PR 38's entries stand: PR 36's lists had 4, 5 and 46 entries.
+CONFIG_AT, CELL_AT, HC_AT = 4, 5, 46
 # Lists that named all five cells before this PR: PR 24's five span and
 # scope metrics and PR 36's six.
 EVERY_CELL = ("prefill_device_wait_ms", "fetch_lag_ms", "sample_share_pct",
@@ -38,11 +38,24 @@ EVERY_CELL = ("prefill_device_wait_ms", "fetch_lag_ms", "sample_share_pct",
 # config.json and is right for this model too.
 SHARED = ("moe_gmm_roofline_pct", "mla_decode_roofline_pct", "moe_share_pct",
           "moe_experts_touched")
+# What names the cell since: PR 49's three of the device's memory, and
+# (PR 51) the per-step time, right where every layer calls the paged
+# decode kernel once a step, as this model's do.
+SINCE = ("hbm_high_water_gb", "hbm_headroom_pct", "hbm_unexplained_gb",
+         "decode_step_ms")
+# The dense arithmetic's two (``lib/shapes.py``) list the dense cells only
+# since PR 51: here they read 0.71 x and 1.58 x the true shares.
+NOT_THIS_MODELS = ("decode_roofline_pct", "prefill_mfu_pct")
 
 
 @pytest.fixture(scope="module")
 def doc():
-    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    return live()
+
+
+@pytest.fixture(scope="module")
+def was():
+    return recorded()
 
 
 def test_pr36_block_is_where_it_was_and_lists_every_cell(doc):
@@ -69,19 +82,19 @@ def test_pr36_block_is_where_it_was_and_lists_every_cell(doc):
         assert metric.get("workloads"), metric["name"]
 
 
-def test_the_new_entries_are_the_last_of_their_lists(doc):
-    assert doc["configs"][-1]["name"] == CONFIG
-    assert doc["configs"][-1]["reduced"] == [
+def test_the_new_entries_are_where_the_record_has_them(was):
+    assert was["configs"][CONFIG_AT]["name"] == CONFIG
+    assert was["configs"][CONFIG_AT]["reduced"] == [
         "num_hidden_layers", "num_nextn_predict_layers"]
-    assert doc["configs"][-1]["file"] == \
+    assert was["configs"][CONFIG_AT]["file"] == \
         f"benchmarks/chip/configs/{CONFIG}/config.json"
-    assert doc["workloads"][-1] == {
+    assert was["workloads"][CELL_AT] == {
         "name": CELL, "config": CONFIG, "traffic": "chat-saturated",
-        "chips": 1, "why": doc["workloads"][-1]["why"]}
-    assert len(doc["workloads"][-1]["why"]) <= 200
-    last = doc["per_layer"][-len(HC_METRICS):]
-    assert [m["name"] for m in last] == list(HC_METRICS)
-    for metric in last:
+        "chips": 1, "why": was["workloads"][CELL_AT]["why"]}
+    assert len(was["workloads"][CELL_AT]["why"]) <= 200
+    block = was["per_layer"][HC_AT:HC_AT + len(HC_METRICS)]
+    assert [m["name"] for m in block] == list(HC_METRICS)
+    for metric in block:
         assert metric == {
             "name": metric["name"], "unit": "%",
             "better": "lower" if metric["name"] == "hc_share_pct"
@@ -90,48 +103,46 @@ def test_the_new_entries_are_the_last_of_their_lists(doc):
             "workloads": [CELL]}
 
 
-def test_the_cell_is_named_last_where_its_readers_find_something(doc):
-    by_name = {m["name"]: m for m in doc["per_layer"]}
+def test_the_cell_is_named_where_the_record_names_it(was):
+    """Last of each list as PR 38 left it: the place it has in the record,
+    whatever followed it."""
+    by_name = {m["name"]: m for m in was["per_layer"]}
     for name in EVERY_CELL + SHARED + ("out_tok_s",):
-        assert by_name[name]["workloads"][-1] == CELL, name
+        at = {"out_tok_s": 3}.get(name, 1 if name in SHARED else CELL_AT)
+        assert by_name[name]["workloads"][at] == CELL, name
     # lib/shapes_moe.py counts a full-rank W_q (22.0 M where q_a + q_b are
     # 7.5 M): kanana's whole-step share would read HIGH here;
     # hc_decode_roofline_pct stands in.
     assert by_name["moe_decode_roofline_pct"]["workloads"] == [
         "kanana-2-30b-a3b-d8.chat-saturated"]
-    listed = {m["name"] for m in doc["per_layer"]
+    listed = {m["name"] for m in was["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == set(EVERY_CELL + SHARED + HC_METRICS) | {"out_tok_s"}
-    # Those without a list are reported in every cell, this one too.
+    assert listed == set(EVERY_CELL + SHARED + HC_METRICS + SINCE) \
+        | {"out_tok_s"}
+    # Those without a list are reported in every cell, this one too; the
+    # dense arithmetic's two are not this model's, and its own stands in.
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "per_layer")}
-    assert {"decode_roofline_pct", "prefill_mfu_pct", "hbm_peak_gb",
-            "decode_rows_per_step"} <= reported
+    assert listed | {"attn_share_pct", "hbm_peak_gb", "device_idle_pct",
+                     "decode_rows_per_step"} <= reported
+    assert not set(NOT_THIS_MODELS) & reported
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
 
-def test_the_manifest_only_grew_at_the_ends_since_the_parent(doc):
-    """Against the committed parent where git has one (a checkout the
-    driver made has no history: skipped there)."""
-    try:
-        was = json.loads(subprocess.run(
-            ["git", "show", "545084e5e735792696ba8dc263f42309c2a91a83:"
-             "BENCHMARK.json"], cwd=REPO, capture_output=True, check=True,
-            text=True).stdout)
-    except (subprocess.CalledProcessError, OSError):
-        pytest.skip("no git history here")
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert doc[key] == was[key]
-    for key in ("configs", "workloads"):
-        assert doc[key][:len(was[key])] == was[key]
-        assert len(doc[key]) == len(was[key]) + 1
-    assert len(doc["per_layer"]) == len(was["per_layer"]) + len(HC_METRICS)
-    for now, then in zip(doc["per_layer"], was["per_layer"]):
-        grown = dict(then)
-        if now != then:
-            grown["workloads"] = then["workloads"] + [CELL]
-        assert now == grown, then["name"]
+def test_the_manifest_only_grew_at_the_ends_from_the_record(doc, was):
+    """What PR 38 appended stands behind what its parent had, in the
+    record, and the live manifest holds the record as its head."""
+    assert grown_from(doc, was) == []
+    assert [c["name"] for c in was["configs"][:CONFIG_AT]] == [
+        "qwen2.5-3b", "mistral-7b-d16", "olmo-hybrid-7b-d16",
+        "kanana-2-30b-a3b-d8"]
+    assert [w["name"] for w in was["workloads"][:CELL_AT]] == [
+        "qwen2.5-3b.chat-steady", "mistral-7b-d16.agent-prefix",
+        "qwen2.5-3b.chat-saturated", "olmo-hybrid-7b-d16.chat-saturated",
+        "kanana-2-30b-a3b-d8.chat-saturated"]
+    # PR 36's six were the last of its parent's ``per_layer``.
+    assert PR36_AT + len(ISSUE_METRICS) == HC_AT
 
 
 def test_the_cells_files_are_beside_the_others():

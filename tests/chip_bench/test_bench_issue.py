@@ -7,14 +7,14 @@ contexts and synthetic captures, nothing on the capture recorded before
 the spans carried tokens, the manifest's six new entries, and a traced
 rehearsal that reports the counter-based five."""
 
-import json
 import os
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO, run_cell  # noqa: E402
+from bench_helpers import (REPO, grown_from, live, recorded,  # noqa: E402
+                           run_cell)
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -28,6 +28,8 @@ COUNTER_METRICS = ("prefill_fill_pct", "prefill_tok_per_dispatch",
                    "decode_empty_step_pct")
 SPAN_METRIC = "prefill_dev_us_per_token"
 ISSUE_METRICS = COUNTER_METRICS[:3] + (SPAN_METRIC,) + COUNTER_METRICS[3:]
+# Where the six start in ``per_layer`` (PR 35's list had 40).
+PR36_AT = 40
 
 
 def read(name, ctx):
@@ -244,14 +246,20 @@ def test_nothing_on_the_capture_recorded_before_spans_carried_tokens():
 
 
 # ------------------------------------------------------------ the manifest
-def test_the_six_are_the_last_of_per_layer_and_list_every_cell():
-    doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+def test_the_six_are_where_the_record_has_them_and_list_every_cell():
+    """PR 36 held its six to be the LAST of ``per_layer``, which no
+    appending PR can keep: they are the block at index 40 of the recorded
+    manifest, naming every cell it has, and the live manifest has only
+    grown from that record (``test_bench_hc.py`` holds the block on the
+    live one)."""
+    doc, was = live(), recorded()
     assert validate(doc, REPO) == []
-    cells = [w["name"] for w in doc["workloads"]]
-    last = doc["per_layer"][-len(ISSUE_METRICS):]
-    assert [m["name"] for m in last] == list(ISSUE_METRICS)
+    assert grown_from(doc, was) == []
+    cells = [w["name"] for w in was["workloads"]]
+    block = was["per_layer"][PR36_AT:PR36_AT + len(ISSUE_METRICS)]
+    assert [m["name"] for m in block] == list(ISSUE_METRICS)
     manifest = Manifest(REPO)
-    for metric in last:
+    for metric in block:
         assert metric["workloads"] == cells
         assert metric["moves"] == ("tpot_p50_ms" if metric["name"]
                                    == "decode_empty_step_pct"
@@ -260,7 +268,7 @@ def test_the_six_are_the_last_of_per_layer_and_list_every_cell():
                                     == SPAN_METRIC else "program_counter")
         fn, args = manifest.reader(metric["name"])
         assert callable(fn) and isinstance(args, dict)
-    layers = {m["name"]: m["layer"] for m in last}
+    layers = {m["name"]: m["layer"] for m in block}
     assert layers[SPAN_METRIC] == layers["serving_compile_s"] == "runner"
     assert {layers[n] for n in ISSUE_METRICS
             if layers[n] != "runner"} == {"scheduler"}

@@ -5,24 +5,24 @@ entries have and to no end of a list, so that the next appending PR needs no
 mark (``tests/chip_bench/test_bench_ssm.py`` did the same for PR 40). The
 reader of the six metrics on hand-built contexts: nothing without a capture,
 for a model of another family, or on a capture recorded before the scopes
-existed; its arithmetic on a made-up capture."""
+existed; its arithmetic on a made-up capture. What the cell REPORTS is
+held as the ONE recorded manifest has it (``data/manifest.recorded.json``,
+PR 51), from which the live one may only have grown."""
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO  # noqa: E402
+from bench_helpers import REPO, grown_from, live, recorded  # noqa: E402
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 from benchmarks.chip.lib import shapes, shapes_lfm  # noqa: E402
 from benchmarks.chip.lib.manifest import Manifest, validate  # noqa: E402
 from benchmarks.chip.readers import lfm_trace  # noqa: E402
-from test_bench_ssm import EVERY_CELL  # noqa: E402
+from test_bench_ssm import EVERY_CELL, HBM_METRICS  # noqa: E402
 
 CELL = "lfm2-8b-a1b-d16.chat-saturated"
 CONFIG = "lfm2-8b-a1b-d16"
@@ -43,13 +43,17 @@ NOT_OURS = ("hyb_decode_step_ms", "hyb_decode_roofline_pct",
             "hc_share_pct", "ssm_decode_step_ms", "ssm_decode_roofline_pct",
             "ssd_step_roofline_pct", "ssd_chunk_roofline_pct",
             "ssd_share_pct")
-PARENT = "7e9fef552c91d0ed6bed8b77d1425df3c876eb83"
 PEAK = {"bf16_tflops": 197.0, "hbm_gbps": 819.0}
 
 
 @pytest.fixture(scope="module")
 def doc():
-    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    return live()
+
+
+@pytest.fixture(scope="module")
+def was():
+    return recorded()
 
 
 @pytest.fixture(scope="module")
@@ -96,44 +100,35 @@ def test_another_architectures_arithmetic_is_not_this_cells(by_name, name):
     assert CELL not in by_name[name]["workloads"]
 
 
-def test_what_the_cell_reports(doc):
-    listed = {m["name"] for m in doc["per_layer"]
+def test_what_the_cell_reports_in_the_record(was):
+    listed = {m["name"] for m in was["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == set(EVERY_CELL + LFM_METRICS + tuple(NAMED_AT))
+    assert listed == set(EVERY_CELL + LFM_METRICS + tuple(NAMED_AT)
+                         + HBM_METRICS)
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "per_layer")}
-    # Those without a list are reported in every cell, this one too (with
-    # a dense llama's arithmetic: PERF.md section 7).
-    assert {"decode_step_ms", "decode_roofline_pct", "attn_share_pct",
-            "prefill_mfu_pct", "hbm_peak_gb", "device_idle_pct"} <= reported
+    # Those without a list are reported in every cell, this one too.
+    assert listed | {"attn_share_pct", "hbm_peak_gb",
+                     "device_idle_pct"} <= reported
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
 
-def test_the_manifest_only_grew_since_the_parent(doc):
-    """Against the committed parent where git has one (a checkout the
-    driver made has no history: skipped there): nothing that was there
-    changed but thirteen ``workloads`` lists, each by this cell's name."""
-    try:
-        was = json.loads(subprocess.run(
-            ["git", "show", f"{PARENT}:BENCHMARK.json"], cwd=REPO,
-            capture_output=True, check=True, text=True).stdout)
-    except (subprocess.CalledProcessError, OSError):
-        pytest.skip("no git history here")
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert doc[key] == was[key]
-    for key, at in (("configs", CONFIG_AT), ("workloads", CELL_AT)):
-        assert doc[key][:len(was[key])] == was[key]
-        assert len(was[key]) == at
-    assert len(was["per_layer"]) == LFM_AT
-    grew = []
-    for now, then in zip(doc["per_layer"], was["per_layer"]):
-        if now != then:
-            at = len(then["workloads"])
-            assert now == dict(then, workloads=now["workloads"])
-            assert now["workloads"][:at] == then["workloads"]
-            assert now["workloads"][at] == CELL
-            grew.append(now["name"])
+def test_the_manifest_only_grew_from_the_record(doc, was):
+    """PR 44's configuration, cell and six metrics stand in the record at
+    the ends of what its parent had, and the live manifest holds the
+    record as its head."""
+    assert grown_from(doc, was) == []
+    assert (was["configs"][CONFIG_AT]["name"],
+            was["workloads"][CELL_AT]["name"]) == (CONFIG, CELL)
+    assert CELL_AT == CONFIG_AT + 1
+    assert [m["name"] for m in was["per_layer"][
+        LFM_AT:LFM_AT + len(LFM_METRICS)]] == list(LFM_METRICS)
+    # Among what the parent had, the lists that named the cell when its PR
+    # ended name it where it stood then.
+    grew = [then["name"] for then in was["per_layer"][:LFM_AT]
+            if CELL in then.get("workloads", ())
+            and then["name"] in EVERY_CELL + tuple(NAMED_AT)]
     assert sorted(grew) == sorted(EVERY_CELL + tuple(NAMED_AT))
 
 

@@ -6,17 +6,17 @@ synthetic captures built the way ``test_bench_issue.py`` builds its own;
 nothing without the attributes (the parent's program, the capture recorded
 before them) or without a capture; the manifest's three entries pinned to
 the INDICES they have and to no end of a list, each naming the nine cells,
-and nothing that was in ``BENCHMARK.json`` changed."""
+and nothing that was in ``BENCHMARK.json`` changed (held against the ONE
+recorded manifest, ``data/manifest.recorded.json``, PR 51)."""
 
 import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO  # noqa: E402
+from bench_helpers import REPO, grown_from, live, recorded  # noqa: E402
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -37,7 +37,6 @@ NINE_CELLS = [
     "xing4.0-29b-a4b-d7.chat-saturated",
     "granite-4.0-h-micro.chat-saturated", "lfm2-8b-a1b-d16.chat-saturated",
     "trinity-mini-d8.longdoc-saturated"]
-PARENT = "f41d722ca7a9b52c75466ce698f156a0a479e48d"
 GB = 10 ** 9
 LIMIT = 16_900_000_000
 
@@ -200,7 +199,7 @@ def test_a_capture_that_cannot_be_read_is_a_note_not_an_exception(
 # ----------------------------------------------------------- the manifest
 @pytest.fixture(scope="module")
 def doc():
-    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    return live()
 
 
 @pytest.mark.parametrize("i,entry", list(enumerate(HBM_METRICS)))
@@ -230,65 +229,58 @@ def test_the_manifest_is_valid_and_the_nine_cells_report_the_three(doc):
 
 
 def _cells_and_what_listed_them():
-    """(cell, the metrics that listed it when its PR ended) of the four
-    accepted tests that hold that set exactly, from their own constants."""
+    """(cell, the metrics that listed it when its PR ended, whether every
+    layer of its model calls the paged decode kernel once a step) of the
+    four accepted tests that hold that set, from their own constants."""
     import test_bench_afmoe as afmoe
     import test_bench_lfm as lfm
     import test_bench_ssm as ssm
 
     return [
         (ssm.HC_CELL, set(ssm.EVERY_CELL + ssm.HC_SHARED + ssm.HC_METRICS)
-         | {"out_tok_s"}),
-        (ssm.CELL, set(ssm.EVERY_CELL + ssm.SSM_METRICS) | {"out_tok_s"}),
+         | {"out_tok_s"}, True),
+        (ssm.CELL, set(ssm.EVERY_CELL + ssm.SSM_METRICS) | {"out_tok_s"},
+         False),
         (lfm.CELL, set(lfm.EVERY_CELL + lfm.LFM_METRICS
-                       + tuple(lfm.NAMED_AT))),
+                       + tuple(lfm.NAMED_AT)), False),
         (afmoe.CELL, set(afmoe.EVERY_CELL + afmoe.AFM_METRICS
-                         + tuple(afmoe.NAMED_AT))),
+                         + tuple(afmoe.NAMED_AT)), True),
     ]
 
 
 @pytest.mark.parametrize("case", range(4))
 def test_a_cell_is_listed_where_it_was_and_by_the_three(doc, case):
-    """What four accepted tests hold beside the set this PR grew
-    (``tests/conftest.py`` marks them): among the entries that were there
-    the cell is named by the metrics that named it; after them by this
-    PR's three; it reports the list-less metrics and its end-to-end ones
-    as before."""
-    cell, was_listed = _cells_and_what_listed_them()[case]
+    """What four accepted tests hold beside the set this PR grew: among
+    the entries that were there the cell is named by the metrics that
+    named it (and, since PR 51, by the per-step time where kernel calls
+    over layers IS a step); after them by this PR's three; it reports the
+    list-less metrics and its end-to-end ones as before, and neither of
+    the dense arithmetic's two shares (PR 51)."""
+    cell, was_listed, a_kernel_a_layer = _cells_and_what_listed_them()[case]
     listed = {m["name"] for m in doc["per_layer"][:HBM_AT]
               if cell in m.get("workloads", ())}
-    assert listed == was_listed
+    assert listed - {"decode_step_ms"} == was_listed
+    assert ("decode_step_ms" in listed) == a_kernel_a_layer
     assert {m["name"] for m in doc["per_layer"][HBM_AT:HBM_AT + 3]
             if cell in m["workloads"]} == {m[0] for m in HBM_METRICS}
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         cell, "per_layer")}
-    assert {"decode_step_ms", "decode_roofline_pct", "attn_share_pct",
-            "prefill_mfu_pct", "hbm_peak_gb", "device_idle_pct",
+    assert {"attn_share_pct", "hbm_peak_gb", "device_idle_pct",
             "decode_rows_per_step"} <= reported
+    assert not {"decode_roofline_pct", "prefill_mfu_pct"} & reported
+    assert ("decode_step_ms" in reported) == a_kernel_a_layer
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         cell, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
 
 def test_nothing_that_was_in_the_manifest_changed(doc):
-    """Against the committed parent where git has one (a checkout the
-    driver made has no history: skipped there): what the parent had is
-    there as it was, three entries follow the last of ``per_layer``, and
-    no list that names cells lost or moved one (a later PR may append)."""
-    try:
-        was = json.loads(subprocess.run(
-            ["git", "show", f"{PARENT}:BENCHMARK.json"], cwd=REPO,
-            capture_output=True, check=True, text=True).stdout)
-    except (subprocess.CalledProcessError, OSError):
-        pytest.skip("no git history here")
-    assert set(doc) == set(was)
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert doc[key] == was[key]
-    for key in ("configs", "workloads"):
-        assert doc[key][:len(was[key])] == was[key]
-    assert len(was["per_layer"]) == HBM_AT
+    """Against the recorded manifest: what it had is there as it was, the
+    three entries follow what PR 49's parent had of ``per_layer``, name
+    the nine cells it had, and no list that names cells lost or moved one
+    (a later PR may append)."""
+    was = recorded()
+    assert grown_from(doc, was) == []
     assert [w["name"] for w in was["workloads"]] == NINE_CELLS
-    for now, then in zip(doc["per_layer"], was["per_layer"]):
-        if now != then:
-            assert now == dict(then, workloads=now["workloads"])
-            assert now["workloads"][:len(then["workloads"])] == \
-                then["workloads"]
+    assert [m["name"] for m in was["per_layer"][HBM_AT:]] == [
+        m[0] for m in HBM_METRICS]
+    assert len(was["per_layer"]) == HBM_AT + 3

@@ -161,8 +161,9 @@ def test_prefill_attention_is_the_keys_scored(cfg):
 
 
 def test_the_dense_arithmetic_cannot_pass_100_in_this_cell(cfg):
-    """The four metrics without a ``workloads`` list read this cell with
-    ``lib/shapes.py`` (PERF.md section 7). Its decode step holds 8 dense
+    """What ``lib/shapes.py``'s count WOULD read in this cell, where two
+    metrics reported it until PR 51 gave them the list of the dense cells
+    (PERF.md section 7). Its decode step holds 8 dense
     FFNs and every key of every layer: at 12 rows x 12.8 k keys 4.2 GB,
     where the true step (this file's) reads at least the fixed weights and
     the touched experts: its share of a roofline errs LOW. Its prefill

@@ -16,7 +16,8 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 sys.path.insert(0, os.path.dirname(__file__))
 
-from bench_helpers import run_cell  # noqa: E402
+from bench_helpers import grown_from, live, run_cell  # noqa: E402
+from bench_helpers import recorded as recorded_manifest  # noqa: E402
 
 from benchmarks.chip.lib import spans, xplane  # noqa: E402
 from benchmarks.chip.lib.manifest import Manifest, validate  # noqa: E402
@@ -29,6 +30,8 @@ SPAN_METRICS = ("prefill_device_wait_ms", "fetch_lag_ms")
 SCOPE_METRICS = ("sample_share_pct", "kv_write_share_pct",
                  "unscoped_share_pct")
 NEW_METRICS = COUNTER_METRICS + SPAN_METRICS + SCOPE_METRICS
+# Where the ten start in ``per_layer`` (PR 23's list had 20).
+PR24_AT = 20
 
 
 def read(name, ctx):
@@ -312,12 +315,16 @@ def test_recorded_capture_through_the_readers(recorded):
 
 # ------------------------------------------------------------ the manifest
 def test_extended_manifest_is_valid_and_only_grew():
-    doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    """PR 24's ten where the recorded manifest has them (it held them to
+    be the LAST of ``per_layer``, which no appending PR can keep), and the
+    live manifest grown from that record at the ends of its lists only."""
+    doc, was = live(), recorded_manifest()
     assert validate(doc, REPO) == []
-    names = [m["name"] for m in doc["per_layer"]]
-    assert names[-len(NEW_METRICS):] == list(NEW_METRICS)
-    cells = [w["name"] for w in doc["workloads"]]
-    by_name = {m["name"]: m for m in doc["per_layer"]}
+    assert grown_from(doc, was) == []
+    names = [m["name"] for m in was["per_layer"]]
+    assert names[PR24_AT:PR24_AT + len(NEW_METRICS)] == list(NEW_METRICS)
+    cells = [w["name"] for w in was["workloads"]]
+    by_name = {m["name"]: m for m in was["per_layer"]}
     for name in SPAN_METRICS + SCOPE_METRICS:
         assert by_name[name]["workloads"] == cells
     for name in COUNTER_METRICS:

@@ -1,23 +1,23 @@
 """What PR 40 appended to ``BENCHMARK.json`` (a configuration, a cell, five
 per-layer metrics of the state-space scan, and the cell's name in the lists
 that name every cell), pinned to the INDICES the entries have and to no
-end of a list, so that the next appending PR needs no mark; and what
-``test_bench_hc.py`` asserts of PR 38's entries except that they are LAST
-(``tests/conftest.py`` marks those three tests), with PR 38's block pinned
-to the indices it has. The reader of the five metrics on hand-built
+end of a list, so that the next appending PR needs no mark; and PR 38's
+entries at the indices they have. What a cell REPORTS is held as the ONE
+recorded manifest has it (``data/manifest.recorded.json``, PR 51: the set
+of metrics that name a cell grows whenever a PR appends one that lists
+every cell), and the live manifest may only have grown from that record
+(``bench_helpers.grown_from``). The reader of the five metrics on hand-built
 contexts: nothing without a capture, for a model without state-space
 layers, or on the capture recorded before the scopes existed; its
 arithmetic on a made-up capture."""
 
-import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 sys.path.insert(0, os.path.dirname(__file__))
-from bench_helpers import REPO  # noqa: E402
+from bench_helpers import REPO, grown_from, live, recorded  # noqa: E402
 
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
@@ -50,12 +50,21 @@ NOT_OURS = ("hyb_decode_step_ms", "hyb_decode_roofline_pct",
             "gdn_step_roofline_pct", "gdn_chunk_roofline_pct",
             "gdn_share_pct", "moe_decode_roofline_pct") + HC_SHARED \
     + HC_METRICS
-PARENT = "87ea46fced145d5e973f0345327c489d0155937f"
+# What names every cell since: PR 49's three of the device's memory.
+HBM_METRICS = ("hbm_high_water_gb", "hbm_headroom_pct", "hbm_unexplained_gb")
+# The dense arithmetic's two list the dense cells only (PR 51):
+# ``lib/shapes.py``'s count is neither a hybrid's nor a sparse model's.
+DENSE_ONLY = ("decode_roofline_pct", "prefill_mfu_pct")
 
 
 @pytest.fixture(scope="module")
 def doc():
-    return json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    return live()
+
+
+@pytest.fixture(scope="module")
+def was():
+    return recorded()
 
 
 @pytest.fixture(scope="module")
@@ -95,16 +104,23 @@ def test_pr38_cell_is_named_where_it_was(by_name, name):
         {"out_tok_s": 3}.get(name, 1 if name in HC_SHARED else 5))
 
 
-def test_pr38_cell_reports_what_it_did(doc, by_name):
+def test_pr38_cell_reports_what_the_record_says(was):
+    by_name = {m["name"]: m for m in was["per_layer"]}
     assert by_name["moe_decode_roofline_pct"]["workloads"] == [
         "kanana-2-30b-a3b-d8.chat-saturated"]
-    listed = {m["name"] for m in doc["per_layer"]
+    listed = {m["name"] for m in was["per_layer"]
               if HC_CELL in m.get("workloads", ())}
-    assert listed == set(EVERY_CELL + HC_SHARED + HC_METRICS) | {"out_tok_s"}
+    # Every layer of this model calls the latent decode kernel once a
+    # step: kernel calls / layers IS a step, so the per-step time names it.
+    assert listed == set(EVERY_CELL + HC_SHARED + HC_METRICS + HBM_METRICS) \
+        | {"out_tok_s", "decode_step_ms"}
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         HC_CELL, "per_layer")}
-    assert {"decode_roofline_pct", "prefill_mfu_pct", "hbm_peak_gb",
-            "decode_rows_per_step"} <= reported
+    assert listed | {"attn_share_pct", "hbm_peak_gb",
+                     "decode_rows_per_step"} <= reported
+    # ``hc_decode_roofline_pct`` stands in for the dense count's share;
+    # ``prefill_dev_us_per_token`` judges prefill.
+    assert not set(DENSE_ONLY) & reported
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         HC_CELL, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
@@ -148,46 +164,38 @@ def test_another_architectures_arithmetic_is_not_this_cells(by_name, name):
     assert CELL not in by_name[name]["workloads"]
 
 
-def test_what_the_cell_reports(doc):
-    listed = {m["name"] for m in doc["per_layer"]
+def test_what_the_cell_reports_in_the_record(was):
+    listed = {m["name"] for m in was["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == set(EVERY_CELL + SSM_METRICS) | {"out_tok_s"}
+    assert listed == set(EVERY_CELL + SSM_METRICS + HBM_METRICS) \
+        | {"out_tok_s"}
     reported = {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "per_layer")}
-    # Those without a list are reported in every cell, this one too (with
-    # a dense llama's arithmetic: PERF.md section 7).
-    assert {"decode_step_ms", "decode_roofline_pct", "attn_share_pct",
-            "prefill_mfu_pct", "hbm_peak_gb", "device_idle_pct"} <= reported
+    # Those without a list are reported in every cell, this one too.
+    assert listed | {"attn_share_pct", "hbm_peak_gb",
+                     "device_idle_pct"} <= reported
     assert {m["name"] for m in Manifest(REPO).metrics_of(
         CELL, "end_to_end")} == {"req_p50_ms", "tpot_p50_ms", "setup_s"}
 
 
-def test_the_manifest_only_grew_since_the_parent(doc):
-    """Against the committed parent where git has one (a checkout the
-    driver made has no history: skipped there): nothing that was there
-    changed but twelve ``workloads`` lists, each by this cell's name."""
-    try:
-        was = json.loads(subprocess.run(
-            ["git", "show", f"{PARENT}:BENCHMARK.json"], cwd=REPO,
-            capture_output=True, check=True, text=True).stdout)
-    except (subprocess.CalledProcessError, OSError):
-        pytest.skip("no git history here")
-    for key in ("command", "paths", "run_seconds", "end_to_end"):
-        assert doc[key] == was[key]
-    for key in ("configs", "workloads"):
-        assert doc[key][:len(was[key])] == was[key]
-        assert doc[key][len(was[key])]["name"] in (CONFIG, CELL)
-    assert [m["name"] for m in doc["per_layer"][
-        len(was["per_layer"]):len(was["per_layer"]) + 5]] == list(SSM_METRICS)
-    grew = []
-    for now, then in zip(doc["per_layer"], was["per_layer"]):
-        if now != then:
-            at = len(then["workloads"])
-            assert now == dict(then, workloads=now["workloads"])
-            assert now["workloads"][:at] == then["workloads"]
-            assert now["workloads"][at] == CELL
-            grew.append(now["name"])
-    assert sorted(grew) == sorted(EVERY_CELL + ("out_tok_s",))
+def test_the_manifest_only_grew_from_the_record(doc, was):
+    """PR 40's configuration, cell and five metrics stand in the record
+    behind what its parent had (PR 38's last), and the live manifest holds
+    the record as its head."""
+    assert grown_from(doc, was) == []
+    assert (HC_CONFIG_AT + 1, HC_CELL_AT + 1, HC_AT + len(HC_METRICS)) == \
+        (CONFIG_AT, CELL_AT, SSM_AT)
+    assert (was["configs"][CONFIG_AT]["name"],
+            was["workloads"][CELL_AT]["name"]) == (CONFIG, CELL)
+    assert [m["name"] for m in was["per_layer"][
+        SSM_AT:SSM_AT + len(SSM_METRICS)]] == list(SSM_METRICS)
+    # Among what the parent had, the lists that name the cell name it
+    # right behind PR 38's.
+    for then in was["per_layer"][:SSM_AT]:
+        cells = then.get("workloads", ())
+        if CELL in cells:
+            assert cells[cells.index(CELL) - 1] == HC_CELL, then["name"]
+            assert then["name"] in EVERY_CELL + ("out_tok_s",)
 
 
 def test_the_cells_files_are_beside_the_others():

@@ -96,9 +96,77 @@ def test_spread_is_the_quartile_distance_over_the_median():
     assert stats.percentile([], 50) is None
 
 
+OPEN = [m for m in MIXES if Manifest(REPO).traffic(m)["loop"] == "open"]
+
+
+def schedule(reqs, system_tokens):
+    """(gap before it, prompt, output) of each request, in order."""
+    dues = [r.due_s for r in reqs]
+    gaps = [dues[0]] + [b - a for a, b in zip(dues, dues[1:])]
+    return [(round(g, 9), r.prompt_tokens - system_tokens, r.output_tokens)
+            for g, r in zip(gaps, reqs)]
+
+
+class NoTurn:
+    """Stands in for the run's generator: the schedule from its start."""
+    randrange = staticmethod(lambda n: 0)
+
+
+def unturned(reqs, spec, seconds=51.0):
+    """The requests from where the file's own schedule starts."""
+    _, gaps = traffic.open_schedule(spec, len(reqs), seconds, NoTurn)
+    first = round(gaps[0], 9)
+    got = schedule(reqs, spec["system"]["tokens"])
+    at = [i for i, row in enumerate(got) if row[0] == first]
+    assert len(at) == 1
+    return reqs[at[0]:] + reqs[:at[0]]
+
+
+@pytest.mark.parametrize("mix", OPEN)
+def test_an_open_loops_seed_turns_the_files_schedule_and_redraws_nothing(mix):
+    """PR 51: every seed offers the same requests after the same gaps, in
+    another order: the file's schedule started at another request."""
+    spec = Manifest(REPO).traffic(mix)
+    assert isinstance(spec["schedule_seed"], int)
+    system = spec["system"]["tokens"]
+    seeds = (1, 2, 2**31 + 12345, 2147521011)
+    first, *rest = (schedule(gen(mix, s)["requests"], system) for s in seeds)
+    starts = {first[0]}
+    for other in rest:
+        assert sorted(other) == sorted(first)
+        at = other.index(first[0])
+        assert other[at:] + other[:at] == first
+        starts.add(other[0])
+    assert len(starts) > 1
+    # the last arrival falls where it fell, so the window's tail is one gap
+    assert len({round(gen(mix, s)["requests"][-1].due_s, 6)
+                for s in seeds}) == 1
+
+
+@pytest.mark.parametrize("mix", OPEN)
+def test_an_open_loop_without_a_schedule_seed_is_refused(mix):
+    """One way to order an open loop: a file that leaves the seed out gets
+    no schedule drawn from the run's."""
+    spec = {k: v for k, v in Manifest(REPO).traffic(mix).items()
+            if k != "schedule_seed"}
+    with pytest.raises(KeyError, match="schedule_seed"):
+        traffic.generate(spec, 1, 51.0)
+
+
+@pytest.mark.parametrize("mix", OPEN)
+def test_a_sweeps_variation_turns_the_same_schedule(mix):
+    spec = Manifest(REPO).traffic(mix)
+    system = spec["system"]["tokens"]
+    steps = [traffic.generate(spec, 5, 51.0, variation=v)["requests"]
+             for v in range(4)]
+    assert len({tuple(sorted(schedule(r, system))) for r in steps}) == 1
+    assert len({r[0].messages[1]["content"] for r in steps}) == 4
+
+
 @pytest.mark.parametrize("seed", SEEDS)
 def test_every_run_of_eight_requests_spans_the_work(seed):
-    reqs = gen("chat-steady", seed)["requests"]
+    reqs = unturned(gen("chat-steady", seed)["requests"],
+                    Manifest(REPO).traffic("chat-steady"))
     ranked = sorted(reqs, key=lambda r: (r.output_tokens, r.prompt_tokens))
     octile = {id(r): i * 8 // len(ranked) for i, r in enumerate(ranked)}
     whole = len(reqs) // 8 * 8 - 8     # the last runs hold the remainders
