@@ -437,7 +437,9 @@ class EngineConfig:
             fits = supports_latent_decode(
                 specs.latent.width, specs.latent.rank, self.block_size)
         else:
-            fits = supports_pallas_decode(model_config.head_dim_,
+            # The row the kernel reads, which a module may pad or pack
+            # (``cache_specs``), not the model's own head.
+            fits = supports_pallas_decode(specs.paged_kv.head_dim,
                                           self.block_size)
         supported = (
             get_model(model_config).PAGED_DECODE_VALIDATED

@@ -293,6 +293,12 @@ class ServingEngine:
         # Both stay 0 for a model without a bounded layer.
         self.attn_keys_in_span_total = 0
         self.attn_keys_held_total = 0
+        # Keys the window layers' rings hold for the sequences of every
+        # delivered decode row-step (min(context, window) a layer), and the
+        # keys of those sequences' contexts (what one pool would hold for
+        # the same layers); both 0 for a model without a ring.
+        self.ring_keys_held_total = 0
+        self.ring_keys_context_total = 0
         # Compiles and persistent-cache loads WHILE SERVING
         # (flight_recorder.CompileClock): the process's clock, and its
         # reading when start() ended (warm-up's own work is
@@ -982,9 +988,11 @@ class ServingEngine:
                 )
                 self.last_step_time = self._last_fetch_done = \
                     time.monotonic()
+                rings = self.runner.ring_layers \
+                    if batch.kind == "decode" else 0
                 bounded = batch.kind == "decode" \
                     and self.runner.layer_spans is not None
-                if bounded:
+                if bounded or rings:
                     # The query of output token j sits at position
                     # prompt + j - 1.
                     before = [s.num_prompt_tokens + len(s.output_token_ids)
@@ -993,9 +1001,17 @@ class ServingEngine:
                     batch, tokens, lps
                 )
                 self.generation_tokens_total += accepted
-                if bounded:
+                if bounded or rings:
                     after = [s.num_prompt_tokens + len(s.output_token_ids)
                              - 1 for s in batch.seqs]
+                if rings:
+                    steps = np.subtract(after, before)
+                    self.ring_keys_held_total += rings * int(keys_in_span(
+                        before, steps,
+                        self.model_config.sliding_window).sum())
+                    self.ring_keys_context_total += rings * int(
+                        keys_in_span(before, steps, NO_SPAN).sum())
+                if bounded:
                     keys_seen, keys_held = self._attn_keys(
                         before, np.subtract(after, before))
                     self.attn_keys_in_span_total += keys_seen
@@ -1504,6 +1520,10 @@ class ServingEngine:
             "moe_prefill_experts_touched_total":
                 pre.get("experts_touched", 0),
             "moe_prefill_layer_calls_total": pre.get("layer_calls", 0),
+            # Only where the module counts them (a share of the experts).
+            **({"moe_assignments_elsewhere_total":
+                dec["assignments_elsewhere"] + pre["assignments_elsewhere"]}
+               if "assignments_elsewhere" in dec else {}),
         }
 
     def _live_perf(self) -> Dict[str, float]:
@@ -1615,6 +1635,7 @@ class ServingEngine:
                 "num_layers": self.model_config.num_layers,
                 **r.residual_report(),
                 **r.span_report(),
+                **r.ring_report(),
                 "mesh": dict(self.mesh.shape),
                 "attn_impl": r.attn_impl,
                 "pallas_interpret": r._pallas_interpret,
@@ -1787,6 +1808,8 @@ class ServingEngine:
             "prefill_segments_total": self.prefill_segments_total,
             "attn_keys_in_span_total": self.attn_keys_in_span_total,
             "attn_keys_held_total": self.attn_keys_held_total,
+            "ring_keys_held_total": self.ring_keys_held_total,
+            "ring_keys_context_total": self.ring_keys_context_total,
             "prefill_left_waiting_total": self.prefill_left_waiting_total,
             **{f"prefill_stop_{stop}_total":
                n + self.scheduler.prefill_blocked[stop]

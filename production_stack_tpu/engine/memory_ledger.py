@@ -89,6 +89,9 @@ class MemoryLedger:
         self.residents_by_device: Dict[str, Dict[str, int]] = {}
         self.device: Optional[str] = None
         self.other_arrays: List[dict] = []
+        # The ``state`` holder's bytes on the fullest device by the name
+        # its module declares each pool under (models/config.py:StateSpec).
+        self.state_pools: Dict[str, int] = {}
         self.built: Dict[str, int] = {}
         # What the ledger expects ``bytes_in_use`` to read: the read before
         # a warm-up enqueue, then the read the ledger was built at, each
@@ -252,7 +255,8 @@ class MemoryLedger:
             self._entry(program).update(analysis)
 
     def build(self, residents_by_device: Dict[str, Dict[str, int]],
-              other_arrays: List[dict]) -> None:
+              other_arrays: List[dict],
+              state_pools: Optional[Dict[str, int]] = None) -> None:
         """``start()`` has ended: enter the residents (one entry a device,
         in the reading function's order; ``other`` is entered here, bytes
         in use less the named holders) and call what follows serving."""
@@ -273,6 +277,7 @@ class MemoryLedger:
             self.residents = dict(residents_by_device.get(self.device, {}))
             self.other_arrays = [a for a in other_arrays
                                  if a["device"] == self.device]
+            self.state_pools = dict(state_pools or {})
             self.built = self._numbers(now)
             self.phase = "serving"
             self._quiet = False
@@ -292,6 +297,7 @@ class MemoryLedger:
                 "resident_bytes": self.resident_bytes,
                 "built": dict(self.built),
                 "other_arrays": list(self.other_arrays),
+                "state_pools": dict(self.state_pools),
                 "programs": {k: dict(v) for k, v in self.programs.items()},
                 "events": list(self.events),
                 "events_dropped": self.events_dropped,

@@ -1139,7 +1139,11 @@ class ModelRunner:
                 group["count"] += 1
         largest = sorted(groups.values(),
                          key=lambda g: -g["bytes"])[:16 * len(labels)]
-        self.memory.build(self.resident_bytes(), largest)
+        self.memory.build(
+            self.resident_bytes(), largest,
+            # Replicated: a device holds each pool whole.
+            {spec.name: max(_bytes_by_device([pool]).values(), default=0)
+             for spec, pool in zip(self.state_specs, self.state_pools)})
         logger.info(
             "Memory ledger (%s): residents %s; %d programs measured; "
             "peak rose %d times in warm-up",
@@ -3528,6 +3532,7 @@ class ModelRunner:
                     out[-1][name] = path
             out[-1].update(self.residual_report())
             out[-1].update(self.span_report())
+            out[-1].update(self.ring_report())
             if kind == "prefill":
                 out[-1]["prefill_attn"] = prefill_attn_path(text)
                 out[-1]["prefill_reads_pool"] = self.prefill_reads_pool
@@ -3586,6 +3591,19 @@ class ModelRunner:
         bounded = [i for i, s in enumerate(self.layer_spans) if s != NO_SPAN]
         return {"span_layers": bounded,
                 "span": int(self.layer_spans[bounded[0]])}
+
+    def ring_report(self) -> Dict:
+        """Of a model that keeps some layers' keys and values as a
+        per-sequence window ring in its state slots, or holds a share of
+        its experts: what its module says of them (``ring_report``: the
+        window layers, the ring's shape, the experts held); else nothing."""
+        report = getattr(get_model(self.model_config), "ring_report", None)
+        return report(self.model_config) if report is not None else {}
+
+    @functools.cached_property
+    def ring_layers(self) -> int:
+        """Layers that keep a window ring (0: the model has none)."""
+        return len(self.ring_report().get("window_layers", ()))
 
     def _warmup_compile_prepass(self) -> int:
         """Compile-only AOT pass over every reachable shape family using

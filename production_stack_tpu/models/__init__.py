@@ -14,6 +14,7 @@ from production_stack_tpu.models import (
     granite_hybrid,
     lfm2_moe,
     llama,
+    mimo_v2,
     olmo_hybrid,
     opt,
 )
@@ -29,7 +30,7 @@ from production_stack_tpu.models.config import (
 
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
           "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid,
-          "lfm2_moe": lfm2_moe, "afmoe": afmoe}
+          "lfm2_moe": lfm2_moe, "afmoe": afmoe, "mimo_v2": mimo_v2}
 
 
 def get_model(cfg: ModelConfig):

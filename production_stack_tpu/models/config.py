@@ -12,6 +12,7 @@ images, reference helm/templates/deployment-vllm-multi.yaml:58-134); here the
 model tier is in-repo and TPU-native.
 """
 
+import dataclasses
 import json
 import os
 from dataclasses import dataclass, field
@@ -84,7 +85,7 @@ class CacheSpecs(NamedTuple):
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid" |
-    # "lfm2_moe" | "afmoe"
+    # "lfm2_moe" | "afmoe" | "mimo_v2"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -172,6 +173,22 @@ class ModelConfig:
     # A "sliding_attention" layer's span (models/afmoe.py): a query sees
     # itself and the sliding_window - 1 keys before it. 0: no such layer.
     sliding_window: int = 0
+    # models/mimo_v2.py. A "sliding_attention" layer has swa_num_kv_heads KV
+    # heads (a full layer num_kv_heads) and rotates by swa_rope_theta; every
+    # layer rotates the first rotary_dim lanes of a head (0: all of them),
+    # scales its values by attention_value_scale and, where
+    # swa_attention_sink, a sliding layer's softmax takes one learned logit
+    # a query head into its denominator. Expert parallelism: this chip
+    # holds n_routed_experts experts of every sparse layer, those of rank
+    # ep_rank among ep_size (the router's width is n_routed_experts *
+    # ep_size; 1: all of them are here).
+    swa_num_kv_heads: int = 0
+    swa_rope_theta: float = 10000.0
+    rotary_dim: int = 0
+    attention_value_scale: float = 1.0
+    swa_attention_sink: bool = False
+    ep_size: int = 1
+    ep_rank: int = 0
 
     def __post_init__(self):
         if self.arch in ANY_ORDER_LISTS:
@@ -460,6 +477,8 @@ class ModelConfig:
                 if d.get("mup_enabled", False) else 1.0,
                 name=name,
             )
+        if model_type == "mimo_v2":
+            return _mimo_v2_config(d, name)
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
@@ -484,7 +503,102 @@ FREE_LAYER_LISTS = {"lfm2_moe": ("conv", "full_attention")}
 # Per arch whose layers differ by DATA alone (models/afmoe.py: a layer's span
 # and whether it rotates are two scalars its scans index), so its list is
 # taken in any order and need not hold both kinds.
-ANY_ORDER_LISTS = {"afmoe": ("sliding_attention", "full_attention")}
+ANY_ORDER_LISTS = {"afmoe": ("sliding_attention", "full_attention"),
+                   "mimo_v2": ("sliding_attention", "full_attention")}
+
+
+def _mimo_v2_config(d: dict, name: str) -> ModelConfig:
+    """``model_type: mimo_v2`` (models/mimo_v2.py): what the module does not
+    implement is refused by its key, not served as something else; so is
+    every key of the towers and heads the language model's row leaves out
+    (vision, audio, next-token prediction)."""
+    scaling = d.get("rope_scaling") or {}
+    pattern = list(d["hybrid_layer_pattern"])
+    freq = d.get("moe_layer_freq", 1)
+    freq = list(freq) if isinstance(freq, (list, tuple)) \
+        else [int(bool(freq))] * len(pattern)
+    dense = freq.index(1) if 1 in freq else len(freq)
+    window = d.get("sliding_window") or 0
+    ep_size, ep_rank = int(d.get("ep_size", 1)), int(d.get("ep_rank", 0))
+    unsupported = {
+        "add_full_attention_sink_bias":
+            bool(d.get("add_full_attention_sink_bias", False)),
+        "n_group/topk_group > 1":
+            max(d.get("n_group") or 1, d.get("topk_group") or 1) > 1,
+        "n_shared_experts": bool(d.get("n_shared_experts")),
+        "attention_chunk_size != sliding_window":
+            d.get("attention_chunk_size") not in (None, window),
+        "sliding_window_size != sliding_window":
+            d.get("sliding_window_size") not in (None, window),
+        "sliding_window < 1": window < 1,
+        **{f"{k} (a vision or audio tower or a next-token-prediction "
+           f"head is not served)": True for k, v in d.items()
+           if v and ("vision" in k or "audio" in k
+                     or k == "num_nextn_predict_layers")},
+        "rope_scaling.type != default":
+            scaling.get("type", scaling.get("rope_type", "default"))
+            != "default",
+        "scoring_func != sigmoid":
+            d.get("scoring_func", "sigmoid") != "sigmoid",
+        "topk_method != noaux_tc":
+            d.get("topk_method", "noaux_tc") != "noaux_tc",
+        "attention_bias": bool(d.get("attention_bias", False)),
+        "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+        "hybrid_block_size": d.get("hybrid_block_size") is not None,
+        "swa_head_dim/swa_v_head_dim/swa_num_attention_heads differ from "
+        "the full layers'": (
+            d.get("swa_head_dim", d["head_dim"]),
+            d.get("swa_v_head_dim", d["v_head_dim"]),
+            d.get("swa_num_attention_heads", d["num_attention_heads"]),
+        ) != (d["head_dim"], d["v_head_dim"], d["num_attention_heads"]),
+        "hybrid_layer_pattern / moe_layer_freq: an entry a layer, 0 or 1":
+            len(pattern) != d["num_hidden_layers"]
+            or len(freq) != d["num_hidden_layers"]
+            or bool((set(pattern) | set(freq)) - {0, 1}),
+        "hybrid_layer_pattern needs a layer of each kind":
+            set(pattern) != {0, 1},
+        "moe_layer_freq: leading dense layers, then sparse ones only":
+            any(f != 1 for f in freq[dense:]) or dense == len(freq),
+        "ep_rank outside ep_size": not 0 <= ep_rank < max(ep_size, 1),
+    }
+    asked = [k for k, on in unsupported.items() if on]
+    if asked:
+        raise ValueError(f"mimo_v2: not supported: {', '.join(asked)}")
+    return ModelConfig(
+        arch="mimo_v2",
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_key_value_heads"],
+        head_dim=d["head_dim"],
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("layernorm_epsilon", 1e-5),
+        tie_word_embeddings=d.get("tie_word_embeddings", False),
+        layer_types=tuple(ANY_ORDER_LISTS["mimo_v2"][1 - p]
+                          for p in pattern),
+        sliding_window=window,
+        v_head_dim=d["v_head_dim"],
+        swa_num_kv_heads=d.get("swa_num_key_value_heads",
+                               d["num_key_value_heads"]),
+        swa_rope_theta=float(d.get("swa_rope_theta",
+                                   d.get("rope_theta", 10000.0))),
+        # Whole pairs: rotate-half turns lane i with lane i + rotary_dim/2.
+        rotary_dim=int(d["head_dim"]
+                       * d.get("partial_rotary_factor", 1.0)) // 2 * 2,
+        attention_value_scale=float(d.get("attention_value_scale") or 1.0),
+        swa_attention_sink=bool(d.get("add_swa_attention_sink_bias", False)),
+        n_routed_experts=d["n_routed_experts"],
+        num_experts_per_tok=d["num_experts_per_tok"],
+        moe_intermediate_size=d["moe_intermediate_size"],
+        first_k_dense_replace=dense,
+        routed_scaling_factor=float(d.get("routed_scaling_factor") or 1.0),
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        ep_size=ep_size, ep_rank=ep_rank,
+        name=name,
+    )
 
 
 def _known_kinds(layer_types, num_layers: int,
@@ -710,8 +824,31 @@ TINY_AFMOE = ModelConfig(
     name="tiny-afmoe",
 )
 
+# Tiny window-ring sparse-expert decoder: full layers of 1 KV head and window
+# layers of 2, keys of 48 lanes (16 of them rotate) and values of 32, a window
+# of 128 keys with a sink, 1 leading dense layer, 16 experts top-4 with no
+# shared one (tests/test_mimo_v2.py compares it with the plain reference);
+# and the same as rank 1 of 4 chips that share every sparse layer's experts.
+TINY_MIMO_V2 = ModelConfig(
+    arch="mimo_v2", vocab_size=512, hidden_size=128, intermediate_size=256,
+    num_layers=6, num_heads=4, num_kv_heads=1, head_dim=48,
+    max_position_embeddings=1024, rope_theta=10000000.0, rms_norm_eps=1e-5,
+    layer_types=("full_attention", "sliding_attention", "sliding_attention",
+                 "full_attention", "sliding_attention", "full_attention"),
+    sliding_window=128, v_head_dim=32, swa_num_kv_heads=2,
+    swa_rope_theta=10000.0, rotary_dim=16, attention_value_scale=0.707,
+    swa_attention_sink=True, n_routed_experts=16, num_experts_per_tok=4,
+    moe_intermediate_size=64, first_k_dense_replace=1,
+    name="tiny-mimo-v2",
+)
+TINY_MIMO_V2_EP4 = dataclasses.replace(
+    TINY_MIMO_V2, n_routed_experts=4, ep_size=4, ep_rank=1,
+    name="tiny-mimo-v2-ep4")
+
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-mimo-v2": TINY_MIMO_V2,
+    "tiny-mimo-v2-ep4": TINY_MIMO_V2_EP4,
     "tiny-afmoe": TINY_AFMOE,
     "tiny-lfm2-moe": TINY_LFM2_MOE,
     "tiny-granite-hybrid": TINY_GRANITE_HYBRID,

@@ -101,56 +101,72 @@ def load_hf_params(
     # Leaves the module computes with in float32 whatever ``dtype`` is.
     keep_f32 = set(getattr(model, "FLOAT32_LEAVES", ()))
 
+    # A module whose checkpoint fuses several of its leaves into one tensor
+    # takes it apart first (models/mimo_v2.py:split_fused).
+    split = getattr(model, "split_fused", None)
+    # Expert parallelism: this chip files the experts it holds, numbered
+    # from its first (0 where every expert is here), and passes over the
+    # others.
+    first_expert = cfg.ep_rank * cfg.n_routed_experts
+
+    def file_layer_tensor(hf_name, layer_idx, suffix, tensor):
+        em = _EXPERT_RE.match(suffix)
+        if em is not None:
+            suffix = em.group(1) + "*" + em.group(3)
+        mapped = per_layer_map.get(suffix)
+        if mapped is None:
+            logger.debug("Skipping unmapped tensor %s", hf_name)
+            return
+        ours, transpose = mapped
+        t = tensor.T if transpose else tensor
+        if nl <= layer_idx < nl + cfg.num_nextn_predict_layers:
+            # Published next-token-prediction layers lie behind the
+            # last layer and are not served (models/deepseek_v3.py).
+            logger.debug("Skipping next-token-prediction tensor %s",
+                         hf_name)
+            return
+        if layer_idx >= nl:
+            raise ValueError(
+                f"Checkpoint tensor {hf_name} indexes layer {layer_idx} "
+                f"but the config has only {nl} layers"
+            )
+        depth = nl
+        if slots is not None:
+            slot = slots[layer_idx]
+            kind, layer_idx = slot[ours] if isinstance(slot, dict) \
+                else slot
+            ours, depth = f"{kind}/{ours}", sizes[kind]
+        if em is not None:
+            # Filed per (layer, expert); a layer counts as filled when
+            # its last expert has arrived (holes: the check below).
+            e, n_e = int(em.group(2)) - first_expert, cfg.n_routed_experts
+            if cfg.ep_size > 1 and not 0 <= e < n_e:
+                return
+            if ours not in stacks:
+                stacks[ours] = np.empty((depth, n_e) + t.shape, t.dtype)
+                filled[ours] = set()
+                seen_experts[ours] = {}
+            stacks[ours][layer_idx, e] = t
+            seen = seen_experts[ours].setdefault(layer_idx, set())
+            seen.add(e)
+            if len(seen) == n_e:
+                filled[ours].add(layer_idx)
+            return
+        if ours not in stacks:
+            stacks[ours] = np.empty((depth,) + t.shape, t.dtype)
+            filled[ours] = set()
+        stacks[ours][layer_idx] = t
+        filled[ours].add(layer_idx)
+
     for hf_name, tensor in _iter_checkpoint_tensors(model_dir):
         m = _LAYER_RE.search(hf_name)
         if m is not None:
             layer_idx = int(m.group(1))
             suffix = hf_name[m.end():]
-            em = _EXPERT_RE.match(suffix)
-            if em is not None:
-                suffix = em.group(1) + "*" + em.group(3)
-            mapped = per_layer_map.get(suffix)
-            if mapped is None:
-                logger.debug("Skipping unmapped tensor %s", hf_name)
-                continue
-            ours, transpose = mapped
-            t = tensor.T if transpose else tensor
-            if nl <= layer_idx < nl + cfg.num_nextn_predict_layers:
-                # Published next-token-prediction layers lie behind the
-                # last layer and are not served (models/deepseek_v3.py).
-                logger.debug("Skipping next-token-prediction tensor %s",
-                             hf_name)
-                continue
-            if layer_idx >= nl:
-                raise ValueError(
-                    f"Checkpoint tensor {hf_name} indexes layer {layer_idx} "
-                    f"but the config has only {nl} layers"
-                )
-            depth = nl
-            if slots is not None:
-                slot = slots[layer_idx]
-                kind, layer_idx = slot[ours] if isinstance(slot, dict) \
-                    else slot
-                ours, depth = f"{kind}/{ours}", sizes[kind]
-            if em is not None:
-                # Filed per (layer, expert); a layer counts as filled when
-                # its last expert has arrived (holes: the check below).
-                e, n_e = int(em.group(2)), cfg.n_routed_experts
-                if ours not in stacks:
-                    stacks[ours] = np.empty((depth, n_e) + t.shape, t.dtype)
-                    filled[ours] = set()
-                    seen_experts[ours] = {}
-                stacks[ours][layer_idx, e] = t
-                seen = seen_experts[ours].setdefault(layer_idx, set())
-                seen.add(e)
-                if len(seen) == n_e:
-                    filled[ours].add(layer_idx)
-                continue
-            if ours not in stacks:
-                stacks[ours] = np.empty((depth,) + t.shape, t.dtype)
-                filled[ours] = set()
-            stacks[ours][layer_idx] = t
-            filled[ours].add(layer_idx)
+            pieces = ((suffix, tensor),) if split is None or layer_idx >= nl \
+                else split(cfg, layer_idx, suffix, tensor)
+            for suffix, piece in pieces:
+                file_layer_tensor(hf_name, layer_idx, suffix, piece)
         else:
             mapped = top_map.get(hf_name)
             if mapped is None:

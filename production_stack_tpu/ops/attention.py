@@ -290,6 +290,154 @@ def merge_attention_segments(
     return out.astype(out_a.dtype)
 
 
+def sink_merged(out: jax.Array, m: jax.Array, l: jax.Array,
+                sink: jax.Array) -> jax.Array:
+    """A normalized attention segment ``out`` [..., H, Dv] with its softmax
+    statistics ``m``, ``l`` [..., H] (float32) after a learned SINK joins
+    the softmax's denominator: ``sink`` [H], one logit a query head, a key
+    every query sees whose value is zero. One more segment of
+    ``merge_attention_segments``: ``(0, sink, 1)``."""
+    with jax.named_scope("attn_sink"):
+        sink = jnp.broadcast_to(sink.astype(jnp.float32), m.shape)
+        return merge_attention_segments(
+            out, m, l, jnp.zeros_like(out), sink, jnp.ones_like(l))
+
+
+def window_ring_positions(start: jax.Array, window: int) -> jax.Array:
+    """The position each slot of a sequence's WINDOW RING holds before the
+    token at ``start`` [B] is written: slot s keeps the newest position
+    below ``start`` that is s modulo ``window``; a slot nothing was written
+    to yet reads far behind every span. [B, window] int32."""
+    s = jnp.arange(window, dtype=jnp.int32)[None, :]
+    last = start[:, None] - 1
+    held = last - jnp.mod(last - s, window)
+    return jnp.where(held >= 0, held, -NO_SPAN)
+
+
+def window_ring_attend(
+    q: jax.Array,            # [B, T, H, Dk] queries (post-rope)
+    k: jax.Array,            # [B, T, Hkv, Dk] this chunk's keys
+    v: jax.Array,            # [B, T, Hkv, Dv]
+    positions: jax.Array,    # [B, T] consecutive from a row's positions[:, 0]
+    chunk_lens: jax.Array,   # [B] valid tokens per row
+    ring_k: jax.Array,       # [B, Hkv, W, Dk] the rows' rings BEFORE the chunk
+    ring_v: jax.Array,       # [B, Hkv, W, Dv]
+    *,
+    scale: float,
+    sink: Optional[jax.Array] = None,    # [H] float32
+) -> jax.Array:
+    """Attention of a layer whose queries see the W newest keys up to
+    themselves and whose sequences keep exactly those: a per-sequence ring
+    of W slots in a state slot (models/config.py:StateSpec), position p in
+    slot p mod W, instead of paged rows. The one statement for a decode
+    step (T == 1) and a prefill chunk: a query at position i sees the ring's
+    keys and the chunk's at ``0 <= i - j < W``; the ring slot the chunk's
+    first token will overwrite holds position ``start - W`` and is out of
+    every query's span already. A chunk of whole windows is scored a window
+    of queries at a time against the window of keys before it (the ring for
+    the first) and its own: [.., W, 2 W] scores a block, never [T, T].
+    ``sink``: see ``sink_merged``. Returns [B, T, H, Dv] in q.dtype."""
+    b, t, h, dk = q.shape
+    hkv, w = ring_k.shape[1], ring_k.shape[2]
+    g = h // hkv
+    with jax.named_scope("ring_attend"):
+        start = positions[:, 0]
+        t_idx = jnp.arange(t, dtype=jnp.int32)
+        # A key past its row's length lies ahead of every query.
+        pos_c = jnp.where(t_idx[None, :] < chunk_lens[:, None], positions,
+                          NO_SPAN)
+        pos_k = jnp.concatenate(
+            [window_ring_positions(start, w), pos_c], axis=1)  # [B, W + T]
+        keys = jnp.concatenate(
+            [ring_k.astype(k.dtype), k.transpose(0, 2, 1, 3)], axis=2)
+        vals = jnp.concatenate(
+            [ring_v.astype(v.dtype), v.transpose(0, 2, 1, 3)], axis=2)
+        # Blocks of queries and the keys each can see: whole windows where
+        # the chunk is, else the one block of everything.
+        tq = w if t % w == 0 and t > w else t
+        nb = t // tq
+
+        def blocks(x, axis):
+            # [.., W + T, ..] -> [.., nb, W + tq, ..]: block n holds the tq
+            # entries of its queries and the W before them.
+            if nb == 1:
+                return jnp.expand_dims(x, axis)
+            shape = x.shape[:axis] + (nb, tq) + x.shape[axis + 1:]
+            before = jax.lax.slice_in_dim(x, 0, t, axis=axis).reshape(shape)
+            own = jax.lax.slice_in_dim(x, w, w + t, axis=axis).reshape(shape)
+            return jnp.concatenate([before, own], axis=axis + 1)
+
+        qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
+        qf = qf.reshape(b, nb, tq, hkv, g, dk).transpose(0, 3, 1, 4, 2, 5)
+        scores = jnp.einsum(
+            "bhngqd,bhnkd->bhngqk", qf, blocks(keys, 2),
+            preferred_element_type=jnp.float32)
+        dist = positions.reshape(b, nb, tq)[:, :, :, None] \
+            - blocks(pos_k, 1)[:, :, None, :]                  # [B, nb, tq, K]
+        seen = (dist >= 0) & (dist < w)
+        scores = jnp.where(seen[:, None, :, None], scores,
+                           jnp.float32(_NEG_INF))
+        m = jnp.max(scores, axis=-1)
+        p = jnp.exp(scores - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        vb = blocks(vals, 2)
+        out = jnp.einsum("bhngqk,bhnkd->bhngqd", p.astype(vb.dtype), vb,
+                         preferred_element_type=jnp.float32)
+        out = out / l[..., None]
+        # [B, Hkv, nb, G, tq, ..] -> [B, T, H, ..]
+        out = out.transpose(0, 2, 4, 1, 3, 5).reshape(b, t, h, -1)
+        m = m.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
+        l = l.transpose(0, 2, 4, 1, 3).reshape(b, t, h)
+    if sink is not None:
+        out = sink_merged(out, m, l, sink)
+    return out.astype(q.dtype)
+
+
+def window_ring_write(
+    rings: Tuple[jax.Array, ...],   # each [B, Lr, Hkv, W, D*]: the rows' rings
+    at: jax.Array,                  # [] int32: the layer's index among Lr
+    new: Tuple[jax.Array, ...],     # each [B, T, Hkv, D*]: the chunk's rows
+    positions: jax.Array,           # [B, T]
+    chunk_lens: jax.Array,          # [B] valid tokens (0: the ring stays)
+) -> Tuple[jax.Array, ...]:
+    """The rows' rings after their chunk: position p in slot p mod W of
+    layer ``at``, every other layer and the rings of rows without a valid
+    token as they were. A decode step (T == 1) writes its one row in place
+    (a scatter a row: nothing else of the carry moves); a chunk gathers,
+    for every slot, the chunk's newest token that lands there."""
+    w = rings[0].shape[3]
+    b, t = positions.shape
+    with jax.named_scope("ring_write"):
+        s = jnp.arange(w, dtype=jnp.int32)[None, :]
+        if t == 1:
+            # The one row into its slot of the layer, every other slot as
+            # it was: a select over the layer's slice, which keeps the
+            # rings in the layout the attention reads them in (a scatter of
+            # [Hkv, D] rows would have the heads on the rows' axis).
+            lands = (chunk_lens[:, None] > 0) \
+                & (s == jnp.mod(positions[:, :1], w))            # [B, W]
+
+            def taken(x):
+                return x.transpose(0, 2, 1, 3)                   # [B,Hkv,1,D]
+        else:
+            end = positions[:, :1] + chunk_lens[:, None] - 1   # last valid
+            src = chunk_lens[:, None] - 1 - jnp.mod(end - s, w)  # [B, W]
+            lands = src >= 0
+
+            def taken(x):
+                return jnp.take_along_axis(
+                    x, jnp.maximum(src, 0)[:, :, None, None],
+                    axis=1).transpose(0, 2, 1, 3)
+        out = []
+        for ring, x in zip(rings, new):
+            old = jax.lax.dynamic_index_in_dim(ring, at, 1, False)
+            layer = jnp.where(lands[:, None, :, None],
+                              taken(x).astype(ring.dtype), old)
+            out.append(jax.lax.dynamic_update_index_in_dim(
+                ring, layer, at, 1))
+        return tuple(out)
+
+
 class KVView(NamedTuple):
     """The KV a forward may read, every part optional. The runner builds it;
     a model passes it to ``scan_layers`` and ``attend`` without opening it.

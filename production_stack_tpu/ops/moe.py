@@ -21,12 +21,20 @@ that do not count (``valid`` false: a bucket's padded rows, a prompt's
 padding, a decode row past its budget) are given to no expert: they sort
 behind every run and are neither computed nor counted.
 
+Expert parallelism (``expert_ffn(..., here=)``): the router keeps its whole
+width and its k, and a chip holds SOME of a layer's experts. A pair whose
+expert lies on another chip is given to no expert here, exactly as a token
+that does not count: it sorts behind every run and is neither computed nor
+counted among the assignments (it is counted apart, ``STATS_EP``'s last).
+What the absent experts would have added is left out: nothing stands in
+for the other chips or for their exchange.
+
 ``grouped_matmul`` is the one entry to the product: a program lowered for
 a TPU holds the Pallas kernel (and so does one with ``interpret`` set: a
 CPU's tests of the kernel), every other ``jax.lax.ragged_dot``.
 """
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +44,9 @@ _HI = jax.lax.Precision.HIGHEST
 # calls): pairs computed, distinct experts touched, the busiest expert's
 # tokens, calls.
 STATS = ("assignments", "experts_touched", "expert_load_max", "layer_calls")
+# The same of a layer that holds a share of its experts, and the pairs its
+# router gave to experts held elsewhere.
+STATS_EP = STATS + ("assignments_elsewhere",)
 
 
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, top_k: int,
@@ -75,18 +86,28 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 
 def expert_ffn(x: jax.Array, idx: jax.Array, w: jax.Array, valid: jax.Array,
                w_gate_up: jax.Array, w_down: jax.Array, *,
-               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+               interpret: bool = False,
+               here: Optional[jax.Array] = None,
+               ) -> Tuple[jax.Array, jax.Array]:
     """``sum_j w[n, j] * expert_{idx[n, j]}(x[n])`` for the valid tokens.
 
     x [N, D]; idx, w [N, k]; valid [N] bool; w_gate_up [E, D, 2F] (gate
     then up); w_down [E, F, D]. Returns (y [N, D] float32, zeros where a
-    token is not valid; stats int32[4] as ``STATS`` names them)."""
+    token is not valid; stats int32[4] as ``STATS`` names them).
+
+    ``here`` [N, k] bool (None, static: every expert is held, and the
+    program as it was): the pairs whose expert this chip holds, ``idx``
+    being the expert's place in the stacks HELD for those and anything for
+    the others, which add nothing; stats int32[5] as ``STATS_EP``."""
     n, d = x.shape
     k = idx.shape[1]
     e, f = w_down.shape[0], w_down.shape[1]
     with jax.named_scope("moe_experts"):
-        # Pairs in expert order; a token that does not count sorts last.
-        pair_expert = jnp.where(valid[:, None], idx, e).reshape(-1)
+        # Pairs in expert order; a token that does not count sorts last,
+        # and so does a pair whose expert is held elsewhere.
+        counts = None if here is None else valid[:, None] & here
+        pair_expert = jnp.where(
+            valid[:, None] if here is None else counts, idx, e).reshape(-1)
         order = jnp.argsort(pair_expert, stable=True)             # [N*k]
         group_sizes = jnp.zeros((e,), jnp.int32).at[pair_expert].add(
             1, mode="drop")
@@ -99,9 +120,12 @@ def expert_ffn(x: jax.Array, idx: jax.Array, w: jax.Array, valid: jax.Array,
         # Back to token order, weighted; what lies behind the last run was
         # never computed and is replaced, not multiplied.
         back = jnp.argsort(order)
-        y = jnp.where(valid[:, None, None],
+        y = jnp.where(valid[:, None, None] if here is None
+                      else counts[:, :, None],
                       out[back].reshape(n, k, d) * w[:, :, None], 0.0)
-        stats = jnp.stack([
-            jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
-            jnp.max(group_sizes), jnp.int32(1)]).astype(jnp.int32)
+        stats = [jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
+                 jnp.max(group_sizes), jnp.int32(1)]
+        if here is not None:
+            stats.append(jnp.sum(valid[:, None] & ~here))
+        stats = jnp.stack(stats).astype(jnp.int32)
         return jnp.sum(y, axis=1), stats

@@ -51,6 +51,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "prefill_tokens_issued_total", "prefill_tokens_padded_total",
                 "prefill_rows_issued_total", "prefill_segments_total",
                 "attn_keys_in_span_total", "attn_keys_held_total",
+                "ring_keys_held_total", "ring_keys_context_total",
                 "prefill_left_waiting_total",
                 "prefill_stop_rows_total", "prefill_stop_seqs_total",
                 "prefill_stop_tokens_total", "prefill_stop_window_total",
@@ -426,6 +427,18 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "the keys the one block table holds for those queries",
         "# TYPE pstpu:attn_keys_held_total counter",
         f"pstpu:attn_keys_held_total{label} {s['attn_keys_held_total']}",
+        "# HELP pstpu:ring_keys_held_total Keys the window layers' "
+        "per-sequence rings hold (min(context, window) a layer) for the "
+        "sequence of every delivered decode row-step (0 for a model "
+        "without a ring)",
+        "# TYPE pstpu:ring_keys_held_total counter",
+        f"pstpu:ring_keys_held_total{label} {s['ring_keys_held_total']}",
+        "# HELP pstpu:ring_keys_context_total The keys of those "
+        "sequences' contexts over the same layers: what one pool would "
+        "hold for them",
+        "# TYPE pstpu:ring_keys_context_total counter",
+        f"pstpu:ring_keys_context_total{label} "
+        f"{s['ring_keys_context_total']}",
         "# HELP pstpu:prefill_left_waiting_total Requests still waiting "
         "that a prefill could have taken, summed over prefill "
         "dispatches at the end of their admission pass",
@@ -665,4 +678,17 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
     http_surface = getattr(engine, "http_surface", None)
     if http_surface is not None:
         lines += http_surface.render(label)
+    if "moe_assignments_elsewhere_total" in s:
+        # Only a model whose chip holds a SHARE of its experts counts them
+        # (``ops/moe.py:STATS_EP``): every other model's series are as
+        # they were.
+        lines += [
+            "# HELP pstpu:moe_assignments_elsewhere_total Token-expert "
+            "pairs the router gave to experts another chip of the "
+            "expert-parallel deployment holds: neither computed nor "
+            "counted among the assignments here",
+            "# TYPE pstpu:moe_assignments_elsewhere_total counter",
+            f"pstpu:moe_assignments_elsewhere_total{label} "
+            f"{s['moe_assignments_elsewhere_total']}",
+        ]
     return "\n".join(lines) + "\n"

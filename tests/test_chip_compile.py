@@ -1523,3 +1523,95 @@ def test_programs_of_the_bounded_span_deployment_fit_the_compile_cache(v5e):
                                         (128, 256, 512, 1024, 2048)]
     assert {f[3] for f in prefill} == {False}
     assert len(r.reachable_decode_families()) + len(prefill) <= 24
+
+
+# ---- mimo-v2.5-ep16: a window ring in the state slots, a share of the
+# experts (PR 52)
+# Instructions of a compiled dispatch program (ONE scan over the sparse
+# layers with both kinds of attention under a ``cond``, the dense layer
+# traced once beside it).
+MIMO_INSTRUCTIONS = 9000
+
+
+@pytest.mark.parametrize("program", ["decode-32x32", "decode-8x32",
+                                     "prefill-1x2048", "prefill-1x128",
+                                     "prefill-16x128"])
+def test_window_ring_dispatch_programs_compile_in_place_for_v5e(v5e,
+                                                                program):
+    """The decode program at the widest bucket and at 8 rows and three
+    prefill rectangles of mimo-v2.5-ep16's envelope (deployment.json's
+    flags, published widths, 16 of 256 experts of 11 sparse layers, nine
+    window layers' rings in the state slots, three full layers paged at 256
+    lanes) compile for a v5e, fit its HBM beside 11.83 GB of weights, the
+    2.01 GB K/V pool and the rings, and copy neither a pool nor an expert
+    stack. They hold the Mosaic kernels of the FULL layers alone (the paged
+    decode kernel, or the flash prefill kernel, once in the dense layer and
+    once in the scan) and the two grouped matmuls; the window layers are
+    XLA under their scopes."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.kv_write import pool_copies
+
+    r = _deployment_runner(v5e, "mimo-v2.5-ep16")
+    assert r.kv_k.shape == r.kv_v.shape == (3, 4, 10240 * 16, 256)
+    assert [p.shape for p in r.state_pools] == [
+        (33, 9, 8, 128, 192), (33, 9, 8, 128, 128)]
+    assert r.prefill_reads_pool and not r.prefill_packs
+    assert r.fwd_stats[-1] == "assignments_elsewhere"
+    assert r.ring_report() == {
+        "window_layers": [1, 2, 3, 4, 6, 7, 8, 9, 10],
+        "ring": {"ring_k": [8, 128, 192], "ring_v": [8, 128, 128]},
+        "experts_held": [0, 16], "experts_routed": 256}
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    sparse = aparams["layers"]["sparse"]
+    assert sparse["w_gate_up"].shape == (11, 16, 4096, 4096)
+    assert sparse["w_router"].shape == (11, 4096, 256)
+    assert sparse["w_router"].dtype == jnp.float32
+    assert aparams["lm_head"].shape == (4096, 19072)
+    decode = program.startswith("decode")
+    rows, t = (int(x) for x in program.split("-")[1].split("x"))
+    if decode:
+        lowered = r._lower_decode(aparams, rows, full_mb, t, False)
+    else:
+        assert (rows, t, full_mb, False) in r.reachable_prefill_families()
+        lowered = r._lower_prefill(aparams, rows, t, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    experts = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for k in (
+        "w_gate_up", "we_down") for shape in (
+            sparse[k].shape, (11 * 16, *sparse[k].shape[2:]))]
+    assert pool_copies(text, [r.kv_k, *r.state_pools, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert ("%paged_flash_decode" in text) == decode
+    if not decode:
+        assert prefill_attn_path(text) == "pallas"
+    for scope in ("embed", "attn_proj", "attn_core", "ring_attend",
+                  "attn_sink", "ring_write", "ffn", "moe_route",
+                  "moe_experts", "moe_gmm", "logits", "kv_write",
+                  "state_read", "state_write", "sample"):
+        assert f"/{scope}/" in text, scope
+    instructions = sum(1 for ln in text.splitlines() if " = " in ln)
+    assert instructions < MIMO_INSTRUCTIONS, instructions
+    mem = compiled.memory_analysis()
+    # Weights 11.83 GB, K/V 2.01 GB and the rings' pools (0.23 GB as laid
+    # out) are arguments; a program's temporaries fit beside them.
+    assert 13.9e9 < mem.argument_size_in_bytes < 14.3e9, \
+        mem.argument_size_in_bytes
+    assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_programs_of_the_window_ring_deployment_fit_the_compile_cache(v5e):
+    """8 prefill rectangles and the decode families of mimo-v2.5-ep16's
+    envelope, counted before chip time (the chip machine caps a
+    configuration's compile cache at 192 MiB: PERF.md section 6, PR 31 and
+    PR 33)."""
+    r = _deployment_runner(v5e, "mimo-v2.5-ep16")
+    prefill = r.reachable_prefill_families()
+    assert [f[:2] for f in prefill] == [
+        (1, 128), (1, 256), (1, 512), (1, 1024), (1, 2048), (8, 128),
+        (8, 256), (16, 128)]
+    assert {f[3] for f in prefill} == {False}
+    assert len(r.reachable_decode_families()) + len(prefill) <= 24

@@ -154,6 +154,14 @@ REGISTRY: Tuple[Series, ...] = (
     Series("pstpu:moe_prefill_layer_calls_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
            "Sparse-layer calls of prefill chunks"),
+    Series("pstpu:moe_assignments_elsewhere_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Token-expert pairs the router gave to experts another chip of "
+           "the expert-parallel deployment holds (`ep_size` > 1: the chip "
+           "holds a share of every sparse layer's experts, "
+           "`ops/moe.py:expert_ffn(here=)`): neither computed nor counted "
+           "among `pstpu:moe_assignments_total` here; 0 where every "
+           "expert is here"),
     Series("pstpu:prefix_hit_tokens_unserved_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
            "Prompt tokens whose K/V the prefix index held but that were "
@@ -587,6 +595,18 @@ REGISTRY: Tuple[Series, ...] = (
            "The same with no layer bounded: the keys the one block table "
            "holds for those queries; in-span over held is the share of "
            "the held keys the bounded kernels read"),
+    Series("pstpu:ring_keys_held_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "Keys the window layers' per-sequence rings hold (a model whose "
+           "sliding-window layers keep their window as a ring in a state "
+           "slot, `ops/attention.py:window_ring_attend`): min(context, "
+           "window) a layer, for the sequence of every delivered decode "
+           "row-step; 0 for a model without a ring"),
+    Series("pstpu:ring_keys_context_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "loop"),
+           "The keys of those sequences' contexts over the same layers: "
+           "what one pool would hold for them; held over context is the "
+           "share of a pool's keys the rings keep"),
     Series("pstpu:prefill_left_waiting_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "loop"),
            "Requests still waiting that a prefill could have taken, at "
