@@ -30,7 +30,8 @@ their ratio, the kernel's own roofline share. It is read by no metric.
 ``--memory-stats`` (alone) times ``device.memory_stats()``, which the engine
 calls twice a dispatch. Each serving line's ``memory`` is the engine's memory
 ledger after the phase (``GET /debug/memory``: residents, rises, the last).
-``--prefill`` (alone, like ``--gdn``, ``--ssd``, ``--moe`` and ``--hc``) does the same for the
+``--prefill`` (alone, like ``--gdn``, ``--ssd``, ``--ring``, ``--moe`` and
+``--hc``) does the same for the
 prefill flash kernels at the benchmark's prefill shapes (four over K/V rows,
 nine over latent rows), with ``window_attention`` at the parent's window
 width beside it.
@@ -917,6 +918,189 @@ def ssd_child(rehearse: bool) -> int:
           "timing": timing, "paged_decode_kernel": kernel, "peak": peak,
           "device": device, "ok": finite})
     return 0 if finite else 1
+
+
+# --ring: the window ring's decode step alone (ops/attention.py:
+# window_ring_step) at MiMo-V2.5's published widths (benchmarks/chip/
+# configs/mimo-v2.5-ep16/config.json): 9 window layers' rings of 8 KV heads
+# x 128 slots in a carry of the cell's two decode buckets, 64 queries a row,
+# positions past the window; 4, 13 (longctx-decode's mean in its 32-row
+# bucket) and 32 live rows. The kernel beside the XLA statement on the same
+# inputs, and at (32, 13) the series of the kernel's two constants (a row's
+# heads are one block: blocks of 4 / 2 / 1 heads read 4-30 us a call more in
+# PR 53's first series, and Mosaic loads no single sublane at a traced head).
+RING_CONFIG = os.path.join(HERE, "benchmarks", "chip", "configs",
+                           "mimo-v2.5-ep16", "config.json")
+RING_STEP_ROWS = ((32, 1), (32, 4), (32, 13), (32, 32), (8, 4), (8, 8))
+RING_SERIES_AT = (32, 13)
+# (NUM_BUFS, FETCH_AHEAD) of ops/pallas/window_ring.py
+RING_SERIES = ((2, 1), (3, 1), (3, 2), (4, 2))
+RING_CALLS = 8
+
+
+def ring_child(rehearse: bool) -> int:
+    """``--ring``: times one decode step of a window layer alone on the
+    chip, the calls chained through the layers of a donated pair of rings
+    in turn (in place), every operand behind an ``optimization_barrier``
+    (``chained_chunks`` says why): the kernel a program lowered for a TPU
+    holds, the ``jnp`` statement and the chain with no step in it (what the
+    harness itself costs a call), microseconds a LIVE row-layer beside
+    the time of its bytes (the payload benchmarks/chip/lib/shapes_mimo.py
+    counts, and as the rows lie in HBM: 192 lanes in 256, a tile of slots
+    written a head), then the kernel under other values of its two
+    constants. Fails where the kernel's program holds the ``jnp`` form on a
+    TPU, or the two disagree. Run by no benchmark cell and no other
+    phase."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.chip.lib import shapes_mimo
+    from production_stack_tpu.models.mimo_v2 import ring_width
+    from production_stack_tpu.ops import attention as att
+    from production_stack_tpu.ops.pallas import window_ring
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    if dev.platform != "tpu" and not rehearse:
+        emit({"phase": "ring", "ok": False, "device": device,
+              "error": "no TPU: nothing was timed"})
+        return 1
+    with open(RING_CONFIG) as f:
+        cfg = json.load(f)
+    d = shapes_mimo.dims(cfg)
+    layers, w, dk, dv = d["windowed"], d["window"], d["dk"], d["dv"]
+    h, hkv = d["heads"], cfg["swa_num_key_value_heads"]
+    rows_list, series, calls = RING_STEP_ROWS, RING_SERIES, RING_CALLS
+    dtype = jnp.bfloat16
+    if rehearse:
+        rows_list, series, calls, layers, hkv, h, dtype = \
+            ((3, 2),), ((2, 1),), 1, 2, 2, 16, jnp.float32
+    with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
+        peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+    scale = dk ** -0.5
+    tile = window_ring.tile_rows(dtype)
+    item = jnp.dtype(dtype).itemsize
+
+    def inputs(b, n_live):
+        ks = jax.random.split(jax.random.PRNGKey(b * 64 + n_live), 7)
+
+        def draw(key, *shape):
+            return jax.random.normal(key, shape, jnp.float32).astype(dtype)
+
+        ring_k = jnp.pad(draw(ks[0], b, layers, hkv, w, dk),
+                         ((0, 0),) * 4 + ((0, ring_width(dk) - dk),))
+        ring_v = draw(ks[1], b, layers, hkv, w, dv)
+        # The live rows spread over the bucket, as a bucket's are;
+        # positions past the window, so every slot is seen.
+        lens = ((jnp.arange(b) * n_live) % b < n_live).astype(jnp.int32)
+        pos = jax.random.randint(ks[2], (b, 1), 2 * w, 64 * w)
+        sink = 2.0 + jax.random.normal(ks[3], (h,), jnp.float32)
+        return (ring_k, ring_v), (draw(ks[4], b, 1, h, dk),
+                                  draw(ks[5], b, 1, hkv, dk),
+                                  draw(ks[6], b, 1, hkv, dv), pos, lens, sink)
+
+    def chained(step):
+        def steps(rings, q, k, v, pos, lens, sink):
+            def one(i, both):
+                rings, acc = both
+                rings, *xs = jax.lax.optimization_barrier(
+                    (rings, q, k, v, pos, lens, sink))
+                o, rings = step(rings, i % layers, *xs[:5], scale=scale,
+                                sink=xs[5])
+                return tuple(rings), acc + o.astype(jnp.float32)
+            return jax.lax.fori_loop(
+                0, calls * layers, one,
+                (rings, jnp.zeros((q.shape[0], 1, h, dv), jnp.float32)))
+        return steps
+
+    def timed(step, rings, xs):
+        """(seconds a call, the program's execution of the step)"""
+        steps = jax.jit(chained(step), donate_argnums=0).lower(
+            rings, *xs).compile()
+        rings = jax.tree.map(jnp.copy, rings)     # the caller keeps its own
+        best = float("inf")
+        for _ in range(6):        # the first run is the warm-up
+            t0 = time.perf_counter()
+            rings, acc = jax.block_until_ready(steps(rings, *xs))
+            best = min(best, time.perf_counter() - t0)
+        assert bool(jnp.all(jnp.isfinite(acc)))
+        return best / (calls * layers), att.ring_step_path(steps.as_text())
+
+    def entry(form, sec, n_live, b, path, **more):
+        # A live row-layer: the payload shapes_mimo counts (129 rows of a
+        # head's keys and values), and what moves as the rows lie: the
+        # slots' whole lane tiles read, a tile of slots a head written.
+        payload = (w + 1) * shapes_mimo.ring_row_bytes(cfg)
+        laid = hkv * (w + tile) * (ring_width(dk) + ring_width(dv)) * item
+        out = {"form": form, "rows": b, "live": n_live, "path": path,
+               **more, "payload_bytes_a_row_layer": payload,
+               "laid_out_bytes_a_row_layer": laid, "us_per_call": None,
+               "us_per_live_row_layer": None, "payload_us": None,
+               "laid_out_us": None, "roofline_pct": None}
+        if peak and not rehearse:
+            gbps = peak["hbm_gbps"] * 1e9
+            out.update(
+                us_per_call=sec * 1e6,
+                us_per_live_row_layer=sec * 1e6 / n_live,
+                payload_us=payload / gbps * 1e6, laid_out_us=laid / gbps * 1e6,
+                roofline_pct=100.0 * n_live * payload / gbps / sec)
+        return out
+
+    kernel = functools.partial(att.window_ring_step, interpret=rehearse)
+
+    def harness(rings, at, q, k, v, pos, lens, *, scale, sink):
+        # No step at all: what the chain itself costs a call (the barrier,
+        # the loop, the sum of the outputs), which both forms pay here and a
+        # decode program does not.
+        return q[..., :dv], rings
+    timing, checks, ok = [], [], True
+    for b, n_live in rows_list:
+        rings, xs = inputs(b, n_live)
+        # Once against the jnp form: live rows agree within the dtype's
+        # rounding, the rings bit for bit.
+        args = (rings, 1, *xs[:5])
+        want_o, want = jax.jit(functools.partial(
+            att.window_ring_step_jnp, scale=scale))(*args, sink=xs[5])
+        got_o, got = jax.jit(functools.partial(
+            kernel, scale=scale))(*args, sink=xs[5])
+        live = (xs[4] > 0)[:, None, None, None]
+        err = float(jnp.max(jnp.abs(jnp.where(
+            live, got_o.astype(jnp.float32) - want_o.astype(jnp.float32),
+            0.0))))
+        same = all(bool(jnp.all(a == c)) for a, c in zip(got, want))
+        checks.append({"rows": b, "live": n_live, "max_abs_err": err,
+                       "rings_equal": same})
+        ok &= same and err < (1e-4 if dtype == jnp.float32 else 5e-2)
+        for form, step in (("kernel", kernel),
+                           ("jnp", att.window_ring_step_jnp),
+                           ("harness", harness)):
+            sec, path = timed(step, rings, xs)
+            ok &= rehearse or (path == "pallas") == (form == "kernel")
+            timing.append(entry(form, sec, n_live, b, path))
+    # The kernel's constants: each value set where the kernel reads it, the
+    # program traced anew.
+    shipped = (window_ring.NUM_BUFS, window_ring.FETCH_AHEAD)
+    b, n_live = (3, 2) if rehearse else RING_SERIES_AT
+    rings, xs = inputs(b, n_live)
+    tuned = []
+    try:
+        for bufs, ahead in series:
+            window_ring.NUM_BUFS, window_ring.FETCH_AHEAD = bufs, ahead
+            jax.clear_caches()
+            sec, path = timed(kernel, rings, xs)
+            tuned.append(entry("kernel", sec, n_live, b, path, num_bufs=bufs,
+                               fetch_ahead=ahead))
+    finally:
+        window_ring.NUM_BUFS, window_ring.FETCH_AHEAD = shipped
+    emit({"phase": "ring",
+          "widths": {"heads": h, "kv_heads": hkv, "window": w, "dk": dk,
+                     "dv": dv, "ring_lanes": [ring_width(dk),
+                                              ring_width(dv)]},
+          "calls": calls, "step_layers": layers,
+          "shipped": dict(zip(("num_bufs", "fetch_ahead"), shipped)),
+          "checks": checks, "timing": timing, "series": tuned, "peak": peak,
+          "device": device, "ok": ok})
+    return 0 if ok else 1
 
 
 # --hc: the stream mix alone (ops/hyper_connections.py) at Xing4.0-29B-A4B's
@@ -2070,6 +2254,10 @@ def main(argv=None) -> int:
                     help="only time the Mamba-2 scan alone (ssd_step, "
                          "ssd_chunk) and the paged decode kernel at its "
                          "model's attention shape, and exit")
+    ap.add_argument("--ring", action="store_true",
+                    help="only time the window ring's decode step alone "
+                         "(the kernel beside the XLA statement, and the "
+                         "kernel's two constants) and exit")
     ap.add_argument("--moe", action="store_true",
                     help="only time the latent decode kernel and the "
                          "experts' grouped matmuls alone and exit")
@@ -2098,6 +2286,10 @@ def main(argv=None) -> int:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"
         return ssd_child(args.rehearse)
+    if args.ring:
+        if args.rehearse:
+            os.environ["JAX_PLATFORMS"] = "cpu"
+        return ring_child(args.rehearse)
     if args.moe:
         if args.rehearse:
             os.environ["JAX_PLATFORMS"] = "cpu"

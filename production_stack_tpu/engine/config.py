@@ -529,7 +529,7 @@ class EngineConfig:
         from production_stack_tpu.models import cache_specs
 
         return sum(
-            s.layers * int(np.prod(s.shape))
+            s.layers * int(np.prod(s.stored))
             * jnp.dtype(s.dtype or self.dtype).itemsize
             for s in cache_specs(model_config).state
         )

@@ -57,6 +57,7 @@ from production_stack_tpu.ops.attention import (
     gather_window,
     prefill_attn_path,
     prefill_kernel_covers,
+    ring_step_path,
     segment_of_token,
 )
 from production_stack_tpu.ops import gated_delta, ssd
@@ -794,7 +795,7 @@ class ModelRunner:
         rep = NamedSharding(self.mesh, PartitionSpec())
         self.state_pools = tuple(
             jax.device_put(
-                jnp.zeros((self.num_state_slots, s.layers, *s.shape),
+                jnp.zeros((self.num_state_slots, s.layers, *s.stored),
                           _dtype(s.dtype) if s.dtype else self.dtype), rep)
             for s in self.state_specs
         )
@@ -3476,7 +3477,8 @@ class ModelRunner:
         carried state, ops/gated_delta.py) — and the program's temporaries
         beside one payload pool's bytes; for a decode program of a model
         with recurrent state, ``gdn_step`` / ``ssd_step``: which execution
-        of that recurrence's step it holds (``"pallas"`` / ``"xla"``), and
+        of that recurrence's step it holds (``"pallas"`` / ``"xla"``), for
+        one of a model with window rings ``ring_step`` likewise, and
         for a prefill program of a Gated DeltaNet model ``gdn_chunk``
         likewise (the chunkwise form), and ``short_conv`` on every line of
         a model of gated short convolutions; for a
@@ -3526,6 +3528,7 @@ class ModelRunner:
             for name, path in (("gdn_step", gated_delta.step_path(text)),
                                ("gdn_chunk", gated_delta.chunk_path(text)),
                                ("ssd_step", ssd.step_path(text)),
+                               ("ring_step", ring_step_path(text)),
                                ("short_conv",
                                 gated_delta.short_conv_path(text))):
                 if path:

@@ -29,11 +29,21 @@ class PagedKVSpec(NamedTuple):
 class StateSpec(NamedTuple):
     """State a sequence holds whole, whatever its length: ``shape`` per
     sequence and layer, for ``layers`` layers. ``dtype`` None: the
-    activations'."""
+    activations'. ``lanes``: the lanes a row is STORED in where that is
+    more than ``shape``'s last axis (whole 128-lane tiles, zeros past the
+    row's own: what the row takes in HBM either way, declared because a
+    kernel can slice only an array of whole tiles where it lies); None: as
+    many as the row has."""
     name: str
     layers: int
     shape: Tuple[int, ...]
     dtype: Optional[str]
+    lanes: Optional[int] = None
+
+    @property
+    def stored(self) -> Tuple[int, ...]:
+        """The shape the runner allocates a sequence and layer."""
+        return (*self.shape[:-1], self.lanes or self.shape[-1])
 
 
 class LatentKVSpec(NamedTuple):

@@ -20,10 +20,11 @@ that the runner owns, ops/moe.py's router and experts, the counters
     the model's scale ``head_dim ** -0.5``. A window layer has
     ``swa_num_kv_heads``, rotates by ``swa_rope_theta`` and keeps, a
     sequence, the ``sliding_window`` newest keys and values as a RING in a
-    state slot (ops/attention.py:window_ring_attend / window_ring_write):
-    position p in slot p mod W, nothing paged, nothing held behind the
-    bound. A query at position i sees ``0 <= i - j < W``: the token and
-    the W - 1 before it.
+    state slot (ops/attention.py:window_ring_attend / window_ring_write
+    for a prefill chunk, window_ring_step for a decode step: on a TPU one
+    kernel in place in the carried rings): position p in slot p mod W,
+    nothing paged, nothing held behind the bound. A query at position i
+    sees ``0 <= i - j < W``: the token and the W - 1 before it.
   * Keys are ``head_dim`` wide and values ``v_head_dim``. Rotate-half rope
     over the first ``rotary_dim`` lanes of q and k, the others as they
     are. Values are scaled by ``attention_value_scale`` where they are
@@ -49,8 +50,10 @@ this module is held to.
 
 Device scopes: ``attn_proj`` (norm, projections, rope), ``attn_core`` with
 the inner ``ring_attend`` / ``attn_sink`` / ``ring_write`` of a window
-layer, ``ffn`` (the dense FFN; a sparse layer's norm and sum) and inside it
-``moe_route`` and ``moe_experts`` (inner ``moe_gmm``); ``embed``, ``logits``.
+layer (a decode step's under ``ring_step``, the kernel's time whole under
+``ring_attend``), ``ffn`` (the dense FFN; a sparse layer's norm and sum) and
+inside it ``moe_route`` and ``moe_experts`` (inner ``moe_gmm``); ``embed``,
+``logits``.
 """
 
 from typing import Dict, Optional, Tuple
@@ -76,6 +79,7 @@ from production_stack_tpu.ops.attention import (
     KVView,
     attend,
     window_ring_attend,
+    window_ring_step,
     window_ring_write,
 )
 
@@ -169,6 +173,14 @@ def paged_width(cfg: ModelConfig) -> int:
     return -(-max(cfg.head_dim_, cfg.v_head_dim) // 128) * 128
 
 
+def ring_width(lanes: int) -> int:
+    """Lanes of a ring's row: a head's in whole 128-lane tiles. What the
+    row takes in HBM whatever is declared (192 lanes lie in 256), declared,
+    because only an array of whole tiles can be sliced where it lies
+    (ops/pallas/window_ring.py); zeros past the head's own."""
+    return -(-lanes // 128) * 128
+
+
 def held_experts(cfg: ModelConfig) -> Tuple[int, int]:
     """(the first expert this chip holds, how many), of every sparse
     layer's ``n_routed_experts * ep_size``."""
@@ -237,15 +249,17 @@ def finish_params(cfg: ModelConfig, params: Params) -> Params:
 def cache_specs(cfg: ModelConfig) -> CacheSpecs:
     """Paged K and V for the FULL layers only, a row of ``paged_width``
     lanes each; per sequence and window layer the ring's keys and its
-    values, head-major ``[Hkv, W, D]`` in the activations' dtype (W rows of
-    D lanes a head: the builder pads 192 lanes to 256 in HBM, and 8 heads
-    on the rows' axis would pad to 16)."""
+    values, head-major ``[Hkv, W, D]`` in the activations' dtype, STORED in
+    rows of ``ring_width(D)`` lanes (W rows of whole lane tiles a head: 192
+    lanes take 256 in HBM either way, and 8 heads on the rows' axis would
+    pad to 16)."""
     n_window, n_full = _counts(cfg)
     hkv, w = cfg.swa_num_kv_heads, cfg.sliding_window
     return CacheSpecs(
         PagedKVSpec(n_full, cfg.num_kv_heads, paged_width(cfg)),
-        (StateSpec("ring_k", n_window, (hkv, w, cfg.head_dim_), None),
-         StateSpec("ring_v", n_window, (hkv, w, cfg.v_head_dim), None)),
+        tuple(StateSpec(name, n_window, (hkv, w, d), None, ring_width(d))
+              for name, d in (("ring_k", cfg.head_dim_),
+                              ("ring_v", cfg.v_head_dim))),
     )
 
 
@@ -465,10 +479,10 @@ def forward(
     state, stats int32[5] as ``FORWARD_STATS``) and, with ``routing``, the
     chosen experts [n_sparse, B*T, k] (of the router's whole width).
 
-    ``state``: (the rows' ring keys [B, n_window, Hkv, W, Dk], their ring
-    values [B, n_window, Hkv, W, Dv]) before the first token, one array per
-    spec of ``cache_specs``, rows first as the runner's pools are; ``None``
-    starts every row from empty rings (a whole sequence in one call: then
+    ``state``: (the rows' ring keys [B, n_window, Hkv, W, ring_width(Dk)],
+    their ring values [.., ring_width(Dv)]) before the first token, one array
+    per spec of ``cache_specs`` (as ``StateSpec.stored``), rows first as the
+    runner's pools are; ``None`` starts every row from empty rings (a whole sequence in one call: then
     ``positions`` start at 0). The returned state is that after each row's
     last valid token. The view's layer axis counts the full layers only. A
     row's ``positions`` are consecutive from its first."""
@@ -480,7 +494,7 @@ def forward(
         hidden = hidden.astype(view.act_dtype(params["embed"].dtype))
     if state is None:
         state = tuple(
-            jnp.zeros((b, s.layers, *s.shape), s.dtype or hidden.dtype)
+            jnp.zeros((b, s.layers, *s.stored), s.dtype or hidden.dtype)
             for s in cache_specs(cfg).state)
     rings = tuple(state)
     # A layer's rope: its kind's table (two tables a forward, chosen by a
@@ -533,11 +547,68 @@ def forward(
         kv = jnp.zeros((hkv_f, b, t, width), hidden.dtype)
         return hidden + branch, kv, kv, k_c, v_c
 
+    def window_qkv(hidden, w_at, f_at):
+        q, k_c, v_c = _project(
+            cfg, ropes["window"], hidden,
+            {k: of_layer(layers["window"][k], w_at)
+             for k in ("attn_norm", "wq", "wk", "wv")}, hkv_w)
+        kv = jnp.zeros((hkv_f, b, t, width), hidden.dtype)
+        return hidden, kv, kv, q, k_c, v_c
+
+    def full_step(hidden, w_at, f_at):
+        hidden, k_l, v_l, k_c, v_c = full(hidden, None, w_at, f_at)
+        return (hidden, k_l, v_l,
+                jnp.zeros((b, t, cfg.num_heads, dk), hidden.dtype), k_c, v_c)
+
+    def ring_step(rings, w_at, q, k_c, v_c, live):
+        with jax.named_scope("attn_core"):
+            return window_ring_step(
+                rings, w_at, q, k_c, v_c, positions, live,
+                scale=dk ** -0.5,
+                sink=of_layer(layers["window"]["sink"], w_at)
+                if cfg.swa_attention_sink else None,
+                interpret=view.interpret)
+
+    def back(hidden, attn, w_at):
+        return hidden + _out(
+            cfg, attn, {"wo": of_layer(layers["window"]["wo"], w_at)})
+
+    def window_only(hidden, rings, w_at, f_at):
+        hidden, k_l, v_l, q, k_c, v_c = window_qkv(hidden, w_at, f_at)
+        attn, rings = ring_step(rings, w_at, q, k_c, v_c, chunk_lens)
+        return back(hidden, attn, w_at), rings, k_l, v_l
+
+    def full_only(hidden, rings, w_at, f_at):
+        hidden, k_l, v_l = full(hidden, rings, w_at, f_at)[:3]
+        return hidden, rings, k_l, v_l
+
+    def step_attention(hidden, rings, is_window, w_at, f_at):
+        """A decode step's attention (T == 1) of either kind. A window
+        layer's ring is read AND written by one statement
+        (``window_ring_step``), which stands OUTSIDE the ``cond``s, as the
+        write of a chunk does, so that no branch returns the carry: the
+        first ``cond`` projects (a full layer attends there too and hands
+        the step no live row, for which it moves no byte), the second
+        projects a window layer's attention back."""
+        if isinstance(is_window, bool):
+            return (window_only if is_window else full_only)(
+                hidden, rings, w_at, f_at)
+        hidden, k_l, v_l, q, k_c, v_c = jax.lax.cond(
+            is_window > 0, window_qkv, full_step, hidden, w_at, f_at)
+        attn, rings = ring_step(
+            rings, w_at, q, k_c, v_c,
+            chunk_lens * is_window.astype(chunk_lens.dtype))
+        hidden = jax.lax.cond(
+            is_window > 0, back, lambda x, *_: x, hidden, attn, w_at)
+        return hidden, rings, k_l, v_l
+
     def attention(hidden, rings, is_window, w_at, f_at):
         """One layer's attention of either kind: the rings pass into the
         ``cond`` to be READ (a window layer's branch takes its layer out of
         them) and come out through the write below, which both kinds share
         and which a full layer hands no valid token."""
+        if t == 1:
+            return step_attention(hidden, rings, is_window, w_at, f_at)
         if isinstance(is_window, bool):
             hidden, k_l, v_l, k_c, v_c = (window if is_window else full)(
                 hidden, rings, w_at, f_at)

@@ -336,11 +336,14 @@ def window_ring_attend(
     every query's span already. A chunk of whole windows is scored a window
     of queries at a time against the window of keys before it (the ring for
     the first) and its own: [.., W, 2 W] scores a block, never [T, T].
-    ``sink``: see ``sink_merged``. Returns [B, T, H, Dv] in q.dtype."""
+    ``sink``: see ``sink_merged``. A ring whose rows are wider than the
+    chunk's (whole 128-lane tiles: models/mimo_v2.py:ring_width) is read up
+    to the chunk's width. Returns [B, T, H, Dv] in q.dtype."""
     b, t, h, dk = q.shape
     hkv, w = ring_k.shape[1], ring_k.shape[2]
     g = h // hkv
     with jax.named_scope("ring_attend"):
+        ring_k, ring_v = ring_k[..., :dk], ring_v[..., :v.shape[-1]]
         start = positions[:, 0]
         t_idx = jnp.arange(t, dtype=jnp.int32)
         # A key past its row's length lies ahead of every query.
@@ -404,7 +407,8 @@ def window_ring_write(
     layer ``at``, every other layer and the rings of rows without a valid
     token as they were. A decode step (T == 1) writes its one row in place
     (a scatter a row: nothing else of the carry moves); a chunk gathers,
-    for every slot, the chunk's newest token that lands there."""
+    for every slot, the chunk's newest token that lands there. Where a
+    ring's rows are wider than the chunk's, zeros fill them."""
     w = rings[0].shape[3]
     b, t = positions.shape
     with jax.named_scope("ring_write"):
@@ -430,12 +434,93 @@ def window_ring_write(
                     axis=1).transpose(0, 2, 1, 3)
         out = []
         for ring, x in zip(rings, new):
+            x = jnp.pad(x, ((0, 0),) * 3
+                        + ((0, ring.shape[-1] - x.shape[-1]),))
             old = jax.lax.dynamic_index_in_dim(ring, at, 1, False)
             layer = jnp.where(lands[:, None, :, None],
                               taken(x).astype(ring.dtype), old)
             out.append(jax.lax.dynamic_update_index_in_dim(
                 ring, layer, at, 1))
         return tuple(out)
+
+
+def window_ring_step_jnp(rings, at, q, k, v, positions, chunk_lens, *,
+                         scale, sink=None):
+    """``window_ring_step`` as plain ``jnp``: the statement of a decode
+    step (``window_ring_attend`` over the layer's rings sliced out of the
+    carry, then ``window_ring_write``), the path of a backend without the
+    kernel, and the tests' oracle."""
+    ring = tuple(jax.lax.dynamic_index_in_dim(r, at, 1, False)
+                 for r in rings)
+    attn = window_ring_attend(q, k, v, positions, chunk_lens, *ring,
+                              scale=scale, sink=sink)
+    return attn, window_ring_write(rings, at, (k, v), positions, chunk_lens)
+
+
+def window_ring_step(
+    rings: Tuple[jax.Array, jax.Array],  # [B, Lr, Hkv, W, Dk], [.., Dv]
+    at: jax.Array,            # [] int32: the layer's index among Lr
+    q: jax.Array,             # [B, 1, H, Dk] queries (post-rope)
+    k: jax.Array,             # [B, 1, Hkv, Dk] the step's keys
+    v: jax.Array,             # [B, 1, Hkv, Dv]
+    positions: jax.Array,     # [B, 1]
+    chunk_lens: jax.Array,    # [B] 1: the row takes the token; 0: it is inert
+    *,
+    scale: float,
+    sink: Optional[jax.Array] = None,    # [H] float32
+    interpret: bool = False,
+):
+    """One decode step (T == 1) of a window layer on layer ``at`` of the
+    rows' carried rings: (the attention [B, 1, H, Dv] of the rows that take
+    a token, the rings with those rows' key and value in slot ``position mod
+    W``). A row with ``chunk_lens`` 0 keeps its rings (its attention is
+    nothing anybody reads), and with none live no ring moves.
+
+    One algorithm, two executions, chosen HERE by what can be seen (the
+    rule of ops/gated_delta.py:gdn_step_at): where the rings' shape fits
+    it, a program LOWERED for a TPU (``lax.platform_dependent``) holds the
+    Pallas kernel (ops/pallas/window_ring.py: in place in the carried rings,
+    a live row's slots read once and one row written, a row that is not
+    live untouched), and so does any program with ``interpret`` set (the
+    runner's Pallas interpret switch: a CPU's tests); every other holds the
+    ``jnp`` form. The two round alike (scores and statistics in float32,
+    ``p`` in the values' dtype); only the order of the float32 sums over
+    the slots differs, and the rings come out bit for bit the same."""
+    from production_stack_tpu.ops.pallas.window_ring import (
+        ring_step_in_place,
+        supports_step_kernel,
+    )
+
+    def as_jnp(*args):
+        return window_ring_step_jnp(*args, scale=scale, sink=sink)
+
+    def as_kernel(rings, at, q, k, v, positions, chunk_lens, interpret=False):
+        with jax.named_scope("ring_attend"):
+            none = jnp.full((q.shape[2],), -jnp.inf, jnp.float32)
+            o, *rings = ring_step_in_place(
+                *rings, at, q[:, 0], k[:, 0], v[:, 0], positions[:, 0],
+                chunk_lens, none if sink is None else sink,
+                scale=scale, interpret=interpret)
+        return o[:, None], tuple(rings)
+
+    args = (tuple(rings), jnp.asarray(at, jnp.int32), q, k, v, positions,
+            chunk_lens)
+    with jax.named_scope("ring_step"):
+        if not supports_step_kernel(*rings, q.shape[2]):
+            return as_jnp(*args)
+        if interpret:
+            return as_kernel(*args, interpret=True)
+        return jax.lax.platform_dependent(
+            *args, tpu=as_kernel, default=as_jnp)
+
+
+def ring_step_path(hlo_text: str):
+    """Which execution of ``window_ring_step`` a compiled program
+    (``as_text()``) holds: ``"pallas"``, ``"xla"``, or None where it holds
+    no decode step of a window ring."""
+    if "ring_step_in_place" in hlo_text:
+        return "pallas"
+    return "xla" if "/ring_step/" in hlo_text else None
 
 
 class KVView(NamedTuple):

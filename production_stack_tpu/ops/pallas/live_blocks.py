@@ -1,17 +1,22 @@
 """How the live rows' state blocks pass through VMEM in place: the data
-movement of the decode-step kernels over a carried recurrent state
+movement of the decode-step kernels over a carried per-sequence state
 (ops/pallas/gated_delta.py:gdn_step_in_place, ops/pallas/ssd.py:
-ssd_step_in_place), written once.
+ssd_step_in_place, ops/pallas/window_ring.py:ring_step_in_place), written
+once.
 
-The decode loop carries its rows' state as one array ``[rows, layers,
-heads, ...]`` float32, and a layer's step has to read each live row's
-``(row, layer)`` slab once and write it once:
+The decode loop carries its rows' state as arrays ``[rows, layers, heads,
+...]`` (one, or several that are stepped together: a window ring's keys and
+its values), and a layer's step has to read each live row's ``(row, layer)``
+slab once and write back what it changed:
 
-  * The carry stays in HBM and is ALIASED to the kernel's output: nothing
+  * A carry stays in HBM and is ALIASED to the kernel's output: nothing
     of its size is allocated, copied, sliced out or put back. Blocks of
     ``HB`` heads ``[HB, ...]`` of a live row's slab (contiguous) are copied
-    into one of ``num_bufs`` VMEM buffers, updated there by the kernel's own
-    ``compute`` and copied back to where they came from.
+    into one of ``num_bufs`` VMEM buffers of the carry's dtype, updated
+    there by the kernel's own ``compute`` and copied back to where they
+    came from: the whole block, or the part of it ``written`` names (a
+    ring's step changes one row of a head's 128: the tile that holds it
+    goes back and nothing else).
   * The call's live blocks form ONE sequence, row after row: while block n
     is computed, the ``fetch_ahead`` blocks behind it (the same row's next
     ones or the next LIVE row's first) are in flight into the next buffers
@@ -43,13 +48,17 @@ def _rows_per_program(b: int, row_bytes: int, operand_bytes: int) -> int:
                                   <= operand_bytes))
 
 
+def _each(x):
+    return x if isinstance(x, (tuple, list)) else (x,)
+
+
 def live_blocks(
     at_ref,        # SMEM [1] int32: which layer of the carry
     live_ref,      # SMEM [B] int32: rows that take a token
-    s_in,          # HBM  [B, NL, H, ...] f32: the carry
+    s_in,          # HBM  [B, NL, H, ...]: the carry (or a tuple of carries)
     s_out,         # HBM: the carry again (aliased to s_in)
-    # scratch (outlives a program)
-    buf,           # VMEM [num_bufs, HB, ...] f32
+    # scratch (outlives a program), one of each a carry
+    buf,           # VMEM [num_bufs, HB, ...] of the carry's dtype
     sem_in,        # DMA (num_bufs,)
     sem_out,       # DMA (num_bufs,)
     rows_ref,      # SMEM [B] int32: the live rows, in order
@@ -57,16 +66,22 @@ def live_blocks(
     *,
     rows: int,         # rows a program holds the operands of
     fetch_ahead: int,  # blocks in flight towards the one computed
+    written=None,      # row -> the part of a block that goes back
 ):
     """Inside a step kernel: lists the live rows (program 0) and returns
     ``run(compute)``, which takes this program's live blocks through the
-    buffers in turn. ``compute(n, row, j, slot, r)`` updates ``buf[slot]``,
-    block ``j`` of ``row``'s slab and the call's n-th, where it lies; ``r``
-    is the row's index among the program's own."""
+    buffers in turn. ``compute(n, row, j, slot, r)`` updates ``buf[slot]``
+    (of every carry), block ``j`` of ``row``'s slab and the call's n-th,
+    where it lies; ``r`` is the row's index among the program's own.
+    ``written(row)``: indices into a block behind its heads' axis, the
+    same for every carry (``(pl.ds(first, size),)``: those rows of every
+    head); None: the whole block goes back."""
+    s_ins, s_outs, bufs = _each(s_in), _each(s_out), _each(buf)
+    sems_in, sems_out = _each(sem_in), _each(sem_out)
     pid = pl.program_id(0)
     num_rows = live_ref.shape[0]
-    num_bufs, hb = buf.shape[:2]
-    nb = s_in.shape[2] // hb             # blocks a row
+    num_bufs, hb = bufs[0].shape[:2]
+    nb = s_ins[0].shape[2] // hb         # blocks a row
     at = at_ref[0]
 
     @pl.when(pid == 0)
@@ -93,19 +108,34 @@ def live_blocks(
         li = n // nb
         return rows_ref[jnp.minimum(li, num_rows - 1)], n - li * nb
 
+    class _Copies(tuple):
+        # A block's copies, one a carry, started and awaited together.
+        def start(self):
+            for c in self:
+                c.start()
+
+        def wait(self):
+            for c in self:
+                c.wait()
+
     def fetch(n):
         row, j = block(n)
         slot = jax.lax.rem(n, num_bufs)
-        return pltpu.make_async_copy(
-            s_in.at[row, at, pl.ds(j * hb, hb)], buf.at[slot],
-            sem_in.at[slot])
+        return _Copies(
+            pltpu.make_async_copy(
+                s.at[row, at, pl.ds(j * hb, hb)], b.at[slot], sem.at[slot])
+            for s, b, sem in zip(s_ins, bufs, sems_in))
 
     def store(n):
         row, j = block(n)
         slot = jax.lax.rem(n, num_bufs)
-        return pltpu.make_async_copy(
-            buf.at[slot], s_out.at[row, at, pl.ds(j * hb, hb)],
-            sem_out.at[slot])
+        part = () if written is None else written(row)
+        at_buf = (slot, slice(None), *part) if part else (slot,)
+        return _Copies(
+            pltpu.make_async_copy(
+                b.at[at_buf], s.at[(row, at, pl.ds(j * hb, hb), *part)],
+                sem.at[slot])
+            for s, b, sem in zip(s_outs, bufs, sems_out))
 
     def run(compute):
         def step(n, carry):
@@ -149,16 +179,20 @@ def live_blocks(
 
 
 def step_call(kernel, scalars, operands, carry, *, out_row, heads_per_block,
-              num_bufs, row_bytes, operand_bytes, name, interpret):
-    """The ``pallas_call`` of a step kernel over ``carry`` [B, NL, H, ...]:
-    ``kernel(*scalar refs, *operand refs, s_in, o_ref, s_out, buf, sem_in,
-    sem_out, rows_ref, count_ref)``, of which ``live_blocks`` takes the
-    first two scalars (the layer [1] and the rows' liveness [B], int32) and
-    everything from ``s_in`` on but ``o_ref``. ``operands`` [B, ...] and the
-    output [B, *out_row] f32 reach a program as blocks of whole rows, as
-    many as ``row_bytes`` a row lets fit ``operand_bytes``; the carry is
-    left in HBM and aliased to the second output: (o, carry)."""
-    b = carry.shape[0]
+              num_bufs, row_bytes, operand_bytes, name, interpret,
+              out_dtype=jnp.float32, scratch=()):
+    """The ``pallas_call`` of a step kernel over ``carry`` [B, NL, H, ...]
+    (or a tuple of such, stepped together): ``kernel(*scalar refs, *operand
+    refs, *s_in, o_ref, *s_out, *buf, *sem_in, *sem_out, rows_ref,
+    count_ref, *scratch refs)``, of which ``live_blocks`` takes the first
+    two scalars (the layer [1] and the rows' liveness [B], int32) and
+    everything from ``s_in`` to ``count_ref`` but ``o_ref`` (``scratch``:
+    further scratch shapes of the kernel's own). ``operands`` [B, ...] and
+    the output [B, *out_row] of ``out_dtype`` reach a program as blocks of
+    whole rows, as many as ``row_bytes`` a row lets fit ``operand_bytes``; a
+    carry is left in HBM and aliased to its output: (o, *carries)."""
+    carries = _each(carry)
+    b = carries[0].shape[0]
     rb = _rows_per_program(b, row_bytes, operand_bytes)
 
     def rows(*shape):
@@ -168,30 +202,36 @@ def step_call(kernel, scalars, operands, carry, *, out_row, heads_per_block,
 
     return pl.pallas_call(
         kernel,
-        out_shape=[jax.ShapeDtypeStruct((b, *out_row), jnp.float32),
-                   jax.ShapeDtypeStruct(carry.shape, carry.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((b, *out_row), out_dtype),
+                   *(jax.ShapeDtypeStruct(c.shape, c.dtype)
+                     for c in carries)],
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=(b // rb,),
             in_specs=[
                 *(rows(*x.shape[1:]) for x in operands),
-                pl.BlockSpec(memory_space=pl.ANY),   # the carry stays in HBM
+                # a carry stays in HBM
+                *(pl.BlockSpec(memory_space=pl.ANY) for _ in carries),
             ],
-            out_specs=[rows(*out_row), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[rows(*out_row),
+                       *(pl.BlockSpec(memory_space=pl.ANY) for _ in carries)],
             scratch_shapes=[
-                pltpu.VMEM((num_bufs, heads_per_block, *carry.shape[3:]),
-                           jnp.float32),
-                pltpu.SemaphoreType.DMA((num_bufs,)),
-                pltpu.SemaphoreType.DMA((num_bufs,)),
+                *(pltpu.VMEM((num_bufs, heads_per_block, *c.shape[3:]),
+                             c.dtype) for c in carries),
+                *(pltpu.SemaphoreType.DMA((num_bufs,))
+                  for _ in range(2 * len(carries))),
                 pltpu.SMEM((b,), jnp.int32),
                 pltpu.SMEM((1,), jnp.int32),
+                *scratch,
             ],
         ),
-        # scalars, operands, carry -> (o, carry): in place.
-        input_output_aliases={len(scalars) + len(operands): 1},
+        # scalars, operands, carries -> (o, *carries): in place.
+        input_output_aliases={
+            len(scalars) + len(operands) + i: 1 + i
+            for i in range(len(carries))},
         # Programs run in order: each hands its buffers to the next.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
-    )(*scalars, *operands, carry)
+    )(*scalars, *operands, *carries)

@@ -331,7 +331,7 @@ def _described_runner(v5e, model_dir: str, **engine):
     r.kv_k = sds((*pool, specs.paged_kv.head_dim), jnp.bfloat16)
     r.kv_v = sds((*pool, r.kv_v_dim), jnp.bfloat16)
     r.state_pools = tuple(
-        sds((r.num_state_slots, s.layers, *s.shape),
+        sds((r.num_state_slots, s.layers, *s.stored),
             jnp.dtype(s.dtype or "bfloat16")) for s in specs.state)
     r._b_max = _bucket(cfg.max_num_seqs, 1, cfg.max_num_seqs)
     r._zero_last = sds((r._b_max,), jnp.int32)
@@ -1122,8 +1122,8 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
 
 def _kernel_entry_points():
     """name -> (entry point, ShapeDtypeStructs at one cell's shape, the sha1
-    of its jaxpr's text): the nine Pallas kernels of the serving path."""
-    from production_stack_tpu.ops.pallas import gated_delta, ssd
+    of its jaxpr's text): the ten Pallas kernels of the serving path."""
+    from production_stack_tpu.ops.pallas import gated_delta, ssd, window_ring
     from production_stack_tpu.ops.pallas import paged_attention as pa
 
     def sds(*shape, dtype=jnp.float32):
@@ -1199,6 +1199,18 @@ def _kernel_entry_points():
              sds(32, 128), sds(32, 128), sds(32, 64), sds(32, 64), sds(64),
              sds(32, dtype=jnp.bool_)),
             "de3586aacc14ccaf97b2d31521467be0726a83ef"),
+        # The third user of ops/pallas/live_blocks.py (PR 53), which left
+        # the two above the programs they were: 9 window layers' rings of
+        # 8 x 128 slots, keys of 192 lanes in rows of 256, values of 128.
+        "ring_step_in_place-mimo-32": (
+            functools.partial(window_ring.ring_step_in_place,
+                              scale=192 ** -0.5),
+            (sds(32, 9, 8, 128, 256, dtype=bf16),
+             sds(32, 9, 8, 128, 128, dtype=bf16), sds(dtype=i32),
+             sds(32, 64, 192, dtype=bf16), sds(32, 8, 192, dtype=bf16),
+             sds(32, 8, 128, dtype=bf16), sds(32, dtype=i32),
+             sds(32, dtype=jnp.bool_), sds(64)),
+            "5ec3311ea8adefa49dcebfc44664b1f16a74767f"),
     }
 
 
@@ -1544,18 +1556,25 @@ def test_window_ring_dispatch_programs_compile_in_place_for_v5e(v5e,
     window layers' rings in the state slots, three full layers paged at 256
     lanes) compile for a v5e, fit its HBM beside 11.83 GB of weights, the
     2.01 GB K/V pool and the rings, and copy neither a pool nor an expert
-    stack. They hold the Mosaic kernels of the FULL layers alone (the paged
+    stack. They hold the Mosaic kernels of the FULL layers (the paged
     decode kernel, or the flash prefill kernel, once in the dense layer and
-    once in the scan) and the two grouped matmuls; the window layers are
-    XLA under their scopes."""
+    once in the scan) and the two grouped matmuls; a DECODE program holds a
+    fifth, the window layers' step in place in the carried rings (PR 53:
+    ``ring_step`` reads ``"pallas"``, its time under ``ring_attend``, and no
+    carried ring is copied at the ``cond``s it stands between); a prefill
+    program's window layers are XLA under their three scopes."""
     from production_stack_tpu.engine.runner import _bucket
-    from production_stack_tpu.ops.attention import prefill_attn_path
+    from production_stack_tpu.ops.attention import (
+        prefill_attn_path,
+        ring_step_path,
+    )
     from production_stack_tpu.ops.kv_write import pool_copies
 
     r = _deployment_runner(v5e, "mimo-v2.5-ep16")
     assert r.kv_k.shape == r.kv_v.shape == (3, 4, 10240 * 16, 256)
+    # A key's 192 lanes STORED in a row of 256: what it took in HBM before.
     assert [p.shape for p in r.state_pools] == [
-        (33, 9, 8, 128, 192), (33, 9, 8, 128, 128)]
+        (33, 9, 8, 128, 256), (33, 9, 8, 128, 128)]
     assert r.prefill_reads_pool and not r.prefill_packs
     assert r.fwd_stats[-1] == "assignments_elsewhere"
     assert r.ring_report() == {
@@ -1582,15 +1601,24 @@ def test_window_ring_dispatch_programs_compile_in_place_for_v5e(v5e,
     experts = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for k in (
         "w_gate_up", "we_down") for shape in (
             sparse[k].shape, (11 * 16, *sparse[k].shape[2:]))]
-    assert pool_copies(text, [r.kv_k, *r.state_pools, *experts]) == []
-    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    carried = [jax.ShapeDtypeStruct((rows, *p.shape[1:]), p.dtype)
+               for p in r.state_pools] if decode else []
+    assert pool_copies(
+        text, [r.kv_k, *r.state_pools, *carried, *experts]) == []
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (5 if decode else 4)
     assert ("%paged_flash_decode" in text) == decode
-    if not decode:
+    assert ring_step_path(text) == ("pallas" if decode else None)
+    if decode:
+        # The kernel's time is booked where the statement's was.
+        assert any("/ring_attend/" in ln and "ring_step_in_place" in ln
+                   for ln in text.splitlines() if "tpu_custom_call" in ln)
+    else:
         assert prefill_attn_path(text) == "pallas"
     for scope in ("embed", "attn_proj", "attn_core", "ring_attend",
-                  "attn_sink", "ring_write", "ffn", "moe_route",
-                  "moe_experts", "moe_gmm", "logits", "kv_write",
-                  "state_read", "state_write", "sample"):
+                  *(() if decode else ("attn_sink", "ring_write")), "ffn",
+                  "moe_route", "moe_experts", "moe_gmm", "logits",
+                  "kv_write", "state_read", "state_write", "sample"):
         assert f"/{scope}/" in text, scope
     instructions = sum(1 for ln in text.splitlines() if " = " in ln)
     assert instructions < MIMO_INSTRUCTIONS, instructions

@@ -266,15 +266,20 @@ async def test_the_served_surface_names_the_ring_and_the_counters():
         sample["pstpu:moe_assignments_total"] > 0
     assert sample["pstpu:moe_layer_calls_total"] > 0
     assert {p["program"] for p in programs} == {"decode", "prefill"}
+    # The tiny preset's 2 queries a KV head, on a CPU: the ``jnp`` step.
+    assert {p["program"]: p.get("ring_step") for p in programs} == {
+        "decode": "xla", "prefill": None}
     for said in (*programs, version["engine"]):
         assert said["window_layers"] == [1, 2, 4]
         assert said["ring"] == {"ring_k": [2, W, 48], "ring_v": [2, W, 32]}
         assert said["experts_held"] == [4, 8]
         assert said["experts_routed"] == 16
     slots = eng.runner.num_state_slots
+    # Stored in rows of whole 128-lane tiles (keys of 48 lanes, values of
+    # 32): what the arrays hold.
     assert memory["state_pools"] == {
-        "ring_k": slots * 3 * 2 * W * 48 * 4,
-        "ring_v": slots * 3 * 2 * W * 32 * 4}
+        "ring_k": slots * 3 * 2 * W * 128 * 4,
+        "ring_v": slots * 3 * 2 * W * 128 * 4}
     assert sum(memory["state_pools"].values()) == \
         memory["residents"]["state"]
     # A model without a ring or a share says and counts none of it.
@@ -345,6 +350,163 @@ def test_window_ring_attend_and_write_are_the_masked_softmax(start, t, live):
             np.testing.assert_array_equal(new_v[0, 0, :, p % w], v_all[p])
     if start + live < w:        # slots nothing reached keep what they held
         assert float(new_k[0, 0, 0, w - 1, 0]) == 1e3
+
+
+# ---- a decode step in place in the carried rings (ops/pallas/window_ring.py)
+RING_STEP_CASES = {
+    # positions of the bucket's rows, which of them take a token
+    # (six rows each: one program of the interpreted kernel a dtype)
+    "below-the-window": ([0, 1, 5, 15, 16, 126], [1] * 6),
+    "at-the-window": ([127, 128, 129, 143, 144, 255], [1] * 6),
+    "several-wraps": ([256, 1000, 4095, 8191, 8192, 70001], [1] * 6),
+    # A state slot's second sequence: its slots hold the first one's keys,
+    # which no query of the new sequence may see.
+    "a-slot-reused": ([0, 3, 40, 100, 17, 31], [1] * 6),
+    "dead-rows-between": ([7, 300, 131, 64, 2000, 90], [1, 0, 1, 0, 0, 1]),
+    "none-live": ([7, 300, 131, 64, 2, 1], [0] * 6),
+    "one-live-last": ([7, 300, 131, 640, 3, 911], [0, 0, 0, 0, 0, 1]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("sink", ["sink", "no-sink"])
+@pytest.mark.parametrize("case", list(RING_STEP_CASES))
+def test_ring_step_kernel_is_the_jnp_statement_in_place(case, sink, dtype):
+    """``ring_step_in_place`` (interpreted) against ``window_ring_step_jnp``
+    at the published head shapes (8 queries a KV head, keys of 192 lanes in
+    rows of 256, values of 128; two KV heads and three layers here): the
+    attention of every live row within the dtype's rounding, the rings
+    EQUAL bit for bit in every slot, every dead row and every other layer.
+    Every slot holds finite junk before the step (another sequence's keys),
+    so a slot the visibility should hide and does not shows."""
+    positions, live = RING_STEP_CASES[case]
+    b, nl, hkv, g, w, dk, dv, at = len(positions), 3, 2, 8, 128, 192, 128, 1
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(len(case) * 7 + sum(positions))
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, dt)
+
+    lanes = mimo_v2.ring_width(dk)
+    ring_k = jnp.pad(draw(b, nl, hkv, w, dk, scale=3.0),
+                     ((0, 0),) * 4 + ((0, lanes - dk),))
+    ring_v = draw(b, nl, hkv, w, dv, scale=3.0)
+    q, k, v = draw(b, 1, hkv * g, dk), draw(b, 1, hkv, dk), \
+        draw(b, 1, hkv, dv)
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    lens = jnp.asarray(live, jnp.int32)
+    sinks = jnp.asarray(rng.standard_normal(hkv * g) * 2 + 2, jnp.float32) \
+        if sink == "sink" else None
+    args = ((ring_k, ring_v), jnp.int32(at), q, k, v, pos, lens)
+    want_o, want_rings = att.window_ring_step_jnp(
+        *args, scale=dk ** -0.5, sink=sinks)
+    got_o, got_rings = att.window_ring_step(
+        *args, scale=dk ** -0.5, sink=sinks, interpret=True)
+    assert got_o.shape == want_o.shape == (b, 1, hkv * g, dv)
+    assert got_o.dtype == dt
+    alive = np.asarray(live, bool)
+    if alive.any():
+        np.testing.assert_allclose(
+            np.asarray(got_o, np.float32)[alive],
+            np.asarray(want_o, np.float32)[alive],
+            atol=3e-2 if dtype == "bfloat16" else 2e-5)
+    for got, want, old in zip(got_rings, want_rings, (ring_k, ring_v)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        # and what the statement says of them: one row a live row's head.
+        changed = np.asarray(got != old).any(axis=(-1, -3))   # [B, NL, W]
+        want_changed = np.zeros((b, nl, w), bool)
+        for r in np.flatnonzero(alive):
+            want_changed[r, at, positions[r] % w] = True
+        np.testing.assert_array_equal(changed, want_changed)
+
+
+def test_ring_step_is_the_kernel_only_where_the_rings_fit_it():
+    """The choice of ``window_ring_step`` is by what it can see: rings of
+    whole lane tiles with 8 queries a KV head and ``interpret`` hold the
+    kernel, the tiny preset's 2 queries a KV head the ``jnp`` form, and a
+    program lowered for a CPU without the switch the ``jnp`` form too."""
+    from production_stack_tpu.ops.pallas.window_ring import (
+        supports_step_kernel,
+        tile_rows,
+    )
+
+    def rings(hkv, w, dk, dv, dtype=jnp.bfloat16):
+        return (jax.ShapeDtypeStruct((4, 2, hkv, w, dk), dtype),
+                jax.ShapeDtypeStruct((4, 2, hkv, w, dv), dtype))
+
+    assert tile_rows(jnp.bfloat16) == 16 and tile_rows(jnp.float32) == 8
+    assert supports_step_kernel(*rings(8, 128, 256, 128), 64)
+    assert not supports_step_kernel(*rings(8, 128, 192, 128), 64)
+    assert not supports_step_kernel(*rings(2, 128, 128, 128), 4)
+    assert not supports_step_kernel(*rings(8, 24, 256, 128), 64)
+
+    def step(interpret):
+        def fn(rk, rv, q, k, v, pos, lens):
+            return att.window_ring_step(
+                (rk, rv), 0, q, k, v, pos, lens, scale=1.0,
+                interpret=interpret)
+        b, (rk, rv) = 4, rings(2, 128, 256, 128)
+        sds = jax.ShapeDtypeStruct
+        return jax.jit(fn).lower(
+            rk, rv, sds((b, 1, 16, 192), rk.dtype),
+            sds((b, 1, 2, 192), rk.dtype), sds((b, 1, 2, 128), rk.dtype),
+            sds((b, 1), jnp.int32), sds((b,), jnp.int32)).as_text(
+                debug_info=True)
+
+    assert att.ring_step_path(step(True)) == "pallas"
+    assert att.ring_step_path(step(False)) == "xla"
+    assert att.ring_step_path("HloModule jit__prefill_impl") is None
+
+
+def test_forward_steps_through_the_kernel_as_through_the_jnp_statement():
+    """A decode step of ``forward`` (T == 1) with the view's ``interpret``
+    switch holds the ring's kernel where the rings fit it (here 8 queries a
+    KV head, keys of 48 lanes in rows of 128) and comes out as the ``jnp``
+    statement's: the hidden state of the live rows, every full layer's new
+    K and V, and the rings bit for bit, a dead row's among them. The leading
+    layer is a window layer here (traced outside the scan, no ``cond``) and
+    the scan holds both kinds (two ``cond``s a layer, the step between
+    them)."""
+    mc = dataclasses.replace(
+        TINY_MIMO_V2, num_heads=16, num_layers=4,
+        layer_types=("sliding_attention", "full_attention",
+                     "sliding_attention", "sliding_attention"))
+    params = mimo_v2.init_params(mc, jax.random.PRNGKey(3), jnp.float32)
+    b, n = 3, 140
+    rng = np.random.default_rng(5)
+    prompt_ids = jnp.asarray(rng.integers(0, mc.vocab_size, (b, n)))
+    lens = jnp.asarray([n, 37, n - 12], jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (b, n))
+    state = mimo_v2.forward(params, mc, prompt_ids, positions, lens)[3]
+    assert [s.shape for s in state] == [(b, 3, 2, W, 128)] * 2
+    toks = jnp.asarray(rng.integers(0, mc.vocab_size, (b, 1)))
+    step_lens = jnp.asarray([1, 0, 1], jnp.int32)
+    outs = {}
+    for interpret in (False, True):
+        view = att.KVView(interpret=interpret)
+        fn = jax.jit(lambda st, view=view: mimo_v2.forward(
+            params, mc, toks, lens[:, None], step_lens, view, state=st))
+        outs[interpret] = fn(state)
+        text = fn.lower(state).as_text(debug_info=True)
+        assert att.ring_step_path(text) == ("pallas" if interpret else "xla")
+    (h0, k0, v0, st0, _), (h1, k1, v1, st1, _) = outs[False], outs[True]
+    live = np.asarray(step_lens, bool)
+    np.testing.assert_allclose(h1[live], h0[live], atol=2e-5)
+    np.testing.assert_allclose(k1[:, :, live], k0[:, :, live], atol=2e-5)
+    np.testing.assert_allclose(v1[:, :, live], v0[:, :, live], atol=2e-5)
+    # Layer 0's ring is written from the same inputs on both paths: bit for
+    # bit. Deeper layers' keys come from hidden states that differ by the
+    # order of a float32 sum; their untouched slots and the dead row do not.
+    for got, want, old in zip(st1, st0, state):
+        np.testing.assert_array_equal(got[:, 0], want[:, 0])
+        np.testing.assert_array_equal(got[1], old[1])
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        changed = np.asarray(got != old).any(axis=(-1, -3))    # [B, NL, W]
+        want_changed = np.zeros(changed.shape, bool)
+        for r in np.flatnonzero(live):
+            want_changed[r, :, int(lens[r]) % W] = True
+        np.testing.assert_array_equal(changed, want_changed)
 
 
 def test_the_sink_merged_by_statistics_is_one_more_softmax_column():
@@ -463,6 +625,7 @@ def test_the_published_row_and_the_cut_read_as_the_issue_says():
     assert specs.paged_kv == (3, 4, 256)
     assert [(s.name, s.layers, s.shape) for s in specs.state] == [
         ("ring_k", 9, (8, 128, 192)), ("ring_v", 9, (8, 128, 128))]
+    assert [s.stored for s in specs.state] == [(8, 128, 256), (8, 128, 128)]
 
 
 def test_the_cut_changes_only_what_reduced_lists():
