@@ -60,7 +60,7 @@ from production_stack_tpu.ops.attention import (
     ring_step_path,
     segment_of_token,
 )
-from production_stack_tpu.ops import gated_delta, ssd
+from production_stack_tpu.ops import gated_delta, selective_scan, ssd
 from production_stack_tpu.ops.kv_write import (
     pool_copies,
     read_state_rows,
@@ -3334,8 +3334,13 @@ class ModelRunner:
             })
             cached_variants = (False, True)
         fams = set()
-        nb = 1
-        while nb <= b_max:
+        # The row buckets ``_bucket`` can give: the powers of two below
+        # ``b_max`` and ``b_max`` itself, which need not be one
+        # (``--max-num-seqs 48``: a train of 33 to 48 rows runs in the
+        # bucket of 48).
+        buckets = [1 << i for i in range(b_max.bit_length())
+                   if 1 << i < b_max] + [b_max]
+        for nb in buckets:
             # Tier bounds can land mid-bucket (counts 1..nb share bucket
             # nb), so both endpoints' caps are warmed; the interactive cap
             # makes (nb, INTERACTIVE) reachable at every row bucket.
@@ -3352,7 +3357,6 @@ class ModelRunner:
                 for dk in ks:
                     for cached in cached_variants:
                         fams.add((nb, mb, dk, cached))
-            nb *= 2
         return sorted(fams)
 
     def reachable_prefill_families(self):
@@ -3529,6 +3533,8 @@ class ModelRunner:
                                ("gdn_chunk", gated_delta.chunk_path(text)),
                                ("ssd_step", ssd.step_path(text)),
                                ("ring_step", ring_step_path(text)),
+                               ("s6_chunk",
+                                selective_scan.chunk_path(text)),
                                ("short_conv",
                                 gated_delta.short_conv_path(text))):
                 if path:

@@ -17,6 +17,7 @@ from production_stack_tpu.models import (
     mimo_v2,
     olmo_hybrid,
     opt,
+    phi4flash,
 )
 from production_stack_tpu.models.config import (
     LLAMA3_8B,
@@ -30,7 +31,8 @@ from production_stack_tpu.models.config import (
 
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
           "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid,
-          "lfm2_moe": lfm2_moe, "afmoe": afmoe, "mimo_v2": mimo_v2}
+          "lfm2_moe": lfm2_moe, "afmoe": afmoe, "mimo_v2": mimo_v2,
+          "phi4flash": phi4flash}
 
 
 def get_model(cfg: ModelConfig):

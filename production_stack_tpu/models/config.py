@@ -95,7 +95,7 @@ class CacheSpecs(NamedTuple):
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid" |
-    # "lfm2_moe" | "afmoe" | "mimo_v2"
+    # "lfm2_moe" | "afmoe" | "mimo_v2" | "phi4flash"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -199,6 +199,14 @@ class ModelConfig:
     swa_attention_sink: bool = False
     ep_size: int = 1
     ep_rank: int = 0
+    # models/phi4flash.py. Mamba-1's selective scan (ops/selective_scan.py)
+    # over mamba_d_inner channels (``mamba_expand * hidden_size``) and a
+    # state of mamba_d_state, its step size through a low-rank pair of
+    # mamba_dt_rank; mamba_d_conv and mamba_conv_bias as above. Which layer
+    # is of which kind follows from num_layers (``layer_kinds``);
+    # sliding_window is the window layers' span and their ring's slots.
+    mamba_d_inner: int = 0
+    mamba_dt_rank: int = 0
 
     def __post_init__(self):
         if self.arch in ANY_ORDER_LISTS:
@@ -489,6 +497,8 @@ class ModelConfig:
             )
         if model_type == "mimo_v2":
             return _mimo_v2_config(d, name)
+        if model_type == "phi4flash":
+            return _phi4flash_config(d, name)
         raise ValueError(f"Unsupported model_type: {model_type}")
 
     @staticmethod
@@ -607,6 +617,70 @@ def _mimo_v2_config(d: dict, name: str) -> ModelConfig:
         routed_scaling_factor=float(d.get("routed_scaling_factor") or 1.0),
         norm_topk_prob=d.get("norm_topk_prob", True),
         ep_size=ep_size, ep_rank=ep_rank,
+        name=name,
+    )
+
+
+def _phi4flash_config(d: dict, name: str) -> ModelConfig:
+    """``model_type: phi4flash`` (models/phi4flash.py): what the module does
+    not implement is refused by its key, not served as something else. The
+    four ``mamba_*`` sizes the published config omits read Mamba-1's
+    defaults."""
+    hidden, heads = d["hidden_size"], d["num_attention_heads"]
+    kv_heads = d.get("num_key_value_heads", heads)
+    layers = d["num_hidden_layers"]
+    window = d.get("sliding_window")
+    n_state = d.get("mamba_d_state", 16)
+    inner = d.get("mamba_expand", 2) * hidden
+    rank = d.get("mamba_dt_rank", "auto")
+    rank = -(-hidden // 16) if rank == "auto" else rank
+    unsupported = {
+        "mb_per_layer != 2": d.get("mb_per_layer", 2) != 2,
+        "num_hidden_layers: a multiple of 4, at least 8":
+            layers % 4 != 0 or layers < 8,
+        "sliding_window: one number, a multiple of 16 (the ring's tile of "
+        "slots)": not isinstance(window, int) or isinstance(window, bool)
+        or window < 16 or window % 16 != 0,
+        **{f"{k} (the model has no position embedding: a rotation is not "
+           f"served)": True for k, v in d.items()
+           if v and (k.startswith("rope_") or "rotary" in k)},
+        "mamba_d_state: a multiple of 8, at most 64":
+            n_state % 8 != 0 or not 8 <= n_state <= 64,
+        "mamba_expand * hidden_size: a multiple of 128": inner % 128 != 0,
+        "mamba_d_conv < 2": d.get("mamba_d_conv", 4) < 2,
+        "mamba_dt_rank < 1": not isinstance(rank, int) or rank < 1,
+        "mamba_proj_bias": bool(d.get("mamba_proj_bias", False)),
+        "num_attention_heads / num_key_value_heads: whole pairs, the query "
+        "pairs a multiple of the KV pairs":
+            heads % 2 != 0 or kv_heads % 2 != 0 or heads % kv_heads != 0,
+        "hidden_size: a multiple of num_attention_heads": hidden % heads != 0,
+        "tie_word_embeddings false": not d.get("tie_word_embeddings", True),
+        "mlp_bias": bool(d.get("mlp_bias", False)),
+        "lm_head_bias": bool(d.get("lm_head_bias", False)),
+        "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+    }
+    asked = [k for k, on in unsupported.items() if on]
+    if asked:
+        raise ValueError(f"phi4flash: not supported: {', '.join(asked)}")
+    return ModelConfig(
+        arch="phi4flash",
+        vocab_size=d["vocab_size"],
+        hidden_size=hidden,
+        intermediate_size=d["intermediate_size"],
+        num_layers=layers,
+        num_heads=heads,
+        num_kv_heads=kv_heads,
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rope_theta=None,
+        rms_norm_eps=d.get("layer_norm_eps", 1e-5),
+        tie_word_embeddings=True,
+        attention_bias=True,
+        sliding_window=window,
+        mamba_d_state=n_state,
+        mamba_d_conv=d.get("mamba_d_conv", 4),
+        mamba_conv_bias=d.get("mamba_conv_bias", True),
+        mamba_d_inner=inner,
+        mamba_dt_rank=rank,
         name=name,
     )
 
@@ -851,12 +925,26 @@ TINY_MIMO_V2 = ModelConfig(
     moe_intermediate_size=64, first_k_dense_replace=1,
     name="tiny-mimo-v2",
 )
+# Tiny SambaY decoder: two (S6, window) pairs, the S6 layer that hands its
+# scan output on, the one paged full layer, one (memory unit, cross) pair;
+# 8 query heads over 4 KV heads of 64 lanes (4 query pairs over 2 KV pairs: a
+# KV pair is a row of 128 lanes, which the paged kernels take), a window of
+# 64 keys (tests/test_phi4flash.py compares it with the plain reference).
+TINY_PHI4FLASH = ModelConfig(
+    arch="phi4flash", vocab_size=512, hidden_size=512, intermediate_size=256,
+    num_layers=8, num_heads=8, num_kv_heads=4, max_position_embeddings=1024,
+    rope_theta=None, rms_norm_eps=1e-5, tie_word_embeddings=True,
+    attention_bias=True, sliding_window=64, mamba_d_state=16, mamba_d_conv=4,
+    mamba_conv_bias=True, mamba_d_inner=1024, mamba_dt_rank=32,
+    name="tiny-phi4flash",
+)
 TINY_MIMO_V2_EP4 = dataclasses.replace(
     TINY_MIMO_V2, n_routed_experts=4, ep_size=4, ep_rank=1,
     name="tiny-mimo-v2-ep4")
 
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
+    "tiny-phi4flash": TINY_PHI4FLASH,
     "tiny-mimo-v2": TINY_MIMO_V2,
     "tiny-mimo-v2-ep4": TINY_MIMO_V2_EP4,
     "tiny-afmoe": TINY_AFMOE,

@@ -1643,3 +1643,111 @@ def test_programs_of_the_window_ring_deployment_fit_the_compile_cache(v5e):
         (8, 256), (16, 128)]
     assert {f[3] for f in prefill} == {False}
     assert len(r.reachable_decode_families()) + len(prefill) <= 24
+
+
+# ---- phi-4-mini-flash: a selective scan beside window rings in the state
+# slots, ONE paged layer read by eight, a second half that caches nothing
+# (PR 54)
+# Instructions of a compiled dispatch program (a scan over the first half's
+# (S6, window) pairs, the two layers between, a scan over the second half's
+# (memory unit, cross) pairs).
+SAMBAY_INSTRUCTIONS = 9000
+
+
+@pytest.mark.parametrize("program", ["decode-48x32", "decode-8x32",
+                                     "prefill-1x2048", "prefill-1x128",
+                                     "prefill-16x128"])
+def test_sambay_dispatch_programs_compile_in_place_for_v5e(v5e, program):
+    """The decode program at the widest bucket and at 8 rows and three
+    prefill rectangles of phi-4-mini-flash's envelope (deployment.json's
+    flags, published widths, all 32 layers and 200064 rows of vocabulary)
+    compile for a v5e, fit its HBM beside 7.70 GB of weights, the 1.34 GB
+    K/V pool of ONE layer and 1.19 GB of state, and copy neither a pool nor
+    a weight stack. A prefill program holds the selective scan as its
+    Mosaic kernel, in both places an S6 layer stands, and no serial loop of
+    XLA steps a token; the paged kernel stands twice (the full layer and
+    the cross layers' scan)."""
+    from production_stack_tpu.engine.runner import _bucket
+    from production_stack_tpu.ops.attention import (
+        prefill_attn_path,
+        ring_step_path,
+    )
+    from production_stack_tpu.ops.kv_write import pool_copies
+    from production_stack_tpu.ops.selective_scan import chunk_path
+
+    r = _deployment_runner(v5e, "phi-4-mini-flash")
+    assert r.kv_k.shape == r.kv_v.shape == (1, 10, 16384 * 16, 128)
+    assert [p.shape for p in r.state_pools] == [
+        (49, 8, 10, 512, 128), (49, 8, 10, 512, 128), (49, 9, 16, 5120),
+        (49, 9, 120, 128)]
+    assert r.state_pools[2].dtype == jnp.float32
+    assert r.prefill_reads_pool and not r.prefill_packs
+    assert r.ring_report()["window_layers"] == [1, 3, 5, 7, 9, 11, 13, 15]
+    assert r.ring_report()["paged_layer_readers"] == [
+        17, 19, 21, 23, 25, 27, 29, 31]
+    full_mb = _bucket(r.config.max_blocks_per_seq, 1,
+                      r.config.max_blocks_per_seq)
+    aparams = r._abstract_params()
+    layers = aparams["layers"]
+    assert layers["ffn"]["w_in"].shape == (32, 2560, 20480)
+    assert layers["s6"]["a_log"].shape == (9, 16, 5120)
+    assert layers["s6"]["a_log"].dtype == jnp.float32
+    assert layers["attn"]["wqkv"].shape == (9, 2560, 5120)
+    assert layers["cross"]["wqkv"].shape == (7, 2560, 2560)
+    assert layers["gmu"]["in_proj"].shape == (7, 2560, 5120)
+    assert aparams["embed"].shape == (200064, 2560)
+    assert "lm_head" not in aparams
+    decode = program.startswith("decode")
+    rows, t = (int(x) for x in program.split("-")[1].split("x"))
+    if decode:
+        lowered = r._lower_decode(aparams, rows, full_mb, t, False)
+    else:
+        assert (rows, t, full_mb, False) in r.reachable_prefill_families()
+        lowered = r._lower_prefill(aparams, rows, t, full_mb, False)
+    compiled = lowered.compile()      # raises where HBM or VMEM overflow
+    text = compiled.as_text()
+    stacks = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+              for x in (layers["ffn"]["w_in"], layers["ffn"]["w_out"],
+                        layers["s6"]["in_proj"], layers["attn"]["wqkv"],
+                        aparams["embed"])]
+    assert pool_copies(text, [r.kv_k, *r.state_pools, *stacks]) == []
+    # The paged kernel of the full layer and of the cross layers' scan;
+    # a prefill chunk's selective scan in the first half's scan and in
+    # layer 16.
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (2 if decode else 4)
+    assert ("%paged_flash_decode" in text) == decode
+    assert chunk_path(text) == (None if decode else "pallas")
+    assert ring_step_path(text) == ("xla" if decode else None)
+    if not decode:
+        assert prefill_attn_path(text) == "pallas"
+    for scope in ("embed", "attn_proj", "attn_core", "s6_conv",
+                  "s6_step" if decode else "s6_chunk", "ring_attend",
+                  "ring_write", "diff_attn", "gmu", "xdec_attend", "ffn",
+                  "logits", "kv_write", "state_read", "state_write",
+                  "sample"):
+        assert f"/{scope}/" in text, scope
+    instructions = sum(1 for ln in text.splitlines() if " = " in ln)
+    assert instructions < SAMBAY_INSTRUCTIONS, instructions
+    mem = compiled.memory_analysis()
+    # Weights 7.70 GB, K/V 1.34 GB and the state's pools 1.19 GB are
+    # arguments; a program's temporaries (a decode program's carried rows,
+    # 1.16 GB at 48) fit beside them.
+    assert 10.1e9 < mem.argument_size_in_bytes < 10.5e9, \
+        mem.argument_size_in_bytes
+    assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_programs_of_the_sambay_deployment_fit_the_compile_cache(v5e):
+    """8 prefill rectangles and the decode families of phi-4-mini-flash's
+    envelope, counted before chip time (the chip machine caps a
+    configuration's compile cache at 192 MiB: PERF.md section 6, PR 31 and
+    PR 33)."""
+    r = _deployment_runner(v5e, "phi-4-mini-flash")
+    prefill = r.reachable_prefill_families()
+    assert [f[:2] for f in prefill] == [
+        (1, 128), (1, 256), (1, 512), (1, 1024), (1, 2048), (8, 128),
+        (8, 256), (16, 128)]
+    assert {f[3] for f in prefill} == {False}
+    assert len(r.reachable_decode_families()) + len(prefill) <= 28
