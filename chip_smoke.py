@@ -921,43 +921,76 @@ def ssd_child(rehearse: bool) -> int:
 
 
 # --ring: the window ring's decode step alone (ops/attention.py:
-# window_ring_step) at MiMo-V2.5's published widths (benchmarks/chip/
-# configs/mimo-v2.5-ep16/config.json): 9 window layers' rings of 8 KV heads
-# x 128 slots in a carry of the cell's two decode buckets, 64 queries a row,
-# positions past the window; 4, 13 (longctx-decode's mean in its 32-row
-# bucket) and 32 live rows. The kernel beside the XLA statement on the same
-# inputs, and at (32, 13) the series of the kernel's two constants (a row's
-# heads are one block: blocks of 4 / 2 / 1 heads read 4-30 us a call more in
-# PR 53's first series, and Mosaic loads no single sublane at a traced head).
-RING_CONFIG = os.path.join(HERE, "benchmarks", "chip", "configs",
-                           "mimo-v2.5-ep16", "config.json")
-RING_STEP_ROWS = ((32, 1), (32, 4), (32, 13), (32, 32), (8, 4), (8, 8))
-RING_SERIES_AT = (32, 13)
-# (NUM_BUFS, FETCH_AHEAD) of ops/pallas/window_ring.py
-RING_SERIES = ((2, 1), (3, 1), (3, 2), (4, 2))
+# window_ring_step) at the published widths of the two configurations that
+# keep rings, each read from its benchmarks/chip/configs/<name>/config.json:
+# MiMo-V2.5's 9 window layers of 8 KV heads x 128 slots, 64 queries a row
+# (8 a KV head), keys of 192 lanes in rows of 256, sinks, in the cell's two
+# decode buckets at 4, 13 (longctx-decode's mean in its 32-row bucket) and
+# 32 live rows; Phi-4-mini-flash's 8 window layers of 10 packed KV rows x
+# 512 slots x 128 lanes, 40 queries a row (4 a KV row), no sink, in its 48-
+# and 8-row buckets at 8 / 24 / 44 (reasoning-saturated's mean) / 48 live.
+# Positions past the window. The kernel beside the XLA statement on the
+# same inputs, and at one (bucket, live) a shape the series of the kernel's
+# two constants (a row's heads are one block: blocks of 4 / 2 / 1 heads
+# read 4-30 us a call more in PR 53's first series, and Mosaic loads no
+# single sublane at a traced head).
 RING_CALLS = 8
+
+
+def _ring_shapes():
+    """name -> the rings' widths from the configuration's published
+    ``config.json``, the (bucket, live) pairs to time, where the constants'
+    series is taken and its (NUM_BUFS, FETCH_AHEAD) values (four buffers of
+    Phi's 2.5 MiB blocks are past the kernel's VMEM: not in its series)."""
+    from benchmarks.chip.lib import shapes_mimo, shapes_sambay
+
+    def cfg_of(name):
+        with open(os.path.join(HERE, "benchmarks", "chip", "configs", name,
+                               "config.json")) as f:
+            return json.load(f)
+
+    mimo, phi = cfg_of("mimo-v2.5-ep16"), cfg_of("phi-4-mini-flash")
+    dm, dp = shapes_mimo.dims(mimo), shapes_sambay.dims(phi)
+    head = phi["hidden_size"] // phi["num_attention_heads"]
+    return {
+        "mimo-v2.5-ep16": {
+            "layers": dm["windowed"], "window": dm["window"],
+            "heads": dm["heads"], "kv_heads": dm["kv_window"],
+            "dk": dm["dk"], "dv": dm["dv"], "sink": True,
+            # a position of a layer's ring: its keys and values
+            "row_bytes": shapes_mimo.ring_row_bytes(mimo),
+            "rows": ((32, 1), (32, 4), (32, 13), (32, 32), (8, 4), (8, 8)),
+            "series_at": (32, 13),
+            "series": ((2, 1), (3, 1), (3, 2), (4, 2))},
+        # A packed differential row: two KV heads side by side, the two
+        # queries of a pair each over the row's whole width
+        # (models/phi4flash.py:kv_rows, pack_queries).
+        "phi-4-mini-flash": {
+            "layers": dp["ring"], "window": dp["window"],
+            "heads": phi["num_attention_heads"],
+            "kv_heads": phi["num_key_value_heads"] // 2,
+            "dk": 2 * head, "dv": 2 * head, "sink": False,
+            "row_bytes": shapes_sambay.kv_bytes_per_token(phi),
+            "rows": ((48, 8), (48, 24), (48, 44), (48, 48), (8, 4), (8, 8)),
+            "series_at": (48, 44),
+            "series": ((2, 1), (3, 1), (3, 2))},
+    }
 
 
 def ring_child(rehearse: bool) -> int:
     """``--ring``: times one decode step of a window layer alone on the
-    chip, the calls chained through the layers of a donated pair of rings
-    in turn (in place), every operand behind an ``optimization_barrier``
+    chip at each shape of ``_ring_shapes`` (one line a shape), the calls
+    chained through the layers of a donated pair of rings in turn (in
+    place), every operand behind an ``optimization_barrier``
     (``chained_chunks`` says why): the kernel a program lowered for a TPU
     holds, the ``jnp`` statement and the chain with no step in it (what the
     harness itself costs a call), microseconds a LIVE row-layer beside
-    the time of its bytes (the payload benchmarks/chip/lib/shapes_mimo.py
-    counts, and as the rows lie in HBM: 192 lanes in 256, a tile of slots
-    written a head), then the kernel under other values of its two
-    constants. Fails where the kernel's program holds the ``jnp`` form on a
-    TPU, or the two disagree. Run by no benchmark cell and no other
-    phase."""
+    the time of its bytes (the payload benchmarks/chip/lib counts, and as
+    the rows lie in HBM: 192 lanes in 256, a tile of slots written a head),
+    then the kernel under other values of its two constants. Fails where
+    the kernel's program holds the ``jnp`` form on a TPU, or the two
+    disagree. Run by no benchmark cell and no other phase."""
     import jax
-    import jax.numpy as jnp
-
-    from benchmarks.chip.lib import shapes_mimo
-    from production_stack_tpu.models.mimo_v2 import ring_width
-    from production_stack_tpu.ops import attention as att
-    from production_stack_tpu.ops.pallas import window_ring
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind}
@@ -965,18 +998,32 @@ def ring_child(rehearse: bool) -> int:
         emit({"phase": "ring", "ok": False, "device": device,
               "error": "no TPU: nothing was timed"})
         return 1
-    with open(RING_CONFIG) as f:
-        cfg = json.load(f)
-    d = shapes_mimo.dims(cfg)
-    layers, w, dk, dv = d["windowed"], d["window"], d["dk"], d["dv"]
-    h, hkv = d["heads"], cfg["swa_num_key_value_heads"]
-    rows_list, series, calls = RING_STEP_ROWS, RING_SERIES, RING_CALLS
-    dtype = jnp.bfloat16
-    if rehearse:
-        rows_list, series, calls, layers, hkv, h, dtype = \
-            ((3, 2),), ((2, 1),), 1, 2, 2, 16, jnp.float32
     with open(os.path.join(HERE, "benchmarks", "chip", "peaks.json")) as f:
         peak = json.load(f)["by_device_kind"].get(dev.device_kind)
+    ok = True
+    for name, shape in _ring_shapes().items():
+        ok &= _ring_shape(name, shape, rehearse, peak, device)
+    return 0 if ok else 1
+
+
+def _ring_shape(name, shape, rehearse, peak, device) -> bool:
+    """One shape of ``ring_child``: its line, and whether it held."""
+    import jax
+    import jax.numpy as jnp
+
+    from production_stack_tpu.models.mimo_v2 import ring_width
+    from production_stack_tpu.ops import attention as att
+    from production_stack_tpu.ops.pallas import window_ring
+
+    layers, w, dk, dv = (shape[k] for k in ("layers", "window", "dk", "dv"))
+    h, hkv = shape["heads"], shape["kv_heads"]
+    rows_list, series, calls = shape["rows"], shape["series"], RING_CALLS
+    series_at = shape["series_at"]
+    dtype = jnp.bfloat16
+    if rehearse:    # the shape's queries a KV head over few heads and rows
+        rows_list, series, series_at, calls, layers, h, hkv, dtype = \
+            ((3, 2),), ((2, 1),), (3, 2), 1, 2, 2 * (h // hkv), 2, \
+            jnp.float32
     scale = dk ** -0.5
     tile = window_ring.tile_rows(dtype)
     item = jnp.dtype(dtype).itemsize
@@ -994,7 +1041,8 @@ def ring_child(rehearse: bool) -> int:
         # positions past the window, so every slot is seen.
         lens = ((jnp.arange(b) * n_live) % b < n_live).astype(jnp.int32)
         pos = jax.random.randint(ks[2], (b, 1), 2 * w, 64 * w)
-        sink = 2.0 + jax.random.normal(ks[3], (h,), jnp.float32)
+        sink = 2.0 + jax.random.normal(ks[3], (h,), jnp.float32) \
+            if shape["sink"] else None
         return (ring_k, ring_v), (draw(ks[4], b, 1, h, dk),
                                   draw(ks[5], b, 1, hkv, dk),
                                   draw(ks[6], b, 1, hkv, dv), pos, lens, sink)
@@ -1027,10 +1075,11 @@ def ring_child(rehearse: bool) -> int:
         return best / (calls * layers), att.ring_step_path(steps.as_text())
 
     def entry(form, sec, n_live, b, path, **more):
-        # A live row-layer: the payload shapes_mimo counts (129 rows of a
-        # head's keys and values), and what moves as the rows lie: the
-        # slots' whole lane tiles read, a tile of slots a head written.
-        payload = (w + 1) * shapes_mimo.ring_row_bytes(cfg)
+        # A live row-layer: the payload the benchmark's count takes (W + 1
+        # rows of a layer's keys and values), and what moves as the rows
+        # lie: the slots' whole lane tiles read, a tile of slots a head
+        # written.
+        payload = (w + 1) * shape["row_bytes"]
         laid = hkv * (w + tile) * (ring_width(dk) + ring_width(dv)) * item
         out = {"form": form, "rows": b, "live": n_live, "path": path,
                **more, "payload_bytes_a_row_layer": payload,
@@ -1080,7 +1129,7 @@ def ring_child(rehearse: bool) -> int:
     # The kernel's constants: each value set where the kernel reads it, the
     # program traced anew.
     shipped = (window_ring.NUM_BUFS, window_ring.FETCH_AHEAD)
-    b, n_live = (3, 2) if rehearse else RING_SERIES_AT
+    b, n_live = series_at
     rings, xs = inputs(b, n_live)
     tuned = []
     try:
@@ -1092,7 +1141,8 @@ def ring_child(rehearse: bool) -> int:
                                fetch_ahead=ahead))
     finally:
         window_ring.NUM_BUFS, window_ring.FETCH_AHEAD = shipped
-    emit({"phase": "ring",
+        jax.clear_caches()
+    emit({"phase": "ring", "config": name,
           "widths": {"heads": h, "kv_heads": hkv, "window": w, "dk": dk,
                      "dv": dv, "ring_lanes": [ring_width(dk),
                                               ring_width(dv)]},
@@ -1100,7 +1150,7 @@ def ring_child(rehearse: bool) -> int:
           "shipped": dict(zip(("num_bufs", "fetch_ahead"), shipped)),
           "checks": checks, "timing": timing, "series": tuned, "peak": peak,
           "device": device, "ok": ok})
-    return 0 if ok else 1
+    return ok
 
 
 # --hc: the stream mix alone (ops/hyper_connections.py) at Xing4.0-29B-A4B's

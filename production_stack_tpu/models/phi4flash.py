@@ -53,9 +53,11 @@ equations this module is held to.
 
 Device scopes: ``attn_proj`` (norms, projections), ``attn_core`` with the
 inner ``s6_conv``, ``s6_step`` / ``s6_chunk``, ``ring_attend`` /
-``ring_write`` (a decode step's under ``ring_step``), ``xdec_attend`` (the
-cross layers' reads of the full layer's rows), ``diff_attn`` (the
-subtraction and its norm) and ``gmu``; ``ffn``, ``embed``, ``logits``.
+``ring_write`` (a decode step's under ``ring_step``: on a TPU the kernel of
+ops/pallas/window_ring.py, its time whole under ``ring_attend``),
+``xdec_attend`` (the cross layers' reads of the full layer's rows),
+``diff_attn`` (the subtraction and its norm) and ``gmu``; ``ffn``,
+``embed``, ``logits``.
 """
 
 import math

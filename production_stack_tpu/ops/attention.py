@@ -478,7 +478,11 @@ def window_ring_step(
 
     One algorithm, two executions, chosen HERE by what can be seen (the
     rule of ops/gated_delta.py:gdn_step_at): where the rings' shape fits
-    it, a program LOWERED for a TPU (``lax.platform_dependent``) holds the
+    it (``supports_step_kernel``: rows of whole lane tiles, 8, 16, .. or 4,
+    2, 1 queries a KV head, a row's KV heads one block of bounded bytes;
+    mimo-v2.5's 8 x 128 slots at 8 queries a head and phi-4-mini-flash's 10
+    packed rows x 512 slots at 4 both do, since PR 55),
+    a program LOWERED for a TPU (``lax.platform_dependent``) holds the
     Pallas kernel (ops/pallas/window_ring.py: in place in the carried rings,
     a live row's slots read once and one row written, a row that is not
     live untouched), and so does any program with ``interpret`` set (the

@@ -19,6 +19,20 @@ goes BACK is the tile of ``tile_rows`` slots a head that holds the written
 one (a single row of a packed dtype cannot be addressed), not the slab. What
 is here is the operands' layout and a block's arithmetic.
 
+Which rings take it (``supports_step_kernel``, by shape alone): rows of whole
+128-lane tiles, a window of whole tiles of slots, ``G`` = 8, 16, .. or 4, 2,
+1 queries a KV head, and a row's KV heads ONE block whose ``NUM_BUFS``
+buffers fit ``BUFFER_BYTES`` of VMEM. Two published shapes do: MiMo-V2.5's
+(8 KV heads x 128 slots x (256 + 128) lanes, 8 queries a head, sinks: a
+block of 768 KiB) and Phi-4-mini-flash's packed differential rows (10 KV
+rows x 512 slots x (128 + 128) lanes, 4 queries a row, no sink: 2.5 MiB, PR
+55). Fewer than 8 queries a head lie 8 sublanes a head in the float32
+scratch, zeros in the rest, so both products keep whole sublane tiles
+(``[8, D] x [D, W]``, ``[8, W] x [W, D]``): the matrix unit is bound by the
+128 x 128 tiles of keys and values it is fed, not by the rows pushed through
+them (PERF.md section 6, PR 53), so the padding is free; only the head's own
+rows are rounded into the output. Every other ring keeps the ``jnp`` form.
+
 Arithmetic, a KV head of a live row (``G`` query heads share it):
 
   * The step's key and scaled value go into slot ``position mod W`` of the
@@ -43,7 +57,7 @@ key and its value in the activations' dtype, the sinks as 64 scalars) and
 the attention comes out in the queries' dtype: nothing is cast, padded or
 laid out again around the call, which cost more launches than the kernel's
 own fixed time (PERF.md section 6, PR 53). Inside, a row's operands are
-widened once to float32 scratch of the ring's lanes (a float32 ``[G, D]``
+widened once to float32 scratch of the ring's lanes (a float32 ``[8, D]``
 block is whole sublane tiles where a 16-bit one is half of one; the casts
 back are exact), and its result is rounded once from float32 scratch.
 
@@ -66,8 +80,11 @@ from production_stack_tpu.ops.pallas.live_blocks import (
 
 NUM_BUFS = 3             # one block coming in, one computed, one going out
 FETCH_AHEAD = 1          # blocks in flight towards the one computed
-HEADS_PER_BLOCK = 8      # a row's KV heads are ONE block (at most so many),
-                         # unrolled in the kernel: 768 KiB at 128 x (256 + 128)
+BUFFER_BYTES = 8 << 20   # VMEM the NUM_BUFS buffers of both rings may take,
+                         # beside live_blocks' OPERAND_BYTES under a v5e's
+                         # 16 MiB: a row's KV heads are ONE block, unrolled in
+                         # the kernel (768 KiB at 8 x 128 x (256 + 128) lanes,
+                         # 2.5 MiB at 10 x 512 x (128 + 128))
 LANES, SUBLANES = 128, 8
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -78,20 +95,31 @@ def tile_rows(dtype) -> int:
     return SUBLANES * 4 // jnp.dtype(dtype).itemsize
 
 
+def padded_group(g: int) -> int:
+    """Sublanes a KV head's ``g`` queries take in the float32 scratch: whole
+    tiles of 8, zeros in the rows past its own."""
+    return -(-g // SUBLANES) * SUBLANES
+
+
 def supports_step_kernel(ring_k, ring_v, num_heads: int) -> bool:
     """Whether the rings ``[.., Hkv, W, Dk]`` / ``[.., Hkv, W, Dv]`` fit the
     kernel: one dtype, rows of whole lanes (a slice of an array in HBM whose
     rows are not is refused by Mosaic: 192 lanes lie in 256 there and cannot
-    be addressed), the window whole tiles of slots, a KV head's queries whole
-    sublane tiles, a row's KV heads one block (a head's operands are then
-    static sublanes: Mosaic loads no single sublane at a traced index)."""
+    be addressed), the window whole tiles of slots, a KV head's queries
+    whole sublane tiles or an even part of one (8, 16, .. or 4, 2, 1: fewer
+    than 8 lie 8 sublanes a head, so every head's rows start a tile), and a
+    row's KV heads ONE block (a head's operands are then static sublanes:
+    Mosaic loads no single sublane at a traced index) whose ``NUM_BUFS``
+    buffers fit ``BUFFER_BYTES``."""
     hkv, w, dk = ring_k.shape[-3:]
     dv = ring_v.shape[-1]
+    g = num_heads // hkv
+    block = hkv * w * (dk + dv) * jnp.dtype(ring_k.dtype).itemsize
     return (ring_k.dtype == ring_v.dtype and num_heads % hkv == 0
             and dk % LANES == 0 and dv % LANES == 0
             and w % tile_rows(ring_k.dtype) == 0
-            and (num_heads // hkv) % SUBLANES == 0
-            and hkv <= HEADS_PER_BLOCK)
+            and (g % SUBLANES == 0 or SUBLANES % g == 0)
+            and NUM_BUFS * block <= BUFFER_BYTES)
 
 
 def _step_kernel(
@@ -122,6 +150,7 @@ def _step_kernel(
     qs, new, acc, sinks = scratch[6:]
     _, hkv, w, dkr = kbuf.shape         # a block is a row's heads
     g = q_ref.shape[1] // hkv
+    gp = padded_group(g)                # a head's rows of ``qs``
     dk, dv = q_ref.shape[-1], v_ref.shape[-1]
     tile = tile_rows(kbuf.dtype)
 
@@ -142,6 +171,8 @@ def _step_kernel(
     # their scalars.
     qs[...] = jnp.zeros(qs.shape, qs.dtype)
     new[...] = jnp.zeros(new.shape, new.dtype)
+    if g < gp:
+        sinks[...] = jnp.zeros(sinks.shape, sinks.dtype)
     for i in range(hkv * g):
         sinks[i // g, pl.ds(i % g, 1), :] = jnp.full(
             (1, LANES), sink_ref[i], jnp.float32)
@@ -154,7 +185,13 @@ def _step_kernel(
         # The queries scaled and rounded as the statement does; the step's
         # key beside its value, a head a sublane.
         scaled = q_ref[r].astype(jnp.float32) * scale
-        qs[:, pl.ds(0, dk)] = scaled.astype(kbuf.dtype).astype(jnp.float32)
+        scaled = scaled.astype(kbuf.dtype).astype(jnp.float32)
+        if g == gp:
+            qs[:, pl.ds(0, dk)] = scaled
+        else:       # 8 sublanes a head, its own queries the first g
+            for h in range(hkv):
+                qs[pl.ds(h * gp, g), pl.ds(0, dk)] = \
+                    scaled[h * g:(h + 1) * g]
         new[:, pl.ds(0, dk)] = k_ref[r].astype(jnp.float32)
         new[:, pl.ds(dkr, dv)] = v_ref[r].astype(jnp.float32)
         for h in range(hkv):
@@ -164,11 +201,10 @@ def _step_kernel(
                 buf[slot, h, pl.ds(first, tile), :] = jnp.where(
                     in_tile == place, row_new.astype(buf.dtype), held)
         for h in range(hkv):
-            rows_h = pl.ds(h * g, g)
             s = jax.lax.dot_general(
-                qs[rows_h, :].astype(kbuf.dtype), kbuf[slot, h],
+                qs[pl.ds(h * gp, gp), :].astype(kbuf.dtype), kbuf[slot, h],
                 (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)             # [G, W]
+                preferred_element_type=jnp.float32)             # [Gp, W]
             s = jnp.where(seen, s, _NEG_INF)
             m = jnp.max(s, axis=-1, keepdims=True)
             p = jnp.exp(s - m)
@@ -181,7 +217,7 @@ def _step_kernel(
             top = jnp.maximum(jnp.maximum(m, sink), _NEG_INF)
             wa = l * jnp.exp(m - top)
             denom = jnp.maximum(wa + jnp.exp(sink - top), 1e-30)
-            acc[rows_h, :] = out * (wa / denom)
+            acc[pl.ds(h * g, g), :] = (out * (wa / denom))[:g]
         o_ref[r] = acc[...].astype(o_ref.dtype)
 
     run(compute)
@@ -211,6 +247,7 @@ def ring_step_in_place(
     hkv, _, dkr = ring_k.shape[2:]
     dvr = ring_v.shape[-1]
     h = q.shape[1]
+    gp = padded_group(h // hkv)
     f32 = jnp.float32
 
     def tiles(rows, width, dtype):
@@ -230,7 +267,8 @@ def ring_step_in_place(
         heads_per_block=hkv, num_bufs=NUM_BUFS,
         row_bytes=row_bytes, operand_bytes=OPERAND_BYTES,
         name="ring_step_in_place", interpret=interpret,
-        scratch=(pltpu.VMEM((h, dkr), f32), pltpu.VMEM((hkv, dkr + dvr), f32),
+        scratch=(pltpu.VMEM((hkv * gp, dkr), f32),
+                 pltpu.VMEM((hkv, dkr + dvr), f32),
                  pltpu.VMEM((h, dvr), f32),
-                 pltpu.VMEM((hkv, h // hkv, LANES), f32)))
+                 pltpu.VMEM((hkv, gp, LANES), f32)))
     return o[..., :v.shape[-1]], ring_k, ring_v

@@ -1122,7 +1122,8 @@ def test_latent_prefill_program_is_the_parents_on_v5e(v5e):
 
 def _kernel_entry_points():
     """name -> (entry point, ShapeDtypeStructs at one cell's shape, the sha1
-    of its jaxpr's text): the ten Pallas kernels of the serving path."""
+    of its jaxpr's text): the ten Pallas kernels of the serving path, the
+    ring's step at both configurations' shapes."""
     from production_stack_tpu.ops.pallas import gated_delta, ssd, window_ring
     from production_stack_tpu.ops.pallas import paged_attention as pa
 
@@ -1211,6 +1212,19 @@ def _kernel_entry_points():
              sds(32, 8, 128, dtype=bf16), sds(32, dtype=i32),
              sds(32, dtype=jnp.bool_), sds(64)),
             "5ec3311ea8adefa49dcebfc44664b1f16a74767f"),
+        # The same kernel at phi-4-mini-flash's rings (PR 55), which left
+        # the one above the program it was: 8 window layers of 10 packed
+        # KV rows x 512 slots x 128 lanes, 4 queries a KV row (8 sublanes a
+        # head in the float32 scratch), a 48-row bucket, no sink.
+        "ring_step_in_place-phi4flash-48": (
+            functools.partial(window_ring.ring_step_in_place,
+                              scale=64 ** -0.5),
+            (sds(48, 8, 10, 512, 128, dtype=bf16),
+             sds(48, 8, 10, 512, 128, dtype=bf16), sds(dtype=i32),
+             sds(48, 40, 128, dtype=bf16), sds(48, 10, 128, dtype=bf16),
+             sds(48, 10, 128, dtype=bf16), sds(48, dtype=i32),
+             sds(48, dtype=i32), sds(40)),
+            "14fc57344db0f8b35b9129d44b18fd98e978dc1b"),
     }
 
 
@@ -1666,7 +1680,11 @@ def test_sambay_dispatch_programs_compile_in_place_for_v5e(v5e, program):
     a weight stack. A prefill program holds the selective scan as its
     Mosaic kernel, in both places an S6 layer stands, and no serial loop of
     XLA steps a token; the paged kernel stands twice (the full layer and
-    the cross layers' scan)."""
+    the cross layers' scan). A DECODE program holds a third, the window
+    layers' step in place in the carried rings (PR 55: 4 queries a KV row
+    lie 8 sublanes a head in the kernel's scratch, a row's 10 heads of 512
+    slots are one block of 2.5 MiB; ``ring_step`` reads ``"pallas"``, its
+    time under ``ring_attend``, and no carried ring is copied around it)."""
     from production_stack_tpu.engine.runner import _bucket
     from production_stack_tpu.ops.attention import (
         prefill_attn_path,
@@ -1710,22 +1728,29 @@ def test_sambay_dispatch_programs_compile_in_place_for_v5e(v5e, program):
               for x in (layers["ffn"]["w_in"], layers["ffn"]["w_out"],
                         layers["s6"]["in_proj"], layers["attn"]["wqkv"],
                         aparams["embed"])]
-    assert pool_copies(text, [r.kv_k, *r.state_pools, *stacks]) == []
-    # The paged kernel of the full layer and of the cross layers' scan;
-    # a prefill chunk's selective scan in the first half's scan and in
-    # layer 16.
+    carried = [jax.ShapeDtypeStruct((rows, *p.shape[1:]), p.dtype)
+               for p in r.state_pools] if decode else []
+    assert pool_copies(
+        text, [r.kv_k, *r.state_pools, *carried, *stacks]) == []
+    # The paged kernel of the full layer and of the cross layers' scan; a
+    # decode step's ring kernel in the first half's scan; a prefill chunk's
+    # selective scan in the first half's scan and in layer 16.
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        (2 if decode else 4)
+        (3 if decode else 4)
     assert ("%paged_flash_decode" in text) == decode
     assert chunk_path(text) == (None if decode else "pallas")
-    assert ring_step_path(text) == ("xla" if decode else None)
-    if not decode:
+    assert ring_step_path(text) == ("pallas" if decode else None)
+    if decode:
+        # The kernel's time is booked where the statement's was.
+        assert any("/ring_attend/" in ln and "ring_step_in_place" in ln
+                   for ln in text.splitlines() if "tpu_custom_call" in ln)
+    else:
         assert prefill_attn_path(text) == "pallas"
     for scope in ("embed", "attn_proj", "attn_core", "s6_conv",
                   "s6_step" if decode else "s6_chunk", "ring_attend",
-                  "ring_write", "diff_attn", "gmu", "xdec_attend", "ffn",
-                  "logits", "kv_write", "state_read", "state_write",
-                  "sample"):
+                  *(() if decode else ("ring_write",)), "diff_attn", "gmu",
+                  "xdec_attend", "ffn", "logits", "kv_write", "state_read",
+                  "state_write", "sample"):
         assert f"/{scope}/" in text, scope
     instructions = sum(1 for ln in text.splitlines() if " = " in ln)
     assert instructions < SAMBAY_INSTRUCTIONS, instructions

@@ -368,19 +368,48 @@ RING_STEP_CASES = {
 }
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("sink", ["sink", "no-sink"])
-@pytest.mark.parametrize("case", list(RING_STEP_CASES))
-def test_ring_step_kernel_is_the_jnp_statement_in_place(case, sink, dtype):
+# (KV heads, queries a KV head, slots, key lanes, value lanes) of a ring:
+RING_STEP_HEADS = {
+    # MiMo-V2.5's: 8 queries a KV head, keys of 192 lanes in rows of 256
+    "8-queries": (2, 8, 128, 192, 128),
+    # Phi-4-mini-flash's packed differential rows: 4 queries a KV row (half
+    # a sublane tile), an odd number of rows (3 for its 10), a window of
+    # several tiles of slots, keys and values of 128 lanes
+    "4-queries": (3, 4, 64, 128, 128),
+}
+
+
+def _ring_step_params():
+    """Every case of the first head shape in both dtypes, and of the second
+    a subset in bfloat16 (float32 adds nothing there: the layout of a
+    head's queries is the dtype's only where it is 16 bits wide)."""
+    second = ("below-the-window", "at-the-window", "several-wraps",
+              "a-slot-reused", "dead-rows-between", "none-live")
+    out = [(case, sink, dtype, "8-queries")
+           for dtype in ("bfloat16", "float32")
+           for sink in ("sink", "no-sink") for case in RING_STEP_CASES]
+    out += [(case, sink, "bfloat16", "4-queries")
+            for sink in ("sink", "no-sink") for case in second]
+    return [pytest.param(*p, id="-".join(
+        p if p[3] != "8-queries" else p[:3])) for p in out]
+
+
+@pytest.mark.parametrize("case,sink,dtype,heads", _ring_step_params())
+def test_ring_step_kernel_is_the_jnp_statement_in_place(case, sink, dtype,
+                                                        heads):
     """``ring_step_in_place`` (interpreted) against ``window_ring_step_jnp``
-    at the published head shapes (8 queries a KV head, keys of 192 lanes in
-    rows of 256, values of 128; two KV heads and three layers here): the
-    attention of every live row within the dtype's rounding, the rings
-    EQUAL bit for bit in every slot, every dead row and every other layer.
-    Every slot holds finite junk before the step (another sequence's keys),
-    so a slot the visibility should hide and does not shows."""
+    at the two published head shapes (``RING_STEP_HEADS``; few KV heads and
+    three layers here): the attention of every live row within the dtype's
+    rounding, the rings EQUAL bit for bit in every slot, every dead row and
+    every other layer. Every slot holds finite junk before the step
+    (another sequence's keys), so a slot the visibility should hide and
+    does not shows. The second shape's window is half the first's, so its
+    positions are taken at half theirs where the case means the window."""
     positions, live = RING_STEP_CASES[case]
-    b, nl, hkv, g, w, dk, dv, at = len(positions), 3, 2, 8, 128, 192, 128, 1
+    hkv, g, w, dk, dv = RING_STEP_HEADS[heads]
+    if w < 128 and case in ("below-the-window", "at-the-window"):
+        positions = [p // (128 // w) for p in positions]
+    b, nl, at = len(positions), 3, 1
     dt = jnp.dtype(dtype)
     rng = np.random.default_rng(len(case) * 7 + sum(positions))
 
@@ -423,10 +452,17 @@ def test_ring_step_kernel_is_the_jnp_statement_in_place(case, sink, dtype):
 
 def test_ring_step_is_the_kernel_only_where_the_rings_fit_it():
     """The choice of ``window_ring_step`` is by what it can see: rings of
-    whole lane tiles with 8 queries a KV head and ``interpret`` hold the
-    kernel, the tiny preset's 2 queries a KV head the ``jnp`` form, and a
-    program lowered for a CPU without the switch the ``jnp`` form too."""
+    whole lane tiles whose KV heads have whole sublane tiles of queries or
+    an even part of one, a row's heads ONE block of bounded bytes, and
+    ``interpret`` hold the kernel (MiMo-V2.5's 8 queries over each of 8 KV
+    heads, Phi-4-mini-flash's 4 over each of 10 rows of 512 slots, and both
+    tiny presets as they fall: 2 over 2 of 128 slots, 4 over 2 of 64); rows
+    of 192 lanes, a window that is no whole tile, 3 queries a KV head and a
+    block past the byte bound the ``jnp`` form; and a program lowered for a
+    CPU without the switch the ``jnp`` form too."""
     from production_stack_tpu.ops.pallas.window_ring import (
+        BUFFER_BYTES,
+        NUM_BUFS,
         supports_step_kernel,
         tile_rows,
     )
@@ -437,9 +473,19 @@ def test_ring_step_is_the_kernel_only_where_the_rings_fit_it():
 
     assert tile_rows(jnp.bfloat16) == 16 and tile_rows(jnp.float32) == 8
     assert supports_step_kernel(*rings(8, 128, 256, 128), 64)
+    assert supports_step_kernel(*rings(10, 512, 128, 128), 40)
+    assert supports_step_kernel(*rings(2, 128, 128, 128), 4)
+    assert supports_step_kernel(*rings(2, 64, 128, 128, jnp.float32), 8)
     assert not supports_step_kernel(*rings(8, 128, 192, 128), 64)
-    assert not supports_step_kernel(*rings(2, 128, 128, 128), 4)
     assert not supports_step_kernel(*rings(8, 24, 256, 128), 64)
+    assert not supports_step_kernel(*rings(2, 128, 128, 128), 6)
+    # Twelve rows of 512 slots are 3 MiB a block, and NUM_BUFS of them past
+    # the buffers' VMEM; in float32 ten are.
+    assert NUM_BUFS * 10 * 512 * 256 * 2 <= BUFFER_BYTES \
+        < NUM_BUFS * 12 * 512 * 256 * 2
+    assert not supports_step_kernel(*rings(12, 512, 128, 128), 48)
+    assert not supports_step_kernel(
+        *rings(10, 512, 128, 128, jnp.float32), 40)
 
     def step(interpret):
         def fn(rk, rv, q, k, v, pos, lens):
