@@ -31,10 +31,9 @@ their ratio, the kernel's own roofline share. It is read by no metric.
 calls twice a dispatch. Each serving line's ``memory`` is the engine's memory
 ledger after the phase (``GET /debug/memory``: residents, rises, the last).
 ``--prefill`` (alone, like ``--gdn``, ``--ssd``, ``--ring``, ``--moe`` and
-``--hc``) does the same for the
-prefill flash kernels at the benchmark's prefill shapes (four over K/V rows,
-nine over latent rows), with ``window_attention`` at the parent's window
-width beside it.
+``--hc``) does the same for the prefill flash kernel at the benchmark's
+prefill shapes: over K/V rows and over latent rows, a packed row and a
+rectangle laid as a row.
 
 This process never imports JAX: a chip belongs to one process at a time and
 the engine children need it (the kernel phase runs in a child of its own).
@@ -1465,102 +1464,73 @@ def moe_child(rehearse: bool) -> int:
     return 0 if ok else 1
 
 
-# The prefill chunk's attention alone at the benchmark's prefill shapes
-# (Dh 128, block 16): cell 3's 8 x 256 rectangle (rows of 64-320 tokens of
-# history, a padded row last), cell 2's one suffix in a 256-token chunk
-# behind 6300 tokens, and cell 4's two (the hybrid's full layers, 30 query
-# and 30 KV heads, so a KV head's score block is only TQ rows): one long
-# prompt's first chunk in a 1 x 2048 program, and the 8 x 256 rectangle
-# whose rows are first chunks or follow one or two (no prefix is served
-# there: a history is whole chunks). ``window`` is the width of the window
-# PR 33's tree gathered for such a dispatch with history
-# (utils/misc.py:window_mb_bucket: a quarter of the full bucket at least;
-# the hybrid's pinned at the full bucket, 4096 keys).
+# The prefill chunk's attention alone (``--prefill``): ONE timing a pool kind
+# and form of dispatch (ops/pallas/paged_attention.py: over K/V rows the
+# packed kernel and the rectangle kernel; over latent rows one body, a
+# rectangle laid as a row, since PR 56). Dh 128, block 16; ``rows`` says a
+# rectangle, ``segments`` a packed row.
 PREFILL_TIMING_SHAPES = [
-    {"name": "chat-saturated", "rows": 8, "t": 256, "hist": (64, 320),
-     "chunk": (96, 256), "heads": 16, "kv_heads": 2, "window": 1024},
-    {"name": "agent-prefix", "rows": 1, "t": 256, "hist": (6300, 6300),
-     "chunk": (192, 192), "heads": 32, "kv_heads": 8, "window": 8192},
-    {"name": "hybrid-long-row", "rows": 1, "t": 2048, "hist": (0, 0),
-     "chunk": (1200, 2048), "heads": 30, "kv_heads": 30, "window": 4096},
-    {"name": "hybrid-rectangle", "rows": 8, "t": 256, "hist": (0, 2),
-     "hist_unit": 256, "chunk": (64, 256), "heads": 30, "kv_heads": 30,
-     "window": 4096},
-]
-# The same over LATENT rows, at the shape both latent configurations share
-# (kanana-2-30b-a3b-d8, xing4.0-29b-a4b-d7: 32 heads over one 640-lane row
-# a token, 576 of them the key, the first 512 the values, block 16) and
-# their three fullest rectangles at a token budget of 1024, each behind the
-# cached system prompt (64), a median prompt's earlier chunks (320) and a
-# long one's (2048). ``window``: the parent's window, pinned at the full
-# block table (PR 33 to PR 38).
-LATENT_PREFILL_TIMING_SHAPES = [
-    {"name": f"latent-{rows}x{t}-hist{hist}", "rows": rows, "t": t,
-     "hist": (hist, hist), "chunk": (t * 3 // 4, t), "heads": 32,
-     "row": 640, "key": 576, "values": 512, "window": 3072}
-    for rows, t in ((8, 128), (4, 256), (1, 1024))
-    for hist in (64, 320, 2048)
-]
-# A PACKED row (PR 46: ``runner.prefill_packs``) beside the rectangle the
-# same sequences would have run as: cell 3's dispatch, 16 segments that
-# fill a 2048-token row behind histories of 0-300 tokens, against the
-# ``[16, 128]`` rectangle of their first 128 tokens each; and cell 2's, one
-# 256-token suffix behind 6000 tokens, against its ``[1, 256]`` row (a
-# segment that fills whole query blocks must cost what its row costs).
-# ``sub_blocks``: the packed kernel also at these sub-block widths (tokens;
-# the first is ``packed_sub_block``'s own; a segment that has a whole
-# block takes it in the kernel's wider pieces whatever this says).
-PACKED_PREFILL_TIMING_SHAPES = [
-    {"name": "packed-chat-saturated", "t": 2048, "segments": 16, "slots": 16,
-     "hist": (0, 300), "heads": 16, "kv_heads": 2, "rect": (16, 128),
-     "sub_blocks": (32, 64, 256)},
-    {"name": "packed-agent-prefix", "t": 256, "segments": 1, "slots": 16,
-     "hist": (6000, 6000), "heads": 32, "kv_heads": 8, "rect": (1, 256),
-     "sub_blocks": (64, 128)},
-    # PR 48: the same over LATENT rows (LATENT_PREFILL_TIMING_SHAPES'
-    # widths), cells 5 and 6's dispatch at a 1024-token budget: 8 segments
-    # of 128 tokens behind the cached system prompt beside the ``[8, 128]``
-    # rectangle of the same tokens, and 8 segments cut at random behind
-    # 64-320 tokens beside the rectangle of their first 128 each.
-    # ``key_tiles``: the kernel also at these widths of a row tile (keys;
-    # the first is ``packed_latent_tile``'s own).
-    {"name": "packed-latent-8x128", "t": 1024, "segments": 8, "slots": 8,
-     "equal": True, "hist": (64, 64), "heads": 32, "row": 640, "key": 576,
-     "values": 512, "rect": (8, 128), "sub_blocks": (16, 32),
-     "key_tiles": (256, 128, 512)},
+    # K/V rows, packed: cell 3's dispatch, 16 segments that fill a
+    # 2048-token row behind histories of 0-300 tokens (16 query heads over 2
+    # KV heads), and cell 2's, one 256-token suffix behind 6000 tokens.
+    {"name": "packed-chat-saturated", "t": 2048, "segments": 16,
+     "hist": (0, 300), "heads": 16, "kv_heads": 2},
+    {"name": "packed-agent-prefix", "t": 256, "segments": 1,
+     "hist": (6000, 6000), "heads": 32, "kv_heads": 8},
+    # K/V rows, rectangles: the widest and the longest dispatch of cell 4's
+    # full layers (30 query and 30 KV heads, so a KV head's score block is
+    # only TQ rows) and of cell 7's attention layers (32 query heads over 4
+    # KV rows of two paired heads), the rows of a wide one behind 0-1500
+    # tokens, its last row padding.
+    {"name": "hybrid-16x128", "rows": 16, "t": 128, "hist": (0, 1500),
+     "heads": 30, "kv_heads": 30},
+    {"name": "hybrid-1x2048", "rows": 1, "t": 2048, "hist": (0, 0),
+     "heads": 30, "kv_heads": 30},
+    {"name": "granite-16x128", "rows": 16, "t": 128, "hist": (0, 1500),
+     "heads": 32, "kv_heads": 4},
+    {"name": "granite-1x2048", "rows": 1, "t": 2048, "hist": (0, 0),
+     "heads": 32, "kv_heads": 4},
+    # LATENT rows, at the shape both latent configurations share
+    # (kanana-2-30b-a3b-d8, xing4.0-29b-a4b-d7: 32 heads over one 640-lane
+    # row a token, 576 of them the key, the first 512 the values): cells 5
+    # and 6's packed row at a 1024-token budget, 8 segments of 128 tokens
+    # behind the cached system prompt and 8 cut at random behind 64-320
+    # tokens; and the 8 x 128 rectangle of a runner with an adapter.
+    {"name": "packed-latent-8x128", "t": 1024, "segments": 8, "equal": True,
+     "hist": (64, 64), "heads": 32, "row": 640, "key": 576, "values": 512},
     {"name": "packed-latent-chat-saturated", "t": 1024, "segments": 8,
-     "slots": 8, "hist": (64, 320), "heads": 32, "row": 640, "key": 576,
-     "values": 512, "rect": (8, 128), "sub_blocks": (16,),
-     "key_tiles": (256, 128)},
+     "hist": (64, 320), "heads": 32, "row": 640, "key": 576, "values": 512},
+    {"name": "latent-8x128", "rows": 8, "t": 128, "hist": (64, 320),
+     "heads": 32, "row": 640, "key": 576, "values": 512},
 ]
 PREFILL_TIMING_CALLS = 64
 PREFILL_MAX_ABS_ERR = 2e-2     # bf16 outputs of unit-variance values
 
 
 def prefill_child(rehearse: bool) -> int:
-    """``--prefill``: the Pallas flash prefill kernels
-    (ops/pallas/paged_attention.py:paged_flash_prefill over K/V rows,
-    paged_flash_prefill_latent over latent rows) alone on the chip at the
-    benchmark's prefill shapes (PREFILL_TIMING_SHAPES,
-    LATENT_PREFILL_TIMING_SHAPES), checked once against ``window_attention``
-    over the gathered history, then timed (calls chained through the queries
-    inside one program over the layers of one pool) against the larger of
-    the least times its bytes and its FLOPs allow (what a call must do: each
-    valid query against its row's history and the chunk's keys up to itself;
-    the history's rows once, the chunk's operands and the output once), with
-    ``window_attention`` over a pre-gathered window of the PARENT's width
-    beside it (its gather, once a dispatch for every layer, is timed apart).
-    Run by no benchmark cell."""
+    """``--prefill``: the Pallas flash prefill kernels alone on the chip at
+    the benchmark's prefill shapes (PREFILL_TIMING_SHAPES: over K/V rows and
+    over latent rows, a packed row through ``paged_flash_prefill_packed`` /
+    ``_packed_latent`` and a rectangle through ``paged_flash_prefill``, the
+    K/V rectangle kernel, and ``paged_flash_prefill_latent``, which lays it
+    as a row of the packed body), checked once against
+    ``window_attention`` over the gathered history of the sequences taken
+    apart, a row each, then timed: calls chained through the queries inside
+    one program over the layers of one pool, every operand an argument
+    behind an ``optimization_barrier`` (``chained_chunks``'s lesson: what
+    does not change between calls is otherwise lifted out of the loop),
+    against the larger of the least times its bytes and its FLOPs allow
+    (what a call must do: each valid query against its sequence's history
+    and the chunk's keys up to itself; the history's rows once, the chunk's
+    operands and the output once). Run by no benchmark cell."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from production_stack_tpu.ops.attention import (gather_window,
+                                                    unpack_segments,
                                                     window_attention)
-    from production_stack_tpu.ops.pallas.paged_attention import (
-        paged_flash_prefill,
-        paged_flash_prefill_latent,
-    )
+    from production_stack_tpu.ops.pallas import paged_attention as pa
 
     dev = jax.devices()[0]
     device = {"platform": dev.platform, "kind": dev.device_kind}
@@ -1575,325 +1545,158 @@ def prefill_child(rehearse: bool) -> int:
     calls = 2 if rehearse else PREFILL_TIMING_CALLS
 
     timing, checks, ok = [], [], True
-    for shape in PREFILL_TIMING_SHAPES + LATENT_PREFILL_TIMING_SHAPES:
-        latent = "row" in shape
+    for shape in PREFILL_TIMING_SHAPES:
+        latent, packed = "row" in shape, "segments" in shape
         if rehearse:
-            shape = {**shape, "rows": min(shape["rows"], 2), "t": 32,
-                     "hist": (5, 40), "hist_unit": 1, "chunk": (7, 32),
+            shape = {**shape, "t": 64 if packed else 32, "hist": (0, 40),
                      "heads": 16 if latent else 4, "kv_heads": 2,
-                     "window": 64, "row": 256, "key": 192, "values": 128}
+                     "row": 256, "key": 192, "values": 128,
+                     **({"segments": min(shape["segments"], 3)} if packed
+                        else {"rows": min(shape["rows"], 3)})}
         rng = np.random.default_rng(len(timing))
-        b, t, h = (shape[k] for k in ("rows", "t", "heads"))
-        hist = rng.integers(shape["hist"][0], shape["hist"][1] + 1, b) \
-            * shape.get("hist_unit", 1)
-        clen = rng.integers(shape["chunk"][0], shape["chunk"][1] + 1, b)
-        if b > 2:
-            clen[-1] = 0            # a padded row of the rectangle
-            hist[-1] = 0
-        mb = shape["window"] // bs
-        live = -(-(hist + clen) // bs)
-        tables = np.zeros((b, mb), np.int32)
+        t, h = shape["t"], shape["heads"]
+        n = shape["segments"] if packed else shape["rows"]
+        if packed:
+            # Lengths that fill the row: an equal cut of t into n segments,
+            # or a random one.
+            cuts = np.arange(1, n) * (t // n) if shape.get("equal") else \
+                np.sort(rng.choice(np.arange(1, t), n - 1, replace=False))
+            lens = np.diff(np.concatenate([[0], cuts, [t]]))
+        else:
+            lens = rng.integers(t * 3 // 4, t + 1, n)
+            if n > 2:
+                lens[-1] = 0        # a padded row of the rectangle
+        hist = rng.integers(shape["hist"][0], shape["hist"][1] + 1, n) \
+            * (lens > 0)
+        live = -(-(hist + lens) // bs) * (lens > 0)
+        tables = np.zeros((n, int(max(live)) + 4), np.int32)
         order = 1 + rng.permutation(int(live.sum()))
         at = 0
-        for i in range(b):
+        for i in range(n):
             tables[i, :live[i]] = order[at:at + live[i]]
             at += live[i]
-        keys = jax.random.split(jax.random.PRNGKey(b), 5)
         slots = (1 + int(live.sum())) * bs
+        keys = jax.random.split(jax.random.PRNGKey(n), 5)
         tables = jnp.asarray(tables)
+        seg_lens = jnp.asarray(lens, jnp.int32)
         kv_lens = jnp.asarray(hist, jnp.int32)
-        chunk_lens = jnp.asarray(clen, jnp.int32)
-        positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
-        # What a call must do: a valid query i of a row against its history
-        # and chunk keys 0..i, two products a (query, key, head).
-        pairs = int(np.sum(clen * hist + clen * (clen + 1) // 2))
+        # What a call must do: a valid query i of a sequence against its
+        # history and chunk keys 0..i, two products a (query, key, head).
+        pairs = int(np.sum(lens * hist + lens * (lens + 1) // 2))
+        lead = (1, t) if packed else (n, t)
 
         def normal(key, *dims):
             return jax.random.normal(key, dims, jnp.bfloat16)
 
-        # The pools and the window are ARGUMENTS of the timed programs: a
-        # closed-over array is a constant of the program, and the hybrid's
-        # window (1 GB each of K and V) as a constant took the host's
-        # 40 GiB in the compiler (my chip run, PR 35).
+        # ``own``: the chunk's operands beside q (K and V, or the latent
+        # rows); they and the pools are ARGUMENTS of the timed programs (a
+        # closed-over array is a constant of the program: the hybrid's
+        # window as one took the host's 40 GiB in the compiler, PR 35).
         if latent:
             w, dv = shape["row"], shape["values"]
             scale = 192 ** -0.5    # 128 + 64 lanes a head before absorption
-            q = normal(keys[0], b, t, h, w)
-            rows = normal(keys[1], b, t, 1, w)
+            q, own = normal(keys[0], *lead, h, w), (
+                normal(keys[1], *lead, 1, w),)
             pools = (normal(keys[3], layers, 1, slots, w),)
+            entry = pa.paged_flash_prefill_packed_latent if packed \
+                else pa.paged_flash_prefill_latent
 
-            def kernel(q, layer, pool):
-                return paged_flash_prefill_latent(
-                    q, rows, positions, chunk_lens, pool, tables, kv_lens,
-                    layer, block_size=bs, value_dim=dv, scale=scale,
-                    interpret=interpret)
-
-            gather = jax.jit(lambda pool: gather_window(
-                pool, pool[..., :0], tables, bs)[:1])
+            def kernel(q, layer, rows, seg_lens, tables, kv_lens, pool):
+                return entry(q, rows, seg_lens, pool, tables, kv_lens, layer,
+                             block_size=bs, value_dim=dv, scale=scale,
+                             interpret=interpret)
 
             # As ops/attention.py:_attend_latent calls it over a window.
-            def window(q, layer, win):
-                win = jax.lax.dynamic_index_in_dim(win, layer, 0, False)
+            def window(q, rows, positions, seg_lens, kv_lens, wins):
                 return window_attention(
-                    q, rows, rows, positions, chunk_lens, win, win, kv_lens,
-                    scale=scale, qblock=max(16, 2048 // h))[..., :dv]
+                    q, rows, rows, positions, seg_lens, wins[0][1],
+                    wins[0][1], kv_lens, scale=scale,
+                    qblock=max(16, 2048 // h))[..., :dv]
 
+            gather = lambda pool: gather_window(   # noqa: E731
+                pool, pool[..., :0], tables, bs)[:1]
             # Scores over the key's lanes, values over theirs; a pool row
             # is read whole, padding included.
             flops = 2 * pairs * h * (shape["key"] + dv)
             nbytes = 2 * (int(hist.sum()) * w
-                          + int(clen.sum()) * (h * w + w + h * dv))
+                          + int(lens.sum()) * (h * w + w + h * dv))
         else:
-            hkv = shape["kv_heads"]
-            q = normal(keys[0], b, t, h, dh)
-            k = normal(keys[1], b, t, hkv, dh)
-            v = normal(keys[2], b, t, hkv, dh)
+            hkv, dv = shape["kv_heads"], dh
+            q, own = normal(keys[0], *lead, h, dh), (
+                normal(keys[1], *lead, hkv, dh),
+                normal(keys[2], *lead, hkv, dh))
             pools = (normal(keys[3], layers, hkv, slots, dh),
                      normal(keys[4], layers, hkv, slots, dh))
+            entry = pa.paged_flash_prefill_packed if packed \
+                else pa.paged_flash_prefill
 
-            def kernel(q, layer, k_pool, v_pool):
-                return paged_flash_prefill(
-                    q, k, v, positions, chunk_lens, k_pool, v_pool, tables,
-                    kv_lens, layer, block_size=bs, interpret=interpret)
+            def kernel(q, layer, k, v, seg_lens, tables, kv_lens, *pools):
+                # The rectangle kernel takes its rows' positions.
+                where = () if packed else (kv_lens[:, None] + jnp.arange(
+                    t, dtype=jnp.int32)[None],)
+                return entry(q, k, v, *where, seg_lens, *pools, tables,
+                             kv_lens, layer, block_size=bs,
+                             interpret=interpret)
 
-            gather = jax.jit(
-                lambda kp, vp: gather_window(kp, vp, tables, bs))
+            def window(q, k, v, positions, seg_lens, kv_lens, wins):
+                return window_attention(q, k, v, positions, seg_lens,
+                                        wins[0][1], wins[1][1], kv_lens)
 
-            def window(q, layer, win_k, win_v):
-                return window_attention(
-                    q, k, v, positions, chunk_lens,
-                    jax.lax.dynamic_index_in_dim(win_k, layer, 0, False),
-                    jax.lax.dynamic_index_in_dim(win_v, layer, 0, False),
-                    kv_lens)
-
+            gather = lambda kp, vp: gather_window(  # noqa: E731
+                kp, vp, tables, bs)
             flops = 4 * pairs * h * dh
             nbytes = 2 * (int(hist.sum()) * hkv * dh * 2
-                          + int(clen.sum()) * (2 * h + 2 * hkv) * dh)
-        wins = gather(*pools)
+                          + int(lens.sum()) * (2 * h + 2 * hkv) * dh)
+        held = (*own, seg_lens, tables, kv_lens, *pools)
 
-        got = jax.jit(kernel)(q, 1, *pools).astype(jnp.float32)
-        want = jax.jit(window)(q, 1, *wins).astype(jnp.float32)
-        valid = (jnp.arange(t)[None] < chunk_lens[:, None])[..., None, None]
-        err = float(jnp.max(jnp.where(valid, jnp.abs(got - want), 0.0)))
+        # The check: the sequences taken apart, a row each of t tokens.
+        got = jax.jit(kernel)(q, 1, *held).astype(jnp.float32)
+        apart = (lambda x: x[0][unpack_segments(seg_lens, t)[0]]) if packed \
+            else (lambda x: x)
+        positions = kv_lens[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        want = jax.jit(window)(
+            apart(q), *(apart(x) for x in own), positions, seg_lens, kv_lens,
+            gather(*pools)).astype(jnp.float32)
+        valid = (jnp.arange(t)[None] < seg_lens[:, None])[..., None, None]
+        err = float(jnp.max(jnp.where(valid, jnp.abs(apart(got) - want),
+                                      0.0)))
         finite = bool(jnp.all(jnp.isfinite(got)))
         checks.append({"shape": shape["name"], "max_abs_err": err,
                        "bound": PREFILL_MAX_ABS_ERR, "finite": finite})
         ok = ok and finite and err <= PREFILL_MAX_ABS_ERR
 
-        def chained(fn):
-            def run(q, *held):
-                return jax.lax.fori_loop(
-                    0, calls, lambda i, x: fn(x, i % layers, *held), q)
-            return jax.jit(run)
+        def fed_back(x, *a):
+            # A call's output goes back into the queries (a latent call's is
+            # narrower: one token's, in place).
+            out = kernel(x, *a)
+            return x.at[:1, :1, :, :dv].set(out[:1, :1]) if latent else out
 
-        entry = {"shape": shape["name"], "rows": b, "t": t,
-                 "history": [int(x) for x in hist],
-                 "chunk_lens": [int(x) for x in clen],
-                 "flops": flops, "bytes": nbytes, "us_per_call": None,
-                 "least_us": None, "roofline_pct": None,
-                 "window_attention_us": None, "window_keys": mb * bs,
-                 "window_gather_us_per_layer": None}
-        if latent:
-            # The output is narrower than the queries: a chained call
-            # feeds one token's back into them, in place.
-            dv = shape["values"]
-            chain = lambda fn: chained(      # noqa: E731
-                lambda x, *a: x.at[:1, :1, :, :dv].set(fn(x, *a)[:1, :1]))
-        else:
-            chain = chained
-        sec = best_of(chain(kernel), (q, *pools), calls)
-        sec_win = best_of(chain(window), (q, *wins), calls)
-        sec_gather = best_of(gather, pools, layers)
+        def chained(x, *held):
+            def one(i, x):
+                x, args = jax.lax.optimization_barrier((x, held))
+                return fed_back(x, i % layers, *args)
+            return jax.lax.fori_loop(0, calls, one, x)
+
+        sec = best_of(jax.jit(chained), (q, *held), calls)
+        line = {"shape": shape["name"],
+                "form": "packed" if packed else "rectangle",
+                "rows": lead[0], "t": t, "seg_lens": [int(x) for x in lens],
+                "history": [int(x) for x in hist], "flops": flops,
+                "bytes": nbytes, "us_per_call": None, "least_us": None,
+                "roofline_pct": None}
         if peak and not rehearse:
             least = max(nbytes / (peak["hbm_gbps"] * 1e9),
                         flops / (peak["bf16_tflops"] * 1e12))
-            entry.update(us_per_call=sec * 1e6, least_us=least * 1e6,
-                         roofline_pct=100.0 * least / sec,
-                         window_attention_us=sec_win * 1e6,
-                         window_gather_us_per_layer=sec_gather * 1e6)
-        timing.append(entry)
+            line.update(us_per_call=sec * 1e6, least_us=least * 1e6,
+                        roofline_pct=100.0 * least / sec)
+        timing.append(line)
         # As it goes, beside the phase's one line at the end: a later
         # shape that dies keeps the earlier ones' numbers.
-        print(json.dumps(entry), file=sys.stderr, flush=True)
-    packed = []
-    for shape in PACKED_PREFILL_TIMING_SHAPES:
-        if rehearse:
-            shape = {**shape, "t": 64, "segments": min(shape["segments"], 3),
-                     "slots": 4, "hist": (0, 40), "heads": 4, "kv_heads": 2,
-                     "rect": (min(shape["rect"][0], 4), 32),
-                     "sub_blocks": (16, 32)}
-            if "row" in shape:
-                shape.update(heads=16, row=256, key=192, values=128,
-                             key_tiles=(32, 64))
-        entry, err = _time_packed_prefill(shape, calls, layers, interpret)
-        checks.append({"shape": shape["name"], "max_abs_err": err,
-                       "bound": PREFILL_MAX_ABS_ERR,
-                       "finite": err != float("inf")})
-        ok = ok and err <= PREFILL_MAX_ABS_ERR
-        packed.append(entry)
-        print(json.dumps(entry), file=sys.stderr, flush=True)
+        print(json.dumps(line), file=sys.stderr, flush=True)
     emit({"phase": "prefill", "interpret": interpret, "checks": checks,
-          "timing": timing, "packed": packed, "peak": peak,
-          "device": device, "ok": ok and (rehearse or not interpret)})
+          "timing": timing, "peak": peak, "device": device,
+          "ok": ok and (rehearse or not interpret)})
     return 0 if ok else 1
-
-
-def _time_packed_prefill(shape, calls, layers, interpret):
-    """One of PACKED_PREFILL_TIMING_SHAPES: the packed kernel over a row of
-    ``segments`` segments that fill ``t`` tokens, checked against the
-    rectangle kernel over the row taken apart (a row a segment), then both
-    timed: the packed row, and the ``rect`` rectangle of the segments'
-    first tokens, every operand an argument behind an
-    ``optimization_barrier`` (``chained_chunks``'s lesson: what does not
-    change between calls is otherwise lifted out of the loop). Returns (the
-    entry, the largest difference over the segments' tokens)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from production_stack_tpu.ops.attention import unpack_segments
-    from production_stack_tpu.ops.pallas.paged_attention import (
-        paged_flash_prefill,
-        paged_flash_prefill_latent,
-        paged_flash_prefill_packed,
-        paged_flash_prefill_packed_latent,
-    )
-
-    dh, bs = 128, 16
-    rng = np.random.default_rng(46)
-    latent = "row" in shape
-    t, n, slots_n, h = (shape[k] for k in (
-        "t", "segments", "slots", "heads"))
-    # Lengths that fill the row: a random cut of t into n segments (or an
-    # equal one).
-    cuts = np.sort(rng.choice(np.arange(1, t), n - 1, replace=False)) \
-        if n > 1 else np.zeros((0,), np.int64)
-    if shape.get("equal"):
-        cuts = np.arange(1, n) * (t // n)
-    lens = np.zeros((slots_n,), np.int64)
-    lens[:n] = np.diff(np.concatenate([[0], cuts, [t]]))
-    hist = np.zeros((slots_n,), np.int64)
-    hist[:n] = rng.integers(shape["hist"][0], shape["hist"][1] + 1, n)
-    live = -(-(hist + lens) // bs) * (lens > 0)
-    mb = int(max(live)) + 4
-    tables = np.zeros((slots_n, mb), np.int32)
-    order = 1 + rng.permutation(int(live.sum()))
-    at = 0
-    for i in range(slots_n):
-        tables[i, :live[i]] = order[at:at + live[i]]
-        at += live[i]
-    pool_slots = (1 + int(live.sum())) * bs
-    keys = jax.random.split(jax.random.PRNGKey(46), 5)
-
-    def normal(key, *dims):
-        return jax.random.normal(key, dims, jnp.bfloat16)
-
-    tables = jnp.asarray(tables)
-    seg_lens = jnp.asarray(lens, jnp.int32)
-    kv_lens = jnp.asarray(hist, jnp.int32)
-    # ``own``: the row's operands beside q (K and V, or the latent rows).
-    if latent:
-        w, dv = shape["row"], shape["values"]
-        scale = 192 ** -0.5
-        q, own = normal(keys[0], 1, t, h, w), (normal(keys[1], 1, t, 1, w),)
-        pools = (normal(keys[3], layers, 1, pool_slots, w),)
-
-        def packed_call(sub_block, key_tile=None):
-            def call(q, layer, rows, seg_lens, tables, kv_lens, pool):
-                return paged_flash_prefill_packed_latent(
-                    q, rows, seg_lens, pool, tables, kv_lens, layer,
-                    block_size=bs, value_dim=dv, scale=scale,
-                    interpret=interpret, sub_block=sub_block,
-                    key_tile=key_tile)
-            return call
-
-        def rectangle(q, layer, rows, chunk_lens, tables, kv_lens, pool):
-            positions = kv_lens[:, None] + jnp.arange(
-                q.shape[1], dtype=jnp.int32)[None]
-            return paged_flash_prefill_latent(
-                q, rows, positions, chunk_lens, pool, tables, kv_lens,
-                layer, block_size=bs, value_dim=dv, scale=scale,
-                interpret=interpret)
-
-        # The output is narrower than the queries: a chained call feeds
-        # one token's back into them, in place (``prefill_child``'s way).
-        def fed_back(fn):
-            return lambda x, *a: x.at[:1, :1, :, :dv].set(fn(x, *a)[:1, :1])
-    else:
-        hkv = shape["kv_heads"]
-        q, own = normal(keys[0], 1, t, h, dh), (
-            normal(keys[1], 1, t, hkv, dh), normal(keys[2], 1, t, hkv, dh))
-        pools = (normal(keys[3], layers, hkv, pool_slots, dh),
-                 normal(keys[4], layers, hkv, pool_slots, dh))
-
-        def packed_call(sub_block, key_tile=None):
-            def call(q, layer, k, v, seg_lens, tables, kv_lens, *pools):
-                return paged_flash_prefill_packed(
-                    q, k, v, seg_lens, *pools, tables, kv_lens, layer,
-                    block_size=bs, interpret=interpret, sub_block=sub_block)
-            return call
-
-        def rectangle(q, layer, k, v, chunk_lens, tables, kv_lens, *pools):
-            positions = kv_lens[:, None] + jnp.arange(
-                q.shape[1], dtype=jnp.int32)[None]
-            return paged_flash_prefill(
-                q, k, v, positions, chunk_lens, *pools, tables, kv_lens,
-                layer, block_size=bs, interpret=interpret)
-
-        def fed_back(fn):
-            return fn
-
-    # The row taken apart, a row a segment of t tokens: the check.
-    rows, put_back = unpack_segments(seg_lens, t)
-    scalars = (seg_lens, tables, kv_lens, *pools)
-    held = (*own, *scalars)
-    got = jax.jit(packed_call(None))(q, 1, *held).astype(jnp.float32)
-    apart = jax.jit(rectangle)(
-        q[0][rows], 1, *(x[0][rows] for x in own), *scalars)
-    want = put_back(apart.astype(jnp.float32))[None]
-    err = float(jnp.max(jnp.abs(got - want))) \
-        if bool(jnp.all(jnp.isfinite(got))) else float("inf")
-
-    def chained(fn):
-        fn = fed_back(fn)
-
-        def run(x, *held):
-            def one(i, x):
-                x, args = jax.lax.optimization_barrier((x, held))
-                return fn(x, i % layers, *args)
-            return jax.lax.fori_loop(0, calls, one, x)
-        return jax.jit(run)
-
-    # The rectangle the same sequences would have run as: their first
-    # ``rect`` tokens each, a row a sequence.
-    rb, rt = shape["rect"]
-    rect_lens = jnp.minimum(seg_lens[:rb], rt)
-    rect_held = (*(x[0][rows[:rb, :rt]] for x in own), rect_lens,
-                 tables[:rb], kv_lens[:rb], *pools)
-    sec_rect = best_of(chained(rectangle),
-                       (q[0][rows[:rb, :rt]], *rect_held), calls)
-    entry = {
-        "shape": shape["name"], "t": t, "segments": n,
-        "seg_lens": [int(x) for x in lens[:n]],
-        "history": [int(x) for x in hist[:n]],
-        "rectangle": [rb, rt], "rectangle_tokens": int(rect_lens.sum()),
-        "rectangle_us": None, "packed_us": {}}
-    for sb in shape["sub_blocks"]:
-        sec = best_of(chained(packed_call(sb)), (q, *held), calls)
-        if not interpret:
-            entry["packed_us"][str(sb)] = sec * 1e6
-    for tk in shape.get("key_tiles", ())[1:]:
-        sec = best_of(chained(packed_call(None, tk)), (q, *held), calls)
-        if not interpret:
-            entry.setdefault("packed_us_by_key_tile", {})[str(tk)] = \
-                sec * 1e6
-    if not interpret:
-        entry["rectangle_us"] = sec_rect * 1e6
-        first = entry["packed_us"][str(shape["sub_blocks"][0])]
-        entry["packed_us_per_token"] = first / t
-        entry["rectangle_us_per_token"] = \
-            sec_rect * 1e6 / max(1, entry["rectangle_tokens"])
-    return entry, err
 
 
 def phase_serve(model, engine_args, attn: str) -> dict:

@@ -1120,8 +1120,9 @@ class ModelRunner:
         """Enter what is resident into ``self.memory`` (the engine calls
         this once, when ``start()`` ends and nothing is in flight): the
         named holders from the arrays the runner holds, the 16 largest
-        groups of live arrays outside them, and ``other`` from the
-        allocator's own count. Lowers and compiles nothing."""
+        groups of live arrays outside them ON EACH DEVICE of the mesh, and
+        ``other`` from the allocator's own count. Lowers and compiles
+        nothing."""
         named = self.resident_arrays()
         mine = {id(x) for arrays in named.values() for x in arrays}
         labels = self.device_labels()
@@ -1138,8 +1139,9 @@ class ModelRunner:
                      "dtype": str(array.dtype), "bytes": 0, "count": 0})
                 group["bytes"] += nbytes
                 group["count"] += 1
-        largest = sorted(groups.values(),
-                         key=lambda g: -g["bytes"])[:16 * len(labels)]
+        largest = [g for label in labels for g in sorted(
+            (g for g in groups.values() if g["device"] == label),
+            key=lambda g: -g["bytes"])[:16]]
         self.memory.build(
             self.resident_bytes(), largest,
             # Replicated: a device holds each pool whole.

@@ -161,7 +161,8 @@ def kv_pack(cfg: ModelConfig) -> int:
     128 lanes (``_attention_layer`` pairs them): a pool whose minor axis is
     64 the compiler for a v5e keeps slots-minor and copies whole into every
     dispatch, and the decode kernel's two-tokens-a-row view of it is a
-    reshape of the whole pool a layer a step (tests/test_chip_compile.py)."""
+    reshape of the whole pool a layer a step
+    (tests/test_chip_compile_recurrent.py)."""
     dh = cfg.head_dim_
     pack = 128 // dh if dh < 128 and 128 % dh == 0 else 1
     return pack if cfg.num_kv_heads % pack == 0 else 1

@@ -754,15 +754,15 @@ def prefill_kernel_covers(
     (``supports_pallas_prefill``), or ``latent`` rows: one pool of one row
     a token, ``head_dim`` its width, the values its first ``value_dim``
     lanes (``supports_latent_prefill``). ``packed``: the chunk is one row
-    of several sequences' segments (``KVView.seg_lens``), for which either
-    kernel has a form (``supports_packed_prefill``,
-    ``supports_packed_latent_prefill``: the same body). Not covered
-    by choice, though the decode kernels cover them: an int8 pool (its
+    of several sequences' segments (``KVView.seg_lens``), which over K/V
+    rows is a kernel of its own (``supports_packed_prefill``); over latent
+    rows ONE body runs both forms since PR 56 (a rectangle is laid as a row
+    whose segments begin at multiples of ``t``), so one predicate answers
+    for both. Not covered by choice, though the decode kernels cover them: an int8 pool (its
     scales would ride as the decode kernel's do) and a kv-head-sharded
     pool; no benchmark cell runs either."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         supports_latent_prefill,
-        supports_packed_latent_prefill,
         supports_packed_prefill,
         supports_pallas_prefill,
     )
@@ -772,9 +772,7 @@ def prefill_kernel_covers(
         return False
     itemsize = kinds.pop().itemsize
     if latent:
-        supports = supports_packed_latent_prefill if packed \
-            else supports_latent_prefill
-        return num_kv_heads == 1 and supports(
+        return num_kv_heads == 1 and supports_latent_prefill(
             t, num_heads, head_dim, value_dim, itemsize, block_size)
     supports = supports_packed_prefill if packed else supports_pallas_prefill
     return value_dim == head_dim and supports(
@@ -983,10 +981,11 @@ def _attend_latent_chunk_over_pool(q, rows, positions, chunk_lens, view,
                                    layer, scale, value_dim):
     """``_attend_chunk_over_pool`` over ONE pool of latent rows: the same
     algorithm and two executions (ops/pallas/paged_attention.py:
-    paged_flash_prefill_latent, or this layer's rows gathered and
-    ``window_attention``), the same pair again for a PACKED row
-    (``paged_flash_prefill_packed_latent``, or the row taken apart), the
-    same raise at trace time on a pool view the kernel does not cover."""
+    paged_flash_prefill_latent, the rectangle laid as a row of
+    ``paged_flash_prefill_packed_latent``; or this layer's rows gathered
+    and ``window_attention``), the same pair again for a PACKED row (the
+    kernel as it is, or the row taken apart), the same raise at trace time
+    on a pool view the kernel does not cover."""
     from production_stack_tpu.ops.pallas.paged_attention import (
         paged_flash_prefill_latent,
         paged_flash_prefill_packed_latent,
@@ -1017,9 +1016,11 @@ def _attend_latent_chunk_over_pool(q, rows, positions, chunk_lens, view,
         return _latent_window_attention(
             q, rows, positions, chunk_lens, win, kv_lens, scale, value_dim)
 
-    def kernel(*args, interpret=False):
+    def kernel(q, rows, positions, *args, interpret=False):
+        # Token i of a row sits at position kv_lens[row] + i: the kernel
+        # takes no positions.
         return paged_flash_prefill_latent(
-            *args, block_size=bs, value_dim=value_dim, scale=scale,
+            q, rows, *args, block_size=bs, value_dim=value_dim, scale=scale,
             interpret=interpret)
 
     if packed:

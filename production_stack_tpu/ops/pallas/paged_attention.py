@@ -1,6 +1,6 @@
 """Pallas TPU flash kernels over the paged KV pool: decode (below) and
-prefill (the sections behind it: a rectangle of rows, and ONE packed row of
-several sequences' segments), each over K/V rows and over latent rows.
+prefill (the sections behind it: a rectangle of K/V rows, and ONE row of
+several sequences' segments over K/V rows and over latent rows).
 
 The TPU-native replacement for the paged-attention CUDA kernels inside the
 reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
@@ -54,12 +54,14 @@ reference's external vLLM images (SURVEY.md §2.2 "vLLM engine"). Design:
 The decode kernels take T == 1: queries sit at position >= kv_len, so
 causality over the pool is exactly "attend to slots < kv_len" and no
 per-token causal mask is needed. A prefill chunk (T > 1) has kernels of its
-own on the same machinery, ``paged_flash_prefill`` over K/V rows and
-``paged_flash_prefill_latent`` over latent rows: the history from the pool up
-to the row's length, then the chunk causally; and their packed forms,
-``paged_flash_prefill_packed`` and ``paged_flash_prefill_packed_latent`` (one
-kernel body: the last two sections), for a row in which several sequences'
-chunks lie end to end.
+own on the same machinery: the history from the pool up to a sequence's
+length, then its chunk causally. ``paged_flash_prefill`` runs a RECTANGLE of
+K/V rows; ``paged_flash_prefill_packed`` and
+``paged_flash_prefill_packed_latent`` (one kernel body: the last two
+sections) a row in which several sequences' chunks lie. A rectangle of
+LATENT rows is such a row, its chunks beginning at multiples of T
+(``paged_flash_prefill_latent``); why the K/V rectangle kernel stays:
+ROADMAP D21.
 """
 
 import functools
@@ -98,7 +100,7 @@ def super_tokens(num_kv_heads: int, head_dim: int, itemsize: int,
 
 
 class _PageFetch:
-    """How pages of a row reach VMEM, stated once for the four kernels
+    """How pages of a row reach VMEM, stated once for the kernels
     below (trace time: built inside a kernel from its refs).
 
     A page is one copy a STREAM: ``streams`` lists ``(pool ref [L, heads,
@@ -248,14 +250,21 @@ def _superpage_sequence(fetch, cleared, b, kv_len, n_super, first,
     return advance
 
 
-def _one_more_prefetched(kernel, prefetched: int, keyword: str):
-    """``kernel`` with ONE more scalar-prefetch ref behind the ``prefetched``
-    it has, handed on as ``keyword``."""
-    def one_more(*refs):
-        kernel(*refs[:prefetched], *refs[prefetched + 1:],
-               **{keyword: refs[prefetched]})
+def _prefetched_behind(kernel, prefetched: int, **scalars):
+    """(``kernel`` taking every one of ``scalars`` that is given as one more
+    scalar-prefetch ref behind the ``prefetched`` it has, by its keyword;
+    the operands to pass there, in that order): the kernel as it is and
+    nothing where all are None."""
+    given = {name: x for name, x in scalars.items() if x is not None}
+    if not given:
+        return kernel, ()
+    upto = prefetched + len(given)
 
-    return one_more
+    def more(*refs):
+        kernel(*refs[:prefetched], *refs[upto:],
+               **dict(zip(given, refs[prefetched:upto])))
+
+    return more, tuple(given.values())
 
 
 def _decode_kernel(
@@ -465,13 +474,11 @@ def paged_flash_decode_stats(
     quantized = k_scale is not None
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
     sup = super_tokens(hkv, dh, k_pool.dtype.itemsize, block_size)
-    bound = ()
     if kv_lo is not None:
         assert not quantized, "a span over an int8 pool is refused at start"
         kv_lo = jnp.maximum(kv_lo.astype(jnp.int32), 0)
         # No visible key in the pool: an empty row (fetches nothing).
         kv_lens = jnp.where(kv_lo < kv_lens, kv_lens, 0)
-        bound = (kv_lo,)
 
     # Lane-pack the pool view: [L, Hkv, NS/PACK, Dh*PACK] (free reshape).
     kp = k_pool.reshape(l_, hkv, num_slots // pack, dh * pack)
@@ -516,8 +523,7 @@ def paged_flash_decode_stats(
         block_size=block_size, num_kv_heads=hkv, q_per_kv=g,
         scale=float(scale), quantized=quantized, super_tokens=sup,
     )
-    if kv_lo is not None:
-        kernel = _one_more_prefetched(kernel, 3, "lo_ref")
+    kernel, bound = _prefetched_behind(kernel, 3, lo_ref=kv_lo)
 
     def resident(*shape):
         # The whole array is one block that every program sees: copied in
@@ -854,11 +860,12 @@ def paged_flash_decode_latent_stats(
 # Scores, max, exp and sums are float32; both products take operands in the
 # pool's dtype with float32 accumulation; the output is q.dtype: what
 # ``ops/attention.py:window_attention`` computes, nothing lower. This
-# section's kernel reads K/V rows of two pools; latent rows (one pool, every
-# head the same row) have theirs in the next section, on the same tile
-# sequence, and ``ops/attention.py:prefill_kernel_covers`` says which views
-# either covers (neither: int8 scales, a sharded pool, a ring, a
-# ``chunk_bias``; those keep the gathered window).
+# section's kernel reads a RECTANGLE of K/V rows of two pools; a rectangle
+# of latent rows (one pool, every head the same row) is laid as a row of the
+# packed body (the last section), and
+# ``ops/attention.py:prefill_kernel_covers`` says which views a kernel
+# covers (none: int8 scales, a sharded pool, a ring, a ``chunk_bias``; those
+# keep the gathered window).
 QUERY_BLOCK = 256    # chunk queries a program, chunk keys a tile
 PREFILL_VMEM_BYTES = 64 << 20   # of a v5e's 128 MiB: buffers 8, q and o
                                 # blocks twice 8, flash state 13, scores 16
@@ -1008,14 +1015,10 @@ def _tile_sequence(fetch, chunk, chunk_lens_ref, fetched_ref, cleared, *,
     return n_hist, n_tiles, advance
 
 
-def _span_behind(kernel, prefetched: int, span):
-    """(``kernel`` taking the layer's span as one more scalar-prefetch ref
-    behind the ``prefetched`` it has, the operand to pass there): the
-    kernel as it is and nothing where ``span`` is None."""
-    if span is None:
-        return kernel, ()
-    return _one_more_prefetched(kernel, prefetched, "span_ref"), (
-        jnp.maximum(jnp.asarray(span, jnp.int32), 1).reshape(1),)
+def _span_operand(span):
+    """The layer's span as the kernels' scalar: [1] int32, at least 1."""
+    return None if span is None else jnp.maximum(
+        jnp.asarray(span, jnp.int32), 1).reshape(1)
 
 
 def _prefill_kernel(
@@ -1246,7 +1249,8 @@ def paged_flash_prefill(
         _prefill_kernel, block_size=block_size, super_tokens=sup, tq=tq,
         q_per_kv=g,
     )
-    kernel, bound = _span_behind(kernel, 4, span)
+    kernel, bound = _prefetched_behind(
+        kernel, 4, span_ref=_span_operand(span))
     q_block = pl.BlockSpec((1, hkv, 1, m, dh),
                            lambda i, j, *_: (i, 0, j, 0, 0),
                            memory_space=pltpu.VMEM)
@@ -1296,241 +1300,6 @@ def paged_flash_prefill(
     return out.reshape(b, t, h, dh)
 
 
-# ------------------------------------------------------ prefill, latent rows
-# The chunk's attention over a LATENT pool (see "latent rows" above): the
-# prefill kernel with ONE pool and one superpage buffer a tile, every head
-# attending the same rows, the values the first ``value_dim`` lanes of the
-# buffer (a slice in VMEM, free, as in ``_latent_decode_kernel``). Absorbed
-# latent attention is multi-query attention, so what differs from the K/V
-# kernel follows from there being one KV head of a wide row:
-#
-#   * A program holds TQ queries of one row for ALL heads as ONE matmul
-#     operand, M = H x TQ rows (``prefill_tiles`` at Hkv 1 and the row's
-#     width: 32 queries of 32 heads at 640 lanes). No loop over heads, and
-#     q and the output keep their own layout [B, T, H, .]: a block's
-#     [TQ, H, W] is [TQ * H, W] with no data moved where the heads fill
-#     whole sublane tiles (``supports_latent_prefill``), so no transpose of
-#     either crosses HBM.
-#   * The chunk's key tiles are ``latent_chunk_tile`` keys wide, not TQ: at
-#     TQ 32 a tile of TQ keys would pay the flash state's round trip (M x
-#     value_dim float32) for 32 keys. A query block's tiles are its row's
-#     history superpages, then the chunk's tiles up to the one that holds
-#     its last query, each ONE copy out of the chunk's rows [1, B, T, W];
-#     tiles wholly above the diagonal are never fetched.
-#   * The buffers are keys AND values: cleared once a call, whole.
-
-
-def latent_chunk_tile(t: int, super_tokens: int) -> int:
-    """Keys a chunk tile of the latent prefill kernel holds: the chunk
-    whole, up to a superpage."""
-    return min(t, super_tokens)
-
-
-def supports_latent_prefill(t: int, num_heads: int, width: int,
-                            value_dim: int, itemsize: int,
-                            block_size: int) -> bool:
-    """What ``supports_pallas_prefill`` asks at one KV head of ``width``
-    lanes, values that are whole lanes of the row, heads that fill whole
-    sublane tiles of the dtype (a block's [TQ, H, W] is then [TQ * H, W] as
-    it lies) and a chunk of whole key tiles."""
-    if value_dim % LANES or value_dim > width \
-            or num_heads % (32 // itemsize) \
-            or not supports_pallas_prefill(t, num_heads, 1, width, itemsize,
-                                           block_size):
-        return False
-    sup, _ = prefill_tiles(t, num_heads, 1, width, itemsize, block_size)
-    return t % latent_chunk_tile(t, sup) == 0
-
-
-def _latent_prefill_kernel(
-    # scalar prefetch
-    layer_ref,          # SMEM [1] int32
-    block_tables_ref,   # SMEM [B, Mb] int32
-    kv_lens_ref,        # SMEM [B] int32: tokens of the row in the pool
-    chunk_lens_ref,     # SMEM [B] int32: valid tokens of the row's chunk
-    # inputs
-    q_ref,              # VMEM [1, TQ, H, W] (pre-scaled; zeros past the key)
-    posq_ref,           # VMEM [1, 1, TQ, 1] int32: the block's positions
-    posk_ref,           # VMEM [1, NK, 1, TK] int32: the row's, as rows
-    rows_hbm,           # HBM  [1, B, T, W]: the chunk's rows
-    kv_hbm,             # HBM  [L, 1, num_slots, W]
-    # output
-    o_ref,              # VMEM [1, TQ, H, Dv]
-    # scratch (outlives a program: the buffers are handed on)
-    kv_buf,             # VMEM [NUM_BUFS, 1, super_tokens, W]
-    sem,                # DMA sems (NUM_BUFS,)
-    fetched_ref,        # SMEM [1] int32: tiles the programs before fetched
-    m_ref,              # VMEM [H*TQ, 1] f32: running max
-    l_ref,              # VMEM [H*TQ, 1] f32: running sum
-    acc_ref,            # VMEM [H*TQ, Dv] f32
-    *,
-    block_size: int,
-    super_tokens: int,
-    tk: int,
-):
-    b, qb = pl.program_id(0), pl.program_id(1)
-    num_rows, nq = pl.num_programs(0), pl.num_programs(1)
-    layer = layer_ref[0]
-    bs, sup = block_size, super_tokens
-    _, tq, h, w = q_ref.shape
-    dv = o_ref.shape[-1]
-    kv_len = kv_lens_ref[b]
-    chunk_len = chunk_lens_ref[b]
-    fetch = _PageFetch(
-        [(kv_hbm, kv_buf, sem)], bs, block_size=bs, super_tokens=sup,
-        layer=layer, block_tables_ref=block_tables_ref,
-        kv_lens_ref=kv_lens_ref)
-    # The chunk's key tiles up to the one that holds the block's last
-    # query, TK keys each; the buffers are keys AND values, cleared whole.
-    n_hist, n_tiles, advance = _tile_sequence(
-        fetch, (rows_hbm,), chunk_lens_ref, fetched_ref, kv_buf,
-        program=(b, qb), programs=(num_rows, nq), tq=tq, tile_rows=tk,
-        tiles=lambda row, hist, blk: hist + pl.cdiv((blk + 1) * tq, tk))
-
-    def flash_block(rows, mask):
-        # One tile's rows [keys, W] against every head's queries at once.
-        q = q_ref[0].reshape(tq * h, w)                      # [M, W]
-        scores = jax.lax.dot_general(
-            q, rows, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                    # [M, keys]
-        scores = jnp.where(mask, scores, _MASKED)
-        m_prev = m_ref[...]                                  # [M, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(rows.dtype), rows[:, :dv],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
-
-    def tile(s, carry):
-        slot = advance(s)
-
-        @pl.when(s < n_hist)
-        def _():
-            # History: every key below kv_len is before every query.
-            pos = s * sup + jax.lax.broadcasted_iota(jnp.int32, (1, sup), 1)
-            flash_block(kv_buf[slot, 0], pos < kv_len)
-
-        @pl.when(s >= n_hist)
-        def _():
-            # The chunk's key tile c, masked as window_attention masks it:
-            # key position <= query position, key index < chunk_len. A
-            # block's rows are (query, head), query-major.
-            c = s - n_hist
-            pos_q = jnp.broadcast_to(
-                posq_ref[0, 0][:, None], (tq, h, 1)).reshape(tq * h, 1)
-            idx = c * tk + jax.lax.broadcasted_iota(jnp.int32, (1, tk), 1)
-            flash_block(kv_buf[slot, 0, pl.ds(0, tk), :],
-                        (posk_ref[0, c] <= pos_q) & (idx < chunk_len))
-
-        return carry
-
-    @pl.when(n_tiles > 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-        jax.lax.fori_loop(0, n_tiles, tile, 0)
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = out.reshape(tq, h, dv).astype(o_ref.dtype)
-
-    @pl.when(n_tiles == 0)
-    def _():
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_size", "value_dim", "scale", "interpret"),
-)
-def paged_flash_prefill_latent(
-    q: jax.Array,             # [B, T, H, W] absorbed queries, zeros past the key
-    rows: jax.Array,          # [B, T, 1, W] the chunk's latent rows
-    positions: jax.Array,     # [B, T] int32 absolute position per token
-    chunk_lens: jax.Array,    # [B] int32 valid tokens per row
-    kv_pool: jax.Array,       # [L, 1, num_slots, W] latent rows
-    block_tables: jax.Array,  # [B, Mb] int32
-    kv_lens: jax.Array,       # [B] int32: the row's tokens in the pool
-    layer_idx: jax.Array,     # [] or [1] int32
-    *,
-    block_size: int,
-    value_dim: int,
-    scale: float,
-    interpret: bool = False,
-) -> jax.Array:
-    """``paged_flash_prefill`` over a latent pool: causal attention of a
-    chunk over its rows' history (pool slots below ``kv_lens``, read in
-    place) and over itself, every head against the same rows, keys the whole
-    row, values its first ``value_dim`` lanes: [B, T, H, value_dim] in
-    q.dtype, equal to ``window_attention`` over the gathered rows. What
-    ``paged_flash_prefill`` asks of positions, padded blocks and block
-    tables holds here; the pool must be finite wherever a live row's pages
-    reach, padding lanes included. See the section comment and
-    ``supports_latent_prefill``."""
-    b, t, h, w = q.shape
-    sup, tq = prefill_tiles(t, h, 1, w, kv_pool.dtype.itemsize, block_size)
-    tk = latent_chunk_tile(t, sup)
-    nq, nk, m = t // tq, t // tk, h * tq
-    layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
-    # Scaled as window_attention scales.
-    qf = (q.astype(jnp.float32) * scale).astype(kv_pool.dtype)
-    chunk = rows.transpose(2, 0, 1, 3).astype(kv_pool.dtype)  # [1, B, T, W]
-    positions = positions.astype(jnp.int32)
-
-    kernel = functools.partial(
-        _latent_prefill_kernel, block_size=block_size, super_tokens=sup,
-        tk=tk,
-    )
-
-    def block(lanes):
-        return pl.BlockSpec((1, tq, h, lanes), lambda i, j, *_: (i, j, 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, t, h, value_dim), q.dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(b, nq),
-            in_specs=[
-                block(w),
-                pl.BlockSpec((1, 1, tq, 1), lambda i, j, *_: (i, j, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, nk, 1, tk), lambda i, j, *_: (i, 0, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec(memory_space=pl.ANY),   # the chunk's rows and
-                pl.BlockSpec(memory_space=pl.ANY),   # the pool stay in HBM
-            ],
-            out_specs=block(value_dim),
-            scratch_shapes=[
-                pltpu.VMEM((NUM_BUFS, 1, sup, w), kv_pool.dtype),
-                pltpu.SemaphoreType.DMA((NUM_BUFS,)),
-                pltpu.SMEM((1,), jnp.int32),
-                pltpu.VMEM((m, 1), jnp.float32),
-                pltpu.VMEM((m, 1), jnp.float32),
-                pltpu.VMEM((m, value_dim), jnp.float32),
-            ],
-        ),
-        # Programs run in order: each hands its buffers to the next.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=PREFILL_VMEM_BYTES,
-        ),
-        name="paged_flash_prefill_latent",
-        interpret=interpret,
-    )(
-        layer, block_tables, kv_lens.astype(jnp.int32),
-        chunk_lens.astype(jnp.int32),
-        qf, positions.reshape(b, nq, tq, 1), positions.reshape(b, nk, 1, tk),
-        chunk, kv_pool,
-    )
-
-
 # ------------------------------------------------------ prefill, a packed row
 # A PACKED prefill dispatch is ONE row of T tokens in which up to S
 # sequences' chunks ("segments") lie end to end from token 0, each with its
@@ -1561,7 +1330,7 @@ def paged_flash_prefill_latent(
 #     every query has at least its own key; one no segment owns stays
 #     finite garbage, which nothing reads.
 #   * A segment that fills whole query blocks visits what its row visits in
-#     the kernel above, tile for tile.
+#     the rectangle kernel above, tile for tile.
 #   * The body serves latent rows too (the next section): what it asks of a
 #     pool is how a query block lies in VMEM and what a page's streams are,
 #     and the two classes below say that for K/V rows and for latent rows.
@@ -1654,8 +1423,9 @@ class _HeadMajorBlock:
 class _TokenMajorBlock:
     """A packed query block over LATENT rows: every head attends the one
     row, so a sub-block's tokens x heads are ONE matmul operand, q and the
-    output in their own layout [TQ, H, .] (no transpose crosses HBM, as in
-    ``_latent_prefill_kernel``), the flash state flat [TQ * H, .], rows
+    output in their own layout [TQ, H, .] (a block's [TQ, H, W] is
+    [TQ * H, W] with no data moved where the heads fill whole sublane
+    tiles: no transpose crosses HBM), the flash state flat [TQ * H, .], rows
     (token, head); the one stream is keys AND values, the values the first
     lanes of the buffer."""
 
@@ -1727,6 +1497,9 @@ def _packed_prefill_kernel(
     tq: int,
     sub_block: int,
     tile_rows: int,     # keys a tile of the ROW holds: TQ, or whole TQs
+    key_end_ref=None,   # SMEM [P] int32: one past the segment's last KEY in
+                        # the row, where its queries run on behind it (a
+                        # rectangle's padded queries); absent: ``end_ref``
     span_ref=None,      # SMEM [1] int32: the layer's span (absent: none)
 ):
     """ONE body for both pools: what differs between K/V rows and latent
@@ -1914,6 +1687,8 @@ def _packed_prefill_kernel(
                 idx_k = c * tk + jax.lax.broadcasted_iota(
                     jnp.int32, (1, width), 1)
                 seen = (idx_k >= start) & (idx_k <= idx_q)
+                if key_end_ref is not None:
+                    seen = seen & (idx_k < key_end_ref[p])
                 if span_ref is not None:
                     seen = seen & (idx_q - idx_k < span)
                 return seen
@@ -1964,6 +1739,25 @@ def packed_pairs(seg_lens: jax.Array, nq: int, tq: int):
     end = jnp.where(has, ends[seg], 0).astype(jnp.int32)
     tokens = jnp.minimum(end, (blk + 1) * tq) - jnp.maximum(start, blk * tq)
     return seg, blk, start, end, jnp.where(has, tokens, 0).astype(jnp.int32)
+
+
+def rectangle_pairs(chunk_lens: jax.Array, t: int, tq: int):
+    """The pairs of a RECTANGLE laid as a row, in closed form: row r of
+    ``chunk_lens`` [rows] is the segment that begins at token ``r * t``
+    (``t`` whole query blocks), so block p of the row is segment
+    ``p // (t // tq)``'s alone and the pairs are the row's blocks, one each
+    (a block behind its row's chunk: 0 tokens). A segment's QUERIES run on
+    to the end of its last live block: a live block's padded queries see
+    their row's valid keys, as ``window_attention``'s do. So beside
+    ``packed_pairs``' five, the last: one past the pair's segment's last
+    KEY. Rows that hold nothing may stand anywhere."""
+    per = t // tq                                   # blocks a row
+    blk = jnp.arange(chunk_lens.shape[0] * per, dtype=jnp.int32)
+    seg = blk // per
+    lens, start = chunk_lens[seg], seg * t
+    live = lens > (blk % per) * tq
+    end = start + -(-lens // tq) * tq
+    return seg, blk, start, end, jnp.where(live, tq, 0), start + lens
 
 
 @functools.partial(
@@ -2026,7 +1820,8 @@ def paged_flash_prefill_packed(
         _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
         tq=tq, sub_block=sb, tile_rows=tq,
     )
-    kernel, bound = _span_behind(kernel, 7, span)
+    kernel, bound = _prefetched_behind(
+        kernel, 7, span_ref=_span_operand(span))
     # A pair's blocks of q and the output are its query block's: resident
     # while the block's pairs follow one another.
     q_block = pl.BlockSpec(
@@ -2074,16 +1869,23 @@ def paged_flash_prefill_packed(
     return out.reshape(1, t, h, dh)
 
 
-# ------------------------------------------ prefill, a packed row, latent rows
-# The packed row over a LATENT pool: the kernel above with one stream (a
-# tile is keys AND values, cleared whole) and the query block in the latent
-# rectangle kernel's layout (``_TokenMajorBlock``). What follows from one row
-# for every head, as there: TQ is 32 tokens at 32 heads of 640 lanes, too
-# few keys for a tile of the row (the flash state's round trip, M x
-# value_dim float32, for 32 keys), so a row tile is ``packed_latent_tile``
-# keys, whole query blocks of them: a pair reads from the tile that holds
-# its segment's first token to the one that holds its block, and a
-# sub-block the keys up to its own end.
+# ------------------------------------------ prefill, a row of latent rows
+# The row over a LATENT pool (see "latent rows" above): the body above with
+# ONE pool and one stream (a tile is keys AND values, cleared whole; the
+# values the first ``value_dim`` lanes of the buffer, a slice in VMEM, free,
+# as in ``_latent_decode_kernel``) and the query block token-major
+# (``_TokenMajorBlock``). Absorbed latent attention is multi-query
+# attention, so what differs follows from there being one KV head of a wide
+# row: a query block holds TQ tokens for ALL heads as ONE matmul operand, M
+# = H x TQ rows (``prefill_tiles`` at Hkv 1 and the row's width: 32 queries
+# of 32 heads at 640 lanes), too few keys for a tile of the row (the flash
+# state's round trip, M x value_dim float32, for 32 keys), so a row tile is
+# ``packed_latent_tile`` keys, whole query blocks of them: a pair reads from
+# the tile that holds its segment's first token to the one that holds its
+# block, and a sub-block the keys up to its own end. A RECTANGLE of latent
+# rows (``paged_flash_prefill_latent``) is the row ``[1, rows * T]`` whose
+# segment r begins at ``r * T`` (``stride``): the tiles are chosen for T, so
+# a segment owns whole query blocks (``rectangle_pairs``).
 PACKED_LATENT_TILE = 256     # keys a row tile holds, a whole TQ where that
                              # is wider: 8 segments cut at random in a
                              # 1024-token row behind 124-271 tokens took
@@ -2097,24 +1899,30 @@ def packed_latent_tile(t: int, tq: int) -> int:
     return max(tq, min(t, PACKED_LATENT_TILE))
 
 
-def supports_packed_latent_prefill(t: int, num_heads: int, width: int,
-                                   value_dim: int, itemsize: int,
-                                   block_size: int) -> bool:
-    """What ``supports_latent_prefill`` asks, query blocks of whole
-    sublane tiles (``supports_packed_prefill``'s reason) and a row of whole
-    key tiles of whole query blocks."""
-    if not supports_latent_prefill(t, num_heads, width, value_dim, itemsize,
-                                   block_size):
+def supports_latent_prefill(t: int, num_heads: int, width: int,
+                            value_dim: int, itemsize: int,
+                            block_size: int) -> bool:
+    """Whether the packed body tiles latent rows at ``t`` tokens (a packed
+    row's length, a rectangle's T): what ``supports_packed_prefill`` asks at
+    one KV head of ``width`` lanes, values that are whole lanes of the row,
+    heads that fill whole sublane tiles of the dtype (a block's [TQ, H, W]
+    is then [TQ * H, W] as it lies) and ``t`` tokens of whole key tiles of
+    whole query blocks and, past a superpage, of whole superpages (the
+    lengths it is warmed at)."""
+    if value_dim % LANES or value_dim > width \
+            or num_heads % (32 // itemsize) \
+            or not supports_packed_prefill(t, num_heads, 1, width, itemsize,
+                                           block_size):
         return False
-    _, tq = prefill_tiles(t, num_heads, 1, width, itemsize, block_size)
+    sup, tq = prefill_tiles(t, num_heads, 1, width, itemsize, block_size)
     tk = packed_latent_tile(t, tq)
-    return tq % (32 // itemsize) == 0 and tk % tq == 0 and t % tk == 0
+    return tk % tq == 0 and t % tk == 0 and t % min(t, sup) == 0
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("block_size", "value_dim", "scale", "interpret",
-                     "sub_block", "key_tile"),
+                     "sub_block", "key_tile", "stride"),
 )
 def paged_flash_prefill_packed_latent(
     q: jax.Array,             # [1, T, H, W] absorbed queries, zeros past the key
@@ -2131,29 +1939,40 @@ def paged_flash_prefill_packed_latent(
     interpret: bool = False,
     sub_block: Optional[int] = None,
     key_tile: Optional[int] = None,
+    stride: Optional[int] = None,
 ) -> jax.Array:
-    """``paged_flash_prefill_packed`` over a latent pool, which is
-    ``paged_flash_prefill_latent`` of a PACKED row: segment i is the row's
-    tokens [sum(seg_lens[:i]), sum(seg_lens[:i + 1])), its history the
-    pool's slots below ``kv_lens[i]`` by ``block_tables[i]``; every head
+    """``paged_flash_prefill_packed`` over a latent pool: segment i is the
+    row's tokens [sum(seg_lens[:i]), sum(seg_lens[:i + 1])), its history
+    the pool's slots below ``kv_lens[i]`` by ``block_tables[i]``; every head
     attends the segment's history and the segment causally, keys the whole
     row, values its first ``value_dim`` lanes: [1, T, H, value_dim] in
-    q.dtype, a segment's tokens equal to ``paged_flash_prefill_latent`` of
-    the segment as a row of its own. Live segments come first; tokens past
-    the last are padding (finite, meaning nothing; a query block no segment
-    reaches is zeros). See the section comments and
-    ``supports_packed_latent_prefill``; ``sub_block`` (tokens) and
-    ``key_tile`` (keys) override ``packed_sub_block`` and
-    ``packed_latent_tile`` for tests and sweeps."""
+    q.dtype, a segment's tokens equal to ``window_attention`` of the
+    segment as a row of its own over its gathered rows. Live segments come
+    first; tokens past the last are padding (finite, meaning nothing; a
+    query block no segment reaches is zeros). The pool must be finite
+    wherever a live segment's pages reach, padding lanes included. See the
+    section comments and ``supports_latent_prefill``; ``sub_block``
+    (tokens) and ``key_tile`` (keys) override ``packed_sub_block`` and
+    ``packed_latent_tile`` for tests and sweeps.
+
+    ``stride`` (static; ``paged_flash_prefill_latent``'s way in): the row is
+    a RECTANGLE of T / stride rows laid end to end, segment i beginning at
+    ``i * stride`` whatever ``seg_lens`` hold (``rectangle_pairs``), and
+    the tiles are chosen for ``stride`` tokens."""
     _, t, h, w = q.shape
     itemsize = kv_pool.dtype.itemsize
-    sup, tq = prefill_tiles(t, h, 1, w, itemsize, block_size)
+    sup, tq = prefill_tiles(stride or t, h, 1, w, itemsize, block_size)
     sb = sub_block or packed_sub_block(tq, h, itemsize)
-    tk = key_tile or packed_latent_tile(t, tq)
+    tk = key_tile or packed_latent_tile(stride or t, tq)
     nq, m = t // tq, h * tq
     layer = jnp.asarray(layer_idx, jnp.int32).reshape(1)
-    seg, blk, start, end, tokens = packed_pairs(
-        seg_lens.astype(jnp.int32), nq, tq)
+    if stride is None:
+        seg, blk, start, end, tokens = packed_pairs(
+            seg_lens.astype(jnp.int32), nq, tq)
+        key_end = None
+    else:
+        seg, blk, start, end, tokens, key_end = rectangle_pairs(
+            seg_lens.astype(jnp.int32), stride, tq)
     # Scaled as window_attention scales.
     qf = (q.astype(jnp.float32) * scale).astype(kv_pool.dtype)
     chunk = rows.transpose(2, 0, 1, 3).astype(kv_pool.dtype)  # [1, 1, T, W]
@@ -2162,6 +1981,7 @@ def paged_flash_prefill_packed_latent(
         _packed_prefill_kernel, block_size=block_size, super_tokens=sup,
         tq=tq, sub_block=sb, tile_rows=tk,
     )
+    kernel, bound = _prefetched_behind(kernel, 7, key_end_ref=key_end)
 
     def block(lanes):
         # A pair's blocks of q and the output are its query block's.
@@ -2174,7 +1994,7 @@ def paged_flash_prefill_packed_latent(
         kernel,
         out_shape=jax.ShapeDtypeStruct((1, t, h, value_dim), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
+            num_scalar_prefetch=7 + len(bound),
             grid=(seg.shape[0],),
             in_specs=[
                 block(w),
@@ -2200,6 +2020,40 @@ def paged_flash_prefill_packed_latent(
         interpret=interpret,
     )(
         layer, block_tables[seg], kv_lens.astype(jnp.int32)[seg] * (tokens > 0),
-        tokens, blk, start, end,
+        tokens, blk, start, end, *bound,
         qf, chunk, kv_pool,
     )
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "value_dim", "scale", "interpret"),
+)
+def paged_flash_prefill_latent(
+    q: jax.Array,             # [B, T, H, W] absorbed queries, zeros past the key
+    rows: jax.Array,          # [B, T, 1, W] the chunk's latent rows
+    chunk_lens: jax.Array,    # [B] int32 valid tokens per row
+    kv_pool: jax.Array,       # [L, 1, num_slots, W] latent rows
+    block_tables: jax.Array,  # [B, Mb] int32
+    kv_lens: jax.Array,       # [B] int32: the row's tokens in the pool
+    layer_idx: jax.Array,     # [] or [1] int32
+    *,
+    block_size: int,
+    value_dim: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """``paged_flash_prefill`` over a latent pool, with no kernel of its
+    own: the rectangle as the row [1, B * T] of
+    ``paged_flash_prefill_packed_latent``, segment b at ``b * T`` (a
+    reshape: nothing is copied), token i of a row at position
+    ``kv_lens[row] + i``: [B, T, H, value_dim] in q.dtype, equal to
+    ``window_attention`` over the gathered rows. A live query block's padded
+    queries see their row's valid keys; a query block that is all padding
+    is zeros and fetches nothing."""
+    b, t, h, w = q.shape
+    out = paged_flash_prefill_packed_latent(
+        q.reshape(1, b * t, h, w), rows.reshape(1, b * t, 1, w), chunk_lens,
+        kv_pool, block_tables, kv_lens, layer_idx, block_size=block_size,
+        value_dim=value_dim, scale=scale, interpret=interpret, stride=t)
+    return out.reshape(b, t, h, value_dim)

@@ -25,67 +25,8 @@ os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 import asyncio
 import functools
 import inspect
-import json
 
 import pytest
-
-# One accepted assertion of the benchmark's own tests that no appending PR
-# can keep: ``test_bench_issue.py`` (PR 36) asserts that PR 36's six metrics
-# are the LAST entries of ``per_layer``. A PR may only append to a list of
-# ``BENCHMARK.json`` and may not edit a file under the benchmark's ``paths``
-# (this one lies outside them; ``tests/chip_bench/conftest.py`` does the same
-# for PR 24's block and may not be edited either). Once entries follow the
-# six, that one test is expected to fail at its ``[-6:]`` line; everything
-# else it asserts is held, with the block pinned to the place it has, by
-# ``tests/chip_bench/test_bench_hc.py``. Conditional and strict: not applied
-# while the six are last, and a ``benchmark`` PR that loosens the assertion
-# makes the test pass, which fails the run until this mark is deleted.
-#
-# PR 38's ``test_bench_hc.py`` pins its own entries to the ENDS of their
-# lists in three tests, which the next appending PR (PR 40) cannot keep
-# either: the same mark, on the same condition (entries follow PR 38's
-# last). What else the three assert is held by
-# ``tests/chip_bench/test_bench_ssm.py`` with PR 38's block pinned to the
-# indices it has. (``..._since_the_parent`` skips where git has no history,
-# which a strict xfail lets through.) From PR 40 on the benchmark's tests
-# pin their entries by INDEX and nothing to an end, so this list need not
-# grow again for THAT reason.
-#
-# PR 40's ``test_bench_ssm.py`` holds one more assumption: that every
-# ``workloads`` list which changed since PR 40's parent changed by granite's
-# cell. PR 44's cell reports ``moe_experts_touched`` (its experts are the
-# program's counters'), a list granite's cell is not in, so that one test
-# fails at the name it finds there. Same mark, on that condition; what else
-# it asserts (nothing that was there changed, lists only grew at their ends)
-# is held against PR 44's parent by ``tests/chip_bench/test_bench_lfm.py``.
-#
-# Four tests (PR 38's cell in ``test_bench_ssm.py``, and ``test_what_the_
-# cell_reports`` of PR 40, PR 44 and PR 47) hold the SET of metrics whose
-# ``workloads`` list names their cell to be exactly what their PR left.
-# PR 49 appends three metrics of the device's memory that list every cell
-# (``hbm_high_water_gb`` the first), so each of those sets grew by three.
-# Same mark, while an entry of that name lists cells; what else the four
-# assert (the list-less metrics and the end-to-end ones a cell reports,
-# and the set itself among the entries that were there) is held by
-# ``tests/chip_bench/test_bench_memory.py``.
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_PR36_TEST = ("test_bench_issue.py::"
-              "test_the_six_are_the_last_of_per_layer_and_list_every_cell")
-_PR36_LAST = "decode_empty_step_pct"
-_PR38_TESTS = tuple("test_bench_hc.py::" + name for name in (
-    "test_the_new_entries_are_the_last_of_their_lists",
-    "test_the_cell_is_named_last_where_its_readers_find_something",
-    "test_the_manifest_only_grew_at_the_ends_since_the_parent"))
-_PR38_LAST = "hc_share_pct"
-_PR40_TEST = ("test_bench_ssm.py::"
-              "test_the_manifest_only_grew_since_the_parent")
-_PR40_NOT_IN = "moe_experts_touched"
-_PR40_CELL = "granite-4.0-h-micro.chat-saturated"
-_PR49_TESTS = ("test_bench_ssm.py::test_pr38_cell_reports_what_it_did",
-               "test_bench_ssm.py::test_what_the_cell_reports",
-               "test_bench_lfm.py::test_what_the_cell_reports",
-               "test_bench_afmoe.py::test_what_the_cell_reports")
-_PR49_FIRST = "hbm_high_water_gb"
 
 
 def pytest_collection_modifyitems(items):
@@ -93,29 +34,6 @@ def pytest_collection_modifyitems(items):
     for item in items:
         if inspect.iscoroutinefunction(getattr(item, "function", None)):
             item.obj = _sync_wrapper(item.function)
-    with open(os.path.join(_REPO, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    last = per_layer[-1]["name"]
-    overtaken = [(tests, pr) for tests, is_last, pr in (
-        ((_PR36_TEST,), _PR36_LAST, 36), (_PR38_TESTS, _PR38_LAST, 38))
-        if last != is_last]
-    others = next(m["workloads"] for m in per_layer
-                  if m["name"] == _PR40_NOT_IN)
-    if _PR40_CELL not in others and len(others) > 2:
-        overtaken.append(((_PR40_TEST,), 40))
-    if any(m["name"] == _PR49_FIRST and m.get("workloads")
-           for m in per_layer):
-        overtaken.append((_PR49_TESTS, 47))
-    for item in items:
-        for tests, pr in overtaken:
-            if item.nodeid.endswith(tests):
-                item.add_marker(pytest.mark.xfail(
-                    strict=True, raises=AssertionError,
-                    reason=f"asserts PR {pr}'s entries are the last of "
-                           "their lists (PR 40: the only cell lists grew "
-                           "by; PR 47: the only metrics that name a "
-                           "cell); a PR may only append (see the note "
-                           "above)"))
 
 
 def _sync_wrapper(fn):

@@ -445,6 +445,35 @@ def test_a_cpu_runner_reads_nothing_and_sizes_its_residents_from_arrays():
     assert not memory.events and not memory.programs
 
 
+def test_the_ledger_lists_sixteen_groups_a_device_of_the_mesh():
+    """Live arrays of 40 distinct shapes on the first device of a two-device
+    engine and two small ones on the second: at most 16 groups are listed a
+    device and both devices are listed (one cut over all devices kept 32
+    groups of the first and none of the second)."""
+    import jax
+    import jax.numpy as jnp
+
+    engine = ServingEngine(EngineConfig(
+        model="tiny-llama-8kv", max_model_len=128, num_kv_blocks=64,
+        block_size=4, dtype="float32", max_num_seqs=2, attn_impl="xla",
+        tensor_parallel_size=2))
+    runner = engine.runner
+    first, second = runner.mesh.devices.flat
+    crowd = [jax.device_put(jnp.zeros((4096 + i,), jnp.float32), first)
+             for i in range(40)]
+    few = [jax.device_put(jnp.zeros((3 + i,), jnp.float32), second)
+           for i in range(2)]
+    runner.wait_for_weights()
+    runner.build_memory_ledger()
+    memory = runner.memory
+    assert memory.device == "cpu:0"
+    assert len(memory.other_arrays) == 16
+    assert all(a["device"] == "cpu:0" for a in memory.other_arrays)
+    assert memory.residents_by_device["cpu:1"]["other"] >= sum(
+        x.nbytes for x in few)
+    del crowd, few
+
+
 @pytest.mark.parametrize("family,variant,key", [
     ((32, 192, 32, False), {}, "decode[32,192,32,0]"),
     ((1, 24, 8, True), {"logprobs_k": 8}, "decode[1,24,8,1]+lp8"),

@@ -356,8 +356,9 @@ async def test_the_served_surface_says_which_step_runs_and_counts_bucket_rows(
     assert bucket > rows > 0
     assert {p["program"] for p in programs} == {"decode", "prefill"}
     # (Not ``pool_copies``: the interpreter's loops copy the carry that
-    # the chip's kernel updates where it lies; tests/test_chip_compile.py
-    # holds the program compiled for the chip to that.)
+    # the chip's kernel updates where it lies;
+    # tests/test_chip_compile_recurrent.py holds the program compiled for
+    # the chip to that.)
     for p in programs:
         assert p.get("gdn_step") == \
             (path if p["program"] == "decode" else None)

@@ -24,6 +24,7 @@ from production_stack_tpu.ops.pallas.paged_attention import (
     paged_flash_prefill,
     paged_flash_prefill_packed,
     prefill_tiles,
+    rectangle_pairs,
     supports_packed_prefill,
     supports_pallas_prefill,
 )
@@ -501,6 +502,41 @@ def test_one_segment_that_fills_the_row_is_the_row_bit_for_bit(hist,
     assert np.array_equal(np.asarray(packed), np.asarray(_kernel(c)))
 
 
+def _padding_set_to(c, clens, value, names):
+    """``c`` with every token past a row's chunk set to +-``value`` in its
+    operands ``names``."""
+    c = dict(c)
+    for name in names:
+        x = np.array(c[name])
+        for i, cl in enumerate(clens):
+            x[i, cl:] = value
+            x[i, cl::2] = -value
+        c[name] = jnp.asarray(x)
+    return c
+
+
+def test_what_lies_between_segments_is_inert_and_comes_out_finite():
+    """A rectangle's rows hold 1, 77 and 0 tokens: whatever FINITE values
+    the padding of q, k and v holds (the engine's is the model's own
+    projections of padding tokens), the live tokens come out the same bit
+    for bit and equal the oracle, and every output at a padding token is
+    finite (no block of the output is left unwritten; a recurrence
+    downstream multiplies padding by a mask, and NaN times zero is NaN)."""
+    clens = [1, 77, 0]
+    c = _case(128, 4, 2, hists=[40, 0, 64], clens=clens)
+    ref = np.asarray(_window_reference(c))
+    outs = [np.asarray(_kernel(_padding_set_to(c, clens, value,
+                                               ("q", "k", "v"))))
+            for value in (0.0, 1e4)]
+    for out in outs:
+        assert np.all(np.isfinite(out))
+        for i, cl in enumerate(clens):
+            np.testing.assert_allclose(out[i, :cl], ref[i, :cl], atol=ATOL,
+                                       rtol=0)
+    for i, cl in enumerate(clens):
+        assert np.array_equal(outs[0][i, :cl], outs[1][i, :cl])
+
+
 @pytest.mark.parametrize("lens,nq,tq,want", [
     # (segment, block) pairs, block by block; a block nobody reaches has a
     # pair of no tokens, and so have the entries past the last block.
@@ -525,6 +561,33 @@ def test_the_pairs_of_a_packed_row(lens, nq, tq, want):
         if n:
             assert (a, e) == (offs[s], offs[s + 1])
     assert np.all(np.diff(blk) >= 0)
+
+
+@pytest.mark.parametrize("lens,t,tq", [
+    ([128, 1] + [0] * 14, 128, 128),        # 16 x 128
+    ([0, 200, 0, 5], 256, 128),             # rows that hold nothing, anywhere
+    ([256, 100, 7], 256, 128),              # 3 x 256
+    ([2000], 2048, 256),                    # 1 x 2048
+], ids=["16x128", "4x256-gaps", "3x256", "1x2048"])
+def test_the_pairs_of_a_rectangle_laid_as_a_row(lens, t, tq):
+    """``rectangle_pairs`` against a Python loop: segment r begins at
+    ``r * t``, a block of the row is one segment's and has one pair; a
+    segment's queries end with its last live block, its keys with its
+    chunk; a block behind its row's chunk holds no token."""
+    seg, blk, start, end, tokens, key_end = (
+        np.asarray(x) for x in rectangle_pairs(
+            jnp.asarray(lens, jnp.int32), t, tq))
+    want = []
+    for b in range(len(lens) * t // tq):
+        r, first = b * tq // t, b * tq % t
+        live = lens[r] > first
+        want.append((r, b, r * t, r * t + -(-lens[r] // tq) * tq,
+                     tq if live else 0, r * t + lens[r]))
+    got = list(zip(*(x.tolist() for x in (seg, blk, start, end, tokens,
+                                          key_end))))
+    assert got == want
+    # Every valid token lies in a live block of its own segment.
+    assert sum(-(-n // tq) for n in lens) == int((tokens > 0).sum())
 
 
 @pytest.mark.parametrize("tq,g,itemsize,want", [

@@ -22,14 +22,12 @@ from production_stack_tpu.ops.attention import (
     window_attention,
 )
 from production_stack_tpu.ops.pallas.paged_attention import (
-    latent_chunk_tile,
     packed_latent_tile,
     packed_sub_block,
     paged_flash_prefill_latent,
     paged_flash_prefill_packed_latent,
     prefill_tiles,
     supports_latent_prefill,
-    supports_packed_latent_prefill,
 )
 from tests.test_paged_prefill import _greedy
 from tests.test_paged_prefill import _pack as _pack_row
@@ -77,9 +75,9 @@ def _case(t, shape, hists, clens, *, extra_blocks=3, max_pos=None, seed=0,
 
 def _kernel(c):
     return paged_flash_prefill_latent(
-        c["q"], c["rows"], c["positions"], c["chunk_lens"], c["pool"],
-        c["bt"], c["kv_lens"], jnp.int32(LAYER), block_size=BS,
-        value_dim=c["dv"], scale=SCALE, interpret=True)
+        c["q"], c["rows"], c["chunk_lens"], c["pool"], c["bt"],
+        c["kv_lens"], jnp.int32(LAYER), block_size=BS, value_dim=c["dv"],
+        scale=SCALE, interpret=True)
 
 
 def _window_reference(c, dtype=jnp.float32):
@@ -124,12 +122,10 @@ def test_the_tile_rule_follows_from_width_heads_and_vmem():
     """32 queries of 32 heads are one matmul operand at 640 lanes (both of
     ``prefill_tiles``' limits bind: a program's rows over all heads, and
     one KV head's score rows); a narrow row of few heads takes the whole
-    chunk; the chunk's key tiles are the chunk whole up to a superpage."""
+    chunk."""
     assert prefill_tiles(128, 32, 1, 640, 2, BS) == (512, 32)
     assert prefill_tiles(1024, 32, 1, 640, 2, BS) == (512, 32)
     assert prefill_tiles(128, 8, 1, 256, 4, BS) == (512, 128)
-    assert latent_chunk_tile(128, 512) == 128
-    assert latent_chunk_tile(1024, 512) == 512
 
 
 # ---- where the history ends
@@ -164,6 +160,26 @@ def test_padded_row_is_zeros_and_fetches_nothing():
 def test_padded_rows_everywhere_are_zeros():
     c = _case(128, SMALL, hists=[0, 0], clens=[0, 0])
     assert not np.asarray(_kernel(c)).any()
+
+
+def test_what_lies_between_segments_is_inert_and_comes_out_finite():
+    """tests/test_paged_prefill.py's, over latent rows: rows of 1, 77 and 0
+    tokens, the padding of q and of the rows zeros and then +-1e4."""
+    from tests.test_paged_prefill import _padding_set_to
+
+    clens = [1, 77, 0]
+    c = _case(128, SMALL, hists=[40, 0, 64], clens=clens)
+    ref = np.asarray(_window_reference(c))
+    outs = [np.asarray(_kernel(_padding_set_to(c, clens, value,
+                                               ("q", "rows"))))
+            for value in (0.0, 1e4)]
+    for out in outs:
+        assert np.all(np.isfinite(out))
+        for i, cl in enumerate(clens):
+            np.testing.assert_allclose(out[i, :cl], ref[i, :cl], atol=ATOL,
+                                       rtol=0)
+    for i, cl in enumerate(clens):
+        assert np.array_equal(outs[0][i, :cl], outs[1][i, :cl])
 
 
 def test_positions_clamped_at_max_model_len():
@@ -215,7 +231,8 @@ def test_prefill_kernel_covers_latent_rows():
     assert not covers(t=16, bs=32)          # a chunk of half a block
     assert not covers(t=768)                # not whole key tiles of 512
     # A PACKED row (PR 48): the same, and query blocks of whole sublane
-    # tiles; the rows a deployment's envelope dispatches are covered.
+    # tiles; the rows a deployment's envelope dispatches are covered. Since
+    # PR 56 a rectangle runs the same body, so ``packed`` changes nothing.
     assert all(covers(t=t, packed=True) for t in (128, 256, 512, 1024))
     assert covers(h=8, w=256, dv=128, dtypes=(jnp.float32,), packed=True)
     assert not covers(h=8, packed=True) and not covers(t=768, packed=True)
@@ -376,7 +393,7 @@ def test_packed_latent_segments_match_window_a_segment(t, shape, hists,
     late) is told apart: the neighbour's first token then attends the wrong
     sequence."""
     h, w, dv = shape
-    assert supports_packed_latent_prefill(t, h, w, dv, 4, BS)
+    assert supports_latent_prefill(t, h, w, dv, 4, BS)
     c = _case(t, shape, hists=hists, clens=clens)
     ref = np.asarray(_window_reference(c))
     live = [cl for cl in clens if cl]

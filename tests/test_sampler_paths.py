@@ -137,7 +137,7 @@ def test_a_skipped_pick_is_not_traced_into_the_taken_path():
     Gumbel field only inside ``cond`` branches, each branch one call to a
     jitted body (a body written inside the cond costs every decode program
     0.65 s of lowering at each boot on the TPU: PERF.md, PR 28). The
-    chip-side guard reads the compiled HLO: tests/test_chip_compile.py."""
+    chip-side guard reads the compiled HLO: tests/test_chip_compile_dense.py."""
     logits, temps, top_k, top_p, seeds = _batch((GREEDY,), 4, 300, 0)
     jaxpr = jax.make_jaxpr(sample_tokens.__wrapped__)(
         logits, temps, top_k, top_p, seeds)
