@@ -373,11 +373,16 @@ class ServingEngine:
         dev = self.device_report()
         logger.info(
             "Engine started: model=%s kv_blocks=%d block_size=%d attn=%s "
-            "mesh=%s platform=%s device_kind=%r devices=%d ids=%s",
+            "mesh=%s platform=%s device_kind=%r devices=%d ids=%s; ready "
+            "%.1fs after construction began (weights %.1fs, AOT prepass "
+            "%.1fs, warm-up %.1fs)",
             self.config.model_name, self.runner.num_kv_blocks,
             self.config.block_size, self.runner.attn_impl,
             dict(self.mesh.shape), dev["platform"], dev["kind"],
-            dev["count"], dev["ids"],
+            dev["count"], dev["ids"], self.startup_total_seconds,
+            self.runner.startup_weight_load_seconds,
+            self.runner.startup_compile_seconds,
+            self.runner.startup_warmup_seconds,
         )
 
     async def stop(self) -> None:
@@ -1647,6 +1652,9 @@ class ServingEngine:
                 "cache_hit_families": r.startup_cache_hit_families,
                 "cache_miss_families": r.startup_cache_miss_families,
                 "deferred_families": r.startup_deferred_families,
+                # Of the hits: programs loaded from the runner's store,
+                # none of them traced (engine/program_store.py).
+                "loaded_families": r.startup_loaded_families,
                 "compile_seconds": round(r.startup_compile_seconds, 3),
                 "warmup_seconds": round(r.startup_warmup_seconds, 3),
                 "weight_load_seconds": round(
@@ -1773,6 +1781,8 @@ class ServingEngine:
                 self.runner.startup_cache_hit_families,
             "startup_cache_miss_families":
                 self.runner.startup_cache_miss_families,
+            "startup_loaded_families":
+                self.runner.startup_loaded_families,
             "num_preemptions": self.scheduler.num_preemptions_total,
             # Observability plane (docs/OBSERVABILITY.md): OTLP exporter
             # queue drops (0 with tracing off).

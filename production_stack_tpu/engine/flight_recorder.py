@@ -135,7 +135,9 @@ class CompileClock:
     ``backend_compile_duration``, spans the persistent cache's lookup too,
     so a deferred variant's first-use load counts like a true compile and
     ``/jax/compilation_cache/cache_retrieval_time_sec`` is not added
-    again; a call that finds its program compiled records nothing.
+    again; a call that finds its program compiled records nothing. A
+    program the runner loads from its own store (engine/program_store.py)
+    never enters JAX's compile path: the runner says so (``loaded``).
 
     The clock runs from the first ``compile_clock()`` on; an engine
     exports what it read past its own warm-up
@@ -159,6 +161,13 @@ class CompileClock:
             with self._lock:
                 self.seconds += duration
                 self.count += event == self.COMPILED
+
+    def loaded(self, seconds: float) -> None:
+        """A stored program was loaded without JAX's compile path (the
+        runner's program store): one program, these seconds."""
+        with self._lock:
+            self.seconds += seconds
+            self.count += 1
 
     def reading(self) -> tuple:
         """``(programs, seconds)`` so far."""

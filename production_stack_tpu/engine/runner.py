@@ -39,9 +39,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from production_stack_tpu.engine.config import EngineConfig
+from production_stack_tpu.engine.flight_recorder import compile_clock
 from production_stack_tpu.engine.memory_ledger import (
     MemoryLedger,
     analysis_of,
+)
+from production_stack_tpu.engine.program_store import (
+    ProgramStore,
+    source_digest,
 )
 from production_stack_tpu.engine.sampling import (
     sample_tokens,
@@ -370,6 +375,13 @@ class ModelRunner:
         # Interpret mode is for the CPU backend (tests); on a TPU the kernel
         # runs compiled — the engine reports which (ServingEngine.report).
         self._pallas_interpret = devices[0].platform == "cpu"
+        # Whether an executable that was itself LOADED (JAX's cache
+        # supplied it) may be stored again. XLA:CPU serializes such a one
+        # without its kernels' functions, and the copy fails when it runs
+        # ("Function wrapped_iota not found"); so on the CPU backend (tests,
+        # rehearsals) only a program compiled here is stored, and one the
+        # cache supplied is traced again by the next boot, as it always was.
+        self._stores_loaded = devices[0].platform != "cpu"
         self.dtype = _dtype(config.dtype)
         # KV-cache STORAGE dtype (--kv-cache-dtype): int8 pools carry a
         # per-(slot, head) bf16 scale sidecar (ops/quantization.py) and
@@ -400,6 +412,19 @@ class ModelRunner:
         # limped is visible in the engine's report, not only in its log).
         self.startup_warmed_families = 0
         self.startup_warmup_failures = 0
+        # Dispatch programs this process holds as LOADED executables, by
+        # ``program()["key"]`` (engine/program_store.py): compiled and
+        # stored by a cold boot's warm-up, loaded by a manifest-verified
+        # warm boot's. ``_dispatch`` calls a program through this table and
+        # only one that is not in it through the jitted function, so a
+        # program is resident once. ``_stored``: programs the store holds
+        # that are loaded at first use (a warm boot's deferred variants).
+        self._programs: Dict[str, "jax.stages.Compiled"] = {}
+        self._store = None
+        self._stored: frozenset = frozenset()
+        # Programs a boot loaded without tracing them (each also counts as
+        # a cache hit).
+        self.startup_loaded_families = 0
 
         model = get_model(model_config)
         # What the architecture caches per sequence, as its module declares
@@ -2342,6 +2367,50 @@ class ModelRunner:
         """Rows of the decode program that ``rows`` sequences run in."""
         return _bucket(rows, 1, max(1, self.config.max_num_seqs))
 
+    # -------------------------------------------------------- stored programs
+    def _jitted(self, kind: str):
+        """``_decode`` or ``_prefill``, by a program's kind."""
+        return self._decode if kind == "decode" else self._prefill
+
+    def _load_program(self, key: str):
+        """The stored program ``key``, loaded into the table; None where
+        its file is missing, short or refused by the backend: the program
+        then takes the traced path in this process, and the manifest goes,
+        so that the next boot stores everything again."""
+        t0 = time.monotonic()
+        try:
+            loaded = self._store.load(key, list(self.mesh.devices.flat))
+        except Exception:  # noqa: BLE001 — whatever refuses it: trace it
+            logger.warning(
+                "Stored program %s did not load: it is traced, and the "
+                "warm-up manifest is dropped", key, exc_info=True)
+            self._forget_program(key)
+            return None
+        self._programs[key] = loaded
+        compile_clock().loaded(time.monotonic() - t0)
+        return loaded
+
+    def _forget_program(self, key: str) -> None:
+        """The store failed ``key``: out of the table, traced from now on
+        in this process, and no manifest for the next boot to trust."""
+        self._programs.pop(key, None)
+        self._stored -= {key}
+        self._store.drop_manifest()
+
+    def _dispatch(self, kind: str, program: Dict, args, static):
+        """Enqueue one dispatch program: through its loaded executable
+        where this process holds one or the store has one to load (a warm
+        boot's deferred variant, at its first use), else through the jitted
+        function, which traces, lowers and compiles it. Never both, so a
+        program is resident once."""
+        key = program["key"]
+        loaded = self._programs.get(key)
+        if loaded is None and key in self._stored:
+            loaded = self._load_program(key)
+        if loaded is not None:
+            return loaded(*args)
+        return self._jitted(kind)(*args, **static)
+
     def _issue_decode(self, batch: ScheduledBatch) -> "DispatchHandle":
         cfg = self.config
         seqs = batch.seqs
@@ -2499,18 +2568,19 @@ class ModelRunner:
         )
         kv_ks, kv_vs = self._scale_pool_args()
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
-        (toks_all, self.kv_k, self.kv_v, kv_ks2, kv_vs2, wk2, wv2, lp_c,
-         lp_t, lp_i, last_token, emits, spec_stats_dev, sp_k2,
-         sp_v2, sp_p2, self.state_pools, fwd_stats) = self._decode(
-            self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
-            kv_ks, kv_vs, wk, wv, jnp.asarray(counts), prev_last,
-            dparams, sp_k, sp_v, sp_p, self.state_pools,
-            b=b, mb=mb, num_steps=k, use_cached_window=use_cached,
-            has_penalties=has_penalties, logprobs_k=logprobs_k,
-            spec_on=spec_on,
-        )
         program = self.program("decode", (b, mb, k, use_cached),
                                has_penalties, logprobs_k, spec_on)
+        (toks_all, self.kv_k, self.kv_v, kv_ks2, kv_vs2, wk2, wv2, lp_c,
+         lp_t, lp_i, last_token, emits, spec_stats_dev, sp_k2,
+         sp_v2, sp_p2, self.state_pools, fwd_stats) = self._dispatch(
+            "decode", program,
+            (self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
+             kv_ks, kv_vs, wk, wv, jnp.asarray(counts), prev_last,
+             dparams, sp_k, sp_v, sp_p, self.state_pools),
+            dict(b=b, mb=mb, num_steps=k, use_cached_window=use_cached,
+                 has_penalties=has_penalties, logprobs_k=logprobs_k,
+                 spec_on=spec_on),
+        )
         self._rebind_scale_pools(kv_ks2, kv_vs2)
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
         if self.kv_quantized:
@@ -3037,14 +3107,18 @@ class ModelRunner:
 
         kv_ks, kv_vs = self._scale_pool_args()
         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
+        program = self.program(
+            "prefill", (b, t, mb, has_window), has_penalties, logprobs_k)
         (next_tokens, self.kv_k, self.kv_v, kv_ks2, kv_vs2, lp_c, lp_t,
          lp_i, last_token, sp_k2, sp_v2, sp_p2,
-         self.state_pools, fwd_stats) = self._prefill(
-            self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
-            kv_ks, kv_vs, jnp.asarray(counts), dparams, sp_k, sp_v, sp_p,
-            self.state_pools,
-            b=b, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
-            has_penalties=has_penalties, logprobs_k=logprobs_k, segs=segs,
+         self.state_pools, fwd_stats) = self._dispatch(
+            "prefill", program,
+            (self.params, jnp.asarray(packed), self.kv_k, self.kv_v,
+             kv_ks, kv_vs, jnp.asarray(counts), dparams, sp_k, sp_v, sp_p,
+             self.state_pools),
+            dict(b=b, t=t, mb=mb, has_window=has_window, b_max=self._b_max,
+                 has_penalties=has_penalties, logprobs_k=logprobs_k,
+                 segs=segs),
         )
         self._rebind_scale_pools(kv_ks2, kv_vs2)
         self._rebind_spec_pools(sp_k2, sp_v2, sp_p2)
@@ -3083,8 +3157,7 @@ class ModelRunner:
             )
             return tokens, lp
 
-        return DispatchHandle(fetch, self.program(
-            "prefill", (b, t, mb, has_window), has_penalties, logprobs_k), n)
+        return DispatchHandle(fetch, program, n)
 
     # ------------------------------------------------------------ token chain
     def _push_chain(self, entry: Dict) -> None:
@@ -3719,56 +3792,69 @@ class ModelRunner:
         )
         return n
 
-    def _warmup_manifest_path(self) -> Optional[str]:
-        """Path of the warmup manifest for THIS exact configuration (None
-        without a persistent cache). The manifest is written only after a
-        FULLY successful warmup of every variant, keyed by everything that
-        shapes the lowered modules — model, dtypes, mesh, pool geometry,
-        loop construct, and the complete reachable family enumeration —
-        so any config change misses to a different manifest and the boot
-        warms cold. Its existence is the proof that lets a warm boot
-        defer the non-default sampling variants: their first use is then
-        a bounded persistent-cache LOAD, never an XLA compile."""
+    def _program_store(self) -> Optional[ProgramStore]:
+        """The program store of THIS exact boot (None without a persistent
+        cache): its key names the warm-up manifest and every stored
+        program (engine/program_store.py). The manifest is written only
+        after a FULLY successful warm-up of every variant, and a warm boot
+        under it LOADS its programs and traces none, so the key holds all
+        that a trace would have noticed: what shapes the lowered modules
+        (model and its configuration, dtypes, mesh and its devices, pool
+        geometry, loop construct, the complete reachable family
+        enumeration) and what turns them into executables (a digest of
+        this package's source, the jax, jaxlib and backend versions, the
+        latter libtpu's build on a TPU, the device kind, the XLA and
+        libtpu flags in the environment). A key that differs in anything
+        names another manifest, and the boot warms cold."""
         if not self.compilation_cache_path:
             return None
-        import hashlib
-        import json as _json
         import os
 
+        import jaxlib
+
         cfg = self.config
-        doc = {
+        device = self.mesh.devices.flat[0]
+        return ProgramStore(self.compilation_cache_path, {
             "model": cfg.model, "dtype": cfg.dtype,
+            "model_config": repr(self.model_config),
             "kv_cache_dtype": cfg.kv_cache_dtype,
             "block_size": cfg.block_size,
             "num_kv_blocks": self.num_kv_blocks,
             "attn": self.attn_impl, "decode_loop": cfg.decode_loop,
             "mesh": sorted(dict(self.mesh.shape).items()),
+            "devices": [d.id for d in self.mesh.devices.flat],
             "b_max": self._b_max,
             "max_model_len": cfg.max_model_len,
             "max_num_batched_tokens": cfg.max_num_batched_tokens,
             "max_prefill_seqs": prefill_row_cap(cfg),
             "spec": cfg.speculative_num_tokens,
+            "spec_model": cfg.speculative_model,
             "spec_ring": self.spec_ring_len,
             "spec_adaptive": cfg.speculative_adaptive,
             "spec_tree": cfg.speculative_tree_width,
+            "lora": sorted(cfg.lora_modules),
             "logprob_buckets": LOGPROB_BUCKETS,
             "decode_families": self.reachable_decode_families(),
             "prefill_families": self.reachable_prefill_families(),
-        }
-        key = hashlib.blake2b(
-            _json.dumps(doc, sort_keys=True, default=str).encode(),
-            digest_size=12,
-        ).hexdigest()
-        return os.path.join(self.compilation_cache_path,
-                            f"pstpu-warmup-{key}.ok")
+            "source": source_digest(),
+            "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+            "backend": device.client.platform_version,
+            "device_kind": device.device_kind,
+            "flags": [os.environ.get(k, "")
+                      for k in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")],
+        })
 
     def warmup(self) -> None:
-        """Compile AND execute every reachable shape family before serving.
+        """Compile (or load) AND execute every reachable shape family before
+        serving.
 
-        Each family is driven through the jitted function itself (not
-        jit.lower().compile(), which fills the persistent XLA cache but NOT
-        the in-process pjit dispatch cache — the first real call would still
-        pay a full retrace + cache load inside the serving path). The dummy
+        Each family is run once through the very callable serving will
+        dispatch it through (``_dispatch``): a loaded executable of the
+        program table, or the jitted function itself (not a
+        jit.lower().compile() thrown away, which fills the persistent XLA
+        cache but NOT the in-process pjit dispatch cache — the first real
+        call would still pay a full retrace + cache load inside the
+        serving path). The dummy
         inputs are all-zero: a decode with per-row budget 0 and a prefill
         with chunk_lens 0 keep no token, so their trailing pool write
         (ops/kv_write.py) rewrites the null block with its own content.
@@ -3788,17 +3874,33 @@ class ModelRunner:
             first-use compile, persistent-cached thereafter.
         With the persistent compilation cache
         (config.compilation_cache_dir) all of this is paid once per
-        machine, not once per process — and on a MANIFEST-VERIFIED warm
-        boot (a previous identical boot completed the full warmup) the
-        logprobs/penalty variants are deferred outright: their first use
-        is a bounded persistent-cache LOAD (trace + deserialize, no XLA
-        compile), the same class as the combos above, so eager warm-boot
-        work shrinks to the default variants of every family
-        (docs/ELASTIC.md fast-start). Fast-start telemetry
-        (docs/ELASTIC.md): each compiled variant is classified as a
-        persistent-cache HIT (no new cache artifact appeared — the
-        executable deserialized instead of compiling) or MISS, and the
-        phase durations land in startup_{compile,warmup}_seconds.
+        machine, not once per process, and what a boot finds on disk
+        chooses its path (docs/ELASTIC.md fast-start; no option):
+          * COLD (no manifest under this boot's key, ``_program_store``):
+            every variant above is lowered from the very arguments it is
+            then run on, compiled (a persistent-cache HIT where no new
+            cache artifact appeared, else a MISS), STORED as its
+            serialized executable beside the cache, and run and served
+            through that one ``Compiled``. When all ran, the manifest is
+            written: the list of the programs stored.
+          * WARM (manifest-verified): the default variant of every family
+            is LOADED from its stored executable and run once on the zero
+            inputs; nothing of it is traced or lowered. The logprobs and
+            penalty variants are deferred: their first use loads the
+            stored executable (``_dispatch``). A loaded program counts as
+            a hit and in ``startup_loaded_families``.
+          * A stored file that is missing, short or refused by the backend
+            (or a loaded program that refuses its arguments) sends THAT
+            program down the traced path, counts as a miss, and drops the
+            manifest, so that the next boot stores everything again; so
+            does a manifest-verified boot that compiled anything.
+        A program the store cannot hold (one that closes over device
+        arrays: LoRA stacks) and every program warm-up does not enumerate
+        go through the jitted function as they always did, and so does
+        every program of a process without a cache directory.
+        The phase durations land in startup_{compile,warmup}_seconds, and
+        warm-up's own seconds split into loading, tracing + lowering +
+        compiling, and executing in its log line.
 
         With overlapped weight loading (config.overlap_weight_load) a
         compile-only PREPASS lowers+compiles every family against abstract
@@ -3819,20 +3921,31 @@ class ModelRunner:
         cfg = self.config
         mc = self.model_config
         # Warmup manifest (docs/ELASTIC.md): a previous FULLY successful
-        # warmup of this exact configuration proves every variant is in
-        # the persistent cache, so this boot eagerly warms only the
-        # DEFAULT (no-logprobs/no-penalties) variants — the deferred ones
-        # pay a bounded first-use cache load instead of a compile. Any
-        # config change keys a different manifest and warms cold.
-        manifest = self._warmup_manifest_path()
-        warm_verified = manifest is not None and _os.path.exists(manifest)
+        # warmup under this exact key stored every program it names, so
+        # this boot LOADS the DEFAULT (no-logprobs/no-penalties) variants
+        # and defers the others to a first-use load. A key that differs in
+        # anything names another manifest and warms cold.
+        store = self._program_store()
+        # Programs that close over device arrays bake them in: no file
+        # could stand for them (the LoRA stacks; ``serialize`` refuses).
+        self._store = store if not self.lora_stacks else None
+        stored = store.manifest() if store is not None else None
+        warm_verified = stored is not None
+        self._stored = stored if warm_verified and self._store else frozenset()
+        # Stored once and gone since (a pruned directory): each takes the
+        # traced path and counts as a miss, a deferred variant too, whose
+        # first use would else find it out inside serving.
+        lost = {k for k in self._stored
+                if not _os.path.exists(store.path(k))}
+        self._stored -= lost
+        ran = set()
         self.startup_deferred_families = 0
         prepassed = 0
         if warm_verified:
             logger.info(
-                "Warmup manifest present (%s): deferring non-default "
-                "sampling variants to first-use persistent-cache loads",
-                _os.path.basename(manifest),
+                "Warmup manifest present (%s): loading %d stored programs, "
+                "non-default sampling variants at their first use",
+                _os.path.basename(store.manifest_path), len(self._stored),
             )
         elif self._params is None and self._param_thread is not None:
             tc = _time.monotonic()
@@ -3857,16 +3970,23 @@ class ModelRunner:
         t0 = _time.monotonic()
         count_dir = self.compilation_cache_path
         call_idx = 0
+        saved = set()
+        spent = {"load": 0.0, "trace": 0.0, "execute": 0.0}
+
+        def timed(phase, fn, *args, **kwargs):
+            t = _time.monotonic()
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                spent[phase] += _time.monotonic() - t
 
         def counted(fn, *args, **kwargs):
-            """Run one warmup call, classifying it as a persistent-cache
+            """Run one compiling call, classifying it as a persistent-cache
             hit or miss by whether a new cache artifact appeared. The
             first ``prepassed`` calls were already classified by the
             prepass (same enumeration order) — re-counting them here
             would double-book, and its freshly written artifacts would
             masquerade as hits."""
-            nonlocal call_idx
-            call_idx += 1
             if count_dir is None or call_idx <= prepassed:
                 return fn(*args, **kwargs)
             before = _cache_entries(count_dir)
@@ -3879,23 +3999,86 @@ class ModelRunner:
                     self.startup_cache_hit_families += 1
             return out
 
+        def traced(kind, args, static):
+            # One call traces, lowers, compiles (or finds) and enqueues.
+            return timed("trace", counted, self._jitted(kind), *args,
+                         **static)
+
+        def compile_and_store(kind, key, args, static):
+            # Lowered from the very arguments it is then run on: what a
+            # jitted call would have traced, committed shardings and all.
+            before = _cache_entries(count_dir)
+            compiled = timed(
+                "trace",
+                lambda: self._jitted(kind).lower(*args, **static).compile())
+            after = _cache_entries(count_dir)
+            fresh = before is None or after is None or bool(after - before)
+            if call_idx > prepassed:   # the prepass classified the first
+                if fresh:
+                    self.startup_cache_miss_families += 1
+                else:
+                    self.startup_cache_hit_families += 1
+            if fresh or self._stores_loaded:
+                try:
+                    self._store.save(key, compiled)
+                    saved.add(key)
+                except Exception:  # noqa: BLE001 — the next boot traces it
+                    logger.warning("Program %s was not stored", key,
+                                   exc_info=True)
+            self._programs[key] = compiled
+            return timed("execute", compiled, *args)
+
+        def run(kind, program, args, static):
+            """One variant, once, on its zero inputs. Cold: compiled here,
+            stored, and run through that ``Compiled``. Warm: through its
+            stored executable. Through the jitted function where there is
+            no store, where no boot could store this program, or where
+            the store fails it (a miss)."""
+            nonlocal call_idx
+            call_idx += 1
+            key = program["key"]
+            ran.add(key)
+            if self._store is None:
+                return traced(kind, args, static)
+            if not warm_verified:
+                return compile_and_store(kind, key, args, static)
+            if key not in stored:
+                return traced(kind, args, static)
+            loaded = timed("load", self._load_program, key) \
+                if key in self._stored else None
+            if loaded is not None:
+                try:
+                    out = timed("execute", loaded, *args)
+                    self.startup_cache_hit_families += 1
+                    self.startup_loaded_families += 1
+                    return out
+                except Exception:  # noqa: BLE001 — refused: trace it
+                    # Arguments are checked before anything runs: the
+                    # donated pools are whole.
+                    logger.exception(
+                        "Stored program %s refused its arguments: it is "
+                        "traced, and the manifest dropped", key)
+                    self._forget_program(key)
+            # Stored once and lost since: a miss, whatever JAX's cache
+            # still holds of it.
+            self.startup_cache_miss_families += 1
+            return timed("trace", self._jitted(kind), *args, **static)
+
         # Where the devices report their memory the ledger reads the
         # allocator right before and right after each family's enqueue:
         # the difference is what the count shows of the program (its code
         # and outputs). No sync: a family still running changes neither
         # read (its temporaries are in no count; PERF.md section 6, PR 49),
         # and a sync a family cost a warm boot 2-3 s.
-        from production_stack_tpu.engine.flight_recorder import compile_clock
-
         reads = bool(self.memory.reading())
         clock = compile_clock()
 
-        def measured(program, rows, fn, *args, **kwargs):
+        def measured(kind, program, rows, args, static):
             if not reads:
-                return counted(fn, *args, **kwargs)
+                return run(kind, program, args, static)
             self.memory.quiet()
             before = clock.reading()
-            out = counted(fn, *args, **kwargs)
+            out = run(kind, program, args, static)
             self.memory.issued(n_warmed, program, rows, clock.since(before))
             self.memory.fetched(n_warmed)
             return out
@@ -3938,19 +4121,19 @@ class ModelRunner:
                         kv_ks, kv_vs = self._scale_pool_args()
                         dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
                         out = measured(
+                            "decode",
                             self.program("decode", (db, mb, dk, cached),
                                          pen, lpk, sp_on), db,
-                            self._decode,
-                            self.params,
-                            jnp.zeros((NUM_SCALARS * db + db * mb,),
-                                      jnp.int32),
-                            self.kv_k, self.kv_v, kv_ks, kv_vs, wk, wv,
-                            counts, self._zero_last, dparams, sp_k, sp_v,
-                            sp_p, self.state_pools,
-                            b=db, mb=mb, num_steps=dk,
-                            use_cached_window=cached,
-                            has_penalties=pen, logprobs_k=lpk,
-                            spec_on=sp_on,
+                            (self.params,
+                             jnp.zeros((NUM_SCALARS * db + db * mb,),
+                                       jnp.int32),
+                             self.kv_k, self.kv_v, kv_ks, kv_vs, wk, wv,
+                             counts, self._zero_last, dparams, sp_k, sp_v,
+                             sp_p, self.state_pools),
+                            dict(b=db, mb=mb, num_steps=dk,
+                                 use_cached_window=cached,
+                                 has_penalties=pen, logprobs_k=lpk,
+                                 spec_on=sp_on),
                         )
                         _, self.kv_k, self.kv_v = out[0], out[1], out[2]
                         self._rebind_scale_pools(out[3], out[4])
@@ -3989,14 +4172,14 @@ class ModelRunner:
                     kv_ks, kv_vs = self._scale_pool_args()
                     dparams, sp_k, sp_v, sp_p = self._spec_pool_args()
                     out = measured(
+                        "prefill",
                         self.program("prefill", (pb, t, mb, has_window),
                                      pen, lpk), seqs,
-                        self._prefill,
-                        self.params,
-                        jnp.zeros((length,), jnp.int32),
-                        self.kv_k, self.kv_v, kv_ks, kv_vs, counts,
-                        dparams, sp_k, sp_v, sp_p, self.state_pools,
-                        **shape, has_penalties=pen, logprobs_k=lpk,
+                        (self.params,
+                         jnp.zeros((length,), jnp.int32),
+                         self.kv_k, self.kv_v, kv_ks, kv_vs, counts,
+                         dparams, sp_k, sp_v, sp_p, self.state_pools),
+                        dict(**shape, has_penalties=pen, logprobs_k=lpk),
                     )
                     self.kv_k, self.kv_v = out[1], out[2]
                     self._rebind_scale_pools(out[3], out[4])
@@ -4009,8 +4192,9 @@ class ModelRunner:
                 t_ing = 16
                 t_max = max(16, 1 << (self.spec_ring_len - 1).bit_length())
                 while t_ing <= t_max:
-                    self.spec_k, self.spec_v, self.spec_pos = counted(
-                        self._spec_ingest_jit,
+                    call_idx += 1
+                    self.spec_k, self.spec_v, self.spec_pos = timed(
+                        "trace", counted, self._spec_ingest_jit,
                         self.spec_params, self.spec_k, self.spec_v,
                         self.spec_pos, jnp.int32(0),
                         jnp.zeros((t_ing,), jnp.int32), jnp.int32(0),
@@ -4020,48 +4204,50 @@ class ModelRunner:
                     t_ing *= 2
             # Warmup dispatches block-wait on the last output so compile
             # failures surface here, not mid-serving.
-            jax.block_until_ready(self.kv_k)
+            timed("execute", jax.block_until_ready, self.kv_k)
+            self.startup_cache_miss_families += len(lost - ran)
             if count_dir is None:
                 # No persistent cache configured: every variant compiled
                 # from scratch — an all-miss boot by definition.
                 self.startup_cache_hit_families = 0
                 self.startup_cache_miss_families = n_warmed
             logger.info(
-                "Warmup: %d shape families compiled+executed (attn=%s) "
-                "in %.1fs (persistent cache: %d hit / %d miss; %d "
-                "variants deferred to first-use cache loads)",
+                "Warmup: %d shape families run (attn=%s) in %.1fs: %d "
+                "loaded from stored executables (load %.1fs), trace + "
+                "lower + compile %.1fs, execute %.1fs (persistent cache: "
+                "%d hit / %d miss; %d variants deferred to first-use "
+                "loads)",
                 n_warmed, self.attn_impl, _time.monotonic() - t0,
+                self.startup_loaded_families, spent["load"],
+                spent["trace"], spent["execute"],
                 self.startup_cache_hit_families,
                 self.startup_cache_miss_families,
                 self.startup_deferred_families,
             )
             self.startup_warmup_seconds = _time.monotonic() - t0
             self.startup_warmed_families = n_warmed
-            if manifest is not None:
+            if store is not None:
                 if not warm_verified and \
                         self.startup_cache_hit_families \
                         + self.startup_cache_miss_families > 0:
-                    # Every variant is now persistently cached: later
-                    # identical boots may defer the non-default variants.
+                    # Every variant is now persistently cached, and those
+                    # the manifest lists are stored: later boots under
+                    # this key load them and defer the non-default ones.
                     try:
-                        with open(manifest, "w") as f:
-                            f.write("complete\n")
+                        store.write_manifest(saved)
                     except OSError:
                         logger.warning("Could not write warmup manifest",
                                        exc_info=True)
                 elif warm_verified and self.startup_cache_miss_families:
-                    # The cache was pruned under the manifest: the
-                    # deferral proof no longer holds — drop it so the
-                    # next boot re-warms (and re-caches) everything.
+                    # The cache or the store was pruned under the
+                    # manifest: its proof no longer holds — drop it so the
+                    # next boot re-warms (and stores) everything.
                     logger.warning(
-                        "Warmup manifest was stale (%d cache misses on a "
+                        "Warmup manifest was stale (%d misses on a "
                         "verified-warm boot); removing it",
                         self.startup_cache_miss_families,
                     )
-                    try:
-                        _os.unlink(manifest)
-                    except OSError:
-                        pass
+                    store.drop_manifest()
         except Exception:  # noqa: BLE001 — warmup must never kill serving
             self.startup_warmup_failures += 1
             logger.exception("Warmup compilation failed (continuing)")

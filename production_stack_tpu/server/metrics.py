@@ -38,7 +38,7 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
                 "startup_weight_load_seconds", "startup_compile_seconds",
                 "startup_warmup_seconds", "startup_prewarm_seconds",
                 "startup_total_seconds", "startup_cache_hit_families",
-                "startup_cache_miss_families",
+                "startup_cache_miss_families", "startup_loaded_families",
                 "trace_spans_dropped_total",
                 "host_stall_seconds_total",
                 "loop_schedule_seconds_total", "loop_issue_seconds_total",
@@ -264,6 +264,11 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
         "# TYPE pstpu:startup_cache_miss_families gauge",
         f"pstpu:startup_cache_miss_families{label} "
         f"{s['startup_cache_miss_families']}",
+        "# HELP pstpu:startup_loaded_families Warmup variants loaded from "
+        "the runner's stored executables and not traced (each also a hit)",
+        "# TYPE pstpu:startup_loaded_families gauge",
+        f"pstpu:startup_loaded_families{label} "
+        f"{s['startup_loaded_families']}",
         # Two-slot dispatch-pipeline telemetry (engine.py:_run_loop): the
         # prefill/decode overlap win is observable, not asserted.
         "# HELP pstpu:decode_dispatches_total Fused decode dispatches issued",

@@ -294,6 +294,10 @@ REGISTRY: Tuple[Series, ...] = (
            (ENGINE,), ("catalogue", "elastic"),
            "Warmup variants that compiled from scratch (cold cache or "
            "changed config)"),
+    Series("pstpu:startup_loaded_families", "gauge", ("model_name",),
+           (ENGINE,), ("catalogue", "elastic"),
+           "Warmup variants loaded from the runner's stored executables "
+           "and not traced (each also a hit)"),
     # ------------------------------------------ engine: request lifecycle
     # (docs/OBSERVABILITY.md): per-phase latency split — where a request's
     # TTFT went — plus tracing exporter hygiene.

@@ -10,6 +10,10 @@ call site rebinds them **in the same statement** —
 
     self.kv_k, self.kv_v = ... = self._decode(..., self.kv_k, self.kv_v, ...)
 
+(or, the same operands as a tuple, ``self._dispatch("decode", program,
+(..., self.kv_k, self.kv_v, ...), static)``, which calls the binding named
+by its first argument or the loaded executable compiled from it).
+
 This rule makes that idiom the checked contract. Per module it builds the
 jit binding graph (tools/pstpu_lint/jaxmodel.py): which bindings hold a
 donating dispatch (direct ``jax.jit`` assignments, decorated defs, and
@@ -122,6 +126,19 @@ class _BodyScan:
                 continue
             key = _read_key(node.func)
             if key is None:
+                continue
+            if key == "self._dispatch" and len(node.args) >= 3 \
+                    and isinstance(node.args[0], ast.Constant) \
+                    and isinstance(node.args[2], ast.Tuple):
+                # runner._dispatch("decode", program, (operands), static):
+                # the operands go to self._decode or to the loaded
+                # executable compiled from it, which donates the same.
+                binding = self.model.bindings.get(
+                    f"self._{node.args[0].value}")
+                if binding is not None and binding.donate:
+                    yield ast.copy_location(
+                        ast.Call(func=node.func, args=node.args[2].elts,
+                                 keywords=[]), node), binding
                 continue
             binding = self.model.bindings.get(key)
             if binding is None and key.startswith("self."):
