@@ -492,7 +492,8 @@ class EngineConfig:
 
         kv = specs.paged_kv
         worst_window_bytes = (
-            specs.kv_pools * kv.layers * kv.kv_heads * kv.head_dim
+            kv.layers * kv.kv_heads
+            * (kv.head_dim + specs.second_pool_dim)
             * jnp.dtype(self.dtype).itemsize
             * self.max_model_len * self.max_num_seqs
         )
@@ -515,10 +516,13 @@ class EngineConfig:
         specs = cache_specs(model_config)
         kv = specs.paged_kv
         if self.kv_cache_quantized:
-            per_slot = kv.head_dim + SCALE_ITEMSIZE
+            per_slot = specs.kv_pools * (kv.head_dim + SCALE_ITEMSIZE)
         else:
-            per_slot = kv.head_dim * jnp.dtype(self.dtype).itemsize
-        return specs.kv_pools * kv.layers * kv.kv_heads * per_slot
+            # Both pools' rows: keys and values, or the latent row and
+            # whatever lies beside it (an indexer's key, or nothing).
+            per_slot = (kv.head_dim + specs.second_pool_dim) \
+                * jnp.dtype(self.dtype).itemsize
+        return kv.layers * kv.kv_heads * per_slot
 
     def state_bytes_per_seq(self, model_config) -> int:
         """Bytes of recurrent state one sequence holds whole, over every

@@ -1529,6 +1529,16 @@ class ServingEngine:
             **({"moe_assignments_elsewhere_total":
                 dec["assignments_elsewhere"] + pre["assignments_elsewhere"]}
                if "assignments_elsewhere" in dec else {}),
+            # Only where the module's full layers select the keys they read
+            # (a learned indexer): decode's and prefill's apart, as a
+            # decode step READS what it selected and a chunk masks.
+            **({"index_keys_visible_total": dec["index_keys_visible"],
+                "index_keys_selected_total": dec["index_keys_selected"],
+                "index_prefill_keys_visible_total":
+                    pre["index_keys_visible"],
+                "index_prefill_keys_selected_total":
+                    pre["index_keys_selected"]}
+               if "index_keys_visible" in dec else {}),
         }
 
     def _live_perf(self) -> Dict[str, float]:

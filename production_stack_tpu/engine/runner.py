@@ -437,11 +437,12 @@ class ModelRunner:
         self.state_specs = specs.state
         # Pools a layer keeps a token in (keys and values; one where the
         # rows are latent rows, which are both), and the width of a token's
-        # row in the second pool: 0 where there is none. The second pool
+        # row in the second pool: 0 where there is none (the second pool
         # then exists with no byte in it, so that every program keeps its
-        # operands and the writes below adapt to nothing but shapes.
+        # operands and the writes below adapt to nothing but shapes), or
+        # the lanes of an indexer's key beside a latent row.
         self.kv_pools = specs.kv_pools
-        self.kv_v_dim = self.kv_spec.head_dim if specs.latent is None else 0
+        self.kv_v_dim = specs.second_pool_dim
         # Lanes of a token's row that are its values: the row of the second
         # pool, or the head of a latent row (its compressed KV).
         self.kv_value_dim = self.kv_spec.head_dim if specs.latent is None \
@@ -1215,9 +1216,9 @@ class ModelRunner:
         mc, cfg = self.model_config, self.config
         bytes_per_block = cfg.kv_cache_bytes_per_block(mc)
         window_bytes_per_block = (
-            self.kv_pools * self.kv_spec.layers * cfg.block_size
-            * self.kv_spec.kv_heads
-            * self.kv_spec.head_dim * jnp.dtype(self.dtype).itemsize
+            self.kv_spec.layers * cfg.block_size * self.kv_spec.kv_heads
+            * (self.kv_spec.head_dim + self.kv_v_dim)
+            * jnp.dtype(self.dtype).itemsize
         )
         # The budget is PER DEVICE: the least free HBM over the devices of
         # the engine's own mesh (not whatever jax.local_devices()[0] is).

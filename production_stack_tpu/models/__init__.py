@@ -11,6 +11,7 @@ outside this package asks for an architecture by name.
 from production_stack_tpu.models import (
     afmoe,
     deepseek_v3,
+    dots3_note,
     granite_hybrid,
     lfm2_moe,
     llama,
@@ -32,7 +33,7 @@ from production_stack_tpu.models.config import (
 _ARCHS = {"llama": llama, "opt": opt, "olmo_hybrid": olmo_hybrid,
           "deepseek_v3": deepseek_v3, "granite_hybrid": granite_hybrid,
           "lfm2_moe": lfm2_moe, "afmoe": afmoe, "mimo_v2": mimo_v2,
-          "phi4flash": phi4flash}
+          "phi4flash": phi4flash, "dots3_note": dots3_note}
 
 
 def get_model(cfg: ModelConfig):

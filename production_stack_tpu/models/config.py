@@ -52,9 +52,18 @@ class LatentKVSpec(NamedTuple):
     and the values whole), then the rotary key every head shares
     (``rope_dim``, after rope), then zeros up to whole 128-lane tiles. There
     is no second pool: ``paged_kv`` then describes that one pool
-    (``kv_heads`` 1, ``head_dim`` the padded row)."""
+    (``kv_heads`` 1, ``head_dim`` the padded row). ``index_dim`` > 0: a
+    token ALSO caches the key of a learned sparse-attention indexer
+    (models/dots3_note.py), ``index_dim`` lanes (whole tiles), in the SECOND
+    pool, which is otherwise empty: the same block table, the same write,
+    and a block of that pool is whole tiles, so an index scan reads its
+    keys and nothing of the latent rows (a slice of the latent row's last
+    tile by block cannot be gathered where it lies: XLA lays the whole pool
+    out again for it, 15 GB at a deployment's size; PERF.md section 6,
+    PR 58)."""
     rank: int
     rope_dim: int
+    index_dim: int = 0
 
     @property
     def width(self) -> int:
@@ -91,11 +100,18 @@ class CacheSpecs(NamedTuple):
         one latent row."""
         return 1 if self.latent is not None else 2
 
+    @property
+    def second_pool_dim(self) -> int:
+        """Lanes of a token's row in the second pool: its values, nothing
+        beside a latent row, or that row's index key."""
+        return self.paged_kv.head_dim if self.latent is None \
+            else self.latent.index_dim
+
 
 @dataclass(frozen=True)
 class ModelConfig:
     # "llama" | "opt" | "olmo_hybrid" | "deepseek_v3" | "granite_hybrid" |
-    # "lfm2_moe" | "afmoe" | "mimo_v2" | "phi4flash"
+    # "lfm2_moe" | "afmoe" | "mimo_v2" | "phi4flash" | "dots3_note"
     arch: str = "llama"
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -207,6 +223,24 @@ class ModelConfig:
     # sliding_window is the window layers' span and their ring's slots.
     mamba_d_inner: int = 0
     mamba_dt_rank: int = 0
+    # models/dots3_note.py. Both kinds of layer are latent attention: a
+    # "full_attention" layer has the sizes above (num_heads, q_lora_rank,
+    # kv_lora_rank, qk_*_head_dim, v_head_dim, rope_theta) and a learned
+    # indexer of index_n_heads heads of index_head_dim lanes that picks the
+    # index_topk keys a query attends; a "sliding_attention" layer has the
+    # swa_* sizes and swa_rope_theta and sees the sliding_window newest
+    # keys, itself included. mla_lora_rescale: the normed latents are
+    # scaled by (hidden / rank) ** 0.5.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    swa_num_heads: int = 0
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_head_dim: int = 0
+    swa_qk_rope_head_dim: int = 0
+    swa_v_head_dim: int = 0
+    mla_lora_rescale: bool = False
 
     def __post_init__(self):
         if self.arch in ANY_ORDER_LISTS:
@@ -497,6 +531,8 @@ class ModelConfig:
             )
         if model_type == "mimo_v2":
             return _mimo_v2_config(d, name)
+        if model_type == "dots3_note":
+            return _dots3_note_config(d, name)
         if model_type == "phi4flash":
             return _phi4flash_config(d, name)
         raise ValueError(f"Unsupported model_type: {model_type}")
@@ -524,7 +560,8 @@ FREE_LAYER_LISTS = {"lfm2_moe": ("conv", "full_attention")}
 # and whether it rotates are two scalars its scans index), so its list is
 # taken in any order and need not hold both kinds.
 ANY_ORDER_LISTS = {"afmoe": ("sliding_attention", "full_attention"),
-                   "mimo_v2": ("sliding_attention", "full_attention")}
+                   "mimo_v2": ("sliding_attention", "full_attention"),
+                   "dots3_note": ("sliding_attention", "full_attention")}
 
 
 def _mimo_v2_config(d: dict, name: str) -> ModelConfig:
@@ -614,6 +651,104 @@ def _mimo_v2_config(d: dict, name: str) -> ModelConfig:
         num_experts_per_tok=d["num_experts_per_tok"],
         moe_intermediate_size=d["moe_intermediate_size"],
         first_k_dense_replace=dense,
+        routed_scaling_factor=float(d.get("routed_scaling_factor") or 1.0),
+        norm_topk_prob=d.get("norm_topk_prob", True),
+        ep_size=ep_size, ep_rank=ep_rank,
+        name=name,
+    )
+
+
+def _dots3_note_config(d: dict, name: str) -> ModelConfig:
+    """``model_type: dots3_note`` (models/dots3_note.py): what the module
+    does not implement is refused by its key, not served as something else;
+    so is every key of the towers and heads the language model's row leaves
+    out (vision, audio, next-token prediction)."""
+    types = list(d["layer_types"])
+    kinds = ANY_ORDER_LISTS["dots3_note"]
+    ep_size, ep_rank = int(d.get("ep_size", 1)), int(d.get("ep_rank", 0))
+    window = d.get("sliding_window_size") or 0
+    unsupported = {
+        "attention_gate_type != headwise":
+            d.get("attention_gate_type") != "headwise",
+        "swa_attention_gate_type != headwise":
+            d.get("swa_attention_gate_type") != "headwise",
+        "rope_scaling": d.get("rope_scaling") is not None,
+        "n_shared_experts != 1": d.get("n_shared_experts") != 1,
+        "scoring_func != sigmoid":
+            d.get("scoring_func", "sigmoid") != "sigmoid",
+        "topk_method != noaux_tc":
+            d.get("topk_method", "noaux_tc") != "noaux_tc",
+        "moe_layer_freq != 1": d.get("moe_layer_freq", 1) != 1,
+        "attention_bias": bool(d.get("attention_bias", False)),
+        "n_group/topk_group > 1":
+            max(d.get("n_group") or 1, d.get("topk_group") or 1) > 1,
+        "hidden_act != silu": d.get("hidden_act", "silu") != "silu",
+        "tie_word_embeddings": bool(d.get("tie_word_embeddings", False)),
+        "sliding_window_size < 1": window < 1,
+        "index_topk < 1": (d.get("index_topk") or 0) < 1,
+        "q_lora_rank / swa_q_lora_rank: a low-rank query is the only one "
+        "served": not d.get("q_lora_rank") or not d.get("swa_q_lora_rank"),
+        "num_key_value_heads != num_attention_heads (latent attention has "
+        "no grouped heads)": (
+            d.get("num_key_value_heads", d["num_attention_heads"]),
+            d.get("swa_num_key_value_heads", d["swa_num_attention_heads"]),
+        ) != (d["num_attention_heads"], d["swa_num_attention_heads"]),
+        "qk_rope_head_dim / swa_qk_rope_head_dim / index_head_dim: an even "
+        "number of rope lanes, the index head at least as wide":
+            d["qk_rope_head_dim"] % 2 != 0
+            or d["swa_qk_rope_head_dim"] % 2 != 0
+            or d["index_head_dim"] < d["qk_rope_head_dim"],
+        "layer_types: an entry a layer, sliding_attention or "
+        "full_attention": len(types) != d["num_hidden_layers"]
+        or bool(set(types) - set(kinds)),
+        "layer_types needs a layer of each kind": set(types) != set(kinds),
+        "first_k_dense_replace >= num_hidden_layers":
+            d.get("first_k_dense_replace", 0) >= d["num_hidden_layers"],
+        "ep_rank outside ep_size": not 0 <= ep_rank < max(ep_size, 1),
+        **{f"{k} (a vision or audio tower or a next-token-prediction head "
+           f"is not served)": True for k, v in d.items()
+           if v and ("vision" in k or "audio" in k
+                     or k == "num_nextn_predict_layers")},
+    }
+    asked = [k for k, on in unsupported.items() if on]
+    if asked:
+        raise ValueError(f"dots3_note: not supported: {', '.join(asked)}")
+    return ModelConfig(
+        arch="dots3_note",
+        vocab_size=d["vocab_size"],
+        hidden_size=d["hidden_size"],
+        intermediate_size=d["intermediate_size"],
+        num_layers=d["num_hidden_layers"],
+        num_heads=d["num_attention_heads"],
+        num_kv_heads=d["num_attention_heads"],
+        max_position_embeddings=d.get("max_position_embeddings", 4096),
+        rope_theta=float(d.get("rope_theta", 10000.0)),
+        rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+        tie_word_embeddings=False,
+        layer_types=tuple(types),
+        sliding_window=window,
+        kv_lora_rank=d["kv_lora_rank"],
+        q_lora_rank=d["q_lora_rank"],
+        qk_nope_head_dim=d["qk_nope_head_dim"],
+        qk_rope_head_dim=d["qk_rope_head_dim"],
+        v_head_dim=d["v_head_dim"],
+        index_n_heads=d["index_n_heads"],
+        index_head_dim=d["index_head_dim"],
+        index_topk=d["index_topk"],
+        swa_num_heads=d["swa_num_attention_heads"],
+        swa_q_lora_rank=d["swa_q_lora_rank"],
+        swa_kv_lora_rank=d["swa_kv_lora_rank"],
+        swa_qk_nope_head_dim=d["swa_qk_nope_head_dim"],
+        swa_qk_rope_head_dim=d["swa_qk_rope_head_dim"],
+        swa_v_head_dim=d["swa_v_head_dim"],
+        swa_rope_theta=float(d.get("swa_rope_theta",
+                                   d.get("rope_theta", 10000.0))),
+        mla_lora_rescale=bool(d.get("apply_mla_qkv_lora_rescale", False)),
+        n_routed_experts=d["n_routed_experts"],
+        num_experts_per_tok=d["num_experts_per_tok"],
+        n_shared_experts=1,
+        moe_intermediate_size=d["moe_intermediate_size"],
+        first_k_dense_replace=d.get("first_k_dense_replace", 0),
         routed_scaling_factor=float(d.get("routed_scaling_factor") or 1.0),
         norm_topk_prob=d.get("norm_topk_prob", True),
         ep_size=ep_size, ep_rank=ep_rank,
@@ -941,12 +1076,40 @@ TINY_PHI4FLASH = ModelConfig(
 TINY_MIMO_V2_EP4 = dataclasses.replace(
     TINY_MIMO_V2, n_routed_experts=4, ep_size=4, ep_rank=1,
     name="tiny-mimo-v2-ep4")
+# Tiny dots3-note decoder: two kinds of latent attention in the published
+# order (full, full, then sliding x3 + full), the full kind with 4 heads
+# over a latent of 128 + 16 rope lanes (whole lanes, as the paged path asks:
+# a row is 256 lanes and the index key's tile 128 more) and an indexer of 2
+# heads x 128 that picks 48 keys, the sliding kind with 2 heads over a latent
+# of 64 + 16 and a window of 33 keys (an odd number, as the published 513
+# is), 1 leading dense layer, 16 experts top-4 beside a shared one
+# (tests/test_dots3.py compares it with the plain reference); and the same
+# as rank 1 of 4 chips that share every sparse layer's experts.
+TINY_DOTS3 = ModelConfig(
+    arch="dots3_note", vocab_size=512, hidden_size=128,
+    intermediate_size=256, num_layers=6, num_heads=4, num_kv_heads=4,
+    max_position_embeddings=1024, rope_theta=80000000.0, rms_norm_eps=1e-5,
+    layer_types=("full_attention", "full_attention", "sliding_attention",
+                 "sliding_attention", "sliding_attention", "full_attention"),
+    sliding_window=33, kv_lora_rank=128, q_lora_rank=48, qk_nope_head_dim=32,
+    qk_rope_head_dim=16, v_head_dim=32, index_n_heads=2, index_head_dim=128,
+    index_topk=48, swa_num_heads=2, swa_q_lora_rank=48, swa_kv_lora_rank=64,
+    swa_qk_nope_head_dim=48, swa_qk_rope_head_dim=16, swa_v_head_dim=32,
+    swa_rope_theta=50000.0, mla_lora_rescale=True, n_routed_experts=16,
+    num_experts_per_tok=4, n_shared_experts=1, moe_intermediate_size=64,
+    first_k_dense_replace=1, name="tiny-dots3",
+)
+TINY_DOTS3_EP4 = dataclasses.replace(
+    TINY_DOTS3, n_routed_experts=4, ep_size=4, ep_rank=1,
+    name="tiny-dots3-ep4")
 
 NAMED_CONFIGS = {
     "tiny-llama": TINY_LLAMA,
     "tiny-phi4flash": TINY_PHI4FLASH,
     "tiny-mimo-v2": TINY_MIMO_V2,
     "tiny-mimo-v2-ep4": TINY_MIMO_V2_EP4,
+    "tiny-dots3": TINY_DOTS3,
+    "tiny-dots3-ep4": TINY_DOTS3_EP4,
     "tiny-afmoe": TINY_AFMOE,
     "tiny-lfm2-moe": TINY_LFM2_MOE,
     "tiny-granite-hybrid": TINY_GRANITE_HYBRID,

@@ -317,14 +317,15 @@ def window_ring_positions(start: jax.Array, window: int) -> jax.Array:
 def window_ring_attend(
     q: jax.Array,            # [B, T, H, Dk] queries (post-rope)
     k: jax.Array,            # [B, T, Hkv, Dk] this chunk's keys
-    v: jax.Array,            # [B, T, Hkv, Dv]
+    v: Optional[jax.Array],  # [B, T, Hkv, Dv]; None: latent rows
     positions: jax.Array,    # [B, T] consecutive from a row's positions[:, 0]
     chunk_lens: jax.Array,   # [B] valid tokens per row
     ring_k: jax.Array,       # [B, Hkv, W, Dk] the rows' rings BEFORE the chunk
-    ring_v: jax.Array,       # [B, Hkv, W, Dv]
+    ring_v: Optional[jax.Array] = None,  # [B, Hkv, W, Dv]
     *,
     scale: float,
     sink: Optional[jax.Array] = None,    # [H] float32
+    value_dim: Optional[int] = None,     # latent rows (``v`` None) only
 ) -> jax.Array:
     """Attention of a layer whose queries see the W newest keys up to
     themselves and whose sequences keep exactly those: a per-sequence ring
@@ -338,12 +339,22 @@ def window_ring_attend(
     the first) and its own: [.., W, 2 W] scores a block, never [T, T].
     ``sink``: see ``sink_merged``. A ring whose rows are wider than the
     chunk's (whole 128-lane tiles: models/mimo_v2.py:ring_width) is read up
-    to the chunk's width. Returns [B, T, H, Dv] in q.dtype."""
+    to the chunk's width. Returns [B, T, H, Dv] in q.dtype.
+
+    ``v`` None: LATENT rows (models/dots3_note.py): one ring, ``k`` the
+    chunk's rows [B, T, 1, Dk], the values a row's first ``value_dim``
+    lanes: [B, T, H, value_dim]. A window that does not divide the chunk
+    (513 in 2048) is scored in blocks of the power of two of queries at or
+    above ``W - 1``: block n sees the ``W`` entries before its own and its
+    own, of the ring and the chunk end to end."""
     b, t, h, dk = q.shape
     hkv, w = ring_k.shape[1], ring_k.shape[2]
     g = h // hkv
     with jax.named_scope("ring_attend"):
-        ring_k, ring_v = ring_k[..., :dk], ring_v[..., :v.shape[-1]]
+        if v is None:
+            ring_k = ring_k[..., :dk]
+        else:
+            ring_k, ring_v = ring_k[..., :dk], ring_v[..., :v.shape[-1]]
         start = positions[:, 0]
         t_idx = jnp.arange(t, dtype=jnp.int32)
         # A key past its row's length lies ahead of every query.
@@ -353,11 +364,17 @@ def window_ring_attend(
             [window_ring_positions(start, w), pos_c], axis=1)  # [B, W + T]
         keys = jnp.concatenate(
             [ring_k.astype(k.dtype), k.transpose(0, 2, 1, 3)], axis=2)
-        vals = jnp.concatenate(
+        vals = keys[..., :value_dim] if v is None else jnp.concatenate(
             [ring_v.astype(v.dtype), v.transpose(0, 2, 1, 3)], axis=2)
         # Blocks of queries and the keys each can see: whole windows where
         # the chunk is, else the one block of everything.
         tq = w if t % w == 0 and t > w else t
+        if tq == t and t > w:
+            # A window that does not divide the chunk: the smallest power
+            # of two of queries whose block needs no key behind the W
+            # entries before it, where that divides the chunk.
+            tq = 1 << max(w - 2, 0).bit_length()
+            tq = tq if t % tq == 0 and t > tq else t
         nb = t // tq
 
         def blocks(x, axis):
@@ -365,6 +382,11 @@ def window_ring_attend(
             # entries of its queries and the W before them.
             if nb == 1:
                 return jnp.expand_dims(x, axis)
+            if tq != w:
+                return jnp.stack(
+                    [jax.lax.slice_in_dim(x, n * tq, n * tq + w + tq,
+                                          axis=axis) for n in range(nb)],
+                    axis=axis)
             shape = x.shape[:axis] + (nb, tq) + x.shape[axis + 1:]
             before = jax.lax.slice_in_dim(x, 0, t, axis=axis).reshape(shape)
             own = jax.lax.slice_in_dim(x, w, w + t, axis=axis).reshape(shape)
@@ -445,7 +467,7 @@ def window_ring_write(
 
 
 def window_ring_step_jnp(rings, at, q, k, v, positions, chunk_lens, *,
-                         scale, sink=None):
+                         scale, sink=None, value_dim=None):
     """``window_ring_step`` as plain ``jnp``: the statement of a decode
     step (``window_ring_attend`` over the layer's rings sliced out of the
     carry, then ``window_ring_write``), the path of a backend without the
@@ -453,8 +475,9 @@ def window_ring_step_jnp(rings, at, q, k, v, positions, chunk_lens, *,
     ring = tuple(jax.lax.dynamic_index_in_dim(r, at, 1, False)
                  for r in rings)
     attn = window_ring_attend(q, k, v, positions, chunk_lens, *ring,
-                              scale=scale, sink=sink)
-    return attn, window_ring_write(rings, at, (k, v), positions, chunk_lens)
+                              scale=scale, sink=sink, value_dim=value_dim)
+    new = (k,) if v is None else (k, v)
+    return attn, window_ring_write(rings, at, new, positions, chunk_lens)
 
 
 def window_ring_step(
@@ -469,6 +492,7 @@ def window_ring_step(
     scale: float,
     sink: Optional[jax.Array] = None,    # [H] float32
     interpret: bool = False,
+    value_dim: Optional[int] = None,     # latent rows (``v`` None) only
 ):
     """One decode step (T == 1) of a window layer on layer ``at`` of the
     rows' carried rings: (the attention [B, 1, H, Dv] of the rows that take
@@ -489,7 +513,18 @@ def window_ring_step(
     runner's Pallas interpret switch: a CPU's tests); every other holds the
     ``jnp`` form. The two round alike (scores and statistics in float32,
     ``p`` in the values' dtype); only the order of the float32 sums over
-    the slots differs, and the rings come out bit for bit the same."""
+    the slots differs, and the rings come out bit for bit the same.
+
+    ``v`` None: LATENT rows in ONE ring ``[B, Lr, 1, W, D]`` (``rings`` a
+    1-tuple; ``window_ring_attend``): the ``jnp`` form on every backend
+    (the kernel steps two rings of whole tiles of slots; a ring of 513
+    latent rows read in place is a later kernel's)."""
+    if v is None:
+        with jax.named_scope("ring_step"):
+            return window_ring_step_jnp(
+                tuple(rings), jnp.asarray(at, jnp.int32), q, k, None,
+                positions, chunk_lens, scale=scale, sink=sink,
+                value_dim=value_dim)
     from production_stack_tpu.ops.pallas.window_ring import (
         ring_step_in_place,
         supports_step_kernel,
@@ -1227,3 +1262,356 @@ def write_kv_to_pool(
     kf = k_new.reshape(-1, *k_new.shape[2:]).transpose(1, 0, 2).astype(k_pool.dtype)
     vf = v_new.reshape(-1, *v_new.shape[2:]).transpose(1, 0, 2).astype(v_pool.dtype)
     return k_pool.at[:, flat].set(kf), v_pool.at[:, flat].set(vf)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse selection over latent rows (models/dots3_note.py; DeepSeek-
+# V3.2's indexer). Beside its latent row a full layer's token caches the
+# INDEX KEY of its indexer, in the SECOND pool (models/config.py:
+# LatentKVSpec.index_dim): the same block table, the same write, and every
+# part of a ``KVView`` that holds values elsewhere (``pool_v``, ``win_v``,
+# ``ring_v``) holds index keys here. A query scores every visible key by
+#
+#     I[t, s] = sum_h w[t, h] * relu(q_idx[t, h] . k_idx[s])      (float32)
+#
+# and attends the ``topk`` keys of largest I (all of them while no more are
+# visible; ties to the lower position, ``lax.top_k``'s rule). Selection is
+# discontinuous like routing: scores and the choice are float32 whatever the
+# activations. Two executions of the one statement:
+#
+#   * a CHUNK (a prefill chunk, a whole sequence, a window-path decode step)
+#     scores its queries against history, ring and itself DENSELY under the
+#     mask ``s in S_t`` (``_selected_chunk``): the same mathematics at more
+#     work than O(T k); the k-th score is found by a radix select over the
+#     float32 bit patterns (``topk_mask``: 32 counting passes, no sort);
+#   * a DECODE STEP over a view that holds the pools reads what it selected
+#     (``_selected_decode``): the rows' index keys (a block of the index
+#     pool is a whole tile: 256 B a key, nothing of the latent rows),
+#     ``lax.top_k``, then a gather of the min(L, topk) selected latent rows,
+#     attended by ``dense_decode_stats``.
+#
+# Both count what they did (int32: keys visible, keys selected, over the live
+# queries), which the model returns among its ``FORWARD_STATS``.
+
+_HI = jax.lax.Precision.HIGHEST
+# Bytes a block of float32 scores may take where a loop bounds them.
+SCORE_BLOCK_BYTES = 128 << 20
+# History lengths a chunk's (or a step's index scan's) work is cut to: the
+# smallest of ``S >> 3, S >> 2, S >> 1, S`` that holds every row's history,
+# chosen at run time (``_by_history``); below this many slots there is one.
+HISTORY_CUT_FLOOR = 2048
+
+
+def _query_block(t: int, bytes_per_query: int) -> int:
+    """The largest divisor of ``t`` whose block of queries stays inside
+    ``SCORE_BLOCK_BYTES`` (at least 1)."""
+    cap = max(1, SCORE_BLOCK_BYTES // max(1, bytes_per_query))
+    return max(d for d in range(1, min(t, cap) + 1) if t % d == 0)
+
+
+def index_scores(q_idx: jax.Array, w_idx: jax.Array,
+                 k_idx: jax.Array) -> jax.Array:
+    """q_idx [B, T, Hi, Di], w_idx [B, T, Hi] float32, k_idx [B, K, Di] ->
+    I [B, T, K] float32. The per-head scores [.., Hi, K] exist a block of
+    queries at a time (64 heads x 2048 queries x 17 k keys would be 9 GB)."""
+    b, t, hi, _ = q_idx.shape
+    k = k_idx.shape[1]
+
+    def block(qb, wb):
+        s = jnp.einsum("bthd,bkd->bthk", qb, k_idx, precision=_HI,
+                       preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(s) * wb[..., None], axis=2)
+
+    tq = _query_block(t, b * hi * k * 4)
+    if tq == t:
+        return block(q_idx, w_idx)
+    nb = t // tq
+    _, out = jax.lax.scan(
+        lambda _, xs: ((), block(*xs)), (),
+        (q_idx.reshape(b, nb, tq, *q_idx.shape[2:]).swapaxes(0, 1),
+         w_idx.reshape(b, nb, tq, hi).swapaxes(0, 1)))
+    return out.swapaxes(0, 1).reshape(b, t, k)
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+
+def topk_mask(scores: jax.Array, pos_k: jax.Array, visible: jax.Array,
+              k: int) -> jax.Array:
+    """The ``k`` visible keys of largest score a query, as a mask: scores
+    [B, T, K] float32, pos_k [B, K] int32 (the key's position: ties go to
+    the lower), visible [B, T, K] bool -> [B, T, K] bool; every visible key
+    where no more than ``k`` are. No sort: the k-th largest score is built
+    bit by bit from counts (32 passes over the scores), and only where
+    scores tie at it does a second search find the position that cuts the
+    tie."""
+    keys = jnp.where(visible, _sortable(scores), jnp.uint32(0))
+
+    def count(mask):
+        return jnp.sum(mask, axis=-1, dtype=jnp.int32)
+
+    def bit(i, thr):
+        cand = thr | jax.lax.shift_left(jnp.uint32(1),
+                                        (31 - i).astype(jnp.uint32))
+        return jnp.where(count(keys >= cand[..., None]) >= k, cand, thr)
+
+    # The largest value that k keys reach (0: fewer than k are visible).
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:2], jnp.uint32))
+    above = keys > thr[..., None]
+    tied = keys == thr[..., None]
+    need = k - count(above)                                     # [B, T]
+
+    def cut_by_position(_):
+        # The largest x with count(tied & pos < x) <= need: positions of
+        # visible keys differ, so exactly ``need`` lie below it.
+        def pbit(i, x):
+            cand = x | jax.lax.shift_left(jnp.int32(1), 30 - i)
+            under = count(tied & (pos_k[:, None, :] < cand[..., None]))
+            return jnp.where(under <= need, cand, x)
+
+        x = jax.lax.fori_loop(0, 31, pbit, jnp.zeros(need.shape, jnp.int32))
+        return tied & (pos_k[:, None, :] < x[..., None])
+
+    tied = jax.lax.cond(jnp.any(count(tied & visible) > need),
+                        cut_by_position, lambda _: tied, None)
+    return visible & (above | tied)
+
+
+def _by_history(longest: jax.Array, slots: int, unit: int, fn):
+    """``fn(slots_read)`` with the smallest of the history cuts that holds
+    ``longest`` slots (whole ``unit``s; every branch returns the same
+    shapes): the work follows the history the rows have, not the table's
+    width."""
+    cuts = sorted({-(-(slots >> i) // unit) * unit for i in (3, 2, 1, 0)})
+    if slots < HISTORY_CUT_FLOOR or len(cuts) == 1:
+        return fn(slots)
+    which = sum((longest > c).astype(jnp.int32) for c in cuts[:-1])
+    return jax.lax.switch(which, [lambda c=c: fn(c) for c in cuts])
+
+
+def _gather_blocks(pool, layer, tables, block_size):
+    """The pages ``tables`` [B, n] of ONE layer of a pool [L, 1, slots, D],
+    a block a slice (whole tiles), the layer an index of the same gather:
+    [B, n * block_size, D]; no layer of the pool is sliced out."""
+    l, _, slots, d = pool.shape
+    b, n = tables.shape
+    where = jnp.stack(
+        [jnp.broadcast_to(jnp.asarray(layer, jnp.int32), tables.shape),
+         tables], axis=-1)
+    out = jax.lax.gather(
+        pool.reshape(l, slots // block_size, block_size, d), where,
+        jax.lax.GatherDimensionNumbers(
+            offset_dims=(2, 3), collapsed_slice_dims=(0, 1),
+            start_index_map=(0, 1)),
+        slice_sizes=(1, 1, block_size, d),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return out.reshape(b, n * block_size, d)
+
+
+def _selected_chunk(q, rows, k_idx, q_idx, w_idx, positions, chunk_lens,
+                    hist, hist_idx, hist_pos, *, scale, value_dim, topk,
+                    with_mask=False):
+    """Chunk queries q [B, T, H, W] (absorbed) over ``hist`` [B, S, W] with
+    its index keys ``hist_idx`` [B, S, Di] (``hist_pos`` [B, S]: a slot's
+    position, or one no query reaches) and the chunk's own ``rows``
+    [B, T, W] / ``k_idx`` [B, T, Di], densely under the selection's mask:
+    ([B, T, H, value_dim], int32[2]) and, ``with_mask``, the mask
+    [B, T, S + T]."""
+    b, t, h, w = q.shape
+    t_idx = jnp.arange(t, dtype=jnp.int32)
+    live = t_idx[None, :] < chunk_lens[:, None]                      # [B, T]
+    pos_c = jnp.where(live, positions, NO_SPAN)
+    keys = jnp.concatenate([hist, rows], axis=1)                  # [B, K, W]
+    pos_k = jnp.concatenate([hist_pos, pos_c], axis=1)               # [B, K]
+    kk = keys.shape[1]
+    visible = pos_k[:, None, :] <= positions[:, :, None]          # [B, T, K]
+    with jax.named_scope("attn_index"):
+        scores = index_scores(
+            q_idx, w_idx, jnp.concatenate([hist_idx, k_idx], axis=1))
+        chosen = topk_mask(scores, pos_k, visible, topk)
+    with jax.named_scope("attn_select"):
+        vals = keys[..., :value_dim]
+        qf = (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+        def block(qb, mb):
+            # qb [B, tq, H, W], mb [B, tq, K]
+            tq = qb.shape[1]
+            s = jax.lax.dot_general(
+                qb.reshape(b, tq * h, w), keys,
+                (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)          # [B, tq*H, K]
+            s = jnp.where(mb[:, :, None, :], s.reshape(b, tq, h, kk),
+                          jnp.float32(_NEG_INF))
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            o = jax.lax.dot_general(
+                p.astype(vals.dtype).reshape(b, tq * h, kk), vals,
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return (o.reshape(b, tq, h, value_dim) / l).astype(q.dtype)
+
+        tq = _query_block(t, b * h * kk * 4)
+        if tq == t:
+            out = block(qf, chosen)
+        else:
+            nb = t // tq
+            _, out = jax.lax.scan(
+                lambda _, xs: ((), block(*xs)), (),
+                (qf.reshape(b, nb, tq, h, w).swapaxes(0, 1),
+                 chosen.reshape(b, nb, tq, kk).swapaxes(0, 1)))
+            out = out.swapaxes(0, 1).reshape(b, t, h, value_dim)
+    stats = jnp.stack([jnp.sum(visible & live[:, :, None]),
+                       jnp.sum(chosen & live[:, :, None])]).astype(jnp.int32)
+    return (out, stats, chosen) if with_mask else (out, stats)
+
+
+def _selected_decode(q, row, k_idx, q_idx, w_idx, positions, live, view,
+                     layer, *, scale, value_dim, topk):
+    """One decode step over a view that holds the POOLS: q [B, H, W], the
+    step's row [B, W] and index key [B, Di], q_idx [B, Hi, Di], w_idx
+    [B, Hi] float32, positions and live [B]. Reads the rows' index keys,
+    ``lax.top_k``s, gathers the selected latent rows and attends them with
+    the train's ring and the token itself: ([B, H, value_dim],
+    int32[2])."""
+    b, _, w = q.shape
+    bs = view.block_size
+    slots = view.block_tables.shape[1] * bs
+    at = jnp.asarray(layer, jnp.int32).reshape(())
+    s_idx = jnp.arange(slots, dtype=jnp.int32)
+    with jax.named_scope("attn_index"):
+        def pool_scores(read):
+            # The index keys of the rows' first ``read`` slots.
+            s = index_scores(
+                q_idx[:, None], w_idx[:, None], _gather_blocks(
+                    view.pool_v, at, view.block_tables[:, :read // bs],
+                    bs))[:, 0]
+            return jnp.pad(s, ((0, 0), (0, slots - read)))
+
+        in_pool = s_idx[None, :] < view.kv_lens[:, None]          # [B, S]
+        s_pool = jnp.where(
+            in_pool, _by_history(jnp.max(view.kv_lens), slots, bs,
+                                 pool_scores), -jnp.inf)
+        # The train's earlier steps and the token itself, in position order
+        # behind the pool's.
+        late, late_idx = row[:, None], k_idx[:, None]
+        seen = jnp.ones((b, 1), bool)
+        if view.ring_k is not None:
+            late = jnp.concatenate([view.ring_k[0], late], axis=1)
+            late_idx = jnp.concatenate([view.ring_v[0], late_idx], axis=1)
+            seen = jnp.concatenate(
+                [view.ring_pos < positions[:, None], seen], axis=1)
+        s_late = jnp.where(seen, index_scores(
+            q_idx[:, None], w_idx[:, None], late_idx)[:, 0], -jnp.inf)
+        scores = jnp.concatenate([s_pool, s_late], axis=1)
+        k = min(topk, scores.shape[1])
+        top, which = jax.lax.top_k(scores, k)                     # [B, k]
+        picked = top > -jnp.inf
+    with jax.named_scope("attn_select"):
+        from_pool = picked & (which < slots)
+        slot = jnp.where(from_pool, which, 0)
+        block = jnp.take_along_axis(view.block_tables, slot // bs, axis=1)
+        where = jnp.stack([jnp.broadcast_to(at, slot.shape),
+                           block * bs + slot % bs], axis=-1)
+        chosen = jax.lax.gather(
+            view.pool_k[:, 0], where, jax.lax.GatherDimensionNumbers(
+                offset_dims=(2,), collapsed_slice_dims=(0, 1),
+                start_index_map=(0, 1)),
+            slice_sizes=(1, 1, w),
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)  # [B, k, W]
+        # A late entry is attended where the top-k named it.
+        late_at = slots + jnp.arange(late.shape[1], dtype=jnp.int32)
+        late_in = jnp.any(picked[:, :, None]
+                          & (which[:, :, None] == late_at), axis=1)
+        keys = jnp.concatenate([chosen, late.astype(chosen.dtype)],
+                               axis=1)[None]
+        bias = jnp.where(jnp.concatenate([from_pool, late_in], axis=1),
+                         0.0, jnp.float32(_NEG_INF))
+        out, _, _ = dense_decode_stats(q, keys, keys, bias, scale=scale)
+    live = live > 0
+    stats = jnp.stack([
+        jnp.sum(jnp.where(live, view.kv_lens + jnp.sum(seen, axis=1), 0)),
+        jnp.sum(picked & live[:, None])]).astype(jnp.int32)
+    return out[..., :value_dim], stats
+
+
+def attend_selected_latent(
+    q: jax.Array,            # [B, T, H, W] absorbed queries, zeros past the key
+    rows: jax.Array,         # [B, T, 1, W] the chunk's latent rows
+    k_idx: jax.Array,        # [B, T, 1, Di] the chunk's index keys
+    q_idx: jax.Array,        # [B, T, Hi, Di] the indexer's queries
+    w_idx: jax.Array,        # [B, T, Hi] float32: its heads' weights
+    positions: jax.Array,    # [B, T]
+    chunk_lens: jax.Array,   # [B] valid tokens per row
+    view: KVView,            # ONE layer's view: index keys where values are
+    layer: Optional[jax.Array] = None,   # pool views only
+    *,
+    scale: float,
+    value_dim: int,
+    topk: int,
+    with_mask: bool = False,
+):
+    """``attend`` over latent rows of which every query reads the ``topk``
+    its indexer picks (see above): ([B, T, H, value_dim] in q.dtype, int32
+    [keys visible, keys selected] over the valid queries). A view that
+    holds the pools is read in place at T == 1 (index keys, then the
+    selected rows) and gathered a layer at T > 1; a gathered window is used
+    as it is; sharded and int8 pools, the sequence-parallel ring and a
+    speculative tree have no execution and raise. ``with_mask`` (a view
+    that holds nothing: a whole sequence): also the selection [B, T, T],
+    for the tests and the on-chip comparison."""
+    if view.tp_mesh is not None or view.sp_mesh is not None \
+            or view.k_scale is not None or view.chunk_bias is not None:
+        raise ValueError(
+            "attend_selected_latent: a sharded or int8 pool, the sequence-"
+            "parallel ring or a speculative tree has no execution")
+    b, t = positions.shape
+    kw = dict(scale=scale, value_dim=value_dim, topk=topk)
+    rows, k_idx = rows[:, :, 0], k_idx[:, :, 0]
+    if view.pool_k is not None and t == 1:
+        out, stats = _selected_decode(
+            q[:, 0], rows[:, 0], k_idx[:, 0], q_idx[:, 0], w_idx[:, 0],
+            positions[:, 0], chunk_lens, view, layer, **kw)
+        return out[:, None], stats
+    bs = view.block_size
+
+    def over(read):
+        # History of ``read`` slots: the layer's pages gathered, or the
+        # window's head; slot s holds position s.
+        if view.pool_k is not None:
+            tables = view.block_tables[:, :read // bs]
+            hist = _gather_blocks(view.pool_k, layer, tables, bs)
+            hist_idx = _gather_blocks(view.pool_v, layer, tables, bs)
+            held = view.kv_lens
+        else:
+            hist, hist_idx = view.win_k[0, :, :read], view.win_v[0, :, :read]
+            held = view.win_len
+        s_idx = jnp.arange(read, dtype=jnp.int32)[None, :]
+        hist_pos = jnp.where(s_idx < held[:, None], s_idx, NO_SPAN)
+        if view.ring_k is not None:
+            hist = jnp.concatenate([hist, view.ring_k[0]], axis=1)
+            hist_idx = jnp.concatenate([hist_idx, view.ring_v[0]], axis=1)
+            hist_pos = jnp.concatenate([hist_pos, view.ring_pos], axis=1)
+        return _selected_chunk(
+            q, rows, k_idx, q_idx, w_idx, positions, chunk_lens,
+            hist.astype(q.dtype), hist_idx.astype(q.dtype), hist_pos, **kw)
+
+    if view.pool_k is not None:
+        return _by_history(jnp.max(view.kv_lens),
+                           view.block_tables.shape[1] * bs, bs, over)
+    if view.win_k is not None:
+        return _by_history(jnp.max(view.win_len), view.win_k.shape[2], 1,
+                           over)
+    hist = jnp.zeros((b, 0, q.shape[-1]), q.dtype)
+    hist_idx = jnp.zeros((b, 0, k_idx.shape[-1]), q.dtype)
+    hist_pos = jnp.zeros((b, 0), jnp.int32)
+    if view.ring_k is not None:
+        hist, hist_idx = view.ring_k[0].astype(q.dtype), \
+            view.ring_v[0].astype(q.dtype)
+        hist_pos = view.ring_pos
+    return _selected_chunk(q, rows, k_idx, q_idx, w_idx, positions,
+                           chunk_lens, hist, hist_idx, hist_pos,
+                           with_mask=with_mask, **kw)

@@ -1452,6 +1452,8 @@ def build_engine_from_args(args: argparse.Namespace) -> ServingEngine:
         data_parallel_size=args.data_parallel_size,
         **({"num_decode_steps": args.num_decode_steps}
            if args.num_decode_steps is not None else {}),
+        **({"max_prefill_seqs": args.max_prefill_seqs}
+           if args.max_prefill_seqs is not None else {}),
         **({"decode_loop": args.decode_loop}
            if args.decode_loop is not None else {}),
         attn_impl=args.attn_impl,
@@ -1546,6 +1548,11 @@ def parse_args(argv=None) -> argparse.Namespace:
                    help="sp degree (ring-attention prefill)")
     p.add_argument("--data-parallel-size", type=int, default=1,
                    help="dp replica count within this process")
+    p.add_argument("--max-prefill-seqs", type=int, default=None,
+                   help="most sequences one prefill dispatch carries "
+                        "(default: as many as the token budget holds at the "
+                        "narrowest chunk; 1: a dispatch is one sequence's "
+                        "chunk)")
     p.add_argument("--num-decode-steps", type=int, default=None,
                    help="fused decode scan length K (default: EngineConfig "
                         "tuned value)")

@@ -696,4 +696,32 @@ def render_engine_metrics(engine: "ServingEngine", model_name: str) -> str:
             f"pstpu:moe_assignments_elsewhere_total{label} "
             f"{s['moe_assignments_elsewhere_total']}",
         ]
+    if "index_keys_visible_total" in s:
+        # Only a model whose full layers attend what a learned indexer
+        # selects counts them (models/dots3_note.py).
+        lines += [
+            "# HELP pstpu:index_keys_visible_total Keys the full layers' "
+            "DECODE queries could see (their contexts, a layer and a "
+            "row-step)",
+            "# TYPE pstpu:index_keys_visible_total counter",
+            f"pstpu:index_keys_visible_total{label} "
+            f"{s['index_keys_visible_total']}",
+            "# HELP pstpu:index_keys_selected_total Latent rows those "
+            "queries' indexers selected and the step read (min(context, "
+            "index_topk) a layer and a row-step)",
+            "# TYPE pstpu:index_keys_selected_total counter",
+            f"pstpu:index_keys_selected_total{label} "
+            f"{s['index_keys_selected_total']}",
+            "# HELP pstpu:index_prefill_keys_visible_total Keys the full "
+            "layers' PREFILL queries could see",
+            "# TYPE pstpu:index_prefill_keys_visible_total counter",
+            f"pstpu:index_prefill_keys_visible_total{label} "
+            f"{s['index_prefill_keys_visible_total']}",
+            "# HELP pstpu:index_prefill_keys_selected_total Keys those "
+            "queries' indexers selected (a chunk scores densely under the "
+            "selection's mask)",
+            "# TYPE pstpu:index_prefill_keys_selected_total counter",
+            f"pstpu:index_prefill_keys_selected_total{label} "
+            f"{s['index_prefill_keys_selected_total']}",
+        ]
     return "\n".join(lines) + "\n"

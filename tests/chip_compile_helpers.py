@@ -90,7 +90,7 @@ def _described_runner(v5e, model_dir: str, **engine):
         model.init_params, model.forward, model.compute_logits
     r.kv_spec, r.state_specs = specs.paged_kv, specs.state
     r.kv_pools = specs.kv_pools
-    r.kv_v_dim = specs.paged_kv.head_dim if specs.latent is None else 0
+    r.kv_v_dim = specs.second_pool_dim
     r.kv_value_dim = specs.paged_kv.head_dim if specs.latent is None \
         else specs.latent.rank
     r.fwd_stats = tuple(getattr(model, "FORWARD_STATS", ()))

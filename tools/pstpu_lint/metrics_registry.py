@@ -162,6 +162,25 @@ REGISTRY: Tuple[Series, ...] = (
            "`ops/moe.py:expert_ffn(here=)`): neither computed nor counted "
            "among `pstpu:moe_assignments_total` here; 0 where every "
            "expert is here"),
+    Series("pstpu:index_keys_visible_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Keys the full layers' DECODE queries could see (a model whose "
+           "full layers attend what a learned indexer selects, "
+           "`models/dots3_note.py`): their contexts, a layer and a "
+           "row-step"),
+    Series("pstpu:index_keys_selected_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Latent rows those queries' indexers selected and the step "
+           "read (min(context, `index_topk`) a layer and a row-step); over "
+           "`pstpu:index_keys_visible_total` it is the share of its "
+           "context a full layer's decode step reads"),
+    Series("pstpu:index_prefill_keys_visible_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Keys the full layers' PREFILL queries could see"),
+    Series("pstpu:index_prefill_keys_selected_total", "counter",
+           ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
+           "Keys those queries' indexers selected (a chunk scores densely "
+           "under the selection's mask)"),
     Series("pstpu:prefix_hit_tokens_unserved_total", "counter",
            ("model_name",), (ENGINE,), ("catalogue", "lifecycle"),
            "Prompt tokens whose K/V the prefix index held but that were "
